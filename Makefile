@@ -108,9 +108,21 @@ train-ops-smoke:
 benchwatch:
 	$(PY) -m tools.benchwatch
 
+# Compile-only check (no chip needed): every Pallas kernel and the
+# rounds/scoring programs against a described v5e. Before chip time.
+aot-check:
+	JAX_PLATFORMS=cpu $(PY) scripts/tpu_aot_check.py
+
+# The chip smoke's control flow on a CPU (the real thing, on the chip,
+# is `python chip_smoke.py` through the chip tool).
+chip-smoke-rehearse:
+	XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+		$(PY) chip_smoke.py --rehearse
+
 native:
 	$(MAKE) -C ddt_tpu/native
 
 .PHONY: lint lint-baseline lint-smoke tsan-audit test report trace-smoke \
 	profile-smoke kernel-smoke chaos-smoke serve-smoke registry-smoke \
-	bigdata-smoke train-ops-smoke benchwatch native
+	bigdata-smoke train-ops-smoke benchwatch aot-check \
+	chip-smoke-rehearse native

@@ -1,0 +1,400 @@
+#!/usr/bin/env python
+"""chip_smoke.py — does the system still train and score on the chip?
+
+One process drives the main path once, through the entry points `cli train`
+and `cli predict` call (ddt_tpu.api.train -> Driver fused path ->
+TPUDevice.grow_rounds; ddt_tpu.api.predict -> TPUDevice.predict_raw), at the
+full width of BASELINE.json config 1: 1,000,000 rows x 28 features from
+`synthetic_binary`, 255 bins, depth 6, backend="tpu", every other field at
+its default. Depth of the ENSEMBLE is cut: ten boosting rounds, not a
+hundred. Then all 1M binned rows are scored with the ten trees.
+
+It asserts WHAT ran (the Pallas kernels, compiled: `tpu_custom_call` in both
+lowered programs; histogram resolved to `pallas`, sibling subtraction on; no
+OOM degrade, no fault retry) and that the results are RIGHT by means that
+need no device and no compiled artefact: chip scores against the NumPy
+traversal, a small chip training against reference/numpy_trainer.
+
+    python chip_smoke.py              # on a machine with a TPU; exit 0 = pass
+    python chip_smoke.py --rehearse   # CPU, 1/100 of the rows, kernels
+                                      # interpreted: debug the control flow
+                                      # before chip time is spent on it
+
+With four devices visible the ten rounds repeat on a rows=4 mesh and on a
+2x2 (rows x features) mesh (rehearse that with
+XLA_FLAGS=--xla_force_host_platform_device_count=4).
+
+No phase is wrapped in a try: any exception ends the run non-zero. Without
+--rehearse there is no CPU path: a platform other than "tpu" is an error.
+Every time printed is a SMOKE TIMING — one sample, compile and transfers
+mixed in as labelled — not a measurement.
+
+Last line of stdout on success, and only then:
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+ROWS, FEATURES, BINS, DEPTH, ROUNDS = 1_000_000, 28, 255, 6, 10
+SEED = 42
+SCORE_CHECK_ROWS = 50_000
+SCORE_TOL = dict(rtol=3e-4, atol=3e-4)      # as __graft_entry__'s oracle check
+# Chip-vs-oracle training parity: the bounds the earlier chip runs measured
+# inside (0.9871 agreement, 0.0024 AUC) and bench.py holds. Never bitwise
+# across platforms (ops/split.py "Determinism boundary").
+PARITY_MIN_AGREEMENT = 0.95
+PARITY_MAX_AUC_DELTA = 0.01
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def timing(what: str, **secs: float) -> None:
+    body = " ".join(f"{k}={v:.2f}s" for k, v in secs.items())
+    say(f"smoke timing (one sample, not a measurement): {what}: {body}")
+
+
+class Compiles:
+    """Backend-compile seconds since construction (telemetry's own
+    jit_compile_seconds counter) — compile printed apart from run."""
+
+    def __init__(self):
+        from ddt_tpu.telemetry import counters
+
+        self._c = counters
+        self._start = counters.snapshot()
+
+    def split(self, wall: float) -> dict:
+        d = self._c.delta(self._start)
+        c = float(d["jit_compile_seconds"])
+        return {"compile": c, "rest": wall - c}
+
+
+def rounds_program_args(be, rows: int, features: int) -> list:
+    """ShapeDtypeStructs of the fused-rounds program's operands, laid out
+    as the backend lays the real ones out (for .lower())."""
+    import jax
+    import jax.numpy as jnp
+
+    def sds(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    rp = -(-rows // be.row_shards) * be.row_shards
+    fp = -(-features // be.feature_partitions) * be.feature_partitions
+    vec = be._row_sharding()
+    return [sds((rp, fp), jnp.uint8, be._named(be.layout.binned_data())),
+            sds((rp,), jnp.float32, vec), sds((rp,), jnp.float32, vec),
+            sds((rp,), jnp.float32, vec)]
+
+
+def check_ensemble(ens, n_trees: int) -> None:
+    n_nodes = 2 ** (DEPTH + 1) - 1
+    assert ens.n_trees == n_trees, ens.n_trees
+    assert ens.feature.shape == (n_trees, n_nodes), ens.feature.shape
+    assert np.isfinite(ens.leaf_value).all(), "non-finite leaf values"
+    assert np.isfinite(ens.split_gain).all(), "non-finite split gains"
+    n_splits = int((~ens.is_leaf).sum())
+    # A depth-6 tree on this data splits far more than once; an ensemble
+    # of stumps would mean the histograms or the gains came back empty.
+    assert n_splits >= 10 * n_trees, f"only {n_splits} splits grown"
+    assert ((ens.feature >= -1) & (ens.feature < FEATURES)).all()
+
+
+def train_and_score(cfg, Xb, y, label: str):
+    """The main path: api.train then api.predict, with the assertions on
+    what ran. Returns (ensemble, raw scores, backend)."""
+    import jax
+
+    from ddt_tpu import api
+    from ddt_tpu.backends import get_backend
+    from ddt_tpu.ops import grow as grow_ops
+    from ddt_tpu.ops import histogram as hist_ops
+    from ddt_tpu.utils import device
+
+    rows = Xb.shape[0]
+    comp = Compiles()
+    t0 = time.perf_counter()
+    res = api.train(Xb, y, cfg, binned=True)
+    wall = time.perf_counter() - t0
+    timing(f"{label} train, {ROUNDS} rounds x {rows} rows, first call",
+           wall=wall, **comp.split(wall))
+    ens = res.ensemble
+    check_ensemble(ens, ROUNDS)
+    loss = res.history[-1]["train_loss"]
+    assert np.isfinite(loss) and loss < np.log(2.0), \
+        f"train loss {loss} not below the prior's {np.log(2.0):.4f}"
+    say(f"{label} train: loss after round {ROUNDS} = {loss:.5f}")
+
+    be = get_backend(cfg)            # the instance api.train just used
+    rounds_fn = be._rounds_fns.get(ROUNDS)
+    assert rounds_fn is not None, \
+        "the fused path did not run (no grow_rounds program was built)"
+    lowered = rounds_fn.lower(*rounds_program_args(be, rows, FEATURES))
+    if device.platform() == "tpu":
+        assert "tpu_custom_call" in lowered.as_text(), \
+            "rounds program carries no compiled Pallas kernel"
+    # Levels 0..5 build 1..32 nodes (half that from level 1 on under
+    # sibling subtraction): every one must resolve to the kernel.
+    for n_nodes in (1, 2, 4, 8, 16, 32):
+        impl = hist_ops.resolve_hist_impl(
+            cfg.hist_impl, n_nodes=n_nodes, n_features=FEATURES,
+            n_bins=BINS)
+        assert impl == "pallas", f"hist impl at {n_nodes} nodes: {impl}"
+    assert grow_ops.resolve_hist_subtraction(cfg.hist_subtraction), \
+        "sibling subtraction resolved off"
+    say(f"{label} train: fused rounds program, hist=pallas, "
+        f"subtraction=on, split_comms={be.split_comms}")
+
+    t0 = time.perf_counter()
+    api.train(Xb, y, cfg, binned=True)
+    timing(f"{label} train, same call again (programs compiled)",
+           wall=time.perf_counter() - t0)
+
+    comp = Compiles()
+    t0 = time.perf_counter()
+    scores = api.predict(ens, Xb, binned=True, raw=True, cfg=cfg)
+    wall = time.perf_counter() - t0
+    timing(f"{label} predict, {rows} rows x {ens.n_trees} trees, first "
+           "call", wall=wall, **comp.split(wall))
+    assert scores.shape == (rows,) and scores.dtype == np.float32, \
+        (scores.shape, scores.dtype)
+    assert np.isfinite(scores).all(), "non-finite scores"
+    fn, ens_dev = be._predict_fn(ens)
+    x_spec = jax.ShapeDtypeStruct(
+        (-(-rows // be.row_shards) * be.row_shards, FEATURES), np.uint8,
+        sharding=be._row_sharding(extra_dims=1))
+    if device.platform() == "tpu":
+        assert "tpu_custom_call" in jax.jit(fn).lower(
+            *ens_dev, x_spec).as_text(), \
+            "scoring program carries no compiled Pallas kernel"
+    t0 = time.perf_counter()
+    again = api.predict(ens, Xb, binned=True, raw=True, cfg=cfg)
+    timing(f"{label} predict, same call again", wall=time.perf_counter() - t0)
+    np.testing.assert_array_equal(scores, again)
+    return ens, scores, be
+
+
+def check_scores_against_numpy(ens, Xb, scores) -> None:
+    n = min(SCORE_CHECK_ROWS, Xb.shape[0])
+    want = ens.predict_raw(Xb[:n], binned=True)      # NumPy traversal
+    np.testing.assert_allclose(scores[:n], want, **SCORE_TOL)
+    say(f"scores: {n} rows match TreeEnsemble.predict_raw (NumPy) within "
+        f"{SCORE_TOL['rtol']:g}; max |diff| = "
+        f"{float(np.abs(scores[:n] - want).max()):.2e}")
+
+
+def parity_against_reference(overrides: dict) -> None:
+    """5 trees on 20k rows: the chip against reference/numpy_trainer (pure
+    NumPy — no native library decides this verdict)."""
+    from ddt_tpu import api
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.data.datasets import synthetic_binary
+    from ddt_tpu.data.quantizer import quantize
+    from ddt_tpu.reference import numpy_trainer
+    from ddt_tpu.utils.metrics import auc
+
+    X, y = synthetic_binary(24_000, n_features=12, seed=31)
+    Xt, yt, Xv, yv = X[:20_000], y[:20_000], X[20_000:], y[20_000:]
+    Xb, mapper = quantize(Xt, n_bins=BINS, seed=31)
+    Xvb = mapper.transform(Xv)
+    cfg = TrainConfig(n_trees=5, max_depth=4, n_bins=BINS, backend="tpu",
+                      **overrides)
+    chip = api.train(Xb, yt, cfg, binned=True, log_every=10**9).ensemble
+    ref = numpy_trainer.fit(Xb, yt, cfg.replace(backend="cpu"))
+    agree = float((chip.feature == ref.feature).mean())
+    d_auc = abs(auc(yv, chip.predict_raw(Xvb, binned=True))
+                - auc(yv, ref.predict_raw(Xvb, binned=True)))
+    say(f"parity vs reference/numpy_trainer (5 trees, 20k rows): "
+        f"split agreement = {agree:.4f} (>= {PARITY_MIN_AGREEMENT}), "
+        f"held-out AUC difference = {d_auc:.5f} "
+        f"(<= {PARITY_MAX_AUC_DELTA})")
+    assert agree >= PARITY_MIN_AGREEMENT, agree
+    assert d_auc <= PARITY_MAX_AUC_DELTA, d_auc
+
+
+def barrier_experiment(be, Xb) -> None:
+    """Is jax.block_until_ready a barrier on this machine? One histogram
+    build timed three ways: not waited for, under block_until_ready, under
+    a scalar read-back (which cannot return before the program ran)."""
+    import jax
+    import jax.numpy as jnp
+
+    rows = Xb.shape[0]
+    rng = np.random.default_rng(SEED)
+    data = be.upload(Xb)
+    g = be._put_rows(rng.standard_normal(rows).astype(np.float32))
+    h = be._put_rows(np.ones(rows, np.float32))
+    ni = be._put_rows(np.zeros(rows, np.int32))
+
+    def build():
+        return be.build_histograms(data, g, h, ni, 1)
+
+    float(jnp.sum(build()))                  # compile both programs
+    jax.block_until_ready(build())
+    enq, blk, rdb = [], [], []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        out = build()
+        enq.append(time.perf_counter() - t0)
+        jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        jax.block_until_ready(build())
+        blk.append(time.perf_counter() - t0)
+        t0 = time.perf_counter()
+        float(jnp.sum(build()))
+        rdb.append(time.perf_counter() - t0)
+    med = {k: float(np.median(v)) * 1e3
+           for k, v in (("enqueue_only", enq), ("block_until_ready", blk),
+                        ("scalar_readback", rdb))}
+    say("smoke timing (one sample of 5, not a measurement): histogram "
+        f"build, {rows} rows x {FEATURES} features, 1 node: "
+        + " ".join(f"{k}={v:.2f}ms" for k, v in med.items()))
+    say("block_until_ready waits for the device: "
+        + ("yes" if med["block_until_ready"] > 0.5 * med["scalar_readback"]
+           else "NO — it returned long before the read-back did"))
+
+
+def four_device_phases(cfg, Xb, y, ens1, scores1) -> None:
+    """The same ten rounds on a rows=4 mesh and on a 2x2 mesh."""
+    rows = Xb.shape[0]
+    for label, kw, shard_shape in (
+            ("rows=4", dict(n_partitions=4), (rows // 4, FEATURES)),
+            ("2x2", dict(mesh_shape=(2, 2)), (rows // 2, FEATURES // 2))):
+        cfg4 = cfg.replace(**kw)
+        ens4, scores4, be4 = train_and_score(cfg4, Xb, y, label)
+        data = be4.upload(Xb)
+        assert len(data.sharding.device_set) == 4, data.sharding
+        shapes = [tuple(s.data.shape) for s in data.addressable_shards]
+        assert shapes == [shard_shape] * 4, shapes
+        del data
+        text = be4._rounds_fns[ROUNDS].lower(
+            *rounds_program_args(be4, rows, FEATURES)).compile().as_text()
+        assert "reduce-scatter" in text, \
+            f"{label}: compiled rounds program has no reduce-scatter"
+        same = all(np.array_equal(getattr(ens4, f), getattr(ens1, f))
+                   for f in ("feature", "threshold_bin", "is_leaf"))
+        if same:
+            say(f"{label}: 4 devices hold a quarter each {shard_shape}, "
+                "reduce-scatter compiled in, all "
+                f"{ens1.n_trees} trees match the one-device trees in "
+                "structure")
+        else:
+            # f32 sums taken in another order can flip a decision whose
+            # candidates tie at a bf16 boundary; anything else is a bug.
+            # The at-scale cross-partition contract (tests/tree_compare):
+            # bitwise up to the first divergence, which must be a PROVEN
+            # tie; later trees train on what that choice changed.
+            sys.path.insert(0, os.path.join(
+                os.path.dirname(os.path.abspath(__file__)), "tests"))
+            from tree_compare import assert_prefix_identity_mod_ties
+
+            prefix, first = assert_prefix_identity_mod_ties(
+                ens1, ens4, cfg.min_split_gain)
+            say(f"{label}: {prefix} of {ens1.n_trees} trees match the "
+                f"one-device trees bitwise; tree {first} diverges at a "
+                "proven bf16 tie (f32 summation order)")
+        np.testing.assert_allclose(scores4[:SCORE_CHECK_ROWS],
+                                   ens4.predict_raw(
+                                       Xb[:SCORE_CHECK_ROWS], binned=True),
+                                   **SCORE_TOL)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU, 1/100 of the rows, kernels interpreted; "
+                         "prints REHEARSAL, never the pass line")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    if args.rehearse:
+        jax.config.update("jax_platforms", "cpu")
+    elif not (os.environ.get("JAX_PLATFORMS")
+              or os.environ.get("JAX_PLATFORM_NAME")):
+        # A chip that cannot be opened must be an error, not a CPU run.
+        jax.config.update("jax_platforms", "tpu")
+    dev = jax.devices()[0]
+    count = len(jax.devices())
+    say(f"device: platform={dev.platform} device_kind={dev.device_kind} "
+        f"count={count}")
+    if dev.platform != "tpu" and not args.rehearse:
+        print(f"chip_smoke: JAX's platform is {dev.platform!r}, not 'tpu' "
+              "(JAX_PLATFORMS="
+              f"{os.environ.get('JAX_PLATFORMS')!r}); this check runs on "
+              "the chip only. `--rehearse` debugs the control flow on a "
+              "CPU and proves nothing.", file=sys.stderr)
+        return 1
+
+    from ddt_tpu.backends.tpu import (DEFAULT_COMPILE_CACHE_DIR,
+                                      enable_persistent_compile_cache)
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.data.datasets import synthetic_binary
+    from ddt_tpu.data.quantizer import quantize
+    from ddt_tpu.telemetry import counters
+
+    enable_persistent_compile_cache()
+    cache_dir = jax.config.jax_compilation_cache_dir
+    n_cached = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+    say(f"compile cache: {cache_dir} ({n_cached} entries at start; "
+        + ("from $JAX_COMPILATION_CACHE_DIR"
+           if os.environ.get("JAX_COMPILATION_CACHE_DIR")
+           else "the checkout's default") + ")")
+    assert os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or cache_dir == DEFAULT_COMPILE_CACHE_DIR, cache_dir
+    counters.install_jax_listener()
+    all_compiles = Compiles()
+    t_start = time.perf_counter()
+
+    rows = ROWS // 100 if args.rehearse else ROWS
+    # Rehearsal forces what a TPU resolves by default, so that the same
+    # kernels run (interpreted) and the same assertions hold.
+    overrides = (dict(hist_impl="pallas", hist_subtraction="on",
+                      predict_impl="pallas") if args.rehearse else {})
+    t0 = time.perf_counter()
+    X, y = synthetic_binary(rows, n_features=FEATURES, seed=SEED)
+    Xb, _ = quantize(X, n_bins=BINS, seed=SEED)
+    del X
+    timing(f"host: generate + quantize {rows} x {FEATURES}",
+           wall=time.perf_counter() - t0)
+    cfg = TrainConfig(n_trees=ROUNDS, max_depth=DEPTH, n_bins=BINS,
+                      backend="tpu", **overrides)
+
+    ens, scores, be = train_and_score(cfg, Xb, y, "one device")
+    check_scores_against_numpy(ens, Xb, scores)
+    parity_against_reference(overrides)
+    barrier_experiment(be, Xb)
+    if count >= 4:
+        four_device_phases(cfg, Xb, y, ens, scores)
+    else:
+        say(f"{count} device(s) visible: the four-device phases did not "
+            "run")
+
+    snap = counters.snapshot()
+    assert snap["hist_oom_degrades"] == 0, snap["hist_oom_degrades"]
+    assert snap["fault_retries"] == 0, snap["fault_retries"]
+    wall = time.perf_counter() - t_start
+    timing("whole run", wall=wall, **all_compiles.split(wall))
+    n_now = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+    say(f"compile cache: {n_now} entries at end ({n_now - n_cached} new); "
+        "hist_oom_degrades=0 fault_retries=0")
+    if args.rehearse:
+        say("REHEARSAL complete: CPU, interpreted kernels, "
+            f"{rows} rows — this proves nothing about the chip")
+        return 0
+    say(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind, "count": count}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

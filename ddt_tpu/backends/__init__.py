@@ -35,8 +35,8 @@ class FPGADevice(DeviceBackend):
 
 # Backend instances are cached on the config fields that shape their traced
 # programs: a TPUDevice's jitted grow/grad/predict functions live on the
-# instance, and recompiling them costs seconds (tens of seconds through a
-# remote-attached chip) — far more than any training round. Fields like
+# instance, and recompiling them costs seconds — far more than any
+# training round. Fields like
 # n_trees never enter a trace, so two train() calls differing only there
 # share one compiled backend. subsample and seed DO enter the fused trace
 # since round 5 (the in-scan counter-based bagging hash bakes both in);

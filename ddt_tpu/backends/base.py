@@ -36,6 +36,10 @@ from ddt_tpu.config import TrainConfig
 from ddt_tpu.models.tree import TreeEnsemble
 
 
+#: The device stamp of work done in NumPy / native code on the host.
+HOST_STAMP = {"platform": "cpu", "device_kind": "host", "n_devices": 1}
+
+
 class HostTree(dict):
     """One grown tree, host-side: np arrays feature/threshold_bin/is_leaf/
     leaf_value, each [n_nodes_total]. Plain dict subclass for clarity."""
@@ -142,8 +146,8 @@ class DeviceBackend(abc.ABC):
 
         The handle lets device backends defer the device→host copy: the
         Driver resolves it one round later, hiding the transfer round-trip
-        (~tens of ms on a remote-attached chip) under the next tree's
-        compute. CPU-resident backends just return the HostTree itself.
+        under the next tree's compute. CPU-resident backends just return
+        the HostTree itself.
         """
 
     def fetch_tree(self, handle: Any) -> HostTree:
@@ -174,6 +178,12 @@ class DeviceBackend(abc.ABC):
         content hash, others may ignore it."""
 
     # ------------------------------------------------------------------ #
+
+    def device_stamp(self) -> dict:
+        """{"platform", "device_kind", "n_devices"} of what this backend
+        computes on — stamped into every printed result and run-log
+        manifest. Default: the host (backends that never touch JAX)."""
+        return dict(HOST_STAMP)
 
     def __repr__(self) -> str:  # pragma: no cover
         return f"<{type(self).__name__} backend={self.name!r}>"

@@ -24,6 +24,8 @@ import time
 import numpy as np
 
 from ddt_tpu import api
+from ddt_tpu.backends import get_backend
+from ddt_tpu.backends.base import HOST_STAMP
 from ddt_tpu.config import BACKENDS, LOSSES, TrainConfig
 from ddt_tpu.data import datasets
 from ddt_tpu.models.tree import TreeEnsemble
@@ -140,7 +142,6 @@ def _predict_streaming(args, bundle) -> int:
         # + upload rides under the current shard's traversal, scores
         # drain asynchronously, and the compiled ensemble stays resident
         # across shards. Per-shard outputs keep host memory O(chunk).
-        from ddt_tpu.backends import get_backend
         from ddt_tpu.streaming import predict_streaming
 
         rows = predict_streaming(
@@ -163,8 +164,15 @@ def _predict_streaming(args, bundle) -> int:
         "wallclock_s": round(dt, 3),
         "rows_per_sec": round(rows / dt, 1),
         "out_dir": out_dir,
+        **_device_stamp(cfg),
     }))
     return 0
+
+
+def _device_stamp(cfg: TrainConfig) -> dict:
+    """platform / device_kind / n_devices of the backend `cfg` selects —
+    every result line names the device its numbers came from."""
+    return get_backend(cfg).device_stamp()
 
 
 def _capture_window(args):
@@ -256,6 +264,7 @@ def _train_streaming(args, X, y, cfg, encoder, status=None) -> int:
         "chunk_rows": chunk_rows_max,
         "wallclock_s": round(dt, 3),
         "model": args.out,
+        **_device_stamp(cfg),
     }
     if history:
         from ddt_tpu.utils.metrics import GREATER_IS_BETTER
@@ -1041,6 +1050,7 @@ def main(argv: list[str] | None = None) -> int:
                 (r["train_loss"] for r in reversed(res.history)
                  if r.get("train_loss") is not None), None),
             "model": args.out,
+            **_device_stamp(cfg),
         }
         if res.best_score is not None:
             out["best_round"] = res.best_round + 1
@@ -1080,8 +1090,11 @@ def main(argv: list[str] | None = None) -> int:
             # Training-time binning, loaded from the artifact — NEVER refit
             # on the scoring data (its distribution may differ).
             scores = api.predict(ens, X, mapper=bundle.mapper, cfg=cfg)
+            stamp = _device_stamp(cfg)
         elif ens.has_raw_thresholds:
-            scores = api.predict(ens, X, cfg=cfg)  # raw-value traversal
+            # Raw-value traversal: NumPy on the host, whatever --backend.
+            scores = api.predict(ens, X, cfg=cfg)
+            stamp = HOST_STAMP
         else:
             raise SystemExit(
                 f"{args.model} carries neither a bin mapper nor raw "
@@ -1095,6 +1108,7 @@ def main(argv: list[str] | None = None) -> int:
             "cmd": "predict", "backend": args.backend, "rows": len(X),
             "trees": ens.n_trees, "wallclock_s": round(dt, 3),
             "rows_per_sec": round(len(X) / dt, 1),
+            **stamp,
         }))
         return 0
 
@@ -1378,6 +1392,7 @@ def main(argv: list[str] | None = None) -> int:
             hist_impl=args.hist_impl, seed=args.seed,
             grad_dtype=args.grad_dtype,
         )
+        out.update(_device_stamp(TrainConfig(backend=args.backend)))
         print(json.dumps(out))
         return 0
 

@@ -76,15 +76,16 @@ from ddt_tpu.telemetry import counters as tele_counters
 from ddt_tpu.telemetry.annotations import phase_ctx
 from ddt_tpu.telemetry.events import (
     PartitionRecorder, RoundRecorder, RunLog, comms_manifest_fields,
-    derive_run_id, emit_early_stop, emit_train_heartbeat, finish_run_log)
+    derive_run_id, device_manifest_fields, emit_early_stop,
+    emit_train_heartbeat, finish_run_log)
 from ddt_tpu.utils import checkpoint
 from ddt_tpu.utils.profiling import PhaseTimer
 
 log = logging.getLogger("ddt_tpu.driver")
 
 # The cap on rounds per fused dispatch is cfg.fused_block_rounds — a
-# config field (not a constant) because it encodes a remote-runtime
-# watchdog interaction that varies by deployment; rationale in
+# config field (not a constant) because it encodes a runtime-watchdog
+# interaction that varies by deployment; rationale in
 # TrainConfig's field docstring.
 
 
@@ -351,6 +352,7 @@ class Driver:
                 # RESOLVED split-finding comms config — report renders
                 # the per-mode comms line from these.
                 **comms_manifest_fields(self.backend),
+                **device_manifest_fields(self.backend),
                 # v3 extras: the xprof cross-reference — a flight-recorder
                 # lane and a profiler session join on run_id through
                 # these (telemetry/profiler.py).
@@ -455,9 +457,8 @@ class Driver:
         t_out = start_round * C
         completed_rounds = cfg.n_trees
         # One-deep fetch pipeline: a device backend's grow_tree returns an
-        # unresolved handle; resolving it costs a device→host round-trip
-        # (~tens of ms on a remote-attached chip), so we fetch tree k while
-        # tree k+1 computes. HOST-side eval needs each tree immediately for
+        # unresolved handle; resolving it costs a device→host round-trip,
+        # so we fetch tree k while tree k+1 computes. HOST-side eval needs each tree immediately for
         # incremental scoring (pipeline bypassed); device-side eval applies
         # the handle on device, so the pipeline stays on.
         pending: tuple | None = None   # (handle, ensemble slot)
@@ -530,8 +531,7 @@ class Driver:
         colsample = cfg.colsample_bytree < 1.0
 
         # Fused block path: backends exposing grow_rounds run whole blocks
-        # of rounds in one device dispatch + one tree fetch (per-round
-        # dispatch latency dominates on a remote-attached chip). Validation
+        # of rounds in one device dispatch + one tree fetch. Validation
         # rides INSIDE the scan (grow_rounds_eval) when its metric has a
         # device twin; EARLY STOPPING rides too — the stopping rule is
         # replayed post-hoc over the block's per-round scores vector

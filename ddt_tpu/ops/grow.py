@@ -53,8 +53,8 @@ from ddt_tpu.ops import grad as grad_ops
 from ddt_tpu.ops import histogram as H
 from ddt_tpu.ops import split as S
 from ddt_tpu.parallel import comms
-from ddt_tpu.parallel import mesh as mesh_lib
 from ddt_tpu.telemetry.annotations import traced_scope
+from ddt_tpu.utils import device
 
 # Perfetto alignment (docs/OBSERVABILITY.md): the traced_scope blocks
 # below name the lowered XLA ops `ddt:fused_round` (one whole level's
@@ -93,7 +93,7 @@ def resolve_hist_subtraction(flag: str, platform: str | None = None,
     if integer_hists:
         return True
     if platform is None:
-        platform = jax.default_backend()
+        platform = device.platform()
     return platform == "tpu"
 
 
@@ -292,7 +292,7 @@ def grow_tree(
     # offset applied below), so the bound must cover shards x local width,
     # not just the local F. axis_size is static at trace time.
     F_global = F if feature_axis_name is None else (
-        F * mesh_lib.static_axis_size(feature_axis_name))
+        F * jax.lax.axis_size(feature_axis_name))
     assert F_global < 2 ** 19, \
         f"routing pack needs global F < 2^19, got {F_global}"
     N = 2 ** (max_depth + 1) - 1

@@ -16,7 +16,11 @@ on-chip:
     inputs per grid step (one tile of TILE_R rows):
       Xb  [TILE_R, F]  uint8  binned features (cast int32 in-VMEM — the
                        only row-sized HBM read, 1 byte/feature/row)
-      g,h [1, TILE_R]  f32    gradient/hessian rows
+      g,h [1, TILE_R]  f32    gradient/hessian rows (one block of the
+                       [n_tiles, 1, TILE_R] fold: Mosaic wants a block's
+                       last two dims divisible by (8, 128) or equal to the
+                       array's, and (1, TILE_R) of [n_tiles, TILE_R] is
+                       neither)
       ni  [1, TILE_R]  i32    level-local node index, -1 = frozen
     on-chip per tile (VPU):
       A   [TILE_R, 2N]   node one-hot weighted by g (cols 0..N-1) and by
@@ -62,8 +66,9 @@ measures.
 
 Contract identical to ops/histogram.py: returns [n_nodes, F, n_bins, 2]
 f32. Tests run this kernel in Pallas interpret mode on CPU
-(tests/test_hist_pallas.py, tests/test_hist_fused.py); the real-chip path
-is exercised by bench.py.
+(tests/test_hist_pallas.py, tests/test_hist_fused.py) and lower its
+compiled form for a TPU (tests/test_tpu_lowering.py); chip_smoke.py runs
+it on the chip.
 """
 
 from __future__ import annotations
@@ -77,6 +82,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ddt_tpu.telemetry.annotations import traced_scope
 from ddt_tpu.telemetry.costmodel import costed
+from ddt_tpu.utils import device
 
 LANE = 128
 
@@ -158,6 +164,12 @@ def _weighted_node_onehot(ni, g, h, n_nodes: int, input_dtype):
     tile_r = ni.shape[0]
     noh = ni[:, None] == jax.lax.broadcasted_iota(
         jnp.int32, (tile_r, n_nodes), 1)
+    if jnp.issubdtype(g.dtype, jnp.integer):
+        # The row -> column relayout ([T] on lanes to [T, 1] on sublanes)
+        # exists in Mosaic for 32-bit elements only; int8/int16 rows
+        # widen first (exact) and A narrows back after the select.
+        g = g.astype(jnp.int32)
+        h = h.astype(jnp.int32)
     zero = jnp.zeros((), g.dtype)
     return jnp.concatenate(
         [jnp.where(noh, g[:, None], zero), jnp.where(noh, h[:, None], zero)],
@@ -302,7 +314,7 @@ def build_histograms_pallas(
     above the matmul fallback.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = device.platform() != "tpu"
     if tile_r is None:
         tile_r = _default_tile_r(n_bins)
     quant = jnp.issubdtype(jnp.dtype(g.dtype), jnp.integer)
@@ -347,7 +359,7 @@ def _build_histograms_pallas(
     acc_dtype = _acc_dtype(input_dtype)
 
     # Stream prologue (XLA, cheap): pad rows to a tile multiple and fold
-    # the per-row vectors to [n_tiles, tile_r] blocks. Pad rows carry
+    # the per-row vectors to [n_tiles, 1, tile_r] blocks. Pad rows carry
     # ni = -1, so they match no node column in-kernel — no weighted
     # one-hot, no int32 input copy, nothing row-sized materialises.
     # Quantized g/h keep their narrow dtype on the stream (the whole
@@ -363,11 +375,13 @@ def _build_histograms_pallas(
         gz = jnp.pad(gz, (0, pad))
         hz = jnp.pad(hz, (0, pad))
         ni = jnp.pad(ni, (0, pad), constant_values=-1)
-    g2 = gz.reshape(n_tiles, tile_r)
-    h2 = hz.reshape(n_tiles, tile_r)
-    ni2 = ni.reshape(n_tiles, tile_r)
+    g2 = gz.reshape(n_tiles, 1, tile_r)
+    h2 = hz.reshape(n_tiles, 1, tile_r)
+    ni2 = ni.reshape(n_tiles, 1, tile_r)
 
-    row_spec = pl.BlockSpec((1, tile_r), lambda i: (i, 0),
+    # Leading dim squeezed: the kernel sees [1, tile_r] refs, and the
+    # block's last two dims EQUAL the array's (module docstring).
+    row_spec = pl.BlockSpec((None, 1, tile_r), lambda i: (i, 0, 0),
                             memory_space=pltpu.VMEM)
 
     def slab(Xs):

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 
 from ddt_tpu.telemetry.annotations import op_scope
 from ddt_tpu.telemetry.costmodel import costed
+from ddt_tpu.utils import device
 
 
 def _mask_inactive(
@@ -157,7 +158,7 @@ def _hist_chunk_matmul(
     # f32 anyway, so this reproduces the TPU path's numerics (used by the
     # bf16-vs-f32 training-quality tests, tests/test_numerics.py).
     emulate_bf16 = (
-        input_dtype == jnp.bfloat16 and jax.default_backend() == "cpu"
+        input_dtype == jnp.bfloat16 and device.platform() == "cpu"
     )
     if emulate_bf16:
         A = A.astype(jnp.float32)
@@ -269,7 +270,7 @@ def resolve_hist_impl(
     if hist_impl != "auto":
         return hist_impl
     if platform is None:
-        platform = jax.default_backend()
+        platform = device.platform()
     if platform == "cpu":
         return "segment"
     if platform != "tpu":

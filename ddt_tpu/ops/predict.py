@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from ddt_tpu.telemetry.annotations import op_scope, traced_scope
 from ddt_tpu.telemetry.costmodel import costed
+from ddt_tpu.utils import device
 
 _DEFAULT_ROW_CHUNK = 65_536
 
@@ -228,7 +229,7 @@ def resolve_use_pallas(use_pallas, binned: bool, n_trees_padded: int,
     from ddt_tpu.ops import predict_pallas
 
     if use_pallas is None:
-        return (binned and jax.default_backend() == "tpu"
+        return (binned and device.platform() == "tpu"
                 and predict_pallas.predict_pallas_fits(
                     n_trees_padded, tree_chunk, max_depth, n_features,
                     n_classes))

@@ -100,8 +100,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ddt_tpu.ops.predict_pallas import WORK_BYTES_PER_LANE
 from ddt_tpu.telemetry.annotations import op_scope, traced_scope
 from ddt_tpu.telemetry.costmodel import costed
+from ddt_tpu.utils import device
 
 # Same ceiling discipline as predict_pallas: the per-tile colval/comp
 # working set + the (now int8/fp16) resident tables + Mosaic's
@@ -118,7 +120,7 @@ _I8_OFFSET = 128
 _NIB_THR_MAX = 14
 #: what the sentinel nibble decodes to in-kernel: 256 > every uint8 bin
 #: value, so "fv > 256" is always False — the +BIG always-left contract
-#: in 4-bit clothing (exact in bf16: 2^8).
+#: in 4-bit clothing (exact in f32).
 _NIB_BIG = 256
 
 
@@ -363,7 +365,7 @@ def predict_lut_fits(
     if n_tc * (n_int + n_leaves) > _MAX_TRACE_SELECTS:
         return False
     lanes = n_int * tree_chunk
-    work = tile_r * lanes * 3                 # colval bf16 + comp bytes
+    work = tile_r * lanes * WORK_BYTES_PER_LANE   # the same descent
     # Resident tables: feat int32 + thr int8 + leaves (2B f16 / 1B int8
     # + 4B scale) + class one-hot — the quantized footprint.
     trees = n_tc * (lanes * 5 + n_leaves * tree_chunk * 2)
@@ -393,7 +395,9 @@ def _lut_kernel(x_ref, feat_ref, thr_ref, val_ref, *rest,
     cat_ref = rest.pop(0) if use_cat else None
     tile_r = x_ref.shape[0]
     lanes = n_int * tc
-    xb = x_ref[:].astype(jnp.bfloat16)                    # bins: exact
+    # uint8 -> bf16 has no Mosaic lowering; widen through int32 (the
+    # cast the histogram kernel uses). Bins <= 255 are exact in bf16.
+    xb = x_ref[:].astype(jnp.int32).astype(jnp.bfloat16)
     f_iota = jax.lax.broadcasted_iota(jnp.int32, (n_feat, lanes), 0)
     acc = jnp.zeros((tile_r, out_ref.shape[1]), jnp.float32)
     for c in range(n_tc):
@@ -401,40 +405,44 @@ def _lut_kernel(x_ref, feat_ref, thr_ref, val_ref, *rest,
         # hist_pallas trick); feat = -1 matches no sublane -> colval 0.
         feat = jnp.broadcast_to(feat_ref[c:c + 1, :], (n_feat, lanes))
         fohT = (feat == f_iota).astype(jnp.bfloat16)      # [F, Nint*Tc]
+        # f32 accumulator, f32 compares, int32 comparison bits: the
+        # three forms the v5e compiler takes (predict_pallas.
+        # _traverse_kernel says why).
         colval = jax.lax.dot_general(
             xb, fohT, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.bfloat16,   # bins <= 255: exact
+            preferred_element_type=jnp.float32,
         )                                                 # [T, Nint*Tc]
-        # Undo the int8 recentring in VMEM: int8 -> bf16 is exact, and
-        # +128 keeps every value an exact bf16 integer <= 255. A clipped
+        # Undo the int8 recentring in VMEM: int8 -> f32 is exact, and
+        # +128 keeps every value an exact integer <= 255. A clipped
         # +BIG threshold decodes to 255 -> "fv > 255" is always False,
         # the always-left contract (module doc, contract 1).
         thr = jnp.broadcast_to(
-            thr_ref[c:c + 1, :], (tile_r, lanes)
-        ).astype(jnp.bfloat16) + jnp.bfloat16(_I8_OFFSET)
-        comp = colval > thr
+            thr_ref[c:c + 1, :].astype(jnp.float32)
+            + jnp.float32(_I8_OFFSET), (tile_r, lanes))
+        comp = (colval > thr).astype(jnp.int32)
         if use_cat:
             cat = jnp.broadcast_to(
-                cat_ref[c:c + 1, :], (tile_r, lanes)) != 0
-            comp = jnp.where(cat, colval != thr, comp)
+                cat_ref[c:c + 1, :].astype(jnp.int32),
+                (tile_r, lanes)) != 0
+            comp = jnp.where(cat, (colval != thr).astype(jnp.int32), comp)
         if use_missing:
             # Reserved-NaN-bin rows (raw bin space — x streams
             # unrecentred) follow the learned direction; pushed-down
             # leaves have colval 0, never the reserved bin.
-            miss = colval == jnp.bfloat16(missing_bin_value)
-            dl = jnp.broadcast_to(
-                dl_ref[c:c + 1, :], (tile_r, lanes)) != 0
-            comp = jnp.where(miss, ~dl, comp)
+            miss = colval == jnp.float32(missing_bin_value)
+            not_dl = 1 - jnp.broadcast_to(
+                dl_ref[c:c + 1, :].astype(jnp.int32), (tile_r, lanes))
+            comp = jnp.where(miss, not_dl, comp)
         # Indexed descent: k-select the path node's bit per level (every
         # node plane a static lane slice of the node-major comp).
         k = jnp.zeros((tile_r, tc), jnp.int32)
         for d in range(max_depth):
             lo = (1 << d) - 1
-            go = jnp.zeros((tile_r, tc), jnp.bool_)
+            go = jnp.zeros((tile_r, tc), jnp.int32)
             for i in range(1 << d):
                 n = lo + i
                 go = jnp.where(k == i, comp[:, n * tc:(n + 1) * tc], go)
-            k = 2 * k + go.astype(jnp.int32)
+            k = 2 * k + go
         # Bottom-level leaf select, dequantizing in VMEM: f16 -> f32 cast
         # is exact; int8 * f32 scale is exact in f32 (contract 2).
         vals = jnp.zeros((tile_r, tc), jnp.float32)
@@ -519,7 +527,7 @@ def predict_effective_lut_ops(
     """LUT scoring core on prebuilt node-major operands (jit-safe; the
     backend caches the device copies of `ops` per model token)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = device.platform() != "tpu"
     if tile_r is None:
         tile_r = _DEFAULT_TILE_R
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
@@ -653,7 +661,7 @@ def predict_lut4_fits(
     if n_tc * (n_int + n_leaves) > _MAX_TRACE_SELECTS:
         return False
     lanes = n_int * tree_chunk
-    work = tile_r * lanes * 3                 # colval bf16 + comp bytes
+    work = tile_r * lanes * WORK_BYTES_PER_LANE   # the same descent
     # Resident tables at the PACKED widths: feat int32 + thr (half a
     # byte/node when nibble-packed, else int8) + leaf nibbles (half a
     # byte per leaf) + f32 scale + class one-hot — half the int8 tier's
@@ -693,7 +701,9 @@ def _lut4_kernel(x_ref, feat_ref, thr_ref, val_ref, scale_ref, coh_ref,
     tile_r = x_ref.shape[0]
     lanes = n_int * tc
     h_l = (n_leaves + 1) // 2
-    xb = x_ref[:].astype(jnp.bfloat16)                    # bins: exact
+    # uint8 -> bf16 has no Mosaic lowering; widen through int32 (the
+    # cast the histogram kernel uses). Bins <= 255 are exact in bf16.
+    xb = x_ref[:].astype(jnp.int32).astype(jnp.bfloat16)
     f_iota = jax.lax.broadcasted_iota(jnp.int32, (n_feat, lanes), 0)
     acc = jnp.zeros((tile_r, out_ref.shape[1]), jnp.float32)
     for c in range(n_tc):
@@ -701,7 +711,7 @@ def _lut4_kernel(x_ref, feat_ref, thr_ref, val_ref, scale_ref, coh_ref,
         fohT = (feat == f_iota).astype(jnp.bfloat16)      # [F, Nint*Tc]
         colval = jax.lax.dot_general(
             xb, fohT, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.bfloat16,   # bins <= 255: exact
+            preferred_element_type=jnp.float32,    # see _lut_kernel
         )                                                 # [T, Nint*Tc]
         if thr_packed:
             # In-VPU nibble decode: low/high nibbles are node blocks
@@ -712,31 +722,32 @@ def _lut4_kernel(x_ref, feat_ref, thr_ref, val_ref, scale_ref, coh_ref,
                 [jnp.bitwise_and(tp, 15),
                  jnp.right_shift(tp, 4)], axis=1)[:, :lanes]
             thr_row = jnp.where(nib >= 15, jnp.int32(_NIB_BIG),
-                                nib).astype(jnp.bfloat16)
+                                nib).astype(jnp.float32)
         else:
             # Lossless int8 form (a model whose thresholds exceed the
             # nibble): undo the recentring exactly like _lut_kernel.
-            thr_row = (thr_ref[c:c + 1, :].astype(jnp.bfloat16)
-                       + jnp.bfloat16(_I8_OFFSET))
+            thr_row = (thr_ref[c:c + 1, :].astype(jnp.float32)
+                       + jnp.float32(_I8_OFFSET))
         thr = jnp.broadcast_to(thr_row, (tile_r, lanes))
-        comp = colval > thr
+        comp = (colval > thr).astype(jnp.int32)
         if use_cat:
             cat = jnp.broadcast_to(
-                cat_ref[c:c + 1, :], (tile_r, lanes)) != 0
-            comp = jnp.where(cat, colval != thr, comp)
+                cat_ref[c:c + 1, :].astype(jnp.int32),
+                (tile_r, lanes)) != 0
+            comp = jnp.where(cat, (colval != thr).astype(jnp.int32), comp)
         if use_missing:
-            miss = colval == jnp.bfloat16(missing_bin_value)
-            dl = jnp.broadcast_to(
-                dl_ref[c:c + 1, :], (tile_r, lanes)) != 0
-            comp = jnp.where(miss, ~dl, comp)
+            miss = colval == jnp.float32(missing_bin_value)
+            not_dl = 1 - jnp.broadcast_to(
+                dl_ref[c:c + 1, :].astype(jnp.int32), (tile_r, lanes))
+            comp = jnp.where(miss, not_dl, comp)
         k = jnp.zeros((tile_r, tc), jnp.int32)
         for d in range(max_depth):
             lo = (1 << d) - 1
-            go = jnp.zeros((tile_r, tc), jnp.bool_)
+            go = jnp.zeros((tile_r, tc), jnp.int32)
             for i in range(1 << d):
                 n = lo + i
                 go = jnp.where(k == i, comp[:, n * tc:(n + 1) * tc], go)
-            k = 2 * k + go.astype(jnp.int32)
+            k = 2 * k + go
         # Unpack + dequantize the WHOLE leaf table once per chunk:
         # two's-complement sign extension of each nibble, then the one
         # f32 multiply by the per-tree scale — the very multiply the
@@ -787,7 +798,7 @@ def predict_effective_lut4_ops(
     backend caches the device copies of `ops` per model token, the AOT
     export lowers exactly this computation per bucket shape)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = device.platform() != "tpu"
     if tile_r is None:
         tile_r = _DEFAULT_TILE_R
     if not jnp.issubdtype(Xc.dtype, jnp.integer):

@@ -15,7 +15,8 @@ Layout strategy (one grid step = one tile of TILE_R rows; ALL tree tables are
 pinned in VMEM for the whole kernel via constant index maps — a 1000-tree
 depth-6 ensemble is ~1 MB):
 
-    X     [TILE_R, F]        int32 bins, cast bf16 in-VMEM.
+    X     [TILE_R, F]        int32 bins, cast bf16 in-VMEM (the matmul
+                             operand; its f32 result is what compares).
     feat  [n_tc, Nint*Tc]    NODE-MAJOR flattened effective features per
                              tree chunk (lane block n holds node n of all
                              Tc trees) — so every descent select is a
@@ -27,8 +28,8 @@ depth-6 ensemble is ~1 MB):
 Per tree chunk (static Python loop, traced once):
     fohT [F, Nint*Tc] bf16 one-hot built on the VPU by SUBLANE-broadcasting
         the feature row against a lane iota (the hist_pallas transposed-
-        kernel trick), then ONE MXU matmul: colval = X @ fohT — the exact
-        bin value at every (row, tree, node).
+        kernel trick), then ONE MXU matmul with an f32 accumulator:
+        colval = X @ fohT — the exact bin value at every (row, tree, node).
     comp = colval > thr (with categorical one-vs-rest and reserved-NaN-bin
         routing applied exactly as ops/predict._descend_comp).
     D-step indexed descent: k[r, t] starts 0; level d selects the path
@@ -39,12 +40,15 @@ Per tree chunk (static Python loop, traced once):
         W bottom planes, then acc += vals @ class-one-hot (f32, HIGHEST —
         bit-stable, mirroring the one-hot path's accumulation order).
 
-Contract: EXACT match with ops/predict.predict_raw at the same tree_chunk
-(missing-value routing, categorical one-vs-rest, softmax round-major classes
-all preserved; integer descent identical, float accumulation mirrored
-term-for-term — tests/test_predict_pallas.py asserts array equality).
-Interpret-mode CPU fallback auto-selects off-TPU, same pattern as
-hist_pallas.py; dispatch lives in ops/predict.resolve_use_pallas (the
+Contract: the SAME leaf per (row, tree) as ops/predict.predict_raw at the
+same tree_chunk (missing-value routing, categorical one-vs-rest, softmax
+round-major classes all preserved; the integer descent is identical). The
+float accumulation is written term for term like the one-hot path's, but
+the summation order inside a dot belongs to the compiler, so scores agree
+to f32 rounding, not bitwise (tests/test_predict_pallas.py: equality on
+dyadic leaf values, a 1e-6 tolerance on random ones).
+Interpret mode auto-selects off-TPU (utils/device.platform), same pattern
+as hist_pallas.py; dispatch lives in ops/predict.resolve_use_pallas (the
 `use_pallas` flag on predict_raw / predict_raw_effective, one-hot fallback).
 """
 
@@ -59,17 +63,35 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ddt_tpu.telemetry.annotations import op_scope, traced_scope
 from ddt_tpu.telemetry.costmodel import costed
+from ddt_tpu.utils import device
 
-# VMEM ceiling for auto-dispatch: the per-chunk [TILE_R, Nint*Tc] colval
-# (bf16) + comparison bits + the resident tree tables + Mosaic's
-# double-buffered input windows must fit ~16 MB/core; 12 MB leaves the
-# same headroom hist_pallas budgets.
+# VMEM ceiling for auto-dispatch: the kernel's working set + the resident
+# tree tables + Mosaic's double-buffered operand windows must fit the
+# 16 MiB scoped-VMEM limit; 12 MB leaves the same headroom hist_pallas
+# budgets.
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _DEFAULT_TILE_R = 256
+# Working-set bytes per (row, lane) of a tree chunk's [TILE_R, Nint*Tc]
+# colval (f32) and comparison bits (int32). NOT 8: the compiler streams
+# both through vector registers plane by plane and spills little. Taken
+# from the compiler's own account — AOT compiles for a described v5e with
+# the scoped limit forced to 1 MiB, so that each reports its allocation
+# (PERF.md, PR 21): <= 0.9 B per (row, lane) at every probed shape (tile
+# 256 and 512, depth 4-8, with and without the missing/cat operands, 1
+# and 7 classes), and tile 512 x depth 8 (8.4M row-lanes) compiled under
+# the real limit where tile 1024 x depth 8 did not. 2 keeps every
+# admitted shape inside what was shown to compile.
+WORK_BYTES_PER_LANE = 2
 # Static-unroll ceiling: the kernel traces n_tc * (Nint + W + ~4) ops;
 # past this the trace (and Mosaic compile) grows pathological — the
 # one-hot path is the right tool for such shapes anyway.
 _MAX_TRACE_SELECTS = 32_768
+
+
+def _window_bytes(rows: int, cols: int) -> int:
+    """VMEM bytes of one pipelined 32-bit operand window: padded to whole
+    (8, 128) tiles, and double-buffered."""
+    return 2 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128) * 4
 
 
 def predict_pallas_fits(
@@ -93,12 +115,15 @@ def predict_pallas_fits(
     if n_tc * (n_int + n_leaves) > _MAX_TRACE_SELECTS:
         return False
     lanes = n_int * tree_chunk
-    work = tile_r * lanes * 3                 # colval bf16 + comp bytes
-    trees = n_tc * (lanes * 8                 # feat i32 + thr f32
-                    + n_leaves * tree_chunk * 4)
-    trees += n_trees_padded * n_classes * 4   # class one-hot
-    x_tile = tile_r * n_features * 4
-    out = tile_r * max(n_classes, 8) * 4
+    work = tile_r * lanes * WORK_BYTES_PER_LANE
+    # Resident tables, every one a whole-array window: feat i32, thr f32,
+    # the optional dl and cat i32 (counted always — the guard is asked
+    # before the operands exist), bottom values, class one-hot.
+    trees = 4 * _window_bytes(n_tc, lanes)
+    trees += _window_bytes(n_tc, n_leaves * tree_chunk)
+    trees += _window_bytes(n_trees_padded, n_classes)
+    x_tile = _window_bytes(tile_r, n_features)
+    out = _window_bytes(tile_r, n_classes)
     return work + trees + x_tile + out <= _VMEM_BUDGET_BYTES
 
 
@@ -126,36 +151,41 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         # matches no sublane -> colval 0 < thr(+BIG) -> always-left.
         feat = jnp.broadcast_to(feat_ref[c:c + 1, :], (n_feat, lanes))
         fohT = (feat == f_iota).astype(jnp.bfloat16)      # [F, Nint*Tc]
+        # bf16 operands (bins <= 255 and the 0/1 one-hot are exact), f32
+        # accumulator: the MXU accumulates in 32 bits only, and the v5e's
+        # VPU has no bf16 compare, so colval stays f32 from here on.
         colval = jax.lax.dot_general(
             xb, fohT, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.bfloat16,   # bins <= 255: exact
+            preferred_element_type=jnp.float32,
         )                                                 # [T, Nint*Tc]
-        thr = jnp.broadcast_to(
-            thr_ref[c:c + 1, :], (tile_r, lanes)).astype(jnp.bfloat16)
-        comp = colval > thr
+        thr = jnp.broadcast_to(thr_ref[c:c + 1, :], (tile_r, lanes))
+        # Comparison bits live as int32 0/1, never as a bool ARRAY:
+        # Mosaic keeps i1 only as a select's predicate — a select BETWEEN
+        # bool arrays needs an i8 -> i1 truncation it does not have.
+        comp = (colval > thr).astype(jnp.int32)
         if use_cat:
             # One-vs-rest nodes (pre-gated on eff_feat >= 0 in the
             # prologue): the matched bin goes left.
             cat = jnp.broadcast_to(
                 cat_ref[c:c + 1, :], (tile_r, lanes)) != 0
-            comp = jnp.where(cat, colval != thr, comp)
+            comp = jnp.where(cat, (colval != thr).astype(jnp.int32), comp)
         if use_missing:
             # Reserved-NaN-bin rows follow the learned direction;
             # pushed-down leaves have colval 0, never the reserved bin.
-            miss = colval == jnp.bfloat16(missing_bin_value)
-            dl = jnp.broadcast_to(
-                dl_ref[c:c + 1, :], (tile_r, lanes)) != 0
-            comp = jnp.where(miss, ~dl, comp)
+            miss = colval == jnp.float32(missing_bin_value)
+            not_dl = 1 - jnp.broadcast_to(
+                dl_ref[c:c + 1, :], (tile_r, lanes))
+            comp = jnp.where(miss, not_dl, comp)
         # Indexed descent: k-select the path node's bit per level. Every
         # node plane is a STATIC lane slice of the node-major comp.
         k = jnp.zeros((tile_r, tc), jnp.int32)
         for d in range(max_depth):
             lo = (1 << d) - 1
-            go = jnp.zeros((tile_r, tc), jnp.bool_)
+            go = jnp.zeros((tile_r, tc), jnp.int32)
             for i in range(1 << d):
                 n = lo + i
                 go = jnp.where(k == i, comp[:, n * tc:(n + 1) * tc], go)
-            k = 2 * k + go.astype(jnp.int32)
+            k = 2 * k + go
         # Bottom-level leaf select (exact: k matches exactly one plane).
         vals = jnp.zeros((tile_r, tc), jnp.float32)
         for j in range(n_leaves):
@@ -194,12 +224,11 @@ def predict_effective_pallas(
     """Pallas twin of ops/predict._predict_effective (binned data only).
 
     interpret=None auto-selects Pallas interpreter mode off-TPU (the CPU
-    test suite exercises the identical kernel logic; the compiled path
-    needs a real chip) — the same fallback pattern as
-    hist_pallas.build_histograms_pallas. Jit-safe: callable inside
+    test suite exercises the identical kernel logic) — the same pattern
+    as hist_pallas.build_histograms_pallas. Jit-safe: callable inside
     predict_raw / predict_raw_effective traces or standalone."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = device.platform() != "tpu"
     if tile_r is None:
         tile_r = _DEFAULT_TILE_R
     if not jnp.issubdtype(Xc.dtype, jnp.integer):

@@ -1,6 +1,5 @@
 """One-home collectives for split finding: the single spelling of
-psum/reduce_scatter/all_gather (+ compressed payloads), like
-`mesh.shard_map` is for shard_map.
+psum/reduce_scatter/all_gather (+ compressed payloads).
 
 Every cross-device byte the trainer moves funnels through this module —
 the ddtlint `one-home-collective` rule flags raw `jax.lax.psum`/
@@ -11,15 +10,11 @@ edit and the `hist_allreduce_bytes` counter's payload model
 
 Three concerns live here (ISSUE 10, docs/PERF.md "Histogram comms"):
 
-- **Version-portable collectives.** `psum`/`pmax`/`pmin`/`all_gather`
-  are thin wrappers (identity when `axis_name` is None, so single-device
-  traces share the callers' code path). `reduce_scatter` takes
-  `jax.lax.psum_scatter(tiled=True)` where the runtime supports it
-  (this image's 0.4.37 does, lowering to a true `reduce-scatter` HLO
-  over tuple (hosts, rows) axes) and falls back to psum + a local
-  dynamic slice — same VALUES and same memory contract for the caller,
-  full allreduce wire cost (the fallback is for portability, not
-  performance; `HAS_PSUM_SCATTER` says which spelling is live).
+- **The collectives.** `psum`/`pmax`/`pmin`/`all_gather` are thin
+  wrappers (identity when `axis_name` is None, so single-device traces
+  share the callers' code path). `reduce_scatter` is
+  `jax.lax.psum_scatter(tiled=True)`, which lowers to a true
+  `reduce-scatter` HLO, tuple (hosts, rows) axes included.
 
 - **Reduce-scatter split finding** (`cfg.split_comms`): instead of
   psumming the full `[n, F, B, 2]` level histogram to every device and
@@ -58,8 +53,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ddt_tpu.parallel import mesh as mesh_lib
 from ddt_tpu.telemetry.annotations import traced_scope
+from ddt_tpu.utils import device
 
 #: cfg.split_comms values (config.py validates; backends resolve "auto").
 SPLIT_COMMS = ("auto", "allreduce", "reduce_scatter")
@@ -69,11 +64,6 @@ COMMS_DTYPES = ("f32", "bf16", "int32_fixed")
 #: Wire bytes per histogram entry under each comms dtype (the
 #: hist_allreduce_bytes payload model reads this — one home).
 COMMS_DTYPE_BYTES = {"f32": 4, "bf16": 2, "int32_fixed": 4}
-
-#: Whether this jax exposes the true reduce-scatter collective. Absent
-#: (ancient jax), reduce_scatter() below emulates with psum + slice —
-#: same values, allreduce wire cost.
-HAS_PSUM_SCATTER = hasattr(jax.lax, "psum_scatter")
 
 #: int32_fixed headroom: the per-partial quantized magnitude cap is
 #: (2^30 - 1) // P so the P-way integer sum can never overflow int32
@@ -93,9 +83,9 @@ def axis_size(axis_name) -> int:
     if isinstance(axis_name, tuple):
         n = 1
         for a in axis_name:
-            n *= mesh_lib.static_axis_size(a)
+            n *= jax.lax.axis_size(a)
         return n
-    return mesh_lib.static_axis_size(axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def flat_axis_index(axis_name):
@@ -107,7 +97,7 @@ def flat_axis_index(axis_name):
     if isinstance(axis_name, tuple):
         idx = jax.lax.axis_index(axis_name[0])
         for a in axis_name[1:]:
-            idx = idx * mesh_lib.static_axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return idx.astype(jnp.int32)
     return jax.lax.axis_index(axis_name).astype(jnp.int32)
 
@@ -148,8 +138,7 @@ def reduce_scatter(x, axis_name, dim: int):
     """Sum `x` over `axis_name` and hand each shard its contiguous
     1/P block of dimension `dim` (shard i gets block i in flattened
     axis order). `x.shape[dim]` must be a multiple of the axis size —
-    callers pad (see `pad_to_multiple`). Falls back to psum + local
-    slice when the runtime lacks psum_scatter."""
+    callers pad (see `pad_to_multiple`)."""
     if axis_name is None:
         return x
     P = axis_size(axis_name)
@@ -157,17 +146,9 @@ def reduce_scatter(x, axis_name, dim: int):
         raise ValueError(
             f"reduce_scatter dim {dim} extent {x.shape[dim]} not a "
             f"multiple of the axis size {P}; pad first")
-    if HAS_PSUM_SCATTER:
-        with traced_scope("comms:reduce_scatter"):
-            return jax.lax.psum_scatter(
-                x, axis_name, scatter_dimension=dim, tiled=True)
-    # Portability fallback: full allreduce then a local slice — same
-    # values and caller contract, no wire saving.
     with traced_scope("comms:reduce_scatter"):
-        full = jax.lax.psum(x, axis_name)
-        block = x.shape[dim] // P
-        return jax.lax.dynamic_slice_in_dim(
-            full, flat_axis_index(axis_name) * block, block, axis=dim)
+        return jax.lax.psum_scatter(
+            x, axis_name, scatter_dimension=dim, tiled=True)
 
 
 def pad_to_multiple(x, dim: int, multiple: int):
@@ -387,5 +368,5 @@ def resolve_comms_slabs(flag: int, *, distributed: bool,
     if not distributed:
         return 1
     if platform is None:
-        platform = jax.default_backend()
+        platform = device.platform()
     return _AUTO_SLABS if platform == "tpu" else 1

@@ -21,6 +21,7 @@ import logging
 import re
 
 import jax
+from jax.sharding import AxisType
 
 log = logging.getLogger("ddt_tpu.parallel")
 
@@ -127,40 +128,26 @@ def match_partition_rules(rules, names) -> tuple:
     return tuple(out)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable jax.shard_map — the ONE home of the API seam.
+def _make_mesh(shape: tuple, names: tuple, devices: list):
+    """jax.make_mesh with AUTO axes over exactly `devices`.
 
-    jax promoted shard_map from jax.experimental to the top level (and
-    renamed check_rep -> check_vma) across the versions this repo must
-    run on; every shard_map site in the backend routes through here so
-    the codebase tracks exactly one spelling. Older jax (<= 0.4.x,
-    including this image's 0.4.37) takes the experimental import with
-    the check_rep spelling; newer jax takes jax.shard_map verbatim."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # The legacy rep-checker predates the VMA formulation and rejects
-    # sound programs the new checker accepts (scan carries that start
-    # replicated, gathered argmaxes — its own error message says to
-    # disable it). Correctness on old jax is held by the suite's
-    # bit-identity contracts (N-partition == 1-partition trees), not by
-    # the static checker, so it is off unconditionally here.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
-def static_axis_size(axis_name) -> int:
-    """Static (trace-time python int) extent of a named mesh axis — the
-    version-portable jax.lax.axis_size (absent before jax 0.5; there,
-    jax.core.axis_frame(name) IS the size). Must be called inside a
-    shard_map/collective trace over the axis, like the original."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    import jax.core as _core
-
-    return int(_core.axis_frame(axis_name))
+    Auto: every sharded program here is a shard_map with explicit specs
+    (SpecLayout), and the installed jax makes `Explicit` axes by default,
+    under which plain jnp ops on the sharded handles outside shard_map
+    raise ShardingTypeError. Device order is jax.make_mesh's: on a TPU it
+    follows the physical torus, and it REFUSES a device set that is not a
+    contiguous box of it (3 of a 2x2's 4 chips, say) with a bare
+    AssertionError — re-raised here with the reason."""
+    try:
+        return jax.make_mesh(
+            shape, names, axis_types=(AxisType.Auto,) * len(shape),
+            devices=devices)
+    except AssertionError as e:
+        raise ValueError(
+            f"cannot lay a {dict(zip(names, shape))} mesh over devices "
+            f"{[d.id for d in devices]}: on a TPU the device set must be a "
+            "contiguous box of the physical torus (jax.make_mesh refused "
+            "it)") from e
 
 
 def make_row_mesh(
@@ -173,8 +160,7 @@ def make_row_mesh(
         raise ValueError(
             f"n_partitions={n_partitions} but only {len(devs)} devices visible"
         )
-    return jax.make_mesh((n_partitions,), (ROWS_AXIS,),
-                         devices=devs[:n_partitions])
+    return _make_mesh((n_partitions,), (ROWS_AXIS,), devs[:n_partitions])
 
 
 def make_pod_mesh(
@@ -205,14 +191,11 @@ def make_pod_mesh(
             f"have {len(devs)}"
         )
     if feature_partitions > 1:
-        return jax.make_mesh(
+        return _make_mesh(
             (n_hosts, devices_per_host, feature_partitions),
-            (HOSTS_AXIS, ROWS_AXIS, "features"), devices=devs[:n_dev],
-        )
-    return jax.make_mesh(
-        (n_hosts, devices_per_host), (HOSTS_AXIS, ROWS_AXIS),
-        devices=devs[:n_dev],
-    )
+            (HOSTS_AXIS, ROWS_AXIS, FEATURES_AXIS), devs[:n_dev])
+    return _make_mesh((n_hosts, devices_per_host), (HOSTS_AXIS, ROWS_AXIS),
+                      devs[:n_dev])
 
 
 def make_mesh_2d(
@@ -244,14 +227,11 @@ def make_mesh_2d(
             f"have {len(devs)}"
         )
     if n_hosts > 1:
-        return jax.make_mesh(
+        return _make_mesh(
             (n_hosts, row_partitions, feature_partitions),
-            (HOSTS_AXIS, ROWS_AXIS, FEATURES_AXIS), devices=devs[:n_dev],
-        )
-    return jax.make_mesh(
-        (row_partitions, feature_partitions), (ROWS_AXIS, FEATURES_AXIS),
-        devices=devs[:n_dev],
-    )
+            (HOSTS_AXIS, ROWS_AXIS, FEATURES_AXIS), devs[:n_dev])
+    return _make_mesh((row_partitions, feature_partitions),
+                      (ROWS_AXIS, FEATURES_AXIS), devs[:n_dev])
 
 
 def shard_ready_times(arr, poll_interval_s: float = 5e-5,
@@ -262,13 +242,11 @@ def shard_ready_times(arr, poll_interval_s: float = 5e-5,
     The flight recorder's probe (telemetry.events.PartitionRecorder):
     polling each shard's is_ready() records every device's completion
     moment independently — the per-partition wall-time signal a single
-    block_until_ready collapses into one number. Where the runtime
-    exposes no is_ready (old jax array wrappers), falls back to blocking
-    shard-by-shard in device order, which keeps the MAX (the straggler)
-    exact while flattening earlier lanes onto the running prefix-max —
-    documented bias, not silent error. Returns None for values with no
-    shard view (host arrays). Only meaningful to call on a handle whose
-    producer has been dispatched; the probe IS a barrier on the array."""
+    block_until_ready collapses into one number. Shards still pending at
+    `timeout_s` are blocked on in device order (their lanes flatten onto
+    the running prefix-max). Returns None for values with no shard view
+    (host arrays). Only meaningful to call on a handle whose producer
+    has been dispatched; the probe IS a barrier on the array."""
     import time as _time
 
     try:
@@ -277,17 +255,15 @@ def shard_ready_times(arr, poll_interval_s: float = 5e-5,
         return None
     pending = {int(s.device.id): s.data for s in shards}
     out: dict[int, float] = {}
-    can_poll = all(hasattr(d, "is_ready") for d in pending.values())
-    if can_poll:
-        deadline = _time.perf_counter() + timeout_s
-        while pending and _time.perf_counter() < deadline:
-            for dev in list(pending):
-                if pending[dev].is_ready():
-                    out[dev] = _time.perf_counter()
-                    del pending[dev]
-            if pending:
-                _time.sleep(poll_interval_s)
-    for dev in sorted(pending):              # fallback / timeout residue
+    deadline = _time.perf_counter() + timeout_s
+    while pending and _time.perf_counter() < deadline:
+        for dev in list(pending):
+            if pending[dev].is_ready():
+                out[dev] = _time.perf_counter()
+                del pending[dev]
+        if pending:
+            _time.sleep(poll_interval_s)
+    for dev in sorted(pending):              # timeout residue
         pending[dev].block_until_ready()
         out[dev] = _time.perf_counter()
     return sorted(out.items())

@@ -18,24 +18,21 @@ Three pieces:
 - **costed(op, phase)** — a transparent wrapper for jit entry points
   (`CostedFn`). When a collector is ACTIVE (a run log is attached), the
   first top-level call with a new argument signature AOT-lowers and
-  compiles the same program once more purely for analysis
-  (`fn.lower(*args).compile()`), records FLOPs / bytes-accessed /
+  compiles the program (`fn.lower(*args).compile()` — the executable
+  the call itself then reuses), records FLOPs / bytes-accessed /
   HBM-byte breakdown, and counts subsequent calls per signature. When no
   collector is active the wrapper is ONE module-global read per call —
   the hot path never lowers, never compiles, never syncs (guard-tested
   with the rest of the disabled-telemetry invariant). Calls made while
   tracing (the op riding inside a larger jit/shard_map program) are
   skipped: the enclosing program's own entry point carries the cost.
-  The analysis compile is paid once per (op, signature) per telemetry
-  run; with the persistent XLA compile cache enabled it degrades to a
-  disk read.
 - **Collector / activate() / flush_into()** — per-run capture state. The
   trainers activate on telemetry runs, and `finish_run_log` flushes one
   schema-v3 `cost_analysis` event per (op, signature) — per-call FLOPs
   and bytes plus the observed call count — into the run log's epilogue.
 - **roofline_table()** — the read side: join cost events against the
   run's `phase_timings` and the compile-time counters, compute achieved
-  GFLOP/s and GB/s against per-platform peak ceilings, and attach a
+  GFLOP/s and GB/s against the device's peak ceilings, and attach a
   bound-by verdict. Pure host math, no jax — a log reports anywhere
   (the report CLI contract).
 
@@ -56,19 +53,17 @@ try:
 except ImportError:               # jax-less host: capture never activates
     jax = None
 
-#: Nominal per-platform roofline ceilings: peak GFLOP/s and HBM GB/s.
-#: These are deployment constants, not measurements — the v5e figures are
-#: the spec sheet (bf16 MXU peak, HBM2E bandwidth per chip); the cpu/gpu
-#: rows are order-of-magnitude defaults so off-TPU logs still render a
-#: table. Utilization fractions, not absolute verdicts, are the signal —
-#: refine per fleet in one place here.
-#: `coll_gbs` is the interconnect ceiling the comms roofline row divides
-#: by: order-of-magnitude per-chip collective bandwidth (v5e ICI; DCN is
-#: lower still — the verdict is about whether the wire binds at all, not
-#: which wire).
+#: Roofline ceilings by `device_kind` (as jax.devices()[0].device_kind
+#: reports it): peak GFLOP/s, HBM GB/s and, for the comms row, per-chip
+#: collective GB/s. "TPU v5 lite" is the v5e: 197 TFLOP/s bf16 and
+#: 819 GB/s of HBM per chip (Google Cloud documentation, "TPU v5e");
+#: `coll_gbs` is an order of magnitude for its ICI (the comms verdict asks
+#: whether the wire binds at all, not which wire). "cpu" is NOT a
+#: measurement of any host: a nominal row so that the CPU test suite's
+#: logs render a table. A device that is not here has no peaks to divide
+#: by — peaks_for raises; add the row with its source.
 PEAK_CEILINGS: dict[str, dict] = {
-    "tpu": {"gflops": 197_000.0, "gbs": 819.0, "coll_gbs": 90.0},
-    "gpu": {"gflops": 19_500.0, "gbs": 900.0, "coll_gbs": 300.0},
+    "TPU v5 lite": {"gflops": 197_000.0, "gbs": 819.0, "coll_gbs": 90.0},
     "cpu": {"gflops": 150.0, "gbs": 30.0, "coll_gbs": 10.0},
 }
 
@@ -184,15 +179,13 @@ def _capture(fn, args, kwargs) -> dict:
     memory analyses. One extra backend compile per (op, signature),
     paid only on telemetry runs; failures degrade to a zeroed record
     carrying the error — cost capture must never fail a training run."""
-    from ddt_tpu.telemetry import counters as tele_counters
-
     try:
-        # The analysis compile must not bill itself to the recompile
-        # counters it exists to explain (counters.suppress_compile_
-        # counting); its wall time inside the enclosing phase span is a
-        # one-time cost documented in docs/OBSERVABILITY.md.
-        with tele_counters.suppress_compile_counting():
-            compiled = fn.lower(*args, **kwargs).compile()
+        # This compile IS the program's one counted compile: jit keeps
+        # the executable, and the call that follows finds it (no second
+        # backend compile, so jit_compiles matches a telemetry-less
+        # run's). Its wall time lands in the enclosing phase span, like
+        # any first compile.
+        compiled = fn.lower(*args, **kwargs).compile()
         ca = compiled.cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else {}
@@ -200,7 +193,7 @@ def _capture(fn, args, kwargs) -> dict:
         rec = {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
-            "platform": str(jax.default_backend()),
+            **_device_fields(),
         }
         try:
             ma = compiled.memory_analysis()
@@ -216,9 +209,13 @@ def _capture(fn, args, kwargs) -> dict:
         return rec
     except (TypeError, ValueError, RuntimeError, NotImplementedError,
             AttributeError, KeyError, OSError) as e:
-        return {"flops": 0.0, "bytes_accessed": 0.0,
-                "platform": str(jax.default_backend()) if jax else None,
+        return {"flops": 0.0, "bytes_accessed": 0.0, **_device_fields(),
                 "error": f"{type(e).__name__}: {e}"[:300]}
+
+
+def _device_fields() -> dict:
+    d = jax.devices()[0]
+    return {"platform": d.platform, "device_kind": d.device_kind}
 
 
 class CostedFn:
@@ -268,7 +265,7 @@ def analyze(fn, *args, **kwargs) -> dict:
     ({flops, bytes_accessed, platform, ...})."""
     if jax is None:
         return {"flops": 0.0, "bytes_accessed": 0.0, "platform": None,
-                "error": "jax unavailable"}
+                "device_kind": None, "error": "jax unavailable"}
     if not hasattr(fn, "lower"):
         fn = jax.jit(fn)
     return _capture(fn, args, kwargs)
@@ -278,16 +275,26 @@ def analyze(fn, *args, **kwargs) -> dict:
 # the read side: roofline join (pure host math — no jax)
 # ------------------------------------------------------------------ #
 
-def peaks_for(platform: str | None) -> dict:
-    return PEAK_CEILINGS.get(platform or "", PEAK_CEILINGS["cpu"])
+def peaks_for(device_kind: str | None) -> dict:
+    """PEAK_CEILINGS row of a device kind. An unknown (or unstamped)
+    device is an error, never another device's peaks."""
+    try:
+        return PEAK_CEILINGS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(PEAK_CEILINGS)} (telemetry/costmodel.PEAK_CEILINGS "
+            "— add the row with its source)") from None
 
 
 def roofline_table(phases: list[dict], cost_events: list[dict],
                    counters: dict | None = None,
                    wallclock_s: float | None = None) -> list[dict]:
     """Join `phase_timings` records against `cost_analysis` events into
-    roofline rows: achieved GFLOP/s and GB/s per phase vs the platform's
-    peak ceilings, with a bound-by verdict.
+    roofline rows: achieved GFLOP/s and GB/s per phase vs the peak
+    ceilings of the device the events name (`device_kind`; a log whose
+    cost events name none, or one PEAK_CEILINGS lacks, raises), with a
+    bound-by verdict.
 
     `phases` is PhaseTimer.as_json() (the run log's phase_timings);
     `cost_events` the run's cost_analysis records. Phases without cost
@@ -299,11 +306,11 @@ def roofline_table(phases: list[dict], cost_events: list[dict],
     dropped)."""
     ms_by_phase = {p["phase"]: p for p in phases}
     ev_by_phase: dict[str, list] = {}
-    platform = None
+    device_kind = None
     for e in cost_events:
         ev_by_phase.setdefault(e.get("phase", e.get("op")), []).append(e)
-        platform = platform or e.get("platform")
-    peaks = peaks_for(platform)
+        device_kind = device_kind or e.get("device_kind")
+    peaks = peaks_for(device_kind)
     compile_s = float((counters or {}).get("jit_compile_seconds") or 0.0)
     compile_share = (compile_s / wallclock_s
                      if wallclock_s and wallclock_s > 0 else 0.0)

@@ -199,6 +199,9 @@ EVENT_EXTRAS: dict[str, tuple] = {
         "hist_comms_slabs", "mesh_layout",
         # v3 xprof cross-reference (telemetry/profiler.py).
         "xprof_dir", "xprof_rounds",
+        # The device the run used, as JAX reports it (backend.
+        # device_stamp — PR 21): a log's numbers name their device.
+        "platform", "device_kind", "n_devices",
     ),
     "round": ("train_loss", "valid_*"),
     "phase_timings": (),
@@ -232,8 +235,9 @@ EVENT_EXTRAS: dict[str, tuple] = {
         "train_rounds", "train_heartbeats",
         "device_peak_bytes", "host_peak_rss_bytes",
     ),
-    "cost_analysis": ("phase", "calls", "platform", "signature",
-                      "arg_bytes", "output_bytes", "temp_bytes"),
+    "cost_analysis": ("phase", "calls", "platform", "device_kind",
+                      "signature", "arg_bytes", "output_bytes",
+                      "temp_bytes"),
     "artifact": ("name", "version", "kind", "run_id", "model_token",
                  "mode"),
     "serve_latency": ("batches", "window_s", "p999_ms", "max_ms",
@@ -461,6 +465,15 @@ def comms_manifest_fields(backend) -> dict:
         "mesh_layout": [int(getattr(backend, "row_shards", 1)),
                         int(getattr(backend, "feature_partitions", 1))],
     }
+
+
+def device_manifest_fields(backend) -> dict:
+    """run_manifest extras naming the device the run used (platform,
+    device_kind, n_devices — backend.device_stamp). The one home the
+    Driver's and the streaming trainers' manifests share; empty for a
+    duck-typed backend without the method."""
+    stamp = getattr(backend, "device_stamp", None)
+    return stamp() if stamp is not None else {}
 
 
 def derive_run_id(**fields) -> str:

@@ -5,8 +5,7 @@ Two layers:
   phases the reference cares about (hist / allreduce / gain / predict). On
   TPU each phase must end with a device sync to be meaningful — pass
   utils/device.device_sync (bound to the phase's output) as the `sync`
-  callable; see that module for why block_until_ready is not a barrier on
-  this platform.
+  callable.
 - trace(): context manager around jax.profiler.trace producing a
   TensorBoard/Perfetto trace directory with Pallas kernel timelines.
 """

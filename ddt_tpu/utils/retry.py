@@ -2,7 +2,7 @@
 
 The seams this wraps (stream chunk read, checkpoint save/load, multihost
 bootstrap, the per-tree D2H fetch) share one failure shape: a transient
-environmental fault — NFS blip, preempted peer, tunnel reset — that a
+environmental fault — NFS blip, preempted peer, runtime reset — that a
 second attempt moments later survives. The engine is deliberately dumb:
 classify (is_transient), back off exponentially with DETERMINISTICALLY
 seeded jitter (no wall-clock entropy — chaos runs must replay), respect

@@ -1,0 +1,395 @@
+#!/usr/bin/env python
+"""Compile-only check: does the installed compiler accept the TPU programs?
+
+No chip is needed. jax + libtpu compile for a DESCRIBED v5e
+(jax.experimental.topologies) from a CPU host, so a kernel the Mosaic
+compiler refuses — a block shape, an accumulator width, a vector-layout
+cast — is found here, for free, before chip time is spent on it. Run it
+before each use of the chip:
+
+    JAX_PLATFORMS=cpu python scripts/tpu_aot_check.py            # everything
+    JAX_PLATFORMS=cpu python scripts/tpu_aot_check.py --only hist
+
+A compile is not a run: it says nothing of results, of memory at run time
+or of speed. chip_smoke.py is the run.
+
+What is compiled:
+
+- every Pallas kernel, at the smoke (Higgs 1M x 28) and Covertype
+  (200k x 54, 7 classes) shapes: `kernel_cases()` — the SAME table
+  tests/test_tpu_lowering.py lowers with jax.export in tier-1 (its
+  `default` cases), so the two checks cannot drift apart;
+- the whole fused-rounds program TPUDevice builds
+  (`TPUDevice._build_rounds_fn`) on one device, on a rows=4 mesh and on a
+  2x2 (rows x features) mesh over the four described chips, and the
+  scoring program (`TPUDevice._predict_fn`).
+
+Exit 0 iff every default-dispatch case compiled; opt-in kernels
+(grad_dtype=int8|int16, predict_impl=lut|lut4) are reported and do not
+decide the exit code.
+"""
+
+from __future__ import annotations
+
+import argparse
+import os
+import sys
+import time
+import typing
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+TOPOLOGY = "v5e:2x2"
+
+HIGGS = dict(rows=1_000_000, features=28)
+COVERTYPE = dict(rows=200_000, features=54, classes=7)
+
+
+class KernelCase(typing.NamedTuple):
+    """One kernel at one shape: `build()` -> (traceable fn, [(shape, dtype)])."""
+
+    name: str
+    default: bool          # on the default dispatch (decides the verdict)
+    build: typing.Callable
+
+
+def _hist_case(rows, features, n_nodes, n_bins, grad_dtype="float32"):
+    def build():
+        import jax.numpy as jnp
+
+        from ddt_tpu.ops.hist_pallas import build_histograms_pallas
+
+        def fn(Xb, g, h, ni):
+            return build_histograms_pallas(Xb, g, h, ni, n_nodes, n_bins)
+
+        gd = jnp.dtype(grad_dtype)
+        return fn, [((rows, features), jnp.uint8), ((rows,), gd),
+                    ((rows,), gd), ((rows,), jnp.int32)]
+
+    return build
+
+
+def _random_ensemble(n_trees, depth, features, n_classes, missing, cat):
+    """A full-depth random ensemble (seeded) as a models/tree.TreeEnsemble:
+    the scoring kernels take the COMPILED (pushed-down) layout, so the
+    cases go through the same ens.compile() the backend uses."""
+    import numpy as np
+
+    from ddt_tpu.models.tree import empty_ensemble
+
+    rng = np.random.default_rng(7)
+    loss = "softmax" if n_classes > 1 else "logloss"
+    ens = empty_ensemble(
+        n_trees, depth, features, 0.1, 0.0, loss, max(n_classes, 2),
+        missing_bin=missing, n_bins=255,
+        cat_features=(0, 1) if cat else ())
+    n_int = (1 << depth) - 1
+    ens.feature[:, :n_int] = rng.integers(0, features, (n_trees, n_int))
+    ens.threshold_bin[:, :n_int] = rng.integers(0, 254, (n_trees, n_int))
+    ens.is_leaf[:, n_int:] = True
+    ens.leaf_value[:, n_int:] = rng.standard_normal(
+        (n_trees, n_int + 1)).astype(np.float32)
+    if missing:
+        ens.default_left[:, :n_int] = rng.random((n_trees, n_int)) < 0.5
+    return ens
+
+
+def _predict_case(rows, features, n_trees, depth, n_classes=1,
+                  missing=False, cat=False, tier="f32"):
+    def build():
+        import jax.numpy as jnp
+
+        from ddt_tpu.ops import predict_lut, predict_pallas
+
+        ens = _random_ensemble(n_trees, depth, features, n_classes,
+                               missing, cat)
+        ce = ens.compile(tree_chunk=64)
+        if tier == "f32":
+            ops = ce.arrays()
+            use_missing = ce.eff_dl is not None
+            use_cat = ce.eff_cat is not None
+
+            def fn(ef, et, bv, coh, *rest):
+                *opt, Xc = rest
+                opt = list(opt)
+                dl = opt.pop(0) if use_missing else None
+                cn = opt.pop(0) if use_cat else None
+                return predict_pallas.predict_effective_pallas(
+                    ef, et, bv, coh, Xc.astype(jnp.int32),
+                    max_depth=ce.max_depth, learning_rate=ce.learning_rate,
+                    base=ce.base_score, n_classes=ce.n_classes_out,
+                    tree_chunk=ce.tree_chunk,
+                    missing_bin_value=ce.missing_bin_value,
+                    eff_dl=dl, eff_cat=cn)
+        elif tier in ("lut", "lut_int8leaf"):
+            tables = ce.quantize(
+                leaf_dtype="int8" if tier == "lut_int8leaf" else "float16")
+            ops = predict_lut.lut_device_operands(tables)
+            static = dict(
+                max_depth=tables.max_depth,
+                learning_rate=tables.learning_rate,
+                base=tables.base_score, n_classes=tables.n_classes_out,
+                tree_chunk=tables.tree_chunk,
+                n_trees_padded=tables.n_trees_padded,
+                missing_bin_value=tables.missing_bin_value,
+                use_missing=tables.eff_dl is not None,
+                use_cat=tables.eff_cat is not None,
+                use_scale=tables.leaf_scale is not None)
+
+            def fn(*args):
+                *o, Xc = args
+                return predict_lut.predict_effective_lut_ops(
+                    tuple(o), Xc, **static)
+        else:
+            packed = ce.quantize(leaf_dtype="int4").pack_int4()
+            ops = packed.ops
+            static = packed.static_kwargs()
+
+            def fn(*args):
+                *o, Xc = args
+                return predict_lut.predict_effective_lut4_ops(
+                    tuple(o), Xc, **static)
+
+        shapes = [(a.shape, a.dtype) for a in ops]
+        shapes.append(((rows, features), jnp.uint8))
+        return fn, shapes
+
+    return build
+
+
+def kernel_cases() -> list:
+    """Every Pallas kernel the system has, at the shapes the repo names.
+    `default` marks the default dispatch on a TPU (f32 gradients, the f32
+    traversal kernel); the rest are the opt-in kernels."""
+    H, C = HIGGS, COVERTYPE
+    hr, hf = H["rows"], H["features"]
+    cr, cf, cc = C["rows"], C["features"], C["classes"]
+    return [
+        # Histogram: row-major at 255 bins, transposed at <= 128, root
+        # level (N=1) and the deepest level of a depth-6 tree (N=32).
+        KernelCase("hist/higgs/255bins/N=1", True,
+                   _hist_case(hr, hf, 1, 255)),
+        KernelCase("hist/higgs/255bins/N=32", True,
+                   _hist_case(hr, hf, 32, 255)),
+        KernelCase("hist/higgs/64bins/N=1", True,
+                   _hist_case(hr, hf, 1, 64)),
+        KernelCase("hist/higgs/64bins/N=32", True,
+                   _hist_case(hr, hf, 32, 64)),
+        # Covertype's deepest level (depth 8): 2N = 256 matmul columns,
+        # feature-chunked to fit VMEM.
+        KernelCase("hist/covertype/255bins/N=128", True,
+                   _hist_case(cr, cf, 128, 255)),
+        # Traversal: with and without the missing / categorical
+        # operands, one output and seven.
+        KernelCase("predict/higgs/1000x6", True,
+                   _predict_case(hr, hf, 1000, 6)),
+        KernelCase("predict/50x4/missing+cat", True,
+                   _predict_case(hr, hf, 50, 4, missing=True, cat=True)),
+        KernelCase("predict/covertype/210x6/7classes", True,
+                   _predict_case(cr, cf, 210, 6, n_classes=cc)),
+        # Opt-in kernels.
+        KernelCase("hist/higgs/255bins/N=32/int8", False,
+                   _hist_case(hr, hf, 32, 255, "int8")),
+        KernelCase("hist/higgs/255bins/N=32/int16", False,
+                   _hist_case(hr, hf, 32, 255, "int16")),
+        KernelCase("hist/higgs/64bins/N=32/int8", False,
+                   _hist_case(hr, hf, 32, 64, "int8")),
+        KernelCase("hist/higgs/64bins/N=32/int16", False,
+                   _hist_case(hr, hf, 32, 64, "int16")),
+        KernelCase("lut/higgs/1000x6/f16leaf", False,
+                   _predict_case(hr, hf, 1000, 6, tier="lut")),
+        KernelCase("lut/50x4/int8leaf/missing+cat", False,
+                   _predict_case(hr, hf, 50, 4, missing=True, cat=True,
+                                 tier="lut_int8leaf")),
+        KernelCase("lut4/higgs/1000x6", False,
+                   _predict_case(hr, hf, 1000, 6, tier="lut4")),
+        KernelCase("lut4/50x4/missing+cat", False,
+                   _predict_case(hr, hf, 50, 4, missing=True, cat=True,
+                                 tier="lut4")),
+    ]
+
+
+# ------------------------------------------------------------------ #
+# whole programs, as TPUDevice builds them
+# ------------------------------------------------------------------ #
+
+def _rounds_program(topo_devices, *, rows, features, n_rounds, mesh_shape,
+                    cfg_kw):
+    """(jit fn, ShapeDtypeStruct args) of the fused-rounds program for a
+    mesh over the described devices (mesh_shape None = one device)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, SingleDeviceSharding
+
+    from ddt_tpu.backends.tpu import TPUDevice
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.parallel import mesh as mesh_lib
+
+    cfg = TrainConfig(backend="tpu", **cfg_kw)
+    C = cfg.n_classes if cfg.loss == "softmax" else 1
+    if mesh_shape is None:
+        be = TPUDevice(cfg)
+        one = SingleDeviceSharding(topo_devices[0])
+        sh_data = sh_vec = sh_mat = one
+    else:
+        pr, pf = mesh_shape
+        mesh = jax.sharding.Mesh(
+            np.asarray(topo_devices[:pr * pf]).reshape(pr, pf),
+            (mesh_lib.ROWS_AXIS, mesh_lib.FEATURES_AXIS))
+        be = TPUDevice(cfg, mesh=mesh)
+        lay = be.layout
+        sh_data = NamedSharding(mesh, lay.binned_data())
+        sh_vec = NamedSharding(mesh, lay.row_vector())
+        sh_mat = NamedSharding(mesh, lay.row_matrix())
+    fn = be._build_rounds_fn(n_rounds)
+    pred = (jax.ShapeDtypeStruct((rows, C), jnp.float32, sharding=sh_mat)
+            if C > 1 else
+            jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=sh_vec))
+    ydt = jnp.int32 if cfg.loss == "softmax" else jnp.float32
+    args = [
+        jax.ShapeDtypeStruct((rows, features), jnp.uint8, sharding=sh_data),
+        pred,
+        jax.ShapeDtypeStruct((rows,), ydt, sharding=sh_vec),
+        jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=sh_vec),
+    ]
+    return be, fn, args
+
+
+def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
+                     n_classes=1):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ddt_tpu.backends.tpu import TPUDevice
+    from ddt_tpu.config import TrainConfig
+
+    be = TPUDevice(TrainConfig(backend="tpu", max_depth=depth))
+    ens = _random_ensemble(n_trees, depth, features, n_classes, False,
+                           False)
+    fn, ens_dev = be._predict_fn(ens)
+    one = SingleDeviceSharding(topo_devices[0])
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+            for a in ens_dev]
+    args.append(jax.ShapeDtypeStruct((rows, features), jnp.uint8,
+                                     sharding=one))
+    return jax.jit(fn), args
+
+
+def program_cases(topo_devices) -> list:
+    """(name, build) — build() -> (jit fn, sharded ShapeDtypeStruct args,
+    [substrings the compiled text must contain])."""
+    hr, hf = HIGGS["rows"], HIGGS["features"]
+    cr, cf, cc = (COVERTYPE["rows"], COVERTYPE["features"],
+                  COVERTYPE["classes"])
+    higgs = dict(max_depth=6, n_bins=255)
+
+    def rounds(mesh_shape, rows=hr, features=hf, cfg_kw=higgs,
+               n_rounds=10):
+        def build():
+            be, fn, args = _rounds_program(
+                topo_devices, rows=rows, features=features,
+                n_rounds=n_rounds, mesh_shape=mesh_shape, cfg_kw=cfg_kw)
+            want = ["tpu_custom_call"]
+            if mesh_shape is not None and mesh_shape[0] > 1:
+                assert be.split_comms == "reduce_scatter", be.split_comms
+                want.append("reduce-scatter")
+            return fn, args, want
+        return build
+
+    def scoring(n_trees):
+        def build():
+            fn, args = _scoring_program(topo_devices, rows=hr, features=hf,
+                                        n_trees=n_trees, depth=6)
+            return fn, args, ["tpu_custom_call"]
+        return build
+
+    def scoring_onehot():
+        # Depth 8 is past predict_pallas_fits: the auto dispatch takes the
+        # XLA one-hot path, which Covertype's own models will score on.
+        fn, args = _scoring_program(topo_devices, rows=cr, features=cf,
+                                    n_trees=10 * cc, depth=8, n_classes=cc)
+        return fn, args, []
+
+    return [
+        ("rounds/higgs/1dev", rounds(None)),
+        ("rounds/higgs/rows=4", rounds((4, 1))),
+        ("rounds/higgs/2x2", rounds((2, 2))),
+        ("rounds/covertype/1dev", rounds(
+            None, rows=cr, features=cf, n_rounds=2,
+            cfg_kw=dict(max_depth=8, n_bins=255, loss="softmax",
+                        n_classes=cc))),
+        ("scoring/higgs/10x6", scoring(10)),
+        ("scoring/higgs/1000x6", scoring(1000)),
+        ("scoring/covertype/70x8/onehot-path", scoring_onehot),
+    ]
+
+
+# ------------------------------------------------------------------ #
+
+def _first_line(e: BaseException) -> str:
+    msg = " ".join(str(e).split())
+    return f"{type(e).__name__}: {msg[:400]}"
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--only", default="",
+                    help="run only the cases whose name contains this")
+    args = ap.parse_args(argv)
+
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from ddt_tpu.utils import device
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name=TOPOLOGY)
+    devs = list(topo.devices)
+    one = SingleDeviceSharding(devs[0])
+    print(f"# compile-only check for {devs[0].device_kind} x {len(devs)} "
+          f"({TOPOLOGY}), jax {jax.__version__}, host platform "
+          f"{jax.default_backend()}", flush=True)
+
+    failed_default = 0
+    with device.assume_platform("tpu"):
+        for case in kernel_cases():
+            if args.only not in case.name:
+                continue
+            t0 = time.perf_counter()
+            try:
+                fn, shapes = case.build()
+                sds = [jax.ShapeDtypeStruct(s, d, sharding=one)
+                       for s, d in shapes]
+                jax.jit(fn, out_shardings=one).lower(*sds).compile()
+                verdict = "compiled"
+            except Exception as e:  # the report IS the failure message
+                verdict = "REFUSED  " + _first_line(e)
+                failed_default += case.default
+            tag = "default" if case.default else "opt-in "
+            print(f"{tag}  {case.name:<40s} "
+                  f"{time.perf_counter() - t0:6.1f}s  {verdict}", flush=True)
+        for name, build in program_cases(devs):
+            if args.only not in name:
+                continue
+            t0 = time.perf_counter()
+            try:
+                fn, sds, want = build()
+                txt = fn.lower(*sds).compile().as_text()
+                missing = [w for w in want if w not in txt]
+                verdict = ("compiled" if not missing else
+                           f"REFUSED  compiled text lacks {missing}")
+                failed_default += bool(missing)
+            except Exception as e:  # the report IS the failure message
+                verdict = "REFUSED  " + _first_line(e)
+                failed_default += 1
+            print(f"program  {name:<40s} "
+                  f"{time.perf_counter() - t0:6.1f}s  {verdict}", flush=True)
+    print(f"# {failed_default} default-dispatch case(s) refused")
+    return 1 if failed_default else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

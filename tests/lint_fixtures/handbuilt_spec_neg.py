@@ -3,11 +3,9 @@ sanctioned pattern (and a bare `P(...)` call where P is NOT the
 PartitionSpec alias)."""
 import jax
 
-from ddt_tpu.parallel import mesh as mesh_lib
-
 
 def sharded_fn(f, mesh, lay):
-    return mesh_lib.shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=lay.specs("data", "grad"),
         out_specs=lay.replicated(),

@@ -2,13 +2,11 @@
 resolve through backend.layout (SpecLayout) by operand name."""
 import jax
 
-from ddt_tpu.parallel import mesh as mesh_lib
-
 P = jax.sharding.PartitionSpec
 
 
 def sharded_fn(f, mesh):
-    return mesh_lib.shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=P(None),                        # LINT: handbuilt-partition-spec
         out_specs=jax.sharding.PartitionSpec(),  # LINT: handbuilt-partition-spec

@@ -304,9 +304,7 @@ def test_predict_backend_row_chunking_identity(monkeypatch):
 @pytest.mark.parametrize("block_rounds", [3, 4])
 def test_fused_block_cap_multi_block_identity(block_rounds):
     """Long configs split into multiple fused dispatches
-    (cfg.fused_block_rounds caps single-dispatch runtime — an
-    unbounded 500-round block crashed the remote chip worker in round
-    4). Block boundaries must not change results: a 10-round run forced
+    (cfg.fused_block_rounds caps single-dispatch runtime). Block boundaries must not change results: a 10-round run forced
     through small blocks (both even and uneven final blocks) equals the
     single-block run and the CPU oracle exactly."""
     Xb, y, _ = _small_problem()

@@ -24,6 +24,9 @@ def test_cli_train_predict_roundtrip(tmp_path, capsys):
     ])
     assert rec["trees"] == 4 and rec["backend"] == "cpu"
     assert rec["final_train_loss"] < 0.693  # below chance for logloss
+    # Results name their device: the NumPy backend computes on the host.
+    host = {"platform": "cpu", "device_kind": "host", "n_devices": 1}
+    assert {k: rec[k] for k in host} == host
 
     scores = str(tmp_path / "scores.npy")
     rec = _run(capsys, [
@@ -31,6 +34,7 @@ def test_cli_train_predict_roundtrip(tmp_path, capsys):
         "--dataset=higgs", "--rows=500", "--bins=31", f"--out={scores}",
     ])
     assert rec["rows"] == 500
+    assert {k: rec[k] for k in host} == host
     s = np.load(scores)
     assert s.shape == (500,) and (0 <= s).all() and (s <= 1).all()
 
@@ -45,6 +49,10 @@ def test_cli_train_tpu_backend_with_partitions(tmp_path, capsys):
         f"--out={model}",
     ])
     assert rec["backend"] == "tpu"
+    # ... and the XLA backend's stamp is JAX's own account (8 virtual CPU
+    # devices here, conftest) — a CPU run can never read as a chip run.
+    assert (rec["platform"], rec["device_kind"], rec["n_devices"]) == \
+        ("cpu", "cpu", 8)
 
 
 def test_cli_train_feature_partitions_and_early_stop(tmp_path, capsys):
@@ -90,6 +98,7 @@ def test_cli_bench_histogram_cpu(capsys):
     ])
     assert rec["kernel"] == "histogram"
     assert rec["mrows_per_sec_per_chip"] > 0
+    assert rec["device_kind"] == "host"
     assert rec["impl"] in ("native-c++", "numpy")
 
 

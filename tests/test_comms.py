@@ -72,8 +72,6 @@ def test_reduce_scatter_matches_psum_slice():
     order."""
     import jax
 
-    from ddt_tpu.parallel import mesh as mesh_lib
-
     P = jax.sharding.PartitionSpec
     mesh = jax.make_mesh((2, 4), ("hosts", "rows"))
     x = np.arange(8 * 16, dtype=np.float32).reshape(8, 16)
@@ -81,8 +79,8 @@ def test_reduce_scatter_matches_psum_slice():
     def f(a):
         return comms.reduce_scatter(a, ("hosts", "rows"), dim=1)
 
-    g = mesh_lib.shard_map(f, mesh=mesh, in_specs=P(("hosts", "rows")),
-                           out_specs=P(None, ("hosts", "rows")))
+    g = jax.shard_map(f, mesh=mesh, in_specs=P(("hosts", "rows")),
+                      out_specs=P(None, ("hosts", "rows")))
     out = np.asarray(g(x)).reshape(-1)
     np.testing.assert_allclose(out, x.sum(axis=0), rtol=1e-6)
 
@@ -90,16 +88,14 @@ def test_reduce_scatter_matches_psum_slice():
 def test_reduce_scatter_requires_alignment():
     import jax
 
-    from ddt_tpu.parallel import mesh as mesh_lib
-
     P = jax.sharding.PartitionSpec
     mesh = jax.make_mesh((8,), ("rows",))
 
     def f(a):
         return comms.reduce_scatter(a, "rows", dim=1)
 
-    g = mesh_lib.shard_map(f, mesh=mesh, in_specs=P("rows"),
-                           out_specs=P(None, "rows"))
+    g = jax.shard_map(f, mesh=mesh, in_specs=P("rows"),
+                      out_specs=P(None, "rows"))
     with pytest.raises(ValueError, match="multiple"):
         g(np.zeros((8, 12), np.float32))          # 12 % 8 != 0
 
@@ -128,8 +124,6 @@ def test_hist_reduce_holds_computed_error_bound(dtype):
     comms.comms_error_bound of the exact f32 merge."""
     import jax
 
-    from ddt_tpu.parallel import mesh as mesh_lib
-
     P = jax.sharding.PartitionSpec
     n_dev = 8
     mesh = jax.make_mesh((n_dev,), ("rows",))
@@ -139,8 +133,8 @@ def test_hist_reduce_holds_computed_error_bound(dtype):
     def f(a):
         return comms.hist_reduce(a[0], "rows", comms_dtype=dtype)
 
-    g = mesh_lib.shard_map(f, mesh=mesh, in_specs=P("rows"),
-                           out_specs=P())
+    g = jax.shard_map(f, mesh=mesh, in_specs=P("rows"),
+                      out_specs=P())
     got = np.asarray(g(parts))
     exact = parts.astype(np.float64).sum(axis=0)
     bound = comms.comms_error_bound(dtype, n_dev, float(np.abs(parts).max()))
@@ -162,8 +156,6 @@ def test_combine_shard_winners_global_tiebreak():
     import jax
     import jax.numpy as jnp
 
-    from ddt_tpu.parallel import mesh as mesh_lib
-
     P = jax.sharding.PartitionSpec
     mesh = jax.make_mesh((2,), ("rows",))
     # Shard 0 proposes feature 0 with dl=True; shard 1 proposes feature
@@ -180,9 +172,12 @@ def test_combine_shard_winners_global_tiebreak():
             g[0], ft[0], b[0], d[0], "rows",
             n_features=8, n_bins=16, missing_bin=True)
 
-    g = mesh_lib.shard_map(
+    # check_vma=False as in the backend's grow programs: the outputs are
+    # replicated by construction, but the static checker cannot see
+    # through the gathered argmax.
+    g = jax.shard_map(
         f, mesh=mesh, in_specs=(P("rows"),) * 4,
-        out_specs=(P(), P(), P(), P()))
+        out_specs=(P(), P(), P(), P()), check_vma=False)
     ga, fa, ba, da = (np.asarray(x) for x in g(
         jnp.asarray(gains), jnp.asarray(feats), jnp.asarray(bins_),
         jnp.asarray(dls)))
@@ -492,7 +487,8 @@ def test_roofline_comms_row():
     phases = [{"phase": "hist", "ms_total": 1000.0, "ms_per_call": 10.0,
                "calls": 100, "share": 1.0}]
     cost = [{"op": "hist", "phase": "hist", "flops": 1e9,
-             "bytes_accessed": 1e6, "calls": 100, "platform": "cpu"}]
+             "bytes_accessed": 1e6, "calls": 100, "platform": "cpu",
+             "device_kind": "cpu"}]
     hot = roofline_table(phases, cost,
                          counters={"collective_bytes_est": int(20e9)},
                          wallclock_s=1.0)

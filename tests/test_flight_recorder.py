@@ -558,11 +558,17 @@ def test_benchwatch_multichip_failure_flags(tmp_path):
     assert benchwatch.run([str(p)])["ok"]
 
 
-def test_benchwatch_passes_on_real_repo_history():
-    """The acceptance criterion's other half: the shipped BENCH_r01-r05
-    + MULTICHIP_r01-r05 artifacts pass the sentinel as-is."""
-    paths = benchwatch.collect_default_paths(REPO)
-    assert len(paths) >= 10
+def test_benchwatch_passes_on_a_collected_history(tmp_path):
+    """The default collection (BENCH_r* + MULTICHIP_r* under a root) of a
+    history with ordinary spread passes the sentinel as-is."""
+    for i, v in enumerate([55.0, 57.0, 56.3, 45.0, 47.9]):
+        _bench_artifact(tmp_path, i + 1, value=v,
+                        e2e_train_s=13.8 - 0.5 * i)
+        (tmp_path / f"MULTICHIP_r{i + 1:02d}.json").write_text(json.dumps(
+            {"n_devices": 8, "rc": 0, "ok": True, "skipped": False,
+             "tail": "all 4 phases ok"}))
+    paths = benchwatch.collect_default_paths(str(tmp_path))
+    assert len(paths) == 10
     rep = benchwatch.run(paths)
     assert rep["ok"], rep
     assert rep["bench"]["checked"], "no metric had banding history"

@@ -375,9 +375,7 @@ def test_device_auc_sharded_matches_single():
         return {"sum": jax.lax.psum, "min": jax.lax.pmin,
                 "max": jax.lax.pmax}[op](x, "rows")
 
-    from ddt_tpu.parallel import mesh as mesh_lib
-
-    sharded_fn = jax.jit(mesh_lib.shard_map(
+    sharded_fn = jax.jit(jax.shard_map(
         lambda y_, s_, v_: fn(y_, s_, v_, allreduce),
         mesh=mesh, in_specs=(P("rows"), P("rows"), P("rows")),
         out_specs=P()))

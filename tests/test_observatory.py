@@ -77,7 +77,7 @@ def test_cost_events_and_roofline_on_real_run(tmp_path, capsys):
     for e in cost:
         assert e["calls"] >= 1
         assert e["flops"] >= 0 and e["bytes_accessed"] >= 0
-        assert e["platform"] == "cpu"
+        assert e["platform"] == "cpu" and e["device_kind"] == "cpu"
         # memory_analysis landed (CPU XLA supports it on this jax).
         assert "signature" in e
 
@@ -214,9 +214,10 @@ def _phase(name, ms, calls=1):
             "share": 1.0}
 
 
-def _cost(phase, flops, byts, calls=1, platform="cpu"):
+def _cost(phase, flops, byts, calls=1, device_kind="cpu"):
     return {"op": phase, "phase": phase, "flops": flops,
-            "bytes_accessed": byts, "calls": calls, "platform": platform}
+            "bytes_accessed": byts, "calls": calls, "platform": "cpu",
+            "device_kind": device_kind}
 
 
 def test_roofline_verdicts():
@@ -249,6 +250,18 @@ def test_roofline_recompile_verdict_and_growblock_fold():
     assert rows[0]["phase"] == "grow_block"
     assert rows[0]["ms"] == pytest.approx(1000.0)
     assert rows[0]["verdict"] == "recompile"
+
+
+def test_roofline_unknown_device_raises():
+    """A device PEAK_CEILINGS does not list has no peaks to divide by:
+    an error, never another device's row (and "tpu" is a platform, not a
+    device kind)."""
+    for kind in ("TPU v9", "tpu", None):
+        with pytest.raises(ValueError, match="no roofline peaks"):
+            costmodel.roofline_table(
+                [_phase("hist", 100.0)],
+                [_cost("hist", 1e9, 1e9, device_kind=kind)])
+    assert costmodel.peaks_for("TPU v5 lite")["gbs"] == 819.0
 
 
 def test_roofline_phase_without_cost_is_host():

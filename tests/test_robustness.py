@@ -170,7 +170,7 @@ def test_retry_exhausted_emits_and_raises():
 def test_is_transient_classification():
     assert retry.is_transient(IOError("x"))
     assert retry.is_transient(TimeoutError("x"))
-    assert retry.is_transient(RuntimeError("UNAVAILABLE: tunnel reset"))
+    assert retry.is_transient(RuntimeError("UNAVAILABLE: connection reset"))
     assert retry.is_transient(faultplan.InjectedTransient("d2h"))
     assert not retry.is_transient(ValueError("x"))
     assert not retry.is_transient(faultplan.InjectedCrash("kill"))

@@ -353,7 +353,7 @@ def _v3_log(path):
          "round": 1, "ms_per_round": 5.0, "train_loss": 0.6},
         {"event": "cost_analysis", "schema": 3, "t": 101.5, "seq": 2,
          "op": "hist", "flops": 1e9, "bytes_accessed": 1e8,
-         "phase": "grow", "calls": 2},
+         "phase": "grow", "calls": 2, "device_kind": "cpu"},
         {"event": "phase_timings", "schema": 3, "t": 102.0, "seq": 3,
          "phases": [{"phase": "grow", "ms_total": 5.0,
                      "ms_per_call": 2.5, "calls": 2, "share": 1.0}]},

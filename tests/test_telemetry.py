@@ -56,6 +56,7 @@ def test_runlog_emits_and_round_trips_every_event_type(tmp_path):
         # one op entry point at one signature.
         "cost_analysis": dict(op="hist", flops=2.5e9, bytes_accessed=1e9,
                               phase="hist", calls=12, platform="cpu",
+                              device_kind="cpu",
                               arg_bytes=1000, output_bytes=200,
                               temp_bytes=50,
                               signature="([1000, 7]:uint8)"),
@@ -173,6 +174,9 @@ def test_driver_e2e_run_log_counters_and_eval_curve(tmp_path):
     man = by_type["run_manifest"][0]
     assert (man["trainer"], man["backend"]) == ("driver", "tpu")
     assert (man["rows"], man["features"]) == (2113, 7)
+    # The log names its device, as JAX reports it (8 virtual CPUs here).
+    assert (man["platform"], man["device_kind"], man["n_devices"]) == \
+        ("cpu", "cpu", 8)
 
     rounds = by_type["round"]
     assert [r["round"] for r in rounds] == [1, 2, 3, 4]
@@ -333,6 +337,7 @@ def test_streaming_host_run_log_and_phase_timer(tmp_path):
     man = by_type["run_manifest"][0]
     assert man["trainer"] == "streaming_host"
     assert man["n_chunks"] == 3
+    assert man["device_kind"] == "host"       # the NumPy streaming loop
     rounds = by_type["round"]
     assert [r["round"] for r in rounds] == [1, 2, 3]
     assert all("valid_logloss" in r for r in rounds)

@@ -1,0 +1,69 @@
+"""Every Pallas kernel on the default dispatch LOWERS for a TPU.
+
+The CPU suite runs the kernels interpreted, which never meets the Mosaic
+lowering: at PR 21 the histogram kernel's row operands had a block shape
+the TPU lowering refuses and the traversal kernel asked the MXU for a
+16-bit accumulator, and every interpret-mode test passed. jax.export
+lowers the COMPILED form (interpret=False) for platform "tpu" from a CPU
+host — no chip and no libtpu needed — so both refusals fail here.
+
+What this does not see: refusals inside the Mosaic compiler itself (vector
+layouts, unsupported casts). Those need libtpu: scripts/tpu_aot_check.py
+compiles the same cases (its `kernel_cases()` table is the one this file
+reads) against a described v5e, and chip_smoke.py runs them.
+"""
+
+import importlib.util
+import os
+
+import jax
+import pytest
+
+from ddt_tpu.utils import device
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_spec = importlib.util.spec_from_file_location(
+    "tpu_aot_check", os.path.join(REPO, "scripts", "tpu_aot_check.py"))
+aot = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(aot)
+
+DEFAULT_CASES = [c for c in aot.kernel_cases() if c.default]
+
+
+def _export_for_tpu(case):
+    fn, shapes = case.build()
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    with device.assume_platform("tpu"):
+        return jax.export.export(jax.jit(fn), platforms=("tpu",))(*args)
+
+
+@pytest.mark.parametrize("case", DEFAULT_CASES, ids=lambda c: c.name)
+def test_kernel_lowers_for_tpu(case):
+    exported = _export_for_tpu(case)
+    assert exported.platforms == ("tpu",)
+    # The kernel is IN the program, compiled — not interpreted away.
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+def test_case_table_covers_the_default_dispatch():
+    """Both histogram forms, feature-chunked at the Covertype width, and
+    the traversal kernel with and without the optional operands, for one
+    output and for seven."""
+    names = [c.name for c in DEFAULT_CASES]
+    for needle in ("hist/higgs/255bins", "hist/higgs/64bins",
+                   "hist/covertype", "predict/higgs", "missing+cat",
+                   "7classes"):
+        assert any(needle in n for n in names), (needle, names)
+
+
+def test_assume_platform_restores():
+    assert device.platform() == "cpu"
+    with device.assume_platform("tpu"):
+        assert device.platform() == "tpu"
+        with pytest.raises(RuntimeError):
+            with device.assume_platform("gpu"):
+                assert device.platform() == "gpu"
+                raise RuntimeError("boom")
+        assert device.platform() == "tpu"
+    assert device.platform() == "cpu"

@@ -1,20 +1,17 @@
 """benchwatch — the bench-artifact regression sentinel (`make benchwatch`).
 
-Five BENCH_r*.json / MULTICHIP_r*.json artifacts accumulated with zero
-consumers; this tool is the consumer. It ingests the artifact history
-plus a "current" run, computes a robust per-metric band (median ± the
+The consumer of BENCH_r*.json / MULTICHIP_r*.json artifacts. It ingests
+the artifact history plus a "current" run, computes a robust per-metric band (median ± the
 larger of K·MAD and a relative floor), and exits nonzero when the
 current run sits ADVERSELY outside the band — a one-sided check, so a
 pleasantly fast run never fails the gate.
 
 Why median/MAD with a relative floor instead of mean/σ or MAD alone:
-the remote-tunnel throughput drifts in ±20% bands run to run
-(docs/PERF.md drift analysis), so (a) the mean is polluted by band
-outliers a median shrugs off, and (b) with ~5 samples that happen to
-land in one band the raw MAD collapses toward zero and would flag
-ordinary band-hopping — the REL_FLOOR (default 20% of the median)
-keeps the gate wider than the known noise while a real 30% regression
-still trips it. Metrics with fewer than MIN_HISTORY samples are
+(a) the mean is polluted by outliers a median shrugs off, and (b) with
+~5 samples that happen to land close together the raw MAD collapses
+toward zero and would flag ordinary run-to-run spread — the REL_FLOOR
+(default 20% of the median; the chip's own spread is not measured yet)
+keeps the gate wide while a real 30% regression still trips it. Metrics with fewer than MIN_HISTORY samples are
 reported as skipped, never guessed at.
 
 Artifact shapes accepted (load_artifact):
@@ -88,8 +85,8 @@ METRICS: dict[str, str] = {
     "predict_pallas_ab_ratio": "higher",
     # Roofline utilization stamps (cost observatory): achieved/peak
     # fractions from XLA's cost model at the measured wallclock — losing
-    # utilization is a regression even when absolute throughput drift
-    # hides it inside the tunnel bands. hist_roofline_hbm_util is
+    # utilization is a regression even when absolute throughput spread
+    # hides it. hist_roofline_hbm_util is
     # deliberately NOT banded since bench schema v2: the VMEM-streaming
     # histogram kernel LOWERS bytes-accessed by design (the hist verdict
     # flipping hbm -> compute is the kernel campaign's goal), so a drop
