@@ -1551,12 +1551,38 @@ class TPUDevice(DeviceBackend):
     # a handful of live model versions.
     PREDICT_CACHE_MAX = 4
 
+    # Counters whose movement over one predict_raw call rides on its root
+    # span: whether the call compiled, traced or lowered anything, and
+    # whether the model was already resident.
+    _PREDICT_ROOT_COUNTERS = (
+        "jit_compiles", "jit_compile_seconds", "jit_trace_seconds",
+        "jit_lower_seconds", "compile_cache_hits",
+        "compiled_ensemble_cache_hits")
+
     def predict_raw(self, ens: TreeEnsemble, Xb: np.ndarray,
                     compiled=None) -> np.ndarray:
         """Score binned rows. `compiled` (a models/tree.CompiledEnsemble
         already built for THIS ens) skips the per-call content hash —
         the serving tier holds one per model version, so a micro-batch
-        request pays upload + dispatch only (docs/SERVING.md)."""
+        request pays upload + dispatch only (docs/SERVING.md).
+
+        Every call is one root span `ddt:predict` with a child span per
+        step (token, ensemble, upload, dispatch, fetch, concat: the
+        table is in docs/OBSERVABILITY.md); the spans time the host's
+        side and add no sync."""
+        with phase_span("predict", rows=int(Xb.shape[0])) as root:
+            c0 = tele_counters.snapshot()
+            try:
+                return self._predict_raw(ens, Xb, compiled, root.counts)
+            finally:
+                moved = tele_counters.delta(c0)
+                root.counts.update(
+                    {k: moved[k] for k in self._PREDICT_ROOT_COUNTERS})
+
+    def _predict_raw(self, ens: TreeEnsemble, Xb: np.ndarray, compiled,
+                     counts: dict) -> np.ndarray:
+        """predict_raw's body; `counts` is the root span's (branch and
+        chunks are written as soon as they are known)."""
         R = Xb.shape[0]
         chunk = self.PREDICT_ROW_CHUNK * max(1, self.row_shards)
         fn, ens_dev = self._predict_fn(ens, compiled=compiled)
@@ -1566,44 +1592,73 @@ class TPUDevice(DeviceBackend):
             # upload, isolating device compute for benchmarking); the
             # other paths pad/shard on host.
             Xb = np.asarray(Xb)
-        if R > chunk:
-            if self.distributed:
-                # Per-chunk host→device upload (each chunk must be laid out
-                # over the mesh); ensemble arrays + shard_map fn hoisted.
-                outs = [
-                    fn(*ens_dev, self._put_rows(Xb[i:i + chunk],
-                                                extra_dims=1)
-                       )[:min(chunk, R - i)]       # drop per-chunk pad rows
-                    for i in range(0, R, chunk)
-                ]
-            else:
-                # Single chip: upload the whole batch ONCE (uint8 — 4x less
-                # host→device traffic than int32), slice chunks on device,
-                # and
-                # OVERLAP each chunk's device→host score fetch with the
-                # later chunks' compute: async dispatch keeps the device
-                # busy while finished chunks stream back, so the link and
-                # the chip pay their costs concurrently instead of
-                # back-to-back (what the overlap buys on the chip: not
-                # measured).
-                with phase_span("predict:upload"):
-                    Xd = (Xb if isinstance(Xb, jax.Array)
-                          else jax.device_put(np.ascontiguousarray(Xb)))
-                outs = [
-                    fn(*ens_dev, Xd[i:i + chunk]) for i in range(0, R, chunk)
-                ]
-                for o in outs:          # start all D2H copies in flight
-                    o.copy_to_host_async()
+        starts = range(0, R, chunk) if R > chunk else (0,)
+        counts["chunks"] = len(starts)
+        if R <= chunk:
+            counts["branch"] = "one"
+            with phase_span("predict:upload", bytes=Xb.nbytes):
+                Xc = self._put_rows(Xb, extra_dims=1)  # uint8; ops widen it
+            with phase_span("predict:dispatch", chunk=0):
+                out = fn(*ens_dev, Xc)
+            with phase_span("predict:fetch", chunk=0) as sp:
+                out = np.asarray(out)
+                sp.counts["bytes"] = out.nbytes
+            tele_counters.record_d2h(out.nbytes)
+            return out[:R]
+        if self.distributed:
+            # Per-chunk host→device upload (each chunk must be laid out
+            # over the mesh); ensemble arrays + shard_map fn hoisted.
+            counts["branch"] = "mesh"
+            outs = []
+            for k, i in enumerate(starts):
+                part = Xb[i:i + chunk]
+                with phase_span("predict:upload", chunk=k,
+                                bytes=part.nbytes):
+                    Xc = self._put_rows(part, extra_dims=1)
+                with phase_span("predict:dispatch", chunk=k):
+                    outs.append(fn(*ens_dev, Xc)
+                                [:min(chunk, R - i)])  # drop chunk pad rows
+            with phase_span("predict:fetch", chunk=0) as sp:
+                out = np.asarray(jnp.concatenate(outs))
+                sp.counts["bytes"] = out.nbytes
+            tele_counters.record_d2h(out.nbytes)
+            return out[:R]
+        # Single chip: upload the whole batch ONCE (uint8 — 4x less
+        # host→device traffic than int32), slice chunks on device, and
+        # OVERLAP each chunk's device→host score fetch with the later
+        # chunks' compute: async dispatch keeps the device busy while
+        # finished chunks stream back, so the link and the chip pay their
+        # costs concurrently instead of back-to-back. What is left
+        # exposed on the chip is the fetch tail, the last device
+        # operation's end to the call's return: 395 ms of a 22.9 s
+        # 100M-row call, of which the last chunk's fetch 0.2 ms and the
+        # np.concatenate 394 ms (PERF.md section 5, PR 25).
+        counts["branch"] = "chunks"
+        resident = isinstance(Xb, jax.Array)
+        with phase_span("predict:upload",
+                        bytes=0 if resident else Xb.nbytes):
+            Xd = Xb if resident else jax.device_put(np.ascontiguousarray(Xb))
+        if not resident:
+            tele_counters.record_h2d(Xb.nbytes)
+        outs = []
+        for k, i in enumerate(starts):
+            with phase_span("predict:dispatch", chunk=k):
+                outs.append(fn(*ens_dev, Xd[i:i + chunk]))
+        parts = []
+        for k, o in enumerate(outs):
+            with phase_span("predict:fetch", chunk=k) as sp:
+                if k == 0:              # start all D2H copies in flight
+                    for later in outs:
+                        later.copy_to_host_async()
                 # Not a per-iter sync: the copies are already in flight
                 # (copy_to_host_async above); asarray only materialises.
-                return np.concatenate(
-                    [np.asarray(o)  # ddtlint: disable=host-sync
-                     for o in outs])[:R]
-            return np.asarray(jnp.concatenate(outs))[:R]
-        with phase_span("predict:upload"):
-            Xc = self._put_rows(Xb, extra_dims=1)   # uint8; ops widen it
-        out = fn(*ens_dev, Xc)
-        return np.asarray(out)[:R]
+                parts.append(np.asarray(o))  # ddtlint: disable=host-sync
+                sp.counts["bytes"] = parts[-1].nbytes
+        with phase_span("predict:concat") as sp:
+            out = np.concatenate(parts)[:R]
+            sp.counts["bytes"] = out.nbytes
+        tele_counters.record_d2h(sum(p.nbytes for p in parts))
+        return out
 
     @functools.cached_property
     def _predict_cache(self) -> dict:
@@ -1678,9 +1733,8 @@ class TPUDevice(DeviceBackend):
                 use_scale=tables.leaf_scale is not None,
             )
             core = predict_lut.predict_effective_lut_ops
-        with phase_span("predict:upload"):
-            dev_ops = tuple(self._put(a, self._named(
-                self.layout.replicated())) for a in host_ops)
+        dev_ops = tuple(self._put(a, self._named(
+            self.layout.replicated())) for a in host_ops)
 
         def lut0(*args):
             *ops, Xc = args
@@ -1695,8 +1749,10 @@ class TPUDevice(DeviceBackend):
         CompiledEnsemble) and its device copies are cached per model
         version: the cache key is a content digest of the node arrays, so
         in-place trainer mutation can never serve stale trees, and a hit
-        skips pushdown AND re-upload entirely (the resident-vs-total
-        bench gap showed ~27% of predict wall time there). Hits feed the
+        skips pushdown AND re-upload entirely. The digest is the span
+        `ddt:predict:token` (1.2 ms for 1000 trees of depth 6 on the
+        v5e's host), a miss the span `ddt:predict:ensemble` (18 ms there;
+        PERF.md section 5). Hits feed the
         run log's `compiled_ensemble_cache_hits` counter.
 
         `compiled` (a CompiledEnsemble snapshot the caller already
@@ -1714,13 +1770,31 @@ class TPUDevice(DeviceBackend):
         tier can stamp the TRUE tier into /healthz + serve_latency —
         a silent guard trip must be visible in telemetry, not only in
         debug logs."""
-        token = compiled.token if compiled is not None \
-            else ens.cache_token()
+        if compiled is not None:
+            token = compiled.token
+        else:
+            with phase_span("predict:token"):
+                token = ens.cache_token()
         hit = self._predict_cache.pop(token, None)
         if hit is not None:
             self._predict_cache[token] = hit     # most-recently-used
             tele_counters.record_compiled_ensemble_hit()
             return hit
+        with phase_span("predict:ensemble") as sp:
+            fn, ens_dev, resolved = self._build_predict_fn(ens, compiled)
+            sp.counts["bytes"] = sum(a.nbytes for a in ens_dev)
+        self._predict_cache[token] = (fn, ens_dev)
+        self._predict_impl_resolved[token] = resolved
+        while len(self._predict_cache) > self.PREDICT_CACHE_MAX:
+            gone = next(iter(self._predict_cache))
+            self._predict_cache.pop(gone)
+            self._predict_impl_resolved.pop(gone, None)
+        return fn, ens_dev
+
+    def _build_predict_fn(self, ens: TreeEnsemble, compiled):
+        """_predict_fn's cache miss: (fn, device arrays, the tier that
+        serves) — layout build or reuse, quantisation, the node tables'
+        upload and the mesh wrapper."""
         ce = compiled if compiled is not None else ens.compile(
             tree_chunk=64)
         impl_req = self.cfg.predict_impl
@@ -1748,9 +1822,8 @@ class TPUDevice(DeviceBackend):
                     "predict_impl=%r: shape exceeds the LUT kernel's "
                     "VMEM budget; falling back to the f32 path",
                     impl_req)
-            with phase_span("predict:upload"):
-                ens_dev = tuple(self._put(a, self._named(
-                    self.layout.replicated())) for a in ce.arrays())
+            ens_dev = tuple(self._put(a, self._named(
+                self.layout.replicated())) for a in ce.arrays())
             use_missing = ce.eff_dl is not None
             use_cat = ce.eff_cat is not None
             use_pallas = self._use_pallas
@@ -1800,10 +1873,4 @@ class TPUDevice(DeviceBackend):
                 # here (no collectives anywhere in the traversal).
                 check_vma=False,
             ))
-        self._predict_cache[token] = (fn, ens_dev)
-        self._predict_impl_resolved[token] = resolved
-        while len(self._predict_cache) > self.PREDICT_CACHE_MAX:
-            gone = next(iter(self._predict_cache))
-            self._predict_cache.pop(gone)
-            self._predict_impl_resolved.pop(gone, None)
-        return fn, ens_dev
+        return fn, ens_dev, resolved

@@ -93,6 +93,27 @@ def _load_dataset(args, encoder=None, n_features=None):
     raise SystemExit(f"unknown dataset {args.dataset!r}")
 
 
+def _predict_phases_ms(since_ns: int) -> "dict | None":
+    """Host milliseconds of each step of the device scoring calls whose
+    root span `ddt:predict` started at or after `since_ns`
+    (time.perf_counter_ns): token, ensemble, upload, dispatch, fetch,
+    concat (docs/OBSERVABILITY.md has the table). None when no such call
+    ran: the NumPy backend and raw-threshold scoring open no span."""
+    from ddt_tpu.telemetry.annotations import PREFIX, root_spans
+
+    roots = [r for r in root_spans("predict") if r["start"] >= since_ns]
+    if not roots:
+        return None
+    ms = dict.fromkeys(
+        ("token", "ensemble", "upload", "dispatch", "fetch", "concat"), 0.0)
+    for r in roots:
+        for s in r["spans"]:
+            step = s["name"].removeprefix(PREFIX + "predict:")
+            if step in ms:
+                ms[step] += (s["end"] - s["start"]) / 1e6
+    return {k: round(v, 3) for k, v in ms.items()}
+
+
 def _predict_streaming(args, bundle) -> int:
     """`predict --stream-dir=D`: score npz shards chunk-by-chunk in
     O(chunk) host memory (the 10M-row x 1000-tree config at beyond-RAM
@@ -1086,6 +1107,7 @@ def main(argv: list[str] | None = None) -> int:
                           predict_impl=TIER_IMPL.get(args.quantized,
                                                      "auto"))
         t0 = time.perf_counter()
+        t0_ns = time.perf_counter_ns()
         if bundle.mapper is not None:
             # Training-time binning, loaded from the artifact — NEVER refit
             # on the scoring data (its distribution may differ).
@@ -1108,6 +1130,7 @@ def main(argv: list[str] | None = None) -> int:
             "cmd": "predict", "backend": args.backend, "rows": len(X),
             "trees": ens.n_trees, "wallclock_s": round(dt, 3),
             "rows_per_sec": round(len(X) / dt, 1),
+            "phases_ms": _predict_phases_ms(t0_ns),
             **stamp,
         }))
         return 0

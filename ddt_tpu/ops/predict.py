@@ -388,9 +388,9 @@ def predict_raw_effective(
 ) -> jax.Array:
     """predict_raw on a CompiledEnsemble's precomputed arrays — no
     pushdown, no padding, no class-one-hot construction in-trace. The
-    backend keeps these arrays device-resident across calls (the
-    resident-vs-total bench gap showed ~27% of predict wall time was
-    re-upload/setup). Tpad must be a multiple of tree_chunk
+    backend keeps these arrays device-resident across calls (a rebuild
+    and re-upload is the span `ddt:predict:ensemble`, 18 ms for 1000
+    trees on the v5e: PERF.md). Tpad must be a multiple of tree_chunk
     (CompiledEnsemble.build guarantees it)."""
     return _predict_effective(
         eff_feat, eff_thr, bot_val, cls_oh, Xc,

@@ -3,16 +3,24 @@
 Four counters, each chosen because the literature says it is the silent
 TPU perf killer the host wallclock alone cannot see:
 
-- `jit_compiles` — every XLA backend compile, counted by a
-  jax.monitoring listener on the `/jax/core/compile/
-  backend_compile_duration` event (recompiles from shape churn are the
-  classic hidden cost: arXiv:1810.09868). The listener installs lazily
+- `jit_compiles` — every program XLA's backend BUILT OR LOADED FROM THE
+  PERSISTENT CACHE, counted by a jax.monitoring listener on the
+  `/jax/core/compile/backend_compile_duration` event: in this jax
+  (0.9.0) the event wraps `compile_or_get_cached`, so it fires on a
+  persistent-cache hit too, and `compile_cache_hits` (event
+  `/jax/compilation_cache/cache_hits`) says how many of them were
+  loads (recompiles from shape churn are the classic hidden cost:
+  arXiv:1810.09868). The listeners install lazily
   (install_jax_listener) so a process that never attaches telemetry
-  never registers it; once installed it is a single host integer add
-  per COMPILE — nothing per dispatch. The SAME listener accumulates
-  `jit_compile_seconds` (cumulative backend-compile wall time) so the
-  run log carries recompile COST, not just count — the roofline
-  verdict's "recompile" leg reads it (telemetry/costmodel.py).
+  never registers them; once installed they are a host add per
+  COMPILE — nothing per dispatch. The SAME listener accumulates
+  `jit_compile_seconds` (cumulative wall time of that event: compile,
+  or cache read and load) so the run log carries recompile COST, not
+  just count — the roofline verdict's "recompile" leg reads it
+  (telemetry/costmodel.py) — and `jit_trace_seconds` /
+  `jit_lower_seconds`: tracing to a jaxpr and lowering to an MLIR
+  module, which no cache skips (what a process's first call of a
+  program pays even when `compile_cache_hits` covers every program).
 - `h2d_bytes` / `d2h_bytes` — host↔device transfer bytes recorded at
   the backends' upload/fetch funnels (TPUDevice._put / fetch_tree and
   the fused tree-fetch). Approximate by design: scalar metric
@@ -39,6 +47,13 @@ from __future__ import annotations
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# duration event -> the float counter that accumulates its seconds
+_DURATION_COUNTERS = {
+    _COMPILE_EVENT: "jit_compile_seconds",
+    "/jax/core/compile/jaxpr_trace_duration": "jit_trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit_lower_seconds",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # Monotonic process-wide counters (plain ints: the GIL makes += atomic
 # enough for counting; these feed reports, not invariants).
@@ -51,12 +66,18 @@ _c = {
     # healthy its kernels are (the roofline verdict in
     # telemetry/costmodel.py reads exactly this).
     "jit_compile_seconds": 0.0,
+    # Tracing and lowering, which the persistent cache cannot skip, and
+    # how many of `jit_compiles` were loads from it (module docstring).
+    "jit_trace_seconds": 0.0,
+    "jit_lower_seconds": 0.0,
+    "compile_cache_hits": 0,
     "h2d_bytes": 0,
     "d2h_bytes": 0,
     "collective_bytes_est": 0,
     # Device-resident CompiledEnsemble cache hits (TPUDevice._predict_fn):
-    # a hit skips the per-call pushdown + ensemble re-upload (~27% of
-    # predict wall time in the resident-vs-total bench gap). Zero hits
+    # a hit skips the per-call pushdown + ensemble re-upload (a miss is
+    # the `ddt:predict:ensemble` span: 18 ms for 1000 trees of depth 6 on
+    # the v5e, 0.08% of a 100M-row call — PERF.md section 5). Zero hits
     # across a many-call scoring run means the cache is thrashing (more
     # live models than the LRU holds) or the model is being rebuilt
     # between calls.
@@ -146,11 +167,18 @@ def install_jax_listener() -> None:
         return
 
     def _on_duration(event, duration_secs=None, **kw) -> None:
-        if event == _COMPILE_EVENT:
-            _c["jit_compiles"] += 1
-            _c["jit_compile_seconds"] += float(duration_secs or 0.0)
+        name = _DURATION_COUNTERS.get(event)
+        if name is not None:
+            _c[name] += float(duration_secs or 0.0)
+            if event == _COMPILE_EVENT:
+                _c["jit_compiles"] += 1
+
+    def _on_event(event, **kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            _c["compile_cache_hits"] += 1
 
     monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
     _listener_installed = True
 
 
@@ -237,7 +265,8 @@ def delta(start: dict, end: dict | None = None) -> dict:
     log's JSON readable; integer counters pass through exact."""
     end = end if end is not None else snapshot()
     out = {k: end[k] - start.get(k, 0) for k in _c}
-    out["jit_compile_seconds"] = round(out["jit_compile_seconds"], 4)
+    for name in _DURATION_COUNTERS.values():
+        out[name] = round(out[name], 4)
     return out
 
 

@@ -36,6 +36,11 @@ REL_FLOOR = 0.20
 COUNTER_DIRECTIONS: dict[str, str] = {
     "jit_compiles": "lower",
     "jit_compile_seconds": "lower",
+    "jit_trace_seconds": "lower",
+    "jit_lower_seconds": "lower",
+    # Programs loaded from the persistent compile cache: a property of
+    # the cache directory the run found, not of the code under diff.
+    "compile_cache_hits": "neutral",
     "h2d_bytes": "lower",
     "d2h_bytes": "lower",
     "collective_bytes_est": "lower",

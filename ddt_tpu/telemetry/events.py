@@ -226,7 +226,8 @@ EVENT_EXTRAS: dict[str, tuple] = {
     # publish beyond the required four — kept in sync with the `_c`
     # registry by the undeclared-event-extra cross-check.
     "counters": (
-        "jit_compile_seconds", "compiled_ensemble_cache_hits",
+        "jit_compile_seconds", "jit_trace_seconds", "jit_lower_seconds",
+        "compile_cache_hits", "compiled_ensemble_cache_hits",
         "fault_retries", "hist_oom_degrades",
         "serve_requests", "serve_batches", "serve_hot_swaps",
         "serve_express", "fleet_evictions", "fleet_reloads",

@@ -542,15 +542,28 @@ def test_http_metrics_read_only_with_drift_enabled(served_drift_fleet,
     for i in range(0, 600, 100):
         _post(port, "/models/champ/predict",
               {"rows": shifted[i:i + 100].tolist()})
-    a = _get_raw(port, "/metrics")
-    dbg = json.loads(_get_raw(port, "/debug/drift"))
-    assert dbg["fleet"] is True
-    assert dbg["models"]["champ"]["state"]["alerting"] is True
-    b = _get_raw(port, "/metrics")
 
     def drift_series(text):
         return {k: v for k, v in parse_exposition(text).items()
                 if k.startswith("ddt_drift_")}
+
+    # The drift observer runs AFTER the waiter has its answer
+    # (serve/engine.py), so the last batch may still be on its way into
+    # the window when its response arrives: scrape until it is there
+    # (scrapes are read-only, which is what this test pins), then
+    # compare two scrapes of a settled window.
+    champ = frozenset({("model", "champ")})
+    deadline = time.monotonic() + 30.0
+    while True:
+        a = _get_raw(port, "/metrics")
+        if drift_series(a)["ddt_drift_window_rows"][champ] >= 600 \
+                or time.monotonic() > deadline:
+            break
+        time.sleep(0.01)
+    dbg = json.loads(_get_raw(port, "/debug/drift"))
+    assert dbg["fleet"] is True
+    assert dbg["models"]["champ"]["state"]["alerting"] is True
+    b = _get_raw(port, "/metrics")
 
     # scrape-idempotent on the drift series: the scrapes (and the
     # /debug/drift read between them) rotated no window, reset no

@@ -1,0 +1,293 @@
+"""Host spans (telemetry/annotations.phase_span): the in-memory records,
+the spans and counters of one TPUDevice.predict_raw call on each of its
+three branches, and the compile listener's new counters."""
+
+import collections
+import json
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from ddt_tpu.backends import get_backend
+from ddt_tpu.config import TrainConfig
+from ddt_tpu.models.tree import TreeEnsemble
+from ddt_tpu.telemetry import annotations as an
+from ddt_tpu.telemetry import counters as tele_counters
+
+
+def _rand_ensemble(seed, T=5, depth=3, F=6, bins=31):
+    rng = np.random.default_rng(seed)
+    N = 2 ** (depth + 1) - 1
+    return TreeEnsemble(
+        feature=rng.integers(0, F, size=(T, N)).astype(np.int32),
+        threshold_bin=rng.integers(0, bins - 1, (T, N)).astype(np.int32),
+        threshold_raw=np.zeros((T, N), np.float32),
+        is_leaf=rng.random((T, N)) < 0.25,
+        leaf_value=rng.standard_normal((T, N)).astype(np.float32),
+        split_gain=np.zeros((T, N), np.float32),
+        max_depth=depth, n_features=F, learning_rate=0.1, base_score=0.3,
+        loss="logloss", n_classes=2, n_bins=bins)
+
+
+def _by_name(root):
+    out = collections.defaultdict(list)
+    for s in root["spans"]:
+        out[s["name"]].append(s)
+    return out
+
+
+# ------------------------------------------------------------------ #
+# the records
+# ------------------------------------------------------------------ #
+
+def test_nesting_gives_cause_and_root_on_one_thread():
+    with an.phase_span("t:outer", rows=3) as outer:
+        with an.phase_span("t:mid") as mid:
+            with an.phase_span("t:leaf") as leaf:
+                pass
+        with an.phase_span("t:second") as second:
+            second.counts["bytes"] = 7
+    assert (outer.cause, outer.root) == (None, outer.id)
+    assert (mid.cause, mid.root) == (outer.id, outer.id)
+    assert (leaf.cause, leaf.root) == (mid.id, outer.id)
+    assert (second.cause, second.root) == (outer.id, outer.id)
+    assert outer.id < mid.id < leaf.id < second.id
+    assert outer.start <= mid.start <= leaf.start <= leaf.end <= mid.end \
+        <= second.start <= second.end <= outer.end
+    root, = [r for r in an.root_spans("t:outer") if r["id"] == outer.id]
+    assert [s["name"] for s in root["spans"]] == [
+        "ddt:t:outer", "ddt:t:mid", "ddt:t:leaf", "ddt:t:second"]
+    assert root["counts"] == {"rows": 3}
+    assert root["spans"][-1]["counts"] == {"bytes": 7}
+    # the next span of this thread is a root again: the stack unwound
+    with an.phase_span("t:after") as after:
+        pass
+    assert after.cause is None and after.root == after.id
+
+
+def test_a_span_opened_on_another_thread_is_a_root():
+    seen = {}
+
+    def work():
+        with an.phase_span("t:worker") as w:
+            with an.phase_span("t:worker:child") as c:
+                pass
+        seen["w"], seen["c"] = w, c
+
+    with an.phase_span("t:main") as main:
+        th = threading.Thread(target=work)
+        th.start()
+        th.join(timeout=30)
+        assert not th.is_alive()
+        with an.phase_span("t:main:child") as child:
+            pass
+    w, c = seen["w"], seen["c"]
+    assert w.cause is None and w.root == w.id != main.id
+    assert (c.cause, c.root) == (w.id, w.id)
+    assert (child.cause, child.root) == (main.id, main.id)
+    assert main.start <= w.start <= w.end <= main.end   # same clock
+
+
+def test_the_ring_is_bounded(monkeypatch):
+    assert an._ring.maxlen == an.SPAN_RING >= 4096
+    monkeypatch.setattr(an, "_ring", collections.deque(maxlen=16))
+    ids = []
+    for _ in range(17):
+        with an.phase_span("t:ring") as s:
+            pass
+        ids.append(s.id)
+    kept = an.recent_spans()
+    assert len(kept) == 16
+    assert [s["id"] for s in kept] == ids[1:]       # the oldest went
+
+
+def test_a_span_that_raises_is_recorded_and_unwinds():
+    with pytest.raises(KeyError):
+        with an.phase_span("t:raises") as s:
+            raise KeyError("x")
+    assert s.end >= s.start
+    assert an.recent_spans()[-1]["id"] == s.id
+    with an.phase_span("t:next") as nxt:
+        pass
+    assert nxt.cause is None
+
+
+def test_the_anchor_turns_a_span_into_wall_clock_time():
+    perf, wall = an.SPAN_ANCHOR
+    with an.phase_span("t:anchor") as s:
+        now = time.time_ns()
+    as_wall = s.start - perf + wall
+    assert abs(as_wall - now) < 5e9         # same instant, two clocks
+
+
+def test_an_empty_span_costs_microseconds():
+    """A generous guard, not a measurement (that is PERF.md's, on the
+    chip's host): the mean of 100,000 empty spans stays under 20 us."""
+    t0 = time.perf_counter_ns()
+    for _ in range(100_000):
+        with an.phase_span("t:cost"):
+            pass
+    assert (time.perf_counter_ns() - t0) / 100_000 < 20_000
+
+
+# ------------------------------------------------------------------ #
+# one predict_raw call
+# ------------------------------------------------------------------ #
+
+BRANCHES = {
+    # branch: (partitions, PREDICT_ROW_CHUNK, rows,
+    #          {span: how many of it}, chunks)
+    "one": (1, 4096, 1000, dict(upload=1, dispatch=1, fetch=1, concat=0), 1),
+    "chunks": (1, 256, 1000, dict(upload=1, dispatch=4, fetch=4, concat=1),
+               4),
+    "mesh": (4, 64, 1000, dict(upload=4, dispatch=4, fetch=1, concat=0), 4),
+}
+
+
+@pytest.mark.parametrize("branch", sorted(BRANCHES))
+def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
+    parts, row_chunk, R, want, chunks = BRANCHES[branch]
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31,
+                                 n_partitions=parts))
+    monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", row_chunk)
+    ens = _rand_ensemble(seed=1000 + sorted(BRANCHES).index(branch))
+    Xb = np.random.default_rng(5).integers(0, 31, size=(R, 6),
+                                           dtype=np.uint8)
+
+    c0 = tele_counters.snapshot()
+    scores = be.predict_raw(ens, Xb)
+    moved = tele_counters.delta(c0)
+    root = an.root_spans("predict")[-1]
+    kids = _by_name(root)
+
+    assert root["counts"]["rows"] == R
+    assert root["counts"]["chunks"] == chunks
+    assert root["counts"]["branch"] == branch
+    for k in type(be)._PREDICT_ROOT_COUNTERS:
+        assert root["counts"][k] == moved[k]
+    assert root["counts"]["compiled_ensemble_cache_hits"] == 0
+    assert len(kids["ddt:predict"]) == 1
+    assert len(kids["ddt:predict:token"]) == 1
+    assert len(kids["ddt:predict:ensemble"]) == 1
+    for name, n in want.items():
+        assert len(kids["ddt:predict:" + name]) == n, name
+    for s in root["spans"]:
+        if s["id"] != root["id"]:
+            assert (s["cause"], s["root"]) == (root["id"], root["id"])
+            assert root["start"] <= s["start"] <= s["end"] <= root["end"]
+    for name in ("dispatch", "fetch"):
+        assert [s["counts"]["chunk"] for s in kids["ddt:predict:" + name]] \
+            == list(range(want[name]))
+    # bytes where the work happens, and the same bytes on the counters
+    ens_bytes = kids["ddt:predict:ensemble"][0]["counts"]["bytes"]
+    assert ens_bytes > 0
+    assert sum(s["counts"]["bytes"] for s in kids["ddt:predict:upload"]) \
+        == Xb.nbytes
+    assert sum(s["counts"]["bytes"] for s in kids["ddt:predict:fetch"]) \
+        == scores.nbytes
+    for s in kids["ddt:predict:concat"]:
+        assert s["counts"]["bytes"] == scores.nbytes
+    assert moved["h2d_bytes"] == Xb.nbytes + ens_bytes
+    assert moved["d2h_bytes"] == scores.nbytes
+    np.testing.assert_allclose(scores, ens.predict_raw(Xb, binned=True),
+                               rtol=2e-4, atol=2e-5)
+
+    # the second call finds the model resident: no ensemble span, and
+    # the link carries the batch and the scores alone
+    c1 = tele_counters.snapshot()
+    again = be.predict_raw(ens, Xb)
+    moved = tele_counters.delta(c1)
+    second = an.root_spans("predict")[-1]
+    assert second["id"] > root["id"]
+    assert "ddt:predict:ensemble" not in _by_name(second)
+    assert second["counts"]["compiled_ensemble_cache_hits"] == 1
+    assert (moved["h2d_bytes"], moved["d2h_bytes"]) \
+        == (Xb.nbytes, scores.nbytes)
+    np.testing.assert_array_equal(scores, again)
+
+
+def test_a_device_resident_batch_uploads_nothing(monkeypatch):
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31))
+    monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", 256)
+    ens = _rand_ensemble(seed=1100)
+    Xb = np.random.default_rng(6).integers(0, 31, size=(1000, 6),
+                                           dtype=np.uint8)
+    want = be.predict_raw(ens, Xb)
+    c0 = tele_counters.snapshot()
+    got = be.predict_raw(ens, jax.device_put(Xb))
+    root = an.root_spans("predict")[-1]
+    assert root["counts"]["branch"] == "chunks"
+    assert _by_name(root)["ddt:predict:upload"][0]["counts"]["bytes"] == 0
+    assert tele_counters.delta(c0)["h2d_bytes"] == 0
+    np.testing.assert_array_equal(want, got)
+
+
+def test_a_compiled_ensemble_skips_the_token_span():
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31))
+    ens = _rand_ensemble(seed=1200)
+    Xb = np.random.default_rng(7).integers(0, 31, size=(64, 6),
+                                           dtype=np.uint8)
+    be.predict_raw(ens, Xb, compiled=ens.compile(tree_chunk=64))
+    kids = _by_name(an.root_spans("predict")[-1])
+    assert "ddt:predict:token" not in kids
+    assert len(kids["ddt:predict:ensemble"]) == 1
+
+
+# ------------------------------------------------------------------ #
+# the compile listener
+# ------------------------------------------------------------------ #
+
+def test_listener_times_tracing_and_lowering_of_a_fresh_jit():
+    tele_counters.install_jax_listener()
+    c0 = tele_counters.snapshot()
+    jax.jit(lambda x: x * 3 + 1)(np.arange(7.0)).block_until_ready()
+    moved = tele_counters.delta(c0)
+    assert moved["jit_compiles"] >= 1
+    assert moved["jit_trace_seconds"] > 0
+    assert moved["jit_lower_seconds"] > 0
+    assert moved["jit_compile_seconds"] > 0
+    assert moved["compile_cache_hits"] == 0     # the suite's cache is off
+
+
+def test_listener_counts_loads_from_the_persistent_cache():
+    """jax reports a persistent-cache hit as a plain event; the suite
+    keeps the cache off, so the event is fired by hand."""
+    from jax import monitoring
+
+    tele_counters.install_jax_listener()
+    c0 = tele_counters.snapshot()
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event(
+        "/jax/compilation_cache/compile_requests_use_cache")
+    assert tele_counters.delta(c0)["compile_cache_hits"] == 1
+
+
+# ------------------------------------------------------------------ #
+# the operator's surface
+# ------------------------------------------------------------------ #
+
+def test_cli_predict_prints_phases_ms(tmp_path, capsys):
+    from ddt_tpu.cli import main
+
+    model = str(tmp_path / "ens.npz")
+    assert main(["train", "--backend=tpu", "--dataset=higgs", "--rows=1500",
+                 "--trees=3", "--depth=3", "--bins=31",
+                 f"--out={model}"]) == 0
+    capsys.readouterr()
+    assert main(["predict", "--backend=tpu", f"--model={model}",
+                 "--dataset=higgs", "--rows=400", "--bins=31"]) == 0
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert sorted(rec["phases_ms"]) == sorted(
+        ["token", "ensemble", "upload", "dispatch", "fetch", "concat"])
+    assert all(v >= 0 for v in rec["phases_ms"].values())
+    assert rec["phases_ms"]["upload"] > 0 and rec["phases_ms"]["fetch"] > 0
+    assert sum(rec["phases_ms"].values()) <= rec["wallclock_s"] * 1e3
+
+    # the NumPy backend opens no device call: nothing to break down
+    assert main(["predict", "--backend=cpu", f"--model={model}",
+                 "--dataset=higgs", "--rows=400", "--bins=31"]) == 0
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rec["phases_ms"] is None
