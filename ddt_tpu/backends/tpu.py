@@ -1781,8 +1781,10 @@ class TPUDevice(DeviceBackend):
             tele_counters.record_compiled_ensemble_hit()
             return hit
         with phase_span("predict:ensemble") as sp:
-            fn, ens_dev, resolved = self._build_predict_fn(ens, compiled)
+            fn, ens_dev, resolved, tree_group = self._build_predict_fn(
+                ens, compiled)
             sp.counts["bytes"] = sum(a.nbytes for a in ens_dev)
+            sp.counts["tree_group"] = tree_group
         self._predict_cache[token] = (fn, ens_dev)
         self._predict_impl_resolved[token] = resolved
         while len(self._predict_cache) > self.PREDICT_CACHE_MAX:
@@ -1793,13 +1795,17 @@ class TPUDevice(DeviceBackend):
 
     def _build_predict_fn(self, ens: TreeEnsemble, compiled):
         """_predict_fn's cache miss: (fn, device arrays, the tier that
-        serves) — layout build or reuse, quantisation, the node tables'
-        upload and the mesh wrapper."""
+        serves, the f32 traversal kernel's tree group) — layout build or
+        reuse, quantisation, the node tables' upload and the mesh wrapper.
+        The tree group is the lane width of the kernel's tree planes
+        (ops/predict_pallas.TREE_GROUP), 0 when that kernel does not serve
+        the model: the one-hot path, the LUT tiers."""
         ce = compiled if compiled is not None else ens.compile(
             tree_chunk=64)
         impl_req = self.cfg.predict_impl
         lut = None
         resolved = "f32"
+        tree_group = 0
         if impl_req in ("lut", "lut4"):
             if impl_req == "lut4":
                 lut = self._lut_fn(ce, ens.n_features, tier="lut4")
@@ -1827,6 +1833,13 @@ class TPUDevice(DeviceBackend):
             use_missing = ce.eff_dl is not None
             use_cat = ce.eff_cat is not None
             use_pallas = self._use_pallas
+            if predict_ops.resolve_use_pallas(
+                    use_pallas, True, ce.n_trees_padded, ce.tree_chunk,
+                    ce.max_depth, ens.n_features, ce.n_classes_out,
+                    use_missing + use_cat):
+                from ddt_tpu.ops.predict_pallas import TREE_GROUP
+
+                tree_group = TREE_GROUP
 
             def fn0(ef, et, bv, coh, *rest):
                 *opt, Xc = rest
@@ -1873,4 +1886,4 @@ class TPUDevice(DeviceBackend):
                 # here (no collectives anywhere in the traversal).
                 check_vma=False,
             ))
-        return fn, ens_dev, resolved
+        return fn, ens_dev, resolved, tree_group

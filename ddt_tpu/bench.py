@@ -804,7 +804,7 @@ def bench_predict_both(
 
     tpad = -(-trees // 64) * 64
     impl = ("pallas" if resolve_use_pallas(None, True, tpad, 64, depth,
-                                           features, 1) else "onehot")
+                                           features, 1, 0) else "onehot")
     base = {"kernel": "predict", "backend": "tpu", "rows": rows,
             "trees": trees, "depth": depth, "impl": impl}
     out = []

@@ -97,8 +97,11 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     """Host milliseconds of each step of the device scoring calls whose
     root span `ddt:predict` started at or after `since_ns`
     (time.perf_counter_ns): token, ensemble, upload, dispatch, fetch,
-    concat (docs/OBSERVABILITY.md has the table). None when no such call
-    ran: the NumPy backend and raw-threshold scoring open no span."""
+    concat (docs/OBSERVABILITY.md has the table), and with them
+    `tree_group`, not a time: the lane width of the traversal kernel's
+    tree planes as the model's `ensemble` span recorded it (0: the
+    kernel does not serve the model). None when no such call ran: the
+    NumPy backend and raw-threshold scoring open no span."""
     from ddt_tpu.telemetry.annotations import PREFIX, root_spans
 
     roots = [r for r in root_spans("predict") if r["start"] >= since_ns]
@@ -106,12 +109,16 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
         return None
     ms = dict.fromkeys(
         ("token", "ensemble", "upload", "dispatch", "fetch", "concat"), 0.0)
+    tree_group = None
     for r in roots:
         for s in r["spans"]:
             step = s["name"].removeprefix(PREFIX + "predict:")
             if step in ms:
                 ms[step] += (s["end"] - s["start"]) / 1e6
-    return {k: round(v, 3) for k, v in ms.items()}
+            if step == "ensemble":
+                tree_group = s["counts"]["tree_group"]
+    return {**{k: round(v, 3) for k, v in ms.items()},
+            "tree_group": tree_group}
 
 
 def _predict_streaming(args, bundle) -> int:

@@ -215,12 +215,14 @@ def traverse(
 
 def resolve_use_pallas(use_pallas, binned: bool, n_trees_padded: int,
                        tree_chunk: int, max_depth: int, n_features: int,
-                       n_classes: int) -> bool:
+                       n_classes: int, optional_operands: int = 2) -> bool:
     """The ONE home of the pallas-vs-one-hot predict dispatch rule.
 
     None = auto: the Pallas traversal kernel is taken when the data is
     binned, a real TPU backs the computation, and the kernel's VMEM
-    working set fits (predict_pallas.predict_pallas_fits). Explicit True
+    working set fits (predict_pallas.predict_pallas_fits, which is told
+    how many of the missing and categorical tables the ensemble carries;
+    both, where the caller cannot say). Explicit True
     demands the kernel (binned data required — raises otherwise; off-TPU
     it runs in interpret mode, the test contract); explicit False always
     takes the one-hot path."""
@@ -232,7 +234,7 @@ def resolve_use_pallas(use_pallas, binned: bool, n_trees_padded: int,
         return (binned and device.platform() == "tpu"
                 and predict_pallas.predict_pallas_fits(
                     n_trees_padded, tree_chunk, max_depth, n_features,
-                    n_classes))
+                    n_classes, optional_operands=optional_operands))
     if not binned:
         raise ValueError(
             "use_pallas=True requires binned (integer) data; the Pallas "
@@ -267,7 +269,7 @@ def _predict_effective(
         return out[:, 0] if C == 1 else out
     Tpad = eff_feat.shape[0]
     if resolve_use_pallas(use_pallas, binned, Tpad, tree_chunk, max_depth,
-                          F, C):
+                          F, C, (eff_dl is not None) + (eff_cat is not None)):
         from ddt_tpu.ops import predict_pallas
 
         return predict_pallas.predict_effective_pallas(

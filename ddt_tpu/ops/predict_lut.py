@@ -100,7 +100,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ddt_tpu.ops.predict_pallas import WORK_BYTES_PER_LANE
 from ddt_tpu.telemetry.annotations import op_scope, traced_scope
 from ddt_tpu.telemetry.costmodel import costed
 from ddt_tpu.utils import device
@@ -111,6 +110,18 @@ from ddt_tpu.utils import device
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _DEFAULT_TILE_R = 256
 _MAX_TRACE_SELECTS = 32_768
+# Working-set bytes per (row, lane) of a tree chunk's [TILE_R, Nint*Tc]
+# colval (f32) and comparison bits (int32) under the k-indexed descent
+# (the f32 kernel has no such array and counts by the row:
+# predict_pallas._ROW_BYTES). NOT 8: the compiler streams both through
+# vector registers plane by plane and spills little. Taken from the
+# compiler's own account of this descent with f32 tables — AOT compiles
+# for a described v5e with the scoped limit forced to 1 MiB, so that
+# each reports its allocation (PERF.md, PR 21): <= 0.9 B
+# per (row, lane) at every probed shape (tile 256 and 512, depth 4-8,
+# with and without the missing/cat operands, 1 and 7 classes). 2 keeps
+# every admitted shape inside what was shown to compile.
+WORK_BYTES_PER_LANE = 2
 
 #: int8 bin recentering offset: uint8 bins [0, 255] -> [-128, 127].
 _I8_OFFSET = 128
@@ -385,8 +396,10 @@ def _lut_kernel(x_ref, feat_ref, thr_ref, val_ref, *rest,
     copy); feat [n_tc, Nint*Tc] int32 node-major; thr [n_tc, Nint*Tc]
     int8 recentred; val [n_tc, W*Tc] f16 or int8; optional scale
     [n_tc, Tc] f32; coh [Tpad, C] f32; optional dl/cat [n_tc, Nint*Tc]
-    int8; out [TILE_R, C] f32. Descent logic mirrors predict_pallas.
-    _traverse_kernel plane for plane; only the table dtypes differ."""
+    int8; out [TILE_R, C] f32. A k-indexed descent and leaf select over
+    tree_chunk-lane planes; the f32 kernel (predict_pallas) walks a value
+    mux tree over 128-lane tree groups instead (ROADMAP C1 decides these
+    kernels)."""
     rest = list(rest)
     out_ref = rest.pop()
     scale_ref = rest.pop(0) if use_scale else None

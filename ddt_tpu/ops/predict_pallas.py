@@ -13,40 +13,47 @@ die inside one row tile's VMEM residency.
 
 Layout strategy (one grid step = one tile of TILE_R rows; ALL tree tables are
 pinned in VMEM for the whole kernel via constant index maps — a 1000-tree
-depth-6 ensemble is ~1 MB):
+depth-6 ensemble is ~1.3 MB). Trees are taken in GROUPS of TREE_GROUP = 128,
+one vreg's lanes and one MXU weight tile, whatever tree_chunk the compiled
+ensemble was laid out with (the padded tree count is padded again, inside the
+jitted program, to a multiple of 128 with trees that score 0):
 
     X     [TILE_R, F]        int32 bins, cast bf16 in-VMEM (the matmul
                              operand; its f32 result is what compares).
-    feat  [n_tc, Nint*Tc]    NODE-MAJOR flattened effective features per
-                             tree chunk (lane block n holds node n of all
-                             Tc trees) — so every descent select is a
-                             STATIC lane slice, no gathers anywhere.
-    thr/dl/cat               same node-major layout.
-    val   [n_tc, W*Tc]       bottom-level pushed-down leaf values.
-    coh   [Tpad, C]          round-major class one-hot.
+    feat  [n_tg*Nint, 128]   ONE PLANE PER ROW: row g*Nint + n holds node n
+    thr/dl/cat               of the 128 trees of group g. A node's table
+                             entries are a row load at lane offset 0 and a
+                             sublane broadcast (the only form of broadcast
+                             Mosaic gives a layout on both sides of a
+                             select), no lane slices, no gathers anywhere.
+    val   [n_tg*W, 128]      bottom-level pushed-down leaf values, same.
+    coh   [n_tg*128, C]      round-major class one-hot.
 
-Per tree chunk (static Python loop, traced once):
-    fohT [F, Nint*Tc] bf16 one-hot built on the VPU by SUBLANE-broadcasting
-        the feature row against a lane iota (the hist_pallas transposed-
-        kernel trick), then ONE MXU matmul with an f32 accumulator:
-        colval = X @ fohT — the exact bin value at every (row, tree, node).
-    comp = colval > thr (with categorical one-vs-rest and reserved-NaN-bin
-        routing applied exactly as ops/predict._descend_comp).
-    D-step indexed descent: k[r, t] starts 0; level d selects the path
-        node's comparison bit by k-indexed predicated selects over the
-        level's 2^d node planes (each plane a static lane slice) —
-        sum(2^d) = Nint VPU selects per chunk, zero HBM traffic.
-    Leaf select + class scatter: vals[r, t] by k-indexed select over the
-        W bottom planes, then acc += vals @ class-one-hot (f32, HIGHEST —
-        bit-stable, mirroring the one-hot path's accumulation order).
+Per tree group (static Python loop, traced once), per node n (depth-first):
+    foh [F, 128] bf16 one-hot built on the VPU by SUBLANE-broadcasting the
+        node's feature row against a sublane iota (the hist_pallas
+        transposed-kernel trick), then one MXU weight tile:
+        colval = X @ foh — the exact bin value of node n's feature at every
+        (row, tree) of the group.
+    goes_right = colval > thr, a predicate used as it is (with the
+        categorical one-vs-rest and reserved-NaN-bin operands the integer
+        routing of ops/predict._descend_comp, term for term, then != 0).
+    Value mux tree: a full tree whose Nint comparison bits are all known
+        is a multiplexer over its W leaf values —
+        leaf(n) = where(goes_right(n), leaf(2n+2), leaf(2n+1)), the leaves
+        being val's rows. Nint compares + Nint selects per (row, tree)
+        where the path has depth nodes: there is no node index k, no k == i
+        and no leaf select. Depth-first keeps depth + 1 planes live.
+    Class scatter: acc += vals @ class-one-hot (f32, HIGHEST), one dot a
+        group.
 
-Contract: the SAME leaf per (row, tree) as ops/predict.predict_raw at the
-same tree_chunk (missing-value routing, categorical one-vs-rest, softmax
-round-major classes all preserved; the integer descent is identical). The
-float accumulation is written term for term like the one-hot path's, but
-the summation order inside a dot belongs to the compiler, so scores agree
-to f32 rounding, not bitwise (tests/test_predict_pallas.py: equality on
-dyadic leaf values, a 1e-6 tolerance on random ones).
+Contract: the SAME leaf per (row, tree) as ops/predict.predict_raw
+(missing-value routing, categorical one-vs-rest, softmax round-major classes
+all preserved). The float accumulation sums a group's 128 trees in one dot
+where the one-hot path sums tree_chunk at a time, and the summation order
+inside a dot belongs to the compiler, so scores agree to f32 rounding, not
+bitwise (tests/test_predict_pallas.py: equality on dyadic leaf values, a
+1e-6 tolerance on random ones).
 Interpret mode auto-selects off-TPU (utils/device.platform), same pattern
 as hist_pallas.py; dispatch lives in ops/predict.resolve_use_pallas (the
 `use_pallas` flag on predict_raw / predict_raw_effective, one-hot fallback).
@@ -71,21 +78,33 @@ from ddt_tpu.utils import device
 # budgets.
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _DEFAULT_TILE_R = 256
-# Working-set bytes per (row, lane) of a tree chunk's [TILE_R, Nint*Tc]
-# colval (f32) and comparison bits (int32). NOT 8: the compiler streams
-# both through vector registers plane by plane and spills little. Taken
-# from the compiler's own account — AOT compiles for a described v5e with
-# the scoped limit forced to 1 MiB, so that each reports its allocation
-# (PERF.md, PR 21): <= 0.9 B per (row, lane) at every probed shape (tile
-# 256 and 512, depth 4-8, with and without the missing/cat operands, 1
-# and 7 classes), and tile 512 x depth 8 (8.4M row-lanes) compiled under
-# the real limit where tile 1024 x depth 8 did not. 2 keeps every
-# admitted shape inside what was shown to compile.
-WORK_BYTES_PER_LANE = 2
-# Static-unroll ceiling: the kernel traces n_tc * (Nint + W + ~4) ops;
-# past this the trace (and Mosaic compile) grows pathological — the
-# one-hot path is the right tool for such shapes anyway.
-_MAX_TRACE_SELECTS = 32_768
+# Trees per group: the lanes of one vreg and the columns of one MXU weight
+# tile, so that every node plane [TILE_R, 128] is whole vregs. Not the
+# compiled ensemble's tree_chunk (64): on the v5e the same mux tree over
+# 64-lane planes, half of every vreg empty, took 524.8 ms for 2M rows x
+# 1024 trees against 194.2 ms over 128-lane planes (PERF.md section 6,
+# PR 26).
+TREE_GROUP = 128
+# Working-set bytes of a tile beside the operand windows: the bf16 copy of
+# the rows, the one node plane in flight (one-hot, colval, predicate) and
+# the depth + 1 value planes of the depth-first mux tree, each
+# [TILE_R, 128] f32. It grows with the rows and not with the tree count:
+# no [TILE_R, Nint*128] array exists. Taken from the compiler's own
+# account — AOT compiles for a described v5e with the scoped limit forced
+# to 1 MiB, so that each reports its allocation (compile check, PR 26;
+# KiB a row; 1000 trees, 28 features, 1 class, tile 256 unless said):
+#   no optional operand: depth 4 / 6 / 8: 5.5 / 7.2 / 9.2 (9 trees: 6.1;
+#       4000 trees: 7.2; tile 512: 7.3; depth 8, 54 features, 7 classes:
+#       9.2, and 9.4 at tile 512)
+#   one of them:         missing, depth 6 / 7: 7.4 / 8.4; cat, depth 6: 7.8
+#   both:                depth 3 / 5 / 6 / 7 / 8: 7.4 / 15.8 / 19.0 / 27.4 /
+#       34.9 (depth 6 at tile 512: 18.7; 4000 trees: 19.0; depth 8, 54
+#       features, 7 classes: 35.1)
+# Only the three-way integer routing of both operands makes the compiler
+# keep something per node. 12 KiB a row, and 192 B a node more with both,
+# bound every probe by an eighth or more.
+_ROW_BYTES = 12 * 1024
+_ROW_NODE_BYTES_BOTH = 192
 
 
 def _window_bytes(rows: int, cols: int) -> int:
@@ -101,101 +120,111 @@ def predict_pallas_fits(
     n_features: int,
     n_classes: int,
     tile_r: int | None = None,
+    optional_operands: int = 2,
 ) -> bool:
-    """Whether the traversal kernel's VMEM working set (and trace size)
-    fits at this shape — the guard behind use_pallas=None auto-dispatch
-    (ops/predict.resolve_use_pallas)."""
+    """Whether the traversal kernel's VMEM working set fits at this shape —
+    the guard behind use_pallas=None auto-dispatch
+    (ops/predict.resolve_use_pallas). `tree_chunk` is the compiled
+    ensemble's (the padded count must be a multiple of it, as on the
+    one-hot path); the kernel itself regroups the trees in TREE_GROUPs.
+    `optional_operands` counts the missing and categorical tables the
+    ensemble carries (both, where the caller cannot say)."""
     if tile_r is None:
         tile_r = _DEFAULT_TILE_R
     if n_trees_padded % tree_chunk != 0:
         return False
     n_int = (1 << max_depth) - 1
     n_leaves = 1 << max_depth
-    n_tc = n_trees_padded // tree_chunk
-    if n_tc * (n_int + n_leaves) > _MAX_TRACE_SELECTS:
-        return False
-    lanes = n_int * tree_chunk
-    work = tile_r * lanes * WORK_BYTES_PER_LANE
-    # Resident tables, every one a whole-array window: feat i32, thr f32,
-    # the optional dl and cat i32 (counted always — the guard is asked
-    # before the operands exist), bottom values, class one-hot.
-    trees = 4 * _window_bytes(n_tc, lanes)
-    trees += _window_bytes(n_tc, n_leaves * tree_chunk)
-    trees += _window_bytes(n_trees_padded, n_classes)
-    x_tile = _window_bytes(tile_r, n_features)
-    out = _window_bytes(tile_r, n_classes)
-    return work + trees + x_tile + out <= _VMEM_BUDGET_BYTES
+    n_tg = -(-n_trees_padded // TREE_GROUP)
+    # Resident tables, every one a whole-array window of one plane a row:
+    # feat i32, thr f32, dl and cat i32 where present, bottom values, class
+    # one-hot. They bound the trace too: 12 MB of them are at most 37
+    # groups at depth 6, some 2,300 node planes.
+    trees = (2 + optional_operands) * _window_bytes(n_tg * n_int,
+                                                    TREE_GROUP)
+    trees += _window_bytes(n_tg * n_leaves, TREE_GROUP)
+    trees += _window_bytes(n_tg * TREE_GROUP, n_classes)
+    work = tile_r * (_ROW_BYTES + (n_int * _ROW_NODE_BYTES_BOTH
+                                   if optional_operands == 2 else 0))
+    rows = _window_bytes(tile_r, n_features) + _window_bytes(tile_r,
+                                                             n_classes)
+    return trees + work + rows <= _VMEM_BUDGET_BYTES
 
 
 def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
-                     n_tc: int, tc: int, n_int: int, n_leaves: int,
-                     n_feat: int, max_depth: int, missing_bin_value: int,
-                     use_missing: bool, use_cat: bool):
+                     n_tg: int, n_int: int, n_leaves: int, n_feat: int,
+                     missing_bin_value: int, use_missing: bool,
+                     use_cat: bool):
     """One row tile: margins for every class, all trees, fully in VMEM.
 
-    x_ref [TILE_R, F] int32; feat/thr (+ optional dl, cat) [n_tc, Nint*Tc]
-    node-major; val [n_tc, W*Tc]; coh [Tpad, C]; out [TILE_R, C] f32."""
+    x_ref [TILE_R, F] int32; feat/thr (+ optional dl, cat) [n_tg*Nint, 128]
+    and val [n_tg*W, 128], one plane a row; coh [n_tg*128, C]; out
+    [TILE_R, C] f32."""
     rest = list(rest)
     out_ref = rest.pop()
     dl_ref = rest.pop(0) if use_missing else None
     cat_ref = rest.pop(0) if use_cat else None
     tile_r = x_ref.shape[0]
-    lanes = n_int * tc
+    tg = TREE_GROUP
     xb = x_ref[:].astype(jnp.bfloat16)                    # [T, F]
-    f_iota = jax.lax.broadcasted_iota(jnp.int32, (n_feat, lanes), 0)
-    acc = jnp.zeros((tile_r, out_ref.shape[1]), jnp.float32)
-    for c in range(n_tc):
-        # Feature one-hot, TRANSPOSED: sublane-broadcast the feature row
-        # (cheap row replication — the hist_pallas _hist_kernel_t trick)
-        # against the per-feature iota. feat = -1 (pushed-down leaves)
-        # matches no sublane -> colval 0 < thr(+BIG) -> always-left.
-        feat = jnp.broadcast_to(feat_ref[c:c + 1, :], (n_feat, lanes))
-        fohT = (feat == f_iota).astype(jnp.bfloat16)      # [F, Nint*Tc]
+    f_iota = jax.lax.broadcasted_iota(jnp.int32, (n_feat, tg), 0)
+
+    def plane(ref, row, rows=tile_r):
+        """Row `row` of a table over `rows` sublanes: a row load at lane
+        offset 0, then a sublane broadcast."""
+        return jnp.broadcast_to(ref[row:row + 1, :], (rows, tg))
+
+    def goes_right(row):
+        """Predicate [T, 128]: the row leaves the node of table row `row`,
+        in each of its group's trees, to the right."""
+        # Feature one-hot, TRANSPOSED: the node's feature row over F
+        # sublanes against the per-feature iota (the hist_pallas
+        # _hist_kernel_t trick). feat = -1 (pushed-down leaves) matches
+        # no sublane -> colval 0 < thr(+BIG) -> always-left.
+        foh = (plane(feat_ref, row, n_feat) == f_iota).astype(
+            jnp.bfloat16)                                 # [F, 128]
         # bf16 operands (bins <= 255 and the 0/1 one-hot are exact), f32
         # accumulator: the MXU accumulates in 32 bits only, and the v5e's
-        # VPU has no bf16 compare, so colval stays f32 from here on.
+        # VPU has no bf16 compare, so colval is f32.
         colval = jax.lax.dot_general(
-            xb, fohT, (((1,), (0,)), ((), ())),
+            xb, foh, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                 # [T, Nint*Tc]
-        thr = jnp.broadcast_to(thr_ref[c:c + 1, :], (tile_r, lanes))
-        # Comparison bits live as int32 0/1, never as a bool ARRAY:
-        # Mosaic keeps i1 only as a select's predicate — a select BETWEEN
-        # bool arrays needs an i8 -> i1 truncation it does not have.
+        )                                                 # [T, 128]
+        thr = plane(thr_ref, row)
+        if not (use_cat or use_missing):
+            return colval > thr
+        # With the optional operands the bits are routed as int32 0/1,
+        # never as a bool ARRAY: Mosaic keeps i1 only as a select's
+        # predicate — a select BETWEEN bool arrays needs an i8 -> i1
+        # truncation it does not have.
         comp = (colval > thr).astype(jnp.int32)
         if use_cat:
             # One-vs-rest nodes (pre-gated on eff_feat >= 0 in the
             # prologue): the matched bin goes left.
-            cat = jnp.broadcast_to(
-                cat_ref[c:c + 1, :], (tile_r, lanes)) != 0
+            cat = plane(cat_ref, row) != 0
             comp = jnp.where(cat, (colval != thr).astype(jnp.int32), comp)
         if use_missing:
             # Reserved-NaN-bin rows follow the learned direction;
             # pushed-down leaves have colval 0, never the reserved bin.
             miss = colval == jnp.float32(missing_bin_value)
-            not_dl = 1 - jnp.broadcast_to(
-                dl_ref[c:c + 1, :], (tile_r, lanes))
-            comp = jnp.where(miss, not_dl, comp)
-        # Indexed descent: k-select the path node's bit per level. Every
-        # node plane is a STATIC lane slice of the node-major comp.
-        k = jnp.zeros((tile_r, tc), jnp.int32)
-        for d in range(max_depth):
-            lo = (1 << d) - 1
-            go = jnp.zeros((tile_r, tc), jnp.int32)
-            for i in range(1 << d):
-                n = lo + i
-                go = jnp.where(k == i, comp[:, n * tc:(n + 1) * tc], go)
-            k = 2 * k + go
-        # Bottom-level leaf select (exact: k matches exactly one plane).
-        vals = jnp.zeros((tile_r, tc), jnp.float32)
-        for j in range(n_leaves):
-            plane = jnp.broadcast_to(
-                val_ref[c:c + 1, j * tc:(j + 1) * tc], (tile_r, tc))
-            vals = jnp.where(k == j, plane, vals)
-        # Class scatter — the same dot, precision, and per-chunk add order
-        # as the one-hot path's scan body (bit-stable mirror).
+            comp = jnp.where(miss, 1 - plane(dl_ref, row), comp)
+        return comp != 0
+
+    def leaf(g, n):
+        """Value [T, 128] of the leaf the row reaches below heap node n
+        of group g's trees: the mux tree, depth-first (depth + 1 planes
+        live)."""
+        if n >= n_int:
+            return plane(val_ref, g * n_leaves + n - n_int)
+        return jnp.where(goes_right(g * n_int + n),
+                         leaf(g, 2 * n + 2), leaf(g, 2 * n + 1))
+
+    acc = jnp.zeros((tile_r, out_ref.shape[1]), jnp.float32)
+    for g in range(n_tg):
+        # Class scatter — the one-hot path's dot and precision, one add a
+        # group.
         acc = acc + jax.lax.dot_general(
-            vals, coh_ref[c * tc:(c + 1) * tc, :],
+            leaf(g, 0), coh_ref[g * tg:(g + 1) * tg, :],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
@@ -245,8 +274,11 @@ def predict_effective_pallas(
         raise ValueError(
             f"padded tree count {Tpad} is not a multiple of "
             f"tree_chunk={tree_chunk}")
+    use_missing = eff_dl is not None
+    use_cat = eff_cat is not None
     if not interpret and not predict_pallas_fits(
-            Tpad, tree_chunk, max_depth, F, C, tile_r):
+            Tpad, tree_chunk, max_depth, F, C, tile_r,
+            use_missing + use_cat):
         # Compiled dispatch past the budget means a VMEM OOM or a
         # pathological Mosaic trace on the chip — fail at the cause. The
         # auto path (ops/predict.resolve_use_pallas) never gets here;
@@ -255,34 +287,39 @@ def predict_effective_pallas(
         raise ValueError(
             f"predict shape (trees_padded={Tpad}, tree_chunk={tree_chunk}, "
             f"depth={max_depth}, F={F}, C={C}) exceeds the Pallas "
-            "VMEM/trace budget; use the one-hot path")
-    n_tc = Tpad // tree_chunk
+            "VMEM budget; use the one-hot path")
+    tg = TREE_GROUP
+    n_tg = -(-Tpad // tg)
     n_int = (1 << max_depth) - 1
     n_leaves = 1 << max_depth
 
-    def node_major(a, width, dtype):
-        """[Tpad, width] -> [n_tc, width*Tc] with lane block n holding
-        node n of every tree in the chunk (tiny arrays; the transpose is
-        noise next to the row volume)."""
-        return (a.astype(dtype)
-                .reshape(n_tc, tree_chunk, width)
+    def by_plane(a, dtype, fill=0):
+        """[Tpad, width] -> [n_tg*width, 128]: row g*width + n holds
+        column n of the 128 trees of group g. The trees that fill the
+        last group score 0 (feature -1 matches no one-hot row, value 0,
+        a zero class row). Tiny arrays; the transpose is noise next to
+        the row volume."""
+        a = jnp.pad(a.astype(dtype), ((0, n_tg * tg - Tpad), (0, 0)),
+                    constant_values=fill)
+        width = a.shape[1]
+        return (a.reshape(n_tg, tg, width)
                 .transpose(0, 2, 1)
-                .reshape(n_tc, width * tree_chunk))
+                .reshape(n_tg * width, tg))
 
-    feat_nm = node_major(eff_feat[:, :n_int], n_int, jnp.int32)
-    thr_nm = node_major(eff_thr[:, :n_int], n_int, jnp.float32)
-    val_nm = node_major(bot_val, n_leaves, jnp.float32)
-    use_missing = eff_dl is not None
-    use_cat = eff_cat is not None
+    feat_pl = by_plane(eff_feat[:, :n_int], jnp.int32, fill=-1)
+    thr_pl = by_plane(eff_thr[:, :n_int], jnp.float32)
+    val_pl = by_plane(bot_val, jnp.float32)
+    coh = jnp.pad(cls_oh.astype(jnp.float32),
+                  ((0, n_tg * tg - Tpad), (0, 0)))
     extras = []
     if use_missing:
-        extras.append(node_major(eff_dl[:, :n_int], n_int, jnp.int32))
+        extras.append(by_plane(eff_dl[:, :n_int], jnp.int32))
     if use_cat:
         # Pre-gate on eff_feat >= 0 so pushed-down leaves (colval 0,
         # thr +BIG) stay always-left, exactly like _descend_comp.
         cat_eff = eff_cat[:, :n_int].astype(bool) & (eff_feat[:, :n_int]
                                                      >= 0)
-        extras.append(node_major(cat_eff, n_int, jnp.int32))
+        extras.append(by_plane(cat_eff, jnp.int32))
 
     Xi = Xc.astype(jnp.int32)
     n_tiles = -(-R // tile_r)
@@ -290,29 +327,29 @@ def predict_effective_pallas(
     if rpad:
         Xi = jnp.pad(Xi, ((0, rpad), (0, 0)))
 
-    lanes = n_int * tree_chunk
     kernel = functools.partial(
-        _traverse_kernel, n_tc=n_tc, tc=tree_chunk, n_int=n_int,
-        n_leaves=n_leaves, n_feat=F, max_depth=max_depth,
-        missing_bin_value=missing_bin_value, use_missing=use_missing,
-        use_cat=use_cat,
+        _traverse_kernel, n_tg=n_tg, n_int=n_int, n_leaves=n_leaves,
+        n_feat=F, missing_bin_value=missing_bin_value,
+        use_missing=use_missing, use_cat=use_cat,
     )
-    pinned = pl.BlockSpec((n_tc, lanes), lambda i: (0, 0),
-                          memory_space=pltpu.VMEM)
+
+    def pinned(rows, cols):
+        return pl.BlockSpec((rows, cols), lambda i: (0, 0),
+                            memory_space=pltpu.VMEM)
+
+    nodes = pinned(n_tg * n_int, tg)
     in_specs = [
         pl.BlockSpec((tile_r, F), lambda i: (i, 0),
                      memory_space=pltpu.VMEM),
-        pinned,                                           # feat
-        pinned,                                           # thr
-        pl.BlockSpec((n_tc, n_leaves * tree_chunk), lambda i: (0, 0),
-                     memory_space=pltpu.VMEM),            # val
-        pl.BlockSpec((Tpad, C), lambda i: (0, 0),
-                     memory_space=pltpu.VMEM),            # coh
-    ] + [pinned] * len(extras)
+        nodes,                                            # feat
+        nodes,                                            # thr
+        pinned(n_tg * n_leaves, tg),                      # val
+        pinned(n_tg * tg, C),                             # coh
+    ] + [nodes] * len(extras)
     cost = pl.CostEstimate(
-        flops=2 * n_tiles * tile_r * (F * n_tc * lanes + Tpad * C),
+        flops=2 * n_tiles * tile_r * n_tg * tg * (F * n_int + C),
         bytes_accessed=n_tiles * tile_r * (F + C) * 4
-        + n_tc * lanes * 8 + Tpad * C * 4,
+        + n_tg * tg * (n_int * 8 + n_leaves * 4 + C * 4),
         transcendentals=0,
     )
     with traced_scope("predict"):
@@ -327,8 +364,7 @@ def predict_effective_pallas(
                                                jnp.float32),
                 cost_estimate=cost,
                 interpret=interpret,
-            )(Xi, feat_nm, thr_nm, val_nm,
-              cls_oh.astype(jnp.float32), *extras)
+            )(Xi, feat_pl, thr_pl, val_pl, coh, *extras)
         with traced_scope("predict:accumulate"):
             out = base + learning_rate * acc[:R]
     return out[:, 0] if C == 1 else out

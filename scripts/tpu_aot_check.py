@@ -180,13 +180,21 @@ def kernel_cases() -> list:
         KernelCase("hist/covertype/255bins/N=128", True,
                    _hist_case(cr, cf, 128, 255)),
         # Traversal: with and without the missing / categorical
-        # operands, one output and seven.
+        # operands, one output and seven. The kernel takes the trees in
+        # groups of 128 whatever their count, so its layouts are: whole
+        # groups (1000 pads to 1024), a last group filled up with trees
+        # that score 0 (50 -> 64 -> 128; 210 -> 256; 150 -> 192 -> 256),
+        # each with and without the row-per-plane dl and cat tables.
         KernelCase("predict/higgs/1000x6", True,
                    _predict_case(hr, hf, 1000, 6)),
         KernelCase("predict/50x4/missing+cat", True,
                    _predict_case(hr, hf, 50, 4, missing=True, cat=True)),
         KernelCase("predict/covertype/210x6/7classes", True,
                    _predict_case(cr, cf, 210, 6, n_classes=cc)),
+        KernelCase("predict/150x6/missing+cat", True,
+                   _predict_case(hr, hf, 150, 6, missing=True, cat=True)),
+        KernelCase("predict/higgs/1000x6/missing+cat", True,
+                   _predict_case(hr, hf, 1000, 6, missing=True, cat=True)),
         # Opt-in kernels.
         KernelCase("hist/higgs/255bins/N=32/int8", False,
                    _hist_case(hr, hf, 32, 255, "int8")),
@@ -298,18 +306,25 @@ def program_cases(topo_devices) -> list:
             return fn, args, want
         return build
 
-    def scoring(n_trees):
+    def scoring(n_trees, rows=hr, features=hf, depth=6, n_classes=1):
         def build():
-            fn, args = _scoring_program(topo_devices, rows=hr, features=hf,
-                                        n_trees=n_trees, depth=6)
+            fn, args = _scoring_program(topo_devices, rows=rows,
+                                        features=features, n_trees=n_trees,
+                                        depth=depth, n_classes=n_classes)
             return fn, args, ["tpu_custom_call"]
         return build
 
     def scoring_onehot():
-        # Depth 8 is past predict_pallas_fits: the auto dispatch takes the
-        # XLA one-hot path, which Covertype's own models will score on.
+        # Covertype's own models, 500 rounds x 7 classes at depth 8, are
+        # past predict_pallas_fits (their tables alone are 29 MB): the
+        # auto dispatch takes the XLA one-hot path.
+        from ddt_tpu.ops.predict import resolve_use_pallas
+
+        n_trees = 500 * cc
+        assert not resolve_use_pallas(None, True, -(-n_trees // 64) * 64,
+                                      64, 8, cf, cc, 0)
         fn, args = _scoring_program(topo_devices, rows=cr, features=cf,
-                                    n_trees=10 * cc, depth=8, n_classes=cc)
+                                    n_trees=n_trees, depth=8, n_classes=cc)
         return fn, args, []
 
     return [
@@ -322,7 +337,11 @@ def program_cases(topo_devices) -> list:
                         n_classes=cc))),
         ("scoring/higgs/10x6", scoring(10)),
         ("scoring/higgs/1000x6", scoring(1000)),
-        ("scoring/covertype/70x8/onehot-path", scoring_onehot),
+        # Depth 8 costs the kernel tables only, no working set: a small
+        # Covertype model is served by it.
+        ("scoring/covertype/70x8", scoring(
+            10 * cc, rows=cr, features=cf, depth=8, n_classes=cc)),
+        ("scoring/covertype/3500x8/onehot-path", scoring_onehot),
     ]
 
 

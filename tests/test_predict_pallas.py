@@ -1,7 +1,7 @@
 """Pallas traversal kernel exactness sweep + compiled-ensemble cache tests.
 
 The kernel contract (ops/predict_pallas.py): the SAME leaf for every (row,
-tree) as the one-hot predict path at the same tree_chunk — missing-value
+tree) as the one-hot predict path — missing-value
 routing, categorical one-vs-rest, softmax round-major classes, uneven
 tree/row remainders, R=0 — and oracle-grade agreement with the NumPy
 scorer. Selection is integer-exact; the one float step is the class dot,
@@ -166,13 +166,101 @@ def test_pallas_rejects_float_data():
         jpred.predict_raw(*args, jnp.asarray(X), use_pallas=True, **kw)
 
 
+def _mux_cases():
+    """Depth 1-6 x tree counts 9, 64, 130, 1000 (one group with 119 filler
+    trees, half a group, two groups, eight) x 1 and 7 classes x with and
+    without the missing and categorical operands. The 1000-tree ensembles
+    cost 10-20 s each to trace interpreted, so they take every combination
+    at depth 6 only, and one each at depths 1-5."""
+    combos = [(1, False, ()), (7, False, ()), (1, True, (1, 4)),
+              (7, True, (2,))]
+    for depth in range(1, 7):
+        for T in (9, 64, 130):
+            for combo in combos:
+                yield (depth, T, *combo)
+        for combo in (combos if depth == 6 else [combos[depth % 4]]):
+            yield (depth, 1000, *combo)
+
+
+@pytest.mark.parametrize("depth,T,n_classes,missing,cat", list(_mux_cases()))
+def test_mux_tree_matches_onehot(depth, T, n_classes, missing, cat):
+    """The value mux tree on 128-lane tree groups selects the one-hot
+    path's leaf for every (row, tree): bit-equal scores on dyadic leaf
+    values, F32_ACC_TOL on random ones (a group's 128 trees are summed in
+    one dot, the one-hot path's 64 at a time)."""
+    ens = _rand_ensemble(T=T, depth=depth, n_classes=n_classes,
+                         missing=missing, cat=cat, seed=100 * depth + T)
+    # Keep the sums at the magnitude F32_ACC_TOL was sized for (11 leaf
+    # values of about 1), whatever the tree count.
+    ens.leaf_value *= min(1.0, 11 / T)
+    Xb = jnp.asarray(np.random.default_rng(depth).integers(
+        0, ens.n_bins, size=(300, ens.n_features)).astype(np.int32))
+    random_values = ens.leaf_value
+    for values, check in [
+        (np.round(random_values * 64 * max(1, T // 11)) / 64,
+         np.testing.assert_array_equal),
+        (random_values,
+         lambda a, b: np.testing.assert_allclose(a, b, **F32_ACC_TOL)),
+    ]:
+        ens.leaf_value = values.astype(np.float32)
+        args, kw, opt = _dev_args(ens)
+        want = np.asarray(jpred.predict_raw(
+            *args, Xb, tree_chunk=64, use_pallas=False, **kw, **opt))
+        got = np.asarray(jpp.predict_raw_pallas(
+            *args, Xb, tree_chunk=64, **kw, **opt))
+        check(want, got)
+
+
 def test_pallas_fits_guard():
     from ddt_tpu.ops.predict_pallas import predict_pallas_fits
 
     assert predict_pallas_fits(1024, 64, 6, 28, 1)       # the bench shape
     assert not predict_pallas_fits(1000, 64, 6, 28, 1)   # not a multiple
-    # monster shape blows the VMEM/trace budget
+    # monster shape blows the VMEM budget
     assert not predict_pallas_fits(1 << 20, 64, 10, 512, 1)
+    # The kernel regroups the trees in 128s itself: a padded count that is
+    # no multiple of 128 is admitted like its next multiple.
+    for tpad in (64, 192, 1088):
+        assert predict_pallas_fits(tpad, 64, 6, 28, 1)
+    # No [tile, Nint*lanes] array exists any more, so depth costs tables
+    # only, and an ensemble without the missing and categorical tables is
+    # not charged for them: the edges, as AOT-compiled under the real
+    # limit (PERF.md section 6, PR 26).
+    assert predict_pallas_fits(1024, 64, 7, 28, 1, optional_operands=0)
+    assert not predict_pallas_fits(1024, 64, 7, 28, 1)
+    assert predict_pallas_fits(1152, 64, 8, 54, 7, optional_operands=0)
+    assert not predict_pallas_fits(1280, 64, 8, 54, 7, optional_operands=0)
+    assert predict_pallas_fits(2816, 64, 6, 54, 7, optional_operands=1)
+    assert not predict_pallas_fits(2944, 64, 6, 54, 7, optional_operands=1)
+    assert predict_pallas_fits(1536, 64, 6, 54, 7)
+    assert not predict_pallas_fits(1664, 64, 6, 54, 7)
+    assert predict_pallas_fits(384, 64, 7, 54, 7)
+    assert not predict_pallas_fits(128, 64, 8, 28, 1)
+    # a larger tile is charged by the row
+    assert predict_pallas_fits(1024, 64, 6, 28, 1, tile_r=512,
+                               optional_operands=0)
+    assert not predict_pallas_fits(1024, 64, 6, 28, 1, tile_r=512)
+
+
+@pytest.mark.parametrize("impl,T,want", [
+    ("pallas", 9, 128), ("pallas", 130, 128), ("onehot", 9, 0),
+    ("auto", 9, 0),      # off the chip the auto dispatch is the one-hot path
+    ("lut", 9, 0),       # its own kernel, its own chunking
+])
+def test_ensemble_span_says_which_form_served(impl, T, want):
+    """`tree_group` on the `ddt:predict:ensemble` span: the lane width of
+    the traversal kernel's tree planes, 128 whatever the tree count, and 0
+    when that kernel does not serve the model."""
+    from ddt_tpu.telemetry import annotations as an
+
+    ens = _rand_ensemble(T=T, depth=3, F=5, bins=31, seed=40 + T)
+    Xb = np.random.default_rng(3).integers(0, 31, size=(50, 5),
+                                           dtype=np.uint8)
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31,
+                                 predict_impl=impl))
+    be.predict_raw(ens, Xb)
+    spans = {s["name"]: s for s in an.root_spans("predict")[-1]["spans"]}
+    assert spans["ddt:predict:ensemble"]["counts"]["tree_group"] == want
 
 
 # --------------------------------------------------------------------- #

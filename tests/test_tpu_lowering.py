@@ -49,11 +49,14 @@ def test_kernel_lowers_for_tpu(case):
 def test_case_table_covers_the_default_dispatch():
     """Both histogram forms, feature-chunked at the Covertype width, and
     the traversal kernel with and without the optional operands, for one
-    output and for seven."""
+    output and for seven, with whole tree groups and with a filled-up
+    last one."""
     names = [c.name for c in DEFAULT_CASES]
     for needle in ("hist/higgs/255bins", "hist/higgs/64bins",
-                   "hist/covertype", "predict/higgs", "missing+cat",
-                   "7classes"):
+                   "hist/covertype", "predict/higgs/1000x6",
+                   "predict/50x4/missing+cat", "7classes",
+                   "predict/150x6/missing+cat",
+                   "predict/higgs/1000x6/missing+cat"):
         assert any(needle in n for n in names), (needle, names)
 
 
