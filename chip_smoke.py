@@ -7,7 +7,10 @@ TPUDevice.grow_rounds; ddt_tpu.api.predict -> TPUDevice.predict_raw), at the
 full width of BASELINE.json config 1: 1,000,000 rows x 28 features from
 `synthetic_binary`, 255 bins, depth 6, backend="tpu", every other field at
 its default. Depth of the ENSEMBLE is cut: ten boosting rounds, not a
-hundred. Then all 1M binned rows are scored with the ten trees.
+hundred. Then all 1M binned rows are scored with the ten trees. And one
+ensemble of Covertype's own shape (500 rounds x 7 classes, depth 8, 54
+features: random trees, the scorer does not care) scores 100,000 rows through
+the same `api.predict`, its node tables streamed by blocks of tree groups.
 
 It asserts WHAT ran (the Pallas kernels, compiled: `tpu_custom_call` in both
 lowered programs; histogram resolved to `pallas`, sibling subtraction on; no
@@ -46,6 +49,7 @@ import numpy as np
 ROWS, FEATURES, BINS, DEPTH, ROUNDS = 1_000_000, 28, 255, 6, 10
 SEED = 42
 SCORE_CHECK_ROWS = 50_000
+MC_ROUNDS, MC_ROWS = 500, 100_000      # the 7-class scoring phase
 SCORE_TOL = dict(rtol=3e-4, atol=3e-4)      # as __graft_entry__'s oracle check
 # Chip-vs-oracle training parity: the bounds the earlier chip runs measured
 # inside (0.9871 agreement, 0.0024 AUC) and bench.py holds. Never bitwise
@@ -190,6 +194,72 @@ def check_scores_against_numpy(ens, Xb, scores) -> None:
     say(f"scores: {n} rows match TreeEnsemble.predict_raw (NumPy) within "
         f"{SCORE_TOL['rtol']:g}; max |diff| = "
         f"{float(np.abs(scores[:n] - want).max()):.2e}")
+
+
+def score_multiclass(overrides: dict, rows: int) -> None:
+    """Covertype's own ensemble shape through `api.predict`: 3,500 random
+    full trees (500 rounds x 7 classes) of depth 8 over 54 features. Asserts
+    that the traversal kernel served it by the auto dispatch, its tables
+    streamed in more than one block, and holds every class column to the
+    plain reference (reference/numpy_predict, in float64) on the first rows."""
+    import jax
+
+    from ddt_tpu import api
+    from ddt_tpu.backends import get_backend
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.models.tree import empty_ensemble
+    from ddt_tpu.reference import numpy_predict
+    from ddt_tpu.telemetry.annotations import root_spans
+    from ddt_tpu.utils import device
+
+    T, depth, F, C = MC_ROUNDS * 7, 8, 54, 7
+    rng = np.random.default_rng(SEED)
+    ens = empty_ensemble(T, depth, F, 0.1, 0.0, "softmax", C)
+    n_int = 2 ** depth - 1
+    ens.feature[:, :n_int] = rng.integers(0, F, (T, n_int))
+    ens.threshold_bin[:, :n_int] = rng.integers(0, BINS - 1, (T, n_int))
+    ens.is_leaf[:, n_int:] = True
+    ens.leaf_value[:, n_int:] = rng.standard_normal((T, n_int + 1))
+    Xb = rng.integers(0, BINS, size=(rows, F), dtype=np.uint8)
+    cfg = TrainConfig(n_bins=BINS, backend="tpu", **overrides)
+    comp = Compiles()
+    t0 = time.perf_counter()
+    scores = api.predict(ens, Xb, binned=True, raw=True, cfg=cfg)
+    wall = time.perf_counter() - t0
+    timing(f"7-class predict, {rows} rows x {T} trees x depth {depth}, "
+           "first call", wall=wall, **comp.split(wall))
+    assert scores.shape == (rows, C) and scores.dtype == np.float32, \
+        (scores.shape, scores.dtype)
+    assert np.isfinite(scores).all(), "non-finite scores"
+    root = root_spans("predict")[-1]
+    built = {s["name"]: s["counts"] for s in root["spans"]}[
+        "ddt:predict:ensemble"]
+    say(f"7-class predict: ddt:predict:ensemble {built}; root "
+        f"classes={root['counts']['classes']} tables_streamed_bytes="
+        f"{root['counts']['tables_streamed_bytes']}")
+    assert built["tree_group"] == 128, "the traversal kernel did not serve"
+    assert built["table_groups"] == -(-T // 128), built
+    assert built["groups_per_step"] < built["table_groups"], \
+        "the node tables did not stream"
+    assert root["counts"]["tables_streamed_bytes"] > 0
+    if device.platform() == "tpu":
+        be = get_backend(cfg)
+        fn, ens_dev = be._predict_fn(ens)
+        x_spec = jax.ShapeDtypeStruct((rows, F), np.uint8,
+                                      sharding=be._row_sharding(extra_dims=1))
+        assert "tpu_custom_call" in jax.jit(fn).lower(
+            *ens_dev, x_spec).as_text(), \
+            "7-class scoring program carries no compiled Pallas kernel"
+    n = min(2_000, rows)
+    # float64 in the reference: summed in float32 tree by tree, 500 terms
+    # a class, its own rounding is most of the gap (6.7e-6 of the 1e-5 on
+    # the v5e, against 1.6e-6 at most this way; PERF.md, PR 27).
+    want = numpy_predict.predict_raw(ens, Xb[:n], dtype=np.float64)
+    gap = float(np.abs(scores[:n] - want).max())
+    say(f"7-class scores: {n} rows x {C} classes against "
+        f"reference/numpy_predict (float64), max |diff| = {gap:.2e} "
+        "(<= 1e-5)")
+    assert gap <= 1e-5, gap
 
 
 def parity_against_reference(overrides: dict) -> None:
@@ -371,6 +441,7 @@ def main(argv=None) -> int:
 
     ens, scores, be = train_and_score(cfg, Xb, y, "one device")
     check_scores_against_numpy(ens, Xb, scores)
+    score_multiclass(overrides, MC_ROWS // 100 if args.rehearse else MC_ROWS)
     parity_against_reference(overrides)
     barrier_experiment(be, Xb)
     if count >= 4:

@@ -1585,7 +1585,7 @@ class TPUDevice(DeviceBackend):
         chunks are written as soon as they are known)."""
         R = Xb.shape[0]
         chunk = self.PREDICT_ROW_CHUNK * max(1, self.row_shards)
-        fn, ens_dev = self._predict_fn(ens, compiled=compiled)
+        fn, ens_dev, tables = self._predict_entry(ens, compiled)
         if isinstance(Xb, jax.Array) and (R <= chunk or self.distributed):
             # Device-resident input is only special-cased on the
             # single-chip big-batch loop below (where it skips the bulk
@@ -1594,6 +1594,17 @@ class TPUDevice(DeviceBackend):
             Xb = np.asarray(Xb)
         starts = range(0, R, chunk) if R > chunk else (0,)
         counts["chunks"] = len(starts)
+        counts["classes"] = tables["classes"]
+        # What the traversal kernel reads of its node tables from HBM over
+        # the call: every table block once a row tile where they stream,
+        # 0 where one block holds them all (fetched once, resident).
+        counts["tables_streamed_bytes"] = 0
+        if tables["blocks"] > 1:
+            shards = max(1, self.row_shards)
+            shard_rows = [-(-min(chunk, R - i) // shards) for i in starts]
+            tiles = sum(-(-r // tables["tile_rows"]) for r in shard_rows)
+            counts["tables_streamed_bytes"] = (
+                shards * tiles * tables["table_bytes"])
         if R <= chunk:
             counts["branch"] = "one"
             with phase_span("predict:upload", bytes=Xb.nbytes):
@@ -1662,7 +1673,8 @@ class TPUDevice(DeviceBackend):
 
     @functools.cached_property
     def _predict_cache(self) -> dict:
-        # token -> (fn, device arrays); insertion order = LRU order.
+        # token -> (fn, device arrays, table plan); insertion order = LRU
+        # order.
         return {}
 
     @functools.cached_property
@@ -1743,7 +1755,14 @@ class TPUDevice(DeviceBackend):
         return jax.jit(lut0), dev_ops
 
     def _predict_fn(self, ens: TreeEnsemble, compiled=None):
-        """(jittable scoring fn, device-resident compiled-ensemble arrays).
+        """(jittable scoring fn, device-resident compiled-ensemble arrays):
+        `_predict_entry` without the table plan."""
+        return self._predict_entry(ens, compiled)[:2]
+
+    def _predict_entry(self, ens: TreeEnsemble, compiled=None):
+        """(jittable scoring fn, device-resident compiled-ensemble arrays,
+        how the traversal kernel takes the node tables: the dict of
+        `_build_predict_fn`).
 
         The pushed-down/padded scoring layout (models/tree.
         CompiledEnsemble) and its device copies are cached per model
@@ -1781,31 +1800,38 @@ class TPUDevice(DeviceBackend):
             tele_counters.record_compiled_ensemble_hit()
             return hit
         with phase_span("predict:ensemble") as sp:
-            fn, ens_dev, resolved, tree_group = self._build_predict_fn(
+            fn, ens_dev, resolved, tables = self._build_predict_fn(
                 ens, compiled)
             sp.counts["bytes"] = sum(a.nbytes for a in ens_dev)
-            sp.counts["tree_group"] = tree_group
-        self._predict_cache[token] = (fn, ens_dev)
+            sp.counts.update({k: tables[k] for k in (
+                "tree_group", "table_groups", "groups_per_step",
+                "table_bytes")})
+        self._predict_cache[token] = hit = (fn, ens_dev, tables)
         self._predict_impl_resolved[token] = resolved
         while len(self._predict_cache) > self.PREDICT_CACHE_MAX:
             gone = next(iter(self._predict_cache))
             self._predict_cache.pop(gone)
             self._predict_impl_resolved.pop(gone, None)
-        return fn, ens_dev
+        return hit
 
     def _build_predict_fn(self, ens: TreeEnsemble, compiled):
-        """_predict_fn's cache miss: (fn, device arrays, the tier that
-        serves, the f32 traversal kernel's tree group) — layout build or
-        reuse, quantisation, the node tables' upload and the mesh wrapper.
-        The tree group is the lane width of the kernel's tree planes
-        (ops/predict_pallas.TREE_GROUP), 0 when that kernel does not serve
-        the model: the one-hot path, the LUT tiers."""
+        """_predict_entry's cache miss: (fn, device arrays, the tier that
+        serves, how the f32 traversal kernel takes the node tables) —
+        layout build or reuse, quantisation, the node tables' upload and
+        the mesh wrapper. The last is a dict: `classes`; `tree_group`,
+        the lane width of the kernel's tree planes
+        (ops/predict_pallas.TREE_GROUP); and the fields of its
+        predict_pallas.TablePlan. All but `classes` are 0 when that
+        kernel does not serve the model: the one-hot path, the LUT
+        tiers."""
         ce = compiled if compiled is not None else ens.compile(
             tree_chunk=64)
         impl_req = self.cfg.predict_impl
         lut = None
         resolved = "f32"
-        tree_group = 0
+        tables = dict(classes=ce.n_classes_out, tree_group=0,
+                      table_groups=0, groups_per_step=0, blocks=0,
+                      table_bytes=0, tile_rows=0)
         if impl_req in ("lut", "lut4"):
             if impl_req == "lut4":
                 lut = self._lut_fn(ce, ens.n_features, tier="lut4")
@@ -1834,12 +1860,16 @@ class TPUDevice(DeviceBackend):
             use_cat = ce.eff_cat is not None
             use_pallas = self._use_pallas
             if predict_ops.resolve_use_pallas(
-                    use_pallas, True, ce.n_trees_padded, ce.tree_chunk,
-                    ce.max_depth, ens.n_features, ce.n_classes_out,
-                    use_missing + use_cat):
-                from ddt_tpu.ops.predict_pallas import TREE_GROUP
+                    use_pallas, True, ce.max_depth, ens.n_features,
+                    ce.n_classes_out, use_missing + use_cat):
+                from ddt_tpu.ops import predict_pallas
 
-                tree_group = TREE_GROUP
+                tables.update(
+                    predict_pallas.table_plan(
+                        ce.n_trees_padded, ce.max_depth, ens.n_features,
+                        ce.n_classes_out, None,
+                        use_missing + use_cat)._asdict(),
+                    tree_group=predict_pallas.TREE_GROUP)
 
             def fn0(ef, et, bv, coh, *rest):
                 *opt, Xc = rest
@@ -1886,4 +1916,4 @@ class TPUDevice(DeviceBackend):
                 # here (no collectives anywhere in the traversal).
                 check_vma=False,
             ))
-        return fn, ens_dev, resolved, tree_group
+        return fn, ens_dev, resolved, tables

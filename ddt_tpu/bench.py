@@ -802,9 +802,8 @@ def bench_predict_both(
     # otherwise) — recorded so floor trips can be attributed.
     from ddt_tpu.ops.predict import resolve_use_pallas
 
-    tpad = -(-trees // 64) * 64
-    impl = ("pallas" if resolve_use_pallas(None, True, tpad, 64, depth,
-                                           features, 1, 0) else "onehot")
+    impl = ("pallas" if resolve_use_pallas(None, True, depth, features, 1,
+                                           0) else "onehot")
     base = {"kernel": "predict", "backend": "tpu", "rows": rows,
             "trees": trees, "depth": depth, "impl": impl}
     out = []

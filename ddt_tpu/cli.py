@@ -97,11 +97,15 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     """Host milliseconds of each step of the device scoring calls whose
     root span `ddt:predict` started at or after `since_ns`
     (time.perf_counter_ns): token, ensemble, upload, dispatch, fetch,
-    concat (docs/OBSERVABILITY.md has the table), and with them
-    `tree_group`, not a time: the lane width of the traversal kernel's
-    tree planes as the model's `ensemble` span recorded it (0: the
-    kernel does not serve the model). None when no such call ran: the
-    NumPy backend and raw-threshold scoring open no span."""
+    concat (docs/OBSERVABILITY.md has the table), and with them four
+    counts that are no times: `tree_group`, the lane width of the
+    traversal kernel's tree planes as the model's `ensemble` span
+    recorded it (0: the kernel does not serve the model), `table_groups`
+    and `groups_per_step` from the same span (how many tree groups the
+    model fills and how many of them a table block holds), and
+    `tables_streamed_bytes`, what the kernel re-read of its node tables
+    over the calls (0: one block, resident). None when no such call ran:
+    the NumPy backend and raw-threshold scoring open no span."""
     from ddt_tpu.telemetry.annotations import PREFIX, root_spans
 
     roots = [r for r in root_spans("predict") if r["start"] >= since_ns]
@@ -109,16 +113,17 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
         return None
     ms = dict.fromkeys(
         ("token", "ensemble", "upload", "dispatch", "fetch", "concat"), 0.0)
-    tree_group = None
+    plan = dict.fromkeys(("tree_group", "table_groups", "groups_per_step"))
     for r in roots:
         for s in r["spans"]:
             step = s["name"].removeprefix(PREFIX + "predict:")
             if step in ms:
                 ms[step] += (s["end"] - s["start"]) / 1e6
             if step == "ensemble":
-                tree_group = s["counts"]["tree_group"]
-    return {**{k: round(v, 3) for k, v in ms.items()},
-            "tree_group": tree_group}
+                plan = {k: s["counts"][k] for k in plan}
+    return {**{k: round(v, 3) for k, v in ms.items()}, **plan,
+            "tables_streamed_bytes": sum(
+                r["counts"]["tables_streamed_bytes"] for r in roots)}
 
 
 def _predict_streaming(args, bundle) -> int:

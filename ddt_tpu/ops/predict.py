@@ -33,7 +33,8 @@ Since the inference-overhaul PR the module exposes THREE related entries:
   model on host; backends keep them device-resident across calls).
 - the Pallas fast path (`ops/predict_pallas.py`) — dispatched from either
   entry via `use_pallas` (None = auto: binned data on a real TPU whose
-  shape fits the kernel's VMEM budget; the one-hot path is the fallback).
+  depth, features and classes fit the kernel's VMEM budget, at any tree
+  count; the one-hot path is the fallback).
 """
 
 from __future__ import annotations
@@ -213,16 +214,18 @@ def traverse(
     return _select_level(k, eff_slot[:, lo:])
 
 
-def resolve_use_pallas(use_pallas, binned: bool, n_trees_padded: int,
-                       tree_chunk: int, max_depth: int, n_features: int,
-                       n_classes: int, optional_operands: int = 2) -> bool:
+def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
+                       n_features: int, n_classes: int,
+                       optional_operands: int = 2) -> bool:
     """The ONE home of the pallas-vs-one-hot predict dispatch rule.
 
     None = auto: the Pallas traversal kernel is taken when the data is
     binned, a real TPU backs the computation, and the kernel's VMEM
     working set fits (predict_pallas.predict_pallas_fits, which is told
     how many of the missing and categorical tables the ensemble carries;
-    both, where the caller cannot say). Explicit True
+    both, where the caller cannot say). The tree count is no term of the
+    rule: the kernel streams the node tables by blocks of tree groups.
+    Explicit True
     demands the kernel (binned data required — raises otherwise; off-TPU
     it runs in interpret mode, the test contract); explicit False always
     takes the one-hot path."""
@@ -233,8 +236,8 @@ def resolve_use_pallas(use_pallas, binned: bool, n_trees_padded: int,
     if use_pallas is None:
         return (binned and device.platform() == "tpu"
                 and predict_pallas.predict_pallas_fits(
-                    n_trees_padded, tree_chunk, max_depth, n_features,
-                    n_classes, optional_operands=optional_operands))
+                    max_depth, n_features, n_classes,
+                    optional_operands=optional_operands))
     if not binned:
         raise ValueError(
             "use_pallas=True requires binned (integer) data; the Pallas "
@@ -268,8 +271,8 @@ def _predict_effective(
         out = jnp.full((0, C), base, jnp.float32)
         return out[:, 0] if C == 1 else out
     Tpad = eff_feat.shape[0]
-    if resolve_use_pallas(use_pallas, binned, Tpad, tree_chunk, max_depth,
-                          F, C, (eff_dl is not None) + (eff_cat is not None)):
+    if resolve_use_pallas(use_pallas, binned, max_depth, F, C,
+                          (eff_dl is not None) + (eff_cat is not None)):
         from ddt_tpu.ops import predict_pallas
 
         return predict_pallas.predict_effective_pallas(

@@ -11,25 +11,37 @@ binned input itself (R x F int32) plus the tiny tree tables and the [R, C]
 scores — the comparison matrix, feature one-hots, and descent state live and
 die inside one row tile's VMEM residency.
 
-Layout strategy (one grid step = one tile of TILE_R rows; ALL tree tables are
-pinned in VMEM for the whole kernel via constant index maps — a 1000-tree
-depth-6 ensemble is ~1.3 MB). Trees are taken in GROUPS of TREE_GROUP = 128,
-one vreg's lanes and one MXU weight tile, whatever tree_chunk the compiled
-ensemble was laid out with (the padded tree count is padded again, inside the
-jitted program, to a multiple of 128 with trees that score 0):
+Layout strategy. The grid is (row tiles, table blocks): one step scores one
+tile of TILE_R rows against one BLOCK of G tree groups. Trees are taken in
+GROUPS of TREE_GROUP = 128, one vreg's lanes and one MXU weight tile,
+whatever tree_chunk the compiled ensemble was laid out with. The tables
+STREAM from HBM a block at a time (Mosaic double-buffers the windows, so the
+next block's DMA runs under this block's matmuls); the [TILE_R, C] output
+block and the row tile stay resident over the block axis, the output written
+by the first block and added to by the others. G is not a knob
+(`table_plan`): the most groups whose double-buffered windows fit
+_VMEM_BUDGET_BYTES beside the working set, evened out over the blocks, and
+ALL of them where the whole ensemble fits: a 1000-tree depth-6 ensemble
+(8 groups, 1.3 MB) is one block, its grid (tiles, 1), its tables fetched
+once and resident as before. 500 rounds x 7 classes at depth 8 are 28
+groups in 4 blocks of 7. The kernel's trace is G groups long whatever the
+tree count. The padded tree count is padded again, inside the jitted
+program, to a multiple of G x 128 with trees that score 0:
 
     X     [TILE_R, F]        int32 bins, cast bf16 in-VMEM (the matmul
                              operand; its f32 result is what compares).
-    feat  [n_tg*Nint, 128]   ONE PLANE PER ROW: row g*Nint + n holds node n
-    thr/dl/cat               of the 128 trees of group g. A node's table
-                             entries are a row load at lane offset 0 and a
-                             sublane broadcast (the only form of broadcast
-                             Mosaic gives a layout on both sides of a
-                             select), no lane slices, no gathers anywhere.
-    val   [n_tg*W, 128]      bottom-level pushed-down leaf values, same.
-    coh   [n_tg*128, C]      round-major class one-hot.
+    feat  [nb, G*Nint, 128]  ONE PLANE PER ROW: row g*Nint + n of block b
+    thr/dl/cat               holds node n of the 128 trees of the block's
+                             group g. A node's table entries are a row load
+                             at lane offset 0 and a sublane broadcast (the
+                             only form of broadcast Mosaic gives a layout
+                             on both sides of a select), no lane slices,
+                             no gathers anywhere.
+    val   [nb, G*W, 128]     bottom-level pushed-down leaf values, same.
+    coh   [nb, G*128, C]     round-major class one-hot.
 
-Per tree group (static Python loop, traced once), per node n (depth-first):
+Per tree group of the block (static Python loop, traced once), per node n
+(depth-first):
     foh [F, 128] bf16 one-hot built on the VPU by SUBLANE-broadcasting the
         node's feature row against a sublane iota (the hist_pallas
         transposed-kernel trick), then one MXU weight tile:
@@ -62,6 +74,7 @@ as hist_pallas.py; dispatch lives in ops/predict.resolve_use_pallas (the
 from __future__ import annotations
 
 import functools
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -72,10 +85,10 @@ from ddt_tpu.telemetry.annotations import op_scope, traced_scope
 from ddt_tpu.telemetry.costmodel import costed
 from ddt_tpu.utils import device
 
-# VMEM ceiling for auto-dispatch: the kernel's working set + the resident
-# tree tables + Mosaic's double-buffered operand windows must fit the
+# VMEM ceiling: the kernel's working set + one block of tree tables and
+# the row tile in Mosaic's double-buffered operand windows must fit the
 # 16 MiB scoped-VMEM limit; 12 MB leaves the same headroom hist_pallas
-# budgets.
+# budgets. It sets how many tree groups a table block holds (table_plan).
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _DEFAULT_TILE_R = 256
 # Trees per group: the lanes of one vreg and the columns of one MXU weight
@@ -113,9 +126,71 @@ def _window_bytes(rows: int, cols: int) -> int:
     return 2 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128) * 4
 
 
-def predict_pallas_fits(
+def _vmem_bytes(groups: int, max_depth: int, n_features: int,
+                n_classes: int, tile_r: int, optional_operands: int) -> int:
+    """VMEM a grid step takes with `groups` tree groups a table block: the
+    table windows (feat i32, thr f32, dl and cat i32 where present, bottom
+    values, class one-hot: one plane a row), the row tile's windows and
+    the working set."""
+    n_int = (1 << max_depth) - 1
+    tables = ((2 + optional_operands) * _window_bytes(groups * n_int,
+                                                      TREE_GROUP)
+              + _window_bytes(groups * (n_int + 1), TREE_GROUP)
+              + _window_bytes(groups * TREE_GROUP, n_classes))
+    work = tile_r * (_ROW_BYTES + (n_int * _ROW_NODE_BYTES_BOTH
+                                   if optional_operands == 2 else 0))
+    rows = _window_bytes(tile_r, n_features) + _window_bytes(tile_r,
+                                                             n_classes)
+    return tables + work + rows
+
+
+class TablePlan(typing.NamedTuple):
+    """How an ensemble's node tables meet the kernel (`table_plan`)."""
+
+    table_groups: int      # TREE_GROUPs that hold trees (n_tg)
+    groups_per_step: int   # G: groups a table block; 0 = nothing fits
+    blocks: int            # table blocks a row tile walks; 1 = resident
+    table_bytes: int       # HBM bytes of all the blocks' tables, read once
+    tile_rows: int         # rows a tile
+
+
+def table_plan(
     n_trees_padded: int,
-    tree_chunk: int,
+    max_depth: int,
+    n_features: int,
+    n_classes: int,
+    tile_r: int | None = None,
+    optional_operands: int = 2,
+) -> TablePlan:
+    """The kernel's table blocks at this shape: G, the number of tree
+    groups a grid step holds in VMEM, is the most whose double-buffered
+    windows fit _VMEM_BUDGET_BYTES beside the row tile's windows and the
+    working set, evened out over the blocks that takes (28 groups of
+    which 9 fit are 4 blocks of 7, not 3 of 9 and one of 1 padded to 9),
+    and every group where the whole ensemble fits. G = 0: not even one
+    group fits (depth, features, classes and the optional operands decide
+    that; the tree count never does). `optional_operands` counts the
+    missing and categorical tables the ensemble carries (both, where the
+    caller cannot say)."""
+    if tile_r is None:
+        tile_r = _DEFAULT_TILE_R
+    n_tg = -(-n_trees_padded // TREE_GROUP)
+    most = 0
+    while most < n_tg and _vmem_bytes(
+            most + 1, max_depth, n_features, n_classes, tile_r,
+            optional_operands) <= _VMEM_BUDGET_BYTES:
+        most += 1
+    if most == 0:
+        return TablePlan(n_tg, 0, 0, 0, tile_r)
+    blocks = -(-n_tg // most)
+    g = -(-n_tg // blocks)
+    per_group = 4 * TREE_GROUP * ((2 + optional_operands)
+                                  * ((1 << max_depth) - 1)
+                                  + (1 << max_depth) + n_classes)
+    return TablePlan(n_tg, g, blocks, blocks * g * per_group, tile_r)
+
+
+def predict_pallas_fits(
     max_depth: int,
     n_features: int,
     n_classes: int,
@@ -124,42 +199,24 @@ def predict_pallas_fits(
 ) -> bool:
     """Whether the traversal kernel's VMEM working set fits at this shape —
     the guard behind use_pallas=None auto-dispatch
-    (ops/predict.resolve_use_pallas). `tree_chunk` is the compiled
-    ensemble's (the padded count must be a multiple of it, as on the
-    one-hot path); the kernel itself regroups the trees in TREE_GROUPs.
-    `optional_operands` counts the missing and categorical tables the
-    ensemble carries (both, where the caller cannot say)."""
-    if tile_r is None:
-        tile_r = _DEFAULT_TILE_R
-    if n_trees_padded % tree_chunk != 0:
-        return False
-    n_int = (1 << max_depth) - 1
-    n_leaves = 1 << max_depth
-    n_tg = -(-n_trees_padded // TREE_GROUP)
-    # Resident tables, every one a whole-array window of one plane a row:
-    # feat i32, thr f32, dl and cat i32 where present, bottom values, class
-    # one-hot. They bound the trace too: 12 MB of them are at most 37
-    # groups at depth 6, some 2,300 node planes.
-    trees = (2 + optional_operands) * _window_bytes(n_tg * n_int,
-                                                    TREE_GROUP)
-    trees += _window_bytes(n_tg * n_leaves, TREE_GROUP)
-    trees += _window_bytes(n_tg * TREE_GROUP, n_classes)
-    work = tile_r * (_ROW_BYTES + (n_int * _ROW_NODE_BYTES_BOTH
-                                   if optional_operands == 2 else 0))
-    rows = _window_bytes(tile_r, n_features) + _window_bytes(tile_r,
-                                                             n_classes)
-    return trees + work + rows <= _VMEM_BUDGET_BYTES
+    (ops/predict.resolve_use_pallas): the row tile, the working set and
+    ONE tree group's table windows. The tree count is no term of it: the
+    tables stream by blocks of as many groups as fit (`table_plan`)."""
+    return table_plan(TREE_GROUP, max_depth, n_features, n_classes, tile_r,
+                      optional_operands).groups_per_step > 0
 
 
 def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
-                     n_tg: int, n_int: int, n_leaves: int, n_feat: int,
-                     missing_bin_value: int, use_missing: bool,
-                     use_cat: bool):
-    """One row tile: margins for every class, all trees, fully in VMEM.
+                     n_groups: int, n_blocks: int, n_int: int,
+                     n_leaves: int, n_feat: int, missing_bin_value: int,
+                     use_missing: bool, use_cat: bool):
+    """One row tile against one block of tree groups: that block's share
+    of every class's margin, fully in VMEM.
 
-    x_ref [TILE_R, F] int32; feat/thr (+ optional dl, cat) [n_tg*Nint, 128]
-    and val [n_tg*W, 128], one plane a row; coh [n_tg*128, C]; out
-    [TILE_R, C] f32."""
+    x_ref [TILE_R, F] int32; feat/thr (+ optional dl, cat) [G*Nint, 128]
+    and val [G*W, 128], one plane a row; coh [G*128, C]; out [TILE_R, C]
+    f32, resident over the block axis (grid axis 1): written by the first
+    block, added to by the others."""
     rest = list(rest)
     out_ref = rest.pop()
     dl_ref = rest.pop(0) if use_missing else None
@@ -220,7 +277,7 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
                          leaf(g, 2 * n + 2), leaf(g, 2 * n + 1))
 
     acc = jnp.zeros((tile_r, out_ref.shape[1]), jnp.float32)
-    for g in range(n_tg):
+    for g in range(n_groups):
         # Class scatter — the one-hot path's dot and precision, one add a
         # group.
         acc = acc + jax.lax.dot_general(
@@ -229,7 +286,18 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
         )
-    out_ref[:] = acc
+    if n_blocks == 1:
+        out_ref[:] = acc
+        return
+    block = pl.program_id(1)
+
+    @pl.when(block == 0)
+    def _():
+        out_ref[:] = acc
+
+    @pl.when(block > 0)
+    def _():
+        out_ref[:] += acc
 
 
 def predict_effective_pallas(
@@ -277,40 +345,44 @@ def predict_effective_pallas(
     use_missing = eff_dl is not None
     use_cat = eff_cat is not None
     if not interpret and not predict_pallas_fits(
-            Tpad, tree_chunk, max_depth, F, C, tile_r,
-            use_missing + use_cat):
+            max_depth, F, C, tile_r, use_missing + use_cat):
         # Compiled dispatch past the budget means a VMEM OOM or a
         # pathological Mosaic trace on the chip — fail at the cause. The
         # auto path (ops/predict.resolve_use_pallas) never gets here;
         # this guards a forced predict_impl='pallas' at a monster shape.
         # Interpret mode (CPU tests) has no VMEM to protect.
         raise ValueError(
-            f"predict shape (trees_padded={Tpad}, tree_chunk={tree_chunk}, "
-            f"depth={max_depth}, F={F}, C={C}) exceeds the Pallas "
-            "VMEM budget; use the one-hot path")
+            f"predict shape (depth={max_depth}, F={F}, C={C}, "
+            f"{use_missing + use_cat} optional operands) exceeds the "
+            "Pallas VMEM budget; use the one-hot path")
     tg = TREE_GROUP
-    n_tg = -(-Tpad // tg)
+    plan = table_plan(Tpad, max_depth, F, C, tile_r, use_missing + use_cat)
+    # Interpreted past the budget: one block of every group.
+    n_g = plan.groups_per_step or plan.table_groups
+    n_blocks = plan.blocks or 1
     n_int = (1 << max_depth) - 1
     n_leaves = 1 << max_depth
+    t_fill = n_blocks * n_g * tg - Tpad
 
     def by_plane(a, dtype, fill=0):
-        """[Tpad, width] -> [n_tg*width, 128]: row g*width + n holds
-        column n of the 128 trees of group g. The trees that fill the
-        last group score 0 (feature -1 matches no one-hot row, value 0,
+        """[Tpad, width] -> [n_blocks, G*width, 128]: row g*width + n of
+        block b holds column n of the 128 trees of the block's group g.
+        The trees that fill the last group, and the groups that fill the
+        last block, score 0 (feature -1 matches no one-hot row, value 0,
         a zero class row). Tiny arrays; the transpose is noise next to
         the row volume."""
-        a = jnp.pad(a.astype(dtype), ((0, n_tg * tg - Tpad), (0, 0)),
+        a = jnp.pad(a.astype(dtype), ((0, t_fill), (0, 0)),
                     constant_values=fill)
         width = a.shape[1]
-        return (a.reshape(n_tg, tg, width)
-                .transpose(0, 2, 1)
-                .reshape(n_tg * width, tg))
+        return (a.reshape(n_blocks, n_g, tg, width)
+                .transpose(0, 1, 3, 2)
+                .reshape(n_blocks, n_g * width, tg))
 
     feat_pl = by_plane(eff_feat[:, :n_int], jnp.int32, fill=-1)
     thr_pl = by_plane(eff_thr[:, :n_int], jnp.float32)
     val_pl = by_plane(bot_val, jnp.float32)
     coh = jnp.pad(cls_oh.astype(jnp.float32),
-                  ((0, n_tg * tg - Tpad), (0, 0)))
+                  ((0, t_fill), (0, 0))).reshape(n_blocks, n_g * tg, C)
     extras = []
     if use_missing:
         extras.append(by_plane(eff_dl[:, :n_int], jnp.int32))
@@ -328,38 +400,44 @@ def predict_effective_pallas(
         Xi = jnp.pad(Xi, ((0, rpad), (0, 0)))
 
     kernel = functools.partial(
-        _traverse_kernel, n_tg=n_tg, n_int=n_int, n_leaves=n_leaves,
-        n_feat=F, missing_bin_value=missing_bin_value,
+        _traverse_kernel, n_groups=n_g, n_blocks=n_blocks, n_int=n_int,
+        n_leaves=n_leaves, n_feat=F, missing_bin_value=missing_bin_value,
         use_missing=use_missing, use_cat=use_cat,
     )
 
-    def pinned(rows, cols):
-        return pl.BlockSpec((rows, cols), lambda i: (0, 0),
+    def rows_of_tile(cols):
+        """Resident over the block axis: fetched (written back) once a
+        tile."""
+        return pl.BlockSpec((tile_r, cols), lambda i, b: (i, 0),
                             memory_space=pltpu.VMEM)
 
-    nodes = pinned(n_tg * n_int, tg)
+    def table_block(rows, cols):
+        """Block b of a table. One block: the index never moves, and the
+        table is fetched once and stays, as a pinned window would."""
+        return pl.BlockSpec((None, rows, cols), lambda i, b: (b, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    nodes = table_block(n_g * n_int, tg)
     in_specs = [
-        pl.BlockSpec((tile_r, F), lambda i: (i, 0),
-                     memory_space=pltpu.VMEM),
+        rows_of_tile(F),
         nodes,                                            # feat
         nodes,                                            # thr
-        pinned(n_tg * n_leaves, tg),                      # val
-        pinned(n_tg * tg, C),                             # coh
+        table_block(n_g * n_leaves, tg),                  # val
+        table_block(n_g * tg, C),                         # coh
     ] + [nodes] * len(extras)
     cost = pl.CostEstimate(
-        flops=2 * n_tiles * tile_r * n_tg * tg * (F * n_int + C),
+        flops=2 * n_tiles * tile_r * n_blocks * n_g * tg * (F * n_int + C),
         bytes_accessed=n_tiles * tile_r * (F + C) * 4
-        + n_tg * tg * (n_int * 8 + n_leaves * 4 + C * 4),
+        + plan.table_bytes * (n_tiles if n_blocks > 1 else 1),
         transcendentals=0,
     )
     with traced_scope("predict"):
         with traced_scope("predict:traverse"):
             acc = pl.pallas_call(
                 kernel,
-                grid=(n_tiles,),
+                grid=(n_tiles, n_blocks),
                 in_specs=in_specs,
-                out_specs=pl.BlockSpec((tile_r, C), lambda i: (i, 0),
-                                       memory_space=pltpu.VMEM),
+                out_specs=rows_of_tile(C),
                 out_shape=jax.ShapeDtypeStruct((n_tiles * tile_r, C),
                                                jnp.float32),
                 cost_estimate=cost,

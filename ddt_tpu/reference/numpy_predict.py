@@ -1,0 +1,72 @@
+"""The plain scoring reference: an ensemble's raw scores in straightforward
+NumPy — no kernels, no chunking, no leaf pushdown, no padding.
+
+The semantics, on BINNED rows (uint8/int bins, as `api.predict(...,
+binned=True)` takes them): a tree is a heap of 2^(depth+1)-1 nodes; a row at
+node n goes LEFT, to 2n+1, when `bin[feature[n]] <= threshold_bin[n]`, else
+RIGHT, to 2n+2, until `is_leaf[n]`; the tree then scores `leaf_value[n]`.
+The class of tree t is `t % n_classes` (round-major: softmax boosts one tree a
+class a round); raw score [rows, classes] = base_score + learning_rate x the
+sum of the class's leaf values ([rows] for the one-output losses). Two
+optional routings, as the trainer writes them: on a categorical feature
+(`cat_features`) the node is one-vs-rest, `bin == threshold_bin` goes left
+and every other bin right; with a reserved missing bin (`missing_bin`, the
+top bin `n_bins - 1`) a row whose bin is the reserved one follows the node's
+learned `default_left`.
+
+Where this departs from the contract of `ops/predict.py` (and its Pallas twin
+`ops/predict_pallas.py`), all of it arithmetic and none of it routing:
+
+- It STOPS at a leaf. The device paths push every leaf's value down to the
+  bottom level and walk all `max_depth` levels (always-left below a leaf);
+  the leaf reached is the same.
+- It adds `learning_rate * leaf_value` tree by tree, in tree order, in
+  `dtype` (float32 by default; float64 for tolerance studies). The device
+  paths sum a block of trees' values in one dot (64 trees on the one-hot
+  path, 128 on the kernel, in an order that belongs to the compiler), add
+  the blocks, and multiply by the learning rate once at the end. So the two
+  agree to float32 rounding of a sum of `n_trees / n_classes` terms, not
+  bitwise.
+- No tree is padded, no row is chunked, and raw-threshold (float feature)
+  scoring is not covered: bins only.
+
+`tests/test_reference_predict.py` holds `api.predict` to it.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+
+def leaf_of_rows(ens, t: int, Xb: np.ndarray) -> np.ndarray:
+    """Heap index of the leaf each row of `Xb` ends in, in tree `t`."""
+    rows = np.arange(Xb.shape[0])
+    node = np.zeros(Xb.shape[0], np.int64)
+    feature, thr, is_leaf = (ens.feature[t], ens.threshold_bin[t],
+                             ens.is_leaf[t])
+    cat = ens.cat_features if ens.cat_features is not None else ()
+    use_missing = bool(ens.missing_bin) and ens.default_left is not None
+    for _ in range(ens.max_depth):
+        f = np.maximum(feature[node], 0)         # a leaf's feature is -1
+        b = Xb[rows, f].astype(np.int64)
+        right = b > thr[node]
+        if len(cat):
+            right = np.where(np.isin(f, cat), b != thr[node], right)
+        if use_missing:
+            right = np.where(b == ens.n_bins - 1,
+                             ~ens.default_left[t][node], right)
+        node = np.where(is_leaf[node], node, 2 * node + 1 + right)
+    return node
+
+
+def predict_raw(ens, Xb: np.ndarray, dtype=np.float32) -> np.ndarray:
+    """Raw scores of `ens` (a models/tree.TreeEnsemble) over binned rows:
+    [rows, n_classes] for softmax, [rows] otherwise, accumulated in `dtype`
+    in tree order."""
+    n_out = ens.n_classes if ens.loss == "softmax" else 1
+    out = np.full((Xb.shape[0], n_out), ens.base_score, dtype)
+    lr = dtype(ens.learning_rate)
+    for t in range(ens.feature.shape[0]):
+        leaf = leaf_of_rows(ens, t, Xb)
+        out[:, t % n_out] += lr * ens.leaf_value[t].astype(dtype)[leaf]
+    return out if n_out > 1 else out[:, 0]

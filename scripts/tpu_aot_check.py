@@ -314,19 +314,6 @@ def program_cases(topo_devices) -> list:
             return fn, args, ["tpu_custom_call"]
         return build
 
-    def scoring_onehot():
-        # Covertype's own models, 500 rounds x 7 classes at depth 8, are
-        # past predict_pallas_fits (their tables alone are 29 MB): the
-        # auto dispatch takes the XLA one-hot path.
-        from ddt_tpu.ops.predict import resolve_use_pallas
-
-        n_trees = 500 * cc
-        assert not resolve_use_pallas(None, True, -(-n_trees // 64) * 64,
-                                      64, 8, cf, cc, 0)
-        fn, args = _scoring_program(topo_devices, rows=cr, features=cf,
-                                    n_trees=n_trees, depth=8, n_classes=cc)
-        return fn, args, []
-
     return [
         ("rounds/higgs/1dev", rounds(None)),
         ("rounds/higgs/rows=4", rounds((4, 1))),
@@ -341,7 +328,10 @@ def program_cases(topo_devices) -> list:
         # Covertype model is served by it.
         ("scoring/covertype/70x8", scoring(
             10 * cc, rows=cr, features=cf, depth=8, n_classes=cc)),
-        ("scoring/covertype/3500x8/onehot-path", scoring_onehot),
+        # Covertype's own models, 500 rounds x 7 classes at depth 8: 28
+        # tree groups whose tables (11 MB) stream in 4 blocks of 7.
+        ("scoring/covertype/3500x8", scoring(
+            500 * cc, rows=cr, features=cf, depth=8, n_classes=cc)),
     ]
 
 

@@ -211,35 +211,90 @@ def test_mux_tree_matches_onehot(depth, T, n_classes, missing, cat):
         check(want, got)
 
 
-def test_pallas_fits_guard():
-    from ddt_tpu.ops.predict_pallas import predict_pallas_fits
-
-    assert predict_pallas_fits(1024, 64, 6, 28, 1)       # the bench shape
-    assert not predict_pallas_fits(1000, 64, 6, 28, 1)   # not a multiple
-    # monster shape blows the VMEM budget
-    assert not predict_pallas_fits(1 << 20, 64, 10, 512, 1)
-    # The kernel regroups the trees in 128s itself: a padded count that is
-    # no multiple of 128 is admitted like its next multiple.
-    for tpad in (64, 192, 1088):
-        assert predict_pallas_fits(tpad, 64, 6, 28, 1)
-    # No [tile, Nint*lanes] array exists any more, so depth costs tables
-    # only, and an ensemble without the missing and categorical tables is
-    # not charged for them: the edges, as AOT-compiled under the real
-    # limit (PERF.md section 6, PR 26).
-    assert predict_pallas_fits(1024, 64, 7, 28, 1, optional_operands=0)
-    assert not predict_pallas_fits(1024, 64, 7, 28, 1)
-    assert predict_pallas_fits(1152, 64, 8, 54, 7, optional_operands=0)
-    assert not predict_pallas_fits(1280, 64, 8, 54, 7, optional_operands=0)
-    assert predict_pallas_fits(2816, 64, 6, 54, 7, optional_operands=1)
-    assert not predict_pallas_fits(2944, 64, 6, 54, 7, optional_operands=1)
-    assert predict_pallas_fits(1536, 64, 6, 54, 7)
-    assert not predict_pallas_fits(1664, 64, 6, 54, 7)
-    assert predict_pallas_fits(384, 64, 7, 54, 7)
-    assert not predict_pallas_fits(128, 64, 8, 28, 1)
+@pytest.mark.parametrize("depth,F,C,optional,tile_r,fits", [
+    (6, 28, 1, 2, None, True),        # the bench shape
+    (10, 512, 1, 2, None, False),     # monster shape blows the VMEM budget
+    # No [tile, Nint*lanes] array exists, so depth costs tables only, and
+    # an ensemble without the missing and categorical tables is not
+    # charged for them.
+    (7, 28, 1, 0, None, True),
+    (8, 54, 7, 0, None, True),        # Covertype's own shape
+    (6, 54, 7, 1, None, True),
+    (6, 54, 7, 2, None, True),
+    (7, 54, 7, 2, None, True),
+    # With BOTH routing tables the compiler keeps 192 B a row and node:
+    # at depth 7 there is room left for three groups' tables (the guard
+    # refused 1024 such trees while every table had to be resident), at
+    # depth 8 the working set alone is past the budget.
+    (7, 28, 1, 2, None, True),
+    (8, 28, 1, 2, None, False),
     # a larger tile is charged by the row
-    assert predict_pallas_fits(1024, 64, 6, 28, 1, tile_r=512,
-                               optional_operands=0)
-    assert not predict_pallas_fits(1024, 64, 6, 28, 1, tile_r=512)
+    (6, 28, 1, 0, 512, True),
+    (6, 28, 1, 2, 512, False),
+])
+def test_pallas_fits_guard(depth, F, C, optional, tile_r, fits):
+    """Depth, features, classes, the optional operands and the tile decide
+    whether the kernel serves a model; the tree count is no term of the
+    rule (the node tables stream by blocks of tree groups)."""
+    from ddt_tpu.ops.predict_pallas import predict_pallas_fits, table_plan
+
+    assert predict_pallas_fits(depth, F, C, tile_r, optional) is fits
+    for tpad in (64, 1024, 1 << 20):
+        assert (table_plan(tpad, depth, F, C, tile_r,
+                           optional).groups_per_step > 0) is fits
+
+
+@pytest.mark.parametrize("tpad,depth,F,C,optional,groups,g,blocks", [
+    # The kernel regroups the trees in 128s itself: a padded count that is
+    # no multiple of 128 is planned like its next multiple.
+    (64, 6, 28, 1, 2, 1, 1, 1),
+    (192, 6, 28, 1, 2, 2, 2, 1),
+    (1024, 6, 28, 1, 2, 8, 8, 1),     # the bench shape: one block, resident
+    (1088, 6, 28, 1, 2, 9, 9, 1),
+    # The edges at which the resident-tables guard turned a model away
+    # (AOT-compiled under the real limit, PERF.md section 6, PR 26) are
+    # now where one block becomes two, evened out.
+    (1152, 8, 54, 7, 0, 9, 9, 1),
+    (1280, 8, 54, 7, 0, 10, 5, 2),
+    (2816, 6, 54, 7, 1, 22, 22, 1),
+    (2944, 6, 54, 7, 1, 23, 12, 2),
+    (1536, 6, 54, 7, 2, 12, 12, 1),
+    (1664, 6, 54, 7, 2, 13, 7, 2),
+    (384, 7, 54, 7, 2, 3, 3, 1),
+    (1024, 7, 28, 1, 0, 8, 8, 1),
+    (1024, 7, 28, 1, 2, 8, 3, 3),
+    # Covertype's own model, 500 rounds x 7 classes: 9 groups fit, so 4
+    # blocks, of 7 (not 3 of 9 and one of 1 filled to 9)
+    (3520, 8, 54, 7, 0, 28, 7, 4),
+    # the trace is bounded whatever the tree count
+    (1 << 20, 6, 28, 1, 0, 8192, 27, 304),
+    (128, 8, 28, 1, 2, 1, 0, 0),      # nothing fits
+])
+def test_table_plan(tpad, depth, F, C, optional, groups, g, blocks):
+    """How many tree groups a table block holds (G) and how many blocks a
+    row tile walks: the budget's arithmetic, pinned."""
+    plan = jpp.table_plan(tpad, depth, F, C, None, optional)
+    assert plan[:3] == (groups, g, blocks)
+    assert g * blocks >= groups * bool(g)
+    per_group = 4 * 128 * ((2 + optional) * (2 ** depth - 1) + 2 ** depth
+                           + C)
+    assert plan.table_bytes == blocks * g * per_group
+    assert plan.tile_rows == 256
+    if g:
+        assert jpp._vmem_bytes(g, depth, F, C, 256, optional) \
+            <= jpp._VMEM_BUDGET_BYTES
+
+
+def test_padded_tree_count_must_be_a_multiple_of_the_chunk():
+    """What the guard used to answer for (`not fits(1000, 64, ...)`): the
+    kernel entry itself refuses a tree count the compiled layout cannot
+    have."""
+    z = jnp.zeros((1000, 127), jnp.int32)
+    with pytest.raises(ValueError, match="not a multiple"):
+        jpp.predict_effective_pallas(
+            z, z, jnp.zeros((1000, 64)), jnp.ones((1000, 1)),
+            jnp.zeros((8, 28), jnp.int32), max_depth=6, learning_rate=0.1,
+            base=0.0, tree_chunk=64)
 
 
 @pytest.mark.parametrize("impl,T,want", [
@@ -259,8 +314,18 @@ def test_ensemble_span_says_which_form_served(impl, T, want):
     be = get_backend(TrainConfig(backend="tpu", n_bins=31,
                                  predict_impl=impl))
     be.predict_raw(ens, Xb)
-    spans = {s["name"]: s for s in an.root_spans("predict")[-1]["spans"]}
-    assert spans["ddt:predict:ensemble"]["counts"]["tree_group"] == want
+    root = an.root_spans("predict")[-1]
+    counts = {s["name"]: s for s in root["spans"]}[
+        "ddt:predict:ensemble"]["counts"]
+    assert counts["tree_group"] == want
+    # The table plan rides on the same span: these small models are one
+    # resident block of all their groups, and nothing streams.
+    groups = -(-T // 128) if want else 0
+    assert (counts["table_groups"], counts["groups_per_step"]) \
+        == (groups, groups)
+    assert counts["table_bytes"] == groups * 4 * 128 * (2 * 7 + 8 + 1)
+    assert root["counts"]["classes"] == 1
+    assert root["counts"]["tables_streamed_bytes"] == 0
 
 
 # --------------------------------------------------------------------- #
