@@ -239,6 +239,8 @@ def score_multiclass(overrides: dict, rows: int) -> None:
         f"{root['counts']['tables_streamed_bytes']}")
     assert built["tree_group"] == 128, "the traversal kernel did not serve"
     assert built["table_groups"] == -(-T // 128), built
+    assert (built["nodes_per_tile"], built["mxu_tiles_per_group"]) == (
+        2, 2 ** (depth - 1)), "two nodes do not share a weight tile"
     assert built["groups_per_step"] < built["table_groups"], \
         "the node tables did not stream"
     assert root["counts"]["tables_streamed_bytes"] > 0

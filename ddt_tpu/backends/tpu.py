@@ -1805,7 +1805,7 @@ class TPUDevice(DeviceBackend):
             sp.counts["bytes"] = sum(a.nbytes for a in ens_dev)
             sp.counts.update({k: tables[k] for k in (
                 "tree_group", "table_groups", "groups_per_step",
-                "table_bytes")})
+                "table_bytes", "nodes_per_tile", "mxu_tiles_per_group")})
         self._predict_cache[token] = hit = (fn, ens_dev, tables)
         self._predict_impl_resolved[token] = resolved
         while len(self._predict_cache) > self.PREDICT_CACHE_MAX:
@@ -1831,7 +1831,8 @@ class TPUDevice(DeviceBackend):
         resolved = "f32"
         tables = dict(classes=ce.n_classes_out, tree_group=0,
                       table_groups=0, groups_per_step=0, blocks=0,
-                      table_bytes=0, tile_rows=0)
+                      table_bytes=0, tile_rows=0, nodes_per_tile=0,
+                      mxu_tiles_per_group=0)
         if impl_req in ("lut", "lut4"):
             if impl_req == "lut4":
                 lut = self._lut_fn(ce, ens.n_features, tier="lut4")

@@ -97,12 +97,14 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     """Host milliseconds of each step of the device scoring calls whose
     root span `ddt:predict` started at or after `since_ns`
     (time.perf_counter_ns): token, ensemble, upload, dispatch, fetch,
-    concat (docs/OBSERVABILITY.md has the table), and with them four
+    concat (docs/OBSERVABILITY.md has the table), and with them six
     counts that are no times: `tree_group`, the lane width of the
     traversal kernel's tree planes as the model's `ensemble` span
     recorded it (0: the kernel does not serve the model), `table_groups`
     and `groups_per_step` from the same span (how many tree groups the
-    model fills and how many of them a table block holds), and
+    model fills and how many of them a table block holds),
+    `nodes_per_tile` and `mxu_tiles_per_group` (how many nodes share one
+    MXU weight tile, and the tiles a tree group costs a row tile), and
     `tables_streamed_bytes`, what the kernel re-read of its node tables
     over the calls (0: one block, resident). None when no such call ran:
     the NumPy backend and raw-threshold scoring open no span."""
@@ -113,7 +115,8 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
         return None
     ms = dict.fromkeys(
         ("token", "ensemble", "upload", "dispatch", "fetch", "concat"), 0.0)
-    plan = dict.fromkeys(("tree_group", "table_groups", "groups_per_step"))
+    plan = dict.fromkeys(("tree_group", "table_groups", "groups_per_step",
+                          "nodes_per_tile", "mxu_tiles_per_group"))
     for r in roots:
         for s in r["spans"]:
             step = s["name"].removeprefix(PREFIX + "predict:")

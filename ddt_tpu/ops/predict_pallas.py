@@ -28,34 +28,75 @@ groups in 4 blocks of 7. The kernel's trace is G groups long whatever the
 tree count. The padded tree count is padded again, inside the jitted
 program, to a multiple of G x 128 with trees that score 0:
 
-    X     [TILE_R, F]        int32 bins, cast bf16 in-VMEM (the matmul
-                             operand; its f32 result is what compares).
+    X     [TILE_R, F]        int32 bins. In-VMEM the matmul's left operand,
+                             bf16 [TILE_R, K]: the row tile as it is
+                             (P = 1, K = F), or two copies of it side by
+                             side, the second times 256 (P = 2; below).
     feat  [nb, G*Nint, 128]  ONE PLANE PER ROW: row g*Nint + n of block b
     thr/dl/cat               holds node n of the 128 trees of the block's
                              group g. A node's table entries are a row load
                              at lane offset 0 and a sublane broadcast (the
                              only form of broadcast Mosaic gives a layout
                              on both sides of a select), no lane slices,
-                             no gathers anywhere.
+                             no gathers anywhere. thr is f32 bins at P = 1
+                             and at P = 2 int32, prepared for the compare
+                             on the packed word (predict_effective_pallas).
     val   [nb, G*W, 128]     bottom-level pushed-down leaf values, same.
     coh   [nb, G*128, C]     round-major class one-hot.
 
-Per tree group of the block (static Python loop, traced once), per node n
-(depth-first):
-    foh [F, 128] bf16 one-hot built on the VPU by SUBLANE-broadcasting the
-        node's feature row against a sublane iota (the hist_pallas
-        transposed-kernel trick), then one MXU weight tile:
-        colval = X @ foh — the exact bin value of node n's feature at every
-        (row, tree) of the group.
-    goes_right = colval > thr, a predicate used as it is (with the
-        categorical one-vs-rest and reserved-NaN-bin operands the integer
-        routing of ops/predict._descend_comp, term for term, then != 0).
+P nodes share one MXU weight tile (`nodes_per_tile`: from F and whether the
+ensemble carries a routing table, nothing else). A node's matmul contracts
+over K = F of the weight tile's 128 rows, and the kernel is bound by the
+NUMBER of MXU results it asks for, so the idle rows carry a second node of
+the same 128 trees. The left operand is [x | 256 x] (each copy starting at
+a multiple of 8 rows), the weight tile the two nodes' feature one-hots
+stacked along K, and one matmul returns
+
+    word[row, tree] = colval_a + 256 * colval_b
+
+Bins are integers 0..255 and the powers of two are exact in bfloat16, every
+product is exact in f32, and every partial sum is a sum of distinct bytes
+below 2^16, so the word is the exact integer in any summation order; the
+same feature in both nodes is no special case, each copy of x having its
+own K rows. To read the bytes the VPU wants the integer, and an f32 -> i32
+convert costs it two to three operations a vreg: instead a column of ones
+in x times _MANTISSA (1.5 x 2^23) in the weight tile (or, where the tile
+has no 8 rows to spare, F = 57..64, one VPU add) puts the word in the low
+bits of the f32's mantissa, and the bitcast is the conversion. The high
+byte then compares as the whole word against (thr + 1) << 8 plus the
+constant high bits, one operation as before; the low byte behind an `& 255`.
+Every goes_right bit, every leaf and every score is the unpacked form's:
+tests/test_predict_pallas.py demands the bits. The pairs are the mux
+tree's SIBLINGS (2n+1 low, 2n+2 high), computed when their parent is
+visited, and the root alone: 2^(depth-1) weight tiles a group where one a
+node is 2^depth - 1 (`mxu_tiles_per_group`: 32 for 63, 128 for 255). F > 64
+keeps one node a tile, the program it was, and so does an ensemble with the
+missing or the categorical table: its integer routing binds the kernel to
+the VPU, where reading a byte out of the word (a shift and a mask) costs
+more than the MXU results saved. Three nodes a tile (a node with its two
+children, 24 bits, no room for the mantissa trick) lost to two on the v5e:
+3,082 cycles a depth-6 step of 256 rows x 128 trees against 2,696, one node
+a tile 4,654 (PERF.md sections 5 and 6, PR 28).
+
+Per tree group of the block (static Python loop, traced once), per weight
+tile (depth-first, at the parent of its nodes):
+    foh [K, 128] bf16: per node a one-hot [F, 128] built on the VPU by
+        SUBLANE-broadcasting the node's feature row against a sublane iota
+        (the hist_pallas transposed-kernel trick), the two joined along
+        the sublanes with the mantissa row, then one MXU weight tile:
+        colval = X @ foh — the exact bin value of each node's feature at
+        every (row, tree) of the group, one a byte.
+    goes_right = colval > thr per node, a predicate used as it is (with
+        the categorical one-vs-rest and reserved-NaN-bin operands, one
+        node a tile, the integer routing of ops/predict._descend_comp,
+        term for term, then != 0).
     Value mux tree: a full tree whose Nint comparison bits are all known
         is a multiplexer over its W leaf values —
         leaf(n) = where(goes_right(n), leaf(2n+2), leaf(2n+1)), the leaves
         being val's rows. Nint compares + Nint selects per (row, tree)
         where the path has depth nodes: there is no node index k, no k == i
-        and no leaf select. Depth-first keeps depth + 1 planes live.
+        and no leaf select. Depth-first keeps depth + 1 value planes live,
+        and at P = 2 one packed plane a level.
     Class scatter: acc += vals @ class-one-hot (f32, HIGHEST), one dot a
         group.
 
@@ -98,26 +139,73 @@ _DEFAULT_TILE_R = 256
 # 1024 trees against 194.2 ms over 128-lane planes (PERF.md section 6,
 # PR 26).
 TREE_GROUP = 128
-# Working-set bytes of a tile beside the operand windows: the bf16 copy of
-# the rows, the one node plane in flight (one-hot, colval, predicate) and
-# the depth + 1 value planes of the depth-first mux tree, each
-# [TILE_R, 128] f32. It grows with the rows and not with the tree count:
-# no [TILE_R, Nint*128] array exists. Taken from the compiler's own
-# account — AOT compiles for a described v5e with the scoped limit forced
-# to 1 MiB, so that each reports its allocation (compile check, PR 26;
-# KiB a row; 1000 trees, 28 features, 1 class, tile 256 unless said):
-#   no optional operand: depth 4 / 6 / 8: 5.5 / 7.2 / 9.2 (9 trees: 6.1;
-#       4000 trees: 7.2; tile 512: 7.3; depth 8, 54 features, 7 classes:
-#       9.2, and 9.4 at tile 512)
-#   one of them:         missing, depth 6 / 7: 7.4 / 8.4; cat, depth 6: 7.8
-#   both:                depth 3 / 5 / 6 / 7 / 8: 7.4 / 15.8 / 19.0 / 27.4 /
-#       34.9 (depth 6 at tile 512: 18.7; 4000 trees: 19.0; depth 8, 54
-#       features, 7 classes: 35.1)
+# Working-set bytes of a tile beside the operand windows: the bf16 copies
+# of the rows, the weight tile in flight (one-hots, colval, predicate),
+# with two nodes a tile the packed plane a level, and the depth + 1 value
+# planes of the depth-first mux tree, each [TILE_R, 128] f32. It grows
+# with the rows and not with the tree count: no [TILE_R, Nint*128] array
+# exists. Taken from the compiler's own account: AOT compiles for a
+# described v5e with the kernel's scoped limit forced to 1 MiB
+# (pltpu.CompilerParams(vmem_limit_bytes=2**20) and every group in one
+# block), so that each refusal names its scoped allocation; the row
+# tile's two windows (2 KiB a row) are added to it, as PR 26 counted
+# (compile check, PR 28; KiB a row; 1000 trees, 28 features, 1 class,
+# tile 256 unless said; in brackets one node a tile, the parent's
+# program, under the same probe):
+#   no optional operand, two nodes a tile: depth 4 / 6 / 8: under 6 / 7.0
+#       / 9.0 [under 6 / 7.2 / 9.2] (9 trees: under 6; 4000 trees: 7.0;
+#       tile 512: 6.8 [7.3]; 64 features: 7.1 [7.2]; depth 8, 54 features,
+#       7 classes: 9.1 [9.2], and 8.9 at tile 512 [9.4]): the packed plane
+#       a level costs nothing the compiler did not already hold
+#   one of them (one node a tile, as PR 26 read them): missing, depth 6 /
+#       7: 7.4 / 8.4; cat, depth 6: 7.8
+#   both (one node a tile): depth 3 / 5 / 6 / 7 / 8: 7.4 / 15.3 / 19.0 /
+#       27.4 / 34.9 (depth 6 at tile 512: 18.7; 4000 trees: 19.0; 64
+#       features: 19.4; depth 8, 54 features, 7 classes: 35.1)
 # Only the three-way integer routing of both operands makes the compiler
-# keep something per node. 12 KiB a row, and 192 B a node more with both,
-# bound every probe by an eighth or more.
+# keep something per node (two nodes a tile WITH a routing table kept far
+# more: 30.0 with the missing table alone at depth 6, 26.4 with both;
+# one more reason they keep one node a tile). 12 KiB a row, and 192 B a
+# node more with both, bound every probe by an eighth or more.
 _ROW_BYTES = 12 * 1024
 _ROW_NODE_BYTES_BOTH = 192
+# Rows (K) of one MXU weight tile.
+_MXU_ROWS = 128
+# 1.5 * 2^23: an integer below 2^22 added to it is the low bits of the
+# f32's mantissa, so that the bitcast is the conversion (the packed word
+# has 16 bits).
+_MANTISSA = 12582912.0
+_MANTISSA_BITS = 0x4B400000
+
+
+def _copy_stride(n_features: int) -> int:
+    """K rows from one copy of the row tile to the next: the features, up
+    to a multiple of the 8 sublanes the one-hot is built and joined by."""
+    return -(-n_features // 8) * 8
+
+
+def nodes_per_tile(n_features: int, optional_operands: int = 0) -> int:
+    """P, the nodes that share one MXU weight tile: 2 where two copies of
+    the features fit its 128 K rows (F <= 64) and the ensemble carries
+    neither the missing nor the categorical table, else 1. Read from the
+    input, decided by timings on the v5e (PERF.md section 6, PR 28): three
+    nodes a tile lost to two, and with a routing table the kernel is
+    bound by the VPU's integer routing, where reading a node's byte out
+    of the packed word costs more than the MXU results saved (270.8 ms
+    against 236.6 for 2M rows x 1000 trees with both tables)."""
+    packs = (not optional_operands
+             and 2 * _copy_stride(n_features) <= _MXU_ROWS)
+    return 2 if packs else 1
+
+
+def mxu_tiles_per_group(max_depth: int, n_features: int,
+                        optional_operands: int = 0) -> int:
+    """MXU weight tiles (results [TILE_R, 128]) a tree group costs a row
+    tile: one a node, or the root's and one a pair of siblings."""
+    n_int = (1 << max_depth) - 1
+    if nodes_per_tile(n_features, optional_operands) == 1:
+        return n_int
+    return (n_int + 1) // 2
 
 
 def _window_bytes(rows: int, cols: int) -> int:
@@ -152,6 +240,8 @@ class TablePlan(typing.NamedTuple):
     blocks: int            # table blocks a row tile walks; 1 = resident
     table_bytes: int       # HBM bytes of all the blocks' tables, read once
     tile_rows: int         # rows a tile
+    nodes_per_tile: int    # P: nodes that share one MXU weight tile
+    mxu_tiles_per_group: int   # weight tiles a group costs a row tile
 
 
 def table_plan(
@@ -180,14 +270,18 @@ def table_plan(
             most + 1, max_depth, n_features, n_classes, tile_r,
             optional_operands) <= _VMEM_BUDGET_BYTES:
         most += 1
+    packing = (nodes_per_tile(n_features, optional_operands),
+               mxu_tiles_per_group(max_depth, n_features,
+                                   optional_operands))
     if most == 0:
-        return TablePlan(n_tg, 0, 0, 0, tile_r)
+        return TablePlan(n_tg, 0, 0, 0, tile_r, *packing)
     blocks = -(-n_tg // most)
     g = -(-n_tg // blocks)
     per_group = 4 * TREE_GROUP * ((2 + optional_operands)
                                   * ((1 << max_depth) - 1)
                                   + (1 << max_depth) + n_classes)
-    return TablePlan(n_tg, g, blocks, blocks * g * per_group, tile_r)
+    return TablePlan(n_tg, g, blocks, blocks * g * per_group, tile_r,
+                     *packing)
 
 
 def predict_pallas_fits(
@@ -208,10 +302,12 @@ def predict_pallas_fits(
 
 def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
                      n_groups: int, n_blocks: int, n_int: int,
-                     n_leaves: int, n_feat: int, missing_bin_value: int,
-                     use_missing: bool, use_cat: bool):
+                     n_leaves: int, n_feat: int, pack: int,
+                     missing_bin_value: int, use_missing: bool,
+                     use_cat: bool):
     """One row tile against one block of tree groups: that block's share
-    of every class's margin, fully in VMEM.
+    of every class's margin, fully in VMEM. `pack`: the plan's
+    nodes_per_tile.
 
     x_ref [TILE_R, F] int32; feat/thr (+ optional dl, cat) [G*Nint, 128]
     and val [G*W, 128], one plane a row; coh [G*128, C]; out [TILE_R, C]
@@ -223,31 +319,81 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
     cat_ref = rest.pop(0) if use_cat else None
     tile_r = x_ref.shape[0]
     tg = TREE_GROUP
-    xb = x_ref[:].astype(jnp.bfloat16)                    # [T, F]
-    f_iota = jax.lax.broadcasted_iota(jnp.int32, (n_feat, tg), 0)
 
     def plane(ref, row, rows=tile_r):
         """Row `row` of a table over `rows` sublanes: a row load at lane
         offset 0, then a sublane broadcast."""
         return jnp.broadcast_to(ref[row:row + 1, :], (rows, tg))
 
-    def goes_right(row):
-        """Predicate [T, 128]: the row leaves the node of table row `row`,
-        in each of its group's trees, to the right."""
-        # Feature one-hot, TRANSPOSED: the node's feature row over F
-        # sublanes against the per-feature iota (the hist_pallas
-        # _hist_kernel_t trick). feat = -1 (pushed-down leaves) matches
-        # no sublane -> colval 0 < thr(+BIG) -> always-left.
-        foh = (plane(feat_ref, row, n_feat) == f_iota).astype(
-            jnp.bfloat16)                                 # [F, 128]
-        # bf16 operands (bins <= 255 and the 0/1 one-hot are exact), f32
-        # accumulator: the MXU accumulates in 32 bits only, and the v5e's
-        # VPU has no bf16 compare, so colval is f32.
+    if pack == 1:
+        xb = x_ref[:].astype(jnp.bfloat16)                # [T, F]
+        f_iota = jax.lax.broadcasted_iota(jnp.int32, (n_feat, tg), 0)
+    else:
+        # Two copies of the row tile side by side along K, each from a
+        # multiple of 8 (the one-hot's sublane tiles), the second times
+        # 256, and where the weight tile has 8 rows to spare a block of
+        # ones for the row that adds _MANTISSA inside the MXU.
+        stride = _copy_stride(n_feat)
+        ones_in_tile = 2 * stride + 8 <= _MXU_ROWS
+        xf = x_ref[:].astype(jnp.float32)
+        copies = [xf, xf * 256.0]
+        if stride > n_feat:
+            gap = jnp.zeros((tile_r, stride - n_feat), jnp.float32)
+            copies = [xf, gap, xf * 256.0, gap]
+        f_iota = jax.lax.broadcasted_iota(jnp.int32, (stride, tg), 0)
+        mantissa_rows = []
+        if ones_in_tile:
+            copies.append(jnp.ones((tile_r, 8), jnp.float32))
+            mantissa_rows = [jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (8, tg), 0) == 0,
+                _MANTISSA, 0.0)]
+        xb = jnp.concatenate(copies, axis=1).astype(jnp.bfloat16)  # [T, K]
+
+    def tile_plane(g, nodes):
+        """What one MXU weight tile returns for `nodes` of group g's
+        trees, [T, 128]: the bin value of the node's feature at every
+        (row, tree) as f32 (one node a tile), or the two siblings' values
+        as the bytes 0 and 1 of an int32 (the root: byte 0) above
+        _MANTISSA_BITS."""
+        if pack == 1:
+            # Feature one-hot, TRANSPOSED: the node's feature row over F
+            # sublanes against the per-feature iota (the hist_pallas
+            # _hist_kernel_t trick). feat = -1 (pushed-down leaves)
+            # matches no sublane -> colval 0 < thr(+BIG) -> always-left.
+            foh = (plane(feat_ref, g * n_int + nodes[0], n_feat)
+                   == f_iota).astype(jnp.bfloat16)        # [F, 128]
+        else:
+            foh = [jnp.where(plane(feat_ref, g * n_int + n, stride)
+                             == f_iota, 1.0, 0.0) for n in nodes]
+            if len(nodes) == 1:                           # the root
+                foh.append(jnp.zeros((stride, tg), jnp.float32))
+            foh = jnp.concatenate(foh + mantissa_rows,
+                                  axis=0).astype(jnp.bfloat16)  # [K, 128]
+        # bf16 operands (bins <= 255, their multiples of 256, the 0/1
+        # one-hot and _MANTISSA are exact), f32 accumulator: the MXU
+        # accumulates in 32 bits only, and the v5e's VPU has no bf16
+        # compare. Every partial sum is an integer below 2^24: exact in
+        # any order.
         colval = jax.lax.dot_general(
             xb, foh, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                 # [T, 128]
+        if pack == 1:
+            return colval
+        if not ones_in_tile:
+            colval = colval + _MANTISSA
+        return pltpu.bitcast(colval, jnp.int32)
+
+    def goes_right(row, n, colval):
+        """Predicate [T, 128]: the row leaves heap node n (table row
+        `row`), in each of its group's trees, to the right. `colval`:
+        the node's tile_plane."""
         thr = plane(thr_ref, row)
+        if pack == 2:
+            # thr is the prologue's (thr + 1) << 8 * byte, on the tile's
+            # top byte plus _MANTISSA_BITS: the top byte compares as the
+            # whole word, the low one behind a mask.
+            return (colval & 255 if n % 2 else colval) >= thr
         if not (use_cat or use_missing):
             return colval > thr
         # With the optional operands the bits are routed as int32 0/1,
@@ -267,21 +413,30 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
             comp = jnp.where(miss, 1 - plane(dl_ref, row), comp)
         return comp != 0
 
-    def leaf(g, n):
+    def leaf(g, n, planes):
         """Value [T, 128] of the leaf the row reaches below heap node n
-        of group g's trees: the mux tree, depth-first (depth + 1 planes
-        live)."""
+        of group g's trees: the mux tree, depth-first (depth + 1 value
+        planes live, and with two nodes a tile one tile_plane a level:
+        the siblings' is computed at their parent and stays while the
+        first one's subtree is walked). `planes`: node -> its tile_plane,
+        for the nodes on the path whose tile is computed."""
         if n >= n_int:
             return plane(val_ref, g * n_leaves + n - n_int)
-        return jnp.where(goes_right(g * n_int + n),
-                         leaf(g, 2 * n + 2), leaf(g, 2 * n + 1))
+        tiles = [(n,)] if pack == 1 or n == 0 else []
+        if pack == 2 and 2 * n + 2 < n_int:
+            tiles.append((2 * n + 1, 2 * n + 2))
+        for nodes in tiles:
+            planes = planes | dict.fromkeys(nodes, tile_plane(g, nodes))
+        return jnp.where(goes_right(g * n_int + n, n, planes[n]),
+                         leaf(g, 2 * n + 2, planes),
+                         leaf(g, 2 * n + 1, planes))
 
     acc = jnp.zeros((tile_r, out_ref.shape[1]), jnp.float32)
     for g in range(n_groups):
         # Class scatter — the one-hot path's dot and precision, one add a
         # group.
         acc = acc + jax.lax.dot_general(
-            leaf(g, 0), coh_ref[g * tg:(g + 1) * tg, :],
+            leaf(g, 0, {}), coh_ref[g * tg:(g + 1) * tg, :],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
@@ -379,7 +534,19 @@ def predict_effective_pallas(
                 .reshape(n_blocks, n_g * width, tg))
 
     feat_pl = by_plane(eff_feat[:, :n_int], jnp.int32, fill=-1)
-    thr_pl = by_plane(eff_thr[:, :n_int], jnp.float32)
+    if plan.nodes_per_tile == 1:
+        thr_pl = by_plane(eff_thr[:, :n_int], jnp.float32)
+    else:
+        # The compare is made on the packed word, in integers: thr + 1
+        # (>= for >) shifted to the node's byte, and the tile's top byte
+        # (even nodes, the root) carries the word's constant high bits. A
+        # pushed-down leaf's +BIG becomes 255, which no byte exceeds.
+        node = jnp.arange(n_int)
+        top = node % 2 == 0
+        thr_i = jnp.clip(eff_thr[:, :n_int], -1, 255).astype(jnp.int32)
+        thr_pl = by_plane(
+            ((thr_i + 1) << jnp.where(top & (node > 0), 8, 0))
+            + jnp.where(top, _MANTISSA_BITS, 0), jnp.int32)
     val_pl = by_plane(bot_val, jnp.float32)
     coh = jnp.pad(cls_oh.astype(jnp.float32),
                   ((0, t_fill), (0, 0))).reshape(n_blocks, n_g * tg, C)
@@ -401,8 +568,9 @@ def predict_effective_pallas(
 
     kernel = functools.partial(
         _traverse_kernel, n_groups=n_g, n_blocks=n_blocks, n_int=n_int,
-        n_leaves=n_leaves, n_feat=F, missing_bin_value=missing_bin_value,
-        use_missing=use_missing, use_cat=use_cat,
+        n_leaves=n_leaves, n_feat=F, pack=plan.nodes_per_tile,
+        missing_bin_value=missing_bin_value, use_missing=use_missing,
+        use_cat=use_cat,
     )
 
     def rows_of_tile(cols):

@@ -195,6 +195,28 @@ def kernel_cases() -> list:
                    _predict_case(hr, hf, 150, 6, missing=True, cat=True)),
         KernelCase("predict/higgs/1000x6/missing+cat", True,
                    _predict_case(hr, hf, 1000, 6, missing=True, cat=True)),
+        # Two nodes a weight tile (F <= 64 and no routing table: 28
+        # features with 4 K rows between the copies, 54 with 2), at
+        # Covertype's own size: 28 groups in 4 blocks of 7, 128 weight
+        # tiles a group; with a routing table, one node a tile at the
+        # same size and blocks.
+        KernelCase("predict/covertype/3500x8/7classes", True,
+                   _predict_case(cr, cf, 500 * cc, 8, n_classes=cc)),
+        KernelCase("predict/covertype/3500x8/7classes/missing", True,
+                   _predict_case(cr, cf, 500 * cc, 8, n_classes=cc,
+                                 missing=True)),
+        KernelCase("predict/covertype/350x6/7classes/missing+cat", True,
+                   _predict_case(cr, cf, 50 * cc, 6, n_classes=cc,
+                                 missing=True, cat=True)),
+        # The edges of the packing: 56 features leave the weight tile 8
+        # rows for the mantissa row and no gap between the copies; 64 fill
+        # it (the mantissa is one VPU add); 65 keep one node a tile.
+        KernelCase("predict/56f/130x5", True,
+                   _predict_case(hr, 56, 130, 5)),
+        KernelCase("predict/64f/130x5", True,
+                   _predict_case(hr, 64, 130, 5)),
+        KernelCase("predict/65f/130x5", True,
+                   _predict_case(hr, 65, 130, 5)),
         # Opt-in kernels.
         KernelCase("hist/higgs/255bins/N=32/int8", False,
                    _hist_case(hr, hf, 32, 255, "int8")),

@@ -17,6 +17,7 @@ the chip compiles; tests/test_tpu_lowering.py lowers the compiled form).
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from ddt_tpu import api
@@ -169,26 +170,56 @@ def test_pallas_rejects_float_data():
 def _mux_cases():
     """Depth 1-6 x tree counts 9, 64, 130, 1000 (one group with 119 filler
     trees, half a group, two groups, eight) x 1 and 7 classes x with and
-    without the missing and categorical operands. The 1000-tree ensembles
-    cost 10-20 s each to trace interpreted, so they take every combination
-    at depth 6 only, and one each at depths 1-5."""
+    without the missing and categorical operands, at 6 features (two
+    nodes a weight tile). The 1000-tree ensembles cost 10-20 s each to
+    trace interpreted, so they take every combination at depth 6 only,
+    and one each at depths 1-5. Then the feature counts that decide how
+    many nodes share a weight tile and where the copies of the row tile
+    lie: 28 and 54 (the benchmark's), 56 (8 rows of the tile to spare for
+    the mantissa row, no gap between the copies), 57 and 64 (none to
+    spare: the mantissa is added on the VPU; 64 fills the tile), 65 and
+    128 (one node a tile), 42 and 43, over odd and even depths: 1-8 for
+    the first four, which cost 5-16 s each at depths 7 and 8."""
     combos = [(1, False, ()), (7, False, ()), (1, True, (1, 4)),
               (7, True, (2,))]
     for depth in range(1, 7):
         for T in (9, 64, 130):
             for combo in combos:
-                yield (depth, T, *combo)
+                yield (depth, T, 6, *combo)
         for combo in (combos if depth == 6 else [combos[depth % 4]]):
-            yield (depth, 1000, *combo)
+            yield (depth, 1000, 6, *combo)
+    for i, F in enumerate((28, 54, 64, 65, 42, 43, 56, 57, 128)):
+        for depth in (range(1, 9) if i < 4 else (1, 2, 5, 6)):
+            # every combination at each depth, over the feature counts
+            yield (depth, 9 if depth > 6 else 130, F,
+                   *combos[(i + depth) % 4])
+    for F in (54, 65):                        # all four at the deep end
+        for combo in combos:
+            yield (7, 9, F, *combo)
 
 
-@pytest.mark.parametrize("depth,T,n_classes,missing,cat", list(_mux_cases()))
-def test_mux_tree_matches_onehot(depth, T, n_classes, missing, cat):
+@pytest.fixture
+def drop_compiled():
+    """Every case below compiles four programs of its own shape, the
+    interpreted kernels large ones, and a process that keeps them all
+    aborts inside the CPU compiler some 120 cases in (under
+    `--dist loadfile` one worker runs this whole file)."""
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("depth,T,F,n_classes,missing,cat",
+                         list(dict.fromkeys(_mux_cases())))
+def test_mux_tree_matches_onehot(drop_compiled, depth, T, F, n_classes,
+                                 missing, cat):
     """The value mux tree on 128-lane tree groups selects the one-hot
-    path's leaf for every (row, tree): bit-equal scores on dyadic leaf
-    values, F32_ACC_TOL on random ones (a group's 128 trees are summed in
-    one dot, the one-hot path's 64 at a time)."""
-    ens = _rand_ensemble(T=T, depth=depth, n_classes=n_classes,
+    path's leaf for every (row, tree), with one node or two a weight
+    tile: bit-equal scores on dyadic leaf values, F32_ACC_TOL on random
+    ones (a group's 128 trees are summed in one dot, the one-hot path's
+    64 at a time)."""
+    assert jpp.nodes_per_tile(F) == (2 if F <= 64 else 1)
+    ens = _rand_ensemble(T=T, depth=depth, F=F, n_classes=n_classes,
+                         bins=31 if F == 6 else 255,
                          missing=missing, cat=cat, seed=100 * depth + T)
     # Keep the sums at the magnitude F32_ACC_TOL was sized for (11 leaf
     # values of about 1), whatever the tree count.
@@ -209,6 +240,95 @@ def test_mux_tree_matches_onehot(depth, T, n_classes, missing, cat):
         got = np.asarray(jpp.predict_raw_pallas(
             *args, Xb, tree_chunk=64, **kw, **opt))
         check(want, got)
+
+
+@pytest.mark.parametrize("depth", [2, 3, 4])
+@pytest.mark.parametrize("F", [28, 54, 64, 65])
+@pytest.mark.parametrize("missing,cat", [
+    (False, False), (True, True), (True, False), (False, True)])
+def test_packed_fields_are_exact(F, depth, missing, cat):
+    """Two nodes a weight tile return their bins as two bytes of one
+    integer; the compare reads each byte alone. Rows that put 255 (with
+    256 bins also the reserved missing bin) in both bytes at once,
+    siblings that split on the same feature, and the thresholds 0, 254
+    and 255 are where a carry or a mask that leaks would show. Every
+    tree's leaf is checked, not a sum that could cancel: tree t's value
+    at heap node n is n * B^t, so the score is the integer whose base-B
+    digits are the leaves, exact in float32, against the NumPy walk
+    (reference/numpy_predict.py). F = 65 is the same check on one node a
+    tile."""
+    from ddt_tpu.reference import numpy_predict
+
+    n_nodes = 2 ** (depth + 1) - 1
+    T = 24 // (depth + 1)                  # B^T <= 2^24
+    ens = _rand_ensemble(T=T, depth=depth, F=F, bins=256, missing=missing,
+                         cat=(3,) if cat else (), seed=F + depth)
+    rng = np.random.default_rng(depth)
+    ens.threshold_bin = rng.choice(
+        [0, 1, 127, 128, 254, 255], size=(T, n_nodes)).astype(np.int32)
+    ens.threshold_bin[0] = np.array([0, 127, 255])[np.arange(n_nodes) % 3]
+    ens.feature[:2] = 3                    # siblings on one feature
+    ens.feature[2] = F - 1                 # the last K row of each copy
+    ens.is_leaf[:3, :2 ** depth - 1] = False     # full trees
+    ens.learning_rate, ens.base_score = 1.0, 0.0
+    ens.leaf_value = (np.arange(n_nodes)[None, :] * float(n_nodes + 1)
+                      ** np.arange(T)[:, None]).astype(np.float32)
+    Xb = rng.integers(0, 256, size=(300, F))
+    for i, b in enumerate((255, 254, 0, 1, 127, 128)):
+        Xb[i] = b
+        Xb[10 + i, 3] = b                  # one feature, the rest random
+        Xb[20 + i] = np.where(np.arange(F) % 2, b, 255)
+    Xb = Xb.astype(np.uint8)
+    args, kw, opt = _dev_args(ens)
+    got = np.asarray(jpp.predict_raw_pallas(
+        *args, jnp.asarray(Xb.astype(np.int32)), tree_chunk=64, **kw,
+        **opt))
+    leaves = np.stack([numpy_predict.leaf_of_rows(ens, t, Xb)
+                       for t in range(T)], axis=1)       # [rows, T]
+    digits = got.astype(np.int64)[:, None] // (n_nodes + 1) ** np.arange(
+        T) % (n_nodes + 1)
+    np.testing.assert_array_equal(digits, leaves)
+    np.testing.assert_array_equal(got, got.astype(np.int64))
+
+
+def _kernel_jaxpr(F):
+    ens = _rand_ensemble(T=9, depth=3, F=F, bins=255, seed=F)
+    args, kw, opt = _dev_args(ens)
+    Xb = jnp.zeros((300, F), jnp.int32)
+    return str(jax.make_jaxpr(lambda *a: jpp.predict_raw_pallas(
+        *a, tree_chunk=64, **kw))(*args, Xb))
+
+
+def test_wide_models_keep_the_one_node_program():
+    """Past 64 features two copies of a row do not fit the weight tile's
+    128 K rows, and the scoring program is the one it was before nodes
+    were packed: one [256, F] x [F, 128] matmul a node, an f32 compare,
+    nothing of the packed form in it (at F = 65, 72 and 128 the whole
+    jaxpr was compared with the parent commit's by hand: the same text,
+    PR 28). At F = 64 the packed operands are there."""
+    packed_ops = ("= bitcast[", "concatenate", "shift_left")
+    wide = _kernel_jaxpr(65)
+    assert not [op for op in packed_ops if op in wide]
+    assert wide.count("dot_general") == 7 + 1            # nodes + class dot
+    assert "bf16[256,65]" in wide and "bf16[65,128]" in wide
+    packed = _kernel_jaxpr(64)
+    assert not [op for op in packed_ops if op not in packed]
+    assert packed.count("dot_general") == 4 + 1
+    assert "bf16[256,128]" in packed and "bf16[128,128]" in packed
+
+
+@pytest.mark.parametrize("F,depth,nodes,tiles", [
+    (1, 6, 2, 32), (28, 6, 2, 32), (54, 8, 2, 128), (56, 8, 2, 128),
+    (57, 3, 2, 4), (64, 1, 2, 1), (65, 6, 1, 63), (128, 8, 1, 255),
+    (1024, 2, 1, 3),
+])
+def test_nodes_per_tile_is_read_from_the_shape(F, depth, nodes, tiles):
+    """P follows from the feature count alone, and with the depth the
+    MXU results a tree group costs; both ride on the table plan."""
+    assert jpp.nodes_per_tile(F) == nodes
+    assert jpp.mxu_tiles_per_group(depth, F) == tiles
+    plan = jpp.table_plan(1024, depth, F, 1, None, 0)
+    assert (plan.nodes_per_tile, plan.mxu_tiles_per_group) == (nodes, tiles)
 
 
 @pytest.mark.parametrize("depth,F,C,optional,tile_r,fits", [
@@ -324,6 +444,10 @@ def test_ensemble_span_says_which_form_served(impl, T, want):
     assert (counts["table_groups"], counts["groups_per_step"]) \
         == (groups, groups)
     assert counts["table_bytes"] == groups * 4 * 128 * (2 * 7 + 8 + 1)
+    # ... and how the kernel uses the MXU: 5 features, so two nodes a
+    # weight tile, the root's and one a pair of siblings (7 nodes: 4).
+    assert (counts["nodes_per_tile"], counts["mxu_tiles_per_group"]) \
+        == ((2, 4) if want else (0, 0))
     assert root["counts"]["classes"] == 1
     assert root["counts"]["tables_streamed_bytes"] == 0
 

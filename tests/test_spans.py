@@ -284,8 +284,8 @@ def test_cli_predict_prints_phases_ms(tmp_path, capsys):
     # auto dispatch takes the one-hot path, so the kernel's group is 0.
     assert rec["phases_ms"].pop("tree_group") == 0
     # ... and no group of it holds trees, no table block, nothing streamed
-    for count in ("table_groups", "groups_per_step",
-                  "tables_streamed_bytes"):
+    for count in ("table_groups", "groups_per_step", "nodes_per_tile",
+                  "mxu_tiles_per_group", "tables_streamed_bytes"):
         assert rec["phases_ms"].pop(count) == 0
     assert sorted(rec["phases_ms"]) == sorted(
         ["token", "ensemble", "upload", "dispatch", "fetch", "concat"])

@@ -50,13 +50,19 @@ def test_case_table_covers_the_default_dispatch():
     """Both histogram forms, feature-chunked at the Covertype width, and
     the traversal kernel with and without the optional operands, for one
     output and for seven, with whole tree groups and with a filled-up
-    last one."""
+    last one, two nodes a weight tile (every case of at most 64
+    features without a routing table; Covertype's own 3,500 trees at
+    depth 8 among them) and one."""
     names = [c.name for c in DEFAULT_CASES]
     for needle in ("hist/higgs/255bins", "hist/higgs/64bins",
                    "hist/covertype", "predict/higgs/1000x6",
                    "predict/50x4/missing+cat", "7classes",
                    "predict/150x6/missing+cat",
-                   "predict/higgs/1000x6/missing+cat"):
+                   "predict/higgs/1000x6/missing+cat",
+                   "predict/covertype/3500x8/7classes",
+                   "predict/covertype/3500x8/7classes/missing",
+                   "7classes/missing+cat", "predict/56f", "predict/64f",
+                   "predict/65f"):
         assert any(needle in n for n in names), (needle, names)
 
 
