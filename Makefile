@@ -101,13 +101,6 @@ registry-smoke:
 train-ops-smoke:
 	JAX_PLATFORMS=cpu $(PY) scripts/train_ops_smoke.py
 
-# Bench regression sentinel (docs/OBSERVABILITY.md): band every metric
-# of the newest BENCH_r*/MULTICHIP_r* artifact against the history
-# (median ± max(3*MAD, 20%)); exit 1 on an adverse excursion. Point a
-# fresh run at it with `python -m tools.benchwatch --current out.json`.
-benchwatch:
-	$(PY) -m tools.benchwatch
-
 # Compile-only check (no chip needed): every Pallas kernel and the
 # rounds/scoring programs against a described v5e. Before chip time.
 aot-check:
@@ -124,5 +117,5 @@ native:
 
 .PHONY: lint lint-baseline lint-smoke tsan-audit test report trace-smoke \
 	profile-smoke kernel-smoke chaos-smoke serve-smoke registry-smoke \
-	bigdata-smoke train-ops-smoke benchwatch aot-check \
+	bigdata-smoke train-ops-smoke aot-check \
 	chip-smoke-rehearse native

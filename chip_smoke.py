@@ -52,7 +52,7 @@ SCORE_CHECK_ROWS = 50_000
 MC_ROUNDS, MC_ROWS = 500, 100_000      # the 7-class scoring phase
 SCORE_TOL = dict(rtol=3e-4, atol=3e-4)      # as __graft_entry__'s oracle check
 # Chip-vs-oracle training parity: the bounds the earlier chip runs measured
-# inside (0.9871 agreement, 0.0024 AUC) and bench.py holds. Never bitwise
+# inside (0.9871 agreement, 0.0024 AUC). Never bitwise
 # across platforms (ops/split.py "Determinism boundary").
 PARITY_MIN_AGREEMENT = 0.95
 PARITY_MAX_AUC_DELTA = 0.01

@@ -54,8 +54,8 @@ _JIT_FIELDS = (
     # Trace-shaping comms + kernel-phasing knobs: the resolved collective
     # mode/dtype/slab count and the sibling-subtraction flag all bake
     # into the compiled grow/stream programs — a cached instance reused
-    # across them would train with the wrong collectives (the A/B benches
-    # and the comms parity tests flip exactly these).
+    # across them would train with the wrong collectives (the comms
+    # parity tests flip exactly these).
     "hist_subtraction", "split_comms", "hist_comms_dtype",
     "hist_comms_slabs",
     # Quantized-gradient training (ISSUE 14): the integer histogram

@@ -8,7 +8,7 @@ is a `TPUDevice` slotting in beside it with the tree loop unchanged. This
 module is that interface, TPU-first:
 
 - The granular kernels (`build_histograms`, `best_splits`) stay on the
-  interface as the parity/bench surface — tests drive each backend's kernels
+  interface as the parity surface — tests drive each backend's kernels
   against the NumPy oracle through exactly these methods.
 - The Driver's per-tree call is the *fused* `grow_tree`: on TPU a whole tree
   (all levels: histograms → allreduce → gains → split → row routing) is ONE

@@ -7,8 +7,7 @@ CPU-reference histogram throughput"]. This backend wraps the M0 oracle trainer
 
 - backend-parity tests can drive CPU vs TPU through the identical call
   surface (SURVEY.md §4 "Backend parity"), and
-- the bench harness measures the baseline M-rows/sec on the same contract it
-  measures the TPU path.
+- a CPU baseline can be measured on the same contract as the TPU path.
 
 When the native C++ kernel (ddt_tpu/native) is built, `build_histograms` uses
 it (that's the honest CPU baseline — a compiled kernel, like the reference's);

@@ -146,8 +146,8 @@ def enable_persistent_compile_cache() -> None:
     DEFAULT_COMPILE_CACHE_DIR.
 
     Mutates process-global JAX config, so the LIBRARY never calls it
-    implicitly: our own entry points (cli, bench, chip_smoke,
-    __graft_entry__) do, and embedders opt in by calling it or by
+    implicitly: our own entry points (cli, benchmark/run.py,
+    chip_smoke, __graft_entry__) do, and embedders opt in by calling it or by
     setting the variable."""
     if jax.config.jax_compilation_cache_dir is None:
         jax.config.update("jax_compilation_cache_dir",
@@ -399,7 +399,7 @@ class TPUDevice(DeviceBackend):
         return LabelHandle(self._put_rows(y), self._put_rows(valid))
 
     # ------------------------------------------------------------------ #
-    # granular L3 kernels (parity/bench surface)
+    # granular L3 kernels (parity surface)
     # ------------------------------------------------------------------ #
 
     @functools.cached_property
@@ -1585,7 +1585,7 @@ class TPUDevice(DeviceBackend):
         chunks are written as soon as they are known)."""
         R = Xb.shape[0]
         chunk = self.PREDICT_ROW_CHUNK * max(1, self.row_shards)
-        fn, ens_dev, tables = self._predict_entry(ens, compiled)
+        fn, ens_dev, classes, plan = self._predict_entry(ens, compiled)
         if isinstance(Xb, jax.Array) and (R <= chunk or self.distributed):
             # Device-resident input is only special-cased on the
             # single-chip big-batch loop below (where it skips the bulk
@@ -1594,17 +1594,17 @@ class TPUDevice(DeviceBackend):
             Xb = np.asarray(Xb)
         starts = range(0, R, chunk) if R > chunk else (0,)
         counts["chunks"] = len(starts)
-        counts["classes"] = tables["classes"]
+        counts["classes"] = classes
         # What the traversal kernel reads of its node tables from HBM over
         # the call: every table block once a row tile where they stream,
         # 0 where one block holds them all (fetched once, resident).
         counts["tables_streamed_bytes"] = 0
-        if tables["blocks"] > 1:
+        if plan.blocks > 1:
             shards = max(1, self.row_shards)
             shard_rows = [-(-min(chunk, R - i) // shards) for i in starts]
-            tiles = sum(-(-r // tables["tile_rows"]) for r in shard_rows)
+            tiles = sum(-(-r // plan.tile_rows) for r in shard_rows)
             counts["tables_streamed_bytes"] = (
-                shards * tiles * tables["table_bytes"])
+                shards * tiles * plan.table_bytes)
         if R <= chunk:
             counts["branch"] = "one"
             with phase_span("predict:upload", bytes=Xb.nbytes):
@@ -1756,13 +1756,13 @@ class TPUDevice(DeviceBackend):
 
     def _predict_fn(self, ens: TreeEnsemble, compiled=None):
         """(jittable scoring fn, device-resident compiled-ensemble arrays):
-        `_predict_entry` without the table plan."""
+        `_predict_entry` without the class count and the table plan."""
         return self._predict_entry(ens, compiled)[:2]
 
     def _predict_entry(self, ens: TreeEnsemble, compiled=None):
         """(jittable scoring fn, device-resident compiled-ensemble arrays,
-        how the traversal kernel takes the node tables: the dict of
-        `_build_predict_fn`).
+        the model's class count, how the traversal kernel takes the node
+        tables: the ops/predict_pallas.TablePlan of `_build_predict_fn`).
 
         The pushed-down/padded scoring layout (models/tree.
         CompiledEnsemble) and its device copies are cached per model
@@ -1800,13 +1800,11 @@ class TPUDevice(DeviceBackend):
             tele_counters.record_compiled_ensemble_hit()
             return hit
         with phase_span("predict:ensemble") as sp:
-            fn, ens_dev, resolved, tables = self._build_predict_fn(
+            fn, ens_dev, resolved, classes, plan = self._build_predict_fn(
                 ens, compiled)
             sp.counts["bytes"] = sum(a.nbytes for a in ens_dev)
-            sp.counts.update({k: tables[k] for k in (
-                "tree_group", "table_groups", "groups_per_step",
-                "table_bytes", "nodes_per_tile", "mxu_tiles_per_group")})
-        self._predict_cache[token] = hit = (fn, ens_dev, tables)
+            sp.counts.update(plan.span_counts())
+        self._predict_cache[token] = hit = (fn, ens_dev, classes, plan)
         self._predict_impl_resolved[token] = resolved
         while len(self._predict_cache) > self.PREDICT_CACHE_MAX:
             gone = next(iter(self._predict_cache))
@@ -1816,23 +1814,19 @@ class TPUDevice(DeviceBackend):
 
     def _build_predict_fn(self, ens: TreeEnsemble, compiled):
         """_predict_entry's cache miss: (fn, device arrays, the tier that
-        serves, how the f32 traversal kernel takes the node tables) —
-        layout build or reuse, quantisation, the node tables' upload and
-        the mesh wrapper. The last is a dict: `classes`; `tree_group`,
-        the lane width of the kernel's tree planes
-        (ops/predict_pallas.TREE_GROUP); and the fields of its
-        predict_pallas.TablePlan. All but `classes` are 0 when that
-        kernel does not serve the model: the one-hot path, the LUT
-        tiers."""
+        serves, the model's class count, how the f32 traversal kernel
+        takes the node tables) — layout build or reuse, quantisation, the
+        node tables' upload and the mesh wrapper. The last is that
+        kernel's ops/predict_pallas.TablePlan, NO_PLAN when it does not
+        serve the model: the one-hot path, the LUT tiers."""
+        from ddt_tpu.ops import predict_pallas
+
         ce = compiled if compiled is not None else ens.compile(
             tree_chunk=64)
         impl_req = self.cfg.predict_impl
         lut = None
         resolved = "f32"
-        tables = dict(classes=ce.n_classes_out, tree_group=0,
-                      table_groups=0, groups_per_step=0, blocks=0,
-                      table_bytes=0, tile_rows=0, nodes_per_tile=0,
-                      mxu_tiles_per_group=0)
+        plan = predict_pallas.NO_PLAN
         if impl_req in ("lut", "lut4"):
             if impl_req == "lut4":
                 lut = self._lut_fn(ce, ens.n_features, tier="lut4")
@@ -1863,14 +1857,9 @@ class TPUDevice(DeviceBackend):
             if predict_ops.resolve_use_pallas(
                     use_pallas, True, ce.max_depth, ens.n_features,
                     ce.n_classes_out, use_missing + use_cat):
-                from ddt_tpu.ops import predict_pallas
-
-                tables.update(
-                    predict_pallas.table_plan(
-                        ce.n_trees_padded, ce.max_depth, ens.n_features,
-                        ce.n_classes_out, None,
-                        use_missing + use_cat)._asdict(),
-                    tree_group=predict_pallas.TREE_GROUP)
+                plan = predict_pallas.table_plan(
+                    ce.n_trees_padded, ce.max_depth, ens.n_features,
+                    ce.n_classes_out, None, use_missing + use_cat)
 
             def fn0(ef, et, bv, coh, *rest):
                 *opt, Xc = rest
@@ -1917,4 +1906,4 @@ class TPUDevice(DeviceBackend):
                 # here (no collectives anywhere in the traversal).
                 check_vma=False,
             ))
-        return fn, ens_dev, resolved, tables
+        return fn, ens_dev, resolved, ce.n_classes_out, plan

@@ -1,10 +1,9 @@
-"""CLI (layer L8): train / predict / bench with the backend flag.
+"""CLI (layer L8): train / predict with the backend flag.
 
 SURVEY.md §1 L8 + [BASELINE] "backend selectable by flag":
 
     python -m ddt_tpu.cli train   --backend=tpu --dataset=higgs --rows=1000000
     python -m ddt_tpu.cli predict --model=ens.npz --dataset=higgs --rows=10000
-    python -m ddt_tpu.cli bench   --kernel=histogram --backend=tpu
 
 Datasets are the BASELINE.json configs, backed by seeded synthetic generators
 (data/datasets.py) since this environment has no network; a --data=path.npz
@@ -97,26 +96,23 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     """Host milliseconds of each step of the device scoring calls whose
     root span `ddt:predict` started at or after `since_ns`
     (time.perf_counter_ns): token, ensemble, upload, dispatch, fetch,
-    concat (docs/OBSERVABILITY.md has the table), and with them six
-    counts that are no times: `tree_group`, the lane width of the
-    traversal kernel's tree planes as the model's `ensemble` span
-    recorded it (0: the kernel does not serve the model), `table_groups`
-    and `groups_per_step` from the same span (how many tree groups the
-    model fills and how many of them a table block holds),
-    `nodes_per_tile` and `mxu_tiles_per_group` (how many nodes share one
-    MXU weight tile, and the tiles a tree group costs a row tile), and
-    `tables_streamed_bytes`, what the kernel re-read of its node tables
-    over the calls (0: one block, resident). None when no such call ran:
-    the NumPy backend and raw-threshold scoring open no span."""
+    concat, and with them counts that are no times: the traversal
+    kernel's table plan as the model's `ensemble` span recorded it
+    (ops/predict_pallas.PHASES_COUNTS; all 0: the kernel does not serve
+    the model) and `tables_streamed_bytes`, the root spans' sum over the
+    calls. docs/OBSERVABILITY.md has the table of what each means. None
+    when no such call ran: the NumPy backend and raw-threshold scoring
+    open no span."""
     from ddt_tpu.telemetry.annotations import PREFIX, root_spans
 
     roots = [r for r in root_spans("predict") if r["start"] >= since_ns]
     if not roots:
         return None
+    from ddt_tpu.ops.predict_pallas import PHASES_COUNTS
+
     ms = dict.fromkeys(
         ("token", "ensemble", "upload", "dispatch", "fetch", "concat"), 0.0)
-    plan = dict.fromkeys(("tree_group", "table_groups", "groups_per_step",
-                          "nodes_per_tile", "mxu_tiles_per_group"))
+    plan = dict.fromkeys(PHASES_COUNTS)
     for r in roots:
         for s in r["spans"]:
             step = s["name"].removeprefix(PREFIX + "predict:")
@@ -831,26 +827,6 @@ def main(argv: list[str] | None = None) -> int:
     rtg.add_argument("ref", help="name@version (or name for latest)")
     rtg.add_argument("tag", help="tag to set (non-numeric)")
 
-    bp = sub.add_parser("bench", help="kernel/e2e benchmarks (JSON lines)")
-    _add_common(bp)
-    bp.add_argument("--kernel", default="histogram",
-                    choices=["histogram", "train", "predict", "serve",
-                             "registry", "hist_comms", "hist_2d",
-                             "hist_quant", "lut4"])
-    bp.add_argument("--grad-dtype", default=None,
-                    choices=["int8", "int16"],
-                    help="quantized arm for --kernel hist_quant "
-                         "(default int8)")
-    bp.add_argument("--features", type=int, default=None,
-                    help="feature count; default = each kernel's own "
-                         "(28 for the narrow arms, 1024 for the wide "
-                         "hist_2d A/B)")
-    bp.add_argument("--trees", type=int, default=100)
-    bp.add_argument("--depth", type=int, default=6)
-    bp.add_argument("--iters", type=int, default=10)
-    bp.add_argument("--partitions", type=int, default=1)
-    bp.add_argument("--hist-impl", default="auto")
-
     rp = sub.add_parser("report",
                         help="render a run summary from a JSONL telemetry "
                              "log (train --run-log), or diff two logs")
@@ -897,7 +873,7 @@ def main(argv: list[str] | None = None) -> int:
     dp = rsub.add_parser(
         "diff",
         help="align two run logs by phase and counter and flag adverse "
-             "excursions (benchwatch band logic, single-baseline form — "
+             "excursions (a band around the single baseline A — "
              "docs/OBSERVABILITY.md)")
     dp.add_argument("log_a", help="baseline run log (A)")
     dp.add_argument("log_b", help="current run log (B)")
@@ -905,7 +881,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="emit the diff as one JSON object")
     dp.add_argument("--threshold", type=float, default=None,
                     help="adverse relative excursion that flags "
-                         "(default 0.20 — benchwatch's relative floor)")
+                         "(default 0.20 — diffing.REL_FLOOR)")
     dp.add_argument("--abs-floor-ms", type=float, default=None,
                     help="absolute per-phase floor below which moves "
                          "never flag (default 50 ms; 0 bands micro-runs)")
@@ -1418,20 +1394,6 @@ def main(argv: list[str] | None = None) -> int:
             "cmd": "trace", "logs": args.log, "events": len(events),
             "trace_events": n, "out": args.out,
         }))
-        return 0
-
-    if args.cmd == "bench":
-        from ddt_tpu.bench import run_bench
-
-        out = run_bench(
-            kernel=args.kernel, backend=args.backend, rows=args.rows,
-            features=args.features, bins=args.bins, trees=args.trees,
-            depth=args.depth, iters=args.iters, partitions=args.partitions,
-            hist_impl=args.hist_impl, seed=args.seed,
-            grad_dtype=args.grad_dtype,
-        )
-        out.update(_device_stamp(TrainConfig(backend=args.backend)))
-        print(json.dumps(out))
         return 0
 
     if args.cmd == "inspect":

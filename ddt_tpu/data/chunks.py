@@ -136,7 +136,7 @@ def shard_stress_chunks(
     (data.datasets.stress_binned_chunk) into npz shards, ONE chunk in
     memory at a time (the writer itself is O(chunk) — the scale
     harnesses assert that). The single home of the stress-shard naming
-    contract the scale experiments and RSS tests share; returns the
+    contract the scale harnesses and RSS tests share; returns the
     per-chunk row count."""
     from ddt_tpu.data.datasets import stress_binned_chunk
 

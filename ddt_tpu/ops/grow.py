@@ -273,7 +273,7 @@ def grow_tree(
     #   per level just before the gain epilogue.
     quant_tree_id=None,              # traced int32 ABSOLUTE tree index
     #   (round * n_classes + class) — the stochastic-rounding key's
-    #   per-tree component; None = 0 (single-shot callers/benches).
+    #   per-tree component; None = 0 (single-shot callers).
     quant_seed: int = 0,             # cfg.seed (static rounding key part)
 ) -> TreeArrays:
     """Grow one complete-heap tree. Trace under jit (and shard_map if

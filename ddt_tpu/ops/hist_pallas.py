@@ -61,8 +61,7 @@ OH [F*Bp, T] with bins on SUBLANES — x broadcasts along sublanes as cheap
 row replication, ~1.5x the row-major form at 64 bins. Since round 6 the
 64-bin layout is promoted to automatic dispatch: n_bins <= 64 pads to Bp
 = 64 sublanes (half the OH footprint and half the MXU columns of the old
-128-lane padding), which is what the bench's `value_64bin_optin` arm
-measures.
+128-lane padding).
 
 Contract identical to ops/histogram.py: returns [n_nodes, F, n_bins, 2]
 f32. Tests run this kernel in Pallas interpret mode on CPU
@@ -228,7 +227,7 @@ def _hist_kernel_t(xt_ref, g_ref, h_ref, ni_ref, out_ref, acc_ref, *,
     """TRANSPOSED row tile (bins_pad <= 128, i.e. n_bins <= 128):
     acc[F*Bp, 2N] += OH[F*Bp, T] @ A[T, 2N].
 
-    Why a second form exists (experiments/hist_sweep9/10, measured v5e):
+    Why a second form exists (sweeps 9 and 10, earlier host, round 5):
     the row-major kernel is bound by per-feature [T, 1] -> [T, Bp] LANE
     broadcasts (cost flat in Bp — shrinking bins bought nothing), while
     this form broadcasts x rows along SUBLANES ((bin_iota[Bp, 1] ==

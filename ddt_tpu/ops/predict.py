@@ -27,7 +27,7 @@ alone would be 40 GB).
 Since the inference-overhaul PR the module exposes THREE related entries:
 
 - `predict_raw` — the original raw-arrays contract (pushdown computed
-  in-trace); kept for tests/experiments and host callers.
+  in-trace); kept for tests and host callers.
 - `predict_raw_effective` — the same scoring core fed PRE-pushed-down,
   pre-padded arrays (models/tree.CompiledEnsemble builds them ONCE per
   model on host; backends keep them device-resident across calls).

@@ -288,7 +288,7 @@ class PackedTables:
 
     def static_kwargs(self) -> dict:
         """The kernel's static argument set — one home shared by the
-        backend dispatch, the AOT export closure, and the bench."""
+        backend dispatch and the AOT export closure."""
         t = self.tables
         return dict(
             max_depth=t.max_depth, learning_rate=t.learning_rate,
@@ -627,7 +627,7 @@ def predict_effective_lut(
     tile_r: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Standalone host entry (tests/bench/serve fallback): builds the
+    """Standalone host entry (tests): builds the
     node-major operands from the tables and runs the kernel. The backend
     path (TPUDevice._predict_fn with cfg.predict_impl="lut") caches the
     operands device-resident instead — this entry rebuilds them per call
@@ -905,7 +905,7 @@ def predict_effective_lut4(
     tile_r: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Standalone host entry for the int4 tier (tests/bench): packs on
+    """Standalone host entry for the int4 tier (tests): packs on
     demand and runs the kernel. The backend path (TPUDevice._predict_fn
     with cfg.predict_impl="lut4") caches the packed operands
     device-resident instead — this entry exists for correctness work,

@@ -243,6 +243,28 @@ class TablePlan(typing.NamedTuple):
     nodes_per_tile: int    # P: nodes that share one MXU weight tile
     mxu_tiles_per_group: int   # weight tiles a group costs a row tile
 
+    @property
+    def tree_group(self) -> int:
+        """Lane width of the kernel's tree planes; 0 in NO_PLAN."""
+        return TREE_GROUP if self.table_groups else 0
+
+    def span_counts(self) -> dict:
+        """The plan as the `ddt:predict:ensemble` span carries it."""
+        return {k: getattr(self, k) for k in SPAN_COUNTS}
+
+
+# The one list of what the program says of a plan; what each name means is
+# in docs/OBSERVABILITY.md. SPAN_COUNTS: the counts of the
+# `ddt:predict:ensemble` span (backends/tpu.py), in the order they print.
+# PHASES_COUNTS: those of them `cli predict` repeats in `phases_ms`;
+# `table_bytes` is one walk of the blocks, and a call's whole re-read is
+# the root span's `tables_streamed_bytes`, which `phases_ms` has instead.
+SPAN_COUNTS = ("tree_group", "table_groups", "groups_per_step",
+               "table_bytes", "nodes_per_tile", "mxu_tiles_per_group")
+PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
+# This kernel does not serve the model (the one-hot path, the LUT tiers).
+NO_PLAN = TablePlan(*(0,) * len(TablePlan._fields))
+
 
 def table_plan(
     n_trees_padded: int,
@@ -640,7 +662,7 @@ def predict_raw_pallas(
     tile_r: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Standalone raw-arrays entry (tests/bench): pushdown in-trace, then
+    """Standalone raw-arrays entry (tests): pushdown in-trace, then
     the Pallas core — the predict_raw contract with use_pallas forced."""
     from ddt_tpu.ops import predict as predict_ops
 

@@ -116,8 +116,8 @@ def best_splits_impl(
     # real-signal configs (the default-parameter test suites) satisfy
     # this without any explicit floor.
     #
-    # Cross-PLATFORM boundary (round 3, measured — experiments/
-    # chip_parity.py): all of the above holds WITHIN a platform. Real-v5e
+    # Cross-PLATFORM boundary (measured on the earlier host, round 3):
+    # all of the above holds WITHIN a platform. Real-v5e
     # vs CPU training additionally differs by f32 summation ORDER (MXU
     # systolic accumulation vs sequential loops), which flips decisions
     # on EXACT near-ties that straddle a bf16 quantization boundary —

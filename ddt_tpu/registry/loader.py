@@ -282,7 +282,7 @@ def push_servable(root, bundle, *, name: str | None = None,
                   run_id: str | None = None, tag: str | None = None,
                   run_log=None) -> dict:
     """Export + publish in one call (the `cli registry push` body and
-    the test/bench entry): stage a servable artifact for the engine's
+    the tests' entry): stage a servable artifact for the engine's
     power-of-two bucket ladder up to `max_batch`, then push it.
     `quantize` is the tier (False | True/"int8" | "int4" — see
     aot.stage_servable). Returns the store's {digest, name, version}."""

@@ -29,8 +29,8 @@ discipline; guard-tested in tests/test_robustness.py by making
 
 Every firing emits a `fault` run-log event (kind="injected", site +
 context) through the robustness fault sink, so a chaos run's log is
-self-describing — which is also how benchwatch knows to exclude
-injected-fault artifacts from bench history.
+self-describing: whoever compares run logs can tell a chaos run from a
+clean one by its `fault` events alone.
 """
 
 from __future__ import annotations

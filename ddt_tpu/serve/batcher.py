@@ -257,8 +257,8 @@ class MicroBatcher:
         express dispatch runs blocks on the gate for at most one
         single-row pre-traced dispatch — and under load the queue is
         never empty, so the lane stays shut and the coalesced path is
-        untouched (the two-regime contract bench_predict_lut4_ab
-        measures)."""
+        untouched (the two-regime contract of docs/SERVING.md "Express
+        lane")."""
         with self._cv:
             if self._closed:
                 raise ShuttingDown("serve batcher is shut down")

@@ -489,7 +489,8 @@ def dispatch_batch(model, batch, queue_depth: int, stats,
     # Stats land BEFORE any waiter wakes: a caller that resets the
     # stats window the moment result() returns must find this batch in
     # the window it completed in, and never see it leak into the next
-    # one (bench_serve_latency's per-QPS arms do exactly that).
+    # one (a load generator that resets the window per rate does
+    # exactly that).
     tele_counters.record_serve_requests(len(good))
     tele_counters.record_serve_batch()
     if express:
@@ -517,8 +518,7 @@ def dispatch_batch(model, batch, queue_depth: int, stats,
 
 class ServeEngine:
     """The persistent scoring process's core (transport-agnostic: the
-    HTTP front end, the CLI, tests, and the bench all drive this same
-    object).
+    HTTP front end, the CLI and the tests all drive this same object).
 
     Request path: submit -> admission batch (MicroBatcher) -> one
     dispatch against the model reference read at batch start -> scatter

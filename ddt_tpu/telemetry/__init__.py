@@ -46,7 +46,7 @@ Since the device-truth cost observatory (schema v3) three more:
              referenced to the run log through the manifest's
              xprof_dir/xprof_rounds extras and the run_id-named trace dir.
 - diffing  — `cli report diff A B`: per-phase / per-counter deltas with
-             benchwatch-band excursion flags ("gain +34%, jit_compiles
+             one-sided excursion flags ("gain +34%, jit_compiles
              12→48, hist bytes-accessed x2.1").
 
 `report` renders a run summary from a JSONL log (`python -m ddt_tpu.cli

@@ -259,8 +259,7 @@ def costed(op: str, phase: str | None = None):
 
 
 def analyze(fn, *args, **kwargs) -> dict:
-    """One-shot explicit cost analysis of `fn` at `args` — the bench
-    harness's roofline stamp. `fn` may be a jit object (has .lower) or a
+    """One-shot explicit cost analysis of `fn` at `args`. `fn` may be a jit object (has .lower) or a
     plain traceable callable (jitted here). Returns the _capture record
     ({flops, bytes_accessed, platform, ...})."""
     if jax is None:

@@ -3,13 +3,12 @@
 Given two run logs (same config or not — the diff says what changed,
 the reader judges comparability), align by phase and counter and compute
 per-phase wall-time and per-counter deltas, plus cost-analysis byte/flop
-movement per phase. Excursions are flagged with benchwatch's band logic
-degenerated to a single baseline: tools/benchwatch bands a metric at
-median ± max(3·MAD, REL_FLOOR·|median|); with exactly one baseline run
-the MAD term is zero, so the gate is the relative floor — an ADVERSE
-move past REL_FLOOR (20%) of A's value flags, a favorable move never
-does (one-sided, direction-aware, exactly the sentinel's semantics;
-keep REL_FLOOR in sync with tools.benchwatch.REL_FLOOR).
+movement per phase. Excursions are flagged against a band around the
+single baseline A: a band over a history would be median ± max(3·MAD,
+REL_FLOOR·|median|); with exactly one baseline run the MAD term is
+zero, so the gate is the relative floor — an ADVERSE move past
+REL_FLOOR (20%) of A's value flags, a favorable move never does
+(one-sided, direction-aware).
 
 The output turns "round 6 got slower" into "gain +34% (ms_total
 120.1 -> 161.0), jit_compiles 12 -> 48, hist bytes-accessed x2.1".
@@ -19,8 +18,8 @@ device — two logs copied off a pod diff anywhere.
 
 from __future__ import annotations
 
-#: mirror of tools.benchwatch.REL_FLOOR (the library must not import the
-#: repo-layout tools/ package; the value is contract-commented there).
+#: adverse relative move of B against A that flags (`--threshold`'s
+#: default).
 REL_FLOOR = 0.20
 
 #: counter -> the direction whose GAIN is adverse. "lower" = an increase
@@ -31,8 +30,7 @@ REL_FLOOR = 0.20
 #: renders with a loud `direction=?` marker (and fails ddtlint's
 #: counter-direction-missing rule) because an unknown direction silently
 #: exempts the counter from the gate. Unknown numeric counters are still
-#: reported, never flagged (benchwatch's unknown-metric rule: a guessed
-#: direction can invert the gate).
+#: reported, never flagged (a guessed direction can invert the gate).
 COUNTER_DIRECTIONS: dict[str, str] = {
     "jit_compiles": "lower",
     "jit_compile_seconds": "lower",
@@ -163,9 +161,9 @@ def diff_summaries(sa: dict, sb: dict, threshold: float = REL_FLOOR,
         # visible at the point of use, not just in the lint gate.
         rec = {"counter": key, "a": va, "b": vb, "flag": None,
                "direction": direction or "?"}
-        # A zero/absent baseline has no band to measure against — the
-        # benchwatch rule (metrics with no usable history are reported,
-        # never guessed at): a single-chip baseline's
+        # A zero/absent baseline has no band to measure against (a
+        # metric with no usable history is reported, never guessed at):
+        # a single-chip baseline's
         # collective_bytes_est=0 vs a pod run's N must not fail --check.
         # "neutral" (and unknown) directions are reported, never banded.
         if va and vb is not None and direction in ("lower", "higher"):

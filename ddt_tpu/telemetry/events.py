@@ -146,8 +146,7 @@ EVENT_FIELDS: dict[str, set] = {
     # lane dispatched without an admission window). Additive ISSUE 15
     # extra: `model_name` (the fleet tier emits one window per resident
     # model — `report`'s fleet rollup groups on it; absent on
-    # single-model logs). Consumed by `report`'s serving section and
-    # banded (via the bench stamps) by benchwatch.
+    # single-model logs). Consumed by `report`'s serving section.
     "serve_latency": {"requests", "p50_ms", "p99_ms"},
     # Serve-side request traces (ISSUE 17, schema-additive): one flushed
     # per-model ring of completed per-request timing breakdowns —

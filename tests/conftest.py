@@ -66,3 +66,9 @@ except (ImportError, OSError) as _pin_err:
         RuntimeWarning,
         stacklevel=1,
     )
+
+
+def pytest_configure(config):
+    # tier-1 runs `-m "not slow"`; the benchmark's tests
+    # (tests/test_benchmark_suite.py) carry the one such mark.
+    config.addinivalue_line("markers", "slow: drives a whole rehearsal run")

@@ -341,8 +341,8 @@ def test_cat_eval_set_device_path():
 
 
 def test_config3_partitioned_at_reduced_scale():
-    """Reduced-size twin of the config-3 at-scale witness
-    (experiments/config3_scale.py; PERF.md round-5): Criteo-shaped
+    """Reduced-size twin of the config-3 at-scale witness (1M rows on
+    the earlier host, round 5): Criteo-shaped
     categorical training over 4 row partitions upholds the scale
     contract (tree_compare.assert_prefix_identity_mod_ties — ONE home,
     shared with the witness): bitwise-identical tree prefix, any

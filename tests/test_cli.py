@@ -91,17 +91,6 @@ def test_cli_criteo_categoricals(tmp_path, capsys):
     ])
     assert rec["final_train_loss"] < 0.60  # ~25% CTR base rate entropy
 
-def test_cli_bench_histogram_cpu(capsys):
-    rec = _run(capsys, [
-        "bench", "--kernel=histogram", "--backend=cpu", "--rows=20000",
-        "--features=6", "--bins=31", "--iters=1",
-    ])
-    assert rec["kernel"] == "histogram"
-    assert rec["mrows_per_sec_per_chip"] > 0
-    assert rec["device_kind"] == "host"
-    assert rec["impl"] in ("native-c++", "numpy")
-
-
 def test_cli_fpga_backend_fails_loudly(tmp_path):
     with pytest.raises(NotImplementedError, match="FPGA"):
         main([

@@ -502,17 +502,3 @@ def test_roofline_comms_row():
     assert row["verdict"] == "overlapped"
     none = roofline_table(phases, cost, counters={}, wallclock_s=1.0)
     assert all(r["phase"] != "comms" for r in none)
-
-
-def test_bench_hist_comms_ab_smoke():
-    """The paired A/B arm runs on the CPU multi-device pod mesh (tier-1
-    twin of the chip-gated bench arm) and stamps the deterministic
-    payload ratio."""
-    from ddt_tpu.bench import bench_hist_comms_ab
-
-    out = bench_hist_comms_ab(rows=20_000, features=12, bins=31,
-                              depth=3, iters=1, reps=2)
-    assert out["kernel"] == "hist_comms_ab"
-    assert out["payload_ratio"] >= 2.0
-    assert out["mrows_rs"] > 0 and out["mrows_allreduce"] > 0
-    assert out["ratio_allreduce_over_rs"] > 0

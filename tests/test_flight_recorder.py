@@ -1,7 +1,6 @@
 """Distributed flight recorder (docs/OBSERVABILITY.md): per-partition
-attribution on a CPU mesh, the cross-host run-log merge, the Perfetto
-trace-event export, and the benchwatch regression sentinel. CPU
-platform, tier-1; the 8-virtual-device mesh comes from conftest."""
+attribution on a CPU mesh, the cross-host run-log merge and the Perfetto
+trace-event export. CPU platform, tier-1; the 8-virtual-device mesh comes from conftest."""
 
 import copy
 import json
@@ -353,17 +352,6 @@ def test_merge_hostless_v1_logs_stays_deterministic(tmp_path):
     assert key[0][1] == 0
 
 
-def test_benchwatch_unknown_current_fails_loudly(tmp_path, capsys):
-    paths = [_bench_artifact(tmp_path, i + 1, value=50.0)
-             for i in range(4)]
-    junk = tmp_path / "torn.json"
-    junk.write_text(json.dumps({"something": "else"}))
-    rep = benchwatch.run(paths, current_path=str(junk))
-    assert not rep["ok"] and "unrecognized" in rep["error"]
-    assert bw_main([*paths, "--current", str(junk)]) == 1
-    assert "ERROR" in capsys.readouterr().out
-
-
 def test_same_host_restart_still_two_segments(tmp_path):
     """A preemptible restart appends a second segment with the SAME
     config-deterministic run_id on the SAME host — that must stay two
@@ -461,132 +449,6 @@ def test_trace_cli_fails_loudly_on_garbage(tmp_path):
               str(tmp_path / "t.json")])
 
 
-# --------------------------------------------------------------------- #
-# benchwatch (tentpole part 4)
-# --------------------------------------------------------------------- #
-from tools import benchwatch  # noqa: E402
-from tools.benchwatch.__main__ import main as bw_main  # noqa: E402
-
-
-def _bench_artifact(tmp_path, n, **metrics):
-    rec = {"metric": "higgs1m_histogram_throughput", **metrics}
-    p = tmp_path / f"BENCH_r{n:02d}.json"
-    p.write_text(json.dumps({"n": n, "rc": 0, "parsed": rec}))
-    return str(p)
-
-
-def test_benchwatch_flags_30pct_histogram_regression(tmp_path):
-    vals = [55.0, 57.0, 56.3, 45.0, 47.9]
-    paths = [_bench_artifact(tmp_path, i + 1, value=v,
-                             e2e_train_s=12.0 + 0.1 * i)
-             for i, v in enumerate(vals)]
-    med = sorted(vals)[2]
-    bad = _bench_artifact(tmp_path, 6, value=round(med * 0.7, 2),
-                          e2e_train_s=12.2)
-    rep = benchwatch.run(paths, current_path=bad)
-    assert not rep["ok"]
-    names = [r["metric"] for r in rep["bench"]["regressions"]]
-    assert names == ["value"]
-    # the same history with an in-band current passes
-    good = _bench_artifact(tmp_path, 7, value=med, e2e_train_s=12.1)
-    assert benchwatch.run(paths, current_path=good)["ok"]
-
-
-def test_benchwatch_one_sided_and_direction_aware(tmp_path):
-    paths = [_bench_artifact(tmp_path, i + 1, value=50.0 + i,
-                             e2e_train_s=12.0)
-             for i in range(4)]
-    # pleasantly fast run (value up, time down) never fails
-    fast = _bench_artifact(tmp_path, 5, value=200.0, e2e_train_s=3.0)
-    assert benchwatch.run(paths, current_path=fast)["ok"]
-    # a LOWER-is-better metric regresses upward
-    slow = _bench_artifact(tmp_path, 6, value=51.0, e2e_train_s=30.0)
-    rep = benchwatch.run(paths, current_path=slow)
-    assert [r["metric"] for r in rep["bench"]["regressions"]] \
-        == ["e2e_train_s"]
-
-
-def test_benchwatch_schema_gates_redefined_metrics(tmp_path):
-    """A metric whose MEANING changed at a schema bump
-    (METRIC_MIN_SCHEMA) must not band against pre-bump history: the v2
-    e2e_implied_hist_mrows counts effective levels (~0.58x the v1
-    number at depth 6 with subtraction on), so a faster run would
-    otherwise flag as a regression. Same-schema banding still works."""
-    paths = [_bench_artifact(tmp_path, i + 1,
-                             e2e_implied_hist_mrows=50.0 + i)
-             for i in range(4)]                          # schema-1 history
-    # v2 current: ~0.6x the v1 median — semantics, not a regression.
-    cur = _bench_artifact(tmp_path, 5, bench_schema=2,
-                          e2e_implied_hist_mrows=30.0)
-    rep = benchwatch.run(paths, current_path=cur)
-    assert rep["ok"]
-    assert {"metric": "e2e_implied_hist_mrows", "history": 0} \
-        in rep["bench"]["skipped"]
-    # once schema-2 history accumulates, the band re-arms at the new
-    # meaning and a real regression inside it still trips.
-    paths2 = [_bench_artifact(tmp_path, 10 + i, bench_schema=2,
-                              e2e_implied_hist_mrows=30.0 + i)
-              for i in range(4)]
-    bad = _bench_artifact(tmp_path, 15, bench_schema=2,
-                          e2e_implied_hist_mrows=18.0)
-    rep = benchwatch.run(paths2, current_path=bad)
-    assert [r["metric"] for r in rep["bench"]["regressions"]] \
-        == ["e2e_implied_hist_mrows"]
-
-
-def test_benchwatch_skips_thin_history_never_guesses(tmp_path):
-    paths = [_bench_artifact(tmp_path, 1, value=50.0,
-                             predict_mrows_per_sec=2.7)]
-    cur = _bench_artifact(tmp_path, 2, value=49.0,
-                          predict_mrows_per_sec=0.1)
-    rep = benchwatch.run(paths, current_path=cur)
-    assert rep["ok"]
-    skipped = {s["metric"] for s in rep["bench"]["skipped"]}
-    assert {"value", "predict_mrows_per_sec"} <= skipped
-
-
-def test_benchwatch_multichip_failure_flags(tmp_path):
-    p = tmp_path / "MULTICHIP_r01.json"
-    p.write_text(json.dumps({"n_devices": 8, "rc": 1, "ok": False,
-                             "skipped": False, "tail": "boom"}))
-    rep = benchwatch.run([str(p)])
-    assert not rep["ok"]
-    assert rep["multichip"][0]["regressions"]
-    # a skipped run (no chips on this host) is not a regression
-    p.write_text(json.dumps({"n_devices": 0, "rc": 0, "ok": False,
-                             "skipped": True, "tail": ""}))
-    assert benchwatch.run([str(p)])["ok"]
-
-
-def test_benchwatch_passes_on_a_collected_history(tmp_path):
-    """The default collection (BENCH_r* + MULTICHIP_r* under a root) of a
-    history with ordinary spread passes the sentinel as-is."""
-    for i, v in enumerate([55.0, 57.0, 56.3, 45.0, 47.9]):
-        _bench_artifact(tmp_path, i + 1, value=v,
-                        e2e_train_s=13.8 - 0.5 * i)
-        (tmp_path / f"MULTICHIP_r{i + 1:02d}.json").write_text(json.dumps(
-            {"n_devices": 8, "rc": 0, "ok": True, "skipped": False,
-             "tail": "all 4 phases ok"}))
-    paths = benchwatch.collect_default_paths(str(tmp_path))
-    assert len(paths) == 10
-    rep = benchwatch.run(paths)
-    assert rep["ok"], rep
-    assert rep["bench"]["checked"], "no metric had banding history"
-
-
-def test_benchwatch_cli_exit_codes(tmp_path, capsys, monkeypatch):
-    paths = [_bench_artifact(tmp_path, i + 1, value=50.0)
-             for i in range(4)]
-    bad = _bench_artifact(tmp_path, 9, value=10.0)
-    assert bw_main([*paths, "--current", bad]) == 1
-    assert "REGRESSION value" in capsys.readouterr().out
-    assert bw_main(paths) == 0
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    monkeypatch.chdir(empty)
-    assert bw_main([]) == 2                   # nothing to check
-
-
 def test_trace_smoke_script():
     """`make trace-smoke` run in-process: mesh train -> merge -> export
     -> parse (tier-1-safe; conftest's 8-device mesh covers the 2 the
@@ -601,23 +463,8 @@ def test_trace_smoke_script():
 
 
 # --------------------------------------------------------------------- #
-# bench stamping (satellite) + host RSS (satellite)
+# host RSS (satellite)
 # --------------------------------------------------------------------- #
-def test_bench_artifact_stamping_fields():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "root_bench", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    rev = mod._git_rev()
-    assert rev is None or (isinstance(rev, str) and len(rev) >= 7)
-    assert isinstance(mod.BENCH_SCHEMA, int)
-    src = open(os.path.join(REPO, "bench.py"), encoding="utf-8").read()
-    for field in ('"run_id"', '"bench_schema"', '"git_rev"'):
-        assert field in src
-
-
 def test_host_rss_counter_recorded_and_rendered(tmp_path):
     from ddt_tpu.telemetry import counters as tele_counters
 

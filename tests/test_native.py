@@ -1,7 +1,7 @@
 """Native C++ kernel parity vs the NumPy oracle (exact — same f32 op order).
 
-The native kernels are the honest CPU-reference baseline for the bench
-(BASELINE.md); skipped cleanly when the toolchain can't build them.
+The native kernels are the honest CPU-reference baseline (BASELINE.md);
+skipped cleanly when the toolchain can't build them.
 """
 
 import numpy as np

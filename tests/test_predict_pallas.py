@@ -437,6 +437,8 @@ def test_ensemble_span_says_which_form_served(impl, T, want):
     root = an.root_spans("predict")[-1]
     counts = {s["name"]: s for s in root["spans"]}[
         "ddt:predict:ensemble"]["counts"]
+    # The span carries the plan by the kernel module's one list of names.
+    assert list(counts) == ["bytes", *jpp.SPAN_COUNTS]
     assert counts["tree_group"] == want
     # The table plan rides on the same span: these small models are one
     # resident block of all their groups, and nothing streams.

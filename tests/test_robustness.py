@@ -627,37 +627,6 @@ def test_no_plan_no_overhead_guard(monkeypatch, tmp_path):
     assert ens.n_trees == 2
 
 
-def test_benchwatch_excludes_injected_fault_artifacts(tmp_path):
-    """Chaos artifacts never band: not as history, not as current."""
-    from tools import benchwatch
-
-    hist_vals = [50.0, 52.0, 48.0, 51.0]
-    paths = []
-    for i, v in enumerate(hist_vals):
-        p = tmp_path / f"BENCH_r{i:02d}.json"
-        p.write_text(json.dumps({"n": i, "parsed": {
-            "metric": "m", "value": v, "bench_schema": 2}}))
-        paths.append(str(p))
-    # A chaos run with an absurd number in history must not poison bands.
-    pc = tmp_path / "BENCH_r04.json"
-    pc.write_text(json.dumps({"n": 4, "parsed": {
-        "metric": "m", "value": 5.0, "bench_schema": 2,
-        "injected_faults": True}}))
-    paths.append(str(pc))
-    cur = tmp_path / "fresh.json"
-    cur.write_text(json.dumps({"metric": "m", "value": 49.0,
-                               "bench_schema": 2}))
-    rep = benchwatch.run(paths, current_path=str(cur))
-    assert rep["ok"], rep
-    assert str(pc) in rep["excluded_injected"]
-    banded = {c["metric"]: c for c in rep["bench"]["checked"]}
-    assert banded["value"]["n_history"] == 4     # chaos run not counted
-    # And a chaos CURRENT is excluded, not banded.
-    rep2 = benchwatch.run(paths[:-1], current_path=str(pc))
-    assert rep2["ok"]
-    assert rep2["bench"].get("skipped_injected")
-
-
 def test_atomic_save_model_and_ensemble(tmp_path):
     """api.save_model / TreeEnsemble.save leave no torn artifact and
     keep numpy's .npz suffixing semantics."""

@@ -11,9 +11,9 @@ dataset device-side would show up too (it would add ~+240 MB binned /
 +960 MB float between the runs); the device chunk cache is explicitly
 OFF in the worker for the same reason.
 
-The full-size measured run (20M x 64 on the real chip, throughput +
-peak RSS) lives in experiments/stream_scale.py with results in
-docs/PERF.md.
+The full-size runs (20M and 50M x 64: throughput, and peak RSS flat in
+the row count) were measured on the earlier host, rounds 3-5, and are
+not re-measured on this chip.
 """
 
 import json

@@ -42,8 +42,7 @@ def assert_trees_match_mod_ties(full, streamed, min_split_gain,
         rarity cap is calibrated for the fuzz suites' scales;
         million-row witnesses pass explicit `max_root_causes`
         (boundary-tie incidence grows with row count — the config-3
-        witness, experiments/config3_scale.py, documents the measured
-        rates).
+        witness on the earlier host, round 5, measured the rates).
 
     Leaf values pass when EITHER bound holds: the relative/absolute
     allclose (leaf_rtol/leaf_atol), or a pred-CONTRIBUTION bound
@@ -142,8 +141,9 @@ def assert_prefix_identity_mod_ties(ens_a, ens_b, min_split_gain,
                                     leaf_rtol=1e-3, leaf_atol=1e-5,
                                     max_root_causes=4):
     """The at-scale cross-partition identity contract (ONE home — the
-    config-3 witness, experiments/config3_scale.py, and its reduced-size
-    suite twin must assert the SAME thing):
+    config-3 witness of the earlier host, round 5, asserted it at 1M
+    rows over 4 partitions; its reduced-size suite twin asserts the
+    SAME thing):
 
       - every tree BEFORE the first structural divergence is bitwise
         identical in its decisions AND carries equivalent leaf values

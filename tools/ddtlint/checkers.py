@@ -712,7 +712,7 @@ class RawPhaseTimingChecker(Checker):
     `ddt:` scopes + the cost observatory (telemetry/costmodel.py), not a
     clock.  The trainer loops (driver/streaming — PhaseTimer's
     consumers), the timing subsystem itself, the shard-readiness probe
-    (parallel/mesh.py), bench harnesses, cli, and tests are all outside
+    (parallel/mesh.py), the benchmark, cli, and tests are all outside
     the scope: their clocks ARE the instrument.  time.sleep and the time
     module's non-clock helpers are not flagged."""
 
