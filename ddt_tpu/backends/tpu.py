@@ -1567,7 +1567,7 @@ class TPUDevice(DeviceBackend):
         request pays upload + dispatch only (docs/SERVING.md).
 
         Every call is one root span `ddt:predict` with a child span per
-        step (token, ensemble, upload, dispatch, fetch, concat: the
+        step (token, ensemble, upload, dispatch, fetch, place: the
         table is in docs/OBSERVABILITY.md); the spans time the host's
         side and add no sync."""
         with phase_span("predict", rows=int(Xb.shape[0])) as root:
@@ -1635,15 +1635,18 @@ class TPUDevice(DeviceBackend):
             tele_counters.record_d2h(out.nbytes)
             return out[:R]
         # Single chip: upload the whole batch ONCE (uint8 — 4x less
-        # host→device traffic than int32), slice chunks on device, and
-        # OVERLAP each chunk's device→host score fetch with the later
-        # chunks' compute: async dispatch keeps the device busy while
-        # finished chunks stream back, so the link and the chip pay their
-        # costs concurrently instead of back-to-back. What is left
-        # exposed on the chip is the fetch tail, the last device
-        # operation's end to the call's return: 395 ms of a 22.9 s
-        # 100M-row call, of which the last chunk's fetch 0.2 ms and the
-        # np.concatenate 394 ms (PERF.md section 5, PR 25).
+        # host→device traffic than int32) and slice chunks on device.
+        # Each chunk's device→host copy is started as the chunk is
+        # dispatched, so finished chunks stream back while later ones
+        # compute, and each lands in its rows of ONE result array
+        # (`place`) as soon as the host holds it: the copies and the
+        # first touch of the result's pages run under the device's work.
+        # What is left exposed on the chip is the fetch tail, the last
+        # device operation's end to the call's return, which is the last
+        # chunk's D2H and its place: 1.9 ms of a 6.05 s 100M-row call
+        # and 36 ms of a 19.75 s 30M-row x 7-class call, where one
+        # np.concatenate after the loop took 386 and 779 ms (PERF.md
+        # section 5, PR 30).
         counts["branch"] = "chunks"
         resident = isinstance(Xb, jax.Array)
         with phase_span("predict:upload",
@@ -1655,20 +1658,23 @@ class TPUDevice(DeviceBackend):
         for k, i in enumerate(starts):
             with phase_span("predict:dispatch", chunk=k):
                 outs.append(fn(*ens_dev, Xd[i:i + chunk]))
-        parts = []
-        for k, o in enumerate(outs):
-            with phase_span("predict:fetch", chunk=k) as sp:
-                if k == 0:              # start all D2H copies in flight
-                    for later in outs:
-                        later.copy_to_host_async()
-                # Not a per-iter sync: the copies are already in flight
+                outs[-1].copy_to_host_async()
+        # Shape and dtype are the dispatched arrays' (no sync). The places
+        # below touch the result's pages for the first time, under the
+        # device's work; the last chunk's place is the one nothing hides,
+        # so its pages are touched here, while the device is still busy.
+        out = np.empty((R,) + outs[0].shape[1:], outs[0].dtype)
+        out[starts[-1]:] = 0
+        for k, (i, o) in enumerate(zip(starts, outs)):
+            with phase_span("predict:fetch", chunk=k, bytes=o.nbytes):
+                # Not a per-iter sync: the copy is already in flight
                 # (copy_to_host_async above); asarray only materialises.
-                parts.append(np.asarray(o))  # ddtlint: disable=host-sync
-                sp.counts["bytes"] = parts[-1].nbytes
-        with phase_span("predict:concat") as sp:
-            out = np.concatenate(parts)[:R]
-            sp.counts["bytes"] = out.nbytes
-        tele_counters.record_d2h(sum(p.nbytes for p in parts))
+                part = np.asarray(o)    # ddtlint: disable=host-sync
+            # A span of its own: the benchmark's clock anchor is "fetch[k]
+            # ends when the host holds chunk k", and no later.
+            with phase_span("predict:place", chunk=k, bytes=o.nbytes):
+                out[i:i + chunk] = part     # a chunk scores its own rows
+        tele_counters.record_d2h(out.nbytes)
         return out
 
     @functools.cached_property

@@ -96,7 +96,7 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     """Host milliseconds of each step of the device scoring calls whose
     root span `ddt:predict` started at or after `since_ns`
     (time.perf_counter_ns): token, ensemble, upload, dispatch, fetch,
-    concat, and with them counts that are no times: the traversal
+    place, and with them counts that are no times: the traversal
     kernel's table plan as the model's `ensemble` span recorded it
     (ops/predict_pallas.PHASES_COUNTS; all 0: the kernel does not serve
     the model) and `tables_streamed_bytes`, the root spans' sum over the
@@ -111,7 +111,7 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     from ddt_tpu.ops.predict_pallas import PHASES_COUNTS
 
     ms = dict.fromkeys(
-        ("token", "ensemble", "upload", "dispatch", "fetch", "concat"), 0.0)
+        ("token", "ensemble", "upload", "dispatch", "fetch", "place"), 0.0)
     plan = dict.fromkeys(PHASES_COUNTS)
     for r in roots:
         for s in r["spans"]:

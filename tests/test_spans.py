@@ -140,10 +140,12 @@ def test_an_empty_span_costs_microseconds():
 BRANCHES = {
     # branch: (partitions, PREDICT_ROW_CHUNK, rows,
     #          {span: how many of it}, chunks)
-    "one": (1, 4096, 1000, dict(upload=1, dispatch=1, fetch=1, concat=0), 1),
-    "chunks": (1, 256, 1000, dict(upload=1, dispatch=4, fetch=4, concat=1),
-               4),
-    "mesh": (4, 64, 1000, dict(upload=4, dispatch=4, fetch=1, concat=0), 4),
+    "one": (1, 4096, 1000,
+            dict(upload=1, dispatch=1, fetch=1, place=0, concat=0), 1),
+    "chunks": (1, 256, 1000,
+               dict(upload=1, dispatch=4, fetch=4, place=4, concat=0), 4),
+    "mesh": (4, 64, 1000,
+             dict(upload=4, dispatch=4, fetch=1, place=0, concat=0), 4),
 }
 
 
@@ -178,7 +180,7 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
         if s["id"] != root["id"]:
             assert (s["cause"], s["root"]) == (root["id"], root["id"])
             assert root["start"] <= s["start"] <= s["end"] <= root["end"]
-    for name in ("dispatch", "fetch"):
+    for name in ("dispatch", "fetch", "place"):
         assert [s["counts"]["chunk"] for s in kids["ddt:predict:" + name]] \
             == list(range(want[name]))
     # bytes where the work happens, and the same bytes on the counters
@@ -188,8 +190,9 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
         == Xb.nbytes
     assert sum(s["counts"]["bytes"] for s in kids["ddt:predict:fetch"]) \
         == scores.nbytes
-    for s in kids["ddt:predict:concat"]:
-        assert s["counts"]["bytes"] == scores.nbytes
+    if want["place"]:       # every chunk lands once, and nothing else does
+        assert sum(s["counts"]["bytes"] for s in kids["ddt:predict:place"]) \
+            == scores.nbytes
     assert moved["h2d_bytes"] == Xb.nbytes + ens_bytes
     assert moved["d2h_bytes"] == scores.nbytes
     np.testing.assert_allclose(scores, ens.predict_raw(Xb, binned=True),
@@ -207,6 +210,59 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
     assert (moved["h2d_bytes"], moved["d2h_bytes"]) \
         == (Xb.nbytes, scores.nbytes)
     np.testing.assert_array_equal(scores, again)
+
+
+# The chunk loop's result against its chunks scored one call each (the
+# `one` branch): rows a whole number of chunks and rows with a remainder
+# chunk, one class and seven, host rows and rows already on the device.
+CHUNK_CASES = [(rows, classes, resident)
+               for rows in (768, 1000)
+               for classes in (1, 7)
+               for resident in (False, True)]
+
+
+@pytest.mark.parametrize(
+    "rows,classes,resident", CHUNK_CASES,
+    ids=[f"{r}rows-{c}class-{'device' if d else 'host'}"
+         for r, c, d in CHUNK_CASES])
+def test_the_chunk_loop_places_every_chunk_in_one_array(
+        rows, classes, resident, monkeypatch):
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31))
+    ens = _rand_ensemble(seed=1300 + classes, T=3 * classes)
+    if classes > 1:
+        ens.loss, ens.n_classes = "softmax", classes
+    Xb = np.random.default_rng(rows + classes).integers(
+        0, 31, size=(rows, 6), dtype=np.uint8)
+    # every chunk by itself, while a chunk is still the whole batch
+    per_chunk = [be.predict_raw(ens, Xb[i:i + 256])
+                 for i in range(0, rows, 256)]
+    assert an.root_spans("predict")[-1]["counts"]["branch"] == "one"
+
+    monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", 256)
+    scores = be.predict_raw(ens, jax.device_put(Xb) if resident else Xb)
+    root = an.root_spans("predict")[-1]
+    assert root["counts"]["branch"] == "chunks"
+    assert root["counts"]["chunks"] == len(per_chunk)
+    assert root["counts"]["classes"] == classes
+
+    assert type(scores) is np.ndarray and scores.dtype == np.float32
+    assert scores.shape == ((rows,) if classes == 1 else (rows, classes))
+    assert scores.flags.c_contiguous and scores.flags.owndata
+    assert scores.flags.writeable
+    np.testing.assert_array_equal(scores, np.concatenate(per_chunk))
+
+    # The benchmark's clock anchor: fetch[k] ends when the host holds
+    # chunk k, and the copy into the result comes after, a span of its own.
+    kids = _by_name(root)
+    fetch, place = kids["ddt:predict:fetch"], kids["ddt:predict:place"]
+    assert len(fetch) == len(place) == len(per_chunk)
+    assert "ddt:predict:concat" not in kids
+    for k, (f, p, part) in enumerate(zip(fetch, place, per_chunk)):
+        assert f["counts"]["chunk"] == p["counts"]["chunk"] == k
+        assert f["counts"]["bytes"] == p["counts"]["bytes"] == part.nbytes
+        assert f["end"] <= p["start"]
+        if k:               # chunk k is fetched after chunk k - 1 landed
+            assert place[k - 1]["end"] <= f["start"]
 
 
 def test_a_device_resident_batch_uploads_nothing(monkeypatch):
@@ -289,7 +345,7 @@ def test_cli_predict_prints_phases_ms(tmp_path, capsys):
     for count in (*PHASES_COUNTS, "tables_streamed_bytes"):
         assert rec["phases_ms"].pop(count) == 0
     assert sorted(rec["phases_ms"]) == sorted(
-        ["token", "ensemble", "upload", "dispatch", "fetch", "concat"])
+        ["token", "ensemble", "upload", "dispatch", "fetch", "place"])
     assert all(v >= 0 for v in rec["phases_ms"].values())
     assert rec["phases_ms"]["upload"] > 0 and rec["phases_ms"]["fetch"] > 0
     assert sum(rec["phases_ms"].values()) <= rec["wallclock_s"] * 1e3
