@@ -11,6 +11,9 @@ hundred. Then all 1M binned rows are scored with the ten trees. And one
 ensemble of Covertype's own shape (500 rounds x 7 classes, depth 8, 54
 features: random trees, the scorer does not care) scores 100,000 rows through
 the same `api.predict`, its node tables streamed by blocks of tree groups.
+And one of the CTR model's shape (100 trees, depth 6, 39 columns: 13 numeric
+with a NaN bin and learned directions, 26 categorical split one-vs-rest)
+scores 1,000,000 rows, served by the ROUTED form of the traversal kernel.
 
 It asserts WHAT ran (the Pallas kernels, compiled: `tpu_custom_call` in both
 lowered programs; histogram resolved to `pallas`, sibling subtraction on; no
@@ -50,6 +53,7 @@ ROWS, FEATURES, BINS, DEPTH, ROUNDS = 1_000_000, 28, 255, 6, 10
 SEED = 42
 SCORE_CHECK_ROWS = 50_000
 MC_ROUNDS, MC_ROWS = 500, 100_000      # the 7-class scoring phase
+ROUTED_ROWS = 1_000_000                # the routed scoring phase
 SCORE_TOL = dict(rtol=3e-4, atol=3e-4)      # as __graft_entry__'s oracle check
 # Chip-vs-oracle training parity: the bounds the earlier chip runs measured
 # inside (0.9871 agreement, 0.0024 AUC). Never bitwise
@@ -196,21 +200,36 @@ def check_scores_against_numpy(ens, Xb, scores) -> None:
         f"{float(np.abs(scores[:n] - want).max()):.2e}")
 
 
+def assert_compiled_kernel(cfg, ens, rows: int, what: str) -> None:
+    """On the chip: the program that scores `ens` carries a compiled Pallas
+    kernel (a CPU lowers none)."""
+    import jax
+
+    from ddt_tpu.backends import get_backend
+    from ddt_tpu.utils import device
+
+    if device.platform() != "tpu":
+        return
+    be = get_backend(cfg)
+    fn, ens_dev = be._predict_fn(ens)
+    x_spec = jax.ShapeDtypeStruct((rows, ens.n_features), np.uint8,
+                                  sharding=be._row_sharding(extra_dims=1))
+    assert "tpu_custom_call" in jax.jit(fn).lower(
+        *ens_dev, x_spec).as_text(), \
+        f"{what} scoring program carries no compiled Pallas kernel"
+
+
 def score_multiclass(overrides: dict, rows: int) -> None:
     """Covertype's own ensemble shape through `api.predict`: 3,500 random
     full trees (500 rounds x 7 classes) of depth 8 over 54 features. Asserts
     that the traversal kernel served it by the auto dispatch, its tables
     streamed in more than one block, and holds every class column to the
     plain reference (reference/numpy_predict, in float64) on the first rows."""
-    import jax
-
     from ddt_tpu import api
-    from ddt_tpu.backends import get_backend
     from ddt_tpu.config import TrainConfig
     from ddt_tpu.models.tree import empty_ensemble
     from ddt_tpu.reference import numpy_predict
     from ddt_tpu.telemetry.annotations import root_spans
-    from ddt_tpu.utils import device
 
     T, depth, F, C = MC_ROUNDS * 7, 8, 54, 7
     rng = np.random.default_rng(SEED)
@@ -244,14 +263,7 @@ def score_multiclass(overrides: dict, rows: int) -> None:
     assert built["groups_per_step"] < built["table_groups"], \
         "the node tables did not stream"
     assert root["counts"]["tables_streamed_bytes"] > 0
-    if device.platform() == "tpu":
-        be = get_backend(cfg)
-        fn, ens_dev = be._predict_fn(ens)
-        x_spec = jax.ShapeDtypeStruct((rows, F), np.uint8,
-                                      sharding=be._row_sharding(extra_dims=1))
-        assert "tpu_custom_call" in jax.jit(fn).lower(
-            *ens_dev, x_spec).as_text(), \
-            "7-class scoring program carries no compiled Pallas kernel"
+    assert_compiled_kernel(cfg, ens, rows, "7-class")
     n = min(2_000, rows)
     # float64 in the reference: summed in float32 tree by tree, 500 terms
     # a class, its own rounding is most of the gap (6.7e-6 of the 1e-5 on
@@ -261,6 +273,62 @@ def score_multiclass(overrides: dict, rows: int) -> None:
     say(f"7-class scores: {n} rows x {C} classes against "
         f"reference/numpy_predict (float64), max |diff| = {gap:.2e} "
         "(<= 1e-5)")
+    assert gap <= 1e-5, gap
+
+
+def score_routed(overrides: dict, rows: int) -> None:
+    """The CTR model's shape through `api.predict`: 100 random full trees
+    of depth 6 over 39 columns, 13 numeric with a NaN bin and a learned
+    direction a node, 26 categorical split one-vs-rest. Asserts that the
+    ROUTED form of the traversal kernel served it by the auto dispatch
+    (both routing tables, one node a weight tile) and holds a sample of
+    rows to the NumPy oracle, `TreeEnsemble.predict_raw`."""
+    from ddt_tpu import api
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.models.tree import empty_ensemble
+    from ddt_tpu.telemetry.annotations import root_spans
+
+    T, depth, F, numeric = 100, 6, 39, 13
+    rng = np.random.default_rng(SEED)
+    ens = empty_ensemble(T, depth, F, 0.1, 0.0, "logloss", missing_bin=True,
+                         n_bins=BINS, cat_features=tuple(range(numeric, F)))
+    n_int = 2 ** depth - 1
+    ens.feature[:, :n_int] = rng.integers(0, F, (T, n_int))
+    ens.threshold_bin[:, :n_int] = rng.integers(0, BINS - 2, (T, n_int))
+    ens.default_left[:, :n_int] = rng.random((T, n_int)) < 0.5
+    ens.is_leaf[:, n_int:] = True
+    ens.leaf_value[:, n_int:] = rng.standard_normal((T, n_int + 1))
+    # Bins 0..253 everywhere, then a fifth of the numeric cells missing.
+    Xb = rng.integers(0, BINS - 1, size=(rows, F), dtype=np.uint8)
+    Xb[:, :numeric][rng.random((rows, numeric)) < 0.2] = BINS - 1
+    cfg = TrainConfig(n_bins=BINS, backend="tpu", **overrides)
+    comp = Compiles()
+    t0 = time.perf_counter()
+    scores = api.predict(ens, Xb, binned=True, raw=True, cfg=cfg)
+    wall = time.perf_counter() - t0
+    timing(f"routed predict, {rows} rows x {T} trees x depth {depth} x {F} "
+           "features, first call", wall=wall, **comp.split(wall))
+    assert scores.shape == (rows,) and scores.dtype == np.float32, \
+        (scores.shape, scores.dtype)
+    assert np.isfinite(scores).all(), "non-finite scores"
+    root = root_spans("predict")[-1]
+    built = {s["name"]: s["counts"] for s in root["spans"]}[
+        "ddt:predict:ensemble"]
+    say(f"routed predict: ddt:predict:ensemble {built}; root "
+        f"routing_tables={root['counts']['routing_tables']}")
+    assert built["tree_group"] == 128, "the traversal kernel did not serve"
+    assert (built["trees"], built["table_groups"]) == (T, 1), built
+    assert built["routing_tables"] == root["counts"]["routing_tables"] == 2, \
+        "the kernel does not route by both tables"
+    assert (built["nodes_per_tile"], built["mxu_tiles_per_group"]) == (
+        1, n_int), built
+    assert_compiled_kernel(cfg, ens, rows, "routed")
+    n = min(SCORE_CHECK_ROWS, rows)
+    want = ens.predict_raw(Xb[:n], binned=True)      # NumPy traversal
+    # Both sides sum 100 leaf values x 0.1 in float32, in their own order.
+    gap = float(np.abs(scores[:n] - want).max())
+    say(f"routed scores: {n} rows against TreeEnsemble.predict_raw (NumPy), "
+        f"max |diff| = {gap:.2e} (<= 1e-5)")
     assert gap <= 1e-5, gap
 
 
@@ -444,6 +512,8 @@ def main(argv=None) -> int:
     ens, scores, be = train_and_score(cfg, Xb, y, "one device")
     check_scores_against_numpy(ens, Xb, scores)
     score_multiclass(overrides, MC_ROWS // 100 if args.rehearse else MC_ROWS)
+    score_routed(overrides, ROUTED_ROWS // 100 if args.rehearse
+                 else ROUTED_ROWS)
     parity_against_reference(overrides)
     barrier_experiment(be, Xb)
     if count >= 4:

@@ -1544,6 +1544,13 @@ class TPUDevice(DeviceBackend):
     # 10M-row x 1000-tree config [BASELINE] OOM-kills the chip if scored in
     # one dispatch. 2M rows/chip/call keeps the peak well under 1 GB.
     PREDICT_ROW_CHUNK = 2_000_000
+    # Chunks a piece of the single-chip big-batch upload (_predict_raw):
+    # the first piece's transfer is all of the upload a call exposes (46
+    # ms of 156 MB at 2, 88 ms at 5, and it varies by as much less; 31 ms
+    # since that piece goes up flat, see _predict_raw), a
+    # piece's fixed cost (about 18 ms) is hidden under the compute, and a
+    # piece of more than one chunk keeps the device slices, so the peak.
+    PREDICT_UPLOAD_CHUNKS = 2
     # Device-resident CompiledEnsemble slots per backend instance: each
     # entry pins the model's pushed-down node tables on device (~MBs for a
     # 1000-tree model) across predict calls. Small because backend
@@ -1599,6 +1606,9 @@ class TPUDevice(DeviceBackend):
         # the call: every table block once a row tile where they stream,
         # 0 where one block holds them all (fetched once, resident).
         counts["tables_streamed_bytes"] = 0
+        # Which form of that kernel serves: the missing and categorical
+        # tables it routes by (0 also when it does not serve).
+        counts["routing_tables"] = plan.routing_tables
         if plan.blocks > 1:
             shards = max(1, self.row_shards)
             shard_rows = [-(-min(chunk, R - i) // shards) for i in starts]
@@ -1634,8 +1644,22 @@ class TPUDevice(DeviceBackend):
                 sp.counts["bytes"] = out.nbytes
             tele_counters.record_d2h(out.nbytes)
             return out[:R]
-        # Single chip: upload the whole batch ONCE (uint8 — 4x less
-        # host→device traffic than int32) and slice chunks on device.
+        # Single chip: the batch goes up ONCE (uint8 — 4x less
+        # host→device traffic than int32), in pieces of
+        # PREDICT_UPLOAD_CHUNKS chunks, and chunks are sliced on device.
+        # A piece's transfer starts when the chunks of the piece before
+        # it have been dispatched and that piece has arrived, so it runs
+        # under their compute, and only the first piece's is exposed: 46
+        # ms of a 3.9 GB batch where one device_put of it took 644-888
+        # ms before the first chunk could start, and a call's wall
+        # varied by all of that (PERF.md sections 5 and 6, PR 31). Two
+        # forms that lost there: every piece issued before the loop (the
+        # device takes transfers and programs in the host's issue order,
+        # so the first chunk waited for them all, 414 ms), and pieces
+        # issued without waiting for the one before (two transfers share
+        # the host's relayout threads: 110-220 ms for the first). The
+        # pieces stay until the call returns and sum to the batch, so
+        # the call's peak memory is what the whole batch's was.
         # Each chunk's device→host copy is started as the chunk is
         # dispatched, so finished chunks stream back while later ones
         # compute, and each lands in its rows of ONE result array
@@ -1649,16 +1673,40 @@ class TPUDevice(DeviceBackend):
         # section 5, PR 30).
         counts["branch"] = "chunks"
         resident = isinstance(Xb, jax.Array)
-        with phase_span("predict:upload",
-                        bytes=0 if resident else Xb.nbytes):
-            Xd = Xb if resident else jax.device_put(np.ascontiguousarray(Xb))
+        piece = R if resident else chunk * self.PREDICT_UPLOAD_CHUNKS
+        Xh = Xb if resident else np.ascontiguousarray(Xb)
+
+        def upload(p):
+            if resident:
+                with phase_span("predict:upload", piece=p, bytes=0):
+                    return Xb
+            part = Xh[p * piece:(p + 1) * piece]
+            with phase_span("predict:upload", piece=p, bytes=part.nbytes):
+                if p:   # one transfer at a time; the device has p - 1's chunks
+                    pieces[-1].block_until_ready()  # ddtlint: disable=host-sync
+                    return jax.device_put(part)
+                # The exposed piece goes up as flat bytes and takes its
+                # shape on the device: a 1-D array needs no relayout on
+                # the host, whose threads' share of the machine's cores is
+                # what a call's wall varied by (156 MB: 30.6 ms, sd 0.56,
+                # the device's reshape included, where the 2-D
+                # device_put took 34.7, sd 1.99; the rest of a call 1,698
+                # ms, sd 1.0). Later pieces keep the host relayout: the
+                # compute hides it, and the reshape would be device time.
+                return jnp.reshape(jax.device_put(part.reshape(-1)),
+                                   part.shape)
+
+        pieces = [upload(0)]
         if not resident:
             tele_counters.record_h2d(Xb.nbytes)
         outs = []
         for k, i in enumerate(starts):
+            at = i % piece
             with phase_span("predict:dispatch", chunk=k):
-                outs.append(fn(*ens_dev, Xd[i:i + chunk]))
+                outs.append(fn(*ens_dev, pieces[-1][at:at + chunk]))
                 outs[-1].copy_to_host_async()
+            if at + chunk == piece and i + chunk < R:
+                pieces.append(upload(len(pieces)))
         # Shape and dtype are the dispatched arrays' (no sync). The places
         # below touch the result's pages for the first time, under the
         # device's work; the last chunk's place is the one nothing hides,
@@ -1809,6 +1857,9 @@ class TPUDevice(DeviceBackend):
             fn, ens_dev, resolved, classes, plan = self._build_predict_fn(
                 ens, compiled)
             sp.counts["bytes"] = sum(a.nbytes for a in ens_dev)
+            # Beside table_groups: the share of the groups' lanes that
+            # hold a tree (100 of 128).
+            sp.counts["trees"] = ens.n_trees
             sp.counts.update(plan.span_counts())
         self._predict_cache[token] = hit = (fn, ens_dev, classes, plan)
         self._predict_impl_resolved[token] = resolved

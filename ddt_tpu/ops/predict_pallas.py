@@ -76,7 +76,12 @@ the VPU, where reading a byte out of the word (a shift and a mask) costs
 more than the MXU results saved. Three nodes a tile (a node with its two
 children, 24 bits, no room for the mantissa trick) lost to two on the v5e:
 3,082 cycles a depth-6 step of 256 rows x 128 trees against 2,696, one node
-a tile 4,654 (PERF.md sections 5 and 6, PR 28).
+a tile 4,654 (PERF.md sections 5 and 6, PR 28). The routed form's step
+(both tables, one node a tile, depth 6): 5,678 cycles in PR 28's probe at 28
+features and 8 groups a grid step; 5,649 cycles measured through
+`api.predict` at 39 features with ONE group a grid step, the CTR model's
+100 trees, where a grid step's own cost is not shared (PERF.md section 5,
+PR 31).
 
 Per tree group of the block (static Python loop, traced once), per weight
 tile (depth-first, at the parent of its nodes):
@@ -167,6 +172,18 @@ TREE_GROUP = 128
 # more: 30.0 with the missing table alone at depth 6, 26.4 with both;
 # one more reason they keep one node a tile). 12 KiB a row, and 192 B a
 # node more with both, bound every probe by an eighth or more.
+# What it keeps is kept for TWO groups and no more (compile check, PR 31;
+# the same probe at 39 features, depth 6, both tables, scoped MiB at tile
+# 256 without the row tile's windows): 1 group a block 2.09, 2 / 3 / 4 / 8
+# groups 4.08 / 3.96 / 4.34 / 4.34 (28 features: 2.09, then 4.09 / 3.96 /
+# 4.25 / 4.25); the missing table alone 1.09, 1.32, 1.34 at 1, 4, 8 groups.
+# So both tables cost 65 B a row and node in a block of one group (the CTR
+# model's 100 trees: 10.4 KiB a row with the windows, under _ROW_BYTES
+# alone) and 190 B from two groups on: the compiler holds the next group's
+# routing planes while this group's mux tree finishes, and never a third
+# group's. Depth 4 / 5 / 7 with both: 1 group under 1.0 / 1.48 / 3.24, 4
+# groups 2.12 / 3.32 / 6.07. Why it does so for the three-way select and
+# not for one table is still open; the count below stays the bound.
 _ROW_BYTES = 12 * 1024
 _ROW_NODE_BYTES_BOTH = 192
 # Rows (K) of one MXU weight tile.
@@ -242,6 +259,7 @@ class TablePlan(typing.NamedTuple):
     tile_rows: int         # rows a tile
     nodes_per_tile: int    # P: nodes that share one MXU weight tile
     mxu_tiles_per_group: int   # weight tiles a group costs a row tile
+    routing_tables: int    # the missing and categorical tables it carries
 
     @property
     def tree_group(self) -> int:
@@ -260,7 +278,8 @@ class TablePlan(typing.NamedTuple):
 # `table_bytes` is one walk of the blocks, and a call's whole re-read is
 # the root span's `tables_streamed_bytes`, which `phases_ms` has instead.
 SPAN_COUNTS = ("tree_group", "table_groups", "groups_per_step",
-               "table_bytes", "nodes_per_tile", "mxu_tiles_per_group")
+               "table_bytes", "nodes_per_tile", "mxu_tiles_per_group",
+               "routing_tables")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 # This kernel does not serve the model (the one-hot path, the LUT tiers).
 NO_PLAN = TablePlan(*(0,) * len(TablePlan._fields))
@@ -294,7 +313,8 @@ def table_plan(
         most += 1
     packing = (nodes_per_tile(n_features, optional_operands),
                mxu_tiles_per_group(max_depth, n_features,
-                                   optional_operands))
+                                   optional_operands),
+               optional_operands)
     if most == 0:
         return TablePlan(n_tg, 0, 0, 0, tile_r, *packing)
     blocks = -(-n_tg // most)

@@ -43,6 +43,8 @@ TOPOLOGY = "v5e:2x2"
 
 HIGGS = dict(rows=1_000_000, features=28)
 COVERTYPE = dict(rows=200_000, features=54, classes=7)
+# The CTR model's scoring chunk (benchmark config criteo-ctr-100t-d6).
+CRITEO = dict(rows=2_000_000, features=39)
 
 
 class KernelCase(typing.NamedTuple):
@@ -195,6 +197,11 @@ def kernel_cases() -> list:
                    _predict_case(hr, hf, 150, 6, missing=True, cat=True)),
         KernelCase("predict/higgs/1000x6/missing+cat", True,
                    _predict_case(hr, hf, 1000, 6, missing=True, cat=True)),
+        # The CTR model: ONE group of which 100 lanes hold a tree, both
+        # routing tables, over 39 columns and a whole scoring chunk.
+        KernelCase("predict/criteo/100x6/missing+cat", True,
+                   _predict_case(CRITEO["rows"], CRITEO["features"], 100, 6,
+                                 missing=True, cat=True)),
         # Two nodes a weight tile (F <= 64 and no routing table: 28
         # features with 4 K rows between the copies, 54 with 2), at
         # Covertype's own size: 28 groups in 4 blocks of 7, 128 weight
@@ -287,7 +294,7 @@ def _rounds_program(topo_devices, *, rows, features, n_rounds, mesh_shape,
 
 
 def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
-                     n_classes=1):
+                     n_classes=1, routed=False):
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -296,8 +303,8 @@ def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
     from ddt_tpu.config import TrainConfig
 
     be = TPUDevice(TrainConfig(backend="tpu", max_depth=depth))
-    ens = _random_ensemble(n_trees, depth, features, n_classes, False,
-                           False)
+    ens = _random_ensemble(n_trees, depth, features, n_classes, routed,
+                           routed)
     fn, ens_dev = be._predict_fn(ens)
     one = SingleDeviceSharding(topo_devices[0])
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
@@ -328,11 +335,13 @@ def program_cases(topo_devices) -> list:
             return fn, args, want
         return build
 
-    def scoring(n_trees, rows=hr, features=hf, depth=6, n_classes=1):
+    def scoring(n_trees, rows=hr, features=hf, depth=6, n_classes=1,
+                routed=False):
         def build():
             fn, args = _scoring_program(topo_devices, rows=rows,
                                         features=features, n_trees=n_trees,
-                                        depth=depth, n_classes=n_classes)
+                                        depth=depth, n_classes=n_classes,
+                                        routed=routed)
             return fn, args, ["tpu_custom_call"]
         return build
 
@@ -354,6 +363,11 @@ def program_cases(topo_devices) -> list:
         # tree groups whose tables (11 MB) stream in 4 blocks of 7.
         ("scoring/covertype/3500x8", scoring(
             500 * cc, rows=cr, features=cf, depth=8, n_classes=cc)),
+        # The CTR model's chunk through the auto dispatch: the routed
+        # form (both tables), one group.
+        ("scoring/criteo/100x6/routed", scoring(
+            100, rows=CRITEO["rows"], features=CRITEO["features"],
+            routed=True)),
     ]
 
 

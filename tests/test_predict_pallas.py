@@ -417,18 +417,23 @@ def test_padded_tree_count_must_be_a_multiple_of_the_chunk():
             base=0.0, tree_chunk=64)
 
 
-@pytest.mark.parametrize("impl,T,want", [
-    ("pallas", 9, 128), ("pallas", 130, 128), ("onehot", 9, 0),
-    ("auto", 9, 0),      # off the chip the auto dispatch is the one-hot path
-    ("lut", 9, 0),       # its own kernel, its own chunking
+@pytest.mark.parametrize("impl,T,want,routed", [
+    ("pallas", 9, 128, 0), ("pallas", 130, 128, 0), ("onehot", 9, 0, 0),
+    ("auto", 9, 0, 0),   # off the chip the auto dispatch is the one-hot path
+    ("lut", 9, 0, 0),    # its own kernel, its own chunking
+    # the routed forms: the missing table, and the categorical one with it
+    ("pallas", 9, 128, 1), ("pallas", 130, 128, 2), ("onehot", 9, 0, 2),
 ])
-def test_ensemble_span_says_which_form_served(impl, T, want):
+def test_ensemble_span_says_which_form_served(impl, T, want, routed):
     """`tree_group` on the `ddt:predict:ensemble` span: the lane width of
     the traversal kernel's tree planes, 128 whatever the tree count, and 0
-    when that kernel does not serve the model."""
+    when that kernel does not serve the model; `routing_tables`: how many
+    of the missing and categorical tables that kernel routes by."""
     from ddt_tpu.telemetry import annotations as an
 
-    ens = _rand_ensemble(T=T, depth=3, F=5, bins=31, seed=40 + T)
+    ens = _rand_ensemble(T=T, depth=3, F=5, bins=31, seed=40 + T,
+                         missing=routed >= 1, cat=(1, 3) if routed == 2
+                         else ())
     Xb = np.random.default_rng(3).integers(0, 31, size=(50, 5),
                                            dtype=np.uint8)
     be = get_backend(TrainConfig(backend="tpu", n_bins=31,
@@ -438,18 +443,25 @@ def test_ensemble_span_says_which_form_served(impl, T, want):
     counts = {s["name"]: s for s in root["spans"]}[
         "ddt:predict:ensemble"]["counts"]
     # The span carries the plan by the kernel module's one list of names.
-    assert list(counts) == ["bytes", *jpp.SPAN_COUNTS]
-    assert counts["tree_group"] == want
+    assert list(counts) == ["bytes", "trees", *jpp.SPAN_COUNTS]
+    assert counts["tree_group"] == want and counts["trees"] == T
+    # ... on the call's root too: a later call finds the model resident
+    # and opens no `ensemble` span, and still says which form served it.
+    tables = routed if want else 0
+    assert counts["routing_tables"] == tables
+    assert root["counts"]["routing_tables"] == tables
     # The table plan rides on the same span: these small models are one
     # resident block of all their groups, and nothing streams.
     groups = -(-T // 128) if want else 0
     assert (counts["table_groups"], counts["groups_per_step"]) \
         == (groups, groups)
-    assert counts["table_bytes"] == groups * 4 * 128 * (2 * 7 + 8 + 1)
+    assert counts["table_bytes"] == groups * 4 * 128 * (
+        (2 + routed) * 7 + 8 + 1)
     # ... and how the kernel uses the MXU: 5 features, so two nodes a
-    # weight tile, the root's and one a pair of siblings (7 nodes: 4).
+    # weight tile, the root's and one a pair of siblings (7 nodes: 4);
+    # with a routing table one node a tile.
     assert (counts["nodes_per_tile"], counts["mxu_tiles_per_group"]) \
-        == ((2, 4) if want else (0, 0))
+        == (((1, 7) if routed else (2, 4)) if want else (0, 0))
     assert root["counts"]["classes"] == 1
     assert root["counts"]["tables_streamed_bytes"] == 0
 

@@ -142,8 +142,14 @@ BRANCHES = {
     #          {span: how many of it}, chunks)
     "one": (1, 4096, 1000,
             dict(upload=1, dispatch=1, fetch=1, place=0, concat=0), 1),
+    # pieces of PREDICT_UPLOAD_CHUNKS = 2 chunks, a later one under the
+    # compute of the one before it: 4 chunks in 2 pieces, 7 in 4 (the
+    # last piece one remainder chunk)
     "chunks": (1, 256, 1000,
-               dict(upload=1, dispatch=4, fetch=4, place=4, concat=0), 4),
+               dict(upload=2, dispatch=4, fetch=4, place=4, concat=0), 4),
+    "chunks-4pieces": (1, 256, 1700,
+                       dict(upload=4, dispatch=7, fetch=7, place=7,
+                            concat=0), 7),
     "mesh": (4, 64, 1000,
              dict(upload=4, dispatch=4, fetch=1, place=0, concat=0), 4),
 }
@@ -167,7 +173,7 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
 
     assert root["counts"]["rows"] == R
     assert root["counts"]["chunks"] == chunks
-    assert root["counts"]["branch"] == branch
+    assert root["counts"]["branch"] == branch.split("-")[0]
     for k in type(be)._PREDICT_ROOT_COUNTERS:
         assert root["counts"][k] == moved[k]
     assert root["counts"]["compiled_ensemble_cache_hits"] == 0
@@ -183,6 +189,16 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
     for name in ("dispatch", "fetch", "place"):
         assert [s["counts"]["chunk"] for s in kids["ddt:predict:" + name]] \
             == list(range(want[name]))
+    if root["counts"]["branch"] == "chunks":
+        # a piece's transfer starts when the last chunk of the piece before
+        # it has been dispatched, and before its own first chunk is
+        per = type(be).PREDICT_UPLOAD_CHUNKS
+        up, disp = kids["ddt:predict:upload"], kids["ddt:predict:dispatch"]
+        assert [s["counts"]["piece"] for s in up] == list(range(len(up)))
+        assert up[0]["end"] <= disp[0]["start"]
+        for p in range(1, len(up)):
+            assert disp[per * p - 1]["end"] <= up[p]["start"]
+            assert up[p]["end"] <= disp[per * p]["start"]
     # bytes where the work happens, and the same bytes on the counters
     ens_bytes = kids["ddt:predict:ensemble"][0]["counts"]["bytes"]
     assert ens_bytes > 0
@@ -214,9 +230,10 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
 
 # The chunk loop's result against its chunks scored one call each (the
 # `one` branch): rows a whole number of chunks and rows with a remainder
-# chunk, one class and seven, host rows and rows already on the device.
+# chunk, 7 chunks whose last piece is the remainder chunk alone, one class
+# and seven, host rows and rows already on the device.
 CHUNK_CASES = [(rows, classes, resident)
-               for rows in (768, 1000)
+               for rows in (768, 1000, 1700)
                for classes in (1, 7)
                for resident in (False, True)]
 

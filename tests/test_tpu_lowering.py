@@ -59,6 +59,7 @@ def test_case_table_covers_the_default_dispatch():
                    "predict/50x4/missing+cat", "7classes",
                    "predict/150x6/missing+cat",
                    "predict/higgs/1000x6/missing+cat",
+                   "predict/criteo/100x6/missing+cat",
                    "predict/covertype/3500x8/7classes",
                    "predict/covertype/3500x8/7classes/missing",
                    "7classes/missing+cat", "predict/56f", "predict/64f",
