@@ -281,7 +281,8 @@ def score_routed(overrides: dict, rows: int) -> None:
     of depth 6 over 39 columns, 13 numeric with a NaN bin and a learned
     direction a node, 26 categorical split one-vs-rest. Asserts that the
     ROUTED form of the traversal kernel served it by the auto dispatch
-    (both routing tables, one node a weight tile) and holds a sample of
+    (both routing tables, one node a weight tile, both tables' routes
+    inside that tile: `routes_in_tile` 2) and holds a sample of
     rows to the NumPy oracle, `TreeEnsemble.predict_raw`."""
     from ddt_tpu import api
     from ddt_tpu.config import TrainConfig
@@ -315,13 +316,16 @@ def score_routed(overrides: dict, rows: int) -> None:
     built = {s["name"]: s["counts"] for s in root["spans"]}[
         "ddt:predict:ensemble"]
     say(f"routed predict: ddt:predict:ensemble {built}; root "
-        f"routing_tables={root['counts']['routing_tables']}")
+        f"routing_tables={root['counts']['routing_tables']}; "
+        f"routes_in_tile={built['routes_in_tile']}")
     assert built["tree_group"] == 128, "the traversal kernel did not serve"
     assert (built["trees"], built["table_groups"]) == (T, 1), built
     assert built["routing_tables"] == root["counts"]["routing_tables"] == 2, \
         "the kernel does not route by both tables"
     assert (built["nodes_per_tile"], built["mxu_tiles_per_group"]) == (
         1, n_int), built
+    assert built["routes_in_tile"] == 2, \
+        "the two tables' routes do not ride the MXU weight tile"
     assert_compiled_kernel(cfg, ens, rows, "routed")
     n = min(SCORE_CHECK_ROWS, rows)
     want = ens.predict_raw(Xb[:n], binned=True)      # NumPy traversal

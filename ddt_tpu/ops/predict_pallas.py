@@ -40,7 +40,9 @@ program, to a multiple of G x 128 with trees that score 0:
                              on both sides of a select), no lane slices,
                              no gathers anywhere. thr is f32 bins at P = 1
                              and at P = 2 int32, prepared for the compare
-                             on the packed word (predict_effective_pallas).
+                             on the packed word (predict_effective_pallas);
+                             in the folded routed form the three planes
+                             are h, delta and c, all f32 (below).
     val   [nb, G*W, 128]     bottom-level pushed-down leaf values, same.
     coh   [nb, G*128, C]     round-major class one-hot.
 
@@ -71,17 +73,60 @@ tree's SIBLINGS (2n+1 low, 2n+2 high), computed when their parent is
 visited, and the root alone: 2^(depth-1) weight tiles a group where one a
 node is 2^depth - 1 (`mxu_tiles_per_group`: 32 for 63, 128 for 255). F > 64
 keeps one node a tile, the program it was, and so does an ensemble with the
-missing or the categorical table: its integer routing binds the kernel to
-the VPU, where reading a byte out of the word (a shift and a mask) costs
-more than the MXU results saved. Three nodes a tile (a node with its two
+missing or the categorical table (the routed form, next paragraph). Three
+nodes a tile (a node with its two
 children, 24 bits, no room for the mantissa trick) lost to two on the v5e:
 3,082 cycles a depth-6 step of 256 rows x 128 trees against 2,696, one node
-a tile 4,654 (PERF.md sections 5 and 6, PR 28). The routed form's step
-(both tables, one node a tile, depth 6): 5,678 cycles in PR 28's probe at 28
-features and 8 groups a grid step; 5,649 cycles measured through
-`api.predict` at 39 features with ONE group a grid step, the CTR model's
-100 trees, where a grid step's own cost is not shared (PERF.md section 5,
-PR 31).
+a tile 4,654 (PERF.md sections 5 and 6, PR 28).
+
+The ROUTED form (an ensemble with the missing or the categorical table;
+one node a tile) spends the tile's idle K rows on the routing itself
+(`routes_in_tile`, PR 32). What `_descend_comp` decides at a node is a
+function of the bin b of the node's feature and of three constants of the
+(node, tree): the threshold, whether the node is one-vs-rest, the learned
+direction. All of it is linear in b, in m = [b == missing_bin_value] and
+in 1, up to ONE absolute value. So the left operand is [2x | m | ones]
+(each part from a multiple of 8 K rows; built once a grid step), the
+node's weight tile its feature one-hot over the x rows, delta times the
+same one-hot over the m rows and c in the first ones row, and the matmul
+returns
+
+    w[row, tree] = 2 b + delta m + c          goes_right = |w| > h
+
+  ordinal node, threshold t:   c = -t,  h = t:  |2b - t| > t  <=>  b > t
+  category node, bin t:        c = -2t, h = 0:  |2b - 2t| > 0 <=>  b != t
+  NaN bin, learned left:       delta = -2 nan (w = -t) or 2 (t - nan) (w = 0)
+  NaN bin, learned right:      delta = 512, past every |w| a bin reaches
+
+(`_folded_routes`, in the jitted prologue; an ordinal node that sends
+every bin right, t < 0, is a category node that matches none; a
+pushed-down leaf has an empty one-hot, so w = c = -255 against h = 255:
+left.) Without the categorical table there is no doubling, no ones row
+and no absolute value: w = b + delta m against h = t, delta -(nan + 1) or
+256. Without the missing table no m rows. Every constant is an integer of
+at most 256 in magnitude or an even one of at most 512, exact in
+bfloat16, and every partial sum an integer below 2^11: w is exact in any
+summation order, as the packed word is. The operands stay feat and two or
+three planes a node (h where thr was, delta where dl, c where cat), so
+the table plan does not know the difference. It serves where the left
+operand fits the tile's 128 K rows, counted for both tables whichever
+are there: 2 x 8 ceil(F / 8) + 8 <= 128, F <= 56. Wider routed models
+keep the integer routing of `_descend_comp`, term for term, on the VPU
+(the form every routed model had before): a compare and a convert, a
+broadcast and a test of the `cat` plane, a second compare, convert and
+select, a third compare for the NaN bin, a broadcast of the `dl` plane, a
+subtract, a select and a last != 0 a node, which bound the kernel to the
+VPU: 5,678 cycles a depth-6 step with both tables in PR 28's probe (28
+features, 8 groups a grid step), 5,649 through `api.predict` at the CTR
+model's 39 features and 100 trees (PERF.md section 5, PR 31), against
+4,516 for the unrouted one-node form. Folded, that model's step is 4,866
+cycles (PR 32), bit-equal scores: the MXU's 69 results a group (4,416
+cycles) and a grid step of one group. One table alone was never far from
+there (the missing table 4,892 -> 4,865, the categorical one 4,972 ->
+4,763): the three-way select of both was what cost. Two nodes a tile on
+the routed form lost before the fold, 14%, to reading a byte out of the
+packed word for the integer routing; on the folded form it is open
+(ROADMAP A1).
 
 Per tree group of the block (static Python loop, traced once), per weight
 tile (depth-first, at the parent of its nodes):
@@ -91,10 +136,11 @@ tile (depth-first, at the parent of its nodes):
         the sublanes with the mantissa row, then one MXU weight tile:
         colval = X @ foh — the exact bin value of each node's feature at
         every (row, tree) of the group, one a byte.
-    goes_right = colval > thr per node, a predicate used as it is (with
-        the categorical one-vs-rest and reserved-NaN-bin operands, one
-        node a tile, the integer routing of ops/predict._descend_comp,
-        term for term, then != 0).
+    goes_right = colval > thr per node, a predicate used as it is. The
+        routed form, folded: |w| > h, the tile's result against the h
+        plane (w > h with the missing table alone); past 56 features the
+        integer routing of ops/predict._descend_comp on the `cat` and
+        `dl` planes, term for term, then != 0.
     Value mux tree: a full tree whose Nint comparison bits are all known
         is a multiplexer over its W leaf values —
         leaf(n) = where(goes_right(n), leaf(2n+2), leaf(2n+1)), the leaves
@@ -184,6 +230,19 @@ TREE_GROUP = 128
 # group's. Depth 4 / 5 / 7 with both: 1 group under 1.0 / 1.48 / 3.24, 4
 # groups 2.12 / 3.32 / 6.07. Why it does so for the three-way select and
 # not for one table is still open; the count below stays the bound.
+# That is the INTEGER routing, which since PR 32 only models of more than
+# 56 features take (`routes_in_tile` 0). The folded routed form has no
+# three-way select and keeps nothing per node (compile check, PR 32; the
+# same probe, 39 features, scoped MiB at tile 256 without the row tile's
+# windows, 1 MiB = 4 KiB a row): both tables, 100 trees 1.12; 1000 trees
+# in blocks of 2 / 8 groups at depth 6: 1.25 / 1.47 (56 features 1.48);
+# depth 4 / 5 / 7 / 8 / 9 / 10: under 1.0 / 1.03 / 1.47 / 1.87 (54
+# features 1.88) / 1.85 / 2.12; the categorical table alone, depth 6 / 8
+# / 10: 1.48 / 1.85 / 2.07; the missing table alone: under 1.0 / 1.11 /
+# 1.11. So 10.5 KiB a row with the windows at depth 10, an eighth under
+# _ROW_BYTES, which bounds the folded form alone. (With the predicate
+# BEFORE the subtrees, the unrouted order, the same probes read 1.81 at
+# depth 6, 2.46 at depth 8 and 3.09 at depth 10: 14.4 KiB a row.)
 _ROW_BYTES = 12 * 1024
 _ROW_NODE_BYTES_BOTH = 192
 # Rows (K) of one MXU weight tile.
@@ -215,6 +274,18 @@ def nodes_per_tile(n_features: int, optional_operands: int = 0) -> int:
     return 2 if packs else 1
 
 
+def routes_in_tile(n_features: int, optional_operands: int = 0) -> int:
+    """The routing tables whose routes ride the MXU weight tile (the
+    FOLDED routed form, module docstring): all the ensemble carries where
+    [2x | m | ones], two copies of the features and a block of 8, fits
+    the tile's 128 K rows (F <= 56), else 0: the integer routing on the
+    VPU serves, as before PR 32. 0 also without a routing table. Read
+    from the input like `nodes_per_tile`; one rule whichever table is
+    there (a lone table leaves some of those rows unused)."""
+    fits = 2 * _copy_stride(n_features) + 8 <= _MXU_ROWS
+    return optional_operands if fits else 0
+
+
 def mxu_tiles_per_group(max_depth: int, n_features: int,
                         optional_operands: int = 0) -> int:
     """MXU weight tiles (results [TILE_R, 128]) a tree group costs a row
@@ -236,14 +307,16 @@ def _vmem_bytes(groups: int, max_depth: int, n_features: int,
     """VMEM a grid step takes with `groups` tree groups a table block: the
     table windows (feat i32, thr f32, dl and cat i32 where present, bottom
     values, class one-hot: one plane a row), the row tile's windows and
-    the working set."""
+    the working set, which the integer routing of both tables makes grow
+    by the node (`_ROW_NODE_BYTES_BOTH`; not the folded form)."""
     n_int = (1 << max_depth) - 1
     tables = ((2 + optional_operands) * _window_bytes(groups * n_int,
                                                       TREE_GROUP)
               + _window_bytes(groups * (n_int + 1), TREE_GROUP)
               + _window_bytes(groups * TREE_GROUP, n_classes))
-    work = tile_r * (_ROW_BYTES + (n_int * _ROW_NODE_BYTES_BOTH
-                                   if optional_operands == 2 else 0))
+    per_node = optional_operands == 2 and not routes_in_tile(n_features, 2)
+    work = tile_r * (_ROW_BYTES
+                     + (n_int * _ROW_NODE_BYTES_BOTH if per_node else 0))
     rows = _window_bytes(tile_r, n_features) + _window_bytes(tile_r,
                                                              n_classes)
     return tables + work + rows
@@ -260,6 +333,7 @@ class TablePlan(typing.NamedTuple):
     nodes_per_tile: int    # P: nodes that share one MXU weight tile
     mxu_tiles_per_group: int   # weight tiles a group costs a row tile
     routing_tables: int    # the missing and categorical tables it carries
+    routes_in_tile: int    # ... of them, routed inside the MXU weight tile
 
     @property
     def tree_group(self) -> int:
@@ -279,7 +353,7 @@ class TablePlan(typing.NamedTuple):
 # the root span's `tables_streamed_bytes`, which `phases_ms` has instead.
 SPAN_COUNTS = ("tree_group", "table_groups", "groups_per_step",
                "table_bytes", "nodes_per_tile", "mxu_tiles_per_group",
-               "routing_tables")
+               "routing_tables", "routes_in_tile")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 # This kernel does not serve the model (the one-hot path, the LUT tiers).
 NO_PLAN = TablePlan(*(0,) * len(TablePlan._fields))
@@ -314,7 +388,8 @@ def table_plan(
     packing = (nodes_per_tile(n_features, optional_operands),
                mxu_tiles_per_group(max_depth, n_features,
                                    optional_operands),
-               optional_operands)
+               optional_operands,
+               routes_in_tile(n_features, optional_operands))
     if most == 0:
         return TablePlan(n_tg, 0, 0, 0, tile_r, *packing)
     blocks = -(-n_tg // most)
@@ -344,17 +419,18 @@ def predict_pallas_fits(
 
 def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
                      n_groups: int, n_blocks: int, n_int: int,
-                     n_leaves: int, n_feat: int, pack: int,
+                     n_leaves: int, n_feat: int, pack: int, folded: bool,
                      missing_bin_value: int, use_missing: bool,
                      use_cat: bool):
     """One row tile against one block of tree groups: that block's share
     of every class's margin, fully in VMEM. `pack`: the plan's
-    nodes_per_tile.
+    nodes_per_tile; `folded`: its routes_in_tile is not 0.
 
     x_ref [TILE_R, F] int32; feat/thr (+ optional dl, cat) [G*Nint, 128]
     and val [G*W, 128], one plane a row; coh [G*128, C]; out [TILE_R, C]
     f32, resident over the block axis (grid axis 1): written by the first
-    block, added to by the others."""
+    block, added to by the others. Folded, the three planes are the
+    prologue's h, delta and c (`_folded_routes`), all f32."""
     rest = list(rest)
     out_ref = rest.pop()
     dl_ref = rest.pop(0) if use_missing else None
@@ -367,7 +443,26 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         offset 0, then a sublane broadcast."""
         return jnp.broadcast_to(ref[row:row + 1, :], (rows, tg))
 
-    if pack == 1:
+    stride = _copy_stride(n_feat)
+    if folded:
+        # [2x | m | ones]: the bins (doubled with the categorical table,
+        # whose match is a test of |w| against 0), the NaN-bin indicator
+        # with the missing table and a block of ones with the categorical
+        # one, each from a multiple of 8 K rows like the copies below.
+        x = x_ref[:]
+        xf = x.astype(jnp.float32)
+        parts = [xf * 2.0 if use_cat else xf]
+        if use_missing:
+            parts.append(jnp.where(x == missing_bin_value, 1.0, 0.0))
+        if stride > n_feat:
+            gap = jnp.zeros((tile_r, stride - n_feat), jnp.float32)
+            parts = [a for part in parts for a in (part, gap)]
+        if use_cat:
+            parts.append(jnp.ones((tile_r, 8), jnp.float32))
+            first_row = jax.lax.broadcasted_iota(jnp.int32, (8, tg), 0) == 0
+        f_iota = jax.lax.broadcasted_iota(jnp.int32, (stride, tg), 0)
+        xb = jnp.concatenate(parts, axis=1).astype(jnp.bfloat16)  # [T, K]
+    elif pack == 1:
         xb = x_ref[:].astype(jnp.bfloat16)                # [T, F]
         f_iota = jax.lax.broadcasted_iota(jnp.int32, (n_feat, tg), 0)
     else:
@@ -375,7 +470,6 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         # multiple of 8 (the one-hot's sublane tiles), the second times
         # 256, and where the weight tile has 8 rows to spare a block of
         # ones for the row that adds _MANTISSA inside the MXU.
-        stride = _copy_stride(n_feat)
         ones_in_tile = 2 * stride + 8 <= _MXU_ROWS
         xf = x_ref[:].astype(jnp.float32)
         copies = [xf, xf * 256.0]
@@ -396,8 +490,19 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         trees, [T, 128]: the bin value of the node's feature at every
         (row, tree) as f32 (one node a tile), or the two siblings' values
         as the bytes 0 and 1 of an int32 (the root: byte 0) above
-        _MANTISSA_BITS."""
-        if pack == 1:
+        _MANTISSA_BITS; folded, the node's w = 2b + delta m + c."""
+        if folded:
+            # The node's one-hot over the bins' rows, delta times it over
+            # the indicator's, c in the first of the ones' rows.
+            row = g * n_int + nodes[0]
+            hot = plane(feat_ref, row, stride) == f_iota
+            foh = [jnp.where(hot, 1.0, 0.0)]
+            if use_missing:
+                foh.append(jnp.where(hot, plane(dl_ref, row, stride), 0.0))
+            if use_cat:
+                foh.append(jnp.where(first_row, plane(cat_ref, row, 8), 0.0))
+            foh = jnp.concatenate(foh, axis=0).astype(jnp.bfloat16)
+        elif pack == 1:
             # Feature one-hot, TRANSPOSED: the node's feature row over F
             # sublanes against the per-feature iota (the hist_pallas
             # _hist_kernel_t trick). feat = -1 (pushed-down leaves)
@@ -431,6 +536,8 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         `row`), in each of its group's trees, to the right. `colval`:
         the node's tile_plane."""
         thr = plane(thr_ref, row)
+        if folded:
+            return (jnp.abs(colval) if use_cat else colval) > thr
         if pack == 2:
             # thr is the prologue's (thr + 1) << 8 * byte, on the tile's
             # top byte plus _MANTISSA_BITS: the top byte compares as the
@@ -461,9 +568,17 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         planes live, and with two nodes a tile one tile_plane a level:
         the siblings' is computed at their parent and stays while the
         first one's subtree is walked). `planes`: node -> its tile_plane,
-        for the nodes on the path whose tile is computed."""
+        for the nodes on the path whose tile is computed. Folded, a
+        node's tile and predicate come AFTER its two subtrees: no
+        predicate is held while they are walked (scoped VMEM 1.81 ->
+        1.47 MiB at depth 6, 3.09 -> 2.12 at depth 10, and 0.3% of the
+        step; compile check and my chip run, PR 32)."""
         if n >= n_int:
             return plane(val_ref, g * n_leaves + n - n_int)
+        if folded:
+            left, right = leaf(g, 2 * n + 1, {}), leaf(g, 2 * n + 2, {})
+            return jnp.where(goes_right(g * n_int + n, n,
+                                        tile_plane(g, (n,))), right, left)
         tiles = [(n,)] if pack == 1 or n == 0 else []
         if pack == 2 and 2 * n + 2 < n_int:
             tiles.append((2 * n + 1, 2 * n + 2))
@@ -495,6 +610,44 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
     @pl.when(block > 0)
     def _():
         out_ref[:] += acc
+
+
+def _folded_routes(eff_feat, eff_thr, eff_dl, eff_cat, missing_bin_value):
+    """The folded routed form's per-node constants, [Tpad, Nint] f32 each:
+    (h, delta or None, c or None), so that with b the bin of the node's
+    feature and m = [b == missing_bin_value]
+
+        goes_right = |2b + delta m + c| > h      with the categorical table
+        goes_right =    b + delta m     > h      with the missing one alone
+
+    is `_descend_comp`'s routing bit for every bin 0..255 (module
+    docstring). Each constant is an integer that bfloat16 holds exactly:
+    at most 256 in magnitude, or even and at most 512."""
+    nan_bin = min(max(missing_bin_value, 0), 255)
+    thr = jnp.clip(eff_thr, -1, 255).astype(jnp.int32)    # b > thr as it was
+    if eff_dl is not None:
+        eff_dl = eff_dl.astype(bool)
+    if eff_cat is None:
+        # The NaN bin lands under every threshold (-1) or over every one.
+        delta = jnp.where(eff_dl, -(nan_bin + 1), 256)
+        return thr.astype(jnp.float32), delta.astype(jnp.float32), None
+    # One-vs-rest nodes, pre-gated on eff_feat >= 0 so pushed-down leaves
+    # (colval 0, thr +BIG) stay always-left, exactly like _descend_comp:
+    # the matched bin `cat` goes left, -1 where no bin matches. An ordinal
+    # node that sends every bin right (thr < 0) is such a node too.
+    is_cat = eff_cat.astype(bool) & (eff_feat >= 0)
+    cat = jnp.where(is_cat & (eff_thr >= 0) & (eff_thr <= 255),
+                    eff_thr, -1).astype(jnp.int32)
+    is_cat |= thr < 0
+    c = jnp.where(is_cat, -2 * cat, -thr)
+    h = jnp.where(is_cat, 0, thr)
+    delta = None
+    if eff_dl is not None:
+        # Left: w = 0 (a match) or -thr. Right: past every |w| a bin gives.
+        delta = jnp.where(eff_dl, jnp.where(is_cat, 2 * (cat - nan_bin),
+                                            -2 * nan_bin), 512)
+        delta = delta.astype(jnp.float32)
+    return h.astype(jnp.float32), delta, c.astype(jnp.float32)
 
 
 def predict_effective_pallas(
@@ -576,7 +729,16 @@ def predict_effective_pallas(
                 .reshape(n_blocks, n_g * width, tg))
 
     feat_pl = by_plane(eff_feat[:, :n_int], jnp.int32, fill=-1)
-    if plan.nodes_per_tile == 1:
+    folded = plan.routes_in_tile > 0
+    extras = []
+    if folded:
+        h, *routes = _folded_routes(
+            eff_feat[:, :n_int], eff_thr[:, :n_int],
+            eff_dl[:, :n_int] if use_missing else None,
+            eff_cat[:, :n_int] if use_cat else None, missing_bin_value)
+        thr_pl = by_plane(h, jnp.float32)
+        extras = [by_plane(a, jnp.float32) for a in routes if a is not None]
+    elif plan.nodes_per_tile == 1:
         thr_pl = by_plane(eff_thr[:, :n_int], jnp.float32)
     else:
         # The compare is made on the packed word, in integers: thr + 1
@@ -592,10 +754,9 @@ def predict_effective_pallas(
     val_pl = by_plane(bot_val, jnp.float32)
     coh = jnp.pad(cls_oh.astype(jnp.float32),
                   ((0, t_fill), (0, 0))).reshape(n_blocks, n_g * tg, C)
-    extras = []
-    if use_missing:
+    if use_missing and not folded:
         extras.append(by_plane(eff_dl[:, :n_int], jnp.int32))
-    if use_cat:
+    if use_cat and not folded:
         # Pre-gate on eff_feat >= 0 so pushed-down leaves (colval 0,
         # thr +BIG) stay always-left, exactly like _descend_comp.
         cat_eff = eff_cat[:, :n_int].astype(bool) & (eff_feat[:, :n_int]
@@ -611,6 +772,7 @@ def predict_effective_pallas(
     kernel = functools.partial(
         _traverse_kernel, n_groups=n_g, n_blocks=n_blocks, n_int=n_int,
         n_leaves=n_leaves, n_feat=F, pack=plan.nodes_per_tile,
+        folded=folded,
         missing_bin_value=missing_bin_value, use_missing=use_missing,
         use_cat=use_cat,
     )

@@ -202,6 +202,23 @@ def kernel_cases() -> list:
         KernelCase("predict/criteo/100x6/missing+cat", True,
                    _predict_case(CRITEO["rows"], CRITEO["features"], 100, 6,
                                  missing=True, cat=True)),
+        # The folded routed form (the routes inside the weight tile,
+        # `routes_in_tile`) at the CTR model's shape with each table
+        # alone, and its edge with the missing table: 56 features fill
+        # the tile ([x | m], 8 rows to spare), 57 keep the integer
+        # routing on the VPU.
+        KernelCase("predict/criteo/100x6/missing", True,
+                   _predict_case(CRITEO["rows"], CRITEO["features"], 100, 6,
+                                 missing=True)),
+        KernelCase("predict/criteo/100x6/cat", True,
+                   _predict_case(CRITEO["rows"], CRITEO["features"], 100, 6,
+                                 cat=True)),
+        KernelCase("predict/56f/130x5/missing", True,
+                   _predict_case(hr, 56, 130, 5, missing=True)),
+        KernelCase("predict/57f/130x5/missing", True,
+                   _predict_case(hr, 57, 130, 5, missing=True)),
+        KernelCase("predict/56f/130x5/missing+cat", True,
+                   _predict_case(hr, 56, 130, 5, missing=True, cat=True)),
         # Two nodes a weight tile (F <= 64 and no routing table: 28
         # features with 4 K rows between the copies, 54 with 2), at
         # Covertype's own size: 28 groups in 4 blocks of 7, 128 weight
@@ -212,6 +229,12 @@ def kernel_cases() -> list:
         KernelCase("predict/covertype/3500x8/7classes/missing", True,
                    _predict_case(cr, cf, 500 * cc, 8, n_classes=cc,
                                  missing=True)),
+        # Depth 8 with BOTH tables: served since the routes ride the
+        # weight tile (PR 32; the integer routing's working set alone was
+        # past the budget there): 28 groups in 5 blocks of 6.
+        KernelCase("predict/covertype/3500x8/7classes/missing+cat", True,
+                   _predict_case(cr, cf, 500 * cc, 8, n_classes=cc,
+                                 missing=True, cat=True)),
         KernelCase("predict/covertype/350x6/7classes/missing+cat", True,
                    _predict_case(cr, cf, 50 * cc, 6, n_classes=cc,
                                  missing=True, cat=True)),

@@ -242,6 +242,22 @@ def test_mux_tree_matches_onehot(drop_compiled, depth, T, F, n_classes,
         check(want, got)
 
 
+def _assert_every_leaf(ens, Xb, got):
+    """`got` [rows] scored an ensemble whose tree t holds n * B^t at heap
+    node n (B = the node count + 1, learning rate 1, base 0): its base-B
+    digits are the leaves the rows reached, exact in float32, held to the
+    NumPy walk (reference/numpy_predict.py) tree by tree."""
+    from ddt_tpu.reference import numpy_predict
+
+    T, n_nodes = ens.feature.shape
+    leaves = np.stack([numpy_predict.leaf_of_rows(ens, t, Xb)
+                       for t in range(T)], axis=1)       # [rows, T]
+    digits = got.astype(np.int64)[:, None] // (n_nodes + 1) ** np.arange(
+        T) % (n_nodes + 1)
+    np.testing.assert_array_equal(digits, leaves)
+    np.testing.assert_array_equal(got, got.astype(np.int64))
+
+
 @pytest.mark.parametrize("depth", [2, 3, 4])
 @pytest.mark.parametrize("F", [28, 54, 64, 65])
 @pytest.mark.parametrize("missing,cat", [
@@ -257,8 +273,6 @@ def test_packed_fields_are_exact(F, depth, missing, cat):
     digits are the leaves, exact in float32, against the NumPy walk
     (reference/numpy_predict.py). F = 65 is the same check on one node a
     tile."""
-    from ddt_tpu.reference import numpy_predict
-
     n_nodes = 2 ** (depth + 1) - 1
     T = 24 // (depth + 1)                  # B^T <= 2^24
     ens = _rand_ensemble(T=T, depth=depth, F=F, bins=256, missing=missing,
@@ -283,20 +297,147 @@ def test_packed_fields_are_exact(F, depth, missing, cat):
     got = np.asarray(jpp.predict_raw_pallas(
         *args, jnp.asarray(Xb.astype(np.int32)), tree_chunk=64, **kw,
         **opt))
-    leaves = np.stack([numpy_predict.leaf_of_rows(ens, t, Xb)
-                       for t in range(T)], axis=1)       # [rows, T]
-    digits = got.astype(np.int64)[:, None] // (n_nodes + 1) ** np.arange(
-        T) % (n_nodes + 1)
-    np.testing.assert_array_equal(digits, leaves)
-    np.testing.assert_array_equal(got, got.astype(np.int64))
+    _assert_every_leaf(ens, Xb, got)
 
 
-def _kernel_jaxpr(F):
-    ens = _rand_ensemble(T=9, depth=3, F=F, bins=255, seed=F)
+def _folded_cases():
+    """(F, depth, missing, cat, bins): every feature count (56 the last
+    that folds, 57 the fallback's first) with every table set, the depths
+    1-4 and both NaN bins (30, 254) dealt over them; then the corners of
+    the product that dealing leaves out."""
+    cases = []
+    tables = [(True, False), (False, True), (True, True)]
+    for i, F in enumerate((5, 39, 56, 57)):
+        for j, (missing, cat) in enumerate(tables):
+            cases.append((F, 1 + (i + j) % 4, missing, cat,
+                          (31, 255)[(i + j) % 2]))
+    cases += [(39, 4, True, True, 255), (39, 1, True, True, 31),
+              (56, 3, True, True, 31), (56, 4, True, False, 31),
+              (5, 4, False, True, 255), (57, 4, True, True, 255),
+              (5, 3, True, True, 255), (56, 2, False, True, 31)]
+    return list(dict.fromkeys(cases))
+
+
+@pytest.mark.parametrize("F,depth,missing,cat,bins", _folded_cases())
+def test_folded_routes_are_exact(drop_compiled, F, depth, missing, cat,
+                                 bins):
+    """The folded routed form (the missing and category routes inside the
+    MXU weight tile, `routes_in_tile`) reaches `_descend_comp`'s leaf AND
+    the NumPy walk's (reference/numpy_predict.py) for every (row, tree):
+    thresholds 0 / 1 / 253 / 254 / 255 and the NaN bin itself (a category
+    equal to it), `default_left` both ways at ordinal and category nodes,
+    rows that put 0 / 253 / 254 / 255 and the NaN bin in every column.
+    Checked leaf by leaf as test_packed_fields_are_exact does (tree t's
+    value at heap node n is n * B^t), and bit for bit against the one-hot
+    path on the same dyadic values. F = 57 is the same check on the
+    integer routing the fold leaves to wider models."""
+    nan_bin = bins - 1
+    tables = missing + cat
+    assert jpp.routes_in_tile(F, tables) == (tables if F <= 56 else 0)
+    n_nodes = 2 ** (depth + 1) - 1
+    T = 24 // (depth + 1)                  # B^T <= 2^24
+    ens = _rand_ensemble(T=T, depth=depth, F=F, bins=bins, missing=missing,
+                         cat=(0, 3) if cat else (), seed=7 * F + depth)
+    rng = np.random.default_rng(F + depth)
+    ens.threshold_bin = rng.choice(
+        [0, 1, nan_bin - 1, nan_bin, 253, 254, 255],
+        size=(T, n_nodes)).astype(np.int32)
+    ens.feature[0] = 3                     # a category tree, where there are
+    ens.threshold_bin[0] = np.array(       # any, with the NaN bin in it
+        [nan_bin, 0, 255])[np.arange(n_nodes) % 3]
+    ens.feature[1] = F - 1                 # the last K row of each part
+    ens.is_leaf[:2, :2 ** depth - 1] = False     # full trees
+    if missing:
+        ens.default_left[0] = np.arange(n_nodes) % 2 == 0
+        ens.default_left[1] = np.arange(n_nodes) % 2 == 1
+    ens.learning_rate, ens.base_score = 1.0, 0.0
+    ens.leaf_value = (np.arange(n_nodes)[None, :] * float(n_nodes + 1)
+                      ** np.arange(T)[:, None]).astype(np.float32)
+    Xb = rng.integers(0, bins, size=(300, F))
+    for i, b in enumerate((0, 1, nan_bin - 1, nan_bin, 253, 254, 255)):
+        Xb[i] = b
+        Xb[10 + i, 3 % F] = b              # one feature, the rest random
+        Xb[20 + i] = np.where(np.arange(F) % 2, b, nan_bin)
+    Xb = Xb.astype(np.uint8)
+    args, kw, opt = _dev_args(ens)
+    Xi = jnp.asarray(Xb.astype(np.int32))
+    got = np.asarray(jpp.predict_raw_pallas(*args, Xi, tree_chunk=64, **kw,
+                                            **opt))
+    _assert_every_leaf(ens, Xb, got)
+    want = np.asarray(jpred.predict_raw(*args, Xi, tree_chunk=64,
+                                        use_pallas=False, **kw, **opt))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("nan_bin", [-1, 2, 30, 254, 255])
+@pytest.mark.parametrize("missing,cat", [
+    (True, False), (False, True), (True, True)])
+def test_folded_constants_are_exact_in_bfloat16(nan_bin, missing, cat):
+    """What the prologue puts into the weight tile (`_folded_routes`:
+    delta and c) is an integer bfloat16 holds, and with h the fold's one
+    compare IS `_descend_comp`'s routing: every threshold -3..258 and a
+    pushed-down leaf's +BIG, ordinal and category, both directions, a
+    live feature and a leaf's -1, at every bin 0..255, stated here in
+    integers."""
+    big = 2 ** 30
+    thr, is_cat, dl, live = (a.ravel() for a in np.meshgrid(
+        np.r_[-3:259, big], [False, True], [False, True], [False, True],
+        indexing="ij"))
+    keep = live | (thr == big)             # +BIG is a leaf's, and only its
+    thr, is_cat, dl, live = (a[keep] for a in (thr, is_cat, dl, live))
+    feat = np.where(live, 3, -1)[None, :]
+    h, delta, c = jpp._folded_routes(
+        jnp.asarray(feat), jnp.asarray(thr[None, :], jnp.int32),
+        jnp.asarray(dl[None, :]) if missing else None,
+        jnp.asarray(is_cat[None, :]) if cat else None, nan_bin)
+    for const in (delta, c):
+        if const is not None:
+            np.testing.assert_array_equal(
+                np.asarray(const),
+                np.asarray(const.astype(jnp.bfloat16).astype(jnp.float32)))
+            assert np.abs(np.asarray(const)).max() <= 512
+    h, delta, c = (np.zeros(thr.shape) if a is None else np.asarray(a)[0]
+                   for a in (h, delta, c))
+    # A leaf's one-hot is empty: colval 0, no indicator; its c still adds.
+    b = np.arange(256)[:, None] * live[None, :]
+    m = (b == nan_bin) & live[None, :]
+    want = b > thr[None, :]
+    if cat:
+        want = np.where((is_cat & live)[None, :], b != thr[None, :], want)
+    if missing:
+        want = np.where(b == nan_bin, ~dl[None, :], want)
+    w = (2 if cat else 1) * b + delta[None, :] * m + c[None, :]
+    got = (np.abs(w) if cat else w) > h[None, :]
+    np.testing.assert_array_equal(got, want)
+
+
+def _kernel_jaxpr(F, missing=False, cat=()):
+    ens = _rand_ensemble(T=9, depth=3, F=F, bins=255, seed=F,
+                         missing=missing, cat=cat)
     args, kw, opt = _dev_args(ens)
     Xb = jnp.zeros((300, F), jnp.int32)
     return str(jax.make_jaxpr(lambda *a: jpp.predict_raw_pallas(
-        *a, tree_chunk=64, **kw))(*args, Xb))
+        *a, tree_chunk=64, **kw, **opt))(*args, Xb))
+
+
+@pytest.mark.parametrize("missing,cat,k_rows", [
+    (True, (1,), 56 + 56 + 8), (True, (), 56 + 56), (False, (1,), 56 + 8)])
+def test_routed_models_fold_or_keep_the_integer_routing(missing, cat,
+                                                        k_rows):
+    """At 56 features the routed program is the folded one: one matmul a
+    node over [2x | m | ones], an `abs` with the categorical table, no
+    int32 routing. At 57 it is the integer routing on [256, 57] x
+    [57, 128] (the whole jaxpr was compared with the parent commit's at
+    57, 64 and 65 features, one table and both: the same text, PR 32)."""
+    folded = _kernel_jaxpr(56, missing, cat)
+    assert f"bf16[256,{k_rows}]" in folded and f"bf16[{k_rows},128]" in folded
+    assert folded.count("dot_general") == 7 + 1          # nodes + class dot
+    assert (" abs " in folded) == bool(cat)
+    assert "i32[256,128]" not in folded
+    wide = _kernel_jaxpr(57, missing, cat)
+    assert "bf16[256,57]" in wide and "bf16[57,128]" in wide
+    assert " abs " not in wide and "i32[256,128]" in wide
+    assert wide.count("dot_general") == 7 + 1
 
 
 def test_wide_models_keep_the_one_node_program():
@@ -324,11 +465,22 @@ def test_wide_models_keep_the_one_node_program():
 ])
 def test_nodes_per_tile_is_read_from_the_shape(F, depth, nodes, tiles):
     """P follows from the feature count alone, and with the depth the
-    MXU results a tree group costs; both ride on the table plan."""
+    MXU results a tree group costs; both ride on the table plan. So does
+    `routes_in_tile`: with a routing table one node a tile at any F, and
+    the tables' routes inside that tile where [2x | m | ones] fits its
+    128 K rows (F <= 56), all of them or none."""
     assert jpp.nodes_per_tile(F) == nodes
     assert jpp.mxu_tiles_per_group(depth, F) == tiles
     plan = jpp.table_plan(1024, depth, F, 1, None, 0)
     assert (plan.nodes_per_tile, plan.mxu_tiles_per_group) == (nodes, tiles)
+    assert (plan.routing_tables, plan.routes_in_tile) == (0, 0)
+    for tables in (1, 2):
+        assert jpp.routes_in_tile(F, tables) == (tables if F <= 56 else 0)
+        plan = jpp.table_plan(128, min(depth, 6), F, 1, None, tables)
+        assert (plan.nodes_per_tile, plan.mxu_tiles_per_group) == (
+            1, 2 ** min(depth, 6) - 1)
+        assert (plan.routing_tables, plan.routes_in_tile) == (
+            tables, tables if F <= 56 else 0)
 
 
 @pytest.mark.parametrize("depth,F,C,optional,tile_r,fits", [
@@ -342,15 +494,23 @@ def test_nodes_per_tile_is_read_from_the_shape(F, depth, nodes, tiles):
     (6, 54, 7, 1, None, True),
     (6, 54, 7, 2, None, True),
     (7, 54, 7, 2, None, True),
-    # With BOTH routing tables the compiler keeps 192 B a row and node:
-    # at depth 7 there is room left for three groups' tables (the guard
-    # refused 1024 such trees while every table had to be resident), at
-    # depth 8 the working set alone is past the budget.
-    (7, 28, 1, 2, None, True),
-    (8, 28, 1, 2, None, False),
+    # With BOTH routing tables and more than 56 features (the integer
+    # routing) the compiler keeps 192 B a row and node: at depth 7 there
+    # is room left for three groups' tables (the guard refused 1024 such
+    # trees while every table had to be resident), at depth 8 the working
+    # set alone is past the budget.
+    (7, 60, 1, 2, None, True),
+    (8, 60, 1, 2, None, False),
     # a larger tile is charged by the row
     (6, 28, 1, 0, 512, True),
-    (6, 28, 1, 2, 512, False),
+    (6, 60, 1, 2, 512, False),
+    # The folded routed form (F <= 56) keeps nothing per node: depth
+    # costs tables only there too, and past depth 10 they do not fit.
+    (7, 28, 1, 2, None, True),
+    (8, 28, 1, 2, None, True),
+    (6, 28, 1, 2, 512, True),
+    (10, 28, 1, 2, None, True),
+    (11, 28, 1, 2, None, False),
 ])
 def test_pallas_fits_guard(depth, F, C, optional, tile_r, fits):
     """Depth, features, classes, the optional operands and the tile decide
@@ -378,17 +538,25 @@ def test_pallas_fits_guard(depth, F, C, optional, tile_r, fits):
     (1280, 8, 54, 7, 0, 10, 5, 2),
     (2816, 6, 54, 7, 1, 22, 22, 1),
     (2944, 6, 54, 7, 1, 23, 12, 2),
-    (1536, 6, 54, 7, 2, 12, 12, 1),
-    (1664, 6, 54, 7, 2, 13, 7, 2),
-    (384, 7, 54, 7, 2, 3, 3, 1),
+    (1536, 6, 60, 7, 2, 12, 12, 1),
+    (1664, 6, 60, 7, 2, 13, 7, 2),
+    (384, 7, 60, 7, 2, 3, 3, 1),
     (1024, 7, 28, 1, 0, 8, 8, 1),
-    (1024, 7, 28, 1, 2, 8, 3, 3),
+    (1024, 7, 60, 1, 2, 8, 3, 3),
+    # ... which both tables cost only by the integer routing (more than
+    # 56 features); folded, the same models are planned as with one table
+    (1536, 6, 54, 7, 2, 12, 12, 1),
+    (1664, 6, 54, 7, 2, 13, 13, 1),
+    (2944, 6, 54, 7, 2, 23, 12, 2),
+    (1024, 7, 28, 1, 2, 8, 8, 1),
+    (3520, 8, 54, 7, 2, 28, 6, 5),
+    (128, 8, 28, 1, 2, 1, 1, 1),
     # Covertype's own model, 500 rounds x 7 classes: 9 groups fit, so 4
     # blocks, of 7 (not 3 of 9 and one of 1 filled to 9)
     (3520, 8, 54, 7, 0, 28, 7, 4),
     # the trace is bounded whatever the tree count
     (1 << 20, 6, 28, 1, 0, 8192, 27, 304),
-    (128, 8, 28, 1, 2, 1, 0, 0),      # nothing fits
+    (128, 8, 60, 1, 2, 1, 0, 0),      # nothing fits
 ])
 def test_table_plan(tpad, depth, F, C, optional, groups, g, blocks):
     """How many tree groups a table block holds (G) and how many blocks a
@@ -417,24 +585,30 @@ def test_padded_tree_count_must_be_a_multiple_of_the_chunk():
             base=0.0, tree_chunk=64)
 
 
-@pytest.mark.parametrize("impl,T,want,routed", [
-    ("pallas", 9, 128, 0), ("pallas", 130, 128, 0), ("onehot", 9, 0, 0),
-    ("auto", 9, 0, 0),   # off the chip the auto dispatch is the one-hot path
-    ("lut", 9, 0, 0),    # its own kernel, its own chunking
+@pytest.mark.parametrize("impl,T,want,routed,F", [
+    ("pallas", 9, 128, 0, 5), ("pallas", 130, 128, 0, 5),
+    ("onehot", 9, 0, 0, 5),
+    ("auto", 9, 0, 0, 5),  # off the chip the auto dispatch is the one-hot path
+    ("lut", 9, 0, 0, 5),   # its own kernel, its own chunking
     # the routed forms: the missing table, and the categorical one with it
-    ("pallas", 9, 128, 1), ("pallas", 130, 128, 2), ("onehot", 9, 0, 2),
+    ("pallas", 9, 128, 1, 5), ("pallas", 130, 128, 2, 5),
+    ("onehot", 9, 0, 2, 5),
+    # ... past 56 features, where the routes do not fit the weight tile
+    ("pallas", 9, 128, 1, 57), ("pallas", 9, 128, 2, 57),
 ])
-def test_ensemble_span_says_which_form_served(impl, T, want, routed):
+def test_ensemble_span_says_which_form_served(impl, T, want, routed, F):
     """`tree_group` on the `ddt:predict:ensemble` span: the lane width of
     the traversal kernel's tree planes, 128 whatever the tree count, and 0
     when that kernel does not serve the model; `routing_tables`: how many
-    of the missing and categorical tables that kernel routes by."""
+    of the missing and categorical tables that kernel routes by;
+    `routes_in_tile`: how many of those it routes inside the MXU weight
+    tile (all of them at F <= 56), not by integers on the VPU."""
     from ddt_tpu.telemetry import annotations as an
 
-    ens = _rand_ensemble(T=T, depth=3, F=5, bins=31, seed=40 + T,
+    ens = _rand_ensemble(T=T, depth=3, F=F, bins=31, seed=40 + T,
                          missing=routed >= 1, cat=(1, 3) if routed == 2
                          else ())
-    Xb = np.random.default_rng(3).integers(0, 31, size=(50, 5),
+    Xb = np.random.default_rng(3).integers(0, 31, size=(50, F),
                                            dtype=np.uint8)
     be = get_backend(TrainConfig(backend="tpu", n_bins=31,
                                  predict_impl=impl))
@@ -450,6 +624,7 @@ def test_ensemble_span_says_which_form_served(impl, T, want, routed):
     tables = routed if want else 0
     assert counts["routing_tables"] == tables
     assert root["counts"]["routing_tables"] == tables
+    assert counts["routes_in_tile"] == (tables if F <= 56 else 0)
     # The table plan rides on the same span: these small models are one
     # resident block of all their groups, and nothing streams.
     groups = -(-T // 128) if want else 0

@@ -52,7 +52,8 @@ def test_case_table_covers_the_default_dispatch():
     output and for seven, with whole tree groups and with a filled-up
     last one, two nodes a weight tile (every case of at most 64
     features without a routing table; Covertype's own 3,500 trees at
-    depth 8 among them) and one."""
+    depth 8 among them) and one, routed by integers on the VPU
+    (57 features) and inside the weight tile (`routes_in_tile`)."""
     names = [c.name for c in DEFAULT_CASES]
     for needle in ("hist/higgs/255bins", "hist/higgs/64bins",
                    "hist/covertype", "predict/higgs/1000x6",
@@ -63,7 +64,14 @@ def test_case_table_covers_the_default_dispatch():
                    "predict/covertype/3500x8/7classes",
                    "predict/covertype/3500x8/7classes/missing",
                    "7classes/missing+cat", "predict/56f", "predict/64f",
-                   "predict/65f"):
+                   "predict/65f",
+                   # the folded routed form: each table alone, and its
+                   # edge (56 features fold, 57 route on the VPU)
+                   "predict/criteo/100x6/missing",
+                   "predict/criteo/100x6/cat",
+                   "predict/56f/130x5/missing",
+                   "predict/57f/130x5/missing",
+                   "predict/56f/130x5/missing+cat"):
         assert any(needle in n for n in names), (needle, names)
 
 
