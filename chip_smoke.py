@@ -14,6 +14,9 @@ the same `api.predict`, its node tables streamed by blocks of tree groups.
 And one of the CTR model's shape (100 trees, depth 6, 39 columns: 13 numeric
 with a NaN bin and learned directions, 26 categorical split one-vs-rest)
 scores 1,000,000 rows, served by the ROUTED form of the traversal kernel.
+And one of LightGBM's Higgs model's shape (500 leaf-wise trees of 255 leaves,
+28 features: a NODE LIST no heap holds) scores 200,000 rows, served by the
+PATH-MATRIX form of the traversal kernel.
 
 It asserts WHAT ran (the Pallas kernels, compiled: `tpu_custom_call` in both
 lowered programs; histogram resolved to `pallas`, sibling subtraction on; no
@@ -54,6 +57,7 @@ SEED = 42
 SCORE_CHECK_ROWS = 50_000
 MC_ROUNDS, MC_ROWS = 500, 100_000      # the 7-class scoring phase
 ROUTED_ROWS = 1_000_000                # the routed scoring phase
+LEAFWISE_ROWS = 200_000                # the node-list scoring phase
 SCORE_TOL = dict(rtol=3e-4, atol=3e-4)      # as __graft_entry__'s oracle check
 # Chip-vs-oracle training parity: the bounds the earlier chip runs measured
 # inside (0.9871 agreement, 0.0024 AUC). Never bitwise
@@ -336,6 +340,55 @@ def score_routed(overrides: dict, rows: int) -> None:
     assert gap <= 1e-5, gap
 
 
+def score_node_list(overrides: dict, rows: int) -> None:
+    """LightGBM's Higgs model's shape through `api.predict`: 500 random
+    leaf-wise trees of 255 leaves over 28 features, a node list (a random
+    leaf is split 254 times, so a tree is some 20 levels deep). Asserts
+    that the PATH-MATRIX form of the traversal kernel served it by the auto
+    dispatch (`node_list` 1 on its spans, its tables in blocks of trees)
+    and holds a sample of rows to the plain node walk
+    (reference/numpy_predict, in float64)."""
+    from ddt_tpu import api
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.models.tree import random_node_list
+    from ddt_tpu.reference import numpy_predict
+    from ddt_tpu.telemetry.annotations import root_spans
+
+    T, L, F = 500, 255, FEATURES
+    rng = np.random.default_rng(SEED)
+    ens = random_node_list(rng, T, L, F, BINS, learning_rate=0.1,
+                           base_score=0.0, loss="logloss")
+    Xb = rng.integers(0, BINS, size=(rows, F), dtype=np.uint8)
+    cfg = TrainConfig(n_bins=BINS, backend="tpu", **overrides)
+    comp = Compiles()
+    t0 = time.perf_counter()
+    scores = api.predict(ens, Xb, binned=True, raw=True, cfg=cfg)
+    wall = time.perf_counter() - t0
+    timing(f"node-list predict, {rows} rows x {T} trees x {L} leaves "
+           f"(deepest {ens.deepest_leaf}), first call", wall=wall,
+           **comp.split(wall))
+    assert scores.shape == (rows,) and scores.dtype == np.float32, \
+        (scores.shape, scores.dtype)
+    assert np.isfinite(scores).all(), "non-finite scores"
+    root = root_spans("predict")[-1]
+    built = {s["name"]: s["counts"] for s in root["spans"]}[
+        "ddt:predict:ensemble"]
+    say(f"node-list predict: ddt:predict:ensemble {built}; root "
+        f"node_list={root['counts']['node_list']} tables_streamed_bytes="
+        f"{root['counts']['tables_streamed_bytes']}")
+    assert built["node_list"] == root["counts"]["node_list"] == 1, built
+    assert built["deepest_leaf"] > 12, "a tree a heap could have held"
+    assert built["trees_per_step"] > 0, "the path-matrix kernel did not serve"
+    assert built["trees_per_step"] * built["table_blocks"] >= T, built
+    assert_compiled_kernel(cfg, ens, rows, "node-list")
+    n = min(2_000, rows)
+    want = numpy_predict.predict_raw_node_list(ens, Xb[:n], dtype=np.float64)
+    gap = float(np.abs(scores[:n] - want).max())
+    say(f"node-list scores: {n} rows against reference/numpy_predict "
+        f"(float64), max |diff| = {gap:.2e} (<= 1e-5)")
+    assert gap <= 1e-5, gap
+
+
 def parity_against_reference(overrides: dict) -> None:
     """5 trees on 20k rows: the chip against reference/numpy_trainer (pure
     NumPy — no native library decides this verdict)."""
@@ -518,6 +571,8 @@ def main(argv=None) -> int:
     score_multiclass(overrides, MC_ROWS // 100 if args.rehearse else MC_ROWS)
     score_routed(overrides, ROUTED_ROWS // 100 if args.rehearse
                  else ROUTED_ROWS)
+    score_node_list(overrides, LEAFWISE_ROWS // 100 if args.rehearse
+                    else LEAFWISE_ROWS)
     parity_against_reference(overrides)
     barrier_experiment(be, Xb)
     if count >= 4:

@@ -19,7 +19,7 @@ from ddt_tpu.config import TrainConfig
 from ddt_tpu.data.quantizer import (BinMapper, feature_bincounts,
                                     fit_bin_mapper)
 from ddt_tpu.driver import Driver
-from ddt_tpu.models.tree import TreeEnsemble
+from ddt_tpu.models.tree import TreeEnsemble, ensemble_from_dict
 from ddt_tpu.utils.atomic import atomic_savez
 
 log = logging.getLogger("ddt_tpu.api")
@@ -120,7 +120,7 @@ def load_model(path, *, verify: bool = True) -> ModelBundle:
         d = dict(z)
     manifest = manifest_mod.read_npz_manifest(d, verify=verify,
                                               source=str(path))
-    ens = TreeEnsemble.from_dict(d)
+    ens = ensemble_from_dict(d)     # a heap, or a node list
     mapper = None
     if "mapper_edges" in d:
         mapper = BinMapper.load(

@@ -43,7 +43,7 @@ import numpy as np
 
 from ddt_tpu.backends.base import DeviceBackend, HostTree
 from ddt_tpu.config import TrainConfig
-from ddt_tpu.models.tree import TreeEnsemble
+from ddt_tpu.models.tree import CompiledNodeList, TreeEnsemble
 from ddt_tpu.ops import grad as grad_ops
 from ddt_tpu.ops import grow as grow_ops
 from ddt_tpu.ops import histogram as hist_ops
@@ -1538,11 +1538,10 @@ class TPUDevice(DeviceBackend):
     # inference (TreeEnsemble.predict → gather+compare, row-sharded)
     # ------------------------------------------------------------------ #
 
-    # Host-side row chunk for batch scoring: bounds the device working set
-    # (node state is [tree_chunk, rows_chunk] int32 plus traversal
-    # temporaries) independently of how many rows the caller scores — the
-    # 10M-row x 1000-tree config [BASELINE] OOM-kills the chip if scored in
-    # one dispatch. 2M rows/chip/call keeps the peak well under 1 GB.
+    # Rows a scoring dispatch takes on each chip: a batch is scored in
+    # chunks of this many, so one program and its temporaries serve any
+    # row count (a 100M-row call is 50 dispatches; its peak memory is the
+    # whole uploaded batch's, PERF.md section 4, not a chunk's).
     PREDICT_ROW_CHUNK = 2_000_000
     # Chunks a piece of the single-chip big-batch upload (_predict_raw):
     # the first piece's transfer is all of the upload a call exposes (46
@@ -1606,9 +1605,11 @@ class TPUDevice(DeviceBackend):
         # the call: every table block once a row tile where they stream,
         # 0 where one block holds them all (fetched once, resident).
         counts["tables_streamed_bytes"] = 0
-        # Which form of that kernel serves: the missing and categorical
-        # tables it routes by (0 also when it does not serve).
-        counts["routing_tables"] = plan.routing_tables
+        # Which form of which kernel serves: the missing and categorical
+        # tables the heap kernel routes by (0 also when it does not
+        # serve), and for a node list the path-matrix form and the MXU
+        # weight tiles it asks a tree.
+        counts.update(plan.root_counts())
         if plan.blocks > 1:
             shards = max(1, self.row_shards)
             shard_rows = [-(-min(chunk, R - i) // shards) for i in starts]
@@ -1880,6 +1881,8 @@ class TPUDevice(DeviceBackend):
 
         ce = compiled if compiled is not None else ens.compile(
             tree_chunk=64)
+        if isinstance(ce, CompiledNodeList):
+            return self._build_paths_fn(ens, ce)
         impl_req = self.cfg.predict_impl
         lut = None
         resolved = "f32"
@@ -1936,8 +1939,41 @@ class TPUDevice(DeviceBackend):
                     use_pallas=use_pallas,
                 )
 
-        fn = fn0
-        n_rep = len(ens_dev)
+        return (self._row_sharded(fn0, len(ens_dev), ce.n_classes_out),
+                ens_dev, resolved, ce.n_classes_out, plan)
+
+    def _build_paths_fn(self, ens, ce: CompiledNodeList):
+        """_build_predict_fn for a NODE LIST: its path tables up, and the
+        path-matrix scoring program (ops/predict.predict_raw_effective_
+        paths: the Pallas kernel where the dispatch rule takes it, else
+        the jax.numpy form). The plan is an ops/predict_paths.PathPlan."""
+        from ddt_tpu.ops import predict_paths
+
+        if self.cfg.predict_impl in ("lut", "lut4"):
+            log.warning(
+                "predict_impl=%r: the quantized tiers have no node-list "
+                "form; the f32 path-matrix form serves",
+                self.cfg.predict_impl)
+        ens_dev = tuple(self._put(a, self._named(
+            self.layout.replicated())) for a in ce.arrays())
+        use_pallas = self._use_pallas
+        plan = predict_paths.path_plan(
+            ce.n_trees, ce.lanes, ens.n_features, ce.deepest_leaf,
+            served=predict_ops.resolve_use_pallas(
+                use_pallas, True, 0, ens.n_features, 1,
+                path_lanes=ce.lanes))
+
+        def fn0(sel, planes, paths, Xc):
+            return predict_ops.predict_raw_effective_paths(
+                sel, planes, paths, Xc,
+                learning_rate=ce.learning_rate, base=ce.base_score,
+                use_pallas=use_pallas)
+
+        return self._row_sharded(fn0, 3, 1), ens_dev, "f32", 1, plan
+
+    def _row_sharded(self, fn, n_rep: int, C: int):
+        """`fn(*replicated tables, rows)` as it runs on this backend:
+        itself on one chip, row-sharded over the mesh otherwise."""
         if self.distributed:
             # Row-sharded scoring is embarrassingly parallel: trees are
             # replicated, each shard traverses its own rows, no collectives
@@ -1945,7 +1981,6 @@ class TPUDevice(DeviceBackend):
             # sharding explicit — XLA cannot infer it through the
             # take_along_axis traversal.
             lay = self.layout
-            C = ce.n_classes_out
             out_spec = lay.row_vector() if C == 1 else lay.row_matrix()
             # jit: a bare shard_map runs eagerly and re-compiles its body
             # on EVERY call (found on the chip, PR 21: 1.4 s a call for
@@ -1963,4 +1998,4 @@ class TPUDevice(DeviceBackend):
                 # here (no collectives anywhere in the traversal).
                 check_vma=False,
             ))
-        return fn, ens_dev, resolved, ce.n_classes_out, plan
+        return fn

@@ -98,7 +98,8 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     (time.perf_counter_ns): token, ensemble, upload, dispatch, fetch,
     place, and with them counts that are no times: the traversal
     kernel's table plan as the model's `ensemble` span recorded it
-    (ops/predict_pallas.PHASES_COUNTS; all 0: the kernel does not serve
+    (ops/predict_pallas.PHASES_COUNTS and, for a node-list model,
+    ops/predict_paths.PHASES_COUNTS; all 0: that kernel does not serve
     the model) and `tables_streamed_bytes`, the root spans' sum over the
     calls. docs/OBSERVABILITY.md has the table of what each means. None
     when no such call ran: the NumPy backend and raw-threshold scoring
@@ -108,6 +109,7 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     roots = [r for r in root_spans("predict") if r["start"] >= since_ns]
     if not roots:
         return None
+    from ddt_tpu.ops import predict_paths
     from ddt_tpu.ops.predict_pallas import PHASES_COUNTS
 
     ms = dict.fromkeys(
@@ -119,7 +121,11 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
             if step in ms:
                 ms[step] += (s["end"] - s["start"]) / 1e6
             if step == "ensemble":
-                plan = {k: s["counts"][k] for k in plan}
+                # a heap model's span has the heap kernel's counts, a
+                # node list's the path form's
+                plan = {k: s["counts"][k] for k in (
+                    PHASES_COUNTS + predict_paths.PHASES_COUNTS)
+                    if k in s["counts"]}
     return {**{k: round(v, 3) for k, v in ms.items()}, **plan,
             "tables_streamed_bytes": sum(
                 r["counts"]["tables_streamed_bytes"] for r in roots)}
@@ -1416,7 +1422,7 @@ def main(argv: list[str] | None = None) -> int:
             "n_classes": ens.n_classes,
             "learning_rate": ens.learning_rate,
             "base_score": ens.base_score,
-            "n_splits": int(((~ens.is_leaf) & (ens.feature >= 0)).sum()),
+            "n_splits": ens.n_splits,
             "has_raw_thresholds": bool(ens.has_raw_thresholds),
             f"top_features_by_{args.importance}": {
                 int(f): round(float(imp[f]), 5) for f in top if imp[f] > 0

@@ -8,6 +8,22 @@ can load models trained here. `from_lightgbm_text` is the repo's own
 re-parser: the round-trip test (export -> parse -> identical predictions)
 keeps the writer honest without LightGBM installed.
 
+Which layout an import yields. `from_lightgbm_text` returns the HEAP
+(`TreeEnsemble`, [T, 2^(depth+1)-1]) for trees the heap traversal kernel
+serves, at most `HEAP_MAX_DEPTH` levels, and the NODE LIST
+(`NodeListEnsemble`: LightGBM's own arrays, 2 x num_leaves - 1 entries a
+tree) for deeper ones: the shape decides, there is no flag. LightGBM grows
+leaf-wise, so its published settings (num_leaves=255) give trees 13-20
+levels deep whose heap would be 2^21 slots for 509 entries; the node list
+holds them as they are and the path-matrix kernel (ops/predict_paths.py)
+scores them. The node list carries ordinal splits and one output column:
+a deep model with NaN default directions, category sets or several classes
+still imports as a heap, as before, as far as a heap can hold it (depth 30,
+2^27 slots), and past that is refused with the mechanism named. An import
+carries RAW thresholds only; `threshold_bin_mapper` ranks them into bins
+and returns the BinMapper whose edges they are, after which the model
+scores binned rows on the device exactly as it scores raw ones on the host.
+
 Format notes (LightGBM's text serialization, stable since v2):
 - one `Tree=<i>` block per tree; arrays are space-separated lines
 - internal nodes are numbered 0..num_leaves-2, leaves 0..num_leaves-1;
@@ -53,7 +69,15 @@ import warnings
 
 import numpy as np
 
-from ddt_tpu.models.tree import TreeEnsemble
+from ddt_tpu.models.tree import (NodeListEnsemble, TreeEnsemble,
+                                 _refuse_routes, node_list_from_trees)
+
+# The deepest heap an import yields: what the heap traversal kernel's VMEM
+# plan takes at any feature count (ops/predict_pallas.predict_pallas_fits,
+# unrouted; tests/test_node_list.py holds the two together). At 11 levels
+# that kernel asks 8 MXU weight tiles a tree, the path-matrix form 6 for
+# up to 256 leaves.
+HEAP_MAX_DEPTH = 11
 
 _MISSING_NAN = 2 << 2        # decision_type missing-type field: NaN
 _DEFAULT_LEFT = 2            # decision_type default-left bit
@@ -76,9 +100,56 @@ def _fmt_int(values) -> str:
     return " ".join(str(int(v)) for v in values)
 
 
-def to_lightgbm_text(ens: TreeEnsemble,
+def _tree_block(t: int, n_leaves: int, fields: dict, shrinkage: float,
+                n_cat: int = 0) -> list[str]:
+    """One `Tree=<t>` block's lines from its arrays (`fields`, in
+    LightGBM's order); the statistics this repo does not keep are zeros."""
+    n_int = max(1, n_leaves - 1)
+    lines = [f"Tree={t}", f"num_leaves={n_leaves}", f"num_cat={n_cat}"]
+    for k in ("split_feature", "split_gain", "threshold", "decision_type",
+              "left_child", "right_child", "leaf_value"):
+        fmt = _fmt if k in ("split_gain", "threshold", "leaf_value") \
+            else _fmt_int
+        lines.append(f"{k}=" + fmt(fields[k]))
+    lines += [
+        "leaf_weight=" + _fmt([0.0] * n_leaves),
+        "leaf_count=" + _fmt_int([0] * n_leaves),
+        "internal_value=" + _fmt([0.0] * n_int),
+        "internal_weight=" + _fmt([0.0] * n_int),
+        "internal_count=" + _fmt_int([0] * n_int),
+    ]
+    if n_cat:
+        lines += ["cat_boundaries=" + _fmt_int(fields["cat_boundaries"]),
+                  "cat_threshold=" + _fmt_int(fields["cat_threshold"])]
+    return lines + ["is_linear=0", f"shrinkage={shrinkage:.17g}", ""]
+
+
+def _node_list_blocks(ens: NodeListEnsemble) -> list[str]:
+    """The `Tree=` blocks of a node list: its arrays as they are (they are
+    LightGBM's), leaf values with the shrinkage applied and the base score
+    folded into tree 0."""
+    lines: list[str] = []
+    for t in range(ens.n_trees):
+        L = int(ens.n_leaves[t])
+        n = L - 1
+        lv = ens.leaf_value[t, :L].astype(np.float64) * ens.learning_rate
+        if t == 0:
+            lv = lv + ens.base_score
+        lines += _tree_block(t, L, {
+            "split_feature": ens.feature[t, :n],
+            "split_gain": ens.split_gain[t, :n],
+            "threshold": ens.threshold_raw[t, :n],
+            "decision_type": [0] * n,
+            "left_child": ens.left_child[t, :n],
+            "right_child": ens.right_child[t, :n],
+            "leaf_value": lv,
+        }, ens.learning_rate)
+    return lines
+
+
+def to_lightgbm_text(ens: "TreeEnsemble | NodeListEnsemble",
                      feature_names: list[str] | None = None) -> str:
-    """Render the ensemble as a LightGBM model.txt string."""
+    """Render the ensemble (either layout) as a LightGBM model.txt string."""
     if not ens.has_raw_thresholds:
         raise ValueError(
             "LightGBM export needs raw-value thresholds; train through a "
@@ -102,6 +173,9 @@ def to_lightgbm_text(ens: TreeEnsemble,
         "feature_infos=" + " ".join(["[-inf:inf]"] * ens.n_features),
         "",
     ]
+    if isinstance(ens, NodeListEnsemble):
+        return "\n".join(lines + _node_list_blocks(ens) + [
+            "end of trees", "", "pandas_categorical:null", ""])
     use_missing = ens.missing_bin and ens.default_left is not None
     if use_missing and cat_set:
         warnings.warn(
@@ -164,37 +238,13 @@ def to_lightgbm_text(ens: TreeEnsemble,
             return i
 
         walk(0)
-        n_leaves = len(leaf_value)
-        n_cat = len(cat_boundaries) - 1
-        zeros = [0.0] * n_leaves
-        izeros = [0] * max(1, n_leaves - 1)
-        lines += [
-            f"Tree={t}",
-            f"num_leaves={n_leaves}",
-            f"num_cat={n_cat}",
-            "split_feature=" + _fmt_int(split_feature),
-            "split_gain=" + _fmt(split_gain),
-            "threshold=" + _fmt(threshold),
-            "decision_type=" + _fmt_int(decision_type),
-            "left_child=" + _fmt_int(left_child),
-            "right_child=" + _fmt_int(right_child),
-            "leaf_value=" + _fmt(leaf_value),
-            "leaf_weight=" + _fmt(zeros),
-            "leaf_count=" + _fmt_int([0] * n_leaves),
-            "internal_value=" + _fmt([0.0] * max(1, n_leaves - 1)),
-            "internal_weight=" + _fmt([0.0] * max(1, n_leaves - 1)),
-            "internal_count=" + _fmt_int(izeros),
-        ]
-        if n_cat:
-            lines += [
-                "cat_boundaries=" + _fmt_int(cat_boundaries),
-                "cat_threshold=" + _fmt_int(cat_threshold),
-            ]
-        lines += [
-            "is_linear=0",
-            f"shrinkage={ens.learning_rate:.17g}",
-            "",
-        ]
+        lines += _tree_block(t, len(leaf_value), {
+            "split_feature": split_feature, "split_gain": split_gain,
+            "threshold": threshold, "decision_type": decision_type,
+            "left_child": left_child, "right_child": right_child,
+            "leaf_value": leaf_value, "cat_boundaries": cat_boundaries,
+            "cat_threshold": cat_threshold,
+        }, ens.learning_rate, n_cat=len(cat_boundaries) - 1)
     lines += ["end of trees", "", "pandas_categorical:null", ""]
     return "\n".join(lines)
 
@@ -208,15 +258,89 @@ def _parse_block(lines: list[str], i: int) -> tuple[dict, int]:
     return d, i
 
 
-def from_lightgbm_text(text: str) -> TreeEnsemble:
-    """Parse a LightGBM model.txt back into a TreeEnsemble (heap layout).
+def _node_list_of(trees: list, **meta) -> NodeListEnsemble:
+    """The parsed `Tree=` blocks as a node list: LightGBM's arrays as they
+    are. Raw thresholds only (`threshold_bin_mapper` ranks them)."""
+    def ints(blk, k):
+        return [int(v) for v in blk[k].split()]
+
+    def floats(blk, k):
+        return [float(v) for v in blk[k].split()]
+
+    per_tree = []
+    for blk in trees:
+        nodes = []
+        if int(blk["num_leaves"]) > 1:
+            sf, th = ints(blk, "split_feature"), floats(blk, "threshold")
+            nodes = list(zip(sf, [0] * len(sf), th, floats(blk, "split_gain"),
+                             ints(blk, "left_child"),
+                             ints(blk, "right_child")))
+        per_tree.append((nodes, floats(blk, "leaf_value")))
+    return node_list_from_trees(
+        per_tree, learning_rate=1.0,    # leaf values are final contributions
+        base_score=0.0,                 # folded into tree 0's leaves
+        has_raw_thresholds=True, has_bin_thresholds=False, **meta)
+
+
+def threshold_bin_mapper(ens: "TreeEnsemble | NodeListEnsemble",
+                         n_bins: int = 255):
+    """The bin mapper FROM THE MODEL'S OWN THRESHOLDS, and the model's
+    `threshold_bin` filled to match: per feature the sorted distinct raw
+    thresholds are the mapper's edges and a node's bin is its threshold's
+    rank, so `x <= t` is `bin(x) <= rank(t)` EXACTLY for every float32 x
+    (data/quantizer.py: bin = the number of edges below x). An imported
+    model carries raw thresholds only; with this mapper it reaches the
+    binned device path:
+
+        ens = TreeEnsemble.from_lightgbm_text(text)
+        mapper = threshold_bin_mapper(ens)
+        api.predict(ens, X_float, mapper=mapper, cfg=cfg)
+
+    A feature may carry at most `n_bins - 1` distinct thresholds (254 under
+    LightGBM's max_bin=255); more is refused by name. Ordinal splits only.
+    NaN rows take bin 0 (the mapper's "zero" policy) where the raw walk
+    sends them right: bin them yourself if the data has any."""
+    from ddt_tpu.data.quantizer import BinMapper
+
+    if not ens.has_raw_thresholds:
+        raise ValueError("threshold_bin_mapper needs raw thresholds")
+    if ens.missing_bin or ens.has_cat_splits:
+        raise ValueError(
+            "threshold_bin_mapper covers ordinal splits: this model "
+            "carries NaN default directions or category nodes, whose bins "
+            "are not ranks of thresholds")
+    live = (ens.live_nodes if isinstance(ens, NodeListEnsemble)
+            else ~ens.is_leaf & (ens.feature >= 0))
+    edges = np.full((ens.n_features, n_bins - 1), np.inf, np.float32)
+    for f in range(ens.n_features):
+        at = live & (ens.feature == f)
+        distinct = np.unique(ens.threshold_raw[at])
+        if len(distinct) > n_bins - 1:
+            raise ValueError(
+                f"feature {f} carries {len(distinct)} distinct thresholds, "
+                f"more than the {n_bins - 1} edges of {n_bins} bins "
+                "(a model trained with max_bin > 255?): it cannot be "
+                "scored on binned uint8 rows")
+        edges[f, :len(distinct)] = distinct
+        ens.threshold_bin[at] = np.searchsorted(distinct,
+                                                ens.threshold_raw[at])
+    if isinstance(ens, NodeListEnsemble):
+        ens.has_bin_thresholds = True
+    ens.n_bins = n_bins
+    return BinMapper(edges=edges, n_bins=n_bins)
+
+
+def from_lightgbm_text(text: str) -> "TreeEnsemble | NodeListEnsemble":
+    """Parse a LightGBM model.txt back into an ensemble: a TreeEnsemble
+    (heap) for trees of at most HEAP_MAX_DEPTH levels, a NodeListEnsemble
+    for deeper ordinal ones (module docstring, 'Which layout').
 
     Supports what to_lightgbm_text writes (numerical splits, single-bit
     categorical nodes, optional NaN-missing default directions) PLUS
     externally-trained models with multi-category bitsets, which expand
     into equivalent one-vs-rest chains (module docstring, 'Import
-    breadth'). Trees deeper than 30 levels after chain expansion overflow
-    the heap and raise."""
+    breadth'). Routed or multiclass trees deeper than 30 levels after
+    chain expansion overflow the heap and raise."""
     lines = text.splitlines()
     head, i = _parse_block(lines, 0)
     n_features = int(head["max_feature_idx"]) + 1
@@ -301,10 +425,18 @@ def from_lightgbm_text(text: str) -> TreeEnsemble:
 
     max_depth = max(1, max(depth_of(b, bi)
                            for b, bi in zip(trees, tree_bits)))
+    nan_routes = any((int(float(v)) >> 2) == 2 for b in trees
+                     for v in b.get("decision_type", "").split())
+    categories = any(b is not None for bi in tree_bits for b in bi)
+    if max_depth > HEAP_MAX_DEPTH and not (nan_routes or categories
+                                           or C > 1):
+        return _node_list_of(trees, n_features=n_features, loss=loss,
+                             n_classes=max(C, 2))
     if max_depth > 30:
-        raise ValueError(
-            f"tree depth {max_depth} (after multi-category chain "
-            "expansion) overflows the heap layout")
+        _refuse_routes(f"from_lightgbm_text (tree depth {max_depth} after "
+                       "multi-category chain expansion overflows the heap "
+                       "layout)", missing=nan_routes, categories=categories,
+                       classes=C > 1)
     # The heap is DENSE and its depth is GLOBAL: one k-category set deep
     # in one tree adds k-1 levels to EVERY tree's 2^(D+1)-1 node arrays.
     # Real LightGBM categorical splits routinely carry dozens of
@@ -320,8 +452,10 @@ def from_lightgbm_text(text: str) -> TreeEnsemble:
             f"across {len(trees)} trees = {total_nodes} heap nodes "
             f"(> 2^27): the dense heap layout cannot hold this model "
             f"(widest category set: {widest} bits). Models with large "
-            "categorical sets are unrepresentable here; score them with "
-            "LightGBM itself, or retrain with "
+            "categorical sets are unrepresentable here (the node-list "
+            "layout, which holds deep trees as they are, does not support "
+            "category sets, NaN default directions or several classes "
+            "yet); score them with LightGBM itself, or retrain with "
             "cat_features one-vs-rest splits"
         )
     n_nodes = 2 ** (max_depth + 1) - 1

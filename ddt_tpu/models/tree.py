@@ -75,6 +75,11 @@ class TreeEnsemble:
         single home of the cat_features presence test)."""
         return self.cat_features is not None and len(self.cat_features) > 0
 
+    @property
+    def n_splits(self) -> int:
+        """Internal nodes that split, over all trees."""
+        return int(((~self.is_leaf) & (self.feature >= 0)).sum())
+
     # ------------------------------------------------------------------ #
     # compiled scoring layout (device predict fast path)
     # ------------------------------------------------------------------ #
@@ -248,18 +253,8 @@ class TreeEnsemble:
         kind="split": fraction of internal-node splits using the feature;
         kind="gain": fraction of total split gain attributed to the feature
         (LightGBM's importance_type="split"/"gain")."""
-        mask = (~self.is_leaf) & (self.feature >= 0)
-        used = self.feature[mask]
-        if kind == "split":
-            w = np.ones(used.shape[0])
-        elif kind == "gain":
-            w = self.split_gain[mask].astype(np.float64)
-        else:
-            raise ValueError(f"unknown importance kind {kind!r}")
-        counts = np.bincount(used, weights=w, minlength=self.n_features)
-        counts = counts[: self.n_features].astype(np.float64)
-        tot = counts.sum()
-        return (counts / tot if tot > 0 else counts).astype(np.float32)
+        return _importances(self, (~self.is_leaf) & (self.feature >= 0),
+                            kind)
 
     def dump(self, tree: int) -> dict:
         """One tree as a nested plain-Python dict (debugging / interop).
@@ -401,9 +396,10 @@ class TreeEnsemble:
         atomic_savez(path, compressed=True, deterministic=True, **d)
 
     @staticmethod
-    def load(path: str) -> "TreeEnsemble":
+    def load(path: str) -> "TreeEnsemble | NodeListEnsemble":
+        """The saved ensemble, in the layout it was saved in."""
         with np.load(path) as d:
-            return TreeEnsemble.from_dict(dict(d))
+            return ensemble_from_dict(dict(d))
 
     def _dl(self) -> np.ndarray:
         return (self.default_left if self.default_left is not None
@@ -586,6 +582,476 @@ class CompiledEnsemble:
             bot_val=np.ascontiguousarray(ev[:, lo:]),
             cls_oh=cls_oh, eff_dl=eff_dl, eff_cat=eff_cat,
         )
+
+
+# ---------------------------------------------------------------------- #
+# The NODE LIST: the second ensemble layout
+# ---------------------------------------------------------------------- #
+
+# Lanes a node-list tree's nodes and leaves are padded to in its compiled
+# tables: one vreg's lanes, one MXU weight tile's columns.
+PATH_LANES = 128
+
+
+@dataclasses.dataclass
+class NodeListEnsemble:
+    """A boosted ensemble whose trees are NODE LISTS, not heaps: tree t is
+    `n_leaves[t] - 1` internal nodes and `n_leaves[t]` leaves in LightGBM's
+    own numbering. A row starts at node 0; at internal node n it goes LEFT,
+    to `left_child[n]`, when `bin[feature[n]] <= threshold_bin[n]` (raw
+    rows: `value <= threshold_raw[n]`), else RIGHT, to `right_child[n]`; a
+    negative child c is leaf `~c` and the tree scores `leaf_value[~c]`. A
+    tree of one leaf has no node and scores `leaf_value[0]`.
+
+    This is the layout of a tree that is deep and sparse, as best-first
+    (leaf-wise) growth makes them: 255 leaves 13-20 levels down are 509
+    entries here and 2^21 heap slots in `TreeEnsemble`. The trainer writes
+    heaps; a node list is an import (`models/lightgbm_io.py`), a conversion
+    (`from_heap`) or hand-built. Ordinal splits and one output column
+    only: learned directions for missing values, category sets and several
+    classes have no field here, and `from_lightgbm_text` / `from_heap` /
+    the constructor refuse them by name (the heap layout serves them).
+
+    Node arrays are [T, N] and `leaf_value` [T, L], N and L the widest
+    tree's counts; a tree's unused slots hold feature -1, children 0 and
+    value 0 and are never visited."""
+
+    feature: np.ndarray        # int32  [T, N] split feature (-1: unused)
+    threshold_bin: np.ndarray  # int32  [T, N] split bin (go left if <=)
+    threshold_raw: np.ndarray  # float32 [T, N] raw-value threshold
+    left_child: np.ndarray     # int32  [T, N] node index, or ~leaf
+    right_child: np.ndarray    # int32  [T, N]
+    leaf_value: np.ndarray     # float32 [T, L]
+    n_leaves: np.ndarray       # int32  [T] leaves of each tree (>= 1)
+    split_gain: np.ndarray     # float32 [T, N]
+    n_features: int
+    learning_rate: float
+    base_score: float
+    loss: str                  # logloss | mse
+    n_classes: int = 2
+    has_raw_thresholds: bool = False
+    # False: threshold_bin is not filled yet (an import carries raw
+    # thresholds only, until `lightgbm_io.threshold_bin_mapper` ranks them).
+    has_bin_thresholds: bool = True
+    n_bins: int = 0
+
+    # What a heap ensemble answers for, so that scoring entry points ask
+    # one question of either layout.
+    missing_bin = False
+    has_cat_splits = False
+    cat_features = None
+    default_left = None
+
+    def __post_init__(self):
+        if self.loss == "softmax":
+            raise ValueError(
+                "a node-list ensemble scores ONE output column: several "
+                "classes (softmax, round-major trees) are not supported in "
+                "this layout; the heap layout (TreeEnsemble) serves them")
+        T, N = self.feature.shape
+        L = self.leaf_value.shape[1]
+        if self.n_leaves.shape != (T,) or int(self.n_leaves.min(
+                initial=1)) < 1 or int(self.n_leaves.max(initial=1)) > min(
+                    L, N + 1):
+            raise ValueError(
+                f"n_leaves must be [trees] in 1..{min(L, N + 1)} for node "
+                f"arrays [{T}, {N}] and leaf values [{T}, {L}]")
+
+    @property
+    def n_trees(self) -> int:
+        return int(self.feature.shape[0])
+
+    @property
+    def live_nodes(self) -> np.ndarray:
+        """bool [T, N]: the node slots that hold a tree's internal node."""
+        return np.arange(self.feature.shape[1])[None, :] \
+            < (self.n_leaves[:, None] - 1)
+
+    def path_matrix(self) -> "tuple[np.ndarray, np.ndarray]":
+        """(signed path matrix int8 [T, N, L], path length int32 [T, L]):
+        `P[t, n, l]` is +1 where leaf l lies in node n's RIGHT subtree, -1
+        where in its LEFT, 0 elsewhere; `len[t, l]` counts the nodes on
+        leaf l's path (-1: no such leaf). Walking every leaf up to the
+        root also proves the lists are trees: each node and leaf has one
+        parent and the root is reached. Not cached: node arrays may be
+        mutated in place (0.4 s for 500 trees of 255 leaves)."""
+        T, N = self.feature.shape
+        L = self.leaf_value.shape[1]
+        n_int = self.n_leaves.astype(np.int64) - 1
+        live = self.live_nodes
+        node_parent = np.full((T, N), -1, np.int64)
+        node_side = np.zeros((T, N), np.int8)
+        leaf_parent = np.full((T, L), -1, np.int64)
+        leaf_side = np.zeros((T, L), np.int8)
+        seen_n = np.zeros((T, N), np.int64)
+        seen_l = np.zeros((T, L), np.int64)
+        t_idx, n_idx = np.nonzero(live)
+        for child, side in ((self.left_child, -1), (self.right_child, 1)):
+            c = child[t_idx, n_idx].astype(np.int64)
+            to_leaf = c < 0
+            lt, ll, lp = t_idx[to_leaf], ~c[to_leaf], n_idx[to_leaf]
+            nt, nn, np_ = t_idx[~to_leaf], c[~to_leaf], n_idx[~to_leaf]
+            if (ll >= self.n_leaves[lt]).any() or (
+                    nn >= n_int[nt]).any() or (nn == 0).any():
+                raise ValueError("node list: a child index points outside "
+                                 "its tree, or back at the root")
+            leaf_parent[lt, ll], leaf_side[lt, ll] = lp, side
+            node_parent[nt, nn], node_side[nt, nn] = np_, side
+            np.add.at(seen_l, (lt, ll), 1)
+            np.add.at(seen_n, (nt, nn), 1)
+        has_leaf = np.arange(L)[None, :] < self.n_leaves[:, None]
+        lone = (self.n_leaves == 1)[:, None]
+        if not np.array_equal(seen_l == 1, has_leaf & ~lone) or \
+                not np.array_equal(seen_n[:, 1:] == 1, live[:, 1:]):
+            raise ValueError("node list: not every node and leaf has "
+                             "exactly one parent")
+        P = np.zeros((T, N, L), np.int8)
+        plen = np.where(has_leaf, 0, -1).astype(np.int32)
+        tt, ll = np.nonzero(has_leaf & ~lone)
+        cur, sign = leaf_parent[tt, ll], leaf_side[tt, ll]
+        for _ in range(N + 1):
+            if not len(tt):
+                break
+            P[tt, cur, ll] = sign
+            plen[tt, ll] += 1
+            sign, cur = node_side[tt, cur], node_parent[tt, cur]
+            up = cur >= 0
+            tt, ll, cur, sign = tt[up], ll[up], cur[up], sign[up]
+        else:
+            raise ValueError("node list: a cycle among the nodes")
+        return P, plen
+
+    @property
+    def deepest_leaf(self) -> int:
+        """Nodes on the longest root-to-leaf path of any tree."""
+        return int(self.path_matrix()[1].max(initial=0))
+
+    max_depth = deepest_leaf       # what `cli inspect` prints of a heap
+
+    @property
+    def n_splits(self) -> int:
+        """Internal nodes, over all trees."""
+        return int((self.n_leaves - 1).sum())
+
+    # ------------------------------------------------------------------ #
+
+    def cache_token(self) -> str:
+        """Content digest of what the device scoring program depends on
+        (the compiled-ensemble cache key, as `TreeEnsemble.cache_token`)."""
+        h = hashlib.sha1(b"node_list")
+        for a in (self.feature, self.threshold_bin, self.left_child,
+                  self.right_child, self.leaf_value, self.n_leaves):
+            h.update(np.ascontiguousarray(a).tobytes())
+        h.update(repr((self.learning_rate, self.base_score, self.loss,
+                       self.n_features)).encode())
+        return h.hexdigest()
+
+    def compile(self, tree_chunk: int = 64) -> "CompiledNodeList":
+        """Host-side compiled scoring tables (see CompiledNodeList;
+        `tree_chunk` is the heap layout's and means nothing here)."""
+        return CompiledNodeList.build(self)
+
+    # ------------------------------------------------------------------ #
+
+    def _leaf_np(self, X: np.ndarray, binned: bool) -> np.ndarray:
+        """Leaf index per (tree, row), int64 [T, R]: the node walk."""
+        if binned and not self.has_bin_thresholds:
+            raise ValueError(
+                "this node-list ensemble carries raw thresholds only; rank "
+                "them first (models/lightgbm_io.threshold_bin_mapper), or "
+                "predict on raw values")
+        if not binned and not self.has_raw_thresholds:
+            raise ValueError(
+                "Ensemble has no raw-value thresholds; predict on binned "
+                "data with binned=True")
+        thr = self.threshold_bin if binned else self.threshold_raw
+        Xc = X.astype(np.int32) if binned else X.astype(np.float32)
+        T, R = self.n_trees, X.shape[0]
+        rows = np.arange(R)
+        # ~0 from the start in a tree of one leaf
+        cur = np.where(self.n_leaves > 1, 0, -1)[:, None].repeat(R, 1)
+        while (cur >= 0).any():
+            at = np.maximum(cur, 0)
+            feat = np.take_along_axis(self.feature, at, axis=1)
+            fv = np.stack([Xc[rows, np.maximum(feat[t], 0)]
+                           for t in range(T)])
+            nxt = np.where(fv > np.take_along_axis(thr, at, axis=1),
+                           np.take_along_axis(self.right_child, at, axis=1),
+                           np.take_along_axis(self.left_child, at, axis=1))
+            cur = np.where(cur >= 0, nxt, cur)
+        return ~cur
+
+    def predict_raw(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
+        """Raw (margin) scores [R], float32."""
+        leaf = self._leaf_np(np.asarray(X), binned)
+        vals = np.take_along_axis(self.leaf_value, leaf, axis=1)
+        vals = vals * np.float32(self.learning_rate)
+        return (self.base_score + vals.sum(axis=0)).astype(np.float32)
+
+    def predict(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
+        """Probability predictions (or raw values for mse)."""
+        from ddt_tpu.utils.metrics import predict_proba_np
+
+        return predict_proba_np(self.predict_raw(X, binned=binned),
+                                self.loss)
+
+    # ------------------------------------------------------------------ #
+
+    @staticmethod
+    def from_heap(ens: TreeEnsemble) -> "NodeListEnsemble":
+        """The same trees as a node list, nodes and leaves numbered in
+        pre-order (root = node 0). A heap ensemble with learned NaN
+        directions, category nodes or several classes is refused."""
+        _refuse_routes(
+            "from_heap",
+            missing=ens.missing_bin and ens.default_left is not None,
+            categories=ens.has_cat_splits, classes=ens.loss == "softmax")
+        trees = []
+        for t in range(ens.n_trees):
+            nodes, leaves = [], []
+
+            def walk(slot: int) -> int:
+                if ens.is_leaf[t, slot] or ens.feature[t, slot] < 0:
+                    leaves.append(ens.leaf_value[t, slot])
+                    return -len(leaves)
+                i = len(nodes)
+                nodes.append([ens.feature[t, slot],
+                              ens.threshold_bin[t, slot],
+                              ens.threshold_raw[t, slot],
+                              ens.split_gain[t, slot], 0, 0])
+                nodes[i][4] = walk(2 * slot + 1)
+                nodes[i][5] = walk(2 * slot + 2)
+                return i
+
+            walk(0)
+            trees.append((nodes, leaves))
+        return node_list_from_trees(
+            trees, n_features=ens.n_features,
+            learning_rate=ens.learning_rate, base_score=ens.base_score,
+            loss=ens.loss, n_classes=ens.n_classes,
+            has_raw_thresholds=ens.has_raw_thresholds, n_bins=ens.n_bins)
+
+    def feature_importances(self, kind: str = "split") -> np.ndarray:
+        """As `TreeEnsemble.feature_importances`, over the live nodes."""
+        return _importances(self, self.live_nodes, kind)
+
+    def dump_text(self, tree: int) -> str:
+        """Indented text rendering of one tree, as TreeEnsemble.dump_text."""
+        t, lines = int(tree), []
+
+        def walk(ref: int, depth: int) -> None:
+            pad = "  " * depth
+            if ref < 0:
+                lines.append(f"{pad}leaf={self.leaf_value[t, ~ref]:+.6f}")
+                return
+            thr = (f" (<= {self.threshold_raw[t, ref]:.6g})"
+                   if self.has_raw_thresholds else "")
+            lines.append(f"{pad}f{self.feature[t, ref]} <= bin "
+                         f"{self.threshold_bin[t, ref]}{thr}  "
+                         f"gain={self.split_gain[t, ref]:.4g}")
+            walk(int(self.left_child[t, ref]), depth + 1)
+            walk(int(self.right_child[t, ref]), depth + 1)
+
+        walk(0 if self.n_leaves[t] > 1 else -1, 0)
+        return "\n".join(lines)
+
+    def to_lightgbm_text(self, feature_names: list[str] | None = None
+                         ) -> str:
+        """LightGBM model.txt rendering (models/lightgbm_io.py)."""
+        from ddt_tpu.models.lightgbm_io import to_lightgbm_text
+
+        return to_lightgbm_text(self, feature_names=feature_names)
+
+    _ARRAYS = (("feature", np.int32), ("threshold_bin", np.int32),
+               ("threshold_raw", np.float32), ("left_child", np.int32),
+               ("right_child", np.int32), ("leaf_value", np.float32),
+               ("n_leaves", np.int32), ("split_gain", np.float32))
+
+    def to_dict(self) -> dict:
+        d = {k: getattr(self, k) for k, _ in self._ARRAYS}
+        d.update(
+            layout=np.bytes_(b"node_list"),
+            n_features=np.int64(self.n_features),
+            learning_rate=np.float64(self.learning_rate),
+            base_score=np.float64(self.base_score),
+            loss=np.bytes_(self.loss.encode()),
+            n_classes=np.int64(self.n_classes),
+            has_raw_thresholds=np.bool_(self.has_raw_thresholds),
+            has_bin_thresholds=np.bool_(self.has_bin_thresholds),
+            n_bins=np.int64(self.n_bins))
+        return d
+
+    @staticmethod
+    def from_dict(d: dict) -> "NodeListEnsemble":
+        return NodeListEnsemble(
+            **{k: np.asarray(d[k], dt) for k, dt in NodeListEnsemble._ARRAYS},
+            n_features=int(d["n_features"]),
+            learning_rate=float(d["learning_rate"]),
+            base_score=float(d["base_score"]),
+            loss=bytes(d["loss"]).decode(),
+            n_classes=int(d["n_classes"]),
+            has_raw_thresholds=bool(d["has_raw_thresholds"]),
+            has_bin_thresholds=bool(d["has_bin_thresholds"]),
+            n_bins=int(d["n_bins"]))
+
+    save = TreeEnsemble.save       # to_dict, the manifest, one atomic npz
+
+
+def _importances(ens, nodes: np.ndarray, kind: str) -> np.ndarray:
+    """Normalized per-feature importance over the splitting `nodes` (a
+    mask of the node arrays) of either layout."""
+    used = ens.feature[nodes]
+    if kind == "split":
+        w = np.ones(used.shape[0])
+    elif kind == "gain":
+        w = ens.split_gain[nodes].astype(np.float64)
+    else:
+        raise ValueError(f"unknown importance kind {kind!r}")
+    counts = np.bincount(used, weights=w, minlength=ens.n_features)
+    counts = counts[: ens.n_features].astype(np.float64)
+    tot = counts.sum()
+    return (counts / tot if tot > 0 else counts).astype(np.float32)
+
+
+def ensemble_from_dict(d: dict) -> "TreeEnsemble | NodeListEnsemble":
+    """The ensemble a saved artifact holds, in the layout it was saved in
+    (`layout` is written by the node list alone; a heap has no such key)."""
+    if "layout" in d and bytes(d["layout"]) == b"node_list":
+        return NodeListEnsemble.from_dict(d)
+    return TreeEnsemble.from_dict(d)
+
+
+def _refuse_routes(where: str, *, missing: bool, categories: bool,
+                   classes: bool) -> None:
+    """The one list of what a node list cannot carry, named."""
+    for has, what in (
+            (missing, "learned default directions for missing values "
+                      "(NaN bin, default_left)"),
+            (categories, "category-set (one-vs-rest / bitset) nodes"),
+            (classes, "several classes (softmax, round-major trees)")):
+        if has:
+            raise ValueError(
+                f"{where}: the model needs the node-list layout (a tree "
+                f"too deep for the heap) and carries {what}, which the "
+                "node-list layout does not support yet; the heap layout "
+                "serves them for trees it can hold")
+
+
+def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
+    """A NodeListEnsemble from per-tree lists: `trees[t] = (nodes, leaves)`,
+    `nodes[n] = (feature, threshold_bin, threshold_raw, gain, left,
+    right)`, `leaves[l]` the leaf's value."""
+    T = len(trees)
+    N = max(1, max(len(n) for n, _ in trees))
+    L = max(len(lv) for _, lv in trees)
+    out = dict(
+        feature=np.full((T, N), -1, np.int32),
+        threshold_bin=np.zeros((T, N), np.int32),
+        threshold_raw=np.zeros((T, N), np.float32),
+        split_gain=np.zeros((T, N), np.float32),
+        left_child=np.zeros((T, N), np.int32),
+        right_child=np.zeros((T, N), np.int32),
+        leaf_value=np.zeros((T, L), np.float32),
+        n_leaves=np.asarray([len(lv) for _, lv in trees], np.int32))
+    keys = ("feature", "threshold_bin", "threshold_raw", "split_gain",
+            "left_child", "right_child")
+    for t, (nodes, leaves) in enumerate(trees):
+        if nodes:
+            for k, col in zip(keys, zip(*nodes)):
+                out[k][t, :len(nodes)] = col
+        out["leaf_value"][t, :len(leaves)] = leaves
+    return NodeListEnsemble(**out, **meta)
+
+
+def random_node_list(rng, n_trees: int, n_leaves: int, n_features: int,
+                     n_bins: int = 255, dyadic: bool = False,
+                     **meta) -> NodeListEnsemble:
+    """A random leaf-wise ensemble for tests, chip_smoke.py and the compile
+    check (no trainer grows one): a random leaf is split until `n_leaves`
+    are there, so depths are uneven (about 20 levels at 255 leaves);
+    features and threshold bins uniform; leaf values N(0, 1), or eighths in
+    -2..2 (`dyadic`: sums of them round nowhere)."""
+    trees = []
+    for _ in range(n_trees):
+        nodes, where = [], [None]        # leaf -> (parent node, child slot)
+        for _ in range(n_leaves - 1):
+            leaf, n = int(rng.integers(len(where))), len(nodes)
+            nodes.append([int(rng.integers(n_features)),
+                          int(rng.integers(n_bins - 1)), 0.0, 0.0, ~leaf,
+                          ~len(where)])
+            if where[leaf] is not None:
+                nodes[where[leaf][0]][where[leaf][1]] = n
+            where[leaf] = (n, 4)
+            where.append((n, 5))
+        trees.append((nodes, rng.integers(-16, 17, n_leaves) / 8.0 if dyadic
+                      else rng.standard_normal(n_leaves)))
+    return node_list_from_trees(trees, n_features=n_features, n_bins=n_bins,
+                                **meta)
+
+
+@dataclasses.dataclass(frozen=True)
+class CompiledNodeList:
+    """A node-list model's BINNED scoring tables, the path-matrix form
+    (ops/predict.py has the equations): per tree, W lanes (`PATH_LANES`
+    multiples) of nodes and of leaves,
+
+        sel    [T, Fp, W] bf16   feature one-hot of the tree's nodes
+        planes [T, 8, W]  f32    row 0 the nodes' threshold bins (unused
+                                 lanes +BIG: never right), row 1 the
+                                 leaves' path lengths (-1: no such leaf),
+                                 row 2 the leaf values
+        paths  [T, W, W]  bf16   P[n, l]: +1 leaf l in node n's right
+                                 subtree, -1 in its left, 0 elsewhere
+
+    built ONCE per model version on the host; backends keep them device-
+    resident under `token` (the same cache as CompiledEnsemble's)."""
+
+    token: str
+    learning_rate: float
+    base_score: float
+    loss: str
+    n_trees: int
+    lanes: int                 # W
+    deepest_leaf: int
+    sel: np.ndarray
+    planes: np.ndarray
+    paths: np.ndarray
+
+    n_classes_out = 1
+
+    def arrays(self) -> tuple:
+        return (self.sel, self.planes, self.paths)
+
+    @staticmethod
+    def build(ens: NodeListEnsemble) -> "CompiledNodeList":
+        import ml_dtypes
+
+        if not ens.has_bin_thresholds:
+            raise ValueError(
+                "this node-list ensemble carries raw thresholds only; rank "
+                "them first (models/lightgbm_io.threshold_bin_mapper)")
+        P, plen = ens.path_matrix()
+        T, N, L = P.shape
+        W = -(-max(N, L) // PATH_LANES) * PATH_LANES
+        Fp = -(-ens.n_features // 16) * 16      # bf16 sublane tiles
+        live = ens.live_nodes
+        sel = np.zeros((T, Fp, W), ml_dtypes.bfloat16)
+        t_idx, n_idx = np.nonzero(live)
+        sel[t_idx, ens.feature[t_idx, n_idx], n_idx] = 1.0
+        planes = np.zeros((T, 8, W), np.float32)
+        planes[:, 0, :] = 2.0 ** 30
+        planes[:, 0, :N] = np.where(live, ens.threshold_bin, 2.0 ** 30)
+        planes[:, 1, :] = -1.0
+        planes[:, 1, :L] = plen
+        planes[:, 2, :L] = ens.leaf_value
+        paths = np.zeros((T, W, W), ml_dtypes.bfloat16)
+        paths[:, :N, :L] = P
+        return CompiledNodeList(
+            token=ens.cache_token(),
+            learning_rate=float(ens.learning_rate),
+            base_score=float(ens.base_score), loss=ens.loss,
+            n_trees=T, lanes=W, deepest_leaf=int(plen.max(initial=0)),
+            sel=sel, planes=planes, paths=paths)
 
 
 def empty_ensemble(

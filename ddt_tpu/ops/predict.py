@@ -35,6 +35,24 @@ Since the inference-overhaul PR the module exposes THREE related entries:
   entry via `use_pallas` (None = auto: binned data on a real TPU whose
   depth, features and classes fit the kernel's VMEM budget, at any tree
   count; the one-hot path is the fallback).
+
+A FOURTH entry serves the other ensemble layout, the NODE LIST
+(models/tree.NodeListEnsemble: leaf-wise trees too deep and sparse for a
+heap): `predict_raw_effective_paths`, the PATH-MATRIX form (Hummingbird's
+GEMM strategy, OSDI 2020). Per tree, with W lanes of nodes and of leaves:
+
+    v[r, n] = bin[r, feature[n]]            = X[rows, F] @ sel[F, W]
+    s[r, n] = +1 if v[r, n] > thr[n] else -1
+    m[r, l] = sum_n s[r, n] P[n, l]         P = +1 / -1 / 0: leaf l in node
+                                            n's right / left subtree / not
+    score[r] += sum_l where(m[r, l] == len[l], leaf_value[l], 0)
+
+`m[r, l] == len[l]` (the nodes on leaf l's path) for the ONE leaf whose
+every path node sends the row its way. Bins, +-1 and P are exact in
+bfloat16 and every sum an integer below 2^8: exact with float32
+accumulation in any order; leaf values stay float32. The plain jax.numpy
+form below is the fallback and what a CPU runs; the Pallas kernel is
+`ops/predict_paths.py`, dispatched by the same rule.
 """
 
 from __future__ import annotations
@@ -216,7 +234,8 @@ def traverse(
 
 def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
                        n_features: int, n_classes: int,
-                       optional_operands: int = 2) -> bool:
+                       optional_operands: int = 2,
+                       path_lanes: int = 0) -> bool:
     """The ONE home of the pallas-vs-one-hot predict dispatch rule.
 
     None = auto: the Pallas traversal kernel is taken when the data is
@@ -225,6 +244,10 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
     how many of the missing and categorical tables the ensemble carries;
     both, where the caller cannot say). The tree count is no term of the
     rule: the kernel streams the node tables by blocks of tree groups.
+    `path_lanes` says which LAYOUT asks: 0 a heap ensemble, else a node
+    list of that many lanes a tree (`max_depth` and `optional_operands`
+    mean nothing there), whose kernel is ops/predict_paths.py and whose
+    guard is that kernel's own.
     Explicit True
     demands the kernel (binned data required — raises otherwise; off-TPU
     it runs in interpret mode, the test contract); explicit False always
@@ -234,10 +257,15 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
     from ddt_tpu.ops import predict_pallas
 
     if use_pallas is None:
-        return (binned and device.platform() == "tpu"
-                and predict_pallas.predict_pallas_fits(
-                    max_depth, n_features, n_classes,
-                    optional_operands=optional_operands))
+        if path_lanes:
+            from ddt_tpu.ops import predict_paths
+
+            fits = predict_paths.predict_paths_fits(path_lanes, n_features)
+        else:
+            fits = predict_pallas.predict_pallas_fits(
+                max_depth, n_features, n_classes,
+                optional_operands=optional_operands)
+        return binned and device.platform() == "tpu" and fits
     if not binned:
         raise ValueError(
             "use_pallas=True requires binned (integer) data; the Pallas "
@@ -469,6 +497,94 @@ def predict_raw(
         eff_cat=pad_t(cat_node) if cat_node is not None else None,
         use_pallas=use_pallas,
     )
+
+
+# Trees and rows a step of the jax.numpy path form takes: its three
+# [trees, rows, W] float32 intermediates are 67 MB each at 256 lanes.
+_PATHS_TREE_CHUNK, _PATHS_ROW_CHUNK = 8, 8_192
+
+
+def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base):
+    """The path-matrix form (module docstring) in plain jax.numpy: trees in
+    chunks of _PATHS_TREE_CHUNK, rows in chunks of _PATHS_ROW_CHUNK, so the
+    [trees, rows, W] intermediates stay bounded. The operands are
+    widened to float32 (XLA's CPU backend has no bf16 x bf16 = f32 dot);
+    every value is one bfloat16 holds, so a TPU's default one-pass matmul
+    of them is exact too."""
+    T, Fp, W = sel.shape
+    R, F = Xc.shape
+    tree_chunk = _PATHS_TREE_CHUNK
+    n_tc = -(-T // tree_chunk)
+    # trees that fill the last chunk: no node, no leaf (len -1: no match)
+    t_fill = ((0, n_tc * tree_chunk - T), (0, 0), (0, 0))
+    selp = jnp.pad(sel.astype(jnp.float32), t_fill).reshape(
+        n_tc, tree_chunk, Fp, W)
+    planesp = jnp.pad(planes, t_fill, constant_values=-1.0).reshape(
+        n_tc, tree_chunk, 8, W)
+    pathsp = jnp.pad(paths.astype(jnp.float32), t_fill).reshape(
+        n_tc, tree_chunk, W, W)
+    row_chunk = min(_PATHS_ROW_CHUNK, R)
+    n_rc = -(-R // row_chunk)
+    Xp = jnp.pad(Xc.astype(jnp.float32),
+                 ((0, n_rc * row_chunk - R), (0, Fp - F))
+                 ).reshape(n_rc, row_chunk, Fp)
+
+    def row_body(_, xrc):
+        def tree_body(acc, args):
+            a, pl_, p = args
+            with traced_scope("predict:traverse"):
+                v = jnp.einsum("rf,tfn->trn", xrc, a,
+                               preferred_element_type=jnp.float32)
+                s = jnp.where(v > pl_[:, None, 0, :], 1.0, -1.0)
+                m = jnp.einsum("trn,tnl->trl", s, p,
+                               preferred_element_type=jnp.float32)
+            with traced_scope("predict:accumulate"):
+                hit = m == pl_[:, None, 1, :]
+                acc = acc + jnp.sum(
+                    jnp.where(hit, pl_[:, None, 2, :], 0.0), axis=(0, 2))
+            return acc, None
+
+        acc, _ = jax.lax.scan(tree_body, jnp.zeros((row_chunk,), jnp.float32),
+                              (selp, planesp, pathsp))
+        return None, acc
+
+    with traced_scope("predict"):
+        _, accs = jax.lax.scan(row_body, None, Xp)
+    return base + learning_rate * accs.reshape(n_rc * row_chunk)[:R]
+
+
+@costed("predict", phase="predict")
+@functools.partial(
+    jax.jit,
+    static_argnames=("learning_rate", "base", "use_pallas"),
+)
+def predict_raw_effective_paths(
+    sel: jax.Array,            # bf16 [T, Fp, W] feature one-hot of the nodes
+    planes: jax.Array,         # f32 [T, 8, W] rows: thr, path length, value
+    paths: jax.Array,          # bf16 [T, W, W] signed path matrix
+    Xc: jax.Array,             # [R, F] integer bins
+    learning_rate: float,
+    base: float,
+    use_pallas: bool | None = None,
+) -> jax.Array:
+    """Raw margins [R] of a node-list ensemble from its compiled tables
+    (models/tree.CompiledNodeList): the path-matrix form, by the Pallas
+    kernel (ops/predict_paths.py) where `resolve_use_pallas` says so and by
+    `_predict_paths` otherwise. Binned rows only."""
+    if not jnp.issubdtype(Xc.dtype, jnp.integer):
+        raise ValueError("the path-matrix form scores binned (integer) rows")
+    if Xc.shape[0] == 0:
+        with traced_scope("predict"):
+            return jnp.full((0,), base, jnp.float32)
+    if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], 1,
+                          path_lanes=sel.shape[2]):
+        from ddt_tpu.ops import predict_paths
+
+        return predict_paths.predict_paths_pallas(
+            sel, planes, paths, Xc.astype(jnp.int32),
+            learning_rate=learning_rate, base=base)
+    return _predict_paths(sel, planes, paths, Xc,
+                          learning_rate=learning_rate, base=base)
 
 
 def predict_proba(raw: jax.Array, loss: str) -> jax.Array:

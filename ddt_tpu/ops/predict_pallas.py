@@ -344,6 +344,11 @@ class TablePlan(typing.NamedTuple):
         """The plan as the `ddt:predict:ensemble` span carries it."""
         return {k: getattr(self, k) for k in SPAN_COUNTS}
 
+    def root_counts(self) -> dict:
+        """What of the plan every call's `ddt:predict` root span repeats
+        (a node list's plan, ops/predict_paths.PathPlan, says more)."""
+        return {"routing_tables": self.routing_tables}
+
 
 # The one list of what the program says of a plan; what each name means is
 # in docs/OBSERVABILITY.md. SPAN_COUNTS: the counts of the

@@ -31,6 +31,28 @@ Where this departs from the contract of `ops/predict.py` (and its Pallas twin
   scoring is not covered: bins only.
 
 `tests/test_reference_predict.py` holds `api.predict` to it.
+
+The NODE LIST (models/tree.NodeListEnsemble; `leaf_of_rows_node_list`,
+`predict_raw_node_list`): tree t is `n_leaves[t] - 1` internal nodes and
+`n_leaves[t]` leaves. A row starts at node 0; at internal node n it goes
+LEFT, to `left_child[n]`, when `bin[feature[n]] <= threshold_bin[n]`, else
+RIGHT, to `right_child[n]`; a negative child c is leaf `~c` and the tree
+scores `leaf_value[~c]` (a tree of one leaf scores `leaf_value[0]`). Raw
+score [rows] = base_score + learning_rate x the sum over the trees. Ordinal
+splits and one output column only. Where the device path (the path-matrix
+form, `ops/predict.py` and its kernel `ops/predict_paths.py`) departs from
+this walk, all of it arithmetic and none of it routing:
+
+- It does not walk. Every node's compare is made for every row, and the
+  leaf reached is the one whose whole path agrees (`m == len`); the leaf is
+  the walk's, for every row and tree.
+- It pads a tree's nodes and leaves to a multiple of 128 lanes with nodes
+  that always answer left and leaves no path reaches, and the trees to
+  whole blocks with trees of no leaf.
+- It sums the leaf values of a block of trees lane by lane and then over
+  the lanes, adds the blocks, and multiplies by the learning rate once at
+  the end: float32 rounding of a sum in another order, not bitwise.
+  `tests/test_node_list.py` holds `api.predict` to it.
 """
 
 from __future__ import annotations
@@ -70,3 +92,31 @@ def predict_raw(ens, Xb: np.ndarray, dtype=np.float32) -> np.ndarray:
         leaf = leaf_of_rows(ens, t, Xb)
         out[:, t % n_out] += lr * ens.leaf_value[t].astype(dtype)[leaf]
     return out if n_out > 1 else out[:, 0]
+
+
+def leaf_of_rows_node_list(ens, t: int, Xb: np.ndarray) -> np.ndarray:
+    """Index of the leaf each row of `Xb` ends in, in tree `t` of a
+    node-list ensemble: the walk, a level of every row's at a time."""
+    rows = np.arange(Xb.shape[0])
+    if ens.n_leaves[t] == 1:
+        return np.zeros(len(rows), np.int64)
+    cur = np.zeros(len(rows), np.int64)          # a node, or ~leaf
+    while (cur >= 0).any():
+        n = np.maximum(cur, 0)
+        b = Xb[rows, ens.feature[t][n]].astype(np.int64)
+        nxt = np.where(b <= ens.threshold_bin[t][n],
+                       ens.left_child[t][n], ens.right_child[t][n])
+        cur = np.where(cur >= 0, nxt, cur)
+    return ~cur
+
+
+def predict_raw_node_list(ens, Xb: np.ndarray,
+                          dtype=np.float32) -> np.ndarray:
+    """Raw scores [rows] of a models/tree.NodeListEnsemble over binned
+    rows, accumulated in `dtype` in tree order."""
+    out = np.full(Xb.shape[0], ens.base_score, dtype)
+    lr = dtype(ens.learning_rate)
+    for t in range(ens.feature.shape[0]):
+        leaf = leaf_of_rows_node_list(ens, t, Xb)
+        out += lr * ens.leaf_value[t].astype(dtype)[leaf]
+    return out

@@ -22,7 +22,8 @@ What is compiled:
 - the whole fused-rounds program TPUDevice builds
   (`TPUDevice._build_rounds_fn`) on one device, on a rows=4 mesh and on a
   2x2 (rows x features) mesh over the four described chips, and the
-  scoring program (`TPUDevice._predict_fn`).
+  scoring program (`TPUDevice._predict_fn`), for heap ensembles and for a
+  node list (the path-matrix form).
 
 Exit 0 iff every default-dispatch case compiled; opt-in kernels
 (grad_dtype=int8|int16, predict_impl=lut|lut4) are reported and do not
@@ -159,6 +160,41 @@ def _predict_case(rows, features, n_trees, depth, n_classes=1,
     return build
 
 
+def _random_node_list(n_trees, n_leaves, features):
+    """A random leaf-wise ensemble (seeded) as a models/tree
+    NodeListEnsemble."""
+    import numpy as np
+
+    from ddt_tpu.models.tree import random_node_list
+
+    return random_node_list(np.random.default_rng(7), n_trees, n_leaves,
+                            features, learning_rate=0.1, base_score=0.0,
+                            loss="logloss")
+
+
+def _paths_case(rows, features, n_trees, n_leaves):
+    """The path-matrix kernel (ops/predict_paths.py) over a node list's
+    compiled tables."""
+    def build():
+        import jax.numpy as jnp
+
+        from ddt_tpu.ops import predict_paths
+
+        ce = _random_node_list(n_trees, n_leaves, features).compile()
+
+        def fn(sel, planes, paths, Xc):
+            return predict_paths.predict_paths_pallas(
+                sel, planes, paths, Xc.astype(jnp.int32),
+                learning_rate=ce.learning_rate, base=ce.base_score,
+                interpret=False)
+
+        shapes = [(a.shape, a.dtype) for a in ce.arrays()]
+        shapes.append(((rows, features), jnp.uint8))
+        return fn, shapes
+
+    return build
+
+
 def kernel_cases() -> list:
     """Every Pallas kernel the system has, at the shapes the repo names.
     `default` marks the default dispatch on a TPU (f32 gradients, the f32
@@ -247,6 +283,14 @@ def kernel_cases() -> list:
                    _predict_case(hr, 64, 130, 5)),
         KernelCase("predict/65f/130x5", True,
                    _predict_case(hr, 65, 130, 5)),
+        # The path-matrix form: node lists. LightGBM's Higgs model's own
+        # shape (blocks of 8 trees), a tree of one 128-lane tile, and more
+        # features than a bf16 sublane tile.
+        KernelCase("paths/higgs/500x255leaves", True,
+                   _paths_case(2_000_000, hf, 500, 255)),
+        KernelCase("paths/9x15leaves", True, _paths_case(hr, hf, 9, 15)),
+        KernelCase("paths/70f/40x200leaves", True,
+                   _paths_case(hr, 70, 40, 200)),
         # Opt-in kernels.
         KernelCase("hist/higgs/255bins/N=32/int8", False,
                    _hist_case(hr, hf, 32, 255, "int8")),
@@ -317,7 +361,7 @@ def _rounds_program(topo_devices, *, rows, features, n_rounds, mesh_shape,
 
 
 def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
-                     n_classes=1, routed=False):
+                     n_classes=1, routed=False, leaves=0):
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -325,9 +369,10 @@ def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
     from ddt_tpu.backends.tpu import TPUDevice
     from ddt_tpu.config import TrainConfig
 
-    be = TPUDevice(TrainConfig(backend="tpu", max_depth=depth))
-    ens = _random_ensemble(n_trees, depth, features, n_classes, routed,
-                           routed)
+    be = TPUDevice(TrainConfig(backend="tpu", max_depth=depth or 6))
+    ens = (_random_node_list(n_trees, leaves, features) if leaves else
+           _random_ensemble(n_trees, depth, features, n_classes, routed,
+                            routed))
     fn, ens_dev = be._predict_fn(ens)
     one = SingleDeviceSharding(topo_devices[0])
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
@@ -359,12 +404,12 @@ def program_cases(topo_devices) -> list:
         return build
 
     def scoring(n_trees, rows=hr, features=hf, depth=6, n_classes=1,
-                routed=False):
+                routed=False, leaves=0):
         def build():
             fn, args = _scoring_program(topo_devices, rows=rows,
                                         features=features, n_trees=n_trees,
                                         depth=depth, n_classes=n_classes,
-                                        routed=routed)
+                                        routed=routed, leaves=leaves)
             return fn, args, ["tpu_custom_call"]
         return build
 
@@ -391,6 +436,10 @@ def program_cases(topo_devices) -> list:
         ("scoring/criteo/100x6/routed", scoring(
             100, rows=CRITEO["rows"], features=CRITEO["features"],
             routed=True)),
+        # LightGBM's Higgs model's chunk through the auto dispatch: a node
+        # list (`leaves`: no heap depth), the path-matrix form.
+        ("scoring/higgs-lgbm/500x255leaves", scoring(
+            500, rows=2_000_000, depth=0, leaves=255)),
     ]
 
 

@@ -228,6 +228,67 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
     np.testing.assert_array_equal(scores, again)
 
 
+@pytest.mark.parametrize("impl", ["pallas", "auto"])
+def test_a_node_list_says_which_form_serves(impl, monkeypatch):
+    """A node-list model through the same chunk loop: the `ensemble` span
+    carries the path form's plan (ops/predict_paths.SPAN_COUNTS), the
+    root `node_list`, `path_mxu_tiles_per_tree` and what the kernel
+    streams of its tables; a heap model's spans carry none of them."""
+    from ddt_tpu.models.tree import node_list_from_trees
+    from ddt_tpu.ops import predict_paths
+
+    rng = np.random.default_rng(77)
+    # 3 trees of 3 leaves: n0 -> (L0, n1), n1 -> (L1, L2)
+    trees = [([(int(rng.integers(6)), int(rng.integers(30)), 0.0, 0.0,
+                ~0, 1),
+               (int(rng.integers(6)), int(rng.integers(30)), 0.0, 0.0,
+                ~1, ~2)], [1.0, 2.0, 4.0]) for _ in range(3)]
+    ens = node_list_from_trees(trees, n_features=6, learning_rate=0.5,
+                               base_score=0.0, loss="logloss", n_bins=31)
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31,
+                                 predict_impl=impl))
+    monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", 256)
+    Xb = rng.integers(0, 31, size=(1000, 6), dtype=np.uint8)
+    scores = be.predict_raw(ens, Xb)
+    np.testing.assert_array_equal(scores, ens.predict_raw(Xb, binned=True))
+
+    root = an.root_spans("predict")[-1]
+    kids = _by_name(root)
+    assert root["counts"]["branch"] == "chunks"
+    assert root["counts"]["chunks"] == 4
+    assert root["counts"]["node_list"] == 1
+    assert root["counts"]["path_mxu_tiles_per_tree"] == 2     # 128 lanes
+    assert root["counts"]["routing_tables"] == 0
+    # 3 trees are one block: fetched once and resident, nothing streamed
+    assert root["counts"]["tables_streamed_bytes"] == 0
+    counts = kids["ddt:predict:ensemble"][0]["counts"]
+    assert list(counts) == ["bytes", "trees", *predict_paths.SPAN_COUNTS]
+    served = impl == "pallas"      # a CPU's auto takes the jax.numpy form
+    assert counts == dict(
+        bytes=counts["bytes"], trees=3, node_list=1, nodes_per_tree=128,
+        leaves_per_tree=128, deepest_leaf=2, path_mxu_tiles_per_tree=2,
+        trees_per_step=3 * served, table_blocks=1 * served,
+        table_bytes=3 * (16 * 128 * 2 + 8 * 128 * 4 + 128 * 128 * 2) * served)
+    assert counts["bytes"] == counts["table_bytes"] or not served
+
+    # blocks of trees stream once a row tile
+    plan = predict_paths.path_plan(500, 256, 28, 17)
+    assert plan.node_list == 1 and plan.deepest_leaf == 17
+    assert plan.path_mxu_tiles_per_tree == 6
+    assert plan.trees_per_step * plan.table_blocks >= 500
+    assert plan.blocks == plan.table_blocks > 1
+    assert plan.table_bytes == plan.trees_per_step * plan.table_blocks * (
+        32 * 256 * 2 + 8 * 256 * 4 + 256 * 256 * 2)
+
+    # a heap model says nothing of the path form
+    heap = _rand_ensemble(seed=2024)
+    be.predict_raw(heap, Xb)
+    root = an.root_spans("predict")[-1]
+    assert "node_list" not in root["counts"]
+    assert "node_list" not in _by_name(root)[
+        "ddt:predict:ensemble"][0]["counts"]
+
+
 # The chunk loop's result against its chunks scored one call each (the
 # `one` branch): rows a whole number of chunks and rows with a remainder
 # chunk, 7 chunks whose last piece is the remainder chunk alone, one class

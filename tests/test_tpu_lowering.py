@@ -71,7 +71,10 @@ def test_case_table_covers_the_default_dispatch():
                    "predict/criteo/100x6/cat",
                    "predict/56f/130x5/missing",
                    "predict/57f/130x5/missing",
-                   "predict/56f/130x5/missing+cat"):
+                   "predict/56f/130x5/missing+cat",
+                   # the path-matrix form (node lists)
+                   "paths/higgs/500x255leaves", "paths/9x15leaves",
+                   "paths/70f"):
         assert any(needle in n for n in names), (needle, names)
 
 
