@@ -227,8 +227,9 @@ def score_multiclass(overrides: dict, rows: int) -> None:
     """Covertype's own ensemble shape through `api.predict`: 3,500 random
     full trees (500 rounds x 7 classes) of depth 8 over 54 features. Asserts
     that the traversal kernel served it by the auto dispatch, its tables
-    streamed in more than one block, and holds every class column to the
-    plain reference (reference/numpy_predict, in float64) on the first rows."""
+    streamed in more than one block, its groups held whole rounds of the
+    classes and shared one class dot a block, and holds every class column
+    to the plain reference (reference/numpy_predict, in float64) on the first rows."""
     from ddt_tpu import api
     from ddt_tpu.config import TrainConfig
     from ddt_tpu.models.tree import empty_ensemble
@@ -262,6 +263,10 @@ def score_multiclass(overrides: dict, rows: int) -> None:
         f"{root['counts']['tables_streamed_bytes']}")
     assert built["tree_group"] == 128, "the traversal kernel did not serve"
     assert built["table_groups"] == -(-T // 128), built
+    # 18 whole rounds of the 7 classes a group, so that lane l is class
+    # l % 7 in every group and a block's groups share ONE class dot.
+    assert (built["trees_per_group"], built["class_dots_per_step"]) == (
+        126, 1), built
     assert (built["nodes_per_tile"], built["mxu_tiles_per_group"]) == (
         2, 2 ** (depth - 1)), "two nodes do not share a weight tile"
     assert built["groups_per_step"] < built["table_groups"], \

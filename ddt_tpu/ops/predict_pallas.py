@@ -25,8 +25,11 @@ ALL of them where the whole ensemble fits: a 1000-tree depth-6 ensemble
 (8 groups, 1.3 MB) is one block, its grid (tiles, 1), its tables fetched
 once and resident as before. 500 rounds x 7 classes at depth 8 are 28
 groups in 4 blocks of 7. The kernel's trace is G groups long whatever the
-tree count. The padded tree count is padded again, inside the jitted
-program, to a multiple of G x 128 with trees that score 0:
+tree count. A group holds U trees in its lanes 0..U-1 (`trees_per_group`):
+128, or WHOLE ROUNDS of the C classes, 126 at C = 7, where that lets a
+block's groups share their class dot (Class scatter, below). The padded
+tree count is padded again, inside the jitted program, to a multiple of
+G x U with trees that score 0, as the lanes past U do:
 
     X     [TILE_R, F]        int32 bins. In-VMEM the matmul's left operand,
                              bf16 [TILE_R, K]: the row tile as it is
@@ -44,7 +47,10 @@ program, to a multiple of G x 128 with trees that score 0:
                              in the folded routed form the three planes
                              are h, delta and c, all f32 (below).
     val   [nb, G*W, 128]     bottom-level pushed-down leaf values, same.
-    coh   [nb, G*128, C]     round-major class one-hot.
+    coh   [128, C]           class one-hot of a group's lanes, lane l class
+                             l % C, ONE for the whole ensemble, fetched
+                             once; [nb, G*128, C], a window a group, where
+                             each group keeps its own class dot.
 
 P nodes share one MXU weight tile (`nodes_per_tile`: from F and whether the
 ensemble carries a routing table, nothing else). A node's matmul contracts
@@ -148,16 +154,33 @@ tile (depth-first, at the parent of its nodes):
         where the path has depth nodes: there is no node index k, no k == i
         and no leaf select. Depth-first keeps depth + 1 value planes live,
         and at P = 2 one packed plane a level.
-    Class scatter: acc += vals @ class-one-hot (f32, HIGHEST), one dot a
-        group.
+    Class scatter: acc = vsum @ class-one-hot (f32, HIGHEST), ONE dot a
+        grid step, vsum the lane-by-lane f32 sum of the block's G root
+        value planes (one plane live across the groups, 32 vreg adds a
+        group). HIGHEST makes the product six bfloat16 passes of a whole
+        MXU weight tile each (C = 1 pads to 128 columns) and a split of
+        the plane into three bfloat16 parts on the VPU: a dot a group was
+        384 of the 2,432 MXU cycles of a depth-6 group. What lets the
+        lanes be added first: tree t's class is t % C, so lane l is class
+        l % C in EVERY group where a group holds whole rounds,
+        U = C (128 // C) trees (128 wherever C divides 128, always at
+        C = 1; 126 at C = 7, lanes 126 and 127 left to trees that score
+        0). Where whole rounds take more groups than they save in dots
+        (10 classes at depth 8) the groups keep 128 trees and a dot each,
+        as a block of one group does (`table_plan` decides, from C, the
+        tree count and G; `class_dots_per_step` says which). On the v5e
+        a depth-6 step of 256 rows x 128 trees went from 2,573 to 2,177
+        cycles and a depth-8 step from 8,783 to 8,423 (PERF.md section 6,
+        PR 34).
 
 Contract: the SAME leaf per (row, tree) as ops/predict.predict_raw
 (missing-value routing, categorical one-vs-rest, softmax round-major classes
-all preserved). The float accumulation sums a group's 128 trees in one dot
-where the one-hot path sums tree_chunk at a time, and the summation order
-inside a dot belongs to the compiler, so scores agree to f32 rounding, not
-bitwise (tests/test_predict_pallas.py: equality on dyadic leaf values, a
-1e-6 tolerance on random ones).
+all preserved). The float accumulation adds a block's groups lane by lane
+and sums the 128 lanes in one dot where the one-hot path sums tree_chunk
+trees at a time, and the summation order inside a dot belongs to the
+compiler, so scores agree to f32 rounding, not bitwise
+(tests/test_predict_pallas.py: equality on dyadic leaf values, a 1e-6
+tolerance on random ones).
 Interpret mode auto-selects off-TPU (utils/device.platform), same pattern
 as hist_pallas.py; dispatch lives in ops/predict.resolve_use_pallas (the
 `use_pallas` flag on predict_raw / predict_raw_effective, one-hot fallback).
@@ -243,10 +266,24 @@ TREE_GROUP = 128
 # _ROW_BYTES, which bounds the folded form alone. (With the predicate
 # BEFORE the subtrees, the unrouted order, the same probes read 1.81 at
 # depth 6, 2.46 at depth 8 and 3.09 at depth 10: 14.4 KiB a row.)
+# The plane that sums a block's groups before the one class dot costs the
+# compiler nothing it did not hold (compile check, PR 34; the same probe,
+# every group in one block, scoped MiB at tile 256 without the row tile's
+# windows, the parent's program in brackets): 1000 trees, 28 features,
+# depth 6 / 8: 1.14 / 1.67 [1.25 / 1.76]; 4000 trees 1.14 [1.25]; 130
+# trees 1.08 [1.20]; 3 classes 1.14 [1.25]; 64 features 1.20 [1.28]; 3,500
+# trees x depth 8 x 54 features x 7 classes in whole rounds 1.68 [1.79];
+# tile 512 at depth 6: 2.33 [2.41]; only one node a tile (65 features)
+# grows, 1.38 [1.32]: 7.5 KiB a row with the windows. Depth 4 compiles
+# under the probe's 1 MiB, as the parent does.
 _ROW_BYTES = 12 * 1024
 _ROW_NODE_BYTES_BOTH = 192
 # Rows (K) of one MXU weight tile.
 _MXU_ROWS = 128
+# What one class dot costs the MXU, in weight tiles (results [TILE_R, 128])
+# for every 128 class columns: HIGHEST makes an f32 product six bfloat16
+# passes, each a whole tile whatever C is (1 class pads to 128 columns).
+_CLASS_DOT_TILES = 6
 # 1.5 * 2^23: an integer below 2^22 added to it is the low bits of the
 # f32's mantissa, so that the bitcast is the conversion (the packed word
 # has 16 bits).
@@ -308,7 +345,13 @@ def _vmem_bytes(groups: int, max_depth: int, n_features: int,
     table windows (feat i32, thr f32, dl and cat i32 where present, bottom
     values, class one-hot: one plane a row), the row tile's windows and
     the working set, which the integer routing of both tables makes grow
-    by the node (`_ROW_NODE_BYTES_BOTH`; not the folded form)."""
+    by the node (`_ROW_NODE_BYTES_BOTH`; not the folded form). The class
+    window is charged a group whether or not the block shares one class
+    dot and one [128, C] window (`table_plan`): dropping the term would
+    let Covertype's model hold 11 groups where it holds 9, which the
+    evening-out turns into 3 blocks of 10, 30 groups of which two are
+    empty: 7.1% more work for 3.8% fewer table walks (PR 34). Whether the
+    freed windows should buy a larger G is open, with that trap."""
     n_int = (1 << max_depth) - 1
     tables = ((2 + optional_operands) * _window_bytes(groups * n_int,
                                                       TREE_GROUP)
@@ -329,11 +372,14 @@ class TablePlan(typing.NamedTuple):
     groups_per_step: int   # G: groups a table block; 0 = nothing fits
     blocks: int            # table blocks a row tile walks; 1 = resident
     table_bytes: int       # HBM bytes of all the blocks' tables, read once
+                           # (a shared class one-hot: once an ensemble)
     tile_rows: int         # rows a tile
     nodes_per_tile: int    # P: nodes that share one MXU weight tile
     mxu_tiles_per_group: int   # weight tiles a group costs a row tile
     routing_tables: int    # the missing and categorical tables it carries
     routes_in_tile: int    # ... of them, routed inside the MXU weight tile
+    trees_per_group: int   # U: lanes of a group that hold trees (whole rounds)
+    class_dots_per_step: int   # 1: the block's groups share one class dot
 
     @property
     def tree_group(self) -> int:
@@ -358,7 +404,8 @@ class TablePlan(typing.NamedTuple):
 # the root span's `tables_streamed_bytes`, which `phases_ms` has instead.
 SPAN_COUNTS = ("tree_group", "table_groups", "groups_per_step",
                "table_bytes", "nodes_per_tile", "mxu_tiles_per_group",
-               "routing_tables", "routes_in_tile")
+               "routing_tables", "routes_in_tile", "trees_per_group",
+               "class_dots_per_step")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 # This kernel does not serve the model (the one-hot path, the LUT tiers).
 NO_PLAN = TablePlan(*(0,) * len(TablePlan._fields))
@@ -381,29 +428,59 @@ def table_plan(
     group fits (depth, features, classes and the optional operands decide
     that; the tree count never does). `optional_operands` counts the
     missing and categorical tables the ensemble carries (both, where the
-    caller cannot say)."""
+    caller cannot say).
+
+    And how the block's groups meet the class dot (module docstring,
+    Class scatter): groups of whole rounds, `trees_per_group` = U =
+    C (128 // C), sharing ONE dot a grid step (`class_dots_per_step` 1),
+    where the MXU results of a row tile come to fewer that way, blocks x
+    (G x `mxu_tiles_per_group` + the one dot's 6) against the 128-tree
+    groups' blocks x G x (tiles + 6), each layout under its own blocks;
+    else groups of 128 trees and a dot each (`class_dots_per_step` G),
+    as in a block of one group, whose program is what it was. U = 128
+    wherever C divides 128 and costs nothing; Covertype's 3,520 padded
+    trees are 28 groups of 126 as of 128, 4 blocks of 7; 10 classes at
+    depth 8 would pay 12% more groups for 4% of dots and keep 128. Read
+    from C, the tree count and G; no knob."""
     if tile_r is None:
         tile_r = _DEFAULT_TILE_R
-    n_tg = -(-n_trees_padded // TREE_GROUP)
-    most = 0
-    while most < n_tg and _vmem_bytes(
+    rounds = TREE_GROUP // n_classes * n_classes      # 0: C > 128
+    # G is capped by the groups there are, in either layout.
+    most, cap = 0, -(-n_trees_padded // (rounds or TREE_GROUP))
+    while most < cap and _vmem_bytes(
             most + 1, max_depth, n_features, n_classes, tile_r,
             optional_operands) <= _VMEM_BUDGET_BYTES:
         most += 1
-    packing = (nodes_per_tile(n_features, optional_operands),
-               mxu_tiles_per_group(max_depth, n_features,
-                                   optional_operands),
+    tiles = mxu_tiles_per_group(max_depth, n_features, optional_operands)
+    packing = (nodes_per_tile(n_features, optional_operands), tiles,
                optional_operands,
                routes_in_tile(n_features, optional_operands))
+
+    def blocks_of(per_group):
+        """(groups, G, blocks) of groups of `per_group` trees."""
+        n_tg = -(-n_trees_padded // per_group)
+        blocks = -(-n_tg // max(most, 1))
+        return n_tg, -(-n_tg // blocks), blocks
+
+    n_tg, g, blocks = blocks_of(TREE_GROUP)
     if most == 0:
-        return TablePlan(n_tg, 0, 0, 0, tile_r, *packing)
-    blocks = -(-n_tg // most)
-    g = -(-n_tg // blocks)
-    per_group = 4 * TREE_GROUP * ((2 + optional_operands)
-                                  * ((1 << max_depth) - 1)
-                                  + (1 << max_depth) + n_classes)
-    return TablePlan(n_tg, g, blocks, blocks * g * per_group, tile_r,
-                     *packing)
+        return TablePlan(n_tg, 0, 0, 0, tile_r, *packing, TREE_GROUP, 0)
+    dot = _CLASS_DOT_TILES * -(-n_classes // TREE_GROUP)
+    per_group, shared = TREE_GROUP, False
+    if rounds:
+        whole = blocks_of(rounds)
+        shared = (whole[2] * (whole[1] * tiles + dot)
+                  < blocks * g * (tiles + dot))
+        if shared:
+            per_group, (n_tg, g, blocks) = rounds, whole
+    nodes_bytes = 4 * TREE_GROUP * ((2 + optional_operands)
+                                    * ((1 << max_depth) - 1)
+                                    + (1 << max_depth))
+    # The class one-hot: the [128, C] window the whole ensemble shares,
+    # fetched once, or one a group.
+    class_bytes = 4 * TREE_GROUP * n_classes * (1 if shared else blocks * g)
+    return TablePlan(n_tg, g, blocks, blocks * g * nodes_bytes + class_bytes,
+                     tile_r, *packing, per_group, 1 if shared else g)
 
 
 def predict_pallas_fits(
@@ -423,19 +500,21 @@ def predict_pallas_fits(
 
 
 def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
-                     n_groups: int, n_blocks: int, n_int: int,
-                     n_leaves: int, n_feat: int, pack: int, folded: bool,
-                     missing_bin_value: int, use_missing: bool,
-                     use_cat: bool):
+                     n_groups: int, groups_per_dot: int, n_blocks: int,
+                     n_int: int, n_leaves: int, n_feat: int, pack: int,
+                     folded: bool, missing_bin_value: int,
+                     use_missing: bool, use_cat: bool):
     """One row tile against one block of tree groups: that block's share
     of every class's margin, fully in VMEM. `pack`: the plan's
-    nodes_per_tile; `folded`: its routes_in_tile is not 0.
+    nodes_per_tile; `folded`: its routes_in_tile is not 0;
+    `groups_per_dot`: G where the block's groups share one class dot
+    (lane l holds class l % C in every group), 1 where each has its own.
 
     x_ref [TILE_R, F] int32; feat/thr (+ optional dl, cat) [G*Nint, 128]
-    and val [G*W, 128], one plane a row; coh [G*128, C]; out [TILE_R, C]
-    f32, resident over the block axis (grid axis 1): written by the first
-    block, added to by the others. Folded, the three planes are the
-    prologue's h, delta and c (`_folded_routes`), all f32."""
+    and val [G*W, 128], one plane a row; coh [128, C] a dot; out
+    [TILE_R, C] f32, resident over the block axis (grid axis 1): written
+    by the first block, added to by the others. Folded, the three planes
+    are the prologue's h, delta and c (`_folded_routes`), all f32."""
     rest = list(rest)
     out_ref = rest.pop()
     dl_ref = rest.pop(0) if use_missing else None
@@ -594,11 +673,16 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
                          leaf(g, 2 * n + 1, planes))
 
     acc = jnp.zeros((tile_r, out_ref.shape[1]), jnp.float32)
-    for g in range(n_groups):
-        # Class scatter — the one-hot path's dot and precision, one add a
-        # group.
+    for d in range(n_groups // groups_per_dot):
+        # The values the rows reach in the dot's groups, summed lane by
+        # lane: one plane live across the groups, 32 vreg adds each.
+        first = d * groups_per_dot
+        vals = leaf(first, 0, {})
+        for g in range(first + 1, first + groups_per_dot):
+            vals = vals + leaf(g, 0, {})
+        # Class scatter — the one-hot path's dot and precision.
         acc = acc + jax.lax.dot_general(
-            leaf(g, 0, {}), coh_ref[g * tg:(g + 1) * tg, :],
+            vals, coh_ref[d * tg:(d + 1) * tg, :],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
@@ -717,21 +801,25 @@ def predict_effective_pallas(
     n_blocks = plan.blocks or 1
     n_int = (1 << max_depth) - 1
     n_leaves = 1 << max_depth
-    t_fill = n_blocks * n_g * tg - Tpad
+    u = plan.trees_per_group
+    groups_per_dot = n_g // (plan.class_dots_per_step or n_g)
+    t_fill = n_blocks * n_g * u - Tpad
 
     def by_plane(a, dtype, fill=0):
         """[Tpad, width] -> [n_blocks, G*width, 128]: row g*width + n of
-        block b holds column n of the 128 trees of the block's group g.
-        The trees that fill the last group, and the groups that fill the
-        last block, score 0 (feature -1 matches no one-hot row, value 0,
-        a zero class row). Tiny arrays; the transpose is noise next to
-        the row volume."""
+        block b holds column n of the U trees of the block's group g, in
+        lanes 0..U-1. The trees that fill a group's last lanes, the last
+        group and the last block's groups score 0 (feature -1 matches no
+        one-hot row, value 0). Tiny arrays; the transpose is noise next
+        to the row volume."""
         a = jnp.pad(a.astype(dtype), ((0, t_fill), (0, 0)),
                     constant_values=fill)
         width = a.shape[1]
-        return (a.reshape(n_blocks, n_g, tg, width)
-                .transpose(0, 1, 3, 2)
-                .reshape(n_blocks, n_g * width, tg))
+        a = a.reshape(n_blocks, n_g, u, width)
+        if u < tg:
+            a = jnp.pad(a, ((0, 0), (0, 0), (0, tg - u), (0, 0)),
+                        constant_values=fill)
+        return a.transpose(0, 1, 3, 2).reshape(n_blocks, n_g * width, tg)
 
     feat_pl = by_plane(eff_feat[:, :n_int], jnp.int32, fill=-1)
     folded = plan.routes_in_tile > 0
@@ -757,8 +845,12 @@ def predict_effective_pallas(
             ((thr_i + 1) << jnp.where(top & (node > 0), 8, 0))
             + jnp.where(top, _MANTISSA_BITS, 0), jnp.int32)
     val_pl = by_plane(bot_val, jnp.float32)
-    coh = jnp.pad(cls_oh.astype(jnp.float32),
-                  ((0, t_fill), (0, 0))).reshape(n_blocks, n_g * tg, C)
+    if groups_per_dot > 1:
+        # Lane l is class l % C in every group of whole rounds.
+        coh = jnp.pad(cls_oh[:u].astype(jnp.float32), ((0, tg - u), (0, 0)))
+    else:
+        coh = jnp.pad(cls_oh.astype(jnp.float32),
+                      ((0, t_fill), (0, 0))).reshape(n_blocks, n_g * tg, C)
     if use_missing and not folded:
         extras.append(by_plane(eff_dl[:, :n_int], jnp.int32))
     if use_cat and not folded:
@@ -775,9 +867,9 @@ def predict_effective_pallas(
         Xi = jnp.pad(Xi, ((0, rpad), (0, 0)))
 
     kernel = functools.partial(
-        _traverse_kernel, n_groups=n_g, n_blocks=n_blocks, n_int=n_int,
-        n_leaves=n_leaves, n_feat=F, pack=plan.nodes_per_tile,
-        folded=folded,
+        _traverse_kernel, n_groups=n_g, groups_per_dot=groups_per_dot,
+        n_blocks=n_blocks, n_int=n_int, n_leaves=n_leaves, n_feat=F,
+        pack=plan.nodes_per_tile, folded=folded,
         missing_bin_value=missing_bin_value, use_missing=use_missing,
         use_cat=use_cat,
     )
@@ -800,10 +892,14 @@ def predict_effective_pallas(
         nodes,                                            # feat
         nodes,                                            # thr
         table_block(n_g * n_leaves, tg),                  # val
-        table_block(n_g * tg, C),                         # coh
+        # coh: a group's, or the one every group of every block shares
+        # (the index never moves: fetched once).
+        table_block(n_g * tg, C) if groups_per_dot == 1 else pl.BlockSpec(
+            (tg, C), lambda i, b: (0, 0), memory_space=pltpu.VMEM),
     ] + [nodes] * len(extras)
     cost = pl.CostEstimate(
-        flops=2 * n_tiles * tile_r * n_blocks * n_g * tg * (F * n_int + C),
+        flops=2 * n_tiles * tile_r * n_blocks * tg * (
+            n_g * F * n_int + n_g // groups_per_dot * C),
         bytes_accessed=n_tiles * tile_r * (F + C) * 4
         + plan.table_bytes * (n_tiles if n_blocks > 1 else 1),
         transcendentals=0,

@@ -259,7 +259,10 @@ def kernel_cases() -> list:
         # features with 4 K rows between the copies, 54 with 2), at
         # Covertype's own size: 28 groups in 4 blocks of 7, 128 weight
         # tiles a group; with a routing table, one node a tile at the
-        # same size and blocks.
+        # same size and blocks. Since PR 34 the groups hold WHOLE ROUNDS,
+        # 126 trees, and a block's seven share one class dot on a
+        # [128, 7] one-hot the whole ensemble shares; so do the ten
+        # groups of a 3-class model (405 rounds: 1,216 padded trees).
         KernelCase("predict/covertype/3500x8/7classes", True,
                    _predict_case(cr, cf, 500 * cc, 8, n_classes=cc)),
         KernelCase("predict/covertype/3500x8/7classes/missing", True,
@@ -271,6 +274,8 @@ def kernel_cases() -> list:
         KernelCase("predict/covertype/3500x8/7classes/missing+cat", True,
                    _predict_case(cr, cf, 500 * cc, 8, n_classes=cc,
                                  missing=True, cat=True)),
+        KernelCase("predict/3classes/1215x6", True,
+                   _predict_case(hr, hf, 405 * 3, 6, n_classes=3)),
         KernelCase("predict/covertype/350x6/7classes/missing+cat", True,
                    _predict_case(cr, cf, 50 * cc, 6, n_classes=cc,
                                  missing=True, cat=True)),

@@ -19,6 +19,7 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 # The suite COUNTS compiles (telemetry's jit_compiles / recompile
@@ -66,6 +67,25 @@ except (ImportError, OSError) as _pin_err:
         RuntimeWarning,
         stacklevel=1,
     )
+
+
+@pytest.fixture
+def budget(monkeypatch):
+    """budget(groups, depth, F, C, optional): the traversal kernel's VMEM
+    budget at which exactly `groups` tree groups fit a table block at that
+    shape (`ops/predict_pallas.table_plan`), so that a few hundred trees
+    already take several blocks: the BUDGET shrinks, never the program.
+    The jit caches are dropped around the test: the budget is read while
+    tracing."""
+    from ddt_tpu.ops import predict_pallas as jpp
+
+    def shrink(groups, depth, n_features, n_classes, optional=0):
+        monkeypatch.setattr(jpp, "_VMEM_BUDGET_BYTES", jpp._vmem_bytes(
+            groups, depth, n_features, n_classes, jpp._DEFAULT_TILE_R,
+            optional))
+    jax.clear_caches()
+    yield shrink
+    jax.clear_caches()
 
 
 def pytest_configure(config):

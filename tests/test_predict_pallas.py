@@ -258,6 +258,96 @@ def _assert_every_leaf(ens, Xb, got):
     np.testing.assert_array_equal(got, got.astype(np.int64))
 
 
+def _shared_dot_cases():
+    """(classes, trees, depth, features, missing, cat, G the budget admits
+    or None for the real budget, the plan that follows: groups, G, blocks,
+    trees a group, class dots a grid step)."""
+    plain = (6, False, ())
+    # Tree counts that straddle a group of whole rounds (126 at 7
+    # classes; the compiled layout pads to 64s): up to 128 padded trees
+    # are ONE group, whose program is what it was; 256 are two groups of
+    # 128 or three of 126, and at depth 2 the dots saved outweigh the
+    # group.
+    for T in (125, 126, 127):
+        yield (7, T, 2, *plain, None, (1, 1, 1, 128, 1))
+    for T in (252, 253):
+        yield (7, T, 2, *plain, None, (3, 3, 1, 126, 1))
+    yield (1, 128, 2, *plain, None, (1, 1, 1, 128, 1))
+    yield (1, 129, 2, *plain, None, (2, 2, 1, 128, 1))
+    # 1, 2, 3 and 7 classes over depths 3-5 in one block, in several
+    # (the last one ragged), and in blocks of one group, each its own dot.
+    for C, per_group in ((1, 128), (2, 128), (3, 126), (7, 126)):
+        for depth in (3, 4, 5):
+            yield (C, 300, depth, *plain, None, (3, 3, 1, per_group, 1))
+        yield (C, 300, 3, *plain, 2, (3, 2, 2, per_group, 1))
+        yield (C, 300, 3, *plain, 1, (3, 1, 3, 128, 1))
+    # Six groups of whole rounds where 128s would be five: 3 blocks of 2
+    # either way.
+    yield (7, 640, 3, *plain, 2, (6, 2, 3, 126, 1))
+    # 10 classes: 120 trees a group where that costs no group ...
+    yield (10, 300, 2, *plain, None, (3, 3, 1, 120, 1))
+    # The deep end, two groups each: depth 6 at one class, 7 at seven
+    # (192 padded trees: two groups either way), 8 at three; at depth 7
+    # a third group of 126 costs more than the dot saved, so 256 padded
+    # trees keep two groups of 128 and a dot each.
+    yield (1, 130, 6, *plain, None, (2, 2, 1, 128, 1))
+    yield (7, 190, 7, *plain, None, (2, 2, 1, 126, 1))
+    yield (7, 253, 7, *plain, None, (2, 2, 1, 128, 2))
+    yield (3, 130, 8, *plain, None, (2, 2, 1, 126, 1))
+    # One node a weight tile, and the routed forms of several groups,
+    # folded and with the integer routing (no cell runs them).
+    yield (1, 200, 6, 65, False, (), None, (2, 2, 1, 128, 1))
+    yield (3, 300, 4, 6, True, (1, 4), None, (3, 3, 1, 126, 1))
+    yield (3, 300, 4, 60, True, (1, 4), None, (3, 3, 1, 126, 1))
+    yield (1, 300, 4, 6, True, (), 2, (3, 2, 2, 128, 1))
+
+
+@pytest.mark.parametrize("C,T,depth,F,missing,cat,fit,plan",
+                         list(_shared_dot_cases()))
+def test_shared_class_dot_matches_reference(budget, C, T, depth, F, missing,
+                                            cat, fit, plan):
+    """One class dot a block of tree groups, the groups' value planes
+    summed lane by lane first (groups of whole rounds where C does not
+    divide 128): the plain reference's scores (reference/numpy_predict)
+    and the one-hot path's, bit for bit on dyadic leaf values, learning
+    rate and base score (every sum exact in float32 in any order, so
+    equal bits are equal leaves and equal classes) and to F32_ACC_TOL on
+    random ones (eight lane values are added before the dot, where each
+    group's dot was added after)."""
+    from ddt_tpu.reference import numpy_predict
+
+    optional = int(missing) + int(bool(cat))
+    if fit is not None:
+        budget(fit, depth, F, C, optional)
+    got_plan = jpp.table_plan(-(-T // 64) * 64, depth, F, C, None, optional)
+    assert (*got_plan[:3], got_plan.trees_per_group,
+            got_plan.class_dots_per_step) == plan
+    ens = _rand_ensemble(T=T, depth=depth, F=F, n_classes=C,
+                         bins=31 if F == 6 else 255, missing=missing,
+                         cat=cat, seed=1000 * C + 10 * T + depth)
+    ens.learning_rate, ens.base_score = 0.5, 0.25
+    # Sums of about 11 leaf values of magnitude 1, as F32_ACC_TOL was
+    # sized for, whatever the tree count.
+    random_values = ens.leaf_value * np.float32(min(1.0, 11 * C / T))
+    Xb = np.random.default_rng(depth).integers(
+        0, ens.n_bins, size=(300, F)).astype(np.int32)
+    for values, check in [
+        (np.round(random_values * 64 * max(1, T // (11 * C))) / 64,
+         np.testing.assert_array_equal),
+        (random_values,
+         lambda a, b: np.testing.assert_allclose(a, b, **F32_ACC_TOL)),
+    ]:
+        ens.leaf_value = values.astype(np.float32)
+        args, kw, opt = _dev_args(ens)
+        got = np.asarray(jpp.predict_raw_pallas(
+            *args, jnp.asarray(Xb), tree_chunk=64, **kw, **opt))
+        onehot = np.asarray(jpred.predict_raw(
+            *args, jnp.asarray(Xb), tree_chunk=64, use_pallas=False, **kw,
+            **opt))
+        check(onehot, got)
+        check(numpy_predict.predict_raw(ens, Xb), got)
+
+
 @pytest.mark.parametrize("depth", [2, 3, 4])
 @pytest.mark.parametrize("F", [28, 54, 64, 65])
 @pytest.mark.parametrize("missing,cat", [
@@ -411,8 +501,8 @@ def test_folded_constants_are_exact_in_bfloat16(nan_bin, missing, cat):
     np.testing.assert_array_equal(got, want)
 
 
-def _kernel_jaxpr(F, missing=False, cat=()):
-    ens = _rand_ensemble(T=9, depth=3, F=F, bins=255, seed=F,
+def _kernel_jaxpr(F, missing=False, cat=(), T=9, depth=3):
+    ens = _rand_ensemble(T=T, depth=depth, F=F, bins=255, seed=F,
                          missing=missing, cat=cat)
     args, kw, opt = _dev_args(ens)
     Xb = jnp.zeros((300, F), jnp.int32)
@@ -456,6 +546,32 @@ def test_wide_models_keep_the_one_node_program():
     assert not [op for op in packed_ops if op not in packed]
     assert packed.count("dot_general") == 4 + 1
     assert "bf16[256,128]" in packed and "bf16[128,128]" in packed
+
+
+def test_one_group_keeps_the_parents_program():
+    """The CTR model's shape, 100 trees x depth 6 x 39 features with both
+    routing tables: ONE group a grid step, so there is no dot to share,
+    and the scoring program is the one it was (the whole jaxpr was
+    compared with the parent commit's at this shape, at 64 trees x 28
+    features, at 128 trees x 7 classes and at 60 features with both
+    tables: the same text, PR 34): one class dot, on the group's own
+    window of a class one-hot laid out by blocks, no add of value
+    planes. Eight groups (1000 trees) make one dot too, on a [128, 1]
+    one-hot that no block index reaches, and seven adds of [256, 128]
+    planes before it."""
+    plan = jpp.table_plan(128, 6, 39, 1, None, 2)
+    assert (plan.groups_per_step, plan.blocks, plan.class_dots_per_step,
+            plan.trees_per_group, plan.tree_group) == (1, 1, 1, 128, 128)
+    class_dot = "precision=(Precision.HIGHEST, Precision.HIGHEST)"
+    plane_add = "f32[256,128] = add "
+    one = _kernel_jaxpr(39, True, (13, 20), T=100, depth=6)
+    assert one.count("dot_general") == 63 + 1
+    assert one.count(class_dot) == 1 and plane_add not in one
+    assert "f32[1,128,1]" in one
+    eight = _kernel_jaxpr(28, T=1000, depth=6)
+    assert eight.count("dot_general") == 8 * 32 + 1
+    assert eight.count(class_dot) == 1 and eight.count(plane_add) == 7
+    assert "f32[128,1]" in eight and "f32[1,1024,1]" not in eight
 
 
 @pytest.mark.parametrize("F,depth,nodes,tiles", [
@@ -537,22 +653,28 @@ def test_pallas_fits_guard(depth, F, C, optional, tile_r, fits):
     (1152, 8, 54, 7, 0, 9, 9, 1),
     (1280, 8, 54, 7, 0, 10, 5, 2),
     (2816, 6, 54, 7, 1, 22, 22, 1),
-    (2944, 6, 54, 7, 1, 23, 12, 2),
+    # 7 classes: groups of whole rounds, 126 trees, where the class dots
+    # that saves outweigh the groups it adds (PR 34): 2,944 padded trees
+    # are 23 groups of 128 and 24 of 126, two blocks of 12 either way
+    (2944, 6, 54, 7, 1, 24, 12, 2),
     (1536, 6, 60, 7, 2, 12, 12, 1),
-    (1664, 6, 60, 7, 2, 13, 7, 2),
+    (1664, 6, 60, 7, 2, 14, 7, 2),    # 13 of 128: two blocks of 7 as well
     (384, 7, 60, 7, 2, 3, 3, 1),
     (1024, 7, 28, 1, 0, 8, 8, 1),
     (1024, 7, 60, 1, 2, 8, 3, 3),
     # ... which both tables cost only by the integer routing (more than
     # 56 features); folded, the same models are planned as with one table
-    (1536, 6, 54, 7, 2, 12, 12, 1),
-    (1664, 6, 54, 7, 2, 13, 13, 1),
-    (2944, 6, 54, 7, 2, 23, 12, 2),
+    # (a 13th group of 126 at 63 weight tiles against twelve dots of 6;
+    # a 14th against thirteen)
+    (1536, 6, 54, 7, 2, 13, 13, 1),
+    (1664, 6, 54, 7, 2, 14, 14, 1),
+    (2944, 6, 54, 7, 2, 24, 12, 2),
     (1024, 7, 28, 1, 2, 8, 8, 1),
     (3520, 8, 54, 7, 2, 28, 6, 5),
     (128, 8, 28, 1, 2, 1, 1, 1),
     # Covertype's own model, 500 rounds x 7 classes: 9 groups fit, so 4
-    # blocks, of 7 (not 3 of 9 and one of 1 filled to 9)
+    # blocks, of 7 (not 3 of 9 and one of 1 filled to 9); 28 groups of
+    # 128 and 28 of 126
     (3520, 8, 54, 7, 0, 28, 7, 4),
     # the trace is bounded whatever the tree count
     (1 << 20, 6, 28, 1, 0, 8192, 27, 304),
@@ -560,17 +682,67 @@ def test_pallas_fits_guard(depth, F, C, optional, tile_r, fits):
 ])
 def test_table_plan(tpad, depth, F, C, optional, groups, g, blocks):
     """How many tree groups a table block holds (G) and how many blocks a
-    row tile walks: the budget's arithmetic, pinned."""
+    row tile walks: the budget's arithmetic, pinned. G is what it was
+    before the blocks shared their class dot: `_vmem_bytes` charges a
+    class window a group as it did."""
     plan = jpp.table_plan(tpad, depth, F, C, None, optional)
     assert plan[:3] == (groups, g, blocks)
     assert g * blocks >= groups * bool(g)
-    per_group = 4 * 128 * ((2 + optional) * (2 ** depth - 1) + 2 ** depth
-                           + C)
-    assert plan.table_bytes == blocks * g * per_group
+    assert groups == -(-tpad // (plan.trees_per_group or 128))
+    nodes = 4 * 128 * ((2 + optional) * (2 ** depth - 1) + 2 ** depth)
+    # the class one-hot: one window the whole ensemble shares, fetched
+    # once, or one a group
+    shared = plan.class_dots_per_step < g
+    assert plan.table_bytes == (blocks * g * nodes + 4 * 128 * C * (
+        bool(g) if shared else blocks * g))
     assert plan.tile_rows == 256
     if g:
         assert jpp._vmem_bytes(g, depth, F, C, 256, optional) \
             <= jpp._VMEM_BUDGET_BYTES
+
+
+@pytest.mark.parametrize("tpad,depth,F,C,optional,per_group,dots,groups", [
+    # One class, and any C that divides 128: lane l is class l % C in
+    # every group of 128, so every block of more than one group shares
+    # its dot and nothing else moves.
+    (1024, 6, 28, 1, 0, 128, 1, 8),       # the 1000-tree cell
+    (1024, 6, 28, 2, 0, 128, 1, 8),
+    (1024, 8, 54, 64, 0, 128, 1, 8),
+    (128, 6, 39, 1, 2, 128, 1, 1),        # the CTR cell: one group
+    (64, 6, 28, 1, 0, 128, 1, 1),
+    # Covertype's own model: 28 groups of 126 where 28 of 128 were
+    (3520, 8, 54, 7, 0, 126, 1, 28),
+    (3520, 8, 54, 7, 2, 126, 1, 28),
+    # 3 classes: 126 too; 1,216 padded trees are 10 groups either way
+    (1216, 6, 28, 3, 0, 126, 1, 10),
+    # 10 classes at depth 8: 9 groups of 120 for 8 of 128 are 128 weight
+    # tiles more, the seven dots saved 42: each group keeps its dot ...
+    (1024, 8, 54, 10, 0, 128, 8, 8),
+    # ... at depth 3 (4 tiles a group) the dots are what costs
+    (1024, 3, 54, 10, 0, 120, 1, 9),
+    # 7 classes where the 126s do not fit the groups the 128s fill:
+    # 1,152 padded trees in 9 groups, resident, against 10 in two blocks
+    (1152, 8, 54, 7, 0, 128, 9, 9),
+    # more classes than lanes: no whole round fits a group
+    (1024, 4, 28, 200, 0, 128, 8, 8),
+    # blocks of ONE group (depth 10, both tables folded): a dot each, and
+    # the program of before
+    (1024, 10, 28, 1, 2, 128, 1, 8),
+    (128, 8, 60, 1, 2, 128, 0, 1),        # nothing fits
+])
+def test_class_dot_layout_is_read_from_the_shape(tpad, depth, F, C, optional,
+                                                 per_group, dots, groups):
+    """Whether a block's groups share one class dot, and the trees a
+    group then holds (whole rounds of C), follow from C, the padded tree
+    count and G: the layout that asks the MXU for fewer results a row
+    tile, the 128-tree groups with a dot each on a tie."""
+    plan = jpp.table_plan(tpad, depth, F, C, None, optional)
+    assert (plan.trees_per_group, plan.class_dots_per_step,
+            plan.table_groups) == (per_group, dots, groups)
+    assert dots in (1, plan.groups_per_step)
+    assert plan.tree_group == 128
+    if per_group < 128:
+        assert per_group % C == 0 and per_group + C > 128 and dots == 1
 
 
 def test_padded_tree_count_must_be_a_multiple_of_the_chunk():
@@ -602,7 +774,10 @@ def test_ensemble_span_says_which_form_served(impl, T, want, routed, F):
     when that kernel does not serve the model; `routing_tables`: how many
     of the missing and categorical tables that kernel routes by;
     `routes_in_tile`: how many of those it routes inside the MXU weight
-    tile (all of them at F <= 56), not by integers on the VPU."""
+    tile (all of them at F <= 56), not by integers on the VPU;
+    `trees_per_group` and `class_dots_per_step`: the lanes of a group
+    that hold trees and the class dots a grid step makes, 1 where the
+    block's groups share it."""
     from ddt_tpu.telemetry import annotations as an
 
     ens = _rand_ensemble(T=T, depth=3, F=F, bins=31, seed=40 + T,
@@ -630,8 +805,11 @@ def test_ensemble_span_says_which_form_served(impl, T, want, routed, F):
     groups = -(-T // 128) if want else 0
     assert (counts["table_groups"], counts["groups_per_step"]) \
         == (groups, groups)
-    assert counts["table_bytes"] == groups * 4 * 128 * (
-        (2 + routed) * 7 + 8 + 1)
+    # (the class one-hot once: two groups share one dot and its window)
+    assert counts["table_bytes"] == 4 * 128 * (
+        groups * ((2 + routed) * 7 + 8) + bool(groups))
+    assert (counts["trees_per_group"], counts["class_dots_per_step"]) \
+        == ((128, 1) if want else (0, 0))
     # ... and how the kernel uses the MXU: 5 features, so two nodes a
     # weight tile, the root's and one a pair of siblings (7 nodes: 4);
     # with a routing table one node a tile.

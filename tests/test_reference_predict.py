@@ -63,20 +63,6 @@ def rows_of(n_rows, n_features, seed):
         0, BINS, size=(n_rows, n_features), dtype=np.uint8)
 
 
-@pytest.fixture
-def budget(monkeypatch):
-    """budget(groups, depth, F, C, optional): the VMEM budget at which
-    exactly `groups` tree groups fit a table block at that shape. The jit
-    caches are dropped around the test: the budget is read while tracing."""
-    def shrink(groups, depth, n_features, n_classes, optional=0):
-        monkeypatch.setattr(jpp, "_VMEM_BUDGET_BYTES", jpp._vmem_bytes(
-            groups, depth, n_features, n_classes, jpp._DEFAULT_TILE_R,
-            optional))
-    jax.clear_caches()
-    yield shrink
-    jax.clear_caches()
-
-
 def score(ens, Xb, impl="pallas"):
     """The normal path: `api.predict`. Off the chip the auto dispatch is
     the one-hot path, so the kernel (interpreted here) is asked for by
@@ -89,24 +75,32 @@ def score(ens, Xb, impl="pallas"):
 
 
 # (trees, depth, features, classes, missing, cat, G the budget admits,
-#  the plan that follows: groups, G, blocks)
+#  the plan that follows: groups, G, blocks, trees a group, class dots a
+#  grid step: 1 where a block's groups share one, which takes groups of
+#  whole rounds, 126 trees at 7 classes and at 3)
 CASES = [
     # Covertype's shape, three groups of which one fits: three blocks
-    pytest.param(300, 8, 54, 7, False, (), 1, (3, 1, 3), id="covtype-G1"),
+    pytest.param(300, 8, 54, 7, False, (), 1, (3, 1, 3, 128, 1),
+                 id="covtype-G1"),
     # two fit: blocks of 2, the last one ragged (3 groups padded to 4)
-    pytest.param(300, 8, 54, 7, False, (), 2, (3, 2, 2),
+    pytest.param(300, 8, 54, 7, False, (), 2, (3, 2, 2, 126, 1),
                  id="covtype-G2-ragged"),
     # five groups of which two fit: evened out to 3 blocks of 2
-    pytest.param(520, 5, 54, 7, False, (), 2, (5, 2, 3),
+    pytest.param(520, 5, 54, 7, False, (), 2, (5, 2, 3, 126, 1),
                  id="covtype-d5-evened"),
-    # the whole ensemble fits: one block, tables resident
-    pytest.param(256, 8, 54, 7, False, (), 2, (2, 2, 1),
+    # the whole ensemble fits: one block, tables resident; 256 padded
+    # trees are two groups of 128 and three of 126, so each keeps its dot
+    pytest.param(256, 8, 54, 7, False, (), 2, (2, 2, 1, 128, 2),
                  id="covtype-resident"),
-    pytest.param(300, 6, 28, 1, False, (), 1, (3, 1, 3), id="one-class"),
-    pytest.param(300, 4, 12, 3, True, (1, 4), 2, (3, 2, 2),
+    pytest.param(300, 6, 28, 1, False, (), 1, (3, 1, 3, 128, 1),
+                 id="one-class"),
+    pytest.param(300, 6, 28, 1, False, (), 3, (3, 3, 1, 128, 1),
+                 id="one-class-shared-dot"),
+    pytest.param(300, 4, 12, 3, True, (1, 4), 2, (3, 2, 2, 126, 1),
                  id="both-operands"),
-    pytest.param(200, 4, 12, 1, True, (), 1, (2, 1, 2), id="missing-only"),
-    pytest.param(200, 4, 12, 3, False, (0, 5), 1, (2, 1, 2),
+    pytest.param(200, 4, 12, 1, True, (), 1, (2, 1, 2, 128, 1),
+                 id="missing-only"),
+    pytest.param(200, 4, 12, 3, False, (0, 5), 1, (2, 1, 2, 128, 1),
                  id="cat-only"),
 ]
 
@@ -126,12 +120,17 @@ def test_kernel_over_table_blocks_matches_reference(budget, T, depth, F, C,
     assert got.dtype == np.float32
     np.testing.assert_allclose(got, want, **TOL)
 
-    groups, g, blocks = plan
+    groups, g, blocks, per_group, dots = plan
     assert (ensemble["tree_group"], ensemble["table_groups"],
             ensemble["groups_per_step"]) == (128, groups, g)
+    assert (ensemble["trees_per_group"],
+            ensemble["class_dots_per_step"]) == (per_group, dots)
     n_int = 2 ** depth - 1
-    table_bytes = blocks * g * 128 * 4 * ((2 + optional) * n_int
-                                          + n_int + 1 + C)
+    # every block's node tables, and the class one-hot: one the whole
+    # ensemble shares, or one a group
+    table_bytes = 128 * 4 * (blocks * g * ((2 + optional) * n_int
+                                           + n_int + 1)
+                             + (blocks * g if dots == g else 1) * C)
     assert ensemble["table_bytes"] == table_bytes
     assert root["classes"] == C
     assert root["tables_streamed_bytes"] == (
