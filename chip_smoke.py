@@ -45,6 +45,7 @@ Last line of stdout on success, and only then:
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import sys
@@ -394,6 +395,30 @@ def score_node_list(overrides: dict, rows: int) -> None:
     assert gap <= 1e-5, gap
 
 
+def check_device_stages() -> None:
+    """Every instruction the scoring programs traced from this package is
+    under a named stage (telemetry/annotations.device_stages: the newest
+    heap model's program, the routed one, and the node list's, each at the
+    chunk loop's own shape). An `unscoped` instruction with a source line
+    in `ddt_tpu/` is device work that no per-layer metric of the benchmark
+    would read: it fails here, before a benchmark run."""
+    from ddt_tpu.telemetry.annotations import UNSCOPED, device_stages
+
+    t0 = time.perf_counter()
+    stages = device_stages()
+    for program in ("jit_predict_raw_effective",
+                    "jit_predict_raw_effective_paths"):
+        held = stages[program]
+        lost = {name: e for name, e in held.items()
+                if e["stage"] == UNSCOPED
+                and e["source"].startswith("ddt_tpu/")}
+        assert not lost, f"{program}: under no device stage: {lost}"
+        by_stage = collections.Counter(e["stage"] for e in held.values())
+        say(f"device stages of {program}: " + ", ".join(
+            f"{k} {v}" for k, v in sorted(by_stage.items())))
+    timing("device_stages(), two programs", wall=time.perf_counter() - t0)
+
+
 def parity_against_reference(overrides: dict) -> None:
     """5 trees on 20k rows: the chip against reference/numpy_trainer (pure
     NumPy — no native library decides this verdict)."""
@@ -578,6 +603,7 @@ def main(argv=None) -> int:
                  else ROUTED_ROWS)
     score_node_list(overrides, LEAFWISE_ROWS // 100 if args.rehearse
                     else LEAFWISE_ROWS)
+    check_device_stages()
     parity_against_reference(overrides)
     barrier_experiment(be, Xb)
     if count >= 4:

@@ -53,7 +53,7 @@ from ddt_tpu.parallel import comms as comms_lib
 from ddt_tpu.parallel import mesh as mesh_lib
 from ddt_tpu.robustness import emit_fault, faultplan
 from ddt_tpu.telemetry import counters as tele_counters
-from ddt_tpu.telemetry.annotations import phase_span
+from ddt_tpu.telemetry.annotations import phase_span, stage_program
 from ddt_tpu.telemetry.costmodel import costed
 from ddt_tpu.utils import device
 from ddt_tpu.utils import retry as retry_lib
@@ -1921,23 +1921,25 @@ class TPUDevice(DeviceBackend):
                     ce.n_trees_padded, ce.max_depth, ens.n_features,
                     ce.n_classes_out, None, use_missing + use_cat)
 
-            def fn0(ef, et, bv, coh, *rest):
+            static = dict(
+                max_depth=ce.max_depth, learning_rate=ce.learning_rate,
+                base=ce.base_score, n_classes=ce.n_classes_out,
+                tree_chunk=ce.tree_chunk,
+                missing_bin_value=ce.missing_bin_value,
+                use_pallas=use_pallas)
+
+            def fn0(ef, et, bv, coh, *rest,
+                    entry=predict_ops.predict_raw_effective):
                 *opt, Xc = rest
                 opt = list(opt)
                 dl = opt.pop(0) if use_missing else None
                 cn = opt.pop(0) if use_cat else None
-                return predict_ops.predict_raw_effective(
-                    ef, et, bv, coh, Xc,
-                    max_depth=ce.max_depth,
-                    learning_rate=ce.learning_rate,
-                    base=ce.base_score,
-                    n_classes=ce.n_classes_out,
-                    tree_chunk=ce.tree_chunk,
-                    eff_dl=dl,
-                    missing_bin_value=ce.missing_bin_value,
-                    eff_cat=cn,
-                    use_pallas=use_pallas,
-                )
+                return entry(ef, et, bv, coh, Xc, eff_dl=dl, eff_cat=cn,
+                             **static)
+
+            self._stage_scoring_program(
+                predict_ops.predict_raw_effective, fn0, ens_dev,
+                ens.n_features)
 
         return (self._row_sharded(fn0, len(ens_dev), ce.n_classes_out),
                 ens_dev, resolved, ce.n_classes_out, plan)
@@ -1963,13 +1965,48 @@ class TPUDevice(DeviceBackend):
                 use_pallas, True, 0, ens.n_features, 1,
                 path_lanes=ce.lanes))
 
-        def fn0(sel, planes, paths, Xc):
-            return predict_ops.predict_raw_effective_paths(
-                sel, planes, paths, Xc,
-                learning_rate=ce.learning_rate, base=ce.base_score,
-                use_pallas=use_pallas)
+        # Bound here: fn0 outlives this call in the stage registry, and
+        # must not hold the host copy of the path tables (78 MB at 500
+        # trees x 255 leaves).
+        learning_rate, base = ce.learning_rate, ce.base_score
 
+        def fn0(sel, planes, paths, Xc,
+                entry=predict_ops.predict_raw_effective_paths):
+            return entry(sel, planes, paths, Xc, learning_rate=learning_rate,
+                         base=base, use_pallas=use_pallas)
+
+        self._stage_scoring_program(
+            predict_ops.predict_raw_effective_paths, fn0, ens_dev,
+            ens.n_features)
         return self._row_sharded(fn0, 3, 1), ens_dev, "f32", 1, plan
+
+    def _stage_scoring_program(self, entry, fn0, ens_dev,
+                               n_features: int) -> None:
+        """Tell telemetry.annotations.device_stages() how to read the
+        stages of the programs the single-chip big-batch loop runs: the
+        scoring program `entry` (a jitted function of ops/predict.py; fn0
+        calls it with the model's static arguments) at the loop's own
+        shapes, the device tables' and a chunk of PREDICT_ROW_CHUNK uint8
+        rows, and the loop's two small programs by what they are for.
+        Two dict writes: the lowering happens when somebody asks. A call of
+        another row count runs another executable of the same name, whose
+        instructions the map may not hold; a mesh's row-sharded wrapper is
+        another program and is not named."""
+        if self.distributed:
+            return
+        avals = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ens_dev]
+        avals.append(jax.ShapeDtypeStruct(
+            (self.PREDICT_ROW_CHUNK, n_features), jnp.uint8))
+        stage_program(
+            "jit_" + entry.__name__,
+            lambda: fn0(*avals, entry=entry.lower).compile().as_text())
+        loop = "ddt_tpu/backends/tpu.py:%d" % (
+            TPUDevice._predict_raw.__code__.co_firstlineno)
+        # `pieces[-1][at:at + chunk]` and the first piece's `jnp.reshape`,
+        # by the names jax gives the programs of its own eager operations.
+        stage_program("jit_dynamic_slice", stage="predict:slice",
+                      source=loop)
+        stage_program("jit_reshape", stage="predict:unflatten", source=loop)
 
     def _row_sharded(self, fn, n_rep: int, C: int):
         """`fn(*replicated tables, rows)` as it runs on this backend:

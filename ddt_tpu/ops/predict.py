@@ -292,7 +292,8 @@ def _predict_effective(
     once per model on host; predict_raw still computes it in-trace)."""
     binned = bool(jnp.issubdtype(Xc.dtype, jnp.integer))
     if binned:
-        Xc = Xc.astype(jnp.int32)      # uint8 uploads are 4x cheaper; widen
+        with traced_scope("predict:widen"):
+            Xc = Xc.astype(jnp.int32)  # uint8 uploads are 4x cheaper; widen
     R, F = Xc.shape
     C = n_classes
     if R == 0:
@@ -320,21 +321,23 @@ def _predict_effective(
         # including 65536, is always honored.
         row_chunk = 8_192 if binned else _DEFAULT_ROW_CHUNK
     n_tc = Tpad // tree_chunk
-    featp = eff_feat.reshape(n_tc, tree_chunk, -1)
-    thrp = eff_thr.reshape(n_tc, tree_chunk, -1)
     use_missing = eff_dl is not None
-    if use_missing:
-        dlp = eff_dl.reshape(n_tc, tree_chunk, -1)
     use_cat = eff_cat is not None
-    if use_cat:
-        catp = eff_cat.reshape(n_tc, tree_chunk, -1)
-    valp = bot_val.reshape(n_tc, tree_chunk, -1)      # bottom level only
-    cls_ohp = cls_oh.reshape(n_tc, tree_chunk, C)
+    with traced_scope("predict:tables"):
+        featp = eff_feat.reshape(n_tc, tree_chunk, -1)
+        thrp = eff_thr.reshape(n_tc, tree_chunk, -1)
+        if use_missing:
+            dlp = eff_dl.reshape(n_tc, tree_chunk, -1)
+        if use_cat:
+            catp = eff_cat.reshape(n_tc, tree_chunk, -1)
+        valp = bot_val.reshape(n_tc, tree_chunk, -1)  # bottom level only
+        cls_ohp = cls_oh.reshape(n_tc, tree_chunk, C)
 
     row_chunk = min(row_chunk, R)
     n_rc = -(-R // row_chunk)
     rpad = n_rc * row_chunk - R
-    Xp = jnp.pad(Xc, ((0, rpad), (0, 0))).reshape(n_rc, row_chunk, F)
+    with traced_scope("predict:widen"):
+        Xp = jnp.pad(Xc, ((0, rpad), (0, 0))).reshape(n_rc, row_chunk, F)
 
     def row_body(_, xrc):
         def tree_body(acc, args):
@@ -386,14 +389,14 @@ def _predict_effective(
         acc, _ = jax.lax.scan(tree_body, acc0, tuple(xs))
         return None, acc
 
-    # `ddt:predict` on the device timeline (telemetry.annotations): the
-    # whole doubly-chunked descent shows as one named span in Perfetto,
-    # matching the host-side scoring phase name; `ddt:predict:traverse` /
-    # `ddt:predict:accumulate` sub-spans nest inside it.
-    with traced_scope("predict"):
+    # The two scans' own bookkeeping is the traversal's; the class dot
+    # inside them names itself (the innermost scope is an instruction's
+    # stage: telemetry.annotations.device_stages).
+    with traced_scope("predict:traverse"):
         _, accs = jax.lax.scan(row_body, None, Xp)           # [n_rc, Rc, C]
-    out = base + learning_rate * accs.reshape(n_rc * row_chunk, C)[:R]
-    return out[:, 0] if C == 1 else out
+    with traced_scope("predict:accumulate"):
+        out = base + learning_rate * accs.reshape(n_rc * row_chunk, C)[:R]
+        return out[:, 0] if C == 1 else out
 
 
 @costed("predict", phase="predict")
@@ -402,6 +405,7 @@ def _predict_effective(
     static_argnames=("max_depth", "n_classes", "tree_chunk", "row_chunk",
                      "missing_bin_value", "use_pallas"),
 )
+@op_scope("predict")
 def predict_raw_effective(
     eff_feat: jax.Array,       # [Tpad, N] pushed-down features
     eff_thr: jax.Array,        # [Tpad, N] pushed-down thresholds
@@ -517,17 +521,19 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base):
     n_tc = -(-T // tree_chunk)
     # trees that fill the last chunk: no node, no leaf (len -1: no match)
     t_fill = ((0, n_tc * tree_chunk - T), (0, 0), (0, 0))
-    selp = jnp.pad(sel.astype(jnp.float32), t_fill).reshape(
-        n_tc, tree_chunk, Fp, W)
-    planesp = jnp.pad(planes, t_fill, constant_values=-1.0).reshape(
-        n_tc, tree_chunk, 8, W)
-    pathsp = jnp.pad(paths.astype(jnp.float32), t_fill).reshape(
-        n_tc, tree_chunk, W, W)
+    with traced_scope("predict:tables"):
+        selp = jnp.pad(sel.astype(jnp.float32), t_fill).reshape(
+            n_tc, tree_chunk, Fp, W)
+        planesp = jnp.pad(planes, t_fill, constant_values=-1.0).reshape(
+            n_tc, tree_chunk, 8, W)
+        pathsp = jnp.pad(paths.astype(jnp.float32), t_fill).reshape(
+            n_tc, tree_chunk, W, W)
     row_chunk = min(_PATHS_ROW_CHUNK, R)
     n_rc = -(-R // row_chunk)
-    Xp = jnp.pad(Xc.astype(jnp.float32),
-                 ((0, n_rc * row_chunk - R), (0, Fp - F))
-                 ).reshape(n_rc, row_chunk, Fp)
+    with traced_scope("predict:widen"):
+        Xp = jnp.pad(Xc.astype(jnp.float32),
+                     ((0, n_rc * row_chunk - R), (0, Fp - F))
+                     ).reshape(n_rc, row_chunk, Fp)
 
     def row_body(_, xrc):
         def tree_body(acc, args):
@@ -548,9 +554,10 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base):
                               (selp, planesp, pathsp))
         return None, acc
 
-    with traced_scope("predict"):
+    with traced_scope("predict:traverse"):
         _, accs = jax.lax.scan(row_body, None, Xp)
-    return base + learning_rate * accs.reshape(n_rc * row_chunk)[:R]
+    with traced_scope("predict:accumulate"):
+        return base + learning_rate * accs.reshape(n_rc * row_chunk)[:R]
 
 
 @costed("predict", phase="predict")
@@ -558,6 +565,7 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base):
     jax.jit,
     static_argnames=("learning_rate", "base", "use_pallas"),
 )
+@op_scope("predict")
 def predict_raw_effective_paths(
     sel: jax.Array,            # bf16 [T, Fp, W] feature one-hot of the nodes
     planes: jax.Array,         # f32 [T, 8, W] rows: thr, path length, value
@@ -574,15 +582,15 @@ def predict_raw_effective_paths(
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
         raise ValueError("the path-matrix form scores binned (integer) rows")
     if Xc.shape[0] == 0:
-        with traced_scope("predict"):
-            return jnp.full((0,), base, jnp.float32)
+        return jnp.full((0,), base, jnp.float32)
     if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], 1,
                           path_lanes=sel.shape[2]):
         from ddt_tpu.ops import predict_paths
 
+        with traced_scope("predict:widen"):
+            Xi = Xc.astype(jnp.int32)
         return predict_paths.predict_paths_pallas(
-            sel, planes, paths, Xc.astype(jnp.int32),
-            learning_rate=learning_rate, base=base)
+            sel, planes, paths, Xi, learning_rate=learning_rate, base=base)
     return _predict_paths(sel, planes, paths, Xc,
                           learning_rate=learning_rate, base=base)
 

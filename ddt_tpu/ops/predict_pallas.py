@@ -821,50 +821,55 @@ def predict_effective_pallas(
                         constant_values=fill)
         return a.transpose(0, 1, 3, 2).reshape(n_blocks, n_g * width, tg)
 
-    feat_pl = by_plane(eff_feat[:, :n_int], jnp.int32, fill=-1)
-    folded = plan.routes_in_tile > 0
-    extras = []
-    if folded:
-        h, *routes = _folded_routes(
-            eff_feat[:, :n_int], eff_thr[:, :n_int],
-            eff_dl[:, :n_int] if use_missing else None,
-            eff_cat[:, :n_int] if use_cat else None, missing_bin_value)
-        thr_pl = by_plane(h, jnp.float32)
-        extras = [by_plane(a, jnp.float32) for a in routes if a is not None]
-    elif plan.nodes_per_tile == 1:
-        thr_pl = by_plane(eff_thr[:, :n_int], jnp.float32)
-    else:
-        # The compare is made on the packed word, in integers: thr + 1
-        # (>= for >) shifted to the node's byte, and the tile's top byte
-        # (even nodes, the root) carries the word's constant high bits. A
-        # pushed-down leaf's +BIG becomes 255, which no byte exceeds.
-        node = jnp.arange(n_int)
-        top = node % 2 == 0
-        thr_i = jnp.clip(eff_thr[:, :n_int], -1, 255).astype(jnp.int32)
-        thr_pl = by_plane(
-            ((thr_i + 1) << jnp.where(top & (node > 0), 8, 0))
-            + jnp.where(top, _MANTISSA_BITS, 0), jnp.int32)
-    val_pl = by_plane(bot_val, jnp.float32)
-    if groups_per_dot > 1:
-        # Lane l is class l % C in every group of whole rounds.
-        coh = jnp.pad(cls_oh[:u].astype(jnp.float32), ((0, tg - u), (0, 0)))
-    else:
-        coh = jnp.pad(cls_oh.astype(jnp.float32),
-                      ((0, t_fill), (0, 0))).reshape(n_blocks, n_g * tg, C)
-    if use_missing and not folded:
-        extras.append(by_plane(eff_dl[:, :n_int], jnp.int32))
-    if use_cat and not folded:
-        # Pre-gate on eff_feat >= 0 so pushed-down leaves (colval 0,
-        # thr +BIG) stay always-left, exactly like _descend_comp.
-        cat_eff = eff_cat[:, :n_int].astype(bool) & (eff_feat[:, :n_int]
-                                                     >= 0)
-        extras.append(by_plane(cat_eff, jnp.int32))
+    with traced_scope("predict:tables"):
+        feat_pl = by_plane(eff_feat[:, :n_int], jnp.int32, fill=-1)
+        folded = plan.routes_in_tile > 0
+        extras = []
+        if folded:
+            h, *routes = _folded_routes(
+                eff_feat[:, :n_int], eff_thr[:, :n_int],
+                eff_dl[:, :n_int] if use_missing else None,
+                eff_cat[:, :n_int] if use_cat else None, missing_bin_value)
+            thr_pl = by_plane(h, jnp.float32)
+            extras = [by_plane(a, jnp.float32) for a in routes
+                      if a is not None]
+        elif plan.nodes_per_tile == 1:
+            thr_pl = by_plane(eff_thr[:, :n_int], jnp.float32)
+        else:
+            # The compare is made on the packed word, in integers: thr +
+            # 1 (>= for >) shifted to the node's byte, and the tile's top
+            # byte (even nodes, the root) carries the word's constant high
+            # bits. A pushed-down leaf's +BIG becomes 255, which no byte
+            # exceeds.
+            node = jnp.arange(n_int)
+            top = node % 2 == 0
+            thr_i = jnp.clip(eff_thr[:, :n_int], -1, 255).astype(jnp.int32)
+            thr_pl = by_plane(
+                ((thr_i + 1) << jnp.where(top & (node > 0), 8, 0))
+                + jnp.where(top, _MANTISSA_BITS, 0), jnp.int32)
+        val_pl = by_plane(bot_val, jnp.float32)
+        if groups_per_dot > 1:
+            # Lane l is class l % C in every group of whole rounds.
+            coh = jnp.pad(cls_oh[:u].astype(jnp.float32),
+                          ((0, tg - u), (0, 0)))
+        else:
+            coh = jnp.pad(cls_oh.astype(jnp.float32),
+                          ((0, t_fill), (0, 0))).reshape(n_blocks, n_g * tg, C)
+        if use_missing and not folded:
+            extras.append(by_plane(eff_dl[:, :n_int], jnp.int32))
+        if use_cat and not folded:
+            # Pre-gate on eff_feat >= 0 so pushed-down leaves (colval 0,
+            # thr +BIG) stay always-left, exactly like _descend_comp.
+            cat_eff = eff_cat[:, :n_int].astype(bool) & (eff_feat[:, :n_int]
+                                                         >= 0)
+            extras.append(by_plane(cat_eff, jnp.int32))
 
-    Xi = Xc.astype(jnp.int32)
     n_tiles = -(-R // tile_r)
     rpad = n_tiles * tile_r - R
-    if rpad:
-        Xi = jnp.pad(Xi, ((0, rpad), (0, 0)))
+    with traced_scope("predict:widen"):
+        Xi = Xc.astype(jnp.int32)
+        if rpad:
+            Xi = jnp.pad(Xi, ((0, rpad), (0, 0)))
 
     kernel = functools.partial(
         _traverse_kernel, n_groups=n_g, groups_per_dot=groups_per_dot,
@@ -904,21 +909,20 @@ def predict_effective_pallas(
         + plan.table_bytes * (n_tiles if n_blocks > 1 else 1),
         transcendentals=0,
     )
-    with traced_scope("predict"):
-        with traced_scope("predict:traverse"):
-            acc = pl.pallas_call(
-                kernel,
-                grid=(n_tiles, n_blocks),
-                in_specs=in_specs,
-                out_specs=rows_of_tile(C),
-                out_shape=jax.ShapeDtypeStruct((n_tiles * tile_r, C),
-                                               jnp.float32),
-                cost_estimate=cost,
-                interpret=interpret,
-            )(Xi, feat_pl, thr_pl, val_pl, coh, *extras)
-        with traced_scope("predict:accumulate"):
-            out = base + learning_rate * acc[:R]
-    return out[:, 0] if C == 1 else out
+    with traced_scope("predict:traverse"):
+        acc = pl.pallas_call(
+            kernel,
+            grid=(n_tiles, n_blocks),
+            in_specs=in_specs,
+            out_specs=rows_of_tile(C),
+            out_shape=jax.ShapeDtypeStruct((n_tiles * tile_r, C),
+                                           jnp.float32),
+            cost_estimate=cost,
+            interpret=interpret,
+        )(Xi, feat_pl, thr_pl, val_pl, coh, *extras)
+    with traced_scope("predict:accumulate"):
+        out = base + learning_rate * acc[:R]
+        return out[:, 0] if C == 1 else out
 
 
 @costed("predict_pallas", phase="predict")

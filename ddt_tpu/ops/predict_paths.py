@@ -242,11 +242,13 @@ def predict_paths_pallas(
     # Trees that fill the last block: no node, and no leaf of any length
     # (-1), so they add 0. Rows that fill the last tile are cut off below.
     t_fill = ((0, n_blocks * g - T), (0, 0), (0, 0))
-    sel_b, paths_b = jnp.pad(sel, t_fill), jnp.pad(paths, t_fill)
-    planes_b = jnp.pad(planes, t_fill, constant_values=-1.0)
+    with traced_scope("predict:tables"):
+        sel_b, paths_b = jnp.pad(sel, t_fill), jnp.pad(paths, t_fill)
+        planes_b = jnp.pad(planes, t_fill, constant_values=-1.0)
     tile_rows = min(TILE_ROWS, -(-R // SUB_ROWS) * SUB_ROWS)
     n_tiles = -(-R // tile_rows)
-    Xt = jnp.pad(Xi, ((0, n_tiles * tile_rows - R), (0, 0)))
+    with traced_scope("predict:widen"):
+        Xt = jnp.pad(Xi, ((0, n_tiles * tile_rows - R), (0, 0)))
 
     def rows_of_tile(cols):
         return pl.BlockSpec((tile_rows, cols), lambda i, b: (i, 0),
@@ -262,20 +264,19 @@ def predict_paths_pallas(
                                   + plan.table_bytes),
         transcendentals=0,
     )
-    with traced_scope("predict"):
-        with traced_scope("predict:traverse_paths"):
-            acc = pl.pallas_call(
-                functools.partial(_paths_kernel, n_trees=g, n_feat=F),
-                grid=(n_tiles, n_blocks),
-                in_specs=[rows_of_tile(F), table_block(fp, lanes),
-                          table_block(8, lanes), table_block(lanes, lanes)],
-                out_specs=rows_of_tile(1),
-                out_shape=jax.ShapeDtypeStruct((n_tiles * tile_rows, 1),
-                                               jnp.float32),
-                cost_estimate=cost,
-                interpret=interpret,
-                compiler_params=pltpu.CompilerParams(
-                    vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-            )(Xt, sel_b, planes_b, paths_b)
-        with traced_scope("predict:accumulate"):
-            return base + learning_rate * acc[:R, 0]
+    with traced_scope("predict:traverse_paths"):
+        acc = pl.pallas_call(
+            functools.partial(_paths_kernel, n_trees=g, n_feat=F),
+            grid=(n_tiles, n_blocks),
+            in_specs=[rows_of_tile(F), table_block(fp, lanes),
+                      table_block(8, lanes), table_block(lanes, lanes)],
+            out_specs=rows_of_tile(1),
+            out_shape=jax.ShapeDtypeStruct((n_tiles * tile_rows, 1),
+                                           jnp.float32),
+            cost_estimate=cost,
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        )(Xt, sel_b, planes_b, paths_b)
+    with traced_scope("predict:accumulate"):
+        return base + learning_rate * acc[:R, 0]

@@ -17,8 +17,15 @@ Two halves of the Perfetto-alignment story (docs/OBSERVABILITY.md):
   `ddt:predict:upload`, ...; the table is in docs/OBSERVABILITY.md).
 - traced_scope(name): jax.named_scope for use INSIDE traced code. The
   ops kernels wrap their hist/allreduce/gain/route/leaf/predict stages,
-  which names the lowered XLA ops — the device timeline then carries
-  the same `ddt:` prefixes and lines up under the host spans.
+  which writes `ddt:<name>` into the `op_name` of every HLO instruction
+  traced under it. A device timeline does NOT carry that name: on the
+  chip an `XLA Ops` event is named by its instruction (`%fusion.1`,
+  `%copy.5`; benchmark/tracefile.py). The bridge from scope to event is
+  the instruction's NAME, and device_stages() is it: for each scoring
+  program this process built, `{instruction: {"stage", "source", "op"}}`
+  read from the optimized HLO of the executable that runs. The program
+  registers how to get that text (stage_program) when it builds a
+  model's scoring function; the map is made only when asked, once.
 
 Both work without jax (the cpu-backend CLI contract: spans are still
 recorded, the profiler half is skipped). What a span costs is measured,
@@ -31,6 +38,8 @@ import collections
 import contextlib
 import functools
 import itertools
+import os
+import re
 import threading
 import time
 
@@ -152,6 +161,128 @@ def op_scope(name: str):
                 return fn(*args, **kwargs)
         return wrapper
     return deco
+
+
+# ------------------------------------------------------------------ #
+# device stages: which stage of the program an HLO instruction belongs to
+# ------------------------------------------------------------------ #
+
+#: The stage of an instruction whose `op_name` holds no `ddt:<phase>:<stage>`
+#: scope: parameters, constants, the copies the compiler adds, and whatever a
+#: later change traces outside every stage (chip_smoke.py fails on that).
+UNSCOPED = "unscoped"
+#: In a program's map, the entry of EVERY instruction of a program that is
+#: one stage as a whole (the chunk loop's slice and reshape programs).
+WHOLE_PROGRAM = "*"
+
+# program name -> a callable giving its optimized HLO text, or the ready
+# map of a whole-program stage; the newest registration of a name wins
+_stage_programs: dict = {}
+_stage_maps: dict = {}             # program name -> its map, once made
+
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))) + os.sep
+_STAGE = re.compile(r"(?:^|/)" + PREFIX + r"(\w+:[^/]+)")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%[^\s=]+) = .*?[\s)]([a-z][\w-]*\([^)]{0,48})")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[^\s(]+) \(.*\{$")
+_TABLE_ROW = re.compile(r"^(\d+) (.*)$")
+_METADATA = re.compile(r' metadata=\{((?:[^"}]|"[^"]*")*)\}')
+
+
+def stage_program(name: str, hlo=None, *, stage: str | None = None,
+                  source: str = "") -> None:
+    """Name the device stages of program `name` (`jit_<function>`, as a
+    device trace's `XLA Modules` line spells it). Either `hlo`, a
+    zero-argument callable that returns the optimized HLO text of the
+    executable that runs (`jitted.lower(...).compile().as_text()`): it is
+    called when device_stages() is first asked, never before; or `stage`,
+    for a program that is one stage as a whole. A dict write: nothing is
+    lowered here."""
+    _stage_programs[name] = hlo if stage is None else {
+        WHOLE_PROGRAM: {"stage": stage, "source": source, "op": "program"}}
+    _stage_maps.pop(name, None)
+
+
+def device_stages() -> dict:
+    """`{program: {instruction: {"stage", "source", "op"}}}` for every
+    program named through stage_program(): `stage` is the innermost
+    `ddt:<phase>:<stage>` scope of the instruction's `op_name` without the
+    prefix (`predict:widen`) or UNSCOPED, `source` the `file:line` that
+    traced it (relative to the checkout; "" where the compiler made the
+    instruction), `op` its opcode and the start of its operands
+    (`copy(%Xc.1)`). A fusion is what its own metadata says.
+    Made on the first call that finds the program registered (a lowering,
+    a compile that jit's own cache or the persistent one serves, a parse)
+    and kept until the name is registered again."""
+    for name, hlo in list(_stage_programs.items()):
+        if name not in _stage_maps:
+            _stage_maps[name] = hlo if isinstance(hlo, dict) \
+                else stages_of_hlo(hlo())
+    return dict(_stage_maps)
+
+
+def stages_of_hlo(text: str) -> dict:
+    """device_stages()'s map of one program from its HLO text. The text
+    names a source either inline (`source_file=... source_line=...`) or by
+    `stack_frame_id`, an index into the tables at its head. The scalar
+    computation a reduction applies (`to_apply=`) is part of its reduce
+    instruction, never an operation of its own: left out."""
+    tables: dict = {}              # "FileNames" -> {id: the row's text}
+    table = None
+    applied = set(re.findall(r"to_apply=(%[^\s,)]+)", text))
+    skip = False                   # inside an applied computation
+    out = {}
+    for line in text.splitlines():
+        if table is not None:      # inside one of the head's tables
+            row = _TABLE_ROW.match(line)
+            if row:
+                table[int(row.group(1))] = row.group(2)
+                continue
+            table = None
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            table = tables[line] = {}
+            continue
+        found = _INSTRUCTION.match(line)
+        if not found:
+            opens = _COMPUTATION.match(line)
+            if opens:
+                skip = opens.group(1) in applied
+            continue
+        if skip:
+            continue
+        meta = _METADATA.search(line)
+        meta = meta.group(1) if meta else ""
+        scopes = _STAGE.findall(_field(meta, "op_name"))
+        out[found.group(1)] = {
+            "stage": scopes[-1] if scopes else UNSCOPED,
+            "source": _source(meta, tables), "op": found.group(2) + ")"}
+    return out
+
+
+def _field(meta: str, key: str) -> str:
+    found = re.search(r"(?<!\w)" + key + r'=(?:"([^"]*)"|(\d+))', meta)
+    return (found.group(1) or found.group(2) or "") if found else ""
+
+
+def _source(meta: str, tables: dict) -> str:
+    name, line = _field(meta, "source_file"), _field(meta, "source_line")
+    frame = _field(meta, "stack_frame_id")
+    if not name and frame:
+        try:
+            location = _field(tables["StackFrames"][int(frame)],
+                              "file_location_id")
+            where = tables["FileLocations"][int(location)]
+            name = tables["FileNames"][int(_field(where, "file_name_id"))]
+            name, line = name.strip('"'), _field(where, "line")
+        except (KeyError, ValueError):
+            return ""
+    if not name:
+        return ""
+    if name.startswith(_REPO):
+        name = name[len(_REPO):]
+    return f"{name}:{line}"
 
 
 def phase_ctx(timer):

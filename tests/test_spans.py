@@ -1,9 +1,12 @@
 """Host spans (telemetry/annotations.phase_span): the in-memory records,
 the spans and counters of one TPUDevice.predict_raw call on each of its
-three branches, and the compile listener's new counters."""
+three branches, and the compile listener's new counters; and the device
+half: the stage of every instruction of a scoring program
+(annotations.device_stages), made when asked and never by a call."""
 
 import collections
 import json
+import re
 import threading
 import time
 
@@ -30,6 +33,19 @@ def _rand_ensemble(seed, T=5, depth=3, F=6, bins=31):
         split_gain=np.zeros((T, N), np.float32),
         max_depth=depth, n_features=F, learning_rate=0.1, base_score=0.3,
         loss="logloss", n_classes=2, n_bins=bins)
+
+
+def _small_node_list(seed):
+    """3 trees of 3 leaves: n0 -> (L0, n1), n1 -> (L1, L2)."""
+    from ddt_tpu.models.tree import node_list_from_trees
+
+    rng = np.random.default_rng(seed)
+    trees = [([(int(rng.integers(6)), int(rng.integers(30)), 0.0, 0.0,
+                ~0, 1),
+               (int(rng.integers(6)), int(rng.integers(30)), 0.0, 0.0,
+                ~1, ~2)], [1.0, 2.0, 4.0]) for _ in range(3)]
+    return node_list_from_trees(trees, n_features=6, learning_rate=0.5,
+                                base_score=0.0, loss="logloss", n_bins=31)
 
 
 def _by_name(root):
@@ -234,17 +250,10 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
     carries the path form's plan (ops/predict_paths.SPAN_COUNTS), the
     root `node_list`, `path_mxu_tiles_per_tree` and what the kernel
     streams of its tables; a heap model's spans carry none of them."""
-    from ddt_tpu.models.tree import node_list_from_trees
     from ddt_tpu.ops import predict_paths
 
     rng = np.random.default_rng(77)
-    # 3 trees of 3 leaves: n0 -> (L0, n1), n1 -> (L1, L2)
-    trees = [([(int(rng.integers(6)), int(rng.integers(30)), 0.0, 0.0,
-                ~0, 1),
-               (int(rng.integers(6)), int(rng.integers(30)), 0.0, 0.0,
-                ~1, ~2)], [1.0, 2.0, 4.0]) for _ in range(3)]
-    ens = node_list_from_trees(trees, n_features=6, learning_rate=0.5,
-                               base_score=0.0, loss="logloss", n_bins=31)
+    ens = _small_node_list(78)
     be = get_backend(TrainConfig(backend="tpu", n_bins=31,
                                  predict_impl=impl))
     monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", 256)
@@ -368,6 +377,239 @@ def test_a_compiled_ensemble_skips_the_token_span():
     kids = _by_name(an.root_spans("predict")[-1])
     assert "ddt:predict:token" not in kids
     assert len(kids["ddt:predict:ensemble"]) == 1
+
+
+# ------------------------------------------------------------------ #
+# device stages
+# ------------------------------------------------------------------ #
+
+STAGES = {"predict:widen", "predict:tables", "predict:traverse",
+          "predict:traverse_paths", "predict:accumulate"}
+
+
+def _routed(ens, seed):
+    """`ens` with a NaN bin, learned directions and category features:
+    both routing tables."""
+    rng = np.random.default_rng(seed)
+    ens.default_left = rng.random(ens.feature.shape) < 0.5
+    ens.missing_bin = True
+    ens.cat_features = np.asarray([1, 4], np.int32)
+    return ens
+
+
+# model -> (builder, the program it scores by)
+STAGE_MODELS = {
+    "heap": (lambda: _rand_ensemble(seed=3100),
+             "jit_predict_raw_effective"),
+    "heap-7class": (lambda: _rand_ensemble(seed=3101, T=14),
+                    "jit_predict_raw_effective"),
+    "heap-routed": (lambda: _routed(_rand_ensemble(seed=3102), 3103),
+                    "jit_predict_raw_effective"),
+    "node-list": (lambda: _small_node_list(3104),
+                  "jit_predict_raw_effective_paths"),
+}
+STAGE_CASES = [(m, impl) for m in STAGE_MODELS
+               for impl in ("pallas", "onehot")]
+
+
+@pytest.mark.parametrize("model,impl", STAGE_CASES,
+                         ids=[f"{m}-{i}" for m, i in STAGE_CASES])
+def test_every_instruction_of_a_scoring_program_has_a_stage(
+        model, impl, monkeypatch):
+    """Interpreted Pallas and the jax.numpy forms, heap and node list,
+    with and without routing tables: what the program traced is under
+    one of the named stages; `unscoped` holds what no source line made
+    (parameters, constants, the compiler's copies and converts)."""
+    build, program = STAGE_MODELS[model]
+    ens = build()
+    if model == "heap-7class":
+        ens.loss, ens.n_classes = "softmax", 7
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31,
+                                 predict_impl=impl))
+    monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", 256)
+    Xb = np.random.default_rng(8).integers(0, 31, size=(600, 6),
+                                           dtype=np.uint8)
+    scores = be.predict_raw(ens, Xb)
+    np.testing.assert_allclose(scores, ens.predict_raw(Xb, binned=True),
+                               rtol=2e-4, atol=2e-5)
+
+    stages = an.device_stages()
+    held = stages[program]
+    assert len(held) > 10
+    seen = {e["stage"] for e in held.values()}
+    assert seen <= STAGES | {an.UNSCOPED}
+    traverse = "predict:traverse_paths" if (
+        model == "node-list" and impl == "pallas") else "predict:traverse"
+    assert {"predict:widen", traverse, "predict:accumulate"} <= seen
+    for name, e in held.items():
+        assert re.fullmatch(r"%[\w.-]+", name)
+        assert e["op"].endswith(")")
+        if e["stage"] == an.UNSCOPED:
+            assert e["source"] == "", (name, e)     # nobody's source line
+        elif e["source"]:
+            assert re.fullmatch(r"ddt_tpu/ops/predict\w*\.py:\d+",
+                                e["source"]), (name, e)
+    assert any(e["op"].startswith("parameter(") for e in held.values())
+    # the chunk loop's two small programs, each one stage as a whole
+    assert stages["jit_dynamic_slice"] == {an.WHOLE_PROGRAM: {
+        "stage": "predict:slice", "op": "program",
+        "source": stages["jit_reshape"][an.WHOLE_PROGRAM]["source"]}}
+    assert stages["jit_reshape"][an.WHOLE_PROGRAM]["stage"] \
+        == "predict:unflatten"
+    assert re.fullmatch(r"ddt_tpu/backends/tpu\.py:\d+",
+                        stages["jit_reshape"][an.WHOLE_PROGRAM]["source"])
+
+
+def test_the_stage_map_is_made_when_asked_and_once(monkeypatch):
+    """predict_raw registers how to read the program and lowers nothing;
+    device_stages() makes the map on its first call and keeps it; a new
+    model of the same program name drops the kept map."""
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31,
+                                 predict_impl="pallas"))
+    monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", 256)
+    program = "jit_predict_raw_effective"
+    Xb = np.random.default_rng(9).integers(0, 31, size=(600, 6),
+                                           dtype=np.uint8)
+    lowered = []
+
+    def counted(name, hlo=None, **kw):
+        if hlo is not None:
+            made = hlo
+
+            def hlo():
+                lowered.append(name)
+                return made()
+        return register(name, hlo, **kw)
+
+    register = an.stage_program
+    from ddt_tpu.backends import tpu as tpu_backend
+    monkeypatch.setattr(tpu_backend, "stage_program", counted)
+
+    tele_counters.install_jax_listener()
+    c0 = tele_counters.snapshot()
+    # depth 4: a scoring program no other test of this file compiles
+    be.predict_raw(_rand_ensemble(seed=3200, depth=4), Xb)
+    be.predict_raw(_rand_ensemble(seed=3200, depth=4), Xb)  # resident now
+    assert lowered == []
+    assert program not in an._stage_maps
+    assert callable(an._stage_programs[program])
+    compiled_by_the_calls = tele_counters.delta(c0)["jit_compiles"]
+
+    c1 = tele_counters.snapshot()
+    first = an.device_stages()
+    again = an.device_stages()
+    assert lowered == [program]
+    assert first[program] is again[program]
+    # the executable is the one the calls compiled: jit's own cache
+    assert compiled_by_the_calls >= 1
+    assert tele_counters.delta(c1)["jit_compiles"] == 0
+
+    # another model, same program name: the newest's map, made anew
+    be.predict_raw(_rand_ensemble(seed=3201, T=70), Xb)
+    assert program not in an._stage_maps
+    assert lowered == [program]
+    newest = an.device_stages()[program]
+    assert lowered == [program, program]
+    assert newest is not first[program]
+
+
+NAME_STACK_ENTRIES = {
+    "predict_raw_effective": dict(use_pallas=False),
+    "predict_raw_effective-pallas": dict(use_pallas=True),
+    "predict_raw": dict(use_pallas=False),
+    "predict_raw-pallas": dict(use_pallas=True),
+    "predict_raw_pallas": dict(),
+}
+
+
+@pytest.mark.parametrize("entry", sorted(NAME_STACK_ENTRIES))
+def test_the_name_stack_holds_ddt_predict_once(entry):
+    """One path of scopes from every entry point: `ddt:predict`, then the
+    stage; never `ddt:predict/ddt:predict`."""
+    from ddt_tpu.ops import predict as predict_ops
+    from ddt_tpu.ops import predict_pallas
+
+    ens = _rand_ensemble(seed=3300)
+    Xb = np.random.default_rng(10).integers(0, 31, size=(64, 6),
+                                            dtype=np.uint8)
+    kw = dict(NAME_STACK_ENTRIES[entry], max_depth=ens.max_depth,
+              learning_rate=0.1, base=0.3)
+    if entry.startswith("predict_raw_effective"):
+        ce = ens.compile(tree_chunk=64)
+        lowered = predict_ops.predict_raw_effective.lower(
+            *ce.arrays()[:4], Xb, **kw)
+    else:
+        fn = (predict_pallas.predict_raw_pallas
+              if entry == "predict_raw_pallas" else predict_ops.predict_raw)
+        lowered = fn.lower(ens.feature, ens.threshold_bin, ens.is_leaf,
+                           ens.leaf_value, Xb, **kw)
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    # whole paths only: a reduction's scalar computation keeps the tail
+    scoped = [n for n in names if n.startswith("jit(") and "ddt:" in n]
+    assert len(scoped) > 10
+    for n in scoped:
+        parts = n.split("/")
+        assert parts.count("ddt:predict") == 1, n
+        stages = [p for p in parts if p.startswith("ddt:predict:")]
+        assert parts.index("ddt:predict") < parts.index(stages[0]) \
+            if stages else True, n
+
+
+def test_stages_of_hlo_reads_both_ways_a_text_names_its_source():
+    """The tables at the head (`stack_frame_id`) and the inline form; the
+    innermost stage wins; a phase's root scope alone is no stage."""
+    text = """HloModule jit_f, is_scheduled=true
+
+FileNames
+1 "%(repo)sddt_tpu/ops/predict.py"
+2 "/elsewhere/site.py"
+
+FunctionNames
+1 "f"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=296 end_line=296 column=1 end_column=9}
+2 {file_name_id=2 function_name_id=1 line=7 end_line=8 column=1 end_column=9}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=1}
+
+%%fused_computation (p: u8[8,4]) -> s32[8,4] {
+  %%p = u8[8,4]{1,0:T(8,128)(4,1)} parameter(0)
+  ROOT %%convert.2 = s32[8,4]{1,0} convert(%%p), metadata={op_name="jit(f)/ddt:predict/ddt:predict:widen/convert_element_type" stack_frame_id=1}
+}
+
+ENTRY %%main (Xc.1: u8[8,4]) -> f32[8] {
+  %%Xc.1 = u8[8,4]{0,1} parameter(0), metadata={op_name="Xc"}
+  %%copy.5 = u8[8,4]{1,0:T(8,128)(4,1)} copy(%%Xc.1), metadata={op_name="Xc"}
+  %%pad_convert_fusion = s32[8,4]{1,0} fusion(%%copy.5), kind=kLoop, calls=%%fused_computation, metadata={op_name="jit(f)/ddt:predict/ddt:predict:widen/jit(_pad)/pad" stack_frame_id=1}, backend_config={"a":{"b":"metadata={op_name=\\"x\\"}"}}
+  %%copy-start = (f32[8]{0:S(1)}, f32[8]{0}, u32[]{:S(2)}) copy-start(%%pad_convert_fusion)
+  %%while.1 = (s32[], f32[8]{0}) while(%%copy-start), condition=%%c, body=%%b, metadata={op_name="jit(f)/ddt:predict/ddt:predict:traverse/while/body/ddt:predict:accumulate/add" source_file="/elsewhere/site.py" source_line=12}
+  %%rooted = f32[8]{0} add(%%while.1, %%while.1), metadata={op_name="jit(f)/ddt:predict/add" stack_frame_id=2}
+  ROOT %%fusion.1 = f32[8]{0:T(1024)} fusion(%%rooted), kind=kLoop, calls=%%x, metadata={op_name="jit(f)/ddt:predict/ddt:predict:accumulate/mul" stack_frame_id=9}
+}
+""" % {"repo": an._REPO}
+    assert an.stages_of_hlo(text) == {
+        "%p": {"stage": "unscoped", "source": "", "op": "parameter(0)"},
+        "%convert.2": {"stage": "predict:widen", "op": "convert(%p)",
+                       "source": "ddt_tpu/ops/predict.py:296"},
+        "%Xc.1": {"stage": "unscoped", "source": "", "op": "parameter(0)"},
+        "%copy.5": {"stage": "unscoped", "source": "",
+                    "op": "copy(%Xc.1)"},
+        "%pad_convert_fusion": {"stage": "predict:widen",
+                                "op": "fusion(%copy.5)",
+                                "source": "ddt_tpu/ops/predict.py:296"},
+        "%copy-start": {"stage": "unscoped", "source": "",
+                        "op": "copy-start(%pad_convert_fusion)"},
+        "%while.1": {"stage": "predict:accumulate",
+                     "op": "while(%copy-start)",
+                     "source": "/elsewhere/site.py:12"},
+        "%rooted": {"stage": "unscoped", "op": "add(%while.1, %while.1)",
+                    "source": "/elsewhere/site.py:7"},
+        "%fusion.1": {"stage": "predict:accumulate", "source": "",
+                      "op": "fusion(%rooted)"},
+    }
 
 
 # ------------------------------------------------------------------ #
