@@ -1543,6 +1543,10 @@ class TPUDevice(DeviceBackend):
     # row count (a 100M-row call is 50 dispatches; its peak memory is the
     # whole uploaded batch's, PERF.md section 4, not a chunk's).
     PREDICT_ROW_CHUNK = 2_000_000
+    # What a bin of a dispatched chunk is on the device: api.predict holds
+    # its callers to uint8, the batch goes up as it is, and the scoring
+    # programs are built (and their stages read) for chunks of it.
+    PREDICT_ROW_DTYPE = np.dtype(np.uint8)
     # Chunks a piece of the single-chip big-batch upload (_predict_raw):
     # the first piece's transfer is all of the upload a call exposes (46
     # ms of 156 MB at 2, 88 ms at 5, and it varies by as much less; 31 ms
@@ -1619,7 +1623,7 @@ class TPUDevice(DeviceBackend):
         if R <= chunk:
             counts["branch"] = "one"
             with phase_span("predict:upload", bytes=Xb.nbytes):
-                Xc = self._put_rows(Xb, extra_dims=1)  # uint8; ops widen it
+                Xc = self._put_rows(Xb, extra_dims=1)  # uint8, as the kernel takes it
             with phase_span("predict:dispatch", chunk=0):
                 out = fn(*ens_dev, Xc)
             with phase_span("predict:fetch", chunk=0) as sp:
@@ -1919,7 +1923,8 @@ class TPUDevice(DeviceBackend):
                     ce.n_classes_out, use_missing + use_cat):
                 plan = predict_pallas.table_plan(
                     ce.n_trees_padded, ce.max_depth, ens.n_features,
-                    ce.n_classes_out, None, use_missing + use_cat)
+                    ce.n_classes_out, None, use_missing + use_cat,
+                    self.PREDICT_ROW_DTYPE)
 
             static = dict(
                 max_depth=ce.max_depth, learning_rate=ce.learning_rate,
@@ -1996,7 +2001,7 @@ class TPUDevice(DeviceBackend):
             return
         avals = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ens_dev]
         avals.append(jax.ShapeDtypeStruct(
-            (self.PREDICT_ROW_CHUNK, n_features), jnp.uint8))
+            (self.PREDICT_ROW_CHUNK, n_features), self.PREDICT_ROW_DTYPE))
         stage_program(
             "jit_" + entry.__name__,
             lambda: fn0(*avals, entry=entry.lower).compile().as_text())

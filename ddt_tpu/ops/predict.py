@@ -291,9 +291,6 @@ def _predict_effective(
     the pushdown just moved out (models/tree.CompiledEnsemble computes it
     once per model on host; predict_raw still computes it in-trace)."""
     binned = bool(jnp.issubdtype(Xc.dtype, jnp.integer))
-    if binned:
-        with traced_scope("predict:widen"):
-            Xc = Xc.astype(jnp.int32)  # uint8 uploads are 4x cheaper; widen
     R, F = Xc.shape
     C = n_classes
     if R == 0:
@@ -311,6 +308,11 @@ def _predict_effective(
             missing_bin_value=missing_bin_value,
             eff_dl=eff_dl, eff_cat=eff_cat,
         )
+    if binned:
+        # The kernel above takes the uint8 chunk as it is; the one-hot
+        # form compares int32 bins.
+        with traced_scope("predict:widen"):
+            Xc = Xc.astype(jnp.int32)
     if row_chunk is None:
         # The binned comparison-matrix descent materialises
         # [Rc, chunk, Nint] bits; default to a smaller row chunk there to

@@ -7,16 +7,17 @@ total for the 10M x 1000 config against the v5e's ~820 GB/s, while the MXU
 part of the matmul is ~0.2 ms of the 1.13 s P1 phase. Same disease the
 histogram kernel had (ops/hist_pallas.py), same cure: build the per-tile
 working set IN VMEM and never let it touch HBM. The only HBM traffic is the
-binned input itself (R x F int32) plus the tiny tree tables and the [R, C]
-scores — the comparison matrix, feature one-hots, and descent state live and
-die inside one row tile's VMEM residency.
+binned input itself (R x F, at the width it comes in: uint8 from the
+backend) plus the tiny tree tables and the [C, R] scores — the comparison
+matrix, feature one-hots, and descent state live and die inside one row
+tile's VMEM residency.
 
 Layout strategy. The grid is (row tiles, table blocks): one step scores one
 tile of TILE_R rows against one BLOCK of G tree groups. Trees are taken in
 GROUPS of TREE_GROUP = 128, one vreg's lanes and one MXU weight tile,
 whatever tree_chunk the compiled ensemble was laid out with. The tables
 STREAM from HBM a block at a time (Mosaic double-buffers the windows, so the
-next block's DMA runs under this block's matmuls); the [TILE_R, C] output
+next block's DMA runs under this block's matmuls); the [C, TILE_R] output
 block and the row tile stay resident over the block axis, the output written
 by the first block and added to by the others. G is not a knob
 (`table_plan`): the most groups whose double-buffered windows fit
@@ -31,9 +32,11 @@ block's groups share their class dot (Class scatter, below). The padded
 tree count is padded again, inside the jitted program, to a multiple of
 G x U with trees that score 0, as the lanes past U do:
 
-    X     [TILE_R, F]        int32 bins. In-VMEM the matmul's left operand,
-                             bf16 [TILE_R, K]: the row tile as it is
-                             (P = 1, K = F), or two copies of it side by
+    X     [TILE_R, F]        uint8 or int32 bins, as the caller holds
+                             them (HBM interface, below), widened to int32
+                             once a grid step. In-VMEM the matmul's left
+                             operand, bf16 [TILE_R, K]: the row tile as it
+                             is (P = 1, K = F), or two copies of it side by
                              side, the second times 256 (P = 2; below).
     feat  [nb, G*Nint, 128]  ONE PLANE PER ROW: row g*Nint + n of block b
     thr/dl/cat               holds node n of the 128 trees of the block's
@@ -47,10 +50,29 @@ G x U with trees that score 0, as the lanes past U do:
                              in the folded routed form the three planes
                              are h, delta and c, all f32 (below).
     val   [nb, G*W, 128]     bottom-level pushed-down leaf values, same.
-    coh   [128, C]           class one-hot of a group's lanes, lane l class
-                             l % C, ONE for the whole ensemble, fetched
-                             once; [nb, G*128, C], a window a group, where
-                             each group keeps its own class dot.
+    coh   [8k, 128]          class one-hot of a group's lanes, TURNED
+                             OVER: class c on sublane c (C padded to whole
+                             sublanes), lane l hot where l % C == c. ONE
+                             for the whole ensemble, fetched once;
+                             [nb, 8k, G*128], 128 lanes a group, where each
+                             group keeps its own class dot.
+    out   [C, TILE_R]        the block's share of the scores, CLASS-MAJOR:
+                             the tile's rows on the lanes.
+
+The HBM interface (PR 36): the two arrays of the ROWS cross between XLA and
+the kernel at the data's own width. HBM pads an array's last dimension to
+128 lanes, so an `s32[R, F]` copy of the chunk is 512 B a row whatever F
+(1.02 GB for 2M rows where the uint8 chunk is 56 MB at F = 28), and an
+`f32[R, 1]` result the same again to carry 4 B a row: the XLA fusions that
+wrote the one and read the other were 100 and 68 ms of a 100M-row call
+(PERF.md section 6, PR 35). So the row block is the caller's uint8 (or
+int32; any other integer is cast to int32 in XLA first) and the int32 copy
+exists a tile at a time, in VMEM; the grid is cdiv(R, TILE_R) over the
+UNPADDED rows, the last block ragged (rows are independent: what the block
+holds past row R decides nothing that is written, and Pallas drops what
+the out block holds there); and the result is `f32[C, R]`, rows on the
+lanes, 8 MB a 2M-row chunk at C = 1, which the epilogue scales in one pass
+and, at C > 1, hands on as `[R, C]` in the layout it already has.
 
 P nodes share one MXU weight tile (`nodes_per_tile`: from F and whether the
 ensemble carries a routing table, nothing else). A node's matmul contracts
@@ -154,13 +176,28 @@ tile (depth-first, at the parent of its nodes):
         where the path has depth nodes: there is no node index k, no k == i
         and no leaf select. Depth-first keeps depth + 1 value planes live,
         and at P = 2 one packed plane a level.
-    Class scatter: acc = vsum @ class-one-hot (f32, HIGHEST), ONE dot a
-        grid step, vsum the lane-by-lane f32 sum of the block's G root
-        value planes (one plane live across the groups, 32 vreg adds a
-        group). HIGHEST makes the product six bfloat16 passes of a whole
-        MXU weight tile each (C = 1 pads to 128 columns) and a split of
-        the plane into three bfloat16 parts on the VPU: a dot a group was
-        384 of the 2,432 MXU cycles of a depth-6 group. What lets the
+    Class scatter: acc[c, row] = sum over lanes of coh[c, lane] *
+        vsum[row, lane] (f32, HIGHEST; both operands contract their
+        LANES, so the classes land on the sublanes and the rows on the
+        lanes: the out block, no transpose of it), ONE dot a grid step,
+        vsum the lane-by-lane f32 sum of the block's G root value planes
+        (one plane live across the groups, 32 vreg adds a group). HIGHEST
+        makes the product six bfloat16 passes and a split of the plane
+        into three bfloat16 parts on the VPU: a dot a group was 384 of
+        the 2,432 MXU cycles of a depth-6 group. Turned over, the value
+        plane is the dot's weight operand (two tiles of it a pass) and the
+        one-hot's 8 sublanes the streamed one. What that costs depends on
+        the step around it (ms a 2M-row chunk through `api.predict` on
+        the v5e, the parent's [TILE_R, 128] @ [128, C] dot / this one /
+        the parent's dot followed by an XLU transpose of its result;
+        PERF.md section 6, PR 36): the routed one-group step 25.346 /
+        24.939 / 25.637, 1000 trees x depth 6 in a block of 8 groups
+        90.548 / 91.006 / 90.798, Covertype's streamed 7-class step
+        1,228.4 / 1,236.4 / 1,228.7. This form is the one within 1% of
+        the parent everywhere, and ONE form ships; why it loses 0.4-0.6%
+        to the transpose where two nodes share a tile and a block holds
+        7-8 groups, and wins 0.7-8% everywhere else tried (one group a
+        step; one node a tile), is open. What lets the
         lanes be added first: tree t's class is t % C, so lane l is class
         l % C in EVERY group where a group holds whole rounds,
         U = C (128 // C) trees (128 wherever C divides 128, always at
@@ -345,7 +382,12 @@ def _vmem_bytes(groups: int, max_depth: int, n_features: int,
     table windows (feat i32, thr f32, dl and cat i32 where present, bottom
     values, class one-hot: one plane a row), the row tile's windows and
     the working set, which the integer routing of both tables makes grow
-    by the node (`_ROW_NODE_BYTES_BOTH`; not the folded form). The class
+    by the node (`_ROW_NODE_BYTES_BOTH`; not the folded form). The rows'
+    two windows and the class one-hot's are charged as the 32-bit
+    [TILE_R, F], [TILE_R, C] and [G*128, C] they were before PR 36, an
+    upper bound of the uint8 tile, the [C, TILE_R] block and the
+    [8k, G*128] one-hot: G decides every cell's program, and no cell's G
+    may move for bytes nobody was short of. The class
     window is charged a group whether or not the block shares one class
     dot and one [128, C] window (`table_plan`): dropping the term would
     let Covertype's model hold 11 groups where it holds 9, which the
@@ -380,6 +422,8 @@ class TablePlan(typing.NamedTuple):
     routes_in_tile: int    # ... of them, routed inside the MXU weight tile
     trees_per_group: int   # U: lanes of a group that hold trees (whole rounds)
     class_dots_per_step: int   # 1: the block's groups share one class dot
+    row_operand_bytes: int     # a bin of the row block as HBM holds it: 1 or 4
+    scores_class_major: int    # 1: the result leaves as [C, rows], lane-dense
 
     @property
     def tree_group(self) -> int:
@@ -405,10 +449,19 @@ class TablePlan(typing.NamedTuple):
 SPAN_COUNTS = ("tree_group", "table_groups", "groups_per_step",
                "table_bytes", "nodes_per_tile", "mxu_tiles_per_group",
                "routing_tables", "routes_in_tile", "trees_per_group",
-               "class_dots_per_step")
+               "class_dots_per_step", "row_operand_bytes",
+               "scores_class_major")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 # This kernel does not serve the model (the one-hot path, the LUT tiers).
 NO_PLAN = TablePlan(*(0,) * len(TablePlan._fields))
+
+
+def row_operand_dtype(dtype) -> jnp.dtype:
+    """What the kernel's row block is in HBM for rows of `dtype`: uint8
+    and int32 bins go in as they come, the tile widened in VMEM; any
+    other integer is cast to int32 in XLA first."""
+    dtype = jnp.dtype(dtype)
+    return dtype if dtype in (jnp.uint8, jnp.int32) else jnp.dtype(jnp.int32)
 
 
 def table_plan(
@@ -418,6 +471,7 @@ def table_plan(
     n_classes: int,
     tile_r: int | None = None,
     optional_operands: int = 2,
+    row_dtype=jnp.int32,
 ) -> TablePlan:
     """The kernel's table blocks at this shape: G, the number of tree
     groups a grid step holds in VMEM, is the most whose double-buffered
@@ -441,7 +495,12 @@ def table_plan(
     wherever C divides 128 and costs nothing; Covertype's 3,520 padded
     trees are 28 groups of 126 as of 128, 4 blocks of 7; 10 classes at
     depth 8 would pay 12% more groups for 4% of dots and keep 128. Read
-    from C, the tree count and G; no knob."""
+    from C, the tree count and G; no knob.
+
+    And the two arrays that cross between XLA and the kernel, which no
+    term above depends on: `row_operand_bytes`, the width of a bin of the
+    row block in HBM for rows of `row_dtype` (`row_operand_dtype`), and
+    `scores_class_major`, 1: the result is [C, rows]."""
     if tile_r is None:
         tile_r = _DEFAULT_TILE_R
     rounds = TREE_GROUP // n_classes * n_classes      # 0: C > 128
@@ -463,8 +522,10 @@ def table_plan(
         return n_tg, -(-n_tg // blocks), blocks
 
     n_tg, g, blocks = blocks_of(TREE_GROUP)
+    interface = (row_operand_dtype(row_dtype).itemsize, 1)
     if most == 0:
-        return TablePlan(n_tg, 0, 0, 0, tile_r, *packing, TREE_GROUP, 0)
+        return TablePlan(n_tg, 0, 0, 0, tile_r, *packing, TREE_GROUP, 0,
+                         *interface)
     dot = _CLASS_DOT_TILES * -(-n_classes // TREE_GROUP)
     per_group, shared = TREE_GROUP, False
     if rounds:
@@ -480,7 +541,8 @@ def table_plan(
     # fetched once, or one a group.
     class_bytes = 4 * TREE_GROUP * n_classes * (1 if shared else blocks * g)
     return TablePlan(n_tg, g, blocks, blocks * g * nodes_bytes + class_bytes,
-                     tile_r, *packing, per_group, 1 if shared else g)
+                     tile_r, *packing, per_group, 1 if shared else g,
+                     *interface)
 
 
 def predict_pallas_fits(
@@ -510,10 +572,13 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
     `groups_per_dot`: G where the block's groups share one class dot
     (lane l holds class l % C in every group), 1 where each has its own.
 
-    x_ref [TILE_R, F] int32; feat/thr (+ optional dl, cat) [G*Nint, 128]
-    and val [G*W, 128], one plane a row; coh [128, C] a dot; out
-    [TILE_R, C] f32, resident over the block axis (grid axis 1): written
-    by the first block, added to by the others. Folded, the three planes
+    x_ref [TILE_R, F] uint8 or int32, as HBM holds the rows (in the last
+    tile, whatever lies past row R: each row decides its own column of
+    the out block, and those past R are not written back); feat/thr (+
+    optional dl, cat) [G*Nint, 128] and val [G*W, 128], one plane a row;
+    coh [8k, 128] a dot, class c on sublane c; out [C, TILE_R] f32,
+    class-major, resident over the block axis (grid axis 1): written by
+    the first block, added to by the others. Folded, the three planes
     are the prologue's h, delta and c (`_folded_routes`), all f32."""
     rest = list(rest)
     out_ref = rest.pop()
@@ -528,12 +593,14 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         return jnp.broadcast_to(ref[row:row + 1, :], (rows, tg))
 
     stride = _copy_stride(n_feat)
+    # The tile as HBM holds it, uint8 or int32, widened once a step: the
+    # int32 copy lives in VMEM alone.
+    x = x_ref[:].astype(jnp.int32)
     if folded:
         # [2x | m | ones]: the bins (doubled with the categorical table,
         # whose match is a test of |w| against 0), the NaN-bin indicator
         # with the missing table and a block of ones with the categorical
         # one, each from a multiple of 8 K rows like the copies below.
-        x = x_ref[:]
         xf = x.astype(jnp.float32)
         parts = [xf * 2.0 if use_cat else xf]
         if use_missing:
@@ -547,7 +614,7 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         f_iota = jax.lax.broadcasted_iota(jnp.int32, (stride, tg), 0)
         xb = jnp.concatenate(parts, axis=1).astype(jnp.bfloat16)  # [T, K]
     elif pack == 1:
-        xb = x_ref[:].astype(jnp.bfloat16)                # [T, F]
+        xb = x.astype(jnp.bfloat16)                       # [T, F]
         f_iota = jax.lax.broadcasted_iota(jnp.int32, (n_feat, tg), 0)
     else:
         # Two copies of the row tile side by side along K, each from a
@@ -555,7 +622,7 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         # 256, and where the weight tile has 8 rows to spare a block of
         # ones for the row that adds _MANTISSA inside the MXU.
         ones_in_tile = 2 * stride + 8 <= _MXU_ROWS
-        xf = x_ref[:].astype(jnp.float32)
+        xf = x.astype(jnp.float32)
         copies = [xf, xf * 256.0]
         if stride > n_feat:
             gap = jnp.zeros((tile_r, stride - n_feat), jnp.float32)
@@ -672,7 +739,7 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
                          leaf(g, 2 * n + 2, planes),
                          leaf(g, 2 * n + 1, planes))
 
-    acc = jnp.zeros((tile_r, out_ref.shape[1]), jnp.float32)
+    acc = None
     for d in range(n_groups // groups_per_dot):
         # The values the rows reach in the dot's groups, summed lane by
         # lane: one plane live across the groups, 32 vreg adds each.
@@ -680,13 +747,17 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, val_ref, coh_ref, *rest,
         vals = leaf(first, 0, {})
         for g in range(first + 1, first + groups_per_dot):
             vals = vals + leaf(g, 0, {})
-        # Class scatter — the one-hot path's dot and precision.
-        acc = acc + jax.lax.dot_general(
-            vals, coh_ref[d * tg:(d + 1) * tg, :],
-            (((1,), (0,)), ((), ())),
+        # Class scatter — the one-hot path's dot and precision, turned
+        # over: both contract their lanes (the trees), so the classes
+        # land on the sublanes and the tile's rows on the lanes.
+        part = jax.lax.dot_general(
+            coh_ref[:, d * tg:(d + 1) * tg], vals,
+            (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
-        )
+        )                                                 # [8k, T]
+        acc = part if acc is None else acc + part
+    acc = acc[:out_ref.shape[0]]
     if n_blocks == 1:
         out_ref[:] = acc
         return
@@ -795,7 +866,8 @@ def predict_effective_pallas(
             f"{use_missing + use_cat} optional operands) exceeds the "
             "Pallas VMEM budget; use the one-hot path")
     tg = TREE_GROUP
-    plan = table_plan(Tpad, max_depth, F, C, tile_r, use_missing + use_cat)
+    plan = table_plan(Tpad, max_depth, F, C, tile_r, use_missing + use_cat,
+                      Xc.dtype)
     # Interpreted past the budget: one block of every group.
     n_g = plan.groups_per_step or plan.table_groups
     n_blocks = plan.blocks or 1
@@ -855,6 +927,11 @@ def predict_effective_pallas(
         else:
             coh = jnp.pad(cls_oh.astype(jnp.float32),
                           ((0, t_fill), (0, 0))).reshape(n_blocks, n_g * tg, C)
+        # ... and goes in turned over: the classes on the sublanes, padded
+        # to whole ones, a group's trees on the lanes.
+        coh = jnp.swapaxes(coh, -1, -2)
+        coh = jax.lax.pad(coh, 0.0, [(0, 0, 0)] * (coh.ndim - 2)
+                          + [(0, -C % 8, 0), (0, 0, 0)])
         if use_missing and not folded:
             extras.append(by_plane(eff_dl[:, :n_int], jnp.int32))
         if use_cat and not folded:
@@ -865,11 +942,10 @@ def predict_effective_pallas(
             extras.append(by_plane(cat_eff, jnp.int32))
 
     n_tiles = -(-R // tile_r)
-    rpad = n_tiles * tile_r - R
-    with traced_scope("predict:widen"):
-        Xi = Xc.astype(jnp.int32)
-        if rpad:
-            Xi = jnp.pad(Xi, ((0, rpad), (0, 0)))
+    row_dtype = row_operand_dtype(Xc.dtype)
+    if Xc.dtype != row_dtype:
+        with traced_scope("predict:widen"):
+            Xc = Xc.astype(row_dtype)
 
     kernel = functools.partial(
         _traverse_kernel, n_groups=n_g, groups_per_dot=groups_per_dot,
@@ -879,11 +955,14 @@ def predict_effective_pallas(
         use_cat=use_cat,
     )
 
-    def rows_of_tile(cols):
-        """Resident over the block axis: fetched (written back) once a
-        tile."""
-        return pl.BlockSpec((tile_r, cols), lambda i, b: (i, 0),
-                            memory_space=pltpu.VMEM)
+    # The rows' two blocks, resident over the block axis: fetched (written
+    # back) once a tile. The grid walks the UNPADDED rows, so the last
+    # tile's blocks are ragged: rows are independent, and what the row
+    # block holds past row R decides nothing that is written.
+    rows_in = pl.BlockSpec((tile_r, F), lambda i, b: (i, 0),
+                           memory_space=pltpu.VMEM)
+    scores_out = pl.BlockSpec((C, tile_r), lambda i, b: (0, i),
+                              memory_space=pltpu.VMEM)
 
     def table_block(rows, cols):
         """Block b of a table. One block: the index never moves, and the
@@ -893,19 +972,19 @@ def predict_effective_pallas(
 
     nodes = table_block(n_g * n_int, tg)
     in_specs = [
-        rows_of_tile(F),
+        rows_in,
         nodes,                                            # feat
         nodes,                                            # thr
         table_block(n_g * n_leaves, tg),                  # val
         # coh: a group's, or the one every group of every block shares
         # (the index never moves: fetched once).
-        table_block(n_g * tg, C) if groups_per_dot == 1 else pl.BlockSpec(
-            (tg, C), lambda i, b: (0, 0), memory_space=pltpu.VMEM),
+        table_block(*coh.shape[1:]) if groups_per_dot == 1 else pl.BlockSpec(
+            coh.shape, lambda i, b: (0, 0), memory_space=pltpu.VMEM),
     ] + [nodes] * len(extras)
     cost = pl.CostEstimate(
         flops=2 * n_tiles * tile_r * n_blocks * tg * (
             n_g * F * n_int + n_g // groups_per_dot * C),
-        bytes_accessed=n_tiles * tile_r * (F + C) * 4
+        bytes_accessed=n_tiles * tile_r * (F * plan.row_operand_bytes + C * 4)
         + plan.table_bytes * (n_tiles if n_blocks > 1 else 1),
         transcendentals=0,
     )
@@ -914,15 +993,14 @@ def predict_effective_pallas(
             kernel,
             grid=(n_tiles, n_blocks),
             in_specs=in_specs,
-            out_specs=rows_of_tile(C),
-            out_shape=jax.ShapeDtypeStruct((n_tiles * tile_r, C),
-                                           jnp.float32),
+            out_specs=scores_out,
+            out_shape=jax.ShapeDtypeStruct((C, R), jnp.float32),
             cost_estimate=cost,
             interpret=interpret,
-        )(Xi, feat_pl, thr_pl, val_pl, coh, *extras)
+        )(Xc, feat_pl, thr_pl, val_pl, coh, *extras)
     with traced_scope("predict:accumulate"):
-        out = base + learning_rate * acc[:R]
-        return out[:, 0] if C == 1 else out
+        out = base + learning_rate * acc
+        return out[0] if C == 1 else out.T
 
 
 @costed("predict_pallas", phase="predict")

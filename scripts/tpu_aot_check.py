@@ -118,7 +118,7 @@ def _predict_case(rows, features, n_trees, depth, n_classes=1,
                 dl = opt.pop(0) if use_missing else None
                 cn = opt.pop(0) if use_cat else None
                 return predict_pallas.predict_effective_pallas(
-                    ef, et, bv, coh, Xc.astype(jnp.int32),
+                    ef, et, bv, coh, Xc,
                     max_depth=ce.max_depth, learning_rate=ce.learning_rate,
                     base=ce.base_score, n_classes=ce.n_classes_out,
                     tree_chunk=ce.tree_chunk,

@@ -14,6 +14,8 @@ F32_ACC_TOL. Runs through Pallas interpret mode on CPU (the kernel logic
 the chip compiles; tests/test_tpu_lowering.py lowers the compiled form).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -556,8 +558,9 @@ def test_one_group_keeps_the_parents_program():
     features, at 128 trees x 7 classes and at 60 features with both
     tables: the same text, PR 34): one class dot, on the group's own
     window of a class one-hot laid out by blocks, no add of value
-    planes. Eight groups (1000 trees) make one dot too, on a [128, 1]
-    one-hot that no block index reaches, and seven adds of [256, 128]
+    planes. Eight groups (1000 trees) make one dot too, on a [8, 128]
+    one-hot (since PR 36 turned over, the class on the first of 8
+    sublanes) that no block index reaches, and seven adds of [256, 128]
     planes before it."""
     plan = jpp.table_plan(128, 6, 39, 1, None, 2)
     assert (plan.groups_per_step, plan.blocks, plan.class_dots_per_step,
@@ -567,11 +570,96 @@ def test_one_group_keeps_the_parents_program():
     one = _kernel_jaxpr(39, True, (13, 20), T=100, depth=6)
     assert one.count("dot_general") == 63 + 1
     assert one.count(class_dot) == 1 and plane_add not in one
-    assert "f32[1,128,1]" in one
+    assert "f32[1,8,128]" in one
     eight = _kernel_jaxpr(28, T=1000, depth=6)
     assert eight.count("dot_general") == 8 * 32 + 1
     assert eight.count(class_dot) == 1 and eight.count(plane_add) == 7
-    assert "f32[128,1]" in eight and "f32[1,1024,1]" not in eight
+    assert "f32[8,128]" in eight and "f32[1,8,1024]" not in eight
+
+
+# form -> (classes, trees, depth, features, missing, cat, G the budget
+# admits or None for the real budget, the plan that follows: groups, G,
+# blocks, trees a group, class dots a grid step)
+_INTERFACE_FORMS = {
+    "packed": (1, 130, 3, 6, False, (), None, (2, 2, 1, 128, 1)),
+    "one-node-F80": (1, 9, 3, 80, False, (), None, (1, 1, 1, 128, 1)),
+    "folded-routed": (1, 130, 3, 6, True, (1, 4), None, (2, 2, 1, 128, 1)),
+    "integer-routed-F60": (1, 9, 3, 60, True, (1, 4), None,
+                           (1, 1, 1, 128, 1)),
+    "one-table": (1, 9, 3, 6, True, (), None, (1, 1, 1, 128, 1)),
+    "7class-blocks": (7, 300, 3, 6, False, (), 2, (3, 2, 2, 126, 1)),
+    "7class-dot-a-group": (7, 253, 4, 6, False, (), None,
+                           (2, 2, 1, 128, 2)),
+}
+
+
+@pytest.mark.parametrize("rows", [255, 256, 257, 1000])
+@pytest.mark.parametrize("dtype", [np.uint8, np.int32])
+@pytest.mark.parametrize("form", list(_INTERFACE_FORMS))
+def test_kernel_takes_the_rows_as_they_come(budget, form, dtype, rows):
+    """The kernel's HBM interface: uint8 or int32 rows go in as they are
+    (the tile widened in VMEM), over a grid of the UNPADDED rows (one
+    short of a tile, a tile, a tile and one, 3 tiles + 232: the last
+    block ragged), and the scores come out class-major. Every form of
+    the kernel against the jax.numpy form to F32_ACC_TOL, bins up to 254
+    (a uint8 read as signed would miss), and the uint8 call bit-equal to
+    the int32 call on the same bins: the row operand's width decides
+    nothing."""
+    C, T, depth, F, missing, cat, fit, plan = _INTERFACE_FORMS[form]
+    optional = int(missing) + int(bool(cat))
+    if fit is not None:
+        budget(fit, depth, F, C, optional)
+    got_plan = jpp.table_plan(-(-T // 64) * 64, depth, F, C, None, optional,
+                              dtype)
+    assert (*got_plan[:3], got_plan.trees_per_group,
+            got_plan.class_dots_per_step) == plan
+    assert (got_plan.row_operand_bytes, got_plan.scores_class_major) \
+        == (np.dtype(dtype).itemsize, 1)
+    assert got_plan.routes_in_tile == (optional if F <= 56 else 0)
+    ens = _rand_ensemble(T=T, depth=depth, F=F, n_classes=C, bins=255,
+                         missing=missing, cat=cat, seed=len(form) + T)
+    ens.leaf_value *= np.float32(min(1.0, 11 * C / T))
+    args, kw, opt = _dev_args(ens)
+    Xb = np.random.default_rng(rows).integers(
+        0, 255, size=(rows, F), dtype=np.uint8)
+    Xb[::7, 0] = 254                                  # the NaN bin, if any
+
+    def kernel(X):
+        return np.asarray(jpp.predict_raw_pallas(
+            *args, jnp.asarray(X), tree_chunk=64, **kw, **opt))
+
+    got = kernel(Xb.astype(dtype))
+    assert got.shape == ((rows,) if C == 1 else (rows, C))
+    want = np.asarray(jpred.predict_raw(
+        *args, jnp.asarray(Xb.astype(np.int32)), tree_chunk=64,
+        use_pallas=False, **kw, **opt))
+    np.testing.assert_allclose(got, want, **F32_ACC_TOL)
+    if dtype == np.uint8:
+        np.testing.assert_array_equal(got, kernel(Xb.astype(np.int32)))
+
+
+@pytest.mark.parametrize("dtype,operand", [
+    (np.uint8, "u8"), (np.int32, "i32"), (np.int8, "i32"),
+    (np.int16, "i32"), (np.uint16, "i32")])
+def test_row_operand_is_the_datas_own_dtype(dtype, operand):
+    """What crosses into the kernel: uint8 and int32 rows as they come,
+    300 of them (no pad to 512), any other integer cast to int32 in XLA
+    (the `predict:widen` stage, empty otherwise); the result [C, 300]."""
+    ens = _rand_ensemble(T=9, depth=3, F=6, bins=31, seed=61)
+    args, kw, opt = _dev_args(ens)
+    Xb = np.random.default_rng(6).integers(0, 31, size=(300, 6))
+    fn = lambda X: jpp.predict_raw_pallas(              # noqa: E731
+        *args, X, tree_chunk=64, **kw, **opt)
+    text = str(jax.make_jaxpr(fn)(jnp.asarray(Xb.astype(dtype))))
+    assert re.search(r"f32\[1,300\] = pallas_call\[", text)
+    assert "[512," not in text and ",512]" not in text
+    assert "f32[300,1]" not in text
+    cast = "i32[300,6] = convert_element_type" in text
+    assert cast == (dtype not in (np.uint8, np.int32))
+    assert ("i32[300,6]" in text) == (operand == "i32")
+    np.testing.assert_array_equal(
+        np.asarray(fn(jnp.asarray(Xb.astype(dtype)))),
+        np.asarray(fn(jnp.asarray(Xb.astype(np.int32)))))
 
 
 @pytest.mark.parametrize("F,depth,nodes,tiles", [
@@ -777,7 +865,8 @@ def test_ensemble_span_says_which_form_served(impl, T, want, routed, F):
     tile (all of them at F <= 56), not by integers on the VPU;
     `trees_per_group` and `class_dots_per_step`: the lanes of a group
     that hold trees and the class dots a grid step makes, 1 where the
-    block's groups share it."""
+    block's groups share it; `row_operand_bytes` and
+    `scores_class_major`: the kernel's HBM interface (PR 36)."""
     from ddt_tpu.telemetry import annotations as an
 
     ens = _rand_ensemble(T=T, depth=3, F=F, bins=31, seed=40 + T,
@@ -810,6 +899,10 @@ def test_ensemble_span_says_which_form_served(impl, T, want, routed, F):
         groups * ((2 + routed) * 7 + 8) + bool(groups))
     assert (counts["trees_per_group"], counts["class_dots_per_step"]) \
         == ((128, 1) if want else (0, 0))
+    # ... and what crosses between XLA and the kernel: the backend's
+    # uint8 chunk as it is, the scores class-major.
+    assert (counts["row_operand_bytes"], counts["scores_class_major"]) \
+        == ((1, 1) if want else (0, 0))
     # ... and how the kernel uses the MXU: 5 features, so two nodes a
     # weight tile, the root's and one a pair of siblings (7 nodes: 4);
     # with a routing table one node a tile.
@@ -869,7 +962,7 @@ def test_backend_compiled_ensemble_cache_hits_and_invalidation():
 
 def test_backend_predict_impl_pallas_matches_onehot():
     """cfg.predict_impl='pallas' forces the kernel through the whole
-    backend path (compiled cache + chunking) — same scores, bit-exact."""
+    backend path (compiled cache + chunking) — same leaves, same scores."""
     Xb = np.random.default_rng(2).integers(
         0, 31, size=(300, 5), dtype=np.uint8)
     ens = _rand_ensemble(T=7, depth=3, F=5, bins=31, seed=2)
@@ -877,6 +970,11 @@ def test_backend_predict_impl_pallas_matches_onehot():
                                     predict_impl="onehot"))
     be_pl = get_backend(TrainConfig(backend="tpu", n_bins=31,
                                     predict_impl="pallas"))
+    # The class dot's summation order is the compiler's: random leaf
+    # values to F32_ACC_TOL, dyadic ones (every sum exact) bit for bit.
+    np.testing.assert_allclose(be_1h.predict_raw(ens, Xb),
+                               be_pl.predict_raw(ens, Xb), **F32_ACC_TOL)
+    ens.leaf_value = np.round(ens.leaf_value * 64) / 64
     np.testing.assert_array_equal(be_1h.predict_raw(ens, Xb),
                                   be_pl.predict_raw(ens, Xb))
 

@@ -420,6 +420,12 @@ def test_every_instruction_of_a_scoring_program_has_a_stage(
     with and without routing tables: what the program traced is under
     one of the named stages; `unscoped` holds what no source line made
     (parameters, constants, the compiler's copies and converts)."""
+    # `source` is the line that FIRST traced an instruction in this
+    # process: jnp's own jitted helpers (`%`, `jnp.pad`) keep the jaxpr,
+    # source lines and all, of whichever module called them first with the
+    # same shapes, so a trainer's test run earlier in this worker would
+    # lend `ops/split.py` to a constant of `predict:tables`.
+    jax.clear_caches()
     build, program = STAGE_MODELS[model]
     ens = build()
     if model == "heap-7class":
@@ -440,7 +446,12 @@ def test_every_instruction_of_a_scoring_program_has_a_stage(
     assert seen <= STAGES | {an.UNSCOPED}
     traverse = "predict:traverse_paths" if (
         model == "node-list" and impl == "pallas") else "predict:traverse"
-    assert {"predict:widen", traverse, "predict:accumulate"} <= seen
+    assert {traverse, "predict:accumulate"} <= seen
+    # The heap kernel takes the uint8 chunk as it is and widens a tile in
+    # VMEM: no instruction of its program is the widening. The node list
+    # and the jax.numpy forms still widen in XLA.
+    heap_kernel = model != "node-list" and impl == "pallas"
+    assert ("predict:widen" in seen) != heap_kernel
     for name, e in held.items():
         assert re.fullmatch(r"%[\w.-]+", name)
         assert e["op"].endswith(")")

@@ -13,10 +13,13 @@ compiles the same cases (its `kernel_cases()` table is the one this file
 reads) against a described v5e, and chip_smoke.py runs them.
 """
 
+import functools
 import importlib.util
 import os
+import re
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from ddt_tpu.utils import device
@@ -31,19 +34,52 @@ _spec.loader.exec_module(aot)
 DEFAULT_CASES = [c for c in aot.kernel_cases() if c.default]
 
 
+HEAP_CASES = [c for c in DEFAULT_CASES if c.name.startswith("predict/")]
+
+
+@functools.lru_cache(maxsize=None)
 def _export_for_tpu(case):
+    """(exported program, [(shape, dtype)] of its arguments)."""
     fn, shapes = case.build()
     args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
     with device.assume_platform("tpu"):
-        return jax.export.export(jax.jit(fn), platforms=("tpu",))(*args)
+        return jax.export.export(jax.jit(fn), platforms=("tpu",))(
+            *args), shapes
 
 
 @pytest.mark.parametrize("case", DEFAULT_CASES, ids=lambda c: c.name)
 def test_kernel_lowers_for_tpu(case):
-    exported = _export_for_tpu(case)
+    exported, _ = _export_for_tpu(case)
     assert exported.platforms == ("tpu",)
     # The kernel is IN the program, compiled — not interpreted away.
     assert "tpu_custom_call" in exported.mlir_module()
+
+
+@pytest.mark.parametrize("case", HEAP_CASES, ids=lambda c: c.name)
+def test_heap_kernel_crosses_hbm_at_the_datas_width(case):
+    """The two arrays between XLA and the heap traversal kernel: the
+    uint8 chunk goes in as it comes, over a row count that is not whole
+    tiles (no pad), and the scores come out class-major, [C, R] with the
+    rows on the lanes. Nothing of the program holds the rows as int32
+    (HBM pads its lanes to 128: 1 GB a 2M-row chunk whatever F) or a
+    one-column f32 array of them (the same again)."""
+    exported, shapes = _export_for_tpu(case)
+    (rows, features), dtype = shapes[-1]
+    assert dtype == jnp.uint8 and rows % 256
+    text = exported.mlir_module()
+    call, = [ln for ln in text.splitlines()
+             if "@tpu_custom_call" in ln and "_traverse_kernel" in ln]
+    operands, result = re.search(
+        r"\}\s*:\s*\((.*)\)\s*->\s*(tensor<[^>]*>)", call).groups()
+    assert operands.startswith(f"tensor<{rows}x{features}xui8>,")
+    classes = int(re.fullmatch(rf"tensor<(\d+)x{rows}xf32>", result)[1])
+    assert classes == (7 if "7classes" in case.name
+                       else 3 if "3classes" in case.name else 1)
+    assert f"tensor<{rows}x{features}xi32>" not in text
+    assert f"tensor<{rows}x1xf32>" not in text
+    # No padded row count either: every array of the rows has R of them.
+    padded = -(-rows // 256) * 256
+    assert f"tensor<{padded}x" not in text and f"x{padded}x" not in text
 
 
 def test_case_table_covers_the_default_dispatch():
