@@ -16,7 +16,9 @@ with a NaN bin and learned directions, 26 categorical split one-vs-rest)
 scores 1,000,000 rows, served by the ROUTED form of the traversal kernel.
 And one of LightGBM's Higgs model's shape (500 leaf-wise trees of 255 leaves,
 28 features: a NODE LIST no heap holds) scores 200,000 rows, served by the
-PATH-MATRIX form of the traversal kernel.
+PATH-MATRIX form of the traversal kernel; and small node lists at 28, 129
+and 968 columns, with and without learned NaN directions, hold that kernel
+to its jax.numpy twin and to the node walk in every bit, at 1 to 4,999 rows.
 
 It asserts WHAT ran (the Pallas kernels, compiled: `tpu_custom_call` in both
 lowered programs; histogram resolved to `pallas`, sibling subtraction on; no
@@ -395,6 +397,52 @@ def score_node_list(overrides: dict, rows: int) -> None:
     assert gap <= 1e-5, gap
 
 
+def score_node_list_grid(overrides: dict) -> None:
+    """The path-matrix kernel, compiled, against its jax.numpy twin and the
+    plain node walk on the shapes' edges: 1, 255, 256, 257 and 4,999 rows
+    (one ragged row tile, and two), 28, 129 and 968 columns (1, 2 and 8
+    K-blocks of the select, the last of 1 and of 72 columns), with and
+    without learned NaN directions; 12 trees of 255 leaves, dyadic leaf
+    values, so the three agree in every bit."""
+    from ddt_tpu import api
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.models.tree import random_node_list
+    from ddt_tpu.reference import numpy_predict
+    from ddt_tpu.telemetry.annotations import root_spans
+
+    rng = np.random.default_rng(SEED)
+    kernel = TrainConfig(n_bins=BINS, backend="tpu", **overrides)
+    twin = TrainConfig(n_bins=BINS, backend="tpu", predict_impl="onehot")
+    t0 = time.perf_counter()
+    for F in (28, 129, 968):
+        for missing in (False, True):
+            ens = random_node_list(rng, 12, 255, F, BINS, dyadic=True,
+                                   missing=missing, learning_rate=0.5,
+                                   base_score=0.25, loss="logloss")
+            for rows in (1, 255, 256, 257, 4_999):
+                Xb = rng.integers(0, BINS - 1, size=(rows, F), dtype=np.uint8)
+                Xb[rng.random((rows, F)) < 0.6] = BINS - 1
+                want = numpy_predict.predict_raw_node_list(
+                    ens, Xb, np.float64).astype(np.float32)
+                # the twin first: the program whose stages are read after
+                # this phase is the last one built, the kernel's
+                assert np.array_equal(
+                    api.predict(ens, Xb, binned=True, raw=True, cfg=twin),
+                    want), (F, missing, rows, "jax.numpy form")
+                got = api.predict(ens, Xb, binned=True, raw=True, cfg=kernel)
+                built = root_spans("predict")[-1]["counts"]
+                assert built["node_list"] == 1 and built[
+                    "routing_tables"] == int(missing) and built[
+                        "select_k_blocks"] == -(-F // 128), built
+                assert np.array_equal(got, want), (
+                    F, missing, rows, float(np.abs(got - want).max()))
+            assert_compiled_kernel(kernel, ens, 4_999,
+                                   f"node-list F={F} missing={missing}")
+    timing("node-list grid: 5 row counts x 3 widths x with/without NaN "
+           "routes, kernel == jax.numpy form == node walk in every bit",
+           wall=time.perf_counter() - t0)
+
+
 def check_device_stages() -> None:
     """Every instruction the scoring programs traced from this package is
     under a named stage (telemetry/annotations.device_stages: the newest
@@ -603,6 +651,7 @@ def main(argv=None) -> int:
                  else ROUTED_ROWS)
     score_node_list(overrides, LEAFWISE_ROWS // 100 if args.rehearse
                     else LEAFWISE_ROWS)
+    score_node_list_grid(overrides)
     check_device_stages()
     parity_against_reference(overrides)
     barrier_experiment(be, Xb)

@@ -1543,6 +1543,14 @@ class TPUDevice(DeviceBackend):
     # row count (a 100M-row call is 50 dispatches; its peak memory is the
     # whole uploaded batch's, PERF.md section 4, not a chunk's).
     PREDICT_ROW_CHUNK = 2_000_000
+    # ... and the bytes of rows it takes at most, as HBM holds them (the
+    # last dimension in whole tiles of 128 lanes: `predict_chunk_rows`).
+    # Up to 128 columns 2M rows are 256 MB and a chunk is PREDICT_ROW_CHUNK
+    # rows, as it was; at 968 columns they would be 2.05 GB, with as much
+    # again for the slice's copy, and the first piece of the upload, all
+    # of it a call exposes, the whole of a 2.3 GB batch: there a chunk is
+    # 262,144 rows, and what goes up before the first dispatch 0.5 GB.
+    PREDICT_CHUNK_BYTES = 256 * 1024 * 1024
     # What a bin of a dispatched chunk is on the device: api.predict holds
     # its callers to uint8, the batch goes up as it is, and the scoring
     # programs are built (and their stages read) for chunks of it.
@@ -1569,6 +1577,14 @@ class TPUDevice(DeviceBackend):
         "jit_lower_seconds", "compile_cache_hits",
         "compiled_ensemble_cache_hits")
 
+    def predict_chunk_rows(self, n_features: int) -> int:
+        """Rows a scoring dispatch takes on each chip at this width:
+        PREDICT_ROW_CHUNK, or as many as PREDICT_CHUNK_BYTES hold where
+        that is fewer. One rule, read from the rows' width alone."""
+        lanes = -(-n_features // 128) * 128
+        return max(1, min(self.PREDICT_ROW_CHUNK, self.PREDICT_CHUNK_BYTES
+                          // (lanes * self.PREDICT_ROW_DTYPE.itemsize)))
+
     def predict_raw(self, ens: TreeEnsemble, Xb: np.ndarray,
                     compiled=None) -> np.ndarray:
         """Score binned rows. `compiled` (a models/tree.CompiledEnsemble
@@ -1594,7 +1610,8 @@ class TPUDevice(DeviceBackend):
         """predict_raw's body; `counts` is the root span's (branch and
         chunks are written as soon as they are known)."""
         R = Xb.shape[0]
-        chunk = self.PREDICT_ROW_CHUNK * max(1, self.row_shards)
+        chunk = self.predict_chunk_rows(Xb.shape[1]) * max(
+            1, self.row_shards)
         fn, ens_dev, classes, plan = self._predict_entry(ens, compiled)
         if isinstance(Xb, jax.Array) and (R <= chunk or self.distributed):
             # Device-resident input is only special-cased on the
@@ -1961,14 +1978,23 @@ class TPUDevice(DeviceBackend):
                 "predict_impl=%r: the quantized tiers have no node-list "
                 "form; the f32 path-matrix form serves",
                 self.cfg.predict_impl)
-        ens_dev = tuple(self._put(a, self._named(
-            self.layout.replicated())) for a in ce.arrays())
         use_pallas = self._use_pallas
+        missing_routes = ce.missing_bin_value >= 0
         plan = predict_paths.path_plan(
             ce.n_trees, ce.lanes, ens.n_features, ce.deepest_leaf,
             served=predict_ops.resolve_use_pallas(
                 use_pallas, True, 0, ens.n_features, 1,
-                path_lanes=ce.lanes))
+                path_lanes=ce.lanes),
+            missing_routes=missing_routes, row_dtype=self.PREDICT_ROW_DTYPE)
+        # The trees that fill the kernel's last block (no node, no leaf of
+        # any length: they add 0) are made here, once a model, and not by
+        # every chunk's program.
+        fill = ((0, max(0, plan.trees_per_step * plan.table_blocks
+                        - ce.n_trees)), (0, 0), (0, 0))
+        ens_dev = tuple(
+            self._put(np.pad(a, fill, constant_values=v) if fill[0][1]
+                      else a, self._named(self.layout.replicated()))
+            for a, v in zip(ce.arrays(), (0, -1.0, 0)))
 
         # Bound here: fn0 outlives this call in the stage registry, and
         # must not hold the host copy of the path tables (78 MB at 500
@@ -1978,7 +2004,8 @@ class TPUDevice(DeviceBackend):
         def fn0(sel, planes, paths, Xc,
                 entry=predict_ops.predict_raw_effective_paths):
             return entry(sel, planes, paths, Xc, learning_rate=learning_rate,
-                         base=base, use_pallas=use_pallas)
+                         base=base, use_pallas=use_pallas,
+                         missing_routes=missing_routes)
 
         self._stage_scoring_program(
             predict_ops.predict_raw_effective_paths, fn0, ens_dev,
@@ -1991,7 +2018,7 @@ class TPUDevice(DeviceBackend):
         stages of the programs the single-chip big-batch loop runs: the
         scoring program `entry` (a jitted function of ops/predict.py; fn0
         calls it with the model's static arguments) at the loop's own
-        shapes, the device tables' and a chunk of PREDICT_ROW_CHUNK uint8
+        shapes, the device tables' and a chunk of `predict_chunk_rows` uint8
         rows, and the loop's two small programs by what they are for.
         Two dict writes: the lowering happens when somebody asks. A call of
         another row count runs another executable of the same name, whose
@@ -2001,7 +2028,8 @@ class TPUDevice(DeviceBackend):
             return
         avals = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ens_dev]
         avals.append(jax.ShapeDtypeStruct(
-            (self.PREDICT_ROW_CHUNK, n_features), self.PREDICT_ROW_DTYPE))
+            (self.predict_chunk_rows(n_features), n_features),
+            self.PREDICT_ROW_DTYPE))
         stage_program(
             "jit_" + entry.__name__,
             lambda: fn0(*avals, entry=entry.lower).compile().as_text())

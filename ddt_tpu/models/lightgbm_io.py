@@ -16,12 +16,15 @@ tree) for deeper ones: the shape decides, there is no flag. LightGBM grows
 leaf-wise, so its published settings (num_leaves=255) give trees 13-20
 levels deep whose heap would be 2^21 slots for 509 entries; the node list
 holds them as they are and the path-matrix kernel (ops/predict_paths.py)
-scores them. The node list carries ordinal splits and one output column:
-a deep model with NaN default directions, category sets or several classes
+scores them. The node list carries ordinal splits, with or without NaN
+default directions (LightGBM's `use_missing`, its default: a model trained
+on data with a missing value in it), and one output column: a deep model
+with category sets or several classes
 still imports as a heap, as before, as far as a heap can hold it (depth 30,
 2^27 slots), and past that is refused with the mechanism named. An import
 carries RAW thresholds only; `threshold_bin_mapper` ranks them into bins
-and returns the BinMapper whose edges they are, after which the model
+and returns the BinMapper whose edges they are (NaN in the reserved top
+bin where the model has directions for it), after which the model
 scores binned rows on the device exactly as it scores raw ones on the host.
 
 Format notes (LightGBM's text serialization, stable since v2):
@@ -139,7 +142,9 @@ def _node_list_blocks(ens: NodeListEnsemble) -> list[str]:
             "split_feature": ens.feature[t, :n],
             "split_gain": ens.split_gain[t, :n],
             "threshold": ens.threshold_raw[t, :n],
-            "decision_type": [0] * n,
+            "decision_type": (
+                _MISSING_NAN + _DEFAULT_LEFT * ens.default_left[t, :n]
+                if ens.missing_routes else [0] * n),
             "left_child": ens.left_child[t, :n],
             "right_child": ens.right_child[t, :n],
             "leaf_value": lv,
@@ -258,11 +263,15 @@ def _parse_block(lines: list[str], i: int) -> tuple[dict, int]:
     return d, i
 
 
-def _node_list_of(trees: list, **meta) -> NodeListEnsemble:
+def _node_list_of(trees: list, nan_routes: bool, **meta) -> NodeListEnsemble:
     """The parsed `Tree=` blocks as a node list: LightGBM's arrays as they
-    are. Raw thresholds only (`threshold_bin_mapper` ranks them)."""
+    are. Raw thresholds only (`threshold_bin_mapper` ranks them).
+    `nan_routes`: some node's missing type is NaN; every node then carries
+    a default direction, its default-left bit where its missing type is
+    NaN and RIGHT elsewhere (where NaN > threshold would send it: the heap
+    import's rule)."""
     def ints(blk, k):
-        return [int(v) for v in blk[k].split()]
+        return [int(float(v)) for v in blk[k].split()]
 
     def floats(blk, k):
         return [float(v) for v in blk[k].split()]
@@ -272,14 +281,18 @@ def _node_list_of(trees: list, **meta) -> NodeListEnsemble:
         nodes = []
         if int(blk["num_leaves"]) > 1:
             sf, th = ints(blk, "split_feature"), floats(blk, "threshold")
-            nodes = list(zip(sf, [0] * len(sf), th, floats(blk, "split_gain"),
-                             ints(blk, "left_child"),
-                             ints(blk, "right_child")))
+            cols = [sf, [0] * len(sf), th, floats(blk, "split_gain"),
+                    ints(blk, "left_child"), ints(blk, "right_child")]
+            if nan_routes:
+                cols.append([dt >> 2 == 2 and bool(dt & _DEFAULT_LEFT)
+                             for dt in ints(blk, "decision_type")])
+            nodes = list(zip(*cols))
         per_tree.append((nodes, floats(blk, "leaf_value")))
     return node_list_from_trees(
         per_tree, learning_rate=1.0,    # leaf values are final contributions
         base_score=0.0,                 # folded into tree 0's leaves
-        has_raw_thresholds=True, has_bin_thresholds=False, **meta)
+        has_raw_thresholds=True, has_bin_thresholds=False,
+        missing_bin=nan_routes, **meta)
 
 
 def threshold_bin_mapper(ens: "TreeEnsemble | NodeListEnsemble",
@@ -298,28 +311,35 @@ def threshold_bin_mapper(ens: "TreeEnsemble | NodeListEnsemble",
 
     A feature may carry at most `n_bins - 1` distinct thresholds (254 under
     LightGBM's max_bin=255); more is refused by name. Ordinal splits only.
-    NaN rows take bin 0 (the mapper's "zero" policy) where the raw walk
-    sends them right: bin them yourself if the data has any."""
+    A model with learned NaN directions (`missing_bin` and `default_left`)
+    gets the mapper of the "learn" policy: NaN takes the reserved top bin
+    `n_bins - 1` (254), values the bins below it, so a feature may carry
+    one threshold less (253). Without directions NaN rows take bin 0 (the
+    mapper's "zero" policy) where the raw walk sends them right: bin them
+    yourself if the data has any."""
     from ddt_tpu.data.quantizer import BinMapper
 
     if not ens.has_raw_thresholds:
         raise ValueError("threshold_bin_mapper needs raw thresholds")
-    if ens.missing_bin or ens.has_cat_splits:
+    if ens.has_cat_splits:
         raise ValueError(
             "threshold_bin_mapper covers ordinal splits: this model "
-            "carries NaN default directions or category nodes, whose bins "
-            "are not ranks of thresholds")
+            "carries category nodes, whose bins are not ranks of "
+            "thresholds")
+    missing = bool(ens.missing_bin) and ens.default_left is not None
     live = (ens.live_nodes if isinstance(ens, NodeListEnsemble)
             else ~ens.is_leaf & (ens.feature >= 0))
+    n_edges = n_bins - 1 - missing
     edges = np.full((ens.n_features, n_bins - 1), np.inf, np.float32)
     for f in range(ens.n_features):
         at = live & (ens.feature == f)
         distinct = np.unique(ens.threshold_raw[at])
-        if len(distinct) > n_bins - 1:
+        if len(distinct) > n_edges:
             raise ValueError(
                 f"feature {f} carries {len(distinct)} distinct thresholds, "
-                f"more than the {n_bins - 1} edges of {n_bins} bins "
-                "(a model trained with max_bin > 255?): it cannot be "
+                f"more than the {n_edges} edges of {n_bins} bins"
+                + (" of which the top one is NaN's" if missing else "")
+                + " (a model trained with max_bin > 255?): it cannot be "
                 "scored on binned uint8 rows")
         edges[f, :len(distinct)] = distinct
         ens.threshold_bin[at] = np.searchsorted(distinct,
@@ -327,20 +347,21 @@ def threshold_bin_mapper(ens: "TreeEnsemble | NodeListEnsemble",
     if isinstance(ens, NodeListEnsemble):
         ens.has_bin_thresholds = True
     ens.n_bins = n_bins
-    return BinMapper(edges=edges, n_bins=n_bins)
+    return BinMapper(edges=edges, n_bins=n_bins, missing_bin=missing)
 
 
 def from_lightgbm_text(text: str) -> "TreeEnsemble | NodeListEnsemble":
     """Parse a LightGBM model.txt back into an ensemble: a TreeEnsemble
     (heap) for trees of at most HEAP_MAX_DEPTH levels, a NodeListEnsemble
-    for deeper ordinal ones (module docstring, 'Which layout').
+    for deeper ordinal ones, NaN default directions with them (module
+    docstring, 'Which layout').
 
     Supports what to_lightgbm_text writes (numerical splits, single-bit
     categorical nodes, optional NaN-missing default directions) PLUS
     externally-trained models with multi-category bitsets, which expand
     into equivalent one-vs-rest chains (module docstring, 'Import
-    breadth'). Routed or multiclass trees deeper than 30 levels after
-    chain expansion overflow the heap and raise."""
+    breadth'). Trees with category nodes or several classes deeper than
+    30 levels after chain expansion overflow the heap and raise."""
     lines = text.splitlines()
     head, i = _parse_block(lines, 0)
     n_features = int(head["max_feature_idx"]) + 1
@@ -428,15 +449,13 @@ def from_lightgbm_text(text: str) -> "TreeEnsemble | NodeListEnsemble":
     nan_routes = any((int(float(v)) >> 2) == 2 for b in trees
                      for v in b.get("decision_type", "").split())
     categories = any(b is not None for bi in tree_bits for b in bi)
-    if max_depth > HEAP_MAX_DEPTH and not (nan_routes or categories
-                                           or C > 1):
-        return _node_list_of(trees, n_features=n_features, loss=loss,
-                             n_classes=max(C, 2))
+    if max_depth > HEAP_MAX_DEPTH and not (categories or C > 1):
+        return _node_list_of(trees, nan_routes, n_features=n_features,
+                             loss=loss, n_classes=max(C, 2))
     if max_depth > 30:
         _refuse_routes(f"from_lightgbm_text (tree depth {max_depth} after "
                        "multi-category chain expansion overflows the heap "
-                       "layout)", missing=nan_routes, categories=categories,
-                       classes=C > 1)
+                       "layout)", categories=categories, classes=C > 1)
     # The heap is DENSE and its depth is GLOBAL: one k-category set deep
     # in one tree adds k-1 levels to EVERY tree's 2^(D+1)-1 node arrays.
     # Real LightGBM categorical splits routinely carry dozens of
@@ -454,7 +473,7 @@ def from_lightgbm_text(text: str) -> "TreeEnsemble | NodeListEnsemble":
             f"(widest category set: {widest} bits). Models with large "
             "categorical sets are unrepresentable here (the node-list "
             "layout, which holds deep trees as they are, does not support "
-            "category sets, NaN default directions or several classes "
+            "category sets or several classes "
             "yet); score them with LightGBM itself, or retrain with "
             "cat_features one-vs-rest splits"
         )

@@ -601,14 +601,18 @@ class NodeListEnsemble:
     to `left_child[n]`, when `bin[feature[n]] <= threshold_bin[n]` (raw
     rows: `value <= threshold_raw[n]`), else RIGHT, to `right_child[n]`; a
     negative child c is leaf `~c` and the tree scores `leaf_value[~c]`. A
-    tree of one leaf has no node and scores `leaf_value[0]`.
+    tree of one leaf has no node and scores `leaf_value[0]`. With learned
+    directions for missing values (`missing_bin` and `default_left`, the
+    heap's own fields and rule: LightGBM's `use_missing`) a row whose bin
+    is the reserved NaN bin `n_bins - 1` (raw rows: NaN) goes LEFT where
+    `default_left[n]`, else RIGHT, whatever the threshold.
 
     This is the layout of a tree that is deep and sparse, as best-first
     (leaf-wise) growth makes them: 255 leaves 13-20 levels down are 509
     entries here and 2^21 heap slots in `TreeEnsemble`. The trainer writes
     heaps; a node list is an import (`models/lightgbm_io.py`), a conversion
-    (`from_heap`) or hand-built. Ordinal splits and one output column
-    only: learned directions for missing values, category sets and several
+    (`from_heap`) or hand-built. Ordinal splits (with or without the NaN
+    directions) and one output column only: category sets and several
     classes have no field here, and `from_lightgbm_text` / `from_heap` /
     the constructor refuse them by name (the heap layout serves them).
 
@@ -634,13 +638,17 @@ class NodeListEnsemble:
     # thresholds only, until `lightgbm_io.threshold_bin_mapper` ranks them).
     has_bin_thresholds: bool = True
     n_bins: int = 0
+    # Learned directions for missing values, as TreeEnsemble holds them:
+    # with both set, bin n_bins-1 is the NaN bin and a row in it follows
+    # default_left[n] (None: no such routing; thresholds then lie below
+    # n_bins-1, the value bins').
+    default_left: np.ndarray | None = None   # bool [T, N]
+    missing_bin: bool = False
 
     # What a heap ensemble answers for, so that scoring entry points ask
     # one question of either layout.
-    missing_bin = False
     has_cat_splits = False
     cat_features = None
-    default_left = None
 
     def __post_init__(self):
         if self.loss == "softmax":
@@ -656,10 +664,28 @@ class NodeListEnsemble:
             raise ValueError(
                 f"n_leaves must be [trees] in 1..{min(L, N + 1)} for node "
                 f"arrays [{T}, {N}] and leaf values [{T}, {L}]")
+        if self.missing_routes and (
+                self.default_left.shape != (T, N)
+                or (self.has_bin_thresholds and self.n_bins < 3)):
+            raise ValueError(
+                f"learned NaN directions need default_left [{T}, {N}] and, "
+                "with bin thresholds, n_bins (the NaN bin is n_bins - 1; "
+                f"got {self.n_bins})")
 
     @property
     def n_trees(self) -> int:
         return int(self.feature.shape[0])
+
+    @property
+    def missing_routes(self) -> bool:
+        """Whether NaN rows follow learned directions (the heap's rule:
+        the reserved bin AND the directions; else it is a bin like any)."""
+        return bool(self.missing_bin) and self.default_left is not None
+
+    @property
+    def missing_bin_value(self) -> int:
+        """The reserved NaN bin, -1 without learned directions."""
+        return self.n_bins - 1 if self.missing_routes else -1
 
     @property
     def live_nodes(self) -> np.ndarray:
@@ -744,6 +770,9 @@ class NodeListEnsemble:
             h.update(np.ascontiguousarray(a).tobytes())
         h.update(repr((self.learning_rate, self.base_score, self.loss,
                        self.n_features)).encode())
+        if self.missing_routes:
+            h.update(np.ascontiguousarray(self.default_left).tobytes())
+            h.update(repr(("missing_bin", self.n_bins)).encode())
         return h.hexdigest()
 
     def compile(self, tree_chunk: int = 64) -> "CompiledNodeList":
@@ -775,7 +804,13 @@ class NodeListEnsemble:
             feat = np.take_along_axis(self.feature, at, axis=1)
             fv = np.stack([Xc[rows, np.maximum(feat[t], 0)]
                            for t in range(T)])
-            nxt = np.where(fv > np.take_along_axis(thr, at, axis=1),
+            go_right = fv > np.take_along_axis(thr, at, axis=1)
+            if self.missing_routes:
+                miss = (fv == self.n_bins - 1) if binned else np.isnan(fv)
+                go_right = np.where(
+                    miss, ~np.take_along_axis(self.default_left, at, axis=1),
+                    go_right)
+            nxt = np.where(go_right,
                            np.take_along_axis(self.right_child, at, axis=1),
                            np.take_along_axis(self.left_child, at, axis=1))
             cur = np.where(cur >= 0, nxt, cur)
@@ -800,12 +835,11 @@ class NodeListEnsemble:
     @staticmethod
     def from_heap(ens: TreeEnsemble) -> "NodeListEnsemble":
         """The same trees as a node list, nodes and leaves numbered in
-        pre-order (root = node 0). A heap ensemble with learned NaN
-        directions, category nodes or several classes is refused."""
-        _refuse_routes(
-            "from_heap",
-            missing=ens.missing_bin and ens.default_left is not None,
-            categories=ens.has_cat_splits, classes=ens.loss == "softmax")
+        pre-order (root = node 0), learned NaN directions with them. A
+        heap ensemble with category nodes or several classes is refused."""
+        _refuse_routes("from_heap", categories=ens.has_cat_splits,
+                       classes=ens.loss == "softmax")
+        routed = ens.missing_bin and ens.default_left is not None
         trees = []
         for t in range(ens.n_trees):
             nodes, leaves = [], []
@@ -818,7 +852,9 @@ class NodeListEnsemble:
                 nodes.append([ens.feature[t, slot],
                               ens.threshold_bin[t, slot],
                               ens.threshold_raw[t, slot],
-                              ens.split_gain[t, slot], 0, 0])
+                              ens.split_gain[t, slot], 0, 0]
+                             + ([ens.default_left[t, slot]] if routed
+                                else []))
                 nodes[i][4] = walk(2 * slot + 1)
                 nodes[i][5] = walk(2 * slot + 2)
                 return i
@@ -829,7 +865,8 @@ class NodeListEnsemble:
             trees, n_features=ens.n_features,
             learning_rate=ens.learning_rate, base_score=ens.base_score,
             loss=ens.loss, n_classes=ens.n_classes,
-            has_raw_thresholds=ens.has_raw_thresholds, n_bins=ens.n_bins)
+            has_raw_thresholds=ens.has_raw_thresholds, n_bins=ens.n_bins,
+            missing_bin=bool(routed))
 
     def feature_importances(self, kind: str = "split") -> np.ndarray:
         """As `TreeEnsemble.feature_importances`, over the live nodes."""
@@ -846,8 +883,10 @@ class NodeListEnsemble:
                 return
             thr = (f" (<= {self.threshold_raw[t, ref]:.6g})"
                    if self.has_raw_thresholds else "")
+            nan = (f"  nan->{'L' if self.default_left[t, ref] else 'R'}"
+                   if self.missing_routes else "")
             lines.append(f"{pad}f{self.feature[t, ref]} <= bin "
-                         f"{self.threshold_bin[t, ref]}{thr}  "
+                         f"{self.threshold_bin[t, ref]}{thr}{nan}  "
                          f"gain={self.split_gain[t, ref]:.4g}")
             walk(int(self.left_child[t, ref]), depth + 1)
             walk(int(self.right_child[t, ref]), depth + 1)
@@ -878,13 +917,19 @@ class NodeListEnsemble:
             n_classes=np.int64(self.n_classes),
             has_raw_thresholds=np.bool_(self.has_raw_thresholds),
             has_bin_thresholds=np.bool_(self.has_bin_thresholds),
-            n_bins=np.int64(self.n_bins))
+            n_bins=np.int64(self.n_bins),
+            missing_bin=np.bool_(self.missing_bin))
+        if self.default_left is not None:
+            d["default_left"] = self.default_left
         return d
 
     @staticmethod
     def from_dict(d: dict) -> "NodeListEnsemble":
         return NodeListEnsemble(
             **{k: np.asarray(d[k], dt) for k, dt in NodeListEnsemble._ARRAYS},
+            default_left=(np.asarray(d["default_left"], bool)
+                          if "default_left" in d else None),
+            missing_bin=bool(d.get("missing_bin", False)),
             n_features=int(d["n_features"]),
             learning_rate=float(d["learning_rate"]),
             base_score=float(d["base_score"]),
@@ -921,12 +966,10 @@ def ensemble_from_dict(d: dict) -> "TreeEnsemble | NodeListEnsemble":
     return TreeEnsemble.from_dict(d)
 
 
-def _refuse_routes(where: str, *, missing: bool, categories: bool,
+def _refuse_routes(where: str, *, categories: bool,
                    classes: bool) -> None:
     """The one list of what a node list cannot carry, named."""
     for has, what in (
-            (missing, "learned default directions for missing values "
-                      "(NaN bin, default_left)"),
             (categories, "category-set (one-vs-rest / bitset) nodes"),
             (classes, "several classes (softmax, round-major trees)")):
         if has:
@@ -940,7 +983,8 @@ def _refuse_routes(where: str, *, missing: bool, categories: bool,
 def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
     """A NodeListEnsemble from per-tree lists: `trees[t] = (nodes, leaves)`,
     `nodes[n] = (feature, threshold_bin, threshold_raw, gain, left,
-    right)`, `leaves[l]` the leaf's value."""
+    right)`, `leaves[l]` the leaf's value. A seventh entry on the nodes
+    is `default_left` (every node has it, or none)."""
     T = len(trees)
     N = max(1, max(len(n) for n, _ in trees))
     L = max(len(lv) for _, lv in trees)
@@ -955,6 +999,9 @@ def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
         n_leaves=np.asarray([len(lv) for _, lv in trees], np.int32))
     keys = ("feature", "threshold_bin", "threshold_raw", "split_gain",
             "left_child", "right_child")
+    if any(len(n[0]) > len(keys) for n, _ in trees if n):
+        keys += ("default_left",)
+        out["default_left"] = np.zeros((T, N), bool)
     for t, (nodes, leaves) in enumerate(trees):
         if nodes:
             for k, col in zip(keys, zip(*nodes)):
@@ -965,20 +1012,23 @@ def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
 
 def random_node_list(rng, n_trees: int, n_leaves: int, n_features: int,
                      n_bins: int = 255, dyadic: bool = False,
-                     **meta) -> NodeListEnsemble:
+                     missing: bool = False, **meta) -> NodeListEnsemble:
     """A random leaf-wise ensemble for tests, chip_smoke.py and the compile
     check (no trainer grows one): a random leaf is split until `n_leaves`
     are there, so depths are uneven (about 20 levels at 255 leaves);
     features and threshold bins uniform; leaf values N(0, 1), or eighths in
-    -2..2 (`dyadic`: sums of them round nowhere)."""
+    -2..2 (`dyadic`: sums of them round nowhere). `missing`: bin n_bins-1
+    is the NaN bin, thresholds lie in the value bins below it and every
+    node's default direction is a fair coin."""
     trees = []
     for _ in range(n_trees):
         nodes, where = [], [None]        # leaf -> (parent node, child slot)
         for _ in range(n_leaves - 1):
             leaf, n = int(rng.integers(len(where))), len(nodes)
             nodes.append([int(rng.integers(n_features)),
-                          int(rng.integers(n_bins - 1)), 0.0, 0.0, ~leaf,
-                          ~len(where)])
+                          int(rng.integers(n_bins - 1 - missing)), 0.0, 0.0,
+                          ~leaf, ~len(where)]
+                         + ([bool(rng.integers(2))] if missing else []))
             if where[leaf] is not None:
                 nodes[where[leaf][0]][where[leaf][1]] = n
             where[leaf] = (n, 4)
@@ -986,7 +1036,7 @@ def random_node_list(rng, n_trees: int, n_leaves: int, n_features: int,
         trees.append((nodes, rng.integers(-16, 17, n_leaves) / 8.0 if dyadic
                       else rng.standard_normal(n_leaves)))
     return node_list_from_trees(trees, n_features=n_features, n_bins=n_bins,
-                                **meta)
+                                missing_bin=missing, **meta)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -999,7 +1049,14 @@ class CompiledNodeList:
         planes [T, 8, W]  f32    row 0 the nodes' threshold bins (unused
                                  lanes +BIG: never right), row 1 the
                                  leaves' path lengths (-1: no such leaf),
-                                 row 2 the leaf values
+                                 row 2 the leaf values, row 3 (read with
+                                 learned NaN directions alone,
+                                 `missing_bin_value` >= 0) the bin from
+                                 which a node stops answering right: the
+                                 NaN bin where it sends NaN left, +BIG
+                                 where right, so that a node's answer is
+                                 `thr < v < up` and the NaN bin, above
+                                 every threshold, needs no test of its own
         paths  [T, W, W]  bf16   P[n, l]: +1 leaf l in node n's right
                                  subtree, -1 in its left, 0 elsewhere
 
@@ -1016,6 +1073,7 @@ class CompiledNodeList:
     sel: np.ndarray
     planes: np.ndarray
     paths: np.ndarray
+    missing_bin_value: int = -1    # reserved NaN bin id, -1 = no routing
 
     n_classes_out = 1
 
@@ -1044,6 +1102,16 @@ class CompiledNodeList:
         planes[:, 1, :] = -1.0
         planes[:, 1, :L] = plen
         planes[:, 2, :L] = ens.leaf_value
+        nan_bin = ens.missing_bin_value
+        if nan_bin >= 0:
+            if (ens.threshold_bin[live] >= nan_bin).any():
+                raise ValueError(
+                    f"a node's threshold bin is the NaN bin {nan_bin} or "
+                    "above it: with learned NaN directions thresholds lie "
+                    "in the value bins")
+            planes[:, 3, :] = 2.0 ** 30
+            planes[:, 3, :N] = np.where(live & ens.default_left, nan_bin,
+                                        2.0 ** 30)
         paths = np.zeros((T, W, W), ml_dtypes.bfloat16)
         paths[:, :N, :L] = P
         return CompiledNodeList(
@@ -1051,7 +1119,7 @@ class CompiledNodeList:
             learning_rate=float(ens.learning_rate),
             base_score=float(ens.base_score), loss=ens.loss,
             n_trees=T, lanes=W, deepest_leaf=int(plen.max(initial=0)),
-            sel=sel, planes=planes, paths=paths)
+            sel=sel, planes=planes, paths=paths, missing_bin_value=nan_bin)
 
 
 def empty_ensemble(

@@ -43,6 +43,10 @@ GEMM strategy, OSDI 2020). Per tree, with W lanes of nodes and of leaves:
 
     v[r, n] = bin[r, feature[n]]            = X[rows, F] @ sel[F, W]
     s[r, n] = +1 if v[r, n] > thr[n] else -1
+              (with learned NaN directions: +1 if thr[n] < v[r, n] < up[n],
+              up[n] the NaN bin where node n sends NaN left, else +BIG: the
+              NaN bin lies above every threshold, so the plain compare is
+              the default-RIGHT route already)
     m[r, l] = sum_n s[r, n] P[n, l]         P = +1 / -1 / 0: leaf l in node
                                             n's right / left subtree / not
     score[r] += sum_l where(m[r, l] == len[l], leaf_value[l], 0)
@@ -510,13 +514,14 @@ def predict_raw(
 _PATHS_TREE_CHUNK, _PATHS_ROW_CHUNK = 8, 8_192
 
 
-def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base):
+def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
+                   missing_routes: bool = False):
     """The path-matrix form (module docstring) in plain jax.numpy: trees in
     chunks of _PATHS_TREE_CHUNK, rows in chunks of _PATHS_ROW_CHUNK, so the
     [trees, rows, W] intermediates stay bounded. The operands are
     widened to float32 (XLA's CPU backend has no bf16 x bf16 = f32 dot);
     every value is one bfloat16 holds, so a TPU's default one-pass matmul
-    of them is exact too."""
+    of them is exact too. `missing_routes`: planes' row 3 is read."""
     T, Fp, W = sel.shape
     R, F = Xc.shape
     tree_chunk = _PATHS_TREE_CHUNK
@@ -543,7 +548,10 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base):
             with traced_scope("predict:traverse"):
                 v = jnp.einsum("rf,tfn->trn", xrc, a,
                                preferred_element_type=jnp.float32)
-                s = jnp.where(v > pl_[:, None, 0, :], 1.0, -1.0)
+                right = v > pl_[:, None, 0, :]
+                if missing_routes:
+                    right &= v < pl_[:, None, 3, :]
+                s = jnp.where(right, 1.0, -1.0)
                 m = jnp.einsum("trn,tnl->trl", s, p,
                                preferred_element_type=jnp.float32)
             with traced_scope("predict:accumulate"):
@@ -565,22 +573,28 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base):
 @costed("predict", phase="predict")
 @functools.partial(
     jax.jit,
-    static_argnames=("learning_rate", "base", "use_pallas"),
+    static_argnames=("learning_rate", "base", "use_pallas",
+                     "missing_routes"),
 )
 @op_scope("predict")
 def predict_raw_effective_paths(
     sel: jax.Array,            # bf16 [T, Fp, W] feature one-hot of the nodes
-    planes: jax.Array,         # f32 [T, 8, W] rows: thr, path length, value
+    planes: jax.Array,         # f32 [T, 8, W] rows: thr, path length, value, up
     paths: jax.Array,          # bf16 [T, W, W] signed path matrix
     Xc: jax.Array,             # [R, F] integer bins
     learning_rate: float,
     base: float,
     use_pallas: bool | None = None,
+    missing_routes: bool = False,
 ) -> jax.Array:
     """Raw margins [R] of a node-list ensemble from its compiled tables
     (models/tree.CompiledNodeList): the path-matrix form, by the Pallas
     kernel (ops/predict_paths.py) where `resolve_use_pallas` says so and by
-    `_predict_paths` otherwise. Binned rows only."""
+    `_predict_paths` otherwise. Binned rows only, which the kernel takes at
+    the width they come in (uint8 from api.predict: nothing is widened in
+    XLA). `missing_routes`: the model carries learned NaN directions
+    (`CompiledNodeList.missing_bin_value` >= 0); without them the program
+    is the one-compare program."""
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
         raise ValueError("the path-matrix form scores binned (integer) rows")
     if Xc.shape[0] == 0:
@@ -589,12 +603,12 @@ def predict_raw_effective_paths(
                           path_lanes=sel.shape[2]):
         from ddt_tpu.ops import predict_paths
 
-        with traced_scope("predict:widen"):
-            Xi = Xc.astype(jnp.int32)
         return predict_paths.predict_paths_pallas(
-            sel, planes, paths, Xi, learning_rate=learning_rate, base=base)
+            sel, planes, paths, Xc, learning_rate=learning_rate, base=base,
+            missing_routes=missing_routes)
     return _predict_paths(sel, planes, paths, Xc,
-                          learning_rate=learning_rate, base=base)
+                          learning_rate=learning_rate, base=base,
+                          missing_routes=missing_routes)
 
 
 def predict_proba(raw: jax.Array, loss: str) -> jax.Array:

@@ -15,7 +15,33 @@ strategy, OSDI 2020, re-read for the MXU): per tree and tile of rows
 
 so its cost is by the tree's NODES and LEAVES (W lanes of each, a multiple
 of 128), not by its depth: `path_mxu_tiles_per_tree` MXU weight tiles a
-tree, W/128 for v and (W/128)^2 for m, 6 at 255 leaves.
+tree, ceil(F/128) x W/128 for v and (W/128)^2 for m: 6 at 255 leaves and
+28 columns, 20 at 968.
+
+The select is K-BLOCKED: v = sum_k x_k @ sel_k over ceil(F/128) blocks of
+128 columns (`select_k_blocks`: 1 at 28 columns, 8 at 968), each x_k cut
+from the row tile as HBM holds it and widened uint8 -> bf16 in VMEM, once a
+sub-tile, the last block's K padded to the tables' Fp (a multiple of 16).
+One non-zero a column of sel and bins below 256: every partial product is
+exact and so is their sum in any order.
+
+Learned NaN directions (`missing_routes`; models/tree.CompiledNodeList):
+the NaN bin is the top bin, above every threshold, so `v > thr` alone is
+the default-RIGHT route. A node that sends NaN LEFT stops answering right
+at the NaN bin: s = (thr < v < up) ? +1 : -1, `up` (planes' row 3, one more
+of the 8 rows the table already has) the NaN bin there and +BIG elsewhere.
+Two thresholds, not a mask and a test for the NaN bin: one compare and one
+AND a vreg of v more than the plain form, no new table. A model without
+directions traces the one-compare program.
+
+The HBM interface is the heap kernel's (ops/predict_pallas.py, PR 36): the
+rows go in as the caller holds them (uint8 from api.predict; no int32 copy
+of a chunk exists outside VMEM, where at 968 columns it would be 8 GB a
+2M-row chunk), over a grid of cdiv(R, tile) row tiles whose last block is
+ragged (rows are independent, and what a block holds past row R decides
+nothing that is written), and the scores come out `f32[1, R]`, the rows on
+the lanes: a sub-tile's [SUB_ROWS, W] accumulator is folded to 128 lanes,
+turned over on the XLU and summed down its sublanes.
 
 Layout strategy. The tables are 152 KB a tree at W = 256 (sel 16, planes
 8, P 128), 76 MB for 500 trees: they do not stay in VMEM, and streaming
@@ -29,8 +55,10 @@ the [TILE_ROWS, 1] output stays resident over the block axis, zeroed by
 the first block and added to by all. A row tile streams the tables once:
 489 times a 2M-row chunk at 4096 rows, 38 GB against a second of MXU
 time. G is not a knob (`path_plan`): the most trees whose windows fit the
-VMEM budget beside the row tile's (56 at 255 leaves: 500 trees in 9
-blocks), evened out over the blocks. The other order (table blocks
+VMEM budget beside the row tile's (64, the cap, at 255 leaves and 28
+columns; 11 at 968 columns, where a tree's tables are 639 KB), or the
+size down to half of that which leaves the last block the fewest filler
+trees (500 trees: 10 blocks of 50, and 50 of 10). The other order (table blocks
 outside, each fetched once a chunk) would revisit an output block across
 grid steps that do not follow one another, which Pallas does not keep.
 
@@ -53,7 +81,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ddt_tpu.ops.predict_pallas import _window_bytes
+from ddt_tpu.ops.predict_pallas import _window_bytes, row_operand_dtype
 from ddt_tpu.telemetry.annotations import traced_scope
 from ddt_tpu.utils import device
 
@@ -80,8 +108,16 @@ _VMEM_BUDGET_BYTES = _VMEM_LIMIT_BYTES - 4 * 1024 * 1024
 # Trees a block at most: the kernel's trace is that many trees long.
 _MAX_TREES_PER_STEP = 64
 # Bytes a sub-tile's row keeps beside the windows: v, s, m, acc and the
-# select's temporaries, [SUB_ROWS, W] each: 6 float32 planes a lane.
+# select's temporaries, [SUB_ROWS, W] each: 6 float32 planes a lane; and
+# a widened bin of the sub-tile's K-blocks: the bf16 copy the trees of a
+# block share and the float32 it is made from.
 _SUB_ROW_LANE_BYTES = 24
+_SUB_ROW_BIN_BYTES = 6
+
+
+def select_k_blocks(n_features: int) -> int:
+    """K-blocks of 128 columns the feature select is summed over."""
+    return -(-n_features // _LANES)
 
 
 def path_mxu_tiles_per_tree(lanes: int, n_features: int) -> int:
@@ -89,7 +125,7 @@ def path_mxu_tiles_per_tree(lanes: int, n_features: int) -> int:
     the feature select's, ceil(F/128) x W/128, and the path resolve's,
     (W/128)^2."""
     w = lanes // _LANES
-    return -(-n_features // _LANES) * w + w * w
+    return select_k_blocks(n_features) * w + w * w
 
 
 def _tree_bytes(lanes: int, n_features: int) -> int:
@@ -110,6 +146,9 @@ class PathPlan(typing.NamedTuple):
     table_blocks: int          # blocks a row tile walks
     table_bytes: int           # HBM bytes of all the blocks, read once
     tile_rows: int
+    select_k_blocks: int = 1   # 128-column blocks the select is summed over
+    missing_routes: int = 0    # 1: learned NaN directions in the compare
+    row_operand_bytes: int = 1  # a bin of the row block as HBM holds it
 
     @property
     def blocks(self) -> int:
@@ -121,8 +160,10 @@ class PathPlan(typing.NamedTuple):
         return {k: getattr(self, k) for k in SPAN_COUNTS}
 
     def root_counts(self) -> dict:
-        return {"routing_tables": 0, "node_list": self.node_list,
-                "path_mxu_tiles_per_tree": self.path_mxu_tiles_per_tree}
+        return {"routing_tables": self.missing_routes,
+                "node_list": self.node_list,
+                "path_mxu_tiles_per_tree": self.path_mxu_tiles_per_tree,
+                "select_k_blocks": self.select_k_blocks}
 
 
 # What the `ddt:predict:ensemble` span says of a node-list model's plan, in
@@ -130,52 +171,79 @@ class PathPlan(typing.NamedTuple):
 # but `table_bytes` in `phases_ms`, as it does for the heap kernel's.
 SPAN_COUNTS = ("node_list", "nodes_per_tree", "leaves_per_tree",
                "deepest_leaf", "path_mxu_tiles_per_tree", "trees_per_step",
-               "table_blocks", "table_bytes")
+               "table_blocks", "table_bytes", "select_k_blocks",
+               "missing_routes", "row_operand_bytes")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 
 
+def _lane_pad(n: int) -> int:
+    return -(-n // _LANES) * _LANES
+
+
 def path_plan(n_trees: int, lanes: int, n_features: int,
-              deepest_leaf: int = 0, served: bool = True) -> PathPlan:
+              deepest_leaf: int = 0, served: bool = True,
+              missing_routes: bool = False, row_dtype=jnp.uint8) -> PathPlan:
     """The kernel's table blocks at this shape: G trees a block, the most
-    whose double-buffered windows fit _VMEM_BUDGET_BYTES beside the row
-    tile's windows and a sub-tile's working set, evened out over the
-    blocks. `served` False: the plan of a model the jax.numpy form scores
-    (its lanes and depth, no blocks)."""
+    whose double-buffered windows fit _VMEM_BUDGET_BYTES beside what the
+    kernel holds whatever G: the row tile's two windows at the rows' own
+    width (`row_dtype`, as `row_operand_dtype` takes it: 4096 x 968 uint8
+    is 4.2 MB a window where the int32 tile was 16.8), the [1, TILE_ROWS]
+    output's, a sub-tile's widened K-blocks and its working set; then the
+    size near it that leaves the fewest filler trees. `served` False: the
+    plan of a model the jax.numpy form scores (its lanes and depth, no
+    blocks). `missing_routes` rides along for the spans and decides
+    nothing here."""
     tiles = path_mxu_tiles_per_tree(lanes, n_features)
+    row_bytes = row_operand_dtype(row_dtype).itemsize
+    said = (select_k_blocks(n_features), int(missing_routes), row_bytes)
     if not served:
-        return PathPlan(1, lanes, lanes, deepest_leaf, tiles, 0, 0, 0, 0)
+        return PathPlan(1, lanes, lanes, deepest_leaf, tiles, 0, 0, 0, 0,
+                        *said)
     fp = -(-n_features // 16) * 16
     per_tree = (_window_bytes(fp, lanes) // 2          # bf16: half of f32
                 + _window_bytes(8, lanes)
                 + _window_bytes(lanes, lanes) // 2)
-    fixed = (_window_bytes(TILE_ROWS, n_features) + _window_bytes(TILE_ROWS, 1)
-             + SUB_ROWS * lanes * _SUB_ROW_LANE_BYTES)
+    fixed = (2 * TILE_ROWS * _lane_pad(n_features) * row_bytes
+             + _window_bytes(1, TILE_ROWS)
+             + SUB_ROWS * (_lane_pad(fp) * _SUB_ROW_BIN_BYTES
+                           + lanes * _SUB_ROW_LANE_BYTES))
     most = min(n_trees, _MAX_TREES_PER_STEP,
                max(0, (_VMEM_BUDGET_BYTES - fixed) // per_tree))
     if most == 0:
         return PathPlan(1, lanes, lanes, deepest_leaf, tiles, 0, 0, 0,
-                        TILE_ROWS)
-    blocks = -(-n_trees // most)
-    g = -(-n_trees // blocks)
+                        TILE_ROWS, *said)
+    # Of the block sizes from `most` down to half of it, the one that
+    # fills its last block best (filler trees cost what trees cost: 500
+    # trees are 50 blocks of 10 where 46 of 11 would score 506), the
+    # larger where two fill alike.
+    g = min(range(most, max(most // 2, 1) - 1, -1),
+            key=lambda g: (-(-n_trees // g) * g, -g))
+    blocks = -(-n_trees // g)
     return PathPlan(1, lanes, lanes, deepest_leaf, tiles, g, blocks,
-                    blocks * g * _tree_bytes(lanes, n_features), TILE_ROWS)
+                    blocks * g * _tree_bytes(lanes, n_features), TILE_ROWS,
+                    *said)
 
 
-def predict_paths_fits(lanes: int, n_features: int) -> bool:
+def predict_paths_fits(lanes: int, n_features: int,
+                       row_dtype=jnp.uint8) -> bool:
     """Whether one tree's tables fit the kernel's VMEM budget beside a row
     tile: the guard behind use_pallas=None (ops/predict.resolve_use_pallas).
     The tree count is no term of it."""
-    return path_plan(1, lanes, n_features).trees_per_step > 0
+    return path_plan(1, lanes, n_features,
+                     row_dtype=row_dtype).trees_per_step > 0
 
 
 def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
-                  n_trees: int, n_feat: int):
+                  n_trees: int, n_feat: int, missing_routes: bool):
     """One row tile against one block of `n_trees` trees: the block's share
-    of every row's margin. x_ref [TILE_ROWS, F] int32; sel [G, Fp, W] bf16,
-    planes [G, 8, W] f32, paths [G, W, W] bf16; out [TILE_ROWS, 1] f32,
-    resident over the block axis (grid axis 1)."""
+    of every row's margin. x_ref [TILE_ROWS, F] uint8 or int32, as HBM
+    holds the rows (in the last tile, whatever lies past row R); sel
+    [G, Fp, W] bf16, planes [G, 8, W] f32, paths [G, W, W] bf16; out
+    [1, TILE_ROWS] f32, the rows on the lanes, resident over the block
+    axis (grid axis 1)."""
     tile_rows = x_ref.shape[0]
     fp, lanes = sel_ref.shape[1], sel_ref.shape[2]
+    k_starts = range(0, n_feat, _LANES)
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -183,20 +251,33 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
 
     def sub_tile(j, carry):
         r0 = pl.multiple_of(j * SUB_ROWS, SUB_ROWS)
-        xf = x_ref[pl.ds(r0, SUB_ROWS), :].astype(jnp.float32)
-        if fp > n_feat:     # K to whole bf16 sublane tiles
-            xf = jnp.concatenate(
-                [xf, jnp.zeros((SUB_ROWS, fp - n_feat), jnp.float32)], axis=1)
-        xb = xf.astype(jnp.bfloat16)                      # [S, Fp]
+        # The sub-tile's K-blocks, widened once for the block's trees: the
+        # bf16 copy lives in VMEM alone.
+        xs = []
+        for k0 in k_starts:
+            k1, kp = min(k0 + _LANES, n_feat), min(k0 + _LANES, fp)
+            xf = x_ref[pl.ds(r0, SUB_ROWS), k0:k1].astype(
+                jnp.int32).astype(jnp.float32)
+            if kp > k1:     # K to whole bf16 sublane tiles
+                xf = jnp.concatenate(
+                    [xf, jnp.zeros((SUB_ROWS, kp - k1), jnp.float32)], axis=1)
+            xs.append(xf.astype(jnp.bfloat16))            # [S, <= 128]
 
         def tree(g, acc):
             rows = planes_ref[g]                          # [8, W]
             # bf16 operands (bins <= 255 and the 0/1 one-hot are exact),
             # f32 accumulator: the v5e's VPU has no bf16 compare.
-            v = jax.lax.dot_general(
-                xb, sel_ref[g], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)       # [S, W]
-            s = jnp.where(v > rows[0:1, :], 1.0, -1.0).astype(jnp.bfloat16)
+            v = None
+            for k0, xk in zip(k_starts, xs):
+                part = jax.lax.dot_general(
+                    xk, sel_ref[g, k0:k0 + xk.shape[1], :],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # [S, W]
+                v = part if v is None else v + part
+            right = v > rows[0:1, :]
+            if missing_routes:      # not at the NaN bin where NaN goes left
+                right &= v < rows[3:4, :]
+            s = jnp.where(right, 1.0, -1.0).astype(jnp.bfloat16)
             m = jax.lax.dot_general(
                 s, paths_ref[g], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [S, W]
@@ -207,7 +288,12 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
         acc = jnp.zeros((SUB_ROWS, lanes), jnp.float32)
         for g in range(n_trees):
             acc = tree(g, acc)
-        out_ref[pl.ds(r0, SUB_ROWS), :] += jnp.sum(acc, axis=1,
+        # The lanes summed with the rows on the lanes: fold to one vreg
+        # column, turn it over, add down the sublanes.
+        fold = acc[:, :_LANES]
+        for l0 in range(_LANES, lanes, _LANES):
+            fold = fold + acc[:, l0:l0 + _LANES]
+        out_ref[:, pl.ds(r0, SUB_ROWS)] += jnp.sum(fold.T, axis=0,
                                                    keepdims=True)
         return carry
 
@@ -218,10 +304,11 @@ def predict_paths_pallas(
     sel: jax.Array,            # bf16 [T, Fp, W]
     planes: jax.Array,         # f32 [T, 8, W]
     paths: jax.Array,          # bf16 [T, W, W]
-    Xi: jax.Array,             # int32 [R, F] bins
+    Xc: jax.Array,             # [R, F] integer bins, uint8 as api.predict's
     *,
     learning_rate,
     base,
+    missing_routes: bool = False,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Raw margins [R]: Pallas twin of ops/predict._predict_paths. Jit-safe.
@@ -229,9 +316,14 @@ def predict_paths_pallas(
     if interpret is None:
         interpret = device.platform() != "tpu"
     T, fp, lanes = sel.shape
-    R, F = Xi.shape
-    plan = path_plan(T, lanes, F)
-    if not predict_paths_fits(lanes, F):
+    R, F = Xc.shape
+    # The rows as the kernel takes them: uint8 and int32 as they come, any
+    # other integer cast in XLA first (the heap kernel's rule).
+    row_dtype = row_operand_dtype(Xc.dtype)
+    with traced_scope("predict:widen"):
+        rows = Xc if Xc.dtype == row_dtype else Xc.astype(row_dtype)
+    plan = path_plan(T, lanes, F, row_dtype=row_dtype)
+    if not predict_paths_fits(lanes, F, row_dtype):
         if not interpret:
             raise ValueError(
                 f"path-matrix shape ({lanes} lanes a tree, F={F}) exceeds "
@@ -240,19 +332,15 @@ def predict_paths_pallas(
         plan = plan._replace(trees_per_step=T, table_blocks=1)
     g, n_blocks = plan.trees_per_step, plan.table_blocks
     # Trees that fill the last block: no node, and no leaf of any length
-    # (-1), so they add 0. Rows that fill the last tile are cut off below.
+    # (-1), so they add 0. A backend hands the tables over whole blocks
+    # long already (backends/tpu._build_paths_fn: padded once a model, on
+    # the host), and these pads are of no tree: no instruction.
     t_fill = ((0, n_blocks * g - T), (0, 0), (0, 0))
     with traced_scope("predict:tables"):
         sel_b, paths_b = jnp.pad(sel, t_fill), jnp.pad(paths, t_fill)
         planes_b = jnp.pad(planes, t_fill, constant_values=-1.0)
     tile_rows = min(TILE_ROWS, -(-R // SUB_ROWS) * SUB_ROWS)
     n_tiles = -(-R // tile_rows)
-    with traced_scope("predict:widen"):
-        Xt = jnp.pad(Xi, ((0, n_tiles * tile_rows - R), (0, 0)))
-
-    def rows_of_tile(cols):
-        return pl.BlockSpec((tile_rows, cols), lambda i, b: (i, 0),
-                            memory_space=pltpu.VMEM)
 
     def table_block(rows, cols):
         return pl.BlockSpec((g, rows, cols), lambda i, b: (b, 0, 0),
@@ -260,23 +348,28 @@ def predict_paths_pallas(
 
     cost = pl.CostEstimate(
         flops=2 * n_tiles * tile_rows * n_blocks * g * lanes * (fp + lanes),
-        bytes_accessed=n_tiles * (tile_rows * (F + 1) * 4
-                                  + plan.table_bytes),
+        bytes_accessed=n_tiles * (
+            tile_rows * (F * row_dtype.itemsize + 4) + plan.table_bytes),
         transcendentals=0,
     )
     with traced_scope("predict:traverse_paths"):
         acc = pl.pallas_call(
-            functools.partial(_paths_kernel, n_trees=g, n_feat=F),
+            functools.partial(_paths_kernel, n_trees=g, n_feat=F,
+                              missing_routes=missing_routes),
+            # The grid walks the UNPADDED rows: the last tile's blocks are
+            # ragged, as in the heap kernel.
             grid=(n_tiles, n_blocks),
-            in_specs=[rows_of_tile(F), table_block(fp, lanes),
-                      table_block(8, lanes), table_block(lanes, lanes)],
-            out_specs=rows_of_tile(1),
-            out_shape=jax.ShapeDtypeStruct((n_tiles * tile_rows, 1),
-                                           jnp.float32),
+            in_specs=[pl.BlockSpec((tile_rows, F), lambda i, b: (i, 0),
+                                   memory_space=pltpu.VMEM),
+                      table_block(fp, lanes), table_block(8, lanes),
+                      table_block(lanes, lanes)],
+            out_specs=pl.BlockSpec((1, tile_rows), lambda i, b: (0, i),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((1, R), jnp.float32),
             cost_estimate=cost,
             interpret=interpret,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        )(Xt, sel_b, planes_b, paths_b)
+        )(rows, sel_b, planes_b, paths_b)
     with traced_scope("predict:accumulate"):
-        return base + learning_rate * acc[:R, 0]
+        return base + learning_rate * acc[0]

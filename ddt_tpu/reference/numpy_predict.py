@@ -37,7 +37,10 @@ The NODE LIST (models/tree.NodeListEnsemble; `leaf_of_rows_node_list`,
 `n_leaves[t]` leaves. A row starts at node 0; at internal node n it goes
 LEFT, to `left_child[n]`, when `bin[feature[n]] <= threshold_bin[n]`, else
 RIGHT, to `right_child[n]`; a negative child c is leaf `~c` and the tree
-scores `leaf_value[~c]` (a tree of one leaf scores `leaf_value[0]`). Raw
+scores `leaf_value[~c]` (a tree of one leaf scores `leaf_value[0]`). With
+learned NaN directions (`missing_bin` and `default_left`, as in the heap) a
+row whose bin is the reserved one, `n_bins - 1`, goes LEFT where
+`default_left[n]` and else RIGHT, whatever the threshold. Raw
 score [rows] = base_score + learning_rate x the sum over the trees. Ordinal
 splits and one output column only. Where the device path (the path-matrix
 form, `ops/predict.py` and its kernel `ops/predict_paths.py`) departs from
@@ -45,7 +48,9 @@ this walk, all of it arithmetic and none of it routing:
 
 - It does not walk. Every node's compare is made for every row, and the
   leaf reached is the one whose whole path agrees (`m == len`); the leaf is
-  the walk's, for every row and tree.
+  the walk's, for every row and tree. The NaN route is folded into a second
+  threshold (a node answers right when `thr < bin < up`, `up` the NaN bin
+  where it sends NaN left): the same answer for every bin.
 - It pads a tree's nodes and leaves to a multiple of 128 lanes with nodes
   that always answer left and leaves no path reaches, and the trees to
   whole blocks with trees of no leaf.
@@ -100,12 +105,16 @@ def leaf_of_rows_node_list(ens, t: int, Xb: np.ndarray) -> np.ndarray:
     rows = np.arange(Xb.shape[0])
     if ens.n_leaves[t] == 1:
         return np.zeros(len(rows), np.int64)
+    use_missing = bool(ens.missing_bin) and ens.default_left is not None
     cur = np.zeros(len(rows), np.int64)          # a node, or ~leaf
     while (cur >= 0).any():
         n = np.maximum(cur, 0)
         b = Xb[rows, ens.feature[t][n]].astype(np.int64)
-        nxt = np.where(b <= ens.threshold_bin[t][n],
-                       ens.left_child[t][n], ens.right_child[t][n])
+        left = b <= ens.threshold_bin[t][n]
+        if use_missing:
+            left = np.where(b == ens.n_bins - 1, ens.default_left[t][n],
+                            left)
+        nxt = np.where(left, ens.left_child[t][n], ens.right_child[t][n])
         cur = np.where(cur >= 0, nxt, cur)
     return ~cur
 

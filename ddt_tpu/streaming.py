@@ -1520,14 +1520,17 @@ def predict_streaming(
         # Device working-set bound: a chunk past the backend's per-call
         # row limit may NOT go down as one dispatch (the 10M x 1000
         # config OOM-kills the chip that way — backends/tpu.py
-        # PREDICT_ROW_CHUNK). Oversized chunks route through
+        # predict_chunk_rows, the rows a dispatch takes at the chunk's
+        # width). Oversized chunks route through
         # backend.predict_raw, whose internal chunking + overlapped
         # fetch already handle the big-batch case; the double-buffered
         # pipeline below covers the (normal) bounded-chunk regime.
-        limit = (getattr(backend, "PREDICT_ROW_CHUNK", None) or 0) \
-            * max(1, getattr(backend, "row_shards", 1))
+        chunk_rows = getattr(backend, "predict_chunk_rows", None)
+        shards = max(1, getattr(backend, "row_shards", 1))
+
         def fits(x):
-            return not limit or x.shape[0] <= limit
+            return chunk_rows is None \
+                or x.shape[0] <= chunk_rows(x.shape[1]) * shards
 
         Xc = np.asarray(chunk_fn(0)[0])
         data = backend._put_rows(Xc, extra_dims=1) if fits(Xc) else None

@@ -46,6 +46,9 @@ HIGGS = dict(rows=1_000_000, features=28)
 COVERTYPE = dict(rows=200_000, features=54, classes=7)
 # The CTR model's scoring chunk (benchmark config criteo-ctr-100t-d6).
 CRITEO = dict(rows=2_000_000, features=39)
+# LightGBM's Bosch model's scoring chunk (benchmark config
+# bosch-lgbm-500t-255l): 968 columns, TPUDevice.predict_chunk_rows of them.
+BOSCH = dict(chunk_rows=262_144, features=968)
 
 
 class KernelCase(typing.NamedTuple):
@@ -160,33 +163,33 @@ def _predict_case(rows, features, n_trees, depth, n_classes=1,
     return build
 
 
-def _random_node_list(n_trees, n_leaves, features):
+def _random_node_list(n_trees, n_leaves, features, missing=False):
     """A random leaf-wise ensemble (seeded) as a models/tree
-    NodeListEnsemble."""
+    NodeListEnsemble; `missing`: with learned NaN directions."""
     import numpy as np
 
     from ddt_tpu.models.tree import random_node_list
 
     return random_node_list(np.random.default_rng(7), n_trees, n_leaves,
                             features, learning_rate=0.1, base_score=0.0,
-                            loss="logloss")
+                            loss="logloss", missing=missing)
 
 
-def _paths_case(rows, features, n_trees, n_leaves):
+def _paths_case(rows, features, n_trees, n_leaves, missing=False):
     """The path-matrix kernel (ops/predict_paths.py) over a node list's
-    compiled tables."""
+    compiled tables, the rows as api.predict hands them over (uint8)."""
     def build():
         import jax.numpy as jnp
 
         from ddt_tpu.ops import predict_paths
 
-        ce = _random_node_list(n_trees, n_leaves, features).compile()
+        ce = _random_node_list(n_trees, n_leaves, features, missing).compile()
 
         def fn(sel, planes, paths, Xc):
             return predict_paths.predict_paths_pallas(
-                sel, planes, paths, Xc.astype(jnp.int32),
+                sel, planes, paths, Xc,
                 learning_rate=ce.learning_rate, base=ce.base_score,
-                interpret=False)
+                missing_routes=missing, interpret=False)
 
         shapes = [(a.shape, a.dtype) for a in ce.arrays()]
         shapes.append(((rows, features), jnp.uint8))
@@ -296,6 +299,14 @@ def kernel_cases() -> list:
         KernelCase("paths/9x15leaves", True, _paths_case(hr, hf, 9, 15)),
         KernelCase("paths/70f/40x200leaves", True,
                    _paths_case(hr, 70, 40, 200)),
+        # Past one K-block of the select, with and without the NaN route;
+        # Bosch's width (8 K-blocks, the last of 72 columns), a ragged
+        # last row tile.
+        KernelCase("paths/129f/12x255leaves", True,
+                   _paths_case(4_999, 129, 12, 255)),
+        KernelCase("paths/bosch/968f/20x255leaves/nan", True,
+                   _paths_case(BOSCH["chunk_rows"], BOSCH["features"], 20,
+                               255, missing=True)),
         # Opt-in kernels.
         KernelCase("hist/higgs/255bins/N=32/int8", False,
                    _hist_case(hr, hf, 32, 255, "int8")),
@@ -367,6 +378,8 @@ def _rounds_program(topo_devices, *, rows, features, n_rounds, mesh_shape,
 
 def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
                      n_classes=1, routed=False, leaves=0):
+    """`routed`: a heap with the missing and the categorical table; a node
+    list (`leaves`) with learned NaN directions."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -375,7 +388,7 @@ def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
     from ddt_tpu.config import TrainConfig
 
     be = TPUDevice(TrainConfig(backend="tpu", max_depth=depth or 6))
-    ens = (_random_node_list(n_trees, leaves, features) if leaves else
+    ens = (_random_node_list(n_trees, leaves, features, routed) if leaves else
            _random_ensemble(n_trees, depth, features, n_classes, routed,
                             routed))
     fn, ens_dev = be._predict_fn(ens)
@@ -445,6 +458,11 @@ def program_cases(topo_devices) -> list:
         # list (`leaves`: no heap depth), the path-matrix form.
         ("scoring/higgs-lgbm/500x255leaves", scoring(
             500, rows=2_000_000, depth=0, leaves=255)),
+        # LightGBM's Bosch model's chunk: 968 columns, NaN directions, the
+        # K-blocked path kernel; the rows a chunk is at that width.
+        ("scoring/bosch-lgbm/500x255leaves/nan", scoring(
+            500, rows=BOSCH["chunk_rows"], features=BOSCH["features"],
+            depth=0, leaves=255, routed=True)),
     ]
 
 

@@ -337,3 +337,99 @@ def test_header_fields_and_leaf_encoding():
     refs = lc + rc
     assert sum(1 for r in refs if r < 0) == n_leaves
     assert all(-n_leaves <= r < n_leaves - 1 for r in refs)
+
+
+def _leafwise_nan_text(n_trees=4, n_leaves=255, n_features=12, seed=21):
+    """A LightGBM text as `use_missing` writes it for leaf-wise trees of 255
+    leaves: every node's missing type NaN, default-left and default-right
+    nodes mixed (a fair coin); distinct thresholds a feature well under
+    253."""
+    from ddt_tpu.models.tree import random_node_list
+
+    rng = np.random.default_rng(seed)
+    src = random_node_list(rng, n_trees, n_leaves, n_features, missing=True,
+                           learning_rate=1.0, base_score=0.0, loss="logloss",
+                           has_raw_thresholds=True, has_bin_thresholds=False)
+    live = src.live_nodes
+    src.threshold_raw[live] = rng.integers(-90, 90, int(live.sum())) / 8.0
+    return src, src.to_lightgbm_text()
+
+
+def test_leafwise_nan_directions_import_as_a_node_list_and_round_trip():
+    """255 leaves with NaN default-left and default-right nodes: imported as
+    a NODE LIST with its directions (it was refused before PR 37), scored
+    as the independent LightGBM walk scores it, NaN rows and all, and
+    written back to the same text."""
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.models import lightgbm_io
+    from ddt_tpu.models.tree import NodeListEnsemble
+
+    src, txt = _leafwise_nan_text()
+    dts = {int(v) for ln in txt.splitlines() if ln.startswith(
+        "decision_type=") for v in ln.split("=")[1].split()}
+    assert dts == {8, 10}           # NaN missing type; default right / left
+    back = TreeEnsemble.from_lightgbm_text(txt)
+    assert isinstance(back, NodeListEnsemble) and back.missing_routes
+    assert back.deepest_leaf > lightgbm_io.HEAP_MAX_DEPTH
+    np.testing.assert_array_equal(back.default_left, src.default_left)
+    assert back.to_lightgbm_text() == txt
+    rng = np.random.default_rng(22)
+    X = (rng.integers(-100, 100, (300, src.n_features)) / 8.0).astype(
+        np.float32)
+    X[rng.random(X.shape) < 0.6] = np.nan
+    want = _lgbm_oracle_raw(txt, X)
+    np.testing.assert_allclose(back.predict_raw(X), want, rtol=1e-5,
+                               atol=1e-6)
+    # ... and on the device path, through the model's own NaN-aware mapper
+    mapper = lightgbm_io.threshold_bin_mapper(back)
+    assert mapper.missing_bin and back.n_bins == 255
+    Xb = mapper.transform(X)
+    assert (Xb[np.isnan(X)] == 254).all() and Xb[~np.isnan(X)].max() < 254
+    got = api.predict(back, X, mapper=mapper, raw=True,
+                      cfg=TrainConfig(backend="tpu", predict_impl="pallas"))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
+
+
+def test_threshold_mapper_keeps_the_top_bin_for_nan():
+    """With directions a feature holds 253 distinct thresholds (bins
+    0..253 for values, 254 NaN's) and the 254th is refused by name; a
+    node whose missing type is not NaN sends NaN right, as the heap import
+    does; the heap import of a shallow NaN-routed text gets the same
+    mapper."""
+    from ddt_tpu.models import lightgbm_io
+    from ddt_tpu.models.tree import node_list_from_trees
+
+    def chain(n):
+        nodes = [(0, 0, float(k), 0.0, (k + 1) if k < n - 1 else ~0,
+                  ~(k + 1), k % 2 == 0) for k in range(n)]
+        return node_list_from_trees(
+            [(nodes, list(np.arange(n + 1.0)))], n_features=2,
+            learning_rate=1.0, base_score=0.0, loss="mse",
+            has_raw_thresholds=True, has_bin_thresholds=False,
+            missing_bin=True)
+
+    ok = chain(253)
+    mapper = lightgbm_io.threshold_bin_mapper(ok)
+    assert mapper.missing_bin and mapper.n_value_bins == 254
+    assert int(ok.threshold_bin[0].max()) == 252
+    X = np.array([[np.nan, 0.0], [-1.0, 0.0], [251.5, 0.0], [1e9, 0.0]],
+                 np.float32)
+    assert mapper.transform(X)[:, 0].tolist() == [254, 0, 252, 253]
+    np.testing.assert_array_equal(ok.predict_raw(mapper.transform(X),
+                                                 binned=True),
+                                  ok.predict_raw(X))
+    with pytest.raises(ValueError, match="254 distinct thresholds.*NaN"):
+        lightgbm_io.threshold_bin_mapper(chain(254))
+    # missing type none on some nodes: NaN goes right there
+    txt = chain(40).to_lightgbm_text().replace(
+        "decision_type=10 8 10", "decision_type=2 8 10")
+    back = TreeEnsemble.from_lightgbm_text(txt)
+    assert not back.default_left[0, 0] and back.default_left[0, 2]
+    # a shallow text stays a heap, and its mapper keeps the NaN bin too
+    heap = TreeEnsemble.from_lightgbm_text(chain(5).to_lightgbm_text())
+    assert isinstance(heap, TreeEnsemble) and heap.missing_bin
+    hm = lightgbm_io.threshold_bin_mapper(heap)
+    assert hm.missing_bin and heap.n_bins == 255
+    Xs = np.array([[np.nan, 0.0], [2.5, 0.0]], np.float32)
+    np.testing.assert_array_equal(
+        heap.predict_raw(hm.transform(Xs), binned=True), heap.predict_raw(Xs))

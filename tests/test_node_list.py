@@ -287,12 +287,13 @@ def test_a_shallow_import_stays_a_heap():
                                rtol=1e-6)
 
 
-def test_routed_and_multiclass_node_lists_are_refused_by_name():
+def test_category_and_multiclass_node_lists_are_refused_by_name():
+    """What a node list still cannot carry is refused with the mechanism
+    named; learned NaN directions are NOT among them any more."""
     heap = empty_ensemble(2, 2, 4, 0.1, 0.0, "logloss", missing_bin=True,
                           n_bins=255)
     heap.is_leaf[:, 0] = True
-    with pytest.raises(ValueError, match="default directions for missing"):
-        NodeListEnsemble.from_heap(heap)
+    assert NodeListEnsemble.from_heap(heap).n_trees == 2
     heap = empty_ensemble(2, 2, 4, 0.1, 0.0, "logloss", cat_features=(1,))
     heap.is_leaf[:, 0] = True
     with pytest.raises(ValueError, match="category-set"):
@@ -304,19 +305,236 @@ def test_routed_and_multiclass_node_lists_are_refused_by_name():
     src = hand_built()
     with pytest.raises(ValueError, match="several classes"):
         dataclasses.replace(src, loss="softmax", n_classes=3)
-    # a LightGBM text too deep for any heap, with NaN default directions
-    deep = leafwise_raw_model()
+    # directions without the bin count they route by
+    with pytest.raises(ValueError, match="learned NaN directions need"):
+        dataclasses.replace(src, missing_bin=True,
+                            default_left=np.zeros((1, 4), bool))
+    with pytest.raises(ValueError, match="learned NaN directions need"):
+        dataclasses.replace(src, missing_bin=True, n_bins=255,
+                            default_left=np.zeros((1, 3), bool))
+    # a LightGBM text too deep for any heap, with a category node: refused
     chain = 40
     nodes = [(0, 0, float(k), 0.0, (k + 1) if k < chain - 1 else ~0,
               ~(k + 1)) for k in range(chain)]
     deep = node_list_from_trees(
         [(nodes, [0.0] * (chain + 1))], n_features=2, learning_rate=1.0,
         base_score=0.0, loss="logloss", has_raw_thresholds=True)
-    text = deep.to_lightgbm_text().replace(
+    text = deep.to_lightgbm_text()
+    cat = text.replace(
         "decision_type=" + " ".join(["0"] * chain),
-        "decision_type=" + " ".join(["10"] * chain))
-    with pytest.raises(ValueError, match="default directions for missing"):
-        TreeEnsemble.from_lightgbm_text(text)
+        "decision_type=" + " ".join(["1"] + ["0"] * (chain - 1))).replace(
+            "num_cat=0", "num_cat=1").replace(
+                "is_linear=0", "cat_boundaries=0 1\ncat_threshold=6\n"
+                "is_linear=0")
+    with pytest.raises(ValueError, match="category-set"):
+        TreeEnsemble.from_lightgbm_text(cat)
+    # ... and the same text with NaN default directions is a node list now
+    routed = TreeEnsemble.from_lightgbm_text(text.replace(
+        "decision_type=" + " ".join(["0"] * chain),
+        "decision_type=" + " ".join(["10", "8"] * (chain // 2))))
+    assert isinstance(routed, NodeListEnsemble) and routed.missing_routes
+    assert routed.default_left[0].tolist() == [True, False] * (chain // 2)
+
+
+# ------------------------------------------------------------------ #
+# learned NaN directions
+# ------------------------------------------------------------------ #
+
+NAN = 254
+
+
+def hand_built_routed():
+    """`hand_built` with directions: NaN goes left at n0 and n3, right at
+    n1 and n2."""
+    src = hand_built()
+    return dataclasses.replace(
+        src, missing_bin=True, n_bins=255,
+        default_left=np.array([[True, False, False, True]]))
+
+
+def test_reference_walk_follows_the_nan_directions():
+    ens = hand_built_routed()
+    Xb = np.array([[NAN, 5, 9],        # n0 NaN -> left n1; 5 <= 5 -> L0
+                   [NAN, NAN, NAN],    # n1 NaN -> right n3; n3 NaN -> L3
+                   [NAN, NAN, 2],      # n3: 2 > 1 -> L4
+                   [4, 0, 0],          # n0 right n2; 4 <= 7 -> L1
+                   [3, NAN, 0],        # left n1, NaN right n3, 0 <= 1 -> L3
+                   [200, 0, 0]],       # n2: 200 > 7 -> L2
+                  np.uint8)
+    want_leaf = [0, 3, 4, 1, 3, 2]
+    assert list(numpy_predict.leaf_of_rows_node_list(ens, 0, Xb)) == want_leaf
+    assert list(ens._leaf_np(Xb, True)[0]) == want_leaf
+    # n2's default is right: a NaN there is leaf 2, where the plain compare
+    # of an unrouted model sends it too (the NaN bin is above 7)
+    x = np.array([[NAN, 0, 0]], np.uint8)
+    ens.default_left[0, 0] = False
+    assert numpy_predict.leaf_of_rows_node_list(ens, 0, x)[0] == 2
+    assert numpy_predict.leaf_of_rows_node_list(hand_built(), 0, x)[0] == 2
+    # without `missing_bin` the directions are not read (the heap's rule)
+    off = dataclasses.replace(hand_built_routed(), missing_bin=False)
+    assert not off.missing_routes and off.missing_bin_value == -1
+    np.testing.assert_array_equal(
+        numpy_predict.predict_raw_node_list(off, Xb),
+        numpy_predict.predict_raw_node_list(hand_built(), Xb))
+    # raw rows: NaN itself follows the direction
+    raw = dataclasses.replace(hand_built_routed(), has_raw_thresholds=True)
+    raw.threshold_raw[:] = raw.threshold_bin
+    Xf = np.array([[np.nan, 5.0, 9.0], [np.nan, np.nan, np.nan]], np.float32)
+    assert list(raw._leaf_np(Xf, False)[0]) == [0, 3]
+
+
+@pytest.mark.parametrize("n_leaves", [2, 15, 255])
+def test_path_matrix_with_the_folded_nan_route(n_leaves):
+    """`thr < bin < up` (the compiled tables' two thresholds) is the
+    walk's answer at every node, and the path matrix then picks the
+    walk's leaf, alone."""
+    ens = random_node_list(np.random.default_rng(9), 3, n_leaves, 5,
+                           dyadic=True, missing=True, learning_rate=0.5,
+                           base_score=0.0, loss="logloss")
+    Xb = rows(10, 400, 5)
+    Xb[np.random.default_rng(11).random(Xb.shape) < 0.5] = NAN
+    P, plen = ens.path_matrix()
+    ce = ens.compile()
+    assert ce.missing_bin_value == NAN
+    N = ens.feature.shape[1]
+    for t in range(3):
+        thr, up = ce.planes[t, 0, :N], ce.planes[t, 3, :N]
+        np.testing.assert_array_equal(
+            up == NAN, ens.default_left[t] & ens.live_nodes[t])
+        v = Xb[:, np.maximum(ens.feature[t], 0)].astype(np.float32)
+        s = np.where((v > thr) & (v < up), 1, -1)             # [R, N]
+        hit = (s @ P[t].astype(np.int64)) == plen[t][None, :]
+        assert (hit.sum(axis=1) == 1).all()
+        np.testing.assert_array_equal(
+            hit.argmax(axis=1),
+            numpy_predict.leaf_of_rows_node_list(ens, t, Xb))
+    # a threshold in the NaN bin cannot be told from the route: refused
+    ens.threshold_bin[0, 0] = NAN
+    with pytest.raises(ValueError, match="the NaN bin"):
+        ens.compile()
+
+
+ROUTE_SHAPES = [(F, R) for F in (28, 129, 300) for R in (1, 257, 1000)]
+
+
+@pytest.mark.parametrize("missing", [False, True], ids=["plain", "nan"])
+@pytest.mark.parametrize("n_features,n_rows", ROUTE_SHAPES)
+def test_kernel_and_twin_agree_with_the_walk(n_features, n_rows, missing):
+    """The interpreted kernel and the jax.numpy form at 1, 2 and 3 K-blocks
+    of the select (the last one partial), with and without the NaN route,
+    against the plain walk: bit-equal on dyadic leaf values."""
+    rng = np.random.default_rng(n_features + n_rows)
+    ens = random_node_list(rng, 7, 40, n_features, dyadic=True,
+                           missing=missing, learning_rate=0.5,
+                           base_score=0.25, loss="logloss")
+    Xb = rows(13, n_rows, n_features, 254)
+    Xb[rng.random(Xb.shape) < 0.5] = NAN     # a bin like any, unrouted
+    want = numpy_predict.predict_raw_node_list(
+        ens, Xb, np.float64).astype(np.float32)
+    np.testing.assert_array_equal(ens.predict_raw(Xb, binned=True), want)
+    for impl in ("pallas", "onehot"):
+        got = api.predict(ens, Xb, binned=True, raw=True,
+                          cfg=TrainConfig(backend="tpu", predict_impl=impl))
+        np.testing.assert_array_equal(got, want, err_msg=impl)
+
+
+def test_int32_rows_score_as_uint8_rows_do():
+    """The kernel takes the rows at the width they come in."""
+    import jax.numpy as jnp
+
+    from ddt_tpu.ops import predict_paths
+
+    ens = random_node_list(np.random.default_rng(3), 5, 30, 140,
+                           dyadic=True, missing=True, learning_rate=0.5,
+                           base_score=0.0, loss="logloss")
+    ce = ens.compile()
+    Xb = rows(14, 600, 140)
+    outs = [np.asarray(predict_paths.predict_paths_pallas(
+        *map(jnp.asarray, ce.arrays()), jnp.asarray(Xb.astype(dt)),
+        learning_rate=0.5, base=0.0, missing_routes=True))
+        for dt in (np.uint8, np.int32, np.int16)]
+    np.testing.assert_array_equal(outs[0], outs[1])
+    np.testing.assert_array_equal(outs[0], outs[2])
+    np.testing.assert_array_equal(
+        outs[0], numpy_predict.predict_raw_node_list(ens, Xb))
+
+
+def test_a_missing_routed_heap_converts_with_its_directions():
+    """heap -> node list of a model with learned NaN directions: the same
+    scores as the heap's own reference and the heap kernel."""
+    rng = np.random.default_rng(5)
+    T, depth, F = 12, 4, 9
+    heap = empty_ensemble(T, depth, F, 0.5, 0.125, "logloss",
+                          missing_bin=True, n_bins=255)
+    n_int = 2 ** depth - 1
+    heap.feature[:, :n_int] = rng.integers(0, F, (T, n_int))
+    heap.threshold_bin[:, :n_int] = rng.integers(0, 253, (T, n_int))
+    heap.default_left[:, :n_int] = rng.random((T, n_int)) < 0.5
+    heap.is_leaf[:, n_int:] = True
+    heap.leaf_value[:, n_int:] = rng.integers(-16, 17, (T, n_int + 1)) / 8.0
+    heap.is_leaf[0, 2] = True                 # an early leaf
+    heap.leaf_value[0, 2] = 1.5
+    nl = NodeListEnsemble.from_heap(heap)
+    assert nl.missing_routes and nl.missing_bin_value == NAN
+    Xb = rows(6, 900, F, 254)
+    Xb[rng.random(Xb.shape) < 0.4] = NAN
+    want = numpy_predict.predict_raw(heap, Xb)
+    np.testing.assert_array_equal(
+        numpy_predict.predict_raw_node_list(nl, Xb), want)
+    cfg = TrainConfig(backend="tpu", predict_impl="pallas")
+    np.testing.assert_array_equal(
+        api.predict(nl, Xb, binned=True, raw=True, cfg=cfg),
+        api.predict(heap, Xb, binned=True, raw=True, cfg=cfg))
+    # flipping one direction is another model: token and scores
+    token = nl.cache_token()
+    nl.default_left[0, 0] ^= True
+    assert nl.cache_token() != token
+
+
+def test_nan_routed_save_load_token_and_cli(tmp_path, capsys):
+    """A NaN-routed node list as an import hands it over (raw thresholds),
+    ranked by its own mapper, saved, loaded and scored through api.predict
+    and `cli predict` over float rows with NaN in them."""
+    from ddt_tpu.cli import main
+
+    rng = np.random.default_rng(8)
+    ens = random_node_list(rng, 6, 60, 8, missing=True, learning_rate=0.3,
+                           base_score=0.1, loss="logloss",
+                           has_raw_thresholds=True, has_bin_thresholds=False)
+    live = ens.live_nodes
+    ens.threshold_raw[live] = rng.standard_normal(int(live.sum())).round(2)
+    plain = dataclasses.replace(ens, default_left=None, missing_bin=False)
+    assert plain.cache_token() != ens.cache_token()
+    mapper = lightgbm_io.threshold_bin_mapper(ens)
+    assert mapper.missing_bin and ens.missing_bin_value == NAN
+    assert int(ens.threshold_bin[live].max()) < NAN - 1
+    path = str(tmp_path / "routed.npz")
+    api.save_model(path, ens, mapper=mapper)
+    bundle = api.load_model(path)
+    back = bundle.ensemble
+    assert isinstance(back, NodeListEnsemble) and back.missing_routes
+    np.testing.assert_array_equal(back.default_left, ens.default_left)
+    assert back.cache_token() == ens.cache_token()
+    X = rng.standard_normal((500, 8)).astype(np.float32)
+    X[rng.random(X.shape) < 0.5] = np.nan
+    assert (mapper.transform(X) == NAN).mean() > 0.4
+    cfg = TrainConfig(backend="tpu", predict_impl="pallas")
+    got = api.predict(bundle, X, raw=True, cfg=cfg)
+    want = ens.predict_raw(X)                 # the raw walk: NaN itself
+    assert np.abs(got - want).max() <= 1e-6 * max(1.0, np.abs(want).max())
+    assert np.abs(got - plain.predict_raw(X)).max() > 0.01
+    assert "nan->" in back.dump_text(0) and "nan->" not in plain.dump_text(0)
+    # an unrouted artifact saved before this field existed still loads
+    d = plain.to_dict()
+    d.pop("missing_bin")
+    assert not NodeListEnsemble.from_dict(d).missing_routes
+    data = str(tmp_path / "rows.npz")
+    np.savez(data, X=X, y=np.zeros(len(X), np.float32))
+    assert main(["predict", "--backend=tpu", f"--model={path}",
+                 f"--data={data}"]) == 0
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rec["rows"] == 500 and rec["phases_ms"]["missing_routes"] == 1
+    assert rec["phases_ms"]["select_k_blocks"] == 1
 
 
 # ------------------------------------------------------------------ #

@@ -277,8 +277,10 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
         bytes=counts["bytes"], trees=3, node_list=1, nodes_per_tree=128,
         leaves_per_tree=128, deepest_leaf=2, path_mxu_tiles_per_tree=2,
         trees_per_step=3 * served, table_blocks=1 * served,
-        table_bytes=3 * (16 * 128 * 2 + 8 * 128 * 4 + 128 * 128 * 2) * served)
+        table_bytes=3 * (16 * 128 * 2 + 8 * 128 * 4 + 128 * 128 * 2) * served,
+        select_k_blocks=1, missing_routes=0, row_operand_bytes=1)
     assert counts["bytes"] == counts["table_bytes"] or not served
+    assert root["counts"]["select_k_blocks"] == 1
 
     # blocks of trees stream once a row tile
     plan = predict_paths.path_plan(500, 256, 28, 17)
@@ -289,13 +291,51 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
     assert plan.table_bytes == plan.trees_per_step * plan.table_blocks * (
         32 * 256 * 2 + 8 * 256 * 4 + 256 * 256 * 2)
 
+    # Bosch's width: 8 K-blocks of the select, 20 weight tiles a tree, the
+    # row tile charged at the rows' own width, no filler tree in 50 blocks
+    wide = predict_paths.path_plan(500, 256, 968, missing_routes=True)
+    assert (wide.select_k_blocks, wide.path_mxu_tiles_per_tree,
+            wide.missing_routes, wide.row_operand_bytes) == (8, 20, 1, 1)
+    assert wide.trees_per_step * wide.table_blocks == 500
+    assert wide.table_bytes == 500 * (976 * 256 * 2 + 8 * 256 * 4
+                                      + 256 * 256 * 2)
+    assert predict_paths.path_plan(
+        500, 256, 968, row_dtype=np.int32).row_operand_bytes == 4
+
     # a heap model says nothing of the path form
     heap = _rand_ensemble(seed=2024)
     be.predict_raw(heap, Xb)
     root = an.root_spans("predict")[-1]
     assert "node_list" not in root["counts"]
+    assert "select_k_blocks" not in root["counts"]
     assert "node_list" not in _by_name(root)[
         "ddt:predict:ensemble"][0]["counts"]
+
+
+@pytest.mark.parametrize("n_features,k_blocks", [(6, 1), (129, 2), (300, 3)])
+def test_a_nan_routed_node_list_says_so(n_features, k_blocks):
+    """Learned NaN directions and the width of the select on the spans:
+    `missing_routes` and `select_k_blocks` on the `ensemble` span,
+    `routing_tables` 1 and `select_k_blocks` on the root, of every call."""
+    from ddt_tpu.models.tree import random_node_list
+
+    rng = np.random.default_rng(79)
+    ens = random_node_list(rng, 3, 9, n_features, n_bins=31, missing=True,
+                           dyadic=True, learning_rate=0.5, base_score=0.0, loss="logloss")
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31,
+                                 predict_impl="pallas"))
+    Xb = rng.integers(0, 31, size=(300, n_features), dtype=np.uint8)
+    for call in range(2):       # the second from the model cache
+        scores = be.predict_raw(ens, Xb)
+        root = an.root_spans("predict")[-1]
+        assert root["counts"]["routing_tables"] == 1
+        assert root["counts"]["select_k_blocks"] == k_blocks
+        assert root["counts"]["node_list"] == 1
+    np.testing.assert_array_equal(scores, ens.predict_raw(Xb, binned=True))
+    built = [sp for sp in an.recent_spans()
+             if sp["name"] == "ddt:predict:ensemble"][-1]["counts"]
+    assert (built["missing_routes"], built["select_k_blocks"],
+            built["row_operand_bytes"]) == (1, k_blocks, 1)
 
 
 # The chunk loop's result against its chunks scored one call each (the
@@ -447,11 +487,11 @@ def test_every_instruction_of_a_scoring_program_has_a_stage(
     traverse = "predict:traverse_paths" if (
         model == "node-list" and impl == "pallas") else "predict:traverse"
     assert {traverse, "predict:accumulate"} <= seen
-    # The heap kernel takes the uint8 chunk as it is and widens a tile in
-    # VMEM: no instruction of its program is the widening. The node list
-    # and the jax.numpy forms still widen in XLA.
-    heap_kernel = model != "node-list" and impl == "pallas"
-    assert ("predict:widen" in seen) != heap_kernel
+    # Both kernels take the uint8 chunk as it is and widen a tile in VMEM:
+    # no instruction of their programs is the widening (the heap kernel
+    # since PR 36, the path kernel since PR 37). The jax.numpy forms still
+    # widen in XLA.
+    assert ("predict:widen" in seen) != (impl == "pallas")
     for name, e in held.items():
         assert re.fullmatch(r"%[\w.-]+", name)
         assert e["op"].endswith(")")

@@ -35,6 +35,7 @@ DEFAULT_CASES = [c for c in aot.kernel_cases() if c.default]
 
 
 HEAP_CASES = [c for c in DEFAULT_CASES if c.name.startswith("predict/")]
+PATH_CASES = [c for c in DEFAULT_CASES if c.name.startswith("paths/")]
 
 
 @functools.lru_cache(maxsize=None)
@@ -82,6 +83,32 @@ def test_heap_kernel_crosses_hbm_at_the_datas_width(case):
     assert f"tensor<{padded}x" not in text and f"x{padded}x" not in text
 
 
+@pytest.mark.parametrize("case", PATH_CASES, ids=lambda c: c.name)
+def test_path_kernel_crosses_hbm_at_the_datas_width(case):
+    """The path-matrix kernel's interface is the heap kernel's (PR 37): the
+    uint8 chunk as it comes, at 28 columns and at Bosch's 968 (where an
+    int32 copy of a chunk would be 1 GB and more), the last row tile
+    ragged, and the scores as `f32[1, R]`; no array of the program holds
+    the rows as int32 or float32, padded, or as a one-column f32."""
+    exported, shapes = _export_for_tpu(case)
+    (rows, features), dtype = shapes[-1]
+    assert dtype == jnp.uint8
+    text = exported.mlir_module()
+    call, = [ln for ln in text.splitlines()
+             if "@tpu_custom_call" in ln and "_paths_kernel" in ln]
+    operands, result = re.search(
+        r"\}\s*:\s*\((.*)\)\s*->\s*(tensor<[^>]*>)", call).groups()
+    assert operands.startswith(f"tensor<{rows}x{features}xui8>,")
+    assert result == f"tensor<1x{rows}xf32>"
+    for held in ("xi32>", "xf32>", "xbf16>"):
+        assert f"tensor<{rows}x{features}{held}" not in text
+    assert f"tensor<{rows}x1xf32>" not in text
+    assert "ddt:predict:widen" not in text
+    if rows % 4096:
+        padded = -(-rows // 4096) * 4096
+        assert f"tensor<{padded}x" not in text and f"x{padded}x" not in text
+
+
 def test_case_table_covers_the_default_dispatch():
     """Both histogram forms, feature-chunked at the Covertype width, and
     the traversal kernel with and without the optional operands, for one
@@ -110,7 +137,10 @@ def test_case_table_covers_the_default_dispatch():
                    "predict/56f/130x5/missing+cat",
                    # the path-matrix form (node lists)
                    "paths/higgs/500x255leaves", "paths/9x15leaves",
-                   "paths/70f"):
+                   "paths/70f",
+                   # past one K-block of the select; Bosch's width with
+                   # the NaN route in the compare
+                   "paths/129f", "paths/bosch/968f"):
         assert any(needle in n for n in names), (needle, names)
 
 
