@@ -16,9 +16,11 @@ with a NaN bin and learned directions, 26 categorical split one-vs-rest)
 scores 1,000,000 rows, served by the ROUTED form of the traversal kernel.
 And one of LightGBM's Higgs model's shape (500 leaf-wise trees of 255 leaves,
 28 features: a NODE LIST no heap holds) scores 200,000 rows, served by the
-PATH-MATRIX form of the traversal kernel; and small node lists at 28, 129
-and 968 columns, with and without learned NaN directions, hold that kernel
-to its jax.numpy twin and to the node walk in every bit, at 1 to 4,999 rows.
+PATH-MATRIX form of the traversal kernel, its feature select answering two
+nodes a result lane; and small node lists at 28, 64, 65, 129 and 968 columns
+(the last width that packs so, and the first that does not), with and
+without learned NaN directions, hold that kernel to its jax.numpy twin and
+to the node walk in every bit, at 1 to 4,999 rows.
 
 It asserts WHAT ran (the Pallas kernels, compiled: `tpu_custom_call` in both
 lowered programs; histogram resolved to `pallas`, sibling subtraction on; no
@@ -388,6 +390,9 @@ def score_node_list(overrides: dict, rows: int) -> None:
     assert built["deepest_leaf"] > 12, "a tree a heap could have held"
     assert built["trees_per_step"] > 0, "the path-matrix kernel did not serve"
     assert built["trees_per_step"] * built["table_blocks"] >= T, built
+    assert (built["select_nodes_per_lane"],
+            built["path_mxu_tiles_per_tree"]) == (2, 5), \
+        "the select does not answer two nodes a lane"
     assert_compiled_kernel(cfg, ens, rows, "node-list")
     n = min(2_000, rows)
     want = numpy_predict.predict_raw_node_list(ens, Xb[:n], dtype=np.float64)
@@ -400,8 +405,9 @@ def score_node_list(overrides: dict, rows: int) -> None:
 def score_node_list_grid(overrides: dict) -> None:
     """The path-matrix kernel, compiled, against its jax.numpy twin and the
     plain node walk on the shapes' edges: 1, 255, 256, 257 and 4,999 rows
-    (one ragged row tile, and two), 28, 129 and 968 columns (1, 2 and 8
-    K-blocks of the select, the last of 1 and of 72 columns), with and
+    (one ragged row tile, and two), 28, 64, 65, 129 and 968 columns (two
+    nodes a lane of the select up to 64, the mantissa on the VPU at 64;
+    1, 2 and 8 K-blocks, the last of 1 and of 72 columns), with and
     without learned NaN directions; 12 trees of 255 leaves, dyadic leaf
     values, so the three agree in every bit."""
     from ddt_tpu import api
@@ -414,7 +420,7 @@ def score_node_list_grid(overrides: dict) -> None:
     kernel = TrainConfig(n_bins=BINS, backend="tpu", **overrides)
     twin = TrainConfig(n_bins=BINS, backend="tpu", predict_impl="onehot")
     t0 = time.perf_counter()
-    for F in (28, 129, 968):
+    for F in (28, 64, 65, 129, 968):
         for missing in (False, True):
             ens = random_node_list(rng, 12, 255, F, BINS, dyadic=True,
                                    missing=missing, learning_rate=0.5,
@@ -430,15 +436,20 @@ def score_node_list_grid(overrides: dict) -> None:
                     api.predict(ens, Xb, binned=True, raw=True, cfg=twin),
                     want), (F, missing, rows, "jax.numpy form")
                 got = api.predict(ens, Xb, binned=True, raw=True, cfg=kernel)
-                built = root_spans("predict")[-1]["counts"]
+                root = root_spans("predict")[-1]
+                built = root["counts"]
                 assert built["node_list"] == 1 and built[
                     "routing_tables"] == int(missing) and built[
                         "select_k_blocks"] == -(-F // 128), built
+                for span in root["spans"]:      # the model's first call
+                    if span["name"] == "ddt:predict:ensemble":
+                        assert span["counts"]["select_nodes_per_lane"] == (
+                            2 if F <= 64 else 1), span["counts"]
                 assert np.array_equal(got, want), (
                     F, missing, rows, float(np.abs(got - want).max()))
             assert_compiled_kernel(kernel, ens, 4_999,
                                    f"node-list F={F} missing={missing}")
-    timing("node-list grid: 5 row counts x 3 widths x with/without NaN "
+    timing("node-list grid: 5 row counts x 5 widths x with/without NaN "
            "routes, kernel == jax.numpy form == node walk in every bit",
            wall=time.perf_counter() - t0)
 

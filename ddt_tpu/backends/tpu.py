@@ -1986,15 +1986,21 @@ class TPUDevice(DeviceBackend):
                 use_pallas, True, 0, ens.n_features, 1,
                 path_lanes=ce.lanes),
             missing_routes=missing_routes, row_dtype=self.PREDICT_ROW_DTYPE)
-        # The trees that fill the kernel's last block (no node, no leaf of
-        # any length: they add 0) are made here, once a model, and not by
-        # every chunk's program.
+        # What every chunk's program would otherwise make of the tables is
+        # made here, once a model: the select that answers two nodes a lane
+        # with its shifted thresholds (`pack_select`), and the trees that
+        # fill the kernel's last block (no node, no leaf of any length:
+        # they add 0).
+        tables = ce.arrays()
+        if plan.select_nodes_per_lane == 2:
+            tables = (*predict_paths.pack_select(
+                ce.sel, ce.planes, ens.n_features, xp=np), ce.paths)
         fill = ((0, max(0, plan.trees_per_step * plan.table_blocks
                         - ce.n_trees)), (0, 0), (0, 0))
         ens_dev = tuple(
             self._put(np.pad(a, fill, constant_values=v) if fill[0][1]
                       else a, self._named(self.layout.replicated()))
-            for a, v in zip(ce.arrays(), (0, -1.0, 0)))
+            for a, v in zip(tables, (0, -1.0, 0)))
 
         # Bound here: fn0 outlives this call in the stage registry, and
         # must not hold the host copy of the path tables (78 MB at 500

@@ -579,6 +579,8 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
 @op_scope("predict")
 def predict_raw_effective_paths(
     sel: jax.Array,            # bf16 [T, Fp, W] feature one-hot of the nodes
+    #   (or, with its planes, predict_paths.pack_select's where the kernel
+    #   serves and its select answers two nodes a lane)
     planes: jax.Array,         # f32 [T, 8, W] rows: thr, path length, value, up
     paths: jax.Array,          # bf16 [T, W, W] signed path matrix
     Xc: jax.Array,             # [R, F] integer bins
@@ -600,7 +602,7 @@ def predict_raw_effective_paths(
     if Xc.shape[0] == 0:
         return jnp.full((0,), base, jnp.float32)
     if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], 1,
-                          path_lanes=sel.shape[2]):
+                          path_lanes=planes.shape[2]):
         from ddt_tpu.ops import predict_paths
 
         return predict_paths.predict_paths_pallas(

@@ -15,8 +15,35 @@ strategy, OSDI 2020, re-read for the MXU): per tree and tile of rows
 
 so its cost is by the tree's NODES and LEAVES (W lanes of each, a multiple
 of 128), not by its depth: `path_mxu_tiles_per_tree` MXU weight tiles a
-tree, ceil(F/128) x W/128 for v and (W/128)^2 for m: 6 at 255 leaves and
-28 columns, 20 at 968.
+tree, ceil(F/128) x ceil(W / P / 128) for v (P nodes a result lane, next
+paragraph) and (W/128)^2 for m: 5 at 255 leaves and 28 columns (6 at P =
+1), 18 at 512 lanes, 2 at 128, 20 at 968 columns.
+
+TWO NODES A RESULT LANE (`select_nodes_per_lane`, P: from F and W, nothing
+else; the heap kernel's `nodes_per_tile` in this form). The select contracts
+K = F of the weight tile's 128 rows and the kernel is bound by the NUMBER of
+[rows, 128] results it asks the MXU for, so where two copies of the features
+fit the tile (F <= 64) and the tree has 256 node lanes or more, the idle K
+rows carry a second node: the sub-tile's left operand is [x | 256 x | ones]
+(built once a sub-tile for the block's trees), the table `pack_select`'s
+[K2, Wp] (Wp = W/2 up to whole 128 lanes; lane n the one-hot of node n over
+the first copy's rows and of node Wp + n over the second's; 80 x 128 at 28
+columns where [32, 256] was), and one matmul returns
+
+    word[row, n] = M + bin(node n) + 256 bin(node Wp + n)
+
+M = 1.5 x 2^23 (`_MANTISSA`: a K row of the table against the ones, or one
+VPU add where the tile has no 8 rows to spare, F = 57..64) puts the integer
+in the low bits of the f32's mantissa, so the bitcast reads the low byte
+(`& 255`, compared as int32 against thr) without a convert, and the high
+byte compares as the whole f32 word against a threshold `pack_select` has
+moved to it: b > thr is word > M + 256 (thr + 1) - 1, and the NaN route's
+b < up is word < M + 256 up. Both nodes of a lane on one feature is no
+special case, each copy having its own K rows (257 is no bfloat16: hence
+two copies). s comes out [rows, W] as before, the low bytes' lanes then the
+high bytes'; `s @ P`, the accumulate and the fold are the unpacked form's.
+The tables are packed on the host once a model (backends/tpu.py); F > 64 or
+128 lanes is the unpacked program, instruction for instruction.
 
 The select is K-BLOCKED: v = sum_k x_k @ sel_k over ceil(F/128) blocks of
 128 columns (`select_k_blocks`: 1 at 28 columns, 8 at 968), each x_k cut
@@ -43,17 +70,18 @@ nothing that is written), and the scores come out `f32[1, R]`, the rows on
 the lanes: a sub-tile's [SUB_ROWS, W] accumulator is folded to 128 lanes,
 turned over on the XLU and summed down its sublanes.
 
-Layout strategy. The tables are 152 KB a tree at W = 256 (sel 16, planes
-8, P 128), 76 MB for 500 trees: they do not stay in VMEM, and streaming
-them for every 256-row tile as the heap kernel streams its blocks would
-move 0.6 TB a 2M-row chunk. So the ROW TILE is thousands of rows
-(`TILE_ROWS`) and the grid is (row tiles, table blocks): one step holds a
-block of G trees' tables (Mosaic double-buffers the windows: the next
+Layout strategy. The tables are 156 KB a tree at W = 256 (sel 20 packed,
+16 at P = 1; planes 8, P 128), 80 MB for 500 trees: they do not stay in
+VMEM, and streaming them for every 256-row tile as the heap kernel streams
+its blocks would move 0.6 TB a 2M-row chunk. So the ROW TILE is thousands
+of rows (`TILE_ROWS`) and the grid is (row tiles, table blocks): one step
+holds a block of G trees' tables (Mosaic double-buffers the windows: the next
 block's DMA runs under this block's matmuls) and walks the tile in
-sub-tiles of `SUB_ROWS` rows, each against the block's G trees in turn;
+sub-tiles of `SUB_ROWS` rows (1024 where the select answers two nodes a
+lane), each against the block's G trees in turn;
 the [TILE_ROWS, 1] output stays resident over the block axis, zeroed by
 the first block and added to by all. A row tile streams the tables once:
-489 times a 2M-row chunk at 4096 rows, 38 GB against a second of MXU
+489 times a 2M-row chunk at 4096 rows, 39 GB against a second of MXU
 time. G is not a knob (`path_plan`): the most trees whose windows fit the
 VMEM budget beside the row tile's (64, the cap, at 255 leaves and 28
 columns; 11 at 968 columns, where a tree's tables are 639 KB), or the
@@ -62,11 +90,13 @@ trees (500 trees: 10 blocks of 50, and 50 of 10). The other order (table blocks
 outside, each fetched once a chunk) would revisit an output block across
 grid steps that do not follow one another, which Pallas does not keep.
 
-Exactness is the form's own: bins below 256, +-1 and P are bfloat16
-without rounding, the MXU accumulates in float32, every partial sum is an
-integer of at most 255 in magnitude. The leaf reached is the node walk's
-for every (row, tree); scores agree with ops/predict._predict_paths to the
-float32 rounding of a sum in another order (equal on dyadic leaf values).
+Exactness is the form's own: bins below 256, their multiples of 256, M,
++-1 and P are bfloat16 without rounding, the MXU accumulates in float32,
+every partial sum of the resolve is an integer of at most 255 in magnitude
+and of the select one below 2^16, plus M: below 2^24, exact in any order.
+The leaf reached is the node walk's for every (row, tree); scores agree
+with ops/predict._predict_paths to the float32 rounding of a sum in another
+order (equal on dyadic leaf values).
 Interpret mode auto-selects off-TPU, as in predict_pallas.py; dispatch is
 ops/predict.resolve_use_pallas.
 """
@@ -81,7 +111,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ddt_tpu.ops.predict_pallas import _window_bytes, row_operand_dtype
+from ddt_tpu.ops.predict_pallas import (
+    _MANTISSA, _MXU_ROWS, _copy_stride, _window_bytes, row_operand_dtype)
 from ddt_tpu.telemetry.annotations import traced_scope
 from ddt_tpu.utils import device
 
@@ -98,8 +129,16 @@ from ddt_tpu.utils import device
 # lane reduction and the masked add into the [TILE_ROWS, 1] output, once a
 # sub-tile and block) is what more trees a block buy back; a `fori_loop`
 # over the block's trees in place of the unrolled loop: 1,579.8.
+# With two nodes a lane of the select (5 weight tiles a tree: the MXU's
+# time 833.3) the compiler schedules a sub-tile of 512 rows AT the MXU's
+# rate, 16 bundles for every 4 vmatmul, so nothing hides what the hardware
+# stalls on beside it; the same probe (PERF.md section 6, PR 38): sub-tiles
+# of 256 / 512 / 1024 rows 1,085.0 / 893.5 / **862.0**; 2048 (its working
+# set leaves 25 trees a block, so the float32 adds are grouped otherwise:
+# not the unpacked form's bits) 853.2; tile 8192 with 1024: 863.7.
 TILE_ROWS = 4096
 SUB_ROWS = 512
+_SUB_ROWS_PACKED = 1024
 _LANES = 128
 # Scoped VMEM the kernel asks of Mosaic (the default is 16 MiB of the
 # v5e's 128), and what of it `path_plan` fills: the rest is the compiler's.
@@ -120,18 +159,55 @@ def select_k_blocks(n_features: int) -> int:
     return -(-n_features // _LANES)
 
 
-def path_mxu_tiles_per_tree(lanes: int, n_features: int) -> int:
+def select_nodes_per_lane(n_features: int, lanes: int) -> int:
+    """Nodes a result lane of the feature select answers for: 2 where two
+    copies of the features fit the weight tile's 128 K rows (F <= 64, the
+    heap kernel's `nodes_per_tile` rule) and the tree has at least 256
+    node lanes (at 128 the select is one weight tile already), else 1.
+    Read from the input; learned NaN directions do not decide it."""
+    packs = 2 * _copy_stride(n_features) <= _MXU_ROWS and lanes >= 2 * _LANES
+    return 2 if packs else 1
+
+
+def _sub_rows(nodes_per_lane: int) -> int:
+    """Rows a sub-tile of the kernel's walk holds (the constants above)."""
+    return SUB_ROWS if nodes_per_lane == 1 else _SUB_ROWS_PACKED
+
+
+def _mantissa_rows(n_features: int) -> int:
+    """K rows of the packed table that carry _MANTISSA against the left
+    operand's ones: 8 where two copies of the features leave the weight
+    tile that many (F <= 56), else 0 and the VPU adds it."""
+    return 8 if 2 * _copy_stride(n_features) + 8 <= _MXU_ROWS else 0
+
+
+def _select_shape(lanes: int, n_features: int, nodes_per_lane: int) -> tuple:
+    """(K rows, lanes) of a tree's select table as the kernel takes it:
+    [Fp, W], or packed (`pack_select`) [K2, Wp]: two copies of the features
+    and, where they leave the tile 8 rows, the mantissa's, up to whole bf16
+    sublane tiles, over the lanes of the first copy's nodes."""
+    if nodes_per_lane == 1:
+        return -(-n_features // 16) * 16, lanes
+    k2 = 2 * _copy_stride(n_features) + _mantissa_rows(n_features)
+    return -(-k2 // 16) * 16, _lane_pad(lanes // 2)
+
+
+def path_mxu_tiles_per_tree(lanes: int, n_features: int,
+                            nodes_per_lane: int | None = None) -> int:
     """MXU weight tiles (results [rows, 128]) a tree costs a tile of rows:
-    the feature select's, ceil(F/128) x W/128, and the path resolve's,
-    (W/128)^2."""
+    the feature select's, ceil(F/128) x ceil(W / nodes a lane / 128), and
+    the path resolve's, (W/128)^2. `nodes_per_lane` None: the kernel's own
+    (`select_nodes_per_lane`)."""
+    if nodes_per_lane is None:
+        nodes_per_lane = select_nodes_per_lane(n_features, lanes)
     w = lanes // _LANES
-    return select_k_blocks(n_features) * w + w * w
+    return select_k_blocks(n_features) * -(-w // nodes_per_lane) + w * w
 
 
-def _tree_bytes(lanes: int, n_features: int) -> int:
+def _tree_bytes(lanes: int, n_features: int, nodes_per_lane: int = 1) -> int:
     """HBM bytes of one tree's tables: sel bf16, planes f32, P bf16."""
-    fp = -(-n_features // 16) * 16
-    return fp * lanes * 2 + 8 * lanes * 4 + lanes * lanes * 2
+    k, w = _select_shape(lanes, n_features, nodes_per_lane)
+    return k * w * 2 + 8 * lanes * 4 + lanes * lanes * 2
 
 
 class PathPlan(typing.NamedTuple):
@@ -149,6 +225,7 @@ class PathPlan(typing.NamedTuple):
     select_k_blocks: int = 1   # 128-column blocks the select is summed over
     missing_routes: int = 0    # 1: learned NaN directions in the compare
     row_operand_bytes: int = 1  # a bin of the row block as HBM holds it
+    select_nodes_per_lane: int = 1  # 2: the select's lanes answer in pairs
 
     @property
     def blocks(self) -> int:
@@ -172,7 +249,8 @@ class PathPlan(typing.NamedTuple):
 SPAN_COUNTS = ("node_list", "nodes_per_tree", "leaves_per_tree",
                "deepest_leaf", "path_mxu_tiles_per_tree", "trees_per_step",
                "table_blocks", "table_bytes", "select_k_blocks",
-               "missing_routes", "row_operand_bytes")
+               "missing_routes", "row_operand_bytes",
+               "select_nodes_per_lane")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 
 
@@ -193,19 +271,22 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     plan of a model the jax.numpy form scores (its lanes and depth, no
     blocks). `missing_routes` rides along for the spans and decides
     nothing here."""
-    tiles = path_mxu_tiles_per_tree(lanes, n_features)
+    # The jax.numpy form takes the select as the model compiles it.
+    pack = select_nodes_per_lane(n_features, lanes) if served else 1
+    tiles = path_mxu_tiles_per_tree(lanes, n_features, pack)
     row_bytes = row_operand_dtype(row_dtype).itemsize
-    said = (select_k_blocks(n_features), int(missing_routes), row_bytes)
+    said = (select_k_blocks(n_features), int(missing_routes), row_bytes,
+            pack)
     if not served:
         return PathPlan(1, lanes, lanes, deepest_leaf, tiles, 0, 0, 0, 0,
                         *said)
-    fp = -(-n_features // 16) * 16
-    per_tree = (_window_bytes(fp, lanes) // 2          # bf16: half of f32
+    fp, sel_lanes = _select_shape(lanes, n_features, pack)
+    per_tree = (_window_bytes(fp, sel_lanes) // 2      # bf16: half of f32
                 + _window_bytes(8, lanes)
                 + _window_bytes(lanes, lanes) // 2)
     fixed = (2 * TILE_ROWS * _lane_pad(n_features) * row_bytes
              + _window_bytes(1, TILE_ROWS)
-             + SUB_ROWS * (_lane_pad(fp) * _SUB_ROW_BIN_BYTES
+             + _sub_rows(pack) * (_lane_pad(fp) * _SUB_ROW_BIN_BYTES
                            + lanes * _SUB_ROW_LANE_BYTES))
     most = min(n_trees, _MAX_TREES_PER_STEP,
                max(0, (_VMEM_BUDGET_BYTES - fixed) // per_tree))
@@ -220,8 +301,8 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
             key=lambda g: (-(-n_trees // g) * g, -g))
     blocks = -(-n_trees // g)
     return PathPlan(1, lanes, lanes, deepest_leaf, tiles, g, blocks,
-                    blocks * g * _tree_bytes(lanes, n_features), TILE_ROWS,
-                    *said)
+                    blocks * g * _tree_bytes(lanes, n_features, pack),
+                    TILE_ROWS, *said)
 
 
 def predict_paths_fits(lanes: int, n_features: int,
@@ -233,16 +314,61 @@ def predict_paths_fits(lanes: int, n_features: int,
                      row_dtype=row_dtype).trees_per_step > 0
 
 
+def pack_select(sel, planes, n_features: int, xp=jnp) -> tuple:
+    """The select table and the thresholds of `select_nodes_per_lane` 2,
+    from the tables a model compiles (models/tree.CompiledNodeList): sel
+    [T, K2, Wp] bf16 (`_select_shape`), lane n the one-hot of node n over
+    the first copy's K rows and of node Wp + n over the second's, and where
+    the tile has the 8 rows to spare _MANTISSA in the first of them; planes
+    with the thresholds of the second copy's nodes (lanes Wp and up of rows
+    0 and 3) moved to their byte of the word the matmul returns, M + bin_a +
+    256 bin_b: b > thr is word > M + 256 (thr + 1) - 1, b < up is word <
+    M + 256 up; +BIG stays +BIG. `xp` numpy: on the host, once a model
+    (backends/tpu._build_paths_fn); jax.numpy: inside the caller's program."""
+    wp = _select_shape(sel.shape[2], n_features, 2)[1]
+    stride = _copy_stride(n_features)
+
+    def copy(a):        # [T, F, <= Wp] -> [T, stride, Wp]
+        return xp.pad(a, ((0, 0), (0, stride - n_features),
+                          (0, wp - a.shape[2])))
+
+    parts = [copy(sel[:, :n_features, :wp]), copy(sel[:, :n_features, wp:])]
+    if _mantissa_rows(n_features):
+        parts.append(xp.pad(
+            xp.full((sel.shape[0], 1, wp), _MANTISSA, sel.dtype),
+            ((0, 0), (0, 7), (0, 0))))
+    packed = xp.concatenate(parts, axis=1)
+    packed = xp.pad(packed, ((0, 0), (0, -packed.shape[1] % 16), (0, 0)))
+
+    def shifted(row, plus_one):
+        at = xp.clip(row[:, :, wp:], -1.0, 256.0) + plus_one
+        return xp.concatenate(
+            [row[:, :, :wp], xp.where(row[:, :, wp:] < 2.0 ** 29,
+                                      _MANTISSA + 256.0 * at - plus_one,
+                                      row[:, :, wp:])], axis=2)
+
+    planes = xp.concatenate(
+        [shifted(planes[:, 0:1], 1.0), planes[:, 1:3],
+         shifted(planes[:, 3:4], 0.0), planes[:, 4:]], axis=1)
+    return packed, planes
+
+
 def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
                   n_trees: int, n_feat: int, missing_routes: bool):
     """One row tile against one block of `n_trees` trees: the block's share
     of every row's margin. x_ref [TILE_ROWS, F] uint8 or int32, as HBM
     holds the rows (in the last tile, whatever lies past row R); sel
-    [G, Fp, W] bf16, planes [G, 8, W] f32, paths [G, W, W] bf16; out
-    [1, TILE_ROWS] f32, the rows on the lanes, resident over the block
+    [G, Fp, W] bf16, or `pack_select`'s [G, K2, Wp] (fewer lanes than the
+    planes: two nodes a lane), planes [G, 8, W] f32, paths [G, W, W] bf16;
+    out [1, TILE_ROWS] f32, the rows on the lanes, resident over the block
     axis (grid axis 1)."""
     tile_rows = x_ref.shape[0]
-    fp, lanes = sel_ref.shape[1], sel_ref.shape[2]
+    fp, lanes = sel_ref.shape[1], planes_ref.shape[2]
+    wp = sel_ref.shape[2]
+    packed = wp < lanes
+    sub_rows = _sub_rows(2 if packed else 1)
+    stride = _copy_stride(n_feat)
+    ones_in_tile = bool(_mantissa_rows(n_feat))
     k_starts = range(0, n_feat, _LANES)
 
     @pl.when(pl.program_id(1) == 0)
@@ -250,34 +376,61 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     def sub_tile(j, carry):
-        r0 = pl.multiple_of(j * SUB_ROWS, SUB_ROWS)
+        r0 = pl.multiple_of(j * sub_rows, sub_rows)
         # The sub-tile's K-blocks, widened once for the block's trees: the
         # bf16 copy lives in VMEM alone.
         xs = []
         for k0 in k_starts:
             k1, kp = min(k0 + _LANES, n_feat), min(k0 + _LANES, fp)
-            xf = x_ref[pl.ds(r0, SUB_ROWS), k0:k1].astype(
+            xf = x_ref[pl.ds(r0, sub_rows), k0:k1].astype(
                 jnp.int32).astype(jnp.float32)
+            if packed:
+                # [x | 256 x | ones]: the copies from the K rows of the
+                # table's (a multiple of 8 each), the ones against the
+                # row that adds _MANTISSA inside the MXU.
+                gap = [jnp.zeros((sub_rows, stride - n_feat), jnp.float32)
+                       ] * (stride > n_feat)
+                ones = [jnp.ones((sub_rows, 8), jnp.float32)] * ones_in_tile
+                xf = jnp.concatenate([xf, *gap, xf * 256.0, *gap, *ones],
+                                     axis=1)
+                k1 = 2 * stride + 8 * ones_in_tile
             if kp > k1:     # K to whole bf16 sublane tiles
                 xf = jnp.concatenate(
-                    [xf, jnp.zeros((SUB_ROWS, kp - k1), jnp.float32)], axis=1)
+                    [xf, jnp.zeros((sub_rows, kp - k1), jnp.float32)], axis=1)
             xs.append(xf.astype(jnp.bfloat16))            # [S, <= 128]
 
         def tree(g, acc):
             rows = planes_ref[g]                          # [8, W]
-            # bf16 operands (bins <= 255 and the 0/1 one-hot are exact),
-            # f32 accumulator: the v5e's VPU has no bf16 compare.
+            # bf16 operands (bins <= 255, their multiples of 256, the 0/1
+            # one-hot and _MANTISSA are exact), f32 accumulator: the v5e's
+            # VPU has no bf16 compare.
             v = None
             for k0, xk in zip(k_starts, xs):
                 part = jax.lax.dot_general(
                     xk, sel_ref[g, k0:k0 + xk.shape[1], :],
                     (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [S, W]
+                    preferred_element_type=jnp.float32)   # [S, Wp]
                 v = part if v is None else v + part
-            right = v > rows[0:1, :]
-            if missing_routes:      # not at the NaN bin where NaN goes left
-                right &= v < rows[3:4, :]
-            s = jnp.where(right, 1.0, -1.0).astype(jnp.bfloat16)
+            if not packed:
+                right = [v > rows[0:1, :]]
+                if missing_routes:  # not at the NaN bin where NaN goes left
+                    right[0] &= v < rows[3:4, :]
+            else:
+                # v = M + bin_a + 256 bin_b, the exact integer: node Wp + n
+                # (b) compares as the whole word against its shifted
+                # threshold, node n (a) as the low byte of the mantissa,
+                # which the bitcast reads without a convert.
+                if not ones_in_tile:        # no K row left for it
+                    v = v + _MANTISSA
+                low = pltpu.bitcast(v, jnp.int32) & 255
+                high = v[:, :lanes - wp]
+                right = [low > rows[0:1, :wp].astype(jnp.int32),
+                         high > rows[0:1, wp:]]
+                if missing_routes:
+                    right[0] &= low < rows[3:4, :wp].astype(jnp.int32)
+                    right[1] &= high < rows[3:4, wp:]
+            s = jnp.concatenate([jnp.where(r, 1.0, -1.0) for r in right],
+                                axis=1).astype(jnp.bfloat16)
             m = jax.lax.dot_general(
                 s, paths_ref[g], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [S, W]
@@ -285,7 +438,7 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
 
         # Unrolled: the compiler runs a tree's matmuls under the one
         # before's compares (a fori_loop here took 35% longer).
-        acc = jnp.zeros((SUB_ROWS, lanes), jnp.float32)
+        acc = jnp.zeros((sub_rows, lanes), jnp.float32)
         for g in range(n_trees):
             acc = tree(g, acc)
         # The lanes summed with the rows on the lanes: fold to one vreg
@@ -293,16 +446,16 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
         fold = acc[:, :_LANES]
         for l0 in range(_LANES, lanes, _LANES):
             fold = fold + acc[:, l0:l0 + _LANES]
-        out_ref[:, pl.ds(r0, SUB_ROWS)] += jnp.sum(fold.T, axis=0,
+        out_ref[:, pl.ds(r0, sub_rows)] += jnp.sum(fold.T, axis=0,
                                                    keepdims=True)
         return carry
 
-    jax.lax.fori_loop(0, tile_rows // SUB_ROWS, sub_tile, 0)
+    jax.lax.fori_loop(0, tile_rows // sub_rows, sub_tile, 0)
 
 
 def predict_paths_pallas(
-    sel: jax.Array,            # bf16 [T, Fp, W]
-    planes: jax.Array,         # f32 [T, 8, W]
+    sel: jax.Array,            # bf16 [T, Fp, W], or pack_select's
+    planes: jax.Array,         # f32 [T, 8, W]      (then its planes too)
     paths: jax.Array,          # bf16 [T, W, W]
     Xc: jax.Array,             # [R, F] integer bins, uint8 as api.predict's
     *,
@@ -312,11 +465,18 @@ def predict_paths_pallas(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Raw margins [R]: Pallas twin of ops/predict._predict_paths. Jit-safe.
-    interpret=None auto-selects the Pallas interpreter off-TPU."""
+    interpret=None auto-selects the Pallas interpreter off-TPU. Where the
+    select answers two nodes a lane (`select_nodes_per_lane`) a backend
+    hands over `pack_select`'s tables, made once a model; the tables as
+    the model compiles them are packed here, by every call's program."""
     if interpret is None:
         interpret = device.platform() != "tpu"
-    T, fp, lanes = sel.shape
+    T, _, lanes = planes.shape
     R, F = Xc.shape
+    if select_nodes_per_lane(F, lanes) == 2 and sel.shape[2] == lanes:
+        with traced_scope("predict:tables"):
+            sel, planes = pack_select(sel, planes, F)
+    fp, sel_lanes = sel.shape[1:]
     # The rows as the kernel takes them: uint8 and int32 as they come, any
     # other integer cast in XLA first (the heap kernel's rule).
     row_dtype = row_operand_dtype(Xc.dtype)
@@ -339,7 +499,8 @@ def predict_paths_pallas(
     with traced_scope("predict:tables"):
         sel_b, paths_b = jnp.pad(sel, t_fill), jnp.pad(paths, t_fill)
         planes_b = jnp.pad(planes, t_fill, constant_values=-1.0)
-    tile_rows = min(TILE_ROWS, -(-R // SUB_ROWS) * SUB_ROWS)
+    sub_rows = _sub_rows(plan.select_nodes_per_lane)
+    tile_rows = min(TILE_ROWS, -(-R // sub_rows) * sub_rows)
     n_tiles = -(-R // tile_rows)
 
     def table_block(rows, cols):
@@ -347,7 +508,8 @@ def predict_paths_pallas(
                             memory_space=pltpu.VMEM)
 
     cost = pl.CostEstimate(
-        flops=2 * n_tiles * tile_rows * n_blocks * g * lanes * (fp + lanes),
+        flops=2 * n_tiles * tile_rows * n_blocks * g * (
+            fp * sel_lanes + lanes * lanes),
         bytes_accessed=n_tiles * (
             tile_rows * (F * row_dtype.itemsize + 4) + plan.table_bytes),
         transcendentals=0,
@@ -361,7 +523,7 @@ def predict_paths_pallas(
             grid=(n_tiles, n_blocks),
             in_specs=[pl.BlockSpec((tile_rows, F), lambda i, b: (i, 0),
                                    memory_space=pltpu.VMEM),
-                      table_block(fp, lanes), table_block(8, lanes),
+                      table_block(fp, sel_lanes), table_block(8, lanes),
                       table_block(lanes, lanes)],
             out_specs=pl.BlockSpec((1, tile_rows), lambda i, b: (0, i),
                                    memory_space=pltpu.VMEM),

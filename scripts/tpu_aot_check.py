@@ -9,6 +9,14 @@ before each use of the chip:
 
     JAX_PLATFORMS=cpu python scripts/tpu_aot_check.py            # everything
     JAX_PLATFORMS=cpu python scripts/tpu_aot_check.py --only hist
+    JAX_PLATFORMS=cpu python scripts/tpu_aot_check.py --only bosch --digest \
+        [--tree <another checkout>]
+
+`--digest` prints, for every whole program, the SHA-1 of its optimized HLO
+(source metadata and the kernels' serialized bodies cut) and of its Mosaic
+modules without debug locations: two checkouts that print the same pair
+run the same program (`--tree`: take `ddt_tpu` from that checkout; the case
+tables stay this script's).
 
 A compile is not a run: it says nothing of results, of memory at run time
 or of speed. chip_smoke.py is the run.
@@ -33,7 +41,10 @@ decide the exit code.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import hashlib
 import os
+import re
 import sys
 import time
 import typing
@@ -177,13 +188,22 @@ def _random_node_list(n_trees, n_leaves, features, missing=False):
 
 def _paths_case(rows, features, n_trees, n_leaves, missing=False):
     """The path-matrix kernel (ops/predict_paths.py) over a node list's
-    compiled tables, the rows as api.predict hands them over (uint8)."""
+    compiled tables as a backend hands them over (the select packed on the
+    host where it answers two nodes a lane: up to 64 columns, 256 lanes
+    and more), the rows as api.predict does (uint8)."""
     def build():
         import jax.numpy as jnp
+        import numpy as np
 
         from ddt_tpu.ops import predict_paths
 
         ce = _random_node_list(n_trees, n_leaves, features, missing).compile()
+        tables = ce.arrays()
+        # (`--tree` may name a checkout from before the packed select)
+        per_lane = getattr(predict_paths, "select_nodes_per_lane", None)
+        if per_lane and per_lane(features, ce.lanes) == 2:
+            tables = (*predict_paths.pack_select(ce.sel, ce.planes, features,
+                                                 xp=np), ce.paths)
 
         def fn(sel, planes, paths, Xc):
             return predict_paths.predict_paths_pallas(
@@ -191,7 +211,7 @@ def _paths_case(rows, features, n_trees, n_leaves, missing=False):
                 learning_rate=ce.learning_rate, base=ce.base_score,
                 missing_routes=missing, interpret=False)
 
-        shapes = [(a.shape, a.dtype) for a in ce.arrays()]
+        shapes = [(a.shape, a.dtype) for a in tables]
         shapes.append(((rows, features), jnp.uint8))
         return fn, shapes
 
@@ -304,6 +324,15 @@ def kernel_cases() -> list:
         # last row tile.
         KernelCase("paths/129f/12x255leaves", True,
                    _paths_case(4_999, 129, 12, 255)),
+        # The packed select's edges: 64 columns (no K row left for the
+        # mantissa: one VPU add), 65 (one node a lane), 512 lanes with the
+        # NaN route on both bytes of the word.
+        KernelCase("paths/64f/12x255leaves/nan", True,
+                   _paths_case(4_999, 64, 12, 255, missing=True)),
+        KernelCase("paths/65f/12x255leaves", True,
+                   _paths_case(4_999, 65, 12, 255)),
+        KernelCase("paths/56f/12x500leaves/nan", True,
+                   _paths_case(4_999, 56, 12, 500, missing=True)),
         KernelCase("paths/bosch/968f/20x255leaves/nan", True,
                    _paths_case(BOSCH["chunk_rows"], BOSCH["features"], 20,
                                255, missing=True)),
@@ -468,6 +497,38 @@ def program_cases(topo_devices) -> list:
 
 # ------------------------------------------------------------------ #
 
+@contextlib.contextmanager
+def _mosaic_modules(into: list):
+    """Collect the text of every Mosaic module lowered inside, without
+    debug locations (the serialized body in the HLO carries the checkout's
+    path and line numbers)."""
+    from jax._src import tpu_custom_call as tcc
+
+    lower = tcc._lower_mosaic_module_to_asm
+
+    def recording(module, **kw):
+        into.append(module.operation.get_asm(enable_debug_info=False))
+        return lower(module, **kw)
+
+    tcc._lower_mosaic_module_to_asm = recording
+    try:
+        yield
+    finally:
+        tcc._lower_mosaic_module_to_asm = lower
+
+
+def _digest(hlo: str, mosaic: list) -> str:
+    """`hlo <sha1> mosaic <sha1>` of a compiled program: the optimized HLO
+    with the location tables, the source metadata and the kernels'
+    serialized bodies cut, and the kernels' Mosaic modules as text."""
+    hlo = re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n", hlo,
+                 flags=re.S)                    # the location tables
+    hlo = re.sub(r', metadata=\{[^{}]*\}', "", hlo)
+    hlo = re.sub(r'"body":"[^"]*"', '"body":""', hlo)
+    sha = lambda text: hashlib.sha1(text.encode()).hexdigest()[:16]
+    return f"hlo {sha(hlo)} mosaic {sha(chr(10).join(mosaic))}"
+
+
 def _first_line(e: BaseException) -> str:
     msg = " ".join(str(e).split())
     return f"{type(e).__name__}: {msg[:400]}"
@@ -477,7 +538,13 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--only", default="",
                     help="run only the cases whose name contains this")
+    ap.add_argument("--digest", action="store_true",
+                    help="print each whole program's SHA-1s (HLO, Mosaic)")
+    ap.add_argument("--tree", default="",
+                    help="take ddt_tpu from this checkout, not this one")
     args = ap.parse_args(argv)
+    if args.tree:
+        sys.path.insert(0, os.path.abspath(args.tree))
 
     import jax
     from jax.experimental import topologies
@@ -517,10 +584,14 @@ def main(argv=None) -> int:
             t0 = time.perf_counter()
             try:
                 fn, sds, want = build()
-                txt = fn.lower(*sds).compile().as_text()
+                mosaic = []
+                with _mosaic_modules(mosaic):
+                    txt = fn.lower(*sds).compile().as_text()
                 missing = [w for w in want if w not in txt]
                 verdict = ("compiled" if not missing else
                            f"REFUSED  compiled text lacks {missing}")
+                if args.digest:
+                    verdict += "  " + _digest(txt, mosaic)
                 failed_default += bool(missing)
             except Exception as e:  # the report IS the failure message
                 verdict = "REFUSED  " + _first_line(e)

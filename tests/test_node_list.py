@@ -586,6 +586,7 @@ def test_backend_entry_says_which_kernel_serves():
               if sp["name"] == "ddt:predict:ensemble"][-1]["counts"]
     assert counts["node_list"] == 1 and counts["trees"] == 20
     assert counts["nodes_per_tree"] == counts["leaves_per_tree"] == 256
-    assert counts["path_mxu_tiles_per_tree"] == 6
+    assert counts["path_mxu_tiles_per_tree"] == 5
+    assert counts["select_nodes_per_lane"] == 2
     assert counts["trees_per_step"] * counts["table_blocks"] >= 20
     assert counts["table_bytes"] >= 20 * 256 * 256 * 2

@@ -278,24 +278,28 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
         leaves_per_tree=128, deepest_leaf=2, path_mxu_tiles_per_tree=2,
         trees_per_step=3 * served, table_blocks=1 * served,
         table_bytes=3 * (16 * 128 * 2 + 8 * 128 * 4 + 128 * 128 * 2) * served,
-        select_k_blocks=1, missing_routes=0, row_operand_bytes=1)
+        select_k_blocks=1, missing_routes=0, row_operand_bytes=1,
+        select_nodes_per_lane=1)           # 128 lanes: one tile already
     assert counts["bytes"] == counts["table_bytes"] or not served
     assert root["counts"]["select_k_blocks"] == 1
 
     # blocks of trees stream once a row tile
     plan = predict_paths.path_plan(500, 256, 28, 17)
     assert plan.node_list == 1 and plan.deepest_leaf == 17
-    assert plan.path_mxu_tiles_per_tree == 6
+    # two nodes a lane of the select (F <= 64, 256 lanes): 1 + 4 tiles,
+    # the select table [80, 128] where [32, 256] was
+    assert (plan.select_nodes_per_lane, plan.path_mxu_tiles_per_tree) == (2, 5)
     assert plan.trees_per_step * plan.table_blocks >= 500
     assert plan.blocks == plan.table_blocks > 1
     assert plan.table_bytes == plan.trees_per_step * plan.table_blocks * (
-        32 * 256 * 2 + 8 * 256 * 4 + 256 * 256 * 2)
+        80 * 128 * 2 + 8 * 256 * 4 + 256 * 256 * 2)
 
     # Bosch's width: 8 K-blocks of the select, 20 weight tiles a tree, the
     # row tile charged at the rows' own width, no filler tree in 50 blocks
     wide = predict_paths.path_plan(500, 256, 968, missing_routes=True)
     assert (wide.select_k_blocks, wide.path_mxu_tiles_per_tree,
-            wide.missing_routes, wide.row_operand_bytes) == (8, 20, 1, 1)
+            wide.missing_routes, wide.row_operand_bytes,
+            wide.select_nodes_per_lane) == (8, 20, 1, 1, 1)
     assert wide.trees_per_step * wide.table_blocks == 500
     assert wide.table_bytes == 500 * (976 * 256 * 2 + 8 * 256 * 4
                                       + 256 * 256 * 2)

@@ -109,6 +109,35 @@ def test_path_kernel_crosses_hbm_at_the_datas_width(case):
         assert f"tensor<{padded}x" not in text and f"x{padded}x" not in text
 
 
+@pytest.mark.parametrize("case", PATH_CASES, ids=lambda c: c.name)
+def test_path_kernel_takes_the_select_the_rule_packs(case):
+    """Up to 64 columns and from 256 node lanes on, the kernel's select
+    table is `pack_select`'s, [K2, W/2] over two copies of the features
+    (and the mantissa's rows while the tile has them), handed over by the
+    caller: nothing of the program is traced under `predict:tables`. Past
+    64 columns or at 128 lanes it is [Fp, W], as the model compiles it."""
+    from ddt_tpu.ops import predict_paths
+
+    exported, shapes = _export_for_tpu(case)
+    (trees, k_rows, sel_lanes), _ = shapes[0]
+    (_, _, lanes), _ = shapes[1]
+    (_, features), _ = shapes[-1]
+    per_lane = predict_paths.select_nodes_per_lane(features, lanes)
+    assert per_lane == (2 if features <= 64 and lanes >= 256 else 1)
+    assert (k_rows, sel_lanes) == predict_paths._select_shape(
+        lanes, features, per_lane)
+    if per_lane == 2:
+        stride = -(-features // 8) * 8
+        assert sel_lanes == -(-lanes // 256) * 128
+        assert k_rows == min(128, -(-(2 * stride + 8) // 16) * 16)
+    text = exported.mlir_module()
+    call, = [ln for ln in text.splitlines()
+             if "@tpu_custom_call" in ln and "_paths_kernel" in ln]
+    assert f"tensor<{trees}x{k_rows}x{sel_lanes}xbf16>" in call
+    # (the three pads of no filler tree are traced there, and are nothing)
+    assert "ddt:predict:tables/concatenate" not in text
+
+
 def test_case_table_covers_the_default_dispatch():
     """Both histogram forms, feature-chunked at the Covertype width, and
     the traversal kernel with and without the optional operands, for one
@@ -138,6 +167,10 @@ def test_case_table_covers_the_default_dispatch():
                    # the path-matrix form (node lists)
                    "paths/higgs/500x255leaves", "paths/9x15leaves",
                    "paths/70f",
+                   # the packed select's edges: the mantissa on the VPU
+                   # (64 columns), one node a lane (65), 512 lanes routed
+                   "paths/64f/12x255leaves/nan", "paths/65f",
+                   "paths/56f/12x500leaves/nan",
                    # past one K-block of the select; Bosch's width with
                    # the NaN route in the compare
                    "paths/129f", "paths/bosch/968f"):
