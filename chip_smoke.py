@@ -20,7 +20,12 @@ PATH-MATRIX form of the traversal kernel, its feature select answering two
 nodes a result lane; and small node lists at 28, 64, 65, 129 and 968 columns
 (the last width that packs so, and the first that does not), with and
 without learned NaN directions, hold that kernel to its jax.numpy twin and
-to the node walk in every bit, at 1 to 4,999 rows.
+to the node walk in every bit, at 1 to 4,999 rows. And one of CatBoost's
+Epsilon model's shape (8000 OBLIVIOUS trees of depth 6, 2000 dense columns)
+scores 300,000 rows, served by the oblivious form of the traversal kernel (6
+select columns a tree, never the 63-node expansion); small oblivious
+ensembles at depth 1, 6 and 8 and 28, 129 and 2000 columns hold that kernel
+to its twin and to the bit walk in every bit.
 
 It asserts WHAT ran (the Pallas kernels, compiled: `tpu_custom_call` in both
 lowered programs; histogram resolved to `pallas`, sibling subtraction on; no
@@ -63,6 +68,7 @@ SCORE_CHECK_ROWS = 50_000
 MC_ROUNDS, MC_ROWS = 500, 100_000      # the 7-class scoring phase
 ROUTED_ROWS = 1_000_000                # the routed scoring phase
 LEAFWISE_ROWS = 200_000                # the node-list scoring phase
+OBLIVIOUS_ROWS = 300_000               # the oblivious phase: three chunks
 SCORE_TOL = dict(rtol=3e-4, atol=3e-4)      # as __graft_entry__'s oracle check
 # Chip-vs-oracle training parity: the bounds the earlier chip runs measured
 # inside (0.9871 agreement, 0.0024 AUC). Never bitwise
@@ -454,19 +460,120 @@ def score_node_list_grid(overrides: dict) -> None:
            wall=time.perf_counter() - t0)
 
 
+def score_oblivious(overrides: dict, rows: int) -> None:
+    """CatBoost's Epsilon model's shape through `api.predict`: 8000 random
+    oblivious trees of depth 6 over 2000 dense columns. Asserts that the
+    OBLIVIOUS form of the traversal kernel served it by the auto dispatch
+    (`oblivious` 1 and `select_columns_per_tree` 6 on its spans: the layout
+    as it is, never the 63-node expansion; 63 groups of 128 trees streamed)
+    and holds a sample of rows to the plain bit walk
+    (reference/numpy_predict, in float64)."""
+    from ddt_tpu import api
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.models.tree import random_oblivious
+    from ddt_tpu.reference import numpy_predict
+    from ddt_tpu.telemetry.annotations import root_spans
+
+    T, D, F = 8000, 6, 2000
+    rng = np.random.default_rng(SEED)
+    ens = random_oblivious(rng, T, D, F, BINS, scale=0.1)
+    Xb = rng.integers(0, BINS, size=(rows, F), dtype=np.uint8)
+    cfg = TrainConfig(n_bins=BINS, backend="tpu", **overrides)
+    comp = Compiles()
+    t0 = time.perf_counter()
+    scores = api.predict(ens, Xb, binned=True, raw=True, cfg=cfg)
+    wall = time.perf_counter() - t0
+    timing(f"oblivious predict, {rows} rows x {T} trees x depth {D} x {F} "
+           "columns, first call", wall=wall, **comp.split(wall))
+    assert scores.shape == (rows,) and scores.dtype == np.float32, \
+        (scores.shape, scores.dtype)
+    assert np.isfinite(scores).all(), "non-finite scores"
+    root = root_spans("predict")[-1]
+    built = {s["name"]: s["counts"] for s in root["spans"]}[
+        "ddt:predict:ensemble"]
+    said = {k: root["counts"][k] for k in (
+        "oblivious", "select_columns_per_tree", "select_k_blocks",
+        "routing_tables", "tables_streamed_bytes", "chunks")}
+    say(f"oblivious predict: ddt:predict:ensemble {built}; root {said}")
+    assert built["oblivious"] == root["counts"]["oblivious"] == 1, built
+    assert built["select_columns_per_tree"] == D, "an expansion served"
+    assert built["trees_per_step"] == 128, "the oblivious kernel did not serve"
+    assert (built["table_blocks"], built["select_k_blocks"]) == (63, 16), built
+    assert built["oblivious_mxu_tiles_per_tree"] == 0.75, built
+    assert_compiled_kernel(cfg, ens, rows, "oblivious")
+    n = min(2_000, rows)
+    want = numpy_predict.predict_raw_oblivious(ens, Xb[:n], dtype=np.float64)
+    gap = float(np.abs(scores[:n] - want).max())
+    say(f"oblivious scores: {n} rows against reference/numpy_predict "
+        f"(float64), max |diff| = {gap:.2e} (<= 5e-5)")
+    assert gap <= 5e-5, gap
+
+
+def score_oblivious_grid(overrides: dict) -> None:
+    """The oblivious kernel, compiled, against its jax.numpy twin and the
+    plain bit walk on the shapes' edges: depth 1, 6 and 8; 28, 129 and 2000
+    columns (1, 2 and 16 K-blocks of the select, the second of one column);
+    1, 1025 and 4,999 rows (one ragged row tile, two and three); 130
+    trees (two groups, the last with 126 filler lanes), dyadic leaf values,
+    so the three agree in every bit."""
+    from ddt_tpu import api
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.models.tree import random_oblivious
+    from ddt_tpu.ops.predict_oblivious import predict_oblivious_fits
+    from ddt_tpu.reference import numpy_predict
+    from ddt_tpu.telemetry.annotations import root_spans
+
+    rng = np.random.default_rng(SEED)
+    kernel = TrainConfig(n_bins=BINS, backend="tpu", **overrides)
+    twin = TrainConfig(n_bins=BINS, backend="tpu", predict_impl="onehot")
+    t0 = time.perf_counter()
+    for D in (1, 6, 8):
+        for F in (28, 129, 2000):
+            ens = random_oblivious(rng, 130, D, F, BINS, dyadic=True,
+                                   scale=0.5, bias=0.25)
+            fits = bool(overrides) or predict_oblivious_fits(D, F)
+            for rows in (1, 1_025, 4_999):
+                Xb = rng.integers(0, BINS, size=(rows, F), dtype=np.uint8)
+                want = numpy_predict.predict_raw_oblivious(
+                    ens, Xb, np.float64).astype(np.float32)
+                # the twin first: the program whose stages are read after
+                # this phase is the last one built, the kernel's
+                assert np.array_equal(
+                    api.predict(ens, Xb, binned=True, raw=True, cfg=twin),
+                    want), (D, F, rows, "jax.numpy form")
+                got = api.predict(ens, Xb, binned=True, raw=True, cfg=kernel)
+                built = root_spans("predict")[-1]["counts"]
+                assert built["oblivious"] == 1 and built[
+                    "select_columns_per_tree"] == D and built[
+                        "select_k_blocks"] == -(-F // 128), built
+                # depth 8 at 2000 columns is past the kernel's VMEM rule:
+                # the auto dispatch hands it to the jax.numpy form
+                assert (built["tables_streamed_bytes"] > 0) == fits, built
+                assert np.array_equal(got, want), (
+                    D, F, rows, float(np.abs(got - want).max()))
+            if fits:
+                assert_compiled_kernel(kernel, ens, 4_999,
+                                       f"oblivious depth={D} F={F}")
+    timing("oblivious grid: 3 depths x 3 widths x 3 row counts, kernel == "
+           "jax.numpy form == bit walk in every bit",
+           wall=time.perf_counter() - t0)
+
+
 def check_device_stages() -> None:
     """Every instruction the scoring programs traced from this package is
     under a named stage (telemetry/annotations.device_stages: the newest
-    heap model's program, the routed one, and the node list's, each at the
-    chunk loop's own shape). An `unscoped` instruction with a source line
-    in `ddt_tpu/` is device work that no per-layer metric of the benchmark
-    would read: it fails here, before a benchmark run."""
+    heap model's program, the routed one, the node list's and the oblivious
+    ensemble's, each at the chunk loop's own shape). An `unscoped`
+    instruction with a source line in `ddt_tpu/` is device work that no
+    per-layer metric of the benchmark would read: it fails here, before a
+    benchmark run."""
     from ddt_tpu.telemetry.annotations import UNSCOPED, device_stages
 
     t0 = time.perf_counter()
     stages = device_stages()
     for program in ("jit_predict_raw_effective",
-                    "jit_predict_raw_effective_paths"):
+                    "jit_predict_raw_effective_paths",
+                    "jit_predict_raw_effective_oblivious"):
         held = stages[program]
         lost = {name: e for name, e in held.items()
                 if e["stage"] == UNSCOPED
@@ -475,7 +582,7 @@ def check_device_stages() -> None:
         by_stage = collections.Counter(e["stage"] for e in held.values())
         say(f"device stages of {program}: " + ", ".join(
             f"{k} {v}" for k, v in sorted(by_stage.items())))
-    timing("device_stages(), two programs", wall=time.perf_counter() - t0)
+    timing("device_stages(), three programs", wall=time.perf_counter() - t0)
 
 
 def parity_against_reference(overrides: dict) -> None:
@@ -663,6 +770,9 @@ def main(argv=None) -> int:
     score_node_list(overrides, LEAFWISE_ROWS // 100 if args.rehearse
                     else LEAFWISE_ROWS)
     score_node_list_grid(overrides)
+    score_oblivious(overrides, OBLIVIOUS_ROWS // 100 if args.rehearse
+                    else OBLIVIOUS_ROWS)
+    score_oblivious_grid(overrides)
     check_device_stages()
     parity_against_reference(overrides)
     barrier_experiment(be, Xb)

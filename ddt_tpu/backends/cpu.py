@@ -22,7 +22,7 @@ import numpy as np
 
 from ddt_tpu.backends.base import DeviceBackend, HostTree
 from ddt_tpu.config import TrainConfig
-from ddt_tpu.models.tree import NodeListEnsemble, TreeEnsemble
+from ddt_tpu.models.tree import TreeEnsemble
 from ddt_tpu.reference import numpy_trainer as ref
 
 
@@ -190,9 +190,10 @@ class CPUDevice(DeviceBackend):
         # `compiled` is accepted for interface parity (the serving tier
         # passes it unconditionally); the CPU traversal reads the
         # ensemble heap directly, so there is nothing to seed.
-        if self._native_traverse is None or isinstance(ens,
-                                                       NodeListEnsemble):
-            # a node list's own NumPy walk (the C++ traversal reads heaps)
+        if self._native_traverse is None or not isinstance(ens,
+                                                           TreeEnsemble):
+            # a node list's or an oblivious ensemble's own NumPy walk (the
+            # C++ traversal reads heaps)
             return ens.predict_raw(Xb, binned=True)
         # C++ batch traversal (the CPU twin of the device gather+compare
         # path); routing-flag derivation lives in ONE place

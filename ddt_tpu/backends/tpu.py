@@ -43,7 +43,8 @@ import numpy as np
 
 from ddt_tpu.backends.base import DeviceBackend, HostTree
 from ddt_tpu.config import TrainConfig
-from ddt_tpu.models.tree import CompiledNodeList, TreeEnsemble
+from ddt_tpu.models.tree import (CompiledNodeList, CompiledOblivious,
+                                 TreeEnsemble)
 from ddt_tpu.ops import grad as grad_ops
 from ddt_tpu.ops import grow as grow_ops
 from ddt_tpu.ops import histogram as hist_ops
@@ -1562,6 +1563,16 @@ class TPUDevice(DeviceBackend):
     # piece's fixed cost (about 18 ms) is hidden under the compute, and a
     # piece of more than one chunk keeps the device slices, so the peak.
     PREDICT_UPLOAD_CHUNKS = 2
+    # ... and the bytes a LEADING piece holds at most (one chunk at least):
+    # up to 128 columns two chunks are 112-216 MB and every piece is two,
+    # as it was; at 968 and 2000 columns a chunk is 254 and 262 MB, and the
+    # first piece is ONE chunk, and so is the second, which has only the
+    # first's compute to arrive under. The first piece's transfer is the
+    # one a call exposes whole, and on a shared host what a call's wall
+    # varies by: of 1.44 s calls over 2000 columns one in twenty took
+    # 87-117 ms longer in the evening, all of it the first 524 MB arriving
+    # late (PERF.md section 6, PR 39).
+    PREDICT_FIRST_PIECE_BYTES = 256 * 1024 * 1024
     # Device-resident CompiledEnsemble slots per backend instance: each
     # entry pins the model's pushed-down node tables on device (~MBs for a
     # 1000-tree model) across predict calls. Small because backend
@@ -1668,7 +1679,9 @@ class TPUDevice(DeviceBackend):
             return out[:R]
         # Single chip: the batch goes up ONCE (uint8 — 4x less
         # host→device traffic than int32), in pieces of
-        # PREDICT_UPLOAD_CHUNKS chunks, and chunks are sliced on device.
+        # PREDICT_UPLOAD_CHUNKS chunks (the leading ones of fewer where
+        # they would pass PREDICT_FIRST_PIECE_BYTES), and chunks are sliced
+        # on device.
         # A piece's transfer starts when the chunks of the piece before
         # it have been dispatched and that piece has arrived, so it runs
         # under their compute, and only the first piece's is exposed: 46
@@ -1696,13 +1709,20 @@ class TPUDevice(DeviceBackend):
         counts["branch"] = "chunks"
         resident = isinstance(Xb, jax.Array)
         piece = R if resident else chunk * self.PREDICT_UPLOAD_CHUNKS
+        first = piece if resident else chunk * max(1, min(
+            self.PREDICT_UPLOAD_CHUNKS, self.PREDICT_FIRST_PIECE_BYTES
+            // (chunk * Xb.shape[1] * Xb.dtype.itemsize)))
+        # The pieces' rows, bounds[p] .. bounds[p + 1]: the leading
+        # PREDICT_UPLOAD_CHUNKS pieces of `first` rows, the rest of `piece`.
+        lead = min(R, first * self.PREDICT_UPLOAD_CHUNKS)
+        bounds = [*range(0, lead, first), *range(lead, R, piece), R]
         Xh = Xb if resident else np.ascontiguousarray(Xb)
 
         def upload(p):
             if resident:
                 with phase_span("predict:upload", piece=p, bytes=0):
                     return Xb
-            part = Xh[p * piece:(p + 1) * piece]
+            part = Xh[bounds[p]:bounds[p + 1]]
             with phase_span("predict:upload", piece=p, bytes=part.nbytes):
                 if p:   # one transfer at a time; the device has p - 1's chunks
                     pieces[-1].block_until_ready()  # ddtlint: disable=host-sync
@@ -1723,11 +1743,11 @@ class TPUDevice(DeviceBackend):
             tele_counters.record_h2d(Xb.nbytes)
         outs = []
         for k, i in enumerate(starts):
-            at = i % piece
+            at = i - bounds[len(pieces) - 1]
             with phase_span("predict:dispatch", chunk=k):
                 outs.append(fn(*ens_dev, pieces[-1][at:at + chunk]))
                 outs[-1].copy_to_host_async()
-            if at + chunk == piece and i + chunk < R:
+            if i + chunk == bounds[len(pieces)] and i + chunk < R:
                 pieces.append(upload(len(pieces)))
         # Shape and dtype are the dispatched arrays' (no sync). The places
         # below touch the result's pages for the first time, under the
@@ -1904,6 +1924,8 @@ class TPUDevice(DeviceBackend):
             tree_chunk=64)
         if isinstance(ce, CompiledNodeList):
             return self._build_paths_fn(ens, ce)
+        if isinstance(ce, CompiledOblivious):
+            return self._build_oblivious_fn(ens, ce)
         impl_req = self.cfg.predict_impl
         lut = None
         resolved = "f32"
@@ -2015,6 +2037,42 @@ class TPUDevice(DeviceBackend):
 
         self._stage_scoring_program(
             predict_ops.predict_raw_effective_paths, fn0, ens_dev,
+            ens.n_features)
+        return self._row_sharded(fn0, 3, 1), ens_dev, "f32", 1, plan
+
+    def _build_oblivious_fn(self, ens, ce: CompiledOblivious):
+        """_build_predict_fn for an OBLIVIOUS ensemble: its group tables up,
+        and the oblivious scoring program (ops/predict.predict_raw_
+        effective_oblivious: the Pallas kernel where the dispatch rule
+        takes it, else the jax.numpy form). The plan is an
+        ops/predict_oblivious.ObliviousPlan."""
+        from ddt_tpu.ops import predict_oblivious
+
+        if self.cfg.predict_impl in ("lut", "lut4"):
+            log.warning(
+                "predict_impl=%r: the quantized tiers have no oblivious "
+                "form; the f32 oblivious form serves", self.cfg.predict_impl)
+        use_pallas = self._use_pallas
+        plan = predict_oblivious.oblivious_plan(
+            ce.n_trees, ce.depth, ens.n_features,
+            served=predict_ops.resolve_use_pallas(
+                use_pallas, True, 0, ens.n_features, 1,
+                oblivious_depth=ce.depth),
+            row_dtype=self.PREDICT_ROW_DTYPE)
+        ens_dev = tuple(self._put(a, self._named(self.layout.replicated()))
+                        for a in ce.arrays())
+        # Bound here: fn0 outlives this call in the stage registry, and
+        # must not hold the host copy of the tables (197 MB at 8000 trees
+        # x 2000 columns).
+        scale, bias = ce.scale, ce.bias
+
+        def fn0(sel, thr, leaf, Xc,
+                entry=predict_ops.predict_raw_effective_oblivious):
+            return entry(sel, thr, leaf, Xc, scale=scale, bias=bias,
+                         use_pallas=use_pallas)
+
+        self._stage_scoring_program(
+            predict_ops.predict_raw_effective_oblivious, fn0, ens_dev,
             ens.n_features)
         return self._row_sharded(fn0, 3, 1), ens_dev, "f32", 1, plan
 

@@ -98,8 +98,9 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     (time.perf_counter_ns): token, ensemble, upload, dispatch, fetch,
     place, and with them counts that are no times: the traversal
     kernel's table plan as the model's `ensemble` span recorded it
-    (ops/predict_pallas.PHASES_COUNTS and, for a node-list model,
-    ops/predict_paths.PHASES_COUNTS; all 0: that kernel does not serve
+    (ops/predict_pallas.PHASES_COUNTS and, for a node-list or an
+    oblivious model, ops/predict_paths.PHASES_COUNTS or
+    ops/predict_oblivious.PHASES_COUNTS; all 0: that kernel does not serve
     the model) and `tables_streamed_bytes`, the root spans' sum over the
     calls. docs/OBSERVABILITY.md has the table of what each means. None
     when no such call ran: the NumPy backend and raw-threshold scoring
@@ -109,7 +110,7 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     roots = [r for r in root_spans("predict") if r["start"] >= since_ns]
     if not roots:
         return None
-    from ddt_tpu.ops import predict_paths
+    from ddt_tpu.ops import predict_oblivious, predict_paths
     from ddt_tpu.ops.predict_pallas import PHASES_COUNTS
 
     ms = dict.fromkeys(
@@ -122,9 +123,10 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
                 ms[step] += (s["end"] - s["start"]) / 1e6
             if step == "ensemble":
                 # a heap model's span has the heap kernel's counts, a
-                # node list's the path form's
-                plan = {k: s["counts"][k] for k in (
-                    PHASES_COUNTS + predict_paths.PHASES_COUNTS)
+                # node list's the path form's, an oblivious model's its own
+                plan = {k: s["counts"][k] for k in dict.fromkeys(
+                    PHASES_COUNTS + predict_paths.PHASES_COUNTS
+                    + predict_oblivious.PHASES_COUNTS)
                     if k in s["counts"]}
     return {**{k: round(v, 3) for k, v in ms.items()}, **plan,
             "tables_streamed_bytes": sum(

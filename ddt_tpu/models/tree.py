@@ -324,6 +324,7 @@ class TreeEnsemble:
 
     def to_dict(self) -> dict:
         return {
+            "layout": np.bytes_(b"heap"),
             "feature": self.feature,
             "threshold_bin": self.threshold_bin,
             "threshold_raw": self.threshold_raw,
@@ -396,8 +397,11 @@ class TreeEnsemble:
         atomic_savez(path, compressed=True, deterministic=True, **d)
 
     @staticmethod
-    def load(path: str) -> "TreeEnsemble | NodeListEnsemble":
-        """The saved ensemble, in the layout it was saved in."""
+    def load(path: str
+             ) -> "TreeEnsemble | NodeListEnsemble | ObliviousEnsemble":
+        """The saved ensemble, in the layout it was saved in: whichever of
+        the three its `layout` key names (`ensemble_from_dict`), so a
+        caller that needs a heap asks `isinstance` of what comes back."""
         with np.load(path) as d:
             return ensemble_from_dict(dict(d))
 
@@ -958,12 +962,18 @@ def _importances(ens, nodes: np.ndarray, kind: str) -> np.ndarray:
     return (counts / tot if tot > 0 else counts).astype(np.float32)
 
 
-def ensemble_from_dict(d: dict) -> "TreeEnsemble | NodeListEnsemble":
-    """The ensemble a saved artifact holds, in the layout it was saved in
-    (`layout` is written by the node list alone; a heap has no such key)."""
-    if "layout" in d and bytes(d["layout"]) == b"node_list":
-        return NodeListEnsemble.from_dict(d)
-    return TreeEnsemble.from_dict(d)
+def ensemble_from_dict(
+        d: dict) -> "TreeEnsemble | NodeListEnsemble | ObliviousEnsemble":
+    """The ensemble a saved artifact holds, in the layout it was saved in.
+    Every `to_dict` writes the layout's name under `layout` (`LAYOUTS`);
+    two older forms are still read: a heap saved before it had the key (no
+    `layout` at all), and the node list, which always wrote it."""
+    name = bytes(d["layout"]).decode() if "layout" in d else "heap"
+    if name not in LAYOUTS:
+        raise ValueError(
+            f"saved ensemble names the layout {name!r}; this program reads "
+            f"{sorted(LAYOUTS)}")
+    return LAYOUTS[name].from_dict(d)
 
 
 def _refuse_routes(where: str, *, categories: bool,
@@ -1122,6 +1132,335 @@ class CompiledNodeList:
             sel=sel, planes=planes, paths=paths, missing_bin_value=nan_bin)
 
 
+# ---------------------------------------------------------------------- #
+# The OBLIVIOUS ensemble: the third ensemble layout
+# ---------------------------------------------------------------------- #
+
+# Trees a group of the compiled oblivious tables holds: one lane each.
+OBLIVIOUS_GROUP = 128
+# A split no bin passes (uint8 bins end at 255): the filler of a tree
+# shallower than the ensemble's depth, its bit never set.
+OBLIVIOUS_NEVER = 255
+
+
+@dataclasses.dataclass
+class ObliviousEnsemble:
+    """A boosted ensemble of OBLIVIOUS (symmetric) trees, CatBoost's own:
+    every node of a level asks the same question, so tree t of depth D is D
+    splits and 2^D leaf values, and a row's leaf is a D-bit number,
+
+        idx_t(x) = sum_d [bin(x)[split_feature[t, d]] > split_bin[t, d]] << d
+
+    (the FIRST split is the LOW bit; raw rows: `x > split_raw[t, d]`), and
+
+        score(x) = scale * sum_t leaf_value[t, idx_t(x)] + bias.
+
+    `bin > split_bin` sets the bit where the heap's `bin <= threshold_bin`
+    goes left: the repository's one split rule. No missing-value route, no
+    category split and one output column: the import
+    (`models/catboost_io.py`) refuses a model that needs another by name.
+    A tree shallower than D carries never-true splits in its HIGH bits
+    (`OBLIVIOUS_NEVER`, raw +inf) and zeros in the leaves they would reach.
+    The trainer writes heaps; an oblivious ensemble is an import or
+    hand-built. `to_heap` / `to_node_list` expand it for the tests' sake
+    (63 nodes where this layout holds 6 splits): no scoring path does."""
+
+    split_feature: np.ndarray  # int32  [T, D]
+    split_bin: np.ndarray      # int32  [T, D] bit d set where bin > this
+    leaf_value: np.ndarray     # float32 [T, 2^D]
+    n_features: int
+    scale: float = 1.0
+    bias: float = 0.0
+    loss: str = "logloss"      # logloss | mse
+    n_bins: int = 0
+    split_raw: np.ndarray | None = None    # float32 [T, D] raw borders
+    # float32 [F, n_bins - 1], +inf past a feature's last border: the
+    # model's own border lists, whose ranks `split_bin` holds (an import's;
+    # `bin_mapper` bins raw rows by them).
+    borders: np.ndarray | None = None
+
+    # What the other layouts answer for, so that scoring entry points ask
+    # one question of any of the three.
+    has_cat_splits = False
+    cat_features = None
+    missing_bin = False
+    default_left = None
+    n_classes = 2
+    has_bin_thresholds = True
+
+    def __post_init__(self):
+        T, D = self.split_feature.shape
+        if self.loss == "softmax":
+            raise ValueError(
+                "an oblivious ensemble scores ONE output column: vector "
+                "leaves (several classes) are not supported in this layout")
+        if self.split_bin.shape != (T, D) or self.leaf_value.shape != (
+                T, 1 << D) or D < 1:
+            raise ValueError(
+                f"an oblivious ensemble of {T} trees x depth {D} needs "
+                f"split_bin [{T}, {D}] and leaf_value [{T}, {1 << D}], got "
+                f"{self.split_bin.shape} and {self.leaf_value.shape}")
+        if T and not (0 <= int(self.split_feature.min())
+                      and int(self.split_feature.max()) < self.n_features):
+            raise ValueError("a split's feature lies outside 0 .. "
+                             f"{self.n_features - 1}")
+
+    @property
+    def n_trees(self) -> int:
+        return int(self.split_feature.shape[0])
+
+    @property
+    def depth(self) -> int:
+        return int(self.split_feature.shape[1])
+
+    max_depth = depth              # what `cli inspect` prints of a heap
+
+    @property
+    def has_raw_thresholds(self) -> bool:
+        return self.split_raw is not None
+
+    @property
+    def learning_rate(self) -> float:
+        return self.scale
+
+    @property
+    def base_score(self) -> float:
+        return self.bias
+
+    @property
+    def live_splits(self) -> np.ndarray:
+        """bool [T, D]: the splits a bin can pass (not a shallow tree's
+        filler)."""
+        return self.split_bin < OBLIVIOUS_NEVER
+
+    @property
+    def n_splits(self) -> int:
+        return int(self.live_splits.sum())
+
+    def cache_token(self) -> str:
+        """Content digest of what the device scoring program depends on
+        (the compiled-ensemble cache key, as `TreeEnsemble.cache_token`)."""
+        h = hashlib.sha1(b"oblivious")
+        for a in (self.split_feature, self.split_bin, self.leaf_value):
+            h.update(np.ascontiguousarray(a).tobytes())
+        h.update(repr((self.scale, self.bias, self.loss,
+                       self.n_features)).encode())
+        return h.hexdigest()
+
+    def compile(self, tree_chunk: int = 64) -> "CompiledOblivious":
+        """Host-side compiled scoring tables (see CompiledOblivious;
+        `tree_chunk` is the heap layout's and means nothing here)."""
+        return CompiledOblivious.build(self)
+
+    # ------------------------------------------------------------------ #
+
+    def _leaf_np(self, X: np.ndarray, binned: bool) -> np.ndarray:
+        """Leaf index per (tree, row), int64 [T, R]: the bit walk."""
+        if not binned and not self.has_raw_thresholds:
+            raise ValueError(
+                "Ensemble has no raw-value thresholds; predict on binned "
+                "data with binned=True")
+        thr = self.split_bin if binned else self.split_raw
+        Xc = X.astype(np.int32) if binned else X.astype(np.float32)
+        idx = np.zeros((self.n_trees, X.shape[0]), np.int64)
+        for d in range(self.depth):
+            fv = Xc[:, self.split_feature[:, d]].T              # [T, R]
+            idx |= (fv > thr[:, d:d + 1]).astype(np.int64) << d
+        return idx
+
+    def predict_raw(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
+        """Raw (margin) scores [R], float32; the rows in blocks, so that the
+        [T, rows] index of a block stays near 128 MB."""
+        X = np.asarray(X)
+        block = max(1, (1 << 24) // max(1, self.n_trees))
+        total = np.empty(X.shape[0], np.float32)
+        for i in range(0, X.shape[0], block):
+            leaf = self._leaf_np(X[i:i + block], binned)
+            total[i:i + block] = np.take_along_axis(
+                self.leaf_value, leaf, axis=1).sum(axis=0)
+        return (self.bias + np.float32(self.scale) * total
+                ).astype(np.float32)
+
+    def predict(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
+        """Probability predictions (or raw values for mse)."""
+        from ddt_tpu.utils.metrics import predict_proba_np
+
+        return predict_proba_np(self.predict_raw(X, binned=binned),
+                                self.loss)
+
+    def bin_mapper(self):
+        """The BinMapper of the model's own border lists (`borders`): a
+        row's bin is the number of its feature's borders below the value,
+        so `x > border_k` is `bin(x) > k` EXACTLY (the argument of
+        `lightgbm_io.threshold_bin_mapper`)."""
+        from ddt_tpu.data.quantizer import BinMapper
+
+        if self.borders is None:
+            raise ValueError("this oblivious ensemble carries no borders")
+        return BinMapper(edges=self.borders, n_bins=self.n_bins)
+
+    # ------------------------------------------------------------------ #
+
+    def to_heap(self) -> TreeEnsemble:
+        """The same trees as full heaps of depth D (2^D - 1 nodes where
+        this layout holds D splits): a TEST ORACLE, so that the heap
+        scorers give a second opinion. The heap's root decides the leaf
+        position's HIGH bit, so level l asks split D - 1 - l."""
+        T, D = self.split_feature.shape
+        ens = empty_ensemble(T, D, self.n_features, self.scale, self.bias,
+                             self.loss, n_bins=self.n_bins)
+        raw = self.split_raw if self.has_raw_thresholds else np.zeros(
+            (T, D), np.float32)
+        for level in range(D):
+            lo, hi = (1 << level) - 1, (1 << (level + 1)) - 1
+            d = D - 1 - level
+            ens.feature[:, lo:hi] = self.split_feature[:, d:d + 1]
+            ens.threshold_bin[:, lo:hi] = self.split_bin[:, d:d + 1]
+            ens.threshold_raw[:, lo:hi] = raw[:, d:d + 1]
+        ens.is_leaf[:, (1 << D) - 1:] = True
+        ens.leaf_value[:, (1 << D) - 1:] = self.leaf_value
+        ens.has_raw_thresholds = self.has_raw_thresholds
+        return ens
+
+    def to_node_list(self) -> "NodeListEnsemble":
+        """`to_heap()` as a node list: the third opinion, a test oracle."""
+        return NodeListEnsemble.from_heap(self.to_heap())
+
+    def feature_importances(self, kind: str = "split") -> np.ndarray:
+        """As `TreeEnsemble.feature_importances`, over the live splits; no
+        gain is recorded in this layout, so "gain" is all zeros (what a
+        heap saved before gains were recorded answers: `cli inspect` falls
+        back to the split counts)."""
+        if kind not in ("split", "gain"):
+            raise ValueError(f"unknown importance kind {kind!r}")
+        counts = np.bincount(self.split_feature[self.live_splits],
+                             minlength=self.n_features).astype(np.float64)
+        tot = counts.sum()
+        return (counts / tot if tot > 0 and kind == "split"
+                else counts * 0).astype(np.float32)
+
+    def dump_text(self, tree: int) -> str:
+        """One tree as text: its splits, low bit first, and its leaves."""
+        t = int(tree)
+        lines = []
+        for d in range(self.depth):
+            raw = (f" (> {self.split_raw[t, d]:.6g})"
+                   if self.has_raw_thresholds else "")
+            lines.append(f"bit {d}: f{self.split_feature[t, d]} > bin "
+                         f"{self.split_bin[t, d]}{raw}")
+        lines.append("leaves: " + " ".join(
+            f"{v:+.6f}" for v in self.leaf_value[t]))
+        return "\n".join(lines)
+
+    def to_dict(self) -> dict:
+        d = dict(
+            layout=np.bytes_(b"oblivious"),
+            split_feature=self.split_feature, split_bin=self.split_bin,
+            leaf_value=self.leaf_value,
+            n_features=np.int64(self.n_features),
+            scale=np.float64(self.scale), bias=np.float64(self.bias),
+            loss=np.bytes_(self.loss.encode()),
+            n_bins=np.int64(self.n_bins))
+        if self.split_raw is not None:
+            d["split_raw"] = self.split_raw
+        if self.borders is not None:
+            d["borders"] = self.borders
+        return d
+
+    @staticmethod
+    def from_dict(d: dict) -> "ObliviousEnsemble":
+        return ObliviousEnsemble(
+            split_feature=np.asarray(d["split_feature"], np.int32),
+            split_bin=np.asarray(d["split_bin"], np.int32),
+            leaf_value=np.asarray(d["leaf_value"], np.float32),
+            n_features=int(d["n_features"]), scale=float(d["scale"]),
+            bias=float(d["bias"]), loss=bytes(d["loss"]).decode(),
+            n_bins=int(d["n_bins"]),
+            split_raw=(np.asarray(d["split_raw"], np.float32)
+                       if "split_raw" in d else None),
+            borders=(np.asarray(d["borders"], np.float32)
+                     if "borders" in d else None))
+
+    save = TreeEnsemble.save       # to_dict, the manifest, one atomic npz
+
+
+def random_oblivious(rng, n_trees: int, depth: int, n_features: int,
+                     n_bins: int = 255, dyadic: bool = False,
+                     **meta) -> ObliviousEnsemble:
+    """A random oblivious ensemble for tests, chip_smoke.py and the compile
+    check: features uniform, borders uniform over the ranks 0 .. n_bins-2,
+    leaf values N(0, 1), or eighths in -2..2 (`dyadic`: sums of them round
+    nowhere)."""
+    leaves = (rng.integers(-16, 17, (n_trees, 1 << depth)) / 8.0 if dyadic
+              else rng.standard_normal((n_trees, 1 << depth)))
+    return ObliviousEnsemble(
+        split_feature=rng.integers(0, n_features, (n_trees, depth),
+                                   dtype=np.int32),
+        split_bin=rng.integers(0, n_bins - 1, (n_trees, depth),
+                               dtype=np.int32),
+        leaf_value=leaves.astype(np.float32), n_features=n_features,
+        n_bins=n_bins, **meta)
+
+
+@dataclasses.dataclass(frozen=True)
+class CompiledOblivious:
+    """An oblivious model's BINNED scoring tables (ops/predict_oblivious.py
+    has the equations): the trees in groups of `OBLIVIOUS_GROUP`, a tree a
+    lane, a split a lane tile,
+
+        sel  [G, D, Fp, 128] bf16  one-hot of split d's feature, a tree a
+                                   lane (Fp: F up to whole bf16 sublane
+                                   tiles of 16)
+        thr  [G, Dp, 128]    f32   split d's bin (Dp: D up to whole
+                                   sublane tiles of 8)
+        leaf [G, 2^D, 128]   f32   the leaf values, leaf i of a tree in
+                                   row i of its lane
+
+    A lane past the last tree holds no one-hot, the threshold +BIG (its
+    bits never set) and leaves of 0: it adds 0. Built ONCE per model
+    version on the host; backends keep them device-resident under `token`
+    (the same cache as the other layouts')."""
+
+    token: str
+    scale: float
+    bias: float
+    loss: str
+    n_trees: int
+    depth: int
+    sel: np.ndarray
+    thr: np.ndarray
+    leaf: np.ndarray
+
+    n_classes_out = 1
+
+    def arrays(self) -> tuple:
+        return (self.sel, self.thr, self.leaf)
+
+    @staticmethod
+    def build(ens: ObliviousEnsemble) -> "CompiledOblivious":
+        import ml_dtypes
+
+        T, D = ens.split_feature.shape
+        G = max(1, -(-T // OBLIVIOUS_GROUP))
+        Fp = -(-ens.n_features // 16) * 16
+        pad = G * OBLIVIOUS_GROUP - T
+        sel = np.zeros((G, D, Fp, OBLIVIOUS_GROUP), ml_dtypes.bfloat16)
+        g, lane = np.divmod(np.arange(T), OBLIVIOUS_GROUP)
+        for d in range(D):
+            sel[g, d, ens.split_feature[:, d], lane] = 1.0
+        thr = np.full((G * OBLIVIOUS_GROUP, -(-D // 8) * 8), 2.0 ** 30,
+                      np.float32)
+        thr[:T, :D] = ens.split_bin
+        leaf = np.pad(ens.leaf_value.astype(np.float32), ((0, pad), (0, 0)))
+        return CompiledOblivious(
+            token=ens.cache_token(), scale=float(ens.scale),
+            bias=float(ens.bias), loss=ens.loss, n_trees=T, depth=D, sel=sel,
+            thr=np.ascontiguousarray(
+                thr.reshape(G, OBLIVIOUS_GROUP, -1).transpose(0, 2, 1)),
+            leaf=np.ascontiguousarray(
+                leaf.reshape(G, OBLIVIOUS_GROUP, -1).transpose(0, 2, 1)))
+
+
 def empty_ensemble(
     n_trees: int,
     max_depth: int,
@@ -1154,3 +1493,8 @@ def empty_ensemble(
         cat_features=(np.asarray(cat_features, np.int32)
                       if cat_features else None),
     )
+
+
+# A saved ensemble's `layout` key (every `to_dict` writes it) -> its class.
+LAYOUTS = {"heap": TreeEnsemble, "node_list": NodeListEnsemble,
+           "oblivious": ObliviousEnsemble}

@@ -57,6 +57,20 @@ bfloat16 and every sum an integer below 2^8: exact with float32
 accumulation in any order; leaf values stay float32. The plain jax.numpy
 form below is the fallback and what a CPU runs; the Pallas kernel is
 `ops/predict_paths.py`, dispatched by the same rule.
+
+A FIFTH entry serves the third layout, the OBLIVIOUS ensemble
+(models/tree.ObliviousEnsemble: CatBoost's symmetric trees, D splits and
+2^D leaf values a tree): `predict_raw_effective_oblivious`. The trees go in
+groups of 128, a tree a lane; per group
+
+    v_d[r, j] = bin[r, split_feature[j, d]]   = X[rows, F] @ sel_d[F, 128]
+    idx[r, j] = sum_d (v_d[r, j] > thr_d[j]) << d     the first split is
+                                                      the LOW bit
+    score[r] += sum_j leaf[idx[r, j], j]
+
+with no sum that crosses lanes before the last. The jax.numpy form below is
+the fallback and what a CPU runs; the Pallas kernel is
+`ops/predict_oblivious.py`, dispatched by the same rule.
 """
 
 from __future__ import annotations
@@ -239,7 +253,8 @@ def traverse(
 def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
                        n_features: int, n_classes: int,
                        optional_operands: int = 2,
-                       path_lanes: int = 0) -> bool:
+                       path_lanes: int = 0,
+                       oblivious_depth: int = 0) -> bool:
     """The ONE home of the pallas-vs-one-hot predict dispatch rule.
 
     None = auto: the Pallas traversal kernel is taken when the data is
@@ -251,7 +266,8 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
     `path_lanes` says which LAYOUT asks: 0 a heap ensemble, else a node
     list of that many lanes a tree (`max_depth` and `optional_operands`
     mean nothing there), whose kernel is ops/predict_paths.py and whose
-    guard is that kernel's own.
+    guard is that kernel's own; `oblivious_depth` an oblivious ensemble
+    of that depth (ops/predict_oblivious.py, `predict_oblivious_fits`).
     Explicit True
     demands the kernel (binned data required — raises otherwise; off-TPU
     it runs in interpret mode, the test contract); explicit False always
@@ -261,7 +277,12 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
     from ddt_tpu.ops import predict_pallas
 
     if use_pallas is None:
-        if path_lanes:
+        if oblivious_depth:
+            from ddt_tpu.ops import predict_oblivious
+
+            fits = predict_oblivious.predict_oblivious_fits(
+                oblivious_depth, n_features)
+        elif path_lanes:
             from ddt_tpu.ops import predict_paths
 
             fits = predict_paths.predict_paths_fits(path_lanes, n_features)
@@ -611,6 +632,86 @@ def predict_raw_effective_paths(
     return _predict_paths(sel, planes, paths, Xc,
                           learning_rate=learning_rate, base=base,
                           missing_routes=missing_routes)
+
+
+# Rows a step of the jax.numpy oblivious form takes at most (the float32
+# copy of its rows is 64 MB at 2000 columns), and the elements of its
+# [rows, 2^D, 128] leaf one-hot a step may hold (4,096 rows at depth 6).
+_OBLIVIOUS_ROW_CHUNK, _OBLIVIOUS_ONEHOT = 8_192, 1 << 25
+
+
+def _predict_oblivious(sel, thr, leaf, Xc, *, scale, bias):
+    """The oblivious form (module docstring) in plain jax.numpy: a group of
+    128 trees a step of the scan, rows in chunks of _OBLIVIOUS_ROW_CHUNK.
+    The operands are widened to float32 (XLA's CPU backend has no bf16 x
+    bf16 = f32 dot); every value is one bfloat16 holds, so a TPU's default
+    one-pass matmul of them is exact too."""
+    G, D, Fp, W = sel.shape
+    R, F = Xc.shape
+    row_chunk = min(R, _OBLIVIOUS_ROW_CHUNK,
+                    max(512, _OBLIVIOUS_ONEHOT // (W << D)))
+    n_rc = -(-R // row_chunk)
+    with traced_scope("predict:widen"):
+        Xp = jnp.pad(Xc.astype(jnp.float32),
+                     ((0, n_rc * row_chunk - R), (0, Fp - F))
+                     ).reshape(n_rc, row_chunk, Fp)
+
+    def row_body(_, xrc):
+        def group_body(acc, args):
+            a, t, lv = args
+            with traced_scope("predict:traverse"):
+                v = jnp.einsum("rf,dfj->drj", xrc, a.astype(jnp.float32),
+                               preferred_element_type=jnp.float32)
+                idx = jnp.zeros((row_chunk, W), jnp.int32)
+                for d in range(D):
+                    idx |= (v[d] > t[d:d + 1]).astype(jnp.int32) << d
+            with traced_scope("predict:accumulate"):
+                leaves = jnp.arange(lv.shape[0], dtype=jnp.int32)
+                hit = idx[:, None, :] == leaves[None, :, None]  # [Rc, 2^D, W]
+                acc = acc + jnp.sum(jnp.where(hit, lv[None], 0.0),
+                                    axis=(1, 2))
+            return acc, None
+
+        acc, _ = jax.lax.scan(group_body,
+                              jnp.zeros((row_chunk,), jnp.float32),
+                              (sel, thr, leaf))
+        return None, acc
+
+    with traced_scope("predict:traverse"):
+        _, accs = jax.lax.scan(row_body, None, Xp)
+    with traced_scope("predict:accumulate"):
+        return bias + scale * accs.reshape(n_rc * row_chunk)[:R]
+
+
+@costed("predict", phase="predict")
+@functools.partial(jax.jit, static_argnames=("scale", "bias", "use_pallas"))
+@op_scope("predict")
+def predict_raw_effective_oblivious(
+    sel: jax.Array,            # bf16 [G, D, Fp, 128] split d's feature one-hot
+    thr: jax.Array,            # f32 [G, Dp, 128] split d's bin
+    leaf: jax.Array,           # f32 [G, 2^D, 128] leaf values, a tree a lane
+    Xc: jax.Array,             # [R, F] integer bins
+    scale: float,
+    bias: float,
+    use_pallas: bool | None = None,
+) -> jax.Array:
+    """Raw margins [R] of an oblivious ensemble from its compiled tables
+    (models/tree.CompiledOblivious): by the Pallas kernel
+    (ops/predict_oblivious.py) where `resolve_use_pallas` says so and by
+    `_predict_oblivious` otherwise. Binned rows only, which the kernel
+    takes at the width they come in (uint8 from api.predict: nothing is
+    widened in XLA)."""
+    if not jnp.issubdtype(Xc.dtype, jnp.integer):
+        raise ValueError("the oblivious form scores binned (integer) rows")
+    if Xc.shape[0] == 0:
+        return jnp.full((0,), bias, jnp.float32)
+    if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], 1,
+                          oblivious_depth=sel.shape[1]):
+        from ddt_tpu.ops import predict_oblivious
+
+        return predict_oblivious.predict_oblivious_pallas(
+            sel, thr, leaf, Xc, scale=scale, bias=bias)
+    return _predict_oblivious(sel, thr, leaf, Xc, scale=scale, bias=bias)
 
 
 def predict_proba(raw: jax.Array, loss: str) -> jax.Array:

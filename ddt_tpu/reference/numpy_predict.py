@@ -58,6 +58,26 @@ this walk, all of it arithmetic and none of it routing:
   the lanes, adds the blocks, and multiplies by the learning rate once at
   the end: float32 rounding of a sum in another order, not bitwise.
   `tests/test_node_list.py` holds `api.predict` to it.
+
+The OBLIVIOUS ensemble (models/tree.ObliviousEnsemble; CatBoost's symmetric
+trees; `leaf_of_rows_oblivious`, `predict_raw_oblivious`): tree t of depth D
+is D splits and 2^D leaf values. Split d sets BIT d of the row's leaf index
+when `bin[split_feature[t, d]] > split_bin[t, d]` (the first split is the
+low bit; the same rule as above: `<=` goes left, leaves the bit clear), and
+the tree scores `leaf_value[t, index]`. Raw score [rows] = bias + scale x
+the sum over the trees. No missing-value route, no category split, one
+output column. Where the device path (`ops/predict.py`'s jax.numpy form and
+its kernel `ops/predict_oblivious.py`) departs, all of it arithmetic and
+none of it routing:
+
+- A split's column is selected by a one-hot matrix product, not indexed;
+  bins below 256 and 0/1 are exact in bfloat16 and the sum has one term.
+- The trees go in groups of 128, a tree a lane; the lanes past the last
+  tree hold splits no bin passes and leaves of 0.
+- A group's leaf values are summed lane by lane and then over the lanes,
+  the groups are added, and the scale is applied once at the end: float32
+  rounding of a sum in another order, not bitwise.
+  `tests/test_oblivious.py` holds `api.predict` to it.
 """
 
 from __future__ import annotations
@@ -129,3 +149,24 @@ def predict_raw_node_list(ens, Xb: np.ndarray,
         leaf = leaf_of_rows_node_list(ens, t, Xb)
         out += lr * ens.leaf_value[t].astype(dtype)[leaf]
     return out
+
+
+def leaf_of_rows_oblivious(ens, t: int, Xb: np.ndarray) -> np.ndarray:
+    """Index of the leaf each row of `Xb` ends in, in tree `t` of an
+    oblivious ensemble: bit d is split d's answer."""
+    idx = np.zeros(Xb.shape[0], np.int64)
+    for d in range(ens.split_feature.shape[1]):
+        b = Xb[:, ens.split_feature[t, d]].astype(np.int64)
+        idx |= (b > ens.split_bin[t, d]).astype(np.int64) << d
+    return idx
+
+
+def predict_raw_oblivious(ens, Xb: np.ndarray,
+                          dtype=np.float32) -> np.ndarray:
+    """Raw scores [rows] of a models/tree.ObliviousEnsemble over binned
+    rows: the leaf values summed in `dtype` in tree order, then scaled."""
+    total = np.zeros(Xb.shape[0], dtype)
+    for t in range(ens.split_feature.shape[0]):
+        total += ens.leaf_value[t].astype(dtype)[
+            leaf_of_rows_oblivious(ens, t, Xb)]
+    return dtype(ens.bias) + dtype(ens.scale) * total

@@ -30,8 +30,8 @@ What is compiled:
 - the whole fused-rounds program TPUDevice builds
   (`TPUDevice._build_rounds_fn`) on one device, on a rows=4 mesh and on a
   2x2 (rows x features) mesh over the four described chips, and the
-  scoring program (`TPUDevice._predict_fn`), for heap ensembles and for a
-  node list (the path-matrix form).
+  scoring program (`TPUDevice._predict_fn`), for heap ensembles, for a
+  node list (the path-matrix form) and for an oblivious ensemble.
 
 Exit 0 iff every default-dispatch case compiled; opt-in kernels
 (grad_dtype=int8|int16, predict_impl=lut|lut4) are reported and do not
@@ -60,6 +60,9 @@ CRITEO = dict(rows=2_000_000, features=39)
 # LightGBM's Bosch model's scoring chunk (benchmark config
 # bosch-lgbm-500t-255l): 968 columns, TPUDevice.predict_chunk_rows of them.
 BOSCH = dict(chunk_rows=262_144, features=968)
+# CatBoost's Epsilon model's scoring chunk (benchmark config
+# epsilon-catboost-8000t-d6): 2000 dense columns, 8000 trees of depth 6.
+EPSILON = dict(chunk_rows=131_072, features=2000, n_trees=8000, depth=6)
 
 
 class KernelCase(typing.NamedTuple):
@@ -218,6 +221,30 @@ def _paths_case(rows, features, n_trees, n_leaves, missing=False):
     return build
 
 
+def _oblivious_case(rows, features, n_trees, depth):
+    """The oblivious form (ops/predict_oblivious.py) over the compiled
+    tables' SHAPES (models/tree.CompiledOblivious: 197 MB at the Epsilon
+    model's), the rows as api.predict does (uint8)."""
+    def build():
+        import jax.numpy as jnp
+
+        from ddt_tpu.ops import predict_oblivious
+
+        groups = -(-n_trees // predict_oblivious.GROUP)
+
+        def fn(sel, thr, leaf, Xc):
+            return predict_oblivious.predict_oblivious_pallas(
+                sel, thr, leaf, Xc, scale=0.5, bias=0.25, interpret=False)
+
+        return fn, [
+            ((groups, depth, -(-features // 16) * 16, 128), jnp.bfloat16),
+            ((groups, -(-depth // 8) * 8, 128), jnp.float32),
+            ((groups, 1 << depth, 128), jnp.float32),
+            ((rows, features), jnp.uint8)]
+
+    return build
+
+
 def kernel_cases() -> list:
     """Every Pallas kernel the system has, at the shapes the repo names.
     `default` marks the default dispatch on a TPU (f32 gradients, the f32
@@ -336,6 +363,21 @@ def kernel_cases() -> list:
         KernelCase("paths/bosch/968f/20x255leaves/nan", True,
                    _paths_case(BOSCH["chunk_rows"], BOSCH["features"], 20,
                                255, missing=True)),
+        # The oblivious form: CatBoost's Epsilon model's chunk (63 groups,
+        # 16 K-blocks), the depths and widths at the dispatch rule's edges
+        # (depth 10 at 28 columns fits, depth 7 at 2000), one K-block with
+        # a ragged last row tile, a K-block of one column.
+        KernelCase("oblivious/epsilon/8000x6", True,
+                   _oblivious_case(EPSILON["chunk_rows"], EPSILON["features"],
+                                   EPSILON["n_trees"], EPSILON["depth"])),
+        KernelCase("oblivious/28f/300x10", True,
+                   _oblivious_case(hr, hf, 300, 10)),
+        KernelCase("oblivious/2000f/300x7", True,
+                   _oblivious_case(EPSILON["chunk_rows"], 2000, 300, 7)),
+        KernelCase("oblivious/28f/5x1", True,
+                   _oblivious_case(4_999, hf, 5, 1)),
+        KernelCase("oblivious/129f/130x6", True,
+                   _oblivious_case(4_999, 129, 130, 6)),
         # Opt-in kernels.
         KernelCase("hist/higgs/255bins/N=32/int8", False,
                    _hist_case(hr, hf, 32, 255, "int8")),
@@ -406,9 +448,10 @@ def _rounds_program(topo_devices, *, rows, features, n_rounds, mesh_shape,
 
 
 def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
-                     n_classes=1, routed=False, leaves=0):
+                     n_classes=1, routed=False, leaves=0, oblivious=False):
     """`routed`: a heap with the missing and the categorical table; a node
-    list (`leaves`) with learned NaN directions."""
+    list (`leaves`) with learned NaN directions. `oblivious`: symmetric
+    trees of `depth` (models/tree.ObliviousEnsemble)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -417,9 +460,18 @@ def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
     from ddt_tpu.config import TrainConfig
 
     be = TPUDevice(TrainConfig(backend="tpu", max_depth=depth or 6))
-    ens = (_random_node_list(n_trees, leaves, features, routed) if leaves else
-           _random_ensemble(n_trees, depth, features, n_classes, routed,
-                            routed))
+    if oblivious:
+        import numpy as np
+
+        from ddt_tpu.models.tree import random_oblivious
+
+        ens = random_oblivious(np.random.default_rng(7), n_trees, depth,
+                               features, scale=0.5, bias=0.25)
+    else:
+        ens = (_random_node_list(n_trees, leaves, features, routed)
+               if leaves else
+               _random_ensemble(n_trees, depth, features, n_classes, routed,
+                                routed))
     fn, ens_dev = be._predict_fn(ens)
     one = SingleDeviceSharding(topo_devices[0])
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
@@ -451,12 +503,13 @@ def program_cases(topo_devices) -> list:
         return build
 
     def scoring(n_trees, rows=hr, features=hf, depth=6, n_classes=1,
-                routed=False, leaves=0):
+                routed=False, leaves=0, oblivious=False):
         def build():
             fn, args = _scoring_program(topo_devices, rows=rows,
                                         features=features, n_trees=n_trees,
                                         depth=depth, n_classes=n_classes,
-                                        routed=routed, leaves=leaves)
+                                        routed=routed, leaves=leaves,
+                                        oblivious=oblivious)
             return fn, args, ["tpu_custom_call"]
         return build
 
@@ -492,6 +545,13 @@ def program_cases(topo_devices) -> list:
         ("scoring/bosch-lgbm/500x255leaves/nan", scoring(
             500, rows=BOSCH["chunk_rows"], features=BOSCH["features"],
             depth=0, leaves=255, routed=True)),
+        # CatBoost's Epsilon model's chunk through the auto dispatch: an
+        # oblivious ensemble, 63 groups of 128 trees streamed a group a
+        # step over 2000 columns.
+        ("scoring/epsilon-catboost/8000x6/oblivious", scoring(
+            EPSILON["n_trees"], rows=EPSILON["chunk_rows"],
+            features=EPSILON["features"], depth=EPSILON["depth"],
+            oblivious=True)),
     ]
 
 

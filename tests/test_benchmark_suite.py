@@ -18,7 +18,8 @@ BENCHMARK = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
 MODULES = ("test_call_anatomy", "test_correct", "test_correct_mc",
            "test_correct_routed", "test_correct_leafwise",
-           "test_correct_leafwise_nan", "test_opcount",
+           "test_correct_leafwise_nan", "test_correct_oblivious",
+           "test_opcount",
            "test_tracefile", "test_device_stage_ms")
 
 sys.path[:0] = [os.path.join(BENCHMARK, "tests"), BENCHMARK]

@@ -155,7 +155,7 @@ def test_an_empty_span_costs_microseconds():
 
 BRANCHES = {
     # branch: (partitions, PREDICT_ROW_CHUNK, rows,
-    #          {span: how many of it}, chunks)
+    #          {span: how many of it}, chunks[, PREDICT_FIRST_PIECE_BYTES])
     "one": (1, 4096, 1000,
             dict(upload=1, dispatch=1, fetch=1, place=0, concat=0), 1),
     # pieces of PREDICT_UPLOAD_CHUNKS = 2 chunks, a later one under the
@@ -166,6 +166,11 @@ BRANCHES = {
     "chunks-4pieces": (1, 256, 1700,
                        dict(upload=4, dispatch=7, fetch=7, place=7,
                             concat=0), 7),
+    # wide rows: a chunk (256 rows x 6 B) is all a leading piece may hold,
+    # so the first TWO pieces are one chunk, the rest two: 1 1 2 2 1
+    "chunks-lead": (1, 256, 1700,
+                    dict(upload=5, dispatch=7, fetch=7, place=7, concat=0),
+                    7, 256 * 6),
     "mesh": (4, 64, 1000,
              dict(upload=4, dispatch=4, fetch=1, place=0, concat=0), 4),
 }
@@ -173,10 +178,12 @@ BRANCHES = {
 
 @pytest.mark.parametrize("branch", sorted(BRANCHES))
 def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
-    parts, row_chunk, R, want, chunks = BRANCHES[branch]
+    parts, row_chunk, R, want, chunks, *first_bytes = BRANCHES[branch]
     be = get_backend(TrainConfig(backend="tpu", n_bins=31,
                                  n_partitions=parts))
     monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", row_chunk)
+    for b in first_bytes:
+        monkeypatch.setattr(type(be), "PREDICT_FIRST_PIECE_BYTES", b)
     ens = _rand_ensemble(seed=1000 + sorted(BRANCHES).index(branch))
     Xb = np.random.default_rng(5).integers(0, 31, size=(R, 6),
                                            dtype=np.uint8)
@@ -212,9 +219,17 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
         up, disp = kids["ddt:predict:upload"], kids["ddt:predict:dispatch"]
         assert [s["counts"]["piece"] for s in up] == list(range(len(up)))
         assert up[0]["end"] <= disp[0]["start"]
+        # the chunk a piece starts at: `per` chunks a piece, but one a
+        # leading piece where PREDICT_FIRST_PIECE_BYTES holds no more
+        starts_at = ([0, 1] + list(range(2, chunks, per)) if first_bytes
+                     else list(range(0, chunks, per)))
+        assert len(up) == len(starts_at)
         for p in range(1, len(up)):
-            assert disp[per * p - 1]["end"] <= up[p]["start"]
-            assert up[p]["end"] <= disp[per * p]["start"]
+            assert disp[starts_at[p] - 1]["end"] <= up[p]["start"]
+            assert up[p]["end"] <= disp[starts_at[p]]["start"]
+        assert [s["counts"]["bytes"] for s in up] == [
+            6 * (min(R, row_chunk * b) - row_chunk * a)
+            for a, b in zip(starts_at, starts_at[1:] + [chunks])]
     # bytes where the work happens, and the same bytes on the counters
     ens_bytes = kids["ddt:predict:ensemble"][0]["counts"]["bytes"]
     assert ens_bytes > 0
@@ -428,7 +443,15 @@ def test_a_compiled_ensemble_skips_the_token_span():
 # ------------------------------------------------------------------ #
 
 STAGES = {"predict:widen", "predict:tables", "predict:traverse",
-          "predict:traverse_paths", "predict:accumulate"}
+          "predict:traverse_paths", "predict:traverse_oblivious",
+          "predict:accumulate"}
+
+
+def _small_oblivious(seed):
+    from ddt_tpu.models.tree import random_oblivious
+
+    return random_oblivious(np.random.default_rng(seed), 5, 3, 6, n_bins=31,
+                            scale=0.5, bias=0.25)
 
 
 def _routed(ens, seed):
@@ -451,6 +474,8 @@ STAGE_MODELS = {
                     "jit_predict_raw_effective"),
     "node-list": (lambda: _small_node_list(3104),
                   "jit_predict_raw_effective_paths"),
+    "oblivious": (lambda: _small_oblivious(3105),
+                  "jit_predict_raw_effective_oblivious"),
 }
 STAGE_CASES = [(m, impl) for m in STAGE_MODELS
                for impl in ("pallas", "onehot")]
@@ -460,10 +485,11 @@ STAGE_CASES = [(m, impl) for m in STAGE_MODELS
                          ids=[f"{m}-{i}" for m, i in STAGE_CASES])
 def test_every_instruction_of_a_scoring_program_has_a_stage(
         model, impl, monkeypatch):
-    """Interpreted Pallas and the jax.numpy forms, heap and node list,
-    with and without routing tables: what the program traced is under
-    one of the named stages; `unscoped` holds what no source line made
-    (parameters, constants, the compiler's copies and converts)."""
+    """Interpreted Pallas and the jax.numpy forms, heap, node list and
+    oblivious ensemble, with and without routing tables: what the program
+    traced is under one of the named stages; `unscoped` holds what no
+    source line made (parameters, constants, the compiler's copies and
+    converts)."""
     # `source` is the line that FIRST traced an instruction in this
     # process: jnp's own jitted helpers (`%`, `jnp.pad`) keep the jaxpr,
     # source lines and all, of whichever module called them first with the
@@ -488,13 +514,16 @@ def test_every_instruction_of_a_scoring_program_has_a_stage(
     assert len(held) > 10
     seen = {e["stage"] for e in held.values()}
     assert seen <= STAGES | {an.UNSCOPED}
-    traverse = "predict:traverse_paths" if (
-        model == "node-list" and impl == "pallas") else "predict:traverse"
+    traverse = "predict:traverse"
+    if impl == "pallas":
+        traverse = {"node-list": "predict:traverse_paths",
+                    "oblivious": "predict:traverse_oblivious"}.get(
+                        model, traverse)
     assert {traverse, "predict:accumulate"} <= seen
-    # Both kernels take the uint8 chunk as it is and widen a tile in VMEM:
+    # The kernels take the uint8 chunk as it is and widen a tile in VMEM:
     # no instruction of their programs is the widening (the heap kernel
-    # since PR 36, the path kernel since PR 37). The jax.numpy forms still
-    # widen in XLA.
+    # since PR 36, the path kernel since PR 37, the oblivious kernel). The
+    # jax.numpy forms still widen in XLA.
     assert ("predict:widen" in seen) != (impl == "pallas")
     for name, e in held.items():
         assert re.fullmatch(r"%[\w.-]+", name)

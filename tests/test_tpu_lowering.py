@@ -36,6 +36,8 @@ DEFAULT_CASES = [c for c in aot.kernel_cases() if c.default]
 
 HEAP_CASES = [c for c in DEFAULT_CASES if c.name.startswith("predict/")]
 PATH_CASES = [c for c in DEFAULT_CASES if c.name.startswith("paths/")]
+OBLIVIOUS_CASES = [c for c in DEFAULT_CASES
+                   if c.name.startswith("oblivious/")]
 
 
 @functools.lru_cache(maxsize=None)
@@ -138,6 +140,35 @@ def test_path_kernel_takes_the_select_the_rule_packs(case):
     assert "ddt:predict:tables/concatenate" not in text
 
 
+@pytest.mark.parametrize("case", OBLIVIOUS_CASES, ids=lambda c: c.name)
+def test_oblivious_kernel_crosses_hbm_at_the_datas_width(case):
+    """The oblivious form's interface is the other kernels': the uint8 chunk
+    as it comes, at 28 columns and at the Epsilon model's 2000 (where an
+    int32 copy of a 131,072-row chunk would be 1 GB), the last row tile
+    ragged, and the scores as `f32[1, R]`; no array of the program holds the
+    rows widened or padded, and nothing is traced under `predict:widen`."""
+    exported, shapes = _export_for_tpu(case)
+    (rows, features), dtype = shapes[-1]
+    (groups, depth, fp, lanes), _ = shapes[0]
+    assert dtype == jnp.uint8 and lanes == 128
+    text = exported.mlir_module()
+    call, = [ln for ln in text.splitlines()
+             if "@tpu_custom_call" in ln and "_oblivious_kernel" in ln]
+    operands, result = re.search(
+        r"\}\s*:\s*\((.*)\)\s*->\s*(tensor<[^>]*>)", call).groups()
+    assert operands.startswith(
+        f"tensor<{rows}x{features}xui8>, "
+        f"tensor<{groups}x{depth}x{fp}x128xbf16>,")
+    assert result == f"tensor<1x{rows}xf32>"
+    for held in ("xi32>", "xf32>", "xbf16>"):
+        assert f"tensor<{rows}x{features}{held}" not in text
+    assert f"tensor<{rows}x1xf32>" not in text
+    assert "ddt:predict:widen" not in text
+    if rows % 2048:
+        padded = -(-rows // 2048) * 2048
+        assert f"tensor<{padded}x" not in text and f"x{padded}x" not in text
+
+
 def test_case_table_covers_the_default_dispatch():
     """Both histogram forms, feature-chunked at the Covertype width, and
     the traversal kernel with and without the optional operands, for one
@@ -173,7 +204,12 @@ def test_case_table_covers_the_default_dispatch():
                    "paths/56f/12x500leaves/nan",
                    # past one K-block of the select; Bosch's width with
                    # the NaN route in the compare
-                   "paths/129f", "paths/bosch/968f"):
+                   "paths/129f", "paths/bosch/968f",
+                   # the oblivious form: the Epsilon model's chunk, the
+                   # dispatch rule's edges, one and two K-blocks
+                   "oblivious/epsilon/8000x6", "oblivious/28f/300x10",
+                   "oblivious/2000f/300x7", "oblivious/28f/5x1",
+                   "oblivious/129f"):
         assert any(needle in n for n in names), (needle, names)
 
 
