@@ -500,6 +500,9 @@ def score_oblivious(overrides: dict, rows: int) -> None:
     assert built["trees_per_step"] == 128, "the oblivious kernel did not serve"
     assert (built["table_blocks"], built["select_k_blocks"]) == (63, 16), built
     assert built["oblivious_mxu_tiles_per_tree"] == 0.75, built
+    # all but the last of a row tile's 2 x 63 resolves run beside a later
+    # sub-tile's select (PR 40)
+    assert built["resolves_under_select"] == 0.9921, built
     assert_compiled_kernel(cfg, ens, rows, "oblivious")
     n = min(2_000, rows)
     want = numpy_predict.predict_raw_oblivious(ens, Xb[:n], dtype=np.float64)

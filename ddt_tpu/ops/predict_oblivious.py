@@ -21,8 +21,21 @@ so the MXU is asked for D x ceil(F/128) weight tiles a group and 256 rows
 (`oblivious_mxu_tiles_per_tree` = that over 128: 0.75 at depth 6 and 2000
 columns, where the 63-node expansion through the path kernel asks 17) and
 the leaf lookup costs no matmul: the bits of a lane are that tree's own,
-so the index never crosses lanes and the 64-way multiplexer runs under the
-select's matmuls (63 selects a vreg of rows against 96 weight-tile pushes).
+so the index never crosses lanes. The multiplexer (63 selects a vreg of
+rows against 96 weight-tile pushes) is work for the VPU while the MXUs do
+the matmuls, but not of ONE sub-tile: its bits are the matmuls' results. So
+the kernel is SOFTWARE-PIPELINED (PR 40): a sub-tile's SELECT (widen,
+matmuls, compares) leaves the leaf index of every (row, tree) as one int32
+plane in a VMEM scratch of two, and its RESOLVE (multiplexer, the plane
+turned over, the add into the output) is issued with the select of the NEXT
+sub-tile, in one basic block; a step's last sub-tile is resolved by the
+next step on the group axis, against a copy of its leaf table, and a row
+tile's last group resolves its own: 125 of a row tile's 126 resolves run
+beside a select's matmuls (`resolves_under_select`). As PR 39 shipped it
+(select and resolve of a sub-tile in one `fori_loop` body), the compiler's
+final bundles held 6,259 of a body's 8,391 vector selects in one stretch
+of 4,000 bundles with 472 of the 1,000 matmul pushes that keep the four
+MXUs busy: 27,156 cycles a sub-tile against the MXU's 24,576.
 
 The select is K-BLOCKED as the path kernel's is: v_d = sum_k x_k @ sel_d,k
 over ceil(F/128) blocks of 128 columns (`select_k_blocks`: 16 at 2000
@@ -78,7 +91,11 @@ from ddt_tpu.utils import device
 # rows at 1.5 GHz): a weight tile serves a sub-tile's rows once loaded, so
 # longer sub-tiles load fewer. The row tile widened ONCE into a bf16
 # scratch by its first group (and not by each of the 63): 148.9 at 512,
-# 146.8 at 1024, not worth its 8 MB of VMEM.
+# 146.8 at 1024, not worth its 8 MB of VMEM. Those are PR 39's readings,
+# select and resolve of a sub-tile in one loop body; pipelined (PERF.md
+# section 6, PR 40) the shipped form reads **142.1** where that one read
+# 147.9 in the same call (a resolve's strips of 8 rows each tied to a
+# matmul of the select it runs beside; a block of 128 rows to one: 144.5).
 TILE_ROWS = 2048
 SUB_ROWS = 1024
 _LANES = 128
@@ -94,9 +111,13 @@ _VMEM_BUDGET_BYTES = _VMEM_LIMIT_BYTES - 8 * 1024 * 1024
 _MAX_DEPTH = 10
 # Bytes a sub-tile's row keeps beside the windows: a widened bin of its
 # K-blocks (the bf16 copy the group's splits share and the float32 it is
-# made from), and a lane's float32 planes: v_d and b_d of every split and
-# the multiplexer's stack, D deep.
+# made from), and a lane's float32 planes (`_vmem_bytes`).
 _SUB_ROW_BIN_BYTES = 6
+# The deepest tree whose resolve is unrolled beside a select (`_pipelined`).
+_PIPELINED_DEPTH = 8
+# Rows of a resolve's block that depend on ONE matmul of the select they are
+# issued with (`_resolve_later`): a vreg of float32.
+_TIE_ROWS = 8
 
 
 def oblivious_mxu_tiles_per_tree(depth: int, n_features: int) -> float:
@@ -127,6 +148,10 @@ class ObliviousPlan(typing.NamedTuple):
     table_bytes: int           # HBM bytes of all the groups, read once
     tile_rows: int
     row_operand_bytes: int = 1
+    # Of a row tile's resolves (sub-tiles x groups), the share issued beside
+    # a later select's matmuls: all but the last where the step is
+    # pipelined, else 0.
+    resolves_under_select: float = 0.0
 
     @property
     def blocks(self) -> int:
@@ -148,22 +173,53 @@ class ObliviousPlan(typing.NamedTuple):
 SPAN_COUNTS = ("oblivious", "depth", "select_columns_per_tree",
                "trees_per_lane_tile", "select_k_blocks",
                "oblivious_mxu_tiles_per_tree", "trees_per_step",
-               "table_blocks", "table_bytes", "row_operand_bytes")
+               "table_blocks", "table_bytes", "row_operand_bytes",
+               "resolves_under_select")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
+
+
+def _pipelined(depth: int, n_sub: int) -> bool:
+    """Whether a step resolves a sub-tile beside the next one's select. It
+    needs a next one; and its two unrolled resolves are 2 x (2^D - 1) x
+    128 vector selects in one basic block, past 2 x 255 x 128 of which the
+    compiler's scheduler gives the order up (depth 10 at 28 columns:
+    244,687 bundles a step where the rolled form's two sub-tiles take
+    90,964; depth 9: 51,673 against 50,638, nothing gained; compile check,
+    PR 40), where the select is a small part of the step anyway."""
+    return n_sub > 1 and depth <= _PIPELINED_DEPTH
+
+
+def _scratch_shapes(depth: int, n_sub: int) -> list:
+    """The pipeline's VMEM scratch: the leaf indices of the sub-tile being
+    selected and of the one being resolved, an int32 plane each (a
+    sub-tile's D bits in 0.5 MB, where its D bit planes would be 3-5), and
+    the group before's leaf table."""
+    if not _pipelined(depth, n_sub):
+        return []
+    return [pltpu.VMEM((2, SUB_ROWS, GROUP), jnp.int32),
+            pltpu.VMEM((1 << depth, GROUP), jnp.float32)]
 
 
 def _vmem_bytes(depth: int, n_features: int, row_bytes: int) -> int:
     """VMEM a grid step takes: the group's double-buffered table windows,
     the row tile's two at the rows' own width, the [1, TILE_ROWS]
-    output's, a sub-tile's widened K-blocks and its float32 planes."""
+    output's, a sub-tile's widened K-blocks and its float32 planes: v_d
+    and b_d of every split and the multiplexer's stack, D deep (3 D + 2);
+    where the step is pipelined, three planes fewer (a block of 128 rows
+    is resolved at a time) and the scratch: never more than the other
+    form's, so no shape lost the kernel to the pipeline."""
     fp = -(-n_features // 16) * 16
     tables = (depth * _window_bytes(fp, GROUP) // 2     # bf16: half of f32
               + _window_bytes(depth, GROUP) + _window_bytes(1 << depth, GROUP))
     rows = (2 * TILE_ROWS * _lane_pad(n_features) * row_bytes
             + _window_bytes(1, TILE_ROWS))
+    planes, scratch = 3 * depth + 2, 0
+    if _pipelined(depth, TILE_ROWS // SUB_ROWS):
+        planes -= 3
+        scratch = 2 * SUB_ROWS * GROUP * 4 + (GROUP << depth) * 4
     sub = SUB_ROWS * (_lane_pad(n_features) * _SUB_ROW_BIN_BYTES
-                      + GROUP * 4 * (3 * depth + 2))
-    return tables + rows + sub
+                      + GROUP * 4 * planes)
+    return tables + rows + sub + scratch
 
 
 def oblivious_plan(n_trees: int, depth: int, n_features: int,
@@ -178,9 +234,13 @@ def oblivious_plan(n_trees: int, depth: int, n_features: int,
     if not served:
         return ObliviousPlan(*said, 0, 0, 0, 0, row_bytes)
     groups = max(1, -(-n_trees // GROUP))
+    n_sub = TILE_ROWS // SUB_ROWS
+    resolves = n_sub * groups
     return ObliviousPlan(*said, GROUP, groups,
                          groups * _group_bytes(depth, n_features), TILE_ROWS,
-                         row_bytes)
+                         row_bytes,
+                         round((resolves - 1) / resolves, 4)
+                         if _pipelined(depth, n_sub) else 0.0)
 
 
 def predict_oblivious_fits(depth: int, n_features: int,
@@ -194,66 +254,175 @@ def predict_oblivious_fits(depth: int, n_features: int,
         depth, n_features, row_bytes) <= _VMEM_BUDGET_BYTES
 
 
-def _mux(bits: list, leaf_ref, d: int, base: int):
+def _mux(bits: list, leaves: list, d: int, base: int):
     """The leaf value every (row, lane) reaches among leaves base ..
     base + 2^(d+1) - 1, by bits 0 .. d: depth-first, so at most d + 1
-    planes are alive."""
+    values are alive."""
     if d < 0:
-        return leaf_ref[0, base:base + 1, :]              # [1, 128]
-    return jnp.where(bits[d], _mux(bits, leaf_ref, d - 1, base + (1 << d)),
-                     _mux(bits, leaf_ref, d - 1, base))
+        return leaves[base]
+    return jax.lax.select(bits[d],
+                          _mux(bits, leaves, d - 1, base + (1 << d)),
+                          _mux(bits, leaves, d - 1, base))
 
 
-def _oblivious_kernel(x_ref, sel_ref, thr_ref, leaf_ref, out_ref, *,
+def _leaves(leaf_rows, rows: int) -> list:
+    """The group's leaf table `leaf_rows [2^D, 128]`, a leaf a plane of
+    `rows` rows: the multiplexer's operands."""
+    return [jnp.broadcast_to(leaf_rows[i:i + 1, :], (rows, _LANES))
+            for i in range(leaf_rows.shape[0])]
+
+
+def _resolve(bits: list, leaves: list, out_ref, r0):
+    """The RESOLVE of a block of rows whose D bit planes are `bits`: the
+    multiplexer over the group's `leaves`, the block turned over and added
+    into the output's rows from r0."""
+    rows = bits[0].shape[0]
+    leaf = _mux(bits, leaves, len(bits) - 1, 0)           # [rows, 128]
+    # The lanes summed with the rows on the lanes: turn the block over, add
+    # down the sublanes.
+    out_ref[:, pl.ds(r0, rows)] += jnp.sum(leaf.T, axis=0, keepdims=True)
+
+
+def _resolve_later(idx_ref, slot: int, leaf_rows, out_ref, r0: int,
+                   depth: int):
+    """The resolve of the sub-tile whose leaf indices `idx_ref[slot]`
+    holds (its first row the row tile's r0), as a function of a BLOCK of
+    128 of its rows: `block(b0, after)`, the sub-tile's rows from b0;
+    `after`: results of the matmuls over the sub-tile being SELECTED
+    meanwhile. A strip of 8 rows of the block is made to depend on one of
+    them (its indices are replaced where the result is NaN, and a sum of
+    bins is none), because the compiler's critical-path scheduler moves a
+    resolve that depends on nothing of the select into one stretch between
+    two selects, where the MXUs wait for it (PERF.md section 6, PR 40):
+    tied to the matmuls, the strips' selects are issued in the slots the
+    next matmuls leave empty."""
+    shape = (_LANES, _LANES)
+    leaves = _leaves(leaf_rows, _LANES)
+    masks = [jnp.full(shape, 1 << d, jnp.int32) for d in range(depth)]
+    zero = jnp.zeros(shape, jnp.int32)
+    zero_strip = jnp.zeros((_TIE_ROWS, _LANES), jnp.int32)
+
+    def block(b0, after=()):
+        idx = idx_ref[slot, pl.ds(b0, _LANES), :]
+        if after:
+            # (lax, not jnp: a step traces 2,000 of these)
+            strips = []
+            for i, s0 in enumerate(range(0, _LANES, _TIE_ROWS)):
+                tie = jax.lax.slice_in_dim(
+                    after[i * len(after) * _TIE_ROWS // _LANES],
+                    b0 + s0, b0 + s0 + _TIE_ROWS)
+                strips.append(jax.lax.select(
+                    jax.lax.ne(tie, tie), zero_strip,
+                    jax.lax.slice_in_dim(idx, s0, s0 + _TIE_ROWS)))
+            idx = jax.lax.concatenate(strips, 0)
+        bits = [jax.lax.ne(jax.lax.bitwise_and(idx, m), zero) for m in masks]
+        _resolve(bits, leaves, out_ref, r0 + b0)
+
+    return block
+
+
+def _select(x_ref, sel_ref, thr_ref, r0, under=None, *, depth: int,
+            n_feat: int, sub_rows: int) -> list:
+    """The SELECT of the sub-tile at rows r0 against the step's group: the
+    D splits' matmuls and compares, the D bit planes of every (row, tree).
+    `under(b0, after)`: the resolve of another sub-tile to issue beside the
+    matmuls, an even share of its blocks of 128 rows after each."""
+    fp = sel_ref.shape[2]
+    k_starts = range(0, n_feat, _LANES)
+    n_dots, n_blocks = depth * len(k_starts), sub_rows // _LANES
+    # The sub-tile's K-blocks, widened once for the group's D splits: the
+    # bf16 copy lives in VMEM alone.
+    xs = []
+    for k0 in k_starts:
+        k1, kp = min(k0 + _LANES, n_feat), min(k0 + _LANES, fp)
+        xf = x_ref[pl.ds(r0, sub_rows), k0:k1].astype(
+            jnp.int32).astype(jnp.float32)
+        if kp > k1:     # K to whole bf16 sublane tiles
+            xf = jnp.concatenate(
+                [xf, jnp.zeros((sub_rows, kp - k1), jnp.float32)], axis=1)
+        xs.append(xf.astype(jnp.bfloat16))            # [S, <= 128]
+    bits, dot, recent = [], 0, []
+    for d in range(depth):
+        # bf16 operands (bins <= 255 and the 0/1 one-hot are exact), f32
+        # accumulator: the v5e's VPU has no bf16 compare.
+        v = None
+        for k0, xk in zip(k_starts, xs):
+            part = jax.lax.dot_general(
+                xk, sel_ref[0, d, k0:k0 + xk.shape[1], :],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)   # [S, 128]
+            v = part if v is None else v + part
+            if under is not None:
+                recent.append(part)     # the matmuls since the last block
+                for b in range(dot * n_blocks // n_dots,
+                               (dot + 1) * n_blocks // n_dots):
+                    under(b * _LANES, recent)
+                    recent = [part]
+            dot += 1
+        bits.append(v > thr_ref[0, d:d + 1, :])
+    return bits
+
+
+def _oblivious_kernel(x_ref, sel_ref, thr_ref, leaf_ref, out_ref, *scratch,
                       depth: int, n_feat: int, sub_rows: int):
     """One row tile against one group of 128 trees: the group's share of
     every row's margin. x_ref [TILE_ROWS, F] uint8 or int32, as HBM holds
     the rows (in the last tile, whatever lies past row R); sel [1, D, Fp,
     128] bf16, thr [1, Dp, 128] f32, leaf [1, 2^D, 128] f32; out [1,
     TILE_ROWS] f32, the rows on the lanes, resident over the group axis
-    (grid axis 1)."""
-    tile_rows = x_ref.shape[0]
-    fp = sel_ref.shape[2]
-    k_starts = range(0, n_feat, _LANES)
+    (grid axis 1). `scratch` (`_scratch_shapes`): idx [2, SUB_ROWS, 128]
+    int32, the leaf indices of the sub-tile being selected and of the one
+    being resolved; carry [2^D, 128] f32, the group before's leaf table.
 
-    @pl.when(pl.program_id(1) == 0)
+    Software-pipelined (`_pipelined`): the resolve of a sub-tile is issued
+    with the select of the NEXT one, in one basic block, and the step's
+    last sub-tile is resolved by the next step on the group axis, against
+    the carried leaf table (at a row tile's first group: a table of zeros
+    and whatever indices the scratch holds, so it adds zeros); the last
+    group resolves its own last sub-tile too, the one resolve a row tile's
+    MXUs wait for. A row's sum still runs over the groups in grid order."""
+    n_sub = x_ref.shape[0] // sub_rows
+    group, last = pl.program_id(1), pl.num_programs(1) - 1
+    select = functools.partial(_select, x_ref, sel_ref, thr_ref, depth=depth,
+                               n_feat=n_feat, sub_rows=sub_rows)
+
+    @pl.when(group == 0)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
+        for carry in scratch[1:]:       # the pipelined step's alone
+            carry[:] = jnp.zeros_like(carry)
 
-    def sub_tile(j, carry):
-        r0 = pl.multiple_of(j * sub_rows, sub_rows)
-        # The sub-tile's K-blocks, widened once for the group's D splits:
-        # the bf16 copy lives in VMEM alone.
-        xs = []
-        for k0 in k_starts:
-            k1, kp = min(k0 + _LANES, n_feat), min(k0 + _LANES, fp)
-            xf = x_ref[pl.ds(r0, sub_rows), k0:k1].astype(
-                jnp.int32).astype(jnp.float32)
-            if kp > k1:     # K to whole bf16 sublane tiles
-                xf = jnp.concatenate(
-                    [xf, jnp.zeros((sub_rows, kp - k1), jnp.float32)], axis=1)
-            xs.append(xf.astype(jnp.bfloat16))            # [S, <= 128]
-        bits = []
-        for d in range(depth):
-            # bf16 operands (bins <= 255 and the 0/1 one-hot are exact),
-            # f32 accumulator: the v5e's VPU has no bf16 compare.
-            v = None
-            for k0, xk in zip(k_starts, xs):
-                part = jax.lax.dot_general(
-                    xk, sel_ref[0, d, k0:k0 + xk.shape[1], :],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [S, 128]
-                v = part if v is None else v + part
-            bits.append(v > thr_ref[0, d:d + 1, :])
-        leaf = jnp.broadcast_to(_mux(bits, leaf_ref, depth - 1, 0),
-                                (sub_rows, _LANES))
-        # The lanes summed with the rows on the lanes: turn the plane over,
-        # add down the sublanes.
-        out_ref[:, pl.ds(r0, sub_rows)] += jnp.sum(leaf.T, axis=0,
-                                                   keepdims=True)
-        return carry
+    if not _pipelined(depth, n_sub):
+        def sub_tile(j, carry):
+            r0 = pl.multiple_of(j * sub_rows, sub_rows)
+            _resolve(select(r0), _leaves(leaf_ref.at[0], sub_rows), out_ref,
+                     r0)
+            return carry
 
-    jax.lax.fori_loop(0, tile_rows // sub_rows, sub_tile, 0)
+        jax.lax.fori_loop(0, n_sub, sub_tile, 0)
+        return
+    idx_ref, carry_ref = scratch
+
+    def later(j, leaf_rows):
+        return _resolve_later(idx_ref, j % 2, leaf_rows, out_ref,
+                              j * sub_rows, depth)
+
+    for j in range(n_sub):
+        # Beside sub-tile j's matmuls the resolve of the one before it: for
+        # j = 0 the step before's last.
+        bits = select(j * sub_rows, later(j - 1, leaf_ref.at[0]) if j
+                      else later(n_sub - 1, carry_ref))
+        idx_ref[j % 2] = functools.reduce(jnp.bitwise_or, (
+            jnp.where(b, 1 << d, 0) for d, b in enumerate(bits)))
+    carry_ref[:] = leaf_ref[0]
+
+    @pl.when(group == last)
+    def _():
+        # Nothing to run under: a rolled loop, an eighth of the program.
+        block = later(n_sub - 1, leaf_ref.at[0])
+        jax.lax.fori_loop(
+            0, sub_rows // _LANES,
+            lambda b, c: block(pl.multiple_of(b * _LANES, _LANES)), None)
 
 
 def predict_oblivious_pallas(
@@ -311,6 +480,7 @@ def predict_oblivious_pallas(
             out_specs=pl.BlockSpec((1, tile_rows), lambda i, b: (0, i),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((1, R), jnp.float32),
+            scratch_shapes=_scratch_shapes(depth, tile_rows // SUB_ROWS),
             cost_estimate=cost,
             interpret=interpret,
             compiler_params=pltpu.CompilerParams(

@@ -366,7 +366,9 @@ def kernel_cases() -> list:
         # The oblivious form: CatBoost's Epsilon model's chunk (63 groups,
         # 16 K-blocks), the depths and widths at the dispatch rule's edges
         # (depth 10 at 28 columns fits, depth 7 at 2000), one K-block with
-        # a ragged last row tile, a K-block of one column.
+        # a ragged last row tile, a K-block of one column; the pipeline's
+        # edges (PR 40): the deepest tree it unrolls (8; 9 and 10 keep the
+        # rolled step), a step of ONE sub-tile (no select to resolve under).
         KernelCase("oblivious/epsilon/8000x6", True,
                    _oblivious_case(EPSILON["chunk_rows"], EPSILON["features"],
                                    EPSILON["n_trees"], EPSILON["depth"])),
@@ -378,6 +380,10 @@ def kernel_cases() -> list:
                    _oblivious_case(4_999, hf, 5, 1)),
         KernelCase("oblivious/129f/130x6", True,
                    _oblivious_case(4_999, 129, 130, 6)),
+        KernelCase("oblivious/28f/130x8", True,
+                   _oblivious_case(4_999, hf, 130, 8)),
+        KernelCase("oblivious/28f/130x6/1000rows", True,
+                   _oblivious_case(1_000, hf, 130, 6)),
         # Opt-in kernels.
         KernelCase("hist/higgs/255bins/N=32/int8", False,
                    _hist_case(hr, hf, 32, 255, "int8")),

@@ -180,6 +180,92 @@ def test_a_shallow_tree_is_padded_in_its_high_bits():
 
 
 # ------------------------------------------------------------------ #
+# the pipeline's edges (PR 40): a sub-tile resolved beside the next one's
+# select, a step's last by the next group's step, a row tile's last flushed
+# ------------------------------------------------------------------ #
+
+# (rows, trees, depth, features): one sub-tile a step (no pipeline), exactly
+# two, a ragged last tile; one group and several (a deferred resolve crosses
+# a group and is flushed at the last); depth 1 and the deepest pipelined; a
+# depth the rule leaves rolled (9, 10); 1, 2 and 16 K-blocks.
+PIPELINE = [
+    (1, 5, 1, 28), (1024, 130, 6, 28), (1025, 130, 6, 129),
+    (2048, 5, 6, 28), (2049, 300, 1, 28), (4999, 130, 3, 129),
+    (1500, 130, 8, 28), (1025, 257, 2, 2000), (1025, 130, 9, 28),
+    (300, 5, 10, 28),
+]
+
+
+@pytest.mark.parametrize("n_rows,n_trees,depth,n_features", PIPELINE,
+                         ids=["r%d-t%d-d%d-f%d" % c for c in PIPELINE])
+def test_the_pipelined_kernel_at_its_edges(n_rows, n_trees, depth,
+                                           n_features):
+    """Kernel (interpreted), jax.numpy twin and bit walk, bit-equal on
+    dyadic leaves; a second call gives the first one's bits (the scratch a
+    call leaves behind is the next call's first deferred resolve: it must
+    add zeros)."""
+    ens = oblivious(300 + depth, n_trees, depth, n_features)
+    Xb = rows(301 + n_rows, n_rows, n_features)
+    want = numpy_predict.predict_raw_oblivious(ens, Xb, np.float64)
+    ce = ens.compile()
+    tables = [jnp.asarray(a) for a in ce.arrays()]
+    score = lambda: np.asarray(predict_oblivious.predict_oblivious_pallas(
+        *tables, jnp.asarray(Xb), scale=ce.scale, bias=ce.bias))
+    first = score()
+    assert np.array_equal(first, want)
+    assert np.array_equal(score(), first)
+    twin = predict_ops._predict_oblivious(
+        *tables, jnp.asarray(Xb), scale=ce.scale, bias=ce.bias)
+    assert np.array_equal(np.asarray(twin), want)
+    # a step of one sub-tile has no select to resolve under; of two, up to
+    # depth 8
+    assert not predict_oblivious._pipelined(depth, 1)
+    assert predict_oblivious._pipelined(depth, 2) == (depth <= 8)
+
+
+def test_the_pipeline_is_a_rule_on_the_shape_and_the_span_says_its_share():
+    plan = predict_oblivious.oblivious_plan
+    # all but the last of a row tile's 2 x groups resolves
+    assert plan(8000, 6, 2000).resolves_under_select == round(125 / 126, 4)
+    assert plan(100, 8, 28).resolves_under_select == 0.5
+    assert plan(300, 1, 28).resolves_under_select == round(5 / 6, 4)
+    # past depth 8 the two unrolled resolves are more than the compiler's
+    # scheduler orders: the rolled form, nothing under a select
+    assert plan(300, 9, 28).resolves_under_select == 0.0
+    assert plan(300, 10, 28).resolves_under_select == 0.0
+    assert plan(8000, 6, 2000, served=False).resolves_under_select == 0.0
+    assert predict_oblivious.SPAN_COUNTS[-1] == "resolves_under_select"
+    assert "resolves_under_select" in predict_oblivious.PHASES_COUNTS
+    # the scratch is the pipelined form's alone
+    assert predict_oblivious._scratch_shapes(9, 2) == []
+    assert predict_oblivious._scratch_shapes(6, 1) == []
+    idx, carry = predict_oblivious._scratch_shapes(6, 2)
+    assert idx.shape == (2, 1024, 128) and carry.shape == (64, 128)
+
+
+# The widest shape a depth that `predict_oblivious_fits` admitted at 073a17b,
+# for uint8 and for int32 rows: the pipeline's scratch lost none (the
+# estimate is never more than it was).
+ADMITTED = {1: (3584, 1664), 2: (3328, 1536), 3: (2976, 1408),
+            4: (2736, 1328), 5: (2560, 1280), 6: (2304, 1152),
+            7: (2048, 1024), 8: (1920, 1024), 9: (1664, 896),
+            10: (1536, 768)}
+
+
+@pytest.mark.parametrize("depth", sorted(ADMITTED))
+def test_fits_admits_every_shape_it_admitted_before_the_pipeline(depth):
+    fits = predict_oblivious.predict_oblivious_fits
+    wide_u8, wide_i32 = ADMITTED[depth]
+    assert fits(depth, wide_u8) and fits(depth, wide_i32, jnp.int32)
+    assert fits(depth, 28) and fits(depth, 968)
+    # and at the depths the rule leaves rolled, nothing else
+    if depth > 8:
+        assert not fits(depth, wide_u8 + 1)
+        assert not fits(depth, wide_i32 + 1, jnp.int32)
+    assert fits(7, 2000) and not fits(8, 2000)
+
+
+# ------------------------------------------------------------------ #
 # the dispatch rule and the plan
 # ------------------------------------------------------------------ #
 
@@ -208,7 +294,7 @@ def test_the_plan_at_the_epsilon_models_shape():
         "table_blocks": 63, "table_bytes": 63 * (6 * 2000 * 128 * 2
                                                  + 8 * 128 * 4
                                                  + 64 * 128 * 4),
-        "row_operand_bytes": 1}
+        "row_operand_bytes": 1, "resolves_under_select": 0.9921}
     assert plan.root_counts() == {
         "routing_tables": 0, "oblivious": 1, "select_columns_per_tree": 6,
         "select_k_blocks": 16}

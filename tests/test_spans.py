@@ -331,6 +331,42 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
         "ddt:predict:ensemble"][0]["counts"]
 
 
+@pytest.mark.parametrize("impl", ["pallas", "auto"])
+def test_an_oblivious_model_says_which_form_serves(impl, monkeypatch):
+    """An oblivious model through the same chunk loop: the `ensemble` span
+    carries the oblivious form's plan (ops/predict_oblivious.SPAN_COUNTS,
+    spelled out here), `resolves_under_select` last: the share of a row
+    tile's resolves the kernel issues beside a later select's matmuls."""
+    from ddt_tpu.ops import predict_oblivious
+
+    ens = _small_oblivious(3205)               # 5 trees x depth 3 x 6
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31,
+                                 predict_impl=impl))
+    monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", 256)
+    Xb = np.random.default_rng(79).integers(0, 31, size=(1000, 6),
+                                            dtype=np.uint8)
+    scores = be.predict_raw(ens, Xb)
+    np.testing.assert_allclose(scores, ens.predict_raw(Xb, binned=True),
+                               rtol=1e-5, atol=1e-6)
+    root = an.root_spans("predict")[-1]
+    assert root["counts"]["oblivious"] == 1
+    assert root["counts"]["select_columns_per_tree"] == 3
+    counts = _by_name(root)["ddt:predict:ensemble"][0]["counts"]
+    assert list(counts) == ["bytes", "trees",
+                            *predict_oblivious.SPAN_COUNTS]
+    served = impl == "pallas"      # a CPU's auto takes the jax.numpy form
+    assert counts == dict(
+        bytes=counts["bytes"], trees=5, oblivious=1, depth=3,
+        select_columns_per_tree=3, trees_per_lane_tile=42.67,
+        select_k_blocks=1, oblivious_mxu_tiles_per_tree=0.0234,
+        trees_per_step=128 * served, table_blocks=1 * served,
+        table_bytes=(3 * 16 * 128 * 2 + 8 * 128 * 4 + 8 * 128 * 4) * served,
+        row_operand_bytes=1,
+        # one group: of a row tile's two resolves the first runs beside
+        # the second sub-tile's select
+        resolves_under_select=0.5 * served)
+
+
 @pytest.mark.parametrize("n_features,k_blocks", [(6, 1), (129, 2), (300, 3)])
 def test_a_nan_routed_node_list_says_so(n_features, k_blocks):
     """Learned NaN directions and the width of the select on the spans:

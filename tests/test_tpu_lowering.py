@@ -209,7 +209,10 @@ def test_case_table_covers_the_default_dispatch():
                    # dispatch rule's edges, one and two K-blocks
                    "oblivious/epsilon/8000x6", "oblivious/28f/300x10",
                    "oblivious/2000f/300x7", "oblivious/28f/5x1",
-                   "oblivious/129f"):
+                   "oblivious/129f",
+                   # the pipeline's edges: the deepest tree it unrolls, a
+                   # step of one sub-tile
+                   "oblivious/28f/130x8", "oblivious/28f/130x6/1000rows"):
         assert any(needle in n for n in names), (needle, names)
 
 
