@@ -282,6 +282,8 @@ def score_multiclass(overrides: dict, rows: int) -> None:
         2, 2 ** (depth - 1)), "two nodes do not share a weight tile"
     assert built["groups_per_step"] < built["table_groups"], \
         "the node tables did not stream"
+    # a step of 7 x 128 weight tiles keeps 256 rows (PR 42)
+    assert built["rows_per_step"] == 256, built
     assert root["counts"]["tables_streamed_bytes"] > 0
     assert_compiled_kernel(cfg, ens, rows, "7-class")
     n = min(2_000, rows)
@@ -346,6 +348,8 @@ def score_routed(overrides: dict, rows: int) -> None:
         1, n_int), built
     assert built["routes_in_tile"] == 2, \
         "the two tables' routes do not ride the MXU weight tile"
+    # ONE group of 63 weight tiles a step: the step takes 1,024 rows (PR 42)
+    assert built["rows_per_step"] == 1024, built
     assert_compiled_kernel(cfg, ens, rows, "routed")
     n = min(SCORE_CHECK_ROWS, rows)
     want = ens.predict_raw(Xb[:n], binned=True)      # NumPy traversal

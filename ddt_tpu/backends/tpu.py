@@ -1645,7 +1645,7 @@ class TPUDevice(DeviceBackend):
         if plan.blocks > 1:
             shards = max(1, self.row_shards)
             shard_rows = [-(-min(chunk, R - i) // shards) for i in starts]
-            tiles = sum(-(-r // plan.tile_rows) for r in shard_rows)
+            tiles = sum(-(-r // plan.step_rows(r)) for r in shard_rows)
             counts["tables_streamed_bytes"] = (
                 shards * tiles * plan.table_bytes)
         if R <= chunk:

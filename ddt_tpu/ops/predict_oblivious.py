@@ -158,6 +158,11 @@ class ObliviousPlan(typing.NamedTuple):
         """As TablePlan.blocks: past 1 a row tile streams `table_bytes`."""
         return self.table_blocks
 
+    def step_rows(self, rows: int) -> int:
+        """As TablePlan.step_rows: this form's row tile whatever the
+        program's rows (a shorter program is ONE tile either way)."""
+        return self.tile_rows
+
     def span_counts(self) -> dict:
         return {k: getattr(self, k) for k in SPAN_COUNTS}
 

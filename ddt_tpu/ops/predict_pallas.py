@@ -13,7 +13,11 @@ matrix, feature one-hots, and descent state live and die inside one row
 tile's VMEM residency.
 
 Layout strategy. The grid is (row tiles, table blocks): one step scores one
-tile of TILE_R rows against one BLOCK of G tree groups. Trees are taken in
+tile of TILE_R rows against one BLOCK of G tree groups; TILE_R is the
+plan's (`table_plan`, `_step_rows`): 256 rows where the step holds 256 MXU
+weight tiles or more, up to 1,024 where it holds fewer, so that a step of
+one small group does not pay a step's tail and fixed cost four times for
+the work of one. Trees are taken in
 GROUPS of TREE_GROUP = 128, one vreg's lanes and one MXU weight tile,
 whatever tree_chunk the compiled ensemble was laid out with. The tables
 STREAM from HBM a block at a time (Mosaic double-buffers the windows, so the
@@ -315,6 +319,47 @@ TREE_GROUP = 128
 # under the probe's 1 MiB, as the parent does.
 _ROW_BYTES = 12 * 1024
 _ROW_NODE_BYTES_BOTH = 192
+# The rows a grid step takes (`_step_rows`): 256, doubled while the step's
+# MXU weight tiles times its rows stay within _STEP_MXU_TILES (the
+# 1000-tree cell's 8 groups x 32) times 256, _STEP_ROWS_MOST at most, and
+# never more than a _STEP_ROWS_SHARE-th of the program's rows
+# (`TablePlan.step_rows`).
+_STEP_MXU_TILES = 256
+_STEP_ROWS_MOST = 1024
+_STEP_ROWS_SHARE = 16
+# ... and the working set a row of such a longer step is charged, where a
+# 256-row step's (_ROW_BYTES, which decides G and whether the kernel
+# serves at all) would refuse 1,024 rows by itself. From the compiler's
+# own account again (compile check, PR 42: the 1 MiB probe above, every
+# group in one block, uint8 rows, the scoped allocation WITHOUT the
+# operand windows, which the compiler allots apart: a window past the
+# limit is refused under its own size; KiB a row at tile 1024, at tile
+# 512 in brackets; linear in the rows: tile 2048 reads 10.98 / 8.97 MiB
+# where 1024 reads 5.48 / 4.47):
+#   two nodes a tile, 100 trees, 28 features, depth 4 / 5 / 6 / 7 / 8:
+#       2.45 / 3.47 / 4.47 / 5.50 / 6.52 [2.42 / 3.50 / 4.48 / 5.46 /
+#       6.50]; 250 / 500 trees (2 / 4 groups) at depth 6: 4.98 / 5.00;
+#       1000 trees [5.02]; 64 features 4.53, depth 8 6.53; 7 classes, 54
+#       features: 210 trees x depth 6 5.03 [4.98], 105 x depth 8 6.59
+#   one node a tile, 65 / 128 features, depth 6: 4.46 / 4.51, depth 8:
+#       6.46 / 6.47; 256 features 5.80, 512 [6.96]: 5-6 B a row and
+#       feature past 128, which the row tile's windows, charged at 32
+#       bits, cover (8 B)
+#   folded, both tables, 39 features, depth 4 / 5 / 6 / 7 / 8: 3.38 /
+#       3.50 / 5.48 / 5.41 / 7.42 [3.22 / 3.46 / 5.42 / 5.34 / 7.32]; 56
+#       features 5.48 / 7.43 at depth 6 / 8; 250 trees 5.40, 500 [5.82];
+#       the categorical table alone 5.47 / 7.37, the missing one 2.51 /
+#       4.39
+#   integer routing (57 features), one table, depth 6: 4.48 / 4.51,
+#       depth 8 6.53; both, 100 trees, depth 4 / 5 / 6 / 7: 3.67 / 6.16 /
+#       8.65 / 13.10; 250 trees, depth 4 / 5: 7.61 / 12.23, depth 6
+#       [15.24]; 500 trees, depth 4: 9.59: under 8 KiB and
+#       _ROW_NODE_BYTES_BOTH a node by a ninth (10.8 / 13.8 / 19.8)
+# 8 KiB a row bounds every reading of the forms that keep nothing per
+# node by 7% (depth 8, folded), and with the row tile's windows charged
+# as 32-bit (2 KiB a row where the uint8 tile and the [C, rows] block
+# take 0.3) the whole estimate stands a quarter over the compiler's.
+_STEP_ROW_BYTES = 8 * 1024
 # Rows (K) of one MXU weight tile.
 _MXU_ROWS = 128
 # What one class dot costs the MXU, in weight tiles (results [TILE_R, 128])
@@ -377,7 +422,8 @@ def _window_bytes(rows: int, cols: int) -> int:
 
 
 def _vmem_bytes(groups: int, max_depth: int, n_features: int,
-                n_classes: int, tile_r: int, optional_operands: int) -> int:
+                n_classes: int, tile_r: int, optional_operands: int,
+                row_bytes: int = _ROW_BYTES) -> int:
     """VMEM a grid step takes with `groups` tree groups a table block: the
     table windows (feat i32, thr f32, dl and cat i32 where present, bottom
     values, class one-hot: one plane a row), the row tile's windows and
@@ -400,11 +446,47 @@ def _vmem_bytes(groups: int, max_depth: int, n_features: int,
               + _window_bytes(groups * (n_int + 1), TREE_GROUP)
               + _window_bytes(groups * TREE_GROUP, n_classes))
     per_node = optional_operands == 2 and not routes_in_tile(n_features, 2)
-    work = tile_r * (_ROW_BYTES
+    work = tile_r * (row_bytes
                      + (n_int * _ROW_NODE_BYTES_BOTH if per_node else 0))
     rows = _window_bytes(tile_r, n_features) + _window_bytes(tile_r,
                                                              n_classes)
     return tables + work + rows
+
+
+def _step_rows(groups: int, max_depth: int, n_features: int, n_classes: int,
+               optional_operands: int) -> int:
+    """The rows a grid step of `groups` tree groups takes: 256, doubled
+    while the step still asks the MXU for no more than the 1000-tree
+    cell's step asks (_STEP_MXU_TILES weight tiles, 8 groups x 32, x 256
+    rows), _STEP_ROWS_MOST at most, then halved while the kernel's VMEM
+    at that tile (`_vmem_bytes` at _STEP_ROW_BYTES a row) is past the
+    budget; 256 always remains. What a longer step buys: the weight
+    tiles' one-hots are built once a step whatever its rows, and a step's
+    tail (the last selects of the mux tree, the value plane's split, the
+    class dot, the out block: 372 bundles with 12 matmul pushes in the CTR
+    model's step, nothing to run under) and its fixed cost (some 220
+    cycles) are paid once: the one-group routed step of 63 tiles stood at
+    85% of its MXU time at 256 rows where the 1000-tree step of 256 tiles
+    stands at 94%. On the v5e (PERF.md section 6, PR 42; ms a 2M-row chunk
+    at 256 / 512 / 1,024 rows a step): the CTR model's routed group
+    26.43 / 25.02 / 24.34 (its kernel in the cell 1,247.1 -> 1,138.8 ms a
+    call, the scores the same bits), 100 / 250 / 500 unrouted trees
+    15.55 / 14.07 / 13.71, 26.44 / 25.09 / 24.76, 48.66 / 47.29 / 46.80,
+    depth 8 in one group 48.19 / 46.86 / 46.46. Read from the plan's own
+    G and tiles; no knob. (A step of 128 tiles reads another 1% at 1,024
+    rows and the 1000-tree step of 256 tiles 1.7% at 512, where it once
+    LOST 1.8%: left to a PR that claims those cells, ROADMAP A1.)"""
+    tiles = groups * mxu_tiles_per_group(max_depth, n_features,
+                                         optional_operands)
+    rows = _DEFAULT_TILE_R
+    while (rows < _STEP_ROWS_MOST
+           and 2 * rows * tiles <= _DEFAULT_TILE_R * _STEP_MXU_TILES):
+        rows *= 2
+    while rows > _DEFAULT_TILE_R and _vmem_bytes(
+            groups, max_depth, n_features, n_classes, rows,
+            optional_operands, _STEP_ROW_BYTES) > _VMEM_BUDGET_BYTES:
+        rows //= 2
+    return rows
 
 
 class TablePlan(typing.NamedTuple):
@@ -415,7 +497,7 @@ class TablePlan(typing.NamedTuple):
     blocks: int            # table blocks a row tile walks; 1 = resident
     table_bytes: int       # HBM bytes of all the blocks' tables, read once
                            # (a shared class one-hot: once an ensemble)
-    tile_rows: int         # rows a tile
+    tile_rows: int         # rows a grid step of a long program takes
     nodes_per_tile: int    # P: nodes that share one MXU weight tile
     mxu_tiles_per_group: int   # weight tiles a group costs a row tile
     routing_tables: int    # the missing and categorical tables it carries
@@ -429,6 +511,23 @@ class TablePlan(typing.NamedTuple):
     def tree_group(self) -> int:
         """Lane width of the kernel's tree planes; 0 in NO_PLAN."""
         return TREE_GROUP if self.table_groups else 0
+
+    @property
+    def rows_per_step(self) -> int:
+        """`tile_rows`, as the spans name it."""
+        return self.tile_rows
+
+    def step_rows(self, rows: int) -> int:
+        """The rows a grid step of a program of `rows` rows takes:
+        `tile_rows`, halved while that is more than a
+        _STEP_ROWS_SHARE-th of them, down to 256: what the last step
+        holds past the program's rows (under a step) must cost less than
+        the longer step saves, and a serving client's batch of a few
+        thousand rows must not pay for rows it does not have."""
+        step = self.tile_rows
+        while step > _DEFAULT_TILE_R and step * _STEP_ROWS_SHARE > rows:
+            step //= 2
+        return step
 
     def span_counts(self) -> dict:
         """The plan as the `ddt:predict:ensemble` span carries it."""
@@ -450,7 +549,7 @@ SPAN_COUNTS = ("tree_group", "table_groups", "groups_per_step",
                "table_bytes", "nodes_per_tile", "mxu_tiles_per_group",
                "routing_tables", "routes_in_tile", "trees_per_group",
                "class_dots_per_step", "row_operand_bytes",
-               "scores_class_major")
+               "scores_class_major", "rows_per_step")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 # This kernel does not serve the model (the one-hot path, the LUT tiers).
 NO_PLAN = TablePlan(*(0,) * len(TablePlan._fields))
@@ -497,11 +596,20 @@ def table_plan(
     depth 8 would pay 12% more groups for 4% of dots and keep 128. Read
     from C, the tree count and G; no knob.
 
+    And the rows a grid step takes, `tile_rows` (`_step_rows`): decided
+    AFTER G, which is fitted at 256 rows whatever the step turns out to
+    be, so that no shape's G, blocks or `predict_pallas_fits` moves: 256
+    where the step holds 256 MXU weight tiles or more (the 1000-tree
+    model's 8 x 32, Covertype's 7 x 128), 512 from 128 tiles on, 1,024
+    for a step of 64 or fewer (the CTR model's one group of 63). A
+    `tile_r` the caller names is taken as it is, G fitted at it.
+
     And the two arrays that cross between XLA and the kernel, which no
     term above depends on: `row_operand_bytes`, the width of a bin of the
     row block in HBM for rows of `row_dtype` (`row_operand_dtype`), and
     `scores_class_major`, 1: the result is [C, rows]."""
-    if tile_r is None:
+    planned = tile_r is None
+    if planned:
         tile_r = _DEFAULT_TILE_R
     rounds = TREE_GROUP // n_classes * n_classes      # 0: C > 128
     # G is capped by the groups there are, in either layout.
@@ -540,6 +648,9 @@ def table_plan(
     # The class one-hot: the [128, C] window the whole ensemble shares,
     # fetched once, or one a group.
     class_bytes = 4 * TREE_GROUP * n_classes * (1 if shared else blocks * g)
+    if planned:
+        tile_r = _step_rows(g, max_depth, n_features, n_classes,
+                            optional_operands)
     return TablePlan(n_tg, g, blocks, blocks * g * nodes_bytes + class_bytes,
                      tile_r, *packing, per_group, 1 if shared else g,
                      *interface)
@@ -836,8 +947,6 @@ def predict_effective_pallas(
     predict_raw / predict_raw_effective traces or standalone."""
     if interpret is None:
         interpret = device.platform() != "tpu"
-    if tile_r is None:
-        tile_r = _DEFAULT_TILE_R
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
         raise ValueError(
             "the Pallas traversal kernel requires binned integer data; "
@@ -868,6 +977,8 @@ def predict_effective_pallas(
     tg = TREE_GROUP
     plan = table_plan(Tpad, max_depth, F, C, tile_r, use_missing + use_cat,
                       Xc.dtype)
+    if tile_r is None:
+        tile_r = plan.step_rows(R)
     # Interpreted past the budget: one block of every group.
     n_g = plan.groups_per_step or plan.table_groups
     n_blocks = plan.blocks or 1

@@ -233,6 +233,11 @@ class PathPlan(typing.NamedTuple):
         one block's index never moves, so it is fetched once and stays."""
         return self.table_blocks
 
+    def step_rows(self, rows: int) -> int:
+        """As TablePlan.step_rows: this form's row tile whatever the
+        program's rows (a shorter program is ONE tile either way)."""
+        return self.tile_rows
+
     def span_counts(self) -> dict:
         return {k: getattr(self, k) for k in SPAN_COUNTS}
 

@@ -338,6 +338,42 @@ def kernel_cases() -> list:
                    _predict_case(hr, 64, 130, 5)),
         KernelCase("predict/65f/130x5", True,
                    _predict_case(hr, 65, 130, 5)),
+        # The rows a grid step takes (`table_plan`'s `tile_rows`, PR 42)
+        # at the rule's edges, over a whole 2M-row chunk: ONE group of 32
+        # weight tiles (100 unrouted trees; the routed one-group step is
+        # predict/criteo/100x6/missing+cat above), two groups (64 tiles),
+        # both 1,024 rows; four groups and depth 8 in one group (128
+        # tiles), 512; depth 7 folded (127 tiles), 512. Then the widest
+        # shape of each form that still takes the step and the first that
+        # falls back, by the kernel's VMEM at that tile: one node a tile
+        # at 256 columns 1,024 and at 257 512, at 1,792 columns 512 and at
+        # 1,793 256; the integer routing (57 columns, both tables) 512 at
+        # depth 6 and 256 at depth 7.
+        KernelCase("predict/higgs/100x6", True,
+                   _predict_case(CRITEO["rows"], hf, 100, 6)),
+        KernelCase("predict/higgs/250x6", True,
+                   _predict_case(CRITEO["rows"], hf, 250, 6)),
+        KernelCase("predict/higgs/500x6", True,
+                   _predict_case(CRITEO["rows"], hf, 500, 6)),
+        KernelCase("predict/higgs/100x8", True,
+                   _predict_case(CRITEO["rows"], hf, 100, 8)),
+        KernelCase("predict/criteo/100x7/missing+cat", True,
+                   _predict_case(CRITEO["rows"], CRITEO["features"], 100, 7,
+                                 missing=True, cat=True)),
+        KernelCase("predict/256f/100x6", True,
+                   _predict_case(CRITEO["rows"], 256, 100, 6)),
+        KernelCase("predict/257f/100x6", True,
+                   _predict_case(CRITEO["rows"], 257, 100, 6)),
+        KernelCase("predict/1792f/100x6", True,
+                   _predict_case(CRITEO["rows"], 1792, 100, 6)),
+        KernelCase("predict/1793f/100x6", True,
+                   _predict_case(CRITEO["rows"], 1793, 100, 6)),
+        KernelCase("predict/57f/100x6/missing+cat", True,
+                   _predict_case(CRITEO["rows"], 57, 100, 6, missing=True,
+                                 cat=True)),
+        KernelCase("predict/57f/100x7/missing+cat", True,
+                   _predict_case(CRITEO["rows"], 57, 100, 7, missing=True,
+                                 cat=True)),
         # The path-matrix form: node lists. LightGBM's Higgs model's own
         # shape (blocks of 8 trees), a tree of one 128-lane tile, and more
         # features than a bf16 sublane tile.

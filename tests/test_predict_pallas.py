@@ -638,6 +638,65 @@ def test_kernel_takes_the_rows_as_they_come(budget, form, dtype, rows):
         np.testing.assert_array_equal(got, kernel(Xb.astype(np.int32)))
 
 
+# what the rows are: the program's R, the rows a step is FORCED to take
+# (None: the plan's own, `TablePlan.step_rows`), the step that follows
+_STEP_ROWS = {
+    # a long program takes the plan's step: 16 whole steps and 300 rows
+    "planned-ragged": (16 * 1024 + 300, None, 1024),
+    # ... half of it where 1,024 would pass a sixteenth of the rows
+    "planned-half": (16 * 512 + 1, None, 512),
+    # a short one keeps 256 (200 rows: one step, mostly past row R)
+    "planned-short": (200, None, 256),
+    # R not a multiple of the tile; R smaller than it (a tile the caller
+    # names is charged as G's 256 rows are, 12 KiB a row: 1,024 of them
+    # leave no group room, and the interpreter then runs every group in
+    # one block with a class dot each: another order of the float adds)
+    "forced-512-ragged": (1000, 512, 512),
+    "forced-512-short": (300, 512, 512),
+}
+
+
+@pytest.mark.parametrize("rows", list(_STEP_ROWS))
+@pytest.mark.parametrize("form", ["packed", "folded-routed",
+                                  "integer-routed-F60",
+                                  "7class-dot-a-group"])
+def test_a_longer_step_scores_the_same_bits(budget, form, rows):
+    """The rows a grid step takes decide no score: a row's matmuls, its
+    mux tree and its class dot (the same 128 lanes contracted) are its
+    own, so the kernel at the planned step (1,024 rows for these small
+    models in a long program), at a forced one, and at 256 rows give the
+    same bits, random leaf values and all; with a ragged last step, and
+    with a step longer than the program."""
+    C, T, depth, F, missing, cat, fit, _ = _INTERFACE_FORMS[form]
+    optional = int(missing) + int(bool(cat))
+    if fit is not None:
+        budget(fit, depth, F, C, optional)
+    R, forced, step = _STEP_ROWS[rows]
+    plan = jpp.table_plan(-(-T // 64) * 64, depth, F, C, None, optional,
+                          np.uint8)
+    assert plan.tile_rows == 1024
+    if forced is None:
+        assert plan.step_rows(R) == step
+    ens = _rand_ensemble(T=T, depth=depth, F=F, n_classes=C, bins=255,
+                         missing=missing, cat=cat, seed=len(form) + R)
+    args, kw, opt = _dev_args(ens)
+    Xb = np.random.default_rng(R).integers(0, 255, size=(R, F),
+                                           dtype=np.uint8)
+    Xb[::7, 0] = 254                                  # the NaN bin, if any
+
+    def kernel(tile_r):
+        fn = lambda X: jpp.predict_raw_pallas(          # noqa: E731
+            *args, X, tree_chunk=64, tile_r=tile_r, **kw, **opt)
+        text = str(jax.make_jaxpr(fn)(jnp.asarray(Xb)))
+        return np.asarray(fn(jnp.asarray(Xb))), text
+
+    got, text = kernel(forced)
+    assert f"u8[{step},{F}]" in text                  # the row block
+    want, text = kernel(256)
+    assert f"u8[256,{F}]" in text
+    np.testing.assert_array_equal(got, want)
+
+
 @pytest.mark.parametrize("dtype,operand", [
     (np.uint8, "u8"), (np.int32, "i32"), (np.int8, "i32"),
     (np.int16, "i32"), (np.uint16, "i32")])
@@ -728,51 +787,78 @@ def test_pallas_fits_guard(depth, F, C, optional, tile_r, fits):
                            optional).groups_per_step > 0) is fits
 
 
-@pytest.mark.parametrize("tpad,depth,F,C,optional,groups,g,blocks", [
+@pytest.mark.parametrize("tpad,depth,F,C,optional,groups,g,blocks,step", [
     # The kernel regroups the trees in 128s itself: a padded count that is
     # no multiple of 128 is planned like its next multiple.
-    (64, 6, 28, 1, 2, 1, 1, 1),
-    (192, 6, 28, 1, 2, 2, 2, 1),
-    (1024, 6, 28, 1, 2, 8, 8, 1),     # the bench shape: one block, resident
-    (1088, 6, 28, 1, 2, 9, 9, 1),
+    (64, 6, 28, 1, 2, 1, 1, 1, 1024),
+    (192, 6, 28, 1, 2, 2, 2, 1, 512),
+    (1024, 6, 28, 1, 2, 8, 8, 1, 256),   # the bench shape: one resident block
+    (1088, 6, 28, 1, 2, 9, 9, 1, 256),
     # The edges at which the resident-tables guard turned a model away
     # (AOT-compiled under the real limit, PERF.md section 6, PR 26) are
     # now where one block becomes two, evened out.
-    (1152, 8, 54, 7, 0, 9, 9, 1),
-    (1280, 8, 54, 7, 0, 10, 5, 2),
-    (2816, 6, 54, 7, 1, 22, 22, 1),
+    (1152, 8, 54, 7, 0, 9, 9, 1, 256),
+    (1280, 8, 54, 7, 0, 10, 5, 2, 256),
+    (2816, 6, 54, 7, 1, 22, 22, 1, 256),
     # 7 classes: groups of whole rounds, 126 trees, where the class dots
     # that saves outweigh the groups it adds (PR 34): 2,944 padded trees
     # are 23 groups of 128 and 24 of 126, two blocks of 12 either way
-    (2944, 6, 54, 7, 1, 24, 12, 2),
-    (1536, 6, 60, 7, 2, 12, 12, 1),
-    (1664, 6, 60, 7, 2, 14, 7, 2),    # 13 of 128: two blocks of 7 as well
-    (384, 7, 60, 7, 2, 3, 3, 1),
-    (1024, 7, 28, 1, 0, 8, 8, 1),
-    (1024, 7, 60, 1, 2, 8, 3, 3),
+    (2944, 6, 54, 7, 1, 24, 12, 2, 256),
+    (1536, 6, 60, 7, 2, 12, 12, 1, 256),
+    (1664, 6, 60, 7, 2, 14, 7, 2, 256),  # 13 of 128: two blocks of 7 as well
+    (384, 7, 60, 7, 2, 3, 3, 1, 256),
+    (1024, 7, 28, 1, 0, 8, 8, 1, 256),
+    (1024, 7, 60, 1, 2, 8, 3, 3, 256),
     # ... which both tables cost only by the integer routing (more than
     # 56 features); folded, the same models are planned as with one table
     # (a 13th group of 126 at 63 weight tiles against twelve dots of 6;
     # a 14th against thirteen)
-    (1536, 6, 54, 7, 2, 13, 13, 1),
-    (1664, 6, 54, 7, 2, 14, 14, 1),
-    (2944, 6, 54, 7, 2, 24, 12, 2),
-    (1024, 7, 28, 1, 2, 8, 8, 1),
-    (3520, 8, 54, 7, 2, 28, 6, 5),
-    (128, 8, 28, 1, 2, 1, 1, 1),
+    (1536, 6, 54, 7, 2, 13, 13, 1, 256),
+    (1664, 6, 54, 7, 2, 14, 14, 1, 256),
+    (2944, 6, 54, 7, 2, 24, 12, 2, 256),
+    (1024, 7, 28, 1, 2, 8, 8, 1, 256),
+    (3520, 8, 54, 7, 2, 28, 6, 5, 256),
+    (128, 8, 28, 1, 2, 1, 1, 1, 256),
     # Covertype's own model, 500 rounds x 7 classes: 9 groups fit, so 4
     # blocks, of 7 (not 3 of 9 and one of 1 filled to 9); 28 groups of
     # 128 and 28 of 126
-    (3520, 8, 54, 7, 0, 28, 7, 4),
+    (3520, 8, 54, 7, 0, 28, 7, 4, 256),
     # the trace is bounded whatever the tree count
-    (1 << 20, 6, 28, 1, 0, 8192, 27, 304),
-    (128, 8, 60, 1, 2, 1, 0, 0),      # nothing fits
+    (1 << 20, 6, 28, 1, 0, 8192, 27, 304, 256),
+    (128, 8, 60, 1, 2, 1, 0, 0, 256),      # nothing fits
+    # The rows a grid step takes (PR 42): 256, doubled while the step's MXU
+    # weight tiles, G x tiles a group, times its rows stay within 256 x 256,
+    # 1,024 at most. The three heap cells' shapes: 8 x 32 and 7 x 128
+    # tiles keep 256 rows, the CTR model's one group of 63 takes 1,024 ...
+    (1024, 6, 28, 1, 0, 8, 8, 1, 256),
+    (128, 6, 39, 1, 2, 1, 1, 1, 1024),
+    # ... and between them: one and two groups of 32 tiles 1,024, four
+    # 512; depth 8 in one group (128 tiles) 512, depth 7 1,024; one node
+    # a tile, 127 a group at depth 7: 512
+    (128, 6, 28, 1, 0, 1, 1, 1, 1024),
+    (256, 6, 28, 1, 0, 2, 2, 1, 1024),
+    (512, 6, 28, 1, 0, 4, 4, 1, 512),
+    (128, 8, 28, 1, 0, 1, 1, 1, 512),
+    (128, 7, 28, 1, 0, 1, 1, 1, 1024),
+    (128, 7, 65, 1, 0, 1, 1, 1, 512),
+    # where the kernel's VMEM at the tile is past the budget it is
+    # halved: 257 columns, 1,793; the integer routing of both tables,
+    # charged by the node
+    (128, 6, 256, 1, 0, 1, 1, 1, 1024),
+    (128, 6, 257, 1, 0, 1, 1, 1, 512),
+    (128, 6, 1793, 1, 0, 1, 1, 1, 256),
+    (128, 6, 57, 1, 2, 1, 1, 1, 512),
+    (128, 7, 57, 1, 2, 1, 1, 1, 256),
 ])
-def test_table_plan(tpad, depth, F, C, optional, groups, g, blocks):
+def test_table_plan(tpad, depth, F, C, optional, groups, g, blocks, step):
     """How many tree groups a table block holds (G) and how many blocks a
     row tile walks: the budget's arithmetic, pinned. G is what it was
     before the blocks shared their class dot: `_vmem_bytes` charges a
-    class window a group as it did."""
+    class window a group as it did; and what it was before the step's
+    rows followed from its tiles: G is fitted at 256 rows whatever
+    `tile_rows` turns out to be. A program takes the step where it has
+    sixteen of them, half of it where eight, and a program of 200 rows
+    keeps 256."""
     plan = jpp.table_plan(tpad, depth, F, C, None, optional)
     assert plan[:3] == (groups, g, blocks)
     assert g * blocks >= groups * bool(g)
@@ -783,10 +869,16 @@ def test_table_plan(tpad, depth, F, C, optional, groups, g, blocks):
     shared = plan.class_dots_per_step < g
     assert plan.table_bytes == (blocks * g * nodes + 4 * 128 * C * (
         bool(g) if shared else blocks * g))
-    assert plan.tile_rows == 256
+    assert plan.tile_rows == plan.rows_per_step == step
+    assert [plan.step_rows(r) for r in (200, 16 * step - 1, 16 * step,
+                                        2_000_000)] == [
+        256, max(step // 2, 256), step, step]
     if g:
         assert jpp._vmem_bytes(g, depth, F, C, 256, optional) \
             <= jpp._VMEM_BUDGET_BYTES
+        assert jpp._vmem_bytes(g, depth, F, C, step, optional,
+                               jpp._STEP_ROW_BYTES if step > 256
+                               else jpp._ROW_BYTES) <= jpp._VMEM_BUDGET_BYTES
 
 
 @pytest.mark.parametrize("tpad,depth,F,C,optional,per_group,dots,groups", [
@@ -866,7 +958,8 @@ def test_ensemble_span_says_which_form_served(impl, T, want, routed, F):
     `trees_per_group` and `class_dots_per_step`: the lanes of a group
     that hold trees and the class dots a grid step makes, 1 where the
     block's groups share it; `row_operand_bytes` and
-    `scores_class_major`: the kernel's HBM interface (PR 36)."""
+    `scores_class_major`: the kernel's HBM interface (PR 36);
+    `rows_per_step`: the plan's `tile_rows` (PR 42)."""
     from ddt_tpu.telemetry import annotations as an
 
     ens = _rand_ensemble(T=T, depth=3, F=F, bins=31, seed=40 + T,
@@ -903,6 +996,9 @@ def test_ensemble_span_says_which_form_served(impl, T, want, routed, F):
     # uint8 chunk as it is, the scores class-major.
     assert (counts["row_operand_bytes"], counts["scores_class_major"]) \
         == ((1, 1) if want else (0, 0))
+    # ... and the rows a grid step of a long program takes: these steps
+    # hold 4 to 14 weight tiles (this call's 50 rows run one of 256)
+    assert counts["rows_per_step"] == (1024 if want else 0)
     # ... and how the kernel uses the MXU: 5 features, so two nodes a
     # weight tile, the root's and one a pair of siblings (7 nodes: 4);
     # with a routing table one node a tile.

@@ -259,6 +259,41 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
     np.testing.assert_array_equal(scores, again)
 
 
+@pytest.mark.parametrize("cell,T,depth,F,C,routed,step,tiles", [
+    ("score1000t-100m-1chip", 1000, 6, 28, 1, False, 256, 8 * 32),
+    ("covtype3500t-d8-score-1chip", 3500, 8, 54, 7, False, 256, 7 * 128),
+    ("criteo100t-d6-score-1chip", 100, 6, 39, 1, True, 1024, 1 * 63),
+    # between the cells: four groups of 32 tiles, one of 128
+    ("500 trees", 500, 6, 28, 1, False, 512, 4 * 32),
+    ("depth 8, one group", 100, 8, 28, 1, False, 512, 128),
+])
+def test_the_ensemble_span_says_the_rows_a_step(cell, T, depth, F, C, routed,
+                                                step, tiles):
+    """`rows_per_step` on the `ddt:predict:ensemble` span (PR 42): the
+    rows a grid step of the traversal kernel takes in a long program, the
+    plan's `tile_rows`, by the MXU weight tiles the step holds: 256 in
+    the 1000-tree and the Covertype cell, 1,024 in the CTR cell, whose
+    step is one group of 63. (The span is the cache miss's: the model's
+    tables are built and staged, no row is scored.)"""
+    from ddt_tpu.models.tree import empty_ensemble
+
+    ens = empty_ensemble(
+        T, depth, F, 0.1, 0.0, "softmax" if C > 1 else "logloss", max(C, 2),
+        missing_bin=routed, n_bins=255, cat_features=(0, 1) if routed else ())
+    n_int = (1 << depth) - 1
+    ens.feature[:, :n_int] = np.arange(n_int) % F
+    ens.is_leaf[:, n_int:] = True
+    be = get_backend(TrainConfig(backend="tpu", n_bins=255, max_depth=depth,
+                                 predict_impl="pallas"))
+    *_, plan = be._predict_entry(ens)
+    counts = [s for s in an.recent_spans()
+              if s["name"] == "ddt:predict:ensemble"][-1]["counts"]
+    assert counts["trees"] == T
+    assert counts["groups_per_step"] * counts["mxu_tiles_per_group"] == tiles
+    assert counts["rows_per_step"] == plan.tile_rows == step
+    assert counts["routing_tables"] == 2 * routed
+
+
 @pytest.mark.parametrize("impl", ["pallas", "auto"])
 def test_a_node_list_says_which_form_serves(impl, monkeypatch):
     """A node-list model through the same chunk loop: the `ensemble` span
@@ -782,6 +817,7 @@ def test_cli_predict_prints_phases_ms(tmp_path, capsys):
     # trees, no table block, nothing streamed.
     from ddt_tpu.ops.predict_pallas import PHASES_COUNTS
 
+    assert "rows_per_step" in PHASES_COUNTS
     for count in (*PHASES_COUNTS, "tables_streamed_bytes"):
         assert rec["phases_ms"].pop(count) == 0
     assert sorted(rec["phases_ms"]) == sorted(

@@ -85,6 +85,67 @@ def test_heap_kernel_crosses_hbm_at_the_datas_width(case):
     assert f"tensor<{padded}x" not in text and f"x{padded}x" not in text
 
 
+# The rows a grid step takes in every heap case's program (PR 42): 256,
+# doubled while the step's MXU weight tiles (groups x tiles a group) times
+# its rows stay within 256 x 256, 1,024 at most, halved where the kernel's
+# VMEM at that tile would pass the budget; all of these programs are long
+# enough (200k rows and more) for the whole step.
+HEAP_STEP_ROWS = {
+    "predict/higgs/1000x6": 256,                      # 8 x 32 tiles
+    "predict/50x4/missing+cat": 1024,                 # 1 x 15
+    "predict/covertype/210x6/7classes": 1024,         # 2 x 32
+    "predict/150x6/missing+cat": 512,                 # 2 x 63
+    "predict/higgs/1000x6/missing+cat": 256,          # 8 x 63
+    "predict/criteo/100x6/missing+cat": 1024,         # 1 x 63: the CTR cell
+    "predict/criteo/100x6/missing": 1024,
+    "predict/criteo/100x6/cat": 1024,
+    "predict/56f/130x5/missing": 1024,                # 2 x 31
+    "predict/57f/130x5/missing": 1024,
+    "predict/56f/130x5/missing+cat": 1024,
+    "predict/covertype/3500x8/7classes": 256,         # 7 x 128
+    "predict/covertype/3500x8/7classes/missing": 256,
+    "predict/covertype/3500x8/7classes/missing+cat": 256,
+    "predict/3classes/1215x6": 256,                   # 10 x 32
+    "predict/covertype/350x6/7classes/missing+cat": 256,    # 3 x 63
+    "predict/56f/130x5": 1024,                        # 2 x 16
+    "predict/64f/130x5": 1024,
+    "predict/65f/130x5": 1024,                        # 2 x 31
+    # the rule's edges
+    "predict/higgs/100x6": 1024,                      # 1 x 32
+    "predict/higgs/250x6": 1024,                      # 2 x 32
+    "predict/higgs/500x6": 512,                       # 4 x 32
+    "predict/higgs/100x8": 512,                       # 1 x 128
+    "predict/criteo/100x7/missing+cat": 512,          # 1 x 127
+    # ... and its VMEM's: the widest shape that takes a step, the first
+    # that falls back
+    "predict/256f/100x6": 1024,
+    "predict/257f/100x6": 512,
+    "predict/1792f/100x6": 512,
+    "predict/1793f/100x6": 256,
+    "predict/57f/100x6/missing+cat": 512,     # the integer routing: by
+    "predict/57f/100x7/missing+cat": 256,     # the node (1 x 63, 1 x 127)
+}
+
+
+def test_every_heap_case_names_its_step():
+    assert sorted(HEAP_STEP_ROWS) == sorted(c.name for c in HEAP_CASES)
+
+
+@pytest.mark.parametrize("case", HEAP_CASES, ids=lambda c: c.name)
+def test_heap_kernel_takes_the_planned_step(case):
+    """The row block of the kernel that is lowered is the plan's step,
+    [rows a step, F] uint8, and the result's [C, rows a step]."""
+    fn, shapes = case.build()
+    (_, features), _ = shapes[-1]
+    with device.assume_platform("tpu"):
+        text = str(jax.make_jaxpr(fn)(
+            *[jax.ShapeDtypeStruct(s, d) for s, d in shapes]))
+    step = HEAP_STEP_ROWS[case.name]
+    assert f"Ref<vmem>{{u8[{step},{features}]}}" in text
+    for other in {256, 512, 1024} - {step}:
+        assert f"u8[{other},{features}]" not in text
+
+
 @pytest.mark.parametrize("case", PATH_CASES, ids=lambda c: c.name)
 def test_path_kernel_crosses_hbm_at_the_datas_width(case):
     """The path-matrix kernel's interface is the heap kernel's (PR 37): the
