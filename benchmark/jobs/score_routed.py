@@ -2,8 +2,10 @@
 ROUTED ensemble (a learned direction for missing values and one-vs-rest
 category nodes beside the ordinal compare) over its binned click-log batch:
 host uint8 rows in, host float32 raw margins out, both transfers counted.
-Reports `score_mrows_per_s`: all the rows of the calls that finished over all
-the time of the window.
+Reports `score_routed_mrows_per_s`: `score.Job`'s rate, all the rows of the
+calls that finished over all the time of the window, under a name of its own,
+so that `BENCHMARK.json` can give this cell's rate a bound of its own (PR 46;
+the configuration's file says why, under "bound").
 
 The job fails at once where the routed form of the Pallas traversal kernel
 does not serve the model: before the warm-up call `setup` lowers the scoring
@@ -76,6 +78,10 @@ class Job(score.Job):
         self.Xb = datagen_routed.click_log_bins(
             s["rows"], s["numeric_features"], s["features"], s["n_bins"],
             self.seed)
+
+    def end_to_end(self, win: dict) -> dict:
+        (rate,) = super().end_to_end(win).values()
+        return {"score_routed_mrows_per_s": rate}
 
     # ------------------------------------------------------------------ #
 

@@ -186,6 +186,111 @@ def test_click_log_bins_are_the_stated_distributions():
     assert Xb[:, 13:].max() == 253
 
 
+# ---- PR 46: the cell's rate is a metric of its own ----------------------- #
+
+RATE, ROUTED_RATE = "score_mrows_per_s", "score_routed_mrows_per_s"
+# What each cell read at PR 42, the parent of PR 46 (a cell may read more).
+SHARED = ["traverse_kernel_ms_per_call", "score_prologue_ms",
+          "score_upload_exposed_ms", "score_fetch_tail_ms",
+          "score_first_call_extra_ms", "score_tables_streamed_mb",
+          "score_widen_ms", "score_accumulate_ms", "score_other_device_ms",
+          "score_unscoped_device_ms"]
+PARENT_PER_LAYER = {
+    "score1000t-100m-1chip": SHARED + ["traverse_roofline",
+                                       "score_offdevice_ms"],
+    "covtype3500t-d8-score-1chip": SHARED + ["traverse_mc_roofline"],
+    "criteo100t-d6-score-1chip": SHARED + ["traverse_routed_roofline",
+                                           "score_routing_tables"],
+    "higgs-lgbm500t-255l-score-1chip": SHARED + [
+        "traverse_paths_roofline", "score_path_mxu_tiles_per_tree",
+        "score_select_k_blocks"],
+    "bosch-lgbm500t-255l-score-1chip": SHARED + [
+        "score_routing_tables", "traverse_paths_roofline",
+        "score_path_mxu_tiles_per_tree", "score_select_k_blocks"],
+    "epsilon-catboost8000t-d6-score-1chip": SHARED + [
+        "score_select_k_blocks", "traverse_oblivious_roofline",
+        "score_select_columns_per_tree"],
+}
+
+
+def _manifest() -> dict:
+    return run.load_json(ROOT, "BENCHMARK.json")
+
+
+def _names(manifest, group, cell):
+    return [m["name"] for m in run.metrics_of(manifest, group, cell)]
+
+
+def test_the_cell_reports_one_rate_and_setup(job):
+    cell, manifest = cell_of("score_routed"), _manifest()
+    assert _names(manifest, "end_to_end", cell) == [ROUTED_RATE, "setup_s"]
+    # the job answers with that one name, and the number is `score.Job`'s:
+    # all the rows of the calls that finished over the whole span
+    win = {"walls": [1.25, 1.5, 1.25], "span": 4.125}
+    assert job.end_to_end(win) == {
+        ROUTED_RATE: 3 * job.shapes["rows"] / 4.125 / 1e6}
+    (rate,), (routed,) = ([m for m in manifest["end_to_end"]
+                           if m["name"] == name]
+                          for name in (RATE, ROUTED_RATE))
+    assert routed["workloads"] == [cell] and cell not in rate["workloads"]
+    assert [routed[k] for k in ("unit", "better", "source")] == [
+        rate[k] for k in ("unit", "better", "source")]
+    # the other cells' bound is not this cell's to loosen; its own is a
+    # multiple of 0.005 under the contract's 0.1
+    assert rate["bound"] == 0.01
+    assert rate["bound"] < routed["bound"] <= 0.1
+    assert round(routed["bound"] / 0.005, 9).is_integer()
+
+
+def test_a_whole_run_prints_that_rate_and_no_other(capsys):
+    assert run.main(["--workload", cell_of("score_routed"),
+                     "--seed", "4600000007", "--seconds", "0.1",
+                     "--trace", "0", "--rehearse"]) == 0
+    out = capsys.readouterr().out
+    (line,) = [ln for ln in out.splitlines()
+               if ln.startswith("rehearsal line: ")]
+    line = json.loads(line.partition(": ")[2])
+    assert line["correct"] is True
+    assert sorted(line["metrics"]) == [ROUTED_RATE, "setup_s"]
+    assert line["metrics"][ROUTED_RATE]["unit"] == "Mrows/s"
+    assert line["metrics"][ROUTED_RATE]["value"] > 0
+
+
+@pytest.mark.parametrize("cell", sorted(
+    set(PARENT_PER_LAYER) - {"criteo100t-d6-score-1chip"}))
+def test_the_other_cells_metrics_are_the_parents(cell):
+    manifest = _manifest()
+    assert _names(manifest, "end_to_end", cell) == [RATE, "setup_s"]
+    listed = run.metrics_of(manifest, "per_layer", cell)
+    assert set(PARENT_PER_LAYER[cell]) <= {m["name"] for m in listed}
+    assert not [m["name"] for m in listed if m["name"].endswith(".routed")]
+    assert {m["moves"] for m in listed} == {RATE, "setup_s"}
+
+
+def test_the_cell_reads_every_per_layer_metric_the_parent_read():
+    """Under its own name where the cell alone reads it, and as
+    `<name>.routed`, the same reader with the same arguments, where other
+    cells read it too: a metric moves ONE end-to-end metric, and its cells
+    all report that one."""
+    cell, manifest = cell_of("score_routed"), _manifest()
+    listed = run.metrics_of(manifest, "per_layer", cell)
+    assert set(PARENT_PER_LAYER[cell]) <= {
+        m["name"].removesuffix(".routed") for m in listed}
+    assert {m["moves"] for m in listed} == {ROUTED_RATE, "setup_s"}
+    same = ("unit", "better", "source", "layer")
+    for m in listed:
+        if m["name"].endswith(".routed"):
+            assert m["workloads"] == [cell]
+            (twin,) = [t for t in manifest["per_layer"]
+                       if t["name"] == m["name"].removesuffix(".routed")]
+            assert cell not in twin["workloads"] and twin["moves"] == RATE
+            assert [m[k] for k in same] == [twin[k] for k in same]
+            assert run.load_json(run.HERE, "layer_metrics",
+                                 m["name"] + ".json") \
+                == run.load_json(run.HERE, "layer_metrics",
+                                 twin["name"] + ".json")
+
+
 def test_no_chip_no_result_line(capsys, monkeypatch):
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     rc = run.main(["--workload", cell_of("score_routed"), "--seed", "1",

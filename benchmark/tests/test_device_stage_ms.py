@@ -202,14 +202,19 @@ def test_the_map_comes_from_the_program_when_no_test_gives_it(monkeypatch):
 
 
 def test_the_metric_files_name_this_reader():
-    """The four metrics this reader serves, as BENCHMARK.json lists them."""
+    """The four metrics this reader serves, as BENCHMARK.json lists them:
+    each once for the cells that report `score_mrows_per_s` and once, as
+    `<name>.routed` with the same reader and arguments, for the routed cell,
+    whose rate is a metric of its own (PR 46)."""
     import json
     import os
 
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(os.path.dirname(here), "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    cells = [w["name"] for w in manifest["workloads"]]
+    rate_of = {cell: m["name"] for m in manifest["end_to_end"]
+               if m["name"] != "setup_s" for cell in m["workloads"]}
+    assert sorted(rate_of) == sorted(w["name"] for w in manifest["workloads"])
     mine = {}
     for m in manifest["per_layer"]:
         with open(os.path.join(here, "layer_metrics",
@@ -217,17 +222,23 @@ def test_the_metric_files_name_this_reader():
             spec = json.load(f)
         if spec["reader"] == "device_stage_ms":
             mine[m["name"]] = (m, spec["args"])
-    assert sorted(mine) == ["score_accumulate_ms", "score_other_device_ms",
-                            "score_unscoped_device_ms", "score_widen_ms"]
-    ctx = stage_context(planes())
     want = {"score_widen_ms": WIDEN, "score_accumulate_ms": ACCUMULATE,
             "score_unscoped_device_ms": COPY,
             "score_other_device_ms":
                 WIDEN + ACCUMULATE + COPY + SLICES + UNFLATTEN}
+    assert sorted(mine) == sorted(
+        [*want, *(name + ".routed" for name in want)])
+    ctx = stage_context(planes())
+    cells = []
     for name, (m, args) in mine.items():
-        assert (m["unit"], m["better"], m["source"], m["moves"]) == (
-            "ms", "lower", "device_trace", "score_mrows_per_s")
+        assert (m["unit"], m["better"], m["source"]) == (
+            "ms", "lower", "device_trace")
         assert m["layer"] == "ops/predict.py scoring program around the kernel"
-        assert m["workloads"] == cells
+        # a metric lists cells of ONE rate, the one it moves
+        assert {rate_of[cell] for cell in m["workloads"]} == {m["moves"]}
+        assert args == mine[name.removesuffix(".routed")][1]
         assert device_stage_ms.read(ctx, args) \
-            == pytest.approx(want[name], abs=1e-9)
+            == pytest.approx(want[name.removesuffix(".routed")], abs=1e-9)
+        cells += m["workloads"]
+    # and every cell reads each of the four, under one of its two names
+    assert sorted(cells) == sorted(4 * list(rate_of))
