@@ -20,7 +20,11 @@ PATH-MATRIX form of the traversal kernel, its feature select answering two
 nodes a result lane; and small node lists at 28, 64, 65, 129 and 968 columns
 (the last width that packs so, and the first that does not), with and
 without learned NaN directions, hold that kernel to its jax.numpy twin and
-to the node walk in every bit, at 1 to 4,999 rows. And one of CatBoost's
+to the node walk in every bit, at 1 to 4,999 rows. And an AVERAGED FOREST
+(12 trees of 700-1,400 leaves with 10-class leaf vectors over 784 columns:
+every tree cut into sub-trees of 256 lanes, chained) scores 100,000 rows
+through the SUB-TREE form of that kernel, `subtrees_per_tree` > 1 on its
+spans, against the walk of the uncut trees. And one of CatBoost's
 Epsilon model's shape (8000 OBLIVIOUS trees of depth 6, 2000 dense columns)
 scores 300,000 rows, served by the oblivious form of the traversal kernel (6
 select columns a tree, never the 63-node expansion); small oblivious
@@ -68,6 +72,7 @@ SCORE_CHECK_ROWS = 50_000
 MC_ROUNDS, MC_ROWS = 500, 100_000      # the 7-class scoring phase
 ROUTED_ROWS = 1_000_000                # the routed scoring phase
 LEAFWISE_ROWS = 200_000                # the node-list scoring phase
+FOREST_ROWS = 100_000                  # the averaged-forest scoring phase
 OBLIVIOUS_ROWS = 300_000               # the oblivious phase: three chunks
 SCORE_TOL = dict(rtol=3e-4, atol=3e-4)      # as __graft_entry__'s oracle check
 # Chip-vs-oracle training parity: the bounds the earlier chip runs measured
@@ -464,6 +469,72 @@ def score_node_list_grid(overrides: dict) -> None:
            wall=time.perf_counter() - t0)
 
 
+def score_forest(overrides: dict, rows: int) -> None:
+    """An averaged forest through `api.predict`: 12 random trees of 700 to
+    1,400 leaves over 784 columns of 256 bins with a 10-class vector a leaf
+    (a node list with vector leaves, loss "mean"). Asserts that the
+    SUB-TREE form of the path kernel served it by the auto dispatch
+    (`subtrees_per_tree` > 1 and `leaf_columns` 10 on its spans: every tree
+    cut into sub-trees of 256 lanes and chained, the class dot's three
+    bfloat16 pieces), holds the scores [rows, 10] to the walk of the uncut
+    trees (reference/numpy_predict.predict_proba_node_list, float64), and
+    holds the compiled kernel and its jax.numpy twin bit-equal on dyadic
+    leaf vectors over 8 trees (every sum and the mean exact)."""
+    from ddt_tpu import api
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.models.tree import random_node_list
+    from ddt_tpu.reference import numpy_predict
+    from ddt_tpu.telemetry.annotations import root_spans
+
+    T, F, C, bins = 12, 784, 10, 256
+    rng = np.random.default_rng(SEED + 47)
+    ens = random_node_list(rng, T, (700, 1400), F, bins, leaf_columns=C)
+    Xb = rng.integers(0, bins, size=(rows, F), dtype=np.uint8)
+    cfg = TrainConfig(n_bins=bins, backend="tpu", **overrides)
+    comp = Compiles()
+    t0 = time.perf_counter()
+    scores = api.predict(ens, Xb, binned=True, raw=True, cfg=cfg)
+    wall = time.perf_counter() - t0
+    timing(f"forest predict, {rows} rows x {T} trees x "
+           f"{ens.n_leaves.min()}-{ens.n_leaves.max()} leaves x {C} classes "
+           f"(deepest {ens.deepest_leaf}), first call", wall=wall,
+           **comp.split(wall))
+    assert scores.shape == (rows, C) and scores.dtype == np.float32, \
+        (scores.shape, scores.dtype)
+    assert np.isfinite(scores).all(), "non-finite scores"
+    root = root_spans("predict")[-1]
+    built = {s["name"]: s["counts"] for s in root["spans"]}[
+        "ddt:predict:ensemble"]
+    say(f"forest predict: ddt:predict:ensemble {built}; root "
+        f"subtrees_per_tree={root['counts']['subtrees_per_tree']} "
+        f"tables_streamed_bytes={root['counts']['tables_streamed_bytes']}")
+    assert built["node_list"] == root["counts"]["node_list"] == 1, built
+    assert built["subtrees_per_tree"] > 1, "no tree was cut"
+    assert built["subtrees_per_tree"] == root["counts"]["subtrees_per_tree"]
+    assert (built["subtree_lanes"], built["leaf_columns"],
+            built["class_dot_passes"], built["select_k_blocks"]) == (
+                256, C, 3, 7), built
+    assert built["trees_per_step"] > 0, "the path kernel did not serve"
+    assert_compiled_kernel(cfg, ens, rows, "forest")
+    n = min(2_000, rows)
+    want = numpy_predict.predict_proba_node_list(ens, Xb[:n])
+    gap = float(np.abs(scores[:n] - want).max())
+    say(f"forest scores: {n} rows against reference/numpy_predict "
+        f"(float64), max |diff| = {gap:.2e} (<= 1e-5)")
+    assert gap <= 1e-5, gap
+    exact = random_node_list(rng, 8, (300, 900), 129, bins, dyadic=True,
+                             leaf_columns=3)
+    Xe = rng.integers(0, bins, size=(4_999, 129), dtype=np.uint8)
+    kernel = api.predict(exact, Xe, binned=True, raw=True, cfg=cfg)
+    twin = api.predict(exact, Xe, binned=True, raw=True, cfg=TrainConfig(
+        n_bins=bins, backend="tpu", predict_impl="onehot"))
+    walk = numpy_predict.predict_proba_node_list(exact, Xe)
+    assert np.array_equal(kernel, twin) and np.array_equal(
+        kernel, walk.astype(np.float32)), "dyadic forest not bit-equal"
+    say("forest grid: kernel, twin and walk bit-equal on 8 dyadic trees x "
+        "4,999 rows x 129 columns x 3 classes")
+
+
 def score_oblivious(overrides: dict, rows: int) -> None:
     """CatBoost's Epsilon model's shape through `api.predict`: 8000 random
     oblivious trees of depth 6 over 2000 dense columns. Asserts that the
@@ -777,6 +848,8 @@ def main(argv=None) -> int:
     score_node_list(overrides, LEAFWISE_ROWS // 100 if args.rehearse
                     else LEAFWISE_ROWS)
     score_node_list_grid(overrides)
+    score_forest(overrides, FOREST_ROWS // 100 if args.rehearse
+                 else FOREST_ROWS)
     score_oblivious(overrides, OBLIVIOUS_ROWS // 100 if args.rehearse
                     else OBLIVIOUS_ROWS)
     score_oblivious_grid(overrides)

@@ -337,7 +337,13 @@ def predict(
     built via parallel.mesh.make_row_mesh and the batch is row-sharded
     over it — trees replicate, each chip traverses its own rows, no
     collectives (the MULTICHIP dryrun's phase-4 path, now public).
-    Ignored when an explicit `backend`/`cfg` already selects one."""
+    Ignored when an explicit `backend`/`cfg` already selects one.
+
+    An averaged forest (a `NodeListEnsemble` with vector leaves, `loss`
+    "mean": `models/sklearn_io.from_sklearn`) answers float32
+    `[rows, classes]`, the mean over the trees of the reached leaves'
+    vectors, with `raw` or without (it has no link function); its argmax
+    is the forest's class."""
     if n_partitions is not None and n_partitions > 1 \
             and backend is None and cfg is None:
         backend = _row_mesh_backend(n_partitions)

@@ -2002,12 +2002,21 @@ class TPUDevice(DeviceBackend):
                 self.cfg.predict_impl)
         use_pallas = self._use_pallas
         missing_routes = ce.missing_bin_value >= 0
+        # The sub-tree form: the tables' entries are sub-trees, a fourth
+        # table says what their exits are, and vector leaves answer
+        # [rows, C].
+        classes = ce.leaf_columns
+        chain = predict_paths.chain_of(
+            ce.n_trees, classes, ce.leaves.shape[2]) if ce.chained else None
         plan = predict_paths.path_plan(
-            ce.n_trees, ce.lanes, ens.n_features, ce.deepest_leaf,
+            ce.n_subtrees or ce.n_trees, ce.lanes, ens.n_features,
+            ce.deepest_leaf,
             served=predict_ops.resolve_use_pallas(
-                use_pallas, True, 0, ens.n_features, 1,
-                path_lanes=ce.lanes),
-            missing_routes=missing_routes, row_dtype=self.PREDICT_ROW_DTYPE)
+                use_pallas, True, 0, ens.n_features, classes,
+                path_lanes=ce.lanes,
+                path_exit_lanes=chain.exit_lanes if chain else 0),
+            missing_routes=missing_routes, row_dtype=self.PREDICT_ROW_DTYPE,
+            chain=chain, widest_tree=ce.widest_tree)
         # What every chunk's program would otherwise make of the tables is
         # made here, once a model: the select that answers two nodes a lane
         # with its shifted thresholds (`pack_select`), and the trees that
@@ -2016,29 +2025,42 @@ class TPUDevice(DeviceBackend):
         tables = ce.arrays()
         if plan.select_nodes_per_lane == 2:
             tables = (*predict_paths.pack_select(
-                ce.sel, ce.planes, ens.n_features, xp=np), ce.paths)
+                ce.sel, ce.planes, ens.n_features, xp=np), *tables[2:])
         fill = ((0, max(0, plan.trees_per_step * plan.table_blocks
-                        - ce.n_trees)), (0, 0), (0, 0))
+                        - len(ce.sel))), (0, 0), (0, 0))
         ens_dev = tuple(
             self._put(np.pad(a, fill, constant_values=v) if fill[0][1]
                       else a, self._named(self.layout.replicated()))
-            for a, v in zip(tables, (0, -1.0, 0)))
+            for a, v in zip(tables, (0, -1.0, 0, 0)))
 
         # Bound here: fn0 outlives this call in the stage registry, and
         # must not hold the host copy of the path tables (78 MB at 500
-        # trees x 255 leaves).
-        learning_rate, base = ce.learning_rate, ce.base_score
+        # trees x 255 leaves; 1.35 GB at 100 trees x 4,000 vector leaves).
+        static = dict(learning_rate=ce.learning_rate, base=ce.base_score,
+                      use_pallas=use_pallas, missing_routes=missing_routes)
+        if chain:
+            static.update(n_trees=ce.n_trees, leaf_columns=classes,
+                          mean=ce.mean)
 
+        # (two functions: the uncut form keeps its program's parameter
+        # names, so its HLO is what it was)
         def fn0(sel, planes, paths, Xc,
                 entry=predict_ops.predict_raw_effective_paths):
-            return entry(sel, planes, paths, Xc, learning_rate=learning_rate,
-                         base=base, use_pallas=use_pallas,
-                         missing_routes=missing_routes)
+            return entry(sel, planes, paths, Xc, **static)
+
+        def fn0_chain(sel, planes, paths, leaves, Xc,
+                      entry=predict_ops.predict_raw_effective_paths):
+            return entry(sel, planes, paths, Xc, leaves=leaves, **static)
+
+        if chain:
+            fn0 = fn0_chain
 
         self._stage_scoring_program(
             predict_ops.predict_raw_effective_paths, fn0, ens_dev,
             ens.n_features)
-        return self._row_sharded(fn0, 3, 1), ens_dev, "f32", 1, plan
+        # an averaged forest answers [rows, C] whatever C, one column too
+        return (self._row_sharded(fn0, len(ens_dev), 2 if ce.mean else 1),
+                ens_dev, "f32", classes, plan)
 
     def _build_oblivious_fn(self, ens, ce: CompiledOblivious):
         """_build_predict_fn for an OBLIVIOUS ensemble: its group tables up,

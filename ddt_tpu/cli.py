@@ -1100,7 +1100,10 @@ def main(argv: list[str] | None = None) -> int:
                                    n_features=ens.n_features)
         from ddt_tpu.serve.engine import TIER_IMPL
 
-        cfg = TrainConfig(backend=args.backend, loss=ens.loss,
+        # (an averaged forest's "mean" is no training loss; scoring reads
+        # the ensemble's own, never the configuration's)
+        cfg = TrainConfig(backend=args.backend,
+                          loss=ens.loss if ens.loss in LOSSES else "mse",
                           n_classes=max(ens.n_classes, 2),
                           n_partitions=max(1, args.partitions),
                           predict_impl=TIER_IMPL.get(args.quantized,

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import typing
 
 import numpy as np
 
@@ -595,6 +596,16 @@ class CompiledEnsemble:
 # Lanes a node-list tree's nodes and leaves are padded to in its compiled
 # tables: one vreg's lanes, one MXU weight tile's columns.
 PATH_LANES = 128
+# A tree of more lanes than this is CUT into connected sub-trees
+# (`CompiledNodeList`): one path matrix costs (W/128)^2 MXU weight tiles of
+# resolve, 16 at 512 lanes (the widest the path kernel was measured at) and
+# 64 at 1,024, where four sub-trees of 256 cost 16 and 8 more for the chain.
+PATH_UNCUT_LANES = 4 * PATH_LANES
+# ... and the lanes of a sub-tree: at most 255 internal nodes and 256
+# exits. Two weight tiles wide: at 128 a sub-tree's fixed costs (the chain
+# step, a leaf table of at least a tile) are paid twice as often and the
+# cut fills its sub-trees no better; at 512 the resolve doubles a node.
+SUBTREE_LANES = 2 * PATH_LANES
 
 
 @dataclasses.dataclass
@@ -616,26 +627,35 @@ class NodeListEnsemble:
     entries here and 2^21 heap slots in `TreeEnsemble`. The trainer writes
     heaps; a node list is an import (`models/lightgbm_io.py`), a conversion
     (`from_heap`) or hand-built. Ordinal splits (with or without the NaN
-    directions) and one output column only: category sets and several
-    classes have no field here, and `from_lightgbm_text` / `from_heap` /
-    the constructor refuse them by name (the heap layout serves them).
+    directions) only: category sets, and several classes as softmax's
+    round-major trees of one column each, have no field here, and
+    `from_lightgbm_text` / `from_heap` / the constructor refuse them by
+    name (the heap layout serves them).
 
-    Node arrays are [T, N] and `leaf_value` [T, L], N and L the widest
-    tree's counts; a tree's unused slots hold feature -1, children 0 and
-    value 0 and are never visited."""
+    VECTOR LEAVES, the averaged forest (`loss` "mean"; an import of
+    `models/sklearn_io.py` or hand-built): `leaf_value` is [T, L, C], a
+    leaf holds C output columns (a class distribution, or C regression
+    targets; C = 1 is [T, L, 1]) and the score is the MEAN over the trees
+    of the reached leaves' vectors, float32 [rows, C]: no link function, no
+    learning rate, no base score (both refused unless 1 and 0).
+    `to_lightgbm_text` of vector leaves is refused by name.
+
+    Node arrays are [T, N] and `leaf_value` [T, L] (or [T, L, C]), N and L
+    the widest tree's counts; a tree's unused slots hold feature -1,
+    children 0 and value 0 and are never visited."""
 
     feature: np.ndarray        # int32  [T, N] split feature (-1: unused)
     threshold_bin: np.ndarray  # int32  [T, N] split bin (go left if <=)
     threshold_raw: np.ndarray  # float32 [T, N] raw-value threshold
     left_child: np.ndarray     # int32  [T, N] node index, or ~leaf
     right_child: np.ndarray    # int32  [T, N]
-    leaf_value: np.ndarray     # float32 [T, L]
+    leaf_value: np.ndarray     # float32 [T, L], or [T, L, C]: vector leaves
     n_leaves: np.ndarray       # int32  [T] leaves of each tree (>= 1)
     split_gain: np.ndarray     # float32 [T, N]
     n_features: int
     learning_rate: float
     base_score: float
-    loss: str                  # logloss | mse
+    loss: str                  # logloss | mse | mean (vector leaves)
     n_classes: int = 2
     has_raw_thresholds: bool = False
     # False: threshold_bin is not filled yet (an import carries raw
@@ -660,6 +680,17 @@ class NodeListEnsemble:
                 "a node-list ensemble scores ONE output column: several "
                 "classes (softmax, round-major trees) are not supported in "
                 "this layout; the heap layout (TreeEnsemble) serves them")
+        if (self.loss == "mean") != self.vector_leaves or (
+                self.vector_leaves and (self.learning_rate != 1.0
+                                        or self.base_score != 0.0
+                                        or self.leaf_columns < 1)):
+            raise ValueError(
+                "a node-list ensemble with vector leaves (leaf_value "
+                "[trees, leaves, columns]) is an averaged forest: loss "
+                "'mean', learning_rate 1 and base_score 0, and loss 'mean' "
+                f"needs vector leaves; got loss {self.loss!r}, leaf_value "
+                f"{self.leaf_value.shape}, learning_rate "
+                f"{self.learning_rate}, base_score {self.base_score}")
         T, N = self.feature.shape
         L = self.leaf_value.shape[1]
         if self.n_leaves.shape != (T,) or int(self.n_leaves.min(
@@ -681,6 +712,17 @@ class NodeListEnsemble:
         return int(self.feature.shape[0])
 
     @property
+    def vector_leaves(self) -> bool:
+        """Whether a leaf holds a vector (`leaf_value` [T, L, C]) and the
+        score is the mean over the trees: the averaged forest."""
+        return self.leaf_value.ndim == 3
+
+    @property
+    def leaf_columns(self) -> int:
+        """Output columns a leaf holds: C of vector leaves, else 1."""
+        return int(self.leaf_value.shape[2]) if self.vector_leaves else 1
+
+    @property
     def missing_routes(self) -> bool:
         """Whether NaN rows follow learned directions (the heap's rule:
         the reserved bin AND the directions; else it is a bin like any)."""
@@ -697,14 +739,12 @@ class NodeListEnsemble:
         return np.arange(self.feature.shape[1])[None, :] \
             < (self.n_leaves[:, None] - 1)
 
-    def path_matrix(self) -> "tuple[np.ndarray, np.ndarray]":
-        """(signed path matrix int8 [T, N, L], path length int32 [T, L]):
-        `P[t, n, l]` is +1 where leaf l lies in node n's RIGHT subtree, -1
-        where in its LEFT, 0 elsewhere; `len[t, l]` counts the nodes on
-        leaf l's path (-1: no such leaf). Walking every leaf up to the
-        root also proves the lists are trees: each node and leaf has one
-        parent and the root is reached. Not cached: node arrays may be
-        mutated in place (0.4 s for 500 trees of 255 leaves)."""
+    def _parents(self) -> tuple:
+        """(node_parent [T, N], node_side [T, N], leaf_parent [T, L],
+        leaf_side [T, L]): the node above every node and leaf (-1: none)
+        and the side it hangs on (-1 left, +1 right), from the child
+        arrays; proves on the way that every node and leaf has exactly one
+        parent and no child index leaves its tree."""
         T, N = self.feature.shape
         L = self.leaf_value.shape[1]
         n_int = self.n_leaves.astype(np.int64) - 1
@@ -735,26 +775,58 @@ class NodeListEnsemble:
                 not np.array_equal(seen_n[:, 1:] == 1, live[:, 1:]):
             raise ValueError("node list: not every node and leaf has "
                              "exactly one parent")
-        P = np.zeros((T, N, L), np.int8)
+        return node_parent, node_side, leaf_parent, leaf_side
+
+    def _walk_up(self, visit=None) -> np.ndarray:
+        """Every leaf walked up to its tree's root, all leaves a step:
+        `visit(tt, ll, node, sign)` sees each (tree, leaf, node on its
+        path, side the leaf lies on) once. Returns the nodes passed, int32
+        [T, L] (-1: no such leaf). A walk longer than the node count is a
+        cycle among the nodes."""
+        T, N = self.feature.shape
+        L = self.leaf_value.shape[1]
+        node_parent, node_side, leaf_parent, leaf_side = self._parents()
+        has_leaf = np.arange(L)[None, :] < self.n_leaves[:, None]
         plen = np.where(has_leaf, 0, -1).astype(np.int32)
-        tt, ll = np.nonzero(has_leaf & ~lone)
+        tt, ll = np.nonzero(has_leaf & ~(self.n_leaves == 1)[:, None])
         cur, sign = leaf_parent[tt, ll], leaf_side[tt, ll]
         for _ in range(N + 1):
             if not len(tt):
                 break
-            P[tt, cur, ll] = sign
+            if visit is not None:
+                visit(tt, ll, cur, sign)
             plen[tt, ll] += 1
+            up = node_parent[tt, cur] >= 0
             sign, cur = node_side[tt, cur], node_parent[tt, cur]
-            up = cur >= 0
             tt, ll, cur, sign = tt[up], ll[up], cur[up], sign[up]
         else:
             raise ValueError("node list: a cycle among the nodes")
-        return P, plen
+        return plen
+
+    def path_matrix(self) -> "tuple[np.ndarray, np.ndarray]":
+        """(signed path matrix int8 [T, N, L], path length int32 [T, L]):
+        `P[t, n, l]` is +1 where leaf l lies in node n's RIGHT subtree, -1
+        where in its LEFT, 0 elsewhere; `len[t, l]` counts the nodes on
+        leaf l's path (-1: no such leaf). Walking every leaf up to the
+        root also proves the lists are trees: each node and leaf has one
+        parent and the root is reached. Not cached: node arrays may be
+        mutated in place (0.4 s for 500 trees of 255 leaves). DENSE over
+        the whole tree: what compiles a tree of `PATH_UNCUT_LANES` lanes at
+        most, and the tests; a larger tree is cut first (`cut_subtrees`)
+        and no [N, L] matrix of it is ever made."""
+        T, N = self.feature.shape
+        P = np.zeros((T, N, self.leaf_value.shape[1]), np.int8)
+
+        def visit(tt, ll, cur, sign):
+            P[tt, cur, ll] = sign
+
+        return P, self._walk_up(visit)
 
     @property
     def deepest_leaf(self) -> int:
-        """Nodes on the longest root-to-leaf path of any tree."""
-        return int(self.path_matrix()[1].max(initial=0))
+        """Nodes on the longest root-to-leaf path of any tree (a walk up
+        from the leaves: no path matrix is built)."""
+        return int(self._walk_up().max(initial=0))
 
     max_depth = deepest_leaf       # what `cli inspect` prints of a heap
 
@@ -777,6 +849,8 @@ class NodeListEnsemble:
         if self.missing_routes:
             h.update(np.ascontiguousarray(self.default_left).tobytes())
             h.update(repr(("missing_bin", self.n_bins)).encode())
+        if self.vector_leaves:      # [T, L, C] and [T, L * C] hash alike
+            h.update(repr(("leaf_columns", self.leaf_columns)).encode())
         return h.hexdigest()
 
     def compile(self, tree_chunk: int = 64) -> "CompiledNodeList":
@@ -821,14 +895,21 @@ class NodeListEnsemble:
         return ~cur
 
     def predict_raw(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
-        """Raw (margin) scores [R], float32."""
+        """Raw (margin) scores [R], float32; of vector leaves the mean over
+        the trees of the reached leaves' vectors, float32 [R, C]."""
         leaf = self._leaf_np(np.asarray(X), binned)
+        if self.vector_leaves:
+            total = np.zeros((leaf.shape[1], self.leaf_columns), np.float32)
+            for t in range(self.n_trees):
+                total += self.leaf_value[t, leaf[t]]
+            return total / np.float32(self.n_trees)
         vals = np.take_along_axis(self.leaf_value, leaf, axis=1)
         vals = vals * np.float32(self.learning_rate)
         return (self.base_score + vals.sum(axis=0)).astype(np.float32)
 
     def predict(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
-        """Probability predictions (or raw values for mse)."""
+        """Probability predictions (or raw values for mse; the mean
+        vectors of an averaged forest, whose argmax is its class)."""
         from ddt_tpu.utils.metrics import predict_proba_np
 
         return predict_proba_np(self.predict_raw(X, binned=binned),
@@ -883,7 +964,10 @@ class NodeListEnsemble:
         def walk(ref: int, depth: int) -> None:
             pad = "  " * depth
             if ref < 0:
-                lines.append(f"{pad}leaf={self.leaf_value[t, ~ref]:+.6f}")
+                v = self.leaf_value[t, ~ref]
+                lines.append(f"{pad}leaf=" + (
+                    "[" + " ".join(f"{c:.6g}" for c in v) + "]"
+                    if self.vector_leaves else f"{v:+.6f}"))
                 return
             thr = (f" (<= {self.threshold_raw[t, ref]:.6g})"
                    if self.has_raw_thresholds else "")
@@ -903,6 +987,11 @@ class NodeListEnsemble:
         """LightGBM model.txt rendering (models/lightgbm_io.py)."""
         from ddt_tpu.models.lightgbm_io import to_lightgbm_text
 
+        if self.vector_leaves:
+            raise ValueError(
+                "to_lightgbm_text: LightGBM's model text holds one value a "
+                "leaf; an averaged forest's vector leaves (leaf_value "
+                f"{self.leaf_value.shape}) have no rendering there")
         return to_lightgbm_text(self, feature_names=feature_names)
 
     _ARRAYS = (("feature", np.int32), ("threshold_bin", np.int32),
@@ -978,10 +1067,13 @@ def ensemble_from_dict(
 
 def _refuse_routes(where: str, *, categories: bool,
                    classes: bool) -> None:
-    """The one list of what a node list cannot carry, named."""
+    """The one list of what a node list cannot carry, named. (Several
+    output columns as VECTOR LEAVES of an averaged forest it carries:
+    `NodeListEnsemble`.)"""
     for has, what in (
             (categories, "category-set (one-vs-rest / bitset) nodes"),
-            (classes, "several classes (softmax, round-major trees)")):
+            (classes, "several classes as softmax's round-major trees of "
+                      "one column each")):
         if has:
             raise ValueError(
                 f"{where}: the model needs the node-list layout (a tree "
@@ -993,11 +1085,13 @@ def _refuse_routes(where: str, *, categories: bool,
 def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
     """A NodeListEnsemble from per-tree lists: `trees[t] = (nodes, leaves)`,
     `nodes[n] = (feature, threshold_bin, threshold_raw, gain, left,
-    right)`, `leaves[l]` the leaf's value. A seventh entry on the nodes
-    is `default_left` (every node has it, or none)."""
+    right)`, `leaves[l]` the leaf's value, or its vector (every leaf of
+    every tree, or none: `leaf_value` is then [T, L, C]). A seventh entry
+    on the nodes is `default_left` (every node has it, or none)."""
     T = len(trees)
     N = max(1, max(len(n) for n, _ in trees))
     L = max(len(lv) for _, lv in trees)
+    columns = np.shape(trees[0][1])[1:]     # () or (C,)
     out = dict(
         feature=np.full((T, N), -1, np.int32),
         threshold_bin=np.zeros((T, N), np.int32),
@@ -1005,7 +1099,7 @@ def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
         split_gain=np.zeros((T, N), np.float32),
         left_child=np.zeros((T, N), np.int32),
         right_child=np.zeros((T, N), np.int32),
-        leaf_value=np.zeros((T, L), np.float32),
+        leaf_value=np.zeros((T, L) + columns, np.float32),
         n_leaves=np.asarray([len(lv) for _, lv in trees], np.int32))
     keys = ("feature", "threshold_bin", "threshold_raw", "split_gain",
             "left_child", "right_child")
@@ -1022,18 +1116,27 @@ def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
 
 def random_node_list(rng, n_trees: int, n_leaves: int, n_features: int,
                      n_bins: int = 255, dyadic: bool = False,
-                     missing: bool = False, **meta) -> NodeListEnsemble:
+                     missing: bool = False, leaf_columns: int = 0,
+                     **meta) -> NodeListEnsemble:
     """A random leaf-wise ensemble for tests, chip_smoke.py and the compile
     check (no trainer grows one): a random leaf is split until `n_leaves`
     are there, so depths are uneven (about 20 levels at 255 leaves);
     features and threshold bins uniform; leaf values N(0, 1), or eighths in
     -2..2 (`dyadic`: sums of them round nowhere). `missing`: bin n_bins-1
     is the NaN bin, thresholds lie in the value bins below it and every
-    node's default direction is a fair coin."""
+    node's default direction is a fair coin. `leaf_columns` C > 0: vector
+    leaves [T, L, C] of an averaged forest (`loss` "mean"), and `n_leaves`
+    may be a range (lo, hi): each tree draws its own count."""
     trees = []
+    shape = (leaf_columns,) if leaf_columns else ()
+    if leaf_columns:
+        meta = dict(learning_rate=1.0, base_score=0.0, loss="mean",
+                    n_classes=leaf_columns) | meta
     for _ in range(n_trees):
         nodes, where = [], [None]        # leaf -> (parent node, child slot)
-        for _ in range(n_leaves - 1):
+        leaves = n_leaves if np.ndim(n_leaves) == 0 else int(
+            rng.integers(n_leaves[0], n_leaves[1] + 1))
+        for _ in range(leaves - 1):
             leaf, n = int(rng.integers(len(where))), len(nodes)
             nodes.append([int(rng.integers(n_features)),
                           int(rng.integers(n_bins - 1 - missing)), 0.0, 0.0,
@@ -1043,10 +1146,99 @@ def random_node_list(rng, n_trees: int, n_leaves: int, n_features: int,
                 nodes[where[leaf][0]][where[leaf][1]] = n
             where[leaf] = (n, 4)
             where.append((n, 5))
-        trees.append((nodes, rng.integers(-16, 17, n_leaves) / 8.0 if dyadic
-                      else rng.standard_normal(n_leaves)))
+        trees.append((nodes, rng.integers(-16, 17, (leaves,) + shape) / 8.0
+                      if dyadic else rng.standard_normal((leaves,) + shape)))
     return node_list_from_trees(trees, n_features=n_features, n_bins=n_bins,
                                 missing_bin=missing, **meta)
+
+
+class SubtreeCut(typing.NamedTuple):
+    """A node list's trees cut into connected sub-trees (`cut_subtrees`)."""
+
+    n_subtrees: np.ndarray     # int64 [T] sub-trees of each tree (>= 1)
+    root: np.ndarray           # bool  [T, N] the nodes that root a sub-tree
+    subtree: np.ndarray        # int32 [T, N] a node's sub-tree, numbered in
+    #   its tree by the pre-order of the roots (-1: no such node)
+    lane: np.ndarray           # int32 [T, N] its number in the sub-tree, in
+    #   pre-order (the root 0)
+
+
+def cut_subtrees(ens: NodeListEnsemble, lanes: int) -> SubtreeCut:
+    """Every tree cut into connected SUB-TREES of at most `lanes` - 1
+    internal nodes, so of at most `lanes` EXITS (an exit is a child that
+    is a leaf, or a link: a child that roots another sub-tree). A
+    partition: every internal node lies in one sub-tree, every sub-tree is
+    connected and hangs by its root alone, and the tree's root roots
+    sub-tree 0; a parent sub-tree is numbered before its children.
+
+    Bottom-up (Kundu and Misra, 1977: the fewest parts of a tree under a
+    bound on a part's weight): a node keeps its children's remainders
+    while they fit beside it and otherwise cuts the HEAVIER child off as
+    a root of its own. A tree of one leaf is one sub-tree of no node.
+    No [N, L] matrix of a whole tree is made (a walk of the child lists:
+    0.5 s for 100 trees of 4,000 leaves)."""
+    ens._parents()              # in range, one parent each
+    T, N = ens.feature.shape
+    cap = lanes - 1
+    root = np.zeros((T, N), bool)
+    subtree = np.full((T, N), -1, np.int32)
+    lane = np.zeros((T, N), np.int32)
+    n_subtrees = np.ones(T, np.int64)
+    for t in range(T):
+        n_int = int(ens.n_leaves[t]) - 1
+        if n_int == 0:
+            continue
+        lc, rc = ens.left_child[t].tolist(), ens.right_child[t].tolist()
+        order, parent, stack = [], [-1] * n_int, [0]
+        while stack:
+            n = stack.pop()
+            order.append(n)
+            for c in (rc[n], lc[n]):        # left first off the stack
+                if c >= 0:
+                    parent[c] = n
+                    stack.append(c)
+        if len(order) != n_int:
+            raise ValueError("node list: a cycle among the nodes")
+        weight, cut = [0] * n_int, [False] * n_int
+        for n in reversed(order):           # children before parents
+            kids = sorted((weight[c], c) for c in (lc[n], rc[n]) if c >= 0)
+            total = 1 + sum(w for w, _ in kids)
+            while total > cap:
+                w, c = kids.pop()
+                cut[c], total = True, total - w
+            weight[n] = total
+        cut[0] = True
+        filled = []
+        for n in order:
+            if cut[n]:
+                subtree[t, n] = len(filled)
+                filled.append(1)
+            else:
+                k = subtree[t, n] = subtree[t, parent[n]]
+                lane[t, n] = filled[k]
+                filled[k] += 1
+        root[t, :n_int] = cut
+        n_subtrees[t] = len(filled)
+    return SubtreeCut(n_subtrees, root, subtree, lane)
+
+
+# bfloat16 pieces a float32 leaf value is held in (`split_bfloat16`; the
+# kernel's side of it is ops/predict_paths._LEAF_PIECES).
+LEAF_PIECES = 3
+
+
+def split_bfloat16(v: np.ndarray, pieces: int = LEAF_PIECES) -> list:
+    """float32 `v` as a sum of `pieces` bfloat16 arrays, largest first:
+    three hold every float32 exactly (8 bits of the mantissa each, round
+    to nearest), and added smallest first in float32 they give `v` back.
+    What lets a 0/1 matrix pick float32 values through a bfloat16 MXU."""
+    import ml_dtypes
+
+    out, rest = [], np.asarray(v, np.float32)
+    for _ in range(pieces):
+        out.append(rest.astype(ml_dtypes.bfloat16))
+        rest = rest - out[-1].astype(np.float32)
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1070,7 +1262,29 @@ class CompiledNodeList:
         paths  [T, W, W]  bf16   P[n, l]: +1 leaf l in node n's right
                                  subtree, -1 in its left, 0 elsewhere
 
-    built ONCE per model version on the host; backends keep them device-
+    The SUB-TREE form (`n_subtrees` > 0: vector leaves, or a tree of more
+    than `PATH_UNCUT_LANES` lanes): the same three tables with one entry a
+    SUB-TREE of `cut_subtrees` (W = `SUBTREE_LANES`; S entries, a tree's
+    in a row, parents before children), a sub-tree's "leaves" its EXITS,
+    and a fourth table that says what an exit is:
+
+        planes row 4             1 in the entry that roots a tree, else 0
+                                 (row 2 is not read)
+        leaves [S, W, CL + A] bf16
+                                 row e, an exit. A real leaf: its float32
+                                 vector as three bfloat16 pieces
+                                 (`split_bfloat16`), piece p's column c in
+                                 lane p C + c of the CL class lanes (whole
+                                 128s). A link to the tree's sub-tree j
+                                 from its sub-tree k: 1 in lane
+                                 CL + (j - k - 1) of the A activity lanes
+                                 (whole 128s, more than a tree's
+                                 sub-trees), which the chain shifts down a
+                                 lane a sub-tree.
+
+    `mean`: the score is the sum over the trees divided by their number
+    (an averaged forest), else base + learning_rate x sum.
+    Built ONCE per model version on the host; backends keep them device-
     resident under `token` (the same cache as CompiledEnsemble's)."""
 
     token: str
@@ -1084,11 +1298,23 @@ class CompiledNodeList:
     planes: np.ndarray
     paths: np.ndarray
     missing_bin_value: int = -1    # reserved NaN bin id, -1 = no routing
+    leaves: np.ndarray | None = None   # the sub-tree form's exits
+    n_subtrees: int = 0        # S; 0: one uncut path matrix a tree
+    leaf_columns: int = 1      # C
+    mean: bool = False
+    widest_tree: int = 0       # lanes the widest tree would take uncut
 
-    n_classes_out = 1
+    @property
+    def n_classes_out(self) -> int:
+        return self.leaf_columns
+
+    @property
+    def chained(self) -> bool:
+        return self.leaves is not None
 
     def arrays(self) -> tuple:
-        return (self.sel, self.planes, self.paths)
+        return (self.sel, self.planes, self.paths) + (
+            (self.leaves,) if self.chained else ())
 
     @staticmethod
     def build(ens: NodeListEnsemble) -> "CompiledNodeList":
@@ -1098,11 +1324,20 @@ class CompiledNodeList:
             raise ValueError(
                 "this node-list ensemble carries raw thresholds only; rank "
                 "them first (models/lightgbm_io.threshold_bin_mapper)")
-        P, plen = ens.path_matrix()
-        T, N, L = P.shape
+        T, N = ens.feature.shape
+        L = ens.leaf_value.shape[1]
         W = -(-max(N, L) // PATH_LANES) * PATH_LANES
         Fp = -(-ens.n_features // 16) * 16      # bf16 sublane tiles
         live = ens.live_nodes
+        nan_bin = ens.missing_bin_value
+        if nan_bin >= 0 and (ens.threshold_bin[live] >= nan_bin).any():
+            raise ValueError(
+                f"a node's threshold bin is the NaN bin {nan_bin} or "
+                "above it: with learned NaN directions thresholds lie "
+                "in the value bins")
+        if ens.vector_leaves or W > PATH_UNCUT_LANES:
+            return CompiledNodeList._build_subtrees(ens, W, Fp)
+        P, plen = ens.path_matrix()
         sel = np.zeros((T, Fp, W), ml_dtypes.bfloat16)
         t_idx, n_idx = np.nonzero(live)
         sel[t_idx, ens.feature[t_idx, n_idx], n_idx] = 1.0
@@ -1112,13 +1347,7 @@ class CompiledNodeList:
         planes[:, 1, :] = -1.0
         planes[:, 1, :L] = plen
         planes[:, 2, :L] = ens.leaf_value
-        nan_bin = ens.missing_bin_value
         if nan_bin >= 0:
-            if (ens.threshold_bin[live] >= nan_bin).any():
-                raise ValueError(
-                    f"a node's threshold bin is the NaN bin {nan_bin} or "
-                    "above it: with learned NaN directions thresholds lie "
-                    "in the value bins")
             planes[:, 3, :] = 2.0 ** 30
             planes[:, 3, :N] = np.where(live & ens.default_left, nan_bin,
                                         2.0 ** 30)
@@ -1129,7 +1358,98 @@ class CompiledNodeList:
             learning_rate=float(ens.learning_rate),
             base_score=float(ens.base_score), loss=ens.loss,
             n_trees=T, lanes=W, deepest_leaf=int(plen.max(initial=0)),
-            sel=sel, planes=planes, paths=paths, missing_bin_value=nan_bin)
+            sel=sel, planes=planes, paths=paths, missing_bin_value=nan_bin,
+            widest_tree=W)
+
+    @staticmethod
+    def _build_subtrees(ens: NodeListEnsemble, widest: int,
+                        Fp: int) -> "CompiledNodeList":
+        """`build` of the sub-tree form (the class's docstring)."""
+        import ml_dtypes
+
+        bf16 = ml_dtypes.bfloat16
+        T, N = ens.feature.shape
+        W, C = SUBTREE_LANES, ens.leaf_columns    # the module's, as it is
+        cut = cut_subtrees(ens, W)
+        node_parent, node_side, _, _ = ens._parents()
+        first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
+        S = int(first[-1])
+        CL = -(-LEAF_PIECES * C // PATH_LANES) * PATH_LANES
+        A = -(-(int(cut.n_subtrees.max()) + 1) // PATH_LANES) * PATH_LANES
+        t_idx, n_idx = np.nonzero(ens.live_nodes)
+        at = first[t_idx] + cut.subtree[t_idx, n_idx]      # the entry
+        ln = cut.lane[t_idx, n_idx]
+        sel = np.zeros((S, Fp, W), bf16)
+        sel[at, ens.feature[t_idx, n_idx], ln] = 1.0
+        planes = np.zeros((S, 8, W), np.float32)
+        planes[:, 0, :] = 2.0 ** 30
+        planes[at, 0, ln] = ens.threshold_bin[t_idx, n_idx]
+        planes[:, 1, :] = -1.0
+        planes[first[:-1], 4, :] = 1.0
+        if ens.missing_routes:
+            planes[:, 3, :] = 2.0 ** 30
+            left = ens.default_left[t_idx, n_idx]
+            planes[at[left], 3, ln[left]] = ens.missing_bin_value
+        # The exits: every child that is a leaf or roots a sub-tree, in
+        # the order of (entry, the node's lane, left before right).
+        child = np.stack([ens.left_child[t_idx, n_idx],
+                          ens.right_child[t_idx, n_idx]], 1).astype(np.int64)
+        linked = (child >= 0) & cut.root[t_idx[:, None],
+                                         np.maximum(child, 0)]
+        is_exit = (child < 0) | linked
+        order = np.lexsort((ln, at))
+        # [nodes, 2] in that order; an exit's lane its rank in the entry
+        e_node, e_side = np.nonzero(is_exit[order])
+        e_node = order[e_node]
+        e_at = at[e_node]
+        starts = np.searchsorted(e_at, np.arange(S))
+        e_lane = np.arange(len(e_at)) - starts[e_at]
+        e_child = child[e_node, e_side]
+        e_tree = t_idx[e_node]
+        leaves = np.zeros((S, W, CL + A), bf16)
+        values = ens.leaf_value.reshape(T, -1, C)
+
+        def put_leaves(entry, exit_lane, vectors):
+            for p, piece in enumerate(split_bfloat16(vectors)):
+                leaves[entry, exit_lane, p * C:(p + 1) * C] = piece
+
+        real = e_child < 0
+        put_leaves(e_at[real], e_lane[real],
+                   values[e_tree[real], ~e_child[real]])
+        to = first[e_tree[~real]] + cut.subtree[e_tree[~real],
+                                                e_child[~real]]
+        leaves[e_at[~real], e_lane[~real],
+               CL + (to - e_at[~real] - 1)] = 1.0
+        # A tree of one leaf: an entry of no node whose exit 0 has a path
+        # of no node (every row reaches it).
+        lone = np.nonzero(ens.n_leaves == 1)[0]
+        planes[first[lone], 1, 0] = 0.0
+        put_leaves(first[lone], 0, values[lone, 0])
+        # An exit's path inside its sub-tree: up from the node it hangs
+        # on to the sub-tree's root, all exits a step.
+        # (written as bfloat16's bits: +1 is 0x3F80 and -1 0xBF80; a cast
+        # of 132M int8 entries to bfloat16 took 10 s of a 12 s build)
+        paths = np.zeros((S, W, W), np.uint16)
+        plen = np.zeros((S, W), np.float32)
+        tt, cur, ex = e_tree, n_idx[e_node], np.arange(len(e_at))
+        sign = np.where(e_side == 0, -1, 1).astype(np.int8)
+        while len(tt):
+            paths[e_at[ex], cut.lane[tt, cur], e_lane[ex]] = np.where(
+                sign > 0, 0x3F80, 0xBF80)
+            np.add.at(plen, (e_at[ex], e_lane[ex]), 1.0)
+            up = ~cut.root[tt, cur]
+            sign, cur = node_side[tt, cur], node_parent[tt, cur]
+            tt, cur, ex, sign = tt[up], cur[up], ex[up], sign[up]
+        planes[e_at, 1, e_lane] = plen[e_at, e_lane]
+        return CompiledNodeList(
+            token=ens.cache_token(),
+            learning_rate=float(ens.learning_rate),
+            base_score=float(ens.base_score), loss=ens.loss,
+            n_trees=T, lanes=W, deepest_leaf=ens.deepest_leaf,
+            sel=sel, planes=planes, paths=paths.view(bf16),
+            missing_bin_value=ens.missing_bin_value, leaves=leaves,
+            n_subtrees=S, leaf_columns=C, mean=ens.vector_leaves,
+            widest_tree=widest)
 
 
 # ---------------------------------------------------------------------- #

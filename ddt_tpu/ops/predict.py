@@ -58,6 +58,27 @@ accumulation in any order; leaf values stay float32. The plain jax.numpy
 form below is the fallback and what a CPU runs; the Pallas kernel is
 `ops/predict_paths.py`, dispatched by the same rule.
 
+The chain. A tree of more lanes than one path matrix should hold, or one
+whose leaves are VECTORS (an averaged forest), is cut into connected
+SUB-TREES of at most 256 lanes (models/tree.cut_subtrees), each an entry of
+the same three tables whose "leaves" are its EXITS: real leaves, and links
+to the sub-tree that hangs there. Per sub-tree k of a tree, parents first,
+with e_k[r, x] = (m_k[r, x] == len_k[x]) as above (the exit the row would
+take from k's root),
+
+    a_root[r] = 1,   a_j[r] = a_k[r] e_k[r, link k -> j]
+    score[r, :] += a_k[r] (e_k[r, real leaves] @ V_k)       V_k [W, C]
+
+so a row's activity follows its one chain of sub-trees down and the one
+real leaf it ends in adds its vector. One more matmul a sub-tree does both:
+e_k @ [V_k | L_k], L_k[x, j - k - 1] = 1 where exit x links to sub-tree j:
+the activity is a row of A lanes, lane 0 this sub-tree's and lane i that of
+the sub-tree i further on, shifted down a lane a sub-tree and reset to lane
+0 at a tree's first. V_k holds a float32 value as its three bfloat16 pieces
+in lanes of their own (models/tree.split_bfloat16: exact), e and a are 0/1,
+one exit a row: the class lanes' sums are float32 sums of exact products.
+The forest's answer is the sum over its trees divided by their number.
+
 A FIFTH entry serves the third layout, the OBLIVIOUS ensemble
 (models/tree.ObliviousEnsemble: CatBoost's symmetric trees, D splits and
 2^D leaf values a tree): `predict_raw_effective_oblivious`. The trees go in
@@ -254,7 +275,8 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
                        n_features: int, n_classes: int,
                        optional_operands: int = 2,
                        path_lanes: int = 0,
-                       oblivious_depth: int = 0) -> bool:
+                       oblivious_depth: int = 0,
+                       path_exit_lanes: int = 0) -> bool:
     """The ONE home of the pallas-vs-one-hot predict dispatch rule.
 
     None = auto: the Pallas traversal kernel is taken when the data is
@@ -266,7 +288,9 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
     `path_lanes` says which LAYOUT asks: 0 a heap ensemble, else a node
     list of that many lanes a tree (`max_depth` and `optional_operands`
     mean nothing there), whose kernel is ops/predict_paths.py and whose
-    guard is that kernel's own; `oblivious_depth` an oblivious ensemble
+    guard is that kernel's own, `path_exit_lanes` the width of the exits'
+    table of a node list in the SUB-TREE form (its lanes a sub-tree's, and
+    `n_classes` the columns a leaf holds); `oblivious_depth` an oblivious ensemble
     of that depth (ops/predict_oblivious.py, `predict_oblivious_fits`).
     Explicit True
     demands the kernel (binned data required — raises otherwise; off-TPU
@@ -285,7 +309,10 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
         elif path_lanes:
             from ddt_tpu.ops import predict_paths
 
-            fits = predict_paths.predict_paths_fits(path_lanes, n_features)
+            fits = predict_paths.predict_paths_fits(
+                path_lanes, n_features,
+                chain=predict_paths.chain_of(1, n_classes, path_exit_lanes)
+                if path_exit_lanes else None)
         else:
             fits = predict_pallas.predict_pallas_fits(
                 max_depth, n_features, n_classes,
@@ -536,13 +563,19 @@ _PATHS_TREE_CHUNK, _PATHS_ROW_CHUNK = 8, 8_192
 
 
 def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
-                   missing_routes: bool = False):
+                   missing_routes: bool = False, leaves=None, chain=None,
+                   mean: bool = False):
     """The path-matrix form (module docstring) in plain jax.numpy: trees in
     chunks of _PATHS_TREE_CHUNK, rows in chunks of _PATHS_ROW_CHUNK, so the
     [trees, rows, W] intermediates stay bounded. The operands are
     widened to float32 (XLA's CPU backend has no bf16 x bf16 = f32 dot);
     every value is one bfloat16 holds, so a TPU's default one-pass matmul
-    of them is exact too. `missing_routes`: planes' row 3 is read."""
+    of them is exact too. `missing_routes`: planes' row 3 is read.
+    `leaves` and `chain`: the SUB-TREE form, `_predict_chain`."""
+    if chain is not None:
+        return _predict_chain(sel, planes, paths, leaves, Xc, chain=chain,
+                              learning_rate=learning_rate, base=base,
+                              missing_routes=missing_routes, mean=mean)
     T, Fp, W = sel.shape
     R, F = Xc.shape
     tree_chunk = _PATHS_TREE_CHUNK
@@ -591,11 +624,69 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
         return base + learning_rate * accs.reshape(n_rc * row_chunk)[:R]
 
 
+def _predict_chain(sel, planes, paths, leaves, Xc, *, chain, learning_rate,
+                   base, missing_routes: bool, mean: bool):
+    """The SUB-TREE form (module docstring, "The chain") in plain
+    jax.numpy: one sub-tree a step of the scan, in the table's order (a
+    tree's sub-trees in a row, parents first), rows in chunks of
+    _PATHS_ROW_CHUNK; the carry is the kernel's, the class lanes' sums and
+    the activity lanes. Same tables, same equations, another order of the
+    float32 adds."""
+    from ddt_tpu.ops.predict_paths import fold_leaf_pieces
+
+    S, Fp, W = sel.shape
+    R, F = Xc.shape
+    cl, al = chain.class_lanes, chain.act_lanes
+    row_chunk = min(_PATHS_ROW_CHUNK, R)
+    n_rc = -(-R // row_chunk)
+    with traced_scope("predict:widen"):
+        Xp = jnp.pad(Xc.astype(jnp.float32),
+                     ((0, n_rc * row_chunk - R), (0, Fp - F))
+                     ).reshape(n_rc, row_chunk, Fp)
+    with traced_scope("predict:tables"):
+        first = (jnp.arange(al) == 0).astype(jnp.float32)[None, :]
+
+    def row_body(_, xrc):
+        def subtree_body(carry, args):
+            acc, act = carry
+            a_sel, pl_, p, lv = args
+            with traced_scope("predict:traverse"):
+                v = jnp.dot(xrc, a_sel.astype(jnp.float32),
+                            preferred_element_type=jnp.float32)
+                right = v > pl_[None, 0, :]
+                if missing_routes:
+                    right &= v < pl_[None, 3, :]
+                m = jnp.dot(jnp.where(right, 1.0, -1.0),
+                            p.astype(jnp.float32),
+                            preferred_element_type=jnp.float32)
+                e = jnp.where(m == pl_[None, 1, :], 1.0, 0.0)
+                y = jnp.dot(e, lv.astype(jnp.float32),
+                            preferred_element_type=jnp.float32)
+            with traced_scope("predict:accumulate"):
+                act = jnp.where(pl_[4, 0] > 0.0, first, act)
+                a = act[:, 0:1]
+                acc = acc + a * y[:, :cl]
+                act = jnp.roll(act, -1, axis=1) + a * y[:, cl:]
+            return (acc, act), None
+
+        (acc, _), _ = jax.lax.scan(
+            subtree_body, (jnp.zeros((row_chunk, cl), jnp.float32),
+                           jnp.zeros((row_chunk, al), jnp.float32)),
+            (sel, planes, paths, leaves))
+        return None, acc
+
+    with traced_scope("predict:traverse"):
+        _, accs = jax.lax.scan(row_body, None, Xp)
+    with traced_scope("predict:accumulate"):
+        acc = accs.reshape(n_rc * row_chunk, cl)[:R]
+    return fold_leaf_pieces(acc, chain, learning_rate, base, mean)
+
+
 @costed("predict", phase="predict")
 @functools.partial(
     jax.jit,
     static_argnames=("learning_rate", "base", "use_pallas",
-                     "missing_routes"),
+                     "missing_routes", "n_trees", "leaf_columns", "mean"),
 )
 @op_scope("predict")
 def predict_raw_effective_paths(
@@ -609,6 +700,10 @@ def predict_raw_effective_paths(
     base: float,
     use_pallas: bool | None = None,
     missing_routes: bool = False,
+    leaves: jax.Array | None = None,   # bf16 [S, W, CL + A]: the exits
+    n_trees: int = 0,
+    leaf_columns: int = 1,
+    mean: bool = False,
 ) -> jax.Array:
     """Raw margins [R] of a node-list ensemble from its compiled tables
     (models/tree.CompiledNodeList): the path-matrix form, by the Pallas
@@ -617,21 +712,30 @@ def predict_raw_effective_paths(
     the width they come in (uint8 from api.predict: nothing is widened in
     XLA). `missing_routes`: the model carries learned NaN directions
     (`CompiledNodeList.missing_bin_value` >= 0); without them the program
-    is the one-compare program."""
+    is the one-compare program. With `leaves` the tables are the SUB-TREE
+    form's (one entry a sub-tree of the `n_trees` trees, `leaf_columns`
+    values a leaf) and the answer of vector leaves (`mean`) is the mean
+    over the trees, float32 [R, leaf_columns]."""
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
         raise ValueError("the path-matrix form scores binned (integer) rows")
-    if Xc.shape[0] == 0:
-        return jnp.full((0,), base, jnp.float32)
-    if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], 1,
-                          path_lanes=planes.shape[2]):
-        from ddt_tpu.ops import predict_paths
+    from ddt_tpu.ops import predict_paths
 
-        return predict_paths.predict_paths_pallas(
-            sel, planes, paths, Xc, learning_rate=learning_rate, base=base,
-            missing_routes=missing_routes)
-    return _predict_paths(sel, planes, paths, Xc,
-                          learning_rate=learning_rate, base=base,
-                          missing_routes=missing_routes)
+    chain, exit_lanes = None, 0
+    if leaves is not None:
+        exit_lanes = leaves.shape[2]
+        chain = predict_paths.chain_of(n_trees, leaf_columns, exit_lanes)
+    if Xc.shape[0] == 0:
+        return jnp.full((0, leaf_columns) if mean else (0,),
+                        0.0 if mean else base, jnp.float32)
+    form = dict(learning_rate=learning_rate, base=base,
+                missing_routes=missing_routes, leaves=leaves, chain=chain,
+                mean=mean)
+    if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], leaf_columns,
+                          path_lanes=planes.shape[2],
+                          path_exit_lanes=exit_lanes):
+        return predict_paths.predict_paths_pallas(sel, planes, paths, Xc,
+                                                  **form)
+    return _predict_paths(sel, planes, paths, Xc, **form)
 
 
 # Rows a step of the jax.numpy oblivious form takes at most (the float32

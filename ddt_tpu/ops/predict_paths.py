@@ -17,7 +17,10 @@ so its cost is by the tree's NODES and LEAVES (W lanes of each, a multiple
 of 128), not by its depth: `path_mxu_tiles_per_tree` MXU weight tiles a
 tree, ceil(F/128) x ceil(W / P / 128) for v (P nodes a result lane, next
 paragraph) and (W/128)^2 for m: 5 at 255 leaves and 28 columns (6 at P =
-1), 18 at 512 lanes, 2 at 128, 20 at 968 columns.
+1), 18 at 512 lanes, 2 at 128, 20 at 968 columns; in the sub-tree form (THE
+CHAIN, below) W/128 x (CL + A)/128 more a sub-tree for the exits' table, 22
+a sub-tree of 256 lanes at 784 columns and 10 classes (7 x 2 + 4 + 2 + 2),
+443 a tree of the MNIST forest's 20.15 sub-trees.
 
 TWO NODES A RESULT LANE (`select_nodes_per_lane`, P: from F and W, nothing
 else; the heap kernel's `nodes_per_tile` in this form). The select contracts
@@ -51,6 +54,40 @@ from the row tile as HBM holds it and widened uint8 -> bf16 in VMEM, once a
 sub-tile, the last block's K padded to the tables' Fp (a multiple of 16).
 One non-zero a column of sel and bins below 256: every partial product is
 exact and so is their sum in any order.
+
+THE CHAIN: the SUB-TREE form (`class_lanes` > 0; ops/predict.py has the
+equations, models/tree.CompiledNodeList the tables). A tree of more lanes
+than one path matrix should hold (past 512: the resolve is quadratic in W),
+or one whose leaves are VECTORS (an averaged forest: a class distribution a
+leaf, the mean over the trees), is cut on the host into connected sub-trees
+of at most 256 lanes, and a table entry is a SUB-TREE: the grid's block
+axis walks blocks of G sub-trees, a tree's in a row, parents first. A
+sub-tree's v, s and m are the tree's above; its "leaves" are its EXITS, and
+what an exit means is a fourth table, `leaves` [W, CL + A] bf16, against
+which the exit one-hot e = (m == len) is multiplied ONCE:
+
+    y = e @ [V | L]                  [rows, CL + A]
+    a = act[:, 0]                    this sub-tree's activity, 0 or 1
+    acc += a * y[:, :CL]             the class dot: CL class lanes
+    act  = roll(act, -1) + a * y[:, CL:]     the chain: A activity lanes
+
+V holds a real leaf's float32 vector as THREE bfloat16 pieces in lanes of
+their own (`class_dot_passes` 3; piece p's column c in lane p C + c: exact,
+and one MXU tile serves 3 C <= 128), zero rows for links; L holds a 1 in
+lane j - k - 1 of an exit of sub-tree k that links to the tree's sub-tree
+j. So lane 0 of `act` is always the sub-tree at hand, lane i the one i
+further on in the tree, every step shifts the lanes down by one (XLU), and
+an entry that roots a tree (planes' row 4) sets `act` to lane 0 alone. A row
+follows ONE chain of sub-trees, every other sub-tree of the tree has a = 0,
+and the one real leaf it ends in adds its pieces. `act` lives in a VMEM
+scratch [TILE_ROWS, A] over the block axis (a tree's sub-trees may lie in
+several blocks; the table's first entry roots a tree, so the scratch is
+never read before it is set), `acc` in the output block [TILE_ROWS, CL],
+the rows on the sublanes; the three pieces are added and divided by the tree
+count in XLA (`fold_leaf_pieces`: `predict:accumulate`). A > a tree's
+sub-trees, so no lane wraps into use. Nothing of it is traced for a model
+whose trees one path matrix holds with one output column: that program is
+instruction for instruction what it was.
 
 Learned NaN directions (`missing_routes`; models/tree.CompiledNodeList):
 the NaN bin is the top bin, above every threshold, so `v > thr` alone is
@@ -193,21 +230,53 @@ def _select_shape(lanes: int, n_features: int, nodes_per_lane: int) -> tuple:
 
 
 def path_mxu_tiles_per_tree(lanes: int, n_features: int,
-                            nodes_per_lane: int | None = None) -> int:
+                            nodes_per_lane: int | None = None,
+                            exit_lanes: int = 0) -> int:
     """MXU weight tiles (results [rows, 128]) a tree costs a tile of rows:
     the feature select's, ceil(F/128) x ceil(W / nodes a lane / 128), and
-    the path resolve's, (W/128)^2. `nodes_per_lane` None: the kernel's own
-    (`select_nodes_per_lane`)."""
+    the path resolve's, (W/128)^2; of a SUB-TREE besides the exits' table's,
+    W/128 x `exit_lanes`/128 (the class dot and the chain).
+    `nodes_per_lane` None: the kernel's own (`select_nodes_per_lane`)."""
     if nodes_per_lane is None:
         nodes_per_lane = select_nodes_per_lane(n_features, lanes)
     w = lanes // _LANES
-    return select_k_blocks(n_features) * -(-w // nodes_per_lane) + w * w
+    return (select_k_blocks(n_features) * -(-w // nodes_per_lane) + w * w
+            + w * (exit_lanes // _LANES))
 
 
-def _tree_bytes(lanes: int, n_features: int, nodes_per_lane: int = 1) -> int:
-    """HBM bytes of one tree's tables: sel bf16, planes f32, P bf16."""
+def _tree_bytes(lanes: int, n_features: int, nodes_per_lane: int = 1,
+                exit_lanes: int = 0) -> int:
+    """HBM bytes of one tree's (one sub-tree's) tables: sel bf16, planes
+    f32, P bf16, the exits' bf16."""
     k, w = _select_shape(lanes, n_features, nodes_per_lane)
-    return k * w * 2 + 8 * lanes * 4 + lanes * lanes * 2
+    return (k * w * 2 + 8 * lanes * 4 + lanes * lanes * 2
+            + lanes * exit_lanes * 2)
+
+
+class Chain(typing.NamedTuple):
+    """The shape of a model in the sub-tree form, as `path_plan` and the
+    kernel's caller take it (models/tree.CompiledNodeList has the tables)."""
+
+    n_trees: int               # T: what a mean divides by
+    leaf_columns: int          # C
+    class_lanes: int           # CL: three bfloat16 pieces of C columns
+    act_lanes: int             # A: the chain's activity lanes
+
+    @property
+    def exit_lanes(self) -> int:
+        return self.class_lanes + self.act_lanes
+
+
+# bfloat16 pieces a float32 leaf value is held in (models/tree.
+# split_bfloat16: three are exact).
+_LEAF_PIECES = 3
+
+
+def chain_of(n_trees: int, leaf_columns: int, exit_lanes: int) -> Chain:
+    """The Chain of a compiled model whose exits' table is `exit_lanes`
+    wide."""
+    cl = _lane_pad(_LEAF_PIECES * leaf_columns)
+    return Chain(n_trees, leaf_columns, cl, exit_lanes - cl)
 
 
 class PathPlan(typing.NamedTuple):
@@ -226,6 +295,15 @@ class PathPlan(typing.NamedTuple):
     missing_routes: int = 0    # 1: learned NaN directions in the compare
     row_operand_bytes: int = 1  # a bin of the row block as HBM holds it
     select_nodes_per_lane: int = 1  # 2: the select's lanes answer in pairs
+    # The sub-tree form (module docstring, THE CHAIN); a tree that one path
+    # matrix holds is one sub-tree of its own lanes, with no chain.
+    subtrees_per_tree: float = 1.0  # table entries a tree, on average
+    subtree_lanes: int = 0          # W of an entry
+    leaf_columns: int = 1           # C, the output columns a leaf holds
+    chain_mxu_tiles_per_tree: int = 0   # of path_mxu_tiles_per_tree: the
+    #   exits against the activity lanes
+    class_dot_passes: int = 0       # bfloat16 pieces of a float32 leaf value
+    #   in the class dot's tile (0: leaf values added on the VPU)
 
     @property
     def blocks(self) -> int:
@@ -245,17 +323,20 @@ class PathPlan(typing.NamedTuple):
         return {"routing_tables": self.missing_routes,
                 "node_list": self.node_list,
                 "path_mxu_tiles_per_tree": self.path_mxu_tiles_per_tree,
-                "select_k_blocks": self.select_k_blocks}
+                "select_k_blocks": self.select_k_blocks,
+                **{k: getattr(self, k) for k in CHAIN_COUNTS}}
 
 
 # What the `ddt:predict:ensemble` span says of a node-list model's plan, in
 # the order it prints (docs/OBSERVABILITY.md); `cli predict` repeats all
 # but `table_bytes` in `phases_ms`, as it does for the heap kernel's.
+CHAIN_COUNTS = ("subtrees_per_tree", "subtree_lanes", "leaf_columns",
+                "chain_mxu_tiles_per_tree", "class_dot_passes")
 SPAN_COUNTS = ("node_list", "nodes_per_tree", "leaves_per_tree",
                "deepest_leaf", "path_mxu_tiles_per_tree", "trees_per_step",
                "table_blocks", "table_bytes", "select_k_blocks",
                "missing_routes", "row_operand_bytes",
-               "select_nodes_per_lane")
+               "select_nodes_per_lane") + CHAIN_COUNTS
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 
 
@@ -265,7 +346,9 @@ def _lane_pad(n: int) -> int:
 
 def path_plan(n_trees: int, lanes: int, n_features: int,
               deepest_leaf: int = 0, served: bool = True,
-              missing_routes: bool = False, row_dtype=jnp.uint8) -> PathPlan:
+              missing_routes: bool = False, row_dtype=jnp.uint8,
+              chain: Chain | None = None,
+              widest_tree: int = 0) -> PathPlan:
     """The kernel's table blocks at this shape: G trees a block, the most
     whose double-buffered windows fit _VMEM_BUDGET_BYTES beside what the
     kernel holds whatever G: the row tile's two windows at the rows' own
@@ -275,29 +358,51 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     size near it that leaves the fewest filler trees. `served` False: the
     plan of a model the jax.numpy form scores (its lanes and depth, no
     blocks). `missing_routes` rides along for the spans and decides
-    nothing here."""
+    nothing here. `chain`: the SUB-TREE form; `n_trees` then counts the
+    table's entries, the sub-trees, G of which a block holds, whose windows
+    have the exits' table among them, and beside which the kernel holds the
+    [TILE_ROWS, CL] output's windows and the [TILE_ROWS, A] activity;
+    `widest_tree` the lanes the widest tree would take uncut (what the
+    spans call `nodes_per_tree`)."""
     # The jax.numpy form takes the select as the model compiles it.
     pack = select_nodes_per_lane(n_features, lanes) if served else 1
-    tiles = path_mxu_tiles_per_tree(lanes, n_features, pack)
+    exit_lanes = chain.exit_lanes if chain else 0
+    tiles = path_mxu_tiles_per_tree(lanes, n_features, pack, exit_lanes)
     row_bytes = row_operand_dtype(row_dtype).itemsize
     said = (select_k_blocks(n_features), int(missing_routes), row_bytes,
             pack)
+    widest_tree = widest_tree or lanes
+    if chain:
+        per = n_trees / chain.n_trees
+        said += (round(per, 2), lanes, chain.leaf_columns,
+                 round(per * (lanes // _LANES)
+                       * (chain.act_lanes // _LANES)), _LEAF_PIECES)
+        tiles = round(per * tiles)
+    else:
+        said += (1.0, lanes)
     if not served:
-        return PathPlan(1, lanes, lanes, deepest_leaf, tiles, 0, 0, 0, 0,
-                        *said)
+        return PathPlan(1, widest_tree, widest_tree, deepest_leaf, tiles, 0,
+                        0, 0, 0, *said)
     fp, sel_lanes = _select_shape(lanes, n_features, pack)
     per_tree = (_window_bytes(fp, sel_lanes) // 2      # bf16: half of f32
                 + _window_bytes(8, lanes)
-                + _window_bytes(lanes, lanes) // 2)
+                + _window_bytes(lanes, lanes) // 2
+                + _window_bytes(lanes, exit_lanes) // 2)
+    # the scores' window: [1, TILE_ROWS], or the chain's [TILE_ROWS, CL]
+    # with the activity scratch and a sub-tile's copies of both
+    out = _window_bytes(1, TILE_ROWS) if not chain else (
+        _window_bytes(TILE_ROWS, chain.class_lanes)
+        + TILE_ROWS * chain.act_lanes * 4
+        + _sub_rows(pack) * exit_lanes * 3 * 4)
     fixed = (2 * TILE_ROWS * _lane_pad(n_features) * row_bytes
-             + _window_bytes(1, TILE_ROWS)
+             + out
              + _sub_rows(pack) * (_lane_pad(fp) * _SUB_ROW_BIN_BYTES
                            + lanes * _SUB_ROW_LANE_BYTES))
     most = min(n_trees, _MAX_TREES_PER_STEP,
                max(0, (_VMEM_BUDGET_BYTES - fixed) // per_tree))
     if most == 0:
-        return PathPlan(1, lanes, lanes, deepest_leaf, tiles, 0, 0, 0,
-                        TILE_ROWS, *said)
+        return PathPlan(1, widest_tree, widest_tree, deepest_leaf, tiles, 0,
+                        0, 0, TILE_ROWS, *said)
     # Of the block sizes from `most` down to half of it, the one that
     # fills its last block best (filler trees cost what trees cost: 500
     # trees are 50 blocks of 10 where 46 of 11 would score 506), the
@@ -305,18 +410,23 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     g = min(range(most, max(most // 2, 1) - 1, -1),
             key=lambda g: (-(-n_trees // g) * g, -g))
     blocks = -(-n_trees // g)
-    return PathPlan(1, lanes, lanes, deepest_leaf, tiles, g, blocks,
-                    blocks * g * _tree_bytes(lanes, n_features, pack),
+    return PathPlan(1, widest_tree, widest_tree, deepest_leaf, tiles, g,
+                    blocks,
+                    blocks * g * _tree_bytes(lanes, n_features, pack,
+                                             exit_lanes),
                     TILE_ROWS, *said)
 
 
 def predict_paths_fits(lanes: int, n_features: int,
-                       row_dtype=jnp.uint8) -> bool:
+                       row_dtype=jnp.uint8,
+                       chain: Chain | None = None) -> bool:
     """Whether one tree's tables fit the kernel's VMEM budget beside a row
     tile: the guard behind use_pallas=None (ops/predict.resolve_use_pallas).
-    The tree count is no term of it."""
-    return path_plan(1, lanes, n_features,
-                     row_dtype=row_dtype).trees_per_step > 0
+    The tree count is no term of it. `chain`: a model in the sub-tree
+    form; one sub-tree's tables beside the output's windows and the
+    activity."""
+    return path_plan(1, lanes, n_features, row_dtype=row_dtype,
+                     chain=chain).trees_per_step > 0
 
 
 def pack_select(sel, planes, n_features: int, xp=jnp) -> tuple:
@@ -358,15 +468,25 @@ def pack_select(sel, planes, n_features: int, xp=jnp) -> tuple:
     return packed, planes
 
 
-def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
-                  n_trees: int, n_feat: int, missing_routes: bool):
+def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
+                  n_trees: int, n_feat: int, missing_routes: bool,
+                  class_lanes: int = 0):
     """One row tile against one block of `n_trees` trees: the block's share
     of every row's margin. x_ref [TILE_ROWS, F] uint8 or int32, as HBM
     holds the rows (in the last tile, whatever lies past row R); sel
     [G, Fp, W] bf16, or `pack_select`'s [G, K2, Wp] (fewer lanes than the
     planes: two nodes a lane), planes [G, 8, W] f32, paths [G, W, W] bf16;
     out [1, TILE_ROWS] f32, the rows on the lanes, resident over the block
-    axis (grid axis 1)."""
+    axis (grid axis 1).
+
+    `class_lanes` CL > 0, the SUB-TREE form: the block's entries are G
+    sub-trees, `rest` is (leaves [G, W, CL + A] bf16, out [TILE_ROWS, CL]
+    f32, act [TILE_ROWS, A] f32 scratch): the output holds the rows on the
+    sublanes and the leaf values' three pieces on the lanes, and the
+    activity lives in VMEM over the block axis, a tree's sub-trees lying in
+    one block or in several. A first entry of the whole table roots a tree,
+    so what the scratch held before is never read."""
+    out_ref = rest[class_lanes > 0]
     tile_rows = x_ref.shape[0]
     fp, lanes = sel_ref.shape[1], planes_ref.shape[2]
     wp = sel_ref.shape[2]
@@ -405,6 +525,9 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
             xs.append(xf.astype(jnp.bfloat16))            # [S, <= 128]
 
         def tree(g, acc):
+            """`acc` plus tree g's leaf value; of a sub-tree (`acc` None)
+            its exits e [S, W] bf16, 1 at the exit the sub-tree's root
+            leads this row to."""
             rows = planes_ref[g]                          # [8, W]
             # bf16 operands (bins <= 255, their multiples of 256, the 0/1
             # one-hot and _MANTISSA are exact), f32 accumulator: the v5e's
@@ -439,7 +562,39 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, out_ref, *,
             m = jax.lax.dot_general(
                 s, paths_ref[g], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [S, W]
+            if acc is None:
+                return jnp.where(m == rows[1:2, :], 1.0, 0.0).astype(
+                    jnp.bfloat16)
             return acc + jnp.where(m == rows[1:2, :], rows[2:3, :], 0.0)
+
+        if class_lanes:
+            # THE CHAIN (module docstring). Lane 0 of `act` is this
+            # sub-tree's activity, lane i that of the tree's sub-tree i
+            # further on; an exit's row of the leaves' table adds the leaf's
+            # pieces to the class lanes or 1 to the linked sub-tree's lane.
+            leaves_ref, _, act_ref = rest
+            act_lanes = act_ref.shape[1]
+            act = act_ref[pl.ds(r0, sub_rows), :]
+            acc = jnp.zeros((sub_rows, class_lanes), jnp.float32)
+            first = jax.lax.broadcasted_iota(
+                jnp.int32, (1, act_lanes), 1) == 0
+            for g in range(n_trees):
+                e = tree(g, None)
+                y = jax.lax.dot_general(
+                    e, leaves_ref[g], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # [S, CL + A]
+                # (row 4 says it in every lane; W may be fewer than A)
+                roots = jnp.concatenate(
+                    [planes_ref[g, 4:5, :_LANES]] * (act_lanes // _LANES),
+                    axis=1) > 0.0
+                act = jnp.where(roots, jnp.where(first, 1.0, 0.0), act)
+                a = act[:, 0:1]
+                acc = acc + a * y[:, :class_lanes]
+                act = pltpu.roll(act, act_lanes - 1, 1) \
+                    + a * y[:, class_lanes:]
+            act_ref[pl.ds(r0, sub_rows), :] = act
+            out_ref[pl.ds(r0, sub_rows), :] += acc
+            return carry
 
         # Unrolled: the compiler runs a tree's matmuls under the one
         # before's compares (a fori_loop here took 35% longer).
@@ -468,12 +623,19 @@ def predict_paths_pallas(
     base,
     missing_routes: bool = False,
     interpret: bool | None = None,
+    leaves: jax.Array | None = None,   # bf16 [S, W, CL + A]: the sub-tree form
+    chain: Chain | None = None,        # ... and its shape
+    mean: bool = False,
 ) -> jax.Array:
     """Raw margins [R]: Pallas twin of ops/predict._predict_paths. Jit-safe.
     interpret=None auto-selects the Pallas interpreter off-TPU. Where the
     select answers two nodes a lane (`select_nodes_per_lane`) a backend
     hands over `pack_select`'s tables, made once a model; the tables as
-    the model compiles them are packed here, by every call's program."""
+    the model compiles them are packed here, by every call's program.
+    With `leaves` and `chain` the tables' entries are SUB-TREES and the
+    answer is the sum over the trees of the reached leaves' vectors divided
+    by the trees, float32 [R, C] (`mean`: vector leaves), or of scalar
+    leaves the margin [R] as ever."""
     if interpret is None:
         interpret = device.platform() != "tpu"
     T, _, lanes = planes.shape
@@ -487,8 +649,8 @@ def predict_paths_pallas(
     row_dtype = row_operand_dtype(Xc.dtype)
     with traced_scope("predict:widen"):
         rows = Xc if Xc.dtype == row_dtype else Xc.astype(row_dtype)
-    plan = path_plan(T, lanes, F, row_dtype=row_dtype)
-    if not predict_paths_fits(lanes, F, row_dtype):
+    plan = path_plan(T, lanes, F, row_dtype=row_dtype, chain=chain)
+    if not predict_paths_fits(lanes, F, row_dtype, chain):
         if not interpret:
             raise ValueError(
                 f"path-matrix shape ({lanes} lanes a tree, F={F}) exceeds "
@@ -504,6 +666,8 @@ def predict_paths_pallas(
     with traced_scope("predict:tables"):
         sel_b, paths_b = jnp.pad(sel, t_fill), jnp.pad(paths, t_fill)
         planes_b = jnp.pad(planes, t_fill, constant_values=-1.0)
+        tables = (sel_b, planes_b, paths_b) + (
+            (jnp.pad(leaves, t_fill),) if chain else ())
     sub_rows = _sub_rows(plan.select_nodes_per_lane)
     tile_rows = min(TILE_ROWS, -(-R // sub_rows) * sub_rows)
     n_tiles = -(-R // tile_rows)
@@ -512,31 +676,65 @@ def predict_paths_pallas(
         return pl.BlockSpec((g, rows, cols), lambda i, b: (b, 0, 0),
                             memory_space=pltpu.VMEM)
 
+    exit_lanes = chain.exit_lanes if chain else 0
     cost = pl.CostEstimate(
         flops=2 * n_tiles * tile_rows * n_blocks * g * (
-            fp * sel_lanes + lanes * lanes),
+            fp * sel_lanes + lanes * lanes + lanes * exit_lanes),
         bytes_accessed=n_tiles * (
-            tile_rows * (F * row_dtype.itemsize + 4) + plan.table_bytes),
+            tile_rows * (F * row_dtype.itemsize
+                         + 4 * (chain.class_lanes if chain else 1))
+            + plan.table_bytes),
         transcendentals=0,
     )
+    # The scores' block, resident over the block axis: the rows on the
+    # lanes, or (the sub-tree form) on the sublanes with the class lanes'
+    # three pieces beside them and the activity in scratch.
+    if chain:
+        out_block, out_shape = (tile_rows, chain.class_lanes), (
+            R, chain.class_lanes)
+        out_index = lambda i, b: (i, 0)     # noqa: E731
+    else:
+        out_block, out_shape = (1, tile_rows), (1, R)
+        out_index = lambda i, b: (0, i)     # noqa: E731
     with traced_scope("predict:traverse_paths"):
         acc = pl.pallas_call(
             functools.partial(_paths_kernel, n_trees=g, n_feat=F,
-                              missing_routes=missing_routes),
+                              missing_routes=missing_routes,
+                              class_lanes=chain.class_lanes if chain else 0),
             # The grid walks the UNPADDED rows: the last tile's blocks are
             # ragged, as in the heap kernel.
             grid=(n_tiles, n_blocks),
             in_specs=[pl.BlockSpec((tile_rows, F), lambda i, b: (i, 0),
                                    memory_space=pltpu.VMEM),
                       table_block(fp, sel_lanes), table_block(8, lanes),
-                      table_block(lanes, lanes)],
-            out_specs=pl.BlockSpec((1, tile_rows), lambda i, b: (0, i),
+                      table_block(lanes, lanes)]
+            + [table_block(lanes, exit_lanes)] * bool(chain),
+            out_specs=pl.BlockSpec(out_block, out_index,
                                    memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((1, R), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+            scratch_shapes=[pltpu.VMEM((tile_rows, chain.act_lanes),
+                                       jnp.float32)] if chain else [],
             cost_estimate=cost,
             interpret=interpret,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        )(rows, sel_b, planes_b, paths_b)
+        )(rows, *tables)
+    if chain:
+        return fold_leaf_pieces(acc, chain, learning_rate, base, mean)
     with traced_scope("predict:accumulate"):
         return base + learning_rate * acc[0]
+
+
+def fold_leaf_pieces(acc, chain: Chain, learning_rate, base, mean: bool):
+    """[R, CL] sums of the leaf values' bfloat16 pieces, piece p's column c
+    in lane p C + c, to the model's answer float32 [R, C]: the pieces added
+    smallest first, then the mean over the trees or the margin's scale and
+    shift."""
+    c = chain.leaf_columns
+    with traced_scope("predict:accumulate"):
+        total = acc[:, (_LEAF_PIECES - 1) * c:_LEAF_PIECES * c]
+        for p in range(_LEAF_PIECES - 2, -1, -1):
+            total = total + acc[:, p * c:(p + 1) * c]
+        if mean:
+            return total / jnp.float32(chain.n_trees)
+        return base + learning_rate * total[:, 0]   # scalar leaves: a margin

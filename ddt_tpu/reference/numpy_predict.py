@@ -59,6 +59,28 @@ this walk, all of it arithmetic and none of it routing:
   the end: float32 rounding of a sum in another order, not bitwise.
   `tests/test_node_list.py` holds `api.predict` to it.
 
+The AVERAGED FOREST (a node list with VECTOR LEAVES, `leaf_value`
+[T, L, C], `loss` "mean"; scikit-learn's random forests through
+`models/sklearn_io.py`; `predict_proba_node_list`): the same walk of the
+UNCUT tree, and the score [rows, C] is the mean over the trees of the
+reached leaves' vectors, in float64. It knows nothing of sub-trees, links
+or lanes. Where the device path departs (`ops/predict.py`, "The chain"),
+again all arithmetic and no routing:
+
+- It cuts a tree into connected sub-trees of at most 256 lanes and resolves
+  every sub-tree for every row; a row's activity follows its one chain of
+  sub-trees, and the exit it ends in is the walk's leaf, for every row and
+  tree.
+- A leaf's float32 vector is held as three bfloat16 pieces (exactly) and
+  picked by a 0/1 matmul; the pieces are summed over the trees in float32,
+  piece by piece, then added and divided by the tree count: float32
+  rounding of a sum in another order (1e-8 of a class share), not bitwise.
+- Against scikit-learn itself: thresholds are float32 here (rounded down:
+  the same answer for every float32 row) where the library keeps float64,
+  and leaf vectors are normalised once at import where `predict_proba`
+  normalises at every call. `tests/test_forest.py` holds `api.predict` to
+  this file and to the library.
+
 The OBLIVIOUS ensemble (models/tree.ObliviousEnsemble; CatBoost's symmetric
 trees; `leaf_of_rows_oblivious`, `predict_raw_oblivious`): tree t of depth D
 is D splits and 2^D leaf values. Split d sets BIT d of the row's leaf index
@@ -149,6 +171,18 @@ def predict_raw_node_list(ens, Xb: np.ndarray,
         leaf = leaf_of_rows_node_list(ens, t, Xb)
         out += lr * ens.leaf_value[t].astype(dtype)[leaf]
     return out
+
+
+def predict_proba_node_list(ens, Xb: np.ndarray) -> np.ndarray:
+    """Mean class distributions float64 [rows, C] of an averaged forest (a
+    models/tree.NodeListEnsemble with vector leaves) over binned rows: the
+    reached leaves' vectors summed in float64 in tree order, divided by the
+    tree count."""
+    total = np.zeros((Xb.shape[0], ens.leaf_value.shape[2]), np.float64)
+    for t in range(ens.feature.shape[0]):
+        total += ens.leaf_value[t].astype(np.float64)[
+            leaf_of_rows_node_list(ens, t, Xb)]
+    return total / ens.feature.shape[0]
 
 
 def leaf_of_rows_oblivious(ens, t: int, Xb: np.ndarray) -> np.ndarray:

@@ -60,6 +60,9 @@ CRITEO = dict(rows=2_000_000, features=39)
 # LightGBM's Bosch model's scoring chunk (benchmark config
 # bosch-lgbm-500t-255l): 968 columns, TPUDevice.predict_chunk_rows of them.
 BOSCH = dict(chunk_rows=262_144, features=968)
+# scikit-learn's MNIST forest's scoring chunk (benchmark config
+# mnist-rf-100t-full): 784 pixel columns, TPUDevice.predict_chunk_rows.
+FOREST = dict(chunk_rows=299_593, features=784)
 # CatBoost's Epsilon model's scoring chunk (benchmark config
 # epsilon-catboost-8000t-d6): 2000 dense columns, 8000 trees of depth 6.
 EPSILON = dict(chunk_rows=131_072, features=2000, n_trees=8000, depth=6)
@@ -217,6 +220,35 @@ def _paths_case(rows, features, n_trees, n_leaves, missing=False):
         shapes = [(a.shape, a.dtype) for a in tables]
         shapes.append(((rows, features), jnp.uint8))
         return fn, shapes
+
+    return build
+
+
+def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
+                 act_lanes=128, mean=True):
+    """The SUB-TREE form of the path-matrix kernel (ops/predict_paths.py:
+    the chain and the class dot) over the compiled tables' SHAPES
+    (models/tree.CompiledNodeList: 1.35 GB at the MNIST forest's), the rows
+    as api.predict does (uint8)."""
+    def build():
+        import jax.numpy as jnp
+
+        from ddt_tpu.ops import predict_paths
+
+        class_lanes = -(-3 * classes // 128) * 128
+        chain = predict_paths.Chain(n_trees, classes, class_lanes, act_lanes)
+
+        def fn(sel, planes, paths, leaves, Xc):
+            return predict_paths.predict_paths_pallas(
+                sel, planes, paths, Xc, learning_rate=1.0, base=0.0,
+                interpret=False, leaves=leaves, chain=chain, mean=mean)
+
+        return fn, [
+            ((n_subtrees, -(-features // 16) * 16, lanes), jnp.bfloat16),
+            ((n_subtrees, 8, lanes), jnp.float32),
+            ((n_subtrees, lanes, lanes), jnp.bfloat16),
+            ((n_subtrees, lanes, chain.exit_lanes), jnp.bfloat16),
+            ((rows, features), jnp.uint8)]
 
     return build
 
@@ -399,6 +431,27 @@ def kernel_cases() -> list:
         KernelCase("paths/bosch/968f/20x255leaves/nan", True,
                    _paths_case(BOSCH["chunk_rows"], BOSCH["features"], 20,
                                255, missing=True)),
+        # The SUB-TREE form (the chain and the class dot): the MNIST
+        # forest's chunk (100 full-depth trees of up to 4,779 leaves: some
+        # 2,000 sub-trees of 256 lanes over 784 columns, 10 classes), and
+        # the rule's edges: one sub-tree a tree with one column, with 85
+        # (two class tiles, the most the rule takes) and with 128 (three:
+        # refused), sub-trees of one tile with two activity tiles.
+        KernelCase("forest/784f/100x4779x10", True,
+                   _forest_case(FOREST["chunk_rows"], FOREST["features"],
+                                100, 1995, 10)),
+        KernelCase("forest/28f/12x1subtree/c1", True,
+                   _forest_case(4_999, hf, 12, 12, 1)),
+        KernelCase("forest/28f/12x1subtree/c85", True,
+                   _forest_case(4_999, hf, 12, 12, 85)),
+        # (past the rule: three class tiles' output windows do not fit
+        # beside a row tile; `predict_paths_fits` says so and the
+        # jax.numpy form serves: tests/test_forest.py)
+        KernelCase("forest/28f/12x1subtree/c128", False,
+                   _forest_case(4_999, hf, 12, 12, 128)),
+        KernelCase("forest/129f/3x200subtrees/c10/128lanes", True,
+                   _forest_case(4_999, 129, 3, 600, 10, lanes=128,
+                                act_lanes=256)),
         # The oblivious form: CatBoost's Epsilon model's chunk (63 groups,
         # 16 K-blocks), the depths and widths at the dispatch rule's edges
         # (depth 10 at 28 columns fits, depth 7 at 2000), one K-block with

@@ -329,7 +329,10 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
         trees_per_step=3 * served, table_blocks=1 * served,
         table_bytes=3 * (16 * 128 * 2 + 8 * 128 * 4 + 128 * 128 * 2) * served,
         select_k_blocks=1, missing_routes=0, row_operand_bytes=1,
-        select_nodes_per_lane=1)           # 128 lanes: one tile already
+        select_nodes_per_lane=1,           # 128 lanes: one tile already
+        # one uncut path matrix a tree: a sub-tree of its own, no chain
+        subtrees_per_tree=1.0, subtree_lanes=128, leaf_columns=1,
+        chain_mxu_tiles_per_tree=0, class_dot_passes=0)
     assert counts["bytes"] == counts["table_bytes"] or not served
     assert root["counts"]["select_k_blocks"] == 1
 
@@ -525,6 +528,15 @@ def _small_oblivious(seed):
                             scale=0.5, bias=0.25)
 
 
+def _small_forest(seed):
+    """3 trees of 300 to 500 leaves with a 3-column vector a leaf: two or
+    three sub-trees a tree."""
+    from ddt_tpu.models.tree import random_node_list
+
+    return random_node_list(np.random.default_rng(seed), 3, (300, 500), 6,
+                            n_bins=31, leaf_columns=3)
+
+
 def _routed(ens, seed):
     """`ens` with a NaN bin, learned directions and category features:
     both routing tables."""
@@ -547,6 +559,9 @@ STAGE_MODELS = {
                   "jit_predict_raw_effective_paths"),
     "oblivious": (lambda: _small_oblivious(3105),
                   "jit_predict_raw_effective_oblivious"),
+    # an averaged forest: the sub-tree form (the chain and the class dot)
+    "forest": (lambda: _small_forest(3106),
+               "jit_predict_raw_effective_paths"),
 }
 STAGE_CASES = [(m, impl) for m in STAGE_MODELS
                for impl in ("pallas", "onehot")]
@@ -574,6 +589,11 @@ def test_every_instruction_of_a_scoring_program_has_a_stage(
     be = get_backend(TrainConfig(backend="tpu", n_bins=31,
                                  predict_impl=impl))
     monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", 256)
+    # (the node list's jax.numpy forms scan the chunk's rows in two pieces:
+    # what comes out of the scan is put together under a stage too)
+    from ddt_tpu.ops import predict as predict_ops
+
+    monkeypatch.setattr(predict_ops, "_PATHS_ROW_CHUNK", 128)
     Xb = np.random.default_rng(8).integers(0, 31, size=(600, 6),
                                            dtype=np.uint8)
     scores = be.predict_raw(ens, Xb)
@@ -588,6 +608,7 @@ def test_every_instruction_of_a_scoring_program_has_a_stage(
     traverse = "predict:traverse"
     if impl == "pallas":
         traverse = {"node-list": "predict:traverse_paths",
+                    "forest": "predict:traverse_paths",
                     "oblivious": "predict:traverse_oblivious"}.get(
                         model, traverse)
     assert {traverse, "predict:accumulate"} <= seen

@@ -38,6 +38,7 @@ HEAP_CASES = [c for c in DEFAULT_CASES if c.name.startswith("predict/")]
 PATH_CASES = [c for c in DEFAULT_CASES if c.name.startswith("paths/")]
 OBLIVIOUS_CASES = [c for c in DEFAULT_CASES
                    if c.name.startswith("oblivious/")]
+FOREST_CASES = [c for c in DEFAULT_CASES if c.name.startswith("forest/")]
 
 
 @functools.lru_cache(maxsize=None)
@@ -201,6 +202,37 @@ def test_path_kernel_takes_the_select_the_rule_packs(case):
     assert "ddt:predict:tables/concatenate" not in text
 
 
+@pytest.mark.parametrize("case", FOREST_CASES, ids=lambda c: c.name)
+def test_subtree_form_crosses_hbm_at_the_datas_width(case):
+    """The sub-tree form of the path kernel (the chain and the class dot)
+    keeps the kernel's interface on the way in (the uint8 chunk as it
+    comes, 784 columns at the MNIST forest's chunk, the last row tile
+    ragged) and takes a fourth table, the exits'; on the way out the rows
+    lie on the sublanes, `f32[R, CL]` with the leaf values' three pieces
+    on the class lanes, which XLA folds to `[R, C]` and divides by the
+    tree count under `predict:accumulate`."""
+    exported, shapes = _export_for_tpu(case)
+    (rows, features), dtype = shapes[-1]
+    (entries, lanes, exit_lanes), _ = shapes[3]
+    assert dtype == jnp.uint8 and len(shapes) == 5
+    text = exported.mlir_module()
+    call, = [ln for ln in text.splitlines()
+             if "@tpu_custom_call" in ln and "_paths_kernel" in ln]
+    operands, result = re.search(
+        r"\}\s*:\s*\((.*)\)\s*->\s*(tensor<[^>]*>)", call).groups()
+    assert operands.startswith(f"tensor<{rows}x{features}xui8>,")
+    assert f"tensor<{entries}x{lanes}x{exit_lanes}xbf16>" in operands
+    class_lanes = int(re.fullmatch(
+        rf"tensor<{rows}x(\d+)xf32>", result).group(1))
+    assert class_lanes % 128 == 0 and 0 < class_lanes < exit_lanes
+    for held in ("xi32>", "xf32>", "xbf16>"):
+        assert f"tensor<{rows}x{features}{held}" not in text
+    assert "ddt:predict:widen" not in text
+    assert "ddt:predict:accumulate" in text
+    out, = exported.out_avals
+    assert out.shape[0] == rows and out.shape[1] * 3 <= class_lanes
+
+
 @pytest.mark.parametrize("case", OBLIVIOUS_CASES, ids=lambda c: c.name)
 def test_oblivious_kernel_crosses_hbm_at_the_datas_width(case):
     """The oblivious form's interface is the other kernels': the uint8 chunk
@@ -266,6 +298,11 @@ def test_case_table_covers_the_default_dispatch():
                    # past one K-block of the select; Bosch's width with
                    # the NaN route in the compare
                    "paths/129f", "paths/bosch/968f",
+                   # the sub-tree form: the MNIST forest's chunk, one
+                   # sub-tree a tree at one column and at 85, one-tile
+                   # sub-trees with two activity tiles
+                   "forest/784f/100x4779x10", "forest/28f/12x1subtree/c1",
+                   "forest/28f/12x1subtree/c85", "forest/129f/3x200subtrees",
                    # the oblivious form: the Epsilon model's chunk, the
                    # dispatch rule's edges, one and two K-blocks
                    "oblivious/epsilon/8000x6", "oblivious/28f/300x10",
