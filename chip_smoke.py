@@ -476,10 +476,13 @@ def score_forest(overrides: dict, rows: int) -> None:
     SUB-TREE form of the path kernel served it by the auto dispatch
     (`subtrees_per_tree` > 1 and `leaf_columns` 10 on its spans: every tree
     cut into sub-trees of 256 lanes and chained, the class dot's three
-    bfloat16 pieces), holds the scores [rows, 10] to the walk of the uncut
-    trees (reference/numpy_predict.predict_proba_node_list, float64), and
-    holds the compiled kernel and its jax.numpy twin bit-equal on dyadic
-    leaf vectors over 8 trees (every sum and the mean exact)."""
+    bfloat16 pieces; `select_mxu_tiles` under 14: a sub-tree's lanes
+    ordered by their column's K-block, each lane tile of the select asking
+    for its own blocks alone), holds the scores [rows, 10] to the walk of
+    the uncut trees (reference/numpy_predict.predict_proba_node_list,
+    float64), and holds the compiled kernel and its jax.numpy twin bit-equal
+    on dyadic leaf vectors over 8 trees (every sum and the mean exact), at
+    784 columns so that the spans engage there too."""
     from ddt_tpu import api
     from ddt_tpu.config import TrainConfig
     from ddt_tpu.models.tree import random_node_list
@@ -507,6 +510,7 @@ def score_forest(overrides: dict, rows: int) -> None:
         "ddt:predict:ensemble"]
     say(f"forest predict: ddt:predict:ensemble {built}; root "
         f"subtrees_per_tree={root['counts']['subtrees_per_tree']} "
+        f"select_mxu_tiles={root['counts']['select_mxu_tiles']} "
         f"tables_streamed_bytes={root['counts']['tables_streamed_bytes']}")
     assert built["node_list"] == root["counts"]["node_list"] == 1, built
     assert built["subtrees_per_tree"] > 1, "no tree was cut"
@@ -515,6 +519,8 @@ def score_forest(overrides: dict, rows: int) -> None:
             built["class_dot_passes"], built["select_k_blocks"]) == (
                 256, C, 3, 7), built
     assert built["trees_per_step"] > 0, "the path kernel did not serve"
+    # 7 K-blocks x 2 lane tiles dense; uniform columns split at the middle
+    assert 7 <= built["select_mxu_tiles"] <= 9, built
     assert_compiled_kernel(cfg, ens, rows, "forest")
     n = min(2_000, rows)
     want = numpy_predict.predict_proba_node_list(ens, Xb[:n])
@@ -522,9 +528,11 @@ def score_forest(overrides: dict, rows: int) -> None:
     say(f"forest scores: {n} rows against reference/numpy_predict "
         f"(float64), max |diff| = {gap:.2e} (<= 1e-5)")
     assert gap <= 1e-5, gap
-    exact = random_node_list(rng, 8, (300, 900), 129, bins, dyadic=True,
+    exact = random_node_list(rng, 8, (300, 900), F, bins, dyadic=True,
                              leaf_columns=3)
-    Xe = rng.integers(0, bins, size=(4_999, 129), dtype=np.uint8)
+    spans = exact.compile().select_spans
+    assert spans == ((0, 3), (3, 7)), spans
+    Xe = rng.integers(0, bins, size=(4_999, F), dtype=np.uint8)
     kernel = api.predict(exact, Xe, binned=True, raw=True, cfg=cfg)
     twin = api.predict(exact, Xe, binned=True, raw=True, cfg=TrainConfig(
         n_bins=bins, backend="tpu", predict_impl="onehot"))
@@ -532,7 +540,7 @@ def score_forest(overrides: dict, rows: int) -> None:
     assert np.array_equal(kernel, twin) and np.array_equal(
         kernel, walk.astype(np.float32)), "dyadic forest not bit-equal"
     say("forest grid: kernel, twin and walk bit-equal on 8 dyadic trees x "
-        "4,999 rows x 129 columns x 3 classes")
+        f"4,999 rows x {F} columns x 3 classes, select spans {spans}")
 
 
 def score_oblivious(overrides: dict, rows: int) -> None:

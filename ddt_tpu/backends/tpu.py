@@ -2007,7 +2007,8 @@ class TPUDevice(DeviceBackend):
         # [rows, C].
         classes = ce.leaf_columns
         chain = predict_paths.chain_of(
-            ce.n_trees, classes, ce.leaves.shape[2]) if ce.chained else None
+            ce.n_trees, classes, ce.leaves.shape[2],
+            ce.select_spans) if ce.chained else None
         plan = predict_paths.path_plan(
             ce.n_subtrees or ce.n_trees, ce.lanes, ens.n_features,
             ce.deepest_leaf,
@@ -2040,7 +2041,7 @@ class TPUDevice(DeviceBackend):
                       use_pallas=use_pallas, missing_routes=missing_routes)
         if chain:
             static.update(n_trees=ce.n_trees, leaf_columns=classes,
-                          mean=ce.mean)
+                          mean=ce.mean, select_spans=chain.select_spans)
 
         # (two functions: the uncut form keeps its program's parameter
         # names, so its HLO is what it was)

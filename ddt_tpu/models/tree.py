@@ -1159,11 +1159,144 @@ class SubtreeCut(typing.NamedTuple):
     root: np.ndarray           # bool  [T, N] the nodes that root a sub-tree
     subtree: np.ndarray        # int32 [T, N] a node's sub-tree, numbered in
     #   its tree by the pre-order of the roots (-1: no such node)
-    lane: np.ndarray           # int32 [T, N] its number in the sub-tree, in
-    #   pre-order (the root 0)
+    lane: np.ndarray           # int32 [T, N] its number in the sub-tree: the
+    #   pre-order (the root 0), or under `spans` the pre-order inside the
+    #   128-lane tile whose K-blocks hold the node's column
 
 
-def cut_subtrees(ens: NodeListEnsemble, lanes: int) -> SubtreeCut:
+def _levels(ens: NodeListEnsemble) -> list:
+    """The internal nodes of every tree by their depth: [(tree index, node
+    index)] a level, the roots first. `_parents` has proved one parent a
+    node, so what the roots reach are trees; nodes they do not reach are a
+    cycle among themselves."""
+    t = np.nonzero(ens.n_leaves > 1)[0]
+    n = np.zeros(len(t), np.int64)
+    levels = []
+    while len(t):
+        levels.append((t, n))
+        kids = np.stack([ens.left_child[t, n], ens.right_child[t, n]], 1)
+        down = kids >= 0
+        t, n = np.repeat(t, 2)[down.ravel()], kids[down].astype(np.int64)
+    if sum(len(t) for t, _ in levels) != ens.n_splits:
+        raise ValueError("node list: a cycle among the nodes")
+    return levels
+
+
+def _kids(ens: NodeListEnsemble, t, n, of: np.ndarray) -> tuple:
+    """(`of` [..., T, N] at the children [..., k, 2], the children [k, 2])
+    of the nodes (t, n); a child that is a leaf reads 0."""
+    kids = np.stack([ens.left_child[t, n], ens.right_child[t, n]], 1)
+    at = of[..., t[:, None], np.maximum(kids, 0)]
+    at[..., kids < 0] = 0
+    return at, kids
+
+
+def dense_spans(n_features: int, lanes: int) -> tuple:
+    """The select's spans that bound nothing: every 128-lane tile of a
+    sub-tree reads all the K-blocks of 128 columns."""
+    return ((0, -(-n_features // PATH_LANES)),) * -(-lanes // PATH_LANES)
+
+
+def _subtree_roots(ens: NodeListEnsemble, levels: list, lanes: int,
+                   spans: tuple) -> tuple:
+    """`cut_subtrees`'s bottom-up walk: (the nodes that root a sub-tree,
+    bool [T, N]; the nodes that only the first / only the second lane tile
+    may hold under `spans`, bool [2, T, N])."""
+    live = ens.live_nodes
+    block = ens.feature // PATH_LANES
+    only = np.stack([live & (block < spans[-1][0]),
+                     live & (block >= spans[0][1])])
+    if only.any() and (len(spans) != 2 or lanes != 2 * PATH_LANES):
+        raise ValueError("the select's spans bound a cut into sub-trees of "
+                         f"two lane tiles; got {spans} at {lanes} lanes")
+    if (spans[0][0], spans[-1][1]) != dense_spans(ens.n_features, lanes)[0] \
+            or spans[-1][0] > spans[0][1]:
+        raise ValueError(f"the select's spans {spans} leave a K-block of "
+                         f"{ens.n_features} columns to no lane tile")
+    # a node's remainder: its part's nodes, and those only a tile may hold
+    weight = np.concatenate([live[None], only]).astype(np.int64)
+    caps = np.array([lanes - 1, PATH_LANES, PATH_LANES])[:, None]
+    root = np.zeros(live.shape, bool)
+    root[ens.n_leaves > 1, 0] = True
+    for t, n in reversed(levels):       # children before parents
+        w, kids = _kids(ens, t, n, weight)          # [3, k, 2]
+        for _ in range(2):
+            over = weight[:, t, n] + w.sum(axis=2) > caps
+            hit = np.nonzero(over.any(axis=0))[0]
+            if not len(hit):
+                break
+            # the weight that is over (a tile's before the part's), and the
+            # child heaviest in it; equal ones: the later node
+            which = np.where(over[1], 1, np.where(over[2], 2, 0))[hit]
+            left, right = w[which, hit, 0], w[which, hit, 1]
+            side = ((right > left) | ((right == left) & (
+                kids[hit, 1] > kids[hit, 0]))).astype(np.int64)
+            root[t[hit], kids[hit, side]] = True
+            w[:, hit, side] = 0
+        weight[:, t, n] += w.sum(axis=2)
+    return root, only
+
+
+def _rank(key: np.ndarray, mask: np.ndarray | None = None) -> np.ndarray:
+    """Each entry's number among those of its `key` (sorted) that `mask`
+    takes, in the order given."""
+    mask = np.ones(len(key), bool) if mask is None else mask
+    before = np.cumsum(mask) - mask
+    return before - before[np.searchsorted(key, key)]
+
+
+def _numbered(ens: NodeListEnsemble, levels: list, node_parent: np.ndarray,
+              root: np.ndarray, only: np.ndarray, lanes: int) -> SubtreeCut:
+    """`cut_subtrees`'s numbers, from the roots of the sub-trees."""
+    T, N = root.shape
+    live = ens.live_nodes
+    # Pre-order numbers top-down: a left child follows its parent, a right
+    # one the left's whole tree.
+    size = live.astype(np.int64)        # of the uncut tree below a node
+    for t, n in reversed(levels):
+        size[t, n] += _kids(ens, t, n, size)[0].sum(axis=1)
+    pre = np.zeros((T, N), np.int64)
+    for t, n in levels:
+        below, kids = _kids(ens, t, n, size)
+        for side, first in ((0, pre[t, n] + 1),
+                            (1, pre[t, n] + 1 + below[:, 0])):
+            has = kids[:, side] >= 0
+            pre[t[has], kids[has, side]] = first[has]
+    t_idx, n_idx = np.nonzero(live)
+    by_pre = np.lexsort((pre[t_idx, n_idx], t_idx))
+    t_idx, n_idx = t_idx[by_pre], n_idx[by_pre]     # trees, each in pre-order
+    # a root's number in its tree; a node's is its root's, handed down
+    is_root = root[t_idx, n_idx]
+    subtree = np.full((T, N), -1, np.int32)
+    subtree[t_idx[is_root], n_idx[is_root]] = _rank(t_idx, is_root)[is_root]
+    for t, n in levels[1:]:
+        inner = ~root[t, n]
+        subtree[t[inner], n[inner]] = subtree[
+            t[inner], node_parent[t[inner], n[inner]]]
+    n_subtrees = np.maximum(root.sum(axis=1), 1)
+
+    # The lanes: a part's nodes in pre-order, told apart by their tile. A
+    # node only one tile may hold lies there, the others fill the first
+    # tile's room and then the second's (unbounded: all in the first).
+    part = np.concatenate([[0], np.cumsum(n_subtrees)])[t_idx] \
+        + subtree[t_idx, n_idx]
+    by_part = np.argsort(part, kind="stable")
+    part = part[by_part]
+    one0, one1 = (o[t_idx, n_idx][by_part] for o in only)
+    free = ~(one0 | one1)
+    room = (PATH_LANES if only.any() else lanes) \
+        - np.bincount(part, weights=one0)[part]
+    second = one1 | (free & (_rank(part, free) >= room))
+    by_tile = np.lexsort((np.arange(len(part)), second, part))
+    at = by_part[by_tile]
+    lane = np.zeros((T, N), np.int32)
+    lane[t_idx[at], n_idx[at]] = PATH_LANES * second[by_tile] + _rank(
+        (2 * part + second)[by_tile])
+    return SubtreeCut(n_subtrees, root, subtree, lane)
+
+
+def cut_subtrees(ens: NodeListEnsemble, lanes: int,
+                 spans: tuple | None = None) -> SubtreeCut:
     """Every tree cut into connected SUB-TREES of at most `lanes` - 1
     internal nodes, so of at most `lanes` EXITS (an exit is a child that
     is a leaf, or a link: a child that roots another sub-tree). A
@@ -1175,51 +1308,84 @@ def cut_subtrees(ens: NodeListEnsemble, lanes: int) -> SubtreeCut:
     bound on a part's weight): a node keeps its children's remainders
     while they fit beside it and otherwise cuts the HEAVIER child off as
     a root of its own. A tree of one leaf is one sub-tree of no node.
-    No [N, L] matrix of a whole tree is made (a walk of the child lists:
-    0.5 s for 100 trees of 4,000 leaves)."""
-    ens._parents()              # in range, one parent each
-    T, N = ens.feature.shape
-    cap = lanes - 1
-    root = np.zeros((T, N), bool)
-    subtree = np.full((T, N), -1, np.int32)
-    lane = np.zeros((T, N), np.int32)
-    n_subtrees = np.ones(T, np.int64)
-    for t in range(T):
-        n_int = int(ens.n_leaves[t]) - 1
-        if n_int == 0:
-            continue
-        lc, rc = ens.left_child[t].tolist(), ens.right_child[t].tolist()
-        order, parent, stack = [], [-1] * n_int, [0]
-        while stack:
-            n = stack.pop()
-            order.append(n)
-            for c in (rc[n], lc[n]):        # left first off the stack
-                if c >= 0:
-                    parent[c] = n
-                    stack.append(c)
-        if len(order) != n_int:
-            raise ValueError("node list: a cycle among the nodes")
-        weight, cut = [0] * n_int, [False] * n_int
-        for n in reversed(order):           # children before parents
-            kids = sorted((weight[c], c) for c in (lc[n], rc[n]) if c >= 0)
-            total = 1 + sum(w for w, _ in kids)
-            while total > cap:
-                w, c = kids.pop()
-                cut[c], total = True, total - w
-            weight[n] = total
-        cut[0] = True
-        filled = []
-        for n in order:
-            if cut[n]:
-                subtree[t, n] = len(filled)
-                filled.append(1)
-            else:
-                k = subtree[t, n] = subtree[t, parent[n]]
-                lane[t, n] = filled[k]
-                filled[k] += 1
-        root[t, :n_int] = cut
-        n_subtrees[t] = len(filled)
-    return SubtreeCut(n_subtrees, root, subtree, lane)
+
+    `spans`: the K-blocks of the feature select that each of a sub-tree's
+    TWO 128-lane tiles reads, ((0, stop), (start, blocks)) with start <=
+    stop (`CompiledNodeList.select_spans`). A part then holds three bounds,
+    so that its nodes can be numbered with every lane's K-block inside its
+    tile's span: the nodes whose block only the first tile reads (below
+    `start`) are at most 128, those only the second reads (from `stop`)
+    at most 128, all of them at most `lanes` - 1; the child cut off is
+    the heaviest in the weight that is over. The numbers: a node that only
+    one tile reads lies there, the others fill the first tile's room and
+    then the second's; inside a tile the pre-order. Without spans (or with
+    dense ones, which bound nothing) the lanes are the pre-order, 0.. with
+    no gap.
+
+    No [N, L] matrix of a whole tree is made, and no node is walked in
+    Python: a level of all the trees a step (0.4 s for 100 trees of 4,000
+    leaves)."""
+    node_parent = ens._parents()[0]     # in range, one parent each
+    levels = _levels(ens)
+    spans = spans or dense_spans(ens.n_features, lanes)
+    root, only = _subtree_roots(ens, levels, lanes, spans)
+    return _numbered(ens, levels, node_parent, root, only, lanes)
+
+
+def subtree_mxu_tiles(spans: tuple, lanes: int, exit_lanes: int) -> int:
+    """MXU weight tiles a sub-tree of `lanes` lanes costs a tile of rows
+    (ops/predict_paths.path_mxu_tiles_per_tree, the unpacked select): the
+    select's, a tile a K-block of every lane tile's span, the resolve's,
+    the exits' table's."""
+    w = lanes // PATH_LANES
+    return (sum(stop - start for start, stop in spans) + w * w
+            + w * (exit_lanes // PATH_LANES))
+
+
+def _act_lanes(n_subtrees: np.ndarray) -> int:
+    """The chain's activity lanes: whole 128s, more than a tree's
+    sub-trees."""
+    return -(-(int(n_subtrees.max()) + 1) // PATH_LANES) * PATH_LANES
+
+
+def choose_select_spans(ens: NodeListEnsemble, lanes: int,
+                        class_lanes: int) -> tuple:
+    """(`CompiledNodeList.select_spans`, the model's cut under them): which
+    K-blocks of the feature select each 128-lane tile of a sub-tree reads,
+    from what the model shows. A node's one K row lies in ONE K-block, and
+    which block a lane reads is decided by the order of the lanes alone, so
+    lanes ordered by their column's block let a tile skip the blocks none
+    of its nodes reads (all-zero weight tiles), at the price of a cut that
+    holds a bound a tile (`cut_subtrees`) and so makes a few more parts.
+
+    The candidates split the blocks at the one in which the cumulative
+    share of the model's internal nodes passes one half: that block read by
+    both tiles, by the first alone, by the second alone; and the dense
+    spans. Taken is the one whose cut asks the fewest MXU weight tiles a
+    tree (`subtree_mxu_tiles` x the sub-trees), the dense one where none
+    asks fewer: one K-block (F <= 128), sub-trees of another width than two
+    tiles, a model of no internal node."""
+    node_parent = ens._parents()[0]
+    levels = _levels(ens)
+    dense = dense_spans(ens.n_features, lanes)
+    blocks = dense[0][1]
+    candidates = [dense]
+    if blocks > 1 and lanes == 2 * PATH_LANES and ens.n_splits:
+        share = np.cumsum(np.bincount(
+            ens.feature[ens.live_nodes] // PATH_LANES, minlength=blocks))
+        h = int(np.searchsorted(share, share[-1] / 2, side="right"))
+        candidates += [((0, stop), (start, blocks)) for stop, start in (
+            (h + 1, h), (h + 1, h + 1), (h, h)) if stop > 0 and start < blocks]
+    best = None
+    for spans in candidates:
+        root, only = _subtree_roots(ens, levels, lanes, spans)
+        per_tree = np.maximum(root.sum(axis=1), 1)
+        tiles = int(per_tree.sum()) * subtree_mxu_tiles(
+            spans, lanes, class_lanes + _act_lanes(per_tree))
+        if best is None or tiles < best[0]:
+            best = (tiles, spans, root, only)
+    _, spans, root, only = best
+    return spans, _numbered(ens, levels, node_parent, root, only, lanes)
 
 
 # bfloat16 pieces a float32 leaf value is held in (`split_bfloat16`; the
@@ -1266,7 +1432,18 @@ class CompiledNodeList:
     than `PATH_UNCUT_LANES` lanes): the same three tables with one entry a
     SUB-TREE of `cut_subtrees` (W = `SUBTREE_LANES`; S entries, a tree's
     in a row, parents before children), a sub-tree's "leaves" its EXITS,
-    and a fourth table that says what an exit is:
+    and a fourth table that says what an exit is. A sub-tree's nodes are
+    numbered BY THE K-BLOCK OF THEIR COLUMN: `select_spans` says for each of
+    its 128-lane tiles which K-blocks of 128 columns the nodes there may
+    read, ((0, 3), (3, 7)) at the MNIST forest's 784 columns (the same for
+    every entry; `choose_select_spans` reads them from the model), so that
+    the kernel asks the MXU for a tile's own blocks alone and not for the
+    all-zero tiles of `sel`: 7 select tiles a sub-tree where 14. Inside a
+    lane tile the order is the pre-order of the cut; a tile's unused lanes
+    are those of no node (threshold +BIG, no path), wherever they lie;
+    `planes` rows 0 and 3, `paths`' rows and the exits' order follow the
+    lanes. Dense spans (every tile reads every block: F <= 128, or a model
+    the split buys nothing) number the nodes in pre-order, 0.. with no gap.
 
         planes row 4             1 in the entry that roots a tree, else 0
                                  (row 2 is not read)
@@ -1303,6 +1480,8 @@ class CompiledNodeList:
     leaf_columns: int = 1      # C
     mean: bool = False
     widest_tree: int = 0       # lanes the widest tree would take uncut
+    select_spans: tuple = ()   # the sub-tree form: (first, stop) K-blocks
+    #   of the select a lane tile
 
     @property
     def n_classes_out(self) -> int:
@@ -1370,12 +1549,12 @@ class CompiledNodeList:
         bf16 = ml_dtypes.bfloat16
         T, N = ens.feature.shape
         W, C = SUBTREE_LANES, ens.leaf_columns    # the module's, as it is
-        cut = cut_subtrees(ens, W)
+        CL = -(-LEAF_PIECES * C // PATH_LANES) * PATH_LANES
+        spans, cut = choose_select_spans(ens, W, CL)
         node_parent, node_side, _, _ = ens._parents()
         first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
         S = int(first[-1])
-        CL = -(-LEAF_PIECES * C // PATH_LANES) * PATH_LANES
-        A = -(-(int(cut.n_subtrees.max()) + 1) // PATH_LANES) * PATH_LANES
+        A = _act_lanes(cut.n_subtrees)
         t_idx, n_idx = np.nonzero(ens.live_nodes)
         at = first[t_idx] + cut.subtree[t_idx, n_idx]      # the entry
         ln = cut.lane[t_idx, n_idx]
@@ -1449,7 +1628,7 @@ class CompiledNodeList:
             sel=sel, planes=planes, paths=paths.view(bf16),
             missing_bin_value=ens.missing_bin_value, leaves=leaves,
             n_subtrees=S, leaf_columns=C, mean=ens.vector_leaves,
-            widest_tree=widest)
+            widest_tree=widest, select_spans=spans)
 
 
 # ---------------------------------------------------------------------- #

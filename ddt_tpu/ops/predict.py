@@ -686,7 +686,8 @@ def _predict_chain(sel, planes, paths, leaves, Xc, *, chain, learning_rate,
 @functools.partial(
     jax.jit,
     static_argnames=("learning_rate", "base", "use_pallas",
-                     "missing_routes", "n_trees", "leaf_columns", "mean"),
+                     "missing_routes", "n_trees", "leaf_columns", "mean",
+                     "select_spans"),
 )
 @op_scope("predict")
 def predict_raw_effective_paths(
@@ -704,6 +705,7 @@ def predict_raw_effective_paths(
     n_trees: int = 0,
     leaf_columns: int = 1,
     mean: bool = False,
+    select_spans: tuple = (),
 ) -> jax.Array:
     """Raw margins [R] of a node-list ensemble from its compiled tables
     (models/tree.CompiledNodeList): the path-matrix form, by the Pallas
@@ -715,7 +717,9 @@ def predict_raw_effective_paths(
     is the one-compare program. With `leaves` the tables are the SUB-TREE
     form's (one entry a sub-tree of the `n_trees` trees, `leaf_columns`
     values a leaf) and the answer of vector leaves (`mean`) is the mean
-    over the trees, float32 [R, leaf_columns]."""
+    over the trees, float32 [R, leaf_columns]; `select_spans`
+    (`CompiledNodeList.select_spans`) the K-blocks of the select each lane
+    tile of a sub-tree reads, which the kernel alone asks for."""
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
         raise ValueError("the path-matrix form scores binned (integer) rows")
     from ddt_tpu.ops import predict_paths
@@ -723,7 +727,8 @@ def predict_raw_effective_paths(
     chain, exit_lanes = None, 0
     if leaves is not None:
         exit_lanes = leaves.shape[2]
-        chain = predict_paths.chain_of(n_trees, leaf_columns, exit_lanes)
+        chain = predict_paths.chain_of(n_trees, leaf_columns, exit_lanes,
+                                       select_spans)
     if Xc.shape[0] == 0:
         return jnp.full((0, leaf_columns) if mean else (0,),
                         0.0 if mean else base, jnp.float32)

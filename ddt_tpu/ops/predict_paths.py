@@ -18,9 +18,12 @@ of 128), not by its depth: `path_mxu_tiles_per_tree` MXU weight tiles a
 tree, ceil(F/128) x ceil(W / P / 128) for v (P nodes a result lane, next
 paragraph) and (W/128)^2 for m: 5 at 255 leaves and 28 columns (6 at P =
 1), 18 at 512 lanes, 2 at 128, 20 at 968 columns; in the sub-tree form (THE
-CHAIN, below) W/128 x (CL + A)/128 more a sub-tree for the exits' table, 22
-a sub-tree of 256 lanes at 784 columns and 10 classes (7 x 2 + 4 + 2 + 2),
-443 a tree of the MNIST forest's 20.15 sub-trees.
+CHAIN, below) W/128 x (CL + A)/128 more a sub-tree for the exits' table, and
+the select's tiles by the SPANS of its lane tiles (next but one paragraph):
+15 a sub-tree of 256 lanes at 784 columns and 10 classes (3 + 4 of the
+select, 4 + 2 + 2), 317 a tree of the MNIST forest's 21.12 sub-trees; with
+every lane tile reading every K-block (dense spans) 22 (7 x 2 + 4 + 2 + 2),
+443 a tree of 20.15.
 
 TWO NODES A RESULT LANE (`select_nodes_per_lane`, P: from F and W, nothing
 else; the heap kernel's `nodes_per_tile` in this form). The select contracts
@@ -54,6 +57,23 @@ from the row tile as HBM holds it and widened uint8 -> bf16 in VMEM, once a
 sub-tile, the last block's K padded to the tables' Fp (a multiple of 16).
 One non-zero a column of sel and bins below 256: every partial product is
 exact and so is their sum in any order.
+
+... and K-BLOCK SPARSE in the sub-tree form (`Chain.select_spans`,
+`select_mxu_tiles`). A node's one K row lies in ONE K-block, so of the
+ceil(F/128) weight tiles a 128-lane tile of the select asks, all but the
+blocks its own nodes read are zeros; and which block a lane reads is decided
+by the ORDER of the lanes alone, which is free. So the host numbers a
+sub-tree's nodes by the K-block of their column (models/tree.
+CompiledNodeList: lane tile j holds only nodes whose block lies in span j, a
+contiguous range of K-blocks, the same for every entry; the cut holds a
+bound a tile so that every part can be so numbered), and the kernel sums
+lane tile j's `v` over span j's blocks alone: x_k @ sel[k, tile j] for k in
+span j, [S, 128] results, unrolled and static. The skipped tiles are exactly
+zero, so v is what it was. Spans that are all the same (dense: one K-block,
+one lane tile, or a model the split buys nothing) are ONE matmul a K-block
+over all the lanes: the program above, instruction for instruction. `sel`
+stays [S, Fp, W] in HBM and its windows whole in VMEM (the DMA streams the
+zero tiles, the MXU does not ask for them).
 
 THE CHAIN: the SUB-TREE form (`class_lanes` > 0; ops/predict.py has the
 equations, models/tree.CompiledNodeList the tables). A tree of more lanes
@@ -229,19 +249,32 @@ def _select_shape(lanes: int, n_features: int, nodes_per_lane: int) -> tuple:
     return -(-k2 // 16) * 16, _lane_pad(lanes // 2)
 
 
-def path_mxu_tiles_per_tree(lanes: int, n_features: int,
-                            nodes_per_lane: int | None = None,
-                            exit_lanes: int = 0) -> int:
-    """MXU weight tiles (results [rows, 128]) a tree costs a tile of rows:
-    the feature select's, ceil(F/128) x ceil(W / nodes a lane / 128), and
-    the path resolve's, (W/128)^2; of a SUB-TREE besides the exits' table's,
-    W/128 x `exit_lanes`/128 (the class dot and the chain).
+def select_mxu_tiles(lanes: int, n_features: int,
+                     nodes_per_lane: int | None = None,
+                     select_spans: tuple = ()) -> int:
+    """MXU weight tiles of a tree's (a sub-tree's) feature select: ceil(F /
+    128) K-blocks x ceil(W / nodes a lane / 128) lane tiles, or under
+    `select_spans` (`Chain`) the K-blocks of each lane tile's own span.
     `nodes_per_lane` None: the kernel's own (`select_nodes_per_lane`)."""
     if nodes_per_lane is None:
         nodes_per_lane = select_nodes_per_lane(n_features, lanes)
+    if select_spans and nodes_per_lane == 1:
+        return sum(stop - start for start, stop in select_spans)
+    return select_k_blocks(n_features) * -(-(lanes // _LANES)
+                                           // nodes_per_lane)
+
+
+def path_mxu_tiles_per_tree(lanes: int, n_features: int,
+                            nodes_per_lane: int | None = None,
+                            exit_lanes: int = 0,
+                            select_spans: tuple = ()) -> int:
+    """MXU weight tiles (results [rows, 128]) a tree costs a tile of rows:
+    the feature select's (`select_mxu_tiles`) and the path resolve's,
+    (W/128)^2; of a SUB-TREE besides the exits' table's, W/128 x
+    `exit_lanes`/128 (the class dot and the chain)."""
     w = lanes // _LANES
-    return (select_k_blocks(n_features) * -(-w // nodes_per_lane) + w * w
-            + w * (exit_lanes // _LANES))
+    return (select_mxu_tiles(lanes, n_features, nodes_per_lane, select_spans)
+            + w * w + w * (exit_lanes // _LANES))
 
 
 def _tree_bytes(lanes: int, n_features: int, nodes_per_lane: int = 1,
@@ -261,6 +294,9 @@ class Chain(typing.NamedTuple):
     leaf_columns: int          # C
     class_lanes: int           # CL: three bfloat16 pieces of C columns
     act_lanes: int             # A: the chain's activity lanes
+    select_spans: tuple = ()   # (first, stop) K-blocks of the select each
+    #   128-lane tile of a sub-tree reads (models/tree.CompiledNodeList);
+    #   (): every tile reads every block
 
     @property
     def exit_lanes(self) -> int:
@@ -272,11 +308,12 @@ class Chain(typing.NamedTuple):
 _LEAF_PIECES = 3
 
 
-def chain_of(n_trees: int, leaf_columns: int, exit_lanes: int) -> Chain:
+def chain_of(n_trees: int, leaf_columns: int, exit_lanes: int,
+             select_spans: tuple = ()) -> Chain:
     """The Chain of a compiled model whose exits' table is `exit_lanes`
-    wide."""
+    wide and whose lanes are ordered under `select_spans`."""
     cl = _lane_pad(_LEAF_PIECES * leaf_columns)
-    return Chain(n_trees, leaf_columns, cl, exit_lanes - cl)
+    return Chain(n_trees, leaf_columns, cl, exit_lanes - cl, select_spans)
 
 
 class PathPlan(typing.NamedTuple):
@@ -304,6 +341,9 @@ class PathPlan(typing.NamedTuple):
     #   exits against the activity lanes
     class_dot_passes: int = 0       # bfloat16 pieces of a float32 leaf value
     #   in the class dot's tile (0: leaf values added on the VPU)
+    select_mxu_tiles: int = 0       # of a sub-tree's tiles: the feature
+    #   select's (14 at 784 columns and 256 lanes; 7 where each lane tile
+    #   reads its own K-blocks alone, `Chain.select_spans`)
 
     @property
     def blocks(self) -> int:
@@ -331,7 +371,8 @@ class PathPlan(typing.NamedTuple):
 # the order it prints (docs/OBSERVABILITY.md); `cli predict` repeats all
 # but `table_bytes` in `phases_ms`, as it does for the heap kernel's.
 CHAIN_COUNTS = ("subtrees_per_tree", "subtree_lanes", "leaf_columns",
-                "chain_mxu_tiles_per_tree", "class_dot_passes")
+                "chain_mxu_tiles_per_tree", "class_dot_passes",
+                "select_mxu_tiles")
 SPAN_COUNTS = ("node_list", "nodes_per_tree", "leaves_per_tree",
                "deepest_leaf", "path_mxu_tiles_per_tree", "trees_per_step",
                "table_blocks", "table_bytes", "select_k_blocks",
@@ -367,22 +408,28 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     # The jax.numpy form takes the select as the model compiles it.
     pack = select_nodes_per_lane(n_features, lanes) if served else 1
     exit_lanes = chain.exit_lanes if chain else 0
-    tiles = path_mxu_tiles_per_tree(lanes, n_features, pack, exit_lanes)
+    spans = chain.select_spans if chain else ()
+    tiles = path_mxu_tiles_per_tree(lanes, n_features, pack, exit_lanes,
+                                    spans)
     row_bytes = row_operand_dtype(row_dtype).itemsize
-    said = (select_k_blocks(n_features), int(missing_routes), row_bytes,
-            pack)
+    said = dict(select_k_blocks=select_k_blocks(n_features),
+                missing_routes=int(missing_routes),
+                row_operand_bytes=row_bytes, select_nodes_per_lane=pack,
+                subtree_lanes=lanes,
+                select_mxu_tiles=select_mxu_tiles(lanes, n_features, pack,
+                                                  spans))
     widest_tree = widest_tree or lanes
     if chain:
         per = n_trees / chain.n_trees
-        said += (round(per, 2), lanes, chain.leaf_columns,
-                 round(per * (lanes // _LANES)
-                       * (chain.act_lanes // _LANES)), _LEAF_PIECES)
+        said.update(subtrees_per_tree=round(per, 2),
+                    leaf_columns=chain.leaf_columns,
+                    chain_mxu_tiles_per_tree=round(
+                        per * (lanes // _LANES) * (chain.act_lanes // _LANES)),
+                    class_dot_passes=_LEAF_PIECES)
         tiles = round(per * tiles)
-    else:
-        said += (1.0, lanes)
     if not served:
         return PathPlan(1, widest_tree, widest_tree, deepest_leaf, tiles, 0,
-                        0, 0, 0, *said)
+                        0, 0, 0, **said)
     fp, sel_lanes = _select_shape(lanes, n_features, pack)
     per_tree = (_window_bytes(fp, sel_lanes) // 2      # bf16: half of f32
                 + _window_bytes(8, lanes)
@@ -402,7 +449,7 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
                max(0, (_VMEM_BUDGET_BYTES - fixed) // per_tree))
     if most == 0:
         return PathPlan(1, widest_tree, widest_tree, deepest_leaf, tiles, 0,
-                        0, 0, TILE_ROWS, *said)
+                        0, 0, TILE_ROWS, **said)
     # Of the block sizes from `most` down to half of it, the one that
     # fills its last block best (filler trees cost what trees cost: 500
     # trees are 50 blocks of 10 where 46 of 11 would score 506), the
@@ -414,7 +461,7 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
                     blocks,
                     blocks * g * _tree_bytes(lanes, n_features, pack,
                                              exit_lanes),
-                    TILE_ROWS, *said)
+                    TILE_ROWS, **said)
 
 
 def predict_paths_fits(lanes: int, n_features: int,
@@ -470,7 +517,7 @@ def pack_select(sel, planes, n_features: int, xp=jnp) -> tuple:
 
 def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
                   n_trees: int, n_feat: int, missing_routes: bool,
-                  class_lanes: int = 0):
+                  class_lanes: int = 0, select_spans: tuple = ()):
     """One row tile against one block of `n_trees` trees: the block's share
     of every row's margin. x_ref [TILE_ROWS, F] uint8 or int32, as HBM
     holds the rows (in the last tile, whatever lies past row R); sel
@@ -485,7 +532,10 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
     sublanes and the leaf values' three pieces on the lanes, and the
     activity lives in VMEM over the block axis, a tree's sub-trees lying in
     one block or in several. A first entry of the whole table roots a tree,
-    so what the scratch held before is never read."""
+    so what the scratch held before is never read. `select_spans`: the
+    K-blocks each 128-lane tile of the select reads (`Chain`; the caller
+    hands the packed select none); (): every block, one matmul a block over
+    all the lanes."""
     out_ref = rest[class_lanes > 0]
     tile_rows = x_ref.shape[0]
     fp, lanes = sel_ref.shape[1], planes_ref.shape[2]
@@ -495,6 +545,12 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
     stride = _copy_stride(n_feat)
     ones_in_tile = bool(_mantissa_rows(n_feat))
     k_starts = range(0, n_feat, _LANES)
+    # The select's lane groups (first lane, stop, first K-block, stop): a
+    # run of lane tiles that read the same K-blocks is one matmul a block.
+    groups = [(0, wp, 0, len(k_starts))]
+    if len(set(select_spans)) > 1:
+        groups = [(j * _LANES, (j + 1) * _LANES, *span)
+                  for j, span in enumerate(select_spans)]
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -532,13 +588,17 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
             # bf16 operands (bins <= 255, their multiples of 256, the 0/1
             # one-hot and _MANTISSA are exact), f32 accumulator: the v5e's
             # VPU has no bf16 compare.
-            v = None
-            for k0, xk in zip(k_starts, xs):
-                part = jax.lax.dot_general(
-                    xk, sel_ref[g, k0:k0 + xk.shape[1], :],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [S, Wp]
-                v = part if v is None else v + part
+            vs = []
+            for l0, l1, first, stop in groups:
+                v = None
+                for k0, xk in zip(k_starts[first:stop], xs[first:stop]):
+                    part = jax.lax.dot_general(
+                        xk, sel_ref[g, k0:k0 + xk.shape[1], l0:l1],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)   # [S, Wp]
+                    v = part if v is None else v + part
+                vs.append(v)
+            v = vs[0] if len(vs) == 1 else jnp.concatenate(vs, axis=1)
             if not packed:
                 right = [v > rows[0:1, :]]
                 if missing_routes:  # not at the NaN bin where NaN goes left
@@ -677,9 +737,14 @@ def predict_paths_pallas(
                             memory_space=pltpu.VMEM)
 
     exit_lanes = chain.exit_lanes if chain else 0
+    spans = chain.select_spans if chain and sel_lanes == lanes else ()
+    # the select's weights the MXU is asked for: K rows x lanes
+    select = fp * sel_lanes if not spans else sum(
+        (min(stop * _LANES, fp) - start * _LANES) * _LANES
+        for start, stop in spans)
     cost = pl.CostEstimate(
         flops=2 * n_tiles * tile_rows * n_blocks * g * (
-            fp * sel_lanes + lanes * lanes + lanes * exit_lanes),
+            select + lanes * lanes + lanes * exit_lanes),
         bytes_accessed=n_tiles * (
             tile_rows * (F * row_dtype.itemsize
                          + 4 * (chain.class_lanes if chain else 1))
@@ -700,7 +765,8 @@ def predict_paths_pallas(
         acc = pl.pallas_call(
             functools.partial(_paths_kernel, n_trees=g, n_feat=F,
                               missing_routes=missing_routes,
-                              class_lanes=chain.class_lanes if chain else 0),
+                              class_lanes=chain.class_lanes if chain else 0,
+                              select_spans=spans),
             # The grid walks the UNPADDED rows: the last tile's blocks are
             # ragged, as in the heap kernel.
             grid=(n_tiles, n_blocks),

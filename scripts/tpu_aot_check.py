@@ -225,18 +225,21 @@ def _paths_case(rows, features, n_trees, n_leaves, missing=False):
 
 
 def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
-                 act_lanes=128, mean=True):
+                 act_lanes=128, mean=True, select_spans=()):
     """The SUB-TREE form of the path-matrix kernel (ops/predict_paths.py:
     the chain and the class dot) over the compiled tables' SHAPES
-    (models/tree.CompiledNodeList: 1.35 GB at the MNIST forest's), the rows
-    as api.predict does (uint8)."""
+    (models/tree.CompiledNodeList: 1.42 GB at the MNIST forest's), the rows
+    as api.predict does (uint8). `select_spans`: the K-blocks of the select
+    each lane tile reads, as the model's build found them (`CompiledNodeList.
+    select_spans`); (): every block."""
     def build():
         import jax.numpy as jnp
 
         from ddt_tpu.ops import predict_paths
 
         class_lanes = -(-3 * classes // 128) * 128
-        chain = predict_paths.Chain(n_trees, classes, class_lanes, act_lanes)
+        chain = predict_paths.Chain(n_trees, classes, class_lanes, act_lanes,
+                                    select_spans)
 
         def fn(sel, planes, paths, leaves, Xc):
             return predict_paths.predict_paths_pallas(
@@ -432,14 +435,21 @@ def kernel_cases() -> list:
                    _paths_case(BOSCH["chunk_rows"], BOSCH["features"], 20,
                                255, missing=True)),
         # The SUB-TREE form (the chain and the class dot): the MNIST
-        # forest's chunk (100 full-depth trees of up to 4,779 leaves: some
-        # 2,000 sub-trees of 256 lanes over 784 columns, 10 classes), and
+        # forest's chunk (100 full-depth trees of up to 4,779 leaves: 2,112
+        # sub-trees of 256 lanes over 784 columns, 10 classes, the first
+        # lane tile reading K-blocks 0-2 and the second 3-6 as the build
+        # finds for that forest: 7 select tiles a sub-tree), the same with
+        # a K-block both tiles read, and
         # the rule's edges: one sub-tree a tree with one column, with 85
         # (two class tiles, the most the rule takes) and with 128 (three:
         # refused), sub-trees of one tile with two activity tiles.
         KernelCase("forest/784f/100x4779x10", True,
                    _forest_case(FOREST["chunk_rows"], FOREST["features"],
-                                100, 1995, 10)),
+                                100, 2112, 10,
+                                select_spans=((0, 3), (3, 7)))),
+        KernelCase("forest/784f/12x20subtrees/shared-block", True,
+                   _forest_case(4_999, FOREST["features"], 12, 245, 10,
+                                select_spans=((0, 4), (3, 7)))),
         KernelCase("forest/28f/12x1subtree/c1", True,
                    _forest_case(4_999, hf, 12, 12, 1)),
         KernelCase("forest/28f/12x1subtree/c85", True,

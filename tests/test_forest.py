@@ -106,6 +106,152 @@ def test_a_cycle_and_a_stray_child_are_refused():
     ens.left_child[0, 5] = 99
     with pytest.raises(ValueError, match="outside its tree"):
         cut_subtrees(ens, 8)
+    # nodes 3 and 4 made each other's child: one parent each, out of reach
+    loop = forest(2, 1, 40, 1)
+    up, side = loop._parents()[:2]
+    for n in (3, 4):
+        hangs = (loop.left_child if side[0, n] < 0 else loop.right_child)
+        hangs[0, up[0, n]] = ~0 if n == 3 else ~1
+    loop.left_child[0, 3], loop.left_child[0, 4] = 4, 3
+    with pytest.raises(ValueError):
+        cut_subtrees(loop, 8)
+
+
+# the select's spans at 784 columns: 7 K-blocks, the last of 16 columns
+SPANS = {"dense": ((0, 7), (0, 7)), "shared": ((0, 4), (3, 7)),
+         "apart": ((0, 3), (3, 7)), "lopsided": ((0, 1), (1, 7)),
+         "wide": ((0, 5), (2, 7))}
+
+
+@pytest.mark.parametrize("name", list(SPANS))
+def test_the_cut_under_the_spans_bound(name):
+    """Still a partition into connected parts hung by their roots, parents
+    first; a part holds all three bounds and every lane's K-block lies in
+    its tile's span; the bound costs few parts."""
+    spans = SPANS[name]
+    ens = forest(61, 5, (1, 2500), 3, features=784)
+    cut, free = cut_subtrees(ens, 256, spans), cut_subtrees(ens, 256)
+    parent = ens._parents()[0]
+    block = ens.feature // 128
+    for t in range(ens.n_trees):
+        n_int = int(ens.n_leaves[t]) - 1
+        if n_int == 0:
+            assert cut.n_subtrees[t] == 1
+            continue
+        sub, lane, root = cut.subtree[t, :n_int], cut.lane[t, :n_int], \
+            cut.root[t, :n_int]
+        assert root[0] and sub[0] == 0
+        assert np.bincount(sub[root]).tolist() == [1] * cut.n_subtrees[t]
+        inner, hung = np.nonzero(~root)[0], np.nonzero(root)[0][1:]
+        assert (sub[parent[t, inner]] == sub[inner]).all()
+        assert (sub[parent[t, hung]] < sub[hung]).all()
+        # a lane holds one node, and a node's K-block is its tile's to read
+        assert len(set(zip(sub, lane))) == n_int and lane.max() < 256
+        first, stop = np.array(spans)[lane // 128].T
+        assert ((block[t, :n_int] >= first) & (block[t, :n_int] < stop)).all()
+        for k in range(cut.n_subtrees[t]):
+            mine = block[t, :n_int][sub == k]
+            assert len(mine) <= 255
+            assert (mine < spans[1][0]).sum() <= 128      # only tile 0 reads
+            assert (mine >= spans[0][1]).sum() <= 128     # only tile 1
+            # inside a tile: the pre-order, with no gap
+            for tile in (0, 1):
+                here = np.sort(lane[sub == k][lane[sub == k] // 128 == tile])
+                assert here.tolist() == list(range(128 * tile,
+                                                   128 * tile + len(here)))
+    if name == "dense":
+        for a, b in zip(cut, free):
+            np.testing.assert_array_equal(a, b)
+    assert cut.n_subtrees.sum() >= free.n_subtrees.sum()
+
+
+def test_the_spans_cost_the_mnist_forests_cut_few_parts():
+    """12 trees of the benchmark's forest (its drawing, its forest seed;
+    the rehearse forest is the first 4 of them, 80 parts where 75: two
+    parts are 2.7% there): the rule splits 784 columns at the middle and
+    its cut asks no more than 1.06 x the sub-trees of the unbounded one."""
+    import importlib.util
+    import pathlib
+
+    bench = pathlib.Path(__file__).parent.parent / "benchmark"
+    spec = importlib.util.spec_from_file_location(
+        "_datagen_forest", bench / "datagen_forest.py")
+    datagen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(datagen)
+    cell = json.loads((bench / "configs/mnist-rf-100t-full.json").read_text())
+    sh = cell["shapes"]
+    t = datagen.grown_forest(12, sh["features"], sh["n_bins"],
+                             sh["n_classes"], sh["forest_seed"],
+                             **cell["assumed"]["drawing"])
+    zeros = np.zeros(t["feature"].shape, np.float32)
+    ens = NodeListEnsemble(
+        feature=t["feature"], threshold_bin=t["threshold_bin"],
+        threshold_raw=zeros, left_child=t["left_child"],
+        right_child=t["right_child"], leaf_value=t["leaf_value"],
+        n_leaves=t["n_leaves"], split_gain=zeros, n_features=sh["features"],
+        learning_rate=1.0, base_score=0.0, loss="mean", n_classes=10,
+        n_bins=sh["n_bins"])
+    free = cut_subtrees(ens, 256)
+    spans, cut = tree.choose_select_spans(ens, 256, 128)
+    assert spans == ((0, 3), (3, 7))
+    assert free.n_subtrees.sum() < cut.n_subtrees.sum() \
+        <= 1.06 * free.n_subtrees.sum()
+    for a, b in zip(cut, cut_subtrees(ens, 256, spans)):
+        np.testing.assert_array_equal(a, b)
+    # 15 tiles a sub-tree where 22: fewer a tree, for all the parts more
+    assert cut.n_subtrees.sum() * tree.subtree_mxu_tiles(spans, 256, 256) \
+        < 0.75 * free.n_subtrees.sum() * tree.subtree_mxu_tiles(
+            SPANS["dense"], 256, 256)
+
+
+@pytest.mark.parametrize("features,spans,tiles", [
+    (784, ((0, 3), (3, 7)), 7),    # uniform columns: split at the middle
+    (200, ((0, 1), (1, 2)), 2),    # two K-blocks, 64% of nodes in the first
+    (129, ((0, 1), (0, 2)), 3),    # one column of 129 past the first block
+    (100, ((0, 1), (0, 1)), 2),    # one K-block: dense
+])
+def test_the_spans_are_read_from_the_model(monkeypatch, features, spans,
+                                           tiles):
+    monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
+    ens = forest(62, 4, (600, 1500), 3, features=features)
+    ce = ens.compile()
+    assert ce.select_spans == spans
+    chain = predict_paths.chain_of(4, 3, ce.leaves.shape[2], ce.select_spans)
+    plan = predict_paths.path_plan(ce.n_subtrees, 256, features, chain=chain)
+    assert plan.select_mxu_tiles == tiles == tree.subtree_mxu_tiles(
+        spans, 256, 0) - 4
+    assert plan.path_mxu_tiles_per_tree == round(
+        ce.n_subtrees / 4 * tree.subtree_mxu_tiles(spans, 256, 256))
+    # every non-zero of the select lies in a K-block its lane tile reads
+    _, rows, lanes = np.nonzero(ce.sel.astype(np.float32))
+    first, stop = np.array(spans)[lanes // 128].T
+    assert ((rows // 128 >= first) & (rows // 128 < stop)).all()
+    assert len(rows) == ens.n_splits
+
+
+def test_columns_that_crowd_one_block_and_models_with_nothing_to_split(
+        monkeypatch):
+    """Every node on columns 0..127 of 784: the first lane tile reads that
+    block alone and the cut is the unbounded one (no node that only one
+    tile may hold). A model of one-leaf trees keeps the dense spans."""
+    monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
+    ens = forest(63, 3, (600, 900), 2, features=784)
+    ens.feature[ens.live_nodes] %= 128
+    spans, cut = tree.choose_select_spans(ens, 256, 128)
+    assert spans == ens.compile().select_spans == ((0, 1), (0, 7))
+    for a, b in zip(cut, cut_subtrees(ens, 256)):
+        np.testing.assert_array_equal(a, b)
+    lone = forest(64, 3, 1, 2, features=784)
+    assert lone.compile().select_spans == ((0, 7), (0, 7))
+    # one tile a sub-tree: nothing to tell apart
+    monkeypatch.setattr(tree, "SUBTREE_LANES", 128)
+    assert forest(63, 3, (600, 900), 2, features=784).compile(
+        ).select_spans == ((0, 7),)
+    with pytest.raises(ValueError, match="two lane tiles"):
+        cut_subtrees(ens, 128, ((0, 3), (3, 7)))
+    for gap in (((0, 3), (4, 7)), ((1, 3), (3, 7)), ((0, 3), (3, 6))):
+        with pytest.raises(ValueError, match="to no lane tile"):
+            cut_subtrees(ens, 256, gap)
 
 
 def test_three_bfloat16_pieces_hold_a_float32_exactly():
@@ -208,6 +354,78 @@ def test_blocks_that_cut_through_a_tree_and_ragged_row_tiles(monkeypatch):
     np.testing.assert_array_equal(scored(ens, Xb, "onehot"), want)
 
 
+def _one_block(ens):
+    """Tree 0's nodes all on columns of K-block 5: sub-trees whose nodes
+    only the second lane tile may hold."""
+    live = ens.live_nodes[0]
+    ens.feature[0, live] = 640 + ens.feature[0, live] % 128
+    return ens
+
+
+@pytest.mark.parametrize("features,missing,scalar,shape,spans", [
+    (784, False, False, None, ((0, 3), (3, 7))),       # the spans engage
+    (784, True, False, None, ((0, 3), (3, 7))),        # ... under NaN routes
+    (784, False, False, _one_block, ((0, 3), (3, 7))),
+    (784, True, True, None, ((0, 3), (3, 7))),  # past PATH_UNCUT_LANES
+    (200, True, False, None, ((0, 1), (0, 2))),        # two K-blocks
+    (100, False, False, None, ((0, 1), (0, 1))),       # dense
+], ids=["784f", "784f-nan", "784f-one-block", "784f-scalar-nan", "200f-nan",
+        "100f"])
+def test_kernel_twin_and_walk_are_bit_equal_under_the_spans(
+        monkeypatch, features, missing, scalar, shape, spans):
+    """Sub-trees of two lane tiles, their lanes ordered by K-block: the
+    skipped tiles are zeros and every partial product an integer, so the
+    reached leaf is the uncut walk's; dyadic leaf values over 8 trees make
+    every sum exact."""
+    monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
+    rng = np.random.default_rng(91)
+    meta = dict(learning_rate=0.5, base_score=0.25, loss="logloss") \
+        if scalar else dict(leaf_columns=3)
+    ens = random_node_list(rng, 8, (520, 900), features, n_bins=BINS,
+                           dyadic=True, missing=missing, **meta)
+    ens = shape(ens) if shape else ens
+    ce = ens.compile()
+    assert ce.chained and ce.select_spans == spans and ce.lanes == 256
+    Xb = rows_of(92, 600, features)
+    Xb[::7, ::3] = BINS - 1        # rows that sit in the NaN bin
+    want = ens.predict_raw(Xb, binned=True) if scalar else \
+        predict_proba_node_list(ens, Xb)
+    for impl in ("pallas", "onehot"):
+        np.testing.assert_array_equal(scored(ens, Xb, impl),
+                                      want.astype(np.float32))
+
+
+@pytest.mark.parametrize("lanes,features,columns,missing,parts,digest", [
+    (128, 100, 3, False, 8, "0b4a35b5c8f0f745"),
+    (128, 12, 10, True, 19, "5907714f849a871c"),
+    (128, 784, 3, False, 8, "872282a6b2b18dbf"),   # one tile: no span
+    (256, 100, 3, False, 6, "c37e078ebc4f0667"),   # one K-block
+    (256, 12, 10, True, 11, "df962ff860f2425f"),
+    (256, 28, 0, False, 12, "322d840d74a261cc"),   # scalar, 700 leaves
+])
+def test_dense_spans_build_the_parents_tables_bit_for_bit(
+        monkeypatch, lanes, features, columns, missing, parts, digest):
+    """SHA-1s of the four tables as the commit before the spans built them
+    (da8e8d4, the same seeded forests): a model whose spans are dense is
+    numbered in pre-order and cut by the one bound, as ever."""
+    import hashlib
+
+    monkeypatch.setattr(tree, "SUBTREE_LANES", lanes)
+    rng = np.random.default_rng(81)
+    ens = random_node_list(rng, 5, (1, 900), features, n_bins=BINS,
+                           missing=missing, leaf_columns=columns) \
+        if columns else random_node_list(
+            rng, 3, 700, features, n_bins=BINS, learning_rate=0.1,
+            base_score=0.5, loss="logloss")
+    ce = ens.compile()
+    assert ce.select_spans == tree.dense_spans(features, lanes)
+    assert ce.n_subtrees == parts
+    h = hashlib.sha1()
+    for a in ce.arrays():
+        h.update(np.ascontiguousarray(a).view(np.uint8).tobytes())
+    assert h.hexdigest()[:16] == digest
+
+
 def test_a_tall_scalar_tree_is_cut_and_keeps_its_margin(monkeypatch):
     """One output column and no mean: a tree of more lanes than one path
     matrix should hold takes the sub-tree form and answers the margin [R]
@@ -233,8 +451,10 @@ def test_the_plan_says_what_serves_and_the_rule_refuses_what_cannot_build():
     assert chain == predict_paths.Chain(100, 10, 128, 128)
     plan = predict_paths.path_plan(2015, 256, 784, 26, chain=chain,
                                    widest_tree=4864)
-    # the MNIST forest's shape: 7 K-blocks x 2 + 4 + 2 + 2 tiles a sub-tree
+    # the MNIST forest's shape under dense spans: 7 K-blocks x 2 + 4 + 2 + 2
+    # tiles a sub-tree
     assert predict_paths.path_mxu_tiles_per_tree(256, 784, 1, 256) == 22
+    assert plan.select_mxu_tiles == 14
     assert (plan.path_mxu_tiles_per_tree, plan.subtrees_per_tree,
             plan.subtree_lanes, plan.leaf_columns, plan.class_dot_passes,
             plan.chain_mxu_tiles_per_tree, plan.select_k_blocks,
@@ -257,6 +477,40 @@ def test_the_plan_says_what_serves_and_the_rule_refuses_what_cannot_build():
                 1.0, 256, 1, 0, 0)
 
 
+@pytest.mark.parametrize("spans,entries,select,tiles,per_tree", [
+    (((0, 3), (3, 7)), 2112, 7, 15, 317),      # what the forest's build finds
+    (((0, 4), (3, 7)), 2043, 8, 16, 327),
+    (((0, 4), (2, 7)), 2016, 9, 17, 343),
+    ((), 2015, 14, 22, 443),                   # dense, as before
+])
+def test_the_plan_counts_the_select_by_its_spans(spans, entries, select,
+                                                 tiles, per_tree):
+    chain = predict_paths.chain_of(100, 10, 256, spans)
+    assert predict_paths.path_mxu_tiles_per_tree(
+        256, 784, 1, 256, chain.select_spans) == tiles
+    for served in (True, False):
+        plan = predict_paths.path_plan(entries, 256, 784, 26, chain=chain,
+                                       served=served, widest_tree=4864)
+        assert (plan.select_mxu_tiles, plan.path_mxu_tiles_per_tree,
+                plan.select_k_blocks) == (select, per_tree, 7)
+    assert 15 <= tiles <= 17 and 7 <= select <= 9 or not spans
+    # the tables stay whole in HBM: the bytes of an entry do not move
+    assert plan.table_bytes == 0 and predict_paths.path_plan(
+        entries, 256, 784, chain=chain).table_bytes % (
+            784 * 256 * 2 + 8 * 256 * 4 + 2 * 256 * 256 * 2) == 0
+    # what the uncut models said, they say: Higgs's packed select (one
+    # K-block, two nodes a lane), Bosch's 8 K-blocks x 2 lane tiles
+    for features, said in ((28, (1, 5)), (968, (16, 20))):
+        flat = predict_paths.path_plan(500, 256, features)
+        assert (flat.select_mxu_tiles, flat.path_mxu_tiles_per_tree) == said
+        assert predict_paths.path_mxu_tiles_per_tree(256, features) == said[1]
+    # a chained model of one K-block whose select packs two nodes a lane
+    # counts the packed select, whatever its (dense) spans say
+    packed = predict_paths.path_plan(40, 256, 28, chain=predict_paths.chain_of(
+        8, 3, 256, ((0, 1), (0, 1))))
+    assert (packed.select_nodes_per_lane, packed.select_mxu_tiles) == (2, 1)
+
+
 def test_the_spans_say_the_subtree_form(monkeypatch):
     from ddt_tpu.backends import get_backend
     from ddt_tpu.telemetry import annotations as an
@@ -269,7 +523,9 @@ def test_the_spans_say_the_subtree_form(monkeypatch):
     root = an.root_spans("predict")[-1]
     built = [s for s in root["spans"]
              if s["name"] == "ddt:predict:ensemble"][0]["counts"]
-    assert list(built)[-5:] == list(predict_paths.CHAIN_COUNTS)
+    assert list(built)[-len(predict_paths.CHAIN_COUNTS):] == list(
+        predict_paths.CHAIN_COUNTS)
+    assert built["select_mxu_tiles"] == 1        # one tile, one K-block
     assert built["subtrees_per_tree"] > 1 and built["subtree_lanes"] == 128
     assert built["leaf_columns"] == root["counts"]["classes"] == 10
     assert built["class_dot_passes"] == 3
