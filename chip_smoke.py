@@ -478,7 +478,9 @@ def score_forest(overrides: dict, rows: int) -> None:
     cut into sub-trees of 256 lanes and chained, the class dot's three
     bfloat16 pieces; `select_mxu_tiles` under 14: a sub-tree's lanes
     ordered by their column's K-block, each lane tile of the select asking
-    for its own blocks alone), holds the scores [rows, 10] to the walk of
+    for its own blocks alone; `exit_mxu_tiles` 2: the exits' table ONE lane
+    tile, some ten lanes of the chain's links behind 30 of class pieces),
+    holds the scores [rows, 10] to the walk of
     the uncut trees (reference/numpy_predict.predict_proba_node_list,
     float64), and holds the compiled kernel and its jax.numpy twin bit-equal
     on dyadic leaf vectors over 8 trees (every sum and the mean exact), at
@@ -511,6 +513,7 @@ def score_forest(overrides: dict, rows: int) -> None:
     say(f"forest predict: ddt:predict:ensemble {built}; root "
         f"subtrees_per_tree={root['counts']['subtrees_per_tree']} "
         f"select_mxu_tiles={root['counts']['select_mxu_tiles']} "
+        f"exit_mxu_tiles={root['counts']['exit_mxu_tiles']} "
         f"tables_streamed_bytes={root['counts']['tables_streamed_bytes']}")
     assert built["node_list"] == root["counts"]["node_list"] == 1, built
     assert built["subtrees_per_tree"] > 1, "no tree was cut"
@@ -521,6 +524,9 @@ def score_forest(overrides: dict, rows: int) -> None:
     assert built["trees_per_step"] > 0, "the path kernel did not serve"
     # 7 K-blocks x 2 lane tiles dense; uniform columns split at the middle
     assert 7 <= built["select_mxu_tiles"] <= 9, built
+    # 3 x 10 lanes of pieces and a chain of a dozen sub-trees: one tile
+    assert (built["exit_mxu_tiles"], built["chain_mxu_tiles_per_tree"]) == (
+        2, 0), built
     assert_compiled_kernel(cfg, ens, rows, "forest")
     n = min(2_000, rows)
     want = numpy_predict.predict_proba_node_list(ens, Xb[:n])

@@ -1342,14 +1342,41 @@ def subtree_mxu_tiles(spans: tuple, lanes: int, exit_lanes: int) -> int:
             + w * (exit_lanes // PATH_LANES))
 
 
-def _act_lanes(n_subtrees: np.ndarray) -> int:
-    """The chain's activity lanes: whole 128s, more than a tree's
+def exit_table_lanes(leaf_columns: int, n_subtrees: np.ndarray) -> tuple:
+    """(lanes of the exits' table, the lane of a link to the NEXT sub-tree)
+    of a model of `leaf_columns` values a leaf whose trees are cut into
+    `n_subtrees` [T] parts: THE RULE of the table's width, the one place
+    that decides it (`CompiledNodeList` builds by it, the kernel and its
+    twin read the layout back from the table's width:
+    ops/predict_paths.chain_of).
+
+    ONE lane tile where the three bfloat16 pieces of a leaf's C values
+    (lanes 0 .. 3C - 1) and the chain fit 128 lanes together. The chain of a
+    tree of n sub-trees takes n - 1 lanes behind the pieces: a link from
+    sub-tree k to sub-tree j lies in lane 3C + (j - k - 1), j - k - 1 in
+    0 .. n - 2, and the kernel shifts the lanes down by one a sub-tree, all
+    128 of them, so what the class lanes held comes round to lane 127 one
+    sub-tree after it reached lane 0 and is back at lane 3C, the one lane
+    the kernel reads, 129 - 3C sub-trees after it was written, at the
+    soonest: past the tree's last sub-tree exactly where the links fit,
+
+        3C + n - 1 <= 128        (10 classes: trees of up to 99 sub-trees)
+
+    and the tree's root clears every lane. Otherwise the class lanes and
+    the activity lanes are lane tiles of their own (whole 128s each, the
+    activity's more than a tree's sub-trees), the first link behind the
+    class tiles: 85 classes, 128 classes, a 10-class tree of 100
     sub-trees."""
-    return -(-(int(n_subtrees.max()) + 1) // PATH_LANES) * PATH_LANES
+    pieces = LEAF_PIECES * leaf_columns
+    most = int(n_subtrees.max())
+    if pieces + most - 1 <= PATH_LANES:
+        return PATH_LANES, pieces
+    class_lanes = -(-pieces // PATH_LANES) * PATH_LANES
+    act_lanes = -(-(most + 1) // PATH_LANES) * PATH_LANES
+    return class_lanes + act_lanes, class_lanes
 
 
-def choose_select_spans(ens: NodeListEnsemble, lanes: int,
-                        class_lanes: int) -> tuple:
+def choose_select_spans(ens: NodeListEnsemble, lanes: int) -> tuple:
     """(`CompiledNodeList.select_spans`, the model's cut under them): which
     K-blocks of the feature select each 128-lane tile of a sub-tree reads,
     from what the model shows. A node's one K row lies in ONE K-block, and
@@ -1362,7 +1389,8 @@ def choose_select_spans(ens: NodeListEnsemble, lanes: int,
     share of the model's internal nodes passes one half: that block read by
     both tiles, by the first alone, by the second alone; and the dense
     spans. Taken is the one whose cut asks the fewest MXU weight tiles a
-    tree (`subtree_mxu_tiles` x the sub-trees), the dense one where none
+    tree (`subtree_mxu_tiles` x the sub-trees, the exits' table as wide as
+    that cut's model gets it, `exit_table_lanes`), the dense one where none
     asks fewer: one K-block (F <= 128), sub-trees of another width than two
     tiles, a model of no internal node."""
     node_parent = ens._parents()[0]
@@ -1381,7 +1409,7 @@ def choose_select_spans(ens: NodeListEnsemble, lanes: int,
         root, only = _subtree_roots(ens, levels, lanes, spans)
         per_tree = np.maximum(root.sum(axis=1), 1)
         tiles = int(per_tree.sum()) * subtree_mxu_tiles(
-            spans, lanes, class_lanes + _act_lanes(per_tree))
+            spans, lanes, exit_table_lanes(ens.leaf_columns, per_tree)[0])
         if best is None or tiles < best[0]:
             best = (tiles, spans, root, only)
     _, spans, root, only = best
@@ -1447,17 +1475,25 @@ class CompiledNodeList:
 
         planes row 4             1 in the entry that roots a tree, else 0
                                  (row 2 is not read)
-        leaves [S, W, CL + A] bf16
-                                 row e, an exit. A real leaf: its float32
-                                 vector as three bfloat16 pieces
+        leaves [S, W, E] bf16    row e, an exit; E by `exit_table_lanes`,
+                                 THE RULE of its width. A real leaf: its
+                                 float32 vector as three bfloat16 pieces
                                  (`split_bfloat16`), piece p's column c in
-                                 lane p C + c of the CL class lanes (whole
-                                 128s). A link to the tree's sub-tree j
-                                 from its sub-tree k: 1 in lane
-                                 CL + (j - k - 1) of the A activity lanes
-                                 (whole 128s, more than a tree's
-                                 sub-trees), which the chain shifts down a
-                                 lane a sub-tree.
+                                 lane p C + c. A link to the tree's
+                                 sub-tree j from its sub-tree k: 1 in lane
+                                 link0 + (j - k - 1), which the chain
+                                 shifts down a lane a sub-tree.
+                                 E = 128, ONE lane tile (PR 49), where the
+                                 pieces and a tree's chain fit it together,
+                                 3 C + (most sub-trees a tree) - 1 <= 128:
+                                 link0 = 3 C, the links right behind the
+                                 pieces (the MNIST forest: 30 lanes of
+                                 pieces, 24 of links at most). Else
+                                 E = CL + A, the pieces in CL class lanes
+                                 and the links in A activity lanes behind
+                                 them (whole 128s each, A more than a
+                                 tree's sub-trees), link0 = CL: 85 or 128
+                                 classes, a 10-class tree of 100 sub-trees.
 
     `mean`: the score is the sum over the trees divided by their number
     (an averaged forest), else base + learning_rate x sum.
@@ -1549,12 +1585,11 @@ class CompiledNodeList:
         bf16 = ml_dtypes.bfloat16
         T, N = ens.feature.shape
         W, C = SUBTREE_LANES, ens.leaf_columns    # the module's, as it is
-        CL = -(-LEAF_PIECES * C // PATH_LANES) * PATH_LANES
-        spans, cut = choose_select_spans(ens, W, CL)
+        spans, cut = choose_select_spans(ens, W)
         node_parent, node_side, _, _ = ens._parents()
         first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
         S = int(first[-1])
-        A = _act_lanes(cut.n_subtrees)
+        exit_lanes, link0 = exit_table_lanes(C, cut.n_subtrees)
         t_idx, n_idx = np.nonzero(ens.live_nodes)
         at = first[t_idx] + cut.subtree[t_idx, n_idx]      # the entry
         ln = cut.lane[t_idx, n_idx]
@@ -1585,7 +1620,7 @@ class CompiledNodeList:
         e_lane = np.arange(len(e_at)) - starts[e_at]
         e_child = child[e_node, e_side]
         e_tree = t_idx[e_node]
-        leaves = np.zeros((S, W, CL + A), bf16)
+        leaves = np.zeros((S, W, exit_lanes), bf16)
         values = ens.leaf_value.reshape(T, -1, C)
 
         def put_leaves(entry, exit_lane, vectors):
@@ -1598,7 +1633,7 @@ class CompiledNodeList:
         to = first[e_tree[~real]] + cut.subtree[e_tree[~real],
                                                 e_child[~real]]
         leaves[e_at[~real], e_lane[~real],
-               CL + (to - e_at[~real] - 1)] = 1.0
+               link0 + (to - e_at[~real] - 1)] = 1.0
         # A tree of one leaf: an entry of no node whose exit 0 has a path
         # of no node (every row reaches it).
         lone = np.nonzero(ens.n_leaves == 1)[0]

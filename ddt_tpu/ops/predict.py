@@ -77,6 +77,10 @@ the sub-tree i further on, shifted down a lane a sub-tree and reset to lane
 0 at a tree's first. V_k holds a float32 value as its three bfloat16 pieces
 in lanes of their own (models/tree.split_bfloat16: exact), e and a are 0/1,
 one exit a row: the class lanes' sums are float32 sums of exact products.
+Where the 3 C lanes of pieces and a tree's chain fit ONE tile of 128 lanes
+(models/tree.exit_table_lanes: THE RULE) the table is that one tile, L_k
+right behind V_k's pieces, the sums and the activity are 128 lanes each
+that take the same a_k (e_k @ table), and "lane 0" above is lane 3 C.
 The forest's answer is the sum over its trees divided by their number.
 
 A FIFTH entry serves the third layout, the OBLIVIOUS ensemble
@@ -636,7 +640,7 @@ def _predict_chain(sel, planes, paths, leaves, Xc, *, chain, learning_rate,
 
     S, Fp, W = sel.shape
     R, F = Xc.shape
-    cl, al = chain.class_lanes, chain.act_lanes
+    cl, al, hand = chain.class_lanes, chain.act_lanes, chain.at_hand
     row_chunk = min(_PATHS_ROW_CHUNK, R)
     n_rc = -(-R // row_chunk)
     with traced_scope("predict:widen"):
@@ -644,7 +648,7 @@ def _predict_chain(sel, planes, paths, leaves, Xc, *, chain, learning_rate,
                      ((0, n_rc * row_chunk - R), (0, Fp - F))
                      ).reshape(n_rc, row_chunk, Fp)
     with traced_scope("predict:tables"):
-        first = (jnp.arange(al) == 0).astype(jnp.float32)[None, :]
+        first = (jnp.arange(al) == hand).astype(jnp.float32)[None, :]
 
     def row_body(_, xrc):
         def subtree_body(carry, args):
@@ -664,9 +668,14 @@ def _predict_chain(sel, planes, paths, leaves, Xc, *, chain, learning_rate,
                             preferred_element_type=jnp.float32)
             with traced_scope("predict:accumulate"):
                 act = jnp.where(pl_[4, 0] > 0.0, first, act)
-                a = act[:, 0:1]
-                acc = acc + a * y[:, :cl]
-                act = jnp.roll(act, -1, axis=1) + a * y[:, cl:]
+                a = act[:, hand:hand + 1]
+                if chain.shared:    # ONE tile: the links behind the pieces
+                    ay = a * y
+                    acc = acc + ay
+                    act = jnp.roll(act, -1, axis=1) + ay
+                else:
+                    acc = acc + a * y[:, :cl]
+                    act = jnp.roll(act, -1, axis=1) + a * y[:, cl:]
             return (acc, act), None
 
         (acc, _), _ = jax.lax.scan(
@@ -701,7 +710,7 @@ def predict_raw_effective_paths(
     base: float,
     use_pallas: bool | None = None,
     missing_routes: bool = False,
-    leaves: jax.Array | None = None,   # bf16 [S, W, CL + A]: the exits
+    leaves: jax.Array | None = None,   # bf16 [S, W, E]: the exits
     n_trees: int = 0,
     leaf_columns: int = 1,
     mean: bool = False,

@@ -18,12 +18,15 @@ of 128), not by its depth: `path_mxu_tiles_per_tree` MXU weight tiles a
 tree, ceil(F/128) x ceil(W / P / 128) for v (P nodes a result lane, next
 paragraph) and (W/128)^2 for m: 5 at 255 leaves and 28 columns (6 at P =
 1), 18 at 512 lanes, 2 at 128, 20 at 968 columns; in the sub-tree form (THE
-CHAIN, below) W/128 x (CL + A)/128 more a sub-tree for the exits' table, and
-the select's tiles by the SPANS of its lane tiles (next but one paragraph):
-15 a sub-tree of 256 lanes at 784 columns and 10 classes (3 + 4 of the
-select, 4 + 2 + 2), 317 a tree of the MNIST forest's 21.12 sub-trees; with
-every lane tile reading every K-block (dense spans) 22 (7 x 2 + 4 + 2 + 2),
-443 a tree of 20.15.
+CHAIN, below) W/128 x E/128 more a sub-tree for the exits' table of E lanes
+(`exit_mxu_tiles`), and the select's tiles by the SPANS of its lane tiles
+(next but one paragraph): 13 a sub-tree of 256 lanes at 784 columns and 10
+classes (3 + 4 of the select, 4 of the resolve, 2 of the exits' ONE lane
+tile), 275 a tree of the MNIST forest's 21.12 sub-trees; 15 and 317 where
+the class pieces and the chain's links have a lane tile each (4 + 2 + 2:
+until PR 49 every model, since then those the one tile does not fit); with
+every lane tile reading every K-block (dense spans) 7 x 2 of the select, 22
+a sub-tree and 443 a tree of 20.15 before PR 48.
 
 TWO NODES A RESULT LANE (`select_nodes_per_lane`, P: from F and W, nothing
 else; the heap kernel's `nodes_per_tile` in this form). The select contracts
@@ -83,31 +86,66 @@ leaf, the mean over the trees), is cut on the host into connected sub-trees
 of at most 256 lanes, and a table entry is a SUB-TREE: the grid's block
 axis walks blocks of G sub-trees, a tree's in a row, parents first. A
 sub-tree's v, s and m are the tree's above; its "leaves" are its EXITS, and
-what an exit means is a fourth table, `leaves` [W, CL + A] bf16, against
-which the exit one-hot e = (m == len) is multiplied ONCE:
+what an exit means is a fourth table, `leaves` [W, E] bf16, against which
+the exit one-hot e = (m == len) is multiplied ONCE, y = e @ leaves [rows, E].
 
-    y = e @ [V | L]                  [rows, CL + A]
-    a = act[:, 0]                    this sub-tree's activity, 0 or 1
+A row of the table: a real leaf's float32 vector as THREE bfloat16 pieces in
+lanes of their own (`class_dot_passes` 3; piece p's column c in lane p C +
+c: exact, and one MXU tile serves 3 C <= 128), zeros for a link; a link from
+the tree's sub-tree k to its sub-tree j a 1 in lane link0 + (j - k - 1),
+zeros for a leaf. The activity `act` holds in lane h the sub-tree at hand
+and in lane h + i the one i further on in the tree, every step shifts its
+lanes down by one (XLU), and an entry that roots a tree (planes' row 4) sets
+`act` to lane h alone. A row follows ONE chain of sub-trees, every other
+sub-tree of the tree has a = 0, and the one real leaf it ends in adds its
+pieces.
+
+ONE LANE TILE OF EXITS (`Chain.shared`, `exit_mxu_tiles` W/128; PR 49), E =
+128: where the 3 C lanes of pieces and the tree's chain fit a tile together,
+
+    3 C + (most sub-trees a tree) - 1 <= 128
+
+(models/tree.exit_table_lanes, THE RULE, stated there and nowhere else: the
+builder lays the table out by it and `chain_of` reads the layout back from
+the table's width), the links lie right behind the pieces, link0 = h = 3 C,
+and the class sums and the activity are two [rows, 128] arrays that take the
+SAME product:
+
+    a   = act[:, 3C]                 this sub-tree's activity, 0 or 1
+    ay  = a * y                      [rows, 128], once
+    acc += ay                        lanes 0 .. 3C-1: the class dot
+    act  = roll(act, -1) + ay        lanes 3C ..: the chain
+
+No mask and no second operand: `acc`'s lanes from 3 C up hold sums of 0s and
+1s that `fold_leaf_pieces` never reads, and `act`'s lanes below 3 C hold
+class values that the shift moves DOWN, away from lane 3 C, round lane 0 to
+lane 127 and down again a lane a sub-tree: back at lane 3 C, the only lane
+the kernel reads, 129 - 3 C sub-trees after they were written at the
+soonest, which is past the tree's last sub-tree exactly where its last link
+(lane 3 C + n - 2) fits the tile, and the next tree's root clears every
+lane. The MNIST forest: 30 lanes of pieces, 24 of links at most, two weight
+tiles a sub-tree where four.
+
+[V | L] (every model until PR 49; since then 3 C + n - 1 > 128: 85 classes,
+128 classes, a 10-class tree of 100 sub-trees), E = CL + A: the pieces in CL
+class lanes (whole 128s), the links in A activity lanes behind them (whole
+128s, more than a tree's sub-trees, so no lane wraps into use), link0 = CL,
+h = 0:
+
+    a = act[:, 0]
     acc += a * y[:, :CL]             the class dot: CL class lanes
     act  = roll(act, -1) + a * y[:, CL:]     the chain: A activity lanes
 
-V holds a real leaf's float32 vector as THREE bfloat16 pieces in lanes of
-their own (`class_dot_passes` 3; piece p's column c in lane p C + c: exact,
-and one MXU tile serves 3 C <= 128), zero rows for links; L holds a 1 in
-lane j - k - 1 of an exit of sub-tree k that links to the tree's sub-tree
-j. So lane 0 of `act` is always the sub-tree at hand, lane i the one i
-further on in the tree, every step shifts the lanes down by one (XLU), and
-an entry that roots a tree (planes' row 4) sets `act` to lane 0 alone. A row
-follows ONE chain of sub-trees, every other sub-tree of the tree has a = 0,
-and the one real leaf it ends in adds its pieces. `act` lives in a VMEM
-scratch [TILE_ROWS, A] over the block axis (a tree's sub-trees may lie in
-several blocks; the table's first entry roots a tree, so the scratch is
-never read before it is set), `acc` in the output block [TILE_ROWS, CL],
-the rows on the sublanes; the three pieces are added and divided by the tree
-count in XLA (`fold_leaf_pieces`: `predict:accumulate`). A > a tree's
-sub-trees, so no lane wraps into use. Nothing of it is traced for a model
-whose trees one path matrix holds with one output column: that program is
-instruction for instruction what it was.
+that program and its tables are what they were, instruction for instruction.
+
+Either way `act` lives in a VMEM scratch [TILE_ROWS, A] over the block axis
+(a tree's sub-trees may lie in several blocks; the table's first entry roots
+a tree, so the scratch is never read before it is set), `acc` in the output
+block [TILE_ROWS, CL], the rows on the sublanes; the three pieces are added
+and divided by the tree count in XLA (`fold_leaf_pieces`: `predict:
+accumulate`). Nothing of it is traced for a model whose trees one path
+matrix holds with one output column: that program is instruction for
+instruction what it was.
 
 Learned NaN directions (`missing_routes`; models/tree.CompiledNodeList):
 the NaN bin is the top bin, above every threshold, so `v > thr` alone is
@@ -292,15 +330,28 @@ class Chain(typing.NamedTuple):
 
     n_trees: int               # T: what a mean divides by
     leaf_columns: int          # C
-    class_lanes: int           # CL: three bfloat16 pieces of C columns
-    act_lanes: int             # A: the chain's activity lanes
+    class_lanes: int           # CL: the lanes of the class sums (whole
+    #   128s), three bfloat16 pieces of C columns in the first 3 C of them
+    act_lanes: int             # A: the activity's lanes (whole 128s)
     select_spans: tuple = ()   # (first, stop) K-blocks of the select each
     #   128-lane tile of a sub-tree reads (models/tree.CompiledNodeList);
     #   (): every tile reads every block
+    shared: bool = False       # the exits' table is ONE lane tile, the
+    #   links behind the pieces in the class sums' own 128 lanes
+    #   (models/tree.exit_table_lanes: THE RULE); else [V | L], CL + A wide
 
     @property
     def exit_lanes(self) -> int:
-        return self.class_lanes + self.act_lanes
+        """Lanes of the exits' table."""
+        return self.class_lanes + (0 if self.shared else self.act_lanes)
+
+    @property
+    def at_hand(self) -> int:
+        """The lane of the activity that is the sub-tree at hand (a link to
+        the next sub-tree lies one lane above it in the exits' table's
+        chain lanes): right behind the pieces, or the activity tiles'
+        first."""
+        return _LEAF_PIECES * self.leaf_columns if self.shared else 0
 
 
 # bfloat16 pieces a float32 leaf value is held in (models/tree.
@@ -311,9 +362,18 @@ _LEAF_PIECES = 3
 def chain_of(n_trees: int, leaf_columns: int, exit_lanes: int,
              select_spans: tuple = ()) -> Chain:
     """The Chain of a compiled model whose exits' table is `exit_lanes`
-    wide and whose lanes are ordered under `select_spans`."""
+    wide and whose lanes are ordered under `select_spans`. The table's own
+    width says which layout models/tree.exit_table_lanes (THE RULE) gave
+    it: no wider than the class lanes, so the links share their tile (one
+    tile: 3 C < 128); else the activity lanes are what lies behind them."""
     cl = _lane_pad(_LEAF_PIECES * leaf_columns)
-    return Chain(n_trees, leaf_columns, cl, exit_lanes - cl, select_spans)
+    shared = exit_lanes == cl == _LANES
+    if not shared and exit_lanes <= cl:
+        raise ValueError(
+            f"an exits' table of {exit_lanes} lanes holds no chain beside "
+            f"the {cl} class lanes of {leaf_columns} leaf columns")
+    return Chain(n_trees, leaf_columns, cl, _LANES if shared
+                 else exit_lanes - cl, select_spans, shared)
 
 
 class PathPlan(typing.NamedTuple):
@@ -338,12 +398,17 @@ class PathPlan(typing.NamedTuple):
     subtree_lanes: int = 0          # W of an entry
     leaf_columns: int = 1           # C, the output columns a leaf holds
     chain_mxu_tiles_per_tree: int = 0   # of path_mxu_tiles_per_tree: the
-    #   exits against the activity lanes
+    #   exits against the activity lanes' OWN tiles (0 where the links
+    #   share the class lanes' tile: `exit_mxu_tiles` counts that one)
     class_dot_passes: int = 0       # bfloat16 pieces of a float32 leaf value
     #   in the class dot's tile (0: leaf values added on the VPU)
     select_mxu_tiles: int = 0       # of a sub-tree's tiles: the feature
     #   select's (14 at 784 columns and 256 lanes; 7 where each lane tile
     #   reads its own K-blocks alone, `Chain.select_spans`)
+    exit_mxu_tiles: int = 0         # of a sub-tree's tiles: the exits'
+    #   table's (W/128 x its lane tiles: 2 at 256 lanes where the class
+    #   pieces and the chain share ONE tile, `Chain.shared`; 4 or more
+    #   where each has tiles of its own; 0 without a chain)
 
     @property
     def blocks(self) -> int:
@@ -372,7 +437,7 @@ class PathPlan(typing.NamedTuple):
 # but `table_bytes` in `phases_ms`, as it does for the heap kernel's.
 CHAIN_COUNTS = ("subtrees_per_tree", "subtree_lanes", "leaf_columns",
                 "chain_mxu_tiles_per_tree", "class_dot_passes",
-                "select_mxu_tiles")
+                "select_mxu_tiles", "exit_mxu_tiles")
 SPAN_COUNTS = ("node_list", "nodes_per_tree", "leaves_per_tree",
                "deepest_leaf", "path_mxu_tiles_per_tree", "trees_per_step",
                "table_blocks", "table_bytes", "select_k_blocks",
@@ -421,11 +486,13 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     widest_tree = widest_tree or lanes
     if chain:
         per = n_trees / chain.n_trees
+        own = exit_lanes - chain.class_lanes    # the links' own lane tiles
         said.update(subtrees_per_tree=round(per, 2),
                     leaf_columns=chain.leaf_columns,
                     chain_mxu_tiles_per_tree=round(
-                        per * (lanes // _LANES) * (chain.act_lanes // _LANES)),
-                    class_dot_passes=_LEAF_PIECES)
+                        per * (lanes // _LANES) * (own // _LANES)),
+                    class_dot_passes=_LEAF_PIECES,
+                    exit_mxu_tiles=(lanes // _LANES) * (exit_lanes // _LANES))
         tiles = round(per * tiles)
     if not served:
         return PathPlan(1, widest_tree, widest_tree, deepest_leaf, tiles, 0,
@@ -436,11 +503,13 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
                 + _window_bytes(lanes, lanes) // 2
                 + _window_bytes(lanes, exit_lanes) // 2)
     # the scores' window: [1, TILE_ROWS], or the chain's [TILE_ROWS, CL]
-    # with the activity scratch and a sub-tile's copies of both
+    # with the activity scratch, a sub-tile's copies of both, and y and
+    # a * y
     out = _window_bytes(1, TILE_ROWS) if not chain else (
         _window_bytes(TILE_ROWS, chain.class_lanes)
         + TILE_ROWS * chain.act_lanes * 4
-        + _sub_rows(pack) * exit_lanes * 3 * 4)
+        + _sub_rows(pack) * (chain.class_lanes + chain.act_lanes
+                             + 2 * exit_lanes) * 4)
     fixed = (2 * TILE_ROWS * _lane_pad(n_features) * row_bytes
              + out
              + _sub_rows(pack) * (_lane_pad(fp) * _SUB_ROW_BIN_BYTES
@@ -517,7 +586,8 @@ def pack_select(sel, planes, n_features: int, xp=jnp) -> tuple:
 
 def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
                   n_trees: int, n_feat: int, missing_routes: bool,
-                  class_lanes: int = 0, select_spans: tuple = ()):
+                  class_lanes: int = 0, select_spans: tuple = (),
+                  at_hand: int = 0):
     """One row tile against one block of `n_trees` trees: the block's share
     of every row's margin. x_ref [TILE_ROWS, F] uint8 or int32, as HBM
     holds the rows (in the last tile, whatever lies past row R); sel
@@ -527,12 +597,15 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
     axis (grid axis 1).
 
     `class_lanes` CL > 0, the SUB-TREE form: the block's entries are G
-    sub-trees, `rest` is (leaves [G, W, CL + A] bf16, out [TILE_ROWS, CL]
-    f32, act [TILE_ROWS, A] f32 scratch): the output holds the rows on the
+    sub-trees, `rest` is (leaves [G, W, E] bf16, out [TILE_ROWS, CL] f32,
+    act [TILE_ROWS, A] f32 scratch): the output holds the rows on the
     sublanes and the leaf values' three pieces on the lanes, and the
     activity lives in VMEM over the block axis, a tree's sub-trees lying in
     one block or in several. A first entry of the whole table roots a tree,
-    so what the scratch held before is never read. `select_spans`: the
+    so what the scratch held before is never read. E = CL + A, [V | L], or
+    E = CL = A = 128, the links behind the pieces in ONE tile (`Chain.
+    shared`); `at_hand` the activity's lane of the sub-tree at hand
+    (`Chain.at_hand`). `select_spans`: the
     K-blocks each 128-lane tile of the select reads (`Chain`; the caller
     hands the packed select none); (): every block, one matmul a block over
     all the lanes."""
@@ -628,30 +701,43 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
             return acc + jnp.where(m == rows[1:2, :], rows[2:3, :], 0.0)
 
         if class_lanes:
-            # THE CHAIN (module docstring). Lane 0 of `act` is this
-            # sub-tree's activity, lane i that of the tree's sub-tree i
-            # further on; an exit's row of the leaves' table adds the leaf's
-            # pieces to the class lanes or 1 to the linked sub-tree's lane.
+            # THE CHAIN (module docstring). Lane `at_hand` of `act` is this
+            # sub-tree's activity, lane `at_hand` + i that of the tree's
+            # sub-tree i further on; an exit's row of the leaves' table adds
+            # the leaf's pieces to the class lanes or 1 to the linked
+            # sub-tree's lane.
             leaves_ref, _, act_ref = rest
             act_lanes = act_ref.shape[1]
+            shared = leaves_ref.shape[2] == class_lanes
             act = act_ref[pl.ds(r0, sub_rows), :]
             acc = jnp.zeros((sub_rows, class_lanes), jnp.float32)
             first = jax.lax.broadcasted_iota(
-                jnp.int32, (1, act_lanes), 1) == 0
+                jnp.int32, (1, act_lanes), 1) == at_hand
             for g in range(n_trees):
                 e = tree(g, None)
                 y = jax.lax.dot_general(
                     e, leaves_ref[g], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [S, CL + A]
+                    preferred_element_type=jnp.float32)   # [S, E]
                 # (row 4 says it in every lane; W may be fewer than A)
                 roots = jnp.concatenate(
                     [planes_ref[g, 4:5, :_LANES]] * (act_lanes // _LANES),
                     axis=1) > 0.0
                 act = jnp.where(roots, jnp.where(first, 1.0, 0.0), act)
-                a = act[:, 0:1]
-                acc = acc + a * y[:, :class_lanes]
-                act = pltpu.roll(act, act_lanes - 1, 1) \
-                    + a * y[:, class_lanes:]
+                a = act[:, at_hand:at_hand + 1]
+                if shared:
+                    # ONE tile: the pieces and the links take the same
+                    # multiply and the same two adds. `acc`'s link lanes
+                    # and `act`'s class lanes hold sums nobody reads; what
+                    # the shift wraps round from lane 0 to lane 127 is
+                    # cleared by the tree's root before it is back at lane
+                    # `at_hand` (models/tree.exit_table_lanes: THE RULE).
+                    ay = a * y
+                    acc = acc + ay
+                    act = pltpu.roll(act, act_lanes - 1, 1) + ay
+                else:
+                    acc = acc + a * y[:, :class_lanes]
+                    act = pltpu.roll(act, act_lanes - 1, 1) \
+                        + a * y[:, class_lanes:]
             act_ref[pl.ds(r0, sub_rows), :] = act
             out_ref[pl.ds(r0, sub_rows), :] += acc
             return carry
@@ -683,7 +769,7 @@ def predict_paths_pallas(
     base,
     missing_routes: bool = False,
     interpret: bool | None = None,
-    leaves: jax.Array | None = None,   # bf16 [S, W, CL + A]: the sub-tree form
+    leaves: jax.Array | None = None,   # bf16 [S, W, E]: the sub-tree form
     chain: Chain | None = None,        # ... and its shape
     mean: bool = False,
 ) -> jax.Array:
@@ -766,7 +852,8 @@ def predict_paths_pallas(
             functools.partial(_paths_kernel, n_trees=g, n_feat=F,
                               missing_routes=missing_routes,
                               class_lanes=chain.class_lanes if chain else 0,
-                              select_spans=spans),
+                              select_spans=spans,
+                              at_hand=chain.at_hand if chain else 0),
             # The grid walks the UNPADDED rows: the last tile's blocks are
             # ragged, as in the heap kernel.
             grid=(n_tiles, n_blocks),

@@ -12,11 +12,11 @@ before each use of the chip:
     JAX_PLATFORMS=cpu python scripts/tpu_aot_check.py --only bosch --digest \
         [--tree <another checkout>]
 
-`--digest` prints, for every whole program, the SHA-1 of its optimized HLO
-(source metadata and the kernels' serialized bodies cut) and of its Mosaic
-modules without debug locations: two checkouts that print the same pair
-run the same program (`--tree`: take `ddt_tpu` from that checkout; the case
-tables stay this script's).
+`--digest` prints, for every kernel case and whole program, the SHA-1 of
+its optimized HLO (source metadata and the kernels' serialized bodies cut)
+and of its Mosaic modules without debug locations: two checkouts that
+print the same pair run the same program (`--tree`: take `ddt_tpu` from
+that checkout; the case tables stay this script's).
 
 A compile is not a run: it says nothing of results, of memory at run time
 or of speed. chip_smoke.py is the run.
@@ -225,21 +225,34 @@ def _paths_case(rows, features, n_trees, n_leaves, missing=False):
 
 
 def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
-                 act_lanes=128, mean=True, select_spans=()):
+                 most_subtrees=0, mean=True, select_spans=()):
     """The SUB-TREE form of the path-matrix kernel (ops/predict_paths.py:
     the chain and the class dot) over the compiled tables' SHAPES
-    (models/tree.CompiledNodeList: 1.42 GB at the MNIST forest's), the rows
+    (models/tree.CompiledNodeList: 1.28 GB at the MNIST forest's), the rows
     as api.predict does (uint8). `select_spans`: the K-blocks of the select
     each lane tile reads, as the model's build found them (`CompiledNodeList.
-    select_spans`); (): every block."""
+    select_spans`); (): every block. `most_subtrees`: the sub-trees of the
+    model's largest tree (0: every tree has its share), which with the
+    classes decides the width of the exits' table, as the build does
+    (models/tree.exit_table_lanes: ONE lane tile where the pieces and the
+    chain fit it)."""
     def build():
         import jax.numpy as jnp
+        import numpy as np
 
         from ddt_tpu.ops import predict_paths
+        try:
+            from ddt_tpu.models.tree import exit_table_lanes
+        except ImportError:
+            # (`--tree` names a checkout from before the one tile of exits)
+            def exit_table_lanes(classes, n_subtrees):
+                return (-(-3 * classes // 128) * 128
+                        + -(-(int(n_subtrees.max()) + 1) // 128) * 128), 0
 
-        class_lanes = -(-3 * classes // 128) * 128
-        chain = predict_paths.Chain(n_trees, classes, class_lanes, act_lanes,
-                                    select_spans)
+        most = most_subtrees or -(-n_subtrees // n_trees)
+        chain = predict_paths.chain_of(
+            n_trees, classes, exit_table_lanes(classes, np.array([most]))[0],
+            select_spans)
 
         def fn(sel, planes, paths, leaves, Xc):
             return predict_paths.predict_paths_pallas(
@@ -442,10 +455,14 @@ def kernel_cases() -> list:
         # a K-block both tiles read, and
         # the rule's edges: one sub-tree a tree with one column, with 85
         # (two class tiles, the most the rule takes) and with 128 (three:
-        # refused), sub-trees of one tile with two activity tiles.
+        # refused), sub-trees of one tile with two activity tiles. The
+        # exits' table (PR 49) is ONE lane tile in the forest's cases and at
+        # one column (30 or 3 lanes of pieces and a chain of 25, 21 or no
+        # link), [V | L] of three and four tiles at 85 and 128 classes and
+        # under the 200-part trees' chain.
         KernelCase("forest/784f/100x4779x10", True,
                    _forest_case(FOREST["chunk_rows"], FOREST["features"],
-                                100, 2112, 10,
+                                100, 2112, 10, most_subtrees=25,
                                 select_spans=((0, 3), (3, 7)))),
         KernelCase("forest/784f/12x20subtrees/shared-block", True,
                    _forest_case(4_999, FOREST["features"], 12, 245, 10,
@@ -460,8 +477,7 @@ def kernel_cases() -> list:
         KernelCase("forest/28f/12x1subtree/c128", False,
                    _forest_case(4_999, hf, 12, 12, 128)),
         KernelCase("forest/129f/3x200subtrees/c10/128lanes", True,
-                   _forest_case(4_999, 129, 3, 600, 10, lanes=128,
-                                act_lanes=256)),
+                   _forest_case(4_999, 129, 3, 600, 10, lanes=128)),
         # The oblivious form: CatBoost's Epsilon model's chunk (63 groups,
         # 16 K-blocks), the depths and widths at the dispatch rule's edges
         # (depth 10 at 28 columns fits, depth 7 at 2000), one K-block with
@@ -735,8 +751,13 @@ def main(argv=None) -> int:
                 fn, shapes = case.build()
                 sds = [jax.ShapeDtypeStruct(s, d, sharding=one)
                        for s, d in shapes]
-                jax.jit(fn, out_shardings=one).lower(*sds).compile()
+                mosaic = []
+                with _mosaic_modules(mosaic):
+                    txt = jax.jit(fn, out_shardings=one).lower(
+                        *sds).compile().as_text()
                 verdict = "compiled"
+                if args.digest:
+                    verdict += "  " + _digest(txt, mosaic)
             except Exception as e:  # the report IS the failure message
                 verdict = "REFUSED  " + _first_line(e)
                 failed_default += case.default

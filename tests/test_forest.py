@@ -192,16 +192,20 @@ def test_the_spans_cost_the_mnist_forests_cut_few_parts():
         learning_rate=1.0, base_score=0.0, loss="mean", n_classes=10,
         n_bins=sh["n_bins"])
     free = cut_subtrees(ens, 256)
-    spans, cut = tree.choose_select_spans(ens, 256, 128)
+    spans, cut = tree.choose_select_spans(ens, 256)
     assert spans == ((0, 3), (3, 7))
     assert free.n_subtrees.sum() < cut.n_subtrees.sum() \
         <= 1.06 * free.n_subtrees.sum()
     for a, b in zip(cut, cut_subtrees(ens, 256, spans)):
         np.testing.assert_array_equal(a, b)
-    # 15 tiles a sub-tree where 22: fewer a tree, for all the parts more
-    assert cut.n_subtrees.sum() * tree.subtree_mxu_tiles(spans, 256, 256) \
-        < 0.75 * free.n_subtrees.sum() * tree.subtree_mxu_tiles(
-            SPANS["dense"], 256, 256)
+    # the exits' table is ONE tile either way (30 lanes of pieces, a chain
+    # of some 25 sub-trees): 13 tiles a sub-tree where the dense spans ask
+    # 20: fewer a tree, for all the parts more
+    for parts in (cut.n_subtrees, free.n_subtrees):
+        assert tree.exit_table_lanes(10, parts) == (128, 30)
+    assert tree.subtree_mxu_tiles(spans, 256, 128) == 13
+    assert cut.n_subtrees.sum() * 13 < 0.75 * free.n_subtrees.sum() \
+        * tree.subtree_mxu_tiles(SPANS["dense"], 256, 128)
 
 
 @pytest.mark.parametrize("features,spans,tiles", [
@@ -220,8 +224,10 @@ def test_the_spans_are_read_from_the_model(monkeypatch, features, spans,
     plan = predict_paths.path_plan(ce.n_subtrees, 256, features, chain=chain)
     assert plan.select_mxu_tiles == tiles == tree.subtree_mxu_tiles(
         spans, 256, 0) - 4
+    # 9 lanes of pieces and a few links: one tile of exits, 2 weight tiles
+    assert ce.leaves.shape[2] == 128 and plan.exit_mxu_tiles == 2
     assert plan.path_mxu_tiles_per_tree == round(
-        ce.n_subtrees / 4 * tree.subtree_mxu_tiles(spans, 256, 256))
+        ce.n_subtrees / 4 * tree.subtree_mxu_tiles(spans, 256, 128))
     # every non-zero of the select lies in a K-block its lane tile reads
     _, rows, lanes = np.nonzero(ce.sel.astype(np.float32))
     first, stop = np.array(spans)[lanes // 128].T
@@ -237,7 +243,7 @@ def test_columns_that_crowd_one_block_and_models_with_nothing_to_split(
     monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
     ens = forest(63, 3, (600, 900), 2, features=784)
     ens.feature[ens.live_nodes] %= 128
-    spans, cut = tree.choose_select_spans(ens, 256, 128)
+    spans, cut = tree.choose_select_spans(ens, 256)
     assert spans == ens.compile().select_spans == ((0, 1), (0, 7))
     for a, b in zip(cut, cut_subtrees(ens, 256)):
         np.testing.assert_array_equal(a, b)
@@ -270,9 +276,14 @@ def tables_scores(ce, Xb):
         ce.sel, ce.paths, ce.leaves))
     C = ce.leaf_columns
     cl = -(-3 * C // 128) * 128
+    # one tile of exits: the links behind the 3 C lanes of pieces, and the
+    # sums and the activity 128 lanes each that take the same products
+    shared = leaves.shape[2] == 128
+    hand = 3 * C if shared else 0
     X = np.pad(Xb.astype(np.float32), ((0, 0), (0, sel.shape[1] - F)))
     acc = np.zeros((len(Xb), cl), np.float32)
-    act = np.zeros((len(Xb), leaves.shape[2] - cl), np.float32)
+    act = np.zeros((len(Xb), 128 if shared else leaves.shape[2] - cl),
+                   np.float32)
     for k in range(ce.n_subtrees):
         v = X @ sel[k]
         right = v > ce.planes[k, 0]
@@ -282,20 +293,26 @@ def tables_scores(ce, Xb):
              == ce.planes[k, 1]).astype(np.float32)
         assert (e.sum(axis=1) == 1).all()       # one exit a row, always
         if ce.planes[k, 4, 0] > 0:
-            act[:], act[:, 0] = 0.0, 1.0
-        y, a = e @ leaves[k], act[:, :1].copy()
-        acc += a * y[:, :cl]
-        act = np.roll(act, -1, axis=1) + a * y[:, cl:]
+            act[:], act[:, hand] = 0.0, 1.0
+        y, a = e @ leaves[k], act[:, hand:hand + 1].copy()
+        acc += a * (y if shared else y[:, :cl])
+        act = np.roll(act, -1, axis=1) + a * (y if shared else y[:, cl:])
     return (acc[:, 2 * C:3 * C] + acc[:, C:2 * C] + acc[:, :C]) / ce.n_trees
 
 
-@pytest.mark.parametrize("columns,missing", [(1, False), (3, True),
-                                             (10, False)])
-def test_the_subtrees_contributions_sum_to_the_uncut_walk(columns, missing):
-    ens = forest(4 + columns, 5, (1, 600), columns, missing=missing)
+@pytest.mark.parametrize("columns,missing,exit_lanes", [
+    (1, False, 128), (3, True, 128), (10, False, 128),
+    (42, True, 128),           # 126 lanes of pieces, chains of 3 at most
+    (43, False, 384),          # 129: two class tiles and the activity's
+    (85, True, 384)])
+def test_the_subtrees_contributions_sum_to_the_uncut_walk(columns, missing,
+                                                          exit_lanes):
+    ens = forest(4 + columns, 5, (1, 600) if columns != 42 else (1, 300),
+                 columns, missing=missing)
     ce = ens.compile()
     assert ce.chained and ce.mean and ce.n_subtrees > ens.n_trees
     assert ce.leaves.shape[1] == ce.lanes == 128
+    assert ce.leaves.shape[2] == exit_lanes
     assert ce.deepest_leaf == ens.deepest_leaf
     Xb = rows_of(5, 300)
     np.testing.assert_allclose(
@@ -335,23 +352,95 @@ def test_kernel_twin_and_reference_agree(columns, leaves, missing):
     np.testing.assert_array_equal(hits.sum(axis=1), np.full(333, 7.0))
 
 
-def test_blocks_that_cut_through_a_tree_and_ragged_row_tiles(monkeypatch):
+@pytest.mark.parametrize("columns,exit_lanes", [(3, 128), (10, 128),
+                                                (43, 384)])
+def test_blocks_that_cut_through_a_tree_and_ragged_row_tiles(
+        monkeypatch, columns, exit_lanes):
     """Three sub-trees a block and row tiles of 512: a tree's chain runs
     over several grid steps (the activity is kept in scratch over the block
-    axis), the last block holds filler entries, the last row tile is ragged."""
+    axis, under either layout of the exits), the last block holds filler
+    entries, the last row tile is ragged."""
     monkeypatch.setattr(predict_paths, "_MAX_TREES_PER_STEP", 3)
     monkeypatch.setattr(predict_paths, "TILE_ROWS", 512)
-    ens = forest(21, 8, (1, 500), 3, dyadic=True)
+    ens = forest(21, 8, (1, 500), columns, dyadic=True)
     ce = ens.compile()
+    assert ce.leaves.shape[2] == exit_lanes
     plan = predict_paths.path_plan(
         ce.n_subtrees, 128, F, chain=predict_paths.chain_of(
-            8, 3, ce.leaves.shape[2]))
+            8, columns, exit_lanes))
     assert plan.trees_per_step in (2, 3) and plan.table_blocks > 4
     Xb = rows_of(22, 1100)
     want = predict_proba_node_list(ens, Xb).astype(np.float32)
     # dyadic leaf values, 8 trees: every sum and the mean are exact
     np.testing.assert_array_equal(scored(ens, Xb, "pallas"), want)
     np.testing.assert_array_equal(scored(ens, Xb, "onehot"), want)
+
+
+def joined(*models):
+    """One ensemble of the models' trees, in their order (a tree of one
+    leaf has no node and so no `default_left`: none goes left)."""
+    per_tree = {}
+    for k, v in vars(models[0]).items():
+        if isinstance(v, np.ndarray):
+            parts = [getattr(m, k) if getattr(m, k) is not None
+                     else np.zeros(m.feature.shape, v.dtype) for m in models]
+            tail = np.max([a.shape[1:] for a in parts], axis=0)
+            per_tree[k] = np.concatenate([np.pad(
+                a, [(0, 0)] + [(0, int(t) - n)
+                               for n, t in zip(a.shape[1:], tail)],
+                constant_values=-1 if k == "feature" else 0) for a in parts])
+    return NodeListEnsemble(**{**vars(models[0]), **per_tree})
+
+
+def tree_of_parts(rng, parts, **meta):
+    """One random tree that `cut_subtrees` cuts into exactly `parts`
+    sub-trees of 128 lanes (some hundred leaves a part: drawn again, larger
+    or smaller, until the count is met)."""
+    leaves = 100 * parts
+    for _ in range(200):
+        ens = random_node_list(rng, 1, leaves, F, n_bins=BINS, dyadic=True,
+                               missing=True, **meta)
+        got = int(cut_subtrees(ens, 128).n_subtrees[0])
+        if got == parts:
+            return ens
+        leaves += 50 * (parts - got)
+    raise AssertionError(f"no tree of {parts} sub-trees in 200 draws")
+
+
+@pytest.mark.parametrize("columns,most", [(42, 3), (40, 9), (10, 99),
+                                          (1, 126)])
+def test_the_one_tile_of_exits_at_the_rules_edge_and_one_past_it(
+        monkeypatch, columns, most):
+    """THE RULE (models/tree.exit_table_lanes): 3 C + n - 1 <= 128 for the
+    most sub-trees n of a tree. AT the edge a link from the tree's first
+    sub-tree to its last would lie in lane 127, and what the shift wraps round from the class lanes would be read one
+    sub-tree past the tree's last: the longest chain the tile holds, among
+    three short trees (whose roots clear what it left), scores the uncut
+    walk's leaves bit for bit (dyadic leaf values, learned NaN directions).
+    One sub-tree more and the model gets [V | L], the table of before, and
+    its scores. One column: a scalar tree cut past PATH_UNCUT_LANES. (Blocks
+    of 8 entries: the chain crosses a dozen grid steps, and the interpreted
+    kernel's trace is 8 sub-trees long, not 64.)"""
+    monkeypatch.setattr(predict_paths, "_MAX_TREES_PER_STEP", 8)
+    meta = dict(leaf_columns=columns) if columns > 1 else dict(
+        learning_rate=1.0, base_score=0.0, loss="logloss")
+    rng = np.random.default_rng(100 + columns)
+    small = [random_node_list(rng, 1, n, F, n_bins=BINS, dyadic=True,
+                              missing=True, **meta) for n in (150, 1, 40)]
+    cl = -(-3 * columns // 128) * 128
+    Xb = rows_of(93, 200)
+    Xb[::5, ::2] = BINS - 1
+    for n, width in ((most, 128), (most + 1, cl + 128)):
+        ens = joined(small[0], tree_of_parts(rng, n, **meta), *small[1:])
+        ce = ens.compile()
+        assert ce.n_subtrees == 2 + n + 2 and ce.leaves.shape[2] == width
+        assert tree.exit_table_lanes(columns, np.array([2, n, 1, 1])) == (
+            width, 3 * columns if width == 128 else cl)
+        want = predict_proba_node_list(ens, Xb) if columns > 1 \
+            else ens.predict_raw(Xb, binned=True)
+        for impl in ("pallas", "onehot"):   # 4 trees: the mean is exact too
+            np.testing.assert_array_equal(scored(ens, Xb, impl),
+                                          want.astype(np.float32))
 
 
 def _one_block(ens):
@@ -402,12 +491,19 @@ def test_kernel_twin_and_walk_are_bit_equal_under_the_spans(
     (256, 100, 3, False, 6, "c37e078ebc4f0667"),   # one K-block
     (256, 12, 10, True, 11, "df962ff860f2425f"),
     (256, 28, 0, False, 12, "322d840d74a261cc"),   # scalar, 700 leaves
+    # (1ec9f09's, the commit before the one tile of exits:) 129 and 255
+    # lanes of pieces, which no chain shares a tile with
+    (128, 12, 43, False, 13, "8370a7fa64a222f5"),
+    (256, 12, 85, True, 12, "d5871a3342126539"),
 ])
 def test_dense_spans_build_the_parents_tables_bit_for_bit(
         monkeypatch, lanes, features, columns, missing, parts, digest):
     """SHA-1s of the four tables as the commit before the spans built them
     (da8e8d4, the same seeded forests): a model whose spans are dense is
-    numbered in pre-order and cut by the one bound, as ever."""
+    numbered in pre-order and cut by the one bound, as ever. The exits'
+    table of ONE tile hashes to that commit's once its links are moved back
+    to a lane tile of their own: the same exits, the same pieces, the same
+    chain; a model it does not fit builds [V | L] bit for bit."""
     import hashlib
 
     monkeypatch.setattr(tree, "SUBTREE_LANES", lanes)
@@ -420,8 +516,15 @@ def test_dense_spans_build_the_parents_tables_bit_for_bit(
     ce = ens.compile()
     assert ce.select_spans == tree.dense_spans(features, lanes)
     assert ce.n_subtrees == parts
+    pieces = 3 * ce.leaf_columns
+    shared = ce.leaves.shape[2] == 128
+    assert shared == (columns not in (43, 85))
+    leaves = ce.leaves if not shared else np.concatenate(
+        [ce.leaves[:, :, :pieces], np.zeros_like(ce.leaves[:, :, pieces:]),
+         ce.leaves[:, :, pieces:], np.zeros_like(ce.leaves[:, :, :pieces])],
+        axis=2)
     h = hashlib.sha1()
-    for a in ce.arrays():
+    for a in ce.arrays()[:3] + (leaves,):
         h.update(np.ascontiguousarray(a).view(np.uint8).tobytes())
     assert h.hexdigest()[:16] == digest
 
@@ -462,7 +565,26 @@ def test_the_plan_says_what_serves_and_the_rule_refuses_what_cannot_build():
     assert plan.trees_per_step >= 4 and plan.table_blocks > 1
     assert plan.table_bytes == plan.trees_per_step * plan.table_blocks * (
         784 * 256 * 2 + 8 * 256 * 4 + 256 * 256 * 2 + 256 * 256 * 2)
+    assert plan.exit_mxu_tiles == 4
     assert set(predict_paths.CHAIN_COUNTS) <= set(plan.root_counts())
+    # ... and with the exits' table the forest gets, ONE tile: 13 a sub-tree
+    # under its spans, no tile of the chain's own, an entry 64 KB lighter
+    one = predict_paths.chain_of(100, 10, 128, ((0, 3), (3, 7)))
+    assert one == predict_paths.Chain(100, 10, 128, 128, ((0, 3), (3, 7)),
+                                      True)
+    assert (chain.exit_lanes, chain.at_hand, one.exit_lanes, one.at_hand) \
+        == (256, 0, 128, 30)
+    shared = predict_paths.path_plan(2112, 256, 784, 26, chain=one,
+                                     widest_tree=4864)
+    assert (shared.path_mxu_tiles_per_tree, shared.exit_mxu_tiles,
+            shared.chain_mxu_tiles_per_tree, shared.select_mxu_tiles,
+            shared.class_dot_passes) == (275, 2, 0, 7, 3)
+    assert shared.table_bytes == shared.trees_per_step \
+        * shared.table_blocks * (784 * 256 * 2 + 8 * 256 * 4
+                                 + 256 * 256 * 2 + 256 * 128 * 2)
+    assert shared.trees_per_step >= plan.trees_per_step
+    with pytest.raises(ValueError, match="holds no chain"):
+        predict_paths.chain_of(1, 85, 256)
     # one column and the widest leaf the rule takes; 128 columns are three
     # class tiles whose output windows do not fit beside a row tile: the
     # guard says so and `auto` takes the jax.numpy form
@@ -473,10 +595,11 @@ def test_the_plan_says_what_serves_and_the_rule_refuses_what_cannot_build():
     # an uncut model's plan says a tree is a sub-tree of its own
     flat = predict_paths.path_plan(500, 256, 28)
     assert (flat.subtrees_per_tree, flat.subtree_lanes, flat.leaf_columns,
-            flat.chain_mxu_tiles_per_tree, flat.class_dot_passes) == (
-                1.0, 256, 1, 0, 0)
+            flat.chain_mxu_tiles_per_tree, flat.class_dot_passes,
+            flat.exit_mxu_tiles) == (1.0, 256, 1, 0, 0, 0)
 
 
+@pytest.mark.parametrize("exit_lanes", [128, 256])
 @pytest.mark.parametrize("spans,entries,select,tiles,per_tree", [
     (((0, 3), (3, 7)), 2112, 7, 15, 317),      # what the forest's build finds
     (((0, 4), (3, 7)), 2043, 8, 16, 327),
@@ -484,20 +607,26 @@ def test_the_plan_says_what_serves_and_the_rule_refuses_what_cannot_build():
     ((), 2015, 14, 22, 443),                   # dense, as before
 ])
 def test_the_plan_counts_the_select_by_its_spans(spans, entries, select,
-                                                 tiles, per_tree):
-    chain = predict_paths.chain_of(100, 10, 256, spans)
+                                                 tiles, per_tree, exit_lanes):
+    """(`tiles`, `per_tree`: with [V | L], 256 lanes of exits; ONE tile of
+    them asks two weight tiles a sub-tree fewer.)"""
+    chain = predict_paths.chain_of(100, 10, exit_lanes, spans)
+    if exit_lanes == 128:
+        tiles, per_tree = tiles - 2, round(entries / 100 * (tiles - 2))
+        assert per_tree == 275 or entries != 2112
     assert predict_paths.path_mxu_tiles_per_tree(
-        256, 784, 1, 256, chain.select_spans) == tiles
+        256, 784, 1, exit_lanes, chain.select_spans) == tiles
     for served in (True, False):
         plan = predict_paths.path_plan(entries, 256, 784, 26, chain=chain,
                                        served=served, widest_tree=4864)
         assert (plan.select_mxu_tiles, plan.path_mxu_tiles_per_tree,
                 plan.select_k_blocks) == (select, per_tree, 7)
-    assert 15 <= tiles <= 17 and 7 <= select <= 9 or not spans
+    assert 13 <= tiles <= 17 and 7 <= select <= 9 or not spans
     # the tables stay whole in HBM: the bytes of an entry do not move
     assert plan.table_bytes == 0 and predict_paths.path_plan(
         entries, 256, 784, chain=chain).table_bytes % (
-            784 * 256 * 2 + 8 * 256 * 4 + 2 * 256 * 256 * 2) == 0
+            784 * 256 * 2 + 8 * 256 * 4 + 256 * 256 * 2
+            + 256 * exit_lanes * 2) == 0
     # what the uncut models said, they say: Higgs's packed select (one
     # K-block, two nodes a lane), Bosch's 8 K-blocks x 2 lane tiles
     for features, said in ((28, (1, 5)), (968, (16, 20))):
@@ -511,15 +640,16 @@ def test_the_plan_counts_the_select_by_its_spans(spans, entries, select,
     assert (packed.select_nodes_per_lane, packed.select_mxu_tiles) == (2, 1)
 
 
-def test_the_spans_say_the_subtree_form(monkeypatch):
+@pytest.mark.parametrize("columns,exit_tiles", [(10, 1), (85, 3)])
+def test_the_spans_say_the_subtree_form(columns, exit_tiles):
     from ddt_tpu.backends import get_backend
     from ddt_tpu.telemetry import annotations as an
 
-    ens = forest(41, 4, (300, 600), 10)
+    ens = forest(41, 4, (300, 600), columns)
     be = get_backend(TrainConfig(backend="tpu", n_bins=BINS,
                                  predict_impl="pallas"))
     out = be.predict_raw(ens, rows_of(42, 50))
-    assert out.shape == (50, 10)
+    assert out.shape == (50, columns)
     root = an.root_spans("predict")[-1]
     built = [s for s in root["spans"]
              if s["name"] == "ddt:predict:ensemble"][0]["counts"]
@@ -527,10 +657,14 @@ def test_the_spans_say_the_subtree_form(monkeypatch):
         predict_paths.CHAIN_COUNTS)
     assert built["select_mxu_tiles"] == 1        # one tile, one K-block
     assert built["subtrees_per_tree"] > 1 and built["subtree_lanes"] == 128
-    assert built["leaf_columns"] == root["counts"]["classes"] == 10
+    assert built["leaf_columns"] == root["counts"]["classes"] == columns
     assert built["class_dot_passes"] == 3
-    assert built["chain_mxu_tiles_per_tree"] == round(
-        built["subtrees_per_tree"])
+    # the exits' table a sub-tree of ONE lane tile: one weight tile where
+    # the links share the pieces' (and then no tile is the chain's own),
+    # 85 columns' two class tiles and the activity's
+    assert built["exit_mxu_tiles"] == exit_tiles
+    assert built["chain_mxu_tiles_per_tree"] == (
+        0 if exit_tiles == 1 else round(built["subtrees_per_tree"]))
     for k in predict_paths.CHAIN_COUNTS + ("node_list",
                                            "path_mxu_tiles_per_tree"):
         assert root["counts"][k] == built[k]

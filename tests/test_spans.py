@@ -333,7 +333,10 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
         # one uncut path matrix a tree: a sub-tree of its own, no chain
         subtrees_per_tree=1.0, subtree_lanes=128, leaf_columns=1,
         chain_mxu_tiles_per_tree=0, class_dot_passes=0,
-        select_mxu_tiles=1)                # one K-block x one lane tile
+        select_mxu_tiles=1,                # one K-block x one lane tile
+        exit_mxu_tiles=0)                  # no exits' table: no chain
+    assert predict_paths.CHAIN_COUNTS[-2:] == ("select_mxu_tiles",
+                                               "exit_mxu_tiles")
     assert counts["bytes"] == counts["table_bytes"] or not served
     assert root["counts"]["select_k_blocks"] == 1
 

@@ -202,15 +202,42 @@ def test_path_kernel_takes_the_select_the_rule_packs(case):
     assert "ddt:predict:tables/concatenate" not in text
 
 
+# The exits' table of every forest case (PR 49): (its lanes, the class
+# lanes of the kernel's result, the select's spans, `exit_mxu_tiles`, the
+# MXU weight tiles a sub-tree). ONE lane tile where the three pieces of a
+# leaf's values and the tree's chain fit it (models/tree.exit_table_lanes);
+# [V | L], lane tiles of their own, at 85 and 128 classes and under a chain
+# of 200 sub-trees.
+FOREST_EXITS = {
+    "forest/784f/100x4779x10": (128, 128, ((0, 3), (3, 7)), 2, 13),
+    "forest/784f/12x20subtrees/shared-block":
+        (128, 128, ((0, 4), (3, 7)), 2, 14),
+    "forest/28f/12x1subtree/c1": (128, 128, (), 2, 7),
+    "forest/28f/12x1subtree/c85": (384, 256, (), 6, 11),
+    "forest/28f/12x1subtree/c128": (512, 384, (), 8, 13),
+    "forest/129f/3x200subtrees/c10/128lanes": (384, 128, (), 3, 6),
+}
+
+
+def test_every_forest_case_names_its_exits():
+    assert sorted(FOREST_EXITS) == sorted(
+        c.name for c in aot.kernel_cases() if c.name.startswith("forest/"))
+
+
 @pytest.mark.parametrize("case", FOREST_CASES, ids=lambda c: c.name)
 def test_subtree_form_crosses_hbm_at_the_datas_width(case):
     """The sub-tree form of the path kernel (the chain and the class dot)
     keeps the kernel's interface on the way in (the uint8 chunk as it
     comes, 784 columns at the MNIST forest's chunk, the last row tile
-    ragged) and takes a fourth table, the exits'; on the way out the rows
-    lie on the sublanes, `f32[R, CL]` with the leaf values' three pieces
-    on the class lanes, which XLA folds to `[R, C]` and divides by the
-    tree count under `predict:accumulate`."""
+    ragged) and takes a fourth table, the exits', `[entries, lanes, 128]`
+    bf16 where the pieces and the chain share ONE lane tile (the MNIST
+    forest's), the class tiles and the activity's behind them where they do
+    not; on the way out the rows lie on the sublanes, `f32[R, CL]` with the
+    leaf values' three pieces in the first 3 C of the class lanes, which
+    XLA folds to `[R, C]` and divides by the tree count under
+    `predict:accumulate`."""
+    from ddt_tpu.ops import predict_paths
+
     exported, shapes = _export_for_tpu(case)
     (rows, features), dtype = shapes[-1]
     (entries, lanes, exit_lanes), _ = shapes[3]
@@ -224,13 +251,25 @@ def test_subtree_form_crosses_hbm_at_the_datas_width(case):
     assert f"tensor<{entries}x{lanes}x{exit_lanes}xbf16>" in operands
     class_lanes = int(re.fullmatch(
         rf"tensor<{rows}x(\d+)xf32>", result).group(1))
-    assert class_lanes % 128 == 0 and 0 < class_lanes < exit_lanes
+    want_exits, want_class, spans, exit_tiles, tiles = FOREST_EXITS[case.name]
+    assert (exit_lanes, class_lanes) == (want_exits, want_class)
+    # one tile of exits IS the class lanes; else the activity lies behind
+    assert class_lanes % 128 == 0 and (
+        exit_lanes == class_lanes == 128 or exit_lanes >= class_lanes + 128)
     for held in ("xi32>", "xf32>", "xbf16>"):
         assert f"tensor<{rows}x{features}{held}" not in text
     assert "ddt:predict:widen" not in text
     assert "ddt:predict:accumulate" in text
     out, = exported.out_avals
     assert out.shape[0] == rows and out.shape[1] * 3 <= class_lanes
+    # ... and what the plan says of it on the `ensemble` span
+    chain = predict_paths.chain_of(1, out.shape[1], exit_lanes, spans)
+    assert chain.shared == (exit_lanes == 128)
+    assert chain.at_hand == (3 * out.shape[1] if chain.shared else 0)
+    plan = predict_paths.path_plan(entries, lanes, features, chain=chain)
+    assert plan.exit_mxu_tiles == exit_tiles
+    assert predict_paths.path_mxu_tiles_per_tree(
+        lanes, features, None, exit_lanes, spans) == tiles
 
 
 @pytest.mark.parametrize("case", OBLIVIOUS_CASES, ids=lambda c: c.name)
