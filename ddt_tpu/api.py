@@ -107,7 +107,9 @@ def save_model(path, ens: TreeEnsemble, mapper: BinMapper | None = None,
 
 def load_model(path, *, verify: bool = True) -> ModelBundle:
     """Load a model artifact written by save_model (or a bare
-    TreeEnsemble.save file — mapper/encoder come back None then). When
+    TreeEnsemble.save file — mapper/encoder come back None then; or an
+    XGBoost model's `.json`, `models/xgboost_io.py`, with the mapper its
+    own thresholds give). When
     the file carries an embedded manifest, its content digest is
     verified — a mismatch raises registry.IntegrityError (a ValueError)
     rather than returning silently corrupt trees; manifest-less legacy
@@ -116,6 +118,16 @@ def load_model(path, *, verify: bool = True) -> ModelBundle:
     restores behind an artifact-level sha256)."""
     from ddt_tpu.registry import manifest as manifest_mod
 
+    if str(path).endswith((".json", ".ubj")):
+        # an XGBoost model as the library saved it (`.ubj` refused by
+        # name), the data taken to hold missing values; its thresholds
+        # ranked into 256 bins: the mapper that scores raw rows
+        from ddt_tpu.models.lightgbm_io import threshold_bin_mapper
+        from ddt_tpu.models.xgboost_io import load_xgboost
+
+        ens = load_xgboost(path)
+        return ModelBundle(ensemble=ens,
+                           mapper=threshold_bin_mapper(ens, n_bins=256))
     with np.load(path) as z:
         d = dict(z)
     manifest = manifest_mod.read_npz_manifest(d, verify=verify,
@@ -343,7 +355,11 @@ def predict(
     "mean": `models/sklearn_io.from_sklearn`) answers float32
     `[rows, classes]`, the mean over the trees of the reached leaves'
     vectors, with `raw` or without (it has no link function); its argmax
-    is the forest's class."""
+    is the forest's class. A node list of several classes (`loss`
+    "softmax": an XGBoost or LightGBM multiclass model too deep for the
+    heap, `models/xgboost_io.from_xgboost_json`) answers float32
+    `[rows, classes]` class probabilities, the softmax taken by the
+    device's own program (`raw`: the margins)."""
     if n_partitions is not None and n_partitions > 1 \
             and backend is None and cfg is None:
         backend = _row_mesh_backend(n_partitions)
@@ -369,6 +385,10 @@ def predict(
             f"binned=True requires uint8 bin indices, got {X.dtype}"
         )
     if backend is not None and binned:
+        if not raw and backend.links_on_device(ens):
+            # softmax's round-major trees as a node list: the program ends
+            # in the link, on the device (stage `predict:link`)
+            return backend.predict_raw(ens, X, link=True)
         out = backend.predict_raw(ens, X)
         if raw:
             return out

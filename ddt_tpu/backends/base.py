@@ -177,6 +177,13 @@ class DeviceBackend(abc.ABC):
         keep device-resident scoring caches use it to skip the per-call
         content hash, others may ignore it."""
 
+    def links_on_device(self, ens) -> bool:
+        """Whether `predict_raw(ens, Xb, link=True)` answers this model's
+        probabilities, the link function taken by the backend's own scoring
+        program. Default: never; the caller takes it
+        (`utils/metrics.predict_proba_np`)."""
+        return False
+
     # ------------------------------------------------------------------ #
 
     def device_stamp(self) -> dict:

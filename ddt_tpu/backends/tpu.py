@@ -44,7 +44,7 @@ import numpy as np
 from ddt_tpu.backends.base import DeviceBackend, HostTree
 from ddt_tpu.config import TrainConfig
 from ddt_tpu.models.tree import (CompiledNodeList, CompiledOblivious,
-                                 TreeEnsemble)
+                                 NodeListEnsemble, TreeEnsemble)
 from ddt_tpu.ops import grad as grad_ops
 from ddt_tpu.ops import grow as grow_ops
 from ddt_tpu.ops import histogram as hist_ops
@@ -1596,12 +1596,24 @@ class TPUDevice(DeviceBackend):
         return max(1, min(self.PREDICT_ROW_CHUNK, self.PREDICT_CHUNK_BYTES
                           // (lanes * self.PREDICT_ROW_DTYPE.itemsize)))
 
+    def links_on_device(self, ens) -> bool:
+        """Whether `predict_raw(..., link=True)` answers this model's
+        probabilities: the link function taken by the scoring program, on
+        the device, under the stage `predict:link`. A node list of
+        softmax's round-major trees (its [rows, C] margins are on the
+        device as the program ends); every other model's link is the
+        caller's (`utils/metrics.predict_proba_np`, api.predict)."""
+        return isinstance(ens, NodeListEnsemble) and ens.loss == "softmax"
+
     def predict_raw(self, ens: TreeEnsemble, Xb: np.ndarray,
-                    compiled=None) -> np.ndarray:
+                    compiled=None, link: bool = False) -> np.ndarray:
         """Score binned rows. `compiled` (a models/tree.CompiledEnsemble
         already built for THIS ens) skips the per-call content hash —
         the serving tier holds one per model version, so a micro-batch
-        request pays upload + dispatch only (docs/SERVING.md).
+        request pays upload + dispatch only (docs/SERVING.md). `link`: the
+        class probabilities and not the margins, where `links_on_device`
+        says the program takes the link (another program of the same
+        model: its own entry of the cache).
 
         Every call is one root span `ddt:predict` with a child span per
         step (token, ensemble, upload, dispatch, fetch, place: the
@@ -1610,20 +1622,25 @@ class TPUDevice(DeviceBackend):
         with phase_span("predict", rows=int(Xb.shape[0])) as root:
             c0 = tele_counters.snapshot()
             try:
-                return self._predict_raw(ens, Xb, compiled, root.counts)
+                return self._predict_raw(ens, Xb, compiled, root.counts,
+                                         link)
             finally:
                 moved = tele_counters.delta(c0)
                 root.counts.update(
                     {k: moved[k] for k in self._PREDICT_ROOT_COUNTERS})
 
     def _predict_raw(self, ens: TreeEnsemble, Xb: np.ndarray, compiled,
-                     counts: dict) -> np.ndarray:
+                     counts: dict, link: bool = False) -> np.ndarray:
         """predict_raw's body; `counts` is the root span's (branch and
         chunks are written as soon as they are known)."""
         R = Xb.shape[0]
         chunk = self.predict_chunk_rows(Xb.shape[1]) * max(
             1, self.row_shards)
-        fn, ens_dev, classes, plan = self._predict_entry(ens, compiled)
+        fn, ens_dev, classes, plan = self._predict_entry(ens, compiled,
+                                                         link)
+        # (the rows of the executable this call runs: what the stage map is
+        # read at, `_stage_scoring_program`)
+        self._scoring_rows = min(R, chunk)
         if isinstance(Xb, jax.Array) and (R <= chunk or self.distributed):
             # Device-resident input is only special-cased on the
             # single-chip big-batch loop below (where it skips the bulk
@@ -1855,7 +1872,8 @@ class TPUDevice(DeviceBackend):
         `_predict_entry` without the class count and the table plan."""
         return self._predict_entry(ens, compiled)[:2]
 
-    def _predict_entry(self, ens: TreeEnsemble, compiled=None):
+    def _predict_entry(self, ens: TreeEnsemble, compiled=None,
+                       link: bool = False):
         """(jittable scoring fn, device-resident compiled-ensemble arrays,
         the model's class count, how the traversal kernel takes the node
         tables: the ops/predict_pallas.TablePlan of `_build_predict_fn`).
@@ -1885,11 +1903,17 @@ class TPUDevice(DeviceBackend):
         tier can stamp the TRUE tier into /healthz + serve_latency —
         a silent guard trip must be visible in telemetry, not only in
         debug logs."""
+        if link and not self.links_on_device(ens):
+            raise ValueError(
+                "predict_raw(link=True): this model's link function is not "
+                "taken on the device (links_on_device); ask for the "
+                "margins and apply utils.metrics.predict_proba_np")
         if compiled is not None:
             token = compiled.token
         else:
             with phase_span("predict:token"):
                 token = ens.cache_token()
+        token += ":link" * link         # another program of the same model
         hit = self._predict_cache.pop(token, None)
         if hit is not None:
             self._predict_cache[token] = hit     # most-recently-used
@@ -1897,7 +1921,7 @@ class TPUDevice(DeviceBackend):
             return hit
         with phase_span("predict:ensemble") as sp:
             fn, ens_dev, resolved, classes, plan = self._build_predict_fn(
-                ens, compiled)
+                ens, compiled, link)
             sp.counts["bytes"] = sum(a.nbytes for a in ens_dev)
             # Beside table_groups: the share of the groups' lanes that
             # hold a tree (100 of 128).
@@ -1911,7 +1935,8 @@ class TPUDevice(DeviceBackend):
             self._predict_impl_resolved.pop(gone, None)
         return hit
 
-    def _build_predict_fn(self, ens: TreeEnsemble, compiled):
+    def _build_predict_fn(self, ens: TreeEnsemble, compiled,
+                          link: bool = False):
         """_predict_entry's cache miss: (fn, device arrays, the tier that
         serves, the model's class count, how the f32 traversal kernel
         takes the node tables) — layout build or reuse, quantisation, the
@@ -1923,7 +1948,7 @@ class TPUDevice(DeviceBackend):
         ce = compiled if compiled is not None else ens.compile(
             tree_chunk=64)
         if isinstance(ce, CompiledNodeList):
-            return self._build_paths_fn(ens, ce)
+            return self._build_paths_fn(ens, ce, link)
         if isinstance(ce, CompiledOblivious):
             return self._build_oblivious_fn(ens, ce)
         impl_req = self.cfg.predict_impl
@@ -1988,11 +2013,14 @@ class TPUDevice(DeviceBackend):
         return (self._row_sharded(fn0, len(ens_dev), ce.n_classes_out),
                 ens_dev, resolved, ce.n_classes_out, plan)
 
-    def _build_paths_fn(self, ens, ce: CompiledNodeList):
+    def _build_paths_fn(self, ens, ce: CompiledNodeList,
+                        link: bool = False):
         """_build_predict_fn for a NODE LIST: its path tables up, and the
         path-matrix scoring program (ops/predict.predict_raw_effective_
         paths: the Pallas kernel where the dispatch rule takes it, else
-        the jax.numpy form). The plan is an ops/predict_paths.PathPlan."""
+        the jax.numpy form). The plan is an ops/predict_paths.PathPlan.
+        `link`: the program ends in the model's link function (softmax's
+        round-major trees: `links_on_device`)."""
         from ddt_tpu.ops import predict_paths
 
         if self.cfg.predict_impl in ("lut", "lut4"):
@@ -2003,8 +2031,8 @@ class TPUDevice(DeviceBackend):
         use_pallas = self._use_pallas
         missing_routes = ce.missing_bin_value >= 0
         # The sub-tree form: the tables' entries are sub-trees, a fourth
-        # table says what their exits are, and vector leaves answer
-        # [rows, C].
+        # table says what their exits are, and vector leaves (or
+        # softmax's round-major trees) answer [rows, C].
         classes = ce.leaf_columns
         chain = predict_paths.chain_of(
             ce.n_trees, classes, ce.leaves.shape[2],
@@ -2017,7 +2045,10 @@ class TPUDevice(DeviceBackend):
                 path_lanes=ce.lanes,
                 path_exit_lanes=chain.exit_lanes if chain else 0),
             missing_routes=missing_routes, row_dtype=self.PREDICT_ROW_DTYPE,
-            chain=chain, widest_tree=ce.widest_tree)
+            chain=chain, widest_tree=ce.widest_tree)._replace(
+                subtrees_per_tree_max=ce.subtrees_max,
+                single_subtree_trees=ce.single_subtree_trees,
+                link=ce.loss if link else "none")
         # What every chunk's program would otherwise make of the tables is
         # made here, once a model: the select that answers two nodes a lane
         # with its shifted thresholds (`pack_select`), and the trees that
@@ -2041,7 +2072,8 @@ class TPUDevice(DeviceBackend):
                       use_pallas=use_pallas, missing_routes=missing_routes)
         if chain:
             static.update(n_trees=ce.n_trees, leaf_columns=classes,
-                          mean=ce.mean, select_spans=chain.select_spans)
+                          mean=ce.mean, select_spans=chain.select_spans,
+                          **({"link": plan.link} if link else {}))
 
         # (two functions: the uncut form keeps its program's parameter
         # names, so its HLO is what it was)
@@ -2060,7 +2092,8 @@ class TPUDevice(DeviceBackend):
             predict_ops.predict_raw_effective_paths, fn0, ens_dev,
             ens.n_features)
         # an averaged forest answers [rows, C] whatever C, one column too
-        return (self._row_sharded(fn0, len(ens_dev), 2 if ce.mean else 1),
+        return (self._row_sharded(fn0, len(ens_dev),
+                                  2 if ce.mean or classes > 1 else 1),
                 ens_dev, "f32", classes, plan)
 
     def _build_oblivious_fn(self, ens, ce: CompiledOblivious):
@@ -2107,19 +2140,24 @@ class TPUDevice(DeviceBackend):
         calls it with the model's static arguments) at the loop's own
         shapes, the device tables' and a chunk of `predict_chunk_rows` uint8
         rows, and the loop's two small programs by what they are for.
-        Two dict writes: the lowering happens when somebody asks. A call of
-        another row count runs another executable of the same name, whose
-        instructions the map may not hold; a mesh's row-sharded wrapper is
-        another program and is not named."""
+        Two dict writes: the lowering happens when somebody asks, at the
+        rows of the backend's LAST call (a batch of fewer rows than a chunk
+        is ONE program of its own rows: a 581,012-row set at 54 columns). A
+        call of another row count runs another executable of the same name,
+        whose instructions the map may not hold; a mesh's row-sharded
+        wrapper is another program and is not named."""
         if self.distributed:
             return
         avals = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ens_dev]
-        avals.append(jax.ShapeDtypeStruct(
-            (self.predict_chunk_rows(n_features), n_features),
-            self.PREDICT_ROW_DTYPE))
-        stage_program(
-            "jit_" + entry.__name__,
-            lambda: fn0(*avals, entry=entry.lower).compile().as_text())
+
+        def hlo():
+            rows = getattr(self, "_scoring_rows", None) \
+                or self.predict_chunk_rows(n_features)
+            Xc = jax.ShapeDtypeStruct((rows, n_features),
+                                      self.PREDICT_ROW_DTYPE)
+            return fn0(*avals, Xc, entry=entry.lower).compile().as_text()
+
+        stage_program("jit_" + entry.__name__, hlo)
         loop = "ddt_tpu/backends/tpu.py:%d" % (
             TPUDevice._predict_raw.__code__.co_firstlineno)
         # `pieces[-1][at:at + chunk]` and the first piece's `jnp.reshape`,

@@ -681,7 +681,10 @@ def main(argv: list[str] | None = None) -> int:
 
     pp = sub.add_parser("predict", help="score a batch with a saved ensemble")
     _add_common(pp)
-    pp.add_argument("--model", required=True)
+    pp.add_argument("--model", required=True,
+                    help="a saved artifact (.npz), or an XGBoost model as "
+                         "Booster.save_model wrote it (.json; "
+                         "models/xgboost_io.py)")
     pp.add_argument("--quantized", nargs="?", const="int8", default=None,
                     choices=["int8", "int4"],
                     help="score through the quantized TreeLUT ladder "
@@ -1408,7 +1411,10 @@ def main(argv: list[str] | None = None) -> int:
         return 0
 
     if args.cmd == "inspect":
-        ens = TreeEnsemble.load(args.model)
+        # (an XGBoost model's own `.json`: api.load_model imports it)
+        ens = (api.load_model(args.model).ensemble
+               if args.model.endswith((".json", ".ubj"))
+               else TreeEnsemble.load(args.model))
         if args.tree is not None and not (0 <= args.tree < ens.n_trees):
             ap.error(f"--tree must be in [0, {ens.n_trees}), got {args.tree}")
         imp = ens.feature_importances(kind=args.importance)

@@ -18,8 +18,9 @@ levels deep whose heap would be 2^21 slots for 509 entries; the node list
 holds them as they are and the path-matrix kernel (ops/predict_paths.py)
 scores them. The node list carries ordinal splits, with or without NaN
 default directions (LightGBM's `use_missing`, its default: a model trained
-on data with a missing value in it), and one output column: a deep model
-with category sets or several classes
+on data with a missing value in it), and one output column or several
+classes as softmax's round-major trees (`multiclass`: tree t to class t %
+num_class, LightGBM's own order): a deep model with category sets
 still imports as a heap, as before, as far as a heap can hold it (depth 30,
 2^27 slots), and past that is refused with the mechanism named. An import
 carries RAW thresholds only; `threshold_bin_mapper` ranks them into bins
@@ -130,13 +131,13 @@ def _tree_block(t: int, n_leaves: int, fields: dict, shrinkage: float,
 def _node_list_blocks(ens: NodeListEnsemble) -> list[str]:
     """The `Tree=` blocks of a node list: its arrays as they are (they are
     LightGBM's), leaf values with the shrinkage applied and the base score
-    folded into tree 0."""
+    folded into round 0 (tree 0; of softmax's classes a tree each)."""
     lines: list[str] = []
     for t in range(ens.n_trees):
         L = int(ens.n_leaves[t])
         n = L - 1
         lv = ens.leaf_value[t, :L].astype(np.float64) * ens.learning_rate
-        if t == 0:
+        if t < ens.leaf_columns:    # round 0: a tree of every class
             lv = lv + ens.base_score
         lines += _tree_block(t, L, {
             "split_feature": ens.feature[t, :n],
@@ -360,8 +361,8 @@ def from_lightgbm_text(text: str) -> "TreeEnsemble | NodeListEnsemble":
     categorical nodes, optional NaN-missing default directions) PLUS
     externally-trained models with multi-category bitsets, which expand
     into equivalent one-vs-rest chains (module docstring, 'Import
-    breadth'). Trees with category nodes or several classes deeper than
-    30 levels after chain expansion overflow the heap and raise."""
+    breadth'). Trees with category nodes deeper than 30 levels after
+    chain expansion overflow the heap and raise."""
     lines = text.splitlines()
     head, i = _parse_block(lines, 0)
     n_features = int(head["max_feature_idx"]) + 1
@@ -449,13 +450,13 @@ def from_lightgbm_text(text: str) -> "TreeEnsemble | NodeListEnsemble":
     nan_routes = any((int(float(v)) >> 2) == 2 for b in trees
                      for v in b.get("decision_type", "").split())
     categories = any(b is not None for bi in tree_bits for b in bi)
-    if max_depth > HEAP_MAX_DEPTH and not (categories or C > 1):
+    if max_depth > HEAP_MAX_DEPTH and not categories:
         return _node_list_of(trees, nan_routes, n_features=n_features,
                              loss=loss, n_classes=max(C, 2))
     if max_depth > 30:
         _refuse_routes(f"from_lightgbm_text (tree depth {max_depth} after "
                        "multi-category chain expansion overflows the heap "
-                       "layout)", categories=categories, classes=C > 1)
+                       "layout)", categories=categories)
     # The heap is DENSE and its depth is GLOBAL: one k-category set deep
     # in one tree adds k-1 levels to EVERY tree's 2^(D+1)-1 node arrays.
     # Real LightGBM categorical splits routinely carry dozens of
@@ -473,8 +474,8 @@ def from_lightgbm_text(text: str) -> "TreeEnsemble | NodeListEnsemble":
             f"(widest category set: {widest} bits). Models with large "
             "categorical sets are unrepresentable here (the node-list "
             "layout, which holds deep trees as they are, does not support "
-            "category sets or several classes "
-            "yet); score them with LightGBM itself, or retrain with "
+            "category sets yet); score them with LightGBM itself, or "
+            "retrain with "
             "cat_features one-vs-rest splits"
         )
     n_nodes = 2 ** (max_depth + 1) - 1

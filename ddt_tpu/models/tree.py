@@ -627,10 +627,16 @@ class NodeListEnsemble:
     entries here and 2^21 heap slots in `TreeEnsemble`. The trainer writes
     heaps; a node list is an import (`models/lightgbm_io.py`), a conversion
     (`from_heap`) or hand-built. Ordinal splits (with or without the NaN
-    directions) only: category sets, and several classes as softmax's
-    round-major trees of one column each, have no field here, and
-    `from_lightgbm_text` / `from_heap` / the constructor refuse them by
-    name (the heap layout serves them).
+    directions) only: category sets have no field here, and
+    `from_lightgbm_text` / `from_heap` refuse them by name (the heap layout
+    serves them).
+
+    SEVERAL CLASSES (`loss` "softmax"; an import of `models/xgboost_io.py`
+    or `models/lightgbm_io.py`, `from_heap` of a multiclass heap): the
+    heap's own rule, round-major trees of ONE column each, tree t scoring
+    class `t % n_classes` with a scalar leaf value; the margins are
+    `base_score + learning_rate x` the class's sum, float32 [rows, C], and
+    their softmax the probabilities.
 
     VECTOR LEAVES, the averaged forest (`loss` "mean"; an import of
     `models/sklearn_io.py` or hand-built): `leaf_value` is [T, L, C], a
@@ -655,7 +661,7 @@ class NodeListEnsemble:
     n_features: int
     learning_rate: float
     base_score: float
-    loss: str                  # logloss | mse | mean (vector leaves)
+    loss: str                  # logloss | mse | softmax | mean (vector leaves)
     n_classes: int = 2
     has_raw_thresholds: bool = False
     # False: threshold_bin is not filled yet (an import carries raw
@@ -675,11 +681,10 @@ class NodeListEnsemble:
     cat_features = None
 
     def __post_init__(self):
-        if self.loss == "softmax":
+        if self.loss == "softmax" and self.n_classes < 2:
             raise ValueError(
-                "a node-list ensemble scores ONE output column: several "
-                "classes (softmax, round-major trees) are not supported in "
-                "this layout; the heap layout (TreeEnsemble) serves them")
+                "a softmax node list scores tree t into class t % n_classes:"
+                f" n_classes {self.n_classes} is no class count")
         if (self.loss == "mean") != self.vector_leaves or (
                 self.vector_leaves and (self.learning_rate != 1.0
                                         or self.base_score != 0.0
@@ -719,8 +724,12 @@ class NodeListEnsemble:
 
     @property
     def leaf_columns(self) -> int:
-        """Output columns a leaf holds: C of vector leaves, else 1."""
-        return int(self.leaf_value.shape[2]) if self.vector_leaves else 1
+        """Output columns of the score: C of vector leaves (a leaf holds
+        them all), the classes of softmax's round-major trees (a leaf of
+        tree t holds column t % C alone), else 1."""
+        if self.vector_leaves:
+            return int(self.leaf_value.shape[2])
+        return int(self.n_classes) if self.loss == "softmax" else 1
 
     @property
     def missing_routes(self) -> bool:
@@ -841,15 +850,26 @@ class NodeListEnsemble:
         """Content digest of what the device scoring program depends on
         (the compiled-ensemble cache key, as `TreeEnsemble.cache_token`)."""
         h = hashlib.sha1(b"node_list")
-        for a in (self.feature, self.threshold_bin, self.left_child,
-                  self.right_child, self.leaf_value, self.n_leaves):
-            h.update(np.ascontiguousarray(a).tobytes())
+        h.update(np.ascontiguousarray(self.n_leaves).tobytes())
+        # A tree's own entries, in place: trees of 30 and of 11,000 leaves
+        # share arrays as wide as the widest, and a digest of the padding
+        # was 0.85 s of every call of a 2,100-tree model (PERF.md, PR 50).
+        n = self.n_leaves.astype(np.int64)
+        for a, short in ((self.feature, 1), (self.threshold_bin, 1),
+                         (self.left_child, 1), (self.right_child, 1),
+                         (self.leaf_value, 0)) + (
+                             ((self.default_left, 1),)
+                             if self.missing_routes else ()):
+            a = np.ascontiguousarray(a)
+            for t in range(len(n)):
+                h.update(a[t, :n[t] - short])
         h.update(repr((self.learning_rate, self.base_score, self.loss,
                        self.n_features)).encode())
         if self.missing_routes:
-            h.update(np.ascontiguousarray(self.default_left).tobytes())
             h.update(repr(("missing_bin", self.n_bins)).encode())
-        if self.vector_leaves:      # [T, L, C] and [T, L * C] hash alike
+        if self.leaf_columns > 1 or self.vector_leaves:
+            # [T, L, C] and [T, L * C] hash alike; the same trees under
+            # another class count score other columns
             h.update(repr(("leaf_columns", self.leaf_columns)).encode())
         return h.hexdigest()
 
@@ -895,8 +915,9 @@ class NodeListEnsemble:
         return ~cur
 
     def predict_raw(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
-        """Raw (margin) scores [R], float32; of vector leaves the mean over
-        the trees of the reached leaves' vectors, float32 [R, C]."""
+        """Raw (margin) scores [R], float32; of softmax's round-major
+        trees the margins [R, C]; of vector leaves the mean over the trees
+        of the reached leaves' vectors, float32 [R, C]."""
         leaf = self._leaf_np(np.asarray(X), binned)
         if self.vector_leaves:
             total = np.zeros((leaf.shape[1], self.leaf_columns), np.float32)
@@ -905,6 +926,12 @@ class NodeListEnsemble:
             return total / np.float32(self.n_trees)
         vals = np.take_along_axis(self.leaf_value, leaf, axis=1)
         vals = vals * np.float32(self.learning_rate)
+        if self.loss == "softmax":      # TreeEnsemble.aggregate_leaves' rule
+            out = np.full((leaf.shape[1], self.n_classes), self.base_score,
+                          np.float32)
+            for t in range(self.n_trees):
+                out[:, t % self.n_classes] += vals[t]
+            return out
         return (self.base_score + vals.sum(axis=0)).astype(np.float32)
 
     def predict(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
@@ -920,10 +947,10 @@ class NodeListEnsemble:
     @staticmethod
     def from_heap(ens: TreeEnsemble) -> "NodeListEnsemble":
         """The same trees as a node list, nodes and leaves numbered in
-        pre-order (root = node 0), learned NaN directions with them. A
-        heap ensemble with category nodes or several classes is refused."""
-        _refuse_routes("from_heap", categories=ens.has_cat_splits,
-                       classes=ens.loss == "softmax")
+        pre-order (root = node 0), learned NaN directions and softmax's
+        round-major classes with them. A heap ensemble with category nodes
+        is refused."""
+        _refuse_routes("from_heap", categories=ens.has_cat_splits)
         routed = ens.missing_bin and ens.default_left is not None
         trees = []
         for t in range(ens.n_trees):
@@ -1065,21 +1092,16 @@ def ensemble_from_dict(
     return LAYOUTS[name].from_dict(d)
 
 
-def _refuse_routes(where: str, *, categories: bool,
-                   classes: bool) -> None:
+def _refuse_routes(where: str, *, categories: bool) -> None:
     """The one list of what a node list cannot carry, named. (Several
-    output columns as VECTOR LEAVES of an averaged forest it carries:
-    `NodeListEnsemble`.)"""
-    for has, what in (
-            (categories, "category-set (one-vs-rest / bitset) nodes"),
-            (classes, "several classes as softmax's round-major trees of "
-                      "one column each")):
-        if has:
-            raise ValueError(
-                f"{where}: the model needs the node-list layout (a tree "
-                f"too deep for the heap) and carries {what}, which the "
-                "node-list layout does not support yet; the heap layout "
-                "serves them for trees it can hold")
+    classes it carries, as softmax's round-major trees or as the vector
+    leaves of an averaged forest: `NodeListEnsemble`.)"""
+    if categories:
+        raise ValueError(
+            f"{where}: the model needs the node-list layout (a tree too "
+            "deep for the heap) and carries category-set (one-vs-rest / "
+            "bitset) nodes, which the node-list layout does not support "
+            "yet; the heap layout serves them for trees it can hold")
 
 
 def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
@@ -1479,7 +1501,14 @@ class CompiledNodeList:
                                  THE RULE of its width. A real leaf: its
                                  float32 vector as three bfloat16 pieces
                                  (`split_bfloat16`), piece p's column c in
-                                 lane p C + c. A link to the tree's
+                                 lane p C + c (of softmax's round-major
+                                 trees, `loss` "softmax": tree t's scalar
+                                 in column t % C and exact zeros in the
+                                 others, so a tree adds into its class
+                                 alone: 21
+                                 lanes of pieces at 7 classes, trees of up
+                                 to 108 sub-trees in ONE lane tile). A link
+                                 to the tree's
                                  sub-tree j from its sub-tree k: 1 in lane
                                  link0 + (j - k - 1), which the chain
                                  shifts down a lane a sub-tree.
@@ -1496,7 +1525,10 @@ class CompiledNodeList:
                                  classes, a 10-class tree of 100 sub-trees.
 
     `mean`: the score is the sum over the trees divided by their number
-    (an averaged forest), else base + learning_rate x sum.
+    (an averaged forest), else base + learning_rate x sum ([rows] of one
+    column; [rows, C] of softmax's classes, whose trees take the sub-tree
+    form whatever their size: a small tree is ONE entry, a tree of one leaf
+    an entry of no node).
     Built ONCE per model version on the host; backends keep them device-
     resident under `token` (the same cache as CompiledEnsemble's)."""
 
@@ -1518,6 +1550,8 @@ class CompiledNodeList:
     widest_tree: int = 0       # lanes the widest tree would take uncut
     select_spans: tuple = ()   # the sub-tree form: (first, stop) K-blocks
     #   of the select a lane tile
+    subtrees_max: int = 1      # the largest tree's entries ...
+    single_subtree_trees: int = 0   # ... and the trees that are ONE entry
 
     @property
     def n_classes_out(self) -> int:
@@ -1550,7 +1584,8 @@ class CompiledNodeList:
                 f"a node's threshold bin is the NaN bin {nan_bin} or "
                 "above it: with learned NaN directions thresholds lie "
                 "in the value bins")
-        if ens.vector_leaves or W > PATH_UNCUT_LANES:
+        if ens.leaf_columns > 1 or ens.vector_leaves \
+                or W > PATH_UNCUT_LANES:
             return CompiledNodeList._build_subtrees(ens, W, Fp)
         P, plen = ens.path_matrix()
         sel = np.zeros((T, Fp, W), ml_dtypes.bfloat16)
@@ -1574,7 +1609,7 @@ class CompiledNodeList:
             base_score=float(ens.base_score), loss=ens.loss,
             n_trees=T, lanes=W, deepest_leaf=int(plen.max(initial=0)),
             sel=sel, planes=planes, paths=paths, missing_bin_value=nan_bin,
-            widest_tree=W)
+            widest_tree=W, single_subtree_trees=T)
 
     @staticmethod
     def _build_subtrees(ens: NodeListEnsemble, widest: int,
@@ -1587,6 +1622,7 @@ class CompiledNodeList:
         W, C = SUBTREE_LANES, ens.leaf_columns    # the module's, as it is
         spans, cut = choose_select_spans(ens, W)
         node_parent, node_side, _, _ = ens._parents()
+        deepest = len(_levels(ens))     # the levels that hold a node
         first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
         S = int(first[-1])
         exit_lanes, link0 = exit_table_lanes(C, cut.n_subtrees)
@@ -1621,15 +1657,22 @@ class CompiledNodeList:
         e_child = child[e_node, e_side]
         e_tree = t_idx[e_node]
         leaves = np.zeros((S, W, exit_lanes), bf16)
-        values = ens.leaf_value.reshape(T, -1, C)
 
-        def put_leaves(entry, exit_lane, vectors):
-            for p, piece in enumerate(split_bfloat16(vectors)):
-                leaves[entry, exit_lane, p * C:(p + 1) * C] = piece
+        def put_leaves(entry, exit_lane, trees, leaf):
+            """Leaf `leaf[i]` of tree `trees[i]` into row `exit_lane[i]`
+            of entry `entry[i]`: a vector's pieces in all C columns, a
+            scalar's in ONE, the class's of a softmax tree (t % C; the
+            others keep their exact zeros)."""
+            values = ens.leaf_value[trees, leaf]
+            if ens.vector_leaves:
+                for p, piece in enumerate(split_bfloat16(values)):
+                    leaves[entry, exit_lane, p * C:(p + 1) * C] = piece
+                return
+            for p, piece in enumerate(split_bfloat16(values)):
+                leaves[entry, exit_lane, p * C + trees % C] = piece
 
         real = e_child < 0
-        put_leaves(e_at[real], e_lane[real],
-                   values[e_tree[real], ~e_child[real]])
+        put_leaves(e_at[real], e_lane[real], e_tree[real], ~e_child[real])
         to = first[e_tree[~real]] + cut.subtree[e_tree[~real],
                                                 e_child[~real]]
         leaves[e_at[~real], e_lane[~real],
@@ -1638,7 +1681,7 @@ class CompiledNodeList:
         # of no node (every row reaches it).
         lone = np.nonzero(ens.n_leaves == 1)[0]
         planes[first[lone], 1, 0] = 0.0
-        put_leaves(first[lone], 0, values[lone, 0])
+        put_leaves(first[lone], 0, lone, np.zeros(len(lone), np.int64))
         # An exit's path inside its sub-tree: up from the node it hangs
         # on to the sub-tree's root, all exits a step.
         # (written as bfloat16's bits: +1 is 0x3F80 and -1 0xBF80; a cast
@@ -1659,11 +1702,13 @@ class CompiledNodeList:
             token=ens.cache_token(),
             learning_rate=float(ens.learning_rate),
             base_score=float(ens.base_score), loss=ens.loss,
-            n_trees=T, lanes=W, deepest_leaf=ens.deepest_leaf,
+            n_trees=T, lanes=W, deepest_leaf=deepest,
             sel=sel, planes=planes, paths=paths.view(bf16),
             missing_bin_value=ens.missing_bin_value, leaves=leaves,
             n_subtrees=S, leaf_columns=C, mean=ens.vector_leaves,
-            widest_tree=widest, select_spans=spans)
+            widest_tree=widest, select_spans=spans,
+            subtrees_max=int(cut.n_subtrees.max()),
+            single_subtree_trees=int((cut.n_subtrees == 1).sum()))
 
 
 # ---------------------------------------------------------------------- #

@@ -696,7 +696,7 @@ def _predict_chain(sel, planes, paths, leaves, Xc, *, chain, learning_rate,
     jax.jit,
     static_argnames=("learning_rate", "base", "use_pallas",
                      "missing_routes", "n_trees", "leaf_columns", "mean",
-                     "select_spans"),
+                     "select_spans", "link"),
 )
 @op_scope("predict")
 def predict_raw_effective_paths(
@@ -715,6 +715,7 @@ def predict_raw_effective_paths(
     leaf_columns: int = 1,
     mean: bool = False,
     select_spans: tuple = (),
+    link: str = "none",
 ) -> jax.Array:
     """Raw margins [R] of a node-list ensemble from its compiled tables
     (models/tree.CompiledNodeList): the path-matrix form, by the Pallas
@@ -726,9 +727,13 @@ def predict_raw_effective_paths(
     is the one-compare program. With `leaves` the tables are the SUB-TREE
     form's (one entry a sub-tree of the `n_trees` trees, `leaf_columns`
     values a leaf) and the answer of vector leaves (`mean`) is the mean
-    over the trees, float32 [R, leaf_columns]; `select_spans`
-    (`CompiledNodeList.select_spans`) the K-blocks of the select each lane
-    tile of a sub-tree reads, which the kernel alone asks for."""
+    over the trees, float32 [R, leaf_columns]; of scalar leaves with
+    `leaf_columns` C > 1 (softmax's round-major trees) the margins
+    [R, C], and with `link` "softmax" their softmax, the class
+    probabilities, taken here on the device (stage `predict:link`);
+    `select_spans` (`CompiledNodeList.select_spans`) the K-blocks of the
+    select each lane tile of a sub-tree reads, which the kernel alone asks
+    for."""
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
         raise ValueError("the path-matrix form scores binned (integer) rows")
     from ddt_tpu.ops import predict_paths
@@ -738,8 +743,13 @@ def predict_raw_effective_paths(
         exit_lanes = leaves.shape[2]
         chain = predict_paths.chain_of(n_trees, leaf_columns, exit_lanes,
                                        select_spans)
+    if link not in ("none", "softmax") or (
+            link == "softmax" and (mean or leaf_columns < 2)):
+        raise ValueError(f"link {link!r} of {leaf_columns} leaf columns"
+                         + " of an averaged forest" * mean)
     if Xc.shape[0] == 0:
-        return jnp.full((0, leaf_columns) if mean else (0,),
+        wide = mean or leaf_columns > 1
+        return jnp.full((0, leaf_columns) if wide else (0,),
                         0.0 if mean else base, jnp.float32)
     form = dict(learning_rate=learning_rate, base=base,
                 missing_routes=missing_routes, leaves=leaves, chain=chain,
@@ -747,9 +757,14 @@ def predict_raw_effective_paths(
     if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], leaf_columns,
                           path_lanes=planes.shape[2],
                           path_exit_lanes=exit_lanes):
-        return predict_paths.predict_paths_pallas(sel, planes, paths, Xc,
-                                                  **form)
-    return _predict_paths(sel, planes, paths, Xc, **form)
+        out = predict_paths.predict_paths_pallas(sel, planes, paths, Xc,
+                                                 **form)
+    else:
+        out = _predict_paths(sel, planes, paths, Xc, **form)
+    if link == "softmax":
+        with traced_scope("predict:link"):
+            out = jax.nn.softmax(out, axis=1)
+    return out
 
 
 # Rows a step of the jax.numpy oblivious form takes at most (the float32

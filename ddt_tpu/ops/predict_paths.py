@@ -409,6 +409,12 @@ class PathPlan(typing.NamedTuple):
     #   table's (W/128 x its lane tiles: 2 at 256 lanes where the class
     #   pieces and the chain share ONE tile, `Chain.shared`; 4 or more
     #   where each has tiles of its own; 0 without a chain)
+    # What the model's cut looks like beside its mean (the backend fills
+    # them from models/tree.CompiledNodeList), and the link function the
+    # program applies to the margins on the device ("none": the caller's).
+    subtrees_per_tree_max: int = 1      # the largest tree's table entries
+    single_subtree_trees: int = 0       # trees that are ONE entry
+    link: str = "none"
 
     @property
     def blocks(self) -> int:
@@ -429,14 +435,16 @@ class PathPlan(typing.NamedTuple):
                 "node_list": self.node_list,
                 "path_mxu_tiles_per_tree": self.path_mxu_tiles_per_tree,
                 "select_k_blocks": self.select_k_blocks,
+                "select_nodes_per_lane": self.select_nodes_per_lane,
                 **{k: getattr(self, k) for k in CHAIN_COUNTS}}
 
 
 # What the `ddt:predict:ensemble` span says of a node-list model's plan, in
 # the order it prints (docs/OBSERVABILITY.md); `cli predict` repeats all
 # but `table_bytes` in `phases_ms`, as it does for the heap kernel's.
-CHAIN_COUNTS = ("subtrees_per_tree", "subtree_lanes", "leaf_columns",
-                "chain_mxu_tiles_per_tree", "class_dot_passes",
+CHAIN_COUNTS = ("subtrees_per_tree", "subtrees_per_tree_max",
+                "single_subtree_trees", "subtree_lanes", "leaf_columns",
+                "link", "chain_mxu_tiles_per_tree", "class_dot_passes",
                 "select_mxu_tiles", "exit_mxu_tiles")
 SPAN_COUNTS = ("node_list", "nodes_per_tree", "leaves_per_tree",
                "deepest_leaf", "path_mxu_tiles_per_tree", "trees_per_step",
@@ -781,7 +789,9 @@ def predict_paths_pallas(
     With `leaves` and `chain` the tables' entries are SUB-TREES and the
     answer is the sum over the trees of the reached leaves' vectors divided
     by the trees, float32 [R, C] (`mean`: vector leaves), or of scalar
-    leaves the margin [R] as ever."""
+    leaves the margin [R] as ever, or (`chain.leaf_columns` C > 1 without
+    `mean`: softmax's round-major trees, a tree's leaves in its class's
+    lanes alone) the margins [R, C]."""
     if interpret is None:
         interpret = device.platform() != "tpu"
     T, _, lanes = planes.shape
@@ -882,7 +892,8 @@ def fold_leaf_pieces(acc, chain: Chain, learning_rate, base, mean: bool):
     """[R, CL] sums of the leaf values' bfloat16 pieces, piece p's column c
     in lane p C + c, to the model's answer float32 [R, C]: the pieces added
     smallest first, then the mean over the trees or the margin's scale and
-    shift."""
+    shift (the sum, no division: [R] of one column, [R, C] of softmax's
+    classes)."""
     c = chain.leaf_columns
     with traced_scope("predict:accumulate"):
         total = acc[:, (_LEAF_PIECES - 1) * c:_LEAF_PIECES * c]
@@ -890,4 +901,5 @@ def fold_leaf_pieces(acc, chain: Chain, learning_rate, base, mean: bool):
             total = total + acc[:, p * c:(p + 1) * c]
         if mean:
             return total / jnp.float32(chain.n_trees)
-        return base + learning_rate * total[:, 0]   # scalar leaves: a margin
+        margins = base + learning_rate * total
+        return margins[:, 0] if c == 1 else margins

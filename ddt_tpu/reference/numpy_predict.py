@@ -100,6 +100,34 @@ none of it routing:
   the groups are added, and the scale is applied once at the end: float32
   rounding of a sum in another order, not bitwise.
   `tests/test_oblivious.py` holds `api.predict` to it.
+
+AN XGBOOST MODEL, from the library's own JSON (`predict_xgboost_json`): a
+float64 walk of the arrays `Booster.save_model("m.json")` writes, on RAW
+float rows. It imports nothing of `models/` and knows nothing of node
+lists, heaps, sub-trees, bins or lanes. Tree i is `left_children`,
+`right_children` (-1: a leaf), `split_indices`, `split_conditions` and
+`default_left`, the root node 0: at an internal node n a row whose value x
+of column `split_indices[n]` is NaN goes LEFT where `default_left[n]` and
+else RIGHT; any other row goes LEFT where `x < split_conditions[n]`
+(STRICT: the library's test) and else RIGHT; a leaf n scores
+`split_conditions[n]` (`eta` is in it) into class `tree_info[i]`. Margins
+[rows, classes] = the base margin + the class's sum (`binary:logistic`:
+logit(`base_score`), one column; the others `base_score` itself); the answer
+is their softmax (`multi:softprob`), their sigmoid (`binary:logistic`) or
+the margin (`reg:squarederror`). Where this departs from the library, and
+the import (`models/xgboost_io.py`) from both:
+
+- The library casts a row's values to float32 and compares float32; so does
+  this walk (x and the condition are float32 values, compared as float64:
+  the same answer). The import stores `nextafter(condition, -inf)` and
+  tests `<=`: the same answer for every float32 x, which
+  `tests/test_xgboost.py` holds ON the thresholds, a float32 either side of
+  them, at +-0.0 and at +-inf.
+- The library sums a row's margins in float32 in tree order; this walk sums
+  float64 (the tolerance studies' yardstick); the device sums float32 in
+  the kernel's order. Dyadic leaf values: all three bit-equal.
+- `base_score` 0.5 of `multi:softprob` is added to every class alike and
+  the softmax forgets it; it is kept so that margins compare.
 """
 
 from __future__ import annotations
@@ -204,3 +232,52 @@ def predict_raw_oblivious(ens, Xb: np.ndarray,
         total += ens.leaf_value[t].astype(dtype)[
             leaf_of_rows_oblivious(ens, t, Xb)]
     return dtype(ens.bias) + dtype(ens.scale) * total
+
+
+# objective -> (margin of base_score, link), as the library defines them
+_XGB_OBJECTIVES = {
+    "binary:logistic": (lambda p: np.log(p / (1.0 - p)),
+                        lambda m: 1.0 / (1.0 + np.exp(-m))),
+    "reg:squarederror": (float, lambda m: m),
+    "multi:softprob": (float, None),        # the softmax, below
+}
+
+
+def predict_xgboost_json(model: dict, X: np.ndarray,
+                         raw: bool = False) -> np.ndarray:
+    """An XGBoost `gbtree` model's answer over RAW float rows `X`, float64:
+    class probabilities [rows, classes] (`multi:softprob`), probabilities
+    [rows] (`binary:logistic`) or values [rows] (`reg:squarederror`);
+    `raw`: the margins. `model`: the dict the library's JSON parses to.
+    The module docstring has the semantics."""
+    learner = model["learner"]
+    param = learner["learner_model_param"]
+    to_margin, link = _XGB_OBJECTIVES[learner["objective"]["name"]]
+    C = max(int(param.get("num_class", 0)), 1)
+    booster = learner["gradient_booster"]["model"]
+    X = np.asarray(X, np.float32).astype(np.float64)
+    rows = np.arange(X.shape[0])
+    out = np.full((X.shape[0], C), to_margin(float(param["base_score"])),
+                  np.float64)
+    for tree, cls in zip(booster["trees"], booster["tree_info"]):
+        left = np.asarray(tree["left_children"], np.int64)
+        right = np.asarray(tree["right_children"], np.int64)
+        column = np.asarray(tree["split_indices"], np.int64)
+        cond = np.asarray(tree["split_conditions"], np.float32).astype(
+            np.float64)
+        nan_left = np.asarray(tree["default_left"], bool)
+        node = np.zeros(X.shape[0], np.int64)
+        while True:
+            inner = left[node] >= 0
+            if not inner.any():
+                break
+            x = X[rows, column[node]]
+            go_left = np.where(np.isnan(x), nan_left[node], x < cond[node])
+            node = np.where(inner, np.where(go_left, left[node],
+                                            right[node]), node)
+        out[:, int(cls)] += cond[node]
+    if raw or link is not None:
+        out = out if C > 1 else out[:, 0]
+        return out if raw else link(out)
+    e = np.exp(out - out.max(axis=1, keepdims=True))
+    return e / e.sum(axis=1, keepdims=True)

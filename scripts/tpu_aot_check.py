@@ -63,6 +63,9 @@ BOSCH = dict(chunk_rows=262_144, features=968)
 # scikit-learn's MNIST forest's scoring chunk (benchmark config
 # mnist-rf-100t-full): 784 pixel columns, TPUDevice.predict_chunk_rows.
 FOREST = dict(chunk_rows=299_593, features=784)
+# XGBoost's deep Covertype model's call (benchmark config
+# covtype-xgb-softprob-d16): the set's own rows, one chunk.
+XGB = dict(rows=581_012, features=54)
 # CatBoost's Epsilon model's scoring chunk (benchmark config
 # epsilon-catboost-8000t-d6): 2000 dense columns, 8000 trees of depth 6.
 EPSILON = dict(chunk_rows=131_072, features=2000, n_trees=8000, depth=6)
@@ -225,7 +228,8 @@ def _paths_case(rows, features, n_trees, n_leaves, missing=False):
 
 
 def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
-                 most_subtrees=0, mean=True, select_spans=()):
+                 most_subtrees=0, mean=True, select_spans=(),
+                 packed=False):
     """The SUB-TREE form of the path-matrix kernel (ops/predict_paths.py:
     the chain and the class dot) over the compiled tables' SHAPES
     (models/tree.CompiledNodeList: 1.28 GB at the MNIST forest's), the rows
@@ -235,7 +239,10 @@ def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
     model's largest tree (0: every tree has its share), which with the
     classes decides the width of the exits' table, as the build does
     (models/tree.exit_table_lanes: ONE lane tile where the pieces and the
-    chain fit it)."""
+    chain fit it). `mean` False: scalar leaves, the margins [rows, classes]
+    of softmax's round-major trees. `packed`: the select as a backend hands
+    it over where it answers two nodes a lane (`pack_select`'s shape: up to
+    64 columns), and not as the model compiles it."""
     def build():
         import jax.numpy as jnp
         import numpy as np
@@ -259,8 +266,9 @@ def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
                 sel, planes, paths, Xc, learning_rate=1.0, base=0.0,
                 interpret=False, leaves=leaves, chain=chain, mean=mean)
 
+        sel = predict_paths._select_shape(lanes, features, 2 if packed else 1)
         return fn, [
-            ((n_subtrees, -(-features // 16) * 16, lanes), jnp.bfloat16),
+            ((n_subtrees, *sel), jnp.bfloat16),
             ((n_subtrees, 8, lanes), jnp.float32),
             ((n_subtrees, lanes, lanes), jnp.bfloat16),
             ((n_subtrees, lanes, chain.exit_lanes), jnp.bfloat16),
@@ -478,6 +486,18 @@ def kernel_cases() -> list:
                    _forest_case(4_999, hf, 12, 12, 128)),
         KernelCase("forest/129f/3x200subtrees/c10/128lanes", True,
                    _forest_case(4_999, 129, 3, 600, 10, lanes=128)),
+        # Softmax's round-major trees in the sub-tree form (PR 50): scalar
+        # leaves in their class's lanes, the margins [rows, C] out; trees
+        # of one sub-tree and of fifty in one table, the PACKED select
+        # under the chain (54 columns: two nodes a result lane, 7 MXU
+        # weight tiles a sub-tree: 1 + 4 + 2), at the XGBoost Covertype
+        # cell's whole-set call of 581,012 rows and 7 classes, and at 3.
+        KernelCase("paths/54f/softmax7/chain", True,
+                   _forest_case(XGB["rows"], XGB["features"], 21, 300, 7,
+                                most_subtrees=50, mean=False, packed=True)),
+        KernelCase("paths/54f/softmax3/chain", True,
+                   _forest_case(4_999, XGB["features"], 12, 100, 3,
+                                most_subtrees=30, mean=False, packed=True)),
         # The oblivious form: CatBoost's Epsilon model's chunk (63 groups,
         # 16 K-blocks), the depths and widths at the dispatch rule's edges
         # (depth 10 at 28 columns fits, depth 7 at 2000), one K-block with

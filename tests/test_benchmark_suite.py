@@ -19,7 +19,7 @@ BENCHMARK = os.path.join(
 MODULES = ("test_call_anatomy", "test_correct", "test_correct_mc",
            "test_correct_routed", "test_correct_leafwise",
            "test_correct_leafwise_nan", "test_correct_oblivious",
-           "test_correct_forest",
+           "test_correct_forest", "test_correct_xgb",
            "test_opcount",
            "test_tracefile", "test_device_stage_ms")
 

@@ -828,10 +828,9 @@ def test_what_stays_refused_is_refused_by_name():
         NodeListEnsemble(**{**parts, "loss": "softmax"})
     with pytest.raises(ValueError, match="vector leaves"):
         ens.to_lightgbm_text()
-    with pytest.raises(ValueError, match="round-major trees"):
-        tree._refuse_routes("from_heap", categories=False, classes=True)
+    tree._refuse_routes("from_heap", categories=False)
     with pytest.raises(ValueError, match="category-set"):
-        tree._refuse_routes("from_heap", categories=True, classes=False)
+        tree._refuse_routes("from_heap", categories=True)
     with pytest.raises(ValueError, match="binned"):
         api.predict(ens, rows_of(72, 8).astype(np.float32), cfg=TrainConfig(
             backend="tpu", n_bins=BINS))

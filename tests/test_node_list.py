@@ -289,7 +289,8 @@ def test_a_shallow_import_stays_a_heap():
 
 def test_category_and_multiclass_node_lists_are_refused_by_name():
     """What a node list still cannot carry is refused with the mechanism
-    named; learned NaN directions are NOT among them any more."""
+    named; learned NaN directions are NOT among them any more, and since
+    PR 50 neither are several classes (tests/test_xgboost.py)."""
     heap = empty_ensemble(2, 2, 4, 0.1, 0.0, "logloss", missing_bin=True,
                           n_bins=255)
     heap.is_leaf[:, 0] = True
@@ -300,11 +301,13 @@ def test_category_and_multiclass_node_lists_are_refused_by_name():
         NodeListEnsemble.from_heap(heap)
     heap = empty_ensemble(3, 2, 4, 0.1, 0.0, "softmax", n_classes=3)
     heap.is_leaf[:, 0] = True
-    with pytest.raises(ValueError, match="several classes"):
-        NodeListEnsemble.from_heap(heap)
+    assert NodeListEnsemble.from_heap(heap).leaf_columns == 3
     src = hand_built()
-    with pytest.raises(ValueError, match="several classes"):
-        dataclasses.replace(src, loss="softmax", n_classes=3)
+    assert dataclasses.replace(src, loss="softmax",
+                               n_classes=3).predict_raw(
+        np.zeros((2, src.n_features), np.uint8), binned=True).shape == (2, 3)
+    with pytest.raises(ValueError, match="no class count"):
+        dataclasses.replace(src, loss="softmax", n_classes=1)
     # directions without the bin count they route by
     with pytest.raises(ValueError, match="learned NaN directions need"):
         dataclasses.replace(src, missing_bin=True,

@@ -330,15 +330,19 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
         table_bytes=3 * (16 * 128 * 2 + 8 * 128 * 4 + 128 * 128 * 2) * served,
         select_k_blocks=1, missing_routes=0, row_operand_bytes=1,
         select_nodes_per_lane=1,           # 128 lanes: one tile already
-        # one uncut path matrix a tree: a sub-tree of its own, no chain
-        subtrees_per_tree=1.0, subtree_lanes=128, leaf_columns=1,
-        chain_mxu_tiles_per_tree=0, class_dot_passes=0,
+        # one uncut path matrix a tree: a sub-tree of its own, no chain,
+        # and no link function taken by the program
+        subtrees_per_tree=1.0, subtrees_per_tree_max=1,
+        single_subtree_trees=3, subtree_lanes=128, leaf_columns=1,
+        link="none", chain_mxu_tiles_per_tree=0, class_dot_passes=0,
         select_mxu_tiles=1,                # one K-block x one lane tile
         exit_mxu_tiles=0)                  # no exits' table: no chain
     assert predict_paths.CHAIN_COUNTS[-2:] == ("select_mxu_tiles",
                                                "exit_mxu_tiles")
     assert counts["bytes"] == counts["table_bytes"] or not served
     assert root["counts"]["select_k_blocks"] == 1
+    assert root["counts"]["select_nodes_per_lane"] == 1
+    assert root["counts"]["link"] == "none"
 
     # blocks of trees stream once a row tile
     plan = predict_paths.path_plan(500, 256, 28, 17)
@@ -522,7 +526,7 @@ def test_a_compiled_ensemble_skips_the_token_span():
 
 STAGES = {"predict:widen", "predict:tables", "predict:traverse",
           "predict:traverse_paths", "predict:traverse_oblivious",
-          "predict:accumulate"}
+          "predict:accumulate", "predict:link"}
 
 
 def _small_oblivious(seed):
@@ -539,6 +543,16 @@ def _small_forest(seed):
 
     return random_node_list(np.random.default_rng(seed), 3, (300, 500), 6,
                             n_bins=31, leaf_columns=3)
+
+
+def _small_softmax_node_list(seed):
+    """6 round-major trees of 3 classes, 300 to 500 leaves each with a
+    scalar a leaf: the sub-tree form, its link taken by the program."""
+    from ddt_tpu.models.tree import random_node_list
+
+    return random_node_list(np.random.default_rng(seed), 6, (300, 500), 6,
+                            n_bins=31, learning_rate=0.5, base_score=0.25,
+                            loss="softmax", n_classes=3)
 
 
 def _routed(ens, seed):
@@ -566,6 +580,9 @@ STAGE_MODELS = {
     # an averaged forest: the sub-tree form (the chain and the class dot)
     "forest": (lambda: _small_forest(3106),
                "jit_predict_raw_effective_paths"),
+    # softmax's round-major trees as a node list: the link on the device
+    "softmax-node-list": (lambda: _small_softmax_node_list(3107),
+                          "jit_predict_raw_effective_paths"),
 }
 STAGE_CASES = [(m, impl) for m in STAGE_MODELS
                for impl in ("pallas", "onehot")]
@@ -600,9 +617,11 @@ def test_every_instruction_of_a_scoring_program_has_a_stage(
     monkeypatch.setattr(predict_ops, "_PATHS_ROW_CHUNK", 128)
     Xb = np.random.default_rng(8).integers(0, 31, size=(600, 6),
                                            dtype=np.uint8)
-    scores = be.predict_raw(ens, Xb)
-    np.testing.assert_allclose(scores, ens.predict_raw(Xb, binned=True),
-                               rtol=2e-4, atol=2e-5)
+    link = be.links_on_device(ens)
+    scores = be.predict_raw(ens, Xb, link=link)
+    np.testing.assert_allclose(
+        scores, (ens.predict if link else ens.predict_raw)(Xb, binned=True),
+        rtol=2e-4, atol=2e-5)
 
     stages = an.device_stages()
     held = stages[program]
@@ -613,9 +632,11 @@ def test_every_instruction_of_a_scoring_program_has_a_stage(
     if impl == "pallas":
         traverse = {"node-list": "predict:traverse_paths",
                     "forest": "predict:traverse_paths",
+                    "softmax-node-list": "predict:traverse_paths",
                     "oblivious": "predict:traverse_oblivious"}.get(
                         model, traverse)
     assert {traverse, "predict:accumulate"} <= seen
+    assert ("predict:link" in seen) == link     # the softmax, on the device
     # The kernels take the uint8 chunk as it is and widen a tile in VMEM:
     # no instruction of their programs is the widening (the heap kernel
     # since PR 36, the path kernel since PR 37, the oblivious kernel). The

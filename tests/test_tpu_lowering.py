@@ -35,10 +35,14 @@ DEFAULT_CASES = [c for c in aot.kernel_cases() if c.default]
 
 
 HEAP_CASES = [c for c in DEFAULT_CASES if c.name.startswith("predict/")]
-PATH_CASES = [c for c in DEFAULT_CASES if c.name.startswith("paths/")]
+# (a `paths/.../chain` case is the sub-tree form over scalar leaves: its
+# interface is the forest's)
+PATH_CASES = [c for c in DEFAULT_CASES if c.name.startswith("paths/")
+              and not c.name.endswith("/chain")]
 OBLIVIOUS_CASES = [c for c in DEFAULT_CASES
                    if c.name.startswith("oblivious/")]
-FOREST_CASES = [c for c in DEFAULT_CASES if c.name.startswith("forest/")]
+FOREST_CASES = [c for c in DEFAULT_CASES if c.name.startswith("forest/")
+                or c.name.endswith("/chain")]
 
 
 @functools.lru_cache(maxsize=None)
@@ -173,7 +177,9 @@ def test_path_kernel_crosses_hbm_at_the_datas_width(case):
         assert f"tensor<{padded}x" not in text and f"x{padded}x" not in text
 
 
-@pytest.mark.parametrize("case", PATH_CASES, ids=lambda c: c.name)
+@pytest.mark.parametrize(
+    "case", PATH_CASES + [c for c in FOREST_CASES if c.name.endswith("/chain")],
+    ids=lambda c: c.name)
 def test_path_kernel_takes_the_select_the_rule_packs(case):
     """Up to 64 columns and from 256 node lanes on, the kernel's select
     table is `pack_select`'s, [K2, W/2] over two copies of the features
@@ -216,12 +222,17 @@ FOREST_EXITS = {
     "forest/28f/12x1subtree/c85": (384, 256, (), 6, 11),
     "forest/28f/12x1subtree/c128": (512, 384, (), 8, 13),
     "forest/129f/3x200subtrees/c10/128lanes": (384, 128, (), 3, 6),
+    # softmax's round-major trees (PR 50): 21 and 9 lanes of pieces, chains
+    # of 50 and 30, the packed select: 1 + 4 + 2 tiles a sub-tree
+    "paths/54f/softmax7/chain": (128, 128, (), 2, 7),
+    "paths/54f/softmax3/chain": (128, 128, (), 2, 7),
 }
 
 
 def test_every_forest_case_names_its_exits():
     assert sorted(FOREST_EXITS) == sorted(
-        c.name for c in aot.kernel_cases() if c.name.startswith("forest/"))
+        c.name for c in aot.kernel_cases() if c.name.startswith("forest/")
+        or c.name.endswith("/chain"))
 
 
 @pytest.mark.parametrize("case", FOREST_CASES, ids=lambda c: c.name)
@@ -342,6 +353,8 @@ def test_case_table_covers_the_default_dispatch():
                    # sub-trees with two activity tiles
                    "forest/784f/100x4779x10", "forest/28f/12x1subtree/c1",
                    "forest/28f/12x1subtree/c85", "forest/129f/3x200subtrees",
+                   # softmax's round-major trees under the packed select
+                   "paths/54f/softmax7/chain", "paths/54f/softmax3/chain",
                    # the oblivious form: the Epsilon model's chunk, the
                    # dispatch rule's edges, one and two K-blocks
                    "oblivious/epsilon/8000x6", "oblivious/28f/300x10",
