@@ -484,7 +484,8 @@ def score_forest(overrides: dict, rows: int) -> None:
     the uncut trees (reference/numpy_predict.predict_proba_node_list,
     float64), and holds the compiled kernel and its jax.numpy twin bit-equal
     on dyadic leaf vectors over 8 trees (every sum and the mean exact), at
-    784 columns so that the spans engage there too."""
+    784 columns so that the spans engage there too, and at 54 and 100
+    columns, where the sub-trees are HALVED (`resolve_mxu_tiles` 2: PR 51)."""
     from ddt_tpu import api
     from ddt_tpu.config import TrainConfig
     from ddt_tpu.models.tree import random_node_list
@@ -547,6 +548,28 @@ def score_forest(overrides: dict, rows: int) -> None:
         kernel, walk.astype(np.float32)), "dyadic forest not bit-equal"
     say("forest grid: kernel, twin and walk bit-equal on 8 dyadic trees x "
         f"4,999 rows x {F} columns x 3 classes, select spans {spans}")
+    # One K-block: HALVED sub-trees (two halves of 128 lanes that share
+    # their spine, the path table's diagonal blocks alone: 2 tiles of
+    # resolve where 4), under the packed select and under the unpacked one.
+    for few in (54, 100):
+        halved = random_node_list(rng, 8, (300, 900), few, bins, dyadic=True,
+                                  leaf_columns=3)
+        assert halved.compile().halved, few
+        Xh = rng.integers(0, bins, size=(4_999, few), dtype=np.uint8)
+        kernel = api.predict(halved, Xh, binned=True, raw=True, cfg=cfg)
+        said = root_spans("predict")[-1]["counts"]
+        assert (said["resolve_mxu_tiles"], said["select_nodes_per_lane"]) == (
+            2, 2 if few <= 64 else 1), said
+        assert said["spine_copies_per_subtree"] > 0, said
+        twin = api.predict(halved, Xh, binned=True, raw=True, cfg=TrainConfig(
+            n_bins=bins, backend="tpu", predict_impl="onehot"))
+        walk = numpy_predict.predict_proba_node_list(halved, Xh)
+        assert np.array_equal(kernel, twin) and np.array_equal(
+            kernel, walk.astype(np.float32)), f"halved {few}f not bit-equal"
+        say(f"forest grid: kernel, twin and walk bit-equal on 8 dyadic trees "
+            f"x 4,999 rows x {few} columns in HALVED sub-trees, "
+            f"path_mxu_tiles_per_tree={said['path_mxu_tiles_per_tree']} "
+            f"spine_copies_per_subtree={said['spine_copies_per_subtree']}")
 
 
 def score_oblivious(overrides: dict, rows: int) -> None:

@@ -2036,7 +2036,7 @@ class TPUDevice(DeviceBackend):
         classes = ce.leaf_columns
         chain = predict_paths.chain_of(
             ce.n_trees, classes, ce.leaves.shape[2],
-            ce.select_spans) if ce.chained else None
+            ce.select_spans, ce.paths.shape) if ce.chained else None
         plan = predict_paths.path_plan(
             ce.n_subtrees or ce.n_trees, ce.lanes, ens.n_features,
             ce.deepest_leaf,
@@ -2048,7 +2048,9 @@ class TPUDevice(DeviceBackend):
             chain=chain, widest_tree=ce.widest_tree)._replace(
                 subtrees_per_tree_max=ce.subtrees_max,
                 single_subtree_trees=ce.single_subtree_trees,
-                link=ce.loss if link else "none")
+                link=ce.loss if link else "none",
+                spine_copies_per_subtree=round(
+                    ce.spine_copies / max(ce.n_subtrees, 1), 2))
         # What every chunk's program would otherwise make of the tables is
         # made here, once a model: the select that answers two nodes a lane
         # with its shifted thresholds (`pack_select`), and the trees that

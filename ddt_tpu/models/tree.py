@@ -1183,7 +1183,10 @@ class SubtreeCut(typing.NamedTuple):
     #   its tree by the pre-order of the roots (-1: no such node)
     lane: np.ndarray           # int32 [T, N] its number in the sub-tree: the
     #   pre-order (the root 0), or under `spans` the pre-order inside the
-    #   128-lane tile whose K-blocks hold the node's column
+    #   128-lane tile whose K-blocks hold the node's column, or HALVED the
+    #   pre-order inside its half (the second half's behind the copies)
+    copy: np.ndarray | None = None  # HALVED alone: int32 [T, N] the lane of
+    #   the node's COPY in its sub-tree's second half (128..; 0: it has none)
 
 
 def _levels(ens: NodeListEnsemble) -> list:
@@ -1220,10 +1223,11 @@ def dense_spans(n_features: int, lanes: int) -> tuple:
 
 
 def _subtree_roots(ens: NodeListEnsemble, levels: list, lanes: int,
-                   spans: tuple) -> tuple:
+                   spans: tuple, halved: bool = False) -> tuple:
     """`cut_subtrees`'s bottom-up walk: (the nodes that root a sub-tree,
     bool [T, N]; the nodes that only the first / only the second lane tile
-    may hold under `spans`, bool [2, T, N])."""
+    may hold under `spans`, bool [2, T, N]). `halved`: a part's nodes and
+    the nodes on its longest path are at most `lanes` - 1 together."""
     live = ens.live_nodes
     block = ens.feature // PATH_LANES
     only = np.stack([live & (block < spans[-1][0]),
@@ -1235,15 +1239,24 @@ def _subtree_roots(ens: NodeListEnsemble, levels: list, lanes: int,
             or spans[-1][0] > spans[0][1]:
         raise ValueError(f"the select's spans {spans} leave a K-block of "
                          f"{ens.n_features} columns to no lane tile")
+    if halved and (only.any() or lanes != 2 * PATH_LANES):
+        raise ValueError("a halved sub-tree is two lane tiles under dense "
+                         f"spans; got {spans} at {lanes} lanes")
     # a node's remainder: its part's nodes, and those only a tile may hold
-    weight = np.concatenate([live[None], only]).astype(np.int64)
+    weight = np.concatenate([live[None], only]).astype(np.int32)
     caps = np.array([lanes - 1, PATH_LANES, PATH_LANES])[:, None]
     root = np.zeros(live.shape, bool)
     root[ens.n_leaves > 1, 0] = True
+    # (halved) the nodes on the longest path down a node's remainder
+    tall = live.astype(np.int32) if halved else None
     for t, n in reversed(levels):       # children before parents
         w, kids = _kids(ens, t, n, weight)          # [3, k, 2]
+        below = _kids(ens, t, n, tall)[0] if halved else 0      # [k, 2]
         for _ in range(2):
             over = weight[:, t, n] + w.sum(axis=2) > caps
+            if halved:
+                over[0] |= (weight[0, t, n] + w[0].sum(axis=1) + tall[t, n]
+                            + below.max(axis=1) > lanes - 1)
             hit = np.nonzero(over.any(axis=0))[0]
             if not len(hit):
                 break
@@ -1255,7 +1268,11 @@ def _subtree_roots(ens: NodeListEnsemble, levels: list, lanes: int,
                 kids[hit, 1] > kids[hit, 0]))).astype(np.int64)
             root[t[hit], kids[hit, side]] = True
             w[:, hit, side] = 0
+            if halved:
+                below[hit, side] = 0
         weight[:, t, n] += w.sum(axis=2)
+        if halved:
+            tall[t, n] += below.max(axis=1)
     return root, only
 
 
@@ -1268,7 +1285,8 @@ def _rank(key: np.ndarray, mask: np.ndarray | None = None) -> np.ndarray:
 
 
 def _numbered(ens: NodeListEnsemble, levels: list, node_parent: np.ndarray,
-              root: np.ndarray, only: np.ndarray, lanes: int) -> SubtreeCut:
+              root: np.ndarray, only: np.ndarray, lanes: int,
+              halved: bool = False) -> SubtreeCut:
     """`cut_subtrees`'s numbers, from the roots of the sub-trees."""
     T, N = root.shape
     live = ens.live_nodes
@@ -1304,6 +1322,9 @@ def _numbered(ens: NodeListEnsemble, levels: list, node_parent: np.ndarray,
         + subtree[t_idx, n_idx]
     by_part = np.argsort(part, kind="stable")
     part = part[by_part]
+    if halved:
+        return SubtreeCut(n_subtrees, root, subtree, *_halves(
+            levels, node_parent, root, part, t_idx[by_part], n_idx[by_part]))
     one0, one1 = (o[t_idx, n_idx][by_part] for o in only)
     free = ~(one0 | one1)
     room = (PATH_LANES if only.any() else lanes) \
@@ -1317,8 +1338,49 @@ def _numbered(ens: NodeListEnsemble, levels: list, node_parent: np.ndarray,
     return SubtreeCut(n_subtrees, root, subtree, lane)
 
 
+def _halves(levels: list, node_parent: np.ndarray, root: np.ndarray,
+            part: np.ndarray, t_idx: np.ndarray, n_idx: np.ndarray) -> tuple:
+    """`SubtreeCut`'s `lane` and `copy` of HALVED sub-trees (`cut_subtrees`):
+    `part` (sorted) the part of node (`t_idx`, `n_idx`), a part's nodes in
+    pre-order."""
+    depth = np.zeros(root.shape, np.int64)      # nodes above it in its part
+    for t, n in levels[1:]:
+        depth[t, n] = np.where(root[t, n], 0, depth[t, node_parent[t, n]] + 1)
+    pos, deep = _rank(part), depth[t_idx, n_idx]
+    starts = np.nonzero(pos == 0)[0]            # a part's first node
+    nodes = np.diff(np.append(starts, len(part)))
+    seg = np.cumsum(pos == 0) - 1
+    # k: the second half begins at the part's node k, behind copies of the
+    # `deep` nodes above it, and hangs one exit a node and one more a tree
+    # of its nodes (at most `deep` + 1 of them) in lanes of its own. The
+    # fewest copies; of those the fullest first half. A part whose exits
+    # fit one half has no second one.
+    fits = (pos >= 1) & (pos <= PATH_LANES) & (
+        nodes[seg] - pos + deep + 1 <= PATH_LANES)
+    never = 2 * PATH_LANES * PATH_LANES
+    best = np.minimum.reduceat(np.where(
+        fits, deep * (PATH_LANES + 1) + PATH_LANES - pos, never), starts)
+    one = nodes < PATH_LANES
+    if (~one & (best == never)).any():
+        raise ValueError("a sub-tree's nodes fit no two halves of "
+                         f"{PATH_LANES} lanes: the cut did not hold the "
+                         "halved bound")
+    k = np.where(one, nodes, PATH_LANES - best % (PATH_LANES + 1))[seg]
+    spine = np.where(one, 0, best // (PATH_LANES + 1))[seg]
+    lane = np.zeros(root.shape, np.int32)
+    lane[t_idx, n_idx] = np.where(pos < k, pos, PATH_LANES + spine + pos - k)
+    copy = np.zeros(root.shape, np.int32)
+    t, n = t_idx[pos == k], n_idx[pos == k]     # node k, no root: k >= 1
+    while len(t):
+        n = node_parent[t, n]
+        copy[t, n] = PATH_LANES + depth[t, n]
+        t, n = t[~root[t, n]], n[~root[t, n]]
+    return lane, copy
+
+
 def cut_subtrees(ens: NodeListEnsemble, lanes: int,
-                 spans: tuple | None = None) -> SubtreeCut:
+                 spans: tuple | None = None,
+                 halved: bool = False) -> SubtreeCut:
     """Every tree cut into connected SUB-TREES of at most `lanes` - 1
     internal nodes, so of at most `lanes` EXITS (an exit is a child that
     is a leaf, or a link: a child that roots another sub-tree). A
@@ -1350,18 +1412,19 @@ def cut_subtrees(ens: NodeListEnsemble, lanes: int,
     node_parent = ens._parents()[0]     # in range, one parent each
     levels = _levels(ens)
     spans = spans or dense_spans(ens.n_features, lanes)
-    root, only = _subtree_roots(ens, levels, lanes, spans)
-    return _numbered(ens, levels, node_parent, root, only, lanes)
+    root, only = _subtree_roots(ens, levels, lanes, spans, halved)
+    return _numbered(ens, levels, node_parent, root, only, lanes, halved)
 
 
-def subtree_mxu_tiles(spans: tuple, lanes: int, exit_lanes: int) -> int:
+def subtree_mxu_tiles(spans: tuple, lanes: int, exit_lanes: int,
+                      halved: bool = False) -> int:
     """MXU weight tiles a sub-tree of `lanes` lanes costs a tile of rows
     (ops/predict_paths.path_mxu_tiles_per_tree, the unpacked select): the
-    select's, a tile a K-block of every lane tile's span, the resolve's,
-    the exits' table's."""
+    select's, a tile a K-block of every lane tile's span, the resolve's
+    (`halved`: the diagonal blocks alone), the exits' table's."""
     w = lanes // PATH_LANES
-    return (sum(stop - start for start, stop in spans) + w * w
-            + w * (exit_lanes // PATH_LANES))
+    return (sum(stop - start for start, stop in spans)
+            + (w if halved else w * w) + w * (exit_lanes // PATH_LANES))
 
 
 def exit_table_lanes(leaf_columns: int, n_subtrees: np.ndarray) -> tuple:
@@ -1398,7 +1461,8 @@ def exit_table_lanes(leaf_columns: int, n_subtrees: np.ndarray) -> tuple:
     return class_lanes + act_lanes, class_lanes
 
 
-def choose_select_spans(ens: NodeListEnsemble, lanes: int) -> tuple:
+def choose_select_spans(ens: NodeListEnsemble, lanes: int,
+                        node_parent: np.ndarray | None = None) -> tuple:
     """(`CompiledNodeList.select_spans`, the model's cut under them): which
     K-blocks of the feature select each 128-lane tile of a sub-tree reads,
     from what the model shows. A node's one K row lies in ONE K-block, and
@@ -1409,33 +1473,57 @@ def choose_select_spans(ens: NodeListEnsemble, lanes: int) -> tuple:
 
     The candidates split the blocks at the one in which the cumulative
     share of the model's internal nodes passes one half: that block read by
-    both tiles, by the first alone, by the second alone; and the dense
-    spans. Taken is the one whose cut asks the fewest MXU weight tiles a
-    tree (`subtree_mxu_tiles` x the sub-trees, the exits' table as wide as
-    that cut's model gets it, `exit_table_lanes`), the dense one where none
-    asks fewer: one K-block (F <= 128), sub-trees of another width than two
-    tiles, a model of no internal node."""
-    node_parent = ens._parents()[0]
+    both tiles, by the first alone, by the second alone; the dense spans;
+    and the dense spans over HALVED sub-trees (`cut_subtrees`: the lanes
+    ordered by the tree's shape, not by columns, so that the path resolve
+    is 2 weight tiles where 4; the cut says so by its `copy`). Taken is the
+    one whose cut asks the fewest MXU weight tiles a tree
+    (`subtree_mxu_tiles` x the sub-trees, the exits' table as wide as that
+    cut's model gets it, `exit_table_lanes`), the earlier one where two ask
+    alike: the halved layout wherever the select is one K-block (F <= 128:
+    6 tiles a sub-tree against 8), the blocks' spans where the columns
+    split well (the MNIST forest: 13 against the halves' 18). Sub-trees of
+    another width than two tiles, a model of no internal node: the dense
+    spans. `node_parent`: `ens._parents()`'s, where the caller has it."""
+    if node_parent is None:
+        node_parent = ens._parents()[0]
     levels = _levels(ens)
     dense = dense_spans(ens.n_features, lanes)
     blocks = dense[0][1]
-    candidates = [dense]
-    if blocks > 1 and lanes == 2 * PATH_LANES and ens.n_splits:
-        share = np.cumsum(np.bincount(
-            ens.feature[ens.live_nodes] // PATH_LANES, minlength=blocks))
-        h = int(np.searchsorted(share, share[-1] / 2, side="right"))
-        candidates += [((0, stop), (start, blocks)) for stop, start in (
-            (h + 1, h), (h + 1, h + 1), (h, h)) if stop > 0 and start < blocks]
+    candidates = [(dense, False)]
+    if lanes == 2 * PATH_LANES and ens.n_splits:
+        if blocks > 1:
+            share = np.cumsum(np.bincount(
+                ens.feature[ens.live_nodes] // PATH_LANES, minlength=blocks))
+            h = int(np.searchsorted(share, share[-1] / 2, side="right"))
+            candidates += [
+                (((0, stop), (start, blocks)), False) for stop, start in (
+                    (h + 1, h), (h + 1, h + 1), (h, h))
+                if stop > 0 and start < blocks]
+        candidates.append((dense, True))
+    # No cut makes fewer parts than a tree's nodes over a part's most, nor
+    # an exits' table under one tile: a candidate that cannot ask fewer
+    # tiles than the best cut so far is not cut (a cut is a walk of every
+    # node). The cheapest bound first; of equal counts the earlier listed.
+    fewest = int(np.maximum(-(-(ens.n_leaves.astype(np.int64) - 1)
+                              // (lanes - 1)), 1).sum())
+    bound = [fewest * subtree_mxu_tiles(spans, lanes, PATH_LANES, halved)
+             for spans, halved in candidates]
     best = None
-    for spans in candidates:
-        root, only = _subtree_roots(ens, levels, lanes, spans)
+    for i in sorted(range(len(candidates)), key=lambda i: (bound[i], i)):
+        if best is not None and (bound[i], i) > best[:2]:
+            continue
+        spans, halved = candidates[i]
+        root, only = _subtree_roots(ens, levels, lanes, spans, halved)
         per_tree = np.maximum(root.sum(axis=1), 1)
         tiles = int(per_tree.sum()) * subtree_mxu_tiles(
-            spans, lanes, exit_table_lanes(ens.leaf_columns, per_tree)[0])
-        if best is None or tiles < best[0]:
-            best = (tiles, spans, root, only)
-    _, spans, root, only = best
-    return spans, _numbered(ens, levels, node_parent, root, only, lanes)
+            spans, lanes, exit_table_lanes(ens.leaf_columns, per_tree)[0],
+            halved)
+        if best is None or (tiles, i) < best[:2]:
+            best = (tiles, i, spans, halved, root, only)
+    *_, spans, halved, root, only = best
+    return spans, _numbered(ens, levels, node_parent, root, only, lanes,
+                            halved)
 
 
 # bfloat16 pieces a float32 leaf value is held in (`split_bfloat16`; the
@@ -1493,7 +1581,19 @@ class CompiledNodeList:
     are those of no node (threshold +BIG, no path), wherever they lie;
     `planes` rows 0 and 3, `paths`' rows and the exits' order follow the
     lanes. Dense spans (every tile reads every block: F <= 128, or a model
-    the split buys nothing) number the nodes in pre-order, 0.. with no gap.
+    the split buys nothing) number the nodes in pre-order, 0.. with no gap,
+    or, HALVED (`cut_subtrees`; `choose_select_spans` takes it where it asks
+    the fewest tiles: every model of one K-block), as two halves of 128
+    lanes that share their spine: the second half from lane 128, copies of
+    the nodes above its first node and then its own; a copy has its node's
+    K row, threshold and NaN bound and hangs no exit; the exits of a half
+    lie in the half's own 128 exit lanes (`planes` rows 1, `leaves`' rows);
+    and `paths` is the two diagonal blocks alone, side by side:
+
+        paths  [S, W/2, W] bf16  P[n, l] of exit l's OWN half: row n is node
+                                 lane n for l < 128 and node lane 128 + n
+                                 for l >= 128 (`halved`; `spine_copies` the
+                                 lanes that hold a copy, over all entries)
 
         planes row 4             1 in the entry that roots a tree, else 0
                                  (row 2 is not read)
@@ -1552,6 +1652,7 @@ class CompiledNodeList:
     #   of the select a lane tile
     subtrees_max: int = 1      # the largest tree's entries ...
     single_subtree_trees: int = 0   # ... and the trees that are ONE entry
+    spine_copies: int = 0      # halved: the lanes that hold a node's copy
 
     @property
     def n_classes_out(self) -> int:
@@ -1560,6 +1661,11 @@ class CompiledNodeList:
     @property
     def chained(self) -> bool:
         return self.leaves is not None
+
+    @property
+    def halved(self) -> bool:
+        """Whether `paths` holds the two diagonal blocks alone."""
+        return self.paths.shape[1] < self.paths.shape[2]
 
     def arrays(self) -> tuple:
         return (self.sel, self.planes, self.paths) + (
@@ -1620,8 +1726,9 @@ class CompiledNodeList:
         bf16 = ml_dtypes.bfloat16
         T, N = ens.feature.shape
         W, C = SUBTREE_LANES, ens.leaf_columns    # the module's, as it is
-        spans, cut = choose_select_spans(ens, W)
         node_parent, node_side, _, _ = ens._parents()
+        spans, cut = choose_select_spans(ens, W, node_parent)
+        halved = cut.copy is not None
         deepest = len(_levels(ens))     # the levels that hold a node
         first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
         S = int(first[-1])
@@ -1629,19 +1736,29 @@ class CompiledNodeList:
         t_idx, n_idx = np.nonzero(ens.live_nodes)
         at = first[t_idx] + cut.subtree[t_idx, n_idx]      # the entry
         ln = cut.lane[t_idx, n_idx]
+        # the lanes that ask a node's question: its own, and (halved) its
+        # copy's in the second half, which hangs no exit
+        asks = [(t_idx, n_idx, at, ln)]
+        if halved:
+            ct, cn = np.nonzero(cut.copy)
+            asks.append((ct, cn, first[ct] + cut.subtree[ct, cn],
+                         cut.copy[ct, cn]))
         sel = np.zeros((S, Fp, W), bf16)
-        sel[at, ens.feature[t_idx, n_idx], ln] = 1.0
         planes = np.zeros((S, 8, W), np.float32)
         planes[:, 0, :] = 2.0 ** 30
-        planes[at, 0, ln] = ens.threshold_bin[t_idx, n_idx]
         planes[:, 1, :] = -1.0
         planes[first[:-1], 4, :] = 1.0
         if ens.missing_routes:
             planes[:, 3, :] = 2.0 ** 30
-            left = ens.default_left[t_idx, n_idx]
-            planes[at[left], 3, ln[left]] = ens.missing_bin_value
+        for t, n, entry, lane in asks:
+            sel[entry, ens.feature[t, n], lane] = 1.0
+            planes[entry, 0, lane] = ens.threshold_bin[t, n]
+            if ens.missing_routes:
+                left = ens.default_left[t, n]
+                planes[entry[left], 3, lane[left]] = ens.missing_bin_value
         # The exits: every child that is a leaf or roots a sub-tree, in
-        # the order of (entry, the node's lane, left before right).
+        # the order of (entry, the node's lane, left before right); halved,
+        # a run a half: the second half's nodes' exits from lane 128.
         child = np.stack([ens.left_child[t_idx, n_idx],
                           ens.right_child[t_idx, n_idx]], 1).astype(np.int64)
         linked = (child >= 0) & cut.root[t_idx[:, None],
@@ -1652,8 +1769,10 @@ class CompiledNodeList:
         e_node, e_side = np.nonzero(is_exit[order])
         e_node = order[e_node]
         e_at = at[e_node]
-        starts = np.searchsorted(e_at, np.arange(S))
-        e_lane = np.arange(len(e_at)) - starts[e_at]
+        e_half = halved & (ln[e_node] >= PATH_LANES)
+        run = 2 * e_at + e_half
+        e_lane = np.arange(len(e_at)) - np.searchsorted(
+            run, np.arange(2 * S))[run] + PATH_LANES * e_half
         e_child = child[e_node, e_side]
         e_tree = t_idx[e_node]
         leaves = np.zeros((S, W, exit_lanes), bf16)
@@ -1686,14 +1805,25 @@ class CompiledNodeList:
         # on to the sub-tree's root, all exits a step.
         # (written as bfloat16's bits: +1 is 0x3F80 and -1 0xBF80; a cast
         # of 132M int8 entries to bfloat16 took 10 s of a 12 s build)
-        paths = np.zeros((S, W, W), np.uint16)
+        # Halved, the two diagonal [128, 128] blocks alone, side by side:
+        # row n of an exit's column is node lane n of the exit's own half
+        # (a second half's exit reads its first-half ancestors' COPIES).
+        paths = np.zeros((S, W // 2 if halved else W, W), np.uint16)
         plen = np.zeros((S, W), np.float32)
         tt, cur, ex = e_tree, n_idx[e_node], np.arange(len(e_at))
         sign = np.where(e_side == 0, -1, 1).astype(np.int8)
         while len(tt):
-            paths[e_at[ex], cut.lane[tt, cur], e_lane[ex]] = np.where(
+            lane = cut.lane[tt, cur]
+            if halved:
+                lane = np.where(e_half[ex] & (lane < PATH_LANES),
+                                cut.copy[tt, cur], lane)
+                if ((lane >= PATH_LANES) != e_half[ex]).any():
+                    raise ValueError("halved sub-trees: an exit's path "
+                                     "leaves the exit's half")
+                lane = lane % PATH_LANES
+            paths[e_at[ex], lane, e_lane[ex]] = np.where(
                 sign > 0, 0x3F80, 0xBF80)
-            np.add.at(plen, (e_at[ex], e_lane[ex]), 1.0)
+            plen[e_at[ex], e_lane[ex]] += 1.0     # (an exit once a step)
             up = ~cut.root[tt, cur]
             sign, cur = node_side[tt, cur], node_parent[tt, cur]
             tt, cur, ex, sign = tt[up], cur[up], ex[up], sign[up]
@@ -1708,7 +1838,8 @@ class CompiledNodeList:
             n_subtrees=S, leaf_columns=C, mean=ens.vector_leaves,
             widest_tree=widest, select_spans=spans,
             subtrees_max=int(cut.n_subtrees.max()),
-            single_subtree_trees=int((cut.n_subtrees == 1).sum()))
+            single_subtree_trees=int((cut.n_subtrees == 1).sum()),
+            spine_copies=int(np.count_nonzero(cut.copy)) if halved else 0)
 
 
 # ---------------------------------------------------------------------- #

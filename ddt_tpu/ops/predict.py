@@ -82,6 +82,11 @@ Where the 3 C lanes of pieces and a tree's chain fit ONE tile of 128 lanes
 right behind V_k's pieces, the sums and the activity are 128 lanes each
 that take the same a_k (e_k @ table), and "lane 0" above is lane 3 C.
 The forest's answer is the sum over its trees divided by their number.
+Where a sub-tree is numbered as two halves of 128 lanes that share their
+spine (models/tree.cut_subtrees, `halved`: every exit's path in the exit's
+own half, the second half holding copies of the nodes above its first), P_k
+is block-diagonal, the table holds its two diagonal blocks alone and m_k is
+two products of half the size, side by side.
 
 A FIFTH entry serves the third layout, the OBLIVIOUS ensemble
 (models/tree.ObliviousEnsemble: CatBoost's symmetric trees, D splits and
@@ -660,9 +665,16 @@ def _predict_chain(sel, planes, paths, leaves, Xc, *, chain, learning_rate,
                 right = v > pl_[None, 0, :]
                 if missing_routes:
                     right &= v < pl_[None, 3, :]
-                m = jnp.dot(jnp.where(right, 1.0, -1.0),
-                            p.astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+                s, p = jnp.where(right, 1.0, -1.0), p.astype(jnp.float32)
+                if chain.halved:    # the diagonal blocks, side by side
+                    h = W // 2
+                    m = jnp.concatenate(
+                        [jnp.dot(s[:, j * h:(j + 1) * h],
+                                 p[:, j * h:(j + 1) * h],
+                                 preferred_element_type=jnp.float32)
+                         for j in range(2)], axis=1)
+                else:
+                    m = jnp.dot(s, p, preferred_element_type=jnp.float32)
                 e = jnp.where(m == pl_[None, 1, :], 1.0, 0.0)
                 y = jnp.dot(e, lv.astype(jnp.float32),
                             preferred_element_type=jnp.float32)
@@ -742,7 +754,7 @@ def predict_raw_effective_paths(
     if leaves is not None:
         exit_lanes = leaves.shape[2]
         chain = predict_paths.chain_of(n_trees, leaf_columns, exit_lanes,
-                                       select_spans)
+                                       select_spans, paths.shape)
     if link not in ("none", "softmax") or (
             link == "softmax" and (mean or leaf_columns < 2)):
         raise ValueError(f"link {link!r} of {leaf_columns} leaf columns"

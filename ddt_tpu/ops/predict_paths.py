@@ -26,7 +26,11 @@ tile), 275 a tree of the MNIST forest's 21.12 sub-trees; 15 and 317 where
 the class pieces and the chain's links have a lane tile each (4 + 2 + 2:
 until PR 49 every model, since then those the one tile does not fit); with
 every lane tile reading every K-block (dense spans) 7 x 2 of the select, 22
-a sub-tree and 443 a tree of 20.15 before PR 48.
+a sub-tree and 443 a tree of 20.15 before PR 48; and W/128 of the resolve
+where (W/128)^2, the diagonal tiles alone, where the sub-trees are HALVED
+(`resolve_mxu_tiles`; TWO HALVES, below): 5 a sub-tree of the XGBoost
+Covertype model (1 packed select, 2 resolve, 2 exits; 7 before PR 51), 33 a
+tree of its 6.55.
 
 TWO NODES A RESULT LANE (`select_nodes_per_lane`, P: from F and W, nothing
 else; the heap kernel's `nodes_per_tile` in this form). The select contracts
@@ -137,6 +141,34 @@ h = 0:
     act  = roll(act, -1) + a * y[:, CL:]     the chain: A activity lanes
 
 that program and its tables are what they were, instruction for instruction.
+
+TWO HALVES THAT SHARE THEIR SPINE (`Chain.halved`, `resolve_mxu_tiles` W/128;
+PR 51). P[n, l] is non-zero only where node n lies on exit l's path, 12 of
+256 rows a column in a depth-16 model, and which lane a node takes is the
+host's to say. So the host numbers a sub-tree as two halves of 128 lanes
+(models/tree.cut_subtrees, `halved`): the first k nodes of its pre-order in
+lanes 0.., with the exits that hang on them in exit lanes 0..; from lane 128
+COPIES of node k's ancestors (every later node's ancestors among the first k
+are among them: the spine; a copy asks its node's question, the same K row
+and threshold, and hangs no exit) and then the later nodes, with their exits
+from exit lane 128. Every exit's whole path lies in the exit's own half, both
+off-diagonal [128, 128] blocks of P are zeros, the table holds the two
+diagonal ones side by side ([S, 128, 256] bf16, 64 KB an entry where 128:
+172,032 B an entry of the XGBoost model where 237,568) and the resolve is
+
+    m = [ s[:, :128] @ P[:, :128]  |  s[:, 128:] @ P[:, 128:] ]
+
+two [rows, 128] x [128, 128] products where one [rows, 256] x [256, 256]:
+the same integers, the same exit for every (row, sub-tree). Under the packed
+select the halves are the low bytes' lanes and the high bytes', as they come.
+The price is the copies' lanes and a cut that holds one bound more (a part's
+nodes and the nodes on its longest path at most 255 together: 5% more
+sub-trees for the XGBoost model, 1.9 copies a sub-tree); which models take it
+is `models/tree.choose_select_spans`'s to say, by the fewest weight tiles a
+tree: those whose select is one K-block (F <= 128), and none whose lanes are
+ordered by K-blocks (the MNIST forest's select would be asked whole in both
+halves: 18 tiles where 13). The kernel reads the layout back from the path
+table's shape; a model that is not halved traces the program it did.
 
 Either way `act` lives in a VMEM scratch [TILE_ROWS, A] over the block axis
 (a tree's sub-trees may lie in several blocks; the table's first entry roots
@@ -302,25 +334,40 @@ def select_mxu_tiles(lanes: int, n_features: int,
                                            // nodes_per_lane)
 
 
+def resolve_mxu_tiles(lanes: int, halved: bool = False) -> int:
+    """MXU weight tiles of a tree's (a sub-tree's) path resolve: (W/128)^2,
+    or of a HALVED sub-tree (`Chain.halved`) the W/128 diagonal ones."""
+    w = lanes // _LANES
+    return w if halved else w * w
+
+
 def path_mxu_tiles_per_tree(lanes: int, n_features: int,
                             nodes_per_lane: int | None = None,
                             exit_lanes: int = 0,
-                            select_spans: tuple = ()) -> int:
+                            select_spans: tuple = (),
+                            halved: bool = False) -> int:
     """MXU weight tiles (results [rows, 128]) a tree costs a tile of rows:
-    the feature select's (`select_mxu_tiles`) and the path resolve's,
-    (W/128)^2; of a SUB-TREE besides the exits' table's, W/128 x
+    the feature select's (`select_mxu_tiles`) and the path resolve's
+    (`resolve_mxu_tiles`); of a SUB-TREE besides the exits' table's, W/128 x
     `exit_lanes`/128 (the class dot and the chain)."""
-    w = lanes // _LANES
     return (select_mxu_tiles(lanes, n_features, nodes_per_lane, select_spans)
-            + w * w + w * (exit_lanes // _LANES))
+            + resolve_mxu_tiles(lanes, halved)
+            + (lanes // _LANES) * (exit_lanes // _LANES))
+
+
+def _resolve_rows(lanes: int, halved: bool) -> int:
+    """Rows of a tree's path table: its node lanes, or HALVED one half's
+    (the two diagonal blocks side by side)."""
+    return lanes // 2 if halved else lanes
 
 
 def _tree_bytes(lanes: int, n_features: int, nodes_per_lane: int = 1,
-                exit_lanes: int = 0) -> int:
+                exit_lanes: int = 0, halved: bool = False) -> int:
     """HBM bytes of one tree's (one sub-tree's) tables: sel bf16, planes
     f32, P bf16, the exits' bf16."""
     k, w = _select_shape(lanes, n_features, nodes_per_lane)
-    return (k * w * 2 + 8 * lanes * 4 + lanes * lanes * 2
+    return (k * w * 2 + 8 * lanes * 4
+            + _resolve_rows(lanes, halved) * lanes * 2
             + lanes * exit_lanes * 2)
 
 
@@ -339,6 +386,9 @@ class Chain(typing.NamedTuple):
     shared: bool = False       # the exits' table is ONE lane tile, the
     #   links behind the pieces in the class sums' own 128 lanes
     #   (models/tree.exit_table_lanes: THE RULE); else [V | L], CL + A wide
+    halved: bool = False       # a sub-tree is two halves of 128 lanes that
+    #   share their spine (models/tree.cut_subtrees): `paths` holds the two
+    #   diagonal blocks alone, [S, 128, 256]
 
     @property
     def exit_lanes(self) -> int:
@@ -360,12 +410,14 @@ _LEAF_PIECES = 3
 
 
 def chain_of(n_trees: int, leaf_columns: int, exit_lanes: int,
-             select_spans: tuple = ()) -> Chain:
+             select_spans: tuple = (), path_shape: tuple = ()) -> Chain:
     """The Chain of a compiled model whose exits' table is `exit_lanes`
     wide and whose lanes are ordered under `select_spans`. The table's own
     width says which layout models/tree.exit_table_lanes (THE RULE) gave
     it: no wider than the class lanes, so the links share their tile (one
-    tile: 3 C < 128); else the activity lanes are what lies behind them."""
+    tile: 3 C < 128); else the activity lanes are what lies behind them.
+    `path_shape`, the path table's [S, rows, W], says in the same way
+    whether the sub-trees are HALVED: fewer rows than lanes."""
     cl = _lane_pad(_LEAF_PIECES * leaf_columns)
     shared = exit_lanes == cl == _LANES
     if not shared and exit_lanes <= cl:
@@ -373,7 +425,8 @@ def chain_of(n_trees: int, leaf_columns: int, exit_lanes: int,
             f"an exits' table of {exit_lanes} lanes holds no chain beside "
             f"the {cl} class lanes of {leaf_columns} leaf columns")
     return Chain(n_trees, leaf_columns, cl, _LANES if shared
-                 else exit_lanes - cl, select_spans, shared)
+                 else exit_lanes - cl, select_spans, shared,
+                 bool(path_shape) and path_shape[-2] < path_shape[-1])
 
 
 class PathPlan(typing.NamedTuple):
@@ -415,6 +468,13 @@ class PathPlan(typing.NamedTuple):
     subtrees_per_tree_max: int = 1      # the largest tree's table entries
     single_subtree_trees: int = 0       # trees that are ONE entry
     link: str = "none"
+    resolve_mxu_tiles: int = 0          # of a tree's (a sub-tree's) tiles:
+    #   the path resolve's, (W/128)^2: 4 at 256 lanes; 2 where the sub-trees
+    #   are HALVED (`Chain.halved`: the path matrix's diagonal blocks alone)
+    spine_copies_per_subtree: float = 0.0   # ... and what that costs in
+    #   lanes: the copies of a first half's nodes a second half holds, on
+    #   average (the backend fills it; `subtrees_per_tree` says what the
+    #   halves' bound cost the cut)
 
     @property
     def blocks(self) -> int:
@@ -445,7 +505,8 @@ class PathPlan(typing.NamedTuple):
 CHAIN_COUNTS = ("subtrees_per_tree", "subtrees_per_tree_max",
                 "single_subtree_trees", "subtree_lanes", "leaf_columns",
                 "link", "chain_mxu_tiles_per_tree", "class_dot_passes",
-                "select_mxu_tiles", "exit_mxu_tiles")
+                "select_mxu_tiles", "exit_mxu_tiles", "resolve_mxu_tiles",
+                "spine_copies_per_subtree")
 SPAN_COUNTS = ("node_list", "nodes_per_tree", "leaves_per_tree",
                "deepest_leaf", "path_mxu_tiles_per_tree", "trees_per_step",
                "table_blocks", "table_bytes", "select_k_blocks",
@@ -482,15 +543,17 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     pack = select_nodes_per_lane(n_features, lanes) if served else 1
     exit_lanes = chain.exit_lanes if chain else 0
     spans = chain.select_spans if chain else ()
+    halved = bool(chain and chain.halved)
     tiles = path_mxu_tiles_per_tree(lanes, n_features, pack, exit_lanes,
-                                    spans)
+                                    spans, halved)
     row_bytes = row_operand_dtype(row_dtype).itemsize
     said = dict(select_k_blocks=select_k_blocks(n_features),
                 missing_routes=int(missing_routes),
                 row_operand_bytes=row_bytes, select_nodes_per_lane=pack,
                 subtree_lanes=lanes,
                 select_mxu_tiles=select_mxu_tiles(lanes, n_features, pack,
-                                                  spans))
+                                                  spans),
+                resolve_mxu_tiles=resolve_mxu_tiles(lanes, halved))
     widest_tree = widest_tree or lanes
     if chain:
         per = n_trees / chain.n_trees
@@ -508,7 +571,7 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     fp, sel_lanes = _select_shape(lanes, n_features, pack)
     per_tree = (_window_bytes(fp, sel_lanes) // 2      # bf16: half of f32
                 + _window_bytes(8, lanes)
-                + _window_bytes(lanes, lanes) // 2
+                + _window_bytes(_resolve_rows(lanes, halved), lanes) // 2
                 + _window_bytes(lanes, exit_lanes) // 2)
     # the scores' window: [1, TILE_ROWS], or the chain's [TILE_ROWS, CL]
     # with the activity scratch, a sub-tile's copies of both, and y and
@@ -537,7 +600,7 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     return PathPlan(1, widest_tree, widest_tree, deepest_leaf, tiles, g,
                     blocks,
                     blocks * g * _tree_bytes(lanes, n_features, pack,
-                                             exit_lanes),
+                                             exit_lanes, halved),
                     TILE_ROWS, **said)
 
 
@@ -622,6 +685,8 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
     fp, lanes = sel_ref.shape[1], planes_ref.shape[2]
     wp = sel_ref.shape[2]
     packed = wp < lanes
+    half = paths_ref.shape[1]
+    halved = half < lanes
     sub_rows = _sub_rows(2 if packed else 1)
     stride = _copy_stride(n_feat)
     ones_in_tile = bool(_mantissa_rows(n_feat))
@@ -698,11 +763,26 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
                 if missing_routes:
                     right[0] &= low < rows[3:4, :wp].astype(jnp.int32)
                     right[1] &= high < rows[3:4, wp:]
-            s = jnp.concatenate([jnp.where(r, 1.0, -1.0) for r in right],
-                                axis=1).astype(jnp.bfloat16)
-            m = jax.lax.dot_general(
-                s, paths_ref[g], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)       # [S, W]
+            if halved:
+                # Every exit's path lies in its own half of the lanes (the
+                # second half holds copies of the spine): the off-diagonal
+                # blocks of P are zeros nobody stored, and the resolve is a
+                # product a half.
+                if not packed:      # (packed, `right` is the halves' already)
+                    right = [right[0][:, :half], right[0][:, half:]]
+                m = jnp.concatenate([jax.lax.dot_general(
+                    jnp.where(r, 1.0, -1.0).astype(jnp.bfloat16),
+                    paths_ref[g, :, j * half:(j + 1) * half],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                    for j, r in enumerate(right)], axis=1)    # [S, W]
+            else:
+                s = jnp.concatenate(
+                    [jnp.where(r, 1.0, -1.0) for r in right],
+                    axis=1).astype(jnp.bfloat16)
+                m = jax.lax.dot_general(
+                    s, paths_ref[g], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)       # [S, W]
             if acc is None:
                 return jnp.where(m == rows[1:2, :], 1.0, 0.0).astype(
                     jnp.bfloat16)
@@ -770,7 +850,7 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
 def predict_paths_pallas(
     sel: jax.Array,            # bf16 [T, Fp, W], or pack_select's
     planes: jax.Array,         # f32 [T, 8, W]      (then its planes too)
-    paths: jax.Array,          # bf16 [T, W, W]
+    paths: jax.Array,          # bf16 [T, W, W]; halved [S, W/2, W]
     Xc: jax.Array,             # [R, F] integer bins, uint8 as api.predict's
     *,
     learning_rate,
@@ -833,6 +913,7 @@ def predict_paths_pallas(
                             memory_space=pltpu.VMEM)
 
     exit_lanes = chain.exit_lanes if chain else 0
+    resolve_rows = paths.shape[1]
     spans = chain.select_spans if chain and sel_lanes == lanes else ()
     # the select's weights the MXU is asked for: K rows x lanes
     select = fp * sel_lanes if not spans else sum(
@@ -840,7 +921,7 @@ def predict_paths_pallas(
         for start, stop in spans)
     cost = pl.CostEstimate(
         flops=2 * n_tiles * tile_rows * n_blocks * g * (
-            select + lanes * lanes + lanes * exit_lanes),
+            select + resolve_rows * lanes + lanes * exit_lanes),
         bytes_accessed=n_tiles * (
             tile_rows * (F * row_dtype.itemsize
                          + 4 * (chain.class_lanes if chain else 1))
@@ -870,7 +951,7 @@ def predict_paths_pallas(
             in_specs=[pl.BlockSpec((tile_rows, F), lambda i, b: (i, 0),
                                    memory_space=pltpu.VMEM),
                       table_block(fp, sel_lanes), table_block(8, lanes),
-                      table_block(lanes, lanes)]
+                      table_block(resolve_rows, lanes)]
             + [table_block(lanes, exit_lanes)] * bool(chain),
             out_specs=pl.BlockSpec(out_block, out_index,
                                    memory_space=pltpu.VMEM),

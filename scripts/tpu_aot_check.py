@@ -229,7 +229,7 @@ def _paths_case(rows, features, n_trees, n_leaves, missing=False):
 
 def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
                  most_subtrees=0, mean=True, select_spans=(),
-                 packed=False):
+                 packed=False, halved=False):
     """The SUB-TREE form of the path-matrix kernel (ops/predict_paths.py:
     the chain and the class dot) over the compiled tables' SHAPES
     (models/tree.CompiledNodeList: 1.28 GB at the MNIST forest's), the rows
@@ -242,7 +242,10 @@ def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
     chain fit it). `mean` False: scalar leaves, the margins [rows, classes]
     of softmax's round-major trees. `packed`: the select as a backend hands
     it over where it answers two nodes a lane (`pack_select`'s shape: up to
-    64 columns), and not as the model compiles it."""
+    64 columns), and not as the model compiles it. `halved`: the path table
+    of sub-trees numbered as two halves that share their spine, the two
+    diagonal blocks alone (`models/tree.cut_subtrees`: what
+    `choose_select_spans` takes for a model of one K-block)."""
     def build():
         import jax.numpy as jnp
         import numpy as np
@@ -257,9 +260,11 @@ def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
                         + -(-(int(n_subtrees.max()) + 1) // 128) * 128), 0
 
         most = most_subtrees or -(-n_subtrees // n_trees)
+        path_rows = lanes // 2 if halved else lanes
+        # (`--tree` may name a checkout from before the halves)
         chain = predict_paths.chain_of(
             n_trees, classes, exit_table_lanes(classes, np.array([most]))[0],
-            select_spans)
+            select_spans, *([(n_subtrees, path_rows, lanes)] * halved))
 
         def fn(sel, planes, paths, leaves, Xc):
             return predict_paths.predict_paths_pallas(
@@ -270,7 +275,7 @@ def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
         return fn, [
             ((n_subtrees, *sel), jnp.bfloat16),
             ((n_subtrees, 8, lanes), jnp.float32),
-            ((n_subtrees, lanes, lanes), jnp.bfloat16),
+            ((n_subtrees, path_rows, lanes), jnp.bfloat16),
             ((n_subtrees, lanes, chain.exit_lanes), jnp.bfloat16),
             ((rows, features), jnp.uint8)]
 
@@ -489,15 +494,23 @@ def kernel_cases() -> list:
         # Softmax's round-major trees in the sub-tree form (PR 50): scalar
         # leaves in their class's lanes, the margins [rows, C] out; trees
         # of one sub-tree and of fifty in one table, the PACKED select
-        # under the chain (54 columns: two nodes a result lane, 7 MXU
-        # weight tiles a sub-tree: 1 + 4 + 2), at the XGBoost Covertype
-        # cell's whole-set call of 581,012 rows and 7 classes, and at 3.
+        # under the chain (54 columns: two nodes a result lane), at the
+        # XGBoost Covertype cell's whole-set call of 581,012 rows and 7
+        # classes with the HALVED resolve that cell's model gets since PR 51
+        # (the path table's two diagonal blocks: 5 MXU weight tiles a
+        # sub-tree, 1 + 2 + 2), at 3 classes with the whole path matrix (7:
+        # 1 + 4 + 2, the layout every model had before), and the halves
+        # under the unpacked select (100 columns: 2 + 2 + 2).
         KernelCase("paths/54f/softmax7/chain", True,
                    _forest_case(XGB["rows"], XGB["features"], 21, 300, 7,
-                                most_subtrees=50, mean=False, packed=True)),
+                                most_subtrees=50, mean=False, packed=True,
+                                halved=True)),
         KernelCase("paths/54f/softmax3/chain", True,
                    _forest_case(4_999, XGB["features"], 12, 100, 3,
                                 most_subtrees=30, mean=False, packed=True)),
+        KernelCase("paths/100f/softmax7/halved/chain", True,
+                   _forest_case(4_999, 100, 21, 300, 7, most_subtrees=50,
+                                mean=False, halved=True)),
         # The oblivious form: CatBoost's Epsilon model's chunk (63 groups,
         # 16 K-blocks), the depths and widths at the dispatch rule's edges
         # (depth 10 at 28 columns fits, depth 7 at 2000), one K-block with

@@ -165,7 +165,7 @@ def test_the_cut_under_the_spans_bound(name):
     assert cut.n_subtrees.sum() >= free.n_subtrees.sum()
 
 
-def test_the_spans_cost_the_mnist_forests_cut_few_parts():
+def test_the_spans_cost_the_mnist_forests_cut_few_parts(monkeypatch):
     """12 trees of the benchmark's forest (its drawing, its forest seed;
     the rehearse forest is the first 4 of them, 80 parts where 75: two
     parts are 2.7% there): the rule splits 784 columns at the middle and
@@ -206,33 +206,56 @@ def test_the_spans_cost_the_mnist_forests_cut_few_parts():
     assert tree.subtree_mxu_tiles(spans, 256, 128) == 13
     assert cut.n_subtrees.sum() * 13 < 0.75 * free.n_subtrees.sum() \
         * tree.subtree_mxu_tiles(SPANS["dense"], 256, 128)
+    # ... and fewer than the halves' 18 (the select asked whole in both
+    # tiles), so the rule leaves this forest its tables, byte for byte:
+    # their SHA-1 as the commit before the halves built them (daa6000)
+    import hashlib
+
+    monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
+    ce = ens.compile()
+    assert not ce.halved and ce.select_spans == spans
+    assert cut.copy is None and ce.spine_copies == 0
+    h = hashlib.sha1()
+    for a in ce.arrays():
+        h.update(np.ascontiguousarray(a).view(np.uint8).tobytes())
+    assert h.hexdigest()[:16] == "ff7d82dee22cae9a"
 
 
-@pytest.mark.parametrize("features,spans,tiles", [
-    (784, ((0, 3), (3, 7)), 7),    # uniform columns: split at the middle
-    (200, ((0, 1), (1, 2)), 2),    # two K-blocks, 64% of nodes in the first
-    (129, ((0, 1), (0, 2)), 3),    # one column of 129 past the first block
-    (100, ((0, 1), (0, 1)), 2),    # one K-block: dense
+@pytest.mark.parametrize("features,spans,tiles,resolve", [
+    (784, ((0, 3), (3, 7)), 7, 4),  # uniform columns: split at the middle
+    (400, ((0, 2), (2, 4)), 4, 4),  # four K-blocks: the halves would ask 8
+    (300, ((0, 1), (1, 3)), 3, 4),  # three: 3 + 4 against the halves' 6 + 2
+    # two K-blocks: the spans' 2 + 4 and the halves' 4 + 2 ask alike a
+    # sub-tree, and the halves' one bound cuts fewer parts than a bound a
+    # lane tile (24 against 25)
+    (200, ((0, 2), (0, 2)), 4, 2),
+    (129, ((0, 2), (0, 2)), 4, 2),  # one column of 129 past the first block
+    (100, ((0, 1), (0, 1)), 2, 2),  # one K-block: the halves, 2 + 2 for 2 + 4
 ])
 def test_the_spans_are_read_from_the_model(monkeypatch, features, spans,
-                                           tiles):
+                                           tiles, resolve):
     monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
     ens = forest(62, 4, (600, 1500), 3, features=features)
     ce = ens.compile()
-    assert ce.select_spans == spans
-    chain = predict_paths.chain_of(4, 3, ce.leaves.shape[2], ce.select_spans)
+    assert ce.select_spans == spans and ce.halved == (resolve == 2)
+    chain = predict_paths.chain_of(4, 3, ce.leaves.shape[2], ce.select_spans,
+                                   ce.paths.shape)
     plan = predict_paths.path_plan(ce.n_subtrees, 256, features, chain=chain)
+    assert plan.resolve_mxu_tiles == resolve
     assert plan.select_mxu_tiles == tiles == tree.subtree_mxu_tiles(
-        spans, 256, 0) - 4
+        spans, 256, 0, ce.halved) - resolve
     # 9 lanes of pieces and a few links: one tile of exits, 2 weight tiles
     assert ce.leaves.shape[2] == 128 and plan.exit_mxu_tiles == 2
     assert plan.path_mxu_tiles_per_tree == round(
-        ce.n_subtrees / 4 * tree.subtree_mxu_tiles(spans, 256, 128))
+        ce.n_subtrees / 4 * tree.subtree_mxu_tiles(spans, 256, 128,
+                                                   ce.halved))
     # every non-zero of the select lies in a K-block its lane tile reads
     _, rows, lanes = np.nonzero(ce.sel.astype(np.float32))
     first, stop = np.array(spans)[lanes // 128].T
     assert ((rows // 128 >= first) & (rows // 128 < stop)).all()
-    assert len(rows) == ens.n_splits
+    # a lane a node, and (the halves) one more a copy of a spine's node
+    assert len(rows) == ens.n_splits + ce.spine_copies
+    assert (ce.spine_copies > 0) == ce.halved
 
 
 def test_columns_that_crowd_one_block_and_models_with_nothing_to_split(
@@ -269,11 +292,24 @@ def test_three_bfloat16_pieces_hold_a_float32_exactly():
     np.testing.assert_array_equal((pieces[2] + pieces[1]) + pieces[0], v)
 
 
+def dense_paths(ce):
+    """The [S, W, W] path matrices of compiled tables: as they are, or of
+    HALVED sub-trees the two diagonal blocks the table holds side by side,
+    put back on the diagonal (the off-diagonal blocks are the zeros nobody
+    stored)."""
+    if not ce.halved:
+        return ce.paths
+    h = ce.lanes // 2
+    dense = np.zeros((ce.n_subtrees, ce.lanes, ce.lanes), ce.paths.dtype)
+    dense[:, :h, :h], dense[:, h:, h:] = ce.paths[:, :, :h], ce.paths[:, :, h:]
+    return dense
+
+
 def tables_scores(ce, Xb):
     """The compiled tables' own equations in NumPy (ops/predict.py, "The
     chain"): every sub-tree's contribution, summed."""
     sel, paths, leaves = (a.astype(np.float32) for a in (
-        ce.sel, ce.paths, ce.leaves))
+        ce.sel, dense_paths(ce), ce.leaves))
     C = ce.leaf_columns
     cl = -(-3 * C // 128) * 128
     # one tile of exits: the links behind the 3 C lanes of pieces, and the
@@ -475,6 +511,9 @@ def test_kernel_twin_and_walk_are_bit_equal_under_the_spans(
     ens = shape(ens) if shape else ens
     ce = ens.compile()
     assert ce.chained and ce.select_spans == spans and ce.lanes == 256
+    # one K-block: the halves (PR 51), whose select is dense
+    assert ce.halved == (features == 100) and ce.halved == (
+        ce.spine_copies > 0)
     Xb = rows_of(92, 600, features)
     Xb[::7, ::3] = BINS - 1        # rows that sit in the NaN bin
     want = ens.predict_raw(Xb, binned=True) if scalar else \
@@ -513,7 +552,16 @@ def test_dense_spans_build_the_parents_tables_bit_for_bit(
         if columns else random_node_list(
             rng, 3, 700, features, n_bins=BINS, learning_rate=0.1,
             base_score=0.5, loss="logloss")
+    if lanes == 256:
+        # (two lane tiles over one K-block: the rule takes the halves since
+        # PR 51. The dense layout is still the builder's to build, for the
+        # models the rule leaves it: handed to it here.)
+        assert ens.compile().halved
+        monkeypatch.setattr(
+            tree, "choose_select_spans", lambda ens, lanes, node_parent=None:
+            (tree.dense_spans(features, lanes), cut_subtrees(ens, lanes)))
     ce = ens.compile()
+    assert not ce.halved
     assert ce.select_spans == tree.dense_spans(features, lanes)
     assert ce.n_subtrees == parts
     pieces = 3 * ce.leaf_columns
@@ -834,3 +882,255 @@ def test_what_stays_refused_is_refused_by_name():
     with pytest.raises(ValueError, match="binned"):
         api.predict(ens, rows_of(72, 8).astype(np.float32), cfg=TrainConfig(
             backend="tpu", n_bins=BINS))
+
+
+# ---------------------------------------------------------------------- #
+# the halves: a sub-tree numbered as two halves that share their spine
+# ---------------------------------------------------------------------- #
+
+# leaves a tree: tens of nodes, one leaf, thousands, and the halves' edges
+RAGGED = (30, 1, 2600, 90, 700, 128, 129, 255, 256, 257, 400, 1, 1500, 60)
+
+
+def ragged(seed, features=54, missing=False):
+    """Softmax's round-major trees, two rounds of 7 classes, ragged."""
+    rng = np.random.default_rng(seed)
+    return joined(*[random_node_list(
+        rng, 1, n, features, n_bins=BINS, dyadic=True, missing=missing,
+        learning_rate=0.5, base_score=0.25, loss="softmax", n_classes=7)
+        for n in RAGGED])
+
+
+@pytest.mark.parametrize("seed,missing", [(71, False), (72, True),
+                                          (73, False)])
+def test_halved_subtrees_are_two_halves_that_share_their_spine(seed, missing):
+    """Still a partition into connected parts hung by their roots, parents
+    first; every part admits its k: the first half the first k nodes of the
+    part's pre-order in lanes 0.., the second half copies of node k's
+    ancestors (top down, from lane 128) and then the later nodes in
+    pre-order; both halves within 128 lanes of nodes and of exits; a second
+    half's node has no ancestor in the first half but a copied one; a part
+    of under 128 nodes is one half."""
+    ens = ragged(seed, missing=missing)
+    cut, free = cut_subtrees(ens, 256, halved=True), cut_subtrees(ens, 256)
+    assert cut.copy is not None and free.copy is None
+    parent = ens._parents()[0]
+    second_halves = copies = 0
+    for t in range(ens.n_trees):
+        n_int = int(ens.n_leaves[t]) - 1
+        if n_int == 0:
+            assert cut.n_subtrees[t] == 1
+            continue
+        sub, lane, root, copy = (a[t, :n_int] for a in (
+            cut.subtree, cut.lane, cut.root, cut.copy))
+        up = parent[t, :n_int]
+        assert root[0] and sub[0] == 0
+        assert np.bincount(sub[root]).tolist() == [1] * cut.n_subtrees[t]
+        inner, hung = np.nonzero(~root)[0], np.nonzero(root)[0][1:]
+        assert (sub[up[inner]] == sub[inner]).all()
+        assert (sub[up[hung]] < sub[hung]).all()
+        kids = np.stack([ens.left_child[t, :n_int],
+                         ens.right_child[t, :n_int]], 1)
+        # an exit: a child that is a leaf, or roots another part
+        n_exits = ((kids < 0) | root[np.maximum(kids, 0)]).sum(axis=1)
+        copied = np.nonzero(copy)[0]
+        for k in range(cut.n_subtrees[t]):
+            top, = np.nonzero(root & (sub == k))[0]
+            pre, stack, depth = [], [(top, 0)], {}
+            while stack:                    # the part's pre-order
+                n, d = stack.pop()
+                pre.append(n)
+                depth[n] = d
+                stack += [(c, d + 1) for c in kids[n][::-1]
+                          if c >= 0 and not root[c]]
+            n = len(pre)
+            assert n == (sub == k).sum()
+            # the cut's bound: the nodes and the longest path's together
+            assert n + max(depth.values()) + 1 <= 255
+            split = int((lane[pre] < 128).sum())
+            first, second = pre[:split], pre[split:]
+            assert 1 <= split <= 128
+            assert lane[first].tolist() == list(range(split))
+            spine = []
+            if second:
+                a = second[0]
+                while not root[a]:
+                    a = up[a]
+                    spine.insert(0, a)
+                second_halves += 1
+            assert copy[spine].tolist() == list(range(128, 128 + len(spine)))
+            assert set(copied[sub[copied] == k]) == set(spine) <= set(first)
+            assert lane[second].tolist() == list(range(
+                128 + len(spine), 128 + len(spine) + len(second)))
+            assert 128 + len(spine) + len(second) <= 256
+            for x in second:
+                a = x
+                while not root[a]:
+                    a = up[a]
+                    assert lane[a] >= 128 or copy[a] > 0
+            assert n_exits[first].sum() <= 128 >= n_exits[second].sum()
+            assert n_exits[pre].sum() == n + 1
+            if n < 128:
+                assert not second
+            copies += len(spine)
+    assert second_halves > 10 and copies == np.count_nonzero(cut.copy)
+    # what the halves' bound costs the cut
+    assert free.n_subtrees.sum() <= cut.n_subtrees.sum() \
+        <= 1.2 * free.n_subtrees.sum()
+    with pytest.raises(ValueError, match="two lane tiles under dense"):
+        cut_subtrees(ens, 128, halved=True)
+
+
+def walked_exit(ens, cut, t, top, x):
+    """Where row x leaves tree t's part rooted at node `top`: (the leaf, or
+    None; the node that roots the part it goes on in, or None)."""
+    n = top
+    while True:
+        b = int(x[ens.feature[t, n]])
+        left = b <= ens.threshold_bin[t, n]
+        if ens.missing_routes and b == ens.n_bins - 1:
+            left = bool(ens.default_left[t, n])
+        c = int(ens.left_child[t, n] if left else ens.right_child[t, n])
+        if c < 0:
+            return ~c, None
+        if cut.root[t, c]:
+            return None, c
+        n = c
+
+
+@pytest.mark.parametrize("features,missing,packed,digest", [
+    (54, False, 2, "2277425e3d4b7c13"),  # the packed select over the halves
+    (9, True, 2, "f2ba633402c57172"),    # ... with NaN routes
+    (100, True, 1, "911c984f10c7cfd9"),  # the unpacked select
+])
+def test_halved_tables_resolve_every_exit_in_its_own_half(
+        monkeypatch, features, missing, packed, digest):
+    """The tables of a model whose sub-trees are halved: `paths` the two
+    diagonal blocks alone; a copy's column, threshold and NaN bound its
+    node's; every (row, sub-tree) leaves the sub-tree by the exit the node
+    walk from its root takes, a real leaf's value in its class's lanes or
+    the link to the part that hangs there; kernel (interpreted), twin and
+    walk bit-equal, the link's answers to float32 rounding; the spans say
+    `resolve_mxu_tiles` 2 and the copies. The tables' SHA-1 is this PR's
+    (51): a later change to the numbering shows."""
+    import hashlib
+
+    from ddt_tpu.backends import get_backend
+    from ddt_tpu.telemetry import annotations as an
+
+    monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
+    ens = ragged(75 + features, features, missing)
+    ce = ens.compile()
+    spans, cut = tree.choose_select_spans(ens, 256)
+    assert spans == ce.select_spans == tree.dense_spans(features, 256)
+    for a, b in zip(cut, cut_subtrees(ens, 256, halved=True)):
+        np.testing.assert_array_equal(a, b)
+    S, C = ce.n_subtrees, 7
+    assert ce.halved and ce.paths.shape == (S, 128, 256)
+    assert ce.leaves.shape == (S, 256, 128) and ce.planes.shape == (S, 8, 256)
+    h = hashlib.sha1()
+    for a in ce.arrays():
+        h.update(np.ascontiguousarray(a).view(np.uint8).tobytes())
+    assert h.hexdigest()[:16] == digest
+    first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
+    # a copy asks its node's question and hangs no exit
+    t, n = np.nonzero(cut.copy)
+    entry, own, cp = first[t] + cut.subtree[t, n], cut.lane[t, n], \
+        cut.copy[t, n]
+    assert len(t) == ce.spine_copies > 0
+    sel = ce.sel.astype(np.float32)
+    np.testing.assert_array_equal(sel[entry, :, cp], sel[entry, :, own])
+    np.testing.assert_array_equal(sel[entry, ens.feature[t, n], cp], 1.0)
+    for row in (0, 3):
+        np.testing.assert_array_equal(ce.planes[entry, row, cp],
+                                      ce.planes[entry, row, own])
+    np.testing.assert_array_equal(ce.planes[entry, 0, cp],
+                                  ens.threshold_bin[t, n])
+    # every node lane is one node's or one copy's, the others ask nothing
+    assert (sel.sum(axis=1) <= 1).all() and sel.sum() == ens.n_splits + len(t)
+    # the exit every (row, sub-tree) takes is the node walk's
+    Xb = rows_of(76, 40, features)
+    Xb[::5, ::2] = BINS - 1
+    paths, leaves = dense_paths(ce).astype(np.float32), \
+        ce.leaves.astype(np.float32)
+    assert not paths[:, :128, 128:].any() and not paths[:, 128:, :128].any()
+    X = np.pad(Xb.astype(np.float32), ((0, 0), (0, sel.shape[1] - features)))
+    for t in range(ens.n_trees):
+        tops = np.nonzero(cut.root[t])[0]
+        tops = tops[np.argsort(cut.subtree[t, tops])]
+        for k in range(cut.n_subtrees[t]):
+            g = first[t] + k
+            v = X @ sel[g]
+            right = v > ce.planes[g, 0]
+            if missing:
+                right &= v < ce.planes[g, 3]
+            e = np.where(right, 1.0, -1.0).astype(np.float32) @ paths[g] \
+                == ce.planes[g, 1]
+            assert (e.sum(axis=1) == 1).all()
+            y = e.astype(np.float32) @ leaves[g]
+            value = y[:, 2 * C:3 * C] + y[:, C:2 * C] + y[:, :C]
+            for r in range(len(Xb)):
+                leaf, to = (0, None) if not len(tops) else walked_exit(
+                    ens, cut, t, tops[k], Xb[r])
+                want = np.zeros(C, np.float32)
+                link = np.zeros(128 - 3 * C, np.float32)
+                if to is None:
+                    want[t % C] = ens.leaf_value[t, leaf]
+                else:
+                    link[cut.subtree[t, to] - k - 1] = 1.0
+                np.testing.assert_array_equal(value[r], want)
+                np.testing.assert_array_equal(y[r, 3 * C:], link)
+    # kernel, twin, walk
+    Xb = rows_of(77, 700, features)
+    Xb[::7, ::3] = BINS - 1
+    want = ens.predict_raw(Xb, binned=True)
+    for impl in ("pallas", "onehot"):
+        np.testing.assert_array_equal(scored(ens, Xb, impl), want)
+    be = get_backend(TrainConfig(backend="tpu", predict_impl="pallas",
+                                 n_bins=BINS))
+    proba = be.predict_raw(ens, Xb, link=True)
+    z = want.astype(np.float64)
+    z = np.exp(z - z.max(axis=1, keepdims=True))
+    np.testing.assert_allclose(proba, z / z.sum(axis=1, keepdims=True),
+                               atol=1e-6)
+    root = an.root_spans("predict")[-1]
+    built = [s for s in root["spans"]
+             if s["name"] == "ddt:predict:ensemble"][0]["counts"]
+    for counts in (root["counts"], built):
+        assert counts["resolve_mxu_tiles"] == 2
+        assert counts["spine_copies_per_subtree"] == round(
+            ce.spine_copies / S, 2) > 0
+        assert counts["select_nodes_per_lane"] == packed
+        assert counts["path_mxu_tiles_per_tree"] == round(
+            S / ens.n_trees * (3 - packed + 2 + 2))
+
+
+def test_the_rule_takes_the_halves_for_one_k_block_and_keeps_the_forests_spans(
+        monkeypatch):
+    """`choose_select_spans` from the model alone: halved sub-trees at 54
+    and at 100 columns (2 tiles of resolve where 4, for a few more parts),
+    the K-block spans for a forest over 784 columns, whose select the halves
+    would ask whole (14 + 2 + 2 against 7 + 4 + 2); spans that bound a tile
+    take no halves beside them; an uncut model says 4 and no copy."""
+    monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
+    for features in (54, 100):
+        ens = ragged(80, features)
+        spans, cut = tree.choose_select_spans(ens, 256)
+        assert spans == tree.dense_spans(features, 256)
+        assert cut.copy is not None
+        free = cut_subtrees(ens, 256)
+        assert cut.n_subtrees.sum() * tree.subtree_mxu_tiles(
+            spans, 256, 128, True) < free.n_subtrees.sum() \
+            * tree.subtree_mxu_tiles(spans, 256, 128)
+        assert (tree.subtree_mxu_tiles(spans, 256, 128, True),
+                tree.subtree_mxu_tiles(spans, 256, 128)) == (6, 8)
+    wide = forest(62, 4, (600, 1500), 3, features=784)
+    spans, cut = tree.choose_select_spans(wide, 256)
+    assert spans == ((0, 3), (3, 7)) and cut.copy is None
+    assert not wide.compile().halved
+    assert tree.subtree_mxu_tiles(tree.dense_spans(784, 256), 256, 128,
+                                  True) == 18
+    with pytest.raises(ValueError, match="two lane tiles under dense"):
+        cut_subtrees(wide, 256, ((0, 3), (3, 7)), halved=True)
+    flat = predict_paths.path_plan(500, 256, 28)
+    assert (flat.resolve_mxu_tiles, flat.spine_copies_per_subtree) == (4, 0.0)

@@ -336,9 +336,13 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
         single_subtree_trees=3, subtree_lanes=128, leaf_columns=1,
         link="none", chain_mxu_tiles_per_tree=0, class_dot_passes=0,
         select_mxu_tiles=1,                # one K-block x one lane tile
-        exit_mxu_tiles=0)                  # no exits' table: no chain
-    assert predict_paths.CHAIN_COUNTS[-2:] == ("select_mxu_tiles",
-                                               "exit_mxu_tiles")
+        exit_mxu_tiles=0,                  # no exits' table: no chain
+        # one lane tile of nodes against one of leaves; no sub-tree, so
+        # none in two halves
+        resolve_mxu_tiles=1, spine_copies_per_subtree=0.0)
+    assert predict_paths.CHAIN_COUNTS[-4:] == (
+        "select_mxu_tiles", "exit_mxu_tiles", "resolve_mxu_tiles",
+        "spine_copies_per_subtree")
     assert counts["bytes"] == counts["table_bytes"] or not served
     assert root["counts"]["select_k_blocks"] == 1
     assert root["counts"]["select_nodes_per_lane"] == 1

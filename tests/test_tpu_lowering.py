@@ -210,7 +210,8 @@ def test_path_kernel_takes_the_select_the_rule_packs(case):
 
 # The exits' table of every forest case (PR 49): (its lanes, the class
 # lanes of the kernel's result, the select's spans, `exit_mxu_tiles`, the
-# MXU weight tiles a sub-tree). ONE lane tile where the three pieces of a
+# MXU weight tiles a sub-tree; a sixth, PR 51: `resolve_mxu_tiles`, 2 where
+# the path table holds the diagonal blocks of HALVED sub-trees alone). ONE lane tile where the three pieces of a
 # leaf's values and the tree's chain fit it (models/tree.exit_table_lanes);
 # [V | L], lane tiles of their own, at 85 and 128 classes and under a chain
 # of 200 sub-trees.
@@ -223,9 +224,12 @@ FOREST_EXITS = {
     "forest/28f/12x1subtree/c128": (512, 384, (), 8, 13),
     "forest/129f/3x200subtrees/c10/128lanes": (384, 128, (), 3, 6),
     # softmax's round-major trees (PR 50): 21 and 9 lanes of pieces, chains
-    # of 50 and 30, the packed select: 1 + 4 + 2 tiles a sub-tree
-    "paths/54f/softmax7/chain": (128, 128, (), 2, 7),
+    # of 50 and 30, the packed select: 1 + 4 + 2 tiles a sub-tree with the
+    # whole path matrix, 1 + 2 + 2 with the halves' (PR 51: the XGBoost
+    # cell's), 2 + 2 + 2 under the unpacked select of 100 columns
+    "paths/54f/softmax7/chain": (128, 128, (), 2, 5, 2),
     "paths/54f/softmax3/chain": (128, 128, (), 2, 7),
+    "paths/100f/softmax7/halved/chain": (128, 128, (), 2, 6, 2),
 }
 
 
@@ -252,6 +256,7 @@ def test_subtree_form_crosses_hbm_at_the_datas_width(case):
     exported, shapes = _export_for_tpu(case)
     (rows, features), dtype = shapes[-1]
     (entries, lanes, exit_lanes), _ = shapes[3]
+    path_shape, _ = shapes[2]
     assert dtype == jnp.uint8 and len(shapes) == 5
     text = exported.mlir_module()
     call, = [ln for ln in text.splitlines()
@@ -262,8 +267,14 @@ def test_subtree_form_crosses_hbm_at_the_datas_width(case):
     assert f"tensor<{entries}x{lanes}x{exit_lanes}xbf16>" in operands
     class_lanes = int(re.fullmatch(
         rf"tensor<{rows}x(\d+)xf32>", result).group(1))
-    want_exits, want_class, spans, exit_tiles, tiles = FOREST_EXITS[case.name]
+    want_exits, want_class, spans, exit_tiles, tiles, *resolve = \
+        FOREST_EXITS[case.name]
+    resolve, = resolve or [(lanes // 128) ** 2]
     assert (exit_lanes, class_lanes) == (want_exits, want_class)
+    # the path table as the kernel's operand: whole, or the halves' blocks
+    halved = resolve < (lanes // 128) ** 2
+    assert path_shape == (entries, lanes // 2 if halved else lanes, lanes)
+    assert "tensor<" + "x".join(map(str, path_shape)) + "xbf16>" in operands
     # one tile of exits IS the class lanes; else the activity lies behind
     assert class_lanes % 128 == 0 and (
         exit_lanes == class_lanes == 128 or exit_lanes >= class_lanes + 128)
@@ -274,13 +285,16 @@ def test_subtree_form_crosses_hbm_at_the_datas_width(case):
     out, = exported.out_avals
     assert out.shape[0] == rows and out.shape[1] * 3 <= class_lanes
     # ... and what the plan says of it on the `ensemble` span
-    chain = predict_paths.chain_of(1, out.shape[1], exit_lanes, spans)
+    chain = predict_paths.chain_of(1, out.shape[1], exit_lanes, spans,
+                                   path_shape)
     assert chain.shared == (exit_lanes == 128)
+    assert chain.halved == halved
     assert chain.at_hand == (3 * out.shape[1] if chain.shared else 0)
     plan = predict_paths.path_plan(entries, lanes, features, chain=chain)
     assert plan.exit_mxu_tiles == exit_tiles
+    assert plan.resolve_mxu_tiles == resolve
     assert predict_paths.path_mxu_tiles_per_tree(
-        lanes, features, None, exit_lanes, spans) == tiles
+        lanes, features, None, exit_lanes, spans, chain.halved) == tiles
 
 
 @pytest.mark.parametrize("case", OBLIVIOUS_CASES, ids=lambda c: c.name)

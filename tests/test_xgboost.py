@@ -173,6 +173,61 @@ def test_kernel_twin_walk_and_reference_agree(name, depth, sizes, missing):
             assert not pieces[first[t]:first[t + 1]][..., other].any()
 
 
+@pytest.mark.parametrize("missing", [True, False],
+                         ids=["missing", "no-missing"])
+@pytest.mark.parametrize("classes", [7, 3], ids=["softprob7", "softprob3"])
+def test_ragged_softmax_trees_in_halved_subtrees(monkeypatch, classes,
+                                                 missing):
+    """Sub-trees of TWO lane tiles, as the module has them: a model of one
+    K-block is cut into HALVED sub-trees (models/tree.cut_subtrees: two
+    halves of 128 lanes that share their spine, the path table's diagonal
+    blocks alone) under the PACKED select (9 columns: two nodes a result
+    lane). Trees of one leaf, of tens of nodes and of thousands, 16 deep, in
+    one model: every part admits its k (the build raises where one does
+    not), parts with a second half are there beside parts of one, and the
+    interpreted kernel, its twin, the host walk and the float64 walk of the
+    library's own arrays give the same margins BIT FOR BIT; the link's
+    answers hold the tolerance they held."""
+    monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
+    model = drawn_model(300 + classes, classes, "multi:softprob", 16,
+                        (1, 30, 2400, 90, 700, 257, 3100), rounds=2)
+    ens = from_xgboost_json(json.dumps(model), missing=missing)
+    assert isinstance(ens, NodeListEnsemble) and ens.deepest_leaf == 16
+    mapper = threshold_bin_mapper(ens, n_bins=256)
+    ce = ens.compile()
+    cut = cut_subtrees(ens, 256, halved=True)
+    assert ce.halved and ce.paths.shape == (ce.n_subtrees, 128, 256)
+    assert ce.n_subtrees == cut.n_subtrees.sum() > ens.n_trees
+    assert ce.single_subtree_trees == (cut.n_subtrees == 1).sum() > 0
+    assert ce.subtrees_max == cut.n_subtrees.max() >= 10
+    assert 0 < ce.spine_copies == np.count_nonzero(cut.copy)
+    # parts of one half (under 128 nodes, no copy) beside parts of two
+    first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
+    t, n = np.nonzero(ens.live_nodes)
+    entry = first[t] + cut.subtree[t, n]
+    two = np.zeros(ce.n_subtrees, bool)
+    two[entry[cut.lane[t, n] >= 128]] = True
+    assert two.any() and not two.all()
+    assert (np.bincount(entry, minlength=ce.n_subtrees)[~two] < 128).all()
+    # the exits of a second half lie in its own 128 lanes, the path
+    # lengths say which lanes hold an exit: one more than a part's nodes
+    held = ce.planes[:, 1, :] >= 0
+    assert (held.sum(axis=1)[two] == np.bincount(entry)[two] + 1).all()
+    assert held[two][:, 128].all() and not held[~two][:, 128:].any()
+    X = rows_on_and_off(17 + classes, 300, missing)
+    Xb = mapper.transform(X)
+    want = predict_xgboost_json(model, X, raw=True).astype(np.float32)
+    np.testing.assert_array_equal(ens.predict_raw(Xb, binned=True), want)
+    proba = predict_xgboost_json(model, X)
+    for impl in ("pallas", "onehot"):
+        np.testing.assert_array_equal(
+            api.predict(ens, Xb, binned=True, raw=True, cfg=cfg_of(impl)),
+            want)
+        np.testing.assert_allclose(
+            api.predict(ens, X, mapper=mapper, cfg=cfg_of(impl)), proba,
+            atol=1e-6)
+
+
 def stump(cond, column=0, nan_left=0, low=-1.0, high=1.0):
     """x < cond: `low`, else `high`."""
     return {"left_children": [1, -1, -1], "right_children": [2, -1, -1],
