@@ -54,7 +54,8 @@ from ddt_tpu.parallel import comms as comms_lib
 from ddt_tpu.parallel import mesh as mesh_lib
 from ddt_tpu.robustness import emit_fault, faultplan
 from ddt_tpu.telemetry import counters as tele_counters
-from ddt_tpu.telemetry.annotations import phase_span, stage_program
+from ddt_tpu.telemetry.annotations import (note_root, phase_span,
+                                           stage_program)
 from ddt_tpu.telemetry.costmodel import costed
 from ddt_tpu.utils import device
 from ddt_tpu.utils import retry as retry_lib
@@ -1582,11 +1583,17 @@ class TPUDevice(DeviceBackend):
 
     # Counters whose movement over one predict_raw call rides on its root
     # span: whether the call compiled, traced or lowered anything, and
-    # whether the model was already resident.
+    # whether the model was already resident. (Beside them the root carries
+    # the host's pauses over the call, telemetry/counters.HOST_COUNTERS:
+    # what tells a pause of the process from a wait for the link or the
+    # device.)
     _PREDICT_ROOT_COUNTERS = (
         "jit_compiles", "jit_compile_seconds", "jit_trace_seconds",
         "jit_lower_seconds", "compile_cache_hits",
         "compiled_ensemble_cache_hits")
+    # The root's counts that make two calls the same work: what a call is
+    # held to the calls before it by (annotations.note_root, slow_calls()).
+    _PREDICT_ROOT_SHAPE = ("rows", "chunks", "branch", "classes")
 
     def predict_chunk_rows(self, n_features: int) -> int:
         """Rows a scoring dispatch takes on each chip at this width:
@@ -1619,15 +1626,21 @@ class TPUDevice(DeviceBackend):
         step (token, ensemble, upload, dispatch, fetch, place: the
         table is in docs/OBSERVABILITY.md); the spans time the host's
         side and add no sync."""
-        with phase_span("predict", rows=int(Xb.shape[0])) as root:
-            c0 = tele_counters.snapshot()
-            try:
-                return self._predict_raw(ens, Xb, compiled, root.counts,
-                                         link)
-            finally:
-                moved = tele_counters.delta(c0)
-                root.counts.update(
-                    {k: moved[k] for k in self._PREDICT_ROOT_COUNTERS})
+        root = phase_span("predict", rows=int(Xb.shape[0]))
+        try:
+            with root:
+                c0 = tele_counters.snapshot()
+                paused = tele_counters.host_pauses()
+                try:
+                    return self._predict_raw(ens, Xb, compiled, root.counts,
+                                             link)
+                finally:
+                    moved = tele_counters.delta(c0)
+                    root.counts.update(
+                        {k: moved[k] for k in self._PREDICT_ROOT_COUNTERS})
+                    root.counts.update(tele_counters.host_pauses(paused))
+        finally:
+            note_root(root, self._PREDICT_ROOT_SHAPE)
 
     def _predict_raw(self, ens: TreeEnsemble, Xb: np.ndarray, compiled,
                      counts: dict, link: bool = False) -> np.ndarray:
@@ -1700,11 +1713,15 @@ class TPUDevice(DeviceBackend):
         # they would pass PREDICT_FIRST_PIECE_BYTES), and chunks are sliced
         # on device.
         # A piece's transfer starts when the chunks of the piece before
-        # it have been dispatched and that piece has arrived, so it runs
-        # under their compute, and only the first piece's is exposed: 46
-        # ms of a 3.9 GB batch where one device_put of it took 644-888
-        # ms before the first chunk could start, and a call's wall
-        # varied by all of that (PERF.md sections 5 and 6, PR 31). Two
+        # it have been dispatched and that piece has arrived (the wait is
+        # the span `predict:upload:wait`: its end is the host's time by
+        # which that piece had landed), so it runs under their compute,
+        # and only the first piece's is exposed (what that is on the chip,
+        # split into the put, the flight and the device's start: PERF.md
+        # section 5, "the routed call's arrival", PR 52) where one
+        # device_put of a 3.9 GB batch took 644-888 ms before the first
+        # chunk could start, and a call's wall varied by all of that
+        # (PERF.md sections 5 and 6, PR 31). Two
         # forms that lost there: every piece issued before the loop (the
         # device takes transfers and programs in the host's issue order,
         # so the first chunk waited for them all, 414 ms), and pieces
@@ -1742,7 +1759,9 @@ class TPUDevice(DeviceBackend):
             part = Xh[bounds[p]:bounds[p + 1]]
             with phase_span("predict:upload", piece=p, bytes=part.nbytes):
                 if p:   # one transfer at a time; the device has p - 1's chunks
-                    pieces[-1].block_until_ready()  # ddtlint: disable=host-sync
+                    with phase_span("predict:upload:wait", piece=p - 1,
+                                    bytes=pieces[-1].nbytes):
+                        pieces[-1].block_until_ready()  # ddtlint: disable=host-sync
                     return jax.device_put(part)
                 # The exposed piece goes up as flat bytes and takes its
                 # shape on the device: a 1-D array needs no relayout on
@@ -1828,38 +1847,39 @@ class TPUDevice(DeviceBackend):
         # ce.quantize() memoizes: when the serving tier already
         # quantized this model version at publish (for its error-bound
         # reporting), this is a dict hit, not a second O(model) pass.
-        if tier == "lut4":
-            tables = ce.quantize(leaf_dtype="int4")
-            packed = tables.pack_int4()
-            if not predict_lut.predict_lut4_fits(
-                    tables.n_trees_padded, tables.tree_chunk,
-                    tables.max_depth, n_features, tables.n_classes_out,
-                    thr_packed=packed.thr_packed):
-                return None
-            host_ops = packed.ops
-            static = packed.static_kwargs()
-            core = predict_lut.predict_effective_lut4_ops
-        else:
-            tables = ce.quantize()
-            if not predict_lut.predict_lut_fits(
-                    tables.n_trees_padded, tables.tree_chunk,
-                    tables.max_depth, n_features, tables.n_classes_out):
-                return None
-            host_ops = predict_lut.lut_device_operands(tables)
-            static = dict(
-                max_depth=tables.max_depth,
-                learning_rate=tables.learning_rate,
-                base=tables.base_score, n_classes=tables.n_classes_out,
-                tree_chunk=tables.tree_chunk,
-                n_trees_padded=tables.n_trees_padded,
-                missing_bin_value=tables.missing_bin_value,
-                use_missing=tables.eff_dl is not None,
-                use_cat=tables.eff_cat is not None,
-                use_scale=tables.leaf_scale is not None,
-            )
-            core = predict_lut.predict_effective_lut_ops
-        dev_ops = tuple(self._put(a, self._named(
-            self.layout.replicated())) for a in host_ops)
+        with phase_span("predict:ensemble:pack") as sp:
+            if tier == "lut4":
+                tables = ce.quantize(leaf_dtype="int4")
+                packed = tables.pack_int4()
+                if not predict_lut.predict_lut4_fits(
+                        tables.n_trees_padded, tables.tree_chunk,
+                        tables.max_depth, n_features, tables.n_classes_out,
+                        thr_packed=packed.thr_packed):
+                    return None
+                host_ops = packed.ops
+                static = packed.static_kwargs()
+                core = predict_lut.predict_effective_lut4_ops
+            else:
+                tables = ce.quantize()
+                if not predict_lut.predict_lut_fits(
+                        tables.n_trees_padded, tables.tree_chunk,
+                        tables.max_depth, n_features, tables.n_classes_out):
+                    return None
+                host_ops = predict_lut.lut_device_operands(tables)
+                static = dict(
+                    max_depth=tables.max_depth,
+                    learning_rate=tables.learning_rate,
+                    base=tables.base_score, n_classes=tables.n_classes_out,
+                    tree_chunk=tables.tree_chunk,
+                    n_trees_padded=tables.n_trees_padded,
+                    missing_bin_value=tables.missing_bin_value,
+                    use_missing=tables.eff_dl is not None,
+                    use_cat=tables.eff_cat is not None,
+                    use_scale=tables.leaf_scale is not None,
+                )
+                core = predict_lut.predict_effective_lut_ops
+            sp.counts["bytes"] = sum(a.nbytes for a in host_ops)
+        dev_ops = self._put_tables(host_ops)
 
         def lut0(*args):
             *ops, Xc = args
@@ -1945,8 +1965,18 @@ class TPUDevice(DeviceBackend):
         serve the model: the one-hot path, the LUT tiers."""
         from ddt_tpu.ops import predict_pallas
 
-        ce = compiled if compiled is not None else ens.compile(
-            tree_chunk=64)
+        # The build's three stages, children of `ddt:predict:ensemble`:
+        # `compile` (models/tree: the scoring layout from the model's
+        # arrays, a node list's cut into sub-trees with it; nothing to do
+        # where the caller hands the layout), `pack` (what is made of the
+        # layout's tables on the host before they go up) and `upload`
+        # (`_put_tables`).
+        with phase_span("predict:ensemble:compile", trees=ens.n_trees,
+                        nodes=ens.n_nodes) as sp:
+            ce = compiled if compiled is not None else ens.compile(
+                tree_chunk=64)
+            if isinstance(ce, CompiledNodeList):
+                sp.counts["subtrees"] = ce.n_subtrees
         if isinstance(ce, CompiledNodeList):
             return self._build_paths_fn(ens, ce, link)
         if isinstance(ce, CompiledOblivious):
@@ -1977,8 +2007,10 @@ class TPUDevice(DeviceBackend):
                     "predict_impl=%r: shape exceeds the LUT kernel's "
                     "VMEM budget; falling back to the f32 path",
                     impl_req)
-            ens_dev = tuple(self._put(a, self._named(
-                self.layout.replicated())) for a in ce.arrays())
+            with phase_span("predict:ensemble:pack") as sp:
+                tables = ce.arrays()
+                sp.counts["bytes"] = sum(a.nbytes for a in tables)
+            ens_dev = self._put_tables(tables)
             use_missing = ce.eff_dl is not None
             use_cat = ce.eff_cat is not None
             use_pallas = self._use_pallas
@@ -2056,15 +2088,19 @@ class TPUDevice(DeviceBackend):
         # with its shifted thresholds (`pack_select`), and the trees that
         # fill the kernel's last block (no node, no leaf of any length:
         # they add 0).
-        tables = ce.arrays()
-        if plan.select_nodes_per_lane == 2:
-            tables = (*predict_paths.pack_select(
-                ce.sel, ce.planes, ens.n_features, xp=np), *tables[2:])
+        with phase_span("predict:ensemble:pack") as sp:
+            tables = ce.arrays()
+            if plan.select_nodes_per_lane == 2:
+                tables = (*predict_paths.pack_select(
+                    ce.sel, ce.planes, ens.n_features, xp=np), *tables[2:])
+            sp.counts["bytes"] = sum(a.nbytes for a in tables)
+        # The fill goes on inside the put, a table at a time: a padded copy
+        # is gone when its transfer is (2.76 GB of tables in the XGBoost
+        # cell, whose last block lacks 3 sub-trees).
         fill = ((0, max(0, plan.trees_per_step * plan.table_blocks
                         - len(ce.sel))), (0, 0), (0, 0))
-        ens_dev = tuple(
-            self._put(np.pad(a, fill, constant_values=v) if fill[0][1]
-                      else a, self._named(self.layout.replicated()))
+        ens_dev = self._put_tables(
+            np.pad(a, fill, constant_values=v) if fill[0][1] else a
             for a, v in zip(tables, (0, -1.0, 0, 0)))
 
         # Bound here: fn0 outlives this call in the stage registry, and
@@ -2117,8 +2153,10 @@ class TPUDevice(DeviceBackend):
                 use_pallas, True, 0, ens.n_features, 1,
                 oblivious_depth=ce.depth),
             row_dtype=self.PREDICT_ROW_DTYPE)
-        ens_dev = tuple(self._put(a, self._named(self.layout.replicated()))
-                        for a in ce.arrays())
+        with phase_span("predict:ensemble:pack") as sp:
+            tables = ce.arrays()
+            sp.counts["bytes"] = sum(a.nbytes for a in tables)
+        ens_dev = self._put_tables(tables)
         # Bound here: fn0 outlives this call in the stage registry, and
         # must not hold the host copy of the tables (197 MB at 8000 trees
         # x 2000 columns).
@@ -2133,6 +2171,21 @@ class TPUDevice(DeviceBackend):
             predict_ops.predict_raw_effective_oblivious, fn0, ens_dev,
             ens.n_features)
         return self._row_sharded(fn0, 3, 1), ens_dev, "f32", 1, plan
+
+    def _put_tables(self, tables) -> tuple:
+        """A model's tables up, replicated, one at a time (`tables` may
+        make each as it is asked for): the span
+        `ddt:predict:ensemble:upload`, `bytes` what went up. It ends when
+        the transfers are ISSUED: device_put returns before the bytes have
+        landed, and the first chunk's dispatch is what waits for them."""
+        with phase_span("predict:ensemble:upload") as sp:
+            sh = self._named(self.layout.replicated())
+            up = []
+            for a in tables:
+                up.append(self._put(a, sh))
+                sp.counts["bytes"] = sp.counts.get("bytes", 0) + a.nbytes
+                del a       # a made table goes before the next is made
+            return tuple(up)
 
     def _stage_scoring_program(self, entry, fn0, ens_dev,
                                n_features: int) -> None:

@@ -71,6 +71,12 @@ class TreeEnsemble:
         return int(self.feature.shape[1])
 
     @property
+    def n_nodes(self) -> int:
+        """The nodes that ask a question, over all trees (what the span
+        of the model's build counts as `nodes`)."""
+        return int((~self.is_leaf & (self.feature >= 0)).sum())
+
+    @property
     def has_cat_splits(self) -> bool:
         """Whether any feature uses categorical one-vs-rest routing (the
         single home of the cat_features presence test)."""
@@ -715,6 +721,11 @@ class NodeListEnsemble:
     @property
     def n_trees(self) -> int:
         return int(self.feature.shape[0])
+
+    @property
+    def n_nodes(self) -> int:
+        """The nodes that ask a question, over all trees."""
+        return int(np.maximum(self.n_leaves.astype(np.int64) - 1, 0).sum())
 
     @property
     def vector_leaves(self) -> bool:
@@ -1922,6 +1933,11 @@ class ObliviousEnsemble:
     @property
     def depth(self) -> int:
         return int(self.split_feature.shape[1])
+
+    @property
+    def n_nodes(self) -> int:
+        """The questions this layout holds: one a level of a tree."""
+        return self.n_trees * self.depth
 
     max_depth = depth              # what `cli inspect` prints of a heap
 

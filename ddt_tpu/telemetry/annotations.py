@@ -15,6 +15,13 @@ Two halves of the Perfetto-alignment story (docs/OBSERVABILITY.md):
   around each PhaseTimer phase (`ddt:grow`, `ddt:eval`, ...), the scorer
   around each step of TPUDevice.predict_raw (`ddt:predict`,
   `ddt:predict:upload`, ...; the table is in docs/OBSERVABILITY.md).
+- account(root): where one root's time went, by span name, summing to the
+  root's duration exactly; note_root(span, shape): what the owner of a
+  root calls when it has ended (the scorer does, for `ddt:predict`), and
+  slow_calls(): the noted roots that took both SLOW_SHARE and SLOW_NS
+  longer than the median of the last SLOW_HISTORY calls of their shape,
+  each kept with its account and logged as one warning
+  (docs/OBSERVABILITY.md "Call records").
 - traced_scope(name): jax.named_scope for use INSIDE traced code. The
   ops kernels wrap their hist/allreduce/gain/route/leaf/predict stages,
   which writes `ddt:<name>` into the `op_name` of every HLO instruction
@@ -38,10 +45,15 @@ import collections
 import contextlib
 import functools
 import itertools
+import json
+import logging
 import os
 import re
+import statistics
 import threading
 import time
+
+from ddt_tpu.telemetry.counters import HOST_COUNTERS
 
 try:
     import jax
@@ -61,7 +73,21 @@ SPAN_RING = 8192
 #: `span.start - SPAN_ANCHOR[0] + SPAN_ANCHOR[1]`.
 SPAN_ANCHOR = (time.perf_counter_ns(), time.time_ns())
 
+#: A noted root (note_root) is slow when it took BOTH this share and this
+#: long more than the median of the last SLOW_HISTORY calls of its shape: a
+#: serving micro-batch's jitter never reaches 20 ms, a 40 s call's never 5%.
+SLOW_SHARE = 0.05
+SLOW_NS = 20_000_000
+SLOW_HISTORY = 8
+#: Slow calls kept, and shapes whose history is (the oldest shape goes).
+SLOW_RING = 64
+SLOW_SHAPES = 256
+
+log = logging.getLogger(__name__)
+
 _ring: collections.deque = collections.deque(maxlen=SPAN_RING)
+_slow: collections.deque = collections.deque(maxlen=SLOW_RING)
+_history: dict = {}                # shape -> deque of its last durations
 _ids = itertools.count(1)          # next() is one bytecode: GIL-atomic
 _open = threading.local()          # .stack: this thread's open spans
 
@@ -136,6 +162,112 @@ def root_spans(name: str) -> list:
         by_root.setdefault(d["root"], []).append(d)
     return [dict(d, spans=by_root[d["id"]]) for d in spans
             if d["id"] == d["root"] and d["name"] == PREFIX + name]
+
+
+# ------------------------------------------------------------------ #
+# a root's account, and the slow call kept
+# ------------------------------------------------------------------ #
+
+UNNAMED = "unnamed"
+
+
+def account(root: dict) -> dict:
+    """Where one root's time went: `{"name", "duration_ns", "self_ns":
+    {span name without the prefix: ns}, "counts"}` of one root_spans()
+    entry. Every instant of the root belongs to ONE span, the innermost
+    open at it (of two siblings that overlap, the one that started last):
+    for spans that nest, a span's duration minus what its children cover.
+    The root's own share is UNNAMED, last: Python between the spans, and
+    any pause that fell there. The values are whole nanoseconds and sum to
+    `duration_ns` exactly. Pure: it reads the dict it is given."""
+    lo, hi = root["start"], root["end"]
+    by_id = {s["id"]: s for s in root["spans"]}
+    depth: dict = {root["id"]: 0}
+
+    def depth_of(s) -> int:
+        if s["id"] not in depth:
+            above = by_id.get(s["cause"])
+            depth[s["id"]] = 1 if above is None else depth_of(above) + 1
+        return depth[s["id"]]
+
+    events = []                     # (time, opens, key); closes sort first
+    names = {}
+    for s in root["spans"]:
+        a, b = max(s["start"], lo), min(s["end"], hi)
+        if b > a or s["id"] == root["id"]:
+            key = (depth_of(s), s["start"], s["id"])
+            names[key] = (UNNAMED if s["id"] == root["id"]
+                          else s["name"].removeprefix(PREFIX))
+            events += [(a, 1, key), (b, 0, key)]
+    events.sort()
+    self_ns: dict = {}
+    open_now: set = set()
+    at = lo
+    for t, opens, key in events:
+        if t > at and open_now:
+            name = names[max(open_now)]
+            self_ns[name] = self_ns.get(name, 0) + t - at
+        at = max(at, t)
+        (open_now.add if opens else open_now.discard)(key)
+    self_ns[UNNAMED] = self_ns.pop(UNNAMED, 0)          # last
+    return {"name": root["name"], "duration_ns": hi - lo,
+            "self_ns": self_ns, "counts": dict(root["counts"])}
+
+
+def slow_calls() -> list:
+    """The slow calls still kept (SLOW_RING), oldest first: each `{"name",
+    "id", "start", "ms", "median_ms", "excess_ms", "shape", "account_ms",
+    "longest", "pauses"}`, what the warning of its end printed."""
+    return list(_slow)
+
+
+def note_root(span: Span, shape: tuple) -> None:
+    """A root has ended (its owner calls this after the `with` block):
+    hold it to the last calls of its name and `shape`, the names of the
+    root's counts that make two calls the same work; keep and log it if it
+    is slow, and count it among them. The first call of a shape (it
+    compiles) is neither compared nor counted, nor is a span that is not a
+    root (the call ran inside another's span: the account is that one's)."""
+    if span.cause is not None:
+        return
+    key = (span.name, *(span.counts.get(k) for k in shape))
+    past = _history.get(key)
+    if past is None:
+        if len(_history) >= SLOW_SHAPES:    # tuple(): whole under the GIL
+            _history.pop(tuple(_history)[0], None)
+        _history[key] = collections.deque(maxlen=SLOW_HISTORY)
+        return
+    took = span.end - span.start
+    if past:
+        median = statistics.median(past)
+        if took - median > max(SLOW_NS, SLOW_SHARE * median):
+            _keep_slow(span, shape, median)
+    past.append(took)
+
+
+def _keep_slow(span: Span, shape: tuple, median: float) -> None:
+    spans = [s.as_dict() for s in list(_ring) if s.root == span.id]
+    root = dict(span.as_dict(), spans=spans)
+    found = account(root)
+    longest = sorted((s for s in spans if s["id"] != span.id),
+                     key=lambda s: s["start"] - s["end"])[:3]
+    took = span.end - span.start
+    rec = {
+        "name": span.name, "id": span.id, "start": span.start,
+        "ms": took / 1e6, "median_ms": median / 1e6,
+        "excess_ms": (took - median) / 1e6,
+        "shape": {k: span.counts.get(k) for k in shape},
+        "account_ms": {k: v / 1e6 for k, v in found["self_ns"].items()},
+        "longest": [{"name": s["name"].removeprefix(PREFIX),
+                     "ms": (s["end"] - s["start"]) / 1e6,
+                     **{k: s["counts"][k] for k in ("chunk", "piece")
+                        if k in s["counts"]}} for s in longest],
+        # what tells a pause of the process from a wait for the link or
+        # the device: the host's pauses over the call
+        "pauses": {k: span.counts[k] for k in HOST_COUNTERS
+                   if k in span.counts}}
+    _slow.append(rec)
+    log.warning("slow call %s", json.dumps(rec))
 
 
 def traced_scope(name: str):

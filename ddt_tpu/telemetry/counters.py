@@ -38,6 +38,22 @@ snapshot() at run start and publish delta() at run end, so concurrent
 runs in one process each see their own traffic plus any overlap —
 documented, not hidden (docs/OBSERVABILITY.md).
 
+THE HOST'S PAUSES (HOST_COUNTERS, host_pauses()) are NOT in that registry:
+they are read at the two ends of one call and their movement rides on the
+call's root span (TPUDevice.predict_raw), to tell a pause of the process
+from a wait for the link or the device. `cpu_ns`: the CPU time of all the
+process's threads (time.process_time_ns(); against a call's wall it shows
+the host relayout threads' work, and a process that was not running);
+`gc_pause_ns`, `gc_collections`, `gc_gen2_collections`: counted by ONE
+gc.callbacks hook (two perf_counter_ns a collection; every thread waits
+for a collection, the interpreter's lock is held). The registry is what
+statusd scrapes, and a scrape changes nothing and reads the same twice:
+a clock, or a count the collector moves between two scrapes, has no place
+in it. A thread's run-queue wait, the machine's CPU pressure and
+getrusage's switches and faults are not read at all: the hosts the
+benchmark runs on have neither /proc file and report the three as 0
+(PERF.md section 6, PR 52).
+
 `device_peak_bytes()` reads the accelerator's high-water mark from
 device.memory_stats() where the platform exposes one (TPU/GPU; CPU XLA
 returns None).
@@ -45,6 +61,8 @@ returns None).
 
 from __future__ import annotations
 
+import gc
+import time
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # duration event -> the float counter that accumulates its seconds
@@ -154,6 +172,43 @@ _c = {
 }
 _listener_installed = False
 
+#: The host's pauses, in the order a call's root span carries them.
+HOST_COUNTERS = ("cpu_ns", "gc_pause_ns", "gc_collections",
+                 "gc_gen2_collections")
+_gc = {"gc_pause_ns": 0, "gc_collections": 0, "gc_gen2_collections": 0}
+_gc_hooked = False
+_gc_started = 0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_started
+    if phase == "start":
+        _gc_started = time.perf_counter_ns()
+    elif _gc_started:
+        _gc["gc_pause_ns"] += time.perf_counter_ns() - _gc_started
+        _gc["gc_collections"] += 1
+        _gc["gc_gen2_collections"] += info.get("generation") == 2
+
+
+def install_gc_hook() -> None:
+    """Count the collector's pauses from here on (idempotent): called by
+    install_jax_listener and by the first host_pauses()."""
+    global _gc_hooked
+    if not _gc_hooked:
+        _gc_hooked = True
+        gc.callbacks.append(_on_gc)
+
+
+def host_pauses(since: dict | None = None) -> dict:
+    """The host's pauses (HOST_COUNTERS; the module docstring says what
+    each is evidence of) as they stand now, or their movement since an
+    earlier reading: what the two ends of a scoring call take."""
+    install_gc_hook()
+    now = {"cpu_ns": time.process_time_ns(), **_gc}
+    if since is None:
+        return now
+    return {k: now[k] - since[k] for k in now}
+
 
 def install_jax_listener() -> None:
     """Register the recompile-counting jax.monitoring listener (idempotent;
@@ -180,6 +235,7 @@ def install_jax_listener() -> None:
     monitoring.register_event_duration_secs_listener(_on_duration)
     monitoring.register_event_listener(_on_event)
     _listener_installed = True
+    install_gc_hook()
 
 
 def record_h2d(nbytes: int) -> None:

@@ -21,7 +21,7 @@ MODULES = ("test_call_anatomy", "test_correct", "test_correct_mc",
            "test_correct_leafwise_nan", "test_correct_oblivious",
            "test_correct_forest", "test_correct_xgb",
            "test_opcount",
-           "test_tracefile", "test_device_stage_ms")
+           "test_tracefile", "test_device_stage_ms", "test_host_account")
 
 sys.path[:0] = [os.path.join(BENCHMARK, "tests"), BENCHMARK]
 pytest.register_assert_rewrite(*MODULES)

@@ -205,9 +205,16 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
     assert len(kids["ddt:predict:ensemble"]) == 1
     for name, n in want.items():
         assert len(kids["ddt:predict:" + name]) == n, name
+    by_id = {s["id"]: s for s in root["spans"]}
     for s in root["spans"]:
         if s["id"] != root["id"]:
-            assert (s["cause"], s["root"]) == (root["id"], root["id"])
+            # a step is the root's own child; what a step waits for or is
+            # built of hangs under it, and is named after it
+            above = by_id[s["cause"]]
+            assert s["root"] == root["id"]
+            assert above["id"] == root["id"] \
+                or s["name"].startswith(above["name"] + ":")
+            assert above["start"] <= s["start"] <= s["end"] <= above["end"]
             assert root["start"] <= s["start"] <= s["end"] <= root["end"]
     for name in ("dispatch", "fetch", "place"):
         assert [s["counts"]["chunk"] for s in kids["ddt:predict:" + name]] \
@@ -230,6 +237,20 @@ def test_predict_raw_records_one_root_per_call(branch, monkeypatch):
         assert [s["counts"]["bytes"] for s in up] == [
             6 * (min(R, row_chunk * b) - row_chunk * a)
             for a, b in zip(starts_at, starts_at[1:] + [chunks])]
+        # Inside a LATER piece's upload, the wait for the piece before it
+        # is a span of its own (the one place the host learns that a
+        # piece's bytes have arrived), counted by the piece WAITED for;
+        # the upload keeps its name, counts and extent. The first holds
+        # none: piece 0's put is the first upload itself.
+        waits = kids["ddt:predict:upload:wait"]
+        assert [w["cause"] for w in waits] == [u["id"] for u in up[1:]]
+        for p, (w, u) in enumerate(zip(waits, up[1:])):
+            assert w["counts"] == {"piece": p,
+                                   "bytes": up[p]["counts"]["bytes"]}
+            assert set(u["counts"]) == {"piece", "bytes"}
+            assert u["start"] <= w["start"] <= w["end"] <= u["end"]
+    else:
+        assert "ddt:predict:upload:wait" not in kids
     # bytes where the work happens, and the same bytes on the counters
     ens_bytes = kids["ddt:predict:ensemble"][0]["counts"]["bytes"]
     assert ens_bytes > 0
@@ -522,6 +543,321 @@ def test_a_compiled_ensemble_skips_the_token_span():
     kids = _by_name(an.root_spans("predict")[-1])
     assert "ddt:predict:token" not in kids
     assert len(kids["ddt:predict:ensemble"]) == 1
+
+
+# ------------------------------------------------------------------ #
+# the host's side: a root's account, its pauses, the slow call kept
+# ------------------------------------------------------------------ #
+
+def _span(name, id_, cause, root, start, end, **counts):
+    return {"name": "ddt:" + name, "id": id_, "cause": cause, "root": root,
+            "start": start, "end": end, "counts": counts}
+
+
+def test_account_sums_to_the_root_on_nested_and_overlapping_spans():
+    """Every instant of the root belongs to one span: the innermost open
+    at it, of two overlapping siblings the one that started last."""
+    spans = [
+        _span("predict", 1, None, 1, 1_000, 11_000, rows=5),
+        _span("predict:upload", 2, 1, 1, 1_500, 4_000),
+        _span("predict:upload:wait", 3, 2, 1, 2_000, 3_000),
+        # two siblings that overlap by 500 (never on one thread; a
+        # reader's hand-made spans may)
+        _span("predict:dispatch", 4, 1, 1, 5_000, 7_000),
+        _span("predict:fetch", 5, 1, 1, 6_500, 9_000),
+        # a span that leaves the root's extent is cut to it
+        _span("predict:place", 6, 1, 1, 10_500, 12_000),
+        # and one of no length owns nothing
+        _span("predict:token", 7, 1, 1, 1_200, 1_200)]
+    root = dict(spans[0], spans=spans)
+    found = an.account(root)
+    assert found["name"] == "ddt:predict"
+    assert found["duration_ns"] == 10_000
+    assert found["counts"] == {"rows": 5}
+    assert found["self_ns"] == {
+        "predict:upload": 1_500, "predict:upload:wait": 1_000,
+        "predict:dispatch": 1_500, "predict:fetch": 2_500,
+        "predict:place": 500, "unnamed": 3_000}
+    assert list(found["self_ns"])[-1] == "unnamed"
+    assert sum(found["self_ns"].values()) == found["duration_ns"]
+    # pure: the root it was given is as it was
+    assert root["counts"] == {"rows": 5} and len(root["spans"]) == 7
+
+
+@pytest.mark.parametrize("branch", ["one", "chunks-4pieces", "mesh"])
+def test_account_sums_to_a_real_call_to_the_nanosecond(branch, monkeypatch):
+    parts, row_chunk, R, want, chunks = BRANCHES[branch]
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31,
+                                 n_partitions=parts))
+    monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", row_chunk)
+    ens = _rand_ensemble(seed=4100 + sorted(BRANCHES).index(branch))
+    Xb = np.random.default_rng(11).integers(0, 31, size=(R, 6),
+                                            dtype=np.uint8)
+    for call in range(2):           # the model's build, then resident
+        be.predict_raw(ens, Xb)
+        root = an.root_spans("predict")[-1]
+        found = an.account(root)
+        assert sum(found["self_ns"].values()) == found["duration_ns"] \
+            == root["end"] - root["start"]
+        assert all(v >= 0 for v in found["self_ns"].values())
+        names = {s["name"].removeprefix("ddt:") for s in root["spans"]
+                 if s["id"] != root["id"] and s["end"] > s["start"]}
+        assert set(found["self_ns"]) == names | {"unnamed"}
+        assert ("predict:ensemble:compile" in names) == (call == 0)
+        assert ("predict:upload:wait" in names) == (branch[:6] == "chunks")
+
+
+def test_the_root_carries_the_hosts_pauses(monkeypatch):
+    import gc
+
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31))
+    ens = _rand_ensemble(seed=4200)
+    Xb = np.random.default_rng(12).integers(0, 31, size=(500, 6),
+                                            dtype=np.uint8)
+    be.predict_raw(ens, Xb)
+    counts = an.root_spans("predict")[-1]["counts"]
+    held = [k for k in tele_counters.HOST_COUNTERS if k in counts]
+    for k in held:
+        assert isinstance(counts[k], int) and counts[k] >= 0, k
+    assert held == ["cpu_ns", "gc_pause_ns", "gc_collections",
+                    "gc_gen2_collections"]
+    assert counts["cpu_ns"] > 0
+
+    # a collection inside the call is counted on its root, generation 2
+    # apart, with the time every thread waited for it
+    real = type(be)._predict_raw
+
+    def collecting(self, *args, **kw):
+        gc.collect()                # generation 2
+        gc.collect(0)
+        return real(self, *args, **kw)
+
+    monkeypatch.setattr(type(be), "_predict_raw", collecting)
+    be.predict_raw(ens, Xb)
+    counts = an.root_spans("predict")[-1]["counts"]
+    assert counts["gc_collections"] >= 2
+    assert 1 <= counts["gc_gen2_collections"] < counts["gc_collections"]
+    assert counts["gc_pause_ns"] > 0
+
+
+def test_the_hosts_pauses_are_a_calls_and_not_the_registrys():
+    """statusd scrapes the registry, and a scrape reads the same twice: a
+    clock and a count the collector moves are read by host_pauses() at a
+    call's two ends and never written there."""
+    import gc
+
+    kept = tele_counters.snapshot()
+    a = tele_counters.host_pauses()
+    assert tuple(a) == tele_counters.HOST_COUNTERS
+    sum(i * i for i in range(200_000))          # CPU time passes
+    gc.collect()
+    moved = tele_counters.host_pauses(a)
+    assert tuple(moved) == tele_counters.HOST_COUNTERS
+    assert moved["cpu_ns"] > 0 and moved["gc_pause_ns"] > 0
+    assert moved["gc_collections"] >= 1 <= moved["gc_gen2_collections"]
+    assert tele_counters.snapshot() == kept
+    assert not set(kept) & set(tele_counters.HOST_COUNTERS)
+
+
+def _ended_root(took_ns, **counts):
+    """A finished root `ddt:predict` of `took_ns`, as __exit__ leaves it."""
+    with an.phase_span("predict", **counts) as sp:
+        pass
+    sp.end = sp.start + took_ns
+    return sp
+
+
+SHAPE = ("rows", "chunks", "branch", "classes")
+
+
+def _noted(took_ns, caplog, **counts):
+    """note_root of a root of `took_ns`; the warnings it logged."""
+    import logging
+
+    caplog.clear()
+    with caplog.at_level(logging.WARNING, logger=an.__name__):
+        an.note_root(_ended_root(took_ns, **counts), SHAPE)
+    return [r for r in caplog.records if r.name == an.__name__]
+
+
+@pytest.fixture
+def no_history(monkeypatch):
+    monkeypatch.setattr(an, "_history", {})
+    monkeypatch.setattr(an, "_slow", collections.deque(maxlen=an.SLOW_RING))
+
+
+SLOW_CASES = {
+    # name: (steady ms, the call's ms, kept?)
+    "7% and 1.0 s over 14.45 s (the forest cell's)": (14_450, 15_453, True),
+    "25% and 365 ms over 1.44 s (Epsilon's)": (1_440, 1_805, True),
+    "9% and 110 ms over 1.26 s (the routed cell's)": (1_260, 1_370, True),
+    "30% of a 3 ms micro-batch is 0.9 ms": (3, 3.9, False),
+    "19 ms of 100 ms": (100, 119, False),
+    "4% of 10 s is 400 ms": (10_000, 10_400, False),
+    "a faster call": (1_000, 500, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SLOW_CASES))
+def test_a_slow_root_is_one_5_percent_and_20_ms_over_the_median(
+        case, no_history, caplog):
+    steady, took, kept = SLOW_CASES[case]
+    shape = dict(rows=1000, chunks=4, branch="chunks", classes=1)
+    # the first call of a shape (it compiles) is neither held to anything
+    # nor counted; eight steady calls log nothing
+    assert _noted(int(steady * 40e6), caplog, **shape) == []
+    for k in range(8):
+        assert _noted(int(steady * 1e6) + 1000 * k, caplog, **shape) == []
+    assert an.slow_calls() == []
+    logged = _noted(int(took * 1e6), caplog, **shape)
+    assert len(logged) == len(an.slow_calls()) == int(kept)
+    if kept:
+        rec, = an.slow_calls()
+        assert rec["shape"] == shape
+        assert rec["ms"] == pytest.approx(took)
+        assert rec["median_ms"] == pytest.approx(steady, abs=0.01)
+        assert rec["excess_ms"] == pytest.approx(took - steady, abs=0.01)
+        line = logged[0].getMessage()
+        assert "\n" not in line and line.startswith("slow call ")
+        assert json.loads(line.removeprefix("slow call ")) == rec
+    # another shape has a history of its own: its first call is not held
+    # to this one's
+    assert _noted(int(steady * 40e6), caplog, **dict(shape, rows=7)) == []
+    # the rings are bounded
+    assert an._slow.maxlen == an.SLOW_RING == 64
+    assert all(h.maxlen == an.SLOW_HISTORY == 8
+               for h in an._history.values())
+
+
+def test_the_shapes_kept_are_bounded(no_history, monkeypatch):
+    monkeypatch.setattr(an, "SLOW_SHAPES", 4)
+    for rows in range(10):
+        an.note_root(_ended_root(1000, rows=rows), SHAPE)
+    assert len(an._history) == 4
+    assert [k[1] for k in an._history] == [6, 7, 8, 9]
+
+
+def test_a_call_slowed_by_its_fetch_is_kept_with_its_account(
+        monkeypatch, caplog):
+    """A 60 ms stall inside one chunk's fetch: the call lands in
+    slow_calls() with `predict:fetch` holding the excess, and its end logs
+    ONE warning, one line."""
+    import logging
+
+    from ddt_tpu.backends import tpu as tpu_backend
+
+    monkeypatch.setattr(an, "_history", {})
+    monkeypatch.setattr(an, "_slow", collections.deque(maxlen=an.SLOW_RING))
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31))
+    monkeypatch.setattr(type(be), "PREDICT_ROW_CHUNK", 256)
+    ens = _rand_ensemble(seed=4400)
+    Xb = np.random.default_rng(14).integers(0, 31, size=(1000, 6),
+                                            dtype=np.uint8)
+    with caplog.at_level(logging.WARNING, logger=an.__name__):
+        for _ in range(6):          # the first compiles; five steady
+            be.predict_raw(ens, Xb)
+        steady = [r["end"] - r["start"]
+                  for r in an.root_spans("predict")[-5:]]
+
+        class Stalling:
+            """numpy, but the next asarray of a device array waits."""
+            stalls = 1
+
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            def asarray(self, a, *args, **kw):
+                if self.stalls and isinstance(a, jax.Array):
+                    self.stalls -= 1
+                    time.sleep(0.060)
+                return np.asarray(a, *args, **kw)
+
+        monkeypatch.setattr(tpu_backend, "np", Stalling())
+        be.predict_raw(ens, Xb)
+        monkeypatch.setattr(tpu_backend, "np", np)
+    slow_id = an.root_spans("predict")[-1]["id"]
+    rec, = [r for r in an.slow_calls() if r["id"] == slow_id]
+    assert rec["excess_ms"] >= 20 and rec["excess_ms"] >= 0.05 * rec[
+        "median_ms"]
+    assert rec["median_ms"] * 1e6 == pytest.approx(
+        sorted(steady)[2], rel=1e-9)
+    assert rec["account_ms"]["predict:fetch"] >= 59
+    assert sum(rec["account_ms"].values()) == pytest.approx(rec["ms"])
+    assert rec["longest"][0]["name"] == "predict:fetch"
+    assert rec["longest"][0]["chunk"] == 0 and rec["longest"][0]["ms"] >= 59
+    assert len(rec["longest"]) == 3
+    assert rec["shape"] == dict(rows=1000, chunks=4, branch="chunks",
+                                classes=1)
+    assert tuple(rec["pauses"]) == tele_counters.HOST_COUNTERS
+    mine = [r for r in caplog.records if r.name == an.__name__
+            and f'"id": {slow_id},' in r.getMessage()]
+    assert len(mine) == 1 and "\n" not in mine[0].getMessage()
+
+
+def test_what_the_root_pays_for_its_pauses_and_its_history():
+    """A generous guard, not a measurement (PERF.md section 6, PR 52 has
+    that): the process's CPU time read at the call's two ends, the
+    movement and the root's place in its shape's history, everything a
+    scoring call's root gained in PR 52, stay under 100 us a call."""
+    sp = _ended_root(1_000_000, rows=1, chunks=1, branch="one", classes=1)
+    n = 3000
+    t0 = time.perf_counter_ns()
+    for _ in range(n):
+        paused = tele_counters.host_pauses()
+        sp.counts.update(tele_counters.host_pauses(paused))
+        an.note_root(sp, SHAPE)
+    each = (time.perf_counter_ns() - t0) / n
+    assert each < 100_000, each
+
+
+MODEL_BUILDS = {
+    "heap": lambda: _rand_ensemble(seed=4500),
+    "heap-routed": lambda: _routed(_rand_ensemble(seed=4501), 4502),
+    "node-list": lambda: _small_node_list(4503),
+    "forest": lambda: _small_forest(4504),
+    "oblivious": lambda: _small_oblivious(4505),
+}
+
+
+@pytest.mark.parametrize("model,impl", [
+    *((m, "auto") for m in sorted(MODEL_BUILDS)), ("heap", "lut")])
+def test_the_stages_of_a_models_build_tile_its_ensemble_span(model, impl):
+    """`compile`, `pack`, `upload`, in that order and apart, inside
+    `ddt:predict:ensemble`, which keeps its own counts."""
+    ens = MODEL_BUILDS[model]()
+    be = get_backend(TrainConfig(backend="tpu", n_bins=31,
+                                 predict_impl=impl))
+    Xb = np.random.default_rng(15).integers(0, 31, size=(300, 6),
+                                            dtype=np.uint8)
+    be.predict_raw(ens, Xb)
+    root = an.root_spans("predict")[-1]
+    kids = _by_name(root)
+    built, = kids["ddt:predict:ensemble"]
+    stages = [s for s in root["spans"] if s["cause"] == built["id"]]
+    lut = impl == "lut" and model.startswith("heap")
+    assert [s["name"].removeprefix("ddt:predict:ensemble:")
+            for s in stages] == ["compile", "pack", "upload"]
+    compile_, pack, upload = stages
+    assert built["start"] <= compile_["start"] and upload["end"] \
+        <= built["end"]
+    assert compile_["end"] <= pack["start"] and pack["end"] \
+        <= upload["start"]
+    assert compile_["counts"]["trees"] == ens.n_trees
+    assert compile_["counts"]["nodes"] == ens.n_nodes > 0
+    assert ("subtrees" in compile_["counts"]) == (
+        model in ("node-list", "forest"))
+    if model == "forest":
+        assert compile_["counts"]["subtrees"] >= ens.n_trees
+    # the bytes issued are the bytes packed and the fill of the kernel's
+    # last block of trees, which goes on inside the put
+    assert 0 < pack["counts"]["bytes"] <= upload["counts"]["bytes"] \
+        == built["counts"]["bytes"]
+    assert be.resolved_predict_impl(ens.cache_token()) \
+        == ("lut" if lut else "f32")
+    # the stages are the span's time but for its own (the plan, imports)
+    found = an.account(root)["self_ns"]
+    assert sum(s["end"] - s["start"] for s in stages) + found[
+        "predict:ensemble"] == built["end"] - built["start"]
 
 
 # ------------------------------------------------------------------ #
