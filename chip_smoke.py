@@ -519,6 +519,10 @@ def score_forest(overrides: dict, rows: int) -> None:
     assert built["node_list"] == root["counts"]["node_list"] == 1, built
     assert built["subtrees_per_tree"] > 1, "no tree was cut"
     assert built["subtrees_per_tree"] == root["counts"]["subtrees_per_tree"]
+    # the entries are PACKED (PR 53): several pieces of a tree in one,
+    # glued by copies of their common ancestors
+    assert built["pieces_per_subtree"] > 1 \
+        and built["glue_copies_per_subtree"] > 0, built
     assert (built["subtree_lanes"], built["leaf_columns"],
             built["class_dot_passes"], built["select_k_blocks"]) == (
                 256, C, 3, 7), built
@@ -561,6 +565,7 @@ def score_forest(overrides: dict, rows: int) -> None:
         assert (said["resolve_mxu_tiles"], said["select_nodes_per_lane"]) == (
             2, 2 if few <= 64 else 1), said
         assert said["spine_copies_per_subtree"] > 0, said
+        assert said["pieces_per_subtree"] > 1, said
         twin = api.predict(halved, Xh, binned=True, raw=True, cfg=TrainConfig(
             n_bins=bins, backend="tpu", predict_impl="onehot"))
         walk = numpy_predict.predict_proba_node_list(halved, Xh)

@@ -2082,7 +2082,11 @@ class TPUDevice(DeviceBackend):
                 single_subtree_trees=ce.single_subtree_trees,
                 link=ce.loss if link else "none",
                 spine_copies_per_subtree=round(
-                    ce.spine_copies / max(ce.n_subtrees, 1), 2))
+                    ce.spine_copies / max(ce.n_subtrees, 1), 2),
+                pieces_per_subtree=round(
+                    (ce.pieces or 1) / max(ce.n_subtrees, 1), 2),
+                glue_copies_per_subtree=round(
+                    ce.glue_copies / max(ce.n_subtrees, 1), 2))
         # What every chunk's program would otherwise make of the tables is
         # made here, once a model: the select that answers two nodes a lane
         # with its shifted thresholds (`pack_select`), and the trees that

@@ -1186,18 +1186,56 @@ def random_node_list(rng, n_trees: int, n_leaves: int, n_features: int,
 
 
 class SubtreeCut(typing.NamedTuple):
-    """A node list's trees cut into connected sub-trees (`cut_subtrees`)."""
+    """A node list's trees cut into the ENTRIES of the sub-tree form
+    (`cut_subtrees`): an entry holds one or several connected PIECES of its
+    tree, glued into one binary tree by copies of their common ancestors.
+    The arrays are [K] over the SLOTS, the lanes-to-be: first the model's M
+    internal nodes themselves, the trees in a row (node n of tree t is slot
+    `first_node[t] + n`, first_node the running sum of the trees' node
+    counts), then the G GLUE copies, by their entry (one node may be glue
+    in several entries: a slot each)."""
 
-    n_subtrees: np.ndarray     # int64 [T] sub-trees of each tree (>= 1)
-    root: np.ndarray           # bool  [T, N] the nodes that root a sub-tree
-    subtree: np.ndarray        # int32 [T, N] a node's sub-tree, numbered in
-    #   its tree by the pre-order of the roots (-1: no such node)
-    lane: np.ndarray           # int32 [T, N] its number in the sub-tree: the
-    #   pre-order (the root 0), or under `spans` the pre-order inside the
-    #   128-lane tile whose K-blocks hold the node's column, or HALVED the
-    #   pre-order inside its half (the second half's behind the copies)
-    copy: np.ndarray | None = None  # HALVED alone: int32 [T, N] the lane of
-    #   the node's COPY in its sub-tree's second half (128..; 0: it has none)
+    n_subtrees: np.ndarray     # int64 [T] entries of each tree (>= 1)
+    tree: np.ndarray           # int64 [K] the slot's tree ...
+    origin: np.ndarray         # int64 [K] ... and the node there whose
+    #   question it asks: itself, or the common ancestor a glue copy stands
+    #   for
+    root: np.ndarray           # bool  [K] the nodes that root a piece
+    subtree: np.ndarray        # int32 [K] a slot's entry, numbered in its
+    #   tree in the order the entries were filled: the parent of a piece's
+    #   root lies in an earlier one
+    lane: np.ndarray           # int32 [K] its number in the entry: the
+    #   pre-order of the glued tree (its top 0), or under `spans` the
+    #   pre-order inside the 128-lane tile whose K-blocks hold the slot's
+    #   column, or HALVED the pre-order inside its half (the second half's
+    #   behind the copies)
+    copy: np.ndarray | None    # HALVED alone: int32 [K] the lane of the
+    #   slot's SPINE copy in its entry's second half (128..; 0: it has none)
+    up: np.ndarray             # int64 [K] the slot above it in its entry: a
+    #   node's parent, above a piece's root the glue copy it hangs on (-1:
+    #   the entry's top)
+    side: np.ndarray           # int8  [K] ... and the side it hangs on
+    #   (-1 left, +1 right)
+
+
+class _Flat(typing.NamedTuple):
+    """A model's internal nodes in a row: the trees in their order, each in
+    the pre-order that visits a node's HEAVIER child first (equal ones: the
+    left), so that the uncut tree below position q is the positions
+    [q, q + size[q]) and a prefix of them is connected."""
+
+    tree: np.ndarray           # int64 [M] the node's tree ...
+    node: np.ndarray           # int64 [M] ... and its index there
+    first: np.ndarray          # int64 [T + 1] a tree's first position
+    size: np.ndarray           # int64 [M] nodes of the uncut tree below it
+    tall: np.ndarray           # int64 [M] nodes on the longest path down it
+    depth: np.ndarray          # int64 [M] nodes above it in its tree
+    up: np.ndarray             # int64 [M] its parent's position (-1: none)
+    side: np.ndarray           # int8  [M] the side it hangs on (-1, +1)
+    kids: np.ndarray           # int64 [M, 2] its children's positions, the
+    #   heavier first (-1: a leaf)
+    pre: np.ndarray            # int64 [M] its number in the pre-order that
+    #   visits the LEFT child first (the lanes' order)
 
 
 def _levels(ens: NodeListEnsemble) -> list:
@@ -1218,13 +1256,54 @@ def _levels(ens: NodeListEnsemble) -> list:
     return levels
 
 
-def _kids(ens: NodeListEnsemble, t, n, of: np.ndarray) -> tuple:
-    """(`of` [..., T, N] at the children [..., k, 2], the children [k, 2])
-    of the nodes (t, n); a child that is a leaf reads 0."""
-    kids = np.stack([ens.left_child[t, n], ens.right_child[t, n]], 1)
-    at = of[..., t[:, None], np.maximum(kids, 0)]
-    at[..., kids < 0] = 0
-    return at, kids
+def _first_nodes(ens: NodeListEnsemble) -> np.ndarray:
+    """int64 [T + 1]: the internal nodes before each tree, the trees in a
+    row (`SubtreeCut`'s slot of a tree's node 0), and behind them all."""
+    return np.concatenate([[0], np.cumsum(np.maximum(
+        ens.n_leaves.astype(np.int64) - 1, 0))])
+
+
+def _flat(ens: NodeListEnsemble) -> _Flat:
+    """`cut_subtrees`'s view of the model (a level of all the trees a
+    step)."""
+    ens._parents()                      # in range, one parent each
+    levels = _levels(ens)
+    T, N = ens.feature.shape
+    lt = np.concatenate([t for t, _ in levels] or [np.zeros(0, np.int64)])
+    ln = np.concatenate([n for _, n in levels] or [np.zeros(0, np.int64)])
+    M = len(lt)
+    bounds = np.concatenate([[0], np.cumsum([len(t) for t, _ in levels])])
+    where = np.full((T, N), M, np.int32)    # by levels; M: no node
+    where[lt, ln] = np.arange(M)
+    raw = np.stack([ens.left_child[lt, ln], ens.right_child[lt, ln]], 1)
+    kid = np.where(raw >= 0, where[lt[:, None], np.maximum(raw, 0)],
+                   M).astype(np.int64)
+    size, tall = np.ones(M + 1, np.int64), np.ones(M + 1, np.int64)
+    size[M] = tall[M] = 0
+    for lo, hi in zip(bounds[-2::-1], bounds[:0:-1]):   # children first
+        size[lo:hi] += size[kid[lo:hi]].sum(axis=1)
+        tall[lo:hi] += tall[kid[lo:hi]].max(axis=1)
+    # the two pre-orders top-down: the first child follows its parent, the
+    # second the first's whole tree
+    swap = size[kid[:, 1]] > size[kid[:, 0]]
+    heavy = np.where(swap[:, None], kid[:, ::-1], kid)
+    pre, hpre = np.zeros(M + 1, np.int64), np.zeros(M + 1, np.int64)
+    depth, up = np.zeros(M + 1, np.int64), np.full(M + 1, -1, np.int64)
+    side = np.zeros(M + 1, np.int8)
+    for d, (lo, hi) in enumerate(zip(bounds[:-1], bounds[1:])):
+        for order, number in ((kid, pre), (heavy, hpre)):
+            a, b = order[lo:hi, 0], order[lo:hi, 1]
+            number[a] = number[lo:hi] + 1
+            number[b] = number[lo:hi] + 1 + size[a]
+        depth[lo:hi] = d
+        up[kid[lo:hi, 0]] = up[kid[lo:hi, 1]] = np.arange(lo, hi)
+        side[kid[lo:hi, 0]], side[kid[lo:hi, 1]] = -1, 1
+    first = _first_nodes(ens)
+    q = np.append(first[lt] + hpre[:M], -1)      # a level's node's position
+    by_q = np.argsort(q[:M])
+    return _Flat(lt[by_q], ln[by_q], first, size[by_q], tall[by_q],
+                 depth[by_q], q[up[by_q]], side[by_q], q[heavy[by_q]],
+                 pre[by_q])
 
 
 def dense_spans(n_features: int, lanes: int) -> tuple:
@@ -1233,16 +1312,13 @@ def dense_spans(n_features: int, lanes: int) -> tuple:
     return ((0, -(-n_features // PATH_LANES)),) * -(-lanes // PATH_LANES)
 
 
-def _subtree_roots(ens: NodeListEnsemble, levels: list, lanes: int,
-                   spans: tuple, halved: bool = False) -> tuple:
-    """`cut_subtrees`'s bottom-up walk: (the nodes that root a sub-tree,
-    bool [T, N]; the nodes that only the first / only the second lane tile
-    may hold under `spans`, bool [2, T, N]). `halved`: a part's nodes and
-    the nodes on its longest path are at most `lanes` - 1 together."""
-    live = ens.live_nodes
-    block = ens.feature // PATH_LANES
-    only = np.stack([live & (block < spans[-1][0]),
-                     live & (block >= spans[0][1])])
+def _tile_bound(ens: NodeListEnsemble, flat: _Flat, lanes: int, spans: tuple,
+                halved: bool) -> np.ndarray | None:
+    """The nodes that only the first / only the second lane tile may hold
+    under `spans`, bool [2, M] (None: the spans bound nothing); refuses
+    spans and halves that no cut can number."""
+    block = ens.feature[flat.tree, flat.node] // PATH_LANES
+    only = np.stack([block < spans[-1][0], block >= spans[0][1]])
     if only.any() and (len(spans) != 2 or lanes != 2 * PATH_LANES):
         raise ValueError("the select's spans bound a cut into sub-trees of "
                          f"two lane tiles; got {spans} at {lanes} lanes")
@@ -1253,38 +1329,154 @@ def _subtree_roots(ens: NodeListEnsemble, levels: list, lanes: int,
     if halved and (only.any() or lanes != 2 * PATH_LANES):
         raise ValueError("a halved sub-tree is two lane tiles under dense "
                          f"spans; got {spans} at {lanes} lanes")
-    # a node's remainder: its part's nodes, and those only a tile may hold
-    weight = np.concatenate([live[None], only]).astype(np.int32)
-    caps = np.array([lanes - 1, PATH_LANES, PATH_LANES])[:, None]
-    root = np.zeros(live.shape, bool)
-    root[ens.n_leaves > 1, 0] = True
-    # (halved) the nodes on the longest path down a node's remainder
-    tall = live.astype(np.int32) if halved else None
-    for t, n in reversed(levels):       # children before parents
-        w, kids = _kids(ens, t, n, weight)          # [3, k, 2]
-        below = _kids(ens, t, n, tall)[0] if halved else 0      # [k, 2]
-        for _ in range(2):
-            over = weight[:, t, n] + w.sum(axis=2) > caps
+    return only if only.any() else None
+
+
+def _runs(key: np.ndarray) -> tuple:
+    """(where each run of equal values of the sorted `key` begins, every
+    entry's run, numbered 0..)."""
+    new = np.concatenate([[True], key[1:] != key[:-1]])
+    return np.flatnonzero(new), np.cumsum(new) - 1
+
+
+def _largest(size: np.ndarray, seg: np.ndarray,
+             among: np.ndarray) -> np.ndarray:
+    """Of the indices `among` (their `seg` sorted), the one of the largest
+    `size` in each run of one `seg`: the first of equal ones."""
+    if not len(among):
+        return among
+    starts, run = _runs(seg[among])
+    best = np.maximum.reduceat(size[among], starts)[run]
+    return among[np.minimum.reduceat(np.where(
+        size[among] == best, np.arange(len(among)), len(among)), starts)]
+
+
+def _common_ancestor(flat: _Flat, before: np.ndarray,
+                     after: np.ndarray) -> np.ndarray:
+    """The lowest common ancestors of the nodes at `before` and at `after`
+    (later in `_Flat`'s order, neither above the other): the deepest node
+    above the later one that lies before the earlier one."""
+    x = flat.up[after]
+    while (far := x > before).any():
+        x[far] = flat.up[x[far]]
+    return x
+
+
+def _glue_tiles(flat: _Flat, only: np.ndarray, frontier: np.ndarray,
+                seg: np.ndarray, taken: np.ndarray,
+                pick: np.ndarray) -> np.ndarray:
+    """What the glue copy that hangs frontier sub-tree `pick` beside the
+    ones its entry has `taken` costs either lane tile, int [2, picks]: 1
+    where only that tile may hold the copy's node. The copy: the deeper of
+    the sub-tree's common ancestors with its neighbours among the taken,
+    before and behind it (none: the entry's first piece)."""
+    at, end = np.arange(len(frontier)), len(frontier)
+    glue = np.full(len(pick), -1, np.int64)
+    for lo, hi in ((np.maximum.accumulate(np.where(taken, at, -1))[pick],
+                    pick),
+                   (pick, np.minimum.accumulate(
+                       np.where(taken, at, end)[::-1])[::-1][pick])):
+        ok = (lo >= 0) & (hi < end)
+        ok[ok] = seg[lo[ok]] == seg[hi[ok]]
+        glue[ok] = np.maximum(glue[ok], _common_ancestor(
+            flat, frontier[lo[ok]], frontier[hi[ok]]))
+    return np.where(glue >= 0, only[:, np.maximum(glue, 0)], False).astype(
+        np.int64)
+
+
+def _filled(flat: _Flat, lanes: int, only: np.ndarray | None,
+            halved: bool) -> tuple:
+    """`cut_subtrees`'s packer: the PIECES, (first position, nodes, entry
+    in its tree) each, a round of all the trees' entries a step. A piece is
+    a whole frontier sub-tree or a prefix of one (`_Flat`'s order), so an
+    interval of positions."""
+    cap = lanes - 1                     # nodes and glue copies of an entry
+    cum = None
+    if only is not None:
+        cum = np.zeros((2, len(flat.tree) + 1), np.int64)
+        np.cumsum(only, axis=1, out=cum[:, 1:])
+    frontier = flat.first[:-1][np.diff(flat.first) > 0]
+    pieces, entry = [], 0
+    while len(frontier):
+        starts, seg = _runs(flat.tree[frontier])
+        # an entry's own nodes, pieces, longest piece and tile-bound nodes
+        n, p, h = (np.zeros(len(starts), np.int64) for _ in range(3))
+        c = np.zeros((2, len(starts)), np.int64)
+        size, tall = flat.size[frontier], flat.tall[frontier]
+        count = cum[:, frontier + size] - cum[:, frontier] \
+            if cum is not None else None
+        taken, out = (np.zeros(len(frontier), bool) for _ in range(2))
+        cand = np.arange(len(frontier))
+        while True:
+            # whole sub-trees, the largest that fits first. One more piece
+            # is one more glue copy: a lane, one on the longest path
+            # (charged as more than it can cost) and a node of the tile its
+            # column says, which is known of the one picked alone: one that
+            # does not fit for its copy's sake is out for this entry.
+            s = seg[cand]
+            fit = n[s] + size[cand] + p[s] <= cap
             if halved:
-                over[0] |= (weight[0, t, n] + w[0].sum(axis=1) + tall[t, n]
-                            + below.max(axis=1) > lanes - 1)
-            hit = np.nonzero(over.any(axis=0))[0]
-            if not len(hit):
+                fit &= n[s] + size[cand] + 2 * p[s] + np.maximum(
+                    h[s], tall[cand]) <= cap
+            if cum is not None:
+                fit &= (c[:, s] + count[:, cand] <= PATH_LANES).all(axis=0)
+            cand = cand[fit]
+            if not len(cand):
                 break
-            # the weight that is over (a tile's before the part's), and the
-            # child heaviest in it; equal ones: the later node
-            which = np.where(over[1], 1, np.where(over[2], 2, 0))[hit]
-            left, right = w[which, hit, 0], w[which, hit, 1]
-            side = ((right > left) | ((right == left) & (
-                kids[hit, 1] > kids[hit, 0]))).astype(np.int64)
-            root[t[hit], kids[hit, side]] = True
-            w[:, hit, side] = 0
-            if halved:
-                below[hit, side] = 0
-        weight[:, t, n] += w.sum(axis=2)
+            pick = _largest(size, seg, cand)
+            if cum is not None:
+                glue = count[:, pick] + _glue_tiles(
+                    flat, only, frontier, seg, taken, pick)
+                ok = (c[:, seg[pick]] + glue <= PATH_LANES).all(axis=0)
+                out[pick[~ok]] = True
+                pick = pick[ok]
+                c[:, seg[pick]] += glue[:, ok]
+            s = seg[pick]
+            n[s] += size[pick]
+            h[s] = np.maximum(h[s], tall[pick])
+            p[s] += 1
+            taken[pick] = True
+            pieces.append((frontier[pick], size[pick],
+                           np.full(len(pick), entry)))
+            cand = cand[~(taken | out)[cand]]
+        # ... then a TOP piece of the largest sub-tree left: as long a
+        # prefix as fits (a threshold on its length buys nothing: 13,386
+        # entries of the XGBoost model at 1 node, 13,438 at 16, 13,728 at 64)
+        big = _largest(size, seg, np.flatnonzero(~taken))
+        s = seg[big]
+        m = cap - n[s] - p[s]
         if halved:
-            tall[t, n] += below.max(axis=1)
-    return root, only
+            # its longest path is no longer than the sub-tree's, nor than
+            # the piece
+            room = cap - n[s] - 2 * p[s]
+            m = np.minimum(m, np.maximum(
+                room - np.maximum(h[s], tall[big]),
+                np.minimum(room - h[s], room // 2)))
+        if cum is not None:
+            glue = _glue_tiles(flat, only, frontier, seg, taken, big)
+            for tile in range(2):
+                m = np.minimum(m, np.searchsorted(
+                    cum[tile], cum[tile, frontier[big]] + PATH_LANES
+                    - c[tile, s] - glue[tile], side="right") - 1
+                    - frontier[big])
+        m = np.minimum(m, size[big])
+        big, m = big[m >= 1], m[m >= 1]
+        taken[big] = True
+        pieces.append((frontier[big], m, np.full(len(big), entry)))
+        # the children a prefix cuts off hang on the path up from its last
+        # node, and lie behind it: the next entries' frontier
+        top, end = frontier[big], frontier[big] + m
+        x, new = end - 1, [frontier[~taken]]
+        while len(x):
+            kids = flat.kids[x]
+            new.append(kids[kids >= end[:, None]])
+            go = x != top
+            x, top, end = flat.up[x[go]], top[go], end[go]
+        frontier = np.sort(np.concatenate(new))
+        entry += 1
+    if not pieces:
+        return (np.zeros(0, np.int64),) * 3
+    return tuple(np.concatenate(a) for a in zip(*pieces))
 
 
 def _rank(key: np.ndarray, mask: np.ndarray | None = None) -> np.ndarray:
@@ -1295,82 +1487,123 @@ def _rank(key: np.ndarray, mask: np.ndarray | None = None) -> np.ndarray:
     return before - before[np.searchsorted(key, key)]
 
 
-def _numbered(ens: NodeListEnsemble, levels: list, node_parent: np.ndarray,
-              root: np.ndarray, only: np.ndarray, lanes: int,
-              halved: bool = False) -> SubtreeCut:
-    """`cut_subtrees`'s numbers, from the roots of the sub-trees."""
-    T, N = root.shape
-    live = ens.live_nodes
-    # Pre-order numbers top-down: a left child follows its parent, a right
-    # one the left's whole tree.
-    size = live.astype(np.int64)        # of the uncut tree below a node
-    for t, n in reversed(levels):
-        size[t, n] += _kids(ens, t, n, size)[0].sum(axis=1)
-    pre = np.zeros((T, N), np.int64)
-    for t, n in levels:
-        below, kids = _kids(ens, t, n, size)
-        for side, first in ((0, pre[t, n] + 1),
-                            (1, pre[t, n] + 1 + below[:, 0])):
-            has = kids[:, side] >= 0
-            pre[t[has], kids[has, side]] = first[has]
-    t_idx, n_idx = np.nonzero(live)
-    by_pre = np.lexsort((pre[t_idx, n_idx], t_idx))
-    t_idx, n_idx = t_idx[by_pre], n_idx[by_pre]     # trees, each in pre-order
-    # a root's number in its tree; a node's is its root's, handed down
-    is_root = root[t_idx, n_idx]
-    subtree = np.full((T, N), -1, np.int32)
-    subtree[t_idx[is_root], n_idx[is_root]] = _rank(t_idx, is_root)[is_root]
-    for t, n in levels[1:]:
-        inner = ~root[t, n]
-        subtree[t[inner], n[inner]] = subtree[
-            t[inner], node_parent[t[inner], n[inner]]]
-    n_subtrees = np.maximum(root.sum(axis=1), 1)
-
-    # The lanes: a part's nodes in pre-order, told apart by their tile. A
-    # node only one tile may hold lies there, the others fill the first
-    # tile's room and then the second's (unbounded: all in the first).
-    part = np.concatenate([[0], np.cumsum(n_subtrees)])[t_idx] \
-        + subtree[t_idx, n_idx]
-    by_part = np.argsort(part, kind="stable")
-    part = part[by_part]
+def _numbered(flat: _Flat, pieces: tuple, only: np.ndarray | None,
+              halved: bool) -> SubtreeCut:
+    """`cut_subtrees`'s entries from the packer's pieces: the glue copies,
+    every slot's place in its entry's glued tree, and the lanes."""
+    T, M = len(flat.first) - 1, len(flat.tree)
+    at, nodes, entry = (a[np.argsort(pieces[0])] for a in pieces)
+    if M and (at[0] != 0 or (at[1:] != at[:-1] + nodes[:-1]).any()
+              or at[-1] + nodes[-1] != M):
+        raise ValueError("the cut's pieces are no partition of the nodes")
+    n_subtrees = np.ones(T, np.int64)
+    np.maximum.at(n_subtrees, flat.tree[at], entry + 1)
+    first = np.concatenate([[0], np.cumsum(n_subtrees)])
+    gid = first[flat.tree[at]] + entry          # a piece's entry, of all
+    # The glue: of an entry's pieces in pre-order, every two neighbours'
+    # lowest common ancestor (the deepest node above the later one that
+    # lies before the earlier one): the branching nodes of the pieces'
+    # common-ancestor tree, each once.
+    by_entry = np.lexsort((at, gid))
+    g, a = gid[by_entry], at[by_entry]
+    same = g[1:] == g[:-1]
+    before, glue_gid = a[:-1][same], g[1:][same]
+    glue = _common_ancestor(flat, before, a[1:][same])
+    G = len(glue)
+    # Slots: the nodes at their positions, the glue behind. The slot above
+    # a piece's root or a glue copy: the first glue copy of its own entry
+    # on the way up its tree (none at or above the entry's highest).
+    s_at = np.concatenate([np.arange(M), glue])         # the node it asks
+    s_gid = np.concatenate([np.repeat(gid, nodes), glue_gid])
+    s_up = np.concatenate([flat.up, np.full(G, -1)])
+    s_up[at] = -1
+    s_side = np.concatenate([flat.side, np.zeros(G, np.int8)])
+    if G:
+        key = glue_gid * M + glue
+        by_key = np.argsort(key)
+        key = key[by_key]
+        highest = np.full(int(first[-1]), M, np.int64)
+        np.minimum.at(highest, glue_gid, glue)
+        hangs = np.concatenate([at[highest[gid] < M], M + np.arange(G)])
+        cur = s_at[hangs]
+        while len(hangs):
+            above, sd = flat.up[cur], flat.side[cur]
+            on = above >= highest[s_gid[hangs]]
+            hangs, above, sd = hangs[on], above[on], sd[on]
+            k = np.minimum(np.searchsorted(key, s_gid[hangs] * M + above),
+                           G - 1)
+            hit = key[k] == s_gid[hangs] * M + above
+            s_up[hangs[hit]] = M + by_key[k[hit]]
+            s_side[hangs[hit]] = sd[hit]
+            hangs, cur = hangs[~hit], above[~hit]
+    # an entry's slots in the pre-order of its glued tree: that of the
+    # uncut tree, in which a glue copy stands where its node does
+    order = np.lexsort((flat.pre[s_at], s_gid))
+    part = s_gid[order]
+    lane = np.zeros(M + G, np.int64)
+    copy = None
     if halved:
-        return SubtreeCut(n_subtrees, root, subtree, *_halves(
-            levels, node_parent, root, part, t_idx[by_part], n_idx[by_part]))
-    one0, one1 = (o[t_idx, n_idx][by_part] for o in only)
-    free = ~(one0 | one1)
-    room = (PATH_LANES if only.any() else lanes) \
-        - np.bincount(part, weights=one0)[part]
-    second = one1 | (free & (_rank(part, free) >= room))
-    by_tile = np.lexsort((np.arange(len(part)), second, part))
-    at = by_part[by_tile]
-    lane = np.zeros((T, N), np.int32)
-    lane[t_idx[at], n_idx[at]] = PATH_LANES * second[by_tile] + _rank(
-        (2 * part + second)[by_tile])
-    return SubtreeCut(n_subtrees, root, subtree, lane)
+        lane, copy = _halves(flat, s_at, s_up, order, part)
+    elif only is None:
+        lane[order] = _rank(part)
+    else:
+        # told apart by their tile: a slot only one tile may hold lies
+        # there, the others fill the first tile's room and then the second's
+        one0, one1 = only[:, s_at[order]]
+        free = ~(one0 | one1)
+        room = PATH_LANES - np.bincount(part, weights=one0)[part]
+        second = one1 | (free & (_rank(part, free) >= room))
+        by_tile = np.lexsort((np.arange(len(part)), second, part))
+        lane[order[by_tile]] = PATH_LANES * second[by_tile] + _rank(
+            (2 * part + second)[by_tile])
+    # ... the nodes' slots in the order of the node list
+    slot = np.concatenate([flat.first[flat.tree] + flat.node,
+                           M + np.arange(G)])
+
+    def by_slot(values, dtype):
+        out = np.empty(M + G, dtype)
+        out[slot] = values
+        return out
+
+    root = np.zeros(M + G, bool)
+    root[at] = True
+    s_tree = flat.tree[s_at]
+    return SubtreeCut(
+        n_subtrees, by_slot(s_tree, np.int64),
+        by_slot(flat.node[s_at], np.int64), by_slot(root, bool),
+        by_slot(s_gid - first[s_tree], np.int32), by_slot(lane, np.int32),
+        by_slot(copy, np.int32) if halved else None,
+        by_slot(np.where(s_up >= 0, slot[s_up], -1), np.int64),
+        by_slot(s_side, np.int8))
 
 
-def _halves(levels: list, node_parent: np.ndarray, root: np.ndarray,
-            part: np.ndarray, t_idx: np.ndarray, n_idx: np.ndarray) -> tuple:
-    """`SubtreeCut`'s `lane` and `copy` of HALVED sub-trees (`cut_subtrees`):
-    `part` (sorted) the part of node (`t_idx`, `n_idx`), a part's nodes in
-    pre-order."""
-    depth = np.zeros(root.shape, np.int64)      # nodes above it in its part
-    for t, n in levels[1:]:
-        depth[t, n] = np.where(root[t, n], 0, depth[t, node_parent[t, n]] + 1)
-    pos, deep = _rank(part), depth[t_idx, n_idx]
-    starts = np.nonzero(pos == 0)[0]            # a part's first node
+def _halves(flat: _Flat, s_at: np.ndarray, s_up: np.ndarray,
+            order: np.ndarray, part: np.ndarray) -> tuple:
+    """The lanes and the spine copies' lanes of HALVED entries
+    (`cut_subtrees`), a slot each: `order` the slots by entry and in it in
+    pre-order, `part` (sorted) their entries."""
+    # slots above it in its entry: a slot's parent lies higher in the tree
+    depth = np.zeros(len(s_at), np.int64)
+    by_level = np.argsort(flat.depth[s_at], kind="stable")
+    for level in np.split(by_level, np.cumsum(np.bincount(
+            flat.depth[s_at]))[:-1]) if len(s_at) else ():
+        level = level[s_up[level] >= 0]
+        depth[level] = depth[s_up[level]] + 1
+    pos, deep = _rank(part), depth[order]
+    starts = np.nonzero(pos == 0)[0]            # an entry's first slot
     nodes = np.diff(np.append(starts, len(part)))
     seg = np.cumsum(pos == 0) - 1
-    # k: the second half begins at the part's node k, behind copies of the
-    # `deep` nodes above it, and hangs one exit a node and one more a tree
+    # k: the second half begins at the entry's slot k, behind copies of the
+    # `deep` slots above it, and hangs one exit a node and one more a tree
     # of its nodes (at most `deep` + 1 of them) in lanes of its own. The
-    # fewest copies; of those the fullest first half. A part whose exits
+    # fewest copies; of those the fullest first half. An entry whose exits
     # fit one half has no second one.
     fits = (pos >= 1) & (pos <= PATH_LANES) & (
         nodes[seg] - pos + deep + 1 <= PATH_LANES)
     never = 2 * PATH_LANES * PATH_LANES
     best = np.minimum.reduceat(np.where(
-        fits, deep * (PATH_LANES + 1) + PATH_LANES - pos, never), starts)
+        fits, deep * (PATH_LANES + 1) + PATH_LANES - pos, never), starts) \
+        if len(part) else nodes
     one = nodes < PATH_LANES
     if (~one & (best == never)).any():
         raise ValueError("a sub-tree's nodes fit no two halves of "
@@ -1378,53 +1611,65 @@ def _halves(levels: list, node_parent: np.ndarray, root: np.ndarray,
                          "halved bound")
     k = np.where(one, nodes, PATH_LANES - best % (PATH_LANES + 1))[seg]
     spine = np.where(one, 0, best // (PATH_LANES + 1))[seg]
-    lane = np.zeros(root.shape, np.int32)
-    lane[t_idx, n_idx] = np.where(pos < k, pos, PATH_LANES + spine + pos - k)
-    copy = np.zeros(root.shape, np.int32)
-    t, n = t_idx[pos == k], n_idx[pos == k]     # node k, no root: k >= 1
-    while len(t):
-        n = node_parent[t, n]
-        copy[t, n] = PATH_LANES + depth[t, n]
-        t, n = t[~root[t, n]], n[~root[t, n]]
+    lane = np.zeros(len(s_at), np.int64)
+    lane[order] = np.where(pos < k, pos, PATH_LANES + spine + pos - k)
+    copy = np.zeros(len(s_at), np.int64)
+    x = s_up[order[pos == k]]                   # slot k is no top: k >= 1
+    while len(x):
+        copy[x] = PATH_LANES + depth[x]
+        x = s_up[x]
+        x = x[x >= 0]
     return lane, copy
 
 
 def cut_subtrees(ens: NodeListEnsemble, lanes: int,
                  spans: tuple | None = None,
                  halved: bool = False) -> SubtreeCut:
-    """Every tree cut into connected SUB-TREES of at most `lanes` - 1
-    internal nodes, so of at most `lanes` EXITS (an exit is a child that
-    is a leaf, or a link: a child that roots another sub-tree). A
-    partition: every internal node lies in one sub-tree, every sub-tree is
-    connected and hangs by its root alone, and the tree's root roots
-    sub-tree 0; a parent sub-tree is numbered before its children.
+    """Every tree cut into the ENTRIES of the sub-tree form, each of at
+    most `lanes` - 1 node lanes and so of at most `lanes` EXITS (an exit is
+    a child that is a leaf, or a link: a child that lies in another entry).
+    Every internal node lies in ONE entry; an entry holds one or several
+    connected PIECES of its tree, none above another, each piece's root
+    hanging on a node of an EARLIER entry of the tree (the tree's root lies
+    in entry 0), and p pieces are GLUED into one binary tree by p - 1
+    copies of their lowest common ancestors: a copy asks its node's
+    question in a lane of its own, hangs no exit and has one piece (or
+    copy) on either side. Every row that reaches a piece passed the nodes
+    the copies stand for and answered them as the copies do, so of an
+    active entry's exits exactly one fires, the one the node walk takes.
 
-    Bottom-up (Kundu and Misra, 1977: the fewest parts of a tree under a
-    bound on a part's weight): a node keeps its children's remainders
-    while they fit beside it and otherwise cuts the HEAVIER child off as
-    a root of its own. A tree of one leaf is one sub-tree of no node.
+    The cut FILLS its entries (connected parts come out ragged: 178 of 255
+    lanes in the XGBoost model, PERF.md PR 53). A tree keeps a frontier of
+    the sub-trees whose parent lies in a closed entry, at first the tree
+    itself. An entry takes the largest frontier sub-tree that fits WHOLE
+    beside what it holds, again and again; then a TOP piece of the largest
+    one left: as long a prefix of its pre-order as fits, the heavier child
+    first, which leaves the lighter sub-trees to the frontier, and those
+    pack whole. The children
+    a top piece cuts off join the frontier when the entry closes. A tree
+    that fits an entry is ONE piece; a tree of one leaf one entry of no
+    node.
 
-    `spans`: the K-blocks of the feature select that each of a sub-tree's
+    `spans`: the K-blocks of the feature select that each of an entry's
     TWO 128-lane tiles reads, ((0, stop), (start, blocks)) with start <=
-    stop (`CompiledNodeList.select_spans`). A part then holds three bounds,
-    so that its nodes can be numbered with every lane's K-block inside its
-    tile's span: the nodes whose block only the first tile reads (below
-    `start`) are at most 128, those only the second reads (from `stop`)
-    at most 128, all of them at most `lanes` - 1; the child cut off is
-    the heaviest in the weight that is over. The numbers: a node that only
-    one tile reads lies there, the others fill the first tile's room and
-    then the second's; inside a tile the pre-order. Without spans (or with
-    dense ones, which bound nothing) the lanes are the pre-order, 0.. with
-    no gap.
+    stop (`CompiledNodeList.select_spans`). An entry then holds three
+    bounds, glue copies counted, so that its slots can be numbered with
+    every lane's K-block inside its tile's span: those whose block only the
+    first tile reads (below `start`) are at most 128, those only the second
+    reads (from `stop`) at most 128, all of them at most `lanes` - 1. The
+    numbers: a slot that only one tile reads lies there, the others fill
+    the first tile's room and then the second's; inside a tile the
+    pre-order. Without spans (or with dense ones, which bound nothing) the
+    lanes are the pre-order, 0.. with no gap. `halved`: two halves of 128
+    lanes that share their spine (`_halves`), for which an entry's slots
+    and those on its longest path are at most `lanes` - 1 together.
 
     No [N, L] matrix of a whole tree is made, and no node is walked in
-    Python: a level of all the trees a step (0.4 s for 100 trees of 4,000
-    leaves)."""
-    node_parent = ens._parents()[0]     # in range, one parent each
-    levels = _levels(ens)
+    Python: a level of all the trees, or an entry of each, a step."""
+    flat = _flat(ens)
     spans = spans or dense_spans(ens.n_features, lanes)
-    root, only = _subtree_roots(ens, levels, lanes, spans, halved)
-    return _numbered(ens, levels, node_parent, root, only, lanes, halved)
+    only = _tile_bound(ens, flat, lanes, spans, halved)
+    return _numbered(flat, _filled(flat, lanes, only, halved), only, halved)
 
 
 def subtree_mxu_tiles(spans: tuple, lanes: int, exit_lanes: int,
@@ -1472,15 +1717,14 @@ def exit_table_lanes(leaf_columns: int, n_subtrees: np.ndarray) -> tuple:
     return class_lanes + act_lanes, class_lanes
 
 
-def choose_select_spans(ens: NodeListEnsemble, lanes: int,
-                        node_parent: np.ndarray | None = None) -> tuple:
+def choose_select_spans(ens: NodeListEnsemble, lanes: int) -> tuple:
     """(`CompiledNodeList.select_spans`, the model's cut under them): which
     K-blocks of the feature select each 128-lane tile of a sub-tree reads,
     from what the model shows. A node's one K row lies in ONE K-block, and
     which block a lane reads is decided by the order of the lanes alone, so
     lanes ordered by their column's block let a tile skip the blocks none
     of its nodes reads (all-zero weight tiles), at the price of a cut that
-    holds a bound a tile (`cut_subtrees`) and so makes a few more parts.
+    holds a bound a tile (`cut_subtrees`) and so makes a few more entries.
 
     The candidates split the blocks at the one in which the cumulative
     share of the model's internal nodes passes one half: that block read by
@@ -1495,10 +1739,9 @@ def choose_select_spans(ens: NodeListEnsemble, lanes: int,
     6 tiles a sub-tree against 8), the blocks' spans where the columns
     split well (the MNIST forest: 13 against the halves' 18). Sub-trees of
     another width than two tiles, a model of no internal node: the dense
-    spans. `node_parent`: `ens._parents()`'s, where the caller has it."""
-    if node_parent is None:
-        node_parent = ens._parents()[0]
-    levels = _levels(ens)
+    spans. Every candidate is counted by the cut it would get, the PACKED
+    one."""
+    flat = _flat(ens)
     dense = dense_spans(ens.n_features, lanes)
     blocks = dense[0][1]
     candidates = [(dense, False)]
@@ -1512,10 +1755,10 @@ def choose_select_spans(ens: NodeListEnsemble, lanes: int,
                     (h + 1, h), (h + 1, h + 1), (h, h))
                 if stop > 0 and start < blocks]
         candidates.append((dense, True))
-    # No cut makes fewer parts than a tree's nodes over a part's most, nor
-    # an exits' table under one tile: a candidate that cannot ask fewer
-    # tiles than the best cut so far is not cut (a cut is a walk of every
-    # node). The cheapest bound first; of equal counts the earlier listed.
+    # No cut makes fewer entries than a tree's nodes over an entry's most,
+    # nor an exits' table under one tile: a candidate that cannot ask fewer
+    # tiles than the best cut so far is not cut. The cheapest bound first;
+    # of equal counts the earlier listed.
     fewest = int(np.maximum(-(-(ens.n_leaves.astype(np.int64) - 1)
                               // (lanes - 1)), 1).sum())
     bound = [fewest * subtree_mxu_tiles(spans, lanes, PATH_LANES, halved)
@@ -1525,16 +1768,17 @@ def choose_select_spans(ens: NodeListEnsemble, lanes: int,
         if best is not None and (bound[i], i) > best[:2]:
             continue
         spans, halved = candidates[i]
-        root, only = _subtree_roots(ens, levels, lanes, spans, halved)
-        per_tree = np.maximum(root.sum(axis=1), 1)
+        only = _tile_bound(ens, flat, lanes, spans, halved)
+        pieces = _filled(flat, lanes, only, halved)
+        per_tree = np.ones(ens.n_trees, np.int64)
+        np.maximum.at(per_tree, flat.tree[pieces[0]], pieces[2] + 1)
         tiles = int(per_tree.sum()) * subtree_mxu_tiles(
             spans, lanes, exit_table_lanes(ens.leaf_columns, per_tree)[0],
             halved)
         if best is None or (tiles, i) < best[:2]:
-            best = (tiles, i, spans, halved, root, only)
-    *_, spans, halved, root, only = best
-    return spans, _numbered(ens, levels, node_parent, root, only, lanes,
-                            halved)
+            best = (tiles, i, spans, halved, only, pieces)
+    *_, spans, halved, only, pieces = best
+    return spans, _numbered(flat, pieces, only, halved)
 
 
 # bfloat16 pieces a float32 leaf value is held in (`split_bfloat16`; the
@@ -1581,7 +1825,13 @@ class CompiledNodeList:
     than `PATH_UNCUT_LANES` lanes): the same three tables with one entry a
     SUB-TREE of `cut_subtrees` (W = `SUBTREE_LANES`; S entries, a tree's
     in a row, parents before children), a sub-tree's "leaves" its EXITS,
-    and a fourth table that says what an exit is. A sub-tree's nodes are
+    and a fourth table that says what an exit is. An entry holds one
+    connected piece of its tree or SEVERAL (`pieces` over all entries),
+    glued into one binary tree by copies of their lowest common ancestors
+    (`glue_copies`): a copy has its node's K row, threshold and NaN bound in
+    a lane of its own, hangs no exit, and lies on the path of every exit
+    below it with the sign of the side that exit's piece hangs on; a link
+    goes to the entry that holds the child. A sub-tree's lanes are
     numbered BY THE K-BLOCK OF THEIR COLUMN: `select_spans` says for each of
     its 128-lane tiles which K-blocks of 128 columns the nodes there may
     read, ((0, 3), (3, 7)) at the MNIST forest's 784 columns (the same for
@@ -1596,8 +1846,8 @@ class CompiledNodeList:
     or, HALVED (`cut_subtrees`; `choose_select_spans` takes it where it asks
     the fewest tiles: every model of one K-block), as two halves of 128
     lanes that share their spine: the second half from lane 128, copies of
-    the nodes above its first node and then its own; a copy has its node's
-    K row, threshold and NaN bound and hangs no exit; the exits of a half
+    the slots (nodes or glue copies) above its first and then its own; a
+    copy has its node's K row, threshold and NaN bound and hangs no exit; the exits of a half
     lie in the half's own 128 exit lanes (`planes` rows 1, `leaves`' rows);
     and `paths` is the two diagonal blocks alone, side by side:
 
@@ -1664,6 +1914,8 @@ class CompiledNodeList:
     subtrees_max: int = 1      # the largest tree's entries ...
     single_subtree_trees: int = 0   # ... and the trees that are ONE entry
     spine_copies: int = 0      # halved: the lanes that hold a node's copy
+    pieces: int = 0            # the connected pieces of the S entries ...
+    glue_copies: int = 0       # ... and the lanes that hold a glue copy
 
     @property
     def n_classes_out(self) -> int:
@@ -1737,23 +1989,24 @@ class CompiledNodeList:
         bf16 = ml_dtypes.bfloat16
         T, N = ens.feature.shape
         W, C = SUBTREE_LANES, ens.leaf_columns    # the module's, as it is
-        node_parent, node_side, _, _ = ens._parents()
-        spans, cut = choose_select_spans(ens, W, node_parent)
+        spans, cut = choose_select_spans(ens, W)
         halved = cut.copy is not None
         deepest = len(_levels(ens))     # the levels that hold a node
         first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
         S = int(first[-1])
         exit_lanes, link0 = exit_table_lanes(C, cut.n_subtrees)
-        t_idx, n_idx = np.nonzero(ens.live_nodes)
-        at = first[t_idx] + cut.subtree[t_idx, n_idx]      # the entry
-        ln = cut.lane[t_idx, n_idx]
-        # the lanes that ask a node's question: its own, and (halved) its
-        # copy's in the second half, which hangs no exit
+        # the cut's slots: the nodes, and behind them the entries' glue
+        t_idx, n_idx = cut.tree, cut.origin
+        own = np.arange(len(t_idx)) < ens.n_splits
+        at = first[t_idx] + cut.subtree         # the entry
+        ln = cut.lane
+        # the lanes that ask a node's question: its own, a glue copy's,
+        # and (halved) a spine copy's in the second half; a copy hangs no
+        # exit
         asks = [(t_idx, n_idx, at, ln)]
         if halved:
-            ct, cn = np.nonzero(cut.copy)
-            asks.append((ct, cn, first[ct] + cut.subtree[ct, cn],
-                         cut.copy[ct, cn]))
+            c = np.nonzero(cut.copy)[0]
+            asks.append((t_idx[c], n_idx[c], at[c], cut.copy[c]))
         sel = np.zeros((S, Fp, W), bf16)
         planes = np.zeros((S, 8, W), np.float32)
         planes[:, 0, :] = 2.0 ** 30
@@ -1767,14 +2020,16 @@ class CompiledNodeList:
             if ens.missing_routes:
                 left = ens.default_left[t, n]
                 planes[entry[left], 3, lane[left]] = ens.missing_bin_value
-        # The exits: every child that is a leaf or roots a sub-tree, in
-        # the order of (entry, the node's lane, left before right); halved,
-        # a run a half: the second half's nodes' exits from lane 128.
+        # The exits: every child of a node that is a leaf or lies in another
+        # entry, in the order of (entry, the node's lane, left before
+        # right); halved, a run a half: the second half's nodes' exits from
+        # lane 128.
         child = np.stack([ens.left_child[t_idx, n_idx],
                           ens.right_child[t_idx, n_idx]], 1).astype(np.int64)
-        linked = (child >= 0) & cut.root[t_idx[:, None],
-                                         np.maximum(child, 0)]
-        is_exit = (child < 0) | linked
+        node0 = _first_nodes(ens)[t_idx]        # the slot of its tree's
+        linked = (child >= 0) & (cut.subtree[
+            node0[:, None] + np.maximum(child, 0)] != cut.subtree[:, None])
+        is_exit = ((child < 0) | linked) & own[:, None]
         order = np.lexsort((ln, at))
         # [nodes, 2] in that order; an exit's lane its rank in the entry
         e_node, e_side = np.nonzero(is_exit[order])
@@ -1803,8 +2058,8 @@ class CompiledNodeList:
 
         real = e_child < 0
         put_leaves(e_at[real], e_lane[real], e_tree[real], ~e_child[real])
-        to = first[e_tree[~real]] + cut.subtree[e_tree[~real],
-                                                e_child[~real]]
+        to = first[e_tree[~real]] + cut.subtree[
+            node0[e_node[~real]] + e_child[~real]]
         leaves[e_at[~real], e_lane[~real],
                link0 + (to - e_at[~real] - 1)] = 1.0
         # A tree of one leaf: an entry of no node whose exit 0 has a path
@@ -1812,8 +2067,9 @@ class CompiledNodeList:
         lone = np.nonzero(ens.n_leaves == 1)[0]
         planes[first[lone], 1, 0] = 0.0
         put_leaves(first[lone], 0, lone, np.zeros(len(lone), np.int64))
-        # An exit's path inside its sub-tree: up from the node it hangs
-        # on to the sub-tree's root, all exits a step.
+        # An exit's path inside its entry: up from the node it hangs on
+        # to the entry's top (through the glue copies above its piece),
+        # all exits a step.
         # (written as bfloat16's bits: +1 is 0x3F80 and -1 0xBF80; a cast
         # of 132M int8 entries to bfloat16 took 10 s of a 12 s build)
         # Halved, the two diagonal [128, 128] blocks alone, side by side:
@@ -1821,13 +2077,13 @@ class CompiledNodeList:
         # (a second half's exit reads its first-half ancestors' COPIES).
         paths = np.zeros((S, W // 2 if halved else W, W), np.uint16)
         plen = np.zeros((S, W), np.float32)
-        tt, cur, ex = e_tree, n_idx[e_node], np.arange(len(e_at))
+        cur, ex = e_node, np.arange(len(e_at))
         sign = np.where(e_side == 0, -1, 1).astype(np.int8)
-        while len(tt):
-            lane = cut.lane[tt, cur]
+        while len(cur):
+            lane = cut.lane[cur]
             if halved:
                 lane = np.where(e_half[ex] & (lane < PATH_LANES),
-                                cut.copy[tt, cur], lane)
+                                cut.copy[cur], lane)
                 if ((lane >= PATH_LANES) != e_half[ex]).any():
                     raise ValueError("halved sub-trees: an exit's path "
                                      "leaves the exit's half")
@@ -1835,9 +2091,9 @@ class CompiledNodeList:
             paths[e_at[ex], lane, e_lane[ex]] = np.where(
                 sign > 0, 0x3F80, 0xBF80)
             plen[e_at[ex], e_lane[ex]] += 1.0     # (an exit once a step)
-            up = ~cut.root[tt, cur]
-            sign, cur = node_side[tt, cur], node_parent[tt, cur]
-            tt, cur, ex, sign = tt[up], cur[up], ex[up], sign[up]
+            sign, cur = cut.side[cur], cut.up[cur]
+            up = cur >= 0
+            cur, ex, sign = cur[up], ex[up], sign[up]
         planes[e_at, 1, e_lane] = plen[e_at, e_lane]
         return CompiledNodeList(
             token=ens.cache_token(),
@@ -1850,7 +2106,10 @@ class CompiledNodeList:
             widest_tree=widest, select_spans=spans,
             subtrees_max=int(cut.n_subtrees.max()),
             single_subtree_trees=int((cut.n_subtrees == 1).sum()),
-            spine_copies=int(np.count_nonzero(cut.copy)) if halved else 0)
+            spine_copies=int(np.count_nonzero(cut.copy)) if halved else 0,
+            pieces=int(np.count_nonzero(cut.root)) + int(
+                (ens.n_leaves == 1).sum()),
+            glue_copies=int(np.count_nonzero(~own)))
 
 
 # ---------------------------------------------------------------------- #

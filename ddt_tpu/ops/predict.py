@@ -59,10 +59,16 @@ form below is the fallback and what a CPU runs; the Pallas kernel is
 `ops/predict_paths.py`, dispatched by the same rule.
 
 The chain. A tree of more lanes than one path matrix should hold, or one
-whose leaves are VECTORS (an averaged forest), is cut into connected
-SUB-TREES of at most 256 lanes (models/tree.cut_subtrees), each an entry of
-the same three tables whose "leaves" are its EXITS: real leaves, and links
-to the sub-tree that hangs there. Per sub-tree k of a tree, parents first,
+whose leaves are VECTORS (an averaged forest), is cut into SUB-TREES of at
+most 256 lanes (models/tree.cut_subtrees), each an entry of the same three
+tables whose "leaves" are its EXITS: real leaves, and links to the sub-tree
+that holds the child. A sub-tree is one connected piece of its tree or
+(PR 53) SEVERAL, glued into one binary tree by copies of their lowest
+common ancestors, which ask their nodes' questions again and hang no exit: a
+row that reaches a piece answered those nodes on its way, so the glued
+tree's walk ends in the exit the tree's own walk takes, and a row that
+reaches none of them is not active there. Per sub-tree k of a tree, parents
+first (every piece of k hangs on an earlier sub-tree of the tree),
 with e_k[r, x] = (m_k[r, x] == len_k[x]) as above (the exit the row would
 take from k's root),
 

@@ -86,12 +86,24 @@ THE CHAIN: the SUB-TREE form (`class_lanes` > 0; ops/predict.py has the
 equations, models/tree.CompiledNodeList the tables). A tree of more lanes
 than one path matrix should hold (past 512: the resolve is quadratic in W),
 or one whose leaves are VECTORS (an averaged forest: a class distribution a
-leaf, the mean over the trees), is cut on the host into connected sub-trees
-of at most 256 lanes, and a table entry is a SUB-TREE: the grid's block
-axis walks blocks of G sub-trees, a tree's in a row, parents first. A
-sub-tree's v, s and m are the tree's above; its "leaves" are its EXITS, and
-what an exit means is a fourth table, `leaves` [W, E] bf16, against which
-the exit one-hot e = (m == len) is multiplied ONCE, y = e @ leaves [rows, E].
+leaf, the mean over the trees), is cut on the host into sub-trees of at most
+256 lanes (models/tree.cut_subtrees), and a table entry is a SUB-TREE: the
+grid's block axis walks blocks of G sub-trees, a tree's in a row, parents
+first. A sub-tree's v, s and m are the tree's above; its "leaves" are its
+EXITS, and what an exit means is a fourth table, `leaves` [W, E] bf16,
+against which the exit one-hot e = (m == len) is multiplied ONCE, y = e @
+leaves [rows, E]. The kernel never sees whether an entry is CONNECTED, and
+since PR 53 it need not be: an entry holds one or SEVERAL connected pieces
+of its tree, glued into one binary tree by copies of their lowest common
+ancestors (a copy asks its node's question in a lane of its own and hangs no
+exit, as a halved entry's spine copies do). Every row that reaches one of
+the pieces passed those ancestors and answered them as the copies do, so of
+an active entry's exits exactly one fires, the one the node walk takes; an
+entry's pieces hang on EARLIER entries of the tree, possibly several, whose
+links write the same activity lane (a row follows one chain: `a` stays 0 or
+1). The cut FILLS its entries so (`pieces_per_subtree`,
+`glue_copies_per_subtree` on the span): the entries' count is the other
+factor of this kernel's time beside the tiles an entry.
 
 A row of the table: a real leaf's float32 vector as THREE bfloat16 pieces in
 lanes of their own (`class_dot_passes` 3; piece p's column c in lane p C +
@@ -161,9 +173,10 @@ diagonal ones side by side ([S, 128, 256] bf16, 64 KB an entry where 128:
 two [rows, 128] x [128, 128] products where one [rows, 256] x [256, 256]:
 the same integers, the same exit for every (row, sub-tree). Under the packed
 select the halves are the low bytes' lanes and the high bytes', as they come.
-The price is the copies' lanes and a cut that holds one bound more (a part's
-nodes and the nodes on its longest path at most 255 together: 5% more
-sub-trees for the XGBoost model, 1.9 copies a sub-tree); which models take it
+The price is the copies' lanes and a cut that holds one bound more (an
+entry's slots and those on its longest path at most 255 together: 7% more
+sub-trees for the XGBoost model than its nodes alone would ask, 4.5 copies a
+sub-tree since PR 53 fills the entries, 1.9 before); which models take it
 is `models/tree.choose_select_spans`'s to say, by the fewest weight tiles a
 tree: those whose select is one K-block (F <= 128), and none whose lanes are
 ordered by K-blocks (the MNIST forest's select would be asked whole in both
@@ -475,6 +488,11 @@ class PathPlan(typing.NamedTuple):
     #   lanes: the copies of a first half's nodes a second half holds, on
     #   average (the backend fills it; `subtrees_per_tree` says what the
     #   halves' bound cost the cut)
+    pieces_per_subtree: float = 1.0     # connected pieces of its tree an
+    #   entry holds, on average (1.0: nothing packed) ...
+    glue_copies_per_subtree: float = 0.0    # ... and the lanes that hold a
+    #   copy of the pieces' common ancestors (models/tree.cut_subtrees;
+    #   the backend fills both)
 
     @property
     def blocks(self) -> int:
@@ -506,7 +524,8 @@ CHAIN_COUNTS = ("subtrees_per_tree", "subtrees_per_tree_max",
                 "single_subtree_trees", "subtree_lanes", "leaf_columns",
                 "link", "chain_mxu_tiles_per_tree", "class_dot_passes",
                 "select_mxu_tiles", "exit_mxu_tiles", "resolve_mxu_tiles",
-                "spine_copies_per_subtree")
+                "spine_copies_per_subtree", "pieces_per_subtree",
+                "glue_copies_per_subtree")
 SPAN_COUNTS = ("node_list", "nodes_per_tree", "leaves_per_tree",
                "deepest_leaf", "path_mxu_tiles_per_tree", "trees_per_step",
                "table_blocks", "table_bytes", "select_k_blocks",

@@ -461,8 +461,9 @@ def kernel_cases() -> list:
                    _paths_case(BOSCH["chunk_rows"], BOSCH["features"], 20,
                                255, missing=True)),
         # The SUB-TREE form (the chain and the class dot): the MNIST
-        # forest's chunk (100 full-depth trees of up to 4,779 leaves: 2,112
-        # sub-trees of 256 lanes over 784 columns, 10 classes, the first
+        # forest's chunk (100 full-depth trees of up to 4,779 leaves: 1,695
+        # sub-trees of 256 lanes since PR 53 packs their entries, 2,112
+        # before, over 784 columns, 10 classes, the first
         # lane tile reading K-blocks 0-2 and the second 3-6 as the build
         # finds for that forest: 7 select tiles a sub-tree), the same with
         # a K-block both tiles read, and
@@ -475,7 +476,7 @@ def kernel_cases() -> list:
         # under the 200-part trees' chain.
         KernelCase("forest/784f/100x4779x10", True,
                    _forest_case(FOREST["chunk_rows"], FOREST["features"],
-                                100, 2112, 10, most_subtrees=25,
+                                100, 1695, 10, most_subtrees=20,
                                 select_spans=((0, 3), (3, 7)))),
         KernelCase("forest/784f/12x20subtrees/shared-block", True,
                    _forest_case(4_999, FOREST["features"], 12, 245, 10,

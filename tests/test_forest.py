@@ -47,56 +47,171 @@ def scored(ens, Xb, impl):
         backend="tpu", predict_impl=impl, n_bins=BINS))
 
 
+# the slot of every tree's node 0 (`SubtreeCut`: node n of tree t is slot
+# first_node(ens)[t] + n), and behind the last the nodes' count
+first_node = tree._first_nodes
+
+
+def mnist_forest(n_trees):
+    """The first `n_trees` trees of the benchmark's forest (its drawing, its
+    forest seed) and the cell's shapes."""
+    import importlib.util
+    import pathlib
+
+    bench = pathlib.Path(__file__).parent.parent / "benchmark"
+    spec = importlib.util.spec_from_file_location(
+        "_datagen_forest", bench / "datagen_forest.py")
+    datagen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(datagen)
+    cell = json.loads((bench / "configs/mnist-rf-100t-full.json").read_text())
+    sh = cell["shapes"]
+    t = datagen.grown_forest(n_trees, sh["features"], sh["n_bins"],
+                             sh["n_classes"], sh["forest_seed"],
+                             **cell["assumed"]["drawing"])
+    zeros = np.zeros(t["feature"].shape, np.float32)
+    return NodeListEnsemble(
+        feature=t["feature"], threshold_bin=t["threshold_bin"],
+        threshold_raw=zeros, left_child=t["left_child"],
+        right_child=t["right_child"], leaf_value=t["leaf_value"],
+        n_leaves=t["n_leaves"], split_gain=zeros, n_features=sh["features"],
+        learning_rate=1.0, base_score=0.0, loss="mean", n_classes=10,
+        n_bins=sh["n_bins"]), sh
+
+
+def slots_of(ens, cut, t):
+    """Tree t's slots: its nodes' (in their order), its glue copies'."""
+    base = first_node(ens)
+    return np.arange(base[t], base[t + 1]), ens.n_splits + np.nonzero(
+        cut.tree[ens.n_splits:] == t)[0]
+
+
 def chain_depth(ens, cut, t):
-    """Sub-trees on the longest chain of tree t (1: the tree is uncut)."""
+    """Entries on the longest chain of tree t (1: the tree is uncut)."""
     parent = ens._parents()[0][t]
-    depth = {}
-    for n in np.argsort(cut.subtree[t])[(cut.subtree[t] < 0).sum():]:
-        if cut.root[t, n]:
+    own, _ = slots_of(ens, cut, t)
+    sub, depth = cut.subtree[own], {}
+    for n in np.argsort(sub, kind="stable"):
+        if cut.root[own][n]:
             up = parent[n]
-            depth[cut.subtree[t, n]] = 1 + (
-                depth[cut.subtree[t, up]] if up >= 0 else 0)
+            depth[sub[n]] = max(depth.get(sub[n], 1), 1 + (
+                depth[sub[up]] if up >= 0 else 0))
     return max(depth.values(), default=1)
 
 
-# ---------------------------------------------------------------------- #
-# the cut
-# ---------------------------------------------------------------------- #
+def glued(cut, mine):
+    """An entry's slots `mine` in the pre-order of its glued tree (left
+    before right), and the slots above each in the entry."""
+    kids = {}
+    for s in mine:
+        kids.setdefault(int(cut.up[s]), []).append(int(s))
+    top, = kids[-1]
+    pre, depth, stack = [], {}, [(top, 0)]
+    while stack:
+        s, d = stack.pop()
+        pre.append(s)
+        depth[s] = d
+        stack += [(c, d + 1) for c in sorted(
+            kids.get(s, []), key=lambda c: -cut.side[c])]
+    assert sorted(pre) == sorted(int(s) for s in mine)    # one tree, whole
+    return pre, depth
+
+
+def entries_of(ens, cut, lanes):
+    """What an ENTRY of the cut is (models/tree.cut_subtrees), held over
+    every tree; yields (tree, entry, its slots in the glued tree's
+    pre-order, the slots above each, its exits a slot) for the caller's
+    own bounds. Every internal node in ONE entry; an entry's pieces each
+    connected, none above another, each piece's parent in an EARLIER entry
+    of its tree; p pieces glued by p - 1 copies, each the lowest common
+    ancestor it stands for, a piece or copy on either side of it; nodes and
+    copies at most `lanes` - 1, exits at most `lanes`."""
+    M, base = ens.n_splits, first_node(ens)
+    parent, hangs = ens._parents()[:2]
+    assert len(cut.tree) >= M and not cut.root[M:].any()
+    assert np.array_equal(cut.origin[:M], np.nonzero(ens.live_nodes)[1])
+    assert np.array_equal(cut.tree[:M], np.nonzero(ens.live_nodes)[0])
+
+    def above(t, n):                    # the node's ancestors, itself first
+        out = [n]
+        while parent[t, out[-1]] >= 0:
+            out.append(int(parent[t, out[-1]]))
+        return out
+
+    for t in range(ens.n_trees):
+        own, glue = slots_of(ens, cut, t)
+        if not len(own):
+            assert cut.n_subtrees[t] == 1 and not len(glue)
+            continue
+        sub, root = cut.subtree[own], cut.root[own]
+        assert root[0] and sub[0] == 0
+        assert sorted(set(sub)) == list(range(cut.n_subtrees[t]))
+        # a piece is connected, by the tree's own links: a node that roots
+        # none hangs on a node of its entry; a piece's root on an EARLIER
+        # entry's node (a link's target, its parent numbered before it)
+        inner, hung = np.nonzero(~root)[0], np.nonzero(root)[0][1:]
+        assert (sub[parent[t, inner]] == sub[inner]).all()
+        assert (cut.up[own[inner]] == base[t] + parent[t, inner]).all()
+        assert (cut.side[own[inner]] == hangs[t, inner]).all()
+        assert (sub[parent[t, hung]] < sub[hung]).all()
+        kids = np.stack([ens.left_child[t, :len(own)],
+                         ens.right_child[t, :len(own)]], 1)
+        n_exits = ((kids < 0) | (sub[np.maximum(kids, 0)] != sub[:, None])
+                   ).sum(axis=1)
+        for k in range(cut.n_subtrees[t]):
+            tops = np.nonzero(root & (sub == k))[0]
+            copies = glue[cut.subtree[glue] == k]
+            assert len(tops) >= 1 and len(copies) == len(tops) - 1
+            chains = {int(r): above(t, int(r)) for r in tops}
+            lcas = set()
+            for i, a in enumerate(tops):
+                for b in tops[i + 1:]:
+                    assert a not in chains[int(b)] and b not in chains[int(a)]
+                    lcas.add(next(x for x in chains[int(a)]
+                                  if x in chains[int(b)]))
+            # (p leaves of a binary tree branch at p - 1 nodes)
+            assert sorted(cut.origin[copies]) == sorted(lcas)
+            mine = np.concatenate([own[sub == k], copies])
+            pre, depth = glued(cut, mine)
+            for s in copies:            # a copy: one slot on either side,
+                below = mine[cut.up[mine] == s]     # under that child of
+                assert sorted(cut.side[below]) == [-1, 1]   # its node
+                for c in below:
+                    chain = above(t, int(cut.origin[c]))
+                    at = chain.index(int(cut.origin[s]))
+                    assert at >= 1 and hangs[t, chain[at - 1]] == cut.side[c]
+            for r in tops:              # a piece hangs on a copy, or is all
+                assert (cut.up[own[r]] in copies) == (len(tops) > 1)
+            exits = {int(s): int(n_exits[s - base[t]]) if s < M else 0
+                     for s in mine}
+            assert len(mine) <= lanes - 1
+            assert sum(exits.values()) == len(mine) + 1 <= lanes
+            yield t, k, pre, depth, exits
+
 
 @pytest.mark.parametrize("lanes", [8, 32, 128])
 def test_the_cut_is_a_partition_into_connected_subtrees(lanes):
+    """(The name is older than the meaning: a partition of the NODES into
+    entries, an entry one or several connected pieces glued by copies of
+    their common ancestors, `entries_of`.) The lanes of an entry: the
+    pre-order of its glued tree, 0.. without a gap; and the cut FILLS: it
+    makes no fewer entries than nodes / bound, and few more."""
     ens = forest(1, 6, (1, 700), 3)
     cut = cut_subtrees(ens, lanes)
-    parent = ens._parents()[0]
-    live = ens.live_nodes
-    assert (cut.subtree[live] >= 0).all() and (cut.subtree[~live] < 0).all()
-    for t in range(ens.n_trees):
-        n_int = int(ens.n_leaves[t]) - 1
-        if n_int == 0:
-            assert cut.n_subtrees[t] == 1
-            continue
-        sub, lane, root = cut.subtree[t, :n_int], cut.lane[t, :n_int], \
-            cut.root[t, :n_int]
-        assert root[0] and sub[0] == 0
-        assert sorted(set(sub)) == list(range(cut.n_subtrees[t]))
-        # one root a sub-tree, lane 0; every other node hangs on a node of
-        # its own sub-tree (connected), every root on another's (a link's
-        # target is a root, and a parent is numbered before its child)
-        assert np.bincount(sub[root]).tolist() == [1] * cut.n_subtrees[t]
-        assert (lane[root] == 0).all()
-        inner = np.nonzero(~root)[0]
-        assert (sub[parent[t, inner]] == sub[inner]).all()
-        hung = np.nonzero(root)[0][1:]
-        assert (sub[parent[t, hung]] < sub[hung]).all()
-        # lanes: a sub-tree's nodes are numbered 0.. without a gap, and
-        # its exits (nodes + 1) fit the lane count
-        for k in range(cut.n_subtrees[t]):
-            mine = np.sort(lane[sub == k])
-            assert mine.tolist() == list(range(len(mine)))
-            assert len(mine) + 1 <= lanes
-    # the fewest parts a bound allows is no fewer than nodes / bound
-    assert (cut.n_subtrees >= np.ceil(
-        (ens.n_leaves - 1) / (lanes - 1))).all()
+    assert cut.copy is None
+    pieces = packed = 0
+    for t, k, pre, depth, exits in entries_of(ens, cut, lanes):
+        assert cut.lane[pre].tolist() == list(range(len(pre)))
+        pieces += int(cut.root[pre].sum())
+        packed += int(cut.root[pre].sum() > 1)
+    assert packed > 3 and pieces == cut.root.sum()
+    assert len(cut.tree) - ens.n_splits == pieces - (
+        cut.n_subtrees[ens.n_leaves > 1]).sum()
+    # the fewest entries a bound allows is no fewer than nodes / bound; the
+    # connected cut (Kundu and Misra's, until PR 53) made 1.11-1.6 x that
+    fewest = np.maximum(np.ceil((ens.n_leaves - 1) / (lanes - 1)), 1)
+    assert (cut.n_subtrees >= fewest).all()
+    assert cut.n_subtrees.sum() <= {8: 1.2, 32: 1.1, 128: 1.1}[lanes] \
+        * fewest.sum()
     if lanes == 128:
         assert max(chain_depth(ens, cut, t) for t in range(6)) >= 3
 
@@ -125,77 +240,50 @@ SPANS = {"dense": ((0, 7), (0, 7)), "shared": ((0, 4), (3, 7)),
 
 @pytest.mark.parametrize("name", list(SPANS))
 def test_the_cut_under_the_spans_bound(name):
-    """Still a partition into connected parts hung by their roots, parents
-    first; a part holds all three bounds and every lane's K-block lies in
-    its tile's span; the bound costs few parts."""
+    """Still entries of glued pieces, parents first (`entries_of`); an
+    entry holds all three bounds, its glue copies counted, and every lane's
+    K-block lies in its tile's span; the bound costs few entries."""
     spans = SPANS[name]
     ens = forest(61, 5, (1, 2500), 3, features=784)
     cut, free = cut_subtrees(ens, 256, spans), cut_subtrees(ens, 256)
-    parent = ens._parents()[0]
-    block = ens.feature // 128
-    for t in range(ens.n_trees):
-        n_int = int(ens.n_leaves[t]) - 1
-        if n_int == 0:
-            assert cut.n_subtrees[t] == 1
-            continue
-        sub, lane, root = cut.subtree[t, :n_int], cut.lane[t, :n_int], \
-            cut.root[t, :n_int]
-        assert root[0] and sub[0] == 0
-        assert np.bincount(sub[root]).tolist() == [1] * cut.n_subtrees[t]
-        inner, hung = np.nonzero(~root)[0], np.nonzero(root)[0][1:]
-        assert (sub[parent[t, inner]] == sub[inner]).all()
-        assert (sub[parent[t, hung]] < sub[hung]).all()
-        # a lane holds one node, and a node's K-block is its tile's to read
-        assert len(set(zip(sub, lane))) == n_int and lane.max() < 256
-        first, stop = np.array(spans)[lane // 128].T
-        assert ((block[t, :n_int] >= first) & (block[t, :n_int] < stop)).all()
-        for k in range(cut.n_subtrees[t]):
-            mine = block[t, :n_int][sub == k]
-            assert len(mine) <= 255
-            assert (mine < spans[1][0]).sum() <= 128      # only tile 0 reads
-            assert (mine >= spans[0][1]).sum() <= 128     # only tile 1
-            # inside a tile: the pre-order, with no gap
-            for tile in (0, 1):
-                here = np.sort(lane[sub == k][lane[sub == k] // 128 == tile])
-                assert here.tolist() == list(range(128 * tile,
-                                                   128 * tile + len(here)))
+    block = ens.feature[cut.tree, cut.origin] // 128    # a slot's K-block
+    first, stop = np.array(spans)[cut.lane // 128].T
+    assert ((block >= first) & (block < stop)).all() and cut.lane.max() < 256
+    packed = 0
+    for t, k, pre, depth, exits in entries_of(ens, cut, 256):
+        mine, lane = block[pre], cut.lane[pre]
+        assert (mine < spans[1][0]).sum() <= 128      # only tile 0 reads
+        assert (mine >= spans[0][1]).sum() <= 128     # only tile 1
+        # a lane a slot; inside a tile the pre-order, with no gap
+        for tile in (0, 1):
+            here = lane[lane // 128 == tile]
+            assert here.tolist() == list(range(128 * tile,
+                                               128 * tile + len(here)))
+        packed += int(cut.root[pre].sum() > 1)
+    assert packed > 10
     if name == "dense":
         for a, b in zip(cut, free):
             np.testing.assert_array_equal(a, b)
-    assert cut.n_subtrees.sum() >= free.n_subtrees.sum()
+    # (lopsided: six of seven nodes are the second tile's alone, 128 an
+    # entry)
+    assert free.n_subtrees.sum() <= cut.n_subtrees.sum() \
+        <= (1.7 if name == "lopsided" else 1.1) * free.n_subtrees.sum()
 
 
 def test_the_spans_cost_the_mnist_forests_cut_few_parts(monkeypatch):
-    """12 trees of the benchmark's forest (its drawing, its forest seed;
-    the rehearse forest is the first 4 of them, 80 parts where 75: two
-    parts are 2.7% there): the rule splits 784 columns at the middle and
-    its cut asks no more than 1.06 x the sub-trees of the unbounded one."""
-    import importlib.util
-    import pathlib
-
-    bench = pathlib.Path(__file__).parent.parent / "benchmark"
-    spec = importlib.util.spec_from_file_location(
-        "_datagen_forest", bench / "datagen_forest.py")
-    datagen = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(datagen)
-    cell = json.loads((bench / "configs/mnist-rf-100t-full.json").read_text())
-    sh = cell["shapes"]
-    t = datagen.grown_forest(12, sh["features"], sh["n_bins"],
-                             sh["n_classes"], sh["forest_seed"],
-                             **cell["assumed"]["drawing"])
-    zeros = np.zeros(t["feature"].shape, np.float32)
-    ens = NodeListEnsemble(
-        feature=t["feature"], threshold_bin=t["threshold_bin"],
-        threshold_raw=zeros, left_child=t["left_child"],
-        right_child=t["right_child"], leaf_value=t["leaf_value"],
-        n_leaves=t["n_leaves"], split_gain=zeros, n_features=sh["features"],
-        learning_rate=1.0, base_score=0.0, loss="mean", n_classes=10,
-        n_bins=sh["n_bins"])
+    """12 trees of the benchmark's forest (its drawing, its forest seed):
+    the rule splits 784 columns at the middle and its cut asks no more than
+    1.1 x the entries of the unbounded one (204 and 191, where the trees'
+    nodes over 255 a tree are 187; the connected cut of before PR 53 made
+    253 and 241)."""
+    ens, _ = mnist_forest(12)
     free = cut_subtrees(ens, 256)
     spans, cut = tree.choose_select_spans(ens, 256)
     assert spans == ((0, 3), (3, 7))
     assert free.n_subtrees.sum() < cut.n_subtrees.sum() \
-        <= 1.06 * free.n_subtrees.sum()
+        <= 1.1 * free.n_subtrees.sum()
+    assert free.n_subtrees.sum() <= 1.03 * np.ceil(
+        (ens.n_leaves - 1) / 255).sum()
     for a, b in zip(cut, cut_subtrees(ens, 256, spans)):
         np.testing.assert_array_equal(a, b)
     # the exits' table is ONE tile either way (30 lanes of pieces, a chain
@@ -207,8 +295,9 @@ def test_the_spans_cost_the_mnist_forests_cut_few_parts(monkeypatch):
     assert cut.n_subtrees.sum() * 13 < 0.75 * free.n_subtrees.sum() \
         * tree.subtree_mxu_tiles(SPANS["dense"], 256, 128)
     # ... and fewer than the halves' 18 (the select asked whole in both
-    # tiles), so the rule leaves this forest its tables, byte for byte:
-    # their SHA-1 as the commit before the halves built them (daa6000)
+    # tiles), so the rule leaves this forest its spans. The tables' SHA-1
+    # is PR 53's (the packed cut's: a later change to the cut or the
+    # numbering shows)
     import hashlib
 
     monkeypatch.setattr(tree, "SUBTREE_LANES", 256)
@@ -218,12 +307,16 @@ def test_the_spans_cost_the_mnist_forests_cut_few_parts(monkeypatch):
     h = hashlib.sha1()
     for a in ce.arrays():
         h.update(np.ascontiguousarray(a).view(np.uint8).tobytes())
-    assert h.hexdigest()[:16] == "ff7d82dee22cae9a"
+    assert h.hexdigest()[:16] == "f6facea64c1681bb"
 
 
 @pytest.mark.parametrize("features,spans,tiles,resolve", [
     (784, ((0, 3), (3, 7)), 7, 4),  # uniform columns: split at the middle
-    (400, ((0, 2), (2, 4)), 4, 4),  # four K-blocks: the halves would ask 8
+    # four K-blocks: the halves would ask 8; the middle block shared costs
+    # a tile of select more than the blocks told apart and, the cut filling
+    # its entries, four entries fewer (19 x 11 tiles against 23 x 10: every
+    # entry of the split apart must balance 128 nodes a tile to be full)
+    (400, ((0, 2), (1, 4)), 5, 4),
     (300, ((0, 1), (1, 3)), 3, 4),  # three: 3 + 4 against the halves' 6 + 2
     # two K-blocks: the spans' 2 + 4 and the halves' 4 + 2 ask alike a
     # sub-tree, and the halves' one bound cuts fewer parts than a bound a
@@ -253,9 +346,10 @@ def test_the_spans_are_read_from_the_model(monkeypatch, features, spans,
     _, rows, lanes = np.nonzero(ce.sel.astype(np.float32))
     first, stop = np.array(spans)[lanes // 128].T
     assert ((rows // 128 >= first) & (rows // 128 < stop)).all()
-    # a lane a node, and (the halves) one more a copy of a spine's node
-    assert len(rows) == ens.n_splits + ce.spine_copies
-    assert (ce.spine_copies > 0) == ce.halved
+    # a lane a node, one a glue copy of its entry's pieces' common
+    # ancestors, and (the halves) one more a copy of a spine's slot
+    assert len(rows) == ens.n_splits + ce.glue_copies + ce.spine_copies
+    assert (ce.spine_copies > 0) == ce.halved and ce.glue_copies > 0
 
 
 def test_columns_that_crowd_one_block_and_models_with_nothing_to_split(
@@ -398,7 +492,8 @@ def test_blocks_that_cut_through_a_tree_and_ragged_row_tiles(
     entries, the last row tile is ragged."""
     monkeypatch.setattr(predict_paths, "_MAX_TREES_PER_STEP", 3)
     monkeypatch.setattr(predict_paths, "TILE_ROWS", 512)
-    ens = forest(21, 8, (1, 500), columns, dyadic=True)
+    # (a seed whose entries' count is no prime: the plan takes no filler)
+    ens = forest(21 + 2 * (columns == 3), 8, (1, 500), columns, dyadic=True)
     ce = ens.compile()
     assert ce.leaves.shape[2] == exit_lanes
     plan = predict_paths.path_plan(
@@ -492,9 +587,9 @@ def _one_block(ens):
     (784, True, False, None, ((0, 3), (3, 7))),        # ... under NaN routes
     (784, False, False, _one_block, ((0, 3), (3, 7))),
     (784, True, True, None, ((0, 3), (3, 7))),  # past PATH_UNCUT_LANES
-    (200, True, False, None, ((0, 1), (0, 2))),        # two K-blocks
+    (400, True, False, None, ((0, 2), (1, 4))),        # a block shared
     (100, False, False, None, ((0, 1), (0, 1))),       # dense
-], ids=["784f", "784f-nan", "784f-one-block", "784f-scalar-nan", "200f-nan",
+], ids=["784f", "784f-nan", "784f-one-block", "784f-scalar-nan", "400f-nan",
         "100f"])
 def test_kernel_twin_and_walk_are_bit_equal_under_the_spans(
         monkeypatch, features, missing, scalar, shape, spans):
@@ -523,58 +618,60 @@ def test_kernel_twin_and_walk_are_bit_equal_under_the_spans(
                                       want.astype(np.float32))
 
 
-@pytest.mark.parametrize("lanes,features,columns,missing,parts,digest", [
-    (128, 100, 3, False, 8, "0b4a35b5c8f0f745"),
-    (128, 12, 10, True, 19, "5907714f849a871c"),
-    (128, 784, 3, False, 8, "872282a6b2b18dbf"),   # one tile: no span
-    (256, 100, 3, False, 6, "c37e078ebc4f0667"),   # one K-block
-    (256, 12, 10, True, 11, "df962ff860f2425f"),
-    (256, 28, 0, False, 12, "322d840d74a261cc"),   # scalar, 700 leaves
-    # (1ec9f09's, the commit before the one tile of exits:) 129 and 255
-    # lanes of pieces, which no chain shares a tile with
-    (128, 12, 43, False, 13, "8370a7fa64a222f5"),
-    (256, 12, 85, True, 12, "d5871a3342126539"),
+@pytest.mark.parametrize("lanes,features,columns,missing,digest,halves", [
+    (128, 100, 3, False, "7fe203fa6815716f", None),
+    (128, 12, 10, True, "8a0c9f93d4d23817", None),
+    (128, 784, 3, False, "b13cdd8e3f863c7f", None),   # one tile: no span
+    (256, 100, 3, False, "ea266811894a765a", "422f9df3445fea59"),
+    (256, 12, 10, True, "3a80e9b8c48eb1c5", "89574fa661e21424"),
+    # scalar leaves: softmax's round-major trees, 3 classes
+    (256, 28, 0, False, "8b9493e4ac8fec69", "021c07c233c444e3"),
+    # 129 and 255 lanes of pieces, which no chain shares a tile with
+    (128, 12, 43, False, "4b6bd2919d86bedb", None),
+    (256, 12, 85, True, "7856a4876b0d7220", "5bb678aaa009461d"),
 ])
 def test_dense_spans_build_the_parents_tables_bit_for_bit(
-        monkeypatch, lanes, features, columns, missing, parts, digest):
-    """SHA-1s of the four tables as the commit before the spans built them
-    (da8e8d4, the same seeded forests): a model whose spans are dense is
-    numbered in pre-order and cut by the one bound, as ever. The exits'
-    table of ONE tile hashes to that commit's once its links are moved back
-    to a lane tile of their own: the same exits, the same pieces, the same
-    chain; a model it does not fit builds [V | L] bit for bit."""
+        monkeypatch, lanes, features, columns, missing, digest, halves):
+    """A tree that fits one entry is ONE piece and builds the tables it
+    built before the cut packed its entries (PR 53): SHA-1s of the four
+    tables as the parent commit (bfa48fe) built them, for seeded models
+    whose every tree fits an entry, numbered in pre-order under dense spans
+    (`digest`) and, two lane tiles over one K-block, as the HALVES the rule
+    gives them (`halves`). (Until PR 53 this test held models of cut trees
+    to the commit before the spans, da8e8d4: a cut tree's entries are no
+    longer what they were, `entries_of` says what they are.)"""
     import hashlib
+
+    def sha(ce):
+        h = hashlib.sha1()
+        for a in ce.arrays():
+            h.update(np.ascontiguousarray(a).view(np.uint8).tobytes())
+        return h.hexdigest()[:16]
 
     monkeypatch.setattr(tree, "SUBTREE_LANES", lanes)
     rng = np.random.default_rng(81)
-    ens = random_node_list(rng, 5, (1, 900), features, n_bins=BINS,
-                           missing=missing, leaf_columns=columns) \
+    ens = random_node_list(rng, 5, (1, 3 * lanes // 4), features,
+                           n_bins=BINS, missing=missing,
+                           leaf_columns=columns) \
         if columns else random_node_list(
-            rng, 3, 700, features, n_bins=BINS, learning_rate=0.1,
-            base_score=0.5, loss="logloss")
-    if lanes == 256:
+            rng, 6, (1, 3 * lanes // 4), features, n_bins=BINS,
+            learning_rate=0.1, base_score=0.5, loss="softmax", n_classes=3)
+    ce = ens.compile()
+    assert ce.halved == (lanes == 256)
+    if ce.halved:
         # (two lane tiles over one K-block: the rule takes the halves since
         # PR 51. The dense layout is still the builder's to build, for the
         # models the rule leaves it: handed to it here.)
-        assert ens.compile().halved
+        assert sha(ce) == halves
         monkeypatch.setattr(
-            tree, "choose_select_spans", lambda ens, lanes, node_parent=None:
+            tree, "choose_select_spans", lambda ens, lanes:
             (tree.dense_spans(features, lanes), cut_subtrees(ens, lanes)))
-    ce = ens.compile()
+        ce = ens.compile()
     assert not ce.halved
     assert ce.select_spans == tree.dense_spans(features, lanes)
-    assert ce.n_subtrees == parts
-    pieces = 3 * ce.leaf_columns
-    shared = ce.leaves.shape[2] == 128
-    assert shared == (columns not in (43, 85))
-    leaves = ce.leaves if not shared else np.concatenate(
-        [ce.leaves[:, :, :pieces], np.zeros_like(ce.leaves[:, :, pieces:]),
-         ce.leaves[:, :, pieces:], np.zeros_like(ce.leaves[:, :, :pieces])],
-        axis=2)
-    h = hashlib.sha1()
-    for a in ce.arrays()[:3] + (leaves,):
-        h.update(np.ascontiguousarray(a).view(np.uint8).tobytes())
-    assert h.hexdigest()[:16] == digest
+    assert ce.n_subtrees == ce.pieces == ens.n_trees and not ce.glue_copies
+    assert (ce.leaves.shape[2] == 128) == (columns not in (43, 85))
+    assert sha(ce) == digest
 
 
 def test_a_tall_scalar_tree_is_cut_and_keeps_its_margin(monkeypatch):
@@ -904,76 +1001,51 @@ def ragged(seed, features=54, missing=False):
 @pytest.mark.parametrize("seed,missing", [(71, False), (72, True),
                                           (73, False)])
 def test_halved_subtrees_are_two_halves_that_share_their_spine(seed, missing):
-    """Still a partition into connected parts hung by their roots, parents
-    first; every part admits its k: the first half the first k nodes of the
-    part's pre-order in lanes 0.., the second half copies of node k's
-    ancestors (top down, from lane 128) and then the later nodes in
-    pre-order; both halves within 128 lanes of nodes and of exits; a second
-    half's node has no ancestor in the first half but a copied one; a part
-    of under 128 nodes is one half."""
+    """Still entries of glued pieces, parents first (`entries_of`); every
+    entry admits its k: the first half the first k slots of the glued
+    tree's pre-order in lanes 0.., the second half copies of slot k's
+    ancestors in the entry (top down, from lane 128; a glue copy is copied
+    like a node) and then the later slots in pre-order; both halves within
+    128 lanes of slots and of exits; a second half's slot has no ancestor in
+    the first half but a copied one; an entry of under 128 slots is one
+    half."""
     ens = ragged(seed, missing=missing)
     cut, free = cut_subtrees(ens, 256, halved=True), cut_subtrees(ens, 256)
     assert cut.copy is not None and free.copy is None
-    parent = ens._parents()[0]
-    second_halves = copies = 0
-    for t in range(ens.n_trees):
-        n_int = int(ens.n_leaves[t]) - 1
-        if n_int == 0:
-            assert cut.n_subtrees[t] == 1
-            continue
-        sub, lane, root, copy = (a[t, :n_int] for a in (
-            cut.subtree, cut.lane, cut.root, cut.copy))
-        up = parent[t, :n_int]
-        assert root[0] and sub[0] == 0
-        assert np.bincount(sub[root]).tolist() == [1] * cut.n_subtrees[t]
-        inner, hung = np.nonzero(~root)[0], np.nonzero(root)[0][1:]
-        assert (sub[up[inner]] == sub[inner]).all()
-        assert (sub[up[hung]] < sub[hung]).all()
-        kids = np.stack([ens.left_child[t, :n_int],
-                         ens.right_child[t, :n_int]], 1)
-        # an exit: a child that is a leaf, or roots another part
-        n_exits = ((kids < 0) | root[np.maximum(kids, 0)]).sum(axis=1)
-        copied = np.nonzero(copy)[0]
-        for k in range(cut.n_subtrees[t]):
-            top, = np.nonzero(root & (sub == k))[0]
-            pre, stack, depth = [], [(top, 0)], {}
-            while stack:                    # the part's pre-order
-                n, d = stack.pop()
-                pre.append(n)
-                depth[n] = d
-                stack += [(c, d + 1) for c in kids[n][::-1]
-                          if c >= 0 and not root[c]]
-            n = len(pre)
-            assert n == (sub == k).sum()
-            # the cut's bound: the nodes and the longest path's together
-            assert n + max(depth.values()) + 1 <= 255
-            split = int((lane[pre] < 128).sum())
-            first, second = pre[:split], pre[split:]
-            assert 1 <= split <= 128
-            assert lane[first].tolist() == list(range(split))
-            spine = []
-            if second:
-                a = second[0]
-                while not root[a]:
-                    a = up[a]
-                    spine.insert(0, a)
-                second_halves += 1
-            assert copy[spine].tolist() == list(range(128, 128 + len(spine)))
-            assert set(copied[sub[copied] == k]) == set(spine) <= set(first)
-            assert lane[second].tolist() == list(range(
-                128 + len(spine), 128 + len(spine) + len(second)))
-            assert 128 + len(spine) + len(second) <= 256
-            for x in second:
-                a = x
-                while not root[a]:
-                    a = up[a]
-                    assert lane[a] >= 128 or copy[a] > 0
-            assert n_exits[first].sum() <= 128 >= n_exits[second].sum()
-            assert n_exits[pre].sum() == n + 1
-            if n < 128:
-                assert not second
-            copies += len(spine)
+    second_halves = copies = packed = 0
+    for t, k, pre, depth, exits in entries_of(ens, cut, 256):
+        n = len(pre)
+        # the cut's bound: the slots and the longest path's together
+        assert n + max(depth.values()) + 1 <= 255
+        split = int((cut.lane[pre] < 128).sum())
+        first, second = pre[:split], pre[split:]
+        assert 1 <= split <= 128
+        assert cut.lane[first].tolist() == list(range(split))
+        spine = []
+        if second:
+            a = second[0]
+            while cut.up[a] >= 0:
+                a = int(cut.up[a])
+                spine.insert(0, a)
+            second_halves += 1
+        assert cut.copy[spine].tolist() == list(range(128, 128 + len(spine)))
+        assert {s for s in pre if cut.copy[s]} == set(spine) <= set(first)
+        assert cut.lane[second].tolist() == list(range(
+            128 + len(spine), 128 + len(spine) + len(second)))
+        assert 128 + len(spine) + len(second) <= 256
+        for x in second:
+            a = x
+            while cut.up[a] >= 0:
+                a = int(cut.up[a])
+                assert cut.lane[a] >= 128 or cut.copy[a] > 0
+        assert sum(exits[s] for s in first) <= 128 \
+            >= sum(exits[s] for s in second)
+        if n < 128:
+            assert not second
+        copies += len(spine)
+        packed += int(cut.root[pre].sum() > 1)
     assert second_halves > 10 and copies == np.count_nonzero(cut.copy)
+    assert packed > 10
     # what the halves' bound costs the cut
     assert free.n_subtrees.sum() <= cut.n_subtrees.sum() \
         <= 1.2 * free.n_subtrees.sum()
@@ -981,27 +1053,85 @@ def test_halved_subtrees_are_two_halves_that_share_their_spine(seed, missing):
         cut_subtrees(ens, 128, halved=True)
 
 
-def walked_exit(ens, cut, t, top, x):
-    """Where row x leaves tree t's part rooted at node `top`: (the leaf, or
-    None; the node that roots the part it goes on in, or None)."""
-    n = top
+def hung_slots(cut, n_nodes):
+    """{(tree, entry): the entry's top slot} and {(a glue copy's slot,
+    side): the slot that hangs there}."""
+    tops = {(int(cut.tree[s]), int(cut.subtree[s])): int(s)
+            for s in np.nonzero(cut.up < 0)[0]}
+    below = {(int(cut.up[s]), int(cut.side[s])): int(s)
+             for s in np.nonzero(cut.up >= n_nodes)[0]}
+    return tops, below
+
+
+def walked_exit(ens, cut, t, k, x, hung):
+    """Where row x leaves entry k of tree t by the node walk from the
+    entry's top (a glue copy asks its node's question and hands the row to
+    the slot on that side): (the leaf, or None; the entry it goes on in, or
+    None)."""
+    tops, below = hung
+    base = first_node(ens)[t]
+    s = tops[t, k]
     while True:
+        n = int(cut.origin[s])
         b = int(x[ens.feature[t, n]])
         left = b <= ens.threshold_bin[t, n]
         if ens.missing_routes and b == ens.n_bins - 1:
             left = bool(ens.default_left[t, n])
+        if s >= ens.n_splits:
+            s = below[s, -1 if left else 1]
+            continue
         c = int(ens.left_child[t, n] if left else ens.right_child[t, n])
         if c < 0:
             return ~c, None
-        if cut.root[t, c]:
-            return None, c
-        n = c
+        if cut.subtree[base + c] != k:
+            return None, int(cut.subtree[base + c])
+        s = base + c
+
+
+def entries_exits(ens, ce, cut, Xb):
+    """Every (row, entry) of the compiled tables leaves the entry by the
+    exit the node walk from its top takes: a real leaf's value in its
+    columns' lanes (a softmax tree's in its class's alone), or the link to
+    the entry that holds the child. The tables' own equations in NumPy,
+    an entry at a time, whether or not the row is active there."""
+    C, features = ce.leaf_columns, ens.n_features
+    sel, paths, leaves = (a.astype(np.float32) for a in (
+        ce.sel, dense_paths(ce), ce.leaves))
+    link0 = tree.exit_table_lanes(C, cut.n_subtrees)[1]
+    first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
+    hung = hung_slots(cut, ens.n_splits)
+    X = np.pad(Xb.astype(np.float32), ((0, 0), (0, sel.shape[1] - features)))
+    for t in range(ens.n_trees):
+        for k in range(cut.n_subtrees[t]):
+            g = first[t] + k
+            v = X @ sel[g]
+            right = v > ce.planes[g, 0]
+            if ens.missing_routes:
+                right &= v < ce.planes[g, 3]
+            e = np.where(right, 1.0, -1.0).astype(np.float32) @ paths[g] \
+                == ce.planes[g, 1]
+            assert (e.sum(axis=1) == 1).all()
+            y = e.astype(np.float32) @ leaves[g]
+            value = y[:, 2 * C:3 * C] + y[:, C:2 * C] + y[:, :C]
+            for r in range(len(Xb)):
+                leaf, to = (0, None) if ens.n_leaves[t] == 1 else \
+                    walked_exit(ens, cut, t, k, Xb[r], hung)
+                want = np.zeros(C, np.float32)
+                link = np.zeros(leaves.shape[2] - link0, np.float32)
+                if to is not None:
+                    link[to - k - 1] = 1.0
+                elif ens.vector_leaves:
+                    want[:] = ens.leaf_value[t, leaf]
+                else:
+                    want[t % C] = ens.leaf_value[t, leaf]
+                np.testing.assert_array_equal(value[r], want)
+                np.testing.assert_array_equal(y[r, link0:], link)
 
 
 @pytest.mark.parametrize("features,missing,packed,digest", [
-    (54, False, 2, "2277425e3d4b7c13"),  # the packed select over the halves
-    (9, True, 2, "f2ba633402c57172"),    # ... with NaN routes
-    (100, True, 1, "911c984f10c7cfd9"),  # the unpacked select
+    (54, False, 2, "78dca5a2a5336ccb"),  # the packed select over the halves
+    (9, True, 2, "f6b521df954703e3"),    # ... with NaN routes
+    (100, True, 1, "3e9d4fbdc8392c25"),  # the unpacked select
 ])
 def test_halved_tables_resolve_every_exit_in_its_own_half(
         monkeypatch, features, missing, packed, digest):
@@ -1011,8 +1141,9 @@ def test_halved_tables_resolve_every_exit_in_its_own_half(
     walk from its root takes, a real leaf's value in its class's lanes or
     the link to the part that hangs there; kernel (interpreted), twin and
     walk bit-equal, the link's answers to float32 rounding; the spans say
-    `resolve_mxu_tiles` 2 and the copies. The tables' SHA-1 is this PR's
-    (51): a later change to the numbering shows."""
+    `resolve_mxu_tiles` 2 and the copies. The tables' SHA-1 is PR 53's
+    (the packed cut's; PR 51's before it): a later change to the cut or
+    the numbering shows."""
     import hashlib
 
     from ddt_tpu.backends import get_backend
@@ -1033,11 +1164,11 @@ def test_halved_tables_resolve_every_exit_in_its_own_half(
         h.update(np.ascontiguousarray(a).view(np.uint8).tobytes())
     assert h.hexdigest()[:16] == digest
     first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
-    # a copy asks its node's question and hangs no exit
-    t, n = np.nonzero(cut.copy)
-    entry, own, cp = first[t] + cut.subtree[t, n], cut.lane[t, n], \
-        cut.copy[t, n]
-    assert len(t) == ce.spine_copies > 0
+    # a spine copy asks its slot's question and hangs no exit
+    s = np.nonzero(cut.copy)[0]
+    t, n = cut.tree[s], cut.origin[s]
+    entry, own, cp = first[t] + cut.subtree[s], cut.lane[s], cut.copy[s]
+    assert len(s) == ce.spine_copies > 0
     sel = ce.sel.astype(np.float32)
     np.testing.assert_array_equal(sel[entry, :, cp], sel[entry, :, own])
     np.testing.assert_array_equal(sel[entry, ens.feature[t, n], cp], 1.0)
@@ -1046,40 +1177,22 @@ def test_halved_tables_resolve_every_exit_in_its_own_half(
                                       ce.planes[entry, row, own])
     np.testing.assert_array_equal(ce.planes[entry, 0, cp],
                                   ens.threshold_bin[t, n])
-    # every node lane is one node's or one copy's, the others ask nothing
-    assert (sel.sum(axis=1) <= 1).all() and sel.sum() == ens.n_splits + len(t)
-    # the exit every (row, sub-tree) takes is the node walk's
+    # ... and so does a glue copy, its node's own lane in an earlier entry
+    glue = np.arange(ens.n_splits, len(cut.tree))
+    assert len(glue) == ce.glue_copies > 0
+    np.testing.assert_array_equal(
+        ce.planes[first[cut.tree[glue]] + cut.subtree[glue], 0,
+                  cut.lane[glue]],
+        ens.threshold_bin[cut.tree[glue], cut.origin[glue]])
+    # every node lane is one slot's or one copy's, the others ask nothing
+    assert (sel.sum(axis=1) <= 1).all()
+    assert sel.sum() == len(cut.tree) + len(s)
+    # the exit every (row, entry) takes is the node walk's
     Xb = rows_of(76, 40, features)
     Xb[::5, ::2] = BINS - 1
-    paths, leaves = dense_paths(ce).astype(np.float32), \
-        ce.leaves.astype(np.float32)
+    paths = dense_paths(ce).astype(np.float32)
     assert not paths[:, :128, 128:].any() and not paths[:, 128:, :128].any()
-    X = np.pad(Xb.astype(np.float32), ((0, 0), (0, sel.shape[1] - features)))
-    for t in range(ens.n_trees):
-        tops = np.nonzero(cut.root[t])[0]
-        tops = tops[np.argsort(cut.subtree[t, tops])]
-        for k in range(cut.n_subtrees[t]):
-            g = first[t] + k
-            v = X @ sel[g]
-            right = v > ce.planes[g, 0]
-            if missing:
-                right &= v < ce.planes[g, 3]
-            e = np.where(right, 1.0, -1.0).astype(np.float32) @ paths[g] \
-                == ce.planes[g, 1]
-            assert (e.sum(axis=1) == 1).all()
-            y = e.astype(np.float32) @ leaves[g]
-            value = y[:, 2 * C:3 * C] + y[:, C:2 * C] + y[:, :C]
-            for r in range(len(Xb)):
-                leaf, to = (0, None) if not len(tops) else walked_exit(
-                    ens, cut, t, tops[k], Xb[r])
-                want = np.zeros(C, np.float32)
-                link = np.zeros(128 - 3 * C, np.float32)
-                if to is None:
-                    want[t % C] = ens.leaf_value[t, leaf]
-                else:
-                    link[cut.subtree[t, to] - k - 1] = 1.0
-                np.testing.assert_array_equal(value[r], want)
-                np.testing.assert_array_equal(y[r, 3 * C:], link)
+    entries_exits(ens, ce, cut, Xb)
     # kernel, twin, walk
     Xb = rows_of(77, 700, features)
     Xb[::7, ::3] = BINS - 1
@@ -1134,3 +1247,247 @@ def test_the_rule_takes_the_halves_for_one_k_block_and_keeps_the_forests_spans(
         cut_subtrees(wide, 256, ((0, 3), (3, 7)), halved=True)
     flat = predict_paths.path_plan(500, 256, 28)
     assert (flat.resolve_mxu_tiles, flat.spine_copies_per_subtree) == (4, 0.0)
+
+
+# ---------------------------------------------------------------------- #
+# fuller entries (PR 53): several pieces of a tree in one entry, glued by
+# copies of their common ancestors
+# ---------------------------------------------------------------------- #
+
+def one_piece_an_entry(monkeypatch, spans, halved):
+    """The CONNECTED cut, the case "one piece an entry" of the same code:
+    the packer replaced by one that gives an entry ONE top piece of a
+    frontier sub-tree (half the lanes at most, which holds a bound a lane
+    tile and the halves' bound too) and never a second one, and the rule by
+    the spans the packed cut's model was given."""
+    def filled(flat, lanes, only, halved):
+        cap, pieces = (lanes - 1) // 2, []
+        for t in np.nonzero(np.diff(flat.first))[0]:
+            frontier, entry = [int(flat.first[t])], 0
+            while frontier:
+                q = frontier.pop(0)
+                end = x = q + min(cap, int(flat.size[q]))
+                pieces.append((q, end - q, entry))
+                entry += 1
+                while x > q:            # up from the piece's last node
+                    x = end - 1 if x == end else int(flat.up[x])
+                    frontier += [int(c) for c in flat.kids[x] if c >= end]
+        return tuple(np.array(a, np.int64) for a in zip(*pieces)) \
+            if pieces else (np.zeros(0, np.int64),) * 3
+
+    monkeypatch.setattr(tree, "_filled", filled)
+    monkeypatch.setattr(tree, "choose_select_spans", lambda ens, lanes: (
+        spans, cut_subtrees(ens, lanes, spans, halved)))
+
+
+def fresh_scores(ens, Xb, impl):
+    """Scored by a backend of its own: the model's tables are cached by the
+    model's digest, and the same model is compiled under two cuts here."""
+    from ddt_tpu.backends import get_backend
+
+    return get_backend(TrainConfig(backend="tpu", predict_impl=impl,
+                                   n_bins=BINS), use_cache=False
+                       ).predict_raw(ens, Xb)
+
+
+PACKED = {
+    # softmax's round-major trees, halved, NaN routes
+    "halved-softmax-nan": dict(lanes=256, features=54, missing=True,
+                               meta=dict(learning_rate=0.5, base_score=0.25,
+                                         loss="softmax", n_classes=7)),
+    # vector leaves under the K-block spans, NaN routes
+    "spans-vector-nan": dict(lanes=256, features=784, missing=True,
+                             meta=dict(leaf_columns=3)),
+    # one lane tile an entry, blocks of three entries cut through the trees
+    "blocks-vector": dict(lanes=128, features=F, missing=False, step=3,
+                          meta=dict(leaf_columns=10)),
+    # [V | L]: the links in lane tiles of their own
+    "wide-vector-nan": dict(lanes=128, features=F, missing=True,
+                            meta=dict(leaf_columns=43)),
+    # one column: a scalar tree past PATH_UNCUT_LANES
+    "scalar": dict(lanes=256, features=100, missing=False,
+                   meta=dict(learning_rate=0.5, base_score=0.25,
+                             loss="logloss")),
+}
+
+
+@pytest.mark.parametrize("dyadic", [True, False], ids=["dyadic", "normal"])
+@pytest.mark.parametrize("name", list(PACKED))
+def test_packed_tables_take_the_walks_exits_and_score_as_the_connected_cuts(
+        monkeypatch, name, dyadic):
+    """Ragged random trees, their entries packed: every (row, entry) of
+    the tables leaves by the exit the node walk takes (`entries_exits`: a
+    glue copy sends the row to the piece it reaches), kernel and twin give
+    the walk's scores, and the scores over the CONNECTED cut's tables of
+    the same model (more entries, summed in other blocks) are the same BIT
+    FOR BIT on dyadic leaf values and to float32 rounding otherwise."""
+    case = PACKED[name]
+    monkeypatch.setattr(tree, "SUBTREE_LANES", case["lanes"])
+    if "step" in case:
+        monkeypatch.setattr(predict_paths, "_MAX_TREES_PER_STEP",
+                            case["step"])
+        monkeypatch.setattr(predict_paths, "TILE_ROWS", 512)
+    rng = np.random.default_rng(530)
+    ens = joined(*[random_node_list(
+        rng, 1, n, case["features"], n_bins=BINS, dyadic=dyadic,
+        missing=case["missing"], **case["meta"])
+        # (8 trees: a forest's mean of dyadic values is exact)
+        for n in (30, 1, 1300, 90, 700, 257, 400, 900)
+        if n > 1 or case["meta"].get("loss") != "logloss"])
+    ce = ens.compile()
+    spans, cut = tree.choose_select_spans(ens, case["lanes"])
+    assert ce.chained and ce.n_subtrees == cut.n_subtrees.sum()
+    assert ce.pieces > 2 * ce.n_subtrees and ce.glue_copies > ce.n_subtrees
+    assert ce.halved == (name in ("halved-softmax-nan", "scalar"))
+    assert (name == "spans-vector-nan") == (spans != tree.dense_spans(
+        case["features"], case["lanes"]))
+    Xb = rows_of(531, 24, case["features"])
+    Xb[::5, ::2] = BINS - 1             # rows that sit in the NaN bin
+    entries_exits(ens, ce, cut, Xb)
+    Xb = rows_of(532, 700, case["features"])
+    Xb[::7, ::3] = BINS - 1
+    want = ens.predict_raw(Xb, binned=True)
+    packed = {impl: fresh_scores(ens, Xb, impl)
+              for impl in ("pallas", "onehot")}
+    one_piece_an_entry(monkeypatch, spans, ce.halved)
+    connected = ens.compile()
+    assert connected.pieces == connected.n_subtrees > 1.3 * ce.n_subtrees
+    assert not connected.glue_copies
+    for impl, got in packed.items():
+        other = fresh_scores(ens, Xb, impl)
+        if dyadic:
+            np.testing.assert_array_equal(got, want)
+            np.testing.assert_array_equal(got, other)
+        else:
+            np.testing.assert_allclose(got, want, atol=2e-6)
+            np.testing.assert_allclose(got, other, atol=1e-6)
+
+
+def test_a_node_that_is_glue_in_two_entries():
+    """A tree's upper nodes are the common ancestors of pieces in SEVERAL
+    entries: a copy of the same node in each, every one asking its
+    question under its own entry's lanes, and the scores the walk's."""
+    ens = forest(533, 4, (900, 1400), 3, missing=True, dyadic=True)
+    cut = cut_subtrees(ens, 128)
+    glue = np.arange(ens.n_splits, len(cut.tree))
+    node = cut.tree[glue] * ens.feature.shape[1] + cut.origin[glue]
+    often = np.bincount(node).max()
+    assert often >= 3                   # one node, glue in three entries
+    s = glue[node == np.bincount(node).argmax()]
+    assert len(set(cut.subtree[s])) == often    # ... an entry each
+    ce = ens.compile()
+    first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
+    entry = first[cut.tree[s]] + cut.subtree[s]
+    t, n = cut.tree[s[0]], cut.origin[s[0]]
+    # ... and beside its own lane in the entry that holds the node itself
+    own = first_node(ens)[t] + n
+    entry = np.append(entry, first[t] + cut.subtree[own])
+    lane = np.append(cut.lane[s], cut.lane[own])
+    sel = ce.sel.astype(np.float32)
+    np.testing.assert_array_equal(sel[entry, ens.feature[t, n], lane], 1.0)
+    np.testing.assert_array_equal(ce.planes[entry, 0, lane],
+                                  ens.threshold_bin[t, n])
+    Xb = rows_of(534, 16)
+    Xb[::3, ::2] = BINS - 1
+    entries_exits(ens, ce, cut, Xb)
+    Xb = rows_of(535, 300)
+    for impl in ("pallas", "onehot"):
+        np.testing.assert_array_equal(
+            scored(ens, Xb, impl), predict_proba_node_list(ens, Xb))
+
+
+def spine_tree(rng, hung, columns):
+    """One tree: a spine of 127 nodes going right whose node i has on its
+    left a random sub-tree of `hung[i]` leaves (else a leaf), the last
+    one's right child a leaf; dyadic leaf vectors, NaN directions."""
+    leaves = []
+
+    def leaf():
+        leaves.append(rng.integers(-16, 17, (columns,)) / 8.0)
+        return ~(len(leaves) - 1)
+
+    nodes = [[int(rng.integers(F)), int(rng.integers(BINS - 2)), 0.0, 0.0,
+              None, i + 1, bool(rng.integers(2))] for i in range(127)]
+    nodes[-1][5] = leaf()
+    for i in range(127):
+        if i not in hung:
+            nodes[i][4] = leaf()
+            continue
+        sub = random_node_list(rng, 1, hung[i], F, n_bins=BINS, dyadic=True,
+                               missing=True, leaf_columns=columns)
+        n0, l0 = len(nodes), len(leaves)
+        nodes[i][4] = n0
+        for j in range(hung[i] - 1):
+            kids = [int(c) + n0 if c >= 0 else ~(~int(c) + l0) for c in (
+                sub.left_child[0, j], sub.right_child[0, j])]
+            nodes.append([int(sub.feature[0, j]),
+                          int(sub.threshold_bin[0, j]), 0.0, 0.0, *kids,
+                          bool(sub.default_left[0, j])])
+        leaves += list(sub.leaf_value[0, :hung[i]])
+    return tree.node_list_from_trees(
+        [(nodes, leaves)], n_features=F, n_bins=BINS, missing_bin=True,
+        learning_rate=1.0, base_score=0.0, loss="mean", n_classes=columns)
+
+
+@pytest.mark.parametrize("columns,most", [(42, 3), (40, 9)])
+def test_a_pieces_link_reaches_the_last_lane_the_rule_allows(
+        monkeypatch, columns, most):
+    """THE RULE's edge with packed entries: a piece of the tree's LAST
+    entry hangs on a node of its FIRST, so the link lies in lane 3 C + n - 2
+    = 127, the last the one tile of exits has, and the chain carries it
+    n - 1 entries along. (The connected cut linked a part to parts near
+    it; the packer's frontier holds a sub-tree until an entry has room for
+    it.) The tree: a spine of 127 nodes, entry 0; n - 2 sub-trees of 127
+    nodes on its first nodes, an entry each, the largest first; and one of
+    100 nodes at its far end, which fits beside none of them and is left
+    to the last entry. Kernel and twin score the walk's leaves."""
+    monkeypatch.setattr(predict_paths, "_MAX_TREES_PER_STEP", 8)
+    rng = np.random.default_rng(536 + columns)
+    small = random_node_list(rng, 1, 60, F, n_bins=BINS, dyadic=True,
+                             missing=True, leaf_columns=columns)
+    tall = spine_tree(rng, {**{i: 128 for i in range(most - 2)}, 126: 101},
+                      columns)
+    ens = joined(small, tall, small, small)
+    spans, cut = tree.choose_select_spans(ens, 128)
+    assert cut.n_subtrees.tolist() == [1, most, 1, 1]
+    own, _ = slots_of(ens, cut, 1)
+    assert (cut.subtree[own[:127]] == 0).all()         # the spine
+    hung = ens.left_child[1, 126]
+    assert cut.subtree[own[hung]] == most - 1 and cut.root[own[hung]]
+    ce = ens.compile()
+    assert ce.leaves.shape[2] == 128 and ce.n_subtrees == most + 3
+    assert tree.exit_table_lanes(columns, cut.n_subtrees) == (
+        128, 3 * columns) and 3 * columns + most - 2 == 127
+    assert ce.leaves[1, :, 127].astype(np.float32).sum() == 1
+    Xb = rows_of(537, 300)
+    Xb[::5, ::2] = BINS - 1
+    Xb[::2, ens.feature[1, :127]] = BINS - 2    # half the rows: down the spine
+    want = predict_proba_node_list(ens, Xb).astype(np.float32)
+    for impl in ("pallas", "onehot"):   # 4 trees: the mean is exact too
+        np.testing.assert_array_equal(scored(ens, Xb, impl), want)
+    entries_exits(ens, ce, cut, Xb[:12])
+
+
+def test_the_mnist_forest_packs_into_1800_entries():
+    """The forest cell's own model at full size (benchmark/datagen_forest.py,
+    its forest seed): 396,889 nodes in at most 1,800 entries (2,112 until
+    PR 53; 1,557 would do by the nodes alone) under the spans the rule
+    gives it, 20-21 at most a tree (30 lanes of pieces and the chain: ONE
+    tile of exits), and the cut no dearer than it was: under 3.5 x the time
+    of `_parents`, the proof of the node lists that every cut starts with
+    (the connected cut took 1.8 x that on the same machine, this one 1 x)."""
+    import time
+
+    ens, sh = mnist_forest(100)
+    assert sh["n_trees"] == ens.n_trees
+    assert ens.n_splits == 396_889
+    t0 = time.perf_counter()
+    ens._parents()
+    t1 = time.perf_counter()
+    spans, cut = tree.choose_select_spans(ens, 256)
+    t2 = time.perf_counter()
+    assert spans == ((0, 3), (3, 7)) and cut.copy is None
+    assert 1_557 <= cut.n_subtrees.sum() <= 1_800
+    assert tree.exit_table_lanes(10, cut.n_subtrees) == (128, 30)
+    assert cut.n_subtrees.max() <= 25
+    assert t2 - t1 < 3.5 * (t1 - t0) + 0.5

@@ -360,10 +360,13 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
         exit_mxu_tiles=0,                  # no exits' table: no chain
         # one lane tile of nodes against one of leaves; no sub-tree, so
         # none in two halves
-        resolve_mxu_tiles=1, spine_copies_per_subtree=0.0)
-    assert predict_paths.CHAIN_COUNTS[-4:] == (
+        resolve_mxu_tiles=1, spine_copies_per_subtree=0.0,
+        # ... and every tree ONE piece, glued to nothing (PR 53)
+        pieces_per_subtree=1.0, glue_copies_per_subtree=0.0)
+    assert predict_paths.CHAIN_COUNTS[-6:] == (
         "select_mxu_tiles", "exit_mxu_tiles", "resolve_mxu_tiles",
-        "spine_copies_per_subtree")
+        "spine_copies_per_subtree", "pieces_per_subtree",
+        "glue_copies_per_subtree")
     assert counts["bytes"] == counts["table_bytes"] or not served
     assert root["counts"]["select_k_blocks"] == 1
     assert root["counts"]["select_nodes_per_lane"] == 1

@@ -201,16 +201,21 @@ def test_ragged_softmax_trees_in_halved_subtrees(monkeypatch, classes,
     assert ce.single_subtree_trees == (cut.n_subtrees == 1).sum() > 0
     assert ce.subtrees_max == cut.n_subtrees.max() >= 10
     assert 0 < ce.spine_copies == np.count_nonzero(cut.copy)
-    # parts of one half (under 128 nodes, no copy) beside parts of two
+    # the entries are PACKED (PR 53): several pieces of a tree glued by
+    # copies of their common ancestors, a slot each
+    assert ce.pieces == np.count_nonzero(cut.root) + (
+        ens.n_leaves == 1).sum() > 2 * ce.n_subtrees
+    assert ce.glue_copies == len(cut.tree) - ens.n_splits \
+        == ce.pieces - ce.n_subtrees
+    # entries of one half (under 128 slots, no copy) beside entries of two
     first = np.concatenate([[0], np.cumsum(cut.n_subtrees)])
-    t, n = np.nonzero(ens.live_nodes)
-    entry = first[t] + cut.subtree[t, n]
+    entry = first[cut.tree] + cut.subtree
     two = np.zeros(ce.n_subtrees, bool)
-    two[entry[cut.lane[t, n] >= 128]] = True
+    two[entry[cut.lane >= 128]] = True
     assert two.any() and not two.all()
     assert (np.bincount(entry, minlength=ce.n_subtrees)[~two] < 128).all()
     # the exits of a second half lie in its own 128 lanes, the path
-    # lengths say which lanes hold an exit: one more than a part's nodes
+    # lengths say which lanes hold an exit: one more than an entry's slots
     held = ce.planes[:, 1, :] >= 0
     assert (held.sum(axis=1)[two] == np.bincount(entry)[two] + 1).all()
     assert held[two][:, 128].all() and not held[~two][:, 128:].any()
@@ -497,6 +502,12 @@ def test_the_spans_say_softmax_and_the_link(monkeypatch):
             assert counts["subtrees_per_tree_max"] == cut.n_subtrees.max()
             assert counts["single_subtree_trees"] == (
                 cut.n_subtrees == 1).sum()
+            # what the packed entries hold (PR 53)
+            assert counts["pieces_per_subtree"] == round(
+                (cut.root.sum() + (ens.n_leaves == 1).sum())
+                / cut.n_subtrees.sum(), 2) > 1
+            assert counts["glue_copies_per_subtree"] == round(
+                (len(cut.tree) - ens.n_splits) / cut.n_subtrees.sum(), 2) > 0
             assert counts["select_nodes_per_lane"] == 1     # 128 lanes
         assert root["counts"]["classes"] == 7
     heap = from_xgboost_json(drawn_model(63, 3, "multi:softprob", 3, (4,)))
@@ -550,3 +561,44 @@ def test_a_real_classifier_agrees_with_its_own_predict_proba(tmp_path):
     bundle = api.load_model(path)
     got = api.predict(bundle, X[1200:], cfg=cfg_of("pallas"))
     np.testing.assert_allclose(got, clf.predict_proba(X[1200:]), atol=1e-6)
+
+
+def test_the_covtype_model_packs_into_13800_entries():
+    """The XGBoost cell's own model at full size (benchmark/datagen_xgb.py,
+    its model seed, through the import): 2,855,946 nodes in at most 13,800
+    HALVED entries (16,051 until PR 53; 12,502 would do by the nodes alone,
+    a tree's nodes over 255), the 691 trees that fit an entry still ONE,
+    the largest tree under 60, and the cut no dearer than it was: under 3.5
+    x the time of `_parents`, the proof of the node lists that every cut
+    starts with (the connected cut took 1.8 x that on the same machine,
+    this one 1 x)."""
+    import importlib.util
+    import pathlib
+    import time
+
+    bench = pathlib.Path(__file__).parent.parent / "benchmark"
+    spec = importlib.util.spec_from_file_location(
+        "_datagen_xgb_full", bench / "datagen_xgb.py")
+    datagen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(datagen)
+    cell = json.loads(
+        (bench / "configs/covtype-xgb-softprob-d16.json").read_text())
+    sh = cell["shapes"]
+    ens = from_xgboost_json(datagen.drawn_model(
+        sh["rounds"], sh["features"], sh["model_seed"],
+        base_score=cell["model"]["base_score"],
+        **cell["assumed"]["drawing"]), missing=False)
+    assert (ens.n_trees, ens.n_splits) == (2450, 2_855_946)
+    t0 = time.perf_counter()
+    ens._parents()
+    t1 = time.perf_counter()
+    spans, cut = tree.choose_select_spans(ens, 256)
+    t2 = time.perf_counter()
+    assert spans == ((0, 1), (0, 1)) and cut.copy is not None
+    fewest = -(-(ens.n_leaves.astype(np.int64) - 1) // 255)
+    assert fewest.sum() == 12_502 <= cut.n_subtrees.sum() <= 13_800
+    # (740 by their nodes alone; 49 of them not with their longest path)
+    assert (cut.n_subtrees == 1).sum() == 691 < (fewest == 1).sum() == 740
+    assert 20 < cut.n_subtrees.max() < 60
+    assert tree.exit_table_lanes(7, cut.n_subtrees) == (128, 21)
+    assert t2 - t1 < 3.5 * (t1 - t0) + 0.5
