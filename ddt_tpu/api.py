@@ -310,7 +310,18 @@ def validate_mapper_model(mapper: BinMapper, ens: TreeEnsemble) -> None:
             f"{ens.missing_bin}; use the training-time mapper "
             "(api.load_model returns it)"
         )
-    if ens.has_cat_splits:
+    if ens.has_cat_splits and ens.cat_features is None:
+        # A node list's category SETS are over the bins of the mapper the
+        # model's own sets made (lightgbm_io.threshold_bin_mapper).
+        asked = set(np.unique(ens.feature[ens.cat_nodes]).tolist())
+        lacks = sorted(asked - set(mapper.category_ids or ()))
+        if lacks:
+            raise ValueError(
+                f"the ensemble asks category sets of features {lacks} but "
+                "this BinMapper has no category table for them; use the "
+                "mapper models/lightgbm_io.threshold_bin_mapper made of "
+                "this model")
+    elif ens.has_cat_splits:
         # Same loud-failure contract as missing_bin: the model's
         # categorical columns must have been identity-binned by
         # this mapper or every "bin == k" comparison is garbage.

@@ -2069,15 +2069,22 @@ class TPUDevice(DeviceBackend):
         chain = predict_paths.chain_of(
             ce.n_trees, classes, ce.leaves.shape[2],
             ce.select_spans, ce.paths.shape) if ce.chained else None
+        # CATEGORY SETS (ops/predict_paths.py): the one-hot's K-blocks
+        cat = predict_paths.CatSets(ce.cat_blocks, ce.sel.shape[1]) \
+            if ce.cat_blocks else None
         plan = predict_paths.path_plan(
             ce.n_subtrees or ce.n_trees, ce.lanes, ens.n_features,
             ce.deepest_leaf,
             served=predict_ops.resolve_use_pallas(
                 use_pallas, True, 0, ens.n_features, classes,
                 path_lanes=ce.lanes,
-                path_exit_lanes=chain.exit_lanes if chain else 0),
+                path_exit_lanes=chain.exit_lanes if chain else 0,
+                path_cat_blocks=ce.cat_blocks,
+                path_select_rows=ce.sel.shape[1]),
             missing_routes=missing_routes, row_dtype=self.PREDICT_ROW_DTYPE,
-            chain=chain, widest_tree=ce.widest_tree)._replace(
+            chain=chain, widest_tree=ce.widest_tree, cat=cat)._replace(
+                category_nodes=ce.category_nodes,
+                category_set_bits_max=ce.category_set_bits_max,
                 subtrees_per_tree_max=ce.subtrees_max,
                 single_subtree_trees=ce.single_subtree_trees,
                 link=ce.loss if link else "none",
@@ -2103,9 +2110,13 @@ class TPUDevice(DeviceBackend):
         # cell, whose last block lacks 3 sub-trees).
         fill = ((0, max(0, plan.trees_per_step * plan.table_blocks
                         - len(ce.sel))), (0, 0), (0, 0))
+        # (the two small tables of category sets are the model's, not a
+        # tree's: they go up as they are)
+        per_tree = 4 if chain else 3
         ens_dev = self._put_tables(
-            np.pad(a, fill, constant_values=v) if fill[0][1] else a
-            for a, v in zip(tables, (0, -1.0, 0, 0)))
+            np.pad(a, fill, constant_values=v)
+            if fill[0][1] and i < per_tree else a
+            for i, (a, v) in enumerate(zip(tables, (0, -1.0, 0, 0, 0))))
 
         # Bound here: fn0 outlives this call in the stage registry, and
         # must not hold the host copy of the path tables (78 MB at 500
@@ -2127,8 +2138,15 @@ class TPUDevice(DeviceBackend):
                       entry=predict_ops.predict_raw_effective_paths):
             return entry(sel, planes, paths, Xc, leaves=leaves, **static)
 
+        def fn0_sets(sel, planes, paths, cat_expand, cat_bins, Xc,
+                     entry=predict_ops.predict_raw_effective_paths):
+            return entry(sel, planes, paths, Xc, cat_expand=cat_expand,
+                         cat_bins=cat_bins, **static)
+
         if chain:
             fn0 = fn0_chain
+        elif cat:
+            fn0 = fn0_sets
 
         self._stage_scoring_program(
             predict_ops.predict_raw_effective_paths, fn0, ens_dev,

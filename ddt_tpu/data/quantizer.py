@@ -53,6 +53,16 @@ class BinMapper:
     # save()/load() round-trip it through the same `mapper_*` npz
     # channel as every other field.
     ref_counts: "np.ndarray | None" = None
+    # CATEGORY-SET columns (a LightGBM model's categorical features, made
+    # by models/lightgbm_io.threshold_bin_mapper; PR 55): column ->
+    # (ids, nan_as_zero). `ids` are the raw category ids the model's sets
+    # name, ascending: id ids[i] takes bin i, and EVERY other value (an id
+    # the model never names, a negative value, NaN where `nan_as_zero` is
+    # False) the one bin len(ids) that no set holds. A value is truncated
+    # toward zero first, as LightGBM's `static_cast<int>`; `nan_as_zero`:
+    # NaN counts as id 0 (the library's missing types None and Zero). The
+    # column's `edges` are not read.
+    category_ids: "dict | None" = None
 
     @property
     def n_features(self) -> int:
@@ -108,6 +118,9 @@ class BinMapper:
         nv = self.n_value_bins
         for f in range(self.n_features):
             col = X[:, f]
+            if self.category_ids and f in self.category_ids:
+                out[:, f] = self._category_bins(f, col)
+                continue
             binned = np.searchsorted(self.edges[f, : nv - 1], col,
                                      side="left")
             np.clip(binned, 0, nv - 1, out=binned)
@@ -118,6 +131,17 @@ class BinMapper:
             out[:, f] = binned.astype(np.uint8)
         return out
 
+    def _category_bins(self, f: int, col: np.ndarray) -> np.ndarray:
+        """Bins of a category-set column (`category_ids`)."""
+        ids, nan_as_zero = self.category_ids[f]
+        nan = np.isnan(col)
+        v = np.trunc(np.where(nan, 0.0 if nan_as_zero else -1.0,
+                              np.clip(col, -1.0, 2.0 ** 31 - 1))
+                     ).astype(np.int64)
+        at = np.minimum(np.searchsorted(ids, v), max(len(ids) - 1, 0))
+        named = (ids[at] == v) if len(ids) else np.zeros(len(v), bool)
+        return np.where(named, at, len(ids)).astype(np.uint8)
+
     def transform_device(self, X: np.ndarray) -> np.ndarray:
         """transform() on the default JAX device (ops/quantize.py) —
         bit-identical output. Worth it when the float matrix is already
@@ -126,6 +150,8 @@ class BinMapper:
         measured)."""
         from ddt_tpu.ops.quantize import transform_device
 
+        if self.category_ids:       # a table lookup, not an edge search
+            return self.transform(X)
         return transform_device(self, X)
 
     def threshold_value(self, feature: int, threshold_bin: int) -> float:
@@ -141,12 +167,29 @@ class BinMapper:
              "cat_features": np.asarray(self.cat_features, np.int32)}
         if self.ref_counts is not None:
             d["ref_counts"] = np.asarray(self.ref_counts, np.int64)
+        if self.category_ids:
+            cols = sorted(self.category_ids)
+            d["category_columns"] = np.asarray(cols, np.int32)
+            d["category_nan_as_zero"] = np.asarray(
+                [self.category_ids[c][1] for c in cols], bool)
+            d["category_counts"] = np.asarray(
+                [len(self.category_ids[c][0]) for c in cols], np.int64)
+            d["category_values"] = np.concatenate(
+                [np.asarray(self.category_ids[c][0], np.int64)
+                 for c in cols])
         return d
 
     @staticmethod
     def load(d: dict) -> "BinMapper":
         ref = d.get("ref_counts")
-        return BinMapper(edges=np.asarray(d["edges"], np.float32),
+        ids = None
+        if "category_columns" in d:
+            runs = np.split(np.asarray(d["category_values"], np.int64),
+                            np.cumsum(d["category_counts"])[:-1])
+            ids = {int(c): (run, bool(z)) for c, run, z in zip(
+                d["category_columns"], runs, d["category_nan_as_zero"])}
+        return BinMapper(category_ids=ids,
+                         edges=np.asarray(d["edges"], np.float32),
                          n_bins=int(d["n_bins"]),
                          missing_bin=bool(d.get("missing_bin", False)),
                          cat_features=tuple(

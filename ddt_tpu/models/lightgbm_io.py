@@ -18,14 +18,17 @@ levels deep whose heap would be 2^21 slots for 509 entries; the node list
 holds them as they are and the path-matrix kernel (ops/predict_paths.py)
 scores them. The node list carries ordinal splits, with or without NaN
 default directions (LightGBM's `use_missing`, its default: a model trained
-on data with a missing value in it), and one output column or several
+on data with a missing value in it), one output column or several
 classes as softmax's round-major trees (`multiclass`: tree t to class t %
-num_class, LightGBM's own order): a deep model with category sets
-still imports as a heap, as before, as far as a heap can hold it (depth 30,
-2^27 slots), and past that is refused with the mechanism named. An import
+num_class, LightGBM's own order), and (PR 55) LightGBM's CATEGORY SETS as
+they are: a categorical node's bitset is kept (`NodeListEnsemble`, CATEGORY
+SETS: the library's `cat_boundaries` / `cat_threshold`), not expanded. Only
+a model the heap holds AFTER the expansion below (`HEAP_MAX_DEPTH` levels)
+still imports as a heap of one-vs-rest chains. An import
 carries RAW thresholds only; `threshold_bin_mapper` ranks them into bins
 and returns the BinMapper whose edges they are (NaN in the reserved top
-bin where the model has directions for it), after which the model
+bin where the model has directions for it; a category column's named ids a
+bin each and every other value one bin more), after which the model
 scores binned rows on the device exactly as it scores raw ones on the host.
 
 Format notes (LightGBM's text serialization, stable since v2):
@@ -73,8 +76,8 @@ import warnings
 
 import numpy as np
 
-from ddt_tpu.models.tree import (NodeListEnsemble, TreeEnsemble,
-                                 _refuse_routes, node_list_from_trees)
+from ddt_tpu.models.tree import (CAT_SET_WORDS, NodeListEnsemble,
+                                 TreeEnsemble, node_list_from_trees, set_bits)
 
 # The deepest heap an import yields: what the heap traversal kernel's VMEM
 # plan takes at any feature count (ops/predict_pallas.predict_pallas_fits,
@@ -139,17 +142,34 @@ def _node_list_blocks(ens: NodeListEnsemble) -> list[str]:
         lv = ens.leaf_value[t, :L].astype(np.float64) * ens.learning_rate
         if t < ens.leaf_columns:    # round 0: a tree of every class
             lv = lv + ens.base_score
+        threshold = ens.threshold_raw[t, :n].astype(np.float64)
+        decision = np.asarray(
+            _MISSING_NAN + _DEFAULT_LEFT * ens.default_left[t, :n]
+            if ens.missing_routes else [0] * n, np.int64)
+        # A set node: the library's own form, the bitset's index in the
+        # tree as the threshold; missing type NaN (NaN goes right) or None
+        # (NaN counts as id 0), never default-left.
+        bounds, words = [0], []
+        sets = ens.cat_index[t, :n] if ens.cat_index is not None \
+            else np.full(n, -1)
+        for i in np.flatnonzero(sets >= 0):
+            s0 = int(sets[i])
+            threshold[i] = len(bounds) - 1
+            decision[i] = _CATEGORICAL + (
+                0 if ens.cat_nan_as_zero[s0] else _MISSING_NAN)
+            words += list(ens.cat_threshold[
+                ens.cat_boundaries[s0]:ens.cat_boundaries[s0 + 1]])
+            bounds.append(len(words))
         lines += _tree_block(t, L, {
             "split_feature": ens.feature[t, :n],
             "split_gain": ens.split_gain[t, :n],
-            "threshold": ens.threshold_raw[t, :n],
-            "decision_type": (
-                _MISSING_NAN + _DEFAULT_LEFT * ens.default_left[t, :n]
-                if ens.missing_routes else [0] * n),
+            "threshold": threshold,
+            "decision_type": decision,
             "left_child": ens.left_child[t, :n],
             "right_child": ens.right_child[t, :n],
             "leaf_value": lv,
-        }, ens.learning_rate)
+            "cat_boundaries": bounds, "cat_threshold": words,
+        }, ens.learning_rate, n_cat=len(bounds) - 1)
     return lines
 
 
@@ -163,7 +183,8 @@ def to_lightgbm_text(ens: "TreeEnsemble | NodeListEnsemble",
             "reference.numpy_trainer._fill_raw_thresholds first"
         )
     cat_set = (set(int(f) for f in ens.cat_features)
-               if ens.has_cat_splits else set())
+               if ens.has_cat_splits and ens.cat_features is not None
+               else set())
     if feature_names is None:
         feature_names = [f"Column_{i}" for i in range(ens.n_features)]
     C = ens.n_classes if ens.loss == "softmax" else 1
@@ -264,9 +285,12 @@ def _parse_block(lines: list[str], i: int) -> tuple[dict, int]:
     return d, i
 
 
-def _node_list_of(trees: list, nan_routes: bool, **meta) -> NodeListEnsemble:
+def _node_list_of(trees: list, nan_routes: bool, tree_bits: list = (),
+                  **meta) -> NodeListEnsemble:
     """The parsed `Tree=` blocks as a node list: LightGBM's arrays as they
-    are. Raw thresholds only (`threshold_bin_mapper` ranks them).
+    are, a categorical node's bitset with them (`tree_bits[t][n]`: the ids
+    of node n's set, None of an ordinal node).
+    Raw thresholds only (`threshold_bin_mapper` ranks them).
     `nan_routes`: some node's missing type is NaN; every node then carries
     a default direction, its default-left bit where its missing type is
     NaN and RIGHT elsewhere (where NaN > threshold would send it: the heap
@@ -278,22 +302,33 @@ def _node_list_of(trees: list, nan_routes: bool, **meta) -> NodeListEnsemble:
         return [float(v) for v in blk[k].split()]
 
     per_tree = []
-    for blk in trees:
+    at_set, sets, nan_as_zero = [], [], []      # the category-set nodes
+    for t, blk in enumerate(trees):
         nodes = []
         if int(blk["num_leaves"]) > 1:
             sf, th = ints(blk, "split_feature"), floats(blk, "threshold")
+            dts = ints(blk, "decision_type")
             cols = [sf, [0] * len(sf), th, floats(blk, "split_gain"),
                     ints(blk, "left_child"), ints(blk, "right_child")]
             if nan_routes:
+                # (a set node has no direction: its NaN rule is its own)
                 cols.append([dt >> 2 == 2 and bool(dt & _DEFAULT_LEFT)
-                             for dt in ints(blk, "decision_type")])
+                             and not dt & _CATEGORICAL for dt in dts])
             nodes = list(zip(*cols))
+            for n, bits in enumerate(tree_bits[t] if tree_bits else ()):
+                if bits is not None:
+                    at_set.append((t, n))
+                    sets.append([b for b in bits if b >= 0])
+                    nan_as_zero.append(dts[n] >> 2 != 2)
         per_tree.append((nodes, floats(blk, "leaf_value")))
-    return node_list_from_trees(
+    ens = node_list_from_trees(
         per_tree, learning_rate=1.0,    # leaf values are final contributions
         base_score=0.0,                 # folded into tree 0's leaves
         has_raw_thresholds=True, has_bin_thresholds=False,
         missing_bin=nan_routes, **meta)
+    if sets:
+        ens.set_category_nodes(at_set, sets, nan_as_zero=nan_as_zero)
+    return ens
 
 
 def threshold_bin_mapper(ens: "TreeEnsemble | NodeListEnsemble",
@@ -311,7 +346,19 @@ def threshold_bin_mapper(ens: "TreeEnsemble | NodeListEnsemble",
         api.predict(ens, X_float, mapper=mapper, cfg=cfg)
 
     A feature may carry at most `n_bins - 1` distinct thresholds (254 under
-    LightGBM's max_bin=255); more is refused by name. Ordinal splits only.
+    LightGBM's max_bin=255); more is refused by name. A heap's ordinal
+    splits, and a node list's ordinal splits and CATEGORY SETS: a column
+    some set node asks is a category column (one that an ordinal node asks
+    too is refused: the library never makes one), every raw id that some
+    set of the model names on it gets a bin of its own, in the ids' order,
+    and every other value (an id the model never names, one past a bitset,
+    a negative value; NaN too where the column's nodes have the library's
+    missing type NaN, id 0's bin where they have None or Zero: LightGBM's
+    rule, `NodeListEnsemble`) ONE bin more, which no set holds
+    (`BinMapper.category_ids`); the sets' bins are filled to match
+    (`cat_bin_sets`). At most `n_bins` ids a column may be named (255 under
+    max_bin=255, with the bin of the rest a uint8's 256); more is refused
+    by name.
     A model with learned NaN directions (`missing_bin` and `default_left`)
     gets the mapper of the "learn" policy: NaN takes the reserved top bin
     `n_bins - 1` (254), values the bins below it, so a feature may carry
@@ -322,18 +369,23 @@ def threshold_bin_mapper(ens: "TreeEnsemble | NodeListEnsemble",
 
     if not ens.has_raw_thresholds:
         raise ValueError("threshold_bin_mapper needs raw thresholds")
-    if ens.has_cat_splits:
+    sets = ens.cat_nodes if isinstance(ens, NodeListEnsemble) else None
+    if ens.has_cat_splits and sets is None:
         raise ValueError(
-            "threshold_bin_mapper covers ordinal splits: this model "
-            "carries category nodes, whose bins are not ranks of "
-            "thresholds")
+            "threshold_bin_mapper covers ordinal splits and a node list's "
+            "category sets: this heap carries one-vs-rest category nodes, "
+            "whose bins are the category ids themselves")
     missing = bool(ens.missing_bin) and ens.default_left is not None
     live = (ens.live_nodes if isinstance(ens, NodeListEnsemble)
             else ~ens.is_leaf & (ens.feature >= 0))
     n_edges = n_bins - 1 - missing
     edges = np.full((ens.n_features, n_bins - 1), np.inf, np.float32)
+    category_ids = _rank_category_sets(ens, sets, live, n_bins) \
+        if sets is not None and sets.any() else None
     for f in range(ens.n_features):
         at = live & (ens.feature == f)
+        if category_ids and f in category_ids:
+            continue
         distinct = np.unique(ens.threshold_raw[at])
         if len(distinct) > n_edges:
             raise ValueError(
@@ -348,21 +400,70 @@ def threshold_bin_mapper(ens: "TreeEnsemble | NodeListEnsemble",
     if isinstance(ens, NodeListEnsemble):
         ens.has_bin_thresholds = True
     ens.n_bins = n_bins
-    return BinMapper(edges=edges, n_bins=n_bins, missing_bin=missing)
+    return BinMapper(edges=edges, n_bins=n_bins, missing_bin=missing,
+                     category_ids=category_ids)
+
+
+def _rank_category_sets(ens: NodeListEnsemble, sets: np.ndarray,
+                        live: np.ndarray, n_bins: int) -> dict:
+    """`threshold_bin_mapper` of a node list's category columns: column ->
+    (the raw ids its sets name, ascending; whether NaN counts as id 0), and
+    `ens.cat_bin_sets` filled with the sets over those ids' ranks."""
+    out = {}
+    ens.cat_bin_sets = np.zeros((len(ens.cat_bin_sets), CAT_SET_WORDS),
+                                np.uint32)
+    tt, nn = np.nonzero(sets)
+    ss, col = ens.cat_index[tt, nn], ens.feature[tt, nn]
+    for f in np.unique(col):
+        f = int(f)
+        if (live & ~sets & (ens.feature == f)).any():
+            raise ValueError(
+                f"feature {f} is asked by category-set nodes and by ordinal "
+                "nodes: its values would need two binnings (LightGBM makes "
+                "no such model)")
+        mine = ss[col == f]
+        zero = ens.cat_nan_as_zero[mine]
+        if zero.any() != zero.all():
+            raise ValueError(
+                f"feature {f}: its category-set nodes disagree on the "
+                "missing type (NaN goes right in some and counts as id 0 "
+                "in others); one binning cannot serve both")
+        ids_of = [_set_ids(ens, s) for s in mine]
+        ids = np.unique(np.concatenate(ids_of)) if ids_of else np.zeros(
+            0, np.int64)
+        if len(ids) > min(n_bins, 255):
+            raise ValueError(
+                f"feature {f}: the model's category sets name {len(ids)} "
+                f"ids, more than the {min(n_bins, 255)} that {n_bins} bins "
+                "of a uint8 leave beside the bin of every other value (a "
+                "model trained with max_bin > 255?): it cannot be scored on "
+                "binned uint8 rows")
+        for s, mine_ids in zip(mine, ids_of):
+            set_bits(ens.cat_bin_sets[s], np.searchsorted(ids, mine_ids))
+        out[f] = (ids.astype(np.int64), bool(zero.all()))
+    return out
+
+
+def _set_ids(ens: NodeListEnsemble, s: int) -> np.ndarray:
+    """The raw category ids of set `s`, ascending."""
+    words = ens.cat_threshold[ens.cat_boundaries[s]:ens.cat_boundaries[s + 1]]
+    return np.flatnonzero(np.unpackbits(
+        np.ascontiguousarray(words, "<u4").view(np.uint8),
+        bitorder="little")).astype(np.int64)
 
 
 def from_lightgbm_text(text: str) -> "TreeEnsemble | NodeListEnsemble":
     """Parse a LightGBM model.txt back into an ensemble: a TreeEnsemble
     (heap) for trees of at most HEAP_MAX_DEPTH levels, a NodeListEnsemble
-    for deeper ordinal ones, NaN default directions with them (module
-    docstring, 'Which layout').
+    for deeper ones, NaN default directions and category sets with them
+    (module docstring, 'Which layout').
 
-    Supports what to_lightgbm_text writes (numerical splits, single-bit
-    categorical nodes, optional NaN-missing default directions) PLUS
-    externally-trained models with multi-category bitsets, which expand
-    into equivalent one-vs-rest chains (module docstring, 'Import
-    breadth'). Trees with category nodes deeper than 30 levels after
-    chain expansion overflow the heap and raise."""
+    Supports what to_lightgbm_text writes (numerical splits, categorical
+    nodes, optional NaN-missing default directions) PLUS
+    externally-trained models with multi-category bitsets, which a heap
+    holds expanded into equivalent one-vs-rest chains (module docstring,
+    'Import breadth': a model of at most HEAP_MAX_DEPTH levels after the
+    expansion) and a node list as they are."""
     lines = text.splitlines()
     head, i = _parse_block(lines, 0)
     n_features = int(head["max_feature_idx"]) + 1
@@ -447,37 +548,15 @@ def from_lightgbm_text(text: str) -> "TreeEnsemble | NodeListEnsemble":
 
     max_depth = max(1, max(depth_of(b, bi)
                            for b, bi in zip(trees, tree_bits)))
-    nan_routes = any((int(float(v)) >> 2) == 2 for b in trees
-                     for v in b.get("decision_type", "").split())
-    categories = any(b is not None for bi in tree_bits for b in bi)
-    if max_depth > HEAP_MAX_DEPTH and not categories:
-        return _node_list_of(trees, nan_routes, n_features=n_features,
-                             loss=loss, n_classes=max(C, 2))
-    if max_depth > 30:
-        _refuse_routes(f"from_lightgbm_text (tree depth {max_depth} after "
-                       "multi-category chain expansion overflows the heap "
-                       "layout)", categories=categories)
-    # The heap is DENSE and its depth is GLOBAL: one k-category set deep
-    # in one tree adds k-1 levels to EVERY tree's 2^(D+1)-1 node arrays.
-    # Real LightGBM categorical splits routinely carry dozens of
-    # categories, where the expansion allocates astronomically — fail
-    # with the cause and the number, not a MemoryError from np.full.
-    # 2^27 total nodes ~ 2.3 GB across the seven node arrays.
-    total_nodes = len(trees) * (2 ** (max_depth + 1) - 1)
-    if total_nodes > 2 ** 27:
-        widest = max((len(b) for bi in tree_bits
-                      for b in bi if b is not None), default=1)
-        raise ValueError(
-            f"multi-category chain expansion needs depth {max_depth} "
-            f"across {len(trees)} trees = {total_nodes} heap nodes "
-            f"(> 2^27): the dense heap layout cannot hold this model "
-            f"(widest category set: {widest} bits). Models with large "
-            "categorical sets are unrepresentable here (the node-list "
-            "layout, which holds deep trees as they are, does not support "
-            "category sets yet); score them with LightGBM itself, or "
-            "retrain with "
-            "cat_features one-vs-rest splits"
-        )
+    if max_depth > HEAP_MAX_DEPTH:
+        # (a node list's NaN directions are its ORDINAL nodes': a model of
+        # set nodes alone has none, and its program is the plain compare)
+        nan_routes = any(
+            (int(float(v)) >> 2) == 2 and not int(float(v)) & _CATEGORICAL
+            for b in trees for v in b.get("decision_type", "").split())
+        return _node_list_of(trees, nan_routes, tree_bits,
+                             n_features=n_features, loss=loss,
+                             n_classes=max(C, 2))
     n_nodes = 2 ** (max_depth + 1) - 1
     T = len(trees)
     feature = np.full((T, n_nodes), -1, np.int32)

@@ -612,6 +612,8 @@ PATH_UNCUT_LANES = 4 * PATH_LANES
 # step, a leaf table of at least a tile) are paid twice as often and the
 # cut fills its sub-trees no better; at 512 the resolve doubles a node.
 SUBTREE_LANES = 2 * PATH_LANES
+# uint32 words of a category set over a column's bins: 256 bits, a uint8's.
+CAT_SET_WORDS = 8
 
 
 @dataclasses.dataclass
@@ -632,10 +634,25 @@ class NodeListEnsemble:
     (leaf-wise) growth makes them: 255 leaves 13-20 levels down are 509
     entries here and 2^21 heap slots in `TreeEnsemble`. The trainer writes
     heaps; a node list is an import (`models/lightgbm_io.py`), a conversion
-    (`from_heap`) or hand-built. Ordinal splits (with or without the NaN
-    directions) only: category sets have no field here, and
-    `from_lightgbm_text` / `from_heap` refuse them by name (the heap layout
-    serves them).
+    (`from_heap`) or hand-built.
+
+    CATEGORY SETS (LightGBM's categorical splits; `cat_index` and the set
+    tables): node n of tree t with `cat_index[t, n] = s >= 0` asks no
+    threshold. LightGBM's rule, stated here once: a row goes LEFT iff its
+    value is a non-negative integer (the float truncated toward zero, as
+    the library's `static_cast<int>`) whose bit is set in the node's bitset
+    (`cat_boundaries` / `cat_threshold`, the library's own arrays over RAW
+    category ids, one run of uint32 words a set); an id past the bitset, a
+    negative value and an id the set does not name go RIGHT. NaN goes RIGHT
+    where the node's missing type is NaN, and counts as id 0 elsewhere
+    (`cat_nan_as_zero[s]`: the library's missing types None and Zero).
+    Binned rows: `cat_bin_sets[s]` is the same set over the column's BINS
+    (256 bits, uint32 [8]: bit b set, bin b goes left), which
+    `lightgbm_io.threshold_bin_mapper` fills: every raw id some set of the
+    column names has a bin of its own and every other value ONE bin that
+    no set holds. The learned NaN directions of ordinal nodes (`default_
+    left`) mean nothing at a set node, whose column has no NaN bin. A heap's
+    one-vs-rest node (`from_heap`) is a set of one bit.
 
     SEVERAL CLASSES (`loss` "softmax"; an import of `models/xgboost_io.py`
     or `models/lightgbm_io.py`, `from_heap` of a multiclass heap): the
@@ -680,13 +697,35 @@ class NodeListEnsemble:
     # n_bins-1, the value bins').
     default_left: np.ndarray | None = None   # bool [T, N]
     missing_bin: bool = False
+    # Category sets (the docstring's CATEGORY SETS): which nodes, and each
+    # one's set over the column's bins and over raw ids. None: no set node.
+    cat_index: np.ndarray | None = None      # int32 [T, N]: -1 ordinal, else
+    #   the node's row s of the set tables
+    cat_bin_sets: np.ndarray | None = None   # uint32 [S, 8]: bit b, BIN b left
+    cat_boundaries: np.ndarray | None = None  # int64 [S + 1]: set s is words
+    #   cat_threshold[cat_boundaries[s]:cat_boundaries[s + 1]] (raw ids)
+    cat_threshold: np.ndarray | None = None  # uint32 [...]: LightGBM's bitsets
+    cat_nan_as_zero: np.ndarray | None = None    # bool [S]: NaN counts as id 0
 
-    # What a heap ensemble answers for, so that scoring entry points ask
-    # one question of either layout.
-    has_cat_splits = False
+    # What a heap ensemble answers for beside `has_cat_splits`, so that
+    # scoring entry points ask one question of either layout (a node list
+    # names its set NODES, not whole columns).
     cat_features = None
 
     def __post_init__(self):
+        if self.cat_index is not None:
+            S = int(self.cat_index.max(initial=-1)) + 1
+            if self.cat_index.shape != self.feature.shape or any(
+                    a is None or len(a) < S for a in (
+                        self.cat_bin_sets, self.cat_nan_as_zero)) or (
+                    self.has_raw_thresholds and (
+                        self.cat_boundaries is None
+                        or len(self.cat_boundaries) < S + 1)):
+                raise ValueError(
+                    f"category-set nodes need cat_index {self.feature.shape}"
+                    f" and a row of cat_bin_sets [S, {CAT_SET_WORDS}], of "
+                    "cat_nan_as_zero [S] and (with raw thresholds) of "
+                    f"cat_boundaries [S + 1] for each of the {S} sets")
         if self.loss == "softmax" and self.n_classes < 2:
             raise ValueError(
                 "a softmax node list scores tree t into class t % n_classes:"
@@ -726,6 +765,25 @@ class NodeListEnsemble:
     def n_nodes(self) -> int:
         """The nodes that ask a question, over all trees."""
         return int(np.maximum(self.n_leaves.astype(np.int64) - 1, 0).sum())
+
+    @property
+    def cat_nodes(self) -> np.ndarray:
+        """bool [T, N]: the live nodes that ask a category set."""
+        if self.cat_index is None:
+            return np.zeros(self.feature.shape, bool)
+        return self.live_nodes & (self.cat_index >= 0)
+
+    @property
+    def has_cat_splits(self) -> bool:
+        """Whether some node asks a category set (a fact of the model)."""
+        return bool(self.cat_nodes.any())
+
+    def cat_set_bits(self, sets=slice(None)) -> np.ndarray:
+        """bool [S, 256]: set s over the column's bins, a bit a column (of
+        the rows `sets` of the table alone, where given)."""
+        return np.unpackbits(
+            np.ascontiguousarray(self.cat_bin_sets[sets], "<u4").view(
+                np.uint8), axis=-1, bitorder="little").astype(bool)
 
     @property
     def vector_leaves(self) -> bool:
@@ -882,6 +940,12 @@ class NodeListEnsemble:
             # [T, L, C] and [T, L * C] hash alike; the same trees under
             # another class count score other columns
             h.update(repr(("leaf_columns", self.leaf_columns)).encode())
+        at = self.cat_nodes
+        if at.any():
+            h.update(b"category_sets")
+            h.update(np.flatnonzero(at).tobytes())
+            h.update(np.ascontiguousarray(
+                self.cat_bin_sets[self.cat_index[at]]).tobytes())
         return h.hexdigest()
 
     def compile(self, tree_chunk: int = 64) -> "CompiledNodeList":
@@ -919,11 +983,41 @@ class NodeListEnsemble:
                 go_right = np.where(
                     miss, ~np.take_along_axis(self.default_left, at, axis=1),
                     go_right)
+            if self.cat_index is not None:
+                s = np.take_along_axis(self.cat_index, at, axis=1)
+                go_right = np.where(
+                    s >= 0, ~self._in_set(np.maximum(s, 0), fv, binned),
+                    go_right)
             nxt = np.where(go_right,
                            np.take_along_axis(self.right_child, at, axis=1),
                            np.take_along_axis(self.left_child, at, axis=1))
             cur = np.where(cur >= 0, nxt, cur)
         return ~cur
+
+    def _in_set(self, s: np.ndarray, v: np.ndarray,
+                binned: bool) -> np.ndarray:
+        """Whether value `v` (bins, or raw float32 values) lies in set `s`,
+        elementwise: the docstring's CATEGORY SETS."""
+        if binned:
+            b = np.asarray(v, np.int64)
+            ok = (b >= 0) & (b < 32 * CAT_SET_WORDS)
+            b = np.where(ok, b, 0)
+            return ok & ((self.cat_bin_sets[s, b >> 5] >> (b & 31)) & 1
+                         ).astype(bool)
+        if not len(self.cat_threshold):
+            return np.zeros(np.shape(v), bool)
+        nan = np.isnan(v)
+        zero = nan & self.cat_nan_as_zero[s]
+        # the library's static_cast<int>: toward zero (-0.5 is id 0)
+        i = np.trunc(np.where(nan, 0.0, np.clip(v, -1.0, 2.0 ** 31 - 1))
+                     ).astype(np.int64)
+        lo = self.cat_boundaries[s]
+        ok = (~nan | zero) & (i >= 0) & (
+            i < 32 * (self.cat_boundaries[s + 1] - lo))
+        i = np.where(ok, i, 0)
+        word = self.cat_threshold[np.minimum(
+            lo + (i >> 5), len(self.cat_threshold) - 1)]
+        return ok & ((word >> (i & 31).astype(np.uint32)) & 1).astype(bool)
 
     def predict_raw(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
         """Raw (margin) scores [R], float32; of softmax's round-major
@@ -959,10 +1053,16 @@ class NodeListEnsemble:
     def from_heap(ens: TreeEnsemble) -> "NodeListEnsemble":
         """The same trees as a node list, nodes and leaves numbered in
         pre-order (root = node 0), learned NaN directions and softmax's
-        round-major classes with them. A heap ensemble with category nodes
-        is refused."""
-        _refuse_routes("from_heap", categories=ens.has_cat_splits)
+        round-major classes with them. A heap's one-vs-rest category node
+        (`bin == k` goes left) is a set of the one bit k, over bins and
+        over raw ids alike (a heap's category columns are identity-binned);
+        one that sends NaN LEFT is refused by name: a set node sends NaN
+        right (the class's docstring)."""
         routed = ens.missing_bin and ens.default_left is not None
+        cat = set(int(f) for f in ens.cat_features) \
+            if ens.has_cat_splits else set()
+        sets: list = []                 # the set nodes' one bit, in order
+        at_set: list = []               # ... and their (tree, node)
         trees = []
         for t in range(ens.n_trees):
             nodes, leaves = [], []
@@ -972,6 +1072,11 @@ class NodeListEnsemble:
                     leaves.append(ens.leaf_value[t, slot])
                     return -len(leaves)
                 i = len(nodes)
+                if int(ens.feature[t, slot]) in cat:
+                    if routed and ens.default_left[t, slot]:
+                        _refuse_routes("from_heap", nan_left_category=True)
+                    sets.append([int(ens.threshold_bin[t, slot])])
+                    at_set.append((t, i))
                 nodes.append([ens.feature[t, slot],
                               ens.threshold_bin[t, slot],
                               ens.threshold_raw[t, slot],
@@ -984,12 +1089,36 @@ class NodeListEnsemble:
 
             walk(0)
             trees.append((nodes, leaves))
-        return node_list_from_trees(
+        out = node_list_from_trees(
             trees, n_features=ens.n_features,
             learning_rate=ens.learning_rate, base_score=ens.base_score,
             loss=ens.loss, n_classes=ens.n_classes,
             has_raw_thresholds=ens.has_raw_thresholds, n_bins=ens.n_bins,
             missing_bin=bool(routed))
+        if sets:
+            out.set_category_nodes(at_set, sets, bin_sets=sets)
+        return out
+
+    def set_category_nodes(self, at: list, raw_sets: list,
+                           bin_sets: list | None = None,
+                           nan_as_zero=None) -> None:
+        """Make nodes `at[i] = (tree, node)` category-set nodes: set i holds
+        the raw ids `raw_sets[i]` and (where known) the bins `bin_sets[i]`
+        (else `lightgbm_io.threshold_bin_mapper` fills them);
+        `nan_as_zero[i]`: NaN counts as id 0 there (default: goes right)."""
+        S = len(at)
+        self.cat_index = np.full(self.feature.shape, -1, np.int32)
+        tt, nn = np.asarray(at, np.int64).reshape(S, 2).T
+        self.cat_index[tt, nn] = np.arange(S)
+        self.cat_boundaries, self.cat_threshold = bitset_words(raw_sets)
+        self.cat_bin_sets = np.zeros((S, CAT_SET_WORDS), np.uint32)
+        if bin_sets is not None:
+            for i, bins in enumerate(bin_sets):
+                set_bits(self.cat_bin_sets[i], bins)
+        self.cat_nan_as_zero = np.zeros(S, bool) if nan_as_zero is None \
+            else np.asarray(nan_as_zero, bool)
+        self.threshold_bin[tt, nn] = 0
+        self.threshold_raw[tt, nn] = 0.0
 
     def feature_importances(self, kind: str = "split") -> np.ndarray:
         """As `TreeEnsemble.feature_importances`, over the live nodes."""
@@ -1011,9 +1140,16 @@ class NodeListEnsemble:
                    if self.has_raw_thresholds else "")
             nan = (f"  nan->{'L' if self.default_left[t, ref] else 'R'}"
                    if self.missing_routes else "")
-            lines.append(f"{pad}f{self.feature[t, ref]} <= bin "
-                         f"{self.threshold_bin[t, ref]}{thr}{nan}  "
-                         f"gain={self.split_gain[t, ref]:.4g}")
+            if self.cat_index is not None and self.cat_index[t, ref] >= 0:
+                bins = np.flatnonzero(
+                    self.cat_set_bits(self.cat_index[t, ref]))
+                lines.append(f"{pad}f{self.feature[t, ref]} in bins {{"
+                             + ",".join(map(str, bins)) + "}  "
+                             f"gain={self.split_gain[t, ref]:.4g}")
+            else:
+                lines.append(f"{pad}f{self.feature[t, ref]} <= bin "
+                             f"{self.threshold_bin[t, ref]}{thr}{nan}  "
+                             f"gain={self.split_gain[t, ref]:.4g}")
             walk(int(self.left_child[t, ref]), depth + 1)
             walk(int(self.right_child[t, ref]), depth + 1)
 
@@ -1052,12 +1188,21 @@ class NodeListEnsemble:
             missing_bin=np.bool_(self.missing_bin))
         if self.default_left is not None:
             d["default_left"] = self.default_left
+        if self.cat_index is not None:
+            d.update({k: getattr(self, k) for k, _ in self._CAT_ARRAYS
+                      if getattr(self, k) is not None})
         return d
+
+    _CAT_ARRAYS = (("cat_index", np.int32), ("cat_bin_sets", np.uint32),
+                   ("cat_boundaries", np.int64), ("cat_threshold", np.uint32),
+                   ("cat_nan_as_zero", bool))
 
     @staticmethod
     def from_dict(d: dict) -> "NodeListEnsemble":
         return NodeListEnsemble(
             **{k: np.asarray(d[k], dt) for k, dt in NodeListEnsemble._ARRAYS},
+            **{k: np.asarray(d[k], dt)
+               for k, dt in NodeListEnsemble._CAT_ARRAYS if k in d},
             default_left=(np.asarray(d["default_left"], bool)
                           if "default_left" in d else None),
             missing_bin=bool(d.get("missing_bin", False)),
@@ -1103,16 +1248,44 @@ def ensemble_from_dict(
     return LAYOUTS[name].from_dict(d)
 
 
-def _refuse_routes(where: str, *, categories: bool) -> None:
-    """The one list of what a node list cannot carry, named. (Several
-    classes it carries, as softmax's round-major trees or as the vector
-    leaves of an averaged forest: `NodeListEnsemble`.)"""
-    if categories:
+def _refuse_routes(where: str, *, chained_sets: bool = False,
+                   nan_left_category: bool = False) -> None:
+    """The one list of what a node list cannot carry or score, named.
+    (Several classes it carries, as softmax's round-major trees or as the
+    vector leaves of an averaged forest, and category sets in trees that
+    one path matrix holds: `NodeListEnsemble`.)"""
+    if chained_sets:
         raise ValueError(
-            f"{where}: the model needs the node-list layout (a tree too "
-            "deep for the heap) and carries category-set (one-vs-rest / "
-            "bitset) nodes, which the node-list layout does not support "
-            "yet; the heap layout serves them for trees it can hold")
+            f"{where}: category-set nodes in the SUB-TREE form of the path "
+            "tables (a tree past PATH_UNCUT_LANES lanes, vector leaves or "
+            "softmax's round-major trees) are not supported: a sub-tree's "
+            "glue and spine copies have no set tables yet; the host walk "
+            "(backend 'cpu') scores such a model")
+    if nan_left_category:
+        raise ValueError(
+            f"{where}: a heap's category node that sends NaN LEFT has no "
+            "node-list form: a category-set node sends NaN right "
+            "(LightGBM's rule for its missing type NaN)")
+
+
+def set_bits(words: np.ndarray, bits) -> None:
+    """Set the bits `bits` of the uint32 row `words` in place (bit i of
+    word w is 32 w + i)."""
+    b = np.asarray(bits, np.int64)
+    np.bitwise_or.at(words, b >> 5, (1 << (b & 31)).astype(np.uint32))
+
+
+def bitset_words(sets: list) -> tuple:
+    """(boundaries int64 [S + 1], words uint32 [...]): LightGBM's
+    `cat_boundaries` / `cat_threshold` of the id lists `sets`, set s the
+    words boundaries[s]:boundaries[s + 1], bit i of word w the id 32 w + i
+    (a set of no id is a run of one zero word)."""
+    runs = []
+    for ids in sets:
+        runs.append(np.zeros(max(ids, default=0) // 32 + 1, np.uint32))
+        set_bits(runs[-1], ids)
+    bounds = np.cumsum([0] + [len(r) for r in runs], dtype=np.int64)
+    return bounds, np.concatenate(runs + [np.zeros(0, np.uint32)])
 
 
 def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
@@ -1150,6 +1323,7 @@ def node_list_from_trees(trees: list, **meta) -> NodeListEnsemble:
 def random_node_list(rng, n_trees: int, n_leaves: int, n_features: int,
                      n_bins: int = 255, dyadic: bool = False,
                      missing: bool = False, leaf_columns: int = 0,
+                     categories: tuple = (), max_set: int = 32,
                      **meta) -> NodeListEnsemble:
     """A random leaf-wise ensemble for tests, chip_smoke.py and the compile
     check (no trainer grows one): a random leaf is split until `n_leaves`
@@ -1159,8 +1333,13 @@ def random_node_list(rng, n_trees: int, n_leaves: int, n_features: int,
     is the NaN bin, thresholds lie in the value bins below it and every
     node's default direction is a fair coin. `leaf_columns` C > 0: vector
     leaves [T, L, C] of an averaged forest (`loss` "mean"), and `n_leaves`
-    may be a range (lo, hi): each tree draws its own count."""
+    may be a range (lo, hi): each tree draws its own count. `categories`:
+    (column, cardinality k) pairs; a node on such a column asks a CATEGORY
+    SET of 1 to min(`max_set`, k) of the column's bins 0..k-1 (bin k, every
+    unnamed value's, is in no set), over bins and raw ids alike."""
     trees = []
+    cats = dict(categories)
+    at_set, sets = [], []
     shape = (leaf_columns,) if leaf_columns else ()
     if leaf_columns:
         meta = dict(learning_rate=1.0, base_score=0.0, loss="mean",
@@ -1175,14 +1354,23 @@ def random_node_list(rng, n_trees: int, n_leaves: int, n_features: int,
                           int(rng.integers(n_bins - 1 - missing)), 0.0, 0.0,
                           ~leaf, ~len(where)]
                          + ([bool(rng.integers(2))] if missing else []))
+            if nodes[-1][0] in cats:
+                k = cats[nodes[-1][0]]
+                at_set.append((len(trees), n))
+                sets.append(sorted(int(b) for b in rng.choice(
+                    k, int(rng.integers(1, min(max_set, k) + 1)),
+                    replace=False)))
             if where[leaf] is not None:
                 nodes[where[leaf][0]][where[leaf][1]] = n
             where[leaf] = (n, 4)
             where.append((n, 5))
         trees.append((nodes, rng.integers(-16, 17, (leaves,) + shape) / 8.0
                       if dyadic else rng.standard_normal((leaves,) + shape)))
-    return node_list_from_trees(trees, n_features=n_features, n_bins=n_bins,
-                                missing_bin=missing, **meta)
+    ens = node_list_from_trees(trees, n_features=n_features, n_bins=n_bins,
+                               missing_bin=missing, **meta)
+    if sets:
+        ens.set_category_nodes(at_set, sets, bin_sets=sets)
+    return ens
 
 
 class SubtreeCut(typing.NamedTuple):
@@ -1885,6 +2073,30 @@ class CompiledNodeList:
                                  tree's sub-trees), link0 = CL: 85 or 128
                                  classes, a 10-class tree of 100 sub-trees.
 
+    CATEGORY SETS (the uncut form alone; the sub-tree form refuses them by
+    name). The set test stays a row of the select: the kernel widens every
+    category column to the ONE-HOT of its bin, 128 K rows a block
+    (`cat_blocks` B: small columns share a block, a column of more than 128
+    named bins takes two), and a set node's column of `sel` is MULTI-hot
+    over its column's K rows, so that v is 1 where the row's bin is in the
+    set and 0 elsewhere, the threshold 0, and `v > thr` says IN THE SET:
+    left. The node's row of `paths` is negated to say so (+1 left), and the
+    resolve, the accumulate and the fold never know. Two small tables say
+    what a K row of a block is,
+
+        sel        [T, Fo + 128 B, W]  Fo = Fp where a node is ordinal (its
+                                   rows as above), 0 where every node asks a
+                                   set; then the blocks' K rows
+        cat_expand [B, Fp, 128] bf16   1 in row c of lane j where K row j of
+                                   the block reads column c: x @ it is the
+                                   row's bin of that column, in lane j
+        cat_bins   [B, 8, 128] f32     row 0 the bin K row j stands for
+                                   (-1: no K row), so one compare gives the
+                                   block's one-hot
+
+    A bin no K row stands for (every unnamed value's, or one past the
+    mapper's) is in no set: v is 0 and the row goes right.
+
     `mean`: the score is the sum over the trees divided by their number
     (an averaged forest), else base + learning_rate x sum ([rows] of one
     column; [rows, C] of softmax's classes, whose trees take the sub-tree
@@ -1916,6 +2128,20 @@ class CompiledNodeList:
     spine_copies: int = 0      # halved: the lanes that hold a node's copy
     pieces: int = 0            # the connected pieces of the S entries ...
     glue_copies: int = 0       # ... and the lanes that hold a glue copy
+    cat_expand: np.ndarray | None = None   # category sets: the K rows'
+    cat_bins: np.ndarray | None = None     #   columns and bins
+    category_nodes: int = 0    # the nodes that ask a set ...
+    category_set_bits_max: int = 0     # ... and the widest set's bins
+
+    @property
+    def cat_blocks(self) -> int:
+        """K-blocks of 128 one-hot rows the category sets read."""
+        return 0 if self.cat_expand is None else len(self.cat_expand)
+
+    @property
+    def ordinal_rows(self) -> int:
+        """K rows of `sel` that read the bins themselves (ordinal nodes)."""
+        return self.sel.shape[1] - PATH_LANES * self.cat_blocks
 
     @property
     def n_classes_out(self) -> int:
@@ -1932,7 +2158,8 @@ class CompiledNodeList:
 
     def arrays(self) -> tuple:
         return (self.sel, self.planes, self.paths) + (
-            (self.leaves,) if self.chained else ())
+            (self.leaves,) if self.chained else ()) + (
+            (self.cat_expand, self.cat_bins) if self.cat_blocks else ())
 
     @staticmethod
     def build(ens: NodeListEnsemble) -> "CompiledNodeList":
@@ -1953,23 +2180,32 @@ class CompiledNodeList:
                 f"a node's threshold bin is the NaN bin {nan_bin} or "
                 "above it: with learned NaN directions thresholds lie "
                 "in the value bins")
+        sets = ens.cat_nodes
         if ens.leaf_columns > 1 or ens.vector_leaves \
                 or W > PATH_UNCUT_LANES:
+            _refuse_routes("CompiledNodeList.build",
+                           chained_sets=bool(sets.any()))
             return CompiledNodeList._build_subtrees(ens, W, Fp)
         P, plen = ens.path_matrix()
-        sel = np.zeros((T, Fp, W), ml_dtypes.bfloat16)
-        t_idx, n_idx = np.nonzero(live)
-        sel[t_idx, ens.feature[t_idx, n_idx], n_idx] = 1.0
+        if not sets.any():
+            sel = np.zeros((T, Fp, W), ml_dtypes.bfloat16)
+            t_idx, n_idx = np.nonzero(live)
+            sel[t_idx, ens.feature[t_idx, n_idx], n_idx] = 1.0
+            cat = {}
+        else:
+            sel, cat = CompiledNodeList._select_with_sets(ens, sets, Fp, W)
+            P[sets] = -P[sets]      # +1 of a set node: in the set, LEFT
         planes = np.zeros((T, 8, W), np.float32)
         planes[:, 0, :] = 2.0 ** 30
-        planes[:, 0, :N] = np.where(live, ens.threshold_bin, 2.0 ** 30)
+        planes[:, 0, :N] = np.where(live, np.where(sets, 0, ens.threshold_bin),
+                                    2.0 ** 30)
         planes[:, 1, :] = -1.0
         planes[:, 1, :L] = plen
         planes[:, 2, :L] = ens.leaf_value
         if nan_bin >= 0:
             planes[:, 3, :] = 2.0 ** 30
-            planes[:, 3, :N] = np.where(live & ens.default_left, nan_bin,
-                                        2.0 ** 30)
+            planes[:, 3, :N] = np.where(live & ens.default_left & ~sets,
+                                        nan_bin, 2.0 ** 30)
         paths = np.zeros((T, W, W), ml_dtypes.bfloat16)
         paths[:, :N, :L] = P
         return CompiledNodeList(
@@ -1978,7 +2214,61 @@ class CompiledNodeList:
             base_score=float(ens.base_score), loss=ens.loss,
             n_trees=T, lanes=W, deepest_leaf=int(plen.max(initial=0)),
             sel=sel, planes=planes, paths=paths, missing_bin_value=nan_bin,
-            widest_tree=W, single_subtree_trees=T)
+            widest_tree=W, single_subtree_trees=T, **cat)
+
+    @staticmethod
+    def _select_with_sets(ens: NodeListEnsemble, sets: np.ndarray, Fp: int,
+                          W: int) -> tuple:
+        """(`sel` [T, Fo + 128 B, W], the fields that say what its K rows
+        past Fo are): `build` of a model with category sets (the class's
+        docstring, CATEGORY SETS). A column's K rows are the bins its sets
+        name, 0 up to the largest; the columns are packed into blocks of
+        128 rows first-fit, the largest first, a column of more than 128
+        rows as whole blocks and a rest."""
+        import ml_dtypes
+
+        T = ens.feature.shape[0]
+        bits = ens.cat_set_bits()                   # [S, 256]
+        tt, nn = np.nonzero(sets)
+        ss = ens.cat_index[tt, nn]
+        col = ens.feature[tt, nn]
+        rows = {}                                   # column -> its K rows
+        for c in np.unique(col):
+            named = np.flatnonzero(bits[ss[col == c]].any(axis=0))
+            rows[int(c)] = int(named.max(initial=0)) + 1
+        # (rows, column, first bin) pieces of at most a block each
+        pieces = sorted(((min(PATH_LANES, k - b0), c, b0)
+                         for c, k in rows.items()
+                         for b0 in range(0, k, PATH_LANES)),
+                        key=lambda p: (-p[0], p[1], p[2]))
+        free: list = []                             # rows left a block
+        row_of = np.full((ens.n_features, 32 * CAT_SET_WORDS), -1, np.int64)
+        where = []
+        for k, c, b0 in pieces:
+            blk = next((i for i, f in enumerate(free) if f >= k), len(free))
+            if blk == len(free):
+                free.append(PATH_LANES)
+            lane = PATH_LANES - free[blk]
+            free[blk] -= k
+            row_of[c, b0:b0 + k] = blk * PATH_LANES + lane + np.arange(k)
+            where.append((blk, lane, k, c, b0))
+        B = len(free)
+        expand = np.zeros((B, Fp, PATH_LANES), ml_dtypes.bfloat16)
+        bins = np.zeros((B, 8, PATH_LANES), np.float32)
+        bins[:, 0, :] = -1.0
+        for blk, lane, k, c, b0 in where:
+            expand[blk, c, lane:lane + k] = 1.0
+            bins[blk, 0, lane:lane + k] = b0 + np.arange(k)
+        ordinal = ens.live_nodes & ~sets
+        Fo = Fp if ordinal.any() else 0
+        sel = np.zeros((T, Fo + PATH_LANES * B, W), ml_dtypes.bfloat16)
+        t_idx, n_idx = np.nonzero(ordinal)
+        sel[t_idx, ens.feature[t_idx, n_idx], n_idx] = 1.0
+        at, b = np.nonzero(bits[ss])                # (set node, bin in it)
+        sel[tt[at], Fo + row_of[col[at], b], nn[at]] = 1.0
+        return sel, dict(
+            cat_expand=expand, cat_bins=bins, category_nodes=len(ss),
+            category_set_bits_max=int(bits[ss].sum(axis=1).max(initial=0)))
 
     @staticmethod
     def _build_subtrees(ens: NodeListEnsemble, widest: int,

@@ -51,6 +51,15 @@ GEMM strategy, OSDI 2020). Per tree, with W lanes of nodes and of leaves:
                                             n's right / left subtree / not
     score[r] += sum_l where(m[r, l] == len[l], leaf_value[l], 0)
 
+A CATEGORY-SET node (LightGBM's categorical split: the row goes LEFT iff its
+bin is in the node's set; models/tree.CompiledNodeList, CATEGORY SETS) is a
+column of `sel` like any: the rows are widened by the ONE-HOT of every
+category column's bin, K rows a column, the node's column of `sel` is
+multi-hot over its set's K rows, so v[r, n] = 1 where the bin is in the set
+and 0 elsewhere, thr[n] = 0, and row n of P is negated (+1: in the set,
+left). Stage `predict:catset` where XLA makes the one-hot (the jax.numpy
+form); the kernel makes it in VMEM.
+
 `m[r, l] == len[l]` (the nodes on leaf l's path) for the ONE leaf whose
 every path node sends the row its way. Bins, +-1 and P are exact in
 bfloat16 and every sum an integer below 2^8: exact with float32
@@ -291,7 +300,9 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
                        optional_operands: int = 2,
                        path_lanes: int = 0,
                        oblivious_depth: int = 0,
-                       path_exit_lanes: int = 0) -> bool:
+                       path_exit_lanes: int = 0,
+                       path_cat_blocks: int = 0,
+                       path_select_rows: int = 0) -> bool:
     """The ONE home of the pallas-vs-one-hot predict dispatch rule.
 
     None = auto: the Pallas traversal kernel is taken when the data is
@@ -305,7 +316,10 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
     mean nothing there), whose kernel is ops/predict_paths.py and whose
     guard is that kernel's own, `path_exit_lanes` the width of the exits'
     table of a node list in the SUB-TREE form (its lanes a sub-tree's, and
-    `n_classes` the columns a leaf holds); `oblivious_depth` an oblivious ensemble
+    `n_classes` the columns a leaf holds), `path_cat_blocks` the one-hot
+    K-blocks of a node list with CATEGORY SETS and `path_select_rows` all
+    the K rows of its select (read with such blocks alone);
+    `oblivious_depth` an oblivious ensemble
     of that depth (ops/predict_oblivious.py, `predict_oblivious_fits`).
     Explicit True
     demands the kernel (binned data required — raises otherwise; off-TPU
@@ -327,7 +341,9 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
             fits = predict_paths.predict_paths_fits(
                 path_lanes, n_features,
                 chain=predict_paths.chain_of(1, n_classes, path_exit_lanes)
-                if path_exit_lanes else None)
+                if path_exit_lanes else None,
+                cat=predict_paths.CatSets(path_cat_blocks, path_select_rows)
+                if path_cat_blocks else None)
         else:
             fits = predict_pallas.predict_pallas_fits(
                 max_depth, n_features, n_classes,
@@ -579,19 +595,22 @@ _PATHS_TREE_CHUNK, _PATHS_ROW_CHUNK = 8, 8_192
 
 def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
                    missing_routes: bool = False, leaves=None, chain=None,
-                   mean: bool = False):
+                   mean: bool = False, cat=None):
     """The path-matrix form (module docstring) in plain jax.numpy: trees in
     chunks of _PATHS_TREE_CHUNK, rows in chunks of _PATHS_ROW_CHUNK, so the
     [trees, rows, W] intermediates stay bounded. The operands are
     widened to float32 (XLA's CPU backend has no bf16 x bf16 = f32 dot);
     every value is one bfloat16 holds, so a TPU's default one-pass matmul
     of them is exact too. `missing_routes`: planes' row 3 is read.
-    `leaves` and `chain`: the SUB-TREE form, `_predict_chain`."""
+    `leaves` and `chain`: the SUB-TREE form, `_predict_chain`. `cat`:
+    (cat_expand, cat_bins), the model carries CATEGORY SETS: `sel`'s K rows
+    past the ordinal ones are the one-hot's, made here a chunk of rows."""
     if chain is not None:
         return _predict_chain(sel, planes, paths, leaves, Xc, chain=chain,
                               learning_rate=learning_rate, base=base,
                               missing_routes=missing_routes, mean=mean)
-    T, Fp, W = sel.shape
+    T, Fk, W = sel.shape
+    Fp = cat[0].shape[1] if cat else Fk
     R, F = Xc.shape
     tree_chunk = _PATHS_TREE_CHUNK
     n_tc = -(-T // tree_chunk)
@@ -599,7 +618,7 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
     t_fill = ((0, n_tc * tree_chunk - T), (0, 0), (0, 0))
     with traced_scope("predict:tables"):
         selp = jnp.pad(sel.astype(jnp.float32), t_fill).reshape(
-            n_tc, tree_chunk, Fp, W)
+            n_tc, tree_chunk, Fk, W)
         planesp = jnp.pad(planes, t_fill, constant_values=-1.0).reshape(
             n_tc, tree_chunk, 8, W)
         pathsp = jnp.pad(paths.astype(jnp.float32), t_fill).reshape(
@@ -612,6 +631,16 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
                      ).reshape(n_rc, row_chunk, Fp)
 
     def row_body(_, xrc):
+        if cat:
+            with traced_scope("predict:catset"):
+                expand, bins = cat
+                hot = [jnp.where(jnp.dot(xrc, expand[b].astype(jnp.float32))
+                                 == bins[b, 0:1, :], 1.0, 0.0)
+                       for b in range(expand.shape[0])]
+                # (no ordinal K rows where every node asks a set)
+                xrc = jnp.concatenate(
+                    [xrc] * (Fk > 128 * len(hot)) + hot, axis=1)
+
         def tree_body(acc, args):
             a, pl_, p = args
             with traced_scope("predict:traverse"):
@@ -715,7 +744,7 @@ def _predict_chain(sel, planes, paths, leaves, Xc, *, chain, learning_rate,
     static_argnames=("learning_rate", "base", "use_pallas",
                      "missing_routes", "n_trees", "leaf_columns", "mean",
                      "select_spans", "link"),
-)
+)  # (cat_expand / cat_bins: arrays, their shapes say the rest)
 @op_scope("predict")
 def predict_raw_effective_paths(
     sel: jax.Array,            # bf16 [T, Fp, W] feature one-hot of the nodes
@@ -734,6 +763,8 @@ def predict_raw_effective_paths(
     mean: bool = False,
     select_spans: tuple = (),
     link: str = "none",
+    cat_expand: jax.Array | None = None,   # bf16 [B, Fp, 128]: category
+    cat_bins: jax.Array | None = None,     # f32 [B, 8, 128]     sets
 ) -> jax.Array:
     """Raw margins [R] of a node-list ensemble from its compiled tables
     (models/tree.CompiledNodeList): the path-matrix form, by the Pallas
@@ -751,7 +782,9 @@ def predict_raw_effective_paths(
     probabilities, taken here on the device (stage `predict:link`);
     `select_spans` (`CompiledNodeList.select_spans`) the K-blocks of the
     select each lane tile of a sub-tree reads, which the kernel alone asks
-    for."""
+    for. `cat_expand` and `cat_bins`: the model carries CATEGORY SETS
+    (`CompiledNodeList`: the uncut form alone), `sel` the one-hot's K rows
+    behind the ordinal ones."""
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
         raise ValueError("the path-matrix form scores binned (integer) rows")
     from ddt_tpu.ops import predict_paths
@@ -772,9 +805,16 @@ def predict_raw_effective_paths(
     form = dict(learning_rate=learning_rate, base=base,
                 missing_routes=missing_routes, leaves=leaves, chain=chain,
                 mean=mean)
+    if cat_expand is not None:
+        if chain is not None:
+            raise ValueError("category sets in the sub-tree form")
+        form["cat"] = (cat_expand, cat_bins)
     if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], leaf_columns,
                           path_lanes=planes.shape[2],
-                          path_exit_lanes=exit_lanes):
+                          path_exit_lanes=exit_lanes,
+                          path_cat_blocks=0 if cat_expand is None
+                          else cat_expand.shape[0],
+                          path_select_rows=sel.shape[1]):
         out = predict_paths.predict_paths_pallas(sel, planes, paths, Xc,
                                                  **form)
     else:

@@ -192,6 +192,24 @@ accumulate`). Nothing of it is traced for a model whose trees one path
 matrix holds with one output column: that program is instruction for
 instruction what it was.
 
+CATEGORY SETS (`CatSets`; LightGBM's categorical splits, models/tree.
+CompiledNodeList has the tables and ops/predict.py the equations): a set
+node goes LEFT iff the row's bin is in its set. The test is a column of the
+select like any. Once a sub-tile, for the block's trees, the kernel makes
+the ONE-HOT of every category column's bin, B K-blocks of 128 rows
+(`cat_expand` says which column a K row reads: xe = x @ cat_expand[b] is
+that column's bin in lane j, one small matmul a K-block and sub-tile, shared
+by the block's G trees; `cat_bins` the bin the row stands for: hot = (xe ==
+bin), one compare), and a tree's v is summed over the ordinal K-blocks (none
+where every node asks a set: `sel` then has no such rows) and the B one-hot
+blocks, against a `sel` whose set nodes' columns are MULTI-hot over their
+sets' K rows: v = 1 in the set, 0 out of it, threshold 0, the node's row of P
+negated. Compare, resolve, accumulate and fold are untouched. One node a
+result lane (`select_nodes_per_lane` 1 whatever F): a tree of 256 lanes over
+five one-hot blocks asks 5 x 2 + 4 = 14 weight tiles where an ordinal model
+of its shape asks 5 (`catset_mxu_tiles_per_tree` 9). A model without a set
+traces the program it did, instruction for instruction.
+
 Learned NaN directions (`missing_routes`; models/tree.CompiledNodeList):
 the NaN bin is the top bin, above every threshold, so `v > thr` alone is
 the default-RIGHT route. A node that sends NaN LEFT stops answering right
@@ -321,11 +339,29 @@ def _mantissa_rows(n_features: int) -> int:
     return 8 if 2 * _copy_stride(n_features) + 8 <= _MXU_ROWS else 0
 
 
-def _select_shape(lanes: int, n_features: int, nodes_per_lane: int) -> tuple:
+class CatSets(typing.NamedTuple):
+    """The shape of a model's CATEGORY SETS (module docstring), as
+    `path_plan` takes it: read from the tables (models/tree.
+    CompiledNodeList.cat_expand and sel)."""
+
+    blocks: int                # B: one-hot K-blocks of 128 rows
+    select_rows: int           # K rows of `sel`: the ordinal ones (Fp, or
+    #   0 where every node asks a set) and 128 B
+
+    @property
+    def ordinal_rows(self) -> int:
+        return self.select_rows - _LANES * self.blocks
+
+
+def _select_shape(lanes: int, n_features: int, nodes_per_lane: int,
+                  cat: CatSets | None = None) -> tuple:
     """(K rows, lanes) of a tree's select table as the kernel takes it:
     [Fp, W], or packed (`pack_select`) [K2, Wp]: two copies of the features
     and, where they leave the tile 8 rows, the mantissa's, up to whole bf16
-    sublane tiles, over the lanes of the first copy's nodes."""
+    sublane tiles, over the lanes of the first copy's nodes; with CATEGORY
+    SETS the table's own K rows."""
+    if cat:
+        return cat.select_rows, lanes
     if nodes_per_lane == 1:
         return -(-n_features // 16) * 16, lanes
     k2 = 2 * _copy_stride(n_features) + _mantissa_rows(n_features)
@@ -334,17 +370,29 @@ def _select_shape(lanes: int, n_features: int, nodes_per_lane: int) -> tuple:
 
 def select_mxu_tiles(lanes: int, n_features: int,
                      nodes_per_lane: int | None = None,
-                     select_spans: tuple = ()) -> int:
+                     select_spans: tuple = (),
+                     cat: CatSets | None = None) -> int:
     """MXU weight tiles of a tree's (a sub-tree's) feature select: ceil(F /
     128) K-blocks x ceil(W / nodes a lane / 128) lane tiles, or under
     `select_spans` (`Chain`) the K-blocks of each lane tile's own span.
-    `nodes_per_lane` None: the kernel's own (`select_nodes_per_lane`)."""
+    `nodes_per_lane` None: the kernel's own (`select_nodes_per_lane`).
+    `cat`: the K-blocks the kernel asks of a model with CATEGORY SETS, the
+    ordinal ones (if any node is ordinal) and the one-hot's, one node a
+    lane."""
+    if cat:
+        return _cat_k_blocks(n_features, cat) * (lanes // _LANES)
     if nodes_per_lane is None:
         nodes_per_lane = select_nodes_per_lane(n_features, lanes)
     if select_spans and nodes_per_lane == 1:
         return sum(stop - start for start, stop in select_spans)
     return select_k_blocks(n_features) * -(-(lanes // _LANES)
                                            // nodes_per_lane)
+
+
+def _cat_k_blocks(n_features: int, cat: CatSets) -> int:
+    """K-blocks the select of a model with CATEGORY SETS is summed over."""
+    return cat.blocks + (select_k_blocks(n_features)
+                         if cat.ordinal_rows else 0)
 
 
 def resolve_mxu_tiles(lanes: int, halved: bool = False) -> int:
@@ -358,12 +406,14 @@ def path_mxu_tiles_per_tree(lanes: int, n_features: int,
                             nodes_per_lane: int | None = None,
                             exit_lanes: int = 0,
                             select_spans: tuple = (),
-                            halved: bool = False) -> int:
+                            halved: bool = False,
+                            cat: CatSets | None = None) -> int:
     """MXU weight tiles (results [rows, 128]) a tree costs a tile of rows:
     the feature select's (`select_mxu_tiles`) and the path resolve's
     (`resolve_mxu_tiles`); of a SUB-TREE besides the exits' table's, W/128 x
     `exit_lanes`/128 (the class dot and the chain)."""
-    return (select_mxu_tiles(lanes, n_features, nodes_per_lane, select_spans)
+    return (select_mxu_tiles(lanes, n_features, nodes_per_lane, select_spans,
+                             cat)
             + resolve_mxu_tiles(lanes, halved)
             + (lanes // _LANES) * (exit_lanes // _LANES))
 
@@ -375,10 +425,11 @@ def _resolve_rows(lanes: int, halved: bool) -> int:
 
 
 def _tree_bytes(lanes: int, n_features: int, nodes_per_lane: int = 1,
-                exit_lanes: int = 0, halved: bool = False) -> int:
+                exit_lanes: int = 0, halved: bool = False,
+                cat: CatSets | None = None) -> int:
     """HBM bytes of one tree's (one sub-tree's) tables: sel bf16, planes
     f32, P bf16, the exits' bf16."""
-    k, w = _select_shape(lanes, n_features, nodes_per_lane)
+    k, w = _select_shape(lanes, n_features, nodes_per_lane, cat)
     return (k * w * 2 + 8 * lanes * 4
             + _resolve_rows(lanes, halved) * lanes * 2
             + lanes * exit_lanes * 2)
@@ -493,6 +544,12 @@ class PathPlan(typing.NamedTuple):
     glue_copies_per_subtree: float = 0.0    # ... and the lanes that hold a
     #   copy of the pieces' common ancestors (models/tree.cut_subtrees;
     #   the backend fills both)
+    # CATEGORY SETS (module docstring; LightGBM's categorical splits)
+    category_sets: int = 0              # 1: some node asks a set
+    category_nodes: int = 0             # the nodes that do, and the bins of
+    category_set_bits_max: int = 0      #   the widest set (the backend's)
+    catset_mxu_tiles_per_tree: int = 0  # of path_mxu_tiles_per_tree: what
+    #   the set test adds beside an ordinal model of the same F and W
 
     @property
     def blocks(self) -> int:
@@ -514,7 +571,7 @@ class PathPlan(typing.NamedTuple):
                 "path_mxu_tiles_per_tree": self.path_mxu_tiles_per_tree,
                 "select_k_blocks": self.select_k_blocks,
                 "select_nodes_per_lane": self.select_nodes_per_lane,
-                **{k: getattr(self, k) for k in CHAIN_COUNTS}}
+                **{k: getattr(self, k) for k in CHAIN_COUNTS + CAT_COUNTS}}
 
 
 # What the `ddt:predict:ensemble` span says of a node-list model's plan, in
@@ -526,11 +583,13 @@ CHAIN_COUNTS = ("subtrees_per_tree", "subtrees_per_tree_max",
                 "select_mxu_tiles", "exit_mxu_tiles", "resolve_mxu_tiles",
                 "spine_copies_per_subtree", "pieces_per_subtree",
                 "glue_copies_per_subtree")
+CAT_COUNTS = ("category_sets", "category_nodes", "category_set_bits_max",
+              "catset_mxu_tiles_per_tree")
 SPAN_COUNTS = ("node_list", "nodes_per_tree", "leaves_per_tree",
                "deepest_leaf", "path_mxu_tiles_per_tree", "trees_per_step",
                "table_blocks", "table_bytes", "select_k_blocks",
                "missing_routes", "row_operand_bytes",
-               "select_nodes_per_lane") + CHAIN_COUNTS
+               "select_nodes_per_lane") + CAT_COUNTS + CHAIN_COUNTS
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 
 
@@ -542,7 +601,8 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
               deepest_leaf: int = 0, served: bool = True,
               missing_routes: bool = False, row_dtype=jnp.uint8,
               chain: Chain | None = None,
-              widest_tree: int = 0) -> PathPlan:
+              widest_tree: int = 0,
+              cat: CatSets | None = None) -> PathPlan:
     """The kernel's table blocks at this shape: G trees a block, the most
     whose double-buffered windows fit _VMEM_BUDGET_BYTES beside what the
     kernel holds whatever G: the row tile's two windows at the rows' own
@@ -557,22 +617,31 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     have the exits' table among them, and beside which the kernel holds the
     [TILE_ROWS, CL] output's windows and the [TILE_ROWS, A] activity;
     `widest_tree` the lanes the widest tree would take uncut (what the
-    spans call `nodes_per_tree`)."""
+    spans call `nodes_per_tree`). `cat`: the model carries CATEGORY SETS
+    (the uncut form alone): one node a lane, the select's K rows the
+    table's own, the one-hot's tables and copies beside the windows."""
     # The jax.numpy form takes the select as the model compiles it.
-    pack = select_nodes_per_lane(n_features, lanes) if served else 1
+    pack = select_nodes_per_lane(n_features, lanes) \
+        if served and not cat else 1
     exit_lanes = chain.exit_lanes if chain else 0
     spans = chain.select_spans if chain else ()
     halved = bool(chain and chain.halved)
     tiles = path_mxu_tiles_per_tree(lanes, n_features, pack, exit_lanes,
-                                    spans, halved)
+                                    spans, halved, cat)
     row_bytes = row_operand_dtype(row_dtype).itemsize
-    said = dict(select_k_blocks=select_k_blocks(n_features),
+    said = dict(select_k_blocks=_cat_k_blocks(n_features, cat) if cat
+                else select_k_blocks(n_features),
                 missing_routes=int(missing_routes),
                 row_operand_bytes=row_bytes, select_nodes_per_lane=pack,
                 subtree_lanes=lanes,
                 select_mxu_tiles=select_mxu_tiles(lanes, n_features, pack,
-                                                  spans),
+                                                  spans, cat),
                 resolve_mxu_tiles=resolve_mxu_tiles(lanes, halved))
+    if cat:
+        # beside the ordinal model of this shape, under the kernel's own
+        # rule for it (two nodes a lane where they fit)
+        said.update(category_sets=1, catset_mxu_tiles_per_tree=tiles
+                    - path_mxu_tiles_per_tree(lanes, n_features))
     widest_tree = widest_tree or lanes
     if chain:
         per = n_trees / chain.n_trees
@@ -587,7 +656,7 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     if not served:
         return PathPlan(1, widest_tree, widest_tree, deepest_leaf, tiles, 0,
                         0, 0, 0, **said)
-    fp, sel_lanes = _select_shape(lanes, n_features, pack)
+    fp, sel_lanes = _select_shape(lanes, n_features, pack, cat)
     per_tree = (_window_bytes(fp, sel_lanes) // 2      # bf16: half of f32
                 + _window_bytes(8, lanes)
                 + _window_bytes(_resolve_rows(lanes, halved), lanes) // 2
@@ -604,6 +673,12 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
              + out
              + _sub_rows(pack) * (_lane_pad(fp) * _SUB_ROW_BIN_BYTES
                            + lanes * _SUB_ROW_LANE_BYTES))
+    if cat:
+        # the two small tables' windows; the sub-tile's bins are widened by
+        # the columns (`fp` above counts the one-hot's K rows, as it should)
+        fixed += cat.blocks * (
+            _window_bytes(_select_shape(lanes, n_features, 1)[0],
+                          _LANES) // 2 + _window_bytes(8, _LANES))
     most = min(n_trees, _MAX_TREES_PER_STEP,
                max(0, (_VMEM_BUDGET_BYTES - fixed) // per_tree))
     if most == 0:
@@ -619,20 +694,21 @@ def path_plan(n_trees: int, lanes: int, n_features: int,
     return PathPlan(1, widest_tree, widest_tree, deepest_leaf, tiles, g,
                     blocks,
                     blocks * g * _tree_bytes(lanes, n_features, pack,
-                                             exit_lanes, halved),
+                                             exit_lanes, halved, cat),
                     TILE_ROWS, **said)
 
 
 def predict_paths_fits(lanes: int, n_features: int,
                        row_dtype=jnp.uint8,
-                       chain: Chain | None = None) -> bool:
+                       chain: Chain | None = None,
+                       cat: CatSets | None = None) -> bool:
     """Whether one tree's tables fit the kernel's VMEM budget beside a row
     tile: the guard behind use_pallas=None (ops/predict.resolve_use_pallas).
     The tree count is no term of it. `chain`: a model in the sub-tree
     form; one sub-tree's tables beside the output's windows and the
     activity."""
     return path_plan(1, lanes, n_features, row_dtype=row_dtype,
-                     chain=chain).trees_per_step > 0
+                     chain=chain, cat=cat).trees_per_step > 0
 
 
 def pack_select(sel, planes, n_features: int, xp=jnp) -> tuple:
@@ -677,7 +753,7 @@ def pack_select(sel, planes, n_features: int, xp=jnp) -> tuple:
 def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
                   n_trees: int, n_feat: int, missing_routes: bool,
                   class_lanes: int = 0, select_spans: tuple = (),
-                  at_hand: int = 0):
+                  at_hand: int = 0, cat_blocks: int = 0):
     """One row tile against one block of `n_trees` trees: the block's share
     of every row's margin. x_ref [TILE_ROWS, F] uint8 or int32, as HBM
     holds the rows (in the last tile, whatever lies past row R); sel
@@ -698,10 +774,20 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
     (`Chain.at_hand`). `select_spans`: the
     K-blocks each 128-lane tile of the select reads (`Chain`; the caller
     hands the packed select none); (): every block, one matmul a block over
-    all the lanes."""
-    out_ref = rest[class_lanes > 0]
+    all the lanes.
+
+    `cat_blocks` B > 0, CATEGORY SETS (module docstring; the uncut form
+    alone): `rest` is (cat_expand [B, Fp, 128] bf16, cat_bins [B, 8, 128]
+    f32, out); sel's K rows are the ordinal ones (Fp, or none) and then
+    the B one-hot blocks'."""
+    out_ref = rest[2 if cat_blocks else class_lanes > 0]
     tile_rows = x_ref.shape[0]
     fp, lanes = sel_ref.shape[1], planes_ref.shape[2]
+    # the K rows of `sel` that read the bins themselves; the rows are
+    # widened to whole bf16 sublane tiles of the columns either way
+    ordinal_rows = fp - _LANES * cat_blocks
+    if cat_blocks:
+        fp = rest[0].shape[1]
     wp = sel_ref.shape[2]
     packed = wp < lanes
     half = paths_ref.shape[1]
@@ -744,6 +830,28 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
                 xf = jnp.concatenate(
                     [xf, jnp.zeros((sub_rows, kp - k1), jnp.float32)], axis=1)
             xs.append(xf.astype(jnp.bfloat16))            # [S, <= 128]
+        # CATEGORY SETS: the one-hot of every category column's bin, a
+        # K-block of 128 rows each, once for the block's trees: the bin of
+        # the column a K row reads by one small matmul, one compare.
+        hot = []
+        expand_ref, bins_ref = rest[:2] if cat_blocks else (None, None)
+        for b in range(cat_blocks):
+            xe = None
+            for k0, xk in zip(k_starts, xs):
+                part = jax.lax.dot_general(
+                    xk, expand_ref[b, k0:k0 + xk.shape[1], :],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # [S, 128]
+                xe = part if xe is None else xe + part
+            hot.append(jnp.where(xe == bins_ref[b, 0:1, :], 1.0, 0.0
+                                 ).astype(jnp.bfloat16))
+        if cat_blocks:
+            # the select's K-blocks: (first K row of sel, left operand)
+            k_blocks = [(k0, xk) for k0, xk in zip(k_starts, xs)
+                        ] * (ordinal_rows > 0) + [
+                (ordinal_rows + b * _LANES, h) for b, h in enumerate(hot)]
+        else:
+            k_blocks = list(zip(k_starts, xs))
 
         def tree(g, acc):
             """`acc` plus tree g's leaf value; of a sub-tree (`acc` None)
@@ -756,7 +864,8 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
             vs = []
             for l0, l1, first, stop in groups:
                 v = None
-                for k0, xk in zip(k_starts[first:stop], xs[first:stop]):
+                for k0, xk in (k_blocks if cat_blocks
+                               else k_blocks[first:stop]):
                     part = jax.lax.dot_general(
                         xk, sel_ref[g, k0:k0 + xk.shape[1], l0:l1],
                         (((1,), (0,)), ((), ())),
@@ -879,6 +988,7 @@ def predict_paths_pallas(
     leaves: jax.Array | None = None,   # bf16 [S, W, E]: the sub-tree form
     chain: Chain | None = None,        # ... and its shape
     mean: bool = False,
+    cat: tuple | None = None,          # (cat_expand, cat_bins): CATEGORY SETS
 ) -> jax.Array:
     """Raw margins [R]: Pallas twin of ops/predict._predict_paths. Jit-safe.
     interpret=None auto-selects the Pallas interpreter off-TPU. Where the
@@ -890,12 +1000,15 @@ def predict_paths_pallas(
     by the trees, float32 [R, C] (`mean`: vector leaves), or of scalar
     leaves the margin [R] as ever, or (`chain.leaf_columns` C > 1 without
     `mean`: softmax's round-major trees, a tree's leaves in its class's
-    lanes alone) the margins [R, C]."""
+    lanes alone) the margins [R, C]. `cat`: the model carries CATEGORY
+    SETS (module docstring), `sel` as the model compiles it."""
     if interpret is None:
         interpret = device.platform() != "tpu"
     T, _, lanes = planes.shape
     R, F = Xc.shape
-    if select_nodes_per_lane(F, lanes) == 2 and sel.shape[2] == lanes:
+    sets = CatSets(cat[0].shape[0], sel.shape[1]) if cat else None
+    if not cat and select_nodes_per_lane(F, lanes) == 2 \
+            and sel.shape[2] == lanes:
         with traced_scope("predict:tables"):
             sel, planes = pack_select(sel, planes, F)
     fp, sel_lanes = sel.shape[1:]
@@ -904,8 +1017,8 @@ def predict_paths_pallas(
     row_dtype = row_operand_dtype(Xc.dtype)
     with traced_scope("predict:widen"):
         rows = Xc if Xc.dtype == row_dtype else Xc.astype(row_dtype)
-    plan = path_plan(T, lanes, F, row_dtype=row_dtype, chain=chain)
-    if not predict_paths_fits(lanes, F, row_dtype, chain):
+    plan = path_plan(T, lanes, F, row_dtype=row_dtype, chain=chain, cat=sets)
+    if not predict_paths_fits(lanes, F, row_dtype, chain, sets):
         if not interpret:
             raise ValueError(
                 f"path-matrix shape ({lanes} lanes a tree, F={F}) exceeds "
@@ -922,13 +1035,17 @@ def predict_paths_pallas(
         sel_b, paths_b = jnp.pad(sel, t_fill), jnp.pad(paths, t_fill)
         planes_b = jnp.pad(planes, t_fill, constant_values=-1.0)
         tables = (sel_b, planes_b, paths_b) + (
-            (jnp.pad(leaves, t_fill),) if chain else ())
+            (jnp.pad(leaves, t_fill),) if chain else ()) + (cat or ())
     sub_rows = _sub_rows(plan.select_nodes_per_lane)
     tile_rows = min(TILE_ROWS, -(-R // sub_rows) * sub_rows)
     n_tiles = -(-R // tile_rows)
 
     def table_block(rows, cols):
         return pl.BlockSpec((g, rows, cols), lambda i, b: (b, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    def whole(a):       # a small table of the model: fetched once, stays
+        return pl.BlockSpec(a.shape, lambda i, b: (0, 0, 0),
                             memory_space=pltpu.VMEM)
 
     exit_lanes = chain.exit_lanes if chain else 0
@@ -963,7 +1080,8 @@ def predict_paths_pallas(
                               missing_routes=missing_routes,
                               class_lanes=chain.class_lanes if chain else 0,
                               select_spans=spans,
-                              at_hand=chain.at_hand if chain else 0),
+                              at_hand=chain.at_hand if chain else 0,
+                              **({"cat_blocks": sets.blocks} if cat else {})),
             # The grid walks the UNPADDED rows: the last tile's blocks are
             # ragged, as in the heap kernel.
             grid=(n_tiles, n_blocks),
@@ -971,7 +1089,8 @@ def predict_paths_pallas(
                                    memory_space=pltpu.VMEM),
                       table_block(fp, sel_lanes), table_block(8, lanes),
                       table_block(resolve_rows, lanes)]
-            + [table_block(lanes, exit_lanes)] * bool(chain),
+            + [table_block(lanes, exit_lanes)] * bool(chain)
+            + [whole(a) for a in cat or ()],
             out_specs=pl.BlockSpec(out_block, out_index,
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),

@@ -171,7 +171,10 @@ def predict_raw(ens, Xb: np.ndarray, dtype=np.float32) -> np.ndarray:
 
 def leaf_of_rows_node_list(ens, t: int, Xb: np.ndarray) -> np.ndarray:
     """Index of the leaf each row of `Xb` ends in, in tree `t` of a
-    node-list ensemble: the walk, a level of every row's at a time."""
+    node-list ensemble: the walk, a level of every row's at a time. At a
+    CATEGORY-SET node (`cat_index[t, n]` = s >= 0) a row goes left iff bit
+    `bin` of `cat_bin_sets[s]` (256 bits, uint32 [8]) is set; the NaN
+    directions of ordinal nodes mean nothing there."""
     rows = np.arange(Xb.shape[0])
     if ens.n_leaves[t] == 1:
         return np.zeros(len(rows), np.int64)
@@ -184,6 +187,12 @@ def leaf_of_rows_node_list(ens, t: int, Xb: np.ndarray) -> np.ndarray:
         if use_missing:
             left = np.where(b == ens.n_bins - 1, ens.default_left[t][n],
                             left)
+        if getattr(ens, "cat_index", None) is not None:
+            s = ens.cat_index[t][n]
+            word = ens.cat_bin_sets[np.maximum(s, 0), np.minimum(b >> 5, 7)]
+            in_set = (b < 256) & ((word >> (b & 31).astype(np.uint32)) & 1
+                                  ).astype(bool)
+            left = np.where(s >= 0, in_set, left)
         nxt = np.where(left, ens.left_child[t][n], ens.right_child[t][n])
         cur = np.where(cur >= 0, nxt, cur)
     return ~cur

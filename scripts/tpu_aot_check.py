@@ -60,6 +60,18 @@ CRITEO = dict(rows=2_000_000, features=39)
 # LightGBM's Bosch model's scoring chunk (benchmark config
 # bosch-lgbm-500t-255l): 968 columns, TPUDevice.predict_chunk_rows of them.
 BOSCH = dict(chunk_rows=262_144, features=968)
+# LightGBM's Allstate claims model (benchmark config
+# allstate-lgbm-500t-255l-cat): 32 columns, 16 of them categorical, with the
+# ids their sets may name (the two vehicle-model columns at the 254 that
+# max_bin=255 keeps); the 16 others ordinal.
+ALLSTATE = dict(features=32, categories=(
+    (3, 75), (4, 254), (5, 254), (6, 10), (7, 3), (8, 6), (9, 3), (10, 3),
+    (11, 6), (12, 4), (13, 4), (14, 2), (15, 3), (16, 11), (17, 11),
+    (27, 15)))
+# Eight columns ALL categorical (LightGBM's Expo model's shape): a model
+# whose every node asks a set has no ordinal K row.
+ALL_SETS = dict(features=8, categories=(
+    (0, 12), (1, 31), (2, 7), (3, 24), (4, 26), (5, 254), (6, 254), (7, 10)))
 # scikit-learn's MNIST forest's scoring chunk (benchmark config
 # mnist-rf-100t-full): 784 pixel columns, TPUDevice.predict_chunk_rows.
 FOREST = dict(chunk_rows=299_593, features=784)
@@ -183,42 +195,52 @@ def _predict_case(rows, features, n_trees, depth, n_classes=1,
     return build
 
 
-def _random_node_list(n_trees, n_leaves, features, missing=False):
+def _random_node_list(n_trees, n_leaves, features, missing=False,
+                      categories=()):
     """A random leaf-wise ensemble (seeded) as a models/tree
-    NodeListEnsemble; `missing`: with learned NaN directions."""
+    NodeListEnsemble; `missing`: with learned NaN directions;
+    `categories`: (column, cardinality) pairs that ask category sets."""
     import numpy as np
 
     from ddt_tpu.models.tree import random_node_list
 
     return random_node_list(np.random.default_rng(7), n_trees, n_leaves,
                             features, learning_rate=0.1, base_score=0.0,
-                            loss="logloss", missing=missing)
+                            loss="logloss", missing=missing,
+                            **({"categories": categories} if categories
+                               else {}))
 
 
-def _paths_case(rows, features, n_trees, n_leaves, missing=False):
+def _paths_case(rows, features, n_trees, n_leaves, missing=False,
+                categories=()):
     """The path-matrix kernel (ops/predict_paths.py) over a node list's
     compiled tables as a backend hands them over (the select packed on the
     host where it answers two nodes a lane: up to 64 columns, 256 lanes
-    and more), the rows as api.predict does (uint8)."""
+    and more), the rows as api.predict does (uint8). `categories`:
+    (column, cardinality) pairs whose nodes ask CATEGORY SETS (the one-hot
+    K-blocks; one node a lane, the select as the model compiles it)."""
     def build():
         import jax.numpy as jnp
         import numpy as np
 
         from ddt_tpu.ops import predict_paths
 
-        ce = _random_node_list(n_trees, n_leaves, features, missing).compile()
+        ce = _random_node_list(n_trees, n_leaves, features, missing,
+                               categories).compile()
         tables = ce.arrays()
         # (`--tree` may name a checkout from before the packed select)
         per_lane = getattr(predict_paths, "select_nodes_per_lane", None)
-        if per_lane and per_lane(features, ce.lanes) == 2:
+        if not categories and per_lane and per_lane(features, ce.lanes) == 2:
             tables = (*predict_paths.pack_select(ce.sel, ce.planes, features,
                                                  xp=np), ce.paths)
 
-        def fn(sel, planes, paths, Xc):
+        def fn(sel, planes, paths, *rest):
+            *cat, Xc = rest
             return predict_paths.predict_paths_pallas(
                 sel, planes, paths, Xc,
                 learning_rate=ce.learning_rate, base=ce.base_score,
-                missing_routes=missing, interpret=False)
+                missing_routes=missing, interpret=False,
+                **({"cat": tuple(cat)} if cat else {}))
 
         shapes = [(a.shape, a.dtype) for a in tables]
         shapes.append(((rows, features), jnp.uint8))
@@ -460,6 +482,22 @@ def kernel_cases() -> list:
         KernelCase("paths/bosch/968f/20x255leaves/nan", True,
                    _paths_case(BOSCH["chunk_rows"], BOSCH["features"], 20,
                                255, missing=True)),
+        # CATEGORY SETS (LightGBM's categorical splits): the Allstate
+        # cell's own shape, sets and ordinal nodes in one tree (six one-hot
+        # K-blocks beside the ordinal one, 18 weight tiles a tree), eight
+        # columns ALL categorical (five one-hot K-blocks, no ordinal K row,
+        # 14 tiles), and sets beside ordinal nodes WITH NaN directions at
+        # Bosch's width (the eight ordinal K-blocks and the sets' own).
+        KernelCase("paths-cat/32f/500x255", True,
+                   _paths_case(2_000_000, ALLSTATE["features"], 500, 255,
+                               categories=ALLSTATE["categories"])),
+        KernelCase("paths-cat/8f/500x255", True,
+                   _paths_case(2_000_000, ALL_SETS["features"], 500, 255,
+                               categories=ALL_SETS["categories"])),
+        KernelCase("paths-cat/968f/20x255leaves/nan", True,
+                   _paths_case(BOSCH["chunk_rows"], BOSCH["features"], 20,
+                               255, missing=True,
+                               categories=((3, 40), (500, 200), (967, 7)))),
         # The SUB-TREE form (the chain and the class dot): the MNIST
         # forest's chunk (100 full-depth trees of up to 4,779 leaves: 1,695
         # sub-trees of 256 lanes since PR 53 packs their entries, 2,112

@@ -973,9 +973,16 @@ def test_what_stays_refused_is_refused_by_name():
         NodeListEnsemble(**{**parts, "loss": "softmax"})
     with pytest.raises(ValueError, match="vector leaves"):
         ens.to_lightgbm_text()
-    tree._refuse_routes("from_heap", categories=False)
-    with pytest.raises(ValueError, match="category-set"):
-        tree._refuse_routes("from_heap", categories=True)
+    # what the sub-tree form still refuses, by name: category sets (PR 55
+    # serves them in trees one path matrix holds)
+    tree._refuse_routes("from_heap")
+    with pytest.raises(ValueError, match="SUB-TREE form"):
+        tree._refuse_routes("build", chained_sets=True)
+    cats = tree.random_node_list(
+        np.random.default_rng(3), 2, (600, 700), 6, categories=((1, 9),),
+        learning_rate=0.1, base_score=0.0, loss="logloss")
+    with pytest.raises(ValueError, match="category-set nodes in the SUB-TREE"):
+        cats.compile()
     with pytest.raises(ValueError, match="binned"):
         api.predict(ens, rows_of(72, 8).astype(np.float32), cfg=TrainConfig(
             backend="tpu", n_bins=BINS))

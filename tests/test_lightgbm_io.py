@@ -433,3 +433,224 @@ def test_threshold_mapper_keeps_the_top_bin_for_nan():
     Xs = np.array([[np.nan, 0.0], [2.5, 0.0]], np.float32)
     np.testing.assert_array_equal(
         heap.predict_raw(hm.transform(Xs), binned=True), heap.predict_raw(Xs))
+
+
+# ------------------------------------------------------------------ #
+# category sets in a node list (PR 55)
+# ------------------------------------------------------------------ #
+
+def _set_model_text(seed: int, missing_type: int) -> str:
+    """A small LightGBM text: 3 trees of 6 leaves, multi-bit category sets
+    on columns 1 and 3 (ids up to 70: three words), numerical splits on
+    the others; a set node's missing type as given (2 NaN, 0 None)."""
+    rng = np.random.default_rng(seed)
+    lines = ["tree", "version=v3", "num_class=1",
+             "num_tree_per_iteration=1", "label_index=0",
+             "max_feature_idx=3", "objective=regression",
+             "feature_names=a b c d", "feature_infos=none none none none",
+             ""]
+    for t in range(3):
+        feat = [1, 0, 3, 2, 1]
+        left, right = [1, 2, -1, -2, -3], [3, 4, -4, -5, -6]
+        # a balanced-ish tree: node 0 -> (1, 3), 1 -> (2, 4), leaves below
+        left, right = [1, 2, ~0, ~1, ~2], [3, 4, ~3, ~4, ~5]
+        thr, dec, bounds, words = [], [], [0], []
+        for n, f in enumerate(feat):
+            if f in (1, 3):
+                ids = sorted(rng.choice(71, int(rng.integers(2, 6)),
+                                        replace=False).tolist())
+                run = [0] * (max(ids) // 32 + 1)
+                for i in ids:
+                    run[i >> 5] |= 1 << (i & 31)
+                thr.append(float(len(bounds) - 1))
+                words += run
+                bounds.append(len(words))
+                dec.append(1 | (missing_type << 2))
+            else:
+                thr.append(float(rng.integers(0, 50)) + 0.5)
+                dec.append(missing_type << 2)
+        vals = (rng.integers(-16, 17, 6) / 8.0).tolist()
+        lines += [f"Tree={t}", "num_leaves=6", f"num_cat={len(bounds) - 1}",
+                  "split_feature=" + " ".join(map(str, feat)),
+                  "split_gain=" + " ".join(["1"] * 5),
+                  "threshold=" + " ".join(f"{v:.17g}" for v in thr),
+                  "decision_type=" + " ".join(map(str, dec)),
+                  "left_child=" + " ".join(map(str, left)),
+                  "right_child=" + " ".join(map(str, right)),
+                  "leaf_value=" + " ".join(f"{v:.17g}" for v in vals),
+                  "cat_boundaries=" + " ".join(map(str, bounds)),
+                  "cat_threshold=" + " ".join(map(str, words)),
+                  "is_linear=0", "shrinkage=1", ""]
+    return "\n".join(lines + ["end of trees", ""])
+
+
+def _deepened(text: str, missing_type: int) -> str:
+    """The same model past `HEAP_MAX_DEPTH`: one more tree, a chain of 12
+    numerical nodes on column 0 whose 13 leaves are all 0.0, so the import
+    yields a NODE LIST and no score moves."""
+    n = 12
+    block = [
+        "Tree=3", f"num_leaves={n + 1}", "num_cat=0",
+        "split_feature=" + " ".join(["0"] * n),
+        "split_gain=" + " ".join(["1"] * n),
+        "threshold=" + " ".join(f"{i + 0.5}" for i in range(n)),
+        "decision_type=" + " ".join([str(missing_type << 2)] * n),
+        "left_child=" + " ".join(str(~i) for i in range(n)),
+        "right_child=" + " ".join(
+            [str(i + 1) for i in range(n - 1)] + [str(~n)]),
+        "leaf_value=" + " ".join(["0"] * (n + 1)),
+        "is_linear=0", "shrinkage=1", "", "end of trees", ""]
+    return text.replace("end of trees\n", "\n".join(block))
+
+
+def _set_rows(seed: int, with_nan: bool) -> np.ndarray:
+    """Raw rows with named, unseen, negative and past-the-bitset ids (and
+    NaN) in the category columns."""
+    rng = np.random.default_rng(seed)
+    X = rng.integers(0, 72, (400, 4)).astype(np.float32)
+    X[rng.random(400) < 0.1, 1] = -3.0
+    X[rng.random(400) < 0.1, 3] = 5000.0          # past every bitset
+    X[rng.random(400) < 0.1, 1] = 96.0            # inside the last word's
+    if with_nan:                                  # span, never named
+        X[rng.random(400) < 0.15, 3] = np.nan
+        X[rng.random(400) < 0.15, 0] = np.nan
+    return X
+
+
+@pytest.mark.parametrize("missing_type,with_nan", [(2, True), (2, False),
+                                                   (0, False)],
+                         ids=["nan-type", "nan-type-no-nan", "none-type"])
+def test_category_sets_tie_the_node_list_to_the_heap(missing_type, with_nan,
+                                                     tmp_path):
+    """ONE model, two layouts: the heap import expands a k-id set into a
+    chain of k one-vs-rest nodes; the same text with a 12-level tree of
+    zero leaves beside it (`_deepened`) is past the heap and imports as a
+    node list, which keeps the bitsets; both score alike, NaN, negative, unseen and past-the-bitset ids among the
+    rows. The round trip text -> node list -> text -> node list keeps every
+    bitset, and so does save / load; binned rows through the model's own
+    mapper score as raw ones."""
+    from ddt_tpu.models.lightgbm_io import (from_lightgbm_text,
+                                            threshold_bin_mapper,
+                                            to_lightgbm_text)
+    from ddt_tpu.models.tree import NodeListEnsemble, TreeEnsemble
+    from ddt_tpu.reference import numpy_predict
+
+    text = _set_model_text(91 + missing_type, missing_type)
+    heap = from_lightgbm_text(text)
+    assert isinstance(heap, TreeEnsemble) and heap.has_cat_splits
+    ens = from_lightgbm_text(_deepened(text, missing_type))
+    assert isinstance(ens, NodeListEnsemble) and ens.has_cat_splits
+    assert int(ens.cat_nodes.sum()) == 9
+    X = _set_rows(92, with_nan)
+    want = ens.predict_raw(X)
+    np.testing.assert_array_equal(heap.predict_raw(X), want)
+    # the round trip keeps every bitset, word for word
+    again = from_lightgbm_text(to_lightgbm_text(ens))
+    assert isinstance(again, NodeListEnsemble)
+    np.testing.assert_array_equal(again.cat_threshold, ens.cat_threshold)
+    np.testing.assert_array_equal(again.cat_boundaries, ens.cat_boundaries)
+    np.testing.assert_array_equal(again.cat_nan_as_zero, ens.cat_nan_as_zero)
+    np.testing.assert_array_equal(again.predict_raw(X), want)
+    # binned by the model's own mapper: the sets over bins
+    mapper = threshold_bin_mapper(ens, n_bins=255)
+    assert sorted(mapper.category_ids) == [1, 3]
+    Xb = mapper.transform(X)
+    np.testing.assert_array_equal(
+        numpy_predict.predict_raw_node_list(ens, Xb), want)
+    from ddt_tpu import api
+    from ddt_tpu.config import TrainConfig
+
+    for impl in ("onehot", "pallas"):
+        np.testing.assert_array_equal(api.predict(
+            ens, X, mapper=mapper, raw=True, cfg=TrainConfig(
+                backend="tpu", n_bins=255, predict_impl=impl)), want)
+    # save / load keeps the sets and the mapper's category tables
+    path = str(tmp_path / "sets.npz")
+    api.save_model(path, ens, mapper=mapper)
+    bundle = api.load_model(path)
+    np.testing.assert_array_equal(bundle.ensemble.cat_threshold,
+                                  ens.cat_threshold)
+    np.testing.assert_array_equal(bundle.ensemble.cat_bin_sets,
+                                  ens.cat_bin_sets)
+    np.testing.assert_array_equal(bundle.mapper.transform(X), Xb)
+    np.testing.assert_array_equal(api.predict(bundle, X, raw=True), want)
+
+
+def test_category_set_mapper_refuses_by_name():
+    from ddt_tpu.models.lightgbm_io import (from_lightgbm_text,
+                                            threshold_bin_mapper)
+
+    text = _deepened(_set_model_text(93, 2), 2)
+    both = text.replace("split_feature=1 0 3 2 1", "split_feature=1 0 3 1 1")
+    with pytest.raises(ValueError, match="by ordinal"):
+        threshold_bin_mapper(from_lightgbm_text(both))
+    mixed = text.replace("decision_type=9 8 9 8 9", "decision_type=9 8 9 8 1")
+    with pytest.raises(ValueError, match="disagree on the missing type"):
+        threshold_bin_mapper(from_lightgbm_text(mixed))
+    ens = from_lightgbm_text(text)
+    with pytest.raises(ValueError, match="name .* ids, more than"):
+        threshold_bin_mapper(ens, n_bins=8)
+
+
+def _wide_set_text(num_class: int) -> str:
+    """`num_class` trees of 3 leaves: a set of 14 ids at the root (a chain
+    of 14 one-vs-rest nodes in a heap: depth 15), a numerical node under
+    it."""
+    ids = [1, 2, 3, 5, 8, 13, 21, 34, 40, 41, 47, 55, 60, 63]
+    words = [sum(1 << (i & 31) for i in ids if i >> 5 == w) for w in (0, 1)]
+    multi = num_class > 1
+    lines = ["tree", "version=v3", f"num_class={num_class}",
+             f"num_tree_per_iteration={num_class}", "label_index=0",
+             "max_feature_idx=1",
+             "objective=" + (f"multiclass num_class:{num_class}" if multi
+                             else "regression"),
+             "feature_names=a b", "feature_infos=none none", ""]
+    for t in range(num_class):
+        lines += [f"Tree={t}", "num_leaves=3", "num_cat=1",
+                  "split_feature=0 1", "split_gain=1 1",
+                  "threshold=0 20.5", "decision_type=9 8",
+                  "left_child=1 -1", "right_child=-3 -2",
+                  f"leaf_value={t + 1} {t + 2.5} {-t - 0.25}",
+                  "cat_boundaries=0 2",
+                  "cat_threshold=" + " ".join(map(str, words)),
+                  "is_linear=0", "shrinkage=1", ""]
+    return "\n".join(lines + ["end of trees", ""])
+
+
+@pytest.mark.parametrize("num_class", [1, 3], ids=["one-column", "softmax"])
+def test_a_set_model_the_parent_held_as_a_deep_heap_is_a_node_list(num_class):
+    """PR 55 changed the layout of ONE class of model, pinned here: a text
+    with category sets whose chain expansion is 12 to 30 levels deep (here
+    15) imported as a HEAP before (up to 2^27 slots) and is a node list
+    with its bitsets now. One output column: the path kernel scores it.
+    Softmax's round-major trees: the sub-tree form has no set tables, so
+    the XLA backend refuses it BY NAME at the build and the host backend
+    walks it (ROADMAP M13(a), docs/API.md)."""
+    from ddt_tpu import api
+    from ddt_tpu.config import TrainConfig
+    from ddt_tpu.models.lightgbm_io import (from_lightgbm_text,
+                                            threshold_bin_mapper)
+    from ddt_tpu.models.tree import NodeListEnsemble
+
+    ens = from_lightgbm_text(_wide_set_text(num_class))
+    assert isinstance(ens, NodeListEnsemble) and ens.has_cat_splits
+    assert int(ens.cat_nodes.sum()) == num_class
+    X = _set_rows(95, True)[:, :2]
+    want = ens.predict_raw(X)
+    in_set = np.isin(X[:, 0], [1, 2, 3, 5, 8, 13, 21, 34, 40, 41, 47, 55,
+                               60, 63])
+    tree0 = np.where(in_set, np.where(X[:, 1] <= 20.5, 1.0, 2.5), -0.25)
+    np.testing.assert_array_equal(
+        want if num_class == 1 else want[:, 0], tree0.astype(np.float32))
+    mapper = threshold_bin_mapper(ens, n_bins=255)
+
+    def predict(backend):
+        return api.predict(ens, X, mapper=mapper, raw=True, cfg=TrainConfig(
+            backend=backend, n_bins=255))
+
+    np.testing.assert_array_equal(predict("cpu"), want)
+    if num_class == 1:
+        np.testing.assert_array_equal(predict("tpu"), want)
+    else:
+        with pytest.raises(ValueError, match="SUB-TREE form"):
+            predict("tpu")

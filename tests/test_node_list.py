@@ -295,10 +295,28 @@ def test_category_and_multiclass_node_lists_are_refused_by_name():
                           n_bins=255)
     heap.is_leaf[:, 0] = True
     assert NodeListEnsemble.from_heap(heap).n_trees == 2
-    heap = empty_ensemble(2, 2, 4, 0.1, 0.0, "logloss", cat_features=(1,))
-    heap.is_leaf[:, 0] = True
-    with pytest.raises(ValueError, match="category-set"):
-        NodeListEnsemble.from_heap(heap)
+    # a heap's one-vs-rest category node is a set of ONE bit (PR 55) ...
+    heap = empty_ensemble(2, 2, 4, 0.1, 0.0, "logloss", cat_features=(1,),
+                          n_bins=255)
+    heap.is_leaf[:, 1:3] = True
+    heap.feature[:, 0], heap.threshold_bin[:, 0] = 1, 7
+    heap.leaf_value[:, 1], heap.leaf_value[:, 2] = 1.0, -1.0
+    one_bit = NodeListEnsemble.from_heap(heap)
+    assert one_bit.has_cat_splits and one_bit.cat_nodes.sum() == 2
+    assert one_bit.cat_set_bits().sum(axis=1).tolist() == [1, 1]
+    Xc = np.zeros((3, 4), np.uint8)
+    Xc[:, 1] = (7, 8, 254)
+    np.testing.assert_array_equal(one_bit.predict_raw(Xc, binned=True),
+                                  heap.predict_raw(Xc, binned=True))
+    # ... and one that sends NaN LEFT is refused by its new name
+    routed_cat = empty_ensemble(2, 2, 4, 0.1, 0.0, "logloss",
+                                cat_features=(1,), missing_bin=True,
+                                n_bins=255)
+    routed_cat.is_leaf[:, 1:3] = True
+    routed_cat.feature[:, 0] = 1
+    routed_cat.default_left[:, 0] = True
+    with pytest.raises(ValueError, match="sends NaN LEFT"):
+        NodeListEnsemble.from_heap(routed_cat)
     heap = empty_ensemble(3, 2, 4, 0.1, 0.0, "softmax", n_classes=3)
     heap.is_leaf[:, 0] = True
     assert NodeListEnsemble.from_heap(heap).leaf_columns == 3
@@ -315,7 +333,9 @@ def test_category_and_multiclass_node_lists_are_refused_by_name():
     with pytest.raises(ValueError, match="learned NaN directions need"):
         dataclasses.replace(src, missing_bin=True, n_bins=255,
                             default_left=np.zeros((1, 3), bool))
-    # a LightGBM text too deep for any heap, with a category node: refused
+    # a LightGBM text too deep for any heap, with a category node: a node
+    # list with the set as it is (PR 55; refused before), scored as the
+    # reference walk does
     chain = 40
     nodes = [(0, 0, float(k), 0.0, (k + 1) if k < chain - 1 else ~0,
               ~(k + 1)) for k in range(chain)]
@@ -328,9 +348,20 @@ def test_category_and_multiclass_node_lists_are_refused_by_name():
         "decision_type=" + " ".join(["1"] + ["0"] * (chain - 1))).replace(
             "num_cat=0", "num_cat=1").replace(
                 "is_linear=0", "cat_boundaries=0 1\ncat_threshold=6\n"
-                "is_linear=0")
-    with pytest.raises(ValueError, match="category-set"):
-        TreeEnsemble.from_lightgbm_text(cat)
+                "is_linear=0").replace(
+                    "split_feature=" + " ".join(["0"] * chain),
+                    "split_feature=" + " ".join(["1"] + ["0"] * (chain - 1)))
+    kept = TreeEnsemble.from_lightgbm_text(cat)
+    assert isinstance(kept, NodeListEnsemble) and kept.cat_nodes.sum() == 1
+    assert lightgbm_io._set_ids(kept, 0).tolist() == [1, 2]     # word 6
+    mapper = lightgbm_io.threshold_bin_mapper(kept)
+    Xr = np.asarray([[0.0, 1.0], [5.0, 2.0], [7.5, 3.0], [3.0, np.nan],
+                     [50.0, -1.0], [0.0, 99.0], [39.0, 2.0]], np.float32)
+    want = numpy_predict.predict_raw_node_list(kept, mapper.transform(Xr))
+    np.testing.assert_array_equal(kept.predict_raw(Xr), want)
+    np.testing.assert_array_equal(api.predict(
+        kept, Xr, mapper=mapper, raw=True,
+        cfg=TrainConfig(backend="tpu", predict_impl="pallas")), want)
     # ... and the same text with NaN default directions is a node list now
     routed = TreeEnsemble.from_lightgbm_text(text.replace(
         "decision_type=" + " ".join(["0"] * chain),
@@ -593,3 +624,102 @@ def test_backend_entry_says_which_kernel_serves():
     assert counts["select_nodes_per_lane"] == 2
     assert counts["trees_per_step"] * counts["table_blocks"] >= 20
     assert counts["table_bytes"] >= 20 * 256 * 256 * 2
+
+
+# ------------------------------------------------------------------ #
+# category sets (PR 55): LightGBM's categorical splits in a node list
+# ------------------------------------------------------------------ #
+
+# 6 columns of 3 to 300 categories (a column of 300 ids names 255 at most)
+CAT_COLUMNS = ((0, 3), (1, 17), (2, 60), (3, 130), (4, 255), (5, 300))
+# the Allstate claims model's 32 columns (benchmark config
+# allstate-lgbm-500t-255l-cat): 16 categorical, with the ids a set may name
+ALLSTATE_COLUMNS = ((3, 75), (4, 254), (5, 254), (6, 10), (7, 3), (8, 6),
+                    (9, 3), (10, 3), (11, 6), (12, 4), (13, 4), (14, 2),
+                    (15, 3), (16, 11), (17, 11), (27, 15))
+
+
+def category_ensemble(seed, n_trees=12, n_leaves=63, columns=CAT_COLUMNS,
+                      n_features=6, missing=False, max_set=32):
+    """Seeded random leaf-wise trees whose nodes on `columns` ask category
+    sets of 1 to 32 of the column's bins (the others ordinal splits)."""
+    return random_node_list(
+        np.random.default_rng(seed), n_trees, n_leaves, n_features,
+        dyadic=True, missing=missing, max_set=max_set,
+        categories=tuple((c, min(k, 255)) for c, k in columns),
+        learning_rate=0.5, base_score=0.25, loss="logloss")
+
+
+def category_rows(seed, n, columns=CAT_COLUMNS, n_features=6):
+    """uint8 rows: a category column's bins 0 .. k + 1 (two no set names),
+    an ordinal column's any bin."""
+    rng = np.random.default_rng(seed)
+    Xb = rng.integers(0, 255, (n, n_features)).astype(np.uint8)
+    for c, k in columns:
+        Xb[:, c] = rng.integers(0, min(k, 254) + 2, n)
+    return Xb
+
+
+@pytest.mark.parametrize("columns,missing,n_leaves,n_features", [
+    (CAT_COLUMNS, False, 63, 6),            # every node a set
+    (CAT_COLUMNS[1:4], False, 63, 6),       # sets beside ordinal nodes
+    (CAT_COLUMNS[1:4], True, 63, 6),        # ... whose NaN has directions
+    (CAT_COLUMNS, False, (2, 40), 6),       # ragged trees, 128 lanes
+    (((2, 200),), True, 255, 6),            # 256 lanes, one wide column
+    (ALLSTATE_COLUMNS, False, 255, 32),     # the cell's shape: six one-hot
+    (ALLSTATE_COLUMNS, True, 255, 32),      # K-blocks beside the ordinal one
+], ids=["all-sets", "mixed", "mixed-nan", "ragged", "255-leaves",
+        "allstate", "allstate-nan"])
+def test_category_sets_score_as_the_reference_walks(columns, missing,
+                                                    n_leaves, n_features):
+    """The plain reference's walk of the sets, the host backend's, the
+    jax.numpy twin and the Pallas kernel (interpreted) agree bit for bit on
+    seeded random models (dyadic leaf values), through api.predict."""
+    ens = category_ensemble(54, n_leaves=n_leaves, columns=columns,
+                            missing=missing, n_features=n_features)
+    assert ens.has_cat_splits
+    Xb = category_rows(55, 700, columns, n_features)
+    want = numpy_predict.predict_raw_node_list(ens, Xb)
+    np.testing.assert_array_equal(ens.predict_raw(Xb, binned=True), want)
+    np.testing.assert_array_equal(
+        api.predict(ens, Xb, binned=True, raw=True,
+                    cfg=TrainConfig(backend="cpu")), want)
+    for impl in ("onehot", "pallas"):
+        got = api.predict(ens, Xb, binned=True, raw=True, cfg=TrainConfig(
+            backend="tpu", n_bins=255, predict_impl=impl))
+        np.testing.assert_array_equal(got, want, err_msg=impl)
+    # a model WITHOUT a set builds the tables it did: no fifth table
+    plain = leafwise_ensemble(54, 3, 63, 6).compile()
+    assert plain.cat_blocks == 0 and len(plain.arrays()) == 3
+    ce = ens.compile()
+    assert len(ce.arrays()) == 5
+    assert ce.sel.shape[1] == ce.ordinal_rows + 128 * ce.cat_blocks
+    assert ce.ordinal_rows == (0 if columns == CAT_COLUMNS
+                               else -(-n_features // 16) * 16)
+    if columns == ALLSTATE_COLUMNS:
+        # sets AND thresholds in every tree, and the cell's K-blocks
+        sets = ens.cat_nodes.sum(axis=1)
+        assert (sets > 0).all() and (sets < ens.n_leaves - 1).all()
+        assert ce.cat_blocks == 6 and ce.sel.shape[1] == 32 + 768
+
+
+def test_category_sets_cache_token_save_load_and_dump(tmp_path):
+    ens = category_ensemble(56, n_trees=3, n_leaves=15)
+    other = category_ensemble(56, n_trees=3, n_leaves=15)
+    assert ens.cache_token() == other.cache_token()
+    s = int(other.cat_index[other.cat_nodes][0])
+    other.cat_bin_sets[s, 0] ^= np.uint32(1)
+    assert ens.cache_token() != other.cache_token()
+    path = str(tmp_path / "cats.npz")
+    ens.save(path)
+    back = api.load_model(path).ensemble
+    assert isinstance(back, NodeListEnsemble) and back.has_cat_splits
+    for k, _ in NodeListEnsemble._CAT_ARRAYS:
+        np.testing.assert_array_equal(getattr(back, k), getattr(ens, k))
+    Xb = category_rows(57, 200)
+    np.testing.assert_array_equal(back.predict_raw(Xb, binned=True),
+                                  ens.predict_raw(Xb, binned=True))
+    assert " in bins {" in ens.dump_text(0)
+    # sets past what one path matrix holds are refused BY NAME
+    with pytest.raises(ValueError, match="SUB-TREE form"):
+        category_ensemble(58, n_trees=2, n_leaves=600).compile()

@@ -351,6 +351,9 @@ def test_a_node_list_says_which_form_serves(impl, monkeypatch):
         table_bytes=3 * (16 * 128 * 2 + 8 * 128 * 4 + 128 * 128 * 2) * served,
         select_k_blocks=1, missing_routes=0, row_operand_bytes=1,
         select_nodes_per_lane=1,           # 128 lanes: one tile already
+        # no node asks a category set (PR 55)
+        category_sets=0, category_nodes=0, category_set_bits_max=0,
+        catset_mxu_tiles_per_tree=0,
         # one uncut path matrix a tree: a sub-tree of its own, no chain,
         # and no link function taken by the program
         subtrees_per_tree=1.0, subtrees_per_tree_max=1,
@@ -439,6 +442,59 @@ def test_an_oblivious_model_says_which_form_serves(impl, monkeypatch):
         # one group: of a row tile's two resolves the first runs beside
         # the second sub-tile's select
         resolves_under_select=0.5 * served)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "onehot"])
+@pytest.mark.parametrize("categories,missing,said", [
+    # every node a set: no ordinal K row; 3 + 40 rows share ONE one-hot
+    # block: 1 select tile + 1 resolve, as an ordinal model of the shape
+    (((0, 3), (1, 40), (2, 5), (3, 7), (4, 9), (5, 11)), False,
+     dict(select_k_blocks=1, path_mxu_tiles_per_tree=2,
+          catset_mxu_tiles_per_tree=0, missing_routes=0)),
+    # sets of two wide columns (200 and 180 bins: a whole block each and
+    # their rests sharing a third) beside ordinal nodes WITH NaN
+    # directions: the ordinal K-block and the sets' three
+    (((2, 200), (4, 180)), True,
+     dict(select_k_blocks=4, path_mxu_tiles_per_tree=5,
+          catset_mxu_tiles_per_tree=3, missing_routes=1)),
+])
+def test_a_node_list_with_category_sets_says_so(impl, categories, missing,
+                                                said):
+    """Category sets on the spans (PR 55): `category_sets`, `category_nodes`
+    and `category_set_bits_max` on the `ensemble` span, with the K-blocks
+    and weight tiles the kernel REALLY asks (`select_k_blocks`,
+    `path_mxu_tiles_per_tree`) and what the set test adds beside an ordinal
+    model of the shape (`catset_mxu_tiles_per_tree`); the root of every
+    call repeats them."""
+    from ddt_tpu.models.tree import random_node_list
+
+    rng = np.random.default_rng(81)
+    ens = random_node_list(rng, 3, 9, 6, n_bins=255, missing=missing,
+                           dyadic=True, categories=categories, max_set=32,
+                           learning_rate=0.5, base_score=0.0, loss="logloss")
+    be = get_backend(TrainConfig(backend="tpu", n_bins=255,
+                                 predict_impl=impl))
+    Xb = rng.integers(0, 255, size=(300, 6), dtype=np.uint8)
+    for c, k in categories:
+        Xb[:, c] = rng.integers(0, k + 2, 300)
+    for call in range(2):       # the second from the model cache
+        scores = be.predict_raw(ens, Xb)
+        root = an.root_spans("predict")[-1]["counts"]
+        assert root["category_sets"] == 1 and root["node_list"] == 1
+        assert {k: root[k] for k in said if k in root} == {
+            k: v for k, v in said.items() if k in root}
+        assert root["catset_mxu_tiles_per_tree"] == said[
+            "catset_mxu_tiles_per_tree"]
+    np.testing.assert_array_equal(scores, ens.predict_raw(Xb, binned=True))
+    built = [sp for sp in an.recent_spans()
+             if sp["name"] == "ddt:predict:ensemble"][-1]["counts"]
+    assert {k: built[k] for k in said} == said
+    sets = ens.cat_nodes
+    assert built["category_sets"] == 1
+    assert built["category_nodes"] == int(sets.sum())
+    assert built["category_set_bits_max"] == int(
+        ens.cat_set_bits()[ens.cat_index[sets]].sum(axis=1).max())
+    assert built["select_nodes_per_lane"] == 1
 
 
 @pytest.mark.parametrize("n_features,k_blocks", [(6, 1), (129, 2), (300, 3)])

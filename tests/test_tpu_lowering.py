@@ -208,6 +208,68 @@ def test_path_kernel_takes_the_select_the_rule_packs(case):
     assert "ddt:predict:tables/concatenate" not in text
 
 
+# CATEGORY SETS (PR 55; scripts/tpu_aot_check.py `paths-cat/`): (one-hot
+# K-blocks, K rows of the select, `select_k_blocks`, MXU weight tiles a tree,
+# what the set test adds beside an ordinal model of the shape, trees a
+# block): the Allstate cell's shape, sets and ordinal nodes in one tree over
+# 32 columns (the ordinal K-block and six one-hot ones: 7 x 2 lane tiles +
+# 4), eight columns ALL categorical (no ordinal K row: 5 blocks x 2 lane
+# tiles + 4) and sets beside ordinal nodes with NaN directions at Bosch's
+# 968 columns (8 + 2 blocks).
+CAT_CASES = {
+    "paths-cat/32f/500x255": (6, 32 + 768, 7, 18, 13, 20),
+    "paths-cat/8f/500x255": (5, 640, 5, 14, 9, 20),
+    "paths-cat/968f/20x255leaves/nan": (2, 976 + 256, 10, 24, 4, 5),
+}
+
+
+def test_every_category_case_names_its_step():
+    assert sorted(CAT_CASES) == sorted(
+        c.name for c in DEFAULT_CASES if c.name.startswith("paths-cat/"))
+
+
+@pytest.mark.parametrize(
+    "case", [c for c in DEFAULT_CASES if c.name in CAT_CASES],
+    ids=lambda c: c.name)
+def test_category_set_kernel_takes_the_planned_step(case):
+    """A node list with category sets: the kernel's interface is the path
+    kernel's (the uint8 chunk as it comes, `f32[1, R]` back), its select
+    the model's own [T, Fo + 128 B, W], one node a lane whatever F, and
+    two small tables of the model beside the trees'; the plan says what
+    the kernel asks of the MXU."""
+    from ddt_tpu.ops import predict_paths
+
+    blocks, k_rows, k_blocks, tiles, extra, per_step = CAT_CASES[case.name]
+    exported, shapes = _export_for_tpu(case)
+    (trees, sel_rows, sel_lanes), _ = shapes[0]
+    (_, _, lanes), _ = shapes[1]
+    (rows, features), dtype = shapes[-1]
+    fp = -(-features // 16) * 16
+    assert dtype == jnp.uint8
+    assert (sel_rows, sel_lanes) == (k_rows, lanes)
+    assert shapes[3][0] == (blocks, fp, 128) and shapes[4][0] == (
+        blocks, 8, 128)
+    cat = predict_paths.CatSets(blocks, sel_rows)
+    assert cat.ordinal_rows in (0, fp)
+    plan = predict_paths.path_plan(trees, lanes, features, cat=cat)
+    assert (plan.select_k_blocks, plan.path_mxu_tiles_per_tree,
+            plan.catset_mxu_tiles_per_tree, plan.trees_per_step,
+            plan.select_nodes_per_lane, plan.category_sets) == (
+        k_blocks, tiles, extra, per_step, 1, 1)
+    text = exported.mlir_module()
+    call, = [ln for ln in text.splitlines()
+             if "@tpu_custom_call" in ln and "_paths_kernel" in ln]
+    operands, result = re.search(
+        r"\}\s*:\s*\((.*)\)\s*->\s*(tensor<[^>]*>)", call).groups()
+    assert operands.startswith(f"tensor<{rows}x{features}xui8>,")
+    assert f"tensor<{blocks}x{fp}x128xbf16>" in operands
+    assert result == f"tensor<1x{rows}xf32>"
+    for held in ("xi32>", "xf32>", "xbf16>"):
+        assert f"tensor<{rows}x{features}{held}" not in text
+    # the one-hot is the kernel's, in VMEM: no stage of XLA's makes it
+    assert "predict:catset" not in text and "predict:widen" not in text
+
+
 # The exits' table of every forest case (PR 49): (its lanes, the class
 # lanes of the kernel's result, the select's spans, `exit_mxu_tiles`, the
 # MXU weight tiles a sub-tree; a sixth, PR 51: `resolve_mxu_tiles`, 2 where
@@ -362,6 +424,11 @@ def test_case_table_covers_the_default_dispatch():
                    # past one K-block of the select; Bosch's width with
                    # the NaN route in the compare
                    "paths/129f", "paths/bosch/968f",
+                   # category sets: the Allstate cell's shape (sets and
+                   # ordinal nodes), a model of sets alone, and sets
+                   # beside routed ordinal nodes at 968 columns
+                   "paths-cat/32f/500x255", "paths-cat/8f/500x255",
+                   "paths-cat/968f",
                    # the sub-tree form: the MNIST forest's chunk, one
                    # sub-tree a tree at one column and at 85, one-tile
                    # sub-trees with two activity tiles
