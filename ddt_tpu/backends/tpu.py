@@ -2070,8 +2070,9 @@ class TPUDevice(DeviceBackend):
             ce.n_trees, classes, ce.leaves.shape[2],
             ce.select_spans, ce.paths.shape) if ce.chained else None
         # CATEGORY SETS (ops/predict_paths.py): the one-hot's K-blocks
-        cat = predict_paths.CatSets(ce.cat_blocks, ce.sel.shape[1]) \
-            if ce.cat_blocks else None
+        cat = predict_paths.CatSets(
+            ce.cat_blocks, ce.sel.shape[1], ce.select_spans,
+            ce.cat_ordinal_at) if ce.cat_blocks else None
         plan = predict_paths.path_plan(
             ce.n_subtrees or ce.n_trees, ce.lanes, ens.n_features,
             ce.deepest_leaf,
@@ -2127,6 +2128,9 @@ class TPUDevice(DeviceBackend):
             static.update(n_trees=ce.n_trees, leaf_columns=classes,
                           mean=ce.mean, select_spans=chain.select_spans,
                           **({"link": plan.link} if link else {}))
+        elif cat and cat.spans:
+            static.update(select_spans=cat.spans,
+                          cat_ordinal_at=cat.ordinal_at)
 
         # (two functions: the uncut form keeps its program's parameter
         # names, so its HLO is what it was)

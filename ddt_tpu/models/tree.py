@@ -1969,6 +1969,52 @@ def choose_select_spans(ens: NodeListEnsemble, lanes: int) -> tuple:
     return spans, _numbered(flat, pieces, only, halved)
 
 
+# Components of K-blocks `choose_set_spans` searches whole: 3^8 assignments.
+SET_SPAN_COMPONENTS = 8
+
+
+def choose_set_spans(counts: np.ndarray, blocks: np.ndarray,
+                     lanes: int) -> np.ndarray | None:
+    """`choose_select_spans` for the UNCUT tree with CATEGORY SETS
+    (`CompiledNodeList.select_spans`): which of a tree's two 128-lane tiles
+    reads each COMPONENT of the select's K-blocks, int [C]: 0 the first, 1
+    the second, 2 both; None: the dense spans. A set node's K rows lie in one
+    one-hot block, or in two where its column names more than 128 ids, and an
+    ordinal node's in an ordinal one; blocks tied by a node that reads two of
+    them are a component, the ordinal blocks one more, and a node lies in ONE
+    component. `counts` int [T, C]: the nodes of tree t in component c;
+    `blocks` int [C]: the component's K-blocks. A tree is not cut here, so
+    where `choose_select_spans` counts a candidate by the cut it would get,
+    an assignment has to FIT every tree as it is: the nodes only the first
+    tile may hold at most 128, and likewise the second (the nodes of a
+    component both read fill what room is left in either). Taken is the
+    fitting assignment of the FEWEST weight tiles (a component's blocks once
+    for every tile that reads it; the Allstate model: the ordinal block,
+    129.9 nodes a tree, in both and each of the six one-hot blocks in one, 8
+    where 14), of equal counts the one whose fullest tile is least full, then
+    the first in `itertools.product`'s order. Every assignment is scored on
+    the small table, 3^C of them: up to `SET_SPAN_COMPONENTS` components are
+    searched whole; past that, at any other width than two lane tiles (128:
+    nothing to skip; 384 and 512), and where no assignment under the dense
+    count fits every tree, the answer is None and the tables and the program
+    are the dense ones, instruction for instruction."""
+    import itertools
+
+    if lanes != 2 * PATH_LANES or not 1 < len(blocks) <= SET_SPAN_COMPONENTS:
+        return None
+    tiles = np.array(list(itertools.product((0, 1, 2), repeat=len(blocks))))
+    cost = ((tiles == 2) + 1) @ blocks                        # [A]
+    fullest = np.maximum(counts @ (tiles == 0).T,
+                         counts @ (tiles == 1).T).max(axis=0, initial=0)
+    # (a tile reads SOME block: the kernel sums every lane tile's `v`)
+    fits = ((fullest <= PATH_LANES) & (cost < 2 * blocks.sum())
+            & (tiles != 0).any(axis=1) & (tiles != 1).any(axis=1))
+    if not fits.any():
+        return None
+    return tiles[min(np.flatnonzero(fits),
+                     key=lambda a: (cost[a], fullest[a], a))]
+
+
 # bfloat16 pieces a float32 leaf value is held in (`split_bfloat16`; the
 # kernel's side of it is ops/predict_paths._LEAF_PIECES).
 LEAF_PIECES = 3
@@ -2097,6 +2143,18 @@ class CompiledNodeList:
     A bin no K row stands for (every unnamed value's, or one past the
     mapper's) is in no set: v is 0 and the row goes right.
 
+    The node lanes of such a tree are numbered BY THE K-BLOCK THEY READ
+    where the tree is two lane tiles and the model's blocks split
+    (`choose_set_spans`, `_lanes_by_k_block`; PR 56): `select_spans` says
+    which K-blocks of `sel`, in its rows' order, each lane tile reads,
+    ((0, 4), (3, 7)) for the Allstate model, whose `sel` holds three
+    one-hot blocks, the ordinal rows (`cat_ordinal_at` 3) and three more;
+    lane n is then NOT node n: `sel`'s columns, `planes` rows 0 and 3 and
+    `paths`' rows follow the lanes, the leaf lanes (`planes` rows 1 and 2,
+    `paths`' columns) stay where they are. Dense (`select_spans` (),
+    `cat_ordinal_at` 0): the ordinal rows first, the blocks as packed, lane
+    n node n.
+
     `mean`: the score is the sum over the trees divided by their number
     (an averaged forest), else base + learning_rate x sum ([rows] of one
     column; [rows, C] of softmax's classes, whose trees take the sub-tree
@@ -2121,8 +2179,9 @@ class CompiledNodeList:
     leaf_columns: int = 1      # C
     mean: bool = False
     widest_tree: int = 0       # lanes the widest tree would take uncut
-    select_spans: tuple = ()   # the sub-tree form: (first, stop) K-blocks
-    #   of the select a lane tile
+    select_spans: tuple = ()   # (first, stop) K-blocks of the select a
+    #   lane tile: of a sub-tree, or of an uncut tree with category sets
+    #   (there the K-blocks as `sel` holds them; (): every tile reads all)
     subtrees_max: int = 1      # the largest tree's entries ...
     single_subtree_trees: int = 0   # ... and the trees that are ONE entry
     spine_copies: int = 0      # halved: the lanes that hold a node's copy
@@ -2132,6 +2191,8 @@ class CompiledNodeList:
     cat_bins: np.ndarray | None = None     #   columns and bins
     category_nodes: int = 0    # the nodes that ask a set ...
     category_set_bits_max: int = 0     # ... and the widest set's bins
+    cat_ordinal_at: int = 0    # the one-hot blocks whose K rows lie BEFORE
+    #   the ordinal ones in `sel` (0: the ordinal rows first)
 
     @property
     def cat_blocks(self) -> int:
@@ -2187,25 +2248,39 @@ class CompiledNodeList:
                            chained_sets=bool(sets.any()))
             return CompiledNodeList._build_subtrees(ens, W, Fp)
         P, plen = ens.path_matrix()
+        thr = np.where(live, np.where(sets, 0, ens.threshold_bin), 2.0 ** 30)
+        up = np.where(live & ens.default_left & ~sets, nan_bin, 2.0 ** 30) \
+            if nan_bin >= 0 else None
         if not sets.any():
             sel = np.zeros((T, Fp, W), ml_dtypes.bfloat16)
             t_idx, n_idx = np.nonzero(live)
             sel[t_idx, ens.feature[t_idx, n_idx], n_idx] = 1.0
-            cat = {}
+            lane, cat = None, {}
         else:
-            sel, cat = CompiledNodeList._select_with_sets(ens, sets, Fp, W)
+            sel, lane, cat = CompiledNodeList._select_with_sets(
+                ens, sets, Fp, W)
             P[sets] = -P[sets]      # +1 of a set node: in the set, LEFT
+        if lane is not None:
+            # The node lanes ordered by their K-block (`_lanes_by_k_block`):
+            # `sel`'s columns are, the thresholds and P's rows follow; the
+            # leaf lanes stay where they are.
+            def by_lane(a, fill):
+                out = np.full((T, W) + a.shape[2:], fill, a.dtype)
+                out[np.arange(T)[:, None], lane] = a
+                return out
+
+            thr, P = by_lane(thr, 2.0 ** 30), by_lane(P, 0)
+            up = by_lane(up, 2.0 ** 30) if up is not None else None
+            N = W
         planes = np.zeros((T, 8, W), np.float32)
         planes[:, 0, :] = 2.0 ** 30
-        planes[:, 0, :N] = np.where(live, np.where(sets, 0, ens.threshold_bin),
-                                    2.0 ** 30)
+        planes[:, 0, :N] = thr
         planes[:, 1, :] = -1.0
         planes[:, 1, :L] = plen
         planes[:, 2, :L] = ens.leaf_value
-        if nan_bin >= 0:
+        if up is not None:
             planes[:, 3, :] = 2.0 ** 30
-            planes[:, 3, :N] = np.where(live & ens.default_left & ~sets,
-                                        nan_bin, 2.0 ** 30)
+            planes[:, 3, :N] = up
         paths = np.zeros((T, W, W), ml_dtypes.bfloat16)
         paths[:, :N, :L] = P
         return CompiledNodeList(
@@ -2219,12 +2294,16 @@ class CompiledNodeList:
     @staticmethod
     def _select_with_sets(ens: NodeListEnsemble, sets: np.ndarray, Fp: int,
                           W: int) -> tuple:
-        """(`sel` [T, Fo + 128 B, W], the fields that say what its K rows
-        past Fo are): `build` of a model with category sets (the class's
-        docstring, CATEGORY SETS). A column's K rows are the bins its sets
-        name, 0 up to the largest; the columns are packed into blocks of
-        128 rows first-fit, the largest first, a column of more than 128
-        rows as whole blocks and a rest."""
+        """(`sel` [T, Fo + 128 B, W], the lane [T, N] of every node, the
+        fields that say what `sel`'s K rows are):
+        `build` of a model with category sets (the class's docstring,
+        CATEGORY SETS). A column's K rows are the bins its sets name, 0 up
+        to the largest; the columns are packed into blocks of 128 rows
+        first-fit, the largest first, a column of more than 128 rows as
+        whole blocks and a rest. The blocks' order in `sel`, the place of
+        the ordinal rows among them and the nodes' lanes are
+        `_lanes_by_k_block`'s: as packed, the ordinal rows first and lane n
+        node n where the spans are dense."""
         import ml_dtypes
 
         T = ens.feature.shape[0]
@@ -2253,22 +2332,97 @@ class CompiledNodeList:
             row_of[c, b0:b0 + k] = blk * PATH_LANES + lane + np.arange(k)
             where.append((blk, lane, k, c, b0))
         B = len(free)
+        ordinal = ens.live_nodes & ~sets
+        Fo = Fp if ordinal.any() else 0
+        at, b = np.nonzero(bits[ss])                # (set node, bin in it)
+        k_row = row_of[col[at], b]                  # as packed
+        new_block, ordinal_at, spans, lane = \
+            CompiledNodeList._lanes_by_k_block(
+                ens, ordinal, (tt, nn), at, k_row // PATH_LANES, B, W)
         expand = np.zeros((B, Fp, PATH_LANES), ml_dtypes.bfloat16)
         bins = np.zeros((B, 8, PATH_LANES), np.float32)
         bins[:, 0, :] = -1.0
-        for blk, lane, k, c, b0 in where:
-            expand[blk, c, lane:lane + k] = 1.0
-            bins[blk, 0, lane:lane + k] = b0 + np.arange(k)
-        ordinal = ens.live_nodes & ~sets
-        Fo = Fp if ordinal.any() else 0
+        for blk, first, k, c, b0 in where:
+            expand[new_block[blk], c, first:first + k] = 1.0
+            bins[new_block[blk], 0, first:first + k] = b0 + np.arange(k)
+        # K rows of `sel`: the one-hot blocks in their new order, the
+        # ordinal rows behind the first `ordinal_at` of them
+        k_row = new_block[k_row // PATH_LANES] * PATH_LANES \
+            + k_row % PATH_LANES
+        k_row += Fo * (k_row >= ordinal_at * PATH_LANES)
         sel = np.zeros((T, Fo + PATH_LANES * B, W), ml_dtypes.bfloat16)
         t_idx, n_idx = np.nonzero(ordinal)
-        sel[t_idx, ens.feature[t_idx, n_idx], n_idx] = 1.0
-        at, b = np.nonzero(bits[ss])                # (set node, bin in it)
-        sel[tt[at], Fo + row_of[col[at], b], nn[at]] = 1.0
-        return sel, dict(
+        sel[t_idx, ordinal_at * PATH_LANES + ens.feature[t_idx, n_idx],
+            lane[t_idx, n_idx]] = 1.0
+        sel[tt[at], k_row, lane[tt[at], nn[at]]] = 1.0
+        return sel, lane, dict(
             cat_expand=expand, cat_bins=bins, category_nodes=len(ss),
-            category_set_bits_max=int(bits[ss].sum(axis=1).max(initial=0)))
+            category_set_bits_max=int(bits[ss].sum(axis=1).max(initial=0)),
+            select_spans=spans, cat_ordinal_at=ordinal_at)
+
+    @staticmethod
+    def _lanes_by_k_block(ens: NodeListEnsemble, ordinal: np.ndarray,
+                          set_nodes: tuple, at: np.ndarray, block: np.ndarray,
+                          B: int, W: int) -> tuple:
+        """(the place of each packed one-hot block among `sel`'s [B], the
+        one-hot blocks that lie before the ordinal K rows, `select_spans`,
+        the lane of every node [T, N]) of an uncut model with
+        category sets: the K-BLOCK SPARSE select (ops/predict_paths.py) by
+        the order of the node lanes. `ordinal` bool [T, N]: the ordinal
+        nodes; `set_nodes` (tree, node) of the set nodes; `block[i]` the
+        packed one-hot block that K row i of set node `at[i]` lies in.
+
+        The K-blocks tied by a node that reads two of them are a component,
+        the ordinal blocks one more (the first), and `choose_set_spans` says
+        which lane tile reads each. The blocks are laid in the order (the
+        first tile's own, the shared, the second's own), a component's
+        together, so that a tile's blocks are one (first, stop) span of the
+        K-blocks as `sel` holds them; a node that only one tile can hold
+        lies there, the others fill the first tile's room and then the
+        second's, the node order inside each. Dense: the blocks as packed,
+        0, (), lane n node n."""
+        T, N = ordinal.shape
+        tt, nn = set_nodes
+        # the blocks a set node reads: one, or two of a column of more
+        # than 128 named ids (a set no K row stands for: block 0's lanes)
+        lo, hi = np.full(len(tt), B), np.full(len(tt), -1)
+        np.minimum.at(lo, at, block)
+        np.maximum.at(hi, at, block)
+        lo, hi = np.where(hi < 0, 0, lo), np.maximum(hi, 0)
+        root = np.arange(B)                 # a component's first block
+        for a, b in np.unique(np.stack([lo, hi], 1)[lo != hi], axis=0):
+            root[root == max(root[a], root[b])] = min(root[a], root[b])
+        has_ordinal = int(ordinal.any())
+        comp_of = np.unique(root, return_inverse=True)[1] + has_ordinal
+        blocks = np.bincount(comp_of)
+        if has_ordinal:
+            blocks[0] = -(-ens.n_features // PATH_LANES)
+        comp = np.full((T, N), -1)
+        comp[ordinal] = 0
+        comp[tt, nn] = comp_of[lo]
+        counts = (comp[:, :, None] == np.arange(len(blocks))).sum(axis=1)
+        tiles = choose_set_spans(counts, blocks, W)
+        if tiles is None:
+            return np.arange(B), 0, (), np.broadcast_to(np.arange(N), (T, N))
+        group = np.array([0, 2, 1])[tiles]  # first's own, shared, second's
+        by_place = np.lexsort((np.arange(B), comp_of, group[comp_of]))
+        new_block = np.empty(B, np.int64)
+        new_block[by_place] = np.arange(B)
+        ordinal_at = int((group[comp_of] < group[0]).sum()) * has_ordinal
+        first, shared, _ = (int(n) for n in np.bincount(
+            group, blocks, minlength=3))
+        spans = ((0, first + shared), (first, int(blocks.sum())))
+        # lanes: a tile's own nodes from its first lane, then the shared
+        # ones (and the slots of no node) in the room left, tile 0's first
+        which = np.where(comp >= 0, tiles[comp], 2)
+        rank = [np.cumsum(which == k, axis=1) - 1 for k in range(3)]
+        own = [rank[k][:, -1:] + 1 for k in range(2)]
+        room = PATH_LANES - own[0]
+        lane = np.select(
+            [which == 0, which == 1, rank[2] < room],
+            [rank[0], PATH_LANES + rank[1], own[0] + rank[2]],
+            PATH_LANES + own[1] + rank[2] - room)
+        return new_block, ordinal_at, spans, lane
 
     @staticmethod
     def _build_subtrees(ens: NodeListEnsemble, widest: int,

@@ -595,7 +595,7 @@ _PATHS_TREE_CHUNK, _PATHS_ROW_CHUNK = 8, 8_192
 
 def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
                    missing_routes: bool = False, leaves=None, chain=None,
-                   mean: bool = False, cat=None):
+                   mean: bool = False, cat=None, sets=None):
     """The path-matrix form (module docstring) in plain jax.numpy: trees in
     chunks of _PATHS_TREE_CHUNK, rows in chunks of _PATHS_ROW_CHUNK, so the
     [trees, rows, W] intermediates stay bounded. The operands are
@@ -604,7 +604,10 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
     of them is exact too. `missing_routes`: planes' row 3 is read.
     `leaves` and `chain`: the SUB-TREE form, `_predict_chain`. `cat`:
     (cat_expand, cat_bins), the model carries CATEGORY SETS: `sel`'s K rows
-    past the ordinal ones are the one-hot's, made here a chunk of rows."""
+    beside the ordinal ones are the one-hot's, made here a chunk of rows,
+    the ordinal ones behind the first `sets.ordinal_at` blocks of them
+    (`sets`: predict_paths.CatSets; its spans are the kernel's to skip by,
+    every product here is whole)."""
     if chain is not None:
         return _predict_chain(sel, planes, paths, leaves, Xc, chain=chain,
                               learning_rate=learning_rate, base=base,
@@ -638,8 +641,10 @@ def _predict_paths(sel, planes, paths, Xc, *, learning_rate, base,
                                  == bins[b, 0:1, :], 1.0, 0.0)
                        for b in range(expand.shape[0])]
                 # (no ordinal K rows where every node asks a set)
+                at = sets.ordinal_at if sets else 0
                 xrc = jnp.concatenate(
-                    [xrc] * (Fk > 128 * len(hot)) + hot, axis=1)
+                    hot[:at] + [xrc] * (Fk > 128 * len(hot)) + hot[at:],
+                    axis=1)
 
         def tree_body(acc, args):
             a, pl_, p = args
@@ -743,7 +748,7 @@ def _predict_chain(sel, planes, paths, leaves, Xc, *, chain, learning_rate,
     jax.jit,
     static_argnames=("learning_rate", "base", "use_pallas",
                      "missing_routes", "n_trees", "leaf_columns", "mean",
-                     "select_spans", "link"),
+                     "select_spans", "link", "cat_ordinal_at"),
 )  # (cat_expand / cat_bins: arrays, their shapes say the rest)
 @op_scope("predict")
 def predict_raw_effective_paths(
@@ -765,6 +770,7 @@ def predict_raw_effective_paths(
     link: str = "none",
     cat_expand: jax.Array | None = None,   # bf16 [B, Fp, 128]: category
     cat_bins: jax.Array | None = None,     # f32 [B, 8, 128]     sets
+    cat_ordinal_at: int = 0,
 ) -> jax.Array:
     """Raw margins [R] of a node-list ensemble from its compiled tables
     (models/tree.CompiledNodeList): the path-matrix form, by the Pallas
@@ -781,10 +787,11 @@ def predict_raw_effective_paths(
     [R, C], and with `link` "softmax" their softmax, the class
     probabilities, taken here on the device (stage `predict:link`);
     `select_spans` (`CompiledNodeList.select_spans`) the K-blocks of the
-    select each lane tile of a sub-tree reads, which the kernel alone asks
-    for. `cat_expand` and `cat_bins`: the model carries CATEGORY SETS
-    (`CompiledNodeList`: the uncut form alone), `sel` the one-hot's K rows
-    behind the ordinal ones."""
+    select each lane tile of a sub-tree (of an uncut tree with category
+    sets) reads, which the kernel alone asks for. `cat_expand` and
+    `cat_bins`: the model carries CATEGORY SETS (`CompiledNodeList`: the
+    uncut form alone), `sel` the one-hot blocks' K rows with the ordinal
+    ones behind the first `cat_ordinal_at` of them."""
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
         raise ValueError("the path-matrix form scores binned (integer) rows")
     from ddt_tpu.ops import predict_paths
@@ -809,6 +816,8 @@ def predict_raw_effective_paths(
         if chain is not None:
             raise ValueError("category sets in the sub-tree form")
         form["cat"] = (cat_expand, cat_bins)
+        form["sets"] = predict_paths.CatSets(
+            cat_expand.shape[0], sel.shape[1], select_spans, cat_ordinal_at)
     if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], leaf_columns,
                           path_lanes=planes.shape[2],
                           path_exit_lanes=exit_lanes,

@@ -66,7 +66,8 @@ One non-zero a column of sel and bins below 256: every partial product is
 exact and so is their sum in any order.
 
 ... and K-BLOCK SPARSE in the sub-tree form (`Chain.select_spans`,
-`select_mxu_tiles`). A node's one K row lies in ONE K-block, so of the
+`select_mxu_tiles`) and, since PR 56, for an uncut tree with CATEGORY SETS
+(`CatSets.spans`, below). A node's one K row lies in ONE K-block, so of the
 ceil(F/128) weight tiles a 128-lane tile of the select asks, all but the
 blocks its own nodes read are zeros; and which block a lane reads is decided
 by the ORDER of the lanes alone, which is free. So the host numbers a
@@ -205,10 +206,34 @@ where every node asks a set: `sel` then has no such rows) and the B one-hot
 blocks, against a `sel` whose set nodes' columns are MULTI-hot over their
 sets' K rows: v = 1 in the set, 0 out of it, threshold 0, the node's row of P
 negated. Compare, resolve, accumulate and fold are untouched. One node a
-result lane (`select_nodes_per_lane` 1 whatever F): a tree of 256 lanes over
-five one-hot blocks asks 5 x 2 + 4 = 14 weight tiles where an ordinal model
-of its shape asks 5 (`catset_mxu_tiles_per_tree` 9). A model without a set
-traces the program it did, instruction for instruction.
+result lane (`select_nodes_per_lane` 1 whatever F).
+
+The select of such a tree is K-BLOCK SPARSE too (`CatSets.spans`; PR 56): a
+set node's K rows lie in one one-hot block (two for a column of more than
+128 named ids), an ordinal node's in an ordinal one, and of a tree's 254
+nodes few read any one block. So where a tree is two lane tiles the host
+numbers its node lanes by the K-block they read (models/tree.
+choose_set_spans, from the model alone: the blocks tied by a node that
+reads two are a component, the ordinal blocks one more, each component read
+by the first tile, by the second or by both, the fewest weight tiles under
+which every tree fits), lays `sel`'s K-blocks in the order (the first
+tile's own, the shared, the second's own: the ordinal rows then lie behind
+`CatSets.ordinal_at` one-hot blocks, and `cat_expand` / `cat_bins` follow
+their blocks), and the kernel sums lane tile j over span j's blocks alone,
+as for a sub-tree. `planes` rows 0 and 3 and `paths`' rows follow the node
+lanes; the leaf lanes keep their place, so `m = s @ P` sums the same
+integers in another order and the scores are bit for bit those of the
+dense numbering. The weight tiles a tree asks are the spans' sum and the
+resolve's: the Allstate model (six one-hot blocks beside the ordinal one;
+the ordinal block, 130 nodes a tree, in both tiles and each one-hot block
+in one) 4 + 4 + 4 = 12 where an ordinal model of its shape asks 5
+(`catset_mxu_tiles_per_tree` 7) and 7 x 2 + 4 = 18 before PR 56. Dense
+spans (any other width than two lane tiles, more than eight components, a
+model no cheaper assignment fits: eight categorical columns over five
+blocks may ask 5 x 2 + 4 = 14, `catset_mxu_tiles_per_tree` 9) are ONE
+matmul a K-block over all the lanes, the ordinal rows first: that program
+and its tables, instruction for instruction. A model without a set traces
+the program it did.
 
 Learned NaN directions (`missing_routes`; models/tree.CompiledNodeList):
 the NaN bin is the top bin, above every threshold, so `v > thr` alone is
@@ -347,6 +372,11 @@ class CatSets(typing.NamedTuple):
     blocks: int                # B: one-hot K-blocks of 128 rows
     select_rows: int           # K rows of `sel`: the ordinal ones (Fp, or
     #   0 where every node asks a set) and 128 B
+    spans: tuple = ()          # (first, stop) K-blocks of `sel`, in its
+    #   rows' order, that each 128-lane tile of a tree reads (models/tree.
+    #   CompiledNodeList.select_spans); (): every tile reads every block
+    ordinal_at: int = 0        # the one-hot blocks whose K rows lie before
+    #   the ordinal ones in `sel`
 
     @property
     def ordinal_rows(self) -> int:
@@ -378,9 +408,10 @@ def select_mxu_tiles(lanes: int, n_features: int,
     `nodes_per_lane` None: the kernel's own (`select_nodes_per_lane`).
     `cat`: the K-blocks the kernel asks of a model with CATEGORY SETS, the
     ordinal ones (if any node is ordinal) and the one-hot's, one node a
-    lane."""
+    lane: every block a lane tile, or under `cat.spans` each tile's own."""
     if cat:
-        return _cat_k_blocks(n_features, cat) * (lanes // _LANES)
+        return sum(stop - start for start, stop in cat.spans) or (
+            _cat_k_blocks(n_features, cat) * (lanes // _LANES))
     if nodes_per_lane is None:
         nodes_per_lane = select_nodes_per_lane(n_features, lanes)
     if select_spans and nodes_per_lane == 1:
@@ -393,6 +424,16 @@ def _cat_k_blocks(n_features: int, cat: CatSets) -> int:
     """K-blocks the select of a model with CATEGORY SETS is summed over."""
     return cat.blocks + (select_k_blocks(n_features)
                          if cat.ordinal_rows else 0)
+
+
+def _cat_block_rows(n_features: int, cat: CatSets) -> list:
+    """K rows of each K-block of such a model's `sel`, in its rows' order:
+    128 a one-hot block, the ordinal blocks behind `cat.ordinal_at` of them
+    (the last of those up to whole bf16 sublane tiles of the columns)."""
+    ordinal = [min(k0 + _LANES, cat.ordinal_rows) - k0
+               for k0 in range(0, n_features, _LANES)] * (cat.ordinal_rows > 0)
+    return ([_LANES] * cat.ordinal_at + ordinal
+            + [_LANES] * (cat.blocks - cat.ordinal_at))
 
 
 def resolve_mxu_tiles(lanes: int, halved: bool = False) -> int:
@@ -753,7 +794,8 @@ def pack_select(sel, planes, n_features: int, xp=jnp) -> tuple:
 def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
                   n_trees: int, n_feat: int, missing_routes: bool,
                   class_lanes: int = 0, select_spans: tuple = (),
-                  at_hand: int = 0, cat_blocks: int = 0):
+                  at_hand: int = 0, cat_blocks: int = 0,
+                  cat_ordinal_at: int = 0):
     """One row tile against one block of `n_trees` trees: the block's share
     of every row's margin. x_ref [TILE_ROWS, F] uint8 or int32, as HBM
     holds the rows (in the last tile, whatever lies past row R); sel
@@ -772,14 +814,15 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
     E = CL = A = 128, the links behind the pieces in ONE tile (`Chain.
     shared`); `at_hand` the activity's lane of the sub-tree at hand
     (`Chain.at_hand`). `select_spans`: the
-    K-blocks each 128-lane tile of the select reads (`Chain`; the caller
-    hands the packed select none); (): every block, one matmul a block over
-    all the lanes.
+    K-blocks each 128-lane tile of the select reads (`Chain`, or `CatSets`
+    of an uncut tree with category sets: the K-blocks in the order of sel's
+    rows; the caller hands the packed select none); (): every block, one
+    matmul a block over all the lanes.
 
     `cat_blocks` B > 0, CATEGORY SETS (module docstring; the uncut form
     alone): `rest` is (cat_expand [B, Fp, 128] bf16, cat_bins [B, 8, 128]
-    f32, out); sel's K rows are the ordinal ones (Fp, or none) and then
-    the B one-hot blocks'."""
+    f32, out); sel's K rows are the B one-hot blocks' with the ordinal
+    ones (Fp, or none) behind the first `cat_ordinal_at` of them."""
     out_ref = rest[2 if cat_blocks else class_lanes > 0]
     tile_rows = x_ref.shape[0]
     fp, lanes = sel_ref.shape[1], planes_ref.shape[2]
@@ -798,7 +841,7 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
     k_starts = range(0, n_feat, _LANES)
     # The select's lane groups (first lane, stop, first K-block, stop): a
     # run of lane tiles that read the same K-blocks is one matmul a block.
-    groups = [(0, wp, 0, len(k_starts))]
+    groups = [(0, wp, 0, cat_blocks + len(k_starts) * (ordinal_rows > 0))]
     if len(set(select_spans)) > 1:
         groups = [(j * _LANES, (j + 1) * _LANES, *span)
                   for j, span in enumerate(select_spans)]
@@ -845,13 +888,15 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
                 xe = part if xe is None else xe + part
             hot.append(jnp.where(xe == bins_ref[b, 0:1, :], 1.0, 0.0
                                  ).astype(jnp.bfloat16))
+        # the select's K-blocks, in the order of sel's rows: (first K row
+        # of sel, left operand)
+        k_blocks = list(zip(k_starts, xs)) * (ordinal_rows > 0)
         if cat_blocks:
-            # the select's K-blocks: (first K row of sel, left operand)
-            k_blocks = [(k0, xk) for k0, xk in zip(k_starts, xs)
-                        ] * (ordinal_rows > 0) + [
-                (ordinal_rows + b * _LANES, h) for b, h in enumerate(hot)]
-        else:
-            k_blocks = list(zip(k_starts, xs))
+            at = cat_ordinal_at
+            k_blocks = [(b * _LANES, h) for b, h in enumerate(hot[:at])] + [
+                (at * _LANES + k0, xk) for k0, xk in k_blocks] + [
+                (ordinal_rows + b * _LANES, h)
+                for b, h in enumerate(hot[at:], at)]
 
         def tree(g, acc):
             """`acc` plus tree g's leaf value; of a sub-tree (`acc` None)
@@ -864,8 +909,7 @@ def _paths_kernel(x_ref, sel_ref, planes_ref, paths_ref, *rest,
             vs = []
             for l0, l1, first, stop in groups:
                 v = None
-                for k0, xk in (k_blocks if cat_blocks
-                               else k_blocks[first:stop]):
+                for k0, xk in k_blocks[first:stop]:
                     part = jax.lax.dot_general(
                         xk, sel_ref[g, k0:k0 + xk.shape[1], l0:l1],
                         (((1,), (0,)), ((), ())),
@@ -989,6 +1033,7 @@ def predict_paths_pallas(
     chain: Chain | None = None,        # ... and its shape
     mean: bool = False,
     cat: tuple | None = None,          # (cat_expand, cat_bins): CATEGORY SETS
+    sets: CatSets | None = None,       # ... and their shape (its spans)
 ) -> jax.Array:
     """Raw margins [R]: Pallas twin of ops/predict._predict_paths. Jit-safe.
     interpret=None auto-selects the Pallas interpreter off-TPU. Where the
@@ -1001,12 +1046,17 @@ def predict_paths_pallas(
     leaves the margin [R] as ever, or (`chain.leaf_columns` C > 1 without
     `mean`: softmax's round-major trees, a tree's leaves in its class's
     lanes alone) the margins [R, C]. `cat`: the model carries CATEGORY
-    SETS (module docstring), `sel` as the model compiles it."""
+    SETS (module docstring), `sel` as the model compiles it; `sets` says
+    how its K-blocks lie and which of them each lane tile reads (models/
+    tree.CompiledNodeList.select_spans and cat_ordinal_at; None: as the
+    tables' shapes say, the ordinal rows first and every tile every
+    block)."""
     if interpret is None:
         interpret = device.platform() != "tpu"
     T, _, lanes = planes.shape
     R, F = Xc.shape
-    sets = CatSets(cat[0].shape[0], sel.shape[1]) if cat else None
+    if cat and sets is None:
+        sets = CatSets(cat[0].shape[0], sel.shape[1])
     if not cat and select_nodes_per_lane(F, lanes) == 2 \
             and sel.shape[2] == lanes:
         with traced_scope("predict:tables"):
@@ -1050,11 +1100,14 @@ def predict_paths_pallas(
 
     exit_lanes = chain.exit_lanes if chain else 0
     resolve_rows = paths.shape[1]
-    spans = chain.select_spans if chain and sel_lanes == lanes else ()
-    # the select's weights the MXU is asked for: K rows x lanes
+    spans = sets.spans if sets else (
+        chain.select_spans if chain and sel_lanes == lanes else ())
+    # the select's weights the MXU is asked for: K rows x lanes, under
+    # spans a lane tile's own K-blocks' rows
+    k_rows = _cat_block_rows(F, sets) if sets else [
+        min(k0 + _LANES, fp) - k0 for k0 in range(0, fp, _LANES)]
     select = fp * sel_lanes if not spans else sum(
-        (min(stop * _LANES, fp) - start * _LANES) * _LANES
-        for start, stop in spans)
+        sum(k_rows[start:stop]) * _LANES for start, stop in spans)
     cost = pl.CostEstimate(
         flops=2 * n_tiles * tile_rows * n_blocks * g * (
             select + resolve_rows * lanes + lanes * exit_lanes),
@@ -1081,7 +1134,9 @@ def predict_paths_pallas(
                               class_lanes=chain.class_lanes if chain else 0,
                               select_spans=spans,
                               at_hand=chain.at_hand if chain else 0,
-                              **({"cat_blocks": sets.blocks} if cat else {})),
+                              **({"cat_blocks": sets.blocks,
+                                  "cat_ordinal_at": sets.ordinal_at}
+                                 if cat else {})),
             # The grid walks the UNPADDED rows: the last tile's blocks are
             # ragged, as in the heap kernel.
             grid=(n_tiles, n_blocks),

@@ -212,13 +212,18 @@ def _random_node_list(n_trees, n_leaves, features, missing=False,
 
 
 def _paths_case(rows, features, n_trees, n_leaves, missing=False,
-                categories=()):
+                categories=(), cat_spans=(), cat_ordinal_at=0):
     """The path-matrix kernel (ops/predict_paths.py) over a node list's
     compiled tables as a backend hands them over (the select packed on the
     host where it answers two nodes a lane: up to 64 columns, 256 lanes
     and more), the rows as api.predict does (uint8). `categories`:
     (column, cardinality) pairs whose nodes ask CATEGORY SETS (the one-hot
-    K-blocks; one node a lane, the select as the model compiles it)."""
+    K-blocks; one node a lane, the select as the model compiles it).
+    `cat_spans` and `cat_ordinal_at`: the K-blocks of that select each lane
+    tile reads and where its ordinal rows lie, as a model's build found
+    them (`CompiledNodeList.select_spans`, `.cat_ordinal_at`: shape facts
+    of the program; the tables here are a random model's and only their
+    shapes are read); (): every tile reads every block."""
     def build():
         import jax.numpy as jnp
         import numpy as np
@@ -234,13 +239,21 @@ def _paths_case(rows, features, n_trees, n_leaves, missing=False,
             tables = (*predict_paths.pack_select(ce.sel, ce.planes, features,
                                                  xp=np), ce.paths)
 
+        # (`--tree` may name a checkout from before the sets' spans: it
+        # compiles the dense program)
+        spans = cat_spans if categories and "spans" in \
+            predict_paths.CatSets._fields else ()
+
         def fn(sel, planes, paths, *rest):
             *cat, Xc = rest
             return predict_paths.predict_paths_pallas(
                 sel, planes, paths, Xc,
                 learning_rate=ce.learning_rate, base=ce.base_score,
                 missing_routes=missing, interpret=False,
-                **({"cat": tuple(cat)} if cat else {}))
+                **({"cat": tuple(cat)} if cat else {}),
+                **({"sets": predict_paths.CatSets(
+                    len(cat[0]), sel.shape[1], spans, cat_ordinal_at)}
+                   if spans else {}))
 
         shapes = [(a.shape, a.dtype) for a in tables]
         shapes.append(((rows, features), jnp.uint8))
@@ -484,13 +497,21 @@ def kernel_cases() -> list:
                                255, missing=True)),
         # CATEGORY SETS (LightGBM's categorical splits): the Allstate
         # cell's own shape, sets and ordinal nodes in one tree (six one-hot
-        # K-blocks beside the ordinal one, 18 weight tiles a tree), eight
-        # columns ALL categorical (five one-hot K-blocks, no ordinal K row,
-        # 14 tiles), and sets beside ordinal nodes WITH NaN directions at
-        # Bosch's width (the eight ordinal K-blocks and the sets' own).
+        # K-blocks beside the ordinal one) under the spans the build finds
+        # for that cell's drawn model (models/tree.choose_set_spans: three
+        # one-hot blocks, the ordinal rows, three one-hot blocks; the
+        # ordinal block read by both lane tiles, a one-hot block by one: 8
+        # select tiles where 14, 12 weight tiles a tree where 18 until PR
+        # 56), eight columns ALL categorical (five one-hot K-blocks, no
+        # ordinal K row) under DENSE spans (what a model gets whose blocks
+        # do not split: 14 tiles, the program of before the spans), and
+        # sets beside ordinal nodes WITH NaN directions at Bosch's width
+        # (the eight ordinal K-blocks and the sets' own, dense too).
         KernelCase("paths-cat/32f/500x255", True,
                    _paths_case(2_000_000, ALLSTATE["features"], 500, 255,
-                               categories=ALLSTATE["categories"])),
+                               categories=ALLSTATE["categories"],
+                               cat_spans=((0, 4), (3, 7)),
+                               cat_ordinal_at=3)),
         KernelCase("paths-cat/8f/500x255", True,
                    _paths_case(2_000_000, ALL_SETS["features"], 500, 255,
                                categories=ALL_SETS["categories"])),

@@ -12,6 +12,14 @@ the driver takes a later PR's entries only at the END of their lists, so no
 PR after them can satisfy both. Only a `benchmark` PR may edit them. Here
 they run whole against the manifest with every later entry moved BEFORE the
 ones they pin: `benchmark/run.py` reads those lists by membership alone.
+
+One more pins a COUNT of the program's that a later PR took down
+(`DENSE_TILES`, below): the Allstate cell's `path_mxu_tiles_per_tree` as 2 x
+`select_k_blocks` + 4, every lane tile of the select reading every K-block,
+18, where the model's node lanes are ordered by K-block since PR 56 and the
+kernel asks 12. It runs whole against the program under DENSE spans, the
+form it pins (what a model gets whose blocks do not split), and the cell's
+model as the program really builds it is held to its counts right after.
 """
 
 import importlib
@@ -69,3 +77,32 @@ def _with_later_entries_first(test):
 
 for _name in PINNED:
     globals()[_name] = _with_later_entries_first(globals()[_name])
+
+
+DENSE_TILES = "test_correct_leafwise_cat__cat_metrics_are_counted_by_name"
+
+
+def _under_dense_set_spans(test):
+    def run(leafwise_cat_job, monkeypatch):
+        from ddt_tpu.backends import get_backend
+        from ddt_tpu.models import tree
+        from ddt_tpu.telemetry.annotations import recent_spans
+
+        # (the model's tables live in the backend's cache under the model's
+        # token: emptied on both sides, so each form is built where asked)
+        cache = get_backend(leafwise_cat_job.cfg)._predict_cache
+        with monkeypatch.context() as m:
+            m.setattr(tree, "choose_set_spans", lambda *a: None)
+            cache.clear()
+            test(leafwise_cat_job)
+        cache.clear()
+        leafwise_cat_job.one_job()
+        counts = [sp for sp in recent_spans()
+                  if sp["name"] == "ddt:predict"][-1]["counts"]
+        assert [counts[k] for k in (
+            "path_mxu_tiles_per_tree", "catset_mxu_tiles_per_tree",
+            "select_mxu_tiles", "select_k_blocks")] == [12, 7, 8, 7]
+    return run
+
+
+globals()[DENSE_TILES] = _under_dense_set_spans(globals()[DENSE_TILES])

@@ -723,3 +723,224 @@ def test_category_sets_cache_token_save_load_and_dump(tmp_path):
     # sets past what one path matrix holds are refused BY NAME
     with pytest.raises(ValueError, match="SUB-TREE form"):
         category_ensemble(58, n_trees=2, n_leaves=600).compile()
+
+
+# ------------------------------------------------------------------ #
+# the set test's select by SPANS (PR 56): an uncut tree's node lanes
+# ordered by K-block, each lane tile asking its own K-blocks alone
+# ------------------------------------------------------------------ #
+
+def _compiled_twice(ens, monkeypatch):
+    """(the model's tables as the build makes them, its tables under DENSE
+    spans: what the build made before it read the spans from the model)."""
+    from ddt_tpu.models import tree
+
+    ce = tree.CompiledNodeList.build(ens)
+    with monkeypatch.context() as m:
+        m.setattr(tree, "choose_set_spans", lambda *a: None)
+        dense = tree.CompiledNodeList.build(ens)
+    assert dense.select_spans == () and dense.cat_ordinal_at == 0
+    return ce, dense
+
+
+def _cat_sets(ce, n_features):
+    from ddt_tpu.ops import predict_paths
+
+    cat = predict_paths.CatSets(ce.cat_blocks, ce.sel.shape[1],
+                                ce.select_spans, ce.cat_ordinal_at)
+    k_rows = predict_paths._cat_block_rows(n_features, cat)
+    return cat, np.concatenate([[0], np.cumsum(k_rows)])
+
+
+def _scores(ce, Xb, use_pallas):
+    """The scoring program over compiled tables: the Pallas kernel
+    (interpreted) or its jax.numpy twin."""
+    from ddt_tpu.ops import predict as predict_ops
+
+    sel, planes, paths, expand, bins = ce.arrays()
+    return np.asarray(predict_ops.predict_raw_effective_paths(
+        sel, planes, paths, Xb, ce.learning_rate, ce.base_score,
+        use_pallas=use_pallas, missing_routes=ce.missing_bin_value >= 0,
+        select_spans=ce.select_spans, cat_expand=expand, cat_bins=bins,
+        cat_ordinal_at=ce.cat_ordinal_at))
+
+
+def _held_to_the_dense_build(ens, Xb, monkeypatch):
+    """What every model with sets must keep under its spans: every weight
+    tile the kernel skips is all zeros in `sel`, the kernel's scores are
+    the dense build's BITS (leaf values of any float32), and kernel and
+    twin agree with the reference's walk."""
+    ce, dense = _compiled_twice(ens, monkeypatch)
+    cat, first = _cat_sets(ce, ens.n_features)
+    for j, (start, stop) in enumerate(ce.select_spans):
+        tile = ce.sel[:, :, 128 * j:128 * (j + 1)].astype(np.float32)
+        assert not tile[:, :first[start]].any(), (j, "below its span")
+        assert not tile[:, first[stop]:].any(), (j, "past its span")
+    # the leaf lanes and every table's shape are the dense build's
+    for a, b in zip(ce.arrays(), dense.arrays()):
+        assert a.shape == b.shape and a.dtype == b.dtype
+    np.testing.assert_array_equal(ce.planes[:, 1:3], dense.planes[:, 1:3])
+    got = _scores(ce, Xb, True)
+    np.testing.assert_array_equal(got, _scores(dense, Xb, True))
+    want = numpy_predict.predict_raw_node_list(ens, Xb)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+    np.testing.assert_allclose(_scores(ce, Xb, False), want, rtol=0,
+                               atol=2e-5)
+    return ce, cat
+
+
+def _float_leaves(seed, n_trees, n_leaves, columns, n_features,
+                  missing=False):
+    """`category_ensemble` with N(0, 1) leaf values: sums that ROUND, so
+    that equal scores say the float32 adds kept their order."""
+    return random_node_list(
+        np.random.default_rng(seed), n_trees, n_leaves, n_features,
+        missing=missing, max_set=32,
+        categories=tuple((c, min(k, 255)) for c, k in columns),
+        learning_rate=0.1, base_score=0.25, loss="logloss")
+
+
+@pytest.mark.parametrize("missing", [False, True], ids=["plain", "nan"])
+def test_set_spans_at_the_cells_shape(missing, monkeypatch):
+    """The Allstate cell's shape (six one-hot K-blocks beside the ordinal
+    one, 255 leaves): the ordinal block read by BOTH lane tiles and each
+    one-hot block by ONE, 8 select tiles where 14, and the plan says 12 /
+    7 / 8 / 7."""
+    from ddt_tpu.ops import predict_paths
+
+    ens = _float_leaves(54, 6, 255, ALLSTATE_COLUMNS, 32, missing)
+    Xb = category_rows(55, 600, ALLSTATE_COLUMNS, 32)
+    ce, cat = _held_to_the_dense_build(ens, Xb, monkeypatch)
+    assert len(ce.select_spans) == 2
+    assert sum(stop - start for start, stop in ce.select_spans) == 8
+    # the ordinal K-block (behind `cat_ordinal_at` one-hot ones) in both
+    assert all(start <= ce.cat_ordinal_at < stop
+               for start, stop in ce.select_spans)
+    assert ce.sel.shape[1] == 32 + 768 and ce.cat_blocks == 6
+    plan = predict_paths.path_plan(500, ce.lanes, 32, cat=cat)
+    assert (plan.path_mxu_tiles_per_tree, plan.catset_mxu_tiles_per_tree,
+            plan.select_mxu_tiles, plan.select_k_blocks) == (12, 7, 8, 7)
+    assert plan.trees_per_step == predict_paths.path_plan(
+        500, ce.lanes, 32, cat=cat._replace(spans=())).trees_per_step
+
+
+@pytest.mark.parametrize("columns,n_features,n_leaves,tiles", [
+    # one column's sets beside ONE ordinal column: either component passes
+    # 128 nodes in some tree, so both tiles read both: dense
+    (((0, 100),), 2, 255, None),
+    # every node a set of one of two columns, a block each: no tile can
+    # hold a column's nodes alone in every tree: dense
+    (((0, 100), (1, 120)), 2, 255, None),
+    # ... of four columns, a block each (63.5 nodes a tree each): any two
+    # pass 128 together in some tree, so a tile holds ONE alone and the
+    # other two blocks are read by both: 6 where 8
+    (((0, 100), (1, 120), (2, 90), (3, 128)), 4, 255, 6),
+    # all sets and no ordinal K row (six one-hot blocks, the two of each
+    # wide column tied): 8 where 12
+    (CAT_COLUMNS, 6, 255, 8),
+    # three ordinal columns beside three columns' sets
+    (CAT_COLUMNS[1:4], 6, 255, None),
+], ids=["ordinal-and-one", "two-blocks", "four-blocks", "all-sets",
+        "mixed"])
+def test_set_spans_fit_every_tree_or_stay_dense(columns, n_features,
+                                                n_leaves, tiles,
+                                                monkeypatch):
+    """A component whose nodes pass 128 in some tree is read by both
+    tiles; where no assignment under the dense count fits every tree the
+    spans are the dense ones; the scores agree either way."""
+    ens = _float_leaves(61, 8, n_leaves, columns, n_features)
+    Xb = category_rows(62, 400, columns, n_features)
+    ce, cat = _held_to_the_dense_build(ens, Xb, monkeypatch)
+    if tiles is None:
+        assert ce.select_spans == () and ce.cat_ordinal_at == 0
+    else:
+        assert sum(stop - start for start, stop in ce.select_spans) == tiles
+    # every tree fits: a lane tile's own nodes are at most its 128 lanes
+    # (the build would have raised on a lane past W)
+    assert ce.lanes == 256
+
+
+def test_a_set_over_two_blocks_lies_in_one_tiles_span(monkeypatch):
+    """A column of more than 128 named ids takes two one-hot blocks, and a
+    set that names ids of both ties them: the node's lane tile reads
+    both."""
+    columns = ((2, 200), (4, 40))
+    ens = _float_leaves(63, 8, 255, columns, 6)
+    ce, cat = _held_to_the_dense_build(
+        ens, category_rows(64, 400, columns, 6), monkeypatch)
+    _, first = _cat_sets(ce, 6)
+    sel = ce.sel.astype(np.float32)
+    # the K-blocks every node lane reads
+    reads = np.stack([sel[:, first[k]:first[k + 1]].any(axis=1)
+                      for k in range(len(first) - 1)], axis=-1)  # [T, W, K]
+    two = reads.sum(axis=-1) == 2
+    assert two.any() and ce.select_spans
+    for j, (start, stop) in enumerate(ce.select_spans):
+        blocks = np.nonzero(reads[:, 128 * j:128 * (j + 1)][
+            two[:, 128 * j:128 * (j + 1)]])[1]
+        assert ((blocks >= start) & (blocks < stop)).all()
+    # ... and the tile the pair is NOT in skips both: fewer than dense
+    assert sum(stop - start for start, stop in ce.select_spans) < 2 * (
+        len(first) - 1)
+
+
+@pytest.mark.parametrize("n_leaves,lanes", [(63, 128), (300, 384),
+                                            (400, 512)])
+def test_set_spans_of_another_width_are_dense(n_leaves, lanes, monkeypatch):
+    """One lane tile has nothing to skip, three and four are not searched:
+    the tables are the dense build's bit for bit, and so is the program
+    (its static facts are `select_spans` () and `cat_ordinal_at` 0)."""
+    ens = category_ensemble(65, n_trees=4, n_leaves=n_leaves,
+                            columns=ALLSTATE_COLUMNS, n_features=32)
+    ce, dense = _compiled_twice(ens, monkeypatch)
+    assert ce.lanes == lanes
+    assert ce.select_spans == () and ce.cat_ordinal_at == 0
+    for a, b in zip(ce.arrays(), dense.arrays()):
+        np.testing.assert_array_equal(a.astype(np.float32),
+                                      b.astype(np.float32))
+
+
+def test_set_spans_are_read_from_the_model_alone():
+    """Two builds of one model: one `select_spans`, the same tables."""
+    ens = category_ensemble(66, n_trees=5, n_leaves=255,
+                            columns=ALLSTATE_COLUMNS, n_features=32)
+    a, b = ens.compile(), dataclasses.replace(ens).compile()
+    assert a.select_spans == b.select_spans != ()
+    assert a.cat_ordinal_at == b.cat_ordinal_at
+    for x, y in zip(a.arrays(), b.arrays()):
+        np.testing.assert_array_equal(x.astype(np.float32),
+                                      y.astype(np.float32))
+
+
+@pytest.mark.parametrize("counts,blocks,lanes,want", [
+    # the cell's picture: the ordinal component past 128 in some tree, so
+    # in both tiles; the four others one tile each, the first listed of
+    # the most balanced
+    ([[130, 20, 30, 40, 34], [100, 60, 10, 70, 14]], [1, 2, 2, 1, 1], 256,
+     [2, 0, 1, 1, 0]),
+    # everything fits one tile: a tile still reads SOME block, and of the
+    # equal counts the least full
+    ([[40, 50, 30]], [1, 1, 1], 256, [0, 1, 0]),
+    # two components that both pass 128 somewhere: dense
+    ([[130, 100], [100, 130]], [1, 1], 256, None),
+    # one component: nothing to split
+    ([[200]], [3], 256, None),
+    # nine components: past what is searched whole
+    ([[10] * 9], [1] * 9, 256, None),
+    # another width
+    ([[30, 30]], [1, 1], 128, None),
+    ([[130, 30]], [1, 1], 512, None),
+    # the FEWEST tiles first: the 3-block component in ONE tile (5 tiles,
+    # the fuller tile 120 lanes) although both reading it would leave the
+    # tiles less full (8 tiles, 100 lanes)
+    ([[20, 100, 100]], [3, 1, 1], 256, [0, 0, 1]),
+], ids=["cell", "one-tile", "both-wide", "one-component", "nine", "w128",
+        "w512", "fewest-tiles"])
+def test_choose_set_spans_on_a_table(counts, blocks, lanes, want):
+    from ddt_tpu.models import tree
+
+    got = tree.choose_set_spans(np.array(counts), np.array(blocks), lanes)
+    if want is None:
+        assert got is None
+    else:
+        assert got.tolist() == want

@@ -497,6 +497,42 @@ def test_a_node_list_with_category_sets_says_so(impl, categories, missing,
     assert built["select_nodes_per_lane"] == 1
 
 
+@pytest.mark.parametrize("impl", ["pallas", "onehot"])
+def test_the_set_tests_spans_are_on_the_spans(impl):
+    """PR 56: an uncut tree of two lane tiles with category sets whose
+    K-blocks split (the Allstate cell's columns) says the tiles its SPANS
+    ask: `select_mxu_tiles` 8 where 14, `path_mxu_tiles_per_tree` 12,
+    `catset_mxu_tiles_per_tree` 7, `select_k_blocks` 7 as before; the root
+    of every call repeats them."""
+    from ddt_tpu.models.tree import random_node_list
+
+    categories = ((3, 75), (4, 254), (5, 254), (6, 10), (7, 3), (8, 6),
+                  (9, 3), (10, 3), (11, 6), (12, 4), (13, 4), (14, 2),
+                  (15, 3), (16, 11), (17, 11), (27, 15))
+    rng = np.random.default_rng(83)
+    ens = random_node_list(rng, 3, 255, 32, n_bins=255, dyadic=True,
+                           categories=categories, max_set=32,
+                           learning_rate=0.5, base_score=0.0, loss="logloss")
+    assert ens.compile().select_spans == ((0, 4), (3, 7))
+    be = get_backend(TrainConfig(backend="tpu", n_bins=255,
+                                 predict_impl=impl))
+    Xb = rng.integers(0, 255, size=(300, 32), dtype=np.uint8)
+    for c, k in categories:
+        Xb[:, c] = rng.integers(0, k + 2, 300)
+    said = dict(select_k_blocks=7, path_mxu_tiles_per_tree=12,
+                catset_mxu_tiles_per_tree=7, select_mxu_tiles=8,
+                resolve_mxu_tiles=4, select_nodes_per_lane=1,
+                category_sets=1)
+    for call in range(2):       # the second from the model cache
+        scores = be.predict_raw(ens, Xb)
+        root = an.root_spans("predict")[-1]["counts"]
+        assert {k: root[k] for k in said} == said
+    np.testing.assert_array_equal(scores, ens.predict_raw(Xb, binned=True))
+    built = [sp for sp in an.recent_spans()
+             if sp["name"] == "ddt:predict:ensemble"][-1]["counts"]
+    assert {k: built[k] for k in said} == said
+
+
 @pytest.mark.parametrize("n_features,k_blocks", [(6, 1), (129, 2), (300, 3)])
 def test_a_nan_routed_node_list_says_so(n_features, k_blocks):
     """Learned NaN directions and the width of the select on the spans:

@@ -211,15 +211,18 @@ def test_path_kernel_takes_the_select_the_rule_packs(case):
 # CATEGORY SETS (PR 55; scripts/tpu_aot_check.py `paths-cat/`): (one-hot
 # K-blocks, K rows of the select, `select_k_blocks`, MXU weight tiles a tree,
 # what the set test adds beside an ordinal model of the shape, trees a
-# block): the Allstate cell's shape, sets and ordinal nodes in one tree over
-# 32 columns (the ordinal K-block and six one-hot ones: 7 x 2 lane tiles +
-# 4), eight columns ALL categorical (no ordinal K row: 5 blocks x 2 lane
-# tiles + 4) and sets beside ordinal nodes with NaN directions at Bosch's
-# 968 columns (8 + 2 blocks).
+# block, the select's spans and the one-hot blocks before the ordinal rows:
+# PR 56): the Allstate cell's shape, sets and ordinal nodes in one tree over
+# 32 columns (the ordinal K-block and six one-hot ones) under the spans the
+# build finds for that cell's model (the ordinal block in both lane tiles,
+# three one-hot blocks in each: 8 + 4, where 7 x 2 lane tiles + 4 under
+# dense spans), eight columns ALL categorical (no ordinal K row: 5 blocks x
+# 2 lane tiles + 4, dense) and sets beside ordinal nodes with NaN directions
+# at Bosch's 968 columns (8 + 2 blocks, dense).
 CAT_CASES = {
-    "paths-cat/32f/500x255": (6, 32 + 768, 7, 18, 13, 20),
-    "paths-cat/8f/500x255": (5, 640, 5, 14, 9, 20),
-    "paths-cat/968f/20x255leaves/nan": (2, 976 + 256, 10, 24, 4, 5),
+    "paths-cat/32f/500x255": (6, 32 + 768, 7, 12, 7, 20, ((0, 4), (3, 7)), 3),
+    "paths-cat/8f/500x255": (5, 640, 5, 14, 9, 20, (), 0),
+    "paths-cat/968f/20x255leaves/nan": (2, 976 + 256, 10, 24, 4, 5, (), 0),
 }
 
 
@@ -239,7 +242,8 @@ def test_category_set_kernel_takes_the_planned_step(case):
     the kernel asks of the MXU."""
     from ddt_tpu.ops import predict_paths
 
-    blocks, k_rows, k_blocks, tiles, extra, per_step = CAT_CASES[case.name]
+    blocks, k_rows, k_blocks, tiles, extra, per_step, spans, ordinal_at = \
+        CAT_CASES[case.name]
     exported, shapes = _export_for_tpu(case)
     (trees, sel_rows, sel_lanes), _ = shapes[0]
     (_, _, lanes), _ = shapes[1]
@@ -249,13 +253,18 @@ def test_category_set_kernel_takes_the_planned_step(case):
     assert (sel_rows, sel_lanes) == (k_rows, lanes)
     assert shapes[3][0] == (blocks, fp, 128) and shapes[4][0] == (
         blocks, 8, 128)
-    cat = predict_paths.CatSets(blocks, sel_rows)
+    cat = predict_paths.CatSets(blocks, sel_rows, spans, ordinal_at)
     assert cat.ordinal_rows in (0, fp)
     plan = predict_paths.path_plan(trees, lanes, features, cat=cat)
     assert (plan.select_k_blocks, plan.path_mxu_tiles_per_tree,
             plan.catset_mxu_tiles_per_tree, plan.trees_per_step,
             plan.select_nodes_per_lane, plan.category_sets) == (
         k_blocks, tiles, extra, per_step, 1, 1)
+    # the select's share of the tiles: its spans' K-blocks, or every block
+    # a lane tile
+    assert plan.select_mxu_tiles == tiles - plan.resolve_mxu_tiles == (
+        sum(stop - start for start, stop in spans)
+        or k_blocks * (lanes // 128))
     text = exported.mlir_module()
     call, = [ln for ln in text.splitlines()
              if "@tpu_custom_call" in ln and "_paths_kernel" in ln]
@@ -268,6 +277,33 @@ def test_category_set_kernel_takes_the_planned_step(case):
         assert f"tensor<{rows}x{features}{held}" not in text
     # the one-hot is the kernel's, in VMEM: no stage of XLA's makes it
     assert "predict:catset" not in text and "predict:widen" not in text
+
+
+@pytest.mark.parametrize("features,categories,k_blocks", [
+    (aot.ALLSTATE["features"], aot.ALLSTATE["categories"], 7),
+    (aot.ALL_SETS["features"], aot.ALL_SETS["categories"], 5),
+], ids=["32f", "8f"])
+def test_dense_set_spans_trace_the_program_of_no_spans(features, categories,
+                                                       k_blocks):
+    """A model with sets whose blocks do not split gets the DENSE spans,
+    and those (given as (), or spelled out a lane tile) trace one program:
+    what the kernel traced before it read spans; spans that split trace
+    another."""
+    def text(**spans):
+        """The kernel's Mosaic module without debug locations, which is
+        what `tpu_aot_check.py --digest` hashes."""
+        case = aot.KernelCase("cat", True, aot._paths_case(
+            4_999, features, 12, 255, categories=categories, **spans))
+        mosaic = []
+        with aot._mosaic_modules(mosaic):
+            _export_for_tpu(case)
+        kernel, = mosaic
+        return kernel
+
+    dense = text()
+    assert text(cat_spans=((0, k_blocks),) * 2) == dense
+    if k_blocks == 7:
+        assert text(cat_spans=((0, 4), (3, 7)), cat_ordinal_at=3) != dense
 
 
 # The exits' table of every forest case (PR 49): (its lanes, the class
