@@ -370,7 +370,9 @@ def predict(
     "softmax": an XGBoost or LightGBM multiclass model too deep for the
     heap, `models/xgboost_io.from_xgboost_json`) answers float32
     `[rows, classes]` class probabilities, the softmax taken by the
-    device's own program (`raw`: the margins)."""
+    device's own program (`raw`: the margins); so does an oblivious
+    ensemble of vector leaves (CatBoost's `MultiClass`,
+    `models/catboost_io.from_catboost_json`)."""
     if n_partitions is not None and n_partitions > 1 \
             and backend is None and cfg is None:
         backend = _row_mesh_backend(n_partitions)
@@ -397,8 +399,9 @@ def predict(
         )
     if backend is not None and binned:
         if not raw and backend.links_on_device(ens):
-            # softmax's round-major trees as a node list: the program ends
-            # in the link, on the device (stage `predict:link`)
+            # softmax's round-major trees as a node list, or an oblivious
+            # ensemble's vector leaves: the program ends in the link, on
+            # the device (stage `predict:link`)
             return backend.predict_raw(ens, X, link=True)
         out = backend.predict_raw(ens, X)
         if raw:

@@ -44,7 +44,8 @@ import numpy as np
 from ddt_tpu.backends.base import DeviceBackend, HostTree
 from ddt_tpu.config import TrainConfig
 from ddt_tpu.models.tree import (CompiledNodeList, CompiledOblivious,
-                                 NodeListEnsemble, TreeEnsemble)
+                                 NodeListEnsemble, ObliviousEnsemble,
+                                 TreeEnsemble)
 from ddt_tpu.ops import grad as grad_ops
 from ddt_tpu.ops import grow as grow_ops
 from ddt_tpu.ops import histogram as hist_ops
@@ -1607,10 +1608,12 @@ class TPUDevice(DeviceBackend):
         """Whether `predict_raw(..., link=True)` answers this model's
         probabilities: the link function taken by the scoring program, on
         the device, under the stage `predict:link`. A node list of
-        softmax's round-major trees (its [rows, C] margins are on the
-        device as the program ends); every other model's link is the
-        caller's (`utils/metrics.predict_proba_np`, api.predict)."""
-        return isinstance(ens, NodeListEnsemble) and ens.loss == "softmax"
+        softmax's round-major trees and an oblivious ensemble of vector
+        leaves (their [rows, C] margins are on the device as the program
+        ends); every other model's link is the caller's
+        (`utils/metrics.predict_proba_np`, api.predict)."""
+        return isinstance(ens, (NodeListEnsemble, ObliviousEnsemble)) \
+            and ens.loss == "softmax"
 
     def predict_raw(self, ens: TreeEnsemble, Xb: np.ndarray,
                     compiled=None, link: bool = False) -> np.ndarray:
@@ -1980,7 +1983,7 @@ class TPUDevice(DeviceBackend):
         if isinstance(ce, CompiledNodeList):
             return self._build_paths_fn(ens, ce, link)
         if isinstance(ce, CompiledOblivious):
-            return self._build_oblivious_fn(ens, ce)
+            return self._build_oblivious_fn(ens, ce, link)
         impl_req = self.cfg.predict_impl
         lut = None
         resolved = "f32"
@@ -2160,12 +2163,15 @@ class TPUDevice(DeviceBackend):
                                   2 if ce.mean or classes > 1 else 1),
                 ens_dev, "f32", classes, plan)
 
-    def _build_oblivious_fn(self, ens, ce: CompiledOblivious):
+    def _build_oblivious_fn(self, ens, ce: CompiledOblivious,
+                            link: bool = False):
         """_build_predict_fn for an OBLIVIOUS ensemble: its group tables up,
         and the oblivious scoring program (ops/predict.predict_raw_
         effective_oblivious: the Pallas kernel where the dispatch rule
         takes it, else the jax.numpy form). The plan is an
-        ops/predict_oblivious.ObliviousPlan."""
+        ops/predict_oblivious.ObliviousPlan. `link`: the program ends in
+        the model's link function (vector leaves' softmax:
+        `links_on_device`)."""
         from ddt_tpu.ops import predict_oblivious
 
         if self.cfg.predict_impl in ("lut", "lut4"):
@@ -2173,12 +2179,14 @@ class TPUDevice(DeviceBackend):
                 "predict_impl=%r: the quantized tiers have no oblivious "
                 "form; the f32 oblivious form serves", self.cfg.predict_impl)
         use_pallas = self._use_pallas
+        classes = ce.n_classes_out
         plan = predict_oblivious.oblivious_plan(
             ce.n_trees, ce.depth, ens.n_features,
             served=predict_ops.resolve_use_pallas(
-                use_pallas, True, 0, ens.n_features, 1,
+                use_pallas, True, 0, ens.n_features, classes,
                 oblivious_depth=ce.depth),
-            row_dtype=self.PREDICT_ROW_DTYPE)
+            row_dtype=self.PREDICT_ROW_DTYPE, n_cls=classes,
+            link=ce.loss if link else "none")
         with phase_span("predict:ensemble:pack") as sp:
             tables = ce.arrays()
             sp.counts["bytes"] = sum(a.nbytes for a in tables)
@@ -2186,17 +2194,18 @@ class TPUDevice(DeviceBackend):
         # Bound here: fn0 outlives this call in the stage registry, and
         # must not hold the host copy of the tables (197 MB at 8000 trees
         # x 2000 columns).
-        scale, bias = ce.scale, ce.bias
+        static = dict(scale=ce.scale, bias=ce.bias, use_pallas=use_pallas,
+                      **({"link": plan.link} if link else {}))
 
         def fn0(sel, thr, leaf, Xc,
                 entry=predict_ops.predict_raw_effective_oblivious):
-            return entry(sel, thr, leaf, Xc, scale=scale, bias=bias,
-                         use_pallas=use_pallas)
+            return entry(sel, thr, leaf, Xc, **static)
 
         self._stage_scoring_program(
             predict_ops.predict_raw_effective_oblivious, fn0, ens_dev,
             ens.n_features)
-        return self._row_sharded(fn0, 3, 1), ens_dev, "f32", 1, plan
+        return (self._row_sharded(fn0, 3, classes), ens_dev, "f32", classes,
+                plan)
 
     def _put_tables(self, tables) -> tuple:
         """A model's tables up, replicated, one at a time (`tables` may

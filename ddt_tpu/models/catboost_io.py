@@ -11,11 +11,19 @@ takes it):
     oblivious_trees[t].splits[d]    {"split_type": "FloatFeature",
                                      "float_feature_index": i, "border": b}
                                     d = 0 is the LOW bit of the leaf index
-    oblivious_trees[t].leaf_values  2^len(splits) values, leaf i at index i
+    oblivious_trees[t].leaf_values  2^len(splits) values, leaf i at index i;
+                                    of C classes (`MultiClass`) 2^len(splits)
+                                    x C, LEAF-major, the class innermost:
+                                    leaf i's value for class c at i C + c
+                                    (`leaf_weights` stays 2^len(splits))
     features_info.float_features[i] {"feature_index", "flat_feature_index",
                                      "borders": ascending, "has_nans",
                                      "nan_value_treatment"}
-    scale_and_bias                  [scale, bias] or [scale, [bias]]
+    scale_and_bias                  [scale, bias] or [scale, [bias]]; of C
+                                    classes [scale, [bias_0 .. bias_{C-1}]]
+    model_info.params.loss_function.type   the objective, where the key is
+                                    there: Logloss, CrossEntropy, RMSE,
+                                    MultiClass (the `loss` argument wins)
 
 A split is `x > border`, and `border` is one of the feature's `borders`: its
 RANK there is the split's bin, and a row's bin is the number of the
@@ -30,7 +38,9 @@ What is REFUSED, by name (`_refuse`): a model the oblivious layout and its
 kernel have no field for. Scoring such a model needs work this system does
 not do yet (ROADMAP.md): category statistics looked up from a hash table at
 scoring time (CTR features), one-hot category splits, text and embedding
-features, vector leaves (several output dimensions), trees that are not
+features, `MultiClassOneVsAll` (vector leaves under a sigmoid a class, where
+`MultiClass`'s are under one softmax), vector leaves whose width is not
+every tree's and the bias's own, trees that are not
 symmetric (`grow_policy` Depthwise / Lossguide export `trees`, not
 `oblivious_trees`), and a feature that saw NaN in training and sends it to
 a side of its own (`nan_value_treatment` AsFalse / AsTrue: a learned NaN
@@ -55,14 +65,35 @@ def _refuse(has: bool, what: str) -> None:
             "kernel do not support yet")
 
 
-def from_catboost_json(model: "str | dict", loss: str = "logloss",
+# The library's objectives as this system names them (`ObliviousEnsemble.
+# loss`): what `model_info.params.loss_function.type` may say.
+_OBJECTIVES = {"Logloss": "logloss", "CrossEntropy": "logloss",
+               "RMSE": "mse", "MultiClass": "softmax"}
+
+
+def _objective(m: dict) -> "str | None":
+    """`model_info.params.loss_function.type`, where the export carries it
+    (`params` may be the library's JSON text of itself)."""
+    params = (m.get("model_info") or {}).get("params") or {}
+    if isinstance(params, str):
+        params = json.loads(params)
+    return (params.get("loss_function") or {}).get("type")
+
+
+def from_catboost_json(model: "str | dict", loss: str | None = None,
                        n_bins: int | None = None) -> ObliviousEnsemble:
     """An ObliviousEnsemble from CatBoost's JSON export (its text, or the
-    parsed dict). `loss`: the model's objective as this system names it
-    (the JSON's `model_info` is free text). `n_bins`: the bins the rows
-    will be binned into, the widest border list + 1 by default."""
+    parsed dict). `loss`: the model's objective as this system names it;
+    by default what `model_info` says (`_OBJECTIVES`), and where it says
+    nothing "softmax" for vector leaves and "logloss" otherwise. `n_bins`:
+    the bins the rows will be binned into, the widest border list + 1 by
+    default."""
     m = json.loads(model) if isinstance(model, str) else model
     info = m.get("features_info", {})
+    objective = _objective(m)
+    _refuse(objective == "MultiClassOneVsAll",
+            "the objective MultiClassOneVsAll (a sigmoid a class over its "
+            "vector leaves, where MultiClass takes one softmax)")
     _refuse("oblivious_trees" not in m and "trees" in m,
             "non-symmetric trees (grow_policy Depthwise or Lossguide)")
     _refuse(bool(info.get("categorical_features")) or bool(info.get("ctrs"))
@@ -100,12 +131,20 @@ def from_catboost_json(model: "str | dict", loss: str = "logloss",
     feat = np.zeros((T, depth), np.int32)
     rank = np.full((T, depth), OBLIVIOUS_NEVER, np.int32)
     raw = np.full((T, depth), np.inf, np.float32)
-    leaves = np.zeros((T, 1 << depth), np.float32)
+    scale, bias = m.get("scale_and_bias", [1.0, 0.0])
+    bias = np.atleast_1d(np.asarray(bias, np.float64))
+    C = len(bias)      # the width of a leaf: every tree's, and the bias's
+    leaves = np.zeros((T, 1 << depth, C), np.float32)
     for t, tree in enumerate(trees):
         splits = tree["splits"]
-        _refuse(len(tree["leaf_values"]) != 1 << len(splits),
-                f"vector leaves (tree {t}: {len(tree['leaf_values'])} leaf "
-                f"values for {len(splits)} splits)")
+        n_values = len(tree["leaf_values"])
+        _refuse(n_values % (1 << len(splits)) != 0,
+                f"a leaf_values list that is no multiple of 2^splits (tree "
+                f"{t}: {n_values} values for {len(splits)} splits)")
+        _refuse(n_values != C << len(splits),
+                f"vector leaves of a width that is not the bias's (tree {t}: "
+                f"{n_values} leaf values for {len(splits)} splits, "
+                f"{n_values >> len(splits)} a leaf; a bias of {C} values)")
         for d, sp in enumerate(splits):
             kind = sp.get("split_type", "FloatFeature")
             _refuse(kind != "FloatFeature",
@@ -121,17 +160,20 @@ def from_catboost_json(model: "str | dict", loss: str = "logloss",
                     f"from_catboost_json: tree {t} splits float feature "
                     f"{i} at {b!r}, which is none of its borders")
             feat[t, d], rank[t, d], raw[t, d] = i, k, b
-        leaves[t, :len(tree["leaf_values"])] = tree["leaf_values"]
+        # leaf-major, the class innermost: [2^splits, C]
+        leaves[t, :1 << len(splits)] = np.reshape(tree["leaf_values"],
+                                                  (-1, C))
 
-    scale, bias = m.get("scale_and_bias", [1.0, 0.0])
-    bias = np.atleast_1d(np.asarray(bias, np.float64))
-    _refuse(len(bias) != 1, f"vector leaves (a bias of {len(bias)} values)")
+    if loss is None:
+        loss = _OBJECTIVES.get(objective, "softmax" if C > 1 else "logloss")
     edges = np.full((len(borders), n_bins - 1), np.inf, np.float32)
     for i, b in enumerate(borders):
         edges[i, :len(b)] = b
     return ObliviousEnsemble(
-        split_feature=feat, split_bin=rank, leaf_value=leaves,
-        n_features=len(borders), scale=float(scale), bias=float(bias[0]),
+        split_feature=feat, split_bin=rank,
+        leaf_value=leaves if C > 1 else leaves[:, :, 0],
+        n_features=len(borders), scale=float(scale),
+        bias=bias if C > 1 else float(bias[0]),
         loss=loss, n_bins=n_bins, split_raw=raw, borders=edges)
 
 
@@ -155,7 +197,9 @@ def to_catboost_json(ens: ObliviousEnsemble) -> str:
                 "border": float(ens.borders[ens.split_feature[t, d],
                                             ens.split_bin[t, d]]),
             } for d in range(d_t)],
-            "leaf_values": [float(v) for v in ens.leaf_value[t, :1 << d_t]],
+            # (vector leaves: leaf-major, the class innermost)
+            "leaf_values": [float(v) for v in
+                            ens.leaf_value[t, :1 << d_t].reshape(-1)],
         })
     floats = [{
         "feature_index": i, "flat_feature_index": i,
@@ -165,5 +209,8 @@ def to_catboost_json(ens: ObliviousEnsemble) -> str:
     return json.dumps({
         "oblivious_trees": trees,
         "features_info": {"float_features": floats},
-        "scale_and_bias": [float(ens.scale), [float(ens.bias)]],
+        "scale_and_bias": [float(ens.scale),
+                           np.atleast_1d(ens.bias).tolist()],
+        "model_info": {"params": {"loss_function": {"type": next(
+            k for k, v in _OBJECTIVES.items() if v == ens.loss)}}},
     })

@@ -2579,29 +2579,41 @@ class ObliviousEnsemble:
 
         score(x) = scale * sum_t leaf_value[t, idx_t(x)] + bias.
 
+    VECTOR LEAVES (the library's `MultiClass` objective: `loss` "softmax",
+    `leaf_value` [T, 2^D, C], `bias` [C]): a leaf holds one value a class,
+
+        m_c(x) = scale * sum_t leaf_value[t, idx_t(x), c] + bias[c]
+
+    the margins [rows, C], and the answer their softmax. One index a (row,
+    tree) whatever C: the splits are the tree's, not a class's.
+
     `bin > split_bin` sets the bit where the heap's `bin <= threshold_bin`
-    goes left: the repository's one split rule. No missing-value route, no
-    category split and one output column: the import
-    (`models/catboost_io.py`) refuses a model that needs another by name.
+    goes left: the repository's one split rule. No missing-value route and
+    no category split: the import (`models/catboost_io.py`) refuses a model
+    that needs another by name.
     A tree shallower than D carries never-true splits in its HIGH bits
     (`OBLIVIOUS_NEVER`, raw +inf) and zeros in the leaves they would reach.
     The trainer writes heaps; an oblivious ensemble is an import or
     hand-built. `to_heap` / `to_node_list` expand it for the tests' sake
-    (63 nodes where this layout holds 6 splits): no scoring path does."""
+    (63 nodes where this layout holds 6 splits, and C heap trees for a tree
+    of vector leaves): no scoring path does."""
 
     split_feature: np.ndarray  # int32  [T, D]
     split_bin: np.ndarray      # int32  [T, D] bit d set where bin > this
-    leaf_value: np.ndarray     # float32 [T, 2^D]
+    leaf_value: np.ndarray     # float32 [T, 2^D]; vector leaves [T, 2^D, C]
     n_features: int
     scale: float = 1.0
-    bias: float = 0.0
-    loss: str = "logloss"      # logloss | mse
+    bias: "float | np.ndarray" = 0.0   # vector leaves: float64 [C]
+    loss: str = "logloss"      # logloss | mse | softmax (vector leaves)
     n_bins: int = 0
     split_raw: np.ndarray | None = None    # float32 [T, D] raw borders
     # float32 [F, n_bins - 1], +inf past a feature's last border: the
     # model's own border lists, whose ranks `split_bin` holds (an import's;
     # `bin_mapper` bins raw rows by them).
     borders: np.ndarray | None = None
+    # The classes C of vector leaves (0: read from `leaf_value`); 2 for the
+    # one-column losses, as the other layouts answer.
+    n_classes: int = 0
 
     # What the other layouts answer for, so that scoring entry points ask
     # one question of any of the three.
@@ -2609,25 +2621,41 @@ class ObliviousEnsemble:
     cat_features = None
     missing_bin = False
     default_left = None
-    n_classes = 2
     has_bin_thresholds = True
 
     def __post_init__(self):
         T, D = self.split_feature.shape
-        if self.loss == "softmax":
-            raise ValueError(
-                "an oblivious ensemble scores ONE output column: vector "
-                "leaves (several classes) are not supported in this layout")
-        if self.split_bin.shape != (T, D) or self.leaf_value.shape != (
-                T, 1 << D) or D < 1:
+        vector = self.loss == "softmax"
+        C = self.leaf_value.shape[2] if self.leaf_value.ndim == 3 else 0
+        leaves = (T, 1 << D) + (C,) * vector
+        if (self.split_bin.shape != (T, D) or D < 1
+                or self.leaf_value.shape != leaves
+                or vector and (C < 2 or self.n_classes not in (0, C))):
             raise ValueError(
                 f"an oblivious ensemble of {T} trees x depth {D} needs "
-                f"split_bin [{T}, {D}] and leaf_value [{T}, {1 << D}], got "
-                f"{self.split_bin.shape} and {self.leaf_value.shape}")
+                f"split_bin [{T}, {D}] and leaf_value [{T}, {1 << D}] (loss "
+                f"\"softmax\": vector leaves [{T}, {1 << D}, C] of C >= 2 "
+                f"= n_classes values), got {self.split_bin.shape} and "
+                f"{self.leaf_value.shape} with loss {self.loss!r} and "
+                f"n_classes {self.n_classes}")
+        bias = np.asarray(self.bias, np.float64)
+        if bias.size != 1 and not (vector and bias.shape == (C,)):
+            raise ValueError(
+                "an oblivious ensemble's bias is a scalar"
+                + f" or, of {C} classes, [{C}]" * vector
+                + f", got {bias.shape}")
+        self.bias = (np.broadcast_to(bias.reshape(-1), (C,)).copy() if vector
+                     else float(bias.reshape(-1)[0]))
+        self.n_classes = C if vector else 2
         if T and not (0 <= int(self.split_feature.min())
                       and int(self.split_feature.max()) < self.n_features):
             raise ValueError("a split's feature lies outside 0 .. "
                              f"{self.n_features - 1}")
+
+    @property
+    def leaf_columns(self) -> int:
+        """Values a leaf holds: C of vector leaves, else 1."""
+        return self.n_classes if self.loss == "softmax" else 1
 
     @property
     def n_trees(self) -> int:
@@ -2653,8 +2681,10 @@ class ObliviousEnsemble:
         return self.scale
 
     @property
-    def base_score(self) -> float:
-        return self.bias
+    def base_score(self):
+        """The bias as the other layouts name it (what `cli inspect`
+        prints): a float, or a list of C."""
+        return self.bias.tolist() if self.leaf_columns > 1 else self.bias
 
     @property
     def live_splits(self) -> np.ndarray:
@@ -2672,8 +2702,10 @@ class ObliviousEnsemble:
         h = hashlib.sha1(b"oblivious")
         for a in (self.split_feature, self.split_bin, self.leaf_value):
             h.update(np.ascontiguousarray(a).tobytes())
-        h.update(repr((self.scale, self.bias, self.loss,
+        h.update(repr((self.scale, self.base_score, self.loss,
                        self.n_features)).encode())
+        if self.leaf_columns > 1:       # [T, 2^D, C]: C is in the shape alone
+            h.update(repr(self.leaf_value.shape).encode())
         return h.hexdigest()
 
     def compile(self, tree_chunk: int = 64) -> "CompiledOblivious":
@@ -2698,15 +2730,17 @@ class ObliviousEnsemble:
         return idx
 
     def predict_raw(self, X: np.ndarray, binned: bool = False) -> np.ndarray:
-        """Raw (margin) scores [R], float32; the rows in blocks, so that the
-        [T, rows] index of a block stays near 128 MB."""
+        """Raw (margin) scores, float32 [R] ([R, C] of vector leaves); the
+        rows in blocks, so that the [T, rows] index of a block (and the
+        [T, rows, C] values it picks) stays near 128 MB."""
         X = np.asarray(X)
-        block = max(1, (1 << 24) // max(1, self.n_trees))
-        total = np.empty(X.shape[0], np.float32)
+        C = self.leaf_columns
+        block = max(1, (1 << 24) // max(1, self.n_trees * C))
+        total = np.empty((X.shape[0],) + (C,) * (C > 1), np.float32)
+        trees = np.arange(self.n_trees)[:, None]
         for i in range(0, X.shape[0], block):
             leaf = self._leaf_np(X[i:i + block], binned)
-            total[i:i + block] = np.take_along_axis(
-                self.leaf_value, leaf, axis=1).sum(axis=0)
+            total[i:i + block] = self.leaf_value[trees, leaf].sum(axis=0)
         return (self.bias + np.float32(self.scale) * total
                 ).astype(np.float32)
 
@@ -2734,20 +2768,35 @@ class ObliviousEnsemble:
         """The same trees as full heaps of depth D (2^D - 1 nodes where
         this layout holds D splits): a TEST ORACLE, so that the heap
         scorers give a second opinion. The heap's root decides the leaf
-        position's HIGH bit, so level l asks split D - 1 - l."""
+        position's HIGH bit, so level l asks split D - 1 - l. A tree of
+        VECTOR leaves becomes C heap trees, round-major (heap tree t C + c
+        is class c's: the same splits, column c of the leaves), and the
+        bias vector, which a heap has no field for, one more round of
+        trees that are a root leaf each, `bias[c] / scale` (a float32
+        rounding where the scale is not 1)."""
         T, D = self.split_feature.shape
-        ens = empty_ensemble(T, D, self.n_features, self.scale, self.bias,
-                             self.loss, n_bins=self.n_bins)
+        C = self.leaf_columns
+        ens = empty_ensemble(
+            (T + (C > 1)) * C, D, self.n_features, self.scale,
+            self.bias if C == 1 else 0.0, self.loss, n_classes=self.n_classes,
+            n_bins=self.n_bins)
         raw = self.split_raw if self.has_raw_thresholds else np.zeros(
             (T, D), np.float32)
+        # (a tree's splits, once a class: [T, D] -> [T C, D])
+        feature, border, raw = (np.repeat(a, C, axis=0) for a in (
+            self.split_feature, self.split_bin, raw))
         for level in range(D):
             lo, hi = (1 << level) - 1, (1 << (level + 1)) - 1
             d = D - 1 - level
-            ens.feature[:, lo:hi] = self.split_feature[:, d:d + 1]
-            ens.threshold_bin[:, lo:hi] = self.split_bin[:, d:d + 1]
-            ens.threshold_raw[:, lo:hi] = raw[:, d:d + 1]
-        ens.is_leaf[:, (1 << D) - 1:] = True
-        ens.leaf_value[:, (1 << D) - 1:] = self.leaf_value
+            ens.feature[:T * C, lo:hi] = feature[:, d:d + 1]
+            ens.threshold_bin[:T * C, lo:hi] = border[:, d:d + 1]
+            ens.threshold_raw[:T * C, lo:hi] = raw[:, d:d + 1]
+        ens.is_leaf[:T * C, (1 << D) - 1:] = True
+        ens.leaf_value[:T * C, (1 << D) - 1:] = self.leaf_value.reshape(
+            T, 1 << D, C).transpose(0, 2, 1).reshape(T * C, 1 << D)
+        if C > 1:
+            ens.is_leaf[T * C:, 0] = True
+            ens.leaf_value[T * C:, 0] = self.bias / self.scale
         ens.has_raw_thresholds = self.has_raw_thresholds
         return ens
 
@@ -2777,8 +2826,12 @@ class ObliviousEnsemble:
                    if self.has_raw_thresholds else "")
             lines.append(f"bit {d}: f{self.split_feature[t, d]} > bin "
                          f"{self.split_bin[t, d]}{raw}")
-        lines.append("leaves: " + " ".join(
-            f"{v:+.6f}" for v in self.leaf_value[t]))
+        if self.leaf_columns > 1:
+            lines += [f"leaf {i}: " + " ".join(f"{v:+.6f}" for v in vec)
+                      for i, vec in enumerate(self.leaf_value[t])]
+        else:
+            lines.append("leaves: " + " ".join(
+                f"{v:+.6f}" for v in self.leaf_value[t]))
         return "\n".join(lines)
 
     def to_dict(self) -> dict:
@@ -2787,7 +2840,8 @@ class ObliviousEnsemble:
             split_feature=self.split_feature, split_bin=self.split_bin,
             leaf_value=self.leaf_value,
             n_features=np.int64(self.n_features),
-            scale=np.float64(self.scale), bias=np.float64(self.bias),
+            scale=np.float64(self.scale),
+            bias=np.asarray(self.bias, np.float64),     # 0-d, or [C]
             loss=np.bytes_(self.loss.encode()),
             n_bins=np.int64(self.n_bins))
         if self.split_raw is not None:
@@ -2803,7 +2857,8 @@ class ObliviousEnsemble:
             split_bin=np.asarray(d["split_bin"], np.int32),
             leaf_value=np.asarray(d["leaf_value"], np.float32),
             n_features=int(d["n_features"]), scale=float(d["scale"]),
-            bias=float(d["bias"]), loss=bytes(d["loss"]).decode(),
+            bias=np.asarray(d["bias"], np.float64),
+            loss=bytes(d["loss"]).decode(),
             n_bins=int(d["n_bins"]),
             split_raw=(np.asarray(d["split_raw"], np.float32)
                        if "split_raw" in d else None),
@@ -2815,13 +2870,16 @@ class ObliviousEnsemble:
 
 def random_oblivious(rng, n_trees: int, depth: int, n_features: int,
                      n_bins: int = 255, dyadic: bool = False,
-                     **meta) -> ObliviousEnsemble:
+                     n_classes: int = 0, **meta) -> ObliviousEnsemble:
     """A random oblivious ensemble for tests, chip_smoke.py and the compile
     check: features uniform, borders uniform over the ranks 0 .. n_bins-2,
     leaf values N(0, 1), or eighths in -2..2 (`dyadic`: sums of them round
-    nowhere)."""
-    leaves = (rng.integers(-16, 17, (n_trees, 1 << depth)) / 8.0 if dyadic
-              else rng.standard_normal((n_trees, 1 << depth)))
+    nowhere). `n_classes` C >= 2: vector leaves, `loss` "softmax"."""
+    shape = (n_trees, 1 << depth) + ((n_classes,) if n_classes else ())
+    if n_classes:
+        meta = {"loss": "softmax", **meta}
+    leaves = (rng.integers(-16, 17, shape) / 8.0 if dyadic
+              else rng.standard_normal(shape))
     return ObliviousEnsemble(
         split_feature=rng.integers(0, n_features, (n_trees, depth),
                                    dtype=np.int32),
@@ -2842,25 +2900,27 @@ class CompiledOblivious:
                                    tiles of 16)
         thr  [G, Dp, 128]    f32   split d's bin (Dp: D up to whole
                                    sublane tiles of 8)
-        leaf [G, 2^D, 128]   f32   the leaf values, leaf i of a tree in
-                                   row i of its lane
+        leaf [G, C 2^D, 128] f32   the leaf values, leaf i of a tree in
+                                   row i of its lane; of vector leaves
+                                   class c's in rows c 2^D .. (c + 1) 2^D
 
-    A lane past the last tree holds no one-hot, the threshold +BIG (its
-    bits never set) and leaves of 0: it adds 0. Built ONCE per model
-    version on the host; backends keep them device-resident under `token`
-    (the same cache as the other layouts')."""
+    `bias` is a float, or a tuple of C (hashable: the scoring program
+    takes it as a static argument). A lane past the last tree holds no
+    one-hot, the threshold +BIG (its bits never set) and leaves of 0: it
+    adds 0. Built ONCE per model version on the host; backends keep them
+    device-resident under `token` (the same cache as the other
+    layouts')."""
 
     token: str
     scale: float
-    bias: float
+    bias: "float | tuple"
     loss: str
     n_trees: int
     depth: int
     sel: np.ndarray
     thr: np.ndarray
     leaf: np.ndarray
-
-    n_classes_out = 1
+    n_classes_out: int = 1     # C of vector leaves: the answer is [rows, C]
 
     def arrays(self) -> tuple:
         return (self.sel, self.thr, self.leaf)
@@ -2880,14 +2940,20 @@ class CompiledOblivious:
         thr = np.full((G * OBLIVIOUS_GROUP, -(-D // 8) * 8), 2.0 ** 30,
                       np.float32)
         thr[:T, :D] = ens.split_bin
-        leaf = np.pad(ens.leaf_value.astype(np.float32), ((0, pad), (0, 0)))
+        C = ens.leaf_columns
+        leaf = np.pad(ens.leaf_value.astype(np.float32).reshape(T, 1 << D, C),
+                      ((0, pad), (0, 0), (0, 0)))
         return CompiledOblivious(
             token=ens.cache_token(), scale=float(ens.scale),
-            bias=float(ens.bias), loss=ens.loss, n_trees=T, depth=D, sel=sel,
+            bias=float(ens.bias) if C == 1 else tuple(ens.bias.tolist()),
+            loss=ens.loss, n_trees=T, depth=D, sel=sel,
             thr=np.ascontiguousarray(
                 thr.reshape(G, OBLIVIOUS_GROUP, -1).transpose(0, 2, 1)),
+            # [G, lane, leaf, class] -> [G, class, leaf, lane]
             leaf=np.ascontiguousarray(
-                leaf.reshape(G, OBLIVIOUS_GROUP, -1).transpose(0, 2, 1)))
+                leaf.reshape(G, OBLIVIOUS_GROUP, 1 << D, C).transpose(
+                    0, 3, 2, 1)).reshape(G, C << D, OBLIVIOUS_GROUP),
+            n_classes_out=C)
 
 
 def empty_ensemble(

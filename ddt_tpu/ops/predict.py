@@ -113,7 +113,11 @@ groups of 128, a tree a lane; per group
                                                       the LOW bit
     score[r] += sum_j leaf[idx[r, j], j]
 
-with no sum that crosses lanes before the last. The jax.numpy form below is
+with no sum that crosses lanes before the last. VECTOR LEAVES (C values a
+leaf, the library's `MultiClass`): the same index against class c's leaf
+rows for each c, margins [R, C] = bias[c] + scale x the class's sum, and
+with `link` "softmax" their softmax on the device (stage `predict:link`,
+the node list's). The jax.numpy form below is
 the fallback and what a CPU runs; the Pallas kernel is
 `ops/predict_oblivious.py`, dispatched by the same rule.
 """
@@ -320,7 +324,8 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
     K-blocks of a node list with CATEGORY SETS and `path_select_rows` all
     the K rows of its select (read with such blocks alone);
     `oblivious_depth` an oblivious ensemble
-    of that depth (ops/predict_oblivious.py, `predict_oblivious_fits`).
+    of that depth (ops/predict_oblivious.py, `predict_oblivious_fits`;
+    `n_classes` the columns a leaf holds).
     Explicit True
     demands the kernel (binned data required — raises otherwise; off-TPU
     it runs in interpret mode, the test contract); explicit False always
@@ -334,7 +339,7 @@ def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
             from ddt_tpu.ops import predict_oblivious
 
             fits = predict_oblivious.predict_oblivious_fits(
-                oblivious_depth, n_features)
+                oblivious_depth, n_features, n_cls=n_classes)
         elif path_lanes:
             from ddt_tpu.ops import predict_paths
 
@@ -828,10 +833,17 @@ def predict_raw_effective_paths(
                                                  **form)
     else:
         out = _predict_paths(sel, planes, paths, Xc, **form)
+    return _linked(out, link)
+
+
+def _linked(margins: jax.Array, link: str) -> jax.Array:
+    """The answer of a scoring program that ends in `link`: "softmax" the
+    class probabilities of margins [R, C], taken here on the device (stage
+    `predict:link`); "none" the margins."""
     if link == "softmax":
         with traced_scope("predict:link"):
-            out = jax.nn.softmax(out, axis=1)
-    return out
+            return jax.nn.softmax(margins, axis=1)
+    return margins
 
 
 # Rows a step of the jax.numpy oblivious form takes at most (the float32
@@ -848,6 +860,7 @@ def _predict_oblivious(sel, thr, leaf, Xc, *, scale, bias):
     one-pass matmul of them is exact too."""
     G, D, Fp, W = sel.shape
     R, F = Xc.shape
+    C = leaf.shape[1] >> D              # the columns of a leaf
     row_chunk = min(R, _OBLIVIOUS_ROW_CHUNK,
                     max(512, _OBLIVIOUS_ONEHOT // (W << D)))
     n_rc = -(-R // row_chunk)
@@ -866,52 +879,75 @@ def _predict_oblivious(sel, thr, leaf, Xc, *, scale, bias):
                 for d in range(D):
                     idx |= (v[d] > t[d:d + 1]).astype(jnp.int32) << d
             with traced_scope("predict:accumulate"):
-                leaves = jnp.arange(lv.shape[0], dtype=jnp.int32)
+                leaves = jnp.arange(1 << D, dtype=jnp.int32)
                 hit = idx[:, None, :] == leaves[None, :, None]  # [Rc, 2^D, W]
-                acc = acc + jnp.sum(jnp.where(hit, lv[None], 0.0),
-                                    axis=(1, 2))
-            return acc, None
+                if C == 1:
+                    return acc + jnp.sum(jnp.where(hit, lv[None], 0.0),
+                                         axis=(1, 2)), None
+                # a class at a time against the ONE index: [C, Rc]
+                return acc + jnp.stack([
+                    jnp.sum(jnp.where(hit, lv[None, c << D:(c + 1) << D],
+                                      0.0), axis=(1, 2))
+                    for c in range(C)]), None
 
-        acc, _ = jax.lax.scan(group_body,
-                              jnp.zeros((row_chunk,), jnp.float32),
-                              (sel, thr, leaf))
+        acc, _ = jax.lax.scan(
+            group_body,
+            jnp.zeros((row_chunk,) if C == 1 else (C, row_chunk),
+                      jnp.float32),
+            (sel, thr, leaf))
         return None, acc
 
     with traced_scope("predict:traverse"):
         _, accs = jax.lax.scan(row_body, None, Xp)
     with traced_scope("predict:accumulate"):
-        return bias + scale * accs.reshape(n_rc * row_chunk)[:R]
+        if C == 1:
+            return bias + scale * accs.reshape(n_rc * row_chunk)[:R]
+        accs = accs.transpose(1, 0, 2).reshape(C, n_rc * row_chunk)[:, :R]
+        return (jnp.asarray(bias, jnp.float32)[:, None] + scale * accs).T
 
 
 @costed("predict", phase="predict")
-@functools.partial(jax.jit, static_argnames=("scale", "bias", "use_pallas"))
+@functools.partial(jax.jit, static_argnames=("scale", "bias", "use_pallas",
+                                             "link"))
 @op_scope("predict")
 def predict_raw_effective_oblivious(
     sel: jax.Array,            # bf16 [G, D, Fp, 128] split d's feature one-hot
     thr: jax.Array,            # f32 [G, Dp, 128] split d's bin
-    leaf: jax.Array,           # f32 [G, 2^D, 128] leaf values, a tree a lane
+    leaf: jax.Array,           # f32 [G, C 2^D, 128] leaf values, a tree a lane
     Xc: jax.Array,             # [R, F] integer bins
     scale: float,
-    bias: float,
+    bias: "float | tuple",
     use_pallas: bool | None = None,
+    link: str = "none",
 ) -> jax.Array:
     """Raw margins [R] of an oblivious ensemble from its compiled tables
     (models/tree.CompiledOblivious): by the Pallas kernel
     (ops/predict_oblivious.py) where `resolve_use_pallas` says so and by
     `_predict_oblivious` otherwise. Binned rows only, which the kernel
     takes at the width they come in (uint8 from api.predict: nothing is
-    widened in XLA)."""
+    widened in XLA). Of VECTOR LEAVES (`leaf` C 2^D rows a group, `bias` a
+    tuple of C) the margins [R, C], and with `link` "softmax" their
+    softmax, the class probabilities, taken here on the device (stage
+    `predict:link`)."""
     if not jnp.issubdtype(Xc.dtype, jnp.integer):
         raise ValueError("the oblivious form scores binned (integer) rows")
+    C = leaf.shape[1] >> sel.shape[1]
+    if link not in ("none", "softmax") or (link == "softmax" and C < 2) or (
+            C > 1 and len(bias) != C):
+        raise ValueError(f"link {link!r} and a bias {bias!r} of {C} leaf "
+                         "column(s)")
     if Xc.shape[0] == 0:
-        return jnp.full((0,), bias, jnp.float32)
-    if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], 1,
+        return jnp.full((0,), bias, jnp.float32) if C == 1 else jnp.zeros(
+            (0, C), jnp.float32)
+    if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], C,
                           oblivious_depth=sel.shape[1]):
         from ddt_tpu.ops import predict_oblivious
 
-        return predict_oblivious.predict_oblivious_pallas(
+        out = predict_oblivious.predict_oblivious_pallas(
             sel, thr, leaf, Xc, scale=scale, bias=bias)
-    return _predict_oblivious(sel, thr, leaf, Xc, scale=scale, bias=bias)
+    else:
+        out = _predict_oblivious(sel, thr, leaf, Xc, scale=scale, bias=bias)
+    return _linked(out, link)
 
 
 def predict_proba(raw: jax.Array, loss: str) -> jax.Array:

@@ -17,7 +17,27 @@ and a group's split d is one lane tile, so per group and tile of rows
                                          selects on the VPU, bit 0 first
     acc += leaf                          float32, never bfloat16
 
-so the MXU is asked for D x ceil(F/128) weight tiles a group and 256 rows
+VECTOR LEAVES (CatBoost's `MultiClass`: C values a leaf, PR 57) change the
+last two lines alone: the select leaves ONE index a (row, tree) whatever C,
+and the resolve takes it against class c's leaf rows for each c,
+
+    leaf_c = mux(b_0 .. b_{D-1}, L_c)    L_c [2^D, 128]: rows c 2^D ..
+                                         (c + 1) 2^D of the group's table
+    acc[c] += leaf_c                     c = 0 .. C-1: C (2^D - 1) selects
+                                         (`resolve_selects_per_tree`)
+
+into row c of a resident [C, TILE_ROWS] output (the HBM result `f32[C, R]`,
+class-major, the heap kernel's interface). At few columns the select is
+small (54 columns: ONE K-block, 6 weight tiles a group) and the C-fold
+resolve on the VPU sets the pace: 441 selects a (row, tree) at depth 6 and 7
+classes against the 63 of one column. Such a step is not pipelined (441 >
+255 selects: `_pipelined`; there is little select to hide under): it packs
+a sub-tile's indices into one plane and resolves it in blocks of 128 rows,
+a rolled loop, 3.0 selects a bundle where whole [1024, 128] planes reach
+2.2 (compile check, PR 57). A model of one column traces the program it did
+before there were vector leaves.
+
+So the MXU is asked for D x ceil(F/128) weight tiles a group and 256 rows
 (`oblivious_mxu_tiles_per_tree` = that over 128: 0.75 at depth 6 and 2000
 columns, where the 63-node expansion through the path kernel asks 17) and
 the leaf lookup costs no matmul: the bits of a lane are that tree's own,
@@ -47,14 +67,15 @@ and so is their sum in any order.
 The HBM interface is the other kernels' (ops/predict_pallas.py, PR 36): the
 rows go in as the caller holds them (uint8 from api.predict), over a grid
 of cdiv(R, tile) row tiles whose last block is ragged, and the scores come
-out `f32[1, R]`, the rows on the lanes.
+out `f32[C, R]` (`f32[1, R]` of one column), the rows on the lanes.
 
 Layout strategy. A group's tables are D x Fp x 128 bf16 of select (3.07 MB
-at depth 6 and 2000 columns), 4 KB of thresholds and 2^D x 128 f32 of leaf
-values (32 KB): 197 MB for 8000 trees. They stream: the grid is (row tiles,
+at depth 6 and 2000 columns), 4 KB of thresholds and C x 2^D x 128 f32 of
+leaf values (32 KB a class): 197 MB for 8000 trees. They stream: the grid
+is (row tiles,
 groups), one step holds ONE group's tables (Mosaic double-buffers the
 windows: the next group's DMA runs under this group's matmuls) and walks
-the row tile in sub-tiles of `SUB_ROWS`; the [1, TILE_ROWS] output stays
+the row tile in sub-tiles of `SUB_ROWS`; the [C, TILE_ROWS] output stays
 resident over the group axis, zeroed by the first group and added to by
 all. A row tile streams the tables once.
 
@@ -69,6 +90,7 @@ as in predict_pallas.py; dispatch is ops/predict.resolve_use_pallas.
 from __future__ import annotations
 
 import functools
+import math
 import typing
 
 import jax
@@ -106,15 +128,16 @@ GROUP = _LANES
 # compiler's.
 _VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 _VMEM_BUDGET_BYTES = _VMEM_LIMIT_BYTES - 8 * 1024 * 1024
-# The deepest tree the kernel traces: its multiplexer is 2^D - 1 selects
-# long (1023 at depth 10).
-_MAX_DEPTH = 10
+# The longest resolve the kernel traces, in selects a (row, tree): C
+# multiplexers of 2^D - 1 (one column: depth 10; 7 classes: depth 7).
+_MAX_SELECTS = 1023
 # Bytes a sub-tile's row keeps beside the windows: a widened bin of its
 # K-blocks (the bf16 copy the group's splits share and the float32 it is
 # made from), and a lane's float32 planes (`_vmem_bytes`).
 _SUB_ROW_BIN_BYTES = 6
-# The deepest tree whose resolve is unrolled beside a select (`_pipelined`).
-_PIPELINED_DEPTH = 8
+# The longest resolve that is unrolled beside a select (`_pipelined`): one
+# column's of depth 8.
+_PIPELINED_SELECTS = 255
 # Rows of a resolve's block that depend on ONE matmul of the select they are
 # issued with (`_resolve_later`): a vreg of float32.
 _TIE_ROWS = 8
@@ -126,11 +149,17 @@ def oblivious_mxu_tiles_per_tree(depth: int, n_features: int) -> float:
     return round(depth * select_k_blocks(n_features) / GROUP, 4)
 
 
-def _group_bytes(depth: int, n_features: int) -> int:
+def resolve_selects(depth: int, n_cls: int = 1) -> int:
+    """Vector selects the resolve costs a (row, tree): a multiplexer of
+    2^D - 1 a leaf column, over the one index."""
+    return n_cls * ((1 << depth) - 1)
+
+
+def _group_bytes(depth: int, n_features: int, n_cls: int = 1) -> int:
     """HBM bytes of one group's tables: sel bf16, thr and leaf f32."""
     fp = -(-n_features // 16) * 16
     return (depth * fp * GROUP * 2 + -(-depth // 8) * 8 * GROUP * 4
-            + (GROUP << depth) * 4)
+            + n_cls * (GROUP << depth) * 4)
 
 
 class ObliviousPlan(typing.NamedTuple):
@@ -152,6 +181,13 @@ class ObliviousPlan(typing.NamedTuple):
     # a later select's matmuls: all but the last where the step is
     # pipelined, else 0.
     resolves_under_select: float = 0.0
+    # Vector leaves: the columns C a leaf holds (the answer is [rows, C]),
+    # the link the program ends in ("softmax": class probabilities, taken
+    # on the device) and the selects the resolve costs a (row, tree), C
+    # multiplexers of 2^D - 1 over the one index.
+    leaf_columns: int = 1
+    link: str = "none"
+    resolve_selects_per_tree: int = 0
 
     @property
     def blocks(self) -> int:
@@ -169,7 +205,9 @@ class ObliviousPlan(typing.NamedTuple):
     def root_counts(self) -> dict:
         return {"routing_tables": 0, "oblivious": self.oblivious,
                 "select_columns_per_tree": self.select_columns_per_tree,
-                "select_k_blocks": self.select_k_blocks}
+                "select_k_blocks": self.select_k_blocks,
+                "leaf_columns": self.leaf_columns, "link": self.link,
+                "resolve_selects_per_tree": self.resolve_selects_per_tree}
 
 
 # What the `ddt:predict:ensemble` span says of an oblivious model's plan, in
@@ -179,84 +217,97 @@ SPAN_COUNTS = ("oblivious", "depth", "select_columns_per_tree",
                "trees_per_lane_tile", "select_k_blocks",
                "oblivious_mxu_tiles_per_tree", "trees_per_step",
                "table_blocks", "table_bytes", "row_operand_bytes",
+               "leaf_columns", "link", "resolve_selects_per_tree",
                "resolves_under_select")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 
 
-def _pipelined(depth: int, n_sub: int) -> bool:
+def _pipelined(depth: int, n_sub: int, n_cls: int = 1) -> bool:
     """Whether a step resolves a sub-tile beside the next one's select. It
-    needs a next one; and its two unrolled resolves are 2 x (2^D - 1) x
+    needs a next one; and its two unrolled resolves are 2 x C (2^D - 1) x
     128 vector selects in one basic block, past 2 x 255 x 128 of which the
     compiler's scheduler gives the order up (depth 10 at 28 columns:
     244,687 bundles a step where the rolled form's two sub-tiles take
     90,964; depth 9: 51,673 against 50,638, nothing gained; compile check,
-    PR 40), where the select is a small part of the step anyway."""
-    return n_sub > 1 and depth <= _PIPELINED_DEPTH
+    PR 40), where the select is a small part of the step anyway. One
+    column: up to depth 8; 7 classes: up to depth 5."""
+    return n_sub > 1 and resolve_selects(depth, n_cls) <= _PIPELINED_SELECTS
 
 
-def _scratch_shapes(depth: int, n_sub: int) -> list:
+def _scratch_shapes(depth: int, n_sub: int, n_cls: int = 1) -> list:
     """The pipeline's VMEM scratch: the leaf indices of the sub-tile being
     selected and of the one being resolved, an int32 plane each (a
     sub-tile's D bits in 0.5 MB, where its D bit planes would be 3-5), and
-    the group before's leaf table."""
-    if not _pipelined(depth, n_sub):
-        return []
+    the group before's leaf table (32 KB a class at depth 6). A step of
+    VECTOR leaves that is not pipelined keeps ONE plane of indices: its
+    resolve walks the sub-tile in blocks of 128 rows (`_oblivious_kernel`);
+    a one-column step past depth 8 keeps none, as before."""
+    if not _pipelined(depth, n_sub, n_cls):
+        return [pltpu.VMEM((1, SUB_ROWS, GROUP), jnp.int32)] * (n_cls > 1)
     return [pltpu.VMEM((2, SUB_ROWS, GROUP), jnp.int32),
-            pltpu.VMEM((1 << depth, GROUP), jnp.float32)]
+            pltpu.VMEM((n_cls << depth, GROUP), jnp.float32)]
 
 
-def _vmem_bytes(depth: int, n_features: int, row_bytes: int) -> int:
-    """VMEM a grid step takes: the group's double-buffered table windows,
-    the row tile's two at the rows' own width, the [1, TILE_ROWS]
-    output's, a sub-tile's widened K-blocks and its float32 planes: v_d
-    and b_d of every split and the multiplexer's stack, D deep (3 D + 2);
-    where the step is pipelined, three planes fewer (a block of 128 rows
-    is resolved at a time) and the scratch: never more than the other
-    form's, so no shape lost the kernel to the pipeline."""
+def _vmem_bytes(depth: int, n_features: int, row_bytes: int,
+                n_cls: int = 1) -> int:
+    """VMEM a grid step takes: the group's double-buffered table windows
+    (the leaf table's C times one column's), the row tile's two at the
+    rows' own width, the [C, TILE_ROWS] output's, a sub-tile's widened
+    K-blocks and its float32 planes: v_d and b_d of every split and the
+    multiplexer's stack, D deep (3 D + 2; the C multiplexers run one after
+    another over the same bits); where a block of 128 rows is resolved at
+    a time (the pipelined step, and every step of vector leaves), three
+    planes fewer and the scratch: never more than the other form's, so no
+    shape lost the kernel to the pipeline."""
     fp = -(-n_features // 16) * 16
     tables = (depth * _window_bytes(fp, GROUP) // 2     # bf16: half of f32
-              + _window_bytes(depth, GROUP) + _window_bytes(1 << depth, GROUP))
+              + _window_bytes(depth, GROUP)
+              + _window_bytes(n_cls << depth, GROUP))
     rows = (2 * TILE_ROWS * _lane_pad(n_features) * row_bytes
-            + _window_bytes(1, TILE_ROWS))
-    planes, scratch = 3 * depth + 2, 0
-    if _pipelined(depth, TILE_ROWS // SUB_ROWS):
-        planes -= 3
-        scratch = 2 * SUB_ROWS * GROUP * 4 + (GROUP << depth) * 4
+            + _window_bytes(n_cls, TILE_ROWS))
+    scratch = sum(4 * math.prod(s.shape) for s in _scratch_shapes(
+        depth, TILE_ROWS // SUB_ROWS, n_cls))
+    planes = 3 * depth + 2 - (3 if scratch else 0)
     sub = SUB_ROWS * (_lane_pad(n_features) * _SUB_ROW_BIN_BYTES
                       + GROUP * 4 * planes)
     return tables + rows + sub + scratch
 
 
 def oblivious_plan(n_trees: int, depth: int, n_features: int,
-                   served: bool = True, row_dtype=jnp.uint8) -> ObliviousPlan:
+                   served: bool = True, row_dtype=jnp.uint8,
+                   n_cls: int = 1, link: str = "none") -> ObliviousPlan:
     """The kernel's table blocks at this shape: one group of 128 trees a
     step. `served` False: the plan of a model the jax.numpy form scores
-    (its depth and select, no blocks)."""
+    (its depth and select, no blocks). `n_cls`: the columns of a leaf;
+    `link`: what the program ends in."""
     row_bytes = row_operand_dtype(row_dtype).itemsize
     said = (1, depth, depth, round(GROUP / depth, 2),
             select_k_blocks(n_features),
             oblivious_mxu_tiles_per_tree(depth, n_features))
+    leaves = dict(leaf_columns=n_cls, link=link,
+                  resolve_selects_per_tree=resolve_selects(depth, n_cls))
     if not served:
-        return ObliviousPlan(*said, 0, 0, 0, 0, row_bytes)
+        return ObliviousPlan(*said, 0, 0, 0, 0, row_bytes, **leaves)
     groups = max(1, -(-n_trees // GROUP))
     n_sub = TILE_ROWS // SUB_ROWS
     resolves = n_sub * groups
     return ObliviousPlan(*said, GROUP, groups,
-                         groups * _group_bytes(depth, n_features), TILE_ROWS,
-                         row_bytes,
+                         groups * _group_bytes(depth, n_features, n_cls),
+                         TILE_ROWS, row_bytes,
                          round((resolves - 1) / resolves, 4)
-                         if _pipelined(depth, n_sub) else 0.0)
+                         if _pipelined(depth, n_sub, n_cls) else 0.0,
+                         **leaves)
 
 
 def predict_oblivious_fits(depth: int, n_features: int,
-                           row_dtype=jnp.uint8) -> bool:
+                           row_dtype=jnp.uint8, n_cls: int = 1) -> bool:
     """Whether one group's tables fit the kernel's VMEM budget beside a row
-    tile, and its multiplexer the trace: the guard behind use_pallas=None
-    (ops/predict.resolve_use_pallas), the ONE rule. The tree count is no
-    term of it."""
+    tile, and its C multiplexers the trace: the guard behind
+    use_pallas=None (ops/predict.resolve_use_pallas), the ONE rule. The
+    tree count is no term of it."""
     row_bytes = row_operand_dtype(row_dtype).itemsize
-    return depth <= _MAX_DEPTH and _vmem_bytes(
-        depth, n_features, row_bytes) <= _VMEM_BUDGET_BYTES
+    return resolve_selects(depth, n_cls) <= _MAX_SELECTS and _vmem_bytes(
+        depth, n_features, row_bytes, n_cls) <= _VMEM_BUDGET_BYTES
 
 
 def _mux(bits: list, leaves: list, d: int, base: int):
@@ -271,21 +322,24 @@ def _mux(bits: list, leaves: list, d: int, base: int):
 
 
 def _leaves(leaf_rows, rows: int) -> list:
-    """The group's leaf table `leaf_rows [2^D, 128]`, a leaf a plane of
-    `rows` rows: the multiplexer's operands."""
+    """The group's leaf table `leaf_rows [C 2^D, 128]`, a leaf (of a
+    class) a plane of `rows` rows: the multiplexers' operands."""
     return [jnp.broadcast_to(leaf_rows[i:i + 1, :], (rows, _LANES))
             for i in range(leaf_rows.shape[0])]
 
 
 def _resolve(bits: list, leaves: list, out_ref, r0):
-    """The RESOLVE of a block of rows whose D bit planes are `bits`: the
-    multiplexer over the group's `leaves`, the block turned over and added
-    into the output's rows from r0."""
-    rows = bits[0].shape[0]
-    leaf = _mux(bits, leaves, len(bits) - 1, 0)           # [rows, 128]
-    # The lanes summed with the rows on the lanes: turn the block over, add
-    # down the sublanes.
-    out_ref[:, pl.ds(r0, rows)] += jnp.sum(leaf.T, axis=0, keepdims=True)
+    """The RESOLVE of a block of rows whose D bit planes are `bits`: for
+    each leaf column c the multiplexer over the group's `leaves` of that
+    class (2^D of the C 2^D), the block turned over and added into row c
+    of the output, the rows from r0."""
+    rows, per_class = bits[0].shape[0], 1 << len(bits)
+    for c in range(len(leaves) // per_class):
+        leaf = _mux(bits, leaves, len(bits) - 1, c * per_class)  # [rows, 128]
+        # The lanes summed with the rows on the lanes: turn the block over,
+        # add down the sublanes.
+        out_ref[c:c + 1, pl.ds(r0, rows)] += jnp.sum(leaf.T, axis=0,
+                                                     keepdims=True)
 
 
 def _resolve_later(idx_ref, slot: int, leaf_rows, out_ref, r0: int,
@@ -368,16 +422,31 @@ def _select(x_ref, sel_ref, thr_ref, r0, under=None, *, depth: int,
     return bits
 
 
+def _leaf_index(bits: list):
+    """The D bit planes of a sub-tile as ONE int32 plane of leaf indices."""
+    return functools.reduce(jnp.bitwise_or, (
+        jnp.where(b, 1 << d, 0) for d, b in enumerate(bits)))
+
+
+def _in_blocks(block, sub_rows: int):
+    """`block` (`_resolve_later`) over a sub-tile's blocks of 128 rows, in
+    a rolled loop."""
+    jax.lax.fori_loop(
+        0, sub_rows // _LANES,
+        lambda b, c: block(pl.multiple_of(b * _LANES, _LANES)), None)
+
+
 def _oblivious_kernel(x_ref, sel_ref, thr_ref, leaf_ref, out_ref, *scratch,
                       depth: int, n_feat: int, sub_rows: int):
     """One row tile against one group of 128 trees: the group's share of
     every row's margin. x_ref [TILE_ROWS, F] uint8 or int32, as HBM holds
     the rows (in the last tile, whatever lies past row R); sel [1, D, Fp,
-    128] bf16, thr [1, Dp, 128] f32, leaf [1, 2^D, 128] f32; out [1,
-    TILE_ROWS] f32, the rows on the lanes, resident over the group axis
-    (grid axis 1). `scratch` (`_scratch_shapes`): idx [2, SUB_ROWS, 128]
-    int32, the leaf indices of the sub-tile being selected and of the one
-    being resolved; carry [2^D, 128] f32, the group before's leaf table.
+    128] bf16, thr [1, Dp, 128] f32, leaf [1, C 2^D, 128] f32; out [C,
+    TILE_ROWS] f32, a leaf column a row, the rows on the lanes, resident
+    over the group axis (grid axis 1). `scratch` (`_scratch_shapes`): idx
+    [2, SUB_ROWS, 128] int32, the leaf indices of the sub-tile being
+    selected and of the one being resolved; carry [C 2^D, 128] f32, the
+    group before's leaf table.
 
     Software-pipelined (`_pipelined`): the resolve of a sub-tile is issued
     with the select of the NEXT one, in one basic block, and the step's
@@ -387,6 +456,7 @@ def _oblivious_kernel(x_ref, sel_ref, thr_ref, leaf_ref, out_ref, *scratch,
     group resolves its own last sub-tile too, the one resolve a row tile's
     MXUs wait for. A row's sum still runs over the groups in grid order."""
     n_sub = x_ref.shape[0] // sub_rows
+    n_cls = out_ref.shape[0]
     group, last = pl.program_id(1), pl.num_programs(1) - 1
     select = functools.partial(_select, x_ref, sel_ref, thr_ref, depth=depth,
                                n_feat=n_feat, sub_rows=sub_rows)
@@ -397,11 +467,21 @@ def _oblivious_kernel(x_ref, sel_ref, thr_ref, leaf_ref, out_ref, *scratch,
         for carry in scratch[1:]:       # the pipelined step's alone
             carry[:] = jnp.zeros_like(carry)
 
-    if not _pipelined(depth, n_sub):
+    if not _pipelined(depth, n_sub, n_cls):
         def sub_tile(j, carry):
             r0 = pl.multiple_of(j * sub_rows, sub_rows)
-            _resolve(select(r0), _leaves(leaf_ref.at[0], sub_rows), out_ref,
-                     r0)
+            bits = select(r0)
+            if n_cls == 1:
+                _resolve(bits, _leaves(leaf_ref.at[0], sub_rows), out_ref, r0)
+                return carry
+            # C multiplexers over whole [sub_rows, 128] planes keep their
+            # values in VMEM (26,427 bundles a sub-tile at depth 6 and 7
+            # classes, 44,085 of their operands spilled); over blocks of
+            # 128 rows, the indices packed into one plane as the pipelined
+            # step packs them, 20,867 (compile check, PR 57).
+            scratch[0][0] = _leaf_index(bits)
+            _in_blocks(_resolve_later(scratch[0], 0, leaf_ref.at[0], out_ref,
+                                      r0, depth), sub_rows)
             return carry
 
         jax.lax.fori_loop(0, n_sub, sub_tile, 0)
@@ -417,45 +497,45 @@ def _oblivious_kernel(x_ref, sel_ref, thr_ref, leaf_ref, out_ref, *scratch,
         # j = 0 the step before's last.
         bits = select(j * sub_rows, later(j - 1, leaf_ref.at[0]) if j
                       else later(n_sub - 1, carry_ref))
-        idx_ref[j % 2] = functools.reduce(jnp.bitwise_or, (
-            jnp.where(b, 1 << d, 0) for d, b in enumerate(bits)))
+        idx_ref[j % 2] = _leaf_index(bits)
     carry_ref[:] = leaf_ref[0]
 
     @pl.when(group == last)
     def _():
         # Nothing to run under: a rolled loop, an eighth of the program.
-        block = later(n_sub - 1, leaf_ref.at[0])
-        jax.lax.fori_loop(
-            0, sub_rows // _LANES,
-            lambda b, c: block(pl.multiple_of(b * _LANES, _LANES)), None)
+        _in_blocks(later(n_sub - 1, leaf_ref.at[0]), sub_rows)
 
 
 def predict_oblivious_pallas(
     sel: jax.Array,            # bf16 [G, D, Fp, 128]
     thr: jax.Array,            # f32 [G, Dp, 128]
-    leaf: jax.Array,           # f32 [G, 2^D, 128]
+    leaf: jax.Array,           # f32 [G, C 2^D, 128]
     Xc: jax.Array,             # [R, F] integer bins, uint8 as api.predict's
     *,
     scale,
     bias,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Raw margins [R]: Pallas twin of ops/predict._predict_oblivious, over
-    a model's compiled tables (models/tree.CompiledOblivious). Jit-safe.
-    interpret=None auto-selects the Pallas interpreter off-TPU."""
+    """Raw margins [R], or of vector leaves (`leaf` C 2^D rows a group,
+    `bias` a tuple of C) [R, C]: Pallas twin of ops/predict.
+    _predict_oblivious, over a model's compiled tables
+    (models/tree.CompiledOblivious). Jit-safe. interpret=None auto-selects
+    the Pallas interpreter off-TPU."""
     if interpret is None:
         interpret = device.platform() != "tpu"
     n_groups, depth, fp, _ = sel.shape
+    n_cls = leaf.shape[1] >> depth
     R, F = Xc.shape
     # The rows as the kernel takes them: uint8 and int32 as they come, any
     # other integer cast in XLA first (the heap kernel's rule).
     row_dtype = row_operand_dtype(Xc.dtype)
     with traced_scope("predict:widen"):
         rows = Xc if Xc.dtype == row_dtype else Xc.astype(row_dtype)
-    if not (interpret or predict_oblivious_fits(depth, F, row_dtype)):
+    if not (interpret or predict_oblivious_fits(depth, F, row_dtype, n_cls)):
         raise ValueError(
-            f"oblivious shape (depth {depth}, F={F}) exceeds the Pallas "
-            "VMEM budget; use the jax.numpy form")
+            f"oblivious shape (depth {depth}, F={F}, {n_cls} leaf "
+            "column(s)) exceeds the Pallas VMEM budget or the resolve's "
+            "trace; use the jax.numpy form")
     tile_rows = min(TILE_ROWS, -(-R // SUB_ROWS) * SUB_ROWS)
     n_tiles = -(-R // tile_rows)
 
@@ -466,8 +546,8 @@ def predict_oblivious_pallas(
     cost = pl.CostEstimate(
         flops=2 * n_tiles * tile_rows * n_groups * depth * fp * GROUP,
         bytes_accessed=n_tiles * (
-            tile_rows * (F * row_dtype.itemsize + 4)
-            + n_groups * _group_bytes(depth, F)),
+            tile_rows * (F * row_dtype.itemsize + 4 * n_cls)
+            + n_groups * _group_bytes(depth, F, n_cls)),
         transcendentals=0,
     )
     with traced_scope("predict:traverse_oblivious"):
@@ -481,15 +561,20 @@ def predict_oblivious_pallas(
                                    memory_space=pltpu.VMEM),
                       table_block(depth, fp, GROUP),
                       table_block(thr.shape[1], GROUP),
-                      table_block(1 << depth, GROUP)],
-            out_specs=pl.BlockSpec((1, tile_rows), lambda i, b: (0, i),
+                      table_block(n_cls << depth, GROUP)],
+            out_specs=pl.BlockSpec((n_cls, tile_rows), lambda i, b: (0, i),
                                    memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((1, R), jnp.float32),
-            scratch_shapes=_scratch_shapes(depth, tile_rows // SUB_ROWS),
+            out_shape=jax.ShapeDtypeStruct((n_cls, R), jnp.float32),
+            scratch_shapes=_scratch_shapes(depth, tile_rows // SUB_ROWS,
+                                           n_cls),
             cost_estimate=cost,
             interpret=interpret,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         )(rows, sel, thr, leaf)
     with traced_scope("predict:accumulate"):
-        return bias + scale * acc[0]
+        if n_cls == 1:
+            return bias + scale * acc[0]
+        # class-major [C, R] as the kernel leaves it; the `.T` is the
+        # layout's (a bitcast, as in the heap program)
+        return (jnp.asarray(bias, jnp.float32)[:, None] + scale * acc).T

@@ -87,8 +87,12 @@ is D splits and 2^D leaf values. Split d sets BIT d of the row's leaf index
 when `bin[split_feature[t, d]] > split_bin[t, d]` (the first split is the
 low bit; the same rule as above: `<=` goes left, leaves the bit clear), and
 the tree scores `leaf_value[t, index]`. Raw score [rows] = bias + scale x
-the sum over the trees. No missing-value route, no category split, one
-output column. Where the device path (`ops/predict.py`'s jax.numpy form and
+the sum over the trees. No missing-value route, no category split. With
+VECTOR LEAVES (`leaf_value` [T, 2^D, C], `bias` [C]; the library's
+`MultiClass`) the tree scores the C values of the ONE leaf it reaches, the
+margins are [rows, C], `m_c = bias[c] + scale x the sum over the trees of
+leaf_value[t, index, c]`, and the answer is their softmax (`softmax`, in
+the margins' dtype: float64 for the tolerance studies). Where the device path (`ops/predict.py`'s jax.numpy form and
 its kernel `ops/predict_oblivious.py`) departs, all of it arithmetic and
 none of it routing:
 
@@ -99,7 +103,12 @@ none of it routing:
 - A group's leaf values are summed lane by lane and then over the lanes,
   the groups are added, and the scale is applied once at the end: float32
   rounding of a sum in another order, not bitwise.
-  `tests/test_oblivious.py` holds `api.predict` to it.
+- Vector leaves are looked up a class at a time against ONE index plane (C
+  multiplexers over the same bits), the margins leave the kernel
+  class-major `[C, rows]`, and the softmax is `jax.nn.softmax` in float32
+  on the device (stage `predict:link`).
+  `tests/test_oblivious.py` and `tests/test_oblivious_mc.py` hold
+  `api.predict` to it.
 
 AN XGBOOST MODEL, from the library's own JSON (`predict_xgboost_json`): a
 float64 walk of the arrays `Booster.save_model("m.json")` writes, on RAW
@@ -235,12 +244,21 @@ def leaf_of_rows_oblivious(ens, t: int, Xb: np.ndarray) -> np.ndarray:
 def predict_raw_oblivious(ens, Xb: np.ndarray,
                           dtype=np.float32) -> np.ndarray:
     """Raw scores [rows] of a models/tree.ObliviousEnsemble over binned
-    rows: the leaf values summed in `dtype` in tree order, then scaled."""
-    total = np.zeros(Xb.shape[0], dtype)
+    rows ([rows, C] of vector leaves, a sum a class): the leaf values
+    summed in `dtype` in tree order, then scaled, the bias (a vector's
+    entry a class) added."""
+    total = np.zeros((Xb.shape[0],) + ens.leaf_value.shape[2:], dtype)
     for t in range(ens.split_feature.shape[0]):
         total += ens.leaf_value[t].astype(dtype)[
             leaf_of_rows_oblivious(ens, t, Xb)]
-    return dtype(ens.bias) + dtype(ens.scale) * total
+    return np.asarray(ens.bias, dtype) + dtype(ens.scale) * total
+
+
+def softmax(margins: np.ndarray) -> np.ndarray:
+    """Class probabilities [rows, C] of margins [rows, C], in their dtype:
+    exp(m_c - max m) over its sum."""
+    e = np.exp(margins - margins.max(axis=1, keepdims=True))
+    return e / e.sum(axis=1, keepdims=True)
 
 
 # objective -> (margin of base_score, link), as the library defines them
@@ -288,5 +306,4 @@ def predict_xgboost_json(model: dict, X: np.ndarray,
     if raw or link is not None:
         out = out if C > 1 else out[:, 0]
         return out if raw else link(out)
-    e = np.exp(out - out.max(axis=1, keepdims=True))
-    return e / e.sum(axis=1, keepdims=True)
+    return softmax(out)

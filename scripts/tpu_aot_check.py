@@ -317,25 +317,27 @@ def _forest_case(rows, features, n_trees, n_subtrees, classes, lanes=256,
     return build
 
 
-def _oblivious_case(rows, features, n_trees, depth):
+def _oblivious_case(rows, features, n_trees, depth, classes=1):
     """The oblivious form (ops/predict_oblivious.py) over the compiled
     tables' SHAPES (models/tree.CompiledOblivious: 197 MB at the Epsilon
-    model's), the rows as api.predict does (uint8)."""
+    model's), the rows as api.predict does (uint8). `classes` C > 1:
+    vector leaves, the C-fold resolve."""
     def build():
         import jax.numpy as jnp
 
         from ddt_tpu.ops import predict_oblivious
 
         groups = -(-n_trees // predict_oblivious.GROUP)
+        bias = 0.25 if classes == 1 else (0.25,) * classes
 
         def fn(sel, thr, leaf, Xc):
             return predict_oblivious.predict_oblivious_pallas(
-                sel, thr, leaf, Xc, scale=0.5, bias=0.25, interpret=False)
+                sel, thr, leaf, Xc, scale=0.5, bias=bias, interpret=False)
 
         return fn, [
             ((groups, depth, -(-features // 16) * 16, 128), jnp.bfloat16),
             ((groups, -(-depth // 8) * 8, 128), jnp.float32),
-            ((groups, 1 << depth, 128), jnp.float32),
+            ((groups, classes << depth, 128), jnp.float32),
             ((rows, features), jnp.uint8)]
 
     return build
@@ -592,6 +594,21 @@ def kernel_cases() -> list:
                    _oblivious_case(4_999, hf, 130, 8)),
         KernelCase("oblivious/28f/130x6/1000rows", True,
                    _oblivious_case(1_000, hf, 130, 6)),
+        # VECTOR LEAVES (PR 57): CatBoost's MultiClass defaults over
+        # Covertype's chunk (8 groups, ONE K-block, 7 classes: 441 selects
+        # a (row, tree), the rolled step), the longest resolve the dispatch
+        # rule admits (7 classes at depth 7: 889 of 1023) and the longest
+        # that is unrolled beside a select (7 classes at depth 5: 217 of
+        # 255, a carried leaf table of 7 x 16 KB).
+        KernelCase("oblivious/54f/1000x6xC7", True,
+                   _oblivious_case(2_000_000, COVERTYPE["features"], 1000, 6,
+                                   classes=7)),
+        KernelCase("oblivious/54f/130x7xC7", True,
+                   _oblivious_case(4_999, COVERTYPE["features"], 130, 7,
+                                   classes=7)),
+        KernelCase("oblivious/54f/130x5xC7", True,
+                   _oblivious_case(4_999, COVERTYPE["features"], 130, 5,
+                                   classes=7)),
         # Opt-in kernels.
         KernelCase("hist/higgs/255bins/N=32/int8", False,
                    _hist_case(hr, hf, 32, 255, "int8")),
@@ -665,7 +682,8 @@ def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
                      n_classes=1, routed=False, leaves=0, oblivious=False):
     """`routed`: a heap with the missing and the categorical table; a node
     list (`leaves`) with learned NaN directions. `oblivious`: symmetric
-    trees of `depth` (models/tree.ObliviousEnsemble)."""
+    trees of `depth` (models/tree.ObliviousEnsemble), of `n_classes` > 1
+    vector leaves and the program that ends in their softmax."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -680,13 +698,14 @@ def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
         from ddt_tpu.models.tree import random_oblivious
 
         ens = random_oblivious(np.random.default_rng(7), n_trees, depth,
-                               features, scale=0.5, bias=0.25)
+                               features, scale=0.5, bias=0.25,
+                               n_classes=n_classes if n_classes > 1 else 0)
     else:
         ens = (_random_node_list(n_trees, leaves, features, routed)
                if leaves else
                _random_ensemble(n_trees, depth, features, n_classes, routed,
                                 routed))
-    fn, ens_dev = be._predict_fn(ens)
+    fn, ens_dev = be._predict_entry(ens, link=be.links_on_device(ens))[:2]
     one = SingleDeviceSharding(topo_devices[0])
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
             for a in ens_dev]
@@ -766,6 +785,11 @@ def program_cases(topo_devices) -> list:
             EPSILON["n_trees"], rows=EPSILON["chunk_rows"],
             features=EPSILON["features"], depth=EPSILON["depth"],
             oblivious=True)),
+        # CatBoost's MultiClass defaults over Covertype: vector leaves, the
+        # C-fold resolve, the softmax on the device.
+        ("scoring/covtype-catboost/1000x6xC7/oblivious", scoring(
+            1000, rows=2_000_000, features=COVERTYPE["features"], depth=6,
+            n_classes=7, oblivious=True)),
     ]
 
 

@@ -439,6 +439,8 @@ def test_an_oblivious_model_says_which_form_serves(impl, monkeypatch):
         trees_per_step=128 * served, table_blocks=1 * served,
         table_bytes=(3 * 16 * 128 * 2 + 8 * 128 * 4 + 8 * 128 * 4) * served,
         row_operand_bytes=1,
+        # one column a leaf: a multiplexer of 2^3 - 1 selects, no link
+        leaf_columns=1, link="none", resolve_selects_per_tree=7,
         # one group: of a row tile's two resolves the first runs beside
         # the second sub-tile's select
         resolves_under_select=0.5 * served)

@@ -400,11 +400,14 @@ def test_oblivious_kernel_crosses_hbm_at_the_datas_width(case):
     """The oblivious form's interface is the other kernels': the uint8 chunk
     as it comes, at 28 columns and at the Epsilon model's 2000 (where an
     int32 copy of a 131,072-row chunk would be 1 GB), the last row tile
-    ragged, and the scores as `f32[1, R]`; no array of the program holds the
-    rows widened or padded, and nothing is traced under `predict:widen`."""
+    ragged, and the scores as `f32[1, R]` (of vector leaves class-major,
+    `f32[C, R]`, the heap kernel's interface); no array of the program
+    holds the rows widened or padded, and nothing is traced under
+    `predict:widen`."""
     exported, shapes = _export_for_tpu(case)
     (rows, features), dtype = shapes[-1]
     (groups, depth, fp, lanes), _ = shapes[0]
+    classes = shapes[2][0][1] >> depth
     assert dtype == jnp.uint8 and lanes == 128
     text = exported.mlir_module()
     call, = [ln for ln in text.splitlines()
@@ -414,7 +417,7 @@ def test_oblivious_kernel_crosses_hbm_at_the_datas_width(case):
     assert operands.startswith(
         f"tensor<{rows}x{features}xui8>, "
         f"tensor<{groups}x{depth}x{fp}x128xbf16>,")
-    assert result == f"tensor<1x{rows}xf32>"
+    assert result == f"tensor<{classes}x{rows}xf32>"
     for held in ("xi32>", "xf32>", "xbf16>"):
         assert f"tensor<{rows}x{features}{held}" not in text
     assert f"tensor<{rows}x1xf32>" not in text
