@@ -19,23 +19,42 @@ and a group's split d is one lane tile, so per group and tile of rows
 
 VECTOR LEAVES (CatBoost's `MultiClass`: C values a leaf, PR 57) change the
 last two lines alone: the select leaves ONE index a (row, tree) whatever C,
-and the resolve takes it against class c's leaf rows for each c,
+and the resolve takes it against class c's leaf rows for each c, L_c [2^D,
+128]: rows c 2^D .. (c + 1) 2^D of the group's table, into row c of a
+resident [C, TILE_ROWS] output (the HBM result `f32[C, R]`, class-major, the
+heap kernel's interface). Where a class's leaves fill a vreg (`_gathered`:
+C > 1 and D >= 3) the lookup is a SUBLANE GATHER (PR 58): leaf 8 h + s of
+the class is sublane s of vreg h of L_c as HBM holds it, the index never
+crosses lanes, and a v5e permutes a vreg along its sublanes by a per-element
+index (`jnp.take_along_axis(.., axis=0)` on [8, 128] operands, Mosaic's
+`tpu.dynamic_gather`, the VALU's `vperm.slane`, four a bundle like a
+select), so for a strip of 8 rows, idx its packed indices,
 
-    leaf_c = mux(b_0 .. b_{D-1}, L_c)    L_c [2^D, 128]: rows c 2^D ..
-                                         (c + 1) 2^D of the group's table
-    acc[c] += leaf_c                     c = 0 .. C-1: C (2^D - 1) selects
+    v_h    = take_along_axis(L_c[8h : 8h + 8], idx & 7, axis=0)
+                                         h = 0 .. 2^(D-3) - 1: 2^(D-3)
+                                         gathers (`resolve_gathers_per_tree`)
+    leaf_c = mux(bits 3 .. D-1 of idx, v_0 .. v_{2^(D-3) - 1})
+                                         2^(D-3) - 1 selects
+    acc[c] += leaf_c                     c = 0 .. C-1: C (2^(D-2) - 1) VALU
+                                         operations a (row, tree), gathers
+                                         and selects alike
                                          (`resolve_selects_per_tree`)
 
-into row c of a resident [C, TILE_ROWS] output (the HBM result `f32[C, R]`,
-class-major, the heap kernel's interface). At few columns the select is
-small (54 columns: ONE K-block, 6 weight tiles a group) and the C-fold
-resolve on the VPU sets the pace: 441 selects a (row, tree) at depth 6 and 7
-classes against the 63 of one column. Such a step is not pipelined (441 >
-255 selects: `_pipelined`; there is little select to hide under): it packs
-a sub-tile's indices into one plane and resolves it in blocks of 128 rows,
-a rolled loop, 3.0 selects a bundle where whole [1024, 128] planes reach
-2.2 (compile check, PR 57). A model of one column traces the program it did
-before there were vector leaves.
+the float32 values the multiplexer would pick, summed in its order: the
+scores are its bits. At depth 6 and 7 classes that is 56 gathers and 49
+selects, 105 operations where C multiplexers of all D bits took C (2^D - 1)
+= 441 (and take them still for vector leaves under depth 3, fewer than a
+vreg's 8 leaves: `leaf_c = mux(b_0 .. b_{D-1}, L_c)`); one column keeps its
+multiplexer of 63 and traces the program it did before there were vector
+leaves. At few columns the select is small (54 columns: ONE K-block, 6
+weight tiles a group) and the C-fold resolve on the VPU sets the pace. Such
+a step is not pipelined where C (2^D - 1) > 255 (`_pipelined`, a rule about
+the multiplexer's trace that the gather left where it was; there is little
+select to hide under): it packs a sub-tile's indices into one plane and
+resolves it in blocks of 128 rows, a rolled loop: 627 bundles a block by
+the gather (3.0 VALU operations a bundle) where the multiplexer took 2,348
+(3.0 selects a bundle; whole [1024, 128] planes reached 2.2): a sub-tile
+and group of 6,622 bundles where 20,867 (compile checks, PRs 57 and 58).
 
 So the MXU is asked for D x ceil(F/128) weight tiles a group and 256 rows
 (`oblivious_mxu_tiles_per_tree` = that over 128: 0.75 at depth 6 and 2000
@@ -128,8 +147,9 @@ GROUP = _LANES
 # compiler's.
 _VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 _VMEM_BUDGET_BYTES = _VMEM_LIMIT_BYTES - 8 * 1024 * 1024
-# The longest resolve the kernel traces, in selects a (row, tree): C
-# multiplexers of 2^D - 1 (one column: depth 10; 7 classes: depth 7).
+# The longest resolve the kernel traces, in selects a (row, tree) of C
+# multiplexers of 2^D - 1, `_mux_selects` (one column: depth 10; 7 classes:
+# depth 7).
 _MAX_SELECTS = 1023
 # Bytes a sub-tile's row keeps beside the windows: a widened bin of its
 # K-blocks (the bf16 copy the group's splits share and the float32 it is
@@ -139,8 +159,11 @@ _SUB_ROW_BIN_BYTES = 6
 # column's of depth 8.
 _PIPELINED_SELECTS = 255
 # Rows of a resolve's block that depend on ONE matmul of the select they are
-# issued with (`_resolve_later`): a vreg of float32.
+# issued with (`_resolve_later`): a vreg of float32. A vreg's 8 sublanes are
+# also the 8 leaves ONE sublane gather reaches (`_gathered`): the low three
+# bits of the index.
 _TIE_ROWS = 8
+_GATHER_BITS = _TIE_ROWS.bit_length() - 1
 
 
 def oblivious_mxu_tiles_per_tree(depth: int, n_features: int) -> float:
@@ -149,10 +172,33 @@ def oblivious_mxu_tiles_per_tree(depth: int, n_features: int) -> float:
     return round(depth * select_k_blocks(n_features) / GROUP, 4)
 
 
-def resolve_selects(depth: int, n_cls: int = 1) -> int:
-    """Vector selects the resolve costs a (row, tree): a multiplexer of
-    2^D - 1 a leaf column, over the one index."""
+def _gathered(depth: int, n_cls: int) -> bool:
+    """Whether the resolve looks a leaf up by a SUBLANE GATHER (the module's
+    VECTOR LEAVES): vector leaves of at least a vreg's 8 leaves a class.
+    THE RULE, a function of what the kernel sees in its tables; one column
+    keeps the multiplexer and the program it traced (PERF.md section 7)."""
+    return n_cls > 1 and depth >= _GATHER_BITS
+
+
+def _mux_selects(depth: int, n_cls: int = 1) -> int:
+    """Vector selects of C multiplexers of 2^D - 1 over the one index: what
+    `_pipelined` and `predict_oblivious_fits` are rules about."""
     return n_cls * ((1 << depth) - 1)
+
+
+def resolve_gathers(depth: int, n_cls: int = 1) -> int:
+    """Sublane gathers the resolve costs a (row, tree): a class's 2^D leaves
+    are 2^(D-3) vregs, one gather each; 0 where the multiplexer serves."""
+    return n_cls << (depth - _GATHER_BITS) if _gathered(depth, n_cls) else 0
+
+
+def resolve_selects(depth: int, n_cls: int = 1) -> int:
+    """VALU operations the leaf lookup costs a (row, tree), gathers and
+    selects alike: a multiplexer of 2^D - 1 a leaf column over the one
+    index, or (`_gathered`) 2^(D-3) gathers and the 2^(D-3) - 1 selects
+    among their results."""
+    gathers = resolve_gathers(depth, n_cls)
+    return 2 * gathers - n_cls if gathers else _mux_selects(depth, n_cls)
 
 
 def _group_bytes(depth: int, n_features: int, n_cls: int = 1) -> int:
@@ -183,11 +229,13 @@ class ObliviousPlan(typing.NamedTuple):
     resolves_under_select: float = 0.0
     # Vector leaves: the columns C a leaf holds (the answer is [rows, C]),
     # the link the program ends in ("softmax": class probabilities, taken
-    # on the device) and the selects the resolve costs a (row, tree), C
-    # multiplexers of 2^D - 1 over the one index.
+    # on the device) and the VALU operations the lookup costs a (row, tree)
+    # (`resolve_selects`), of which so many sublane gathers (PR 58; 0: the
+    # multiplexer serves).
     leaf_columns: int = 1
     link: str = "none"
     resolve_selects_per_tree: int = 0
+    resolve_gathers_per_tree: int = 0
 
     @property
     def blocks(self) -> int:
@@ -207,7 +255,8 @@ class ObliviousPlan(typing.NamedTuple):
                 "select_columns_per_tree": self.select_columns_per_tree,
                 "select_k_blocks": self.select_k_blocks,
                 "leaf_columns": self.leaf_columns, "link": self.link,
-                "resolve_selects_per_tree": self.resolve_selects_per_tree}
+                "resolve_selects_per_tree": self.resolve_selects_per_tree,
+                "resolve_gathers_per_tree": self.resolve_gathers_per_tree}
 
 
 # What the `ddt:predict:ensemble` span says of an oblivious model's plan, in
@@ -218,7 +267,7 @@ SPAN_COUNTS = ("oblivious", "depth", "select_columns_per_tree",
                "oblivious_mxu_tiles_per_tree", "trees_per_step",
                "table_blocks", "table_bytes", "row_operand_bytes",
                "leaf_columns", "link", "resolve_selects_per_tree",
-               "resolves_under_select")
+               "resolve_gathers_per_tree", "resolves_under_select")
 PHASES_COUNTS = tuple(k for k in SPAN_COUNTS if k != "table_bytes")
 
 
@@ -231,7 +280,7 @@ def _pipelined(depth: int, n_sub: int, n_cls: int = 1) -> bool:
     90,964; depth 9: 51,673 against 50,638, nothing gained; compile check,
     PR 40), where the select is a small part of the step anyway. One
     column: up to depth 8; 7 classes: up to depth 5."""
-    return n_sub > 1 and resolve_selects(depth, n_cls) <= _PIPELINED_SELECTS
+    return n_sub > 1 and _mux_selects(depth, n_cls) <= _PIPELINED_SELECTS
 
 
 def _scratch_shapes(depth: int, n_sub: int, n_cls: int = 1) -> list:
@@ -285,7 +334,8 @@ def oblivious_plan(n_trees: int, depth: int, n_features: int,
             select_k_blocks(n_features),
             oblivious_mxu_tiles_per_tree(depth, n_features))
     leaves = dict(leaf_columns=n_cls, link=link,
-                  resolve_selects_per_tree=resolve_selects(depth, n_cls))
+                  resolve_selects_per_tree=resolve_selects(depth, n_cls),
+                  resolve_gathers_per_tree=resolve_gathers(depth, n_cls))
     if not served:
         return ObliviousPlan(*said, 0, 0, 0, 0, row_bytes, **leaves)
     groups = max(1, -(-n_trees // GROUP))
@@ -306,7 +356,7 @@ def predict_oblivious_fits(depth: int, n_features: int,
     use_pallas=None (ops/predict.resolve_use_pallas), the ONE rule. The
     tree count is no term of it."""
     row_bytes = row_operand_dtype(row_dtype).itemsize
-    return resolve_selects(depth, n_cls) <= _MAX_SELECTS and _vmem_bytes(
+    return _mux_selects(depth, n_cls) <= _MAX_SELECTS and _vmem_bytes(
         depth, n_features, row_bytes, n_cls) <= _VMEM_BUDGET_BYTES
 
 
@@ -328,18 +378,48 @@ def _leaves(leaf_rows, rows: int) -> list:
             for i in range(leaf_rows.shape[0])]
 
 
+def _into_output(leaf, out_ref, c: int, r0):
+    """Class c's leaf values `leaf [rows, 128]` of a block of rows from r0,
+    summed over the group's trees into row c of the output: the lanes
+    summed with the rows on the lanes, so turn the block over and add down
+    the sublanes."""
+    out_ref[c:c + 1, pl.ds(r0, leaf.shape[0])] += jnp.sum(leaf.T, axis=0,
+                                                          keepdims=True)
+
+
 def _resolve(bits: list, leaves: list, out_ref, r0):
     """The RESOLVE of a block of rows whose D bit planes are `bits`: for
     each leaf column c the multiplexer over the group's `leaves` of that
     class (2^D of the C 2^D), the block turned over and added into row c
     of the output, the rows from r0."""
-    rows, per_class = bits[0].shape[0], 1 << len(bits)
+    per_class = 1 << len(bits)
     for c in range(len(leaves) // per_class):
-        leaf = _mux(bits, leaves, len(bits) - 1, c * per_class)  # [rows, 128]
-        # The lanes summed with the rows on the lanes: turn the block over,
-        # add down the sublanes.
-        out_ref[c:c + 1, pl.ds(r0, rows)] += jnp.sum(leaf.T, axis=0,
-                                                     keepdims=True)
+        _into_output(_mux(bits, leaves, len(bits) - 1, c * per_class),
+                     out_ref, c, r0)
+
+
+def _gathered_leaves(idx, leaf_rows, depth: int):
+    """The leaf lookup where `_gathered` says so: each class's leaf values
+    `[rows, 128]` of the leaf indices `idx [rows, 128]`, class by class.
+    Class c's 2^D leaves are 2^(D-3) vregs of `leaf_rows` (row c 2^D + leaf:
+    leaf 8 h + s is sublane s of vreg h); a strip of 8 rows takes the
+    sublane `idx & 7` of each (`vperm.slane`: the index never crosses lanes)
+    and the multiplexer of bits 3 .. D-1 picks among the 2^(D-3) results:
+    the float32 values the multiplexer of all D bits would pick."""
+    n_vregs = 1 << (depth - _GATHER_BITS)
+    strips = [jax.lax.slice_in_dim(idx, s0, s0 + _TIE_ROWS)
+              for s0 in range(0, idx.shape[0], _TIE_ROWS)]
+    low = [s & (_TIE_ROWS - 1) for s in strips]
+    high = [[(s & (_TIE_ROWS << d)) != 0
+             for d in range(depth - _GATHER_BITS)] for s in strips]
+    for v0 in range(0, leaf_rows.shape[0], n_vregs * _TIE_ROWS):
+        vregs = [leaf_rows[v0 + h * _TIE_ROWS:v0 + (h + 1) * _TIE_ROWS, :]
+                 for h in range(n_vregs)]
+        yield jnp.concatenate([
+            _mux(bits, [jnp.take_along_axis(v, lo, axis=0,
+                                            mode="promise_in_bounds")
+                        for v in vregs], len(bits) - 1, 0)
+            for lo, bits in zip(low, high)], axis=0)
 
 
 def _resolve_later(idx_ref, slot: int, leaf_rows, out_ref, r0: int,
@@ -354,11 +434,24 @@ def _resolve_later(idx_ref, slot: int, leaf_rows, out_ref, r0: int,
     resolve that depends on nothing of the select into one stretch between
     two selects, where the MXUs wait for it (PERF.md section 6, PR 40):
     tied to the matmuls, the strips' selects are issued in the slots the
-    next matmuls leave empty."""
+    next matmuls leave empty. The block's leaves are looked up by sublane
+    gathers or by the multiplexer, as `_gathered` says of the table."""
     shape = (_LANES, _LANES)
-    leaves = _leaves(leaf_rows, _LANES)
-    masks = [jnp.full(shape, 1 << d, jnp.int32) for d in range(depth)]
-    zero = jnp.zeros(shape, jnp.int32)
+    if _gathered(depth, leaf_rows.shape[0] >> depth):
+        def lookup(idx, b0):
+            for c, leaf in enumerate(_gathered_leaves(idx, leaf_rows, depth)):
+                _into_output(leaf, out_ref, c, r0 + b0)
+    else:
+        leaves = _leaves(leaf_rows, _LANES)
+        masks = [jnp.full(shape, 1 << d, jnp.int32) for d in range(depth)]
+        zero = jnp.zeros(shape, jnp.int32)
+
+        def lookup(idx, b0):
+            # (the bits before `r0 + b0`: the order of the one-column trace,
+            # whose Mosaic digests the compile check holds to the parent's)
+            bits = [jax.lax.ne(jax.lax.bitwise_and(idx, m), zero)
+                    for m in masks]
+            _resolve(bits, leaves, out_ref, r0 + b0)
     zero_strip = jnp.zeros((_TIE_ROWS, _LANES), jnp.int32)
 
     def block(b0, after=()):
@@ -374,8 +467,7 @@ def _resolve_later(idx_ref, slot: int, leaf_rows, out_ref, r0: int,
                     jax.lax.ne(tie, tie), zero_strip,
                     jax.lax.slice_in_dim(idx, s0, s0 + _TIE_ROWS)))
             idx = jax.lax.concatenate(strips, 0)
-        bits = [jax.lax.ne(jax.lax.bitwise_and(idx, m), zero) for m in masks]
-        _resolve(bits, leaves, out_ref, r0 + b0)
+        lookup(idx, b0)
 
     return block
 
@@ -478,7 +570,8 @@ def _oblivious_kernel(x_ref, sel_ref, thr_ref, leaf_ref, out_ref, *scratch,
             # values in VMEM (26,427 bundles a sub-tile at depth 6 and 7
             # classes, 44,085 of their operands spilled); over blocks of
             # 128 rows, the indices packed into one plane as the pipelined
-            # step packs them, 20,867 (compile check, PR 57).
+            # step packs them, 20,867 (compile check, PR 57), and 6,622
+            # with the leaves gathered from the plane (PR 58).
             scratch[0][0] = _leaf_index(bits)
             _in_blocks(_resolve_later(scratch[0], 0, leaf_ref.at[0], out_ref,
                                       r0, depth), sub_rows)

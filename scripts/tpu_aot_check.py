@@ -595,11 +595,16 @@ def kernel_cases() -> list:
         KernelCase("oblivious/28f/130x6/1000rows", True,
                    _oblivious_case(1_000, hf, 130, 6)),
         # VECTOR LEAVES (PR 57): CatBoost's MultiClass defaults over
-        # Covertype's chunk (8 groups, ONE K-block, 7 classes: 441 selects
-        # a (row, tree), the rolled step), the longest resolve the dispatch
-        # rule admits (7 classes at depth 7: 889 of 1023) and the longest
-        # that is unrolled beside a select (7 classes at depth 5: 217 of
-        # 255, a carried leaf table of 7 x 16 KB).
+        # Covertype's chunk (8 groups, ONE K-block, 7 classes, the rolled
+        # step; since PR 58 the leaves looked up by sublane gathers, the
+        # `tpu.dynamic_gather` these cases lower: 56 gathers and 49 selects
+        # a (row, tree) where the multiplexer took 441 selects), the longest
+        # resolve the dispatch rule admits (7 classes at depth 7: a
+        # multiplexer of 889 selects of 1023; 112 gathers and 105 selects)
+        # and the longest that is unrolled beside a select (7 classes at
+        # depth 5: 217 of 255, a carried leaf table of 7 x 16 KB), and the
+        # shortest gather (depth 3: ONE vreg a class, a gather and no
+        # select; under depth 3 the multiplexer serves).
         KernelCase("oblivious/54f/1000x6xC7", True,
                    _oblivious_case(2_000_000, COVERTYPE["features"], 1000, 6,
                                    classes=7)),
@@ -608,6 +613,9 @@ def kernel_cases() -> list:
                                    classes=7)),
         KernelCase("oblivious/54f/130x5xC7", True,
                    _oblivious_case(4_999, COVERTYPE["features"], 130, 5,
+                                   classes=7)),
+        KernelCase("oblivious/54f/130x3xC7", True,
+                   _oblivious_case(4_999, COVERTYPE["features"], 130, 3,
                                    classes=7)),
         # Opt-in kernels.
         KernelCase("hist/higgs/255bins/N=32/int8", False,

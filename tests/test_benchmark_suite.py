@@ -23,6 +23,15 @@ One more pins a COUNT of the program's that a later PR took down
 kernel asks 12. It runs whole against the program under DENSE spans, the
 form it pins (what a model gets whose blocks do not split), and the cell's
 model as the program really builds it is held to its counts right after.
+
+And one pins a count that PR 58 took down (`MUX_SELECTS`, below): the
+Covertype CatBoost cell's `resolve_selects_per_tree` as 441, seven
+multiplexers of 63 selects, where vector leaves of depth 3 or more are looked
+up by sublane gathers since PR 58 and the lookup costs 105 VALU operations a
+(row, tree), 56 of them gathers. It runs whole against the program under the
+MULTIPLEXER, the form it pins (what one column and vector leaves under depth 3
+get), and the cell's model as the program really builds it is held to `[105,
+56]` right after.
 """
 
 import importlib
@@ -91,6 +100,15 @@ for _name in PINNED:
     globals()[_name] = _with_later_entries_first(globals()[_name])
 
 
+def _last_call_said(*keys):
+    """Those counts of the newest `ddt:predict` root span."""
+    from ddt_tpu.telemetry.annotations import recent_spans
+
+    counts = [sp for sp in recent_spans()
+              if sp["name"] == "ddt:predict"][-1]["counts"]
+    return [counts[k] for k in keys]
+
+
 DENSE_TILES = "test_correct_leafwise_cat__cat_metrics_are_counted_by_name"
 
 
@@ -98,7 +116,6 @@ def _under_dense_set_spans(test):
     def run(leafwise_cat_job, monkeypatch):
         from ddt_tpu.backends import get_backend
         from ddt_tpu.models import tree
-        from ddt_tpu.telemetry.annotations import recent_spans
 
         # (the model's tables live in the backend's cache under the model's
         # token: emptied on both sides, so each form is built where asked)
@@ -109,12 +126,44 @@ def _under_dense_set_spans(test):
             test(leafwise_cat_job)
         cache.clear()
         leafwise_cat_job.one_job()
-        counts = [sp for sp in recent_spans()
-                  if sp["name"] == "ddt:predict"][-1]["counts"]
-        assert [counts[k] for k in (
+        assert _last_call_said(
             "path_mxu_tiles_per_tree", "catset_mxu_tiles_per_tree",
-            "select_mxu_tiles", "select_k_blocks")] == [12, 7, 8, 7]
+            "select_mxu_tiles", "select_k_blocks") == [12, 7, 8, 7]
     return run
 
 
 globals()[DENSE_TILES] = _under_dense_set_spans(globals()[DENSE_TILES])
+
+
+MUX_SELECTS = "test_correct_oblivious_mc__mc_metrics_are_counted_by_name"
+
+
+def _under_the_multiplexer(test):
+    def run(oblivious_mc_job, monkeypatch):
+        import jax
+
+        from ddt_tpu.backends import get_backend
+        from ddt_tpu.ops import predict_oblivious
+
+        # (the model's plan lives in the backend's cache under the model's
+        # token and its traced kernel in jit's: emptied on both sides, so
+        # each form is built and traced where asked)
+        cache = get_backend(oblivious_mc_job.cfg)._predict_cache
+        with monkeypatch.context() as m:
+            m.setattr(predict_oblivious, "_gathered", lambda *a: False)
+            cache.clear()
+            jax.clear_caches()
+            test(oblivious_mc_job)
+            muxed = oblivious_mc_job.one_job()
+        cache.clear()
+        jax.clear_caches()
+        got = oblivious_mc_job.one_job()
+        assert _last_call_said("resolve_selects_per_tree",
+                               "resolve_gathers_per_tree") == [105, 56]
+        # the gathered lookup picks the multiplexer's float32 values and
+        # sums them in its order: the same probabilities, to the bit
+        assert (got == muxed).all() and (got == oblivious_mc_job.sound).all()
+    return run
+
+
+globals()[MUX_SELECTS] = _under_the_multiplexer(globals()[MUX_SELECTS])

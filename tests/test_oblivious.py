@@ -295,11 +295,12 @@ def test_the_plan_at_the_epsilon_models_shape():
                                                  + 8 * 128 * 4
                                                  + 64 * 128 * 4),
         "row_operand_bytes": 1, "leaf_columns": 1, "link": "none",
-        "resolve_selects_per_tree": 63, "resolves_under_select": 0.9921}
+        "resolve_selects_per_tree": 63, "resolve_gathers_per_tree": 0,
+        "resolves_under_select": 0.9921}
     assert plan.root_counts() == {
         "routing_tables": 0, "oblivious": 1, "select_columns_per_tree": 6,
         "select_k_blocks": 16, "leaf_columns": 1, "link": "none",
-        "resolve_selects_per_tree": 63}
+        "resolve_selects_per_tree": 63, "resolve_gathers_per_tree": 0}
     assert plan.blocks == 63 and plan.tile_rows == 2048
     twin = predict_oblivious.oblivious_plan(8000, 6, 2000, served=False)
     assert twin.oblivious == 1 and twin.blocks == 0 and twin.table_bytes == 0

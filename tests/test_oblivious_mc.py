@@ -47,6 +47,17 @@ def cfg(impl):
     return TrainConfig(backend="tpu", predict_impl=impl)
 
 
+def lookup_count(depth, n_classes):
+    """[VALU operations, of them gathers] the leaf lookup costs a (row,
+    tree) by the module's rule: vector leaves of depth 3 or more take
+    2^(D-3) sublane gathers and the 2^(D-3) - 1 selects among their results
+    a class (PR 58), every other shape the multiplexer's 2^D - 1 selects."""
+    if n_classes > 1 and depth >= 3:
+        vregs = 1 << (depth - 3)
+        return [n_classes * (2 * vregs - 1), n_classes * vregs]
+    return [n_classes * ((1 << depth) - 1), 0]
+
+
 def bfloat16(values):
     bits = np.ascontiguousarray(values, np.float32).view(np.uint32)
     return ((bits + 0x7FFF + ((bits >> 16) & 1)) & 0xFFFF0000).view(
@@ -96,7 +107,9 @@ def test_api_predict_margins_and_probabilities(depth, n_classes, n_features,
     root = an.root_spans("predict")[-1]["counts"]
     assert root["classes"] == n_classes and root["oblivious"] == 1
     assert root["leaf_columns"] == n_classes and root["link"] == "softmax"
-    assert root["resolve_selects_per_tree"] == n_classes * ((1 << depth) - 1)
+    assert [root["resolve_selects_per_tree"],
+            root["resolve_gathers_per_tree"]] == lookup_count(depth,
+                                                              n_classes)
 
 
 def test_the_host_walk_and_the_cpu_backend_answer_the_same():
@@ -145,9 +158,14 @@ def test_the_chunk_loop_places_rows_by_class_columns():
 
 # (rows, trees, depth, classes, features): a step of one sub-tile, of two
 # with a ragged tile after; one group and three; pipelined (depth 5 x 7: 217
-# selects; depth 1) and rolled (depth 6 x 7: 441); two K-blocks.
+# selects; depth 1) and rolled (depth 6 x 7: 441); two K-blocks. The lookup
+# by the rule's both sides (PR 58): the multiplexer (depth 1 and 2) and the
+# sublane gather from its shortest (depth 3: one vreg a class, one gather
+# and no select; pipelined) through depth 4 (two vregs, one select) to the
+# deepest tree the kernel takes at 7 classes (depth 7: 16 vregs a class).
 KERNEL = [(300, 5, 3, 3, 5), (2049, 300, 5, 7, 54), (1100, 130, 6, 7, 54),
-          (1025, 130, 1, 3, 200), (2048, 130, 6, 3, 28)]
+          (1025, 130, 1, 3, 200), (2048, 130, 6, 3, 28),
+          (1100, 130, 4, 3, 54), (1030, 130, 7, 7, 54), (300, 5, 2, 7, 5)]
 
 
 @pytest.mark.parametrize("n_rows,n_trees,depth,n_classes,n_features", KERNEL,
@@ -183,6 +201,49 @@ def test_kernel_and_twin_agree_to_the_bit_on_dyadic_leaves(
     assert np.array_equal(np.asarray(twin), want)
     assert predict_oblivious._pipelined(depth, 2, n_classes) == (
         n_classes * (L - 1) <= 255)
+    assert predict_oblivious._gathered(depth, n_classes) == (depth >= 3)
+
+
+@pytest.mark.parametrize("n_classes", [2, 3, 7])
+@pytest.mark.parametrize("depth", [3, 4, 6, 7])
+def test_the_gathered_lookup_is_the_multiplexers(depth, n_classes):
+    """The lookup by sublane gathers (`_gathered_leaves`) against the
+    multiplexer of all D bits (`_mux`), in the Pallas interpreter, on an
+    index plane that holds every leaf 0 .. 2^D - 1 in every sublane
+    position of a strip, the lanes out of step with one another: the same
+    float32 value for every (row, tree) and class, to the bit."""
+    from jax.experimental import pallas as pl
+
+    po = predict_oblivious
+    L = 1 << depth
+    n_rows = 8 * L
+    r, lane = np.arange(n_rows)[:, None], np.arange(128)[None, :]
+    idx = ((r // 8 + 5 * (r % 8) + 3 * lane) % L).astype(np.int32)
+    for s in range(8):      # sublane position s of the strips: every leaf
+        assert len(set(idx[s::8, 77])) == L
+    leaf = np.random.default_rng(depth * 10 + n_classes).standard_normal(
+        (n_classes * L, 128)).astype(np.float32)
+
+    def kernel(idx_ref, leaf_ref, gathered_ref, muxed_ref):
+        got = idx_ref[:]
+        for c, plane in enumerate(po._gathered_leaves(got, leaf_ref, depth)):
+            gathered_ref[c] = plane
+        bits = [(got & (1 << d)) != 0 for d in range(depth)]
+        leaves = po._leaves(leaf_ref, n_rows)
+        for c in range(n_classes):
+            muxed_ref[c] = po._mux(bits, leaves, depth - 1, c * L)
+
+    planes = jax.ShapeDtypeStruct((n_classes, n_rows, 128), jnp.float32)
+    gathered, muxed = pl.pallas_call(
+        kernel, out_shape=(planes, planes), interpret=True)(
+            jnp.asarray(idx), jnp.asarray(leaf))
+    want = leaf.reshape(n_classes, L, 128)[:, idx, lane]
+    assert np.array_equal(np.asarray(muxed), want)
+    assert np.array_equal(np.asarray(gathered), want)
+    assert po.resolve_gathers(depth, n_classes) == n_classes * L // 8
+    assert [po.resolve_selects(depth, n_classes),
+            po.resolve_gathers(depth, n_classes)] == lookup_count(
+                depth, n_classes)
 
 
 def test_the_entry_takes_the_link_and_refuses_what_it_cannot():
@@ -245,7 +306,33 @@ def test_the_rule_is_told_the_leaf_columns():
              if sp["name"] == "ddt:predict:ensemble"][-1]["counts"]
     assert built["trees_per_step"] == 0 and built["table_blocks"] == 0
     assert built["leaf_columns"] == 7
-    assert built["resolve_selects_per_tree"] == 7 * 255
+    # (the lookup's count by its rule, had the kernel served: 32 gathers
+    # and 31 selects a class)
+    assert built["resolve_selects_per_tree"] == 7 * 63
+    assert built["resolve_gathers_per_tree"] == 7 * 32
+
+
+@pytest.mark.parametrize("n_classes", [1, 2, 3, 7])
+@pytest.mark.parametrize("depth", range(1, 11))
+def test_the_rules_answer_as_they_did_for_the_multiplexer(depth, n_classes):
+    """`_pipelined` and `predict_oblivious_fits` are rules about the
+    multiplexer's trace, C (2^D - 1) selects, and the gathered lookup (PR
+    58) moved neither: what the parent answered at every shape (read
+    there: the formula at 54 columns, where VMEM binds nowhere; at 2000
+    columns depth 7 fits and depth 8 does not, one column or seven)."""
+    po = predict_oblivious
+    muxed = n_classes * ((1 << depth) - 1)
+    assert po._pipelined(depth, 2, n_classes) == (muxed <= 255)
+    assert not po._pipelined(depth, 1, n_classes)
+    assert po.predict_oblivious_fits(depth, 54, n_cls=n_classes) == (
+        muxed <= 1023)
+    assert po.predict_oblivious_fits(depth, 2000, n_cls=n_classes) == (
+        muxed <= 1023 and depth <= 7)
+    # the count the span gives is the lookup's own, by the other rule
+    plan = po.oblivious_plan(300, depth, 54, n_cls=n_classes)
+    assert [plan.resolve_selects_per_tree,
+            plan.resolve_gathers_per_tree] == lookup_count(depth, n_classes)
+    assert (plan.resolves_under_select > 0) == (muxed <= 255)
 
 
 def test_the_plan_at_the_covertype_models_shape():
@@ -259,14 +346,26 @@ def test_the_plan_at_the_covertype_models_shape():
         "table_bytes": 8 * (6 * 64 * 128 * 2 + 8 * 128 * 4
                             + 7 * 64 * 128 * 4),
         "row_operand_bytes": 1, "leaf_columns": 7, "link": "softmax",
-        "resolve_selects_per_tree": 441, "resolves_under_select": 0.0}
-    assert plan.root_counts()["resolve_selects_per_tree"] == 441
+        # 8 gathers and 7 selects a class where the multiplexer took 63
+        "resolve_selects_per_tree": 105, "resolve_gathers_per_tree": 56,
+        "resolves_under_select": 0.0}
+    root = plan.root_counts()
+    assert [root["resolve_selects_per_tree"],
+            root["resolve_gathers_per_tree"]] == [105, 56]
+    # one column at that shape, and vector leaves under a vreg's 8 leaves:
+    # the multiplexer
+    for depth, n_cls, said in ((6, 1, [63, 0]), (2, 7, [21, 0])):
+        counts = predict_oblivious.oblivious_plan(
+            1000, depth, 54, n_cls=n_cls).root_counts()
+        assert [counts["resolve_selects_per_tree"],
+                counts["resolve_gathers_per_tree"]] == said
     # seven classes at depth 5 are unrolled beside a select: 217 selects
     assert predict_oblivious.oblivious_plan(
         1000, 5, 54, n_cls=7).resolves_under_select == round(15 / 16, 4)
     idx, carry = predict_oblivious._scratch_shapes(5, 2, 7)
     assert idx.shape == (2, 1024, 128) and carry.shape == (7 * 32, 128)
-    # 441 selects: the rolled step, ONE plane of indices for its blocks
+    # a multiplexer of 441 selects: the rolled step, ONE plane of indices
+    # for its blocks
     idx, = predict_oblivious._scratch_shapes(6, 2, 7)
     assert idx.shape == (1, 1024, 128)
     assert predict_oblivious._scratch_shapes(9, 2, 1) == []
@@ -281,7 +380,8 @@ def test_the_span_and_the_stage_map_say_the_link():
                  if sp["name"] == "ddt:predict:ensemble"][-1]["counts"]
         assert built["oblivious"] == 1 and built["leaf_columns"] == 7
         assert built["link"] == said
-        assert built["resolve_selects_per_tree"] == 441
+        assert built["resolve_selects_per_tree"] == 105
+        assert built["resolve_gathers_per_tree"] == 56
         assert built["select_columns_per_tree"] == 6
         assert built["oblivious_mxu_tiles_per_tree"] == 0.0469
         assert list(built)[-len(predict_oblivious.SPAN_COUNTS):] == list(
@@ -392,7 +492,9 @@ def test_save_load_cache_token_and_cli(tmp_path, capsys):
     phases = rec["phases_ms"]
     assert phases["oblivious"] == 1 and phases["leaf_columns"] == 4
     assert phases["link"] == "softmax"
-    assert phases["resolve_selects_per_tree"] == 4 * 31
+    # depth 5: 4 gathers and 3 selects a class
+    assert phases["resolve_selects_per_tree"] == 4 * 7
+    assert phases["resolve_gathers_per_tree"] == 4 * 4
     want = numpy_predict.softmax(ens.predict_raw(X).astype(np.float64))
     got = np.load(out)
     assert got.shape == (300, 4)

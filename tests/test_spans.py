@@ -441,6 +441,7 @@ def test_an_oblivious_model_says_which_form_serves(impl, monkeypatch):
         row_operand_bytes=1,
         # one column a leaf: a multiplexer of 2^3 - 1 selects, no link
         leaf_columns=1, link="none", resolve_selects_per_tree=7,
+        resolve_gathers_per_tree=0,
         # one group: of a row tile's two resolves the first runs beside
         # the second sub-tile's select
         resolves_under_select=0.5 * served)

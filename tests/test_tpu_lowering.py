@@ -482,7 +482,11 @@ def test_case_table_covers_the_default_dispatch():
                    "oblivious/129f",
                    # the pipeline's edges: the deepest tree it unrolls, a
                    # step of one sub-tile
-                   "oblivious/28f/130x8", "oblivious/28f/130x6/1000rows"):
+                   "oblivious/28f/130x8", "oblivious/28f/130x6/1000rows",
+                   # vector leaves: the Covertype CatBoost model's chunk and
+                   # the gathered lookup's edges (depth 7, 5 and 3)
+                   "oblivious/54f/1000x6xC7", "oblivious/54f/130x7xC7",
+                   "oblivious/54f/130x5xC7", "oblivious/54f/130x3xC7"):
         assert any(needle in n for n in names), (needle, names)
 
 
