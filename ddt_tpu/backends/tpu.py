@@ -43,9 +43,7 @@ import numpy as np
 
 from ddt_tpu.backends.base import DeviceBackend, HostTree
 from ddt_tpu.config import TrainConfig
-from ddt_tpu.models.tree import (CompiledNodeList, CompiledOblivious,
-                                 NodeListEnsemble, ObliviousEnsemble,
-                                 TreeEnsemble)
+from ddt_tpu.models.tree import TreeEnsemble
 from ddt_tpu.ops import grad as grad_ops
 from ddt_tpu.ops import grow as grow_ops
 from ddt_tpu.ops import histogram as hist_ops
@@ -729,7 +727,7 @@ class TPUDevice(DeviceBackend):
         "_hist_fns", "_grow_fn", "_grow_masked_fn", "_grad_fn",
         "_rounds_fns", "_rounds_masked_fns", "_rounds_eval_fns",
         "_eval_fns", "_stream_cache", "_apply_fn", "_row_mask_fn",
-        "_loss_fn", "_predict_cache", "_predict_impl_resolved",
+        "_loss_fn", "_predict_cache",
     )
 
     def rotate_row_partitions(self) -> bool:
@@ -1607,13 +1605,11 @@ class TPUDevice(DeviceBackend):
     def links_on_device(self, ens) -> bool:
         """Whether `predict_raw(..., link=True)` answers this model's
         probabilities: the link function taken by the scoring program, on
-        the device, under the stage `predict:link`. A node list of
-        softmax's round-major trees and an oblivious ensemble of vector
-        leaves (their [rows, C] margins are on the device as the program
-        ends); every other model's link is the caller's
-        (`utils/metrics.predict_proba_np`, api.predict)."""
-        return isinstance(ens, (NodeListEnsemble, ObliviousEnsemble)) \
-            and ens.loss == "softmax"
+        the device, under the stage `predict:link`. The model's layout says
+        which losses' links its program takes (ops/predict.LAYOUTS); every
+        other model's is the caller's (`utils/metrics.predict_proba_np`,
+        api.predict)."""
+        return ens.loss in predict_ops.LAYOUTS[ens.layout].links
 
     def predict_raw(self, ens: TreeEnsemble, Xb: np.ndarray,
                     compiled=None, link: bool = False) -> np.ndarray:
@@ -1653,7 +1649,7 @@ class TPUDevice(DeviceBackend):
         chunk = self.predict_chunk_rows(Xb.shape[1]) * max(
             1, self.row_shards)
         fn, ens_dev, classes, plan = self._predict_entry(ens, compiled,
-                                                         link)
+                                                         link)[:4]
         # (the rows of the executable this call runs: what the stage map is
         # read at, `_stage_scoring_program`)
         self._scoring_rows = min(R, chunk)
@@ -1670,10 +1666,7 @@ class TPUDevice(DeviceBackend):
         # the call: every table block once a row tile where they stream,
         # 0 where one block holds them all (fetched once, resident).
         counts["tables_streamed_bytes"] = 0
-        # Which form of which kernel serves: the missing and categorical
-        # tables the heap kernel routes by (0 also when it does not
-        # serve), and for a node list the path-matrix form and the MXU
-        # weight tiles it asks a tree.
+        # What the layout's plan says of itself on every call's root.
         counts.update(plan.root_counts())
         if plan.blocks > 1:
             shards = max(1, self.row_shards)
@@ -1808,87 +1801,17 @@ class TPUDevice(DeviceBackend):
 
     @functools.cached_property
     def _predict_cache(self) -> dict:
-        # token -> (fn, device arrays, table plan); insertion order = LRU
-        # order.
-        return {}
-
-    @functools.cached_property
-    def _predict_impl_resolved(self) -> dict:
-        # token -> the tier _predict_fn actually compiled ("lut4" |
-        # "lut" | "f32") — pruned with _predict_cache.
+        # token -> (fn, device arrays, classes, table plan, the tier that
+        # serves); insertion order = LRU order.
         return {}
 
     def resolved_predict_impl(self, token: str) -> str:
-        """The scoring tier that ACTUALLY serves model `token` after
-        the fallback ladder ("lut4" | "lut" | "f32"; "f32" when the
-        model never scored here). The serving tier stamps this into
-        /healthz and serve_latency so a silent VMEM-guard fallback is
-        an observable fact, not a debug-log line."""
-        return self._predict_impl_resolved.get(token, "f32")
-
-    @property
-    def _use_pallas(self) -> "bool | None":
-        """cfg.predict_impl as predict_raw_effective's use_pallas value
-        (None = auto-dispatch; ops/predict.resolve_use_pallas). "lut" /
-        "lut4" resolve here to the f32 auto value — it is the FALLBACK
-        the quantized dispatch in _predict_fn degrades to when the LUT
-        kernels' VMEM budgets refuse the shape."""
-        return {"auto": None, "pallas": True, "onehot": False,
-                "lut": None, "lut4": None}[self.cfg.predict_impl]
-
-    def _lut_fn(self, ce, n_features: int, tier: str = "lut"):
-        """(jitted LUT scoring fn, device operand tuple) for one model
-        version at quantization `tier` ("lut" = int8, "lut4" = int4
-        bit-packed), or None when the shape exceeds that kernel's
-        budget (predict_lut_fits / predict_lut4_fits — the
-        pallas-vmem-guard contract; the caller walks the fallback
-        ladder). Tables quantize on host once per model version; the
-        error bound rides on the tables (docs/SERVING.md "Quantized
-        serving")."""
-        from ddt_tpu.ops import predict_lut
-
-        # ce.quantize() memoizes: when the serving tier already
-        # quantized this model version at publish (for its error-bound
-        # reporting), this is a dict hit, not a second O(model) pass.
-        with phase_span("predict:ensemble:pack") as sp:
-            if tier == "lut4":
-                tables = ce.quantize(leaf_dtype="int4")
-                packed = tables.pack_int4()
-                if not predict_lut.predict_lut4_fits(
-                        tables.n_trees_padded, tables.tree_chunk,
-                        tables.max_depth, n_features, tables.n_classes_out,
-                        thr_packed=packed.thr_packed):
-                    return None
-                host_ops = packed.ops
-                static = packed.static_kwargs()
-                core = predict_lut.predict_effective_lut4_ops
-            else:
-                tables = ce.quantize()
-                if not predict_lut.predict_lut_fits(
-                        tables.n_trees_padded, tables.tree_chunk,
-                        tables.max_depth, n_features, tables.n_classes_out):
-                    return None
-                host_ops = predict_lut.lut_device_operands(tables)
-                static = dict(
-                    max_depth=tables.max_depth,
-                    learning_rate=tables.learning_rate,
-                    base=tables.base_score, n_classes=tables.n_classes_out,
-                    tree_chunk=tables.tree_chunk,
-                    n_trees_padded=tables.n_trees_padded,
-                    missing_bin_value=tables.missing_bin_value,
-                    use_missing=tables.eff_dl is not None,
-                    use_cat=tables.eff_cat is not None,
-                    use_scale=tables.leaf_scale is not None,
-                )
-                core = predict_lut.predict_effective_lut_ops
-            sp.counts["bytes"] = sum(a.nbytes for a in host_ops)
-        dev_ops = self._put_tables(host_ops)
-
-        def lut0(*args):
-            *ops, Xc = args
-            return core(tuple(ops), Xc, **static)
-
-        return jax.jit(lut0), dev_ops
+        """The scoring tier that ACTUALLY serves model `token` after the
+        fallback ladder ("lut4" | "lut" | "f32"; "f32" when the model never
+        scored here): the last item of its cache entry. The serving tier
+        stamps it into /healthz and serve_latency, so that a silent
+        VMEM-guard fallback is an observable fact."""
+        return self._predict_cache.get(token, ("f32",))[-1]
 
     def _predict_fn(self, ens: TreeEnsemble, compiled=None):
         """(jittable scoring fn, device-resident compiled-ensemble arrays):
@@ -1898,34 +1821,19 @@ class TPUDevice(DeviceBackend):
     def _predict_entry(self, ens: TreeEnsemble, compiled=None,
                        link: bool = False):
         """(jittable scoring fn, device-resident compiled-ensemble arrays,
-        the model's class count, how the traversal kernel takes the node
-        tables: the ops/predict_pallas.TablePlan of `_build_predict_fn`).
+        the model's class count, the plan of its ops/predict.
+        ScoringProgram, the tier that serves: `resolved_predict_impl`).
 
-        The pushed-down/padded scoring layout (models/tree.
-        CompiledEnsemble) and its device copies are cached per model
-        version: the cache key is a content digest of the node arrays, so
-        in-place trainer mutation can never serve stale trees, and a hit
-        skips pushdown AND re-upload entirely. The digest is the span
-        `ddt:predict:token` (1.2 ms for 1000 trees of depth 6 on the
-        v5e's host), a miss the span `ddt:predict:ensemble` (18 ms there;
-        PERF.md section 5). Hits feed the
-        run log's `compiled_ensemble_cache_hits` counter.
-
-        `compiled` (a CompiledEnsemble snapshot the caller already
-        built) keys the cache on its `token` directly — no per-call
-        full-array hash — and seeds a miss without rebuilding the
-        layout. The serving tier's request path rides this.
-
-        With cfg.predict_impl="lut" the cached entry is the int8
-        quantized path (ops/predict_lut.py): tables quantize + upload
-        once per model version; shapes past the LUT kernel's VMEM
-        budget fall back to the f32 path (predict_lut_fits). "lut4" is
-        the bit-packed int4 tier one rung up, degrading int4 -> int8 ->
-        f32 down the same guards; whatever rung actually serves is
-        recorded per token (`resolved_predict_impl`) so the serving
-        tier can stamp the TRUE tier into /healthz + serve_latency —
-        a silent guard trip must be visible in telemetry, not only in
-        debug logs."""
+        The model's scoring layout (`ens.compile()`) and its device copies
+        are cached per model version: the key is a content digest of the
+        node arrays, so in-place trainer mutation can never serve stale
+        trees, and a hit skips the build AND the re-upload. The digest is
+        the span `ddt:predict:token`, a miss the span
+        `ddt:predict:ensemble` (what each takes: PERF.md section 5); hits
+        feed the run log's `compiled_ensemble_cache_hits` counter.
+        `compiled` (a compiled form the caller already built) keys the
+        cache on its `token` directly, no per-call full-array hash, and
+        seeds a miss: the serving tier's request path."""
         if link and not self.links_on_device(ens):
             raise ValueError(
                 "predict_raw(link=True): this model's link function is not "
@@ -1943,269 +1851,49 @@ class TPUDevice(DeviceBackend):
             tele_counters.record_compiled_ensemble_hit()
             return hit
         with phase_span("predict:ensemble") as sp:
-            fn, ens_dev, resolved, classes, plan = self._build_predict_fn(
-                ens, compiled, link)
+            hit = self._build_predict_fn(ens, compiled, link)
+            _, ens_dev, _, plan, _ = hit
             sp.counts["bytes"] = sum(a.nbytes for a in ens_dev)
-            # Beside table_groups: the share of the groups' lanes that
-            # hold a tree (100 of 128).
+            # (beside the plan's groups: the lanes of them that hold a tree)
             sp.counts["trees"] = ens.n_trees
             sp.counts.update(plan.span_counts())
-        self._predict_cache[token] = hit = (fn, ens_dev, classes, plan)
-        self._predict_impl_resolved[token] = resolved
+        self._predict_cache[token] = hit
         while len(self._predict_cache) > self.PREDICT_CACHE_MAX:
-            gone = next(iter(self._predict_cache))
-            self._predict_cache.pop(gone)
-            self._predict_impl_resolved.pop(gone, None)
+            self._predict_cache.pop(next(iter(self._predict_cache)))
         return hit
 
     def _build_predict_fn(self, ens: TreeEnsemble, compiled,
-                          link: bool = False):
-        """_predict_entry's cache miss: (fn, device arrays, the tier that
-        serves, the model's class count, how the f32 traversal kernel
-        takes the node tables) — layout build or reuse, quantisation, the
-        node tables' upload and the mesh wrapper. The last is that
-        kernel's ops/predict_pallas.TablePlan, NO_PLAN when it does not
-        serve the model: the one-hot path, the LUT tiers."""
-        from ddt_tpu.ops import predict_pallas
-
-        # The build's three stages, children of `ddt:predict:ensemble`:
-        # `compile` (models/tree: the scoring layout from the model's
-        # arrays, a node list's cut into sub-trees with it; nothing to do
-        # where the caller hands the layout), `pack` (what is made of the
-        # layout's tables on the host before they go up) and `upload`
-        # (`_put_tables`).
+                          link: bool = False) -> tuple:
+        """_predict_entry's cache miss, and its entry of the cache. Three
+        stages, children of `ddt:predict:ensemble`: `compile` (models/tree:
+        the scoring layout from the model's arrays; nothing where the
+        caller hands it in), `pack` (the layout's entry, which returns an
+        ops/predict.ScoringProgram: the plan, kernel or twin, the quantized
+        tiers' ladder, what is made of the tables on the host) and `upload`
+        (`_put_tables`, the entry's fill inside it). Which layout it is,
+        the compiled form says; nothing here knows one by name."""
         with phase_span("predict:ensemble:compile", trees=ens.n_trees,
                         nodes=ens.n_nodes) as sp:
             ce = compiled if compiled is not None else ens.compile(
                 tree_chunk=64)
-            if isinstance(ce, CompiledNodeList):
-                sp.counts["subtrees"] = ce.n_subtrees
-        if isinstance(ce, CompiledNodeList):
-            return self._build_paths_fn(ens, ce, link)
-        if isinstance(ce, CompiledOblivious):
-            return self._build_oblivious_fn(ens, ce, link)
-        impl_req = self.cfg.predict_impl
-        lut = None
-        resolved = "f32"
-        plan = predict_pallas.NO_PLAN
-        if impl_req in ("lut", "lut4"):
-            if impl_req == "lut4":
-                lut = self._lut_fn(ce, ens.n_features, tier="lut4")
-                if lut is not None:
-                    resolved = "lut4"
-                else:
-                    log.warning(
-                        "predict_impl='lut4': shape exceeds the int4 "
-                        "kernel's VMEM budget; falling back to the int8 "
-                        "LUT tier")
-            if lut is None:
-                lut = self._lut_fn(ce, ens.n_features, tier="lut")
-                if lut is not None:
-                    resolved = "lut"
-        if lut is not None:
-            fn0, ens_dev = lut
-        else:
-            if impl_req in ("lut", "lut4"):
-                log.warning(
-                    "predict_impl=%r: shape exceeds the LUT kernel's "
-                    "VMEM budget; falling back to the f32 path",
-                    impl_req)
-            with phase_span("predict:ensemble:pack") as sp:
-                tables = ce.arrays()
-                sp.counts["bytes"] = sum(a.nbytes for a in tables)
-            ens_dev = self._put_tables(tables)
-            use_missing = ce.eff_dl is not None
-            use_cat = ce.eff_cat is not None
-            use_pallas = self._use_pallas
-            if predict_ops.resolve_use_pallas(
-                    use_pallas, True, ce.max_depth, ens.n_features,
-                    ce.n_classes_out, use_missing + use_cat):
-                plan = predict_pallas.table_plan(
-                    ce.n_trees_padded, ce.max_depth, ens.n_features,
-                    ce.n_classes_out, None, use_missing + use_cat,
-                    self.PREDICT_ROW_DTYPE)
-
-            static = dict(
-                max_depth=ce.max_depth, learning_rate=ce.learning_rate,
-                base=ce.base_score, n_classes=ce.n_classes_out,
-                tree_chunk=ce.tree_chunk,
-                missing_bin_value=ce.missing_bin_value,
-                use_pallas=use_pallas)
-
-            def fn0(ef, et, bv, coh, *rest,
-                    entry=predict_ops.predict_raw_effective):
-                *opt, Xc = rest
-                opt = list(opt)
-                dl = opt.pop(0) if use_missing else None
-                cn = opt.pop(0) if use_cat else None
-                return entry(ef, et, bv, coh, Xc, eff_dl=dl, eff_cat=cn,
-                             **static)
-
-            self._stage_scoring_program(
-                predict_ops.predict_raw_effective, fn0, ens_dev,
-                ens.n_features)
-
-        return (self._row_sharded(fn0, len(ens_dev), ce.n_classes_out),
-                ens_dev, resolved, ce.n_classes_out, plan)
-
-    def _build_paths_fn(self, ens, ce: CompiledNodeList,
-                        link: bool = False):
-        """_build_predict_fn for a NODE LIST: its path tables up, and the
-        path-matrix scoring program (ops/predict.predict_raw_effective_
-        paths: the Pallas kernel where the dispatch rule takes it, else
-        the jax.numpy form). The plan is an ops/predict_paths.PathPlan.
-        `link`: the program ends in the model's link function (softmax's
-        round-major trees: `links_on_device`)."""
-        from ddt_tpu.ops import predict_paths
-
-        if self.cfg.predict_impl in ("lut", "lut4"):
-            log.warning(
-                "predict_impl=%r: the quantized tiers have no node-list "
-                "form; the f32 path-matrix form serves",
-                self.cfg.predict_impl)
-        use_pallas = self._use_pallas
-        missing_routes = ce.missing_bin_value >= 0
-        # The sub-tree form: the tables' entries are sub-trees, a fourth
-        # table says what their exits are, and vector leaves (or
-        # softmax's round-major trees) answer [rows, C].
-        classes = ce.leaf_columns
-        chain = predict_paths.chain_of(
-            ce.n_trees, classes, ce.leaves.shape[2],
-            ce.select_spans, ce.paths.shape) if ce.chained else None
-        # CATEGORY SETS (ops/predict_paths.py): the one-hot's K-blocks
-        cat = predict_paths.CatSets(
-            ce.cat_blocks, ce.sel.shape[1], ce.select_spans,
-            ce.cat_ordinal_at) if ce.cat_blocks else None
-        plan = predict_paths.path_plan(
-            ce.n_subtrees or ce.n_trees, ce.lanes, ens.n_features,
-            ce.deepest_leaf,
-            served=predict_ops.resolve_use_pallas(
-                use_pallas, True, 0, ens.n_features, classes,
-                path_lanes=ce.lanes,
-                path_exit_lanes=chain.exit_lanes if chain else 0,
-                path_cat_blocks=ce.cat_blocks,
-                path_select_rows=ce.sel.shape[1]),
-            missing_routes=missing_routes, row_dtype=self.PREDICT_ROW_DTYPE,
-            chain=chain, widest_tree=ce.widest_tree, cat=cat)._replace(
-                category_nodes=ce.category_nodes,
-                category_set_bits_max=ce.category_set_bits_max,
-                subtrees_per_tree_max=ce.subtrees_max,
-                single_subtree_trees=ce.single_subtree_trees,
-                link=ce.loss if link else "none",
-                spine_copies_per_subtree=round(
-                    ce.spine_copies / max(ce.n_subtrees, 1), 2),
-                pieces_per_subtree=round(
-                    (ce.pieces or 1) / max(ce.n_subtrees, 1), 2),
-                glue_copies_per_subtree=round(
-                    ce.glue_copies / max(ce.n_subtrees, 1), 2))
-        # What every chunk's program would otherwise make of the tables is
-        # made here, once a model: the select that answers two nodes a lane
-        # with its shifted thresholds (`pack_select`), and the trees that
-        # fill the kernel's last block (no node, no leaf of any length:
-        # they add 0).
+            sp.counts.update(getattr(ce, "compile_counts", {}))
+        entry = predict_ops.layout_entry(ce.layout)     # the ONE lookup
+        impl = self.cfg.predict_impl
         with phase_span("predict:ensemble:pack") as sp:
-            tables = ce.arrays()
-            if plan.select_nodes_per_lane == 2:
-                tables = (*predict_paths.pack_select(
-                    ce.sel, ce.planes, ens.n_features, xp=np), *tables[2:])
-            sp.counts["bytes"] = sum(a.nbytes for a in tables)
-        # The fill goes on inside the put, a table at a time: a padded copy
-        # is gone when its transfer is (2.76 GB of tables in the XGBoost
-        # cell, whose last block lacks 3 sub-trees).
-        fill = ((0, max(0, plan.trees_per_step * plan.table_blocks
-                        - len(ce.sel))), (0, 0), (0, 0))
-        # (the two small tables of category sets are the model's, not a
-        # tree's: they go up as they are)
-        per_tree = 4 if chain else 3
-        ens_dev = self._put_tables(
-            np.pad(a, fill, constant_values=v)
-            if fill[0][1] and i < per_tree else a
-            for i, (a, v) in enumerate(zip(tables, (0, -1.0, 0, 0, 0))))
-
-        # Bound here: fn0 outlives this call in the stage registry, and
-        # must not hold the host copy of the path tables (78 MB at 500
-        # trees x 255 leaves; 1.35 GB at 100 trees x 4,000 vector leaves).
-        static = dict(learning_rate=ce.learning_rate, base=ce.base_score,
-                      use_pallas=use_pallas, missing_routes=missing_routes)
-        if chain:
-            static.update(n_trees=ce.n_trees, leaf_columns=classes,
-                          mean=ce.mean, select_spans=chain.select_spans,
-                          **({"link": plan.link} if link else {}))
-        elif cat and cat.spans:
-            static.update(select_spans=cat.spans,
-                          cat_ordinal_at=cat.ordinal_at)
-
-        # (two functions: the uncut form keeps its program's parameter
-        # names, so its HLO is what it was)
-        def fn0(sel, planes, paths, Xc,
-                entry=predict_ops.predict_raw_effective_paths):
-            return entry(sel, planes, paths, Xc, **static)
-
-        def fn0_chain(sel, planes, paths, leaves, Xc,
-                      entry=predict_ops.predict_raw_effective_paths):
-            return entry(sel, planes, paths, Xc, leaves=leaves, **static)
-
-        def fn0_sets(sel, planes, paths, cat_expand, cat_bins, Xc,
-                     entry=predict_ops.predict_raw_effective_paths):
-            return entry(sel, planes, paths, Xc, cat_expand=cat_expand,
-                         cat_bins=cat_bins, **static)
-
-        if chain:
-            fn0 = fn0_chain
-        elif cat:
-            fn0 = fn0_sets
-
-        self._stage_scoring_program(
-            predict_ops.predict_raw_effective_paths, fn0, ens_dev,
-            ens.n_features)
-        # an averaged forest answers [rows, C] whatever C, one column too
-        return (self._row_sharded(fn0, len(ens_dev),
-                                  2 if ce.mean or classes > 1 else 1),
-                ens_dev, "f32", classes, plan)
-
-    def _build_oblivious_fn(self, ens, ce: CompiledOblivious,
-                            link: bool = False):
-        """_build_predict_fn for an OBLIVIOUS ensemble: its group tables up,
-        and the oblivious scoring program (ops/predict.predict_raw_
-        effective_oblivious: the Pallas kernel where the dispatch rule
-        takes it, else the jax.numpy form). The plan is an
-        ops/predict_oblivious.ObliviousPlan. `link`: the program ends in
-        the model's link function (vector leaves' softmax:
-        `links_on_device`)."""
-        from ddt_tpu.ops import predict_oblivious
-
-        if self.cfg.predict_impl in ("lut", "lut4"):
+            prog = entry(ce, ens.n_features, self.PREDICT_ROW_DTYPE, impl,
+                         link)
+            sp.counts["bytes"] = sum(a.nbytes for a in prog.tables)
+        if impl in ("lut", "lut4") and prog.tier != impl:
             log.warning(
-                "predict_impl=%r: the quantized tiers have no oblivious "
-                "form; the f32 oblivious form serves", self.cfg.predict_impl)
-        use_pallas = self._use_pallas
-        classes = ce.n_classes_out
-        plan = predict_oblivious.oblivious_plan(
-            ce.n_trees, ce.depth, ens.n_features,
-            served=predict_ops.resolve_use_pallas(
-                use_pallas, True, 0, ens.n_features, classes,
-                oblivious_depth=ce.depth),
-            row_dtype=self.PREDICT_ROW_DTYPE, n_cls=classes,
-            link=ce.loss if link else "none")
-        with phase_span("predict:ensemble:pack") as sp:
-            tables = ce.arrays()
-            sp.counts["bytes"] = sum(a.nbytes for a in tables)
-        ens_dev = self._put_tables(tables)
-        # Bound here: fn0 outlives this call in the stage registry, and
-        # must not hold the host copy of the tables (197 MB at 8000 trees
-        # x 2000 columns).
-        static = dict(scale=ce.scale, bias=ce.bias, use_pallas=use_pallas,
-                      **({"link": plan.link} if link else {}))
-
-        def fn0(sel, thr, leaf, Xc,
-                entry=predict_ops.predict_raw_effective_oblivious):
-            return entry(sel, thr, leaf, Xc, **static)
-
-        self._stage_scoring_program(
-            predict_ops.predict_raw_effective_oblivious, fn0, ens_dev,
-            ens.n_features)
-        return (self._row_sharded(fn0, 3, classes), ens_dev, "f32", classes,
-                plan)
+                "predict_impl=%r: the %r tier serves this model (its "
+                "layout has no quantized form, or its shape exceeds that "
+                "kernel's VMEM budget)", impl, prog.tier)
+        ens_dev = self._put_tables(prog.fill(prog.tables))
+        if prog.entry is not None:
+            self._stage_scoring_program(prog.entry, prog.fn, ens_dev,
+                                        ens.n_features)
+        return (self._row_sharded(prog.fn, len(ens_dev), prog.columns),
+                ens_dev, prog.classes, prog.plan, prog.tier)
 
     def _put_tables(self, tables) -> tuple:
         """A model's tables up, replicated, one at a time (`tables` may
@@ -2228,14 +1916,12 @@ class TPUDevice(DeviceBackend):
         stages of the programs the single-chip big-batch loop runs: the
         scoring program `entry` (a jitted function of ops/predict.py; fn0
         calls it with the model's static arguments) at the loop's own
-        shapes, the device tables' and a chunk of `predict_chunk_rows` uint8
-        rows, and the loop's two small programs by what they are for.
-        Two dict writes: the lowering happens when somebody asks, at the
-        rows of the backend's LAST call (a batch of fewer rows than a chunk
-        is ONE program of its own rows: a 581,012-row set at 54 columns). A
-        call of another row count runs another executable of the same name,
-        whose instructions the map may not hold; a mesh's row-sharded
-        wrapper is another program and is not named."""
+        shapes, and the loop's two small programs by what they are for.
+        Dict writes: the lowering happens when somebody asks, at the rows
+        of the backend's LAST call (a batch of fewer rows than a chunk is
+        ONE program of its own rows). A call of another row count runs
+        another executable of the same name, whose instructions the map may
+        not hold; a mesh's row-sharded wrapper is not named."""
         if self.distributed:
             return
         avals = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ens_dev]

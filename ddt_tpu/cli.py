@@ -96,38 +96,30 @@ def _predict_phases_ms(since_ns: int) -> "dict | None":
     """Host milliseconds of each step of the device scoring calls whose
     root span `ddt:predict` started at or after `since_ns`
     (time.perf_counter_ns): token, ensemble, upload, dispatch, fetch,
-    place, and with them counts that are no times: the traversal
-    kernel's table plan as the model's `ensemble` span recorded it
-    (ops/predict_pallas.PHASES_COUNTS and, for a node-list or an
-    oblivious model, ops/predict_paths.PHASES_COUNTS or
-    ops/predict_oblivious.PHASES_COUNTS; all 0: that kernel does not serve
-    the model) and `tables_streamed_bytes`, the root spans' sum over the
-    calls. docs/OBSERVABILITY.md has the table of what each means. None
-    when no such call ran: the NumPy backend and raw-threshold scoring
-    open no span."""
+    place, and with them counts that are no times: the kernel's table plan
+    as the model's `ensemble` span recorded it, by the names the scoring
+    layouts list for this line (ops/predict.phases_counts; the heap
+    kernel's all 0 where it does not serve) and `tables_streamed_bytes`,
+    the root spans' sum over the calls (docs/OBSERVABILITY.md says what
+    each means). None when no such call ran: the NumPy backend and
+    raw-threshold scoring open no span."""
     from ddt_tpu.telemetry.annotations import PREFIX, root_spans
 
     roots = [r for r in root_spans("predict") if r["start"] >= since_ns]
     if not roots:
         return None
-    from ddt_tpu.ops import predict_oblivious, predict_paths
-    from ddt_tpu.ops.predict_pallas import PHASES_COUNTS
+    from ddt_tpu.ops.predict import phases_counts
 
     ms = dict.fromkeys(
         ("token", "ensemble", "upload", "dispatch", "fetch", "place"), 0.0)
-    plan = dict.fromkeys(PHASES_COUNTS)
+    names, plan = phases_counts(), {}
     for r in roots:
         for s in r["spans"]:
             step = s["name"].removeprefix(PREFIX + "predict:")
             if step in ms:
                 ms[step] += (s["end"] - s["start"]) / 1e6
-            if step == "ensemble":
-                # a heap model's span has the heap kernel's counts, a
-                # node list's the path form's, an oblivious model's its own
-                plan = {k: s["counts"][k] for k in dict.fromkeys(
-                    PHASES_COUNTS + predict_paths.PHASES_COUNTS
-                    + predict_oblivious.PHASES_COUNTS)
-                    if k in s["counts"]}
+            if step == "ensemble":      # the span has its own layout's
+                plan = {k: s["counts"][k] for k in names if k in s["counts"]}
     return {**{k: round(v, 3) for k, v in ms.items()}, **plan,
             "tables_streamed_bytes": sum(
                 r["counts"]["tables_streamed_bytes"] for r in roots)}

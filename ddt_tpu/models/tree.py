@@ -111,6 +111,9 @@ class TreeEnsemble:
                        self.n_bins)).encode())
         return h.hexdigest()
 
+    layout = "heap"            # of the model and its compiled form: a key
+    #   of ops/predict.LAYOUTS, which names the layout's kernel module
+
     def compile(self, tree_chunk: int = 64) -> "CompiledEnsemble":
         """Host-side compiled scoring layout (see CompiledEnsemble)."""
         return CompiledEnsemble.build(self, tree_chunk=tree_chunk)
@@ -504,6 +507,8 @@ class CompiledEnsemble:
     @property
     def n_trees_padded(self) -> int:
         return int(self.eff_feat.shape[0])
+
+    layout = TreeEnsemble.layout
 
     def arrays(self) -> tuple:
         """Device-uploadable operand tuple in predict_raw_effective's
@@ -947,6 +952,8 @@ class NodeListEnsemble:
             h.update(np.ascontiguousarray(
                 self.cat_bin_sets[self.cat_index[at]]).tobytes())
         return h.hexdigest()
+
+    layout = "node_list"       # (ops/predict.LAYOUTS)
 
     def compile(self, tree_chunk: int = 64) -> "CompiledNodeList":
         """Host-side compiled scoring tables (see CompiledNodeList;
@@ -2217,6 +2224,13 @@ class CompiledNodeList:
         """Whether `paths` holds the two diagonal blocks alone."""
         return self.paths.shape[1] < self.paths.shape[2]
 
+    layout = NodeListEnsemble.layout
+
+    @property
+    def compile_counts(self) -> dict:
+        """Of this form, on the `ddt:predict:ensemble:compile` span."""
+        return {"subtrees": self.n_subtrees}
+
     def arrays(self) -> tuple:
         return (self.sel, self.planes, self.paths) + (
             (self.leaves,) if self.chained else ()) + (
@@ -2708,6 +2722,8 @@ class ObliviousEnsemble:
             h.update(repr(self.leaf_value.shape).encode())
         return h.hexdigest()
 
+    layout = "oblivious"       # (ops/predict.LAYOUTS)
+
     def compile(self, tree_chunk: int = 64) -> "CompiledOblivious":
         """Host-side compiled scoring tables (see CompiledOblivious;
         `tree_chunk` is the heap layout's and means nothing here)."""
@@ -2921,6 +2937,8 @@ class CompiledOblivious:
     thr: np.ndarray
     leaf: np.ndarray
     n_classes_out: int = 1     # C of vector leaves: the answer is [rows, C]
+
+    layout = ObliviousEnsemble.layout
 
     def arrays(self) -> tuple:
         return (self.sel, self.thr, self.leaf)

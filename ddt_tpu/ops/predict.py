@@ -36,6 +36,13 @@ Since the inference-overhaul PR the module exposes THREE related entries:
   depth, features and classes fit the kernel's VMEM budget, at any tree
   count; the one-hot path is the fallback).
 
+What a backend runs is built through `layout_entry` below: ONE table of
+layouts (`LAYOUTS`), each owned by its kernel module, whose entry makes
+the plan, the host tables and the jitted entry of THIS module with the
+model's static arguments bound, kernel or twin among them: chosen on the
+host, once a model, and handed to the trace as a bool. An entry that a
+direct caller gives None asks its layout's rule itself, once.
+
 A FOURTH entry serves the other ensemble layout, the NODE LIST
 (models/tree.NodeListEnsemble: leaf-wise trees too deep and sparse for a
 heap): `predict_raw_effective_paths`, the PATH-MATRIX form (Hummingbird's
@@ -125,6 +132,8 @@ the fallback and what a CPU runs; the Pallas kernel is
 from __future__ import annotations
 
 import functools
+import importlib
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -299,67 +308,89 @@ def traverse(
     return _select_level(k, eff_slot[:, lo:])
 
 
-def resolve_use_pallas(use_pallas, binned: bool, max_depth: int,
-                       n_features: int, n_classes: int,
-                       optional_operands: int = 2,
-                       path_lanes: int = 0,
-                       oblivious_depth: int = 0,
-                       path_exit_lanes: int = 0,
-                       path_cat_blocks: int = 0,
-                       path_select_rows: int = 0) -> bool:
-    """The ONE home of the pallas-vs-one-hot predict dispatch rule.
-
-    None = auto: the Pallas traversal kernel is taken when the data is
-    binned, a real TPU backs the computation, and the kernel's VMEM
-    working set fits (predict_pallas.predict_pallas_fits, which is told
-    how many of the missing and categorical tables the ensemble carries;
-    both, where the caller cannot say). The tree count is no term of the
-    rule: the kernel streams the node tables by blocks of tree groups.
-    `path_lanes` says which LAYOUT asks: 0 a heap ensemble, else a node
-    list of that many lanes a tree (`max_depth` and `optional_operands`
-    mean nothing there), whose kernel is ops/predict_paths.py and whose
-    guard is that kernel's own, `path_exit_lanes` the width of the exits'
-    table of a node list in the SUB-TREE form (its lanes a sub-tree's, and
-    `n_classes` the columns a leaf holds), `path_cat_blocks` the one-hot
-    K-blocks of a node list with CATEGORY SETS and `path_select_rows` all
-    the K rows of its select (read with such blocks alone);
-    `oblivious_depth` an oblivious ensemble
-    of that depth (ops/predict_oblivious.py, `predict_oblivious_fits`;
-    `n_classes` the columns a leaf holds).
-    Explicit True
-    demands the kernel (binned data required — raises otherwise; off-TPU
-    it runs in interpret mode, the test contract); explicit False always
-    takes the one-hot path."""
-    if use_pallas is False:
-        return False
-    from ddt_tpu.ops import predict_pallas
-
+def resolve_use_pallas(use_pallas, binned: bool, fits) -> bool:
+    """What is common to every layout's kernel-or-twin rule
+    (`kernel_serves` of its kernel module). None = auto: the Pallas kernel
+    is taken when the data is binned, a real TPU backs the computation and
+    `fits()` says so: a zero-argument callable the layout HANDS over, its
+    own module's budget predicate (`predict_pallas_fits`,
+    `predict_paths_fits`, `predict_oblivious_fits`), asked on the auto path
+    alone. Explicit True demands the kernel (binned data required: raises
+    otherwise; off-TPU it runs in interpret mode, the test contract; past
+    the budget the kernel's dispatcher refuses by name); explicit False
+    always takes the jax.numpy form."""
     if use_pallas is None:
-        if oblivious_depth:
-            from ddt_tpu.ops import predict_oblivious
-
-            fits = predict_oblivious.predict_oblivious_fits(
-                oblivious_depth, n_features, n_cls=n_classes)
-        elif path_lanes:
-            from ddt_tpu.ops import predict_paths
-
-            fits = predict_paths.predict_paths_fits(
-                path_lanes, n_features,
-                chain=predict_paths.chain_of(1, n_classes, path_exit_lanes)
-                if path_exit_lanes else None,
-                cat=predict_paths.CatSets(path_cat_blocks, path_select_rows)
-                if path_cat_blocks else None)
-        else:
-            fits = predict_pallas.predict_pallas_fits(
-                max_depth, n_features, n_classes,
-                optional_operands=optional_operands)
-        return binned and device.platform() == "tpu" and fits
-    if not binned:
+        return bool(binned and device.platform() == "tpu" and fits())
+    if use_pallas and not binned:
         raise ValueError(
             "use_pallas=True requires binned (integer) data; the Pallas "
             "traversal kernel has no raw-threshold form — use the one-hot "
             "path for float features")
-    return True
+    return bool(use_pallas)
+
+
+# cfg.predict_impl as a rule's `use_pallas` (None = auto). "lut" / "lut4"
+# are the heap layout's quantized tiers: their fallback, and what the other
+# layouts serve them by, is the f32 auto value.
+USE_PALLAS = {"auto": None, "pallas": True, "onehot": False,
+              "lut": None, "lut4": None}
+
+
+class ScoringProgram(typing.NamedTuple):
+    """What a layout's entry makes of one compiled model for a backend
+    (`layout_entry`): all that lies between `ens.compile()` and the chunk
+    loop and knows the layout."""
+
+    plan: typing.Any       # how the tables meet the kernel; the protocol of
+    #   TablePlan / PathPlan / ObliviousPlan: `blocks` (0: the jax.numpy
+    #   form scores), `step_rows(rows)`, `table_bytes`, `span_counts()` (the
+    #   `ddt:predict:ensemble` span's, in order), `root_counts()`
+    tables: tuple          # the host tables, packed, in upload order
+    fn: typing.Callable    # fn(*device tables, rows[, entry=]): `entry` with
+    #   the model's static arguments bound, kernel-or-twin among them (bool)
+    entry: typing.Any      # the jitted entry of this module, whose name is
+    #   the program's (None: a quantized tier, no stage map)
+    columns: int           # of the answer: 1 = [rows], else [rows, classes]
+    classes: int
+    tier: str = "f32"      # what serves: or the heap's "lut" / "lut4"
+    fill: typing.Callable = iter   # tables -> what goes up, each made as it
+    #   is asked for (a padded copy is gone when its transfer is)
+
+
+class Layout(typing.NamedTuple):
+    module: str        # the kernel module that owns it: the entry
+    #   `scoring_program`, the rule `kernel_serves`, `PHASES_COUNTS`
+    links: tuple = ()  # the losses whose link function its program takes
+    #   on the device (`predict_raw(..., link=True)`, stage `predict:link`)
+
+
+# The ONE table of scoring layouts, by the `layout` a model and its
+# compiled form name (models/tree.py). A layout is a row here, its kernel
+# module and its model class: backend, rule and CLI know none by name.
+LAYOUTS = {
+    "heap": Layout("ddt_tpu.ops.predict_pallas"),
+    "node_list": Layout("ddt_tpu.ops.predict_paths", ("softmax",)),
+    "oblivious": Layout("ddt_tpu.ops.predict_oblivious", ("softmax",)),
+}
+
+
+def layout_entry(layout: str) -> typing.Callable:
+    """The ONE lookup: `scoring_program(ce, n_features, row_dtype,
+    predict_impl, link) -> ScoringProgram` of the kernel module that owns
+    `layout` (imported here): the program that scores rows of `n_features`
+    columns of `row_dtype` with the compiled model `ce`. It resolves kernel
+    or twin on the host, once a model (`predict_impl`: cfg.predict_impl),
+    and binds the answer; `link`: the program ends in the model's link
+    function (`Layout.links`)."""
+    return importlib.import_module(LAYOUTS[layout].module).scoring_program
+
+
+def phases_counts() -> tuple:
+    """The plan counts `cli predict` repeats in `phases_ms`: every
+    registered layout's `PHASES_COUNTS`, the heap's first."""
+    return tuple(dict.fromkeys(
+        k for lay in LAYOUTS.values()
+        for k in importlib.import_module(lay.module).PHASES_COUNTS))
 
 
 def _predict_effective(
@@ -385,17 +416,19 @@ def _predict_effective(
         out = jnp.full((0, C), base, jnp.float32)
         return out[:, 0] if C == 1 else out
     Tpad = eff_feat.shape[0]
-    if resolve_use_pallas(use_pallas, binned, max_depth, F, C,
-                          (eff_dl is not None) + (eff_cat is not None)):
+    if use_pallas is not False:     # (the one-hot form imports no kernel)
         from ddt_tpu.ops import predict_pallas
 
-        return predict_pallas.predict_effective_pallas(
-            eff_feat, eff_thr, bot_val, cls_oh, Xc,
-            max_depth=max_depth, learning_rate=learning_rate, base=base,
-            n_classes=C, tree_chunk=tree_chunk,
-            missing_bin_value=missing_bin_value,
-            eff_dl=eff_dl, eff_cat=eff_cat,
-        )
+        if predict_pallas.kernel_serves(
+                use_pallas, binned, max_depth, F, C,
+                (eff_dl is not None) + (eff_cat is not None)):
+            return predict_pallas.predict_effective_pallas(
+                eff_feat, eff_thr, bot_val, cls_oh, Xc,
+                max_depth=max_depth, learning_rate=learning_rate, base=base,
+                n_classes=C, tree_chunk=tree_chunk,
+                missing_bin_value=missing_bin_value,
+                eff_dl=eff_dl, eff_cat=eff_cat,
+            )
     if binned:
         # The kernel above takes the uint8 chunk as it is; the one-hot
         # form compares int32 bins.
@@ -779,7 +812,8 @@ def predict_raw_effective_paths(
 ) -> jax.Array:
     """Raw margins [R] of a node-list ensemble from its compiled tables
     (models/tree.CompiledNodeList): the path-matrix form, by the Pallas
-    kernel (ops/predict_paths.py) where `resolve_use_pallas` says so and by
+    kernel (ops/predict_paths.py) where its rule, `kernel_serves`, says so
+    (`use_pallas` a bool: decided already, nothing is asked) and by
     `_predict_paths` otherwise. Binned rows only, which the kernel takes at
     the width they come in (uint8 from api.predict: nothing is widened in
     XLA). `missing_routes`: the model carries learned NaN directions
@@ -801,10 +835,9 @@ def predict_raw_effective_paths(
         raise ValueError("the path-matrix form scores binned (integer) rows")
     from ddt_tpu.ops import predict_paths
 
-    chain, exit_lanes = None, 0
+    chain = None
     if leaves is not None:
-        exit_lanes = leaves.shape[2]
-        chain = predict_paths.chain_of(n_trees, leaf_columns, exit_lanes,
+        chain = predict_paths.chain_of(n_trees, leaf_columns, leaves.shape[2],
                                        select_spans, paths.shape)
     if link not in ("none", "softmax") or (
             link == "softmax" and (mean or leaf_columns < 2)):
@@ -823,12 +856,8 @@ def predict_raw_effective_paths(
         form["cat"] = (cat_expand, cat_bins)
         form["sets"] = predict_paths.CatSets(
             cat_expand.shape[0], sel.shape[1], select_spans, cat_ordinal_at)
-    if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], leaf_columns,
-                          path_lanes=planes.shape[2],
-                          path_exit_lanes=exit_lanes,
-                          path_cat_blocks=0 if cat_expand is None
-                          else cat_expand.shape[0],
-                          path_select_rows=sel.shape[1]):
+    if predict_paths.kernel_serves(use_pallas, planes.shape[2], Xc.shape[1],
+                                   Xc.dtype, chain, form.get("sets")):
         out = predict_paths.predict_paths_pallas(sel, planes, paths, Xc,
                                                  **form)
     else:
@@ -922,7 +951,8 @@ def predict_raw_effective_oblivious(
 ) -> jax.Array:
     """Raw margins [R] of an oblivious ensemble from its compiled tables
     (models/tree.CompiledOblivious): by the Pallas kernel
-    (ops/predict_oblivious.py) where `resolve_use_pallas` says so and by
+    (ops/predict_oblivious.py) where its rule, `kernel_serves`, says so
+    (`use_pallas` a bool: decided already, nothing is asked) and by
     `_predict_oblivious` otherwise. Binned rows only, which the kernel
     takes at the width they come in (uint8 from api.predict: nothing is
     widened in XLA). Of VECTOR LEAVES (`leaf` C 2^D rows a group, `bias` a
@@ -939,10 +969,10 @@ def predict_raw_effective_oblivious(
     if Xc.shape[0] == 0:
         return jnp.full((0,), bias, jnp.float32) if C == 1 else jnp.zeros(
             (0, C), jnp.float32)
-    if resolve_use_pallas(use_pallas, True, 0, Xc.shape[1], C,
-                          oblivious_depth=sel.shape[1]):
-        from ddt_tpu.ops import predict_oblivious
+    from ddt_tpu.ops import predict_oblivious
 
+    if predict_oblivious.kernel_serves(use_pallas, sel.shape[1], Xc.shape[1],
+                                       Xc.dtype, C):
         out = predict_oblivious.predict_oblivious_pallas(
             sel, thr, leaf, Xc, scale=scale, bias=bias)
     else:

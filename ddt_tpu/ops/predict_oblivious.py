@@ -103,7 +103,7 @@ rounding, the MXU accumulates in float32 and every partial sum is a bin.
 The leaf reached is the bit walk's for every (row, tree); scores agree with
 ops/predict._predict_oblivious to the float32 rounding of a sum in another
 order (equal on dyadic leaf values). Interpret mode auto-selects off-TPU,
-as in predict_pallas.py; dispatch is ops/predict.resolve_use_pallas.
+as in predict_pallas.py; the dispatch rule is `kernel_serves`, below.
 """
 
 from __future__ import annotations
@@ -353,11 +353,11 @@ def predict_oblivious_fits(depth: int, n_features: int,
                            row_dtype=jnp.uint8, n_cls: int = 1) -> bool:
     """Whether one group's tables fit the kernel's VMEM budget beside a row
     tile, and its C multiplexers the trace: the guard behind
-    use_pallas=None (ops/predict.resolve_use_pallas), the ONE rule. The
-    tree count is no term of it."""
-    row_bytes = row_operand_dtype(row_dtype).itemsize
-    return _mux_selects(depth, n_cls) <= _MAX_SELECTS and _vmem_bytes(
-        depth, n_features, row_bytes, n_cls) <= _VMEM_BUDGET_BYTES
+    use_pallas=None (`kernel_serves`, this layout's rule). The tree count
+    is no term of it."""
+    # (the estimate is `_fits`, below the kernel, where the dispatcher's
+    # refusal reads it too; this name is the rule's question)
+    return _fits(depth, n_features, row_dtype, n_cls)
 
 
 def _mux(bits: list, leaves: list, d: int, base: int):
@@ -624,7 +624,7 @@ def predict_oblivious_pallas(
     row_dtype = row_operand_dtype(Xc.dtype)
     with traced_scope("predict:widen"):
         rows = Xc if Xc.dtype == row_dtype else Xc.astype(row_dtype)
-    if not (interpret or predict_oblivious_fits(depth, F, row_dtype, n_cls)):
+    if not (interpret or _fits(depth, F, row_dtype, n_cls)):
         raise ValueError(
             f"oblivious shape (depth {depth}, F={F}, {n_cls} leaf "
             "column(s)) exceeds the Pallas VMEM budget or the resolve's "
@@ -671,3 +671,56 @@ def predict_oblivious_pallas(
         # class-major [C, R] as the kernel leaves it; the `.T` is the
         # layout's (a bitcast, as in the heap program)
         return (jnp.asarray(bias, jnp.float32)[:, None] + scale * acc).T
+
+
+# ---- the OBLIVIOUS layout's entry (ops/predict.LAYOUTS) ----
+
+def _fits(depth: int, n_features: int, row_dtype, n_cls: int) -> bool:
+    """The kernel's budget estimate: one group's VMEM beside a row tile of
+    `row_dtype` rows, and the multiplexers' selects the trace unrolls."""
+    row_bytes = row_operand_dtype(row_dtype).itemsize
+    return _mux_selects(depth, n_cls) <= _MAX_SELECTS and _vmem_bytes(
+        depth, n_features, row_bytes, n_cls) <= _VMEM_BUDGET_BYTES
+
+
+def kernel_serves(use_pallas, depth: int, n_features: int,
+                  row_dtype=jnp.uint8, n_cls: int = 1) -> bool:
+    """The oblivious layout's kernel-or-twin rule: ops/predict.
+    resolve_use_pallas over this kernel's own budget predicate, for trees
+    of `depth` splits and `n_cls` columns a leaf. The tree count is no term
+    of it."""
+    from ddt_tpu.ops.predict import resolve_use_pallas
+
+    return resolve_use_pallas(
+        use_pallas, True,
+        lambda: predict_oblivious_fits(depth, n_features, row_dtype, n_cls))
+
+
+def scoring_program(ce, n_features: int, row_dtype, predict_impl: str,
+                    link: bool):
+    """The oblivious layout's entry (ops/predict.layout_entry): the program
+    of a models/tree.CompiledOblivious, its group tables as they are and
+    ops/predict.predict_raw_effective_oblivious over them: the Pallas
+    kernel where `kernel_serves` takes it, asked here and bound as a bool,
+    else the jax.numpy form. `link`: the program ends in the model's link
+    function (vector leaves' softmax). The quantized tiers have no
+    oblivious form: the f32 program serves them."""
+    from ddt_tpu.ops import predict as predict_ops
+
+    entry = predict_ops.predict_raw_effective_oblivious
+    classes = ce.n_classes_out
+    served = kernel_serves(predict_ops.USE_PALLAS[predict_impl], ce.depth,
+                           n_features, row_dtype, classes)
+    plan = oblivious_plan(ce.n_trees, ce.depth, n_features, served=served,
+                          row_dtype=row_dtype, n_cls=classes,
+                          link=ce.loss if link else "none")
+    # Bound here: fn0 outlives the build in the stage registry, and must
+    # not hold the host copy of the tables.
+    static = dict(scale=ce.scale, bias=ce.bias, use_pallas=served,
+                  **({"link": plan.link} if link else {}))
+
+    def fn0(sel, thr, leaf, Xc, entry=entry):
+        return entry(sel, thr, leaf, Xc, **static)
+
+    return predict_ops.ScoringProgram(plan, ce.arrays(), fn0, entry, classes,
+                                      classes)

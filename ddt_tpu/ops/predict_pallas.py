@@ -223,7 +223,7 @@ compiler, so scores agree to f32 rounding, not bitwise
 (tests/test_predict_pallas.py: equality on dyadic leaf values, a 1e-6
 tolerance on random ones).
 Interpret mode auto-selects off-TPU (utils/device.platform), same pattern
-as hist_pallas.py; dispatch lives in ops/predict.resolve_use_pallas (the
+as hist_pallas.py; the dispatch rule is `kernel_serves`, below (the
 `use_pallas` flag on predict_raw / predict_raw_effective, one-hot fallback).
 """
 
@@ -665,7 +665,7 @@ def predict_pallas_fits(
 ) -> bool:
     """Whether the traversal kernel's VMEM working set fits at this shape —
     the guard behind use_pallas=None auto-dispatch
-    (ops/predict.resolve_use_pallas): the row tile, the working set and
+    (`kernel_serves`, this layout's rule): the row tile, the working set and
     ONE tree group's table windows. The tree count is no term of it: the
     tables stream by blocks of as many groups as fit (`table_plan`)."""
     return table_plan(TREE_GROUP, max_depth, n_features, n_classes, tile_r,
@@ -963,20 +963,20 @@ def predict_effective_pallas(
             f"tree_chunk={tree_chunk}")
     use_missing = eff_dl is not None
     use_cat = eff_cat is not None
-    if not interpret and not predict_pallas_fits(
-            max_depth, F, C, tile_r, use_missing + use_cat):
+    tg = TREE_GROUP
+    plan = table_plan(Tpad, max_depth, F, C, tile_r, use_missing + use_cat,
+                      Xc.dtype)
+    if not interpret and not _plan_fits(plan):
         # Compiled dispatch past the budget means a VMEM OOM or a
-        # pathological Mosaic trace on the chip — fail at the cause. The
-        # auto path (ops/predict.resolve_use_pallas) never gets here;
-        # this guards a forced predict_impl='pallas' at a monster shape.
+        # pathological Mosaic trace on the chip — fail at the cause, read
+        # off the plan the grid is built from. The auto path
+        # (`kernel_serves`, asked once a model) never gets here; this
+        # guards a forced predict_impl='pallas' at a monster shape.
         # Interpret mode (CPU tests) has no VMEM to protect.
         raise ValueError(
             f"predict shape (depth={max_depth}, F={F}, C={C}, "
             f"{use_missing + use_cat} optional operands) exceeds the "
             "Pallas VMEM budget; use the one-hot path")
-    tg = TREE_GROUP
-    plan = table_plan(Tpad, max_depth, F, C, tile_r, use_missing + use_cat,
-                      Xc.dtype)
     if tile_r is None:
         tile_r = plan.step_rows(R)
     # Interpreted past the budget: one block of every group.
@@ -1166,3 +1166,115 @@ def predict_raw_pallas(
         eff_cat=pad_t(cat_node) if cat_node is not None else None,
         tile_r=tile_r, interpret=interpret,
     )
+
+
+# ---- the HEAP layout's entry (ops/predict.LAYOUTS) ----
+
+def _plan_fits(plan: TablePlan) -> bool:
+    """`predict_pallas_fits`, read off the plan a dispatcher has made for
+    its grid: a block of it holds a tree group at all."""
+    return plan.groups_per_step > 0
+
+
+def kernel_serves(use_pallas, binned: bool, max_depth: int, n_features: int,
+                  n_classes: int, optional_operands: int = 2) -> bool:
+    """The heap layout's kernel-or-twin rule: ops/predict.resolve_use_pallas
+    over this kernel's own budget predicate. `optional_operands` counts the
+    missing and categorical tables the ensemble carries (both, where the
+    caller cannot say). The tree count is no term of it."""
+    from ddt_tpu.ops.predict import resolve_use_pallas
+
+    return resolve_use_pallas(use_pallas, binned, lambda: predict_pallas_fits(
+        max_depth, n_features, n_classes,
+        optional_operands=optional_operands))
+
+
+def _lut_program(ce, n_features: int, tier: str):
+    """The quantized program at `tier` ("lut" = int8, "lut4" = int4
+    bit-packed; ops/predict_lut.py), or None when the shape exceeds that
+    kernel's budget (predict_lut_fits / predict_lut4_fits: the caller walks
+    the ladder down). Tables quantize on the host once per model version
+    (`ce.quantize()` memoizes); the error bound rides on the tables
+    (docs/SERVING.md "Quantized serving")."""
+    from ddt_tpu.ops import predict_lut
+    from ddt_tpu.ops.predict import ScoringProgram
+
+    if tier == "lut4":
+        tables = ce.quantize(leaf_dtype="int4")
+        packed = tables.pack_int4()
+        if not predict_lut.predict_lut4_fits(
+                tables.n_trees_padded, tables.tree_chunk,
+                tables.max_depth, n_features, tables.n_classes_out,
+                thr_packed=packed.thr_packed):
+            return None
+        host_ops = packed.ops
+        static = packed.static_kwargs()
+        core = predict_lut.predict_effective_lut4_ops
+    else:
+        tables = ce.quantize()
+        if not predict_lut.predict_lut_fits(
+                tables.n_trees_padded, tables.tree_chunk,
+                tables.max_depth, n_features, tables.n_classes_out):
+            return None
+        host_ops = predict_lut.lut_device_operands(tables)
+        static = dict(
+            max_depth=tables.max_depth,
+            learning_rate=tables.learning_rate,
+            base=tables.base_score, n_classes=tables.n_classes_out,
+            tree_chunk=tables.tree_chunk,
+            n_trees_padded=tables.n_trees_padded,
+            missing_bin_value=tables.missing_bin_value,
+            use_missing=tables.eff_dl is not None,
+            use_cat=tables.eff_cat is not None,
+            use_scale=tables.leaf_scale is not None,
+        )
+        core = predict_lut.predict_effective_lut_ops
+
+    def lut0(*args):
+        *ops, Xc = args
+        return core(tuple(ops), Xc, **static)
+
+    return ScoringProgram(NO_PLAN, tuple(host_ops), jax.jit(lut0), None,
+                          ce.n_classes_out, ce.n_classes_out, tier)
+
+
+def scoring_program(ce, n_features: int, row_dtype, predict_impl: str,
+                    link: bool):
+    """The heap layout's entry (ops/predict.layout_entry): the program of a
+    models/tree.CompiledEnsemble. predict_impl "lut": the int8 quantized
+    tier where its VMEM budget takes the shape, else the f32 program;
+    "lut4": the bit-packed int4 tier one rung up, degrading int4 -> int8 ->
+    f32 down the same guards; the rung that serves is the record's `tier`.
+    The f32 program's plan is `table_plan`'s where the traversal kernel
+    serves (`kernel_serves`, asked here and bound as a bool), NO_PLAN where
+    the one-hot form does. No heap model's link is taken on the device."""
+    from ddt_tpu.ops import predict as predict_ops
+
+    for tier in {"lut4": ("lut4", "lut"), "lut": ("lut",)}.get(
+            predict_impl, ()):
+        lut = _lut_program(ce, n_features, tier)
+        if lut is not None:
+            return lut
+    use_missing = ce.eff_dl is not None
+    use_cat = ce.eff_cat is not None
+    classes, routes = ce.n_classes_out, use_missing + use_cat
+    served = kernel_serves(predict_ops.USE_PALLAS[predict_impl], True,
+                           ce.max_depth, n_features, classes, routes)
+    plan = table_plan(ce.n_trees_padded, ce.max_depth, n_features, classes,
+                      None, routes, row_dtype) if served else NO_PLAN
+    static = dict(
+        max_depth=ce.max_depth, learning_rate=ce.learning_rate,
+        base=ce.base_score, n_classes=classes, tree_chunk=ce.tree_chunk,
+        missing_bin_value=ce.missing_bin_value, use_pallas=served)
+
+    def fn0(ef, et, bv, coh, *rest,
+            entry=predict_ops.predict_raw_effective):
+        *opt, Xc = rest
+        opt = list(opt)
+        dl = opt.pop(0) if use_missing else None
+        cn = opt.pop(0) if use_cat else None
+        return entry(ef, et, bv, coh, Xc, eff_dl=dl, eff_cat=cn, **static)
+
+    return predict_ops.ScoringProgram(
+        plan, ce.arrays(), fn0, predict_ops.predict_raw_effective, classes,
+        classes)

@@ -280,8 +280,8 @@ and of the select one below 2^16, plus M: below 2^24, exact in any order.
 The leaf reached is the node walk's for every (row, tree); scores agree
 with ops/predict._predict_paths to the float32 rounding of a sum in another
 order (equal on dyadic leaf values).
-Interpret mode auto-selects off-TPU, as in predict_pallas.py; dispatch is
-ops/predict.resolve_use_pallas.
+Interpret mode auto-selects off-TPU, as in predict_pallas.py; the dispatch
+rule is `kernel_serves`, below.
 """
 
 from __future__ import annotations
@@ -567,8 +567,8 @@ class PathPlan(typing.NamedTuple):
     #   table's (W/128 x its lane tiles: 2 at 256 lanes where the class
     #   pieces and the chain share ONE tile, `Chain.shared`; 4 or more
     #   where each has tiles of its own; 0 without a chain)
-    # What the model's cut looks like beside its mean (the backend fills
-    # them from models/tree.CompiledNodeList), and the link function the
+    # What the model's cut looks like beside its mean (`scoring_program`
+    # fills them from models/tree.CompiledNodeList), and the link function the
     # program applies to the margins on the device ("none": the caller's).
     subtrees_per_tree_max: int = 1      # the largest tree's table entries
     single_subtree_trees: int = 0       # trees that are ONE entry
@@ -744,7 +744,7 @@ def predict_paths_fits(lanes: int, n_features: int,
                        chain: Chain | None = None,
                        cat: CatSets | None = None) -> bool:
     """Whether one tree's tables fit the kernel's VMEM budget beside a row
-    tile: the guard behind use_pallas=None (ops/predict.resolve_use_pallas).
+    tile: the guard behind use_pallas=None (`kernel_serves`, the rule).
     The tree count is no term of it. `chain`: a model in the sub-tree
     form; one sub-tree's tables beside the output's windows and the
     activity."""
@@ -762,7 +762,7 @@ def pack_select(sel, planes, n_features: int, xp=jnp) -> tuple:
     0 and 3) moved to their byte of the word the matmul returns, M + bin_a +
     256 bin_b: b > thr is word > M + 256 (thr + 1) - 1, b < up is word <
     M + 256 up; +BIG stays +BIG. `xp` numpy: on the host, once a model
-    (backends/tpu._build_paths_fn); jax.numpy: inside the caller's program."""
+    (`scoring_program`, below); jax.numpy: inside the caller's program."""
     wp = _select_shape(sel.shape[2], n_features, 2)[1]
     stride = _copy_stride(n_features)
 
@@ -1068,7 +1068,7 @@ def predict_paths_pallas(
     with traced_scope("predict:widen"):
         rows = Xc if Xc.dtype == row_dtype else Xc.astype(row_dtype)
     plan = path_plan(T, lanes, F, row_dtype=row_dtype, chain=chain, cat=sets)
-    if not predict_paths_fits(lanes, F, row_dtype, chain, sets):
+    if not _plan_fits(plan):    # (as `predict_paths_fits`: no block)
         if not interpret:
             raise ValueError(
                 f"path-matrix shape ({lanes} lanes a tree, F={F}) exceeds "
@@ -1078,7 +1078,7 @@ def predict_paths_pallas(
     g, n_blocks = plan.trees_per_step, plan.table_blocks
     # Trees that fill the last block: no node, and no leaf of any length
     # (-1), so they add 0. A backend hands the tables over whole blocks
-    # long already (backends/tpu._build_paths_fn: padded once a model, on
+    # long already (`scoring_program`'s fill: padded once a model, on
     # the host), and these pads are of no tree: no instruction.
     t_fill = ((0, n_blocks * g - T), (0, 0), (0, 0))
     with traced_scope("predict:tables"):
@@ -1177,3 +1177,112 @@ def fold_leaf_pieces(acc, chain: Chain, learning_rate, base, mean: bool):
             return total / jnp.float32(chain.n_trees)
         margins = base + learning_rate * total
         return margins[:, 0] if c == 1 else margins
+
+
+# ---- the NODE LIST's entry (ops/predict.LAYOUTS) ----
+
+def _plan_fits(plan: PathPlan) -> bool:
+    """`predict_paths_fits`, read off the plan the dispatcher has made for
+    its grid: a block of it holds a tree at all."""
+    return plan.trees_per_step > 0
+
+
+def kernel_serves(use_pallas, lanes: int, n_features: int,
+                  row_dtype=jnp.uint8, chain: Chain | None = None,
+                  cat: CatSets | None = None) -> bool:
+    """The node list's kernel-or-twin rule: ops/predict.resolve_use_pallas
+    over this kernel's own budget predicate, for trees (sub-trees, with
+    `chain`) of `lanes` lanes; `cat`: the model carries category sets. The
+    tree count is no term of it."""
+    from ddt_tpu.ops.predict import resolve_use_pallas
+
+    return resolve_use_pallas(use_pallas, True, lambda: predict_paths_fits(
+        lanes, n_features, row_dtype, chain, cat))
+
+
+def scoring_program(ce, n_features: int, row_dtype, predict_impl: str,
+                    link: bool):
+    """The node list's entry (ops/predict.layout_entry): the program of a
+    models/tree.CompiledNodeList, its path tables as they go up and
+    ops/predict.predict_raw_effective_paths over them: the Pallas kernel
+    where `kernel_serves` takes it, asked here and bound as a bool, else
+    the jax.numpy form. `link`: the program ends in the model's link
+    function (softmax's round-major trees). The quantized tiers have no
+    node-list form: the f32 program serves them."""
+    import numpy as np
+
+    from ddt_tpu.ops import predict as predict_ops
+
+    entry = predict_ops.predict_raw_effective_paths
+    missing_routes = ce.missing_bin_value >= 0
+    # The sub-tree form: the tables' entries are sub-trees, a fourth table
+    # says what their exits are, and vector leaves (or softmax's
+    # round-major trees) answer [rows, C].
+    classes = ce.leaf_columns
+    chain = chain_of(ce.n_trees, classes, ce.leaves.shape[2],
+                     ce.select_spans, ce.paths.shape) if ce.chained else None
+    # CATEGORY SETS (module docstring): the one-hot's K-blocks
+    cat = CatSets(ce.cat_blocks, ce.sel.shape[1], ce.select_spans,
+                  ce.cat_ordinal_at) if ce.cat_blocks else None
+    served = kernel_serves(predict_ops.USE_PALLAS[predict_impl], ce.lanes,
+                           n_features, row_dtype, chain, cat)
+    entries = max(ce.n_subtrees, 1)
+    plan = path_plan(
+        ce.n_subtrees or ce.n_trees, ce.lanes, n_features, ce.deepest_leaf,
+        served=served, missing_routes=missing_routes, row_dtype=row_dtype,
+        chain=chain, widest_tree=ce.widest_tree, cat=cat)._replace(
+            category_nodes=ce.category_nodes,
+            category_set_bits_max=ce.category_set_bits_max,
+            subtrees_per_tree_max=ce.subtrees_max,
+            single_subtree_trees=ce.single_subtree_trees,
+            link=ce.loss if link else "none",
+            spine_copies_per_subtree=round(ce.spine_copies / entries, 2),
+            pieces_per_subtree=round((ce.pieces or 1) / entries, 2),
+            glue_copies_per_subtree=round(ce.glue_copies / entries, 2))
+    # What every chunk's program would otherwise make of the tables is
+    # made here, once a model: the select that answers two nodes a lane
+    # with its shifted thresholds (`pack_select`), and the trees that fill
+    # the kernel's last block (no node, no leaf of any length: they add 0).
+    tables = ce.arrays()
+    if plan.select_nodes_per_lane == 2:
+        tables = (*pack_select(ce.sel, ce.planes, n_features, xp=np),
+                  *tables[2:])
+    short = max(0, plan.trees_per_step * plan.table_blocks - len(ce.sel))
+    # (the two small tables of category sets are the model's, not a
+    # tree's: they go up as they are)
+    per_tree = 4 if chain else 3
+
+    def fill(tables):
+        """The fill goes on inside the put, a table at a time: a padded
+        copy is gone when its transfer is."""
+        pad = ((0, short), (0, 0), (0, 0))
+        for i, (a, v) in enumerate(zip(tables, (0, -1.0, 0, 0, 0))):
+            yield np.pad(a, pad, constant_values=v) \
+                if short and i < per_tree else a
+
+    # Bound here: fn0 outlives the build in the stage registry, and must
+    # not hold the host copy of the path tables.
+    static = dict(learning_rate=ce.learning_rate, base=ce.base_score,
+                  use_pallas=served, missing_routes=missing_routes)
+    if chain:
+        static.update(n_trees=ce.n_trees, leaf_columns=classes,
+                      mean=ce.mean, select_spans=chain.select_spans,
+                      **({"link": plan.link} if link else {}))
+    elif cat and cat.spans:
+        static.update(select_spans=cat.spans, cat_ordinal_at=cat.ordinal_at)
+
+    # (three functions: a program's HLO names its parameters)
+    def fn0(sel, planes, paths, Xc, entry=entry):
+        return entry(sel, planes, paths, Xc, **static)
+
+    def fn0_chain(sel, planes, paths, leaves, Xc, entry=entry):
+        return entry(sel, planes, paths, Xc, leaves=leaves, **static)
+
+    def fn0_sets(sel, planes, paths, cat_expand, cat_bins, Xc, entry=entry):
+        return entry(sel, planes, paths, Xc, cat_expand=cat_expand,
+                     cat_bins=cat_bins, **static)
+
+    # an averaged forest answers [rows, C] whatever C, one column too
+    return predict_ops.ScoringProgram(
+        plan, tables, fn0_chain if chain else fn0_sets if cat else fn0,
+        entry, 2 if ce.mean or classes > 1 else 1, classes, fill=fill)

@@ -30,8 +30,10 @@ What is compiled:
 - the whole fused-rounds program TPUDevice builds
   (`TPUDevice._build_rounds_fn`) on one device, on a rows=4 mesh and on a
   2x2 (rows x features) mesh over the four described chips, and the
-  scoring program (`TPUDevice._predict_fn`), for heap ensembles, for a
-  node list (the path-matrix form) and for an oblivious ensemble.
+  scoring program (`TPUDevice._predict_entry`) of every cell of
+  BENCHMARK.json: heap ensembles, node lists (the path-matrix form, its
+  chained sub-trees with and without the link, category sets) and
+  oblivious ensembles.
 
 Exit 0 iff every default-dispatch case compiled; opt-in kernels
 (grad_dtype=int8|int16, predict_impl=lut|lut4) are reported and do not
@@ -196,17 +198,24 @@ def _predict_case(rows, features, n_trees, depth, n_classes=1,
 
 
 def _random_node_list(n_trees, n_leaves, features, missing=False,
-                      categories=()):
+                      categories=(), leaf_columns=0, n_classes=1):
     """A random leaf-wise ensemble (seeded) as a models/tree
     NodeListEnsemble; `missing`: with learned NaN directions;
-    `categories`: (column, cardinality) pairs that ask category sets."""
+    `categories`: (column, cardinality) pairs that ask category sets;
+    `leaf_columns`: an averaged forest of vector leaves (`n_leaves` may be
+    a range each tree draws from); `n_classes` > 1: softmax's round-major
+    trees."""
     import numpy as np
 
     from ddt_tpu.models.tree import random_node_list
 
+    meta = dict(learning_rate=0.1, base_score=0.0, loss="logloss")
+    if leaf_columns:
+        meta = dict(leaf_columns=leaf_columns)
+    elif n_classes > 1:
+        meta.update(loss="softmax", n_classes=n_classes)
     return random_node_list(np.random.default_rng(7), n_trees, n_leaves,
-                            features, learning_rate=0.1, base_score=0.0,
-                            loss="logloss", missing=missing,
+                            features, missing=missing, **meta,
                             **({"categories": categories} if categories
                                else {}))
 
@@ -687,11 +696,16 @@ def _rounds_program(topo_devices, *, rows, features, n_rounds, mesh_shape,
 
 
 def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
-                     n_classes=1, routed=False, leaves=0, oblivious=False):
+                     n_classes=1, routed=False, leaves=0, oblivious=False,
+                     leaf_columns=0, categories=()):
     """`routed`: a heap with the missing and the categorical table; a node
-    list (`leaves`) with learned NaN directions. `oblivious`: symmetric
-    trees of `depth` (models/tree.ObliviousEnsemble), of `n_classes` > 1
-    vector leaves and the program that ends in their softmax."""
+    list (`leaves`: a count, or a range (lo, hi) each tree draws from) with
+    learned NaN directions. `oblivious`: symmetric trees of `depth`
+    (models/tree.ObliviousEnsemble), of `n_classes` > 1 vector leaves and
+    the program that ends in their softmax. Of a node list: `leaf_columns`
+    an averaged forest's vector leaves, `n_classes` > 1 softmax's
+    round-major trees and the program that ends in their softmax,
+    `categories` the (column, cardinality) pairs that ask category sets."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -709,7 +723,8 @@ def _scoring_program(topo_devices, *, rows, features, n_trees, depth,
                                features, scale=0.5, bias=0.25,
                                n_classes=n_classes if n_classes > 1 else 0)
     else:
-        ens = (_random_node_list(n_trees, leaves, features, routed)
+        ens = (_random_node_list(n_trees, leaves, features, routed,
+                                 categories, leaf_columns, n_classes)
                if leaves else
                _random_ensemble(n_trees, depth, features, n_classes, routed,
                                 routed))
@@ -744,13 +759,13 @@ def program_cases(topo_devices) -> list:
         return build
 
     def scoring(n_trees, rows=hr, features=hf, depth=6, n_classes=1,
-                routed=False, leaves=0, oblivious=False):
+                routed=False, leaves=0, oblivious=False, **node_list):
         def build():
             fn, args = _scoring_program(topo_devices, rows=rows,
                                         features=features, n_trees=n_trees,
                                         depth=depth, n_classes=n_classes,
                                         routed=routed, leaves=leaves,
-                                        oblivious=oblivious)
+                                        oblivious=oblivious, **node_list)
             return fn, args, ["tpu_custom_call"]
         return build
 
@@ -798,6 +813,25 @@ def program_cases(topo_devices) -> list:
         ("scoring/covtype-catboost/1000x6xC7/oblivious", scoring(
             1000, rows=2_000_000, features=COVERTYPE["features"], depth=6,
             n_classes=7, oblivious=True)),
+        # The three other cells' programs, each at its cell's rows and
+        # columns over a smaller random model of the cell's FORM (the
+        # tables' entries are fewer; what the program is made of is the
+        # cell's). scikit-learn's MNIST forest: vector leaves, every tree
+        # cut into chained sub-trees, the select by its spans.
+        ("scoring/mnist-rf/12x700-1400leavesxC10/chain", scoring(
+            12, rows=FOREST["chunk_rows"], features=FOREST["features"],
+            depth=0, leaves=(700, 1400), leaf_columns=10)),
+        # XGBoost's deep Covertype model: softmax's round-major trees in
+        # the sub-tree form, halved, under the packed select; one program
+        # of the set's own rows that ends in the softmax (`link=True`).
+        ("scoring/covtype-xgb/21x33-3000leaves/softmax7/link", scoring(
+            21, rows=XGB["rows"], features=XGB["features"], depth=0,
+            leaves=(33, 3000), n_classes=7)),
+        # LightGBM's Allstate model: category sets and ordinal nodes in
+        # one tree, under the spans the build finds for this random model.
+        ("scoring/allstate-lgbm/500x255leaves/cat", scoring(
+            500, rows=2_000_000, features=ALLSTATE["features"], depth=0,
+            leaves=255, categories=ALLSTATE["categories"])),
     ]
 
 

@@ -274,15 +274,12 @@ def test_auto_takes_the_kernel_where_the_plan_fits():
     assert fits(6, 2000) and fits(8, 200) and fits(1, 28) and fits(10, 28)
     assert not fits(11, 28)              # the multiplexer's trace
     assert not fits(10, 2000)            # VMEM
+    serves = predict_oblivious.kernel_serves    # the layout's own rule
     with device.assume_platform("tpu"):
-        assert predict_ops.resolve_use_pallas(None, True, 0, 2000, 1,
-                                              oblivious_depth=6)
-        assert not predict_ops.resolve_use_pallas(None, True, 0, 28, 1,
-                                                  oblivious_depth=11)
-    assert not predict_ops.resolve_use_pallas(None, True, 0, 2000, 1,
-                                              oblivious_depth=6)   # a CPU
-    assert predict_ops.resolve_use_pallas(True, True, 0, 28, 1,
-                                          oblivious_depth=11)
+        assert serves(None, 6, 2000)
+        assert not serves(None, 11, 28)
+    assert not serves(None, 6, 2000)     # a CPU
+    assert serves(True, 11, 28)
 
 
 def test_the_plan_at_the_epsilon_models_shape():

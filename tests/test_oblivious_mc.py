@@ -293,11 +293,9 @@ def test_the_rule_is_told_the_leaf_columns():
     plane = 1024 * 128 * 4
     assert vmem(9, 54, 1, 3) - vmem(9, 54, 1, 1) == (
         2 * 2 * 512 * 128 * 4 - 3 * plane + plane)
-    with device.assume_platform("tpu"):
-        assert predict_ops.resolve_use_pallas(None, True, 0, 54, 7,
-                                              oblivious_depth=6)
-        assert not predict_ops.resolve_use_pallas(None, True, 0, 54, 7,
-                                                  oblivious_depth=8)
+    with device.assume_platform("tpu"):     # the layout's own rule
+        assert predict_oblivious.kernel_serves(None, 6, 54, n_cls=7)
+        assert not predict_oblivious.kernel_serves(None, 8, 54, n_cls=7)
     # where the rule says no, the twin serves, and the span says so
     deep = vector_model(300, 3, 8, 6, 7)
     with device.assume_platform("tpu"):

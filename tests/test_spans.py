@@ -306,7 +306,7 @@ def test_the_ensemble_span_says_the_rows_a_step(cell, T, depth, F, C, routed,
     ens.is_leaf[:, n_int:] = True
     be = get_backend(TrainConfig(backend="tpu", n_bins=255, max_depth=depth,
                                  predict_impl="pallas"))
-    *_, plan = be._predict_entry(ens)
+    plan = be._predict_entry(ens)[3]
     counts = [s for s in an.recent_spans()
               if s["name"] == "ddt:predict:ensemble"][-1]["counts"]
     assert counts["trees"] == T
